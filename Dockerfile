@@ -5,7 +5,12 @@
 #   docker build -t petals_tpu .
 #   docker run --privileged --network host \
 #       -v /cache:/cache -e PETALS_TPU_CACHE=/cache \
+#       -e JAX_COMPILATION_CACHE_DIR=/cache/jax \
 #       petals_tpu python -m petals_tpu.cli.run_server MODEL --initial_peers ...
+#
+# One server process per chip: on a multi-chip VM start one container (or
+# process) per chip with TPU_VISIBLE_CHIPS=<n> TPU_CHIPS_PER_PROCESS_BOUNDS=1,1,1
+# TPU_PROCESS_BOUNDS=1,1,1 (README "Running on the chip").
 #
 # --privileged + host networking are the standard TPU-VM container settings
 # (the TPU driver is exposed via /dev and the swarm needs inbound dials).
@@ -21,8 +26,9 @@ RUN apt-get update && apt-get install -y --no-install-recommends \
   g++ \
   && apt-get clean autoclean && rm -rf /var/lib/apt/lists/* /tmp/* /var/tmp/*
 
-# TPU-enabled jax (pulls libtpu); CPU torch only for checkpoint IO
-RUN pip install --no-cache-dir "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html && \
+# TPU-enabled jax (pulls libtpu), the one series pyproject.toml declares and
+# the code is written against; CPU torch only for checkpoint IO
+RUN pip install --no-cache-dir "jax[tpu]>=0.9,<0.10" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html && \
     pip install --no-cache-dir torch --index-url https://download.pytorch.org/whl/cpu && \
     rm -rf ~/.cache/pip
 

@@ -354,6 +354,9 @@ def run_bench(*, cfg=None, n_sessions=48, duration_s=600.0):
 
 
 if __name__ == "__main__":
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     import json
 
     print(json.dumps(run_bench(), indent=2))

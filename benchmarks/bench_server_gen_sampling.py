@@ -161,6 +161,9 @@ def run_bench(n_sessions: int = N_SESSIONS, gen_chunk: int = GEN_CHUNK,
 
 
 if __name__ == "__main__":
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     import json
 
     print(json.dumps(run_bench(), indent=2))

@@ -217,6 +217,9 @@ def run_bench():
 
 
 if __name__ == "__main__":
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     import json
 
     print(json.dumps(run_bench(), indent=2))

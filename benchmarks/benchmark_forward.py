@@ -46,4 +46,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     main()

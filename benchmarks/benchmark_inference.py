@@ -61,4 +61,7 @@ def benchmark_inference(proc_idx, args):
 
 
 if __name__ == "__main__":
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     main()

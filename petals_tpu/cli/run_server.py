@@ -185,6 +185,9 @@ def parse_block_range(args) -> tuple:
 def main(argv=None) -> None:
     import os
 
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     args = build_parser().parse_args(argv)
     first_block, num_blocks = parse_block_range(args)
 

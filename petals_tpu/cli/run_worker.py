@@ -24,6 +24,9 @@ import argparse
 
 
 def main() -> None:
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("model", help="model path or repo id (must match the leader's)")
     parser.add_argument("--coordinator_address", required=True)

@@ -41,9 +41,8 @@ def _attend_sharded(
     ``shard_seq`` the QUERY sequence additionally shards over the "sp" axis —
     the KV-cached prefill path, where each device attends its query shard
     against the replicated cache with a rank-adjusted ``q_offset``."""
+    import jax
     from jax.sharding import PartitionSpec as P
-
-    from petals_tpu.ops.shmap import shard_map_no_check
 
     head_axis = "tp" if mesh.shape.get("tp", 1) > 1 else None
     seq_axis = "sp" if shard_seq else None
@@ -57,8 +56,6 @@ def _attend_sharded(
         kv_length = k.shape[1]
 
     def per_shard(q_, k_, v_, q_offset_, kv_length_, slopes_):
-        import jax
-
         if shard_seq:
             q_offset_ = q_offset_ + jax.lax.axis_index("sp") * q_.shape[1]
         return attend(
@@ -71,11 +68,13 @@ def _attend_sharded(
             use_flash=use_flash,  # per-device: the Mosaic kernel needs no GSPMD rule here
         )
 
-    fn = shard_map_no_check(
+    # check_vma off: the Mosaic custom call inside has no replication rule
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(qspec, kvspec, kvspec, P(), P(), P(head_axis)),
         out_specs=qspec,
+        check_vma=False,
     )
     return fn(
         q, k, v,

@@ -29,8 +29,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from petals_tpu.telemetry.observatory import tracked_jit
 
-# jax<0.5 names this TPUCompilerParams; alias locally, never patch jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 LANES = 128
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -347,7 +345,7 @@ def flash_attend(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

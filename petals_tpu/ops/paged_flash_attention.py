@@ -14,6 +14,15 @@ are never fetched at all (their DMA is redirected to a repeated block index,
 which Pallas elides). Dense is just the identity block table, so the same
 kernel serves the dense-shaped steps too.
 
+What the chip said (v5e, PR 21): Mosaic takes the pool only through a
+lane-trailing VIEW ([n_pages, page_size, hkv * d], see the notes above
+``_kv_heads_per_block``), and under TPU tiling that view is a physical
+relayout — XLA copies the whole per-layer K and V pool in front of every
+call. With that and a grid of n_lanes * hkv * max_pages one-page steps, the
+autotune picks the XLA-composed path for decode on the default pool (PERF.md
+section 5). Storing the pool lane-trailing, or fetching all kv heads of a
+page per step, is ROADMAP S3.
+
 Structure is lifted from ops/flash_attention.py: online-softmax m/l/acc
 scratch carried across the innermost (arbitrary) grid axis, a shared
 "needed" predicate between the kernel's @pl.when skip and the index map's
@@ -26,10 +35,12 @@ Path selection (``paged_attend_dispatch``, reached via ops/attention.py
 attend() on a PagedKV): per (n_lanes, max_pages, page_size, hkv, d, window)
 shape class, an autotune harness on the maybe_autotune_nf4_decode pattern
 times kernel-vs-XLA-composed on the real chip at startup and traces the
-winner into the step program. ``PETALS_TPU_PAGED_KERNEL=pallas|xla|auto``
+winner into the step program; shape classes Mosaic cannot tile are kept off
+the kernel by a static predicate (``paged_kernel_unsupported``), never by
+catching a failed compile. ``PETALS_TPU_PAGED_KERNEL=pallas|xla|auto``
 overrides; off-TPU the XLA-composed path (gather_pages + attend_reference)
-is the guaranteed fallback, so tier-1 CPU runs never depend on interpret-
-mode Mosaic semantics unless a test asks for the kernel explicitly.
+is what runs, so tier-1 CPU runs never depend on interpret-mode Mosaic
+semantics unless a test asks for the kernel explicitly.
 """
 
 from __future__ import annotations
@@ -46,9 +57,6 @@ from jax.experimental.pallas import tpu as pltpu
 from petals_tpu.ops.quant import NF4A_A, NF4A_B
 from petals_tpu.telemetry.observatory import tracked_jit
 
-# jax<0.5 names this TPUCompilerParams; alias locally, never patch jax
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 LANES = 128
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -59,6 +67,8 @@ _MODES = ("pallas", "xla", "auto")
 # Populated by maybe_autotune_paged_attention on TPU, or by tests via
 # set_paged_kernel_decision; consulted at TRACE time by the dispatch.
 _AUTOTUNE: dict = {}
+# shape class -> (pallas_ms, xla_ms) per decode step, as the autotune timed it
+_AUTOTUNE_MS: dict = {}
 
 
 def kernel_mode() -> str:
@@ -91,17 +101,50 @@ def shape_class(
     )
 
 
+def paged_kernel_unsupported(key: Tuple) -> Optional[str]:
+    """Why Mosaic cannot take this shape class, or None if it can — the
+    static gate in front of the kernel (flash_supported's twin). A KV block
+    must end in a lane multiple or the whole [hkv * d_store] row (see the
+    view notes above ``_kv_heads_per_block``): head widths that neither are a
+    multiple of 128 nor pack evenly into 128 lanes are composed from XLA."""
+    _, _, _, hkv, d, _, kv_quant = key
+    d_store = _kv_store_dim(d, kv_quant)
+    hb = _kv_heads_per_block(hkv, d_store)
+    if (hb * d_store) % LANES and hkv != 1:
+        return (
+            f"{hkv} kv heads of {d_store} stored lanes ({kv_quant} pages, head_dim {d}) "
+            f"do not tile into {LANES}-lane blocks"
+        )
+    return None
+
+
+_WARNED_UNSUPPORTED: set = set()
+
+
 def decide_paged_kernel(kind: str, key: Tuple) -> bool:
     """TRACE-time path choice for one shape class. pallas/xla modes force;
     auto uses the autotuned winner (untuned TPU shapes default to the kernel,
     untuned prefill shapes inherit the decode decision for the same class),
-    and non-TPU platforms always take the guaranteed XLA fallback."""
+    shape classes Mosaic cannot take (``paged_kernel_unsupported``) compose
+    from XLA with one WARNING, and non-TPU platforms always take the XLA
+    path."""
     mode = kernel_mode()
     if mode == "pallas":
         return True
     if mode == "xla":
         return False
     if _platform() != "tpu":
+        return False
+    reason = paged_kernel_unsupported(key)
+    if reason is not None:
+        if key not in _WARNED_UNSUPPORTED:
+            _WARNED_UNSUPPORTED.add(key)
+            from petals_tpu.utils.logging import get_logger
+
+            get_logger(__name__).warning(
+                f"paged-attention kernel excluded for shape class {key}: {reason}; "
+                f"attention composes from XLA (gather + attend_reference)"
+            )
         return False
     return _AUTOTUNE.get((kind, *key), _AUTOTUNE.get(("decode", *key), True))
 
@@ -120,6 +163,12 @@ def set_paged_kernel_decision(kind: str, key: Tuple, use_pallas: bool) -> None:
 
 def reset_paged_autotune() -> None:
     _AUTOTUNE.clear()
+    _AUTOTUNE_MS.clear()
+
+
+def paged_autotune_timings() -> dict:
+    """{shape class: (pallas_ms, xla_ms)} for every class timed so far."""
+    return dict(_AUTOTUNE_MS)
 
 
 # ---------------------------------------------------------------------------
@@ -199,6 +248,58 @@ def _kv_store_dim(head_dim: int, kv_quant: str) -> int:
     return head_dim // 2 if kv_quant == "nf4a" else head_dim
 
 
+# Mosaic block rule: a block's last two dims must be multiples of (8, 128) or
+# span the whole array dims. A [n_pages, page_size, hkv, d] pool blocked one
+# head at a time ends in (1, d) and is refused, so the wrappers hand the
+# kernels VIEWS with page_size/lanes trailing:
+#   codes/values  [n_pages, page_size, hkv * d_store], block (1, page_size,
+#                 hb * d_store) at (page, 0, h // hb) — heads narrower than
+#                 128 lanes ride ``hb`` to a block and the tile slices its own;
+#   scales        [n_pages, hkv, page_size] (transposed), block (1, hkv,
+#                 page_size) — the tile reads row h, already lane-major;
+#   alibi slopes  [hkv, group, 1], block (1, group, 1) — a ready column.
+
+
+def _kv_heads_per_block(num_kv_heads: int, d_store: int) -> int:
+    """kv heads per KV block: 128 // d_store when that tiles the heads, else
+    one (which Mosaic takes only if d_store is a lane multiple or hkv == 1 —
+    ``paged_kernel_unsupported`` is the static gate; the interpreter takes
+    any)."""
+    per = LANES // d_store if d_store < LANES and LANES % d_store == 0 else 1
+    return per if num_kv_heads % per == 0 else 1
+
+
+def _pool_views(k_pool, v_pool, quantized: bool):
+    """The pool operands in kernel order — k, [ks], v, [vs] — as the
+    lane-trailing views described above."""
+    def codes_view(a):
+        return a.reshape(a.shape[0], a.shape[1], -1)
+
+    if not quantized:
+        return [codes_view(k_pool), codes_view(v_pool)]
+    return [
+        codes_view(k_pool.codes), k_pool.scales.transpose(0, 2, 1),
+        codes_view(v_pool.codes), v_pool.scales.transpose(0, 2, 1),
+    ]
+
+
+def _head_block(ref, t: int, d_store: int):
+    """[page_size, d_store] of the t-th head in a kv block ref
+    [1, page_size, hb * d_store] (raw codes if quantized)."""
+    return ref[0, :, t * d_store:(t + 1) * d_store]
+
+
+def _tile_branches(tile, needed, interior, kv_head, heads_per_block: int):
+    """Run ``tile(masked, t)`` for this grid step: interior pages skip the
+    mask work, and when a kv block carries several heads the head's slot
+    ``t = kv_head % heads_per_block`` picks a statically sliced variant (a
+    dynamic lane offset is not something Mosaic slices by)."""
+    for t in range(heads_per_block):
+        mine = needed if heads_per_block == 1 else needed & (kv_head % heads_per_block == t)
+        pl.when(mine & interior)(functools.partial(tile, False, t))
+        pl.when(mine & jnp.logical_not(interior))(functools.partial(tile, True, t))
+
+
 # ---------------------------------------------------------------------------
 # decode kernel: grid (n_lanes, hkv, max_pages), one token row per lane
 # ---------------------------------------------------------------------------
@@ -223,9 +324,9 @@ def _decode_kernel(
     kv_lens_ref,  # int32[n_lanes]
     # then, positionally: inputs / outputs / scratch —
     #   q_ref [1, 1, group, head_dim];
-    #   k_ref [1, page_size, 1, d_store] (one page; raw codes if quantized);
-    #   ks_ref [1, page_size, 1] f32 (quantized pools only);
-    #   v_ref / vs_ref likewise; slopes_ref [1, group] f32;
+    #   k_ref [1, page_size, hb * d_store] (one page of hb heads; raw codes
+    #   if quantized); ks_ref [1, hkv, page_size] f32 (quantized pools only);
+    #   v_ref / vs_ref likewise; slopes_ref [1, group, 1] f32;
     #   o_ref [1, 1, group, head_dim];
     #   m/l_scratch [group, LANES] f32, acc_scratch [group, head_dim] f32
     *refs,
@@ -234,6 +335,7 @@ def _decode_kernel(
     max_pages: int,
     group: int,
     head_dim: int,
+    heads_per_block: int,
     use_alibi: bool,
     sliding_window: Optional[int] = None,
     kv_quant: str = "none",
@@ -245,7 +347,9 @@ def _decode_kernel(
         (q_ref, k_ref, ks_ref, v_ref, vs_ref, slopes_ref, o_ref,
          m_scratch, l_scratch, acc_scratch) = refs
     i = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
+    d_store = _kv_store_dim(head_dim, kv_quant)
 
     kv_len = kv_lens_ref[i]
     page = tables_ref[i, j]
@@ -265,23 +369,23 @@ def _decode_kernel(
     if sliding_window is not None:
         interior &= slot_start >= kv_len - sliding_window
 
-    def _tile(masked: bool):
-        q = q_ref[...].reshape(group, head_dim)
+    def _tile(masked: bool, t: int):
+        q = q_ref[0, 0]  # [group, head_dim]
         if kv_quant == "none":
-            k = k_ref[...].reshape(page_size, head_dim)
+            k = _head_block(k_ref, t, d_store)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )  # [group, page_size] f32
         else:
-            k_raw = k_ref[...].reshape(page_size, -1)
-            ks_row = ks_ref[...].reshape(1, page_size)
-            s = _quant_k_scores(q, k_raw, ks_row, kv_quant, head_dim)
+            ks_row = ks_ref[0, pl.ds(h, 1), :]  # [1, page_size]
+            s = _quant_k_scores(
+                q, _head_block(k_ref, t, d_store), ks_row, kv_quant, head_dim
+            )
         s = s * scale
 
         kv_pos_row = slot_start + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
         if use_alibi:
-            slopes_col = slopes_ref[...].reshape(group, 1)
-            s = s + slopes_col * kv_pos_row.astype(jnp.float32)
+            s = s + slopes_ref[0] * kv_pos_row.astype(jnp.float32)
 
         if masked:
             kv_pos = slot_start + jax.lax.broadcasted_iota(
@@ -306,27 +410,22 @@ def _decode_kernel(
 
         acc = acc_scratch[...]
         if kv_quant == "none":
-            v = v_ref[...].reshape(page_size, head_dim)
+            v = _head_block(v_ref, t, d_store)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         else:
-            v_raw = v_ref[...].reshape(page_size, -1)
-            vs_row = vs_ref[...].reshape(1, page_size)
-            pv = _quant_pv(p, v_raw, vs_row, kv_quant, head_dim)
+            vs_row = vs_ref[0, pl.ds(h, 1), :]
+            pv = _quant_pv(
+                p, _head_block(v_ref, t, d_store), vs_row, kv_quant, head_dim
+            )
         acc_scratch[...] = acc * alpha + pv
 
         m_scratch[...] = m_new
         l_scratch[...] = jnp.broadcast_to(l_new, l_scratch.shape)
 
-    @pl.when(needed & interior)
-    def _compute_interior():
-        _tile(masked=False)
-
-    @pl.when(needed & jnp.logical_not(interior))
-    def _compute_edge():
-        _tile(masked=True)
+    _tile_branches(_tile, needed, interior, h, heads_per_block)
 
     @pl.when(j == max_pages - 1)
     def _finalize():
@@ -387,11 +486,12 @@ def paged_flash_attend(
     tables_arr = jnp.asarray(tables, jnp.int32)
     kv_lens = jnp.asarray(positions, jnp.int32) + 1
     if alibi_slopes is None:
-        slopes = jnp.zeros((num_kv_heads, group), jnp.float32)
+        slopes = jnp.zeros((num_kv_heads, group, 1), jnp.float32)
         use_alibi = False
     else:
-        slopes = alibi_slopes.astype(jnp.float32).reshape(num_kv_heads, group)
+        slopes = alibi_slopes.astype(jnp.float32).reshape(num_kv_heads, group, 1)
         use_alibi = True
+    hb = _kv_heads_per_block(num_kv_heads, d_store)
 
     grid = (n_lanes, num_kv_heads, max_pages)
 
@@ -402,41 +502,32 @@ def paged_flash_attend(
         max_pages=max_pages,
         group=group,
         head_dim=head_dim,
+        heads_per_block=hb,
         use_alibi=use_alibi,
         sliding_window=sliding_window,
         kv_quant=kv_quant,
     )
 
-    def kv_index_map(i, h, j, tables_ref, kv_lens_ref):
+    def live_page(i, j, tables_ref, kv_lens_ref):
         # skipped pages redirect to block 0: the repeated index elides the DMA
         page = tables_ref[i, j]
         needed = _decode_page_needed(
             page, j * page_size, kv_lens_ref[i], page_size, sliding_window
         )
-        return (jax.lax.select(needed, page, 0), 0, h, 0)
+        return jax.lax.select(needed, page, 0)
 
-    def kv_scale_index_map(i, h, j, tables_ref, kv_lens_ref):
-        # scales pool [n_pages, page_size, hkv]: same redirect, one axis fewer
-        page = tables_ref[i, j]
-        needed = _decode_page_needed(
-            page, j * page_size, kv_lens_ref[i], page_size, sliding_window
-        )
-        return (jax.lax.select(needed, page, 0), 0, h)
-
-    kv_spec = pl.BlockSpec((1, page_size, 1, d_store), kv_index_map)
+    kv_spec = pl.BlockSpec(
+        (1, page_size, hb * d_store), lambda i, h, j, *pf: (live_page(i, j, *pf), 0, h // hb)
+    )
+    scale_spec = pl.BlockSpec(
+        (1, num_kv_heads, page_size), lambda i, h, j, *pf: (live_page(i, j, *pf), 0, 0)
+    )
     in_specs = [
         pl.BlockSpec((1, 1, group, head_dim), lambda i, h, j, *pf: (i, h, 0, 0)),
+        *([kv_spec, scale_spec, kv_spec, scale_spec] if quantized else [kv_spec, kv_spec]),
+        pl.BlockSpec((1, group, 1), lambda i, h, j, *pf: (h, 0, 0)),
     ]
-    operands = [q4]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, page_size, 1), kv_scale_index_map)
-        in_specs += [kv_spec, scale_spec, kv_spec, scale_spec]
-        operands += [k_pool.codes, k_pool.scales, v_pool.codes, v_pool.scales]
-    else:
-        in_specs += [kv_spec, kv_spec]
-        operands += [k_pool, v_pool]
-    in_specs.append(pl.BlockSpec((1, group), lambda i, h, j, *pf: (h, 0)))
-    operands.append(slopes)
+    operands = [q4, *_pool_views(k_pool, v_pool, quantized), slopes]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -456,7 +547,7 @@ def paged_flash_attend(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -492,8 +583,8 @@ def _prefill_kernel(
     slopes_ref,  # float32[num_q_heads]
     # then, positionally: inputs / outputs / scratch —
     #   q_ref [1, block_q, head_dim];
-    #   k_ref [1, page_size, 1, d_store] (raw codes if quantized);
-    #   ks_ref [1, page_size, 1] f32 (quantized pools only);
+    #   k_ref [1, page_size, hb * d_store] (raw codes if quantized);
+    #   ks_ref [1, hkv, page_size] f32 (quantized pools only);
     #   v_ref / vs_ref likewise; o_ref [1, block_q, head_dim];
     #   m/l_scratch [block_q, LANES] f32, acc_scratch [block_q, head_dim] f32
     *refs,
@@ -501,7 +592,9 @@ def _prefill_kernel(
     block_q: int,
     page_size: int,
     max_pages: int,
+    group: int,
     head_dim: int,
+    heads_per_block: int,
     use_alibi: bool,
     sliding_window: Optional[int] = None,
     kv_quant: str = "none",
@@ -513,6 +606,8 @@ def _prefill_kernel(
         (q_ref, k_ref, ks_ref, v_ref, vs_ref, o_ref,
          m_scratch, l_scratch, acc_scratch) = refs
     h = pl.program_id(0)
+    kv_head = h // group
+    d_store = _kv_store_dim(head_dim, kv_quant)
     qi = pl.program_id(1)
     j = pl.program_id(2)
 
@@ -538,17 +633,18 @@ def _prefill_kernel(
     if sliding_window is not None:
         interior &= slot_start >= q_block_start + block_q - sliding_window
 
-    def _tile(masked: bool):
-        q = q_ref[...].reshape(block_q, head_dim)
+    def _tile(masked: bool, t: int):
+        q = q_ref[0]  # [block_q, head_dim]
         if kv_quant == "none":
-            k = k_ref[...].reshape(page_size, head_dim)
+            k = _head_block(k_ref, t, d_store)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
             )  # [block_q, page_size]
         else:
-            k_raw = k_ref[...].reshape(page_size, -1)
-            ks_row = ks_ref[...].reshape(1, page_size)
-            s = _quant_k_scores(q, k_raw, ks_row, kv_quant, head_dim)
+            ks_row = ks_ref[0, pl.ds(kv_head, 1), :]  # [1, page_size]
+            s = _quant_k_scores(
+                q, _head_block(k_ref, t, d_store), ks_row, kv_quant, head_dim
+            )
         s = s * scale
 
         kv_pos_row = slot_start + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
@@ -581,27 +677,22 @@ def _prefill_kernel(
 
         acc = acc_scratch[...]
         if kv_quant == "none":
-            v = v_ref[...].reshape(page_size, head_dim)
+            v = _head_block(v_ref, t, d_store)
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
         else:
-            v_raw = v_ref[...].reshape(page_size, -1)
-            vs_row = vs_ref[...].reshape(1, page_size)
-            pv = _quant_pv(p, v_raw, vs_row, kv_quant, head_dim)
+            vs_row = vs_ref[0, pl.ds(kv_head, 1), :]
+            pv = _quant_pv(
+                p, _head_block(v_ref, t, d_store), vs_row, kv_quant, head_dim
+            )
         acc_scratch[...] = acc * alpha + pv
 
         m_scratch[...] = m_new
         l_scratch[...] = jnp.broadcast_to(l_new, l_scratch.shape)
 
-    @pl.when(needed & interior)
-    def _compute_interior():
-        _tile(masked=False)
-
-    @pl.when(needed & jnp.logical_not(interior))
-    def _compute_edge():
-        _tile(masked=True)
+    _tile_branches(_tile, needed, interior, kv_head, heads_per_block)
 
     @pl.when(j == max_pages - 1)
     def _finalize():
@@ -675,6 +766,8 @@ def paged_flash_prefill_attend(
         slopes = alibi_slopes.astype(jnp.float32)
         use_alibi = True
 
+    hb = _kv_heads_per_block(num_kv_heads, d_store)
+
     grid = (num_q_heads, num_q_blocks, max_pages)
 
     kernel = functools.partial(
@@ -683,40 +776,34 @@ def paged_flash_prefill_attend(
         block_q=block_q,
         page_size=page_size,
         max_pages=max_pages,
+        group=group,
         head_dim=head_dim,
+        heads_per_block=hb,
         use_alibi=use_alibi,
         sliding_window=sliding_window,
         kv_quant=kv_quant,
     )
 
-    def kv_index_map(h, qi, j, table_row_ref, info_ref, slopes_ref):
+    def live_page(qi, j, table_row_ref, info_ref, slopes_ref):
         page = table_row_ref[j]
         needed = _prefill_page_needed(
             page, info_ref[0] + qi * block_q, block_q,
             j * page_size, info_ref[1], page_size, sliding_window,
         )
-        return (jax.lax.select(needed, page, 0), 0, h // group, 0)
+        return jax.lax.select(needed, page, 0)
 
-    def kv_scale_index_map(h, qi, j, table_row_ref, info_ref, slopes_ref):
-        page = table_row_ref[j]
-        needed = _prefill_page_needed(
-            page, info_ref[0] + qi * block_q, block_q,
-            j * page_size, info_ref[1], page_size, sliding_window,
-        )
-        return (jax.lax.select(needed, page, 0), 0, h // group)
-
-    kv_spec = pl.BlockSpec((1, page_size, 1, d_store), kv_index_map)
+    kv_spec = pl.BlockSpec(
+        (1, page_size, hb * d_store),
+        lambda h, qi, j, *pf: (live_page(qi, j, *pf), 0, h // group // hb),
+    )
+    scale_spec = pl.BlockSpec(
+        (1, num_kv_heads, page_size), lambda h, qi, j, *pf: (live_page(qi, j, *pf), 0, 0)
+    )
     in_specs = [
         pl.BlockSpec((1, block_q, head_dim), lambda h, qi, j, *pf: (h, qi, 0)),
+        *([kv_spec, scale_spec, kv_spec, scale_spec] if quantized else [kv_spec, kv_spec]),
     ]
-    operands = [qt]
-    if quantized:
-        scale_spec = pl.BlockSpec((1, page_size, 1), kv_scale_index_map)
-        in_specs += [kv_spec, scale_spec, kv_spec, scale_spec]
-        operands += [k_pool.codes, k_pool.scales, v_pool.codes, v_pool.scales]
-    else:
-        in_specs += [kv_spec, kv_spec]
-        operands += [k_pool, v_pool]
+    operands = [qt, *_pool_views(k_pool, v_pool, quantized)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -736,7 +823,7 @@ def paged_flash_prefill_attend(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -850,7 +937,11 @@ def maybe_autotune_paged_attention(
     shape class times against QUANTIZED pools on both arms: the kernel pays
     in-tile dequant, the XLA arm pays the dequantizing gather."""
     key = shape_class(n_lanes, max_pages, page_size, hkv, d, window, kv_quant)
-    if kernel_mode() != "auto" or _platform() != "tpu":
+    if (
+        kernel_mode() != "auto"
+        or _platform() != "tpu"
+        or paged_kernel_unsupported(key) is not None
+    ):
         return decide_paged_kernel("decode", key)
     if ("decode", *key) in _AUTOTUNE:
         return _AUTOTUNE[("decode", *key)]
@@ -910,22 +1001,19 @@ def maybe_autotune_paged_attention(
         ts = {}
         for n in (2, 2 + steps):
             f = chain(n)
-            f(q, k_pool, v_pool, tables, positions)  # compile
+            jax.block_until_ready(f(q, k_pool, v_pool, tables, positions))  # compile
             best = float("inf")
             for _ in range(3):
                 t0 = time.perf_counter()
                 for _ in range(5):
                     out = f(q, k_pool, v_pool, tables, positions)
-                np.asarray(jax.device_get(out[0, 0, 0, :1]))  # hard sync
+                jax.block_until_ready(out)
                 best = min(best, (time.perf_counter() - t0) / 5)
             ts[n] = best
         return max((ts[2 + steps] - ts[2]) / steps, 1e-9)
 
-    t_pallas = timed(
-        lambda qv, kp, vp, tb, ps_: paged_flash_attend(
-            qv, kp, vp, tb, ps_, sliding_window=window
-        )
-    )
+    def pallas_arm(qv, kp, vp, tb, ps_):
+        return paged_flash_attend(qv, kp, vp, tb, ps_, sliding_window=window)
 
     def xla_arm(qv, kp, vp, tb, ps_):
         kd = gather_pages(kp, tb)
@@ -934,9 +1022,20 @@ def maybe_autotune_paged_attention(
             qv, kd, vd, q_offset=ps_, kv_length=ps_ + 1, sliding_window=window
         )
 
-    t_xla = timed(xla_arm)
+    # both arms must compile: this is a timing choice, never a rescue. A
+    # refusal here is a bug in the kernel or in paged_kernel_unsupported —
+    # name the shape and stop (the server calls this at start-up)
+    try:
+        t_pallas = timed(pallas_arm)
+        t_xla = timed(xla_arm)
+    except Exception as e:
+        raise RuntimeError(
+            f"paged-attention autotune failed for shape class "
+            f"(n_lanes, max_pages, page_size, hkv, d, window, kv_quant)={key}, group={group}"
+        ) from e
     use_pallas = t_pallas <= t_xla
     set_paged_kernel_decision("decode", key, use_pallas)
+    _AUTOTUNE_MS[key] = (t_pallas * 1e3, t_xla * 1e3)
     from petals_tpu.utils.logging import get_logger
 
     get_logger(__name__).info(

@@ -44,9 +44,7 @@ def ring_attend(
     if scale is None:
         scale = d**-0.5
 
-    from petals_tpu.ops.shmap import axis_size
-
-    n_ring = axis_size(axis_name)
+    n_ring = jax.lax.axis_size(axis_name)
     my_rank = jax.lax.axis_index(axis_name)
     q_pos = my_rank * s_local + jnp.arange(s_local, dtype=jnp.int32)  # global positions
 
@@ -120,8 +118,6 @@ def ring_attention_sharded(
     axis, heads ride it (Megatron layout) — the ring math is per-head, so tp
     and sp compose with no extra collectives; ALiBi slopes shard with the
     heads."""
-    from petals_tpu.ops.shmap import shard_map_no_check
-
     head_axis = "tp" if "tp" in mesh.axis_names and mesh.shape["tp"] > 1 else None
     spec = P(None, axis_name, head_axis, None)
     # one shard_map for both cases: placeholder slopes when None, dropped
@@ -136,10 +132,12 @@ def ring_attention_sharded(
             sliding_window=sliding_window,
         )
 
-    fn = shard_map_no_check(
+    # check_vma off: the flash kernel inside has no replication rule
+    fn = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(spec, spec, spec, P(head_axis)),
         out_specs=spec,
+        check_vma=False,
     )
     return fn(q, k, v, slopes)

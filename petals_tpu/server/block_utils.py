@@ -29,17 +29,31 @@ def estimated_block_size_bytes(family, cfg, quant_type: str = "none") -> int:
     return int(block_params_count(family, cfg) * BITS_PER_PARAM[quant_type] / 8)
 
 
+# HBM per chip by jax ``device_kind``, for a TPU runtime whose memory_stats()
+# carries no bytes_limit. An unknown kind is an error, never a guess.
+HBM_BYTES_BY_DEVICE_KIND = {
+    "TPU v5 lite": 16 * 2**30,  # v5e (Google Cloud "TPU v5e": 16 GB HBM2e per chip)
+    "TPU v5e": 16 * 2**30,
+}
+
+
 def device_memory_bytes() -> Optional[int]:
-    """Total memory of the local accelerator, if the backend reports it."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:  # swarmlint: disable=no-silent-except — backend probe: plugins without memory_stats raise freely; the TPU/None fallback below is the answer
-        pass
-    if jax.default_backend() == "tpu":
-        return 16 * 2**30  # v5e per-chip HBM as a fallback
-    return None
+    """Total memory of the first local accelerator: what the runtime reports,
+    else the table above. None on the CPU backend (no device budget to size
+    against — callers fall back to serving every block)."""
+    device = jax.local_devices()[0]
+    stats = device.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if device.platform == "cpu":
+        return None
+    if device.device_kind not in HBM_BYTES_BY_DEVICE_KIND:
+        raise RuntimeError(
+            f"{device.platform} device {device.device_kind!r} reports no memory_stats() "
+            f"bytes_limit and is not in HBM_BYTES_BY_DEVICE_KIND; pass --num_blocks and "
+            f"--attn_cache_tokens explicitly or add the device"
+        )
+    return HBM_BYTES_BY_DEVICE_KIND[device.device_kind]
 
 
 def choose_num_blocks(

@@ -42,6 +42,24 @@ DEFAULT_UPDATE_PERIOD = 30.0
 PHASE_TIERS = ("generalist", "prefill", "decode")
 
 
+def held_chip_files() -> list:
+    """The accelerator device files this process holds open (``/dev/vfio/<n>``
+    or ``/dev/accel<n>``, one per chip): the only name for the PHYSICAL chip —
+    a process pinned with TPU_VISIBLE_CHIPS numbers its one JAX device 0 at
+    coords (0,0,0) whichever chip it was given. Empty off-TPU."""
+    import os
+
+    held = set()
+    for fd in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{fd}")
+        except OSError:  # the fd of this very listdir, already closed
+            continue
+        if target.startswith(("/dev/vfio/", "/dev/accel")) and target != "/dev/vfio/vfio":
+            held.add(target)
+    return sorted(held)
+
+
 def default_dht_prefix(model_name: str) -> str:
     """Derive the swarm namespace from the model name (reference
     models/*/config.py dht_prefix logic: name minus org, '-hf' suffix)."""
@@ -277,34 +295,15 @@ class Server:
 
     # ------------------------------------------------------------------ lifecycle
 
-    @staticmethod
-    def enable_compilation_cache() -> Optional[str]:
-        """Point XLA's persistent compilation cache at our disk cache so a
-        restarted server re-uses every compiled step executable instead of
-        paying tens of seconds per shape bucket again (the TPU analogue of the
-        reference warm-start concerns; disable with
-        PETALS_TPU_NO_COMPILATION_CACHE=1)."""
-        import os
-
-        if os.environ.get("PETALS_TPU_NO_COMPILATION_CACHE"):
-            return None
-        from petals_tpu.utils.disk_cache import DEFAULT_CACHE_DIR
-
-        cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
-            DEFAULT_CACHE_DIR / "xla_cache"
-        )
-        try:
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            if not os.environ.get("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"):
-                # operator's env setting wins; otherwise skip sub-second compiles
-                jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        except Exception as e:  # older jax: feature-gate, never fail startup
-            logger.debug(f"Compilation cache unavailable: {e}")
-            return None
-        return cache_dir
-
     async def start(self) -> None:
-        self.enable_compilation_cache()
+        from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+        cache_dir = enable_compilation_cache()
+        devices = jax.local_devices()
+        logger.info(
+            f"JAX backend {jax.default_backend()}: {len(devices)} x {devices[0].device_kind}, "
+            f"chips {held_chip_files()}; compile cache: {cache_dir or 'off'}"
+        )
         from petals_tpu.dht.identity import Identity
 
         identity = (
@@ -422,6 +421,15 @@ class Server:
         if self._throughput_spec == "auto" and self.num_hosts > 1:
             await self._measure_multihost_throughput()
         self.handler = self._make_handler()
+        batcher = self.handler.batcher
+        if batcher is not None and batcher.page_size is not None:
+            # decide kernel-vs-XLA paged attention for the pool's geometry
+            # now: a kernel that does not compile stops the start, not a
+            # client's first token
+            await asyncio.get_running_loop().run_in_executor(
+                None, self.backend.autotune_paged_attention,
+                batcher.n_lanes, batcher.max_pages, batcher.page_size,
+            )
         self.handler.register(self.rpc_server)
 
         from petals_tpu.utils.ping import PingAggregator

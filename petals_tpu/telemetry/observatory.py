@@ -338,6 +338,18 @@ class Observatory:
             tls.introspect -= 1
         return record
 
+    def lowered_text(self, record: ProgramRecord) -> str:
+        """StableHLO text of one recorded program, re-lowered from its avals
+        like the cost analysis above: says whether the program that was
+        traced contains a Mosaic custom call (``tpu_custom_call``)."""
+        tls = self._tls
+        tls.introspect = getattr(tls, "introspect", 0) + 1
+        try:
+            args, kwargs = record._structs
+            return record._lower(args, kwargs).as_text()
+        finally:
+            tls.introspect -= 1
+
     def cost_table(
         self, *, memory: bool = False, fn: Optional[str] = None
     ) -> List[dict]:

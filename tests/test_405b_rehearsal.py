@@ -81,7 +81,7 @@ def test_report_consumes_measured_bench_rows():
 
 
 def test_report_floors_measured_hop_against_noise():
-    """The chain row's software-hop derivation subtracts two tunnel-sync-sized
+    """The chain row's software-hop derivation subtracts two similar
     measurements; a tiny result must be floored (1 ms) rather than projecting
     near-free hops, and a solidly-measured hop must pass through unfloored."""
     base = {"decode_70b_int4": {"weight_stream_gb_s": 350.0}}

@@ -219,6 +219,33 @@ def test_prefill_parity(chunk_pos, n_valid, window):
     )
 
 
+def test_parity_when_several_heads_share_a_block():
+    """Heads narrower than 128 lanes ride ``128 // d`` to a KV block (the
+    shape Mosaic needs for d=64 families): each grid step must slice ITS
+    head out of the block, for decode and for the prefill twin."""
+    rng = np.random.default_rng(11)
+    n_lanes, max_pages, ps, hkv, group, d = 3, 4, 8, 4, 2, 64
+    assert pfa._kv_heads_per_block(hkv, d) == 2
+    hq = hkv * group
+    n_pages = 16
+    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
+    pos = np.array([3 * ps - 1, ps + 2, 0], np.int32)
+    used = [-(-int(p + 1) // ps) for p in pos]
+    tables = jnp.asarray(_holey_permuted(rng, n_lanes, max_pages, n_pages, used))
+    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
+    out = paged_flash_attend(q, kp, vp, tables, jnp.asarray(pos), interpret=True)
+    ref = paged_attend(q, kp, vp, tables, jnp.asarray(pos))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
+
+    qc = jnp.asarray(rng.standard_normal((1, 16, hq, d)), jnp.float32)
+    cp, nv = jnp.int32(8), jnp.int32(13)
+    out = paged_flash_prefill_attend(qc, kp, vp, tables[0], cp, nv, interpret=True)
+    ref = paged_prefill_attend(qc, kp, vp, tables[0], cp, nv)
+    np.testing.assert_allclose(
+        np.asarray(out)[:, :13], np.asarray(ref)[:, :13], atol=TOL, rtol=0
+    )
+
+
 # ------------------------------------------------- autotune / dispatch unit
 
 
@@ -244,8 +271,8 @@ def test_autotune_decision_cache(monkeypatch):
     decision for its shape class."""
     monkeypatch.delenv(pfa._ENV_VAR, raising=False)
     monkeypatch.setattr(pfa, "_platform", lambda: "tpu")
-    key = pfa.shape_class(2, 4, 8, 2, 16, None)
-    other = pfa.shape_class(8, 4, 8, 2, 16, None)
+    key = pfa.shape_class(2, 4, 8, 2, 128, None)
+    other = pfa.shape_class(8, 4, 8, 2, 128, None)
     assert pfa.decide_paged_kernel("decode", key) is True  # untuned default
     pfa.set_paged_kernel_decision("decode", key, False)
     assert pfa.decide_paged_kernel("decode", key) is False
@@ -254,10 +281,44 @@ def test_autotune_decision_cache(monkeypatch):
     # maybe_autotune is a no-op for an already-decided class (returns it)
     assert (
         pfa.maybe_autotune_paged_attention(
-            n_lanes=2, max_pages=4, page_size=8, hkv=2, d=16
+            n_lanes=2, max_pages=4, page_size=8, hkv=2, d=128
         )
         is False
     )
+
+
+def test_unsupported_shape_class_is_gated_off_the_kernel(monkeypatch, caplog):
+    """On a TPU in auto mode a head width Mosaic cannot tile (neither a lane
+    multiple nor packing evenly into 128 lanes) composes from XLA by a static
+    predicate — decided before any compile, warned once, never autotuned;
+    the explicit override still reaches the kernel (interpreter tests)."""
+    import logging
+
+    monkeypatch.delenv(pfa._ENV_VAR, raising=False)
+    monkeypatch.setattr(pfa, "_platform", lambda: "tpu")
+    monkeypatch.setattr(pfa, "_WARNED_UNSUPPORTED", set())
+    key = pfa.shape_class(2, 4, 8, 2, 16, None)  # the tiny interpreter shape
+    assert pfa.paged_kernel_unsupported(key) is not None
+    for packed in (
+        pfa.shape_class(8, 16, 64, 8, 64, None),  # two d=64 heads to a block
+        pfa.shape_class(8, 16, 64, 32, 128, None, "nf4a"),  # two packed heads
+        pfa.shape_class(8, 16, 64, 1, 64, None),  # MQA: the block is the whole row
+    ):
+        assert pfa.paged_kernel_unsupported(packed) is None
+    logging.getLogger("petals_tpu").propagate = True
+    try:
+        with caplog.at_level(logging.WARNING, logger="petals_tpu"):
+            assert pfa.decide_paged_kernel("decode", key) is False
+            assert pfa.decide_paged_kernel("prefill", key) is False
+    finally:
+        logging.getLogger("petals_tpu").propagate = False
+    assert sum("excluded for shape class" in r.message for r in caplog.records) == 1
+    assert pfa.maybe_autotune_paged_attention(
+        n_lanes=2, max_pages=4, page_size=8, hkv=2, d=16
+    ) is False
+    assert pfa._AUTOTUNE == {}  # gated, not tuned
+    monkeypatch.setenv(pfa._ENV_VAR, "pallas")
+    assert pfa.decide_paged_kernel("decode", key) is True
 
 
 def test_autotune_noop_off_tpu(monkeypatch):

@@ -302,29 +302,38 @@ def test_server_announces_to_dht(model_path):
     run(main())
 
 
-def test_compilation_cache_persists_executables(tmp_path, monkeypatch):
-    """The persistent XLA cache fills with compiled step executables, so a
-    restarted server skips recompilation (PETALS_TPU_NO_COMPILATION_CACHE
-    opts out)."""
+def test_compilation_cache_rule(tmp_path, monkeypatch):
+    """One rule (utils/compile_cache.py): JAX_COMPILATION_CACHE_DIR set ->
+    the code sets no directory and JAX's own handling fills it; unset ->
+    <checkout>/.jax_cache; PETALS_TPU_NO_COMPILATION_CACHE turns the default
+    off. A restarted server then skips recompiling its step executables."""
     import jax
 
-    # conftest gates the cache off for hermeticity; opt back in with a tmp dir
-    monkeypatch.delenv("PETALS_TPU_NO_COMPILATION_CACHE", raising=False)
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla_cache"))
+    from petals_tpu.utils import compile_cache
 
-    def _reset():  # best-effort de-init of the once-per-process singleton
-        try:
-            from jax._src import compilation_cache as _cc
+    configured = jax.config.jax_compilation_cache_dir  # conftest's per-run temp dir
+    # the variable set from outside: nothing is configured by our code
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "elsewhere"))
+    assert compile_cache.enable_compilation_cache() == str(tmp_path / "elsewhere")
+    assert jax.config.jax_compilation_cache_dir == configured
+    # unset and opted out: off
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    monkeypatch.setenv("PETALS_TPU_NO_COMPILATION_CACHE", "1")
+    assert compile_cache.enable_compilation_cache() is None
+    assert jax.config.jax_compilation_cache_dir == configured
+    # unset: the checkout's own directory, resolved from the package location
+    monkeypatch.delenv("PETALS_TPU_NO_COMPILATION_CACHE")
+    monkeypatch.setattr(compile_cache, "CHECKOUT_CACHE_DIR", tmp_path / ".jax_cache")
 
-            _cc.reset_cache()
-        except Exception:
-            pass
+    def _reset():  # de-init the once-per-process cache singleton
+        from jax._src import compilation_cache as _cc
+
+        _cc.reset_cache()
 
     _reset()
-    assert Server.enable_compilation_cache() == str(tmp_path / "xla_cache")
-    # lower the persistence threshold so the tiny test program qualifies
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    counts = compile_cache.count_cache_events()
     try:
+        assert compile_cache.enable_compilation_cache() == str(tmp_path / ".jax_cache")
         import jax.numpy as jnp
 
         @jax.jit
@@ -332,13 +341,18 @@ def test_compilation_cache_persists_executables(tmp_path, monkeypatch):
             return (x @ x).sum()
 
         jax.block_until_ready(step(jnp.ones((64, 64))))
-        cache_files = list((tmp_path / "xla_cache").rglob("*"))
-        assert cache_files, "compilation cache must be populated"
+        assert list((tmp_path / ".jax_cache").iterdir()), "compilation cache must be populated"
+        assert counts["requests"] >= 1 and counts["writes"] >= 1, counts
     finally:
         # restore process-wide state: later tests must not write to tmp_path
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-        jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_dir", configured)
         _reset()
 
-    monkeypatch.setenv("PETALS_TPU_NO_COMPILATION_CACHE", "1")
-    assert Server.enable_compilation_cache() is None
+
+def test_checkout_cache_dir_is_fixed_and_inside_the_checkout():
+    import os
+
+    from petals_tpu.utils.compile_cache import CHECKOUT_CACHE_DIR
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert str(CHECKOUT_CACHE_DIR) == os.path.join(repo, ".jax_cache")
