@@ -35,7 +35,9 @@ Path selection (``paged_attend_dispatch``, reached via ops/attention.py
 attend() on a PagedKV): per (n_lanes, max_pages, page_size, hkv, d, window)
 shape class, an autotune harness on the maybe_autotune_nf4_decode pattern
 times kernel-vs-XLA-composed on the real chip at startup and traces the
-winner into the step program; shape classes Mosaic cannot tile are kept off
+winner into the step program, the kernel winning only by ``KERNEL_MUST_WIN_BY``
+(a tie in the harness must not flip the step program from one start to the
+next); shape classes Mosaic cannot tile are kept off
 the kernel by a static predicate (``paged_kernel_unsupported``), never by
 catching a failed compile. ``PETALS_TPU_PAGED_KERNEL=pallas|xla|auto``
 overrides; off-TPU the XLA-composed path (gather_pages + attend_reference)
@@ -69,6 +71,15 @@ _MODES = ("pallas", "xla", "auto")
 _AUTOTUNE: dict = {}
 # shape class -> (pallas_ms, xla_ms) per decode step, as the autotune timed it
 _AUTOTUNE_MS: dict = {}
+# The kernel takes a shape class only where the harness times it this share
+# under the composed path. Two reasons, both measured on the v5e (PERF.md
+# section 6, PR 24). Falcon-40B's class (8 lanes, 16 pages of 64, hkv 8, d 64)
+# times 0.45 against 0.45 ms, so `<=` tossed a coin at every start and two
+# starts of one server ran different step programs; and inside the step the
+# kernel costs what the harness does not time (the pool's lane-trailing view
+# is a relayout in front of every layer's call): the coin's two sides were
+# 17.2 and 16.6 ms a step, 37.3 and 36.0 ms a token.
+KERNEL_MUST_WIN_BY = 0.10
 
 
 def kernel_mode() -> str:
@@ -159,6 +170,12 @@ def resolve_paged_kernel_path(kind: str, key: Tuple) -> str:
 
 def set_paged_kernel_decision(kind: str, key: Tuple, use_pallas: bool) -> None:
     _AUTOTUNE[(kind, *key)] = bool(use_pallas)
+
+
+def kernel_wins(t_pallas: float, t_xla: float) -> bool:
+    """The autotune's verdict on two timed arms: the kernel, if it is faster
+    than the composed path by more than ``KERNEL_MUST_WIN_BY`` of it."""
+    return t_pallas < (1.0 - KERNEL_MUST_WIN_BY) * t_xla
 
 
 def reset_paged_autotune() -> None:
@@ -1033,14 +1050,14 @@ def maybe_autotune_paged_attention(
             f"paged-attention autotune failed for shape class "
             f"(n_lanes, max_pages, page_size, hkv, d, window, kv_quant)={key}, group={group}"
         ) from e
-    use_pallas = t_pallas <= t_xla
+    use_pallas = kernel_wins(t_pallas, t_xla)
     set_paged_kernel_decision("decode", key, use_pallas)
     _AUTOTUNE_MS[key] = (t_pallas * 1e3, t_xla * 1e3)
     from petals_tpu.utils.logging import get_logger
 
     get_logger(__name__).info(
-        f"paged-attention autotune {key}: pallas {t_pallas * 1e3:.2f}ms vs "
-        f"xla-composed {t_xla * 1e3:.2f}ms per step -> "
+        f"paged-attention autotune {key}: pallas {t_pallas * 1e3:.3f}ms vs "
+        f"xla-composed {t_xla * 1e3:.3f}ms per step -> "
         f"{'pallas' if use_pallas else 'xla'}"
     )
     return use_pallas

@@ -66,6 +66,7 @@ from petals_tpu.telemetry import get_journal
 from petals_tpu.telemetry import instruments as tm
 from petals_tpu.utils.asyncio_utils import log_exception_callback
 from petals_tpu.utils.logging import get_logger
+from petals_tpu.utils.tracing import step_phases
 
 logger = get_logger(__name__)
 
@@ -319,6 +320,11 @@ class DecodeBatcher:
         # gets every freed page and provably drains the queue
         self._swap_in_turnstile = make_async_lock("batching._swap_in_turnstile")
         self._flush_task: Optional[asyncio.Task] = None
+        # flush tasks spawned so far, and (time, spawn count) at the last step
+        # body's return: together they tell a hand-off with work pending from
+        # a stretch in which the batcher had nothing to run (_step_phases)
+        self._flush_spawns = 0
+        self._last_step_end: Tuple[float, int] = (0.0, -1)
         self._open_lock = make_async_lock("batching._open_lock")
         self._closed = False
         # multi-host lockstep (parallel/multihost.py): lane ops broadcast so
@@ -338,6 +344,11 @@ class DecodeBatcher:
             "max_prefill_tokens_per_step": 0,
             "spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
             "spec_disabled": 0, "max_spec_lanes": 0,
+            # where the compute thread's time went, cumulative seconds: the
+            # four phases of every step body (utils/tracing.step_phases) and
+            # the hand-off between two steps of one flush task (_step_phases)
+            "assemble_s": 0.0, "dispatch_s": 0.0, "wait_s": 0.0, "post_s": 0.0,
+            "turnaround_s": 0.0,
         }
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
@@ -1366,6 +1377,7 @@ class DecodeBatcher:
         tasks weakly) and the done-callback surfaces a crashed drain — a
         silently dead flush loop would hang every pending step future."""
         if self._flush_task is None or self._flush_task.done():
+            self._flush_spawns += 1
             self._flush_task = asyncio.create_task(self._flush_loop())
             self._flush_task.add_done_callback(
                 log_exception_callback(logger, "decode flush loop")
@@ -1833,61 +1845,91 @@ class DecodeBatcher:
                 except KeyError:
                     pass  # racing close(): handles already freed
 
+    @contextlib.contextmanager
+    def _step_phases(self, variant: str, lanes: int, prefill_tokens: int = 0):
+        """The phase clock shared by the four step bodies (compute thread):
+        ``assemble_s`` from the body's entry to the backend call,
+        ``dispatch_s`` the backend call (the device starts inside it),
+        ``wait_s`` blocked until the outputs are on the host, ``post_s`` from
+        there to the return; the same boundaries are ``ptu.step.*``
+        annotations in a profiler trace. ``turnaround_s`` is the time since
+        the previous body returned, counted only where the flush task stayed
+        alive in between: results to the event loop, futures set, the next
+        ``queue.submit``, this thread's wake-up (and whatever else the queue
+        ran meanwhile). Where the flush task ended, the batcher had nothing
+        to run and the stretch is in no counter."""
+        ended, spawn = self._last_step_end
+        if spawn == self._flush_spawns:
+            self.stats["turnaround_s"] += time.perf_counter() - ended
+        try:
+            with step_phases(
+                self.stats, variant=variant, lanes=lanes, prefill_tokens=prefill_tokens
+            ) as phases:
+                yield phases
+        finally:
+            self._last_step_end = (time.perf_counter(), self._flush_spawns)
+
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
-        # generation guards on BOTH sides of the device step: an exclusive
-        # op's failure can reset the pool from the event loop while this
-        # task is queued or mid-flight, and decoding against the
-        # rematerialized zeros must fail loudly, never resolve futures
-        if batch and batch[0][4] != self._generation:
-            raise AllocationFailed("Lane pool was reset before this batched step ran")
-        t_step = time.perf_counter()
-        hsz = self.backend.hidden_size
-        hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
-        positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-        for lane, h, pos, _fut, _gen in batch:
-            hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
-            positions[lane] = pos
-        k_pool, v_pool = self._buffers()
-        if self.page_size is not None:
+        variant = "paged" if self.page_size is not None else "dense"
+        with self._step_phases(variant, len(batch)) as phases:
+            # generation guards on BOTH sides of the device step: an exclusive
+            # op's failure can reset the pool from the event loop while this
+            # task is queued or mid-flight, and decoding against the
+            # rematerialized zeros must fail loudly, never resolve futures
+            if batch and batch[0][4] != self._generation:
+                raise AllocationFailed("Lane pool was reset before this batched step ran")
+            t_step = time.perf_counter()
+            hsz = self.backend.hidden_size
+            hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
+            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
+            for lane, h, pos, _fut, _gen in batch:
+                hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
+                positions[lane] = pos
+            k_pool, v_pool = self._buffers()
             # snapshot the tables: the event loop may grow OTHER lanes while
             # this step runs, but never slots this step reads unmasked or
             # writes (prepare_write ran before each entry was enqueued)
-            out, (k_pool, v_pool) = self.backend.paged_decode_step(
-                hidden, (k_pool, v_pool), positions, self._tables.copy(),
-                handles=self._handles,
+            tables = self._tables.copy() if self.page_size is not None else None
+            phases.enter("dispatch")
+            if tables is not None:
+                out, (k_pool, v_pool) = self.backend.paged_decode_step(
+                    hidden, (k_pool, v_pool), positions, tables,
+                    handles=self._handles,
+                )
+            else:
+                out, (k_pool, v_pool) = self.backend.batched_decode_step(
+                    hidden, (k_pool, v_pool), positions, handles=self._handles
+                )
+            phases.enter("wait")
+            host_out = np.asarray(out)  # device sync: the step has fully executed
+            phases.enter("post")
+            with self._reset_lock:
+                if batch and batch[0][4] != self._generation:
+                    # the reset landed while this step executed: the buffers it
+                    # read were either consumed (we would have raised) or already
+                    # zeroed. Checked atomically with the swap (under the reset
+                    # lock) so the freshly reset pool stays zeroed — swapping in
+                    # the stale stepped buffers would silently break the 'reset
+                    # leaves a zeroed pool' recovery invariant.
+                    raise AllocationFailed("Lane pool was reset while this batched step ran")
+                self._update(k_pool, v_pool)
+            self.stats["batched_steps"] += 1
+            self.stats["batched_tokens"] += len(batch)
+            self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
+            duration = time.perf_counter() - t_step
+            if self.page_size is not None:
+                tm.STEP_PAGED.observe(duration)
+                tm.STEPS_PAGED.inc()
+            else:
+                tm.STEP_DENSE.observe(duration)
+                tm.STEPS_DENSE.inc()
+            tm.DECODE_TOKENS.inc(len(batch))
+            self._record_decode_timing(batch, t_step, duration)
+            self._capture_step_fp([entry[0] for entry in batch])
+            self._ledger_account_step(
+                duration, decode_lanes=[entry[0] for entry in batch]
             )
-        else:
-            out, (k_pool, v_pool) = self.backend.batched_decode_step(
-                hidden, (k_pool, v_pool), positions, handles=self._handles
-            )
-        host_out = np.asarray(out)  # device sync: the step has fully executed
-        with self._reset_lock:
-            if batch and batch[0][4] != self._generation:
-                # the reset landed while this step executed: the buffers it
-                # read were either consumed (we would have raised) or already
-                # zeroed. Checked atomically with the swap (under the reset
-                # lock) so the freshly reset pool stays zeroed — swapping in
-                # the stale stepped buffers would silently break the 'reset
-                # leaves a zeroed pool' recovery invariant.
-                raise AllocationFailed("Lane pool was reset while this batched step ran")
-            self._update(k_pool, v_pool)
-        self.stats["batched_steps"] += 1
-        self.stats["batched_tokens"] += len(batch)
-        self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
-        duration = time.perf_counter() - t_step
-        if self.page_size is not None:
-            tm.STEP_PAGED.observe(duration)
-            tm.STEPS_PAGED.inc()
-        else:
-            tm.STEP_DENSE.observe(duration)
-            tm.STEPS_DENSE.inc()
-        tm.DECODE_TOKENS.inc(len(batch))
-        self._record_decode_timing(batch, t_step, duration)
-        self._capture_step_fp([entry[0] for entry in batch])
-        self._ledger_account_step(
-            duration, decode_lanes=[entry[0] for entry in batch]
-        )
         return host_out
 
     def _record_decode_timing(self, batch, t_step: float, duration: float) -> None:
@@ -1936,136 +1978,146 @@ class DecodeBatcher:
         The prefill lane rides the decode half at the idle sentinel, so its
         decode-side write drops; its tokens ride the prefill half."""
         st, take = pf
-        expected = batch[0][4] if batch else st.generation
-        if expected != self._generation or st.generation != self._generation:
-            raise AllocationFailed("Lane pool was reset before this batched step ran")
-        t_step = time.perf_counter()
-        hsz = self.backend.hidden_size
-        hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
-        positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-        for lane, h, pos, _fut, _gen in batch:
-            hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
-            positions[lane] = pos
-        chunk = st.hidden[:, st.offset : st.offset + take]
-        k_pool, v_pool = self._buffers()
-        out, chunk_out, (k_pool, v_pool) = self.backend.paged_mixed_step(
-            hidden, (k_pool, v_pool), positions, self._tables.copy(),
-            chunk, st.lane, st.position, n_total=st.n_total,
-            handles=self._handles,
-        )
-        host_out = np.asarray(out)  # device sync: the step has fully executed
-        host_chunk = np.asarray(chunk_out)
-        with self._reset_lock:
-            if expected != self._generation:
-                # see _run_batch: checked atomically with the swap so a reset
-                # landing mid-step leaves the freshly zeroed pool in place
-                raise AllocationFailed("Lane pool was reset while this batched step ran")
-            self._update(k_pool, v_pool)
-        self.stats["batched_steps"] += 1
-        self.stats["batched_tokens"] += len(batch)
-        self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
-        self.stats["mixed_steps"] += 1
-        self.stats["prefill_tokens"] += take
-        self.stats["max_prefill_tokens_per_step"] = max(
-            self.stats["max_prefill_tokens_per_step"], take
-        )
-        duration = time.perf_counter() - t_step
-        tm.STEP_MIXED.observe(duration)
-        tm.STEPS_MIXED.inc()
-        tm.DECODE_TOKENS.inc(len(batch))
-        self._record_decode_timing(batch, t_step, duration)
-        self._capture_step_fp(
-            [entry[0] for entry in batch], chunk_lane=st.lane
-        )
-        self._ledger_account_step(
-            duration,
-            decode_lanes=[entry[0] for entry in batch],
-            prefill=(st.lane, take),
-        )
-        st.compute_s += duration  # whole-prefill compute accumulates per chunk
+        with self._step_phases("mixed", len(batch), take) as phases:
+            expected = batch[0][4] if batch else st.generation
+            if expected != self._generation or st.generation != self._generation:
+                raise AllocationFailed("Lane pool was reset before this batched step ran")
+            t_step = time.perf_counter()
+            hsz = self.backend.hidden_size
+            hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
+            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
+            for lane, h, pos, _fut, _gen in batch:
+                hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
+                positions[lane] = pos
+            chunk = st.hidden[:, st.offset : st.offset + take]
+            k_pool, v_pool = self._buffers()
+            tables = self._tables.copy()
+            phases.enter("dispatch")
+            out, chunk_out, (k_pool, v_pool) = self.backend.paged_mixed_step(
+                hidden, (k_pool, v_pool), positions, tables,
+                chunk, st.lane, st.position, n_total=st.n_total,
+                handles=self._handles,
+            )
+            phases.enter("wait")
+            host_out = np.asarray(out)  # device sync: the step has fully executed
+            host_chunk = np.asarray(chunk_out)
+            phases.enter("post")
+            with self._reset_lock:
+                if expected != self._generation:
+                    # see _run_batch: checked atomically with the swap so a reset
+                    # landing mid-step leaves the freshly zeroed pool in place
+                    raise AllocationFailed("Lane pool was reset while this batched step ran")
+                self._update(k_pool, v_pool)
+            self.stats["batched_steps"] += 1
+            self.stats["batched_tokens"] += len(batch)
+            self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
+            self.stats["mixed_steps"] += 1
+            self.stats["prefill_tokens"] += take
+            self.stats["max_prefill_tokens_per_step"] = max(
+                self.stats["max_prefill_tokens_per_step"], take
+            )
+            duration = time.perf_counter() - t_step
+            tm.STEP_MIXED.observe(duration)
+            tm.STEPS_MIXED.inc()
+            tm.DECODE_TOKENS.inc(len(batch))
+            self._record_decode_timing(batch, t_step, duration)
+            self._capture_step_fp(
+                [entry[0] for entry in batch], chunk_lane=st.lane
+            )
+            self._ledger_account_step(
+                duration,
+                decode_lanes=[entry[0] for entry in batch],
+                prefill=(st.lane, take),
+            )
+            st.compute_s += duration  # whole-prefill compute accumulates per chunk
         return host_out, host_chunk
 
     def _run_batch_gen(self, batch, gen_states) -> Tuple[np.ndarray, np.ndarray]:
         """Compute-thread body: one jitted step advancing every pending decode
         lane AND every generating lane together (the client leaves embed the
         gen lanes' tokens and sample their next ones on device)."""
-        expected = (
-            batch[0][4] if batch
-            else next(iter(gen_states.values())).generation
-        )
-        if expected != self._generation or any(
-            st.generation != self._generation for st in gen_states.values()
-        ):
-            raise AllocationFailed("Lane pool was reset before this batched step ran")
-        t_step = time.perf_counter()
-        hsz = self.backend.hidden_size
-        hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
-        positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-        tokens = np.zeros((self.n_lanes,), np.int32)
-        use_token = np.zeros((self.n_lanes,), bool)
-        vecs = sampling_vectors(self.n_lanes, self.backend.cfg.vocab_size)
-        for lane, h, pos, _fut, _gen in batch:
-            hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
-            positions[lane] = pos
-        for lane, st in gen_states.items():
-            tokens[lane] = st.token
-            use_token[lane] = True
-            positions[lane] = st.position
-            vecs["do_sample"][lane] = st.do_sample
-            vecs["temperature"][lane] = st.temperature
-            vecs["top_k"][lane] = st.top_k
-            vecs["top_p"][lane] = st.top_p
-            vecs["repetition_penalty"][lane] = st.repetition_penalty
-            vecs["seeds"][lane] = st.seed
-            vecs["draw_idx"][lane] = st.draw_idx
-            if st.seen is not None:
-                vecs["seen_mask"][lane] = st.seen
-        k_pool, v_pool = self._buffers()
-        if self.page_size is not None:
-            out, toks, (k_pool, v_pool) = self.backend.paged_gen_decode_step(
-                self.gen_params, hidden, tokens, use_token, (k_pool, v_pool),
-                positions, self._tables.copy(), sampling_vecs=vecs,
-                handles=self._handles,
+        with self._step_phases("gen", len(batch) + len(gen_states)) as phases:
+            expected = (
+                batch[0][4] if batch
+                else next(iter(gen_states.values())).generation
             )
-        else:
-            out, toks, (k_pool, v_pool) = self.backend.batched_gen_decode_step(
-                self.gen_params, hidden, tokens, use_token, (k_pool, v_pool),
-                positions, sampling_vecs=vecs, handles=self._handles,
+            if expected != self._generation or any(
+                st.generation != self._generation for st in gen_states.values()
+            ):
+                raise AllocationFailed("Lane pool was reset before this batched step ran")
+            t_step = time.perf_counter()
+            hsz = self.backend.hidden_size
+            hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
+            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
+            tokens = np.zeros((self.n_lanes,), np.int32)
+            use_token = np.zeros((self.n_lanes,), bool)
+            vecs = sampling_vectors(self.n_lanes, self.backend.cfg.vocab_size)
+            for lane, h, pos, _fut, _gen in batch:
+                hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
+                positions[lane] = pos
+            for lane, st in gen_states.items():
+                tokens[lane] = st.token
+                use_token[lane] = True
+                positions[lane] = st.position
+                vecs["do_sample"][lane] = st.do_sample
+                vecs["temperature"][lane] = st.temperature
+                vecs["top_k"][lane] = st.top_k
+                vecs["top_p"][lane] = st.top_p
+                vecs["repetition_penalty"][lane] = st.repetition_penalty
+                vecs["seeds"][lane] = st.seed
+                vecs["draw_idx"][lane] = st.draw_idx
+                if st.seen is not None:
+                    vecs["seen_mask"][lane] = st.seen
+            k_pool, v_pool = self._buffers()
+            tables = self._tables.copy() if self.page_size is not None else None
+            phases.enter("dispatch")
+            if tables is not None:
+                out, toks, (k_pool, v_pool) = self.backend.paged_gen_decode_step(
+                    self.gen_params, hidden, tokens, use_token, (k_pool, v_pool),
+                    positions, tables, sampling_vecs=vecs,
+                    handles=self._handles,
+                )
+            else:
+                out, toks, (k_pool, v_pool) = self.backend.batched_gen_decode_step(
+                    self.gen_params, hidden, tokens, use_token, (k_pool, v_pool),
+                    positions, sampling_vecs=vecs, handles=self._handles,
+                )
+            phases.enter("wait")
+            host_out = np.asarray(out)  # device sync: the step has fully executed
+            host_toks = np.asarray(toks)
+            phases.enter("post")
+            with self._reset_lock:
+                if expected != self._generation:
+                    # see _run_batch: checked atomically with the swap so a reset
+                    # landing mid-step leaves the freshly zeroed pool in place
+                    raise AllocationFailed("Lane pool was reset while this batched step ran")
+                self._update(k_pool, v_pool)
+            self.stats["batched_steps"] += 1
+            self.stats["batched_tokens"] += len(batch) + len(gen_states)
+            self.stats["max_batch"] = max(
+                self.stats["max_batch"], len(batch) + len(gen_states)
             )
-        host_out = np.asarray(out)  # device sync: the step has fully executed
-        host_toks = np.asarray(toks)
-        with self._reset_lock:
-            if expected != self._generation:
-                # see _run_batch: checked atomically with the swap so a reset
-                # landing mid-step leaves the freshly zeroed pool in place
-                raise AllocationFailed("Lane pool was reset while this batched step ran")
-            self._update(k_pool, v_pool)
-        self.stats["batched_steps"] += 1
-        self.stats["batched_tokens"] += len(batch) + len(gen_states)
-        self.stats["max_batch"] = max(
-            self.stats["max_batch"], len(batch) + len(gen_states)
-        )
-        self.stats["gen_steps"] += 1
-        self.stats["gen_lane_tokens"] += len(gen_states)
-        self.stats["max_gen_lanes"] = max(
-            self.stats["max_gen_lanes"], len(gen_states)
-        )
-        duration = time.perf_counter() - t_step
-        tm.STEP_GEN.observe(duration)
-        tm.STEPS_GEN.inc()
-        tm.DECODE_TOKENS.inc(len(batch) + len(gen_states))
-        self._record_decode_timing(batch, t_step, duration)
-        self._capture_step_fp([entry[0] for entry in batch] + list(gen_states))
-        self._ledger_account_step(
-            duration,
-            decode_lanes=[entry[0] for entry in batch],
-            gen_lanes=list(gen_states),
-        )
-        for st in gen_states.values():
-            if not st.started:
-                st.started = True
-                st.queue_s = max(t_step - st.enqueued, 0.0) if st.enqueued else 0.0
-            st.compute_s += duration
+            self.stats["gen_steps"] += 1
+            self.stats["gen_lane_tokens"] += len(gen_states)
+            self.stats["max_gen_lanes"] = max(
+                self.stats["max_gen_lanes"], len(gen_states)
+            )
+            duration = time.perf_counter() - t_step
+            tm.STEP_GEN.observe(duration)
+            tm.STEPS_GEN.inc()
+            tm.DECODE_TOKENS.inc(len(batch) + len(gen_states))
+            self._record_decode_timing(batch, t_step, duration)
+            self._capture_step_fp([entry[0] for entry in batch] + list(gen_states))
+            self._ledger_account_step(
+                duration,
+                decode_lanes=[entry[0] for entry in batch],
+                gen_lanes=list(gen_states),
+            )
+            for st in gen_states.values():
+                if not st.started:
+                    st.started = True
+                    st.queue_s = max(t_step - st.enqueued, 0.0) if st.enqueued else 0.0
+                st.compute_s += duration
         return host_out, host_toks
 
     def _run_batch_spec(self, spec_states) -> Tuple[np.ndarray, np.ndarray]:
@@ -2084,86 +2136,91 @@ class DecodeBatcher:
         share is additionally recorded per lane as the draft_seconds
         'of which' annotation, with proposed/accepted counts feeding the
         per-peer acceptance_rate (/ledger, step_meta usage)."""
-        expected = next(iter(spec_states.values())).generation
-        if expected != self._generation or any(
-            st.generation != self._generation for st in spec_states.values()
-        ):
-            raise AllocationFailed("Lane pool was reset before this batched step ran")
-        if self._draft_warmed is not self.draft:
-            # compile every propose bucket before the first measured tick so
-            # later lane-count mixes never compile (spec_decode.DraftModel)
-            self.draft.warmup(self.n_lanes)
-            self._draft_warmed = self.draft
-        t_step = time.perf_counter()
-        S = self.spec_k + 1
-        contexts: List[Optional[List[int]]] = [None] * self.n_lanes
-        for lane, st in spec_states.items():
-            contexts[lane] = (st.context or []) + st.collected
-        drafts = self.draft.propose(contexts)  # [n_lanes, spec_k] greedy
-        draft_s = time.perf_counter() - t_step
-        tokens = np.zeros((self.n_lanes, S), np.int32)
-        positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-        vecs = sampling_vectors(self.n_lanes, self.backend.cfg.vocab_size)
-        for lane, st in spec_states.items():
-            tokens[lane, 0] = st.token
-            tokens[lane, 1:] = drafts[lane]
-            positions[lane] = st.position
-            vecs["do_sample"][lane] = st.do_sample
-            vecs["temperature"][lane] = st.temperature
-            vecs["top_k"][lane] = st.top_k
-            vecs["top_p"][lane] = st.top_p
-            vecs["repetition_penalty"][lane] = st.repetition_penalty
-            vecs["seeds"][lane] = st.seed
-            vecs["draw_idx"][lane] = st.draw_idx
-            if st.seen is not None:
-                vecs["seen_mask"][lane] = st.seen
-        k_pool, v_pool = self._buffers()
-        g_hat, n_emit, (k_pool, v_pool) = self.backend.paged_spec_verify_step(
-            self.gen_params, tokens, (k_pool, v_pool), positions,
-            self._tables.copy(), sampling_vecs=vecs, handles=self._handles,
-        )
-        host_g = np.asarray(g_hat)  # device sync: the step has fully executed
-        host_m = np.asarray(n_emit)
-        with self._reset_lock:
-            if expected != self._generation:
-                # see _run_batch: checked atomically with the swap so a reset
-                # landing mid-step leaves the freshly zeroed pool in place
-                raise AllocationFailed("Lane pool was reset while this batched step ran")
-            self._update(k_pool, v_pool)
-        n_spec = len(spec_states)
-        emitted_total = int(sum(int(host_m[lane]) for lane in spec_states))
-        accepted_total = emitted_total - n_spec  # one bonus token per lane
-        proposed_total = n_spec * self.spec_k
-        self.stats["batched_steps"] += 1
-        self.stats["batched_tokens"] += emitted_total
-        self.stats["spec_steps"] += 1
-        self.stats["spec_proposed"] += proposed_total
-        self.stats["spec_accepted"] += accepted_total
-        self.stats["max_spec_lanes"] = max(self.stats["max_spec_lanes"], n_spec)
-        duration = time.perf_counter() - t_step
-        tm.STEP_SPEC.observe(duration)
-        tm.STEPS_SPEC.inc()
-        tm.DECODE_TOKENS.inc(emitted_total)
-        tm.SPEC_PROPOSED.inc(proposed_total)
-        tm.SPEC_ACCEPTED.inc(accepted_total)
-        self._capture_step_fp(list(spec_states))
-        keys = []
-        per_lane_draft = draft_s / n_spec
-        for lane, st in spec_states.items():
-            key = self._ledger_keys.get(lane)
-            if key is not None:
-                keys.append(key)
-                self._ledger.note_tokens(key, decode=int(host_m[lane]))
-                self._ledger.note_spec(
-                    key, draft_seconds=per_lane_draft,
-                    proposed=self.spec_k, accepted=int(host_m[lane]) - 1,
-                )
-        self._ledger.note_compute(keys, duration)
-        for st in spec_states.values():
-            if not st.started:
-                st.started = True
-                st.queue_s = max(t_step - st.enqueued, 0.0) if st.enqueued else 0.0
-            st.compute_s += duration
+        with self._step_phases("spec", len(spec_states)) as phases:
+            expected = next(iter(spec_states.values())).generation
+            if expected != self._generation or any(
+                st.generation != self._generation for st in spec_states.values()
+            ):
+                raise AllocationFailed("Lane pool was reset before this batched step ran")
+            if self._draft_warmed is not self.draft:
+                # compile every propose bucket before the first measured tick so
+                # later lane-count mixes never compile (spec_decode.DraftModel)
+                self.draft.warmup(self.n_lanes)
+                self._draft_warmed = self.draft
+            t_step = time.perf_counter()
+            S = self.spec_k + 1
+            contexts: List[Optional[List[int]]] = [None] * self.n_lanes
+            for lane, st in spec_states.items():
+                contexts[lane] = (st.context or []) + st.collected
+            drafts = self.draft.propose(contexts)  # [n_lanes, spec_k] greedy
+            draft_s = time.perf_counter() - t_step
+            tokens = np.zeros((self.n_lanes, S), np.int32)
+            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
+            vecs = sampling_vectors(self.n_lanes, self.backend.cfg.vocab_size)
+            for lane, st in spec_states.items():
+                tokens[lane, 0] = st.token
+                tokens[lane, 1:] = drafts[lane]
+                positions[lane] = st.position
+                vecs["do_sample"][lane] = st.do_sample
+                vecs["temperature"][lane] = st.temperature
+                vecs["top_k"][lane] = st.top_k
+                vecs["top_p"][lane] = st.top_p
+                vecs["repetition_penalty"][lane] = st.repetition_penalty
+                vecs["seeds"][lane] = st.seed
+                vecs["draw_idx"][lane] = st.draw_idx
+                if st.seen is not None:
+                    vecs["seen_mask"][lane] = st.seen
+            k_pool, v_pool = self._buffers()
+            tables = self._tables.copy()
+            phases.enter("dispatch")
+            g_hat, n_emit, (k_pool, v_pool) = self.backend.paged_spec_verify_step(
+                self.gen_params, tokens, (k_pool, v_pool), positions,
+                tables, sampling_vecs=vecs, handles=self._handles,
+            )
+            phases.enter("wait")
+            host_g = np.asarray(g_hat)  # device sync: the step has fully executed
+            host_m = np.asarray(n_emit)
+            phases.enter("post")
+            with self._reset_lock:
+                if expected != self._generation:
+                    # see _run_batch: checked atomically with the swap so a reset
+                    # landing mid-step leaves the freshly zeroed pool in place
+                    raise AllocationFailed("Lane pool was reset while this batched step ran")
+                self._update(k_pool, v_pool)
+            n_spec = len(spec_states)
+            emitted_total = int(sum(int(host_m[lane]) for lane in spec_states))
+            accepted_total = emitted_total - n_spec  # one bonus token per lane
+            proposed_total = n_spec * self.spec_k
+            self.stats["batched_steps"] += 1
+            self.stats["batched_tokens"] += emitted_total
+            self.stats["spec_steps"] += 1
+            self.stats["spec_proposed"] += proposed_total
+            self.stats["spec_accepted"] += accepted_total
+            self.stats["max_spec_lanes"] = max(self.stats["max_spec_lanes"], n_spec)
+            duration = time.perf_counter() - t_step
+            tm.STEP_SPEC.observe(duration)
+            tm.STEPS_SPEC.inc()
+            tm.DECODE_TOKENS.inc(emitted_total)
+            tm.SPEC_PROPOSED.inc(proposed_total)
+            tm.SPEC_ACCEPTED.inc(accepted_total)
+            self._capture_step_fp(list(spec_states))
+            keys = []
+            per_lane_draft = draft_s / n_spec
+            for lane, st in spec_states.items():
+                key = self._ledger_keys.get(lane)
+                if key is not None:
+                    keys.append(key)
+                    self._ledger.note_tokens(key, decode=int(host_m[lane]))
+                    self._ledger.note_spec(
+                        key, draft_seconds=per_lane_draft,
+                        proposed=self.spec_k, accepted=int(host_m[lane]) - 1,
+                    )
+            self._ledger.note_compute(keys, duration)
+            for st in spec_states.values():
+                if not st.started:
+                    st.started = True
+                    st.queue_s = max(t_step - st.enqueued, 0.0) if st.enqueued else 0.0
+                st.compute_s += duration
         return host_g, host_m
 
     # ------------------------------------------------------- non-batchable ops

@@ -3,15 +3,33 @@ here: env-var log levels only, src/petals/utils/logging.py. This build adds
 per-RPC duration spans with aggregates, plus jax profiler integration so a
 device timeline can be captured on demand).
 
-Two layers:
+Three layers:
 - host spans: ``tracer.span("rpc_forward", tokens=...)`` records wall time +
   metadata into a bounded ring; ``tracer.summary()`` gives per-name
   count/p50/p95/total for rpc_info and logs. Each span also emits a
   ``jax.profiler.TraceAnnotation`` so the host block shows up aligned with
   device ops when a jax trace is being captured.
+- step phases: ``step_phases(stats, variant=...)`` walks one batched step
+  through ``assemble`` / ``dispatch`` / ``wait`` / ``post`` on the compute
+  thread. Each phase is a ``ptu.step.<name>`` TraceAnnotation inside one
+  ``ptu.step`` annotation, and its wall time is added to
+  ``stats["<name>_s"]``. Always on; nothing goes into the span ring (a few
+  hundred steps a second would push the RPC-level spans out of it).
 - device timeline: ``start_jax_trace(logdir)`` / ``stop_jax_trace()`` wrap
   ``jax.profiler`` (served via ``PETALS_TPU_TRACE_DIR`` at server startup;
   view in TensorBoard/XProf).
+
+Which serving paths a captured trace names, all on the compute thread
+(``ptu-compute`` to Python; the profiler names every Python thread's line
+after the process, so look for the line that holds these events): every
+batched step of the lane pool (``DecodeBatcher._run_batch``,
+``_run_batch_mixed``, ``_run_batch_gen``, ``_run_batch_spec``: ``ptu.step``
+and its four phases, with ``variant``, ``lanes`` and ``prefill_tokens`` as
+arguments), the private, exclusive and dense-prefill inference paths
+(``inference_step``), ``server_gen``, ``rpc_forward``, ``rpc_backward`` and
+``rpc_probe``. The RPC-level ``inference_step`` span around ``batcher.step``
+and ``batcher.prefill_lane`` lives on the event loop and is not annotated
+(concurrent spans interleave there).
 """
 
 from __future__ import annotations
@@ -27,6 +45,11 @@ from typing import Dict, Optional
 from petals_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+try:  # resolved once: the batched step opens five of these a step
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except Exception:  # profiler unavailable: spans and counters still record wall time
+    _TraceAnnotation = None
 
 TRACE_DIR_ENV = "PETALS_TPU_TRACE_DIR"
 TRACE_SECONDS_ENV = "PETALS_TPU_TRACE_SECONDS"
@@ -143,15 +166,75 @@ class Tracer:
             self._totals.clear()
 
 
-def device_annotation(name: str):
+def device_annotation(name: str, **args):
     """A jax profiler TraceAnnotation (no-op when the profiler is absent) —
-    place it around the compute itself, on the thread that runs it."""
-    try:
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # profiler unavailable: spans still record wall time
+    place it around the compute itself, on the thread that runs it. It
+    records only while a profiler trace is running; ``args`` become the
+    event's arguments in the trace."""
+    if _TraceAnnotation is None:
         return contextlib.nullcontext()
+    return _TraceAnnotation(name, **args)
+
+
+STEP_PHASES = ("assemble", "dispatch", "wait", "post")
+
+
+class phase:
+    """One host phase of a batched step, on the thread that runs it: a
+    ``ptu.step.<name>`` annotation around a ``perf_counter`` interval that is
+    added to ``stats[name + "_s"]``, also when the body raises."""
+
+    __slots__ = ("_stats", "_key", "_annotation", "_t0")
+
+    def __init__(self, stats: dict, name: str):
+        self._stats = stats
+        self._key = name + "_s"
+        self._annotation = device_annotation("ptu.step." + name)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc_info):
+        self._stats[self._key] += time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc_info)
+        return False
+
+
+class step_phases:
+    """One batched step as the four ``STEP_PHASES`` in order and without
+    gaps, inside one ``ptu.step`` annotation that carries ``args``. Entering
+    opens ``assemble``; the body calls ``enter(name)`` at each boundary; the
+    exit closes whichever phase is running, so none stays open on a raise."""
+
+    __slots__ = ("_stats", "_step", "_index", "_phase")
+
+    def __init__(self, stats: dict, **args):
+        self._stats = stats
+        self._step = device_annotation("ptu.step", **args)
+
+    def _open(self, index: int) -> None:
+        self._index = index
+        self._phase = phase(self._stats, STEP_PHASES[index])
+        self._phase.__enter__()
+
+    def __enter__(self):
+        self._step.__enter__()
+        self._open(0)
+        return self
+
+    def enter(self, name: str) -> None:
+        index = self._index + 1
+        if index >= len(STEP_PHASES) or STEP_PHASES[index] != name:
+            raise RuntimeError(f"step phase {name!r} cannot follow {STEP_PHASES[self._index]!r}")
+        self._phase.__exit__(None, None, None)
+        self._open(index)
+
+    def __exit__(self, *exc_info):
+        self._phase.__exit__(*exc_info)
+        self._step.__exit__(*exc_info)
+        return False
 
 
 _global_tracer: Optional[Tracer] = None

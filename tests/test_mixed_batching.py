@@ -8,6 +8,8 @@ Beats the reference, whose server runs every prefill as its own exclusive
 task pool step (reference src/petals/server/task_pool.py:35-36)."""
 
 import asyncio
+import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -502,3 +504,182 @@ def test_server_gen_after_mixed_prefill_greedy_and_sampling(model_path):
     assert stats["prefill_tokens"] >= 44, stats
     assert stats["exclusive_chunks"] == 0, stats
     assert stats["gen_steps"] > 0, stats
+
+
+# ------------------------------------------------ host phases of a step body
+
+PHASE_KEYS = ("assemble_s", "dispatch_s", "wait_s", "post_s")
+PHASE_NAMES = ["ptu.step.assemble", "ptu.step.dispatch", "ptu.step.wait", "ptu.step.post"]
+
+
+def _phase_batcher(backend, queue, **kwargs):
+    return DecodeBatcher(backend, backend.memory_cache, queue, n_lanes=2, max_length=64, **kwargs)
+
+
+def _hidden(cfg, seed, n=1):
+    return np.random.RandomState(seed).randn(1, n, cfg.hidden_size).astype(np.float32) * 0.1
+
+
+def test_phase_counters_sum_to_the_step_walls(model_path):
+    """After a handful of decode steps and one mixed step the four phase
+    counters are positive and together make up the walls the batcher already
+    reports as ``step_meta.compute_s`` (which start after the generation
+    guard and end before the telemetry, so they are the smaller by a little)."""
+    backend, cfg = _tiny_backend(model_path)
+
+    async def main():
+        queue = PriorityTaskQueue()
+        queue.start()
+        batcher = _phase_batcher(backend, queue, page_size=16)
+        try:
+            a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
+            await batcher.prefill_lane(a, _hidden(cfg, 1, 5), 0)  # compiles the mixed step
+            await batcher.step(a, _hidden(cfg, 2), 5)  # ...and the decode step
+            before = dict(batcher.stats)
+            walls = 0.0
+            for i in range(6):
+                await batcher.step(a, _hidden(cfg, 10 + i), 6 + i)
+                walls += batcher.pop_step_timing(a)["compute_s"]
+            await batcher.prefill_lane(b, _hidden(cfg, 3, 5), 0)
+            walls += batcher.pop_step_timing(b)["compute_s"]
+            delta = {k: batcher.stats[k] - before[k] for k in batcher.stats}
+            assert delta["batched_steps"] == 7 and delta["mixed_steps"] == 1
+            assert all(delta[k] > 0 for k in PHASE_KEYS), delta
+            phases = sum(delta[k] for k in PHASE_KEYS)
+            # measured here: 12.60 ms of phases on 12.52 ms of walls
+            assert 0.98 * walls <= phases <= 1.1 * walls + 2e-3, (phases, walls, delta)
+        finally:
+            await batcher.close()
+            queue.shutdown()
+
+    run(main())
+
+
+def test_turnaround_counts_only_inside_one_flush_task(model_path):
+    """``turnaround_s`` is the hand-off with work pending: it grows when two
+    lanes alternate under one flush task, and stays put when every step
+    starts a fresh one (the batcher had nothing to run in between)."""
+    backend, cfg = _tiny_backend(model_path)
+
+    async def main():
+        queue = PriorityTaskQueue()
+        queue.start()
+        batcher = _phase_batcher(backend, queue, page_size=16)
+        try:
+            a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
+            await batcher.step(a, _hidden(cfg, 1), 0)
+            await batcher.step(b, _hidden(cfg, 2), 0)
+            spawns, before = batcher._flush_spawns, batcher.stats["turnaround_s"]
+            for i in range(4):  # one lane alone: its reply ends the flush task every time
+                await batcher.step(a, _hidden(cfg, 10 + i), 1 + i)
+            assert batcher._flush_spawns == spawns + 4
+            assert batcher.stats["turnaround_s"] == before
+
+            fast, in_step = backend.paged_decode_step, threading.Event()
+
+            def slow(*args, **kwargs):  # long enough for the other lane's next step to arrive
+                in_step.set()
+                time.sleep(0.01)
+                return fast(*args, **kwargs)
+
+            backend.paged_decode_step = slow
+
+            async def lane_steps(lane, pos0, seed):
+                for i in range(4):
+                    await batcher.step(lane, _hidden(cfg, seed + i), pos0 + i)
+
+            spawns = batcher._flush_spawns
+            first = asyncio.create_task(lane_steps(a, 5, 20))
+            await asyncio.get_running_loop().run_in_executor(None, in_step.wait)
+            await asyncio.gather(first, lane_steps(b, 1, 30))  # lane b falls in behind lane a's step in flight
+            del backend.paged_decode_step
+            assert batcher._flush_spawns < spawns + 8  # some steps followed another under one task
+            assert batcher.stats["turnaround_s"] > before
+        finally:
+            await batcher.close()
+            queue.shutdown()
+
+    run(main())
+
+
+@pytest.mark.parametrize("variant", ["paged", "dense", "mixed"])
+def test_step_emits_its_phases_in_order(model_path, monkeypatch, variant):
+    """With TraceAnnotation replaced by a recorder, a step is one ``ptu.step``
+    (carrying variant, lanes and prefill tokens) around assemble, dispatch,
+    wait and post, each opened once and closed before the next."""
+    from tests.utils import record_step_annotations, recorded_steps
+
+    backend, cfg = _tiny_backend(model_path)
+    events = record_step_annotations(monkeypatch)
+
+    async def main():
+        queue = PriorityTaskQueue()
+        queue.start()
+        batcher = _phase_batcher(backend, queue, page_size=None if variant == "dense" else 16)
+        try:
+            lane = await batcher.acquire_lane()
+            if variant == "mixed":
+                await batcher.prefill_lane(lane, _hidden(cfg, 1, 5), 0)
+            else:
+                await batcher.step(lane, _hidden(cfg, 1), 0)
+        finally:
+            await batcher.close()
+            queue.shutdown()
+
+    run(main())
+    steps = recorded_steps(events)
+    want = {"variant": variant, "lanes": 0 if variant == "mixed" else 1, "prefill_tokens": 5 if variant == "mixed" else 0}
+    assert steps == [(want, PHASE_NAMES)], steps
+
+
+def test_phases_close_when_the_body_raises(model_path, monkeypatch):
+    """A pool reset that lands between the two generation guards makes the
+    body raise in ``post``: every phase was opened once, none stays open, the
+    time is counted and the step is not."""
+    from tests.utils import record_step_annotations, recorded_steps
+
+    backend, cfg = _tiny_backend(model_path)
+    events = record_step_annotations(monkeypatch)
+
+    async def main():
+        queue = PriorityTaskQueue()
+        queue.start()
+        batcher = _phase_batcher(backend, queue, page_size=16)
+        try:
+            lane = await batcher.acquire_lane()
+            step = backend.paged_decode_step
+
+            def reset_lands_meanwhile(*args, **kwargs):
+                batcher._generation += 1
+                return step(*args, **kwargs)
+
+            backend.paged_decode_step = reset_lands_meanwhile
+            with pytest.raises(AllocationFailed, match="reset while this batched step ran"):
+                await batcher.step(lane, _hidden(cfg, 1), 0)
+            del backend.paged_decode_step
+            assert batcher.stats["batched_steps"] == 0
+            assert all(batcher.stats[k] > 0 for k in PHASE_KEYS), batcher.stats
+        finally:
+            await batcher.close()
+            queue.shutdown()
+
+    run(main())
+    assert [names for _args, names in recorded_steps(events)] == [PHASE_NAMES]
+
+
+def test_phase_order_is_enforced():
+    """The shared walker refuses a phase out of its order or entered twice."""
+    from petals_tpu.utils.tracing import STEP_PHASES, step_phases
+
+    stats = {f"{name}_s": 0.0 for name in STEP_PHASES}
+    with step_phases(stats, variant="paged") as phases:
+        phases.enter("dispatch")
+        with pytest.raises(RuntimeError, match="cannot follow"):
+            phases.enter("dispatch")
+        with pytest.raises(RuntimeError, match="cannot follow"):
+            phases.enter("post")
+        phases.enter("wait")
+        phases.enter("post")
+        with pytest.raises(RuntimeError, match="cannot follow"):
+            phases.enter("assemble")
+    assert all(v > 0 for v in stats.values())

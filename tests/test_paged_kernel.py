@@ -287,6 +287,23 @@ def test_autotune_decision_cache(monkeypatch):
     )
 
 
+@pytest.mark.parametrize(
+    "pallas_ms, xla_ms, kernel",
+    [
+        (0.448, 0.452, False),  # Falcon-40B's class on the v5e: a tie in the harness
+        (0.452, 0.448, False),  # ... and the same tie read the other way round
+        (0.41, 0.45, False),  # faster, but inside the margin
+        (0.40, 0.45, True),  # faster by more than the margin
+        (0.50, 0.27, False),  # Mixtral-8x7B's class: the composed path by far
+    ],
+)
+def test_autotune_tie_goes_to_the_composed_path(pallas_ms, xla_ms, kernel):
+    """Two starts of one server must run the same step program: timings the
+    harness cannot tell apart give the composed path, whichever reads lower,
+    and the kernel takes a class only by ``KERNEL_MUST_WIN_BY``."""
+    assert pfa.kernel_wins(pallas_ms * 1e-3, xla_ms * 1e-3) is kernel
+
+
 def test_unsupported_shape_class_is_gated_off_the_kernel(monkeypatch, caplog):
     """On a TPU in auto mode a head width Mosaic cannot tile (neither a lane
     multiple nor packing evenly into 128 lanes) composes from XLA by a static

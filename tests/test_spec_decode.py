@@ -570,3 +570,36 @@ def test_e2e_generate_with_spec_matches_hf(spec_swarm, model_path):
         assert batcher.stats["spec_steps"] > spec0, "spec path never engaged e2e"
     finally:
         model.close()
+
+
+@pytest.mark.parametrize("variant", ["gen", "spec"])
+def test_gen_and_spec_steps_emit_their_phases_in_order(spec_swarm, monkeypatch, variant):
+    """The server-side generation body and the draft-verify body go through
+    the same phase walker as the decode bodies: one ``ptu.step`` a step, its
+    variant in the arguments, assemble / dispatch / wait / post inside it in
+    that order, and the four counters grow."""
+    from tests.utils import record_step_annotations, recorded_steps
+
+    batcher = _batcher(spec_swarm)
+    events = record_step_annotations(monkeypatch)
+    keys = ("assemble_s", "dispatch_s", "wait_s", "post_s")
+
+    async def main():
+        ctx = [int(t) for t in np.random.RandomState(29).randint(0, 100, size=6)]
+        before = dict(batcher.stats)
+        draft = batcher.draft
+        if variant == "gen":
+            batcher.draft = None  # the lane decodes plain: _run_batch_gen
+        try:
+            await _pooled_generate(batcher, _embed(batcher, ctx), 10, {"context": ctx})
+        finally:
+            batcher.draft = draft
+        assert batcher.stats[f"{variant}_steps"] > before[f"{variant}_steps"]
+        assert all(batcher.stats[k] > before[k] for k in keys)
+
+    spec_swarm.run(main())
+    steps = [s for s in recorded_steps(events) if s[0]["variant"] == variant]
+    assert steps and all(args["lanes"] == 1 and args["prefill_tokens"] == 0 for args, _ in steps)
+    assert all(
+        names == ["ptu.step." + k[:-2] for k in keys] for _args, names in steps
+    ), steps

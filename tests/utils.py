@@ -552,3 +552,51 @@ async def drive_coalescing_sessions(
         return elapsed, await c.call("ptu.info", {}, timeout=30)
     finally:
         await c.close()
+
+
+def record_step_annotations(monkeypatch) -> list:
+    """Replace the TraceAnnotation the server stack resolved at import
+    (utils/tracing.py) by a recorder. Returns the list it appends to:
+    ("open", name, args) and ("close", name) in the order they happen."""
+    from petals_tpu.utils import tracing
+
+    events: list = []
+
+    class Recorder:
+        def __init__(self, name, **args):
+            self.name, self.args = name, args
+
+        def __enter__(self):
+            events.append(("open", self.name, self.args))
+            return self
+
+        def __exit__(self, *exc_info):
+            events.append(("close", self.name))
+            return False
+
+    monkeypatch.setattr(tracing, "_TraceAnnotation", Recorder)
+    return events
+
+
+def recorded_steps(events: list) -> list:
+    """Split a recorder's events into steps: one (args, [names inside
+    ``ptu.step`` in order of opening]) per ``ptu.step``, asserting that every
+    annotation closed before the next opened and all of them inside the step."""
+    steps, stack = [], []
+    for event in events:
+        kind, name = event[0], event[1]
+        if not name.startswith("ptu.step"):
+            continue
+        if kind == "open":
+            if name == "ptu.step":
+                assert not stack, stack
+                steps.append((event[2], []))
+            else:
+                assert stack == ["ptu.step"], (name, stack)  # inside the step, no phase open
+                steps[-1][1].append(name)
+            stack.append(name)
+        else:
+            assert stack and stack[-1] == name, (name, stack)
+            stack.pop()
+    assert not stack, stack
+    return steps
