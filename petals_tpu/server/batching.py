@@ -25,10 +25,15 @@ TPU-first design — a LANE pool, not a page table:
   inserts it back — all under the server's priority queue, so it serializes
   with batched steps.
 
-Scheduling: greedy coalescing, no timers. Step requests accumulate while the
-current device step runs; the flush loop drains whatever is pending into the
-next step. Single-stream latency is untouched (a lone request flushes
-immediately); concurrent sessions batch automatically.
+Scheduling: coalescing, with a gather in front of every step. Step requests
+accumulate while the current device step runs; before the flush loop drains
+them into the next step it waits for the lanes that are predictably on their
+way back, for as long as that wait costs the ready lanes less than the step
+the returning lanes would otherwise sit out (DecodeBatcher._gather: both
+sides of that sum are measured, per lane and per server, and nothing is
+configured). Without it lanes whose clients answer within a few milliseconds
+settle into groups that take turns, and every token's gap is two steps.
+Single-stream latency is untouched (a lone request flushes immediately).
 """
 
 from __future__ import annotations
@@ -38,8 +43,10 @@ import contextlib
 import dataclasses
 import itertools
 import os
+import statistics
 import threading
 import time
+from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -131,6 +138,39 @@ class _LanePrefillState:
     wait_observed: bool = False  # first chunk already recorded the queue wait
     queue_s: float = 0.0  # admission -> first chunk (handler step_meta)
     compute_s: float = 0.0  # cumulative mixed-step wall across chunks
+
+
+@dataclasses.dataclass
+class _LaneReturn:
+    """One lane's way back to the batcher, as the gather sees it: when its
+    last decode reply was resolved, and how long after such a reply its next
+    ``step()`` has been coming lately (handler, wire, client, wire, handler).
+    The prediction is the mean of the last few returns, so one slow return
+    takes the lane out of every gather's reach until it has left the window."""
+
+    replied: Optional[float] = None  # None: not out (pending, in a step, back)
+    eta: Optional[float] = None  # predicted arrival; None: not expected
+    returns: deque = dataclasses.field(default_factory=lambda: deque(maxlen=5))
+
+    def reply_sent(self, now: float) -> None:
+        self.replied = now
+        if self.returns:  # a lane that has never come back is not predicted
+            self.eta = now + sum(self.returns) / len(self.returns)
+
+    def came_back(self, now: float) -> None:
+        self.returns.append(now - self.replied)
+        self.replied = self.eta = None
+
+    def expected(self, now: float, step_s: float) -> bool:
+        """Is this lane on its way back so that a step could wait for it: out
+        with a prediction, each of its last returns shorter than a step (a
+        hop of a chain or a slow client returns steps later and with jitter,
+        and no prediction of that is good to a fraction of one step), and not
+        yet late by more than its usual return (then it has stopped, or
+        thinks)."""
+        if self.eta is None:
+            return False
+        return max(self.returns) < step_s and now - self.eta < self.eta - self.replied
 
 
 @dataclasses.dataclass
@@ -325,6 +365,14 @@ class DecodeBatcher:
         # a stretch in which the batcher had nothing to run (_step_phases)
         self._flush_spawns = 0
         self._last_step_end: Tuple[float, int] = (0.0, -1)
+        # the gather (_gather): each lane's returns after its decode replies,
+        # the median wall of the last decode step bodies that carried no
+        # prompt chunk (the step a late lane sits out), and the event with
+        # which an arrival, a release, close() and a pool reset wake a gather
+        self._returns: Dict[int, _LaneReturn] = {}
+        self._step_walls: deque = deque(maxlen=9)
+        self._step_s = 0.0
+        self._gather_wake = asyncio.Event()
         self._open_lock = make_async_lock("batching._open_lock")
         self._closed = False
         # multi-host lockstep (parallel/multihost.py): lane ops broadcast so
@@ -349,6 +397,12 @@ class DecodeBatcher:
             # the hand-off between two steps of one flush task (_step_phases)
             "assemble_s": 0.0, "dispatch_s": 0.0, "wait_s": 0.0, "post_s": 0.0,
             "turnaround_s": 0.0,
+            # the gather in front of a step (_gather): steps that waited at
+            # all, seconds waited (in none of the five counters above), lanes
+            # that arrived during a wait and rode that step, expected lanes
+            # given up on
+            "gather_waits": 0, "gather_wait_s": 0.0,
+            "gather_joined": 0, "gather_missed": 0,
         }
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
@@ -419,6 +473,7 @@ class DecodeBatcher:
 
     async def close(self) -> None:
         self._closed = True
+        self._gather_wake.set()
         for w in self._lane_waiters:
             if not w.fut.done():
                 w.fut.set_exception(AllocationFailed("Batcher is shutting down"))
@@ -559,6 +614,9 @@ class DecodeBatcher:
         self._enq_t.pop(lane, None)
         self._step_timing.pop(lane, None)
         self._step_fp.pop(lane, None)
+        # nor is the departing tenant's return time the next one's, and a
+        # gather that waits for this lane has one fewer to wait for
+        self._forget_returns(lane)
         # a timed-out/cancelled session may have left a step queued: purge it,
         # or its stale KV write could land in the next tenant's history
         kept = []
@@ -1349,10 +1407,16 @@ class DecodeBatcher:
             )
 
     async def step(self, lane: int, hidden: np.ndarray, position: int) -> np.ndarray:
-        """One decode token for ``lane`` (hidden [1, 1, hidden]); coalesced
-        with whatever other lanes are pending by the time the device is free.
+        """One decode token for ``lane`` (hidden [1, 1, hidden]). It rides the
+        next batched step together with every lane that is pending when that
+        step starts, and the flush loop does not start a step while lanes that
+        are predictably on their way back are worth waiting for (``_gather``).
         A preempted (swapped-out) lane transparently swaps back in first."""
         t_enq = time.perf_counter()  # before _lane_busy: lock + alloc waits count as queue
+        back = self._returns.get(lane)
+        if back is not None and back.replied is not None:
+            back.came_back(t_enq)
+            self._gather_wake.set()  # no longer expected, even if a page wait holds it up
         async with self._lane_busy(lane):
             self._check_lane(lane)
             if self.page_size is not None:
@@ -1368,6 +1432,11 @@ class DecodeBatcher:
             fut = asyncio.get_running_loop().create_future()
             self._enq_t[lane] = t_enq  # written under _lane_busy: no overwrite race
             self._pending.append((lane, hidden, int(position), fut, self._generation))
+            # its way back is on record from here until release_lane: the
+            # reply of a step in flight finds no entry for a lane released
+            # meanwhile, so the next tenant starts without a history
+            self._returns.setdefault(lane, _LaneReturn())
+            self._gather_wake.set()
             self._spawn_flush_loop()
             return await fut
 
@@ -1383,8 +1452,81 @@ class DecodeBatcher:
                 log_exception_callback(logger, "decode flush loop")
             )
 
+    def _gather_until(self, now: float) -> Tuple[Optional[float], List[int]]:
+        """The rule of the gather. N units of work are ready (pending decode
+        lanes, generating and speculating lanes, one admitted prompt chunk)
+        and some lanes are expected (``_LaneReturn.expected``: the decode
+        reply is out, the lane has come back before, in less than a step, and
+        is not long overdue). Waiting w for M of them costs the ready lanes
+        N x w and spares those M lanes (S - w) each, the rest of the step of
+        S seconds they would otherwise sit out, so it pays while
+        w < S x M / (N + M). Over the expected lanes in the order of their
+        predicted arrival, the largest M whose arrival lies inside that bound
+        gives the lanes to wait for, and the bound itself the time until
+        which their coming still pays; (None, []) says start now."""
+        step_s = self._step_s
+        ready = len(self._pending) + len(self._gen_states) + bool(self._prefill_queue)
+        if not step_s or not ready:
+            return None, []
+        expected = sorted(
+            (back.eta, lane) for lane, back in self._returns.items()
+            if back.expected(now, step_s)
+        )
+        until, count = None, 0
+        for m, (eta, _lane) in enumerate(expected, 1):
+            pays_for = step_s * m / (ready + m)
+            if eta - now < pays_for:
+                until, count = now + pays_for, m
+        return until, [lane for _eta, lane in expected[:count]]
+
+    async def _gather(self) -> None:
+        """Before a step starts: wait, on the event loop, for the lanes that
+        ``_gather_until`` finds worth waiting for. An arrival, a release,
+        ``close()`` and a pool reset each wake the wait, and the rule is asked
+        again from that moment (N has grown, M has shrunk; what has been
+        waited is spent either way, so the last of eight lanes is weighed
+        against S/8 from when the seventh came, not from when the first did).
+        It ends the moment nobody is expected, when the time the rule gave
+        runs out with nobody come, and one step after it began whatever the
+        arrivals: that, S, bounds the whole wait. A lane that was waited for
+        and did not come leaves the expected set until it is seen again, so
+        it costs one wait, once. With nobody expected this returns without
+        suspending, and the loop is the one it was without a gather."""
+        start = now = time.perf_counter()
+        stop = start + self._step_s
+        waited_for: set = set()
+        while not self._closed and now < stop:
+            self._gather_wake.clear()
+            until, lanes = self._gather_until(now)
+            if until is None:
+                break
+            waited_for.update(lanes)
+            try:
+                await asyncio.wait_for(self._gather_wake.wait(), min(until, stop) - now)
+            except asyncio.TimeoutError:
+                break
+            now = time.perf_counter()
+        if not waited_for:
+            return
+        waited = time.perf_counter() - start
+        self.stats["gather_waits"] += 1
+        self.stats["gather_wait_s"] += waited
+        pending = {entry[0] for entry in self._pending}
+        for lane in waited_for:
+            back = self._returns.get(lane)
+            if lane in pending:
+                self.stats["gather_joined"] += 1
+            elif back is not None and back.replied is not None:
+                self.stats["gather_missed"] += 1
+                back.eta = None
+        # the batcher chose to have nothing running: that is no hand-off
+        # (turnaround_s: a step to run and the host in the way)
+        ended, spawn = self._last_step_end
+        self._last_step_end = (ended + waited, spawn)
+
     async def _flush_loop(self) -> None:
         while self._pending or self._gen_states or self._prefill_queue:
+            await self._gather()
             batch, self._pending = self._pending, []
             # entries enqueued before a pool reset must fail loudly — running
             # them against the rematerialized (zeroed) pool would be the
@@ -1473,9 +1615,12 @@ class DecodeBatcher:
                         pst.future.set_exception(e)
                 self._maybe_reset_pool()
                 continue
+            replied = time.perf_counter()
             for lane, _, _, fut, _gen in batch:
                 if not fut.done():
                     fut.set_result(out[lane : lane + 1])
+                    if lane in self._returns:  # not released while the step ran
+                        self._returns[lane].reply_sent(replied)
             if pf is not None and chunk_out is not None:
                 self._advance_prefill(pf[0], pf[1], chunk_out)
             if spec_res is not None:
@@ -1699,6 +1844,7 @@ class DecodeBatcher:
                 enqueued=time.perf_counter(),
             )
             self._prefill_queue.append(st)
+            self._forget_returns(lane)  # a new prompt: not a decode token's return
             self._spawn_flush_loop()
             try:
                 return await st.future
@@ -1780,6 +1926,7 @@ class DecodeBatcher:
                         seen[t0] = True
                     st.seen = seen
             self._gen_states[lane] = st
+            self._forget_returns(lane)  # generates here: it will not come back
             self._spawn_flush_loop()
             try:
                 return await st.future
@@ -1812,6 +1959,7 @@ class DecodeBatcher:
             # a collective the workers aren't entering).
             with self._reset_lock:
                 self._generation += 1
+            self._forget_returns()
             logger.warning(
                 "Pool-consuming lockstep op failed: invalidating outstanding "
                 "pooled sessions (group degradation handles the rest)"
@@ -1844,6 +1992,18 @@ class DecodeBatcher:
                     self.memory_cache.reset_buffer(handle)
                 except KeyError:
                     pass  # racing close(): handles already freed
+        self._forget_returns()
+
+    def _forget_returns(self, lane: Optional[int] = None) -> None:
+        """``lane`` (every lane after a pool reset, which invalidated them
+        all) is not expected back as it was: drop what is known of its way
+        back and let a gather in progress ask its rule again, so that work
+        admitted meanwhile counts and stale entries fail at once."""
+        if lane is None:
+            self._returns.clear()
+        else:
+            self._returns.pop(lane, None)
+        self._gather_wake.set()
 
     @contextlib.contextmanager
     def _step_phases(self, variant: str, lanes: int, prefill_tokens: int = 0):
@@ -1925,12 +2085,22 @@ class DecodeBatcher:
                 tm.STEP_DENSE.observe(duration)
                 tm.STEPS_DENSE.inc()
             tm.DECODE_TOKENS.inc(len(batch))
+            self._note_step_wall(duration)
             self._record_decode_timing(batch, t_step, duration)
             self._capture_step_fp([entry[0] for entry in batch])
             self._ledger_account_step(
                 duration, decode_lanes=[entry[0] for entry in batch]
             )
         return host_out
+
+    def _note_step_wall(self, duration: float) -> None:
+        """S of the gather's rule: the median wall of the last decode and gen
+        step bodies (compute thread). A median, so that a body that compiled
+        or stalled does not pass for the step a late lane would sit out; a
+        mixed step is left out because its chunk makes it longer than the
+        step the rule reckons with, which errs towards waiting less."""
+        self._step_walls.append(duration)
+        self._step_s = statistics.median(self._step_walls)
 
     def _record_decode_timing(self, batch, t_step: float, duration: float) -> None:
         """Per-lane queue/compute split for the handler's step_meta: queue is
@@ -2106,6 +2276,7 @@ class DecodeBatcher:
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
             tm.DECODE_TOKENS.inc(len(batch) + len(gen_states))
+            self._note_step_wall(duration)
             self._record_decode_timing(batch, t_step, duration)
             self._capture_step_fp([entry[0] for entry in batch] + list(gen_states))
             self._ledger_account_step(

@@ -556,9 +556,12 @@ def test_phase_counters_sum_to_the_step_walls(model_path):
 
 
 def test_turnaround_counts_only_inside_one_flush_task(model_path):
-    """``turnaround_s`` is the hand-off with work pending: it grows when two
-    lanes alternate under one flush task, and stays put when every step
-    starts a fresh one (the batcher had nothing to run in between)."""
+    """``turnaround_s`` is the hand-off with work pending: it stays put when
+    every step starts a fresh flush task (the batcher had nothing to run in
+    between), it grows when one lane's step follows another's under one task,
+    and the time the batcher spends gathering between two such steps (waiting
+    for a lane on its way back, ``_gather``) goes to ``gather_wait_s`` and
+    not to it."""
     backend, cfg = _tiny_backend(model_path)
 
     async def main():
@@ -575,26 +578,55 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
             assert batcher._flush_spawns == spawns + 4
             assert batcher.stats["turnaround_s"] == before
 
-            fast, in_step = backend.paged_decode_step, threading.Event()
+            fast, in_step, step_s = backend.paged_decode_step, threading.Event(), 0.01
 
-            def slow(*args, **kwargs):  # long enough for the other lane's next step to arrive
+            def slow(*args, **kwargs):  # long enough for the other lane's step to arrive
                 in_step.set()
-                time.sleep(0.01)
+                time.sleep(step_s)
                 return fast(*args, **kwargs)
 
             backend.paged_decode_step = slow
 
-            async def lane_steps(lane, pos0, seed):
-                for i in range(4):
-                    await batcher.step(lane, _hidden(cfg, seed + i), pos0 + i)
+            async def behind_a_step_in_flight(lane_ahead, pos_ahead, lane_behind, pos_behind):
+                in_step.clear()
+                ahead = asyncio.create_task(batcher.step(lane_ahead, _hidden(cfg, 20), pos_ahead))
+                await asyncio.get_running_loop().run_in_executor(None, in_step.wait)
+                await asyncio.gather(ahead, batcher.step(lane_behind, _hidden(cfg, 30), pos_behind))
 
-            spawns = batcher._flush_spawns
-            first = asyncio.create_task(lane_steps(a, 5, 20))
+            # two fresh tenants, so neither lane has a return on record and the
+            # gather waits for nobody: lane b's step falls in behind lane a's
+            # step in flight and follows it under the same flush task
+            batcher.release_lane(a)
+            batcher.release_lane(b)
+            a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
+            spawns, waits = batcher._flush_spawns, batcher.stats["gather_waits"]
+            await behind_a_step_in_flight(a, 0, b, 0)
+            assert batcher._flush_spawns == spawns + 1  # the second step followed the first under one task
+            handed_off = batcher.stats["turnaround_s"] - before
+            assert handed_off > 0 and batcher.stats["gather_waits"] == waits
+
+            # now lane a comes back 50 ms after its replies, on a step of 200 ms:
+            # lane b falls in behind a's step again, and this time the gather
+            # holds b's step for the 50 ms until a is back
+            step_s = 0.2
+            for i in range(5):  # S, the rule's step, is the median of the last nine walls
+                await batcher.step(a, _hidden(cfg, 40 + i), 1 + i)
+                await asyncio.sleep(0.05)
+            spawns, before = batcher._flush_spawns, dict(batcher.stats)
+            in_step.clear()
+            ahead = asyncio.create_task(batcher.step(a, _hidden(cfg, 50), 6))
             await asyncio.get_running_loop().run_in_executor(None, in_step.wait)
-            await asyncio.gather(first, lane_steps(b, 1, 30))  # lane b falls in behind lane a's step in flight
+            behind = asyncio.create_task(batcher.step(b, _hidden(cfg, 51), 1))
+            await ahead
+            await asyncio.sleep(0.05)
+            await asyncio.gather(behind, batcher.step(a, _hidden(cfg, 52), 7))
             del backend.paged_decode_step
-            assert batcher._flush_spawns < spawns + 8  # some steps followed another under one task
-            assert batcher.stats["turnaround_s"] > before
+            assert batcher._flush_spawns == spawns + 1
+            delta = {k: batcher.stats[k] - before[k] for k in before}
+            assert delta["batched_steps"] == 2 and delta["batched_tokens"] == 3, delta
+            assert delta["gather_waits"] == 1 and delta["gather_joined"] == 1, delta
+            # had the 50 ms gathered counted as hand-off, turnaround_s would exceed them
+            assert delta["gather_wait_s"] > 0.04 > delta["turnaround_s"], delta
         finally:
             await batcher.close()
             queue.shutdown()
