@@ -221,37 +221,22 @@ def bench_moe_dispatch(seq=2048, *, runs=3):
     import jax
     import jax.numpy as jnp
 
-    from petals_tpu.models.mixtral.block import moe_apply
-    from petals_tpu.models.mixtral.config import MixtralBlockConfig
+    from petals_tpu.models.moe import moe_apply
 
-    cfg = MixtralBlockConfig(
-        hidden_size=4096,
-        num_attention_heads=32,
-        num_key_value_heads=8,
-        head_dim=128,
-        intermediate_size=14336,
-        num_hidden_layers=1,
-        rms_norm_eps=1e-5,
-        vocab_size=32000,
-        num_local_experts=8,
-        num_experts_per_tok=2,
-        sliding_window=None,
-        rope_theta=1e6,
-    )
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 5)
-    h, m, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_local_experts
+    h, m, E, top_k = 4096, 14336, 8, 2
     params = {
         "gate": jax.random.normal(ks[0], (h, E), jnp.bfloat16) * 0.2,
         "w1": jax.random.normal(ks[1], (E, h, m), jnp.bfloat16) * 0.02,
         "w2": jax.random.normal(ks[2], (E, m, h), jnp.bfloat16) * 0.02,
         "w3": jax.random.normal(ks[3], (E, h, m), jnp.bfloat16) * 0.02,
     }
-    x = jax.random.normal(ks[4], (1, seq, cfg.hidden_size), jnp.bfloat16) * 0.3
+    x = jax.random.normal(ks[4], (1, seq, h), jnp.bfloat16) * 0.3
     jax.block_until_ready(params)
 
     fns = {
-        mode: jax.jit(functools.partial(moe_apply, cfg=cfg, sparse=(mode == "sparse")))
+        mode: jax.jit(functools.partial(moe_apply, top_k=top_k, renormalize=True, grouped=(mode == "sparse")))
         for mode in ("dense", "sparse")
     }
     times = {}
@@ -266,14 +251,14 @@ def bench_moe_dispatch(seq=2048, *, runs=3):
         times[mode] = best
     # useful assignment flops (top-k only): 3 matmuls over N*k rows
     flops_sparse = (
-        2 * seq * cfg.num_experts_per_tok * 3 * cfg.hidden_size * cfg.intermediate_size
+        2 * seq * top_k * 3 * h * m
     )
     result = {
         "label": f"moe_prefill_{seq}",
         "dense_ms": round(times["dense"] * 1e3, 1),
         "sparse_ms": round(times["sparse"] * 1e3, 1),
         "speedup": round(times["dense"] / times["sparse"], 2),
-        "flops_ratio_expected": round(cfg.num_local_experts / cfg.num_experts_per_tok, 1),
+        "flops_ratio_expected": round(E / top_k, 1),
         "sparse_tflops_useful": round(flops_sparse / times["sparse"] / 1e12, 1),
     }
     del params, x, fns

@@ -10,5 +10,6 @@ import petals_tpu.models.mistral  # noqa: F401
 import petals_tpu.models.gemma  # noqa: F401
 import petals_tpu.models.phi3  # noqa: F401
 import petals_tpu.models.gemma2  # noqa: F401
+import petals_tpu.models.olmoe  # noqa: F401
 
 __all__ = ["get_family", "register_family"]

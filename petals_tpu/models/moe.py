@@ -1,0 +1,144 @@
+"""Mixture-of-experts feed-forward shared by the families that route
+(mixtral, olmoe): a softmax router over all experts, the top k kept (and
+renormalised or not, as the family publishes), SwiGLU experts stacked
+``w1``/``w3`` [E, h, m] and ``w2`` [E, m, h]. All experts live on the hosting
+server (no cross-server expert parallelism, matching the reference).
+
+Two dispatches share the routing:
+
+- DENSE: every expert runs over every token (one batched einsum per
+  projection) with a top-k one-hot combine: static shapes, zero scatter, and
+  the only path under a tp/ep mesh or with quantized experts. It reads every
+  expert's weights and computes E / top_k times the FLOPs a token needs. At
+  8 experts a decode step reads them all anyway (a batch of two tokens already
+  reaches 3.5 of 8); at 64 experts of top 8 it reads 8x what one token needs
+  and a third more than eight lanes reach, which is ROADMAP S5's to cut.
+- GROUPED (round-3 "sparse" dispatch): assignments are sorted by expert and
+  the three projections run as grouped matmuls via ``jax.lax.ragged_dot``
+  (static total size N*k, dynamic per-expert group sizes), so FLOPs scale with
+  top_k instead of E: the megablocks-style dispatch in XLA's native ragged op.
+  Tokens are never dropped (no capacity factor); outputs match the dense
+  path's to within accumulation precision (the grouped combine runs in f32
+  where the dense combine rounds the routing weights to the compute dtype).
+
+``grouped_dispatch`` chooses between them from the static shapes.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from petals_tpu.models.common import silu
+
+
+class MoeDims(NamedTuple):
+    """The static shapes of a block's expert layer (a family's ``moe_dims(cfg)``)."""
+
+    experts: int
+    top_k: int
+    hidden: int
+    expert_width: int
+
+
+def _experts_grouped(x, w1, w2, w3, top_idx, top_probs) -> jnp.ndarray:
+    """Grouped-matmul dispatch: FLOPs proportional to N * top_k."""
+    b, s, h = x.shape
+    E, k = w1.shape[0], top_idx.shape[-1]
+    n_assign = b * s * k
+    xf = x.reshape(b * s, h)
+    flat_experts = top_idx.reshape(n_assign)
+    order = jnp.argsort(flat_experts, stable=True)  # group assignments by expert
+    token_of = order // k
+    xg = jnp.take(xf, token_of, axis=0)  # [N*k, h]
+    group_sizes = jnp.bincount(flat_experts, length=E).astype(jnp.int32)
+    g1 = jax.lax.ragged_dot(xg, w1, group_sizes)
+    g3 = jax.lax.ragged_dot(xg, w3, group_sizes)
+    out = jax.lax.ragged_dot(silu(g1) * g3, w2, group_sizes)  # [N*k, h]
+    wts = jnp.take(top_probs.reshape(n_assign), order).astype(jnp.float32)
+    y = jnp.zeros((b * s, h), jnp.float32)
+    y = y.at[token_of].add(out.astype(jnp.float32) * wts[:, None])
+    return y.astype(x.dtype).reshape(b, s, h)
+
+
+def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, grouped: bool = False) -> jnp.ndarray:
+    """x: [batch, seq, hidden] -> mixture of top-k experts, HF-exact routing.
+    ``renormalize`` divides the kept weights by their sum (Mixtral's rule;
+    OLMoE's ``norm_topk_prob`` false keeps the softmax mass as it is)."""
+    from petals_tpu.ops.quant import QuantizedLinear, quant_matmul
+
+    with jax.named_scope("ptu.moe.router"):
+        router_logits = x @ params["gate"]  # [b, s, E]
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        top_probs, top_idx = jax.lax.top_k(probs, top_k)  # [b, s, k]
+        if renormalize:
+            top_probs = top_probs / top_probs.sum(axis=-1, keepdims=True)
+
+    w1, w2, w3 = params["w1"], params["w2"], params["w3"]
+    if grouped and not isinstance(w1, QuantizedLinear):
+        with jax.named_scope("ptu.moe.experts.grouped"):
+            return _experts_grouped(x, w1, w2, w3, top_idx, top_probs)
+
+    n_experts = params["gate"].shape[-1]
+    with jax.named_scope("ptu.moe.experts.dense"):
+        # combine weights per expert: [b, s, E]
+        one_hot = jax.nn.one_hot(top_idx, n_experts, dtype=top_probs.dtype)
+        combine = (one_hot * top_probs[..., None]).sum(axis=2).astype(x.dtype)
+        if isinstance(w1, QuantizedLinear):
+            # Quantized experts: run each expert through quant_matmul (the fused
+            # NF4 kernel on TPU) — dense expert weights are never materialized, so
+            # the 4-bit memory budget that sized this span holds at runtime.
+            def expert(e):
+                def slice_q(q):
+                    return QuantizedLinear(q.kind, q.data[e], q.scales[e], q.in_features, q.out_features)
+
+                g = silu(quant_matmul(x, slice_q(w1))) * quant_matmul(x, slice_q(w3))
+                return quant_matmul(g, slice_q(w2))
+
+            expert_out = jnp.stack([expert(e) for e in range(n_experts)])  # [E, b, s, h]
+        else:
+            # dense expert compute on stacked weights: w1/w3 [E, h, m], w2 [E, m, h]
+            gate_out = jnp.einsum("bsh,ehm->ebsm", x, w1)
+            up = jnp.einsum("bsh,ehm->ebsm", x, w3)
+            expert_out = jnp.einsum("ebsm,emh->ebsh", silu(gate_out) * up, w2)
+        return jnp.einsum("ebsh,bse->bsh", expert_out, combine)
+
+
+# What the rule below reckons with, measured on one TPU v5e (PERF.md section 6,
+# PR 26; benchmarks/ablate_moe_dispatch.py, one layer's experts in bf16):
+GROUPED_MIN_SEQ = 8  # a call of fewer positions a row is decode-shaped ([lanes, 1, h], a spec verify's k + 1)
+FEW_EXPERTS = 8  # up to here ragged_dot's fixed cost a group is a rounding error of a layer (Mixtral: 0.2 ms of 3.7)
+GROUP_COST_S = 25e-6  # that fixed cost, three projections: 64 groups of 12.6 MB take 2.6 ms at 64-256 tokens,
+# where reading them takes 0.98
+HBM_BYTES_PER_S = 819e9
+DENSE_FLOPS_PER_S = 150e12  # the all-experts einsums past the weight read: 5.28 us a token at OLMoE's 805 MFLOP
+GROUPED_FLOPS_PER_S = 48e12  # ragged_dot's slope from 512 tokens to 1024 at the same shapes: 2.08 us a token
+
+
+def grouped_dispatch(dims: MoeDims, seq: int) -> bool:
+    """Whether a block call of ``seq`` positions a row takes the grouped
+    dispatch, from the static shapes alone (one choice per compiled program).
+
+    - Under ``GROUPED_MIN_SEQ`` positions: the all-experts einsum. These are
+      the decode-shaped calls, bound by the weight read either way.
+    - Few experts (Mixtral's 8): grouped from there on, as before PR 26
+      (Mixtral-8x7B, a layer: 2.3-3.4 ms against the einsum's 3.7 at 8-64
+      tokens, 7.2 against 9.0 at 512; worse at 128 and 256, 4.3 and 6.6
+      against 3.8 and 4.5, kept as it was: PERF.md section 7).
+    - Many small experts (OLMoE's 64 of 12.6 MB): ragged_dot's fixed cost is
+      paid once a group whatever the chunk, so the einsum wins (1.15 ms a
+      layer against 1.6-2.8 from 32 tokens to 256) until its E / top_k-fold
+      FLOPs outgrow that: the two estimates below cross at ~810 tokens
+      (measured: 2.70 against 3.13 ms at 512, 5.41 against 4.19 at 1024).
+      Nothing between 8 and 64 experts has been measured."""
+    if seq < GROUPED_MIN_SEQ:
+        return False
+    if dims.experts <= FEW_EXPERTS:
+        return True
+    expert_params = 3 * dims.hidden * dims.expert_width
+    read_s = dims.experts * 2 * expert_params / HBM_BYTES_PER_S
+    dense_s = max(read_s, seq * 2 * dims.experts * expert_params / DENSE_FLOPS_PER_S)
+    grouped_s = read_s + dims.experts * GROUP_COST_S + seq * 2 * dims.top_k * expert_params / GROUPED_FLOPS_PER_S
+    return grouped_s < dense_s

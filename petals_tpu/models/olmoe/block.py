@@ -1,19 +1,16 @@
-"""Mixtral (MoE) decoder block as a pure jitted JAX function.
+"""OLMoE decoder block as a pure jitted JAX function (HF ``modeling_olmoe.py``,
+departures none; the reference has no such family).
 
-Capability parity with the reference's WrappedMixtralBlock
-(/root/reference/src/petals/models/mixtral/block.py:13-113): all experts live
-on the hosting server (no cross-server expert parallelism, matching the
-reference), GQA attention with optional sliding window, top-k softmax routing.
+What sets it apart from Mixtral's block, whose attention plumbing and expert
+dispatch (models/moe.py) it shares:
 
-The expert layer is models/moe.py (shared with olmoe): HF-exact routing with
-the kept weights renormalised, and two dispatches, the all-experts einsum and
-the grouped ``ragged_dot``, chosen from the static shapes by
-``moe.grouped_dispatch``. At Mixtral's 8 experts of top 2 that is the grouped
-dispatch for a prompt chunk of 8 tokens or more and the all-experts einsum
-below that: a decode step is bound by weight bandwidth and reads nearly every
-expert anyway (two tokens already reach 3.5 of 8), so dense compute costs it
-nothing. That is true at 8 experts only: at 64 of top 8 the same einsum reads
-8x what a token needs (models/moe.py, ROADMAP S5).
+- QK-norm: an RMS norm over the WHOLE q and k projections (all heads' outputs
+  together), before they are split into heads and rotated. Under a tp mesh the
+  projections are column-sharded and the norm's mean spans the shards; GSPMD
+  inserts that all-reduce (parallel/tp.py).
+- many small experts (64 of width 1024, 8 a token in OLMoE-1B-7B) and a router
+  whose kept weights are NOT renormalised (``norm_topk_prob`` false): a token's
+  expert outputs are weighted by their share of the 64-way softmax mass.
 """
 
 from __future__ import annotations
@@ -25,15 +22,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from petals_tpu.models.common import KVCache, absolute_positions, mm, rms_norm, update_kv_cache
-from petals_tpu.models.mixtral.config import MixtralBlockConfig
 from petals_tpu.models.moe import MoeDims, grouped_dispatch, moe_apply
+from petals_tpu.models.olmoe.config import OlmoeBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend_maybe_ring
 from petals_tpu.ops.rotary import apply_rotary, rotary_tables
 
 
-def moe_dims(cfg: MixtralBlockConfig) -> MoeDims:
-    return MoeDims(cfg.num_local_experts, cfg.num_experts_per_tok, cfg.hidden_size, cfg.intermediate_size)
+def moe_dims(cfg: OlmoeBlockConfig) -> MoeDims:
+    return MoeDims(cfg.num_experts, cfg.num_experts_per_tok, cfg.hidden_size, cfg.intermediate_size)
 
 
 def block_apply(
@@ -41,7 +38,7 @@ def block_apply(
     hidden_states: jnp.ndarray,
     kv: Optional[KVCache],
     position,
-    cfg: MixtralBlockConfig,
+    cfg: OlmoeBlockConfig,
     *,
     use_flash: bool = False,
     tp_mesh=None,
@@ -53,9 +50,15 @@ def block_apply(
 
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln1"], cfg.rms_norm_eps)
-    q = mm(x, params["wq"]).reshape(batch, seq, hq, d)
-    k = mm(x, params["wk"]).reshape(batch, seq, hkv, d)
-    v = mm(x, params["wv"]).reshape(batch, seq, hkv, d)
+    q, k, v = mm(x, params["wq"]), mm(x, params["wk"]), mm(x, params["wv"])
+    with jax.named_scope("ptu.attn.qk_norm"):
+        q = rms_norm(q, params["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, params["k_norm"], cfg.rms_norm_eps)
+    if cfg.clip_qkv is not None:
+        q, k, v = (jnp.clip(t, -cfg.clip_qkv, cfg.clip_qkv) for t in (q, k, v))
+    q = q.reshape(batch, seq, hq, d)
+    k = k.reshape(batch, seq, hkv, d)
+    v = v.reshape(batch, seq, hkv, d)
 
     positions = absolute_positions(position, batch, seq)
     cos, sin = rotary_tables(positions, d, theta=cfg.rope_theta)
@@ -66,7 +69,7 @@ def block_apply(
     attn = attend_maybe_ring(
         q, k_all, v_all, kv=kv, position=position, n_valid=n_valid,
         kv_length=kv_length, ring_mesh=ring_mesh, use_flash=use_flash,
-        tp_mesh=tp_mesh, sliding_window=cfg.sliding_window,
+        tp_mesh=tp_mesh,
     )
     hidden_states = residual + mm(attn.reshape(batch, seq, hq * d), params["wo"])
 
@@ -75,7 +78,9 @@ def block_apply(
     # grouped dispatch single-device only (under an ep/tp mesh the dense
     # einsums carry the expert shardings; ragged groups don't)
     grouped = tp_mesh is None and ring_mesh is None and grouped_dispatch(moe_dims(cfg), seq)
-    hidden_states = residual + moe_apply(params, x, top_k=cfg.num_experts_per_tok, renormalize=True, grouped=grouped)
+    hidden_states = residual + moe_apply(
+        params, x, top_k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob, grouped=grouped
+    )
 
     new_kv = (k_all, v_all) if kv is not None else None
     return hidden_states, new_kv
@@ -88,36 +93,37 @@ def block_apply(
 _HF_BLOCK_PREFIXES = ("model.layers.{i}.",)
 
 
-def hf_to_block_params(tensors: dict, cfg: MixtralBlockConfig) -> dict:
+def hf_to_block_params(tensors: dict, cfg: OlmoeBlockConfig) -> dict:
     def t(name):
         return np.ascontiguousarray(np.asarray(tensors[name]).T)
 
-    E = cfg.num_local_experts
-    w1 = np.stack([t(f"block_sparse_moe.experts.{e}.w1.weight") for e in range(E)])
-    w2 = np.stack([t(f"block_sparse_moe.experts.{e}.w2.weight") for e in range(E)])
-    w3 = np.stack([t(f"block_sparse_moe.experts.{e}.w3.weight") for e in range(E)])
+    def stack(proj):  # 3 x num_experts tensors a layer, stacked [E, in, out] as Mixtral's are
+        return np.stack([t(f"mlp.experts.{e}.{proj}.weight") for e in range(cfg.num_experts)])
+
     return {
         "ln1": np.asarray(tensors["input_layernorm.weight"]),
         "wq": t("self_attn.q_proj.weight"),
         "wk": t("self_attn.k_proj.weight"),
         "wv": t("self_attn.v_proj.weight"),
         "wo": t("self_attn.o_proj.weight"),
+        "q_norm": np.asarray(tensors["self_attn.q_norm.weight"]),
+        "k_norm": np.asarray(tensors["self_attn.k_norm.weight"]),
         "ln2": np.asarray(tensors["post_attention_layernorm.weight"]),
-        "gate": t("block_sparse_moe.gate.weight"),
-        "w1": w1,
-        "w2": w2,
-        "w3": w3,
+        "gate": t("mlp.gate.weight"),
+        "w1": stack("gate_proj"),
+        "w2": stack("down_proj"),
+        "w3": stack("up_proj"),
     }
 
 
-def block_param_shapes(cfg: MixtralBlockConfig, dtype=jnp.bfloat16) -> dict:
+def block_param_shapes(cfg: OlmoeBlockConfig, dtype=jnp.bfloat16) -> dict:
     h, hq, hkv, d, m, E = (
         cfg.hidden_size,
         cfg.num_attention_heads,
         cfg.num_key_value_heads,
         cfg.head_dim,
         cfg.intermediate_size,
-        cfg.num_local_experts,
+        cfg.num_experts,
     )
     S = jax.ShapeDtypeStruct
     return {
@@ -126,6 +132,8 @@ def block_param_shapes(cfg: MixtralBlockConfig, dtype=jnp.bfloat16) -> dict:
         "wk": S((h, hkv * d), dtype),
         "wv": S((h, hkv * d), dtype),
         "wo": S((hq * d, h), dtype),
+        "q_norm": S((hq * d,), dtype),
+        "k_norm": S((hkv * d,), dtype),
         "ln2": S((h,), dtype),
         "gate": S((h, E), dtype),
         "w1": S((E, h, m), dtype),
@@ -136,8 +144,8 @@ def block_param_shapes(cfg: MixtralBlockConfig, dtype=jnp.bfloat16) -> dict:
 
 FAMILY = register_family(
     ModelFamily(
-        name="mixtral",
-        config_from_hf=MixtralBlockConfig.from_hf_config,
+        name="olmoe",
+        config_from_hf=OlmoeBlockConfig.from_hf_config,
         block_apply=block_apply,
         hf_block_prefixes=_HF_BLOCK_PREFIXES,
         hf_to_block_params=hf_to_block_params,

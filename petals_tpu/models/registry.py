@@ -23,6 +23,8 @@ class ModelFamily:
     hf_block_prefixes: tuple  # checkpoint prefixes of block i, with {i} placeholder
     hf_to_block_params: Callable  # (dict[str, np.ndarray], cfg) -> params pytree
     block_param_shapes: Optional[Callable] = None  # cfg -> pytree of jax.ShapeDtypeStruct
+    # a block with routed experts (models/moe.py): cfg -> MoeDims
+    moe_dims: Optional[Callable] = None
     # Underlying block architecture ("" -> same as name). Derived families
     # built via dataclasses.replace (qwen2/mistral over llama) inherit it, so
     # architecture-keyed tables (quantizable leaves, fuse groups in

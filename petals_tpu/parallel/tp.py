@@ -77,8 +77,8 @@ def span_param_pspecs(family_name: str, cfg) -> Dict[str, P]:
                 bo=P(), b_up=P(None, COL), b_down=P(),
             )
         return specs
-    if family_name == "mixtral":
-        return {
+    if family_name in ("mixtral", "olmoe"):
+        specs = {
             "ln1": P(),
             "wq": P(None, None, COL),
             "wk": P(None, None, COL),
@@ -92,6 +92,12 @@ def span_param_pspecs(family_name: str, cfg) -> Dict[str, P]:
             "w2": P(None, COL, None, None),
             "w3": P(None, COL, None, None),
         }
+        if family_name == "olmoe":
+            # QK-norm runs over the whole column-sharded q and k projections:
+            # its mean spans the shards, which GSPMD sums over ICI like the
+            # row-parallel psums; the norm vectors shard with the columns
+            specs.update(q_norm=P(None, COL), k_norm=P(None, COL))
+        return specs
     raise KeyError(f"No TP spec for family {family_name!r}")
 
 

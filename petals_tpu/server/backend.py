@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from petals_tpu.models.moe import grouped_dispatch
 from petals_tpu.models.registry import ModelFamily
 from petals_tpu.ops import fingerprint as fp_ops
 from petals_tpu.ops.sampling import sample_tokens, sampling_vectors
@@ -126,6 +127,8 @@ class TransformerBackend:
                 # pick the faster decode path ON THIS DEVICE before the first
                 # trace bakes one in (quant.py maybe_autotune_nf4_decode)
                 maybe_autotune_nf4_decode(cfg.hidden_size)
+        # a family with routed experts (models/moe.py): the expert layer's static shapes
+        self.moe_dims = family.moe_dims(cfg) if family.moe_dims is not None else None
         # adapter name -> (stacked {leaf: (A, B)}, scaling); see utils/peft.py
         self.adapters: Dict[str, tuple] = {}
         self._dummy_operands: Dict[tuple, jax.Array] = {}
@@ -135,6 +138,20 @@ class TransformerBackend:
         # — and popped by the batcher on its single compute thread
         self._last_step_fp = None  # [n_lanes, FP_DIM] device array or None
         self._last_chunk_fp = None  # [FP_DIM] (mixed step's prefill chunk)
+
+    def moe_grouped(self, seq: int, *, chunk: bool = False) -> Optional[bool]:
+        """Whether a block call of ``seq`` tokens a row (a mixed step's
+        ``chunk``: padded to its bucket) takes the grouped expert dispatch in
+        the lane pool's step programs (which give the block no mesh), by the
+        rule the block itself asks; None for a family without experts. The
+        batcher counts its tokens by this."""
+        if self.moe_dims is None:
+            return None
+        if chunk:
+            seq = bucket_length(seq)
+        from petals_tpu.ops.quant import QuantizedLinear
+
+        return not isinstance(self.params["w1"], QuantizedLinear) and grouped_dispatch(self.moe_dims, seq)
 
     # ------------------------------------------------------------- cache descriptors
 

@@ -404,6 +404,11 @@ class DecodeBatcher:
             "gather_waits": 0, "gather_wait_s": 0.0,
             "gather_joined": 0, "gather_missed": 0,
         }
+        if getattr(backend, "moe_dims", None) is not None:
+            # a family with routed experts only (_count_moe): tokens by the
+            # dispatch their step gave them, and the times a step's program
+            # walked a layer's experts
+            self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_weight_passes=0)
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
         # (telemetry.journal), and the pool gauges/counters feed the /metrics
@@ -2029,6 +2034,21 @@ class DecodeBatcher:
         finally:
             self._last_step_end = (time.perf_counter(), self._flush_spawns)
 
+    def _count_moe(self, tokens: int, *, seq: int = 1, chunk_tokens: int = 0) -> None:
+        """The expert counters of one step (compute thread; a family with
+        experts only), from the shapes the step was started with and nothing
+        from the device: ``tokens`` rode block calls of ``seq`` positions a
+        lane, ``chunk_tokens`` the mixed step's chunk half at its bucket; each
+        half is one more walk of every layer's experts (backend.py calls
+        ``block_apply`` once for the lanes and once for the chunk)."""
+        if "moe_weight_passes" not in self.stats:
+            return
+        grouped = self.backend.moe_grouped
+        self.stats["moe_grouped_tokens" if grouped(seq) else "moe_dense_tokens"] += tokens
+        if chunk_tokens:
+            self.stats["moe_grouped_tokens" if grouped(chunk_tokens, chunk=True) else "moe_dense_tokens"] += chunk_tokens
+        self.stats["moe_weight_passes"] += 2 if chunk_tokens else 1
+
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
         variant = "paged" if self.page_size is not None else "dense"
@@ -2077,6 +2097,7 @@ class DecodeBatcher:
             self.stats["batched_steps"] += 1
             self.stats["batched_tokens"] += len(batch)
             self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
+            self._count_moe(len(batch))
             duration = time.perf_counter() - t_step
             if self.page_size is not None:
                 tm.STEP_PAGED.observe(duration)
@@ -2186,6 +2207,7 @@ class DecodeBatcher:
             self.stats["max_prefill_tokens_per_step"] = max(
                 self.stats["max_prefill_tokens_per_step"], take
             )
+            self._count_moe(len(batch), chunk_tokens=take)
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2272,6 +2294,7 @@ class DecodeBatcher:
             self.stats["max_gen_lanes"] = max(
                 self.stats["max_gen_lanes"], len(gen_states)
             )
+            self._count_moe(len(batch) + len(gen_states))
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
@@ -2368,6 +2391,7 @@ class DecodeBatcher:
             self.stats["spec_proposed"] += proposed_total
             self.stats["spec_accepted"] += accepted_total
             self.stats["max_spec_lanes"] = max(self.stats["max_spec_lanes"], n_spec)
+            self._count_moe(n_spec * S, seq=S)
             duration = time.perf_counter() - t_step
             tm.STEP_SPEC.observe(duration)
             tm.STEPS_SPEC.inc()

@@ -40,6 +40,8 @@ QUANTIZABLE_LEAVES: Dict[str, Set[str]] = {
     # expert stacks (w1/w2/w3) carry >90% of Mixtral's params — quantized
     # per-expert (3-D leaves), unlike the reference which also quantizes them
     "mixtral": {"wq", "wk", "wv", "wo", "w1", "w2", "w3"},
+    # the same leaves; the two QK-norm vectors stay dense like every norm
+    "olmoe": {"wq", "wk", "wv", "wo", "w1", "w2", "w3"},
     "gemma2": {"wq", "wk", "wv", "wo", "wg", "wu", "wd"},
 }
 
@@ -119,7 +121,7 @@ def convert_block_params(
             out[name] = quantize(jnp.asarray(leaf), quant_type.value)
             n_quantized += 1
         elif name in quantizable and ndim == 3:  # expert stacks [E, in, out]
-            # expert stacks use the BASE kind: the mixtral block slices
+            # expert stacks use the BASE kind: models/moe.py slices
             # experts itself and the outlier side-arrays don't ride that path
             base = quant_type.value[:-2] if quant_type.value.endswith("+o") else quant_type.value
             per_expert = [quantize(jnp.asarray(leaf[e]), base) for e in range(leaf.shape[0])]

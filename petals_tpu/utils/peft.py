@@ -36,6 +36,7 @@ _TARGET_MAP = {
         "gate_proj": "wg", "up_proj": "wu", "down_proj": "wd",
     },
     "mixtral": {"q_proj": "wq", "k_proj": "wk", "v_proj": "wv", "o_proj": "wo"},
+    "olmoe": {"q_proj": "wq", "k_proj": "wk", "v_proj": "wv", "o_proj": "wo"},
     "bloom": {"query_key_value": None, "dense": "wo",  # fused qkv unsupported
               "dense_h_to_4h": "w_up", "dense_4h_to_h": "w_down"},
     "falcon": {"query_key_value": None, "dense": "wo",

@@ -144,23 +144,11 @@ def test_moe_sparse_dispatch_matches_dense():
     import jax
     import jax.numpy as jnp
 
-    from petals_tpu.models.mixtral.block import moe_apply
-    from petals_tpu.models.mixtral.config import MixtralBlockConfig
+    from petals_tpu.models.moe import moe_apply as shared_moe_apply
 
-    cfg = MixtralBlockConfig(
-        hidden_size=64,
-        num_attention_heads=4,
-        num_key_value_heads=2,
-        head_dim=16,
-        intermediate_size=128,
-        num_hidden_layers=2,
-        rms_norm_eps=1e-6,
-        vocab_size=256,
-        num_local_experts=8,
-        num_experts_per_tok=2,
-        sliding_window=None,
-        rope_theta=1e6,
-    )
+    def moe_apply(params, x, *, sparse):
+        return shared_moe_apply(params, x, top_k=2, renormalize=True, grouped=sparse)
+
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 5)
     params = {
@@ -170,8 +158,8 @@ def test_moe_sparse_dispatch_matches_dense():
         "w3": jax.random.normal(ks[3], (8, 64, 128), jnp.float32) * 0.05,
     }
     x = jax.random.normal(ks[4], (2, 16, 64), jnp.float32) * 0.3
-    dense = np.asarray(moe_apply(params, x, cfg, sparse=False))
-    sparse = np.asarray(moe_apply(params, x, cfg, sparse=True))
+    dense = np.asarray(moe_apply(params, x, sparse=False))
+    sparse = np.asarray(moe_apply(params, x, sparse=True))
     np.testing.assert_allclose(sparse, dense, atol=1e-5, rtol=1e-5)
 
     # degenerate routing (all tokens pick the same experts): group sizes are
@@ -181,6 +169,6 @@ def test_moe_sparse_dispatch_matches_dense():
     skew[:, 3] = 5.0
     skew[:, 6] = 4.0
     params_skew["gate"] = jnp.asarray(skew)
-    dense = np.asarray(moe_apply(params_skew, x, cfg, sparse=False))
-    sparse = np.asarray(moe_apply(params_skew, x, cfg, sparse=True))
+    dense = np.asarray(moe_apply(params_skew, x, sparse=False))
+    sparse = np.asarray(moe_apply(params_skew, x, sparse=True))
     np.testing.assert_allclose(sparse, dense, atol=1e-5, rtol=1e-5)

@@ -195,7 +195,7 @@ def test_mixtral_full_model(tmp_path_factory):
             expected = _hf_greedy(path, input_ids, 5)
             np.testing.assert_array_equal(ours, expected)
 
-            # a >= SPARSE_MIN_SEQ prompt exercises the sparse (ragged_dot)
+            # a prompt of 8 tokens or more takes the grouped (ragged_dot)
             # MoE dispatch in the serving prefill; still token-identical
             long_ids = rng.randint(0, 100, (1, 12)).astype(np.int64)
             ours_long = model.generate(long_ids, max_new_tokens=4)

@@ -65,18 +65,19 @@ def test_ring_sliding_window_matches_reference(window):
 
 @pytest.mark.parametrize(
     "family_fixture",
-    ["bloom", pytest.param("falcon", marks=pytest.mark.slow), pytest.param("mixtral", marks=pytest.mark.slow)],
+    ["bloom", pytest.param("falcon", marks=pytest.mark.slow), pytest.param("mixtral", marks=pytest.mark.slow), "olmoe"],
 )
 def test_block_ring_matches_plain(family_fixture, tmp_path):
     """Every family's block must produce identical outputs with and without
     the ring (the sp training path now covers all four families)."""
     from petals_tpu.server.from_pretrained import get_block_config, load_block_params
-    from tests.utils import make_tiny_bloom, make_tiny_falcon, make_tiny_mixtral
+    from tests.utils import make_tiny_bloom, make_tiny_falcon, make_tiny_mixtral, make_tiny_olmoe
 
     maker = {
         "bloom": make_tiny_bloom,
         "falcon": make_tiny_falcon,
         "mixtral": make_tiny_mixtral,
+        "olmoe": make_tiny_olmoe,
     }[family_fixture]
     path = maker(str(tmp_path))
     family, cfg = get_block_config(path)

@@ -209,6 +209,36 @@ def make_tiny_mixtral(tmpdir: str, *, n_layers: int = 2, vocab: int = 128) -> st
 
 
 @_model_build_cache
+def make_tiny_olmoe(tmpdir: str, *, n_layers: int = 2, vocab: int = 128) -> str:
+    from transformers import OlmoeConfig, OlmoeForCausalLM
+
+    cfg = OlmoeConfig(
+        vocab_size=vocab,
+        hidden_size=64,
+        intermediate_size=64,  # one expert's width (a multiple of the 4-bit block of 64)
+        num_hidden_layers=n_layers,
+        num_attention_heads=4,
+        num_key_value_heads=4,
+        num_experts=8,
+        num_experts_per_tok=3,
+        norm_topk_prob=False,
+        max_position_embeddings=256,
+        rms_norm_eps=1e-5,
+        rope_theta=10000.0,
+        tie_word_embeddings=False,
+    )
+    torch.manual_seed(11)
+    model = OlmoeForCausalLM(cfg).eval()
+    with torch.no_grad():  # norm weights initialise to ones, which would hide a missing or misplaced QK-norm vector
+        for name, p in model.named_parameters():
+            if name.endswith("norm.weight"):
+                p.uniform_(0.5, 1.5)
+    path = os.path.join(tmpdir, "tiny-olmoe")
+    model.save_pretrained(path, safe_serialization=True)
+    return path
+
+
+@_model_build_cache
 def make_tiny_qwen2(tmpdir: str, *, n_layers: int = 4, vocab: int = 128, tied: bool = True) -> str:
     from transformers import Qwen2Config, Qwen2ForCausalLM
 
