@@ -14,7 +14,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from petals_tpu.models.bloom.config import BloomBlockConfig
-from petals_tpu.models.common import KVCache, gelu_tanh, layer_norm, mm, update_kv_cache
+from petals_tpu.models.common import (
+    KVCache,
+    gelu_tanh,
+    layer_norm,
+    mm,
+    project_heads,
+    update_kv_cache,
+)
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.alibi import build_alibi_slopes
 from petals_tpu.ops.attention import attend_maybe_ring
@@ -38,9 +45,9 @@ def block_apply(
     ln1 = layer_norm(hidden_states, params["ln1_w"], params["ln1_b"], cfg.layer_norm_epsilon)
     residual = ln1 if cfg.apply_residual_connection_post_layernorm else hidden_states
 
-    q = (mm(ln1, params["wq"]) + params["bq"]).reshape(batch, seq, h, d)
-    k = (mm(ln1, params["wk"]) + params["bk"]).reshape(batch, seq, h, d)
-    v = (mm(ln1, params["wv"]) + params["bv"]).reshape(batch, seq, h, d)
+    q = (project_heads(ln1, params["wq"]) + params["bq"]).reshape(batch, seq, h, d)
+    k = (project_heads(ln1, params["wk"]) + params["bk"]).reshape(batch, seq, h, d)
+    v = (project_heads(ln1, params["wv"]) + params["bv"]).reshape(batch, seq, h, d)
 
     k_all, v_all, kv_length = update_kv_cache(kv, k, v, position, n_valid)
     slopes = build_alibi_slopes(h)

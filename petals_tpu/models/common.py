@@ -71,6 +71,25 @@ def mm(x: jnp.ndarray, w) -> jnp.ndarray:
     return x @ w
 
 
+def project_heads(x: jnp.ndarray, w) -> jnp.ndarray:
+    """``mm`` for a projection whose product is about to be split into heads
+    (q, k, v): the product is handed over in the matmul's own layout.
+
+    Attention wants its operands with the heads minor. Left alone, XLA's
+    layout assignment carries that wish back through the reshape into the dot
+    and pays for it on the WEIGHT: inside the step's scan it slices the
+    layer's matrix out of the stacked span and transposes the copy, every
+    layer of every step (Falcon-40B: 134 MB twice a layer, 27% of the decode
+    loop on a v5e). The barrier stops the wish at the product, so the relayout
+    falls on the activation and the dot reads the stacked weight in place.
+    The arithmetic is as written, and on the CPU the bits are ``mm``'s; the
+    TPU's decode program used to hand q to the rotary in the dot's float32
+    and now rounds it to the activations' dtype first, as the code says.
+    ``tests/test_kernels_lower_tpu.py`` holds the compiled decode step to
+    reading its weights in place."""
+    return jax.lax.optimization_barrier(mm(x, w))
+
+
 def absolute_positions(position, batch: int, seq: int) -> jnp.ndarray:
     """[batch, seq] absolute positions for this chunk's tokens.
 

@@ -17,7 +17,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from petals_tpu.models.common import KVCache, absolute_positions, layer_norm, mm, update_kv_cache
+from petals_tpu.models.common import (
+    KVCache,
+    absolute_positions,
+    layer_norm,
+    mm,
+    project_heads,
+    update_kv_cache,
+)
 from petals_tpu.models.falcon.config import FalconBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.alibi import build_alibi_slopes
@@ -60,9 +67,9 @@ def block_apply(
         attn_ln = layer_norm(hidden_states, params["ln1_w"], params["ln1_b"], cfg.layer_norm_epsilon)
         mlp_ln = attn_ln  # parallel single-LN case; serial case overwritten below
 
-    q = mm(attn_ln, params["wq"])
-    k = mm(attn_ln, params["wk"])
-    v = mm(attn_ln, params["wv"])
+    q = project_heads(attn_ln, params["wq"])
+    k = project_heads(attn_ln, params["wk"])
+    v = project_heads(attn_ln, params["wv"])
     if cfg.bias:
         q = q + params["bq"]
         k = k + params["bk"]

@@ -28,6 +28,7 @@ from petals_tpu.models.common import (
     KVCache,
     absolute_positions,
     mm,
+    project_heads,
     rms_norm,
     update_kv_cache,
 )
@@ -59,9 +60,9 @@ def block_apply(
         k = qkv[..., hq * d : (hq + hkv) * d]
         v = qkv[..., (hq + hkv) * d :]
     else:
-        q = mm(x, params["wq"])
-        k = mm(x, params["wk"])
-        v = mm(x, params["wv"])
+        q = project_heads(x, params["wq"])
+        k = project_heads(x, params["wk"])
+        v = project_heads(x, params["wv"])
     q = q.reshape(batch, seq, hq, d)
     k = k.reshape(batch, seq, hkv, d)
     v = v.reshape(batch, seq, hkv, d)

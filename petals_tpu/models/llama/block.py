@@ -15,7 +15,15 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-from petals_tpu.models.common import ACTIVATIONS, KVCache, absolute_positions, mm, rms_norm, update_kv_cache
+from petals_tpu.models.common import (
+    ACTIVATIONS,
+    KVCache,
+    absolute_positions,
+    mm,
+    project_heads,
+    rms_norm,
+    update_kv_cache,
+)
 from petals_tpu.models.llama.config import LlamaBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend_maybe_ring
@@ -49,9 +57,9 @@ def block_apply(
         k = qkv[..., hq * d : (hq + hkv) * d]
         v = qkv[..., (hq + hkv) * d :]
     else:
-        q = mm(x, params["wq"])
-        k = mm(x, params["wk"])
-        v = mm(x, params["wv"])
+        q = project_heads(x, params["wq"])
+        k = project_heads(x, params["wk"])
+        v = project_heads(x, params["wv"])
         if cfg.attention_bias or cfg.qkv_bias:
             q = q + params["bq"]
             k = k + params["bk"]

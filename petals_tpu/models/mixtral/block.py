@@ -24,7 +24,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from petals_tpu.models.common import KVCache, absolute_positions, mm, rms_norm, update_kv_cache
+from petals_tpu.models.common import (
+    KVCache,
+    absolute_positions,
+    mm,
+    project_heads,
+    rms_norm,
+    update_kv_cache,
+)
 from petals_tpu.models.mixtral.config import MixtralBlockConfig
 from petals_tpu.models.moe import MoeDims, grouped_dispatch, moe_apply
 from petals_tpu.models.registry import ModelFamily, register_family
@@ -53,9 +60,9 @@ def block_apply(
 
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln1"], cfg.rms_norm_eps)
-    q = mm(x, params["wq"]).reshape(batch, seq, hq, d)
-    k = mm(x, params["wk"]).reshape(batch, seq, hkv, d)
-    v = mm(x, params["wv"]).reshape(batch, seq, hkv, d)
+    q = project_heads(x, params["wq"]).reshape(batch, seq, hq, d)
+    k = project_heads(x, params["wk"]).reshape(batch, seq, hkv, d)
+    v = project_heads(x, params["wv"]).reshape(batch, seq, hkv, d)
 
     positions = absolute_positions(position, batch, seq)
     cos, sin = rotary_tables(positions, d, theta=cfg.rope_theta)

@@ -21,7 +21,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from petals_tpu.models.common import KVCache, absolute_positions, mm, rms_norm, update_kv_cache
+from petals_tpu.models.common import (
+    KVCache,
+    absolute_positions,
+    mm,
+    project_heads,
+    rms_norm,
+    update_kv_cache,
+)
 from petals_tpu.models.moe import MoeDims, grouped_dispatch, moe_apply
 from petals_tpu.models.olmoe.config import OlmoeBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
@@ -50,7 +57,7 @@ def block_apply(
 
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln1"], cfg.rms_norm_eps)
-    q, k, v = mm(x, params["wq"]), mm(x, params["wk"]), mm(x, params["wv"])
+    q, k, v = (project_heads(x, params[name]) for name in ("wq", "wk", "wv"))
     with jax.named_scope("ptu.attn.qk_norm"):
         q = rms_norm(q, params["q_norm"], cfg.rms_norm_eps)
         k = rms_norm(k, params["k_norm"], cfg.rms_norm_eps)

@@ -172,3 +172,48 @@ def test_moe_sparse_dispatch_matches_dense():
     dense = np.asarray(moe_apply(params_skew, x, sparse=False))
     sparse = np.asarray(moe_apply(params_skew, x, sparse=True))
     np.testing.assert_allclose(sparse, dense, atol=1e-5, rtol=1e-5)
+
+
+# head geometry of the three families the benchmark's cells run, at a tiny width:
+# (hidden, query heads, kv heads, head_dim)
+HEAD_GEOMETRIES = {"falcon": (64, 8, 2, 8), "mixtral": (64, 4, 2, 16), "olmoe": (32, 4, 4, 8)}
+
+
+@pytest.mark.parametrize("family", sorted(HEAD_GEOMETRIES))
+def test_project_heads_is_mm_bit_for_bit(family):
+    """``project_heads`` pins the product's layout and nothing else: the same
+    bytes as ``mm`` into the head split, for a dense weight and through a
+    ``LoraLinear``, and the same gradients (the backward path runs
+    ``block_apply`` too)."""
+    import functools
+
+    import jax
+
+    from petals_tpu.models.common import mm, project_heads
+    from petals_tpu.utils.peft import LoraLinear
+
+    hidden, hq, hkv, d = HEAD_GEOMETRIES[family]
+    keys = jax.random.split(jax.random.PRNGKey(27), 4)
+    x = jax.random.normal(keys[0], (2, 5, hidden), jnp.float32).astype(jnp.bfloat16)
+    for heads, key in ((hq, keys[1]), (hkv, keys[2])):
+        dense = (0.1 * jax.random.normal(key, (hidden, heads * d), jnp.float32)).astype(jnp.bfloat16)
+        ka, kb = jax.random.split(keys[3])
+        lora = LoraLinear(
+            dense,
+            jax.random.normal(ka, (hidden, 4), jnp.float32).astype(jnp.bfloat16),
+            jax.random.normal(kb, (4, heads * d), jnp.float32).astype(jnp.bfloat16),
+            0.5,
+        )
+        for w in (dense, lora):
+
+            def loss(project, x, w):
+                out = project(x, w).reshape(2, 5, heads, d).astype(jnp.float32)
+                return jnp.sum(out * out), out
+
+            def run(project):
+                grad = jax.value_and_grad(functools.partial(loss, project), (0, 1), has_aux=True)
+                (_, out), grads = jax.jit(grad)(x, w)
+                return [out, *jax.tree_util.tree_leaves(grads)]
+
+            for got, want in zip(run(project_heads), run(mm), strict=True):
+                np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))

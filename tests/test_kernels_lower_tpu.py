@@ -8,9 +8,18 @@ that has no chip, and ``jit(f).lower(<avals placed on one>).compile()`` runs
 the real Pallas -> Mosaic -> libtpu pipeline with ``interpret=False``. Each
 case is one full-width shape (Llama-2-7B / 70B head geometry); nothing
 executes. Numerics on the chip itself are chip_smoke.py's kernel phase.
+
+The last section compiles a whole step program the same way, the paged decode
+step at the benchmark's three configurations, and reads the optimized HLO for
+what no run on the CPU can show: that the decode loop reads every stacked
+weight where it lies (PERF.md section 5, "The relayout").
 """
 
 import functools
+import json
+import math
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -167,3 +176,122 @@ def test_int8_matmul_lowers(v5e, m, stacked):
     else:
         fn = lambda x, d, s: Q._int8_call(x, d, s, interpret=False)  # noqa: E731
         _compile(fn, v5e((m, IN), BF16), data, scales)
+
+
+# ---------------------------------------------------------------- the decode loop reads its weights in place
+
+# HLO ops that move data and compute nothing (a fusion counts when its body holds nothing else)
+_MOVES = frozenset({
+    "parameter", "constant", "get-tuple-element", "tuple", "bitcast", "reshape", "transpose",
+    "copy", "copy-start", "copy-done", "slice", "dynamic-slice",
+})
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+)\s*=\s*\(?\w+\[(?P<dims>[\d,]*)\]\S*.*?\s(?P<op>[\w\-]+)\((?P<rest>.*)$"
+)
+
+
+def _computations(hlo: str) -> dict:
+    """Optimized HLO text -> {computation: [(name, dims, op, rest of the line)]}, in program order."""
+    out, current = {}, None
+    for line in hlo.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = out.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            m = _INSTRUCTION.match(line)
+            if m:
+                dims = tuple(int(n) for n in m.group("dims").split(",") if n)
+                current.append((m.group("name"), dims, m.group("op"), m.group("rest")))
+    return out
+
+
+def weight_relayouts(hlo: str, stacked_shapes: set, min_elements: int) -> tuple:
+    """``(relayouts, weights_seen)`` of the while bodies of an optimized HLO
+    module. A relayout is an instruction that only MOVES a stacked weight: its
+    operand is a loop-carried array of one of ``stacked_shapes`` (or another
+    such move of one), it computes nothing (a ``copy``, or a fusion made of
+    slices, copies and bitcasts alone), and it materialises at least
+    ``min_elements`` values. A dot fusion that takes the stacked array itself
+    (``fusion(bf16[5,8192,32768] ...)``) reads the weight in place and is what
+    the loop should be made of. ``weights_seen`` counts the loop-carried arrays
+    of those shapes, so that a caller can tell "none found" from "not parsed"."""
+    comps = _computations(hlo)
+    relayouts, seen = [], 0
+    for instructions in comps.values():
+        for _, _, op, rest in instructions:
+            body = re.search(r"body=%([\w.\-]+)", rest) if op == "while" else None
+            if body is None:
+                continue
+            moved = set()  # names in the body that are a stacked weight, or a pure move of one
+            for name, dims, op_, rest_ in comps[body.group(1)]:
+                if op_ == "get-tuple-element":
+                    if dims in stacked_shapes:
+                        moved.add(name)
+                        seen += 1
+                    continue
+                called = re.search(r"calls=%([\w.\-]+)", rest_)
+                moves = op_ in _MOVES or (
+                    op_ == "fusion" and called is not None and all(i[2] in _MOVES for i in comps[called.group(1)])
+                )
+                if moves and moved & set(re.findall(r"%([\w.\-]+)", rest_)):
+                    moved.add(name)
+                    if op_ != "bitcast" and math.prod(dims) >= min_elements:
+                        relayouts.append(f"%{name} = {op_} -> {list(dims)}")
+    return relayouts, seen
+
+
+STEP_CASES = [
+    pytest.param(
+        config_name, chunk, id=f"{config_name}-{'mixed-256' if chunk else 'decode'}",
+        marks=[pytest.mark.xfail(strict=True, reason=(
+            "the chunk's grouped expert dispatch: ragged_dot is a custom call and cannot read the stacked span in "
+            "place, so each layer's w1, w3 and w2 (940 MB each) are sliced out first (ROADMAP S7)"
+        ))] if (config_name, chunk) == ("mixtral-8x7b-span2", 256) else [],
+    )
+    for config_name in ("falcon-40b-span5", "mixtral-8x7b-span2", "olmoe-1b-7b-span8")
+    for chunk in (0, 256)
+]
+
+
+@pytest.mark.parametrize("config_name,chunk", STEP_CASES)
+def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_name, chunk):
+    """``TransformerBackend``'s paged decode step, and its mixed step with a
+    prompt chunk of 256 riding it, at a cell's widths and depth (8 lanes, 128
+    pages of 64, 16 pages a lane, pools donated), compiled for the v5e: no
+    instruction of the loop's body may slice a layer's matrix out of the
+    stacked span into a buffer of its own, or copy one. Before
+    ``models/common.py project_heads`` the body held ``bf16[1,8192,8192]``
+    twice a layer for Falcon's ``wq`` (a dynamic-slice fusion, then a
+    transposing copy: 27% of the decode loop on the chip), the same pair for
+    ``wk`` / ``wv``, and Mixtral's and OLMoE's likewise."""
+    from perf.config import load as load_config
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.from_pretrained import get_block_config
+
+    config_file = Path(__file__).resolve().parents[1] / "perf" / "configs" / f"{config_name}.json"
+    hf_config = load_config(config_file, config_name)["config"]
+    (tmp_path / "config.json").write_text(json.dumps(hf_config))
+    family, cfg = get_block_config(str(tmp_path))
+    depth, lanes, n_pages, page_size, pages_a_lane = hf_config["num_hidden_layers"], 8, 128, 64, 16
+    params = {
+        name: v5e((depth, *leaf.shape), leaf.dtype) for name, leaf in family.block_param_shapes(cfg, BF16).items()
+    }
+    backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=depth, memory_cache=None)
+    pool = v5e((depth, n_pages, page_size, backend.num_kv_heads, backend.head_dim), BF16)
+    avals = [params, pool, pool, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
+    step = backend._paged_decode_fn
+    if chunk:  # chunk_hidden, then chunk_lane, chunk_pos, chunk_n_valid, chunk_n_total
+        step = backend._paged_mixed_step_fn
+        avals += [v5e((1, chunk, cfg.hidden_size), BF16)] + [v5e((), I32)] * 4
+    # the raw step under tracked_jit: kernel_path only retraces, attend() resolves the path itself
+    step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
+    hlo = jax.jit(step, donate_argnums=(1, 2)).lower(*avals).compile().as_text()
+    attention = [params[name].shape for name in ("wq", "wk", "wv", "wo")]
+    relayouts, seen = weight_relayouts(
+        hlo, {tuple(p.shape) for p in params.values()}, min(math.prod(shape[1:]) for shape in attention)
+    )
+    assert seen >= len(set(attention)), "the loop's stacked weights were not found: has the HLO text changed?"
+    assert not relayouts, f"the step's loop relays a weight in every layer of every step: {relayouts}"
