@@ -1,28 +1,18 @@
 """Disaggregated prefill/decode serving benchmark: phase tiers + KV handoff.
 
-Two rows over one tiny-llama swarm recipe:
-
-- ``gate_disagg_handoff`` (CPU perf gate, seconds): boots one prefill-tier
-  + one decode-tier replica, runs a handful of greedy sessions through the
-  prefill->decode handoff, and hard-asserts the subsystem's contract:
-  HF-identical tokens, every session decoding on the decode tier, adopts
-  only (zero replays, zero fallbacks), handoff bytes > 0 and billed as
-  migration bytes on BOTH ends of the in-process ledger, and a clean
-  source (no leaked sessions, parked snapshots, or busy lanes). Cheap
-  enough to pin in BENCH_GATE_CPU.json.
-
-- the heavy A/B row (``--check``): the experiment the subsystem claims.
-  One seeded prefill-storm trace (a flat calm stream of short-prompt
-  sessions + seeded bursts of long prompts with short decodes) is
-  replayed against a DISAGGREGATED swarm (1 prefill-tier + 1 decode-tier
-  replica) and a COLOCATED baseline (2 generalists, same lane count),
-  both under a token-proportional device-time floor: every sized
-  compute-queue task sleeps ``size * per_token`` on its server's single
-  compute thread, so a long prefill monopolizes its replica the way it
-  monopolizes a real accelerator — on any host speed, the queueing is
-  scripted, not a machine artifact. The disagg swarm runs FIRST so the
-  process-wide jit cache warms for the baseline (bias, if any, favors
-  colocated — the gate is conservative).
+One seeded prefill-storm trace (a flat calm stream of short-prompt
+sessions + seeded bursts of long prompts with short decodes) is replayed
+over a tiny-llama swarm recipe against a DISAGGREGATED swarm (1 prefill-tier
++ 1 decode-tier replica) and a COLOCATED baseline (2 generalists, same lane
+count), both under a token-proportional device-time floor: every sized
+compute-queue task sleeps ``size * per_token`` on its server's single
+compute thread, so a long prefill monopolizes its replica the way it
+monopolizes a real accelerator — on any host speed, the queueing is
+scripted, not a machine artifact. The disagg swarm runs FIRST so the
+process-wide jit cache warms for the baseline (bias, if any, favors
+colocated — the gate is conservative). The happy-path handoff contract on
+its own (adopt-only, exact ledger attribution, clean source) is
+tests/test_disagg.py.
 
 ``--check`` fails (exit 1) unless:
 - zero lost sessions + full HF token parity, both swarms;
@@ -44,13 +34,11 @@ Two rows over one tiny-llama swarm recipe:
 - under PETALS_TPU_SANITIZE=1, zero runtime-sanitizer violations.
 
 Usage: python benchmarks/bench_disagg.py [--cpu] [--seed 7] [--check]
-       python benchmarks/bench_disagg.py --gate_row   # the gate row alone
 """
 
 import argparse
 import asyncio
 import contextlib
-import json
 import os
 import sys
 import tempfile
@@ -139,146 +127,6 @@ def hf_expected(path, plans):
     return expected
 
 
-# --------------------------------------------------------------- gate row
-
-
-def gate_bench(label, *, n_sessions=4, n_new=6):
-    """CPU gate: one prefill-tier + one decode-tier replica, ``n_sessions``
-    sequential greedy sessions through the step-boundary handoff; pin the
-    happy-path contract (adopt-only, exact ledger attribution, clean
-    source). Sequential on purpose: fixed shapes per step keep the compile
-    count and counter deltas deterministic for the perf-gate baseline."""
-    t_wall = time.perf_counter()
-    import jax
-
-    if jax.default_backend() != "tpu":
-        jax.config.update("jax_platforms", "cpu")
-
-    import torch
-    from transformers import AutoModelForCausalLM
-
-    from tests.test_full_model import SwarmHarness
-    from tests.utils import make_tiny_llama
-
-    from petals_tpu.client.model import AutoDistributedModelForCausalLM
-    from petals_tpu.telemetry import get_journal
-    from petals_tpu.telemetry import instruments as tm
-
-    path = make_tiny_llama(tempfile.mkdtemp())
-    ref = AutoModelForCausalLM.from_pretrained(path, dtype=torch.float32).eval()
-
-    def hf_greedy(ids_np, n):
-        ids = torch.tensor(ids_np.tolist(), dtype=torch.int64)
-        with torch.no_grad():
-            for _ in range(n):
-                logits = ref(ids).logits
-                ids = torch.cat([ids, logits[:, -1, :].argmax(-1, keepdim=True)], dim=1)
-        return ids.numpy()
-
-    harness = SwarmHarness(
-        path,
-        [
-            dict(first_block=0, num_blocks=4, throughput=1000.0,
-                 phase_tier="prefill", server_side_generation=False),
-            dict(first_block=0, num_blocks=4, throughput=1000.0,
-                 phase_tier="decode", server_side_generation=False),
-        ],
-    ).start()
-    model = None
-    try:
-        model = AutoDistributedModelForCausalLM.from_pretrained(
-            path, initial_peers=harness.initial_peers, min_backoff=0.1,
-            prefill_tier_tokens=4,  # the 6-token prompts below count as prefills
-        )
-        decode_peer = harness.servers[1].dht.peer_id
-        baseline_seq = get_journal().event("bench_disagg_gate_start")["seq"]
-        ok0 = tm.HANDOFFS.labels(outcome="ok").value
-        failed0 = tm.HANDOFFS.labels(outcome="failed").value
-        bytes0 = int(tm.HANDOFF_BYTES.value)
-        migrated0 = _ledger_migrated()
-
-        rng = np.random.RandomState(0)
-        with _replay_spy() as replays:
-            for _ in range(n_sessions):
-                input_ids = rng.randint(0, 100, (1, 6)).astype(np.int64)
-                expected = hf_greedy(input_ids, n_new)
-                with model.remote.inference_session(
-                    max_length=6 + n_new + 4, batch_size=1
-                ) as session:
-                    ours = model.generate(
-                        input_ids, max_new_tokens=n_new, session=session
-                    )
-                    np.testing.assert_array_equal(np.asarray(ours), expected)
-                    inner = session._session
-                    assert [s.span.peer_id for s in inner._sessions] == [decode_peer], (
-                        "session must decode on the decode-tier replica after handoff"
-                    )
-                    assert inner._handoff_stats == {
-                        "adopted": 1, "fallback": 0, "replayed": 0
-                    }, f"not a happy-path handoff: {inner._handoff_stats}"
-
-        assert replays == [], "a step-boundary handoff must never replay"
-        handoffs_ok = tm.HANDOFFS.labels(outcome="ok").value - ok0
-        assert handoffs_ok == n_sessions, (
-            f"expected {n_sessions} handoffs, telemetry saw {handoffs_ok}"
-        )
-        assert tm.HANDOFFS.labels(outcome="failed").value == failed0
-        pushed = int(tm.HANDOFF_BYTES.value) - bytes0
-        assert pushed > 0, "the page-push path must move KV bytes"
-        fallbacks = get_journal().events(
-            kind="handoff_fallback", since_seq=baseline_seq
-        )
-        assert not fallbacks, f"degrade-to-colocated in the happy path: {fallbacks}"
-        # both replicas share the in-process ledger singleton: the delta is
-        # exactly both attributions — the source's closed-peer rollup of the
-        # pushed bytes plus the destination's live-session wire bytes
-        migrated = _ledger_migrated() - migrated0
-        assert migrated == 2 * pushed, (
-            f"handoff bytes not conserved in the ledger: "
-            f"migrated {migrated} != 2 * pushed {pushed}"
-        )
-    finally:
-        if model is not None:
-            model.close()
-
-    # the source must come out clean: no leaked sessions, parked snapshots,
-    # busy lanes, or page refcounts from the KV it handed away
-    source = harness.servers[0].handler
-    try:
-        deadline = time.monotonic() + 10.0
-        while time.monotonic() < deadline:
-            pool = source.batcher.occupancy_info()
-            if (
-                not source._session_registry
-                and not source._parked
-                and pool.get("busy_lanes", 0) == 0
-            ):
-                break
-            time.sleep(0.2)
-        assert not source._session_registry, "live session leaked on the source"
-        assert not source._parked, "parked snapshot leaked on the source"
-        pool = source.batcher.occupancy_info()
-        assert pool.get("busy_lanes", 0) == 0, f"source lanes still busy: {pool}"
-        if pool.get("n_pages"):
-            assert pool["pages_free"] == pool["n_pages"], (
-                f"handed-off KV leaked pages on the source: {pool}"
-            )
-    finally:
-        harness.stop()
-
-    return {
-        "label": label,
-        "sessions": n_sessions,
-        "new_tokens_each": n_new,
-        "handoffs_ok": int(handoffs_ok),
-        "handoff_bytes": int(pushed),
-        "handoff_bytes_per_session": int(pushed) // n_sessions,
-        "ledger_migrated_bytes": int(migrated),
-        "replay_fallbacks": 0,
-        "wall_s": round(time.perf_counter() - t_wall, 2),
-    }
-
-
 # --------------------------------------------------------------- heavy A/B
 
 
@@ -302,18 +150,10 @@ def main():
     )
     parser.add_argument("--tick", type=float, default=0.5, help="autoscaler tick seconds")
     parser.add_argument(
-        "--gate_row", action="store_true",
-        help="run the cheap gate row alone and print its metrics",
-    )
-    parser.add_argument(
         "--check", action="store_true",
         help="fail (exit 1) unless every gate above holds",
     )
     args = parser.parse_args()
-
-    if args.gate_row:
-        print(json.dumps(gate_bench("gate_disagg_handoff"), indent=2))
-        return
 
     sanitize = bool(os.environ.get("PETALS_TPU_SANITIZE"))
     if sanitize:

@@ -82,7 +82,8 @@ REFRESH_BACKOFF_S = 2.0
 # routing signal (tens-of-ms WAN RTT gaps, CACHE_MISS_PENALTY).
 #
 # The amplitude ADAPTS to the MEASURED ping noise (round 5; the flat 5 ms
-# constant was measured insufficient — benchmarks/affinity_noise.py: at a
+# constant was measured insufficient — tests/test_sequence_manager.py
+# test_prefix_affinity_under_rtt_noise holds the measurement: at a
 # realistic 0.67 ms smoothed-ping jitter over 3 replicas, convergence was
 # only ~85%): amplitude = clip(30 * sigma_ema, 5 ms, 25 ms), where
 # sigma_ema comes from the ping aggregator's per-peer deviation tracking
@@ -91,7 +92,7 @@ REFRESH_BACKOFF_S = 2.0
 # replicas at that scale anyway, so the larger bias costs nothing real.
 AFFINITY_JITTER_S = 5e-3  # floor (quiet networks)
 AFFINITY_JITTER_MAX_S = 25e-3  # cap: never override a >25 ms-better replica
-AFFINITY_NOISE_MULT = 30.0  # sized by the measured sweep (benchmarks/affinity_noise.py)
+AFFINITY_NOISE_MULT = 30.0  # sized by that measurement's sweep of raw jitter
 
 
 def _affinity01(seed: int, peer_id) -> float:

@@ -109,8 +109,8 @@ def compute_cls_loss_and_grads(
     input_ids: np.ndarray,
     labels: np.ndarray,  # [batch] class ids
 ) -> Tuple[float, Dict[str, jnp.ndarray]]:
-    """Classification swarm training step (the reference's cls task in
-    benchmarks/benchmark_training.py:50-107): cross-entropy on the pooled
+    """Classification swarm training step (the cls task of the reference's
+    own benchmark_training script, lines 50-107): cross-entropy on the pooled
     last-non-pad-token logits, grads for the ptune prompts."""
     input_ids = np.asarray(input_ids)
     pos = model.pool_positions(input_ids)

@@ -128,7 +128,7 @@ def sign_announcement(
 def verify_announcement(value: Any, subkey: Optional[str], expiration: float) -> bool:
     """True iff ``value`` is a well-formed signed record whose signature is
     valid AND whose signer's key hashes to ``subkey`` — nobody can overwrite
-    another peer's announcements (the attack ADVICE.md flags)."""
+    another peer's announcements."""
     if not isinstance(value, dict) or subkey is None:
         return False
     try:

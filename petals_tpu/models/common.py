@@ -11,8 +11,31 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from petals_tpu.parallel.tp import COL
 
 KVCache = Tuple[jnp.ndarray, jnp.ndarray]  # (k, v): [batch, max_len, kv_heads, head_dim]
+
+# What every family with separate q/k/v/o projections declares on its
+# ModelFamily (models/registry.py). Tensor parallelism is Megatron-style:
+# input projections split on the output (head) axis, output projections on
+# the input axis, norms replicated; XLA then inserts the psums over ICI.
+# Leaves carry a leading layer axis (the span stack), so weight specs are
+# (None, <in>, <out>).
+COL_SPLIT, ROW_SPLIT, COL_BIAS = P(None, None, COL), P(None, COL, None), P(None, COL)
+ATTN_PSPECS = {"wq": COL_SPLIT, "wk": COL_SPLIT, "wv": COL_SPLIT, "wo": ROW_SPLIT}
+QKV_BIAS_PSPECS = {"bq": COL_BIAS, "bk": COL_BIAS, "bv": COL_BIAS}
+ATTN_LEAVES = frozenset(ATTN_PSPECS)
+HF_ATTN_LORA_TARGETS = {"q_proj": "wq", "k_proj": "wk", "v_proj": "wv", "o_proj": "wo"}
+
+
+def leaf_pspecs(block_param_shapes, table: dict):
+    """A family's ``tp_pspecs``: the specs of exactly the leaves a config
+    produces, by name from ``table`` (which leaves exist is decided once, in
+    ``block_param_shapes``; a leaf the table forgot is a KeyError here, not a
+    silently replicated weight)."""
+    return lambda cfg: {name: table[name] for name in block_param_shapes(cfg)}
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float) -> jnp.ndarray:

@@ -16,11 +16,19 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from petals_tpu.models.common import (
+    ATTN_LEAVES,
+    ATTN_PSPECS,
+    COL_BIAS,
+    COL_SPLIT,
     KVCache,
+    QKV_BIAS_PSPECS,
+    ROW_SPLIT,
     absolute_positions,
     layer_norm,
+    leaf_pspecs,
     mm,
     project_heads,
     update_kv_cache,
@@ -229,6 +237,13 @@ def block_param_shapes(cfg: FalconBlockConfig, dtype=jnp.bfloat16) -> dict:
     return shapes
 
 
+TP_PSPECS = {
+    **ATTN_PSPECS, **QKV_BIAS_PSPECS, "bo": P(),
+    "w_up": COL_SPLIT, "b_up": COL_BIAS, "w_down": ROW_SPLIT, "b_down": P(),
+    "ln_attn_w": P(), "ln_attn_b": P(), "ln_mlp_w": P(), "ln_mlp_b": P(),
+    "ln1_w": P(), "ln1_b": P(), "ln2_w": P(), "ln2_b": P(),
+}
+
 FAMILY = register_family(
     ModelFamily(
         name="falcon",
@@ -237,6 +252,12 @@ FAMILY = register_family(
         hf_block_prefixes=_HF_BLOCK_PREFIXES,
         hf_to_block_params=hf_to_block_params,
         block_param_shapes=block_param_shapes,
+        tp_pspecs=leaf_pspecs(block_param_shapes, TP_PSPECS),
+        quantizable_leaves=ATTN_LEAVES | {"w_up", "w_down"},
+        lora_targets={
+            "query_key_value": None,  # fused qkv unsupported
+            "dense": "wo", "dense_h_to_4h": "w_up", "dense_4h_to_h": "w_down",
+        },
         supports_ring_attention=True,
     )
 )

@@ -15,6 +15,7 @@ from petals_tpu.models.client_common import (
 )
 from petals_tpu.models.gemma2 import block as block_mod
 from petals_tpu.models.gemma2.config import Gemma2BlockConfig
+from petals_tpu.models.llama import block as llama_block
 from petals_tpu.models.registry import ModelFamily, register_family
 
 
@@ -43,12 +44,15 @@ def client_head(params: dict, hidden, cfg):
 FAMILY = register_family(
     ModelFamily(
         name="gemma2",
-        block_arch="gemma2",
         config_from_hf=Gemma2BlockConfig.from_hf_config,
         block_apply=block_mod.block_apply,
         hf_block_prefixes=block_mod._HF_BLOCK_PREFIXES,
         hf_to_block_params=block_mod.hf_to_block_params,
         block_param_shapes=block_mod.block_param_shapes,
+        # the llama block's matmul leaves under the same names; no TP specs
+        # declared (tp_pspecs stays None: a tp mesh is refused by name)
+        quantizable_leaves=llama_block.FAMILY.quantizable_leaves,
+        fuse_groups=llama_block.FAMILY.fuse_groups,
         hf_client_prefixes=LLAMA_STYLE_CLIENT_PREFIXES,
         hf_to_client_params=hf_to_client_params,
         client_embed=client_embed,

@@ -54,7 +54,7 @@ def block_apply(
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln1"], cfg.rms_norm_eps)
 
-    if "wqkv" in params:  # fused quantized serving (convert_block _FUSE_GROUPS)
+    if "wqkv" in params:  # fused quantized serving (the llama block's FUSE_GROUPS)
         qkv = mm(x, params["wqkv"])
         q = qkv[..., : hq * d]
         k = qkv[..., hq * d : (hq + hkv) * d]

@@ -23,17 +23,22 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from petals_tpu.models.common import (
+    ATTN_LEAVES,
+    ATTN_PSPECS,
+    HF_ATTN_LORA_TARGETS,
     KVCache,
     absolute_positions,
+    leaf_pspecs,
     mm,
     project_heads,
     rms_norm,
     update_kv_cache,
 )
 from petals_tpu.models.mixtral.config import MixtralBlockConfig
-from petals_tpu.models.moe import MoeDims, grouped_dispatch, moe_apply
+from petals_tpu.models.moe import EXPERT_LEAVES, EXPERT_PSPECS, MoeDims, grouped_dispatch, moe_apply
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend_maybe_ring
 from petals_tpu.ops.rotary import apply_rotary, rotary_tables
@@ -141,6 +146,8 @@ def block_param_shapes(cfg: MixtralBlockConfig, dtype=jnp.bfloat16) -> dict:
     }
 
 
+TP_PSPECS = {"ln1": P(), "ln2": P(), **ATTN_PSPECS, **EXPERT_PSPECS}
+
 FAMILY = register_family(
     ModelFamily(
         name="mixtral",
@@ -149,6 +156,9 @@ FAMILY = register_family(
         hf_block_prefixes=_HF_BLOCK_PREFIXES,
         hf_to_block_params=hf_to_block_params,
         block_param_shapes=block_param_shapes,
+        tp_pspecs=leaf_pspecs(block_param_shapes, TP_PSPECS),
+        quantizable_leaves=ATTN_LEAVES | EXPERT_LEAVES,
+        lora_targets=HF_ATTN_LORA_TARGETS,
         moe_dims=moe_dims,
         supports_ring_attention=True,
     )

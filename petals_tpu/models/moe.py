@@ -30,8 +30,20 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from petals_tpu.models.common import silu
+from petals_tpu.parallel.tp import COL
+
+# The expert part of what Mixtral and OLMoE declare on their ModelFamily
+# (models/registry.py). Under a tp mesh the EXPERT axis is sharded: expert
+# parallelism over the mesh (goes beyond the reference, which keeps experts
+# unsharded); the router stays whole.
+_EXPERT_SPLIT = P(None, COL, None, None)
+EXPERT_PSPECS = {"gate": P(), "w1": _EXPERT_SPLIT, "w2": _EXPERT_SPLIT, "w3": _EXPERT_SPLIT}
+# the expert stacks carry >90% of Mixtral's params: quantized per expert
+# (3-D leaves), where the reference leaves them dense
+EXPERT_LEAVES = frozenset({"w1", "w2", "w3"})
 
 
 class MoeDims(NamedTuple):

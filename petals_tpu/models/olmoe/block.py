@@ -20,16 +20,22 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from petals_tpu.models.common import (
+    ATTN_LEAVES,
+    ATTN_PSPECS,
+    COL_BIAS,
+    HF_ATTN_LORA_TARGETS,
     KVCache,
     absolute_positions,
+    leaf_pspecs,
     mm,
     project_heads,
     rms_norm,
     update_kv_cache,
 )
-from petals_tpu.models.moe import MoeDims, grouped_dispatch, moe_apply
+from petals_tpu.models.moe import EXPERT_LEAVES, EXPERT_PSPECS, MoeDims, grouped_dispatch, moe_apply
 from petals_tpu.models.olmoe.config import OlmoeBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend_maybe_ring
@@ -149,6 +155,14 @@ def block_param_shapes(cfg: OlmoeBlockConfig, dtype=jnp.bfloat16) -> dict:
     }
 
 
+TP_PSPECS = {
+    "ln1": P(), "ln2": P(), **ATTN_PSPECS, **EXPERT_PSPECS,
+    # QK-norm runs over the whole column-sharded q and k projections: its
+    # mean spans the shards, which GSPMD sums over ICI like the row-parallel
+    # psums; the norm vectors shard with the columns
+    "q_norm": COL_BIAS, "k_norm": COL_BIAS,
+}
+
 FAMILY = register_family(
     ModelFamily(
         name="olmoe",
@@ -157,6 +171,9 @@ FAMILY = register_family(
         hf_block_prefixes=_HF_BLOCK_PREFIXES,
         hf_to_block_params=hf_to_block_params,
         block_param_shapes=block_param_shapes,
+        tp_pspecs=leaf_pspecs(block_param_shapes, TP_PSPECS),
+        quantizable_leaves=ATTN_LEAVES | EXPERT_LEAVES,
+        lora_targets=HF_ATTN_LORA_TARGETS,
         moe_dims=moe_dims,
         supports_ring_attention=True,
     )

@@ -2,13 +2,21 @@
 which dispatches on HF ``config.model_type``).
 
 Each family registers a ``ModelFamily`` describing how to build block configs,
-apply a block, and map HF checkpoint tensors to our parameter trees.
+apply a block, and map HF checkpoint tensors to our parameter trees, and what
+the framework does with its leaves: how they shard under tensor parallelism,
+which are quantized and fused, which a LoRA adapter targets. Those facts are
+data on the family, set where it is registered, and ``parallel/tp.py``,
+``utils/convert_block.py`` and ``utils/peft.py`` read them through
+``get_family``; a family built with ``dataclasses.replace`` over another
+(mistral, qwen2, phi3, gemma over llama) inherits them with no line of its
+own. So a new family of an existing kind touches ``models/<family>/``, one
+import in ``models/__init__.py``, and the benchmark's own data files.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Mapping, Optional
 
 _FAMILIES: Dict[str, "ModelFamily"] = {}
 
@@ -25,11 +33,19 @@ class ModelFamily:
     block_param_shapes: Optional[Callable] = None  # cfg -> pytree of jax.ShapeDtypeStruct
     # a block with routed experts (models/moe.py): cfg -> MoeDims
     moe_dims: Optional[Callable] = None
-    # Underlying block architecture ("" -> same as name). Derived families
-    # built via dataclasses.replace (qwen2/mistral over llama) inherit it, so
-    # architecture-keyed tables (quantizable leaves, fuse groups in
-    # utils/convert_block.py) resolve without per-alias entries.
-    block_arch: str = ""
+    # cfg -> {leaf: PartitionSpec} for the stacked block leaves over the "tp"
+    # mesh axis (parallel/tp.py). None: the family declares none, and a TP
+    # mesh is refused by name
+    tp_pspecs: Optional[Callable] = None
+    # the big matmul leaves that quantize (utils/convert_block.py); norms,
+    # biases and routers stay dense
+    quantizable_leaves: frozenset = frozenset()
+    # (fused_w, parts, fused_b, bias_parts): leaves merged into one matmul
+    # each for quantized single-chip serving (utils/convert_block.py)
+    fuse_groups: tuple = ()
+    # HF projection name -> the leaf a LoRA adapter wraps, or None for a
+    # projection this build cannot wrap (utils/peft.py)
+    lora_targets: Mapping = dataclasses.field(default_factory=dict)
     # leaf NAMES whose loaded dtype is preserved by the param casters (e.g.
     # gemma's (1+w)-folded norms must stay float32 for the fold to be exact
     # under bf16 serving; rms_norm upcasts anyway, so this is free)

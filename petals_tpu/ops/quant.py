@@ -81,7 +81,7 @@ NF4_CODE = np.array(
 # d = c - 7.5, least-squares fitted to the NF4 codebook values. The levels
 # approximate NF4's normal-float spacing to ~0.03 RMS — measured weight-space
 # SNR actually BEATS NF4 on gaussian, heavy-tailed, and outlier-channel
-# weight distributions (benchmarks/quant_quality.py) because the symmetric
+# weight distributions (tests/test_quant_quality.py) because the symmetric
 # levels waste no code on a duplicate zero — while decode is pure arithmetic
 # (two multiplies and an add per element), so the fused decode kernel never
 # touches the VPU gather that caps NF4 at ~110 GB/s. This is the round-5
@@ -136,7 +136,7 @@ class OutlierQuantLinear:
     channels. The top in/64 channels by magnitude are zeroed in the packed
     stream and applied as a small dense side matmul x[..., idx] @ w_out —
     +0.25 bits/param (4.25 -> 4.5), ~+5-6 dB output SNR in the
-    outlier-channel regime (benchmarks/quant_quality.py), and the packed
+    outlier-channel regime (tests/test_quant_quality.py), and the packed
     stream's bandwidth story is untouched.
 
     ``w_out`` stores the RESIDUAL against the packed stream's decode of the
@@ -784,7 +784,7 @@ def _packed4_decode_kernel(
     starts at ``xe`` — no dead zeros array rides the DMA on the nf4 path.
 
     Decode at M=1 is pure weight streaming, and the round-3 on-chip ablation
-    (benchmarks/ablate_quant_kernel*.py) showed the old big-tile decode was
+    (July 2026 record, not re-measured on the current chip) showed the old big-tile decode was
     VPU-bound at ~12% of HBM bandwidth: per-element scale repeat/multiply/cast
     plus (for nf4) the table gather cost ~8x the DMA itself. This kernel
     restructures the math so per-element work is minimal:

@@ -1,12 +1,10 @@
 """Tensor-parallel shardings for stacked span parameters
 (counterpart of the reference's per-block TP configs,
 src/petals/utils/convert_block.py:118-135 + backend.py:88-99, re-expressed as
-jax.sharding PartitionSpecs — Megatron-style: attention/MLP input projections
-split on the output (head) axis, output projections split on the input axis,
-norms replicated; XLA then inserts the psums over ICI).
-
-All leaf shapes have a leading layer axis (the span stack), so weight specs
-are (None, <in>, <out>).
+jax.sharding PartitionSpecs). Which leaf splits how is each family's own
+declaration (``ModelFamily.tp_pspecs``; the shared Megatron-style pieces are
+in models/common.py); this module holds the axis name, the checks and the
+placement.
 """
 
 from __future__ import annotations
@@ -19,86 +17,17 @@ COL = "tp"  # axis name used for head/ffn splits
 
 
 def span_param_pspecs(family_name: str, cfg) -> Dict[str, P]:
-    """PartitionSpecs for one family's stacked block params."""
-    if family_name == "llama":
-        specs = {
-            "ln1": P(),
-            "wq": P(None, None, COL),
-            "wk": P(None, None, COL),
-            "wv": P(None, None, COL),
-            "wo": P(None, COL, None),
-            "ln2": P(),
-            "wg": P(None, None, COL),
-            "wu": P(None, None, COL),
-            "wd": P(None, COL, None),
-        }
-        if getattr(cfg, "attention_bias", False):
-            specs.update(bq=P(None, COL), bk=P(None, COL), bv=P(None, COL), bo=P())
-        if getattr(cfg, "mlp_bias", False):
-            specs.update(bg=P(None, COL), bu=P(None, COL), bd=P())
-        return specs
-    if family_name == "bloom":
-        return {
-            "ln1_w": P(),
-            "ln1_b": P(),
-            "wq": P(None, None, COL),
-            "bq": P(None, COL),
-            "wk": P(None, None, COL),
-            "bk": P(None, COL),
-            "wv": P(None, None, COL),
-            "bv": P(None, COL),
-            "wo": P(None, COL, None),
-            "bo": P(),
-            "ln2_w": P(),
-            "ln2_b": P(),
-            "w_up": P(None, None, COL),
-            "b_up": P(None, COL),
-            "w_down": P(None, COL, None),
-            "b_down": P(),
-        }
-    if family_name == "falcon":
-        specs = {
-            "wq": P(None, None, COL),
-            "wk": P(None, None, COL),
-            "wv": P(None, None, COL),
-            "wo": P(None, COL, None),
-            "w_up": P(None, None, COL),
-            "w_down": P(None, COL, None),
-        }
-        if cfg.new_decoder_architecture and cfg.num_ln_in_parallel_attn == 2:
-            specs.update(ln_attn_w=P(), ln_attn_b=P(), ln_mlp_w=P(), ln_mlp_b=P())
-        else:
-            specs.update(ln1_w=P(), ln1_b=P())
-            if not cfg.parallel_attn and not cfg.new_decoder_architecture:
-                specs.update(ln2_w=P(), ln2_b=P())
-        if cfg.bias:
-            specs.update(
-                bq=P(None, COL), bk=P(None, COL), bv=P(None, COL),
-                bo=P(), b_up=P(None, COL), b_down=P(),
-            )
-        return specs
-    if family_name in ("mixtral", "olmoe"):
-        specs = {
-            "ln1": P(),
-            "wq": P(None, None, COL),
-            "wk": P(None, None, COL),
-            "wv": P(None, None, COL),
-            "wo": P(None, COL, None),
-            "ln2": P(),
-            "gate": P(),
-            # experts: shard the expert axis — expert parallelism over the mesh
-            # (goes beyond the reference, which keeps experts unsharded)
-            "w1": P(None, COL, None, None),
-            "w2": P(None, COL, None, None),
-            "w3": P(None, COL, None, None),
-        }
-        if family_name == "olmoe":
-            # QK-norm runs over the whole column-sharded q and k projections:
-            # its mean spans the shards, which GSPMD sums over ICI like the
-            # row-parallel psums; the norm vectors shard with the columns
-            specs.update(q_norm=P(None, COL), k_norm=P(None, COL))
-        return specs
-    raise KeyError(f"No TP spec for family {family_name!r}")
+    """PartitionSpecs for one family's stacked block params, as the family
+    declares them (``ModelFamily.tp_pspecs``, set in ``models/<family>/``)."""
+    from petals_tpu.models.registry import get_family
+
+    declared = get_family(family_name).tp_pspecs
+    if declared is None:
+        raise KeyError(
+            f"No TP spec for family {family_name!r}: its ModelFamily declares no "
+            f"tp_pspecs, so it cannot be served over a tensor-parallel mesh"
+        )
+    return declared(cfg)
 
 
 def kv_cache_pspec() -> P:

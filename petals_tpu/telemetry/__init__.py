@@ -33,9 +33,6 @@ The pieces:
 - :mod:`.flight` — the SLO flight recorder: on a TTFT or token-latency
   breach, dump the span waterfall plus the victim server's journal
   excerpt to a bounded JSONL ring.
-- :mod:`.gate` — the perf-regression gate: diff per-row bench telemetry
-  blobs (counter deltas + step-duration histograms) against a committed
-  baseline (``bench.py --gate``).
 - :mod:`.ledger` — the per-tenant resource ledger: page-seconds (COW
   pages attributed fractionally by refcount), compute-seconds, tokens,
   swap/migrated bytes per session and per peer, with a DRF-style

@@ -1,5 +1,5 @@
 """Where XLA's persistent compilation cache lives — one rule for every entry
-point (run_server, run_worker, chip_smoke.py, bench.py children, benchmarks/).
+point (run_server, run_worker, Server.start, chip_smoke.py, perf/, benchmarks/).
 
 If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own handling of that variable
 is the whole story and nothing here sets a directory. Otherwise the cache is

@@ -8,7 +8,6 @@ sharding applied at backend construction).
 from __future__ import annotations
 
 import enum
-from typing import Dict, Set
 
 import jax
 import jax.numpy as jnp
@@ -32,69 +31,34 @@ class QuantType(str, enum.Enum):
     INT4_O = "int4+o"
 
 
-# The big matmul weights of each family (norms/biases/router stay dense).
-QUANTIZABLE_LEAVES: Dict[str, Set[str]] = {
-    "llama": {"wq", "wk", "wv", "wo", "wg", "wu", "wd"},
-    "bloom": {"wq", "wk", "wv", "wo", "w_up", "w_down"},
-    "falcon": {"wq", "wk", "wv", "wo", "w_up", "w_down"},
-    # expert stacks (w1/w2/w3) carry >90% of Mixtral's params — quantized
-    # per-expert (3-D leaves), unlike the reference which also quantizes them
-    "mixtral": {"wq", "wk", "wv", "wo", "w1", "w2", "w3"},
-    # the same leaves; the two QK-norm vectors stay dense like every norm
-    "olmoe": {"wq", "wk", "wv", "wo", "w1", "w2", "w3"},
-    "gemma2": {"wq", "wk", "wv", "wo", "wg", "wu", "wd"},
-}
-
-
-# Leaves fused into one matmul each for quantized single-chip serving: every
-# Pallas custom call carries a fixed launch/boundary cost (~0.2 ms in the July
-# 2026 v5e record; not measured on the current chip), so 7 calls/block -> 4 speeds up
-# decode. Fusion happens on the DENSE weights before quantization: 4-bit/int8
-# scales are per-output-column, so the fused quantization is bit-identical to
-# quantizing separately. Biases (qwen2) fuse alongside.
-_FUSE_GROUPS: Dict[str, tuple] = {
-    "llama": (
-        ("wqkv", ("wq", "wk", "wv"), "bqkv", ("bq", "bk", "bv")),
-        ("wgu", ("wg", "wu"), "bgu", ("bg", "bu")),
-    ),
-    "gemma2": (
-        ("wqkv", ("wq", "wk", "wv"), "bqkv", ("bq", "bk", "bv")),
-        ("wgu", ("wg", "wu"), "bgu", ("bg", "bu")),
-    ),
-}
-
-
-def _block_arch(family_name: str) -> str:
-    """Resolve a family name to the block architecture keying the tables above
-    (qwen2/mistral are llama-architecture blocks registered under their own
-    model_type; quantization must not silently no-op for them)."""
-    if family_name in QUANTIZABLE_LEAVES:
-        return family_name
-    from petals_tpu.models import registry
-
-    try:
-        family = registry.get_family(family_name)
-    except KeyError:
-        return family_name
-    return family.block_arch or family.name
-
-
 def convert_block_params(
     params: dict, family_name: str, quant_type: QuantType, *, fuse: bool = False
 ) -> dict:
     """Quantize one (unstacked) block's matmul weights in place of dense leaves.
 
-    ``fuse=True`` additionally merges qkv / gate+up into single leaves (llama
-    family, which qwen2/mistral share). Callers must keep it off under tensor
-    parallelism (the fused output axis breaks the per-leaf PartitionSpecs) and
-    when hosting LoRA adapters (they target the unfused leaf names).
+    Which leaves quantize, and which fuse, is the family's own declaration
+    (``ModelFamily.quantizable_leaves`` / ``fuse_groups``). ``fuse=True``
+    additionally merges qkv / gate+up into single leaves where the family
+    declares the groups (the llama block and those built over it). Callers
+    must keep it off under tensor parallelism (the fused output axis breaks
+    the per-leaf PartitionSpecs) and when hosting LoRA adapters (they target
+    the unfused leaf names).
     """
     quant_type = QuantType(quant_type)
     if quant_type == QuantType.NONE:
         return params
-    arch = _block_arch(family_name)
+    from petals_tpu.models import registry
+
+    known = registry.known_families()
+    # an unregistered name has no leaves to quantize: refused below, like a
+    # family whose declaration matches nothing in this block
+    quantizable, fuse_groups = set(), ()
+    if family_name in known:
+        family = registry.get_family(family_name)
+        quantizable, fuse_groups = set(family.quantizable_leaves), family.fuse_groups
+    quantizable.update(group[0] for group in fuse_groups)
     if fuse:
-        for fused_w, parts, fused_b, bias_parts in _FUSE_GROUPS.get(arch, ()):
+        for fused_w, parts, fused_b, bias_parts in fuse_groups:
             if all(p in params for p in parts):
                 params = dict(params)
                 fused = jnp.concatenate([jnp.asarray(params.pop(p)) for p in parts], axis=1)
@@ -103,7 +67,6 @@ def convert_block_params(
                     params[fused_b] = jnp.concatenate(
                         [jnp.asarray(params.pop(b)) for b in bias_parts], axis=0
                     )
-    quantizable = QUANTIZABLE_LEAVES.get(arch, set()) | {"wqkv", "wgu"}
     out = {}
     n_quantized = 0
     leaf_names = sorted(params)  # the pop-loop empties params; keep for errors
@@ -133,20 +96,14 @@ def convert_block_params(
         # A silent no-op here would serve dense weights while the operator
         # believes the model is quantized (wrong memory footprint AND
         # throughput advert) — refuse instead.
-        detail = f"family {family_name!r}" if family_name == arch else (
-            f"family {family_name!r} (block arch {arch!r})"
-        )
-        from petals_tpu.models import registry
-
-        known = registry.known_families()
         hint = (
-            "QUANTIZABLE_LEAVES needs an entry for this block architecture"
+            "its ModelFamily declares no quantizable_leaves that this block holds"
             if family_name in known
             else f"family is not registered (known: {list(known)})"
         )
         raise ValueError(
             f"quant_type={quant_type.value!r} requested but no quantizable "
-            f"leaves matched for {detail} (leaves: {leaf_names}); {hint}"
+            f"leaves matched for family {family_name!r} (leaves: {leaf_names}); {hint}"
         )
     return out
 

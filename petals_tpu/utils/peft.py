@@ -29,21 +29,6 @@ from petals_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
-# HF projection names -> our param leaf names, per family
-_TARGET_MAP = {
-    "llama": {
-        "q_proj": "wq", "k_proj": "wk", "v_proj": "wv", "o_proj": "wo",
-        "gate_proj": "wg", "up_proj": "wu", "down_proj": "wd",
-    },
-    "mixtral": {"q_proj": "wq", "k_proj": "wk", "v_proj": "wv", "o_proj": "wo"},
-    "olmoe": {"q_proj": "wq", "k_proj": "wk", "v_proj": "wv", "o_proj": "wo"},
-    "bloom": {"query_key_value": None, "dense": "wo",  # fused qkv unsupported
-              "dense_h_to_4h": "w_up", "dense_4h_to_h": "w_down"},
-    "falcon": {"query_key_value": None, "dense": "wo",
-               "dense_h_to_4h": "w_up", "dense_4h_to_h": "w_down"},
-}
-
-
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class LoraLinear:
@@ -88,15 +73,20 @@ def load_adapter(
 
     from safetensors import safe_open
 
+    from petals_tpu.models.registry import get_family
+
     weights_file = os.path.join(adapter_path, "adapter_model.safetensors")
-    target_map = _TARGET_MAP.get(family_name, {})
+    # HF projection name -> our leaf, as the family declares it
+    target_map = get_family(family_name).lora_targets
     per_block: Dict[int, Dict[str, list]] = {}
+    n_targeted = 0
 
     with safe_open(weights_file, framework="pt") as f:
         for key in f.keys():
             parsed = _parse_adapter_key(key, target_map)
             if parsed is None:
                 continue
+            n_targeted += 1
             block_idx, leaf, which = parsed
             if block_idx not in block_range:
                 continue
@@ -107,6 +97,12 @@ def load_adapter(
             else:
                 entry[1] = np.ascontiguousarray(tensor.T)  # [r, out]
 
+    if not n_targeted:
+        # serving the base model under the adapter's name would be a silent no-op
+        raise ValueError(
+            f"Adapter at {adapter_path!r} holds no LoRA tensor for a projection that family "
+            f"{family_name!r} declares (lora_targets: {sorted(target_map)})"
+        )
     blocks = {
         idx: {leaf: (a, b) for leaf, (a, b) in leaves.items() if a is not None and b is not None}
         for idx, leaves in per_block.items()

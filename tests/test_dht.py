@@ -154,7 +154,7 @@ def test_subkey_announcements_merge_across_swarm():
 
 
 def test_unsigned_or_forged_subkey_records_rejected():
-    """The swarm plane is authenticated (ADVICE.md): a peer cannot overwrite
+    """The swarm plane is authenticated: a peer cannot overwrite
     another peer's announcements — unsigned subkey stores and records signed
     by the WRONG key are rejected by honest storers."""
     from petals_tpu.dht.identity import sign_announcement

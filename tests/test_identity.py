@@ -1,4 +1,4 @@
-"""Swarm-plane authentication (ADVICE.md medium): keypair-derived peer ids,
+"""Swarm-plane authentication: keypair-derived peer ids,
 challenge/response hellos, signed DHT announcements."""
 
 import asyncio
@@ -90,7 +90,7 @@ def test_hello_authentication_proves_both_sides():
 
 def test_unauthenticated_claim_is_not_trusted():
     """A peer id claimed in a hello WITHOUT a key proof must never become
-    ctx.remote_peer_id (the impersonation ADVICE.md flags)."""
+    ctx.remote_peer_id."""
     from petals_tpu.data_structures import PeerID
 
     server_ident = Identity.generate()

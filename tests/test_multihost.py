@@ -677,7 +677,6 @@ def test_multihost_continuous_batching(tmp_path):
         # the first step's lockstep device op runs, the rest pend and drain
         # as one >=3-lane batch (thread-per-client generate above can't pin
         # this down on a single-core machine: the GIL serializes the streams).
-        # The protocol driver is shared with benchmarks/multihost_batching.py.
         import asyncio as _a
 
         from tests.utils import drive_coalescing_sessions

@@ -5,35 +5,180 @@ blocks with the qwen bias convention (q/k/v-only), the mistral all-layer
 sliding window, and gemma's (1+w)-folded norms / tanh-GELU / scaled embeds.
 """
 
+import os
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from petals_tpu.client.model import AutoDistributedModelForCausalLM
+from petals_tpu.models.registry import known_families
 from tests.test_full_model import SwarmHarness, _hf_greedy
-from tests.utils import make_tiny_gemma, make_tiny_gemma2, make_tiny_mistral, make_tiny_phi3, make_tiny_qwen2
-
-
-@pytest.mark.parametrize(
-    "maker,name",
-    [(make_tiny_qwen2, "qwen2"), (make_tiny_mistral, "mistral"), (make_tiny_gemma, "gemma"),
-     (make_tiny_phi3, "phi3"), (make_tiny_gemma2, "gemma2")],
+from tests.utils import (
+    make_tiny_bloom,
+    make_tiny_falcon,
+    make_tiny_gemma,
+    make_tiny_gemma2,
+    make_tiny_llama,
+    make_tiny_mistral,
+    make_tiny_mixtral,
+    make_tiny_olmoe,
+    make_tiny_phi3,
+    make_tiny_qwen2,
 )
-def test_quantization_applies_to_derived_families(tmp_path, maker, name):
-    """Families registered under their own model_type but sharing the llama
-    block architecture must still quantize: QUANTIZABLE_LEAVES/_FUSE_GROUPS
-    resolve through ModelFamily.block_arch, not the registry name (a silent
+
+
+# One tiny checkpoint per registered family. A family registered without a
+# maker here fails every contract test below by name, not silently.
+MAKERS = {
+    "llama": make_tiny_llama, "bloom": make_tiny_bloom, "falcon": make_tiny_falcon,
+    "mixtral": make_tiny_mixtral, "olmoe": make_tiny_olmoe, "qwen2": make_tiny_qwen2,
+    "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
+    "gemma2": make_tiny_gemma2,
+}
+LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
+
+
+@pytest.fixture(scope="module")
+def family_block(tmp_path_factory):
+    """name -> (checkpoint path, family, cfg, block 0's params), built once."""
+    from petals_tpu.server.from_pretrained import get_block_config, load_block_params
+
+    built = {}
+
+    def get(name):
+        if name not in built:
+            path = MAKERS[name](str(tmp_path_factory.mktemp(name)))
+            family, cfg = get_block_config(path)
+            assert family.name == name
+            params = load_block_params(path, 0, dtype=jnp.float32, family=family, cfg=cfg)
+            built[name] = (path, family, cfg, params)
+        return built[name]
+
+    return get
+
+
+@pytest.mark.parametrize("name", known_families())
+def test_quantization_applies_to_every_family(family_block, name):
+    """What a family declares on its ModelFamily is what gets quantized: the
+    declared leaves exist in its block, fuse-group members are among them, and
+    convert_block_params quantizes exactly those (fused where declared).
+    Families built over the llama block inherit its declaration (a silent
     dense fallback here once shipped as a no-op --quant_type)."""
     from petals_tpu.ops.quant import QuantizedLinear
-    from petals_tpu.server.from_pretrained import load_block_params
     from petals_tpu.utils.convert_block import convert_block_params
 
+    _, family, cfg, params = family_block(name)
+    declared = family.quantizable_leaves
+    assert declared and declared <= set(params), (declared, sorted(params))
+    assert declared <= set(family.block_param_shapes(cfg))
+    fused_away = set()
+    for fused_w, parts, _, _ in family.fuse_groups:
+        assert set(parts) <= declared, (fused_w, parts)
+        fused_away |= set(parts)
+    kind = "int8" if name == "mixtral" else "nf4"  # tiny mixtral's experts are 96 wide: no 64-row blocks
+    q = convert_block_params(dict(params), name, kind, fuse=True)
+    quantized = {k for k, v in q.items() if isinstance(v, QuantizedLinear)}
+    assert quantized == (declared - fused_away) | {g[0] for g in family.fuse_groups}
+    if name in LLAMA_ALIASES:
+        assert {"wqkv", "wgu", "wo", "wd"} <= quantized, quantized
+
+
+@pytest.mark.parametrize("name", known_families())
+def test_tp_specs_cover_the_block_or_are_refused(family_block, name):
+    """A family either declares TP specs for exactly the leaves its checkpoint
+    yields, and its stacked block shards over a tp mesh, or is refused by
+    name. The llama aliases get llama's specs (they were a KeyError)."""
+    from jax.sharding import PartitionSpec as P
+
+    from petals_tpu.parallel import make_mesh
+    from petals_tpu.parallel.tp import COL, shard_span_params, span_param_pspecs
+
+    _, family, cfg, params = family_block(name)
+    if family.tp_pspecs is None:
+        with pytest.raises(KeyError, match=f"{name}.*tp_pspecs"):
+            span_param_pspecs(name, cfg)
+        return
+    specs = span_param_pspecs(name, cfg)
+    assert set(specs) == set(params)
+    if name in LLAMA_ALIASES:
+        assert specs == span_param_pspecs("llama", cfg)
+    stacked = {k: jnp.asarray(v)[None] for k, v in params.items()}
+    sharded = shard_span_params(stacked, make_mesh((2,), ("tp",)), name, cfg)
+    for leaf, spec in specs.items():
+        assert isinstance(spec, P) and len(spec) <= stacked[leaf].ndim, (leaf, spec)
+        expect = list(stacked[leaf].shape)
+        for dim, axis in enumerate(spec):
+            if axis == COL:
+                expect[dim] //= 2
+        assert sharded[leaf].addressable_shards[0].data.shape == tuple(expect), leaf
+    assert any(COL in spec for spec in specs.values())
+
+
+@pytest.mark.parametrize("name", known_families())
+def test_lora_targets_name_existing_leaves(family_block, name):
+    """Every projection a family maps to a leaf maps to one its block has; the
+    llama aliases load the tiny llama-style adapter to llama's leaves (they
+    loaded nothing, silently); an adapter that matches nothing in the
+    family's map is refused, not served as the base model."""
+    from tests.test_peft import make_fake_peft_adapter
+
+    from petals_tpu.utils.peft import load_adapter
+
+    path, family, cfg, params = family_block(name)
+    targets = {leaf for leaf in family.lora_targets.values() if leaf is not None}
+    assert targets <= set(params), (targets, sorted(params))
+    if name in LLAMA_ALIASES:
+        assert family.lora_targets == family_block("llama")[1].lora_targets
+    # the adapter of tests/test_peft.py wraps q_proj and down_proj of every layer
+    expected = {family.lora_targets.get(proj) for proj in ("q_proj", "down_proj")} - {None}
+    if name in LLAMA_ALIASES:
+        assert expected == {"wq", "wd"}
+    shapes_from = path if expected else family_block("llama")[0]  # bloom's config has no intermediate_size
+    adapter_path = make_fake_peft_adapter(os.path.dirname(path), shapes_from)
+    if expected:
+        adapter = load_adapter(adapter_path, name, block_range=range(cfg.num_hidden_layers))
+        assert set(adapter.per_block[0]) == expected
+    else:
+        with pytest.raises(ValueError, match=f"no LoRA tensor.*{name}"):
+            load_adapter(adapter_path, name, block_range=range(2))
+
+
+@pytest.mark.parametrize("maker", [make_tiny_qwen2, make_tiny_mistral])
+def test_llama_alias_serves_over_tp_mesh_with_adapter(tmp_path, maker):
+    """What the declarations buy a user: a server of a family built over
+    llama shards over a tp mesh (it died on a KeyError) and applies a LoRA
+    adapter (it served the base model without a word). qwen2 brings the
+    q/k/v-only biases, mistral the sliding window."""
+    from tests.test_peft import _hf_with_lora, make_fake_peft_adapter
+
     path = maker(str(tmp_path))
-    params = load_block_params(path, 0, dtype=jnp.float32)
-    q = convert_block_params(params, name, "nf4", fuse=True)
-    quantized = [k for k, v in q.items() if isinstance(v, QuantizedLinear)]
-    assert "wqkv" in quantized and "wgu" in quantized, quantized
-    assert "wo" in quantized and "wd" in quantized, quantized
+    adapter = make_fake_peft_adapter(str(tmp_path), path)
+    harness = SwarmHarness(
+        path, [dict(first_block=0, num_blocks=4, num_tp_devices=2, adapters=[adapter])]
+    ).start()
+    try:
+        ids = np.random.RandomState(0).randint(0, 100, (1, 5)).astype(np.int64)
+        model = AutoDistributedModelForCausalLM.from_pretrained(
+            path, initial_peers=harness.initial_peers
+        )
+        try:
+            np.testing.assert_array_equal(
+                model.generate(ids, max_new_tokens=4), _hf_greedy(path, ids, 4)
+            )
+        finally:
+            model.close()
+        model = AutoDistributedModelForCausalLM.from_pretrained(
+            path, initial_peers=harness.initial_peers, active_adapter=os.path.basename(adapter)
+        )
+        try:
+            np.testing.assert_allclose(
+                np.asarray(model.forward(ids)), _hf_with_lora(path, adapter, ids), atol=2e-3
+            )
+        finally:
+            model.close()
+    finally:
+        harness.stop()
 
 
 def test_quantization_refuses_unknown_architecture():

@@ -302,7 +302,7 @@ def test_nf4a_roundtrip_error_and_levels():
 def test_nf4a_matches_nf4_quality():
     """The serving-default claim: NF4A's weight-space SNR is at least NF4's
     (within measurement slack) on gaussian AND heavy-tailed weights — the
-    regimes where uniform int4 loses 1-3 dB (benchmarks/quant_quality.py)."""
+    regimes where uniform int4 loses 1-3 dB (tests/test_quant_quality.py)."""
     rng = np.random.RandomState(5)
     shape = (1024, 512)
     for w in (

@@ -1,10 +1,104 @@
-"""Quantization quality evaluation (benchmarks/quant_quality.py): the format
-ordering the serving default rests on must hold — bf16 < int8 < nf4 < int4
-error on every weight distribution (VERDICT r3 #4)."""
+"""Quantization quality: the format ordering the serving default rests on must
+hold — bf16 < int8 < nf4 < int4 error on every weight distribution (VERDICT
+r3 #4). No trained checkpoint is reachable here, so the two tables use
+synthetic weights: gaussian, heavy-tailed (student-t), and gaussian with
+outlier input channels (the regime trained transformers live in, per the
+LLM.int8 observations). Relative MSE depends on the distribution; the ORDER
+of the formats and the size of the gaps carry over to trained weights.
 
-from benchmarks.quant_quality import activation_space_table, weight_space_table
+What the tables said at 7B shapes [4096, 11008] (CPU arithmetic, 2026-07-30):
+NF4A's cubic-fitted levels match or beat NF4's weight-space SNR on every
+distribution while its decode is pure arithmetic, so it is the 4-bit default
+(ops/quant.py); "+o" adds ~5-6 dB in the outlier-channel regime for +0.25
+bits; int4 is 1.3-3.2 dB behind NF4; int8 is near-lossless.
+"""
 
-SMALL = (512, 1024)  # fast CPU shapes; the committed table uses 7B shapes
+import numpy as np
+
+SMALL = (512, 1024)  # fast CPU shapes
+
+
+def _weight_sets(shape, seed=0):
+    rng = np.random.RandomState(seed)
+    rows, cols = shape
+    w_gauss = rng.randn(rows, cols).astype(np.float32) * 0.02
+    w_heavy = (rng.standard_t(df=4, size=shape) * 0.02).astype(np.float32)
+    w_outlier = w_gauss.copy()
+    outlier_rows = rng.choice(rows, size=max(rows // 512, 1), replace=False)
+    w_outlier[outlier_rows] *= 20.0  # outlier input channels (LLM.int8 regime)
+    sets = {"gaussian": w_gauss, "heavy_tailed": w_heavy, "outlier_channels": w_outlier}
+    return sets, outlier_rows
+
+
+def _quant_roundtrip(w32, kind):
+    import jax.numpy as jnp
+
+    from petals_tpu.ops.quant import dequantize, quantize
+
+    w = jnp.asarray(w32, jnp.bfloat16)
+    if kind == "bf16":
+        return np.asarray(w.astype(jnp.float32))
+    q = quantize(w, kind)
+    return np.asarray(dequantize(q, jnp.float32))
+
+
+def weight_space_table(kinds=("bf16", "int8", "nf4", "nf4a", "nf4a+o", "int4"), shape=SMALL) -> dict:
+    table = {}
+    sets, _ = _weight_sets(shape)
+    for dist, w in sets.items():
+        row = {}
+        wn = float(np.square(w).mean())
+        for kind in kinds:
+            dq = _quant_roundtrip(w, kind)
+            err = dq - w
+            rel_mse = float(np.square(err).mean()) / wn
+            row[kind] = {
+                "rel_mse": round(rel_mse, 8),
+                "snr_db": round(10 * np.log10(1.0 / max(rel_mse, 1e-12)), 1),
+                "max_abs_err": round(float(np.abs(err).max()), 5),
+            }
+        table[dist] = row
+    return table
+
+
+def activation_space_table(
+    kinds=("bf16", "int8", "nf4", "nf4a", "nf4a+o", "int4"), seed=1, shape=SMALL
+) -> dict:
+    """Output error of x @ w per format over outlier-channel weights, with
+    activation outliers either ALIGNED to the weight outlier channels or on
+    disjoint channels. (Empirically the aligned case is the more benign one
+    for RELATIVE output error — the amplified channels dominate the output
+    and blockwise scales represent them relatively well — so both are
+    reported and the table's headline is the worse of the two.)"""
+    rng = np.random.RandomState(seed)
+    rows, cols = shape
+    sets, outlier_rows = _weight_sets(shape, seed=0)
+    w = sets["outlier_channels"]
+    other_rows = np.setdiff1d(np.arange(rows), outlier_rows)[: len(outlier_rows)]
+    out = {}
+    for case, amp_rows in (("aligned", outlier_rows), ("disjoint", other_rows)):
+        x = rng.randn(64, rows).astype(np.float32)
+        x[:, amp_rows] *= 8.0
+        y_ref = x @ w
+        yn = float(np.square(y_ref).mean())
+        case_out = {}
+        for kind in kinds:
+            dq = _quant_roundtrip(w, kind)
+            y = x @ dq
+            rel = float(np.square(y - y_ref).mean()) / yn
+            case_out[kind] = {
+                "rel_out_mse": round(rel, 8),
+                "out_snr_db": round(10 * np.log10(1.0 / max(rel, 1e-12)), 1),
+            }
+        out[case] = case_out
+    out["worst_case"] = {
+        kind: min(
+            (out["aligned"][kind], out["disjoint"][kind]),
+            key=lambda r: r["out_snr_db"],
+        )
+        for kind in kinds
+    }
+    return out
 
 
 def test_weight_space_format_ordering():
@@ -26,8 +120,6 @@ def test_activation_space_format_ordering():
         assert table["nf4"]["rel_out_mse"] < table["int4"]["rel_out_mse"], case
     # the gap that sets the default: int4 is measurably worse than nf4, but
     # within ~4 dB (if it blows past that, the affine encoder regressed)
-    import numpy as np
-
     wc = full["worst_case"]
     gap_db = 10 * np.log10(wc["int4"]["rel_out_mse"] / wc["nf4"]["rel_out_mse"])
     assert 0.0 < gap_db < 4.0, gap_db

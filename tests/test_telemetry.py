@@ -497,62 +497,6 @@ def test_health_metrics_summary_aggregation():
     assert agg["occupancy"] == 6 / 8
 
 
-# ------------------------------------------------- perf-gate comparison units
-
-
-def test_gate_compare_blobs_and_report():
-    """The perf gate is pure data->data: an identical blob passes, a 2x
-    step-duration regression fails, a compiled path disappearing fails, and
-    a row that failed to run at all fails."""
-    from petals_tpu.telemetry.gate import compare_blobs, gate_report
-
-    lkg = {
-        "counters_delta": {"decode_tokens": 80.0, "alloc_failed": 0.0},
-        "step_duration": {
-            "paged": {"count": 40, "mean_ms": 5.0, "p50_ms": 4.0, "p99_ms": 9.0},
-        },
-    }
-    assert compare_blobs(lkg, lkg) == []
-
-    # 2x regression on mean and p50 (well past the 1 ms absolute floor)
-    slow = json.loads(json.dumps(lkg))
-    slow["step_duration"]["paged"]["mean_ms"] = 10.0
-    slow["step_duration"]["paged"]["p50_ms"] = 8.0
-    problems = compare_blobs(lkg, slow)
-    assert any("mean_ms" in p for p in problems), problems
-    # ...but a wide tolerance (advisory CI mode) lets the same blob through
-    assert compare_blobs(lkg, slow, tolerance=3.0) == []
-
-    # sub-millisecond jitter stays under the absolute floor even at 2x
-    jitter = json.loads(json.dumps(lkg))
-    jitter["step_duration"]["paged"] = {
-        "count": 40, "mean_ms": 0.9, "p50_ms": 0.8, "p99_ms": 2.0,
-    }
-    tiny_base = json.loads(json.dumps(jitter))
-    tiny_base["step_duration"]["paged"]["mean_ms"] = 0.45
-    tiny_base["step_duration"]["paged"]["p50_ms"] = 0.4
-    assert compare_blobs(tiny_base, jitter) == []
-
-    # the compiled path vanishing is itself a regression
-    gone = {"counters_delta": dict(lkg["counters_delta"]), "step_duration": {}}
-    assert any("no longer exercised" in p for p in compare_blobs(lkg, gone))
-
-    # new failures against a clean baseline, and collapsed workload volume
-    failing = json.loads(json.dumps(lkg))
-    failing["counters_delta"]["alloc_failed"] = 3.0
-    assert any("alloc_failed" in p for p in compare_blobs(lkg, failing))
-    shrunk = json.loads(json.dumps(lkg))
-    shrunk["counters_delta"]["decode_tokens"] = 10.0
-    assert any("decode_tokens" in p for p in compare_blobs(lkg, shrunk))
-
-    baseline = {"tolerance": 1.0, "rows": {"r1": {"telemetry": lkg}}}
-    assert gate_report(baseline, {"r1": {"telemetry": lkg}}) == {}
-    assert "r1" in gate_report(baseline, {"r1": {"telemetry": slow}})
-    assert gate_report(baseline, {"r1": None}) == {
-        "r1": ["row failed to run (no result)"]
-    }
-
-
 # ------------------------------------------------ /journal endpoint filters
 
 
@@ -820,27 +764,6 @@ def test_cost_table_roofline_and_memory_analysis(monkeypatch):
     assert "memory" not in table[0]
     mem_table = obs.cost_table(memory=True)
     assert mem_table[0]["memory"]["argument_bytes"] > 0
-
-
-def test_gate_compile_budget_counters():
-    """The bench gate holds compile counts to the committed baseline:
-    growth fails (budget), anomalies fail (failure counter), and a baseline
-    that predates the observatory gates nothing retroactively."""
-    from petals_tpu.telemetry.gate import compare_blobs
-
-    base = {"counters_delta": {
-        "compiles": 3.0, "compile_anomalies": 0.0, "decode_tokens": 40.0,
-    }}
-    same = {"counters_delta": {"compiles": 3.0, "decode_tokens": 40.0}}
-    assert compare_blobs(base, same) == []
-    grew = {"counters_delta": {"compiles": 5.0, "decode_tokens": 40.0}}
-    assert any("compiles" in p for p in compare_blobs(base, grew))
-    anom = {"counters_delta": {
-        "compiles": 3.0, "compile_anomalies": 1.0, "decode_tokens": 40.0,
-    }}
-    assert any("compile_anomalies" in p for p in compare_blobs(base, anom))
-    old = {"counters_delta": {"decode_tokens": 40.0}}  # pre-observatory
-    assert compare_blobs(old, grew) == []
 
 
 def test_journal_sink_close_and_seq_agreement(tmp_path):

@@ -420,8 +420,8 @@ def spawn_multihost_pair(
     """Start a run_server leader + run_worker pair over a 2-process tp mesh
     and wait for the leader's announce address. Returns (leader_proc,
     worker_proc, addr); both stdouts are drained by daemon reader threads
-    from the start (callers must terminate both). One definition for the
-    multihost tests AND benchmarks — the announce-line protocol lives here.
+    from the start (callers must terminate both). The announce-line protocol
+    lives here.
 
     Readiness is watched through a queue fed by the leader's reader thread,
     so ``ready_timeout`` is enforced even when the leader stops logging
@@ -519,9 +519,8 @@ async def drive_coalescing_sessions(
 ):
     """Drive N raw RPC decode sessions against a span leader. When
     ``concurrent``, each round's sends are all issued BEFORE any reply is
-    awaited, so the leader's lane pool genuinely coalesces — the shared
-    protocol driver for the coalescing test and the multihost batching
-    bench. Returns (elapsed_decode_seconds, ptu.info dict)."""
+    awaited, so the leader's lane pool genuinely coalesces. Returns
+    (elapsed_decode_seconds, ptu.info dict)."""
     import time as _time
 
     import numpy as np
