@@ -55,6 +55,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -227,10 +228,22 @@ class PagedKV(NamedTuple):
     a JAX pytree and rides through ``block_apply``'s kv tuple / lax.scan
     carries unchanged; ``update_kv_cache`` and ``attend`` recognise it by
     isinstance and route to the paged scatter / fused-kernel dispatch instead
-    of the dense buffer code."""
+    of the dense buffer code.
+
+    Inside a step program the pool a block sees is the WHOLE SPAN's, every
+    layer's pages end to end (``[n_layers * n_pages, page_size, hkv, d]``, a
+    bitcast of the stacked pool the layer loop carries and updates in place),
+    and ``tables`` are the lanes' block tables shifted by the layer's first
+    page (holes stay -1): the scatter lands a layer's rows in that layer's
+    pages and the gather reads them from there, without a layer ever being
+    sliced out of the pool or written back into it (server/backend.py
+    ``_scan_paged_span``). ``layer`` then names the block's own stretch of
+    that pool, ``(first_page, n_pages)``; it is None for a pool that holds
+    one block alone."""
 
     pool: PoolLike  # [n_pages, page_size, hkv, d] array, or a PagedPool
     tables: jnp.ndarray  # [n_lanes, max_pages] int32; -1 = unallocated slot
+    layer: Optional[Tuple] = None  # (first page: int32 scalar, pages a layer: int)
 
     @property
     def quant_kind(self) -> str:
@@ -254,6 +267,19 @@ class PagedKV(NamedTuple):
     @property
     def dtype(self):
         return self.pool.dtype
+
+    def own_layer(self) -> "PagedKV":
+        """The block's own pages as a pool of their own, with the tables it
+        was given before the shift: what a consumer takes that would
+        otherwise walk or relay every layer of the span's pool (the fused
+        kernel relays the pool it is handed into a lane-trailing view)."""
+        if self.layer is None:
+            return self
+        first_page, n_pages = self.layer
+        pool = jax.tree_util.tree_map(  # a PagedPool leaf by leaf
+            lambda a: jax.lax.dynamic_slice_in_dim(a, first_page, n_pages, axis=0), self.pool
+        )
+        return PagedKV(pool, jnp.where(self.tables >= 0, self.tables - first_page, -1))
 
 
 def max_pages_for(max_length: int, page_size: int) -> int:
@@ -470,7 +496,7 @@ def paged_update_kv(
         else:
             k_pool = scatter_lane_chunk_rows(k_kv.pool, k_new, tables, pos)
             v_pool = scatter_lane_chunk_rows(v_kv.pool, v_new, tables, pos)
-        return PagedKV(k_pool, tables), PagedKV(v_pool, tables), pos + seq
+        return k_kv._replace(pool=k_pool), v_kv._replace(pool=v_pool), pos + seq
     if k_new.shape[0] != 1 or tables.shape[0] != 1:
         raise ValueError(
             "scalar-position paged writes are single-lane chunks: "
@@ -483,7 +509,7 @@ def paged_update_kv(
     write_pos = jnp.where(offs < n, pos + offs, jnp.int32(k_kv.max_length))
     k_pool = scatter_chunk_rows(k_kv.pool, k_new[0], tables[0], write_pos)
     v_pool = scatter_chunk_rows(v_kv.pool, v_new[0], tables[0], write_pos)
-    return PagedKV(k_pool, tables), PagedKV(v_pool, tables), pos + n
+    return k_kv._replace(pool=k_pool), v_kv._replace(pool=v_pool), pos + n
 
 
 def paged_attend(

@@ -908,6 +908,10 @@ def paged_attend_dispatch(
     )
     kind = "decode" if decode else "prefill"
     if not forced_xla and decide_paged_kernel(kind, key):
+        # the kernel relays the pool it is handed (_pool_views): give it the
+        # block's own layer, not the span's pool the step's loop carries
+        k_kv, v_kv = k_kv.own_layer(), v_kv.own_layer()
+        k_pool, v_pool, tables = k_kv.pool, v_kv.pool, k_kv.tables
         if decode:
             return paged_flash_attend(
                 q, k_pool, v_pool, tables, pos,

@@ -539,6 +539,82 @@ class TransformerBackend:
             path = f"dec:{path},pf:{pfa.resolve_paged_kernel_path('prefill', key)}"
         return path
 
+    def _scan_paged_span(self, params, k_pool, v_pool, carry, layer):
+        """The layer loop of every paged step program: ``jax.lax.scan`` over
+        the span's blocks with the page pools in the loop's CARRY, updated in
+        place, and only the stacked weights (and the layer index) as ``xs``.
+
+        A scan's ``ys`` is a buffer of its own: pools that ride as ``xs`` and
+        come back as ``ys`` are sliced out, copied and written whole into a
+        second pool layer by layer, and that pool is copied over the donated
+        one after the loop, all to land a lane's one new row. So the pools
+        are flattened to ``[n_blocks * n_pages, page_size, hkv, d]`` (a
+        bitcast; a quantized ``PagedPool`` leaf by leaf) and a layer reaches
+        its pages through block tables shifted by ``layer * n_pages`` (holes
+        stay -1): ``PagedKV``'s scatter and gather work on the carried pool
+        as they would on one layer's, the same rows land at the same places,
+        the drop sentinel is one past the end of the flat pool, and the
+        donated buffers alias the outputs.
+
+        ``layer(carry, p_block, k_span, v_span, paged) -> (carry, k_span,
+        v_span)`` runs one block: ``paged(k_span, v_span, tables)`` wraps the
+        carried pools and a set of block tables as that block's ``PagedKV``
+        pair, and the pools ``block_apply`` hands back go on to the next
+        layer. Returns ``(carry, k_pool, v_pool)``, the pools in their
+        stacked shape."""
+        from petals_tpu.ops.paged_attention import PagedKV
+
+        depth, n_pages = k_pool.shape[0], k_pool.shape[1]
+        if self._use_quant_consts:
+            xs_params, quant_params, outlier_names = self._split_quant(params)
+        else:
+            xs_params, quant_params = params, None
+
+        def merged(pool):  # [depth, n_pages, ...] -> [depth * n_pages, ...]
+            return jax.tree_util.tree_map(
+                lambda a: a.reshape(depth * n_pages, *a.shape[2:]), pool
+            )
+
+        def stacked(pool):
+            return jax.tree_util.tree_map(
+                lambda a: a.reshape(depth, n_pages, *a.shape[1:]), pool
+            )
+
+        def body(state, xs):
+            inner, k_span, v_span = state
+            p_block, block_idx = xs
+            if quant_params is not None:
+                p_block = self._reattach_quant(p_block, quant_params, outlier_names, block_idx)
+            first_page = block_idx * n_pages
+
+            def paged(k_span, v_span, tables):
+                shifted = jnp.where(tables >= 0, tables + first_page, -1)
+                own = (first_page, n_pages)
+                return PagedKV(k_span, shifted, own), PagedKV(v_span, shifted, own)
+
+            return layer(inner, p_block, k_span, v_span, paged), None
+
+        (carry, k_span, v_span), _ = jax.lax.scan(
+            body, (carry, merged(k_pool), merged(v_pool)),
+            (xs_params, jnp.arange(depth, dtype=jnp.int32)),
+        )
+        return carry, stacked(k_span), stacked(v_span)
+
+    def _paged_lanes_layer(self, tables, positions):
+        """``_scan_paged_span``'s ``layer`` for a step in which every lane
+        feeds rows at its own position (decode, server-side generation,
+        speculative verify): one ``block_apply`` over the lanes' tables."""
+        family, cfg = self.family, self.cfg
+
+        def layer(h, p_block, k_span, v_span, paged):
+            out, (k_kv, v_kv) = family.block_apply(
+                p_block, h, paged(k_span, v_span, tables), positions, cfg,
+                use_flash=False, tp_mesh=None,
+            )
+            return out, k_kv.pool, v_kv.pool
+
+        return layer
+
     @functools.cached_property
     def _paged_decode_fn(self):
         """Paged twin of ``_batched_decode_fn``: the pool is page-granular
@@ -550,14 +626,14 @@ class TransformerBackend:
         (ops/paged_flash_attention.py). ONE attention code path: dense is
         just the identity block table, with no host-side contiguity special
         case. ``kernel_path`` is a static pass-through whose only job is to
-        retrace the step when the resolved kernel decision changes."""
-        family, cfg = self.family, self.cfg
-        split_quant = self._split_quant
-        use_quant_consts = self._use_quant_consts
-        reattach = self._reattach_quant
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        retrace the step when the resolved kernel decision changes.
 
-        from petals_tpu.ops.paged_attention import PagedKV
+        The pool a block sees is the whole span's and its tables are shifted
+        by the layer: the stacked pools are the layer loop's carry, written
+        in place (``_scan_paged_span``), and come back in the donated
+        buffers."""
+        cfg = self.cfg
+        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -571,27 +647,9 @@ class TransformerBackend:
             # tables: [n_lanes, max_pages] int32 (-1 = unallocated slot)
             del kernel_path  # static retrace trigger; attend() re-resolves
             hidden = hidden.astype(cache_dtype)
-            if use_quant_consts:
-                dense_params, quant_params, outlier_names = split_quant(params)
-                xs_params = dense_params
-                block_indices = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
-            else:
-                xs_params = params
-                block_indices = jnp.zeros((k_pool.shape[0],), jnp.int32)  # unused
-
-            def body(h, xs):
-                p_block, k_blk, v_blk, block_idx = xs
-                if use_quant_consts:
-                    p_block = reattach(p_block, quant_params, outlier_names, block_idx)
-                kv = (PagedKV(k_blk, tables), PagedKV(v_blk, tables))
-                out, (k_kv, v_kv) = family.block_apply(
-                    p_block, h, kv, positions, cfg,
-                    use_flash=False, tp_mesh=None,
-                )
-                return out, (k_kv.pool, v_kv.pool)
-
-            hidden, (k_pool, v_pool) = jax.lax.scan(
-                body, hidden, (xs_params, k_pool, v_pool, block_indices)
+            hidden, k_pool, v_pool = self._scan_paged_span(
+                params, k_pool, v_pool, hidden,
+                self._paged_lanes_layer(tables, positions),
             )
             if with_fp:
                 # same projection as the dense program: path-invariance —
@@ -636,15 +694,12 @@ class TransformerBackend:
     def _paged_gen_decode_fn(self):
         """Paged twin of ``_batched_gen_decode_fn``: the pooled server-gen
         step (client leaves in the loop) over the page-granular pool. Same
-        PagedKV single attention path as ``_paged_decode_fn``."""
+        PagedKV single attention path and the same layer loop as
+        ``_paged_decode_fn``: the span's pool carried and written in place,
+        each block's tables shifted by its layer (``_scan_paged_span``)."""
         family, cfg = self.family, self.cfg
-        split_quant = self._split_quant
-        use_quant_consts = self._use_quant_consts
-        reattach = self._reattach_quant
         client_embed, client_head = family.client_embed, family.client_head
         fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
-
-        from petals_tpu.ops.paged_attention import PagedKV
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -663,27 +718,9 @@ class TransformerBackend:
                 emb.astype(cache_dtype),
                 hidden.astype(cache_dtype),
             )
-            if use_quant_consts:
-                dense_params, quant_params, outlier_names = split_quant(params)
-                xs_params = dense_params
-                block_indices = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
-            else:
-                xs_params = params
-                block_indices = jnp.zeros((k_pool.shape[0],), jnp.int32)  # unused
-
-            def body(h, xs):
-                p_block, k_blk, v_blk, block_idx = xs
-                if use_quant_consts:
-                    p_block = reattach(p_block, quant_params, outlier_names, block_idx)
-                kv = (PagedKV(k_blk, tables), PagedKV(v_blk, tables))
-                out, (k_kv, v_kv) = family.block_apply(
-                    p_block, h, kv, positions, cfg,
-                    use_flash=False, tp_mesh=None,
-                )
-                return out, (k_kv.pool, v_kv.pool)
-
-            hidden, (k_pool, v_pool) = jax.lax.scan(
-                body, hidden, (xs_params, k_pool, v_pool, block_indices)
+            hidden, k_pool, v_pool = self._scan_paged_span(
+                params, k_pool, v_pool, hidden,
+                self._paged_lanes_layer(tables, positions),
             )
             logits = client_head(client_params, hidden, cfg)[:, -1, :]
             next_tok = sample_tokens(
@@ -753,15 +790,14 @@ class TransformerBackend:
         FED token before sampling each row (idempotent for row 0's already-
         seen committed token), matching plain decode's per-token host update.
         Non-speculating lanes ride along with the idle sentinel position:
-        their writes drop and their outputs are ignored."""
+        their writes drop and their outputs are ignored.
+
+        The layer loop is ``_paged_decode_fn``'s (``_scan_paged_span``): the
+        pool a block sees is the whole span's, carried and written in place,
+        and its tables are shifted by the layer."""
         family, cfg = self.family, self.cfg
-        split_quant = self._split_quant
-        use_quant_consts = self._use_quant_consts
-        reattach = self._reattach_quant
         client_embed, client_head = family.client_embed, family.client_head
         fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
-
-        from petals_tpu.ops.paged_attention import PagedKV
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -779,27 +815,9 @@ class TransformerBackend:
             del kernel_path  # static retrace trigger; attend() re-resolves
             S = tokens.shape[1]
             hidden = client_embed(client_params, tokens, cfg).astype(cache_dtype)
-            if use_quant_consts:
-                dense_params, quant_params, outlier_names = split_quant(params)
-                xs_params = dense_params
-                block_indices = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
-            else:
-                xs_params = params
-                block_indices = jnp.zeros((k_pool.shape[0],), jnp.int32)  # unused
-
-            def body(h, xs):
-                p_block, k_blk, v_blk, block_idx = xs
-                if use_quant_consts:
-                    p_block = reattach(p_block, quant_params, outlier_names, block_idx)
-                kv = (PagedKV(k_blk, tables), PagedKV(v_blk, tables))
-                out, (k_kv, v_kv) = family.block_apply(
-                    p_block, h, kv, positions, cfg,
-                    use_flash=False, tp_mesh=None,
-                )
-                return out, (k_kv.pool, v_kv.pool)
-
-            hidden, (k_pool, v_pool) = jax.lax.scan(
-                body, hidden, (xs_params, k_pool, v_pool, block_indices)
+            hidden, k_pool, v_pool = self._scan_paged_span(
+                params, k_pool, v_pool, hidden,
+                self._paged_lanes_layer(tables, positions),
             )
             logits = client_head(client_params, hidden, cfg)  # [n, S, vocab]
             vocab_ids = jnp.arange(logits.shape[-1], dtype=jnp.int32)[None, :]
@@ -879,8 +897,10 @@ class TransformerBackend:
         """Mixed prefill+decode step — the unified continuous-batching
         program ("Ragged Paged Attention" folding, PAPERS.md): every decode
         lane advances one token AND one lane runs a bucketed prefill chunk,
-        in a single jitted scan over the page pool. The decode half is
-        ``_paged_decode_fn``'s body verbatim; the prefill half wraps the
+        in a single jitted scan that carries the page pool (the whole span's,
+        written in place; each block's tables shifted by its layer:
+        ``_scan_paged_span``). The decode half is ``_paged_decode_fn``'s
+        layer verbatim (``_paged_lanes_layer``); the prefill half wraps the
         chunk lane's table row as a single-lane PagedKV and runs the SAME
         block compute as the exclusive path (``_inference_step_fn`` at
         batch=1: scalar position, bucket-padded chunk with n_valid
@@ -892,13 +912,8 @@ class TransformerBackend:
         decode position is the idle sentinel, so its decode-side write
         drops), so decode-before-prefill ordering is immaterial."""
         family, cfg = self.family, self.cfg
-        split_quant = self._split_quant
-        use_quant_consts = self._use_quant_consts
-        reattach = self._reattach_quant
         takes_n_total = "n_total" in inspect.signature(family.block_apply).parameters
         fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
-
-        from petals_tpu.ops.paged_attention import PagedKV
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -917,40 +932,24 @@ class TransformerBackend:
             B = chunk_hidden.shape[1]
             hidden = hidden.astype(cache_dtype)
             chunk_hidden = chunk_hidden.astype(cache_dtype)
-            table_row = jnp.take(tables, chunk_lane, axis=0)  # [max_pages]
-            if use_quant_consts:
-                dense_params, quant_params, outlier_names = split_quant(params)
-                xs_params = dense_params
-                block_indices = jnp.arange(k_pool.shape[0], dtype=jnp.int32)
-            else:
-                xs_params = params
-                block_indices = jnp.zeros((k_pool.shape[0],), jnp.int32)  # unused
+            table_row = jnp.take(tables, chunk_lane, axis=0)[None]  # [1, max_pages]
+            decode_half = self._paged_lanes_layer(tables, positions)
+            extra = {"n_total": chunk_n_total} if takes_n_total else {}
 
-            def body(carry, xs):
+            def layer(carry, p_block, k_span, v_span, paged):
                 h_dec, h_pf = carry
-                p_block, k_blk, v_blk, block_idx = xs
-                if use_quant_consts:
-                    p_block = reattach(p_block, quant_params, outlier_names, block_idx)
-                # --- decode half (== _paged_decode_fn body)
-                kv = (PagedKV(k_blk, tables), PagedKV(v_blk, tables))
-                out_dec, (k_kv, v_kv) = family.block_apply(
-                    p_block, h_dec, kv, positions, cfg,
-                    use_flash=False, tp_mesh=None,
-                )
-                k_blk, v_blk = k_kv.pool, v_kv.pool
+                out_dec, k_span, v_span = decode_half(h_dec, p_block, k_span, v_span, paged)
                 # --- prefill half: the chunk lane's table row as a
-                # single-lane PagedKV; writes land in the pages directly
-                kv_pf = (PagedKV(k_blk, table_row[None]), PagedKV(v_blk, table_row[None]))
-                extra = {"n_total": chunk_n_total} if takes_n_total else {}
+                # single-lane PagedKV over the pools the decode half wrote;
+                # writes land in the pages directly
                 out_pf, (k_kv, v_kv) = family.block_apply(
-                    p_block, h_pf, kv_pf, chunk_pos, cfg,
+                    p_block, h_pf, paged(k_span, v_span, table_row), chunk_pos, cfg,
                     use_flash=False, n_valid=chunk_n_valid, tp_mesh=None, **extra,
                 )
-                return (out_dec, out_pf), (k_kv.pool, v_kv.pool)
+                return (out_dec, out_pf), k_kv.pool, v_kv.pool
 
-            (hidden, chunk_out), (k_pool, v_pool) = jax.lax.scan(
-                body, (hidden, chunk_hidden),
-                (xs_params, k_pool, v_pool, block_indices),
+            (hidden, chunk_out), k_pool, v_pool = self._scan_paged_span(
+                params, k_pool, v_pool, (hidden, chunk_hidden), layer
             )
             if with_fp:
                 fp = fp_ops.fingerprint_rows(hidden[:, -1, :], fp_proj)

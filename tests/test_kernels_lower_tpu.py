@@ -9,10 +9,11 @@ the real Pallas -> Mosaic -> libtpu pipeline with ``interpret=False``. Each
 case is one full-width shape (Llama-2-7B / 70B head geometry); nothing
 executes. Numerics on the chip itself are chip_smoke.py's kernel phase.
 
-The last section compiles a whole step program the same way, the paged decode
-step at the benchmark's three configurations, and reads the optimized HLO for
-what no run on the CPU can show: that the decode loop reads every stacked
-weight where it lies (PERF.md section 5, "The relayout").
+The last two sections compile a whole step program the same way, the paged
+decode step and a mixed step at the benchmark's three configurations, and read
+the optimized HLO for what no run on the CPU can show: that the loop reads
+every stacked weight where it lies (PERF.md section 5, "The relayout"), and
+that the step leaves the page pool where it lies (PERF.md section 5, PR 29).
 """
 
 import functools
@@ -208,6 +209,26 @@ def _computations(hlo: str) -> dict:
     return out
 
 
+def _while_bodies(comps: dict):
+    """The instruction list of every while loop's body."""
+    for instructions in comps.values():
+        for _, _, op, rest in instructions:
+            body = re.search(r"body=%([\w.\-]+)", rest) if op == "while" else None
+            if body is not None:
+                yield comps[body.group(1)]
+
+
+def _fused(comps: dict, op: str, rest: str):
+    """The instructions of the computation a fusion calls, else None."""
+    called = re.search(r"calls=%([\w.\-]+)", rest) if op == "fusion" else None
+    return comps[called.group(1)] if called is not None else None
+
+
+def _only_moves(comps: dict, op: str, rest: str) -> bool:
+    fused = _fused(comps, op, rest)
+    return op in _MOVES or (fused is not None and all(i[2] in _MOVES for i in fused))
+
+
 def weight_relayouts(hlo: str, stacked_shapes: set, min_elements: int) -> tuple:
     """``(relayouts, weights_seen)`` of the while bodies of an optimized HLO
     module. A relayout is an instruction that only MOVES a stacked weight: its
@@ -220,26 +241,18 @@ def weight_relayouts(hlo: str, stacked_shapes: set, min_elements: int) -> tuple:
     of those shapes, so that a caller can tell "none found" from "not parsed"."""
     comps = _computations(hlo)
     relayouts, seen = [], 0
-    for instructions in comps.values():
-        for _, _, op, rest in instructions:
-            body = re.search(r"body=%([\w.\-]+)", rest) if op == "while" else None
-            if body is None:
-                continue
-            moved = set()  # names in the body that are a stacked weight, or a pure move of one
-            for name, dims, op_, rest_ in comps[body.group(1)]:
-                if op_ == "get-tuple-element":
-                    if dims in stacked_shapes:
-                        moved.add(name)
-                        seen += 1
-                    continue
-                called = re.search(r"calls=%([\w.\-]+)", rest_)
-                moves = op_ in _MOVES or (
-                    op_ == "fusion" and called is not None and all(i[2] in _MOVES for i in comps[called.group(1)])
-                )
-                if moves and moved & set(re.findall(r"%([\w.\-]+)", rest_)):
+    for body in _while_bodies(comps):
+        moved = set()  # names in the body that are a stacked weight, or a pure move of one
+        for name, dims, op, rest in body:
+            if op == "get-tuple-element":
+                if dims in stacked_shapes:
                     moved.add(name)
-                    if op_ != "bitcast" and math.prod(dims) >= min_elements:
-                        relayouts.append(f"%{name} = {op_} -> {list(dims)}")
+                    seen += 1
+                continue
+            if _only_moves(comps, op, rest) and moved & set(re.findall(r"%([\w.\-]+)", rest)):
+                moved.add(name)
+                if op != "bitcast" and math.prod(dims) >= min_elements:
+                    relayouts.append(f"%{name} = {op} -> {list(dims)}")
     return relayouts, seen
 
 
@@ -256,17 +269,11 @@ STEP_CASES = [
 ]
 
 
-@pytest.mark.parametrize("config_name,chunk", STEP_CASES)
-def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_name, chunk):
-    """``TransformerBackend``'s paged decode step, and its mixed step with a
-    prompt chunk of 256 riding it, at a cell's widths and depth (8 lanes, 128
-    pages of 64, 16 pages a lane, pools donated), compiled for the v5e: no
-    instruction of the loop's body may slice a layer's matrix out of the
-    stacked span into a buffer of its own, or copy one. Before
-    ``models/common.py project_heads`` the body held ``bf16[1,8192,8192]``
-    twice a layer for Falcon's ``wq`` (a dynamic-slice fusion, then a
-    transposing copy: 27% of the decode loop on the chip), the same pair for
-    ``wk`` / ``wv``, and Mixtral's and OLMoE's likewise."""
+def _compiled_step(v5e, tmp_path, config_name, chunk):
+    """``(optimized HLO, stacked params, pool aval)`` of ``TransformerBackend``'s
+    paged decode step, or of its mixed step with a prompt chunk of ``chunk``
+    riding it, at a cell's widths and depth (8 lanes, 128 pages of 64, 16
+    pages a lane, pools donated), compiled for the v5e."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -289,9 +296,95 @@ def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_na
     # the raw step under tracked_jit: kernel_path only retraces, attend() resolves the path itself
     step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
     hlo = jax.jit(step, donate_argnums=(1, 2)).lower(*avals).compile().as_text()
+    return hlo, params, pool
+
+
+@pytest.mark.parametrize("config_name,chunk", STEP_CASES)
+def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_name, chunk):
+    """No instruction of the step's loop body may slice a layer's matrix out
+    of the stacked span into a buffer of its own, or copy one. Before
+    ``models/common.py project_heads`` the body held ``bf16[1,8192,8192]``
+    twice a layer for Falcon's ``wq`` (a dynamic-slice fusion, then a
+    transposing copy: 27% of the decode loop on the chip), the same pair for
+    ``wk`` / ``wv``, and Mixtral's and OLMoE's likewise."""
+    hlo, params, _ = _compiled_step(v5e, tmp_path, config_name, chunk)
     attention = [params[name].shape for name in ("wq", "wk", "wv", "wo")]
     relayouts, seen = weight_relayouts(
         hlo, {tuple(p.shape) for p in params.values()}, min(math.prod(shape[1:]) for shape in attention)
     )
     assert seen >= len(set(attention)), "the loop's stacked weights were not found: has the HLO text changed?"
     assert not relayouts, f"the step's loop relays a weight in every layer of every step: {relayouts}"
+
+
+# ---------------------------------------------------------------- the step leaves the page pool where it lies
+
+
+def pool_moves(hlo: str, pool_shape: tuple) -> tuple:
+    """``(moves, loops_seen)``: every instruction of an optimized HLO module
+    that moves a page pool, or a layer of one, and computes nothing.
+    ``pool_shape`` is the stacked pool's, ``[layers, n_pages, page_size, hkv,
+    d]``. In ``ENTRY``: an ``AllocateBuffer`` custom call or a ``copy`` of the
+    pool's size (a second pool, and the copy back over the donated one). In a
+    while body: an instruction that produces a pool layer's worth of rows of
+    ``[hkv, d]`` or more and is one of ``_MOVES``, a fusion made of them alone, or a fusion
+    whose root is a ``dynamic-update-slice`` (a layer written back whole). The
+    in-place scatter of the new rows is a ``scatter`` fusion and the gather of
+    the tables' pages computes a select, so neither counts. ``loops_seen``
+    counts the while bodies that carry an array of the pool's size, so that a
+    caller can tell "none found" from "not parsed"."""
+    comps = _computations(hlo)
+    pool_elements = math.prod(pool_shape)
+    layer_elements = pool_elements // pool_shape[0]
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    moves = [
+        f"ENTRY %{name} = {op} -> {list(dims)}"
+        for name, dims, op, rest in comps[entry]
+        if math.prod(dims) == pool_elements
+        and (op == "copy" or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))
+    ]
+    loops_seen = 0
+    for body in _while_bodies(comps):
+        carried = False
+        for name, dims, op, rest in body:
+            if op == "get-tuple-element":
+                carried = carried or math.prod(dims) == pool_elements
+                continue
+            if op in ("parameter", "tuple", "bitcast", "constant") or dims[-2:] != pool_shape[-2:]:
+                continue  # not rows of [kv heads, head_dim]: a weight
+            if math.prod(dims) < layer_elements:
+                continue
+            fused = _fused(comps, op, rest)
+            if _only_moves(comps, op, rest) or (fused is not None and fused[-1][2] == "dynamic-update-slice"):
+                moves.append(f"%{name} = {op} -> {list(dims)}")
+        loops_seen += carried
+    return moves, loops_seen
+
+
+POOL_CASES = [
+    pytest.param(
+        config_name, chunk, id=f"{config_name}-{'mixed-256' if chunk else 'decode'}",
+        marks=[pytest.mark.xfail(strict=True, reason=(
+            "a pool of head_dim 64 lives on the device with the page index minor (bf16[5,128,64,8,64]{1,4,3,2,0}: 64 is "
+            "under the 128-lane tile), so ENTRY relays both pools to the logical layout before the loop and back "
+            "after it, once a step; the follow-up stores such a pool 128 wide (ROADMAP S7 (a))"
+        ))] if config_name == "falcon-40b-span5" else [],
+    )
+    for config_name in ("falcon-40b-span5", "mixtral-8x7b-span2", "olmoe-1b-7b-span8")
+    for chunk in (0, 256)
+]
+
+
+@pytest.mark.parametrize("config_name,chunk", POOL_CASES)
+def test_paged_step_leaves_the_page_pool_in_place(v5e, tmp_path, config_name, chunk):
+    """The page pools are the layer loop's carry (``backend._scan_paged_span``):
+    the compiled step allocates no second pool, copies none, and no layer of
+    one is sliced out, copied or written back whole inside the loop. With the
+    pools as the scan's ``xs`` / ``ys`` (until PR 29) OLMoE's decode step held
+    two ``AllocateBuffer`` and two ``copy`` of ``bf16[8,128,64,16,128]`` (268
+    MB each) in ``ENTRY`` and, a layer and a pool, a ``dynamic-slice`` fusion,
+    a ``copy-start`` / ``copy-done`` and a ``dynamic-update-slice`` fusion of
+    a whole layer in the body: 2.7 ms of a 19.7 ms step on the chip."""
+    hlo, _, pool = _compiled_step(v5e, tmp_path, config_name, chunk)
+    moves, loops_seen = pool_moves(hlo, tuple(pool.shape))
+    assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
+    assert not moves, f"the step moves the page pool around its {pool.shape[0]} layers: {moves}"

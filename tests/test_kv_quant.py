@@ -403,7 +403,7 @@ def test_migration_pack_wire_unpack_byte_exact(kind):
 # ----------------------------------------- backend step: band + no recompile
 
 
-def _tiny_backend(model_path, kind="none"):
+def _tiny_backend(model_path, kind="none", n_blocks=2):
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config, load_block_params
     from petals_tpu.server.memory_cache import MemoryCache
@@ -411,11 +411,11 @@ def _tiny_backend(model_path, kind="none"):
     family, cfg = get_block_config(model_path)
     per_block = [
         load_block_params(model_path, i, dtype=jnp.float32, family=family, cfg=cfg)
-        for i in range(2)
+        for i in range(n_blocks)
     ]
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_block)
     return TransformerBackend(
-        family, cfg, stacked, first_block=0, n_blocks=2,
+        family, cfg, stacked, first_block=0, n_blocks=n_blocks,
         memory_cache=MemoryCache(None), compute_dtype=jnp.float32,
         use_flash=False, kv_quant_type=kind,
     ), cfg
