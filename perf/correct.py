@@ -122,3 +122,19 @@ def judge(rows: list, want: np.ndarray, margin: np.ndarray, limits: dict) -> dic
     # for a reader of the logs, and for setting the family's limits: every row
     out["rows"] = [[k, int(p), round(float(e), 5), round(float(m), 4)] for k, p, e, m in zip(kinds, pos, err, margin[pos])]
     return out
+
+
+def compared(verdict: dict) -> dict:
+    """``{name: [number, limit]}`` of everything ``judge`` and the repeat held a
+    run to, for the result line and the last lines of standard error: a run
+    that is not correct shows there which number crossed which limit. The
+    largest compared row may pass the row bound where ``*_outside`` may be
+    over 0; a share of rows compared is held from below, the rest from above."""
+    out = {"repeat_identical": [int(verdict["repeat_identical"]), 1], "finite": [int(verdict["finite"]), 1]}
+    for kind in ("prefill", "decode"):
+        v = verdict[kind]
+        out[f"{kind}_median"] = [v["median"], verdict["median_bound"]]
+        out[f"{kind}_max"] = [v["max"], verdict["row_bound"]]
+        out[f"{kind}_outside"] = [len(v["positions_outside"]), verdict["positions_allowed"]]
+        out[f"{kind}_compared_share"] = [v["compared"] / v["rows"] if v["rows"] else 0.0, MIN_COMPARED_SHARE]
+    return out

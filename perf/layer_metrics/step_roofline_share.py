@@ -23,15 +23,16 @@ def parts(record):
             return None
         lo, hi = marks["trace_start"]["mono"], marks["trace_stop"]["mono"]
         positions = [pos for s in record.sessions for t, pos in s.replies if lo <= t <= hi]
-        context = sum(positions) / max(len(positions), 1) * (tokens / steps)  # cached positions read per step
+        # cached positions per step: the lanes' mean context (a windowed layer caps it, perf/costs.py) times the lanes
+        context = sum(positions) / max(len(positions), 1) * (tokens / steps)
         prompts = [s.plan.prompt_len for s in record.sessions] or [0]
         n_layers = span["num_blocks"]
         least, bounds = 0.0, {}
         for count, chunk in ((steps - mixed, 0.0), (mixed, prefill / mixed if mixed else 0.0)):
             if count <= 0:
                 continue
-            cost = costs.step_cost(family, hf, n_layers, decode_tokens=tokens / steps, prefill_tokens=chunk,
-                                   context_tokens=context, prefill_context=sum(prompts) / len(prompts) / 2)
+            cost = costs.step_cost(family, hf, n_layers, first_block=span["first_block"], decode_tokens=tokens / steps,
+                                   prefill_tokens=chunk, context_tokens=context, prefill_context=sum(prompts) / len(prompts) / 2)
             seconds, bound = costs.least_seconds(cost, record.peaks)
             least += seconds * count
             bounds[bound] = bounds.get(bound, 0.0) + seconds * count

@@ -18,6 +18,10 @@ is found by the configuration's ``family`` and gives:
     may still be outside the row bound: only for a family whose decisions
     upstream can move one (see mixtral.py).
 ``layer_params(hf)``  the layer's matrix parameters, for perf/costs.py.
+``layer_kinds(hf)``  (optional) for a family whose layers are not all alike:
+    one hashable per layer of the model. ``block`` and ``layer_params`` here,
+    and ``layer_tensors`` and ``block_params`` of perf/weights/<family>.py,
+    are then given the layer's kind as a further, last argument.
 """
 
 from __future__ import annotations
@@ -58,6 +62,14 @@ def family_of(name: str):
     return importlib.import_module(f"perf.reference.{name}")
 
 
+def kinds_of(name: str, hf: dict):
+    """``[(kind,), ...]``, one per layer, where the family defines
+    ``layer_kinds``: what its functions take after their other arguments.
+    None for a family of one kind, whose functions are called without."""
+    kinds = getattr(family_of(name), "layer_kinds", None)
+    return [(kind,) for kind in kinds(hf)] if kinds else None
+
+
 def limits(config: dict) -> dict:
     """The family's limits for perf/correct.py at this configuration's depth."""
     family = family_of(config["family"])
@@ -77,19 +89,24 @@ def run(config: dict, hidden: np.ndarray) -> tuple:
 
     family, maker = family_of(config["family"]), weights.family_of(config["family"])
     hf = config["config"]
+    n_layers = sum(span["num_blocks"] for span in config["servers"])
+    kinds = kinds_of(config["family"], hf) or [()] * n_layers
 
-    def layer(index, x):
-        w = maker.layer_tensors(hf, index, weights.Draws(config["weights_seed"]))
-        out, margin = family.block(hf, {k: v.astype(jnp.float32) for k, v in w.items()}, x)
-        return out, margin, weights.checksum(w)
+    def program(kind: tuple):
+        def layer(index, x):
+            w = maker.layer_tensors(hf, index, weights.Draws(config["weights_seed"]), *kind)
+            out, margin = family.block(hf, {k: v.astype(jnp.float32) for k, v in w.items()}, x, *kind)
+            return out, margin, weights.checksum(w)
+
+        return jax.jit(layer)  # the index is traced: one program for every layer of a kind
 
     with jax.default_matmul_precision("highest"):
-        layer = jax.jit(layer)  # the index is traced: one program for every layer
+        programs = {kind: program(kind) for kind in dict.fromkeys(kinds[:n_layers])}
         x = jnp.asarray(hidden, jnp.float32)
         margin = jnp.full(x.shape[0], jnp.inf)
         checks = []
-        for index in range(sum(span["num_blocks"] for span in config["servers"])):
-            x, layer_margin, check = layer(jnp.uint32(index), x)
+        for index in range(n_layers):
+            x, layer_margin, check = programs[kinds[index]](jnp.uint32(index), x)
             margin = jnp.minimum(margin, layer_margin)
             checks.append(int(check))
         return np.asarray(x, np.float32), np.asarray(margin, np.float32), checks

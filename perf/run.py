@@ -364,6 +364,7 @@ def run_cell(benchmark: dict, workload: str, seed: int, seconds: float, trace: b
         "cache_events": [d["cache_events"] for d in dumps],
         "recompiled": load_reader("layer_metrics", "recompiles_in_window").programs(record),
     }
+    result["compared"] = correct.compared(verdict)  # after "detail", which main() takes out: the last key of the line
     return result
 
 
@@ -380,6 +381,8 @@ def main(argv=None) -> int:
     benchmark = json.loads((ROOT / "BENCHMARK.json").read_text())
     result = run_cell(benchmark, args.workload, args.seed, args.seconds, bool(args.trace))
     log(f"detail: {json.dumps(result.pop('detail'))}")  # for a reader of the logs; the line below has the contract's keys only
+    for name, (number, limit) in result["compared"].items():
+        print(f"compared {name} {number} limit {limit}", file=sys.stderr, flush=True)  # the last lines of standard error
     print(json.dumps(result), flush=True)
     return 0
 

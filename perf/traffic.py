@@ -23,11 +23,19 @@ One general generator. A mix is data; its parameters:
 ``ramp_s``    sessions due this long before the window fill the pool and are
               not counted.
 ``base``      the name of another mix whose keys this one overrides.
+``order_seed`` (optional) the orders below are drawn from this number and not
+              from the run's seed: every seed then runs the same sessions at
+              the same times, on other input rows. For a mix in which the
+              order decides the result (see "Steadiness").
 
 Steadiness: a distribution is not sampled. For n draws it gives the n
 quantiles (i + 0.5) / n of the distribution, in an order drawn from the seed,
 so every seed runs the same set of sizes and gaps in another order, and the
-seed does not change the amount of work.
+seed does not change the amount of work. It can still change the result: at
+a rate where a few sessions are live at once, the order decides how many
+decode side by side, and a token's gap grows with each (PERF.md section 6,
+PR 30: two runs of one seed 0.1-1% apart, three seeds 2-4%). ``order_seed``
+fixes the order for such a mix.
 
 Inputs are rows of one pool of standard normals drawn from the seed
 (``input_pool``); a session names its rows by offset, so a prompt is a
@@ -177,7 +185,7 @@ class _Tree:
 
 def schedule(mix: dict, seed: int, seconds: float) -> Schedule:
     """The whole run's sessions. Pure: same mix, seed and length, same schedule."""
-    rng = np.random.default_rng([int(seed), 2])
+    rng = np.random.default_rng([int(mix.get("order_seed", seed)), 2])
     arrival = mix["arrival"]
     ramp_s = float(mix.get("ramp_s", 0.0))
     tree = _Tree(mix.get("prefix", {"kind": "none"}))

@@ -17,6 +17,14 @@ One file per family (``perf/weights/<family>.py``, found by name) gives
     takes: a mirror, in ``jax.numpy``, of the family's ``hf_to_block_params``
     in ``petals_tpu/models/`` (which works in numpy on the host). tests/perf
     holds the two together at a toy size.
+
+A family whose layers are not all alike (``layer_kinds`` in
+``perf/reference/<family>.py``) is given each layer's kind as a further
+argument of both, and also gives
+
+``span_tree(hf, runs)``  what ``Server._load_span_params`` returns for a span
+    of more than one kind, from ``runs``: ``[(first_block, stacked tree), ...]``,
+    one entry per run of consecutive blocks of one kind.
 """
 
 from __future__ import annotations
@@ -89,19 +97,29 @@ def checksum(tensors: dict):
 
 def span_params(config: dict, first_block: int, num_blocks: int, dtype) -> tuple:
     """The span's parameters as ``Server._load_span_params`` returns them
-    (each leaf stacked over the blocks), made on the default device in one
-    jitted call, and the checksum of the first block's HF tensors."""
+    (each leaf stacked over the blocks; for a span of more than one kind of
+    layer, what the family's ``span_tree`` makes of its runs), made on the
+    default device in one jitted call, and the checksum of the first block's
+    HF tensors."""
     import jax
     import jax.numpy as jnp
 
+    from perf.reference import kinds_of
+
     family, hf = family_of(config["family"]), config["config"]
+    kinds = kinds_of(config["family"], hf) or [()] * (first_block + num_blocks)
+    starts = [i for i in range(first_block, first_block + num_blocks) if i == first_block or kinds[i] != kinds[i - 1]]
 
     def make():
         draws = Draws(config["weights_seed"])
-        layers = [family.layer_tensors(hf, first_block + i, draws) for i in range(num_blocks)]
-        blocks = [family.block_params(hf, t) for t in layers]
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs).astype(dtype), *blocks)
-        return stacked, checksum(layers[0])
+        stacked = []
+        for start, end in zip(starts, [*starts[1:], first_block + num_blocks]):
+            layers = [family.layer_tensors(hf, i, draws, *kinds[start]) for i in range(start, end)]
+            blocks = [family.block_params(hf, t, *kinds[start]) for t in layers]
+            stacked.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs).astype(dtype), *blocks))
+            if start == first_block:
+                first = checksum(layers[0])
+        return stacked, first
 
     stacked, first = jax.jit(make)()
-    return stacked, int(first)
+    return stacked[0] if len(starts) == 1 else family.span_tree(hf, list(zip(starts, stacked))), int(first)

@@ -90,7 +90,11 @@ def test_the_family_states_its_costs_and_limits():
     assert costs.layer_param_count("olmoe", hf) == 419_561_472  # 402.7 M of them in the experts
     assert costs.kv_bytes_per_token_layer("olmoe", hf) == 8192
     cost = costs.step_cost("olmoe", hf, 8, decode_tokens=8, prefill_tokens=0, context_tokens=0)
-    assert cost["bytes"] == 8 * (419_561_472 * 2 + 8192 * 8 + 2 * 2048 * 2 * 8)  # every expert read: 6.71 GB
+    # eight tokens of top 8 of 64 reach 64 x (1 - (7/8)^8) = 42.009 experts (PR 30; every expert until then: 6.71 GB)
+    reached = 64 * (1 - 5764801 / 16777216)
+    assert costs.experts_reached(p, 8) == reached and 42.009 < reached < 42.0091
+    assert cost["bytes"] == 8 * ((4 * 2048**2 + 2048 * 64 + reached * 3 * 2048 * 1024) * 2 + 8192 * 8 + 2 * 2048 * 2 * 8)
+    assert 4.50e9 < cost["bytes"] < 4.51e9  # 4.50 GB a step where every expert made 6.71
     assert cost["flops"] == 8 * 2 * (4 * 2048**2 + 2048 * 64 + 8 * 3 * 2048 * 1024) * 8  # eight experts a token computed
     limits = reference.limits(config)
     assert limits["tie_margin"] == 0 and limits["positions_allowed"] == 0
@@ -120,7 +124,5 @@ def test_expert_readers_on_a_hand_made_record():
     for children in ([_child(dense_family, dense_family)], [{"marks": {}}], [{}], []):
         assert share.read(_record(children)) is None and passes.read(_record(children)) is None
     assert share.read(_record([_child(start, start)])) is None and passes.read(_record([_child(start, start)])) is None
-    # not among BENCHMARK.json's per_layer entries yet: tests/perf/test_step_phase_metrics.py holds the list's
-    # last seven to PR 24's, so nothing can be appended until a benchmark PR selects those by name (PERF.md section 7)
     assert (share.UNIT, passes.UNIT) == ("%", "passes/step") and share.MOVES == passes.MOVES == "gap_p50_ms"
     assert share.LAYER == passes.LAYER == "expert dispatch (models/moe.py)"

@@ -61,6 +61,17 @@ def test_same_seed_same_schedule_another_seed_another(name):
     assert sorted(y for _, y in sizes(a)) == sorted(y for _, y in sizes(c))
 
 
+def test_a_mix_may_fix_its_order_and_the_inputs_still_follow_the_seed():
+    """``order_seed``: every seed runs the same sessions at the same times
+    (where the order decides the result, PERF.md section 6, PR 30); the rows
+    they send are still the seed's."""
+    fixed = {**MIXES["open_rate"], "order_seed": 77}
+    a, b = traffic.schedule(fixed, 2**31 + 7, 30.0), traffic.schedule(fixed, 11, 30.0)
+    assert a == b == traffic.schedule(MIXES["open_rate"], 77, 30.0)  # the order that seed would have drawn
+    assert a != traffic.schedule({**fixed, "order_seed": 78}, 11, 30.0)
+    assert traffic.input_pool(2**31 + 7, 16).tobytes() != traffic.input_pool(11, 16).tobytes()
+
+
 def test_inputs_follow_the_seed():
     a, b, c = (traffic.input_pool(seed, 64) for seed in (5, 5, 6))
     assert a.tobytes() == b.tobytes() and a.tobytes() != c.tobytes()
@@ -225,11 +236,19 @@ def test_costs_equal_hand_worked_numbers():
     # a 512-token chunk riding along makes the step compute-bound
     assert costs.least_seconds(costs.step_cost("falcon", falcon, 8, decode_tokens=8, prefill_tokens=512,
                                                context_tokens=2400, prefill_context=256), peaks)[1] == "compute"
-    # Mixtral reads all eight experts and computes two a token
+    # Mixtral computes two experts a token and reads the experts its tokens reach: four tokens of top 2 of 8
+    # reach 8 x (1 - (3/4)^4) = 8 x 175/256 = 5.46875 of them
     cost = costs.step_cost("mixtral", mixtral, 4, decode_tokens=4, prefill_tokens=0, context_tokens=0)
-    active = 2 * 4096**2 + 2 * 4096 * 1024 + 4096 * 8 + 2 * 3 * 4096 * 14336
-    assert cost["flops"] == 4 * 2 * active * 4
-    assert cost["bytes"] == 4 * (1_451_261_952 * 2 + 4096 * 4 + 2 * 4096 * 2 * 4)
+    shared, expert = 2 * 4096**2 + 2 * 4096 * 1024 + 4096 * 8, 3 * 4096 * 14336
+    assert cost["flops"] == 4 * 2 * (shared + 2 * expert) * 4
+    assert cost["bytes"] == 4 * ((shared + 5.46875 * expert) * 2 + 4096 * 4 + 2 * 4096 * 2 * 4) == 4 * (2_010_710_016 + 16384 + 65536)
+    # one lane reaches its two experts; 1.8 lanes (mixtral8x7b-chat's decode steps) 8 x (1 - 0.75^1.8) = 3.2335;
+    # a chunk of 128 riding along reaches all eight, as the rule before PR 30 had it for every step
+    p = costs.layer_params("mixtral", mixtral)
+    assert costs.experts_reached(p, 1) == 2 and costs.experts_reached(p, 1.8) == pytest.approx(3.2335, abs=1e-4)
+    assert costs.experts_reached(p, 130) == pytest.approx(8, rel=1e-12) and costs.experts_reached(costs.layer_params("falcon", falcon), 8) == 0
+    mixed = costs.step_cost("mixtral", mixtral, 2, decode_tokens=2, prefill_tokens=128, context_tokens=0)
+    assert mixed["bytes"] == pytest.approx(2 * (1_451_261_952 * 2 + 4096 * 130 + 2 * 4096 * 2 * 130), rel=1e-12)
     with pytest.raises(KeyError, match="not in peaks.json"):
         costs.peaks_for("TPU v9 imaginary")
 
@@ -252,6 +271,162 @@ def test_a_family_s_costs_are_found_by_name(tmp_path, monkeypatch):
     assert cost == {"flops": 3 * (2 * 100 * 2 + 4 * 2 * 4 * 10), "bytes": 3 * (100 * 2 + 16 * (10 + 2) + 2 * 8 * 2 * 2)}
     with pytest.raises(ModuleNotFoundError):
         costs.layer_params("nofam", hf)
+
+
+TWO_KINDS_REFERENCE = """
+import jax.numpy as jnp
+
+ROW_BOUND_PER_LAYER, MEDIAN_BOUND_PER_LAYER = 1e-2, 5e-3
+TRACED = []  # block() runs when a program is traced: once a compile
+
+
+def layer_kinds(hf):
+    return hf["kinds"]
+
+
+def layer_params(hf, kind):
+    shape = {"hidden": hf["n_embed"], "q_heads": 2, "kv_heads": 1, "head_dim": 4, "attn": 40}
+    if kind == "mlp":
+        return {**shape, "dense": 60, "expert": 0, "experts": 0, "top_k": 0}
+    routed = {**shape, "dense": 10, "expert": 6, "experts": 16, "experts_routed": 128, "top_k": 8}
+    return {**routed, "window": 4} if kind == "experts-windowed" else routed
+
+
+def block(hf, w, x, kind):
+    TRACED.append(kind)
+    if kind == "mlp":
+        return x + x @ w["w"], jnp.full(x.shape[0], jnp.inf)
+    return x + jnp.tanh(x @ w["a"]) @ w["b"], jnp.full(x.shape[0], 0.5)
+"""
+TWO_KINDS_WEIGHTS = """
+SPAN_TREES = []
+
+
+def layer_tensors(hf, layer, draws, kind):
+    n = hf["n_embed"]
+    if kind == "mlp":
+        return {"w": draws.normal((n, n), layer, 0)}
+    return {"a": draws.normal((n, 4), layer, 1), "b": draws.normal((4, n), layer, 2)}
+
+
+def block_params(hf, t, kind):
+    return {name: tensor.T for name, tensor in t.items()}
+
+
+def span_tree(hf, runs):
+    SPAN_TREES.append([first for first, _ in runs])
+    return {"runs": runs}
+"""
+
+
+@pytest.fixture()
+def two_kinds(tmp_path, monkeypatch):
+    """A family of three kinds of layer written into ``tmp_path`` and found by
+    name: a dense MLP, an expert layer that attends over a window, one that
+    attends over everything; 16 of 128 routed experts held. No file of perf/
+    knows it."""
+    import importlib
+
+    import perf.reference
+
+    for package, text in ((perf.reference, TWO_KINDS_REFERENCE), (weights, TWO_KINDS_WEIGHTS)):
+        (tmp_path / package.__name__).mkdir()
+        (tmp_path / package.__name__ / "twokinds.py").write_text(text)
+        monkeypatch.setattr(package, "__path__", list(package.__path__) + [str(tmp_path / package.__name__)])
+    yield {"family": "twokinds", "weights_seed": 7, "servers": [{"first_block": 0, "num_blocks": 4}],
+           "config": {"n_embed": 8, "kinds": ["mlp", "experts-windowed", "experts", "mlp", "experts"]}}
+    for name in ("perf.reference.twokinds", "perf.weights.twokinds"):
+        sys.modules.pop(name, None)
+    importlib.invalidate_caches()
+
+
+def test_a_family_of_more_than_one_kind_runs_through_the_reference(two_kinds):
+    """``reference.run`` compiles one program a kind, in the order the layers
+    meet them, and gives each layer its own kind's weights and block."""
+    import jax
+    import jax.numpy as jnp
+
+    from perf import reference
+
+    family, maker = reference.family_of("twokinds"), weights.family_of("twokinds")
+    hf = two_kinds["config"]
+    x = np.random.default_rng(1).standard_normal((6, 8), dtype=np.float32)
+    got, margin, checks = reference.run(two_kinds, x)
+    assert family.TRACED == ["mlp", "experts-windowed", "experts"]  # four layers, three kinds, three compiles
+    want = jnp.asarray(x)
+    with jax.default_matmul_precision("highest"):
+        for layer, kind in enumerate(hf["kinds"][:4]):
+            w = {k: v.astype(jnp.float32) for k, v in maker.layer_tensors(hf, layer, weights.Draws(7), kind).items()}
+            want, _ = family.block(hf, w, want, kind)
+    assert np.allclose(got, np.asarray(want), rtol=1e-6, atol=1e-6) and not np.allclose(got, x)
+    assert (margin == 0.5).all() and len(set(checks)) == 4
+    assert reference.limits(two_kinds)["row_bound"] == pytest.approx(4e-2)  # layers counted as for any family
+
+
+def test_a_span_is_stacked_by_runs_of_one_kind(two_kinds):
+    """``span_params``: each run of consecutive blocks of one kind stacked, the
+    runs handed to the family's ``span_tree``; a span of one kind is the one
+    stacked tree, as it is for the families whose layers are all alike."""
+    import jax
+    import jax.numpy as jnp
+
+    from perf import reference
+
+    maker, hf = weights.family_of("twokinds"), two_kinds["config"]
+    checks = reference.run({**two_kinds, "servers": [{"first_block": 0, "num_blocks": 5}]}, np.zeros((2, 8), np.float32))[2]
+    tree, first = weights.span_params(two_kinds, 0, 5, jnp.float32)
+    assert maker.SPAN_TREES == [[0, 1, 2, 3, 4]] and first == checks[0]
+    assert [(start, {k: v.shape for k, v in run.items()}) for start, run in tree["runs"]][:2] == [
+        (0, {"w": (1, 8, 8)}), (1, {"a": (1, 4, 8), "b": (1, 8, 4)})]
+    tree, first = weights.span_params({**two_kinds, "config": {**hf, "kinds": ["mlp", "experts", "experts", "mlp", "mlp"]}}, 1, 4, jnp.bfloat16)
+    assert maker.SPAN_TREES[-1] == [1, 3] and [run["a" if start == 1 else "w"].shape[0] for start, run in tree["runs"]] == [2, 2]
+    assert tree["runs"][0][1]["a"].dtype == jnp.bfloat16
+    want = maker.block_params(hf, maker.layer_tensors(hf, 2, weights.Draws(7), "experts"), "experts")
+    assert np.array_equal(np.asarray(tree["runs"][0][1]["b"][1], np.float32), np.asarray(want["b"], np.float32))
+    one_kind, first = weights.span_params(two_kinds, 1, 1, jnp.float32)  # a span of one kind: no span_tree
+    assert len(maker.SPAN_TREES) == 2 and set(one_kind) == {"a", "b"} and first == checks[1]
+    # the families whose layers are all alike get the bits they got before PR 30: its span_params, inline
+    for config_file in ("falcon-tiny.json", "mixtral-tiny-chain.json"):
+        config = load_config(DATA / config_file, "tiny")
+        family = weights.family_of(config["family"])
+
+        def make():
+            draws = weights.Draws(config["weights_seed"])
+            layers = [family.layer_tensors(config["config"], 1 + i, draws) for i in range(2)]
+            blocks = [family.block_params(config["config"], t) for t in layers]
+            return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs).astype(jnp.bfloat16), *blocks), weights.checksum(layers[0])
+
+        before, check = jax.jit(make)()
+        after, first = weights.span_params(config, 1, 2, jnp.bfloat16)
+        assert first == int(check) and set(after) == set(before)
+        assert all(after[k].dtype == before[k].dtype and np.array_equal(np.asarray(after[k], np.float32), np.asarray(before[k], np.float32)) for k in before)
+
+
+def test_a_span_of_more_than_one_kind_is_costed_layer_by_layer(two_kinds):
+    """One dense layer and two expert layers, one of them windowed, 16 of 128
+    experts held, top 8; 8 lanes at 10 cached positions each. Tokens, bytes
+    (bf16) and FLOPs by hand:
+    experts reached by 8 tokens: 16 x (1 - (15/16)^8) = 1732076671 / 2^28 = 6.4524; computed a token: 8 x 16/128 = 1
+    mlp:              weights (40 + 60) x 2 = 200, cache 16 x (80 + 8) = 1408, activations 2 x 8 x 2 x 8 = 256;
+                      FLOPs 2 x 100 x 8 + 4 x 2 x 4 x 80 = 4160
+    experts-windowed: weights (40 + 10 + 6 r) x 2, cache 16 x (8 x 4 + 8) = 640, activations 256;
+                      FLOPs 2 x (40 + 10 + 6) x 8 + 4 x 2 x 4 x 32 = 1920
+    experts:          weights the same, cache 1408, activations 256; FLOPs 896 + 2560 = 3456"""
+    hf = two_kinds["config"]
+    r = 1732076671 / 2**28
+    assert costs.experts_reached(costs.layer_params("twokinds", hf, 1), 8) == r == 16 * (1 - (15 / 16) ** 8)
+    assert [costs.layer_params("twokinds", hf, i).get("window") for i in range(5)] == [None, 4, None, None, None]
+    assert costs.layer_param_count("twokinds", hf, 0) == 100 and costs.layer_param_count("twokinds", hf, 2) == 50 + 6 * 16
+    cost = costs.step_cost("twokinds", hf, 3, decode_tokens=8, prefill_tokens=0, context_tokens=80)
+    assert cost["bytes"] == (200 + 1408 + 256) + (100 + 12 * r + 640 + 256) + (100 + 12 * r + 1408 + 256) == 4624 + 24 * r
+    assert cost["flops"] == 4160 + 1920 + 3456
+    tail = costs.step_cost("twokinds", hf, 2, first_block=3, decode_tokens=8, prefill_tokens=0, context_tokens=80)  # mlp, experts
+    assert tail == {"bytes": 1864 + 1764 + 12 * r, "flops": 4160 + 3456}
+    # lanes under the window read what they hold; a chunk's positions attend over the lesser of their context and the window
+    short = costs.step_cost("twokinds", hf, 1, first_block=1, decode_tokens=8, prefill_tokens=0, context_tokens=24)
+    assert short["flops"] == 896 + 4 * 2 * 4 * 24 and short["bytes"] == 100 + 12 * r + 16 * (24 + 8) + 256
+    chunk = costs.step_cost("twokinds", hf, 1, first_block=1, decode_tokens=0, prefill_tokens=8, context_tokens=0, prefill_context=6)
+    assert chunk["flops"] == 896 + 4 * 2 * 4 * 8 * 4 and chunk["bytes"] == 100 + 12 * r + 16 * 8 + 256
 
 
 def _tiny(config_file, tmp_path):
@@ -353,19 +528,62 @@ def test_correct_holds_decode_rows_and_prefill_rows_to_the_family_s_limits(case,
         assert verdict["prefill"]["ok"] is all(kind == "decode" for kind, _ in kw["wrong"])
     if limits["tie_margin"] > 0:
         assert verdict["decode"]["compared"] == 32 - len(kw.get("near_tied", ()))
+    numbers = correct.compared({**verdict, "repeat_identical": True})  # what the result line and stderr show beside each limit
+    assert numbers["decode_median"] == [verdict["decode"]["median"], limits["median_bound"]] and numbers["repeat_identical"] == [1, 1]
+    assert numbers["decode_outside"] == [len(verdict["decode"]["positions_outside"]), limits["positions_allowed"]]
+    assert numbers["prefill_max"][1] == limits["row_bound"] and numbers["decode_compared_share"] == [verdict["decode"]["compared"] / 32, 0.25]
+
+
+def _states_its_limits(config: dict) -> None:
+    """What every configuration's family is held to, whatever its name."""
+    from perf import reference
+
+    limits = reference.limits(config)
+    depth = sum(span["num_blocks"] for span in config["servers"])
+    routed = any(costs.layer_params(config["family"], config["config"], layer)["experts"] > 0 for layer in range(depth))
+    assert 0 < limits["median_bound"] <= limits["row_bound"] <= 0.1 * depth
+    assert limits["row_bound"] < 0.3  # a wrong kernel lands at 0.3..1
+    # a margin leaves rows out, so it is stated with the positions it may still cost, and only by a family
+    # that decides something: a layer with routed experts (correct.judge fails a run that leaves out over
+    # three quarters of its rows of a kind, so a margin cannot hide a family)
+    assert (limits["tie_margin"] > 0) == (limits["positions_allowed"] > 0)
+    assert routed or limits["tie_margin"] == 0
+    assert limits["positions_allowed"] <= 2
 
 
 def test_each_family_states_its_limits():
-    from perf import reference
-
     for entry in BENCHMARK["configs"]:
-        config = load_config(ROOT / entry["file"], entry["name"])
-        limits = reference.limits(config)
-        depth = sum(span["num_blocks"] for span in config["servers"])
-        assert 0 < limits["median_bound"] <= limits["row_bound"] <= 0.1 * depth
-        assert limits["row_bound"] < 0.3  # a wrong kernel lands at 0.3..1
-        assert (limits["tie_margin"] > 0) == (limits["positions_allowed"] > 0) == (config["family"] == "mixtral")
-        assert limits["positions_allowed"] <= 2
+        _states_its_limits(load_config(ROOT / entry["file"], entry["name"]))
+
+
+@pytest.mark.parametrize("case,experts,stated,ok", [
+    ("a routed family of any name may state a tie margin", 4, "TIE_MARGIN, POSITIONS_ALLOWED_OUTSIDE = 0.04, 1", True),
+    ("or none, where a flipped expert moves no row", 4, "", True),
+    ("a dense family may not", 0, "TIE_MARGIN, POSITIONS_ALLOWED_OUTSIDE = 0.04, 1", False),
+    ("a margin without the positions it may cost", 4, "TIE_MARGIN = 0.04", False),
+    ("positions outside without a margin", 4, "POSITIONS_ALLOWED_OUTSIDE = 1", False),
+    ("more than two positions", 4, "TIE_MARGIN, POSITIONS_ALLOWED_OUTSIDE = 0.04, 3", False),
+])
+def test_a_family_s_limits_go_by_what_it_is_not_by_its_name(case, experts, stated, ok, tmp_path, monkeypatch):
+    import perf.reference
+
+    (tmp_path / "anyname.py").write_text(
+        f"ROW_BOUND_PER_LAYER, MEDIAN_BOUND_PER_LAYER = 2e-2, 1e-2\n{stated}\n"
+        "def layer_params(hf):\n"
+        f"    return {{'attn': 40, 'dense': 60, 'expert': 6, 'experts': {experts}, 'top_k': 2,\n"
+        "            'hidden': 8, 'q_heads': 2, 'kv_heads': 1, 'head_dim': 4}\n"
+    )
+    monkeypatch.setattr(perf.reference, "__path__", list(perf.reference.__path__) + [str(tmp_path)])
+    monkeypatch.delitem(sys.modules, "perf.reference.anyname", raising=False)
+    config = {"family": "anyname", "config": {}, "servers": [{"first_block": 0, "num_blocks": 2}]}
+    try:
+        if ok:
+            _states_its_limits(config)
+        else:
+            with pytest.raises(AssertionError):
+                _states_its_limits(config)
+    finally:
+        sys.modules.pop("perf.reference.anyname", None)
 
 
 class _CausalRemote:
@@ -509,6 +727,9 @@ def test_tiny_cell_end_to_end(workload, trace, tmp_path):
     assert detail["check"]["repeat_identical"] and detail["recompiled"] == []
     assert detail["check"]["decode"]["ok"] and detail["check"]["decode"]["compared"] >= 9
     assert detail["together_decode_batch_mean"] >= 1.0
+    numbers = result["compared"]  # each number the check compared, beside its limit
+    assert numbers["repeat_identical"] == [1, 1] and numbers["finite"] == [1, 1] and numbers["decode_outside"][0] == 0
+    assert all(0 < numbers[f"{kind}_{what}"][0] < numbers[f"{kind}_{what}"][1] for kind in ("prefill", "decode") for what in ("median", "max"))
     if trace:
         required = {"ttft_p90_ms", "gap_p95_ms", "gen_late_ms_p95", "hop_network_ms", "hop_queue_ms", "decode_batch_mean",
                     "step_compute_ms_p50", "open_route_ms_p50", "recompiles_in_window", "decode_tok_per_s"}

@@ -87,13 +87,16 @@ def test_reader_gives_none_where_there_is_nothing_to_read(name):
     assert got == {"host_serial_share": 0.0, "no_work_share": 100.0}.get(name)
 
 
+def the_seven() -> list:
+    """``BENCHMARK.json``'s entries for the seven, found by name: later PRs append to ``per_layer``."""
+    entries = {m["name"]: m for m in BENCHMARK["per_layer"]}
+    return [entries[name] for name in READERS]
+
+
 def test_benchmark_json_names_the_seven_after_what_it_had():
-    entries = BENCHMARK["per_layer"]
-    assert [m["name"] for m in entries[-7:]] == [
-        "step_assemble_ms", "step_dispatch_ms", "step_wait_ms", "step_post_ms", "step_turnaround_ms",
-        "host_serial_share", "no_work_share"]
-    layers = {m["layer"] for m in entries[:-7]}  # the accepted benchmark's own spellings
-    for m in entries[-7:]:
+    assert len(the_seven()) == len(READERS) == 7
+    layers = {m["layer"] for m in BENCHMARK["per_layer"] if m["name"] not in READERS}  # the other entries' own spellings
+    for m in the_seven():
         unit, better, layer = READERS[m["name"]]
         reader = load_reader("layer_metrics", m["name"])
         assert m == {"name": m["name"], "unit": unit, "better": better, "source": "program_counter",
@@ -106,13 +109,26 @@ def test_a_traced_tiny_cell_prints_all_seven(tmp_path):
     """The whole command at a toy size on the CPU, traced, with the seven
     entries beside the toy benchmark's own: the server child's marks carry
     the counters, every reader finds them, and the identities hold on a real
-    run. The numbers mean nothing and go nowhere."""
+    run. The numbers mean nothing and go nowhere.
+
+    This was the one test that failed in the driver's run at PR 29 (six
+    workers) and it passes alone; the failure did not come again here under
+    ten busy loops, six copies at once or a whole run, so which assertion
+    gave way is inferred, not seen. Two can, on a machine whose other workers
+    take the cores: a 5 s window's traced slice is 1.7 s, and one stalled step
+    (a chunk shape compiling inside ``dispatch``) leaves it without a finished
+    step, so the per-step readers find nothing; and a toy server at 3 sessions
+    a second is then busy all the time, where a step that straddles a mark
+    makes ``no_work_share`` read under 0. So the window is 9 s (the slice the
+    3 s a cell's is) and the mix is ``tiny-open`` at half its rate; every
+    assertion is as it was."""
     from perf import run
 
     data = Path(__file__).resolve().parent / "data"
     bench = json.loads((data / "benchmark-tiny.json").read_text())
-    bench["per_layer"] += BENCHMARK["per_layer"][-7:]
-    result = run.run_cell(bench, "tiny-open", 2**31 + 9, 5.0, True, traffic_dir=data / "traffic",
+    bench["per_layer"] += the_seven()
+    bench["workloads"].append({**bench["workloads"][0], "name": "tiny-open-light", "traffic": "tiny-open-light"})
+    result = run.run_cell(bench, "tiny-open-light", 2**31 + 5, 9.0, True, traffic_dir=data / "traffic",
                           work_dir=tmp_path, allow_cpu=True)
     got = {k: v["value"] for k, v in result["metrics"].items()}
     assert result["correct"] is True and set(READERS) <= set(got), sorted(got)
