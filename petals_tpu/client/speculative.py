@@ -123,8 +123,8 @@ def make_local_draft_fn(model_path: str, *, dtype=None) -> DraftFn:
         out = []
         for _ in range(k):
             hidden = family.client_embed(client_params, ids, cfg)
-            for p in blocks:
-                hidden, _ = family.block_apply(p, hidden, None, 0, cfg)
+            for i, p in enumerate(blocks):  # each block by its kind, for a family whose blocks are not all alike
+                hidden, _ = family.apply_for(family.kind_of(cfg, i))(p, hidden, None, 0, cfg)
             logits = family.client_head(client_params, hidden[:, -1:], cfg)
             nxt = int(np.asarray(logits)[0, -1].argmax())
             out.append(nxt)

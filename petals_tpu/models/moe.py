@@ -1,8 +1,19 @@
 """Mixture-of-experts feed-forward shared by the families that route
-(mixtral, olmoe): a softmax router over all experts, the top k kept (and
-renormalised or not, as the family publishes), SwiGLU experts stacked
-``w1``/``w3`` [E, h, m] and ``w2`` [E, m, h]. All experts live on the hosting
-server (no cross-server expert parallelism, matching the reference).
+(mixtral, olmoe, exaone_moe): a router over the published number of experts,
+the top k kept, SwiGLU experts stacked ``w1``/``w3`` [E, h, m] and ``w2``
+[E, m, h]. The routing rule is data (``Routing``): a softmax whose kept
+weights are renormalised or not, or a sigmoid whose choice a bias moves and
+whose kept weights are renormalised and scaled.
+
+A server may hold a SHARE of a layer's experts: ``w1`` stacks the ``E`` it
+holds, the router (``gate`` [h, routed]) is as wide as the model publishes,
+and ``first`` says which of the routed the first held one is. The layer then
+returns, a token, the part of the result its held experts give: an
+assignment to an absent expert is dropped, in both dispatches alike (the
+chips that hold the rest add theirs; that exchange is not this module's). A
+shared expert (``ws1``/``ws3`` [h, m], ``ws2`` [m, h]) runs for every token
+and is added whole. With every expert held nothing is dropped and the bits
+are what they were before a share could be told.
 
 Two dispatches share the routing:
 
@@ -26,13 +37,13 @@ Two dispatches share the routing:
 
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from petals_tpu.models.common import silu
+from petals_tpu.models.common import mm, silu
 from petals_tpu.parallel.tp import COL
 
 # The expert part of what Mixtral and OLMoE declare on their ModelFamily
@@ -49,19 +60,59 @@ EXPERT_LEAVES = frozenset({"w1", "w2", "w3"})
 class MoeDims(NamedTuple):
     """The static shapes of a block's expert layer (a family's ``moe_dims(cfg)``)."""
 
-    experts: int
+    experts: int  # held on this server
     top_k: int
     hidden: int
     expert_width: int
+    routed: Optional[int] = None  # the router's width; None: every expert is held
+    first: int = 0  # which of the routed experts the first held one is
 
 
-def _experts_grouped(x, w1, w2, w3, top_idx, top_probs) -> jnp.ndarray:
-    """Grouped-matmul dispatch: FLOPs proportional to N * top_k."""
+class Routing(NamedTuple):
+    """How a router's logits become the kept experts and their weights."""
+
+    top_k: int
+    scoring: str = "softmax"  # or "sigmoid": chosen by score + ``gate_bias``, weighed by score
+    renormalize: bool = False  # kept weights divided by their sum
+    scale: float = 1.0  # and multiplied by this (a sigmoid router's ``routed_scaling_factor``)
+
+
+def route(params: dict, x: jnp.ndarray, routing: Routing):
+    """``(top_idx, top_weights)`` [b, s, k]: indices among the ROUTED experts."""
+    if routing.scoring == "softmax":
+        router_logits = x @ params["gate"]  # [b, s, routed]
+        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+        top_probs, top_idx = jax.lax.top_k(probs, routing.top_k)  # [b, s, k]
+        if routing.renormalize:
+            top_probs = top_probs / top_probs.sum(axis=-1, keepdims=True)
+        return top_idx, top_probs
+    if routing.scoring != "sigmoid":
+        raise ValueError(f"unknown routing rule {routing.scoring!r}")
+    # the published router runs in float32 (HF DeepseekV3TopkRouter)
+    logits = jnp.matmul(
+        x.astype(jnp.float32), params["gate"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, top_idx = jax.lax.top_k(scores + params["gate_bias"].astype(jnp.float32), routing.top_k)
+    top_scores = jnp.take_along_axis(scores, top_idx, axis=-1)  # the bias chooses, it does not weigh
+    if routing.renormalize:
+        top_scores = top_scores / (top_scores.sum(axis=-1, keepdims=True) + 1e-20)
+    return top_idx, top_scores * routing.scale
+
+
+def _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share: bool = False) -> jnp.ndarray:
+    """Grouped-matmul dispatch: FLOPs proportional to N * top_k. ``top_idx``
+    counts among the held experts; under a ``share`` an index outside them is
+    an assignment to an absent expert: sorted behind every group, in none of
+    them, and its rows weigh nothing."""
     b, s, h = x.shape
     E, k = w1.shape[0], top_idx.shape[-1]
     n_assign = b * s * k
     xf = x.reshape(b * s, h)
     flat_experts = top_idx.reshape(n_assign)
+    if share:
+        held = (flat_experts >= 0) & (flat_experts < E)
+        flat_experts = jnp.where(held, flat_experts, E)
     order = jnp.argsort(flat_experts, stable=True)  # group assignments by expert
     token_of = order // k
     xg = jnp.take(xf, token_of, axis=0)  # [N*k, h]
@@ -70,32 +121,39 @@ def _experts_grouped(x, w1, w2, w3, top_idx, top_probs) -> jnp.ndarray:
     g3 = jax.lax.ragged_dot(xg, w3, group_sizes)
     out = jax.lax.ragged_dot(silu(g1) * g3, w2, group_sizes)  # [N*k, h]
     wts = jnp.take(top_probs.reshape(n_assign), order).astype(jnp.float32)
+    contribution = out.astype(jnp.float32) * wts[:, None]
+    if share:  # rows past the last group are whatever ragged_dot left there
+        contribution = jnp.where(jnp.take(held, order)[:, None], contribution, 0.0)
     y = jnp.zeros((b * s, h), jnp.float32)
-    y = y.at[token_of].add(out.astype(jnp.float32) * wts[:, None])
+    y = y.at[token_of].add(contribution)
     return y.astype(x.dtype).reshape(b, s, h)
 
 
-def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, grouped: bool = False) -> jnp.ndarray:
-    """x: [batch, seq, hidden] -> mixture of top-k experts, HF-exact routing.
-    ``renormalize`` divides the kept weights by their sum (Mixtral's rule;
-    OLMoE's ``norm_topk_prob`` false keeps the softmax mass as it is)."""
+def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, grouped: bool = False,
+              scoring: str = "softmax", scale: float = 1.0, first: int = 0) -> jnp.ndarray:
+    """x: [batch, seq, hidden] -> what the held experts give each token of the
+    mixture of its top-k experts (HF-exact routing), plus the shared expert
+    where ``params`` has one. ``renormalize`` divides the kept weights by
+    their sum (Mixtral's rule; OLMoE's ``norm_topk_prob`` false keeps the
+    softmax mass as it is); ``scoring`` and ``scale`` are ``Routing``'s,
+    ``first`` is ``MoeDims.first``."""
     from petals_tpu.ops.quant import QuantizedLinear, quant_matmul
 
-    with jax.named_scope("ptu.moe.router"):
-        router_logits = x @ params["gate"]  # [b, s, E]
-        probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-        top_probs, top_idx = jax.lax.top_k(probs, top_k)  # [b, s, k]
-        if renormalize:
-            top_probs = top_probs / top_probs.sum(axis=-1, keepdims=True)
-
     w1, w2, w3 = params["w1"], params["w2"], params["w3"]
+    n_experts, n_routed = w1.shape[0], params["gate"].shape[-1]
+    share = n_experts != n_routed
+    with jax.named_scope("ptu.moe.router"):
+        top_idx, top_probs = route(params, x, Routing(top_k, scoring, renormalize, scale))
+        if share:
+            top_idx = top_idx - first  # among the held; outside [0, n_experts): absent
+
     if grouped and not isinstance(w1, QuantizedLinear):
         with jax.named_scope("ptu.moe.experts.grouped"):
-            return _experts_grouped(x, w1, w2, w3, top_idx, top_probs)
+            y = _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share)
+        return _add_shared(params, x, y)
 
-    n_experts = params["gate"].shape[-1]
     with jax.named_scope("ptu.moe.experts.dense"):
-        # combine weights per expert: [b, s, E]
+        # combine weights per held expert: [b, s, E] (an index outside them one-hots to nothing)
         one_hot = jax.nn.one_hot(top_idx, n_experts, dtype=top_probs.dtype)
         combine = (one_hot * top_probs[..., None]).sum(axis=2).astype(x.dtype)
         if isinstance(w1, QuantizedLinear):
@@ -115,11 +173,21 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, gr
             gate_out = jnp.einsum("bsh,ehm->ebsm", x, w1)
             up = jnp.einsum("bsh,ehm->ebsm", x, w3)
             expert_out = jnp.einsum("ebsm,emh->ebsh", silu(gate_out) * up, w2)
-        return jnp.einsum("ebsh,bse->bsh", expert_out, combine)
+        y = jnp.einsum("ebsh,bse->bsh", expert_out, combine)
+    return _add_shared(params, x, y)
+
+
+def _add_shared(params: dict, x: jnp.ndarray, routed: jnp.ndarray) -> jnp.ndarray:
+    """The shared expert's SwiGLU over every token, added whole (a family
+    without one: ``routed`` as it came)."""
+    if "ws1" not in params:
+        return routed
+    with jax.named_scope("ptu.moe.shared"):
+        return routed + mm(silu(mm(x, params["ws1"])) * mm(x, params["ws3"]), params["ws2"])
 
 
 # What the rule below reckons with, measured on one TPU v5e (PERF.md section 6,
-# PR 26; benchmarks/ablate_moe_dispatch.py, one layer's experts in bf16):
+# PRs 26 and 31; benchmarks/ablate_moe_dispatch.py, one layer's experts in bf16):
 GROUPED_MIN_SEQ = 8  # a call of fewer positions a row is decode-shaped ([lanes, 1, h], a spec verify's k + 1)
 FEW_EXPERTS = 8  # up to here ragged_dot's fixed cost a group is a rounding error of a layer (Mixtral: 0.2 ms of 3.7)
 GROUP_COST_S = 25e-6  # that fixed cost, three projections: 64 groups of 12.6 MB take 2.6 ms at 64-256 tokens,
@@ -135,22 +203,41 @@ def grouped_dispatch(dims: MoeDims, seq: int) -> bool:
 
     - Under ``GROUPED_MIN_SEQ`` positions: the all-experts einsum. These are
       the decode-shaped calls, bound by the weight read either way.
-    - Few experts (Mixtral's 8): grouped from there on, as before PR 26
-      (Mixtral-8x7B, a layer: 2.3-3.4 ms against the einsum's 3.7 at 8-64
-      tokens, 7.2 against 9.0 at 512; worse at 128 and 256, 4.3 and 6.6
+    - Few experts, all held (Mixtral's 8): grouped from there on, as before
+      PR 26 (Mixtral-8x7B, a layer: 2.3-3.4 ms against the einsum's 3.7 at
+      8-64 tokens, 7.2 against 9.0 at 512; worse at 128 and 256, 4.3 and 6.6
       against 3.8 and 4.5, kept as it was: PERF.md section 7).
     - Many small experts (OLMoE's 64 of 12.6 MB): ragged_dot's fixed cost is
       paid once a group whatever the chunk, so the einsum wins (1.15 ms a
       layer against 1.6-2.8 from 32 tokens to 256) until its E / top_k-fold
       FLOPs outgrow that: the two estimates below cross at ~810 tokens
       (measured: 2.70 against 3.13 ms at 512, 5.41 against 4.19 at 1024).
-      Nothing between 8 and 64 experts has been measured."""
+    - A share of the routed experts held (K-EXAONE's 16 of 128, 75.5 MB
+      each, top 8: one of a token's eight assignments falls here): the
+      estimates, which count what the grouped dispatch costs INSIDE a step
+      program. ``ragged_dot`` cannot read a layer's experts where they lie in
+      the stacked run, so the loop first copies them out (a read and a write
+      of all that are held), and it is handed every assignment's row, those
+      of absent experts too. Alone, with its weights handed over as they are,
+      it reads only the experts the call's tokens reach (6.5 of 16 at 8
+      tokens) and wins the small calls: 0.40 ms a layer against the einsum's
+      1.65 at 8 rows of one position, 0.49 at one row of 8, 1.15 at 16; then
+      the einsum, 1.65-1.74 against 2.06, 3.32 and 3.93 at 32, 64 and 128,
+      2.02 against 4.38 at 256, 4.59 against 4.94 at 512, 7.48 against 7.38
+      at 1024. In the step the copy eats the win and more: the cell's decode
+      step took 15.7 ms grouped against 12.3 with the einsum (PERF.md section
+      6, PR 31). So a share keeps the einsum at every shape measured, its
+      decode-shaped calls like everyone's; section 7 says what would let the
+      grouped dispatch read in place."""
     if seq < GROUPED_MIN_SEQ:
         return False
-    if dims.experts <= FEW_EXPERTS:
+    share = dims.routed is not None and dims.routed > dims.experts
+    if dims.experts <= FEW_EXPERTS and not share:
         return True
     expert_params = 3 * dims.hidden * dims.expert_width
     read_s = dims.experts * 2 * expert_params / HBM_BYTES_PER_S
     dense_s = max(read_s, seq * 2 * dims.experts * expert_params / DENSE_FLOPS_PER_S)
     grouped_s = read_s + dims.experts * GROUP_COST_S + seq * 2 * dims.top_k * expert_params / GROUPED_FLOPS_PER_S
+    if share:
+        grouped_s += 2 * read_s  # the layer's held experts copied out of the stacked run
     return grouped_s < dense_s

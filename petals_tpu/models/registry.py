@@ -16,7 +16,8 @@ import in ``models/__init__.py``, and the benchmark's own data files.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Mapping, Optional
+import functools
+from typing import Any, Callable, Dict, Hashable, Mapping, Optional
 
 _FAMILIES: Dict[str, "ModelFamily"] = {}
 
@@ -64,6 +65,42 @@ class ModelFamily:
     # stateless (no-KV) path; ALiBi bias and sliding windows ride the ring
     # on global positions (ops/ring_attention.py)
     supports_ring_attention: bool = False
+    # A family whose blocks are not all alike (a dense first layer before
+    # expert layers, windowed and full attention in turns): (cfg, a block's
+    # ABSOLUTE index in the model) -> a hashable kind. Blocks of one kind
+    # share a parameter tree and a program; ``block_apply`` then takes the
+    # kind as ``kind=``, and ``block_param_shapes``, ``hf_to_block_params``
+    # and ``moe_dims`` as a further argument (``moe_dims``: None for a kind
+    # without experts). None: every block is of one kind, and the four are
+    # called without. Callers go through the ``*_for`` methods below.
+    block_kind: Optional[Callable] = None
+    # (cfg, kind) -> the STATIC window of that kind's attention in positions,
+    # None for full attention. A family that declares it has its gathered and
+    # held pages counted by the batcher (server/batching.py)
+    block_window: Optional[Callable] = None
+
+    def kind_of(self, cfg, block_index: int) -> Hashable:
+        return None if self.block_kind is None else self.block_kind(cfg, block_index)
+
+    def span_kinds(self, cfg, first_block: int, n_blocks: int) -> list:
+        return [self.kind_of(cfg, i) for i in range(first_block, first_block + n_blocks)]
+
+    def apply_for(self, kind: Hashable) -> Callable:
+        return self.block_apply if kind is None else functools.partial(self.block_apply, kind=kind)
+
+    def param_shapes_for(self, cfg, kind: Hashable, *dtype):
+        return self.block_param_shapes(cfg, *_kind_args(kind), *dtype)
+
+    def block_params_for(self, tensors: dict, cfg, kind: Hashable, **kw):
+        return self.hf_to_block_params(tensors, cfg, *_kind_args(kind), **kw)
+
+    def moe_dims_for(self, cfg, kind: Hashable):
+        return None if self.moe_dims is None else self.moe_dims(cfg, *_kind_args(kind))
+
+
+def _kind_args(kind: Hashable) -> tuple:
+    """What a family's per-block functions take after ``cfg``: the kind, for a family that has kinds."""
+    return () if kind is None else (kind,)
 
 
 def register_family(family: ModelFamily) -> ModelFamily:
@@ -77,6 +114,23 @@ def get_family(model_type: str) -> ModelFamily:
             f"Unsupported model family {model_type!r}; known: {sorted(_FAMILIES)}"
         )
     return _FAMILIES[model_type]
+
+
+def span_runs(kinds: list) -> list:
+    """``[(kind, start, length), ...]``: the runs of consecutive blocks of one
+    kind in a span's ``kinds``, ``start`` counted from the span's first block."""
+    runs = []
+    for i, kind in enumerate(kinds):
+        if runs and runs[-1][0] == kind:
+            runs[-1][2] += 1
+        else:
+            runs.append([kind, i, 1])
+    return [tuple(run) for run in runs]
+
+
+def kind_label(kind: Hashable) -> str:
+    """A kind as a word for a named scope or a log line."""
+    return "-".join(map(str, kind)) if isinstance(kind, tuple) else str(kind)
 
 
 def known_families() -> tuple:

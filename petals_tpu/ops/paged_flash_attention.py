@@ -879,8 +879,7 @@ def paged_attend_dispatch(
     express — gemma2's logit softcap and its TRACED effective window,
     non-causal — always compose from XLA, with identical math to the old
     gather/attend sandwich."""
-    from petals_tpu.ops.attention import attend_reference
-    from petals_tpu.ops.paged_attention import gather_pages, kv_quant_kind_of
+    from petals_tpu.ops.paged_attention import kv_quant_kind_of
 
     k_pool, tables = k_kv.pool, k_kv.tables
     v_pool = v_kv.pool
@@ -924,10 +923,53 @@ def paged_attend_dispatch(
             alibi_slopes=alibi_slopes, sliding_window=sliding_window,
             scale=scale,
         )
+    return composed_paged_attend(
+        q, k_pool, v_pool, tables, q_offset=pos, kv_length=kv_length,
+        alibi_slopes=alibi_slopes, sliding_window=sliding_window,
+        scale=scale, causal=causal, logit_softcap=logit_softcap,
+    )
+
+
+def window_pages(window, q_len: int, page_size: int, max_pages: int) -> int:
+    """Table slots the XLA-composed path gathers a lane for ``q_len`` query
+    rows: every slot, or under a STATIC window (and a causal mask) the few
+    pages the first row's window and the last row can reach between them
+    (3 of 16 for a decode row, a window of 128 and pages of 64). The batcher
+    counts a step's gathered pages by this."""
+    if not isinstance(window, int) or window <= 0:
+        return max_pages
+    return min((q_len - 1 + window - 1 + page_size - 1) // page_size + 1, max_pages)
+
+
+def composed_paged_attend(
+    q, k_pool, v_pool, tables, *, q_offset, kv_length, alibi_slopes=None, sliding_window=None,
+    scale=None, causal: bool = True, logit_softcap=None,
+):
+    """The XLA-composed paged attention: gather the lanes' pages into a dense
+    view and run ``attend_reference`` over it. Under a static window the view
+    holds only the pages a lane's rows can reach: its table row is cut to the
+    ``window_pages`` slots from the first row's window on, and positions are
+    counted from that slot's first one (the masks are differences of
+    positions, so the shift changes nothing; ALiBi is a difference too, but
+    its path is left as it was)."""
+    from petals_tpu.ops.attention import attend_reference
+    from petals_tpu.ops.paged_attention import gather_pages
+
+    n_lanes, max_pages = tables.shape
+    page_size = k_pool.shape[1]
+    reach = window_pages(sliding_window, q.shape[1], page_size, max_pages)
+    if reach < max_pages and causal and alibi_slopes is None and kv_length is not None:
+        pos = jnp.asarray(q_offset, jnp.int32)
+        first = jnp.maximum(pos - (sliding_window - 1), 0) // page_size  # first slot in reach: scalar or [n_lanes]
+        slots = jnp.broadcast_to(first, (n_lanes,))[:, None] + jnp.arange(reach, dtype=jnp.int32)[None, :]
+        taken = jnp.take_along_axis(tables, jnp.clip(slots, 0, max_pages - 1), axis=1)
+        tables = jnp.where(slots < max_pages, taken, -1)
+        q_offset = pos - first * page_size
+        kv_length = jnp.asarray(kv_length, jnp.int32) - first * page_size
     k = gather_pages(k_pool, tables)
     v = gather_pages(v_pool, tables)
     return attend_reference(
-        q, k, v, q_offset=pos, kv_length=kv_length,
+        q, k, v, q_offset=q_offset, kv_length=kv_length,
         alibi_slopes=alibi_slopes, sliding_window=sliding_window,
         scale=scale, causal=causal, logit_softcap=logit_softcap,
     )
@@ -970,10 +1012,7 @@ def maybe_autotune_paged_attention(
 
     import numpy as np
 
-    from petals_tpu.ops.paged_attention import (
-        PagedPool, gather_pages, identity_tables, quantize_kv_rows,
-    )
-    from petals_tpu.ops.attention import attend_reference
+    from petals_tpu.ops.paged_attention import PagedPool, quantize_kv_rows
 
     hq = hkv * max(int(group), 1)
     n_pages = n_lanes * max_pages
@@ -1037,11 +1076,7 @@ def maybe_autotune_paged_attention(
         return paged_flash_attend(qv, kp, vp, tb, ps_, sliding_window=window)
 
     def xla_arm(qv, kp, vp, tb, ps_):
-        kd = gather_pages(kp, tb)
-        vd = gather_pages(vp, tb)
-        return attend_reference(
-            qv, kd, vd, q_offset=ps_, kv_length=ps_ + 1, sliding_window=window
-        )
+        return composed_paged_attend(qv, kp, vp, tb, q_offset=ps_, kv_length=ps_ + 1, sliding_window=window)
 
     # both arms must compile: this is a timing choice, never a rescue. A
     # refusal here is a bug in the kernel or in paged_kernel_unsupported —

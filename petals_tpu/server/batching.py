@@ -409,6 +409,13 @@ class DecodeBatcher:
             # dispatch their step gave them, and the times a step's program
             # walked a layer's experts
             self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_weight_passes=0)
+        # a family that declares its layers' windows only (_count_window), on the paged pool: the
+        # table slots the step programs gather against those they are handed, and of the pages the
+        # decoding lanes hold in windowed layers those their windows still reach (summed over steps)
+        self._windows = [w for w in (getattr(backend, "layer_windows", None) or ()) if w] if self.page_size else []
+        if self._windows:
+            self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0, window_pages_held=0, window_pages_in_reach=0)
+            self._lane_pos = np.zeros(n_lanes, np.int64)  # the last position each lane fed, for occupancy_info
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
         # (telemetry.journal), and the pool gauges/counters feed the /metrics
@@ -1358,6 +1365,12 @@ class DecodeBatcher:
             # WIRE bytes/token (what a page actually costs under kv quant)
             info["kv_quant"] = getattr(self.backend, "kv_quant_type", "none")
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
+            if self._windows and self._tables is not None:
+                # over the lanes that hold pages, at the last position each fed
+                live = np.flatnonzero((self._tables >= 0).any(axis=1))
+                info["window_pages_held"], info["window_pages_in_reach"] = self._window_pages(
+                    self._tables, live, self._lane_pos[live]
+                )
         info.update(self._scheduler.summary())
         return info
 
@@ -2049,6 +2062,44 @@ class DecodeBatcher:
             self.stats["moe_grouped_tokens" if grouped(chunk_tokens, chunk=True) else "moe_dense_tokens"] += chunk_tokens
         self.stats["moe_weight_passes"] += 2 if chunk_tokens else 1
 
+    def _window_pages(self, tables: np.ndarray, lanes, positions) -> Tuple[int, int]:
+        """(held, in reach): the pages ``lanes`` hold, once a windowed layer
+        of the span, and those of them a layer's window still reaches from
+        the lane's ``positions`` entry. The rest are held until the session
+        ends (freeing them is ROADMAP B3)."""
+        held = (tables[lanes] >= 0).sum(axis=1)
+        pos = np.asarray(positions, np.int64)
+        reach = 0
+        for window in self._windows:
+            pages = pos // self.page_size - np.maximum(pos - window + 1, 0) // self.page_size + 1
+            reach += int(np.minimum(pages, held).sum())
+        return int(held.sum()) * len(self._windows), reach
+
+    def _count_window(self, tables, positions, *, seq: int = 1, chunk=None) -> None:
+        """The window counters of one paged step (compute thread; a family
+        that declares its layers' windows only), from the shapes the step was
+        started with: its programs gather for every lane of the pool, and for
+        the ``chunk`` (lane, first position, tokens) of a mixed step once more
+        at its bucket."""
+        if not self._windows or tables is None:
+            return
+        from petals_tpu.server.backend import bucket_length
+
+        backend, layers = self.backend, self.backend.n_blocks
+        self.stats["attn_pages_gathered"] += self.n_lanes * backend.pages_gathered(seq, self.max_pages, self.page_size)
+        self.stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
+        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
+        last = positions[lanes].astype(np.int64) + seq - 1
+        if chunk is not None:
+            lane, first, take = chunk
+            self.stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
+            self.stats["attn_pages_tabled"] += self.max_pages * layers
+            lanes, last = np.append(lanes, lane), np.append(last, first + take - 1)
+        self._lane_pos[lanes] = last
+        held, reach = self._window_pages(tables, lanes, last)
+        self.stats["window_pages_held"] += held
+        self.stats["window_pages_in_reach"] += reach
+
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
         variant = "paged" if self.page_size is not None else "dense"
@@ -2098,6 +2149,7 @@ class DecodeBatcher:
             self.stats["batched_tokens"] += len(batch)
             self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
             self._count_moe(len(batch))
+            self._count_window(tables, positions)
             duration = time.perf_counter() - t_step
             if self.page_size is not None:
                 tm.STEP_PAGED.observe(duration)
@@ -2208,6 +2260,7 @@ class DecodeBatcher:
                 self.stats["max_prefill_tokens_per_step"], take
             )
             self._count_moe(len(batch), chunk_tokens=take)
+            self._count_window(tables, positions, chunk=(st.lane, st.position, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2295,6 +2348,7 @@ class DecodeBatcher:
                 self.stats["max_gen_lanes"], len(gen_states)
             )
             self._count_moe(len(batch) + len(gen_states))
+            self._count_window(tables, positions)
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
@@ -2392,6 +2446,7 @@ class DecodeBatcher:
             self.stats["spec_accepted"] += accepted_total
             self.stats["max_spec_lanes"] = max(self.stats["max_spec_lanes"], n_spec)
             self._count_moe(n_spec * S, seq=S)
+            self._count_window(tables, positions, seq=S)
             duration = time.perf_counter() - t_step
             tm.STEP_SPEC.observe(duration)
             tm.STEPS_SPEC.inc()

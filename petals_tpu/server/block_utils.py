@@ -18,15 +18,17 @@ logger = get_logger(__name__)
 AUTOGRAD_RESERVE_FRACTION = 0.15  # headroom for activations/backward buffers
 
 
-def block_params_count(family, cfg) -> int:
-    shapes = family.block_param_shapes(cfg, jnp.bfloat16)
+def block_params_count(family, cfg, block_index: int = 0) -> int:
+    """Parameters of the model's block ``block_index`` (any block, for a
+    family whose blocks are all alike)."""
+    shapes = family.param_shapes_for(cfg, family.kind_of(cfg, block_index), jnp.bfloat16)
     return int(sum(np.prod(s.shape) for s in shapes.values()))
 
 
-def estimated_block_size_bytes(family, cfg, quant_type: str = "none") -> int:
+def estimated_block_size_bytes(family, cfg, quant_type: str = "none", block_index: int = 0) -> int:
     """Bytes of one served block at the given quantization
     (reference block_utils.py:22-53; NF4 = 4.25 bits/param)."""
-    return int(block_params_count(family, cfg) * BITS_PER_PARAM[quant_type] / 8)
+    return int(block_params_count(family, cfg, block_index) * BITS_PER_PARAM[quant_type] / 8)
 
 
 # HBM per chip by jax ``device_kind``, for a TPU runtime whose memory_stats()
@@ -71,11 +73,21 @@ def choose_num_blocks(
         logger.warning("Unknown device memory; defaulting to serving all blocks")
         return cfg.num_hidden_layers
     usable = memory * (1 - AUTOGRAD_RESERVE_FRACTION) - attn_cache_bytes
-    per_block = estimated_block_size_bytes(family, cfg, quant_type)
-    n = max(int(usable // per_block), 1)
-    n = min(n, cfg.num_hidden_layers)
+    # summed block by block (a family's blocks need not be all alike), and the span may start anywhere
+    # (placement comes later): the most consecutive blocks that fit wherever they start
+    by_kind = {}
+    sizes = [
+        by_kind.setdefault(kind, estimated_block_size_bytes(family, cfg, quant_type, i))
+        for i, kind in enumerate(family.span_kinds(cfg, 0, cfg.num_hidden_layers))
+    ]
+    n = max(
+        (n for n in range(1, len(sizes) + 1)
+         if max(sum(sizes[i : i + n]) for i in range(len(sizes) - n + 1)) <= usable),
+        default=1,
+    )
+    each = f"{min(sizes) / 2**20:.0f}" + (f"-{max(sizes) / 2**20:.0f}" if len(by_kind) > 1 else "")
     logger.info(
-        f"Auto-selected {n} blocks ({per_block / 2**20:.0f} MiB each, "
+        f"Auto-selected {n} blocks ({each} MiB each, "
         f"{memory / 2**30:.1f} GiB device memory, quant={quant_type})"
     )
     return n

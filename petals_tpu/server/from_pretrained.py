@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from petals_tpu.models.registry import ModelFamily, get_family
+from petals_tpu.models.registry import ModelFamily, get_family, known_families
 from petals_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -59,11 +59,19 @@ def resolve_model_path(
 
 
 def load_hf_config(model_name_or_path: str, *, revision: str = "main", cache_dir=None):
-    from transformers import AutoConfig
+    from transformers import AutoConfig, PretrainedConfig
+    from transformers.models.auto.configuration_auto import CONFIG_MAPPING
 
-    return AutoConfig.from_pretrained(
-        resolve_model_path(model_name_or_path, revision=revision, cache_dir=cache_dir)
-    )
+    path = resolve_model_path(model_name_or_path, revision=revision, cache_dir=cache_dir)
+    config_dict, _ = PretrainedConfig.get_config_dict(path)
+    model_type = config_dict.get("model_type")
+    if model_type not in CONFIG_MAPPING and model_type in known_families():
+        # a family this build serves and the installed transformers has no
+        # class for: the published keys as attributes, as its config_from_hf reads them
+        config = PretrainedConfig.from_dict(config_dict)
+        config.model_type = model_type
+        return config
+    return AutoConfig.from_pretrained(path)
 
 
 def get_block_config(
@@ -179,12 +187,13 @@ def load_block_params(
 
     import inspect
 
+    kind = family.kind_of(cfg, block_index)  # a family whose blocks are not all alike maps each by its kind
     if "block_index" in inspect.signature(family.hf_to_block_params).parameters:
         # per-layer-heterogeneous architectures (gemma2's alternating
         # windows) need to know WHICH block they are mapping
-        params = family.hf_to_block_params(tensors, cfg, block_index=block_index)
+        params = family.block_params_for(tensors, cfg, kind, block_index=block_index)
     else:
-        params = family.hf_to_block_params(tensors, cfg)
+        params = family.block_params_for(tensors, cfg, kind)
     cast = lambda x: jnp.asarray(x, dtype) if jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating) else jnp.asarray(x)
     params = {
         name: (jnp.asarray(leaf) if name in family.cast_exempt

@@ -410,7 +410,7 @@ class Server:
         stacked = await asyncio.get_running_loop().run_in_executor(
             None, self._load_span_params, self.first_block, self.num_blocks
         )
-        span_bytes = block_size_bytes(stacked)
+        span_bytes = sum(block_size_bytes(run) for run in (stacked if isinstance(stacked, tuple) else (stacked,)))
         logger.info(
             f"Blocks loaded in {time.perf_counter() - t0:.1f}s "
             f"({span_bytes / 2**20:.0f} MiB for {self.num_blocks} blocks, quant={self.quant_type})"
@@ -815,11 +815,25 @@ class Server:
             and not self.adapter_paths
             and self.num_hosts == 1
         )
-        per_block = [
-            self._load_block_converted(i, fuse=fuse)
-            for i in range(first_block, first_block + num_blocks)
-        ]
-        return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_block)
+        from petals_tpu.models.registry import span_runs
+
+        def stack_run(start: int, length: int) -> dict:
+            blocks = [self._load_block_converted(first_block + i, fuse=fuse) for i in range(start, start + length)]
+            # leaf by leaf, each block's array let go as soon as its stack exists: the load peaks at
+            # the span plus one stacked leaf, not at twice the span (ROADMAP D13 (1))
+            stacked = {}
+            for name in list(blocks[0]):
+                leaves = [block.pop(name) for block in blocks]
+                stacked[name] = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *leaves)
+                del leaves
+            return stacked
+
+        # a span of more than one kind of block (ModelFamily.block_kind): one stacked tree per run of
+        # consecutive blocks of one kind, in order (TransformerBackend reads the kinds and where each
+        # run starts from the family again); any other span: the one stacked tree
+        runs = span_runs(self.family.span_kinds(self.cfg, first_block, num_blocks))
+        stacked = tuple(stack_run(start, length) for _, start, length in runs)
+        return stacked[0] if len(stacked) == 1 else stacked
 
     def _load_block_converted(self, block_index: int, *, fuse: bool) -> dict:
         """One block, quantized per --quant_type. Quantized conversions are

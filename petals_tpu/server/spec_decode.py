@@ -173,7 +173,8 @@ class DraftModel:
             def run(hidden, position):
                 h = hidden.astype(dtype)
                 for i, p_block in enumerate(block_params):
-                    h, caches[i] = family.block_apply(
+                    # the draft spans the whole model: block i by its kind, for a family whose blocks are not all alike
+                    h, caches[i] = family.apply_for(family.kind_of(cfg, i))(
                         p_block, h, caches[i], position, cfg,
                         use_flash=False, tp_mesh=None,
                     )

@@ -121,7 +121,7 @@ def measure_compute_rps(
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.memory_cache import MemoryCache
 
-    shapes = family.block_param_shapes(cfg, compute_dtype)
+    shapes = family.param_shapes_for(cfg, family.kind_of(cfg, 0), compute_dtype)  # the model's block 0
     key = jax.random.PRNGKey(0)
     params = {}
     for name, sds in sorted(shapes.items()):

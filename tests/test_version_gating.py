@@ -74,7 +74,9 @@ def test_client_routing_rejects_incompatible_swarm(tmp_path):
     from tests.utils import make_tiny_llama
 
     path = make_tiny_llama(str(tmp_path))
-    harness = SwarmHarness(path, [dict(first_block=0, num_blocks=4)]).start()
+    # no second announce inside the test: the server reads the patched version when it announces again (every
+    # update_period, 30 s by default), and on a loaded machine the client was still looking when it did
+    harness = SwarmHarness(path, [dict(first_block=0, num_blocks=4, update_period=600.0)]).start()
     try:
         real_version = petals_tpu.__version__
         petals_tpu.__version__ = "999.0.0"

@@ -12,6 +12,7 @@ import functools
 import os
 import shutil
 
+import numpy as np
 import torch
 
 
@@ -629,3 +630,69 @@ def recorded_steps(events: list) -> list:
             stack.pop()
     assert not stack, stack
     return steps
+
+
+TINY_EXAONE_MOE = {  # the keys K-EXAONE publishes, at a toy size: kinds D-L, S-L, S-L, S-G, S-L
+    "model_type": "exaone_moe", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "intermediate_size": 128, "moe_intermediate_size": 32, "num_hidden_layers": 5, "first_k_dense_replace": 1,
+    "layer_types": ["sliding_attention", "sliding_attention", "sliding_attention", "full_attention", "sliding_attention"],
+    "mlp_layer_types": ["dense", "sparse", "sparse", "sparse", "sparse"], "sliding_window": 8, "sliding_window_pattern": "LLLG",
+    "num_experts": 16, "num_experts_per_tok": 4, "num_shared_experts": 1, "n_group": 1, "topk_group": 1,
+    "scoring_func": "sigmoid", "norm_topk_prob": True, "routed_scaling_factor": 2.5, "hidden_act": "silu",
+    "rms_norm_eps": 1e-5, "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "num_nextn_predict_layers": 1, "max_position_embeddings": 256, "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_exaone_moe_tensors(config: dict, seed: int = 13) -> dict:
+    """Seeded float32 tensors under the HF names of every layer of ``config``
+    (all the routed experts), the embedding, the final norm and the head.
+    Norm vectors and the router's bias are drawn, not ones and zeros, so a
+    missing or misplaced one shows."""
+    rng = np.random.RandomState(seed)
+    h, hq, hkv, d = (config[k] for k in ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim"))
+    m, md = config["moe_intermediate_size"], config["intermediate_size"]
+    routed = (config.get("expert_share") or {}).get("routed", config["num_experts"])
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda n: rng.uniform(0.5, 1.5, n).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": norm(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i, mlp in enumerate(config["mlp_layer_types"]):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "input_layernorm.weight": norm(h), p + "post_attention_layernorm.weight": norm(h),
+            p + "self_attn.q_proj.weight": normal(hq * d, h), p + "self_attn.k_proj.weight": normal(hkv * d, h),
+            p + "self_attn.v_proj.weight": normal(hkv * d, h), p + "self_attn.o_proj.weight": normal(h, hq * d),
+            p + "self_attn.q_norm.weight": norm(d), p + "self_attn.k_norm.weight": norm(d),
+        })
+        if mlp == "dense":
+            tensors.update({p + "mlp.gate_proj.weight": normal(md, h), p + "mlp.up_proj.weight": normal(md, h),
+                            p + "mlp.down_proj.weight": normal(h, md)})
+            continue
+        tensors[p + "mlp.gate.weight"] = normal(routed, h) * 5  # scores spread over (0, 1)
+        tensors[p + "mlp.gate.e_score_correction_bias"] = normal(routed)
+        for e in [*range(routed), "shared"]:
+            q = p + ("mlp.shared_experts." if e == "shared" else f"mlp.experts.{e}.")
+            tensors.update({q + "gate_proj.weight": normal(m, h), q + "up_proj.weight": normal(m, h), q + "down_proj.weight": normal(h, m)})
+    return tensors
+
+
+@_model_build_cache
+def make_tiny_exaone_moe(tmpdir: str, *, held: int = 16, first: int = 0) -> str:
+    """A K-EXAONE checkpoint at a toy size, written by hand (the installed
+    transformers has no class for ``exaone_moe``): every routed expert is in
+    the file, and a server of this directory holds ``held`` of the 16 from
+    ``first`` on (``expert_share``; all of them by default)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = dict(TINY_EXAONE_MOE)
+    if held != 16 or first:
+        config.update(num_experts=held, expert_share={"routed": 16, "first": first})
+    path = os.path.join(tmpdir, f"tiny-exaone-moe-{held}-{first}")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_exaone_moe_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
