@@ -15,7 +15,9 @@ KIND, the pair (MLP kind, attention kind) read at the block's absolute index:
   whose top k are chosen by score + ``e_score_correction_bias`` and weighed by
   score, renormalised and scaled by ``routed_scaling_factor``, of which this
   server holds ``num_experts`` from ``first_expert`` on, beside one shared
-  expert that every token takes (models/moe.py).
+  expert that every token takes (models/moe.py; of ``moe.grouped_dispatch``'s
+  three a step's decode rows take "hit", the held experts its live rows
+  reach, and every chunk the all-experts einsum).
 
 Pre-norm: ``h = x + attn(ln1(x)); y = h + mlp(ln2(h))``.
 """
@@ -39,7 +41,7 @@ from petals_tpu.models.common import (
     update_kv_cache,
 )
 from petals_tpu.models.exaone_moe.config import DENSE, SLIDING, ExaoneMoeBlockConfig
-from petals_tpu.models.moe import MoeDims, grouped_dispatch, moe_apply
+from petals_tpu.models.moe import MoeDims, choose_dispatch, moe_apply
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend
 from petals_tpu.ops.rotary import apply_rotary, rotary_tables
@@ -73,6 +75,7 @@ def block_apply(
     use_flash: bool = False,
     tp_mesh=None,
     n_valid=None,
+    live_rows=None,  # bool [batch] from a lane pool's step: the rows that are not idle lanes (None: all)
 ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     mlp_kind, attn_kind = kind
     batch, seq, _ = hidden_states.shape
@@ -110,8 +113,8 @@ def block_apply(
     else:
         mlp = moe_apply(
             params, x, top_k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob,
-            grouped=tp_mesh is None and grouped_dispatch(moe_dims(cfg, kind), seq),
-            scoring="sigmoid", scale=cfg.routed_scaling_factor, first=cfg.first_expert,
+            dispatch=choose_dispatch(params, moe_dims(cfg, kind), seq, mesh=tp_mesh is not None),
+            scoring="sigmoid", scale=cfg.routed_scaling_factor, first=cfg.first_expert, live_rows=live_rows,
         )
     hidden_states = residual + mlp
 

@@ -5,15 +5,13 @@ Capability parity with the reference's WrappedMixtralBlock
 on the hosting server (no cross-server expert parallelism, matching the
 reference), GQA attention with optional sliding window, top-k softmax routing.
 
-The expert layer is models/moe.py (shared with olmoe): HF-exact routing with
-the kept weights renormalised, and two dispatches, the all-experts einsum and
-the grouped ``ragged_dot``, chosen from the static shapes by
-``moe.grouped_dispatch``. At Mixtral's 8 experts of top 2 that is the grouped
-dispatch for a prompt chunk of 8 tokens or more and the all-experts einsum
-below that: a decode step is bound by weight bandwidth and reads nearly every
-expert anyway (two tokens already reach 3.5 of 8), so dense compute costs it
-nothing. That is true at 8 experts only: at 64 of top 8 the same einsum reads
-8x what a token needs (models/moe.py, ROADMAP S5).
+The expert layer is models/moe.py (shared with olmoe and exaone_moe): HF-exact
+routing with the kept weights renormalised, and three dispatches chosen by
+``moe.grouped_dispatch`` from what the call shows. At Mixtral's 8 experts of
+top 2 that is the grouped ``ragged_dot`` for a prompt chunk of 8 tokens or
+more; below that "hit" in a step program (the experts the step's live rows
+reach, read out of the stacked run: two live lanes reach 3.5 of 8) and the
+all-experts einsum anywhere else.
 """
 
 from __future__ import annotations
@@ -38,7 +36,7 @@ from petals_tpu.models.common import (
     update_kv_cache,
 )
 from petals_tpu.models.mixtral.config import MixtralBlockConfig
-from petals_tpu.models.moe import EXPERT_LEAVES, EXPERT_PSPECS, MoeDims, grouped_dispatch, moe_apply
+from petals_tpu.models.moe import EXPERT_LEAVES, EXPERT_PSPECS, MoeDims, choose_dispatch, moe_apply
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend_maybe_ring
 from petals_tpu.ops.rotary import apply_rotary, rotary_tables
@@ -59,6 +57,7 @@ def block_apply(
     tp_mesh=None,
     n_valid=None,
     ring_mesh=None,  # "sp" mesh: ring attention (stateless path) or q-sharded prefill (cached)
+    live_rows=None,  # bool [batch] from a lane pool's step: the rows that are not idle lanes (None: all)
 ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     batch, seq, _ = hidden_states.shape
     hq, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -84,10 +83,11 @@ def block_apply(
 
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln2"], cfg.rms_norm_eps)
-    # grouped dispatch single-device only (under an ep/tp mesh the dense
-    # einsums carry the expert shardings; ragged groups don't)
-    grouped = tp_mesh is None and ring_mesh is None and grouped_dispatch(moe_dims(cfg), seq)
-    hidden_states = residual + moe_apply(params, x, top_k=cfg.num_experts_per_tok, renormalize=True, grouped=grouped)
+    # under an ep/tp mesh the dense einsums carry the expert shardings; ragged groups and the hit kernel don't
+    dispatch = choose_dispatch(params, moe_dims(cfg), seq, mesh=tp_mesh is not None or ring_mesh is not None)
+    hidden_states = residual + moe_apply(
+        params, x, top_k=cfg.num_experts_per_tok, renormalize=True, dispatch=dispatch, live_rows=live_rows
+    )
 
     new_kv = (k_all, v_all) if kv is not None else None
     return hidden_states, new_kv

@@ -15,15 +15,14 @@ shared expert (``ws1``/``ws3`` [h, m], ``ws2`` [m, h]) runs for every token
 and is added whole. With every expert held nothing is dropped and the bits
 are what they were before a share could be told.
 
-Two dispatches share the routing:
+Three dispatches share the routing:
 
 - DENSE: every expert runs over every token (one batched einsum per
   projection) with a top-k one-hot combine: static shapes, zero scatter, and
   the only path under a tp/ep mesh or with quantized experts. It reads every
-  expert's weights and computes E / top_k times the FLOPs a token needs. At
-  8 experts a decode step reads them all anyway (a batch of two tokens already
-  reaches 3.5 of 8); at 64 experts of top 8 it reads 8x what one token needs
-  and a third more than eight lanes reach, which is ROADMAP S5's to cut.
+  held expert's weights, whatever the tokens asked for, and computes E / top_k
+  times the FLOPs a token needs: right for a chunk of tokens that reaches
+  them all anyway.
 - GROUPED (round-3 "sparse" dispatch): assignments are sorted by expert and
   the three projections run as grouped matmuls via ``jax.lax.ragged_dot``
   (static total size N*k, dynamic per-expert group sizes), so FLOPs scale with
@@ -31,8 +30,20 @@ Two dispatches share the routing:
   Tokens are never dropped (no capacity factor); outputs match the dense
   path's to within accumulation precision (the grouped combine runs in f32
   where the dense combine rounds the routing weights to the compute dtype).
+  ``ragged_dot`` is a custom call: inside a step program's layer loop it is
+  handed a COPY of the layer's experts, all that are held.
+- HIT: for the decode-shaped calls of a step program. The block is handed the
+  run's expert weights whole (``ExpertStack``: ``w1`` / ``w3`` [L, E, h, m],
+  ``w2`` [L, E, m, h], and which layer of the run it is) and which of its
+  rows are live; one Pallas call (ops/expert_hit.py) addresses ``[layer,
+  expert]`` itself and reads, tile by tile, the experts the live rows reach
+  and no others. Every row rides every hit expert and a combine weight of
+  zero does the selecting, so the mathematics is the einsum's, accumulated in
+  f32. A dead row (an idle lane of the pool) routes like any other but
+  reaches no expert and gets nothing.
 
-``grouped_dispatch`` chooses between them from the static shapes.
+``grouped_dispatch`` chooses among them from what a call can observe: its
+shape, the weights' type, the mesh and whether a stack was handed over.
 """
 
 from __future__ import annotations
@@ -75,6 +86,21 @@ class Routing(NamedTuple):
     scoring: str = "softmax"  # or "sigmoid": chosen by score + ``gate_bias``, weighed by score
     renormalize: bool = False  # kept weights divided by their sum
     scale: float = 1.0  # and multiplied by this (a sigmoid router's ``routed_scaling_factor``)
+
+
+class ExpertStack(NamedTuple):
+    """A run's expert weights where they lie, and which of its layers a block
+    is: what ``server/backend.py _scan_span`` puts in a block's parameters as
+    ``experts`` in place of the layer's own ``w1`` / ``w3`` / ``w2``."""
+
+    w1: jnp.ndarray  # [L, E, h, m]
+    w3: jnp.ndarray  # [L, E, h, m]
+    w2: jnp.ndarray  # [L, E, m, h]
+    layer: jnp.ndarray  # int32 scalar, traced: the loop's counter
+
+    def of_layer(self):
+        """``(w1, w3, w2)`` of the block's own layer (a slice that fuses into the dot that reads it)."""
+        return self.w1[self.layer], self.w3[self.layer], self.w2[self.layer]
 
 
 def route(params: dict, x: jnp.ndarray, routing: Routing):
@@ -129,25 +155,74 @@ def _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share: bool = False) -> 
     return y.astype(x.dtype).reshape(b, s, h)
 
 
-def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, grouped: bool = False,
-              scoring: str = "softmax", scale: float = 1.0, first: int = 0) -> jnp.ndarray:
+def hit_slots(top_idx, top_probs, live, n_experts: int):
+    """What the hit dispatch reads, from the rows' choices: ``(slot_expert [S],
+    n_hit, combine [S, N])``. ``top_idx`` [N, k] counts among the held experts
+    (outside them: absent), ``live`` [N] says which rows count (None: all).
+    The ``n_hit`` held experts some live row chose fill the first slots in
+    ascending order, the rest of the ``S = min(E, N * k)`` repeat the last of
+    them; ``combine`` is the weight of (slot, row), zero where the row did not
+    choose the slot's expert, is dead, or the slot is not in use."""
+    n_rows, k = top_idx.shape
+    n_slots = min(n_experts, n_rows * k)
+    chosen = top_idx[..., None] == jnp.arange(n_experts, dtype=top_idx.dtype)  # [N, k, E]; an absent expert: nowhere
+    if live is not None:
+        chosen = chosen & live[:, None, None]
+    weight_of = jnp.where(chosen, top_probs.astype(jnp.float32)[..., None], 0.0).sum(axis=1)  # [N, E]
+    hit = chosen.any(axis=(0, 1))  # [E]
+    n_hit = hit.sum().astype(jnp.int32)
+    by_hit = jnp.argsort(~hit, stable=True)[:n_slots].astype(jnp.int32)  # the hit experts first, ascending
+    in_use = jnp.arange(n_slots, dtype=jnp.int32) < n_hit
+    slot_expert = jnp.where(in_use, by_hit, by_hit[jnp.maximum(n_hit - 1, 0)])
+    combine = jnp.where(in_use[:, None], jnp.take(weight_of, slot_expert, axis=1).T, 0.0)
+    return slot_expert, n_hit, combine
+
+
+def _experts_hit(x, stack: ExpertStack, top_idx, top_probs, live_rows) -> jnp.ndarray:
+    """The experts the live rows reach, read out of the stacked run in place."""
+    from petals_tpu.ops.expert_hit import hit_experts
+
+    b, s, h = x.shape
+    k = top_idx.shape[-1]
+    live = None if live_rows is None else jnp.broadcast_to(live_rows[:, None], (b, s)).reshape(b * s)
+    slot_expert, n_hit, combine = hit_slots(
+        top_idx.reshape(b * s, k), top_probs.reshape(b * s, k), live, stack.w1.shape[1]
+    )
+    y = hit_experts(x.reshape(b * s, h), stack.w1, stack.w3, stack.w2, stack.layer, slot_expert, n_hit, combine)
+    return y.astype(x.dtype).reshape(b, s, h)
+
+
+def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, dispatch: str = "dense",
+              scoring: str = "softmax", scale: float = 1.0, first: int = 0, live_rows=None) -> jnp.ndarray:
     """x: [batch, seq, hidden] -> what the held experts give each token of the
     mixture of its top-k experts (HF-exact routing), plus the shared expert
     where ``params`` has one. ``renormalize`` divides the kept weights by
     their sum (Mixtral's rule; OLMoE's ``norm_topk_prob`` false keeps the
     softmax mass as it is); ``scoring`` and ``scale`` are ``Routing``'s,
-    ``first`` is ``MoeDims.first``."""
+    ``first`` is ``MoeDims.first``.
+
+    ``dispatch`` is ``grouped_dispatch``'s answer (``choose_dispatch`` asks it
+    for a block). The expert weights are ``params``' ``w1`` / ``w3`` / ``w2``
+    or, from a step program's layer loop, its ``experts`` (an ``ExpertStack``),
+    which "hit" needs; ``live_rows`` (bool [batch], None: every row) is read
+    by "hit" alone: the other two compute dead rows like any other."""
     from petals_tpu.ops.quant import QuantizedLinear, quant_matmul
 
-    w1, w2, w3 = params["w1"], params["w2"], params["w3"]
-    n_experts, n_routed = w1.shape[0], params["gate"].shape[-1]
-    share = n_experts != n_routed
+    stack = params.get("experts")
+    n_experts = stack.w1.shape[1] if stack is not None else params["w1"].shape[0]
+    share = n_experts != params["gate"].shape[-1]
     with jax.named_scope("ptu.moe.router"):
         top_idx, top_probs = route(params, x, Routing(top_k, scoring, renormalize, scale))
         if share:
             top_idx = top_idx - first  # among the held; outside [0, n_experts): absent
 
-    if grouped and not isinstance(w1, QuantizedLinear):
+    if dispatch == "hit":
+        with jax.named_scope("ptu.moe.experts.hit"):
+            y = _experts_hit(x, stack, top_idx, top_probs, live_rows)
+        return _add_shared(params, x, y)
+
+    w1, w3, w2 = stack.of_layer() if stack is not None else (params["w1"], params["w3"], params["w2"])
+    if dispatch == "grouped":
         with jax.named_scope("ptu.moe.experts.grouped"):
             y = _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share)
         return _add_shared(params, x, y)
@@ -197,12 +272,20 @@ DENSE_FLOPS_PER_S = 150e12  # the all-experts einsums past the weight read: 5.28
 GROUPED_FLOPS_PER_S = 48e12  # ragged_dot's slope from 512 tokens to 1024 at the same shapes: 2.08 us a token
 
 
-def grouped_dispatch(dims: MoeDims, seq: int) -> bool:
-    """Whether a block call of ``seq`` positions a row takes the grouped
-    dispatch, from the static shapes alone (one choice per compiled program).
+def grouped_dispatch(dims: MoeDims, seq: int, *, stacked: bool = False, quantized: bool = False, mesh: bool = False) -> str:
+    """The dispatch a block call of ``seq`` positions a row takes: "dense",
+    "grouped" or "hit", from what the call can observe (one choice per
+    compiled program): the static shapes, whether the block was handed the
+    run's stacked experts and its layer in them (``stacked``), whether the
+    expert weights are quantized, whether a tp / ring mesh is about.
 
-    - Under ``GROUPED_MIN_SEQ`` positions: the all-experts einsum. These are
-      the decode-shaped calls, bound by the weight read either way.
+    - Quantized experts, or a mesh: the all-experts einsum, the only one that
+      runs per-expert ``quant_matmul`` or carries the expert shardings.
+    - Under ``GROUPED_MIN_SEQ`` positions (the decode-shaped calls, bound by
+      the weight read): "hit" where a stack was handed over, so that the read
+      is of the experts the live rows reach (K-EXAONE's 8 lanes reach 6.4 of
+      16, OLMoE's 41.6 of 64, two live lanes of Mixtral's 3.5 of 8); without a
+      stack the einsum, which reads them all.
     - Few experts, all held (Mixtral's 8): grouped from there on, as before
       PR 26 (Mixtral-8x7B, a layer: 2.3-3.4 ms against the einsum's 3.7 at
       8-64 tokens, 7.2 against 9.0 at 512; worse at 128 and 256, 4.3 and 6.6
@@ -218,26 +301,34 @@ def grouped_dispatch(dims: MoeDims, seq: int) -> bool:
       program. ``ragged_dot`` cannot read a layer's experts where they lie in
       the stacked run, so the loop first copies them out (a read and a write
       of all that are held), and it is handed every assignment's row, those
-      of absent experts too. Alone, with its weights handed over as they are,
-      it reads only the experts the call's tokens reach (6.5 of 16 at 8
-      tokens) and wins the small calls: 0.40 ms a layer against the einsum's
-      1.65 at 8 rows of one position, 0.49 at one row of 8, 1.15 at 16; then
+      of absent experts too. Alone it wins the small chunks it is handed as
+      they are (1.15 ms a layer against the einsum's 1.65 at 16 tokens); then
       the einsum, 1.65-1.74 against 2.06, 3.32 and 3.93 at 32, 64 and 128,
       2.02 against 4.38 at 256, 4.59 against 4.94 at 512, 7.48 against 7.38
-      at 1024. In the step the copy eats the win and more: the cell's decode
-      step took 15.7 ms grouped against 12.3 with the einsum (PERF.md section
-      6, PR 31). So a share keeps the einsum at every shape measured, its
-      decode-shaped calls like everyone's; section 7 says what would let the
-      grouped dispatch read in place."""
+      at 1024; in a step the copy eats what win there is (PERF.md section 6,
+      PR 31). So a share's chunk-shaped calls keep the einsum at every shape
+      measured."""
+    if quantized or mesh:
+        return "dense"
     if seq < GROUPED_MIN_SEQ:
-        return False
+        return "hit" if stacked else "dense"
     share = dims.routed is not None and dims.routed > dims.experts
     if dims.experts <= FEW_EXPERTS and not share:
-        return True
+        return "grouped"
     expert_params = 3 * dims.hidden * dims.expert_width
     read_s = dims.experts * 2 * expert_params / HBM_BYTES_PER_S
     dense_s = max(read_s, seq * 2 * dims.experts * expert_params / DENSE_FLOPS_PER_S)
     grouped_s = read_s + dims.experts * GROUP_COST_S + seq * 2 * dims.top_k * expert_params / GROUPED_FLOPS_PER_S
     if share:
         grouped_s += 2 * read_s  # the layer's held experts copied out of the stacked run
-    return grouped_s < dense_s
+    return "grouped" if grouped_s < dense_s else "dense"
+
+
+def choose_dispatch(params: dict, dims: MoeDims, seq: int, *, mesh: bool) -> str:
+    """``grouped_dispatch`` for a block: what its parameters show, asked once."""
+    from petals_tpu.ops.quant import QuantizedLinear
+
+    stacked = "experts" in params
+    return grouped_dispatch(
+        dims, seq, stacked=stacked, quantized=not stacked and isinstance(params["w1"], QuantizedLinear), mesh=mesh
+    )

@@ -10,7 +10,9 @@ dispatch (models/moe.py) it shares:
   inserts that all-reduce (parallel/tp.py).
 - many small experts (64 of width 1024, 8 a token in OLMoE-1B-7B) and a router
   whose kept weights are NOT renormalised (``norm_topk_prob`` false): a token's
-  expert outputs are weighted by their share of the 64-way softmax mass.
+  expert outputs are weighted by their share of the 64-way softmax mass. Of
+  ``moe.grouped_dispatch``'s three, a step's decode rows take "hit" (8 lanes
+  reach 41.6 of the 64) and a chunk the all-experts einsum up to ~810 tokens.
 """
 
 from __future__ import annotations
@@ -35,7 +37,7 @@ from petals_tpu.models.common import (
     rms_norm,
     update_kv_cache,
 )
-from petals_tpu.models.moe import EXPERT_LEAVES, EXPERT_PSPECS, MoeDims, grouped_dispatch, moe_apply
+from petals_tpu.models.moe import EXPERT_LEAVES, EXPERT_PSPECS, MoeDims, choose_dispatch, moe_apply
 from petals_tpu.models.olmoe.config import OlmoeBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend_maybe_ring
@@ -57,6 +59,7 @@ def block_apply(
     tp_mesh=None,
     n_valid=None,
     ring_mesh=None,  # "sp" mesh: ring attention (stateless path) or q-sharded prefill (cached)
+    live_rows=None,  # bool [batch] from a lane pool's step: the rows that are not idle lanes (None: all)
 ) -> Tuple[jnp.ndarray, Optional[KVCache]]:
     batch, seq, _ = hidden_states.shape
     hq, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
@@ -88,11 +91,10 @@ def block_apply(
 
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln2"], cfg.rms_norm_eps)
-    # grouped dispatch single-device only (under an ep/tp mesh the dense
-    # einsums carry the expert shardings; ragged groups don't)
-    grouped = tp_mesh is None and ring_mesh is None and grouped_dispatch(moe_dims(cfg), seq)
+    # under an ep/tp mesh the dense einsums carry the expert shardings; ragged groups and the hit kernel don't
+    dispatch = choose_dispatch(params, moe_dims(cfg), seq, mesh=tp_mesh is not None or ring_mesh is not None)
     hidden_states = residual + moe_apply(
-        params, x, top_k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob, grouped=grouped
+        params, x, top_k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob, dispatch=dispatch, live_rows=live_rows
     )
 
     new_kv = (k_all, v_all) if kv is not None else None

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from petals_tpu.models.moe import grouped_dispatch
+from petals_tpu.models.moe import EXPERT_LEAVES, ExpertStack, grouped_dispatch
 from petals_tpu.models.registry import ModelFamily, kind_label, span_runs
 from petals_tpu.ops import fingerprint as fp_ops
 from petals_tpu.ops.sampling import sample_tokens, sampling_vectors
@@ -152,11 +152,12 @@ class TransformerBackend:
         self._last_step_fp = None  # [n_lanes, FP_DIM] device array or None
         self._last_chunk_fp = None  # [FP_DIM] (mixed step's prefill chunk)
 
-    def moe_grouped(self, seq: int, *, chunk: bool = False) -> Optional[bool]:
-        """Whether a block call of ``seq`` tokens a row (a mixed step's
-        ``chunk``: padded to its bucket) takes the grouped expert dispatch in
-        the lane pool's step programs (which give the block no mesh), by the
-        rule the block itself asks; None for a family without experts. The
+    def moe_grouped(self, seq: int, *, chunk: bool = False) -> Optional[str]:
+        """The expert dispatch a block call of ``seq`` tokens a row (a mixed
+        step's ``chunk``: padded to its bucket) takes in the lane pool's step
+        programs (which give the block no mesh, and the run's stacked experts
+        where ``_scan_span`` hands them over): "dense", "grouped" or "hit", by
+        the rule the block itself asks; None for a family without experts. The
         batcher counts its tokens by this."""
         if self.moe_dims is None:
             return None
@@ -165,7 +166,10 @@ class TransformerBackend:
         from petals_tpu.ops.quant import QuantizedLinear
 
         w1 = next(run["w1"] for run in self._by_run(self.params) if "w1" in run)
-        return not isinstance(w1, QuantizedLinear) and grouped_dispatch(self.moe_dims, seq)
+        return grouped_dispatch(
+            self.moe_dims, seq, stacked=self._stacks_experts(w1), quantized=isinstance(w1, QuantizedLinear),
+            mesh=self.mesh is not None,
+        )
 
     # ------------------------------------------------------------- a span as runs of one kind
 
@@ -190,7 +194,15 @@ class TransformerBackend:
         """The span's parameters as one stacked tree per run."""
         return (params,) if len(self.runs) == 1 else tuple(params)
 
-    def _scan_span(self, params, carry, xs, layer):
+    def _stacks_experts(self, w1) -> bool:
+        """Whether ``_scan_span`` hands a run's expert weights over whole:
+        plain arrays, no mesh (models/moe.py's "hit" dispatch reads them where
+        they lie; sharded or quantized experts keep the paths they had)."""
+        from petals_tpu.ops.quant import QuantizedLinear
+
+        return self.mesh is None and not isinstance(w1, QuantizedLinear)
+
+    def _scan_span(self, params, carry, xs, layer, *, stack_experts: bool = True):
         """The layer loop of every program: one ``jax.lax.scan`` a run of
         consecutive blocks of one kind over that run's stacked weights (one
         scan for a family whose blocks are all alike), the carry handed from
@@ -202,17 +214,31 @@ class TransformerBackend:
         ``block_idx`` counts from the span's first block, across runs.
         Returns ``(carry, ys)``, ``ys`` stacked over the span's depth.
 
-        Quantized leaves stay whole as scan consts (``_use_quant_consts``)."""
+        A run's weights ride as the scan's ``xs``, a layer's slice a trip,
+        but for two kinds of leaf that stay whole as scan CONSTS, the block
+        getting a view of the stack and its layer's index in it: quantized
+        leaves (``_use_quant_consts``), and the expert leaves of a run
+        (``_stacks_experts``; ``p_block["experts"]``, a ``moe.ExpertStack``,
+        in place of ``w1`` / ``w3`` / ``w2``), whose decode-shaped calls then
+        read the experts their rows reach and no others. A run of one block is
+        a stack of one. ``stack_experts`` false leaves the experts in ``xs``:
+        for the program the backward pass differentiates."""
         split = self._use_quant_consts
         outs = []
         for (kind, start, length), run_params in zip(self.runs, self._by_run(params)):
             dense, quant, outliers = self._split_quant(run_params) if split else (run_params, None, None)
+            experts = None
+            if stack_experts and "w1" in dense and self._stacks_experts(dense["w1"]):
+                experts = ExpertStack(dense["w1"], dense["w3"], dense["w2"], layer=None)  # the layer: the body's
+                dense = {name: leaf for name, leaf in dense.items() if name not in EXPERT_LEAVES}
             block_apply = self.family.apply_for(kind)
 
-            def body(c, scanned, block_apply=block_apply, quant=quant, outliers=outliers, start=start):
+            def body(c, scanned, block_apply=block_apply, quant=quant, outliers=outliers, experts=experts, start=start):
                 p_block, x, block_idx = scanned
                 if quant is not None:
                     p_block = self._reattach_quant(p_block, quant, outliers, block_idx - start)
+                if experts is not None:
+                    p_block = {**p_block, "experts": experts._replace(layer=block_idx - start)}
                 return layer(block_apply, c, p_block, x, block_idx)
 
             run_xs = xs if len(self.runs) == 1 else jax.tree_util.tree_map(lambda a: a[start : start + length], xs)
@@ -224,6 +250,14 @@ class TransformerBackend:
             outs.append(y)
         ys = outs[0] if len(outs) == 1 else jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *outs)
         return carry, ys
+
+    def _live_rows(self, positions, max_length) -> dict:
+        """``block_apply``'s ``live_rows`` for a lane pool's step, for a
+        family whose block takes it: an idle lane rides at the sentinel
+        position ``max_length`` and is no row of anyone's."""
+        if "live_rows" not in inspect.signature(self.family.block_apply).parameters:
+            return {}
+        return {"live_rows": positions < max_length}
 
     # ------------------------------------------------------------- cache descriptors
 
@@ -508,7 +542,8 @@ class TransformerBackend:
         cfg, tp_mesh = self.cfg, self.mesh
 
         def layer(block_apply, h, p_block, x, block_idx):
-            return block_apply(p_block, h, x, positions, cfg, use_flash=False, tp_mesh=tp_mesh)
+            live = self._live_rows(positions, x[0].shape[1])  # a layer's k: [n_lanes, max_len, hkv, d]
+            return block_apply(p_block, h, x, positions, cfg, use_flash=False, tp_mesh=tp_mesh, **live)
 
         return layer
 
@@ -666,9 +701,10 @@ class TransformerBackend:
         cfg = self.cfg
 
         def layer(block_apply, h, p_block, k_span, v_span, paged):
+            live = self._live_rows(positions, tables.shape[1] * k_span.shape[1])  # max_pages * page_size
             out, (k_kv, v_kv) = block_apply(
                 p_block, h, paged(k_span, v_span, tables), positions, cfg,
-                use_flash=False, tp_mesh=None,
+                use_flash=False, tp_mesh=None, **live,
             )
             return out, k_kv.pool, v_kv.pool
 
@@ -1286,7 +1322,8 @@ class TransformerBackend:
                 )
                 return out, None
 
-            hidden, _ = self._scan_span(params, hidden, prompts, layer)
+            # no stacked experts: the backward differentiates this program, and the hit kernel has no VJP
+            hidden, _ = self._scan_span(params, hidden, prompts, layer, stack_experts=False)
             return hidden
 
         return fwd

@@ -406,9 +406,11 @@ class DecodeBatcher:
         }
         if getattr(backend, "moe_dims", None) is not None:
             # a family with routed experts only (_count_moe): tokens by the
-            # dispatch their step gave them, and the times a step's program
-            # walked a layer's experts
-            self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_weight_passes=0)
+            # dispatch their step gave them (dense: the all-experts einsum;
+            # grouped: one of the two that read the experts reached, of which
+            # hit: the stacked-run kernel, the rest ragged_dot), and the times
+            # a step's program walked a layer's experts
+            self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_hit_tokens=0, moe_weight_passes=0)
         # a family that declares its layers' windows only (_count_window), on the paged pool: the
         # table slots the step programs gather against those they are handed, and of the pages the
         # decoding lanes hold in windowed layers those their windows still reach (summed over steps)
@@ -2056,11 +2058,15 @@ class DecodeBatcher:
         ``block_apply`` once for the lanes and once for the chunk)."""
         if "moe_weight_passes" not in self.stats:
             return
-        grouped = self.backend.moe_grouped
-        self.stats["moe_grouped_tokens" if grouped(seq) else "moe_dense_tokens"] += tokens
+        dispatch = self.backend.moe_grouped
+        halves = [(dispatch(seq), tokens)]
         if chunk_tokens:
-            self.stats["moe_grouped_tokens" if grouped(chunk_tokens, chunk=True) else "moe_dense_tokens"] += chunk_tokens
-        self.stats["moe_weight_passes"] += 2 if chunk_tokens else 1
+            halves.append((dispatch(chunk_tokens, chunk=True), chunk_tokens))
+        for took, n in halves:
+            self.stats["moe_dense_tokens" if took == "dense" else "moe_grouped_tokens"] += n
+            if took == "hit":
+                self.stats["moe_hit_tokens"] += n
+        self.stats["moe_weight_passes"] += len(halves)
 
     def _window_pages(self, tables: np.ndarray, lanes, positions) -> Tuple[int, int]:
         """(held, in reach): the pages ``lanes`` hold, once a windowed layer

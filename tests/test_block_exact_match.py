@@ -147,7 +147,7 @@ def test_moe_sparse_dispatch_matches_dense():
     from petals_tpu.models.moe import moe_apply as shared_moe_apply
 
     def moe_apply(params, x, *, sparse):
-        return shared_moe_apply(params, x, top_k=2, renormalize=True, grouped=sparse)
+        return shared_moe_apply(params, x, top_k=2, renormalize=True, dispatch="grouped" if sparse else "dense")
 
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 5)

@@ -181,7 +181,7 @@ def test_expert_layer_matches_hf_deepseek_v3_moe(tiny):
         want = moe(torch.from_numpy(x)).numpy()
     params = load_block_params(path, 2, dtype=jnp.float32)
     for grouped in (False, True):
-        got = moe_apply(params, jnp.asarray(x), top_k=4, renormalize=True, grouped=grouped, scoring="sigmoid", scale=2.5)
+        got = moe_apply(params, jnp.asarray(x), top_k=4, renormalize=True, dispatch="grouped" if grouped else "dense", scoring="sigmoid", scale=2.5)
         np.testing.assert_allclose(np.asarray(got), want, atol=1e-5, rtol=0, err_msg=f"grouped={grouped}")
 
 
@@ -210,8 +210,8 @@ def test_the_shares_add_up_to_the_uncut_layer_with_the_shared_expert_once(tiny):
         for first in (0, 4, 8, 12):
             mine = {**params, **{k: params[k][first : first + 4] for k in ("w1", "w2", "w3")}}
             routed_only = {k: v for k, v in mine.items() if not k.startswith("ws")}
-            part = np.asarray(moe_apply(routed_only, r, grouped=grouped, first=first, **rule))[0]
-            with_shared = np.asarray(moe_apply(mine, r, grouped=grouped, first=first, **rule))[0]
+            part = np.asarray(moe_apply(routed_only, r, dispatch="grouped" if grouped else "dense", first=first, **rule))[0]
+            with_shared = np.asarray(moe_apply(mine, r, dispatch="grouped" if grouped else "dense", first=first, **rule))[0]
             shared = with_shared - part if shared is None else shared
             none_held = ~((chosen >= first) & (chosen < first + 4)).any(-1)
             assert none_held.any() and not part[none_held].any()  # nothing of the routed experts: dropped, not rerouted
@@ -298,7 +298,7 @@ def test_every_expert_held_and_a_softmax_rule_give_the_bits_they_gave(family_rul
         p = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
         x = (jax.random.normal(keys[4], (batch, seq, h), jnp.float32) * 0.3).astype(dtype)
         for grouped in (False, True):
-            new = jax.jit(lambda p, x: moe_apply(p, x, top_k=top_k, renormalize=renormalize, grouped=grouped))(p, x)
+            new = jax.jit(lambda p, x: moe_apply(p, x, top_k=top_k, renormalize=renormalize, dispatch="grouped" if grouped else "dense"))(p, x)
             old = jax.jit(lambda p, x: _moe_apply_before(p, x, top_k=top_k, renormalize=renormalize, grouped=grouped))(p, x)
             assert np.asarray(new).tobytes() == np.asarray(old).tobytes(), (dtype, grouped)
 
@@ -312,13 +312,13 @@ def test_the_dispatch_rule_knows_the_share_and_keeps_the_others_choices():
     OLMoE's choices at every bucket are what they were."""
     share = MoeDims(16, 8, 6144, 2048, routed=128)
     for seq in (1, 4, 7, 8, 16, 32, 64, 128, 256, 512, 1024):
-        assert not grouped_dispatch(share, seq)
-    assert grouped_dispatch(MoeDims(8, 2, 4096, 14336, routed=8), 64)  # every routed expert held: no share
+        assert grouped_dispatch(share, seq) == "dense"
+    assert grouped_dispatch(MoeDims(8, 2, 4096, 14336, routed=8), 64) == "grouped"  # every routed expert held: no share
     for seq in (1, 4, 7):
-        assert not grouped_dispatch(MoeDims(8, 2, 4096, 14336), seq) and not grouped_dispatch(MoeDims(64, 8, 2048, 1024), seq)
+        assert grouped_dispatch(MoeDims(8, 2, 4096, 14336), seq) == "dense" == grouped_dispatch(MoeDims(64, 8, 2048, 1024), seq)
     for seq in (8, 16, 32, 64, 128, 256, 512, 1024):
-        assert grouped_dispatch(MoeDims(8, 2, 4096, 14336), seq)
-        assert grouped_dispatch(MoeDims(64, 8, 2048, 1024), seq) == (seq >= 1024)
+        assert grouped_dispatch(MoeDims(8, 2, 4096, 14336), seq) == "grouped"
+        assert grouped_dispatch(MoeDims(64, 8, 2048, 1024), seq) == ("grouped" if seq >= 1024 else "dense")
 
 
 # ---------------------------------------------------------------------------------
@@ -382,6 +382,8 @@ def test_paged_prefill_then_decode_matches_the_reference_s_full_pass(swarm):
         delta = {k: batcher.stats[k] - was[k] for k in was if isinstance(was[k], (int, float))}
         assert delta["prefill_tokens"] == 21 and delta["batched_tokens"] == SEQ - 21
         assert delta["moe_dense_tokens"] + delta["moe_grouped_tokens"] == SEQ and delta["moe_weight_passes"] == delta["batched_steps"] + delta["mixed_steps"]
+        # the prompt's chunks took the all-experts einsum (a share keeps it), every decode token the hit dispatch
+        assert delta["moe_dense_tokens"] == 21 and delta["moe_grouped_tokens"] == delta["moe_hit_tokens"] == SEQ - 21
         lanes, pure = batcher.n_lanes, delta["batched_steps"] - delta["mixed_steps"]
         assert pure == SEQ - 21  # the prompt's chunks rode steps of their own kind
         decode_gathered = lanes * sum(3 if w else 12 for w in windows)
@@ -413,7 +415,7 @@ def test_a_family_without_declared_windows_has_no_window_counters(tmp_path):
     assert "moe_weight_passes" not in batcher.stats and not keys & set(batcher.occupancy_info())
     exaone = whole_backend(make_tiny_exaone_moe(str(tmp_path)))
     batcher = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
-    assert keys | {"moe_dense_tokens", "moe_grouped_tokens", "moe_weight_passes"} <= set(batcher.stats)
+    assert keys | {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"} <= set(batcher.stats)
     dense_pool = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=None)
     assert not keys & set(dense_pool.stats)  # the counters count pages: the paged pool only
 

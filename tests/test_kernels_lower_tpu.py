@@ -289,12 +289,7 @@ STEP_CASES = [
         marks=[pytest.mark.xfail(strict=True, reason=(
             "the chunk's grouped expert dispatch: ragged_dot is a custom call and cannot read the stacked span in "
             "place, so each layer's w1, w3 and w2 (940 MB each) are sliced out first (ROADMAP S7)"
-        ))] if (config_name, chunk) == ("mixtral-8x7b-span2", 256) else [pytest.mark.xfail(strict=True, reason=(
-            "in the loop of the run of two expert layers the compiler prefetches the run's WHOLE stacked ws2 (the shared "
-            "expert's down projection, bf16[2,2048,6144], 50 MB) into the alternate memory space in every trip, and the "
-            "chunk's dot reads its layer from there: 25 MB more than the layer needs, ~30 us a layer of a mixed step "
-            "(the decode step has none; PERF.md section 7)"
-        ))] if (config_name, chunk) == ("k-exaone-236b-span5-ep8", 256) else [],
+        ))] if (config_name, chunk) == ("mixtral-8x7b-span2", 256) else [],
     )
     for config_name in ("falcon-40b-span5", "mixtral-8x7b-span2", "olmoe-1b-7b-span8", "k-exaone-236b-span5-ep8")
     for chunk in (0, 256)
@@ -330,7 +325,9 @@ def _compiled_step(v5e, tmp_path, config_name, chunk):
         avals += [v5e((1, chunk, cfg.hidden_size), BF16)] + [v5e((), I32)] * 4
     # the raw step under tracked_jit: kernel_path only retraces, attend() resolves the path itself
     step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
-    hlo = jax.jit(step, donate_argnums=(1, 2)).lower(*avals).compile().as_text()
+    with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
+        patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
+        hlo = jax.jit(step, donate_argnums=(1, 2)).lower(*avals).compile().as_text()
     return hlo, runs, pool
 
 
@@ -352,6 +349,81 @@ def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_na
     # a run of one block is no loop once compiled: its weights are read where the program was handed them
     moved = entry_weight_moves(hlo, {tuple(p.shape) for run in runs for p in run.values()}, min(math.prod(shape[1:]) for shape in attention))
     assert not moved, f"the step relays a weight of a run of one block in every step: {moved}"
+
+
+def hit_calls(hlo: str) -> list:
+    """``[(name, [(operand's op, its dims)])]`` of every call of the hit
+    dispatch's kernel (``ops/expert_hit.py``, named ``moe_hit_experts``) in an
+    optimized HLO module: what each operand IS in the computation that holds
+    the call (a loop body, or ``ENTRY`` for a run of one block)."""
+    calls = []
+    for instructions in _computations(hlo).values():
+        by_name = {name: (op, dims) for name, dims, op, _ in instructions}
+        for name, _, op, rest in instructions:
+            if op == "custom-call" and name.startswith("moe_hit_experts") and "tpu_custom_call" in rest:
+                operands = re.findall(r"%([\w.\-]+)", rest.split("), custom_call_target")[0])
+                calls.append((name, [by_name[o] for o in operands]))
+    return calls
+
+
+@pytest.mark.parametrize("config_name", ["mixtral-8x7b-span2", "olmoe-1b-7b-span8", "k-exaone-236b-span5-ep8"])
+def test_decode_step_reads_the_experts_hit_out_of_the_stacked_run(v5e, tmp_path, config_name):
+    """The decode step of an expert configuration holds the hit dispatch's
+    kernel once a run of expert layers, and the kernel's weight operands are
+    the run's stacked ``w1`` / ``w3`` [L, E, h, m] and ``w2`` [L, E, m, h]
+    themselves, as the loop carries them or the program was handed them: no
+    slice, no copy (what ``ragged_dot`` could not do: PERF.md section 6, PR
+    31), and nothing else in the step moves a weight either."""
+    hlo, runs, _ = _compiled_step(v5e, tmp_path, config_name, 0)
+    expert_runs = [run for run in runs if "w1" in run]
+    calls = hit_calls(hlo)
+    assert len(calls) == len(expert_runs), [name for name, _ in calls]
+    want = sorted(sorted(tuple(run[leaf].shape) for leaf in ("w1", "w3", "w2")) for run in expert_runs)
+    got = sorted(sorted(dims for _, dims in operands if len(dims) == 4) for _, operands in calls)
+    assert got == want
+    for name, operands in calls:
+        held_as = {op for op, dims in operands if len(dims) == 4}
+        assert held_as <= {"get-tuple-element", "parameter"}, f"%{name} is handed a weight something made: {operands}"
+    shapes = {tuple(p.shape) for run in runs for p in run.values()}
+    least = min(math.prod(run["w1"].shape[2:]) for run in expert_runs)  # one expert's matrix
+    relayouts, seen = weight_relayouts(hlo, shapes, least)
+    assert seen and not relayouts, relayouts
+    assert not entry_weight_moves(hlo, shapes, least)
+
+
+def test_dispatch_rule_picks_hit_only_where_it_can_read_in_place(tmp_path):
+    """``grouped_dispatch`` answers "hit" for a decode-shaped call that was
+    handed a stack, with plain weights and no mesh, and in every other case
+    what it answered before there was a third dispatch; ``backend.moe_grouped``
+    asks it for the lane pool's step programs."""
+    from petals_tpu.models.moe import GROUPED_MIN_SEQ, MoeDims, grouped_dispatch
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.from_pretrained import get_block_config
+    from tests.utils import make_tiny_mixtral
+
+    shapes = {"mixtral": MoeDims(8, 2, 4096, 14336), "olmoe": MoeDims(64, 8, 2048, 1024), "k-exaone": MoeDims(16, 8, 6144, 2048, routed=128)}
+    chunk = {  # today's choice between the einsum and ragged_dot, by the chunk's length
+        "mixtral": lambda seq: "grouped", "olmoe": lambda seq: "grouped" if seq >= 1024 else "dense", "k-exaone": lambda seq: "dense",
+    }
+    for name, dims in shapes.items():
+        for seq in (1, 2, 5, GROUPED_MIN_SEQ - 1):  # decode, a speculative verify's k + 1 rows
+            assert grouped_dispatch(dims, seq, stacked=True) == "hit"
+            assert grouped_dispatch(dims, seq) == "dense"  # a caller that hands over no stack
+            assert grouped_dispatch(dims, seq, stacked=True, quantized=True) == "dense"
+            assert grouped_dispatch(dims, seq, stacked=True, mesh=True) == "dense"
+        for seq in (GROUPED_MIN_SEQ, 16, 64, 128, 256, 512, 1024):  # chunk-shaped: the stack changes nothing
+            assert grouped_dispatch(dims, seq, stacked=True) == grouped_dispatch(dims, seq) == chunk[name](seq)
+            assert grouped_dispatch(dims, seq, quantized=True) == "dense" == grouped_dispatch(dims, seq, mesh=True)
+
+    family, cfg = get_block_config(make_tiny_mixtral(str(tmp_path)))
+    def backend(**leaves):
+        params = {name: jax.ShapeDtypeStruct((2, *leaf.shape), leaf.dtype) for name, leaf in family.block_param_shapes(cfg, BF16).items()}
+        return TransformerBackend(family, cfg, {**params, **leaves}, first_block=0, n_blocks=2, memory_cache=None)
+    plain = backend()
+    assert plain.moe_grouped(1) == "hit" and plain.moe_grouped(5) == "hit" and plain.moe_grouped(40, chunk=True) == "grouped"
+    from petals_tpu.ops.quant import QuantizedLinear
+    quantized = backend(w1=QuantizedLinear("nf4", None, None, 0, 0))
+    assert quantized.moe_grouped(1) == "dense" == quantized.moe_grouped(40, chunk=True)
 
 
 # ---------------------------------------------------------------- the step leaves the page pool where it lies

@@ -83,8 +83,11 @@ def test_paged_prefill_then_decode_matches_the_full_forward_pass(olmoe_swarm):
     assert after["prefill_tokens"] - before["prefill_tokens"] == 21  # the prompt rode mixed steps of the lane pool
     assert after["batched_tokens"] - before["batched_tokens"] == 6
     # the expert counters (host side, from the shapes each step started with)
-    assert after["moe_dense_tokens"] - before["moe_dense_tokens"] == 6
-    assert after["moe_grouped_tokens"] - before["moe_grouped_tokens"] == 21
+    # (the toy's 8 experts: the prompt's chunks go to ragged_dot; the 6 decode tokens took the hit dispatch, which
+    # counts among the grouped, the two that read the experts reached, and alone besides)
+    assert after["moe_dense_tokens"] - before["moe_dense_tokens"] == 0
+    assert after["moe_grouped_tokens"] - before["moe_grouped_tokens"] == 27
+    assert after["moe_hit_tokens"] - before["moe_hit_tokens"] == 6
     steps, mixed = (after[k] - before[k] for k in ("batched_steps", "mixed_steps"))
     assert after["moe_weight_passes"] - before["moe_weight_passes"] == steps + mixed
 
@@ -98,7 +101,7 @@ def test_a_family_without_experts_has_no_expert_counters(tmp_path):
     from petals_tpu.server.task_queue import PriorityTaskQueue
     from tests.utils import make_tiny_llama, make_tiny_mixtral
 
-    keys = {"moe_dense_tokens", "moe_grouped_tokens", "moe_weight_passes"}
+    keys = {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"}
     for maker, has in ((make_tiny_llama, False), (make_tiny_mixtral, True), (make_tiny_olmoe, True)):
         path = maker(str(tmp_path))
         family, cfg = get_block_config(path)
@@ -109,7 +112,8 @@ def test_a_family_without_experts_has_no_expert_counters(tmp_path):
         assert (keys <= set(batcher.stats)) == has and (not keys & set(batcher.stats)) == (not has), family.name
         assert (backend.moe_grouped(1) is None) == (not has)
         if has:
-            assert backend.moe_grouped(1) is False and backend.moe_grouped(7, chunk=True) is True  # a chunk of 7 rides bucket 8
+            # a decode row reads the experts hit out of the stacked run; a chunk of 7 rides bucket 8 (few experts: ragged_dot)
+            assert backend.moe_grouped(1) == "hit" and backend.moe_grouped(7, chunk=True) == "grouped"
 
 
 def test_generate_token_identical(olmoe_swarm):
@@ -231,7 +235,7 @@ def test_shared_dispatch_is_mixtral_s_at_mixtral_s_shapes(batch, seq):
     class: the published one's choice is checked, its 2.8 GB are not run)."""
     old_sparse = seq >= OLD_MIN_SEQ
     for h, m, n_experts, top_k in (MIXTRAL_TINY, MIXTRAL_PUBLISHED):
-        assert grouped_dispatch(MoeDims(n_experts, top_k, h, m), seq) == old_sparse
+        assert grouped_dispatch(MoeDims(n_experts, top_k, h, m), seq) == ("grouped" if old_sparse else "dense")
     h, m, n_experts, top_k = MIXTRAL_TINY
     keys = jax.random.split(jax.random.PRNGKey(batch * 4096 + seq), 5)
     params = {"gate": jax.random.normal(keys[0], (h, n_experts), jnp.float32) * 0.2,
@@ -241,7 +245,7 @@ def test_shared_dispatch_is_mixtral_s_at_mixtral_s_shapes(batch, seq):
     for dtype in (jnp.float32, jnp.bfloat16):
         p = jax.tree_util.tree_map(lambda a: a.astype(dtype), params)
         x = (jax.random.normal(keys[4], (batch, seq, h), jnp.float32) * 0.3).astype(dtype)
-        new = jax.jit(lambda p, x: moe_apply(p, x, top_k=top_k, renormalize=True, grouped=old_sparse))(p, x)
+        new = jax.jit(lambda p, x: moe_apply(p, x, top_k=top_k, renormalize=True, dispatch="grouped" if old_sparse else "dense"))(p, x)
         old = jax.jit(lambda p, x: _old_moe_apply(p, x, n_experts, top_k, sparse=old_sparse))(p, x)
         assert np.asarray(new).tobytes() == np.asarray(old).tobytes()
 
@@ -262,5 +266,5 @@ def test_routing_weights_are_kept_or_renormalised_as_the_family_says():
         weights = probs[kept] / (probs[kept].sum() if renormalize else 1.0)
         want = sum(w * expert(e) for w, e in zip(weights, kept))
         for grouped in (False, True):
-            got = np.asarray(moe_apply(params, x, top_k=top_k, renormalize=renormalize, grouped=grouped))[0, 0]
+            got = np.asarray(moe_apply(params, x, top_k=top_k, renormalize=renormalize, dispatch="grouped" if grouped else "dense"))[0, 0]
             np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
