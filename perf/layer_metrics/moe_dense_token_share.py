@@ -1,11 +1,12 @@
 """Of the tokens that went through an expert layer in the traced window, the
-share that took the all-experts einsum (``moe_dense_tokens``) and not the
-grouped ``ragged_dot`` (``moe_grouped_tokens``): both counted by the batcher on
-the host, from the shapes each step was started with (decode lanes, and a mixed
-step's chunk at its bucket). The einsum reads every expert and computes
-experts / top_k times what a token needs, so this is what a sparser decode
-dispatch (ROADMAP S5) moves first. A program or a family without the counters
-gives None."""
+share that took the all-experts einsum (``moe_dense_tokens``) and not one of
+the two dispatches that read the experts reached (``moe_grouped_tokens``:
+``ragged_dot``'s tokens and, since PR 32, the hit kernel's, which
+``moe_hit_tokens`` counts alone): all counted by the batcher on the host, from
+the shapes each step was started with (decode lanes, and a mixed step's chunk
+at its bucket). The einsum reads every held expert and computes
+experts / top_k times what a token needs. A program or a family without the
+counters gives None."""
 UNIT, LAYER, MOVES = "%", "expert dispatch (models/moe.py)", "gap_p50_ms"
 KEYS = ("moe_dense_tokens", "moe_grouped_tokens")
 

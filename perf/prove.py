@@ -7,7 +7,11 @@ Runs ``BENCHMARK.json``'s command once per seed and set, one process after
 the other (a chip belongs to one process at a time), keeps every result line
 in ``chiprun_out/prove_<cell>.jsonl`` with the run's stderr and the server
 logs beside it, and prints each metric's median and spread per set (distance
-between the quartiles of ``statistics.quantiles(n=4)`` over the median).
+between the quartiles of ``statistics.quantiles(n=4)`` over the median, and
+the same without the set's farthest run, which is what the driver holds to
+half the bound). Each run's line also shows what its ``detail`` says of the
+window: ``gap_mid_width_pct``, the mean decode batch, how late the generator
+ran, the programs that compiled.
 This process never imports JAX."""
 
 from __future__ import annotations
@@ -32,6 +36,25 @@ def spread(values: list) -> float:
     return (q3 - q1) / statistics.median(values)
 
 
+def trimmed_spread(values: list) -> float:
+    """The spread without the run farthest from the median, where that is the
+    narrower: what the driver holds a set to (at most half the bound)."""
+    if len(values) < 3:
+        return spread(values)
+    median = statistics.median(values)
+    rest = sorted(values, key=lambda v: abs(v - median))[:-1]
+    return min(spread(values), spread(rest))
+
+
+def detail_of(stderr: str):
+    """The ``detail`` a run logged on standard error (perf/run.py main), or None."""
+    for line in reversed(stderr.splitlines()):
+        _, found, rest = line.partition("] detail: ")
+        if found:
+            return json.loads(rest)
+    return None
+
+
 def one_run(command: list, workload: str, seed: int, seconds, trace: int, tag: str) -> dict:
     cmd = [*command, "--workload", workload, "--seed", str(seed), "--seconds", str(seconds), "--trace", str(trace)]
     t0 = time.perf_counter()
@@ -46,7 +69,7 @@ def one_run(command: list, workload: str, seed: int, seconds, trace: int, tag: s
         print(f"{tag}: exit {proc.returncode} after {wall:.0f}s\n{proc.stderr[-3000:]}", flush=True)
         return {"rc": proc.returncode, "wall_s": wall}
     result = json.loads(lines[-1])
-    result.update(rc=0, wall_s=wall, seed=seed, trace=trace)
+    result.update(rc=0, wall_s=wall, seed=seed, trace=trace, detail=detail_of(proc.stderr))
     return result
 
 
@@ -75,8 +98,11 @@ def main(argv=None) -> int:
                 if r["rc"] != 0:
                     return 1
                 shown = {n: round(m["value"], 3) for n, m in r["metrics"].items()}
+                d = r["detail"] or {}
                 print(f"set {k} seed {seed}: correct={r['correct']} failed={r['failed']}/{r['attempted']} "
-                      f"wall={r['wall_s']:.0f}s {shown}", flush=True)
+                      f"wall={r['wall_s']:.0f}s {shown} middle fifth {d.get('gaps', {}).get('mid_width_pct')}% "
+                      f"batch {d.get('decode_batch_mean')} late p95 {d.get('gen_late_ms_p95')} ms "
+                      f"recompiled {d.get('recompiled')}", flush=True)
                 results.append(r)
             sets.append(results)
         if args.trace_last:
@@ -91,7 +117,8 @@ def main(argv=None) -> int:
             values = [r["metrics"][name]["value"] for i, r in enumerate(results) if not (name == "setup_s" and k == 0 and i == 0)]
             if not values:
                 continue
-            row.append(f"set {k}: median {statistics.median(values):.4f} spread {100 * spread(values):.2f}%")
+            row.append(f"set {k}: median {statistics.median(values):.4f} spread {100 * spread(values):.2f}% "
+                       f"(without its farthest run {100 * trimmed_spread(values):.2f}%)")
         print(f"{name}: " + "; ".join(row), flush=True)
     return 0
 

@@ -37,8 +37,8 @@ class Record:
     def in_window(self, t: float) -> bool:
         return self.t0 <= t <= self.t_end
 
-    def gaps_ms(self) -> np.ndarray:
-        """Reply-to-reply times of consecutive steps of one session, for
+    def gap_samples(self) -> np.ndarray:
+        """Rows (reply time, ms since the session's reply before it), for
         every decode reply that came inside the window (any session's)."""
         out = []
         for s in self.sessions:
@@ -47,9 +47,13 @@ class Record:
             last = s.first_reply
             for t, _pos in s.replies:
                 if self.in_window(t):
-                    out.append((t - last) * 1e3)
+                    out.append((t, (t - last) * 1e3))
                 last = t
-        return np.asarray(out)
+        return np.asarray(out, float).reshape(-1, 2)
+
+    def gaps_ms(self) -> np.ndarray:
+        """Reply-to-reply times of consecutive steps of one session."""
+        return self.gap_samples()[:, 1]
 
     def hop_steps(self, kind: str) -> np.ndarray:
         """Rows (n_hops, network, queue, compute, serialize, other) in

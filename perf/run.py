@@ -37,7 +37,7 @@ import numpy as np
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
 
-from perf import correct, costs, loadgen, reference, traffic  # noqa: E402
+from perf import correct, costs, gaps, loadgen, reference, traffic  # noqa: E402
 from perf.config import load as load_config  # noqa: E402
 from perf.record import Record, load_reader  # noqa: E402
 
@@ -363,6 +363,10 @@ def run_cell(benchmark: dict, workload: str, seed: int, seconds: float, trace: b
         "ttft_p50_ms_first_half": ttft_p50(0, seconds / 2), "ttft_p50_ms_second_half": ttft_p50(seconds / 2, seconds),
         "cache_events": [d["cache_events"] for d in dumps],
         "recompiled": load_reader("layer_metrics", "recompiles_in_window").programs(record),
+        # what an untraced run's line does not carry and a proof of a cell asks of every run (perf/prove.py, perf/gaps.py)
+        "gen_late_ms_p95": load_reader("layer_metrics", "gen_late_ms_p95").read(record),
+        "decode_batch_mean": load_reader("layer_metrics", "decode_batch_mean").read(record),
+        "gaps": gaps.summary(record),
     }
     result["compared"] = correct.compared(verdict)  # after "detail", which main() takes out: the last key of the line
     return result

@@ -69,7 +69,9 @@ def main(argv=None) -> int:
             shown = {n: round(m["value"], 2) for n, m in r["metrics"].items()}
             d = r["detail"]
             print(f"rate {rate}: failed {r['failed']}/{r['attempted']} {shown} drain {d['drain_s']:.1f}s "
-                  f"ttft p50 halves {d['ttft_p50_ms_first_half']}/{d['ttft_p50_ms_second_half']} ms", flush=True)
+                  f"ttft p50 halves {d['ttft_p50_ms_first_half']}/{d['ttft_p50_ms_second_half']} ms "
+                  f"batch {d['decode_batch_mean']} middle fifth {d['gaps'].get('mid_width_pct')}% "
+                  f"cache {d['cache_events']}", flush=True)
     return 0
 
 

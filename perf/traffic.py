@@ -10,7 +10,9 @@ One general generator. A mix is data; its parameters:
               ``{"kind": "burst", "size": n, "period_s": p}`` open loop, n
               sessions at once every p seconds |
               ``{"kind": "closed", "clients": k}`` each client opens its next
-              session when its last one ended.
+              session when its last one ended; ``"sessions": n`` plans that
+              many a client (default 64): more than it can finish in a run,
+              or it falls silent before the window ends.
 ``prompt`` / ``output``  a distribution: ``lognormal`` (median, sigma) |
               ``uniform`` | ``fixed`` (value), each clipped to ``min``..``max``.
               A session is its prompt in one chunk, then ``output`` decode steps.
@@ -59,7 +61,7 @@ import numpy as np
 
 TRAFFIC_DIR = Path(__file__).resolve().parent / "traffic"
 POOL_ROWS = 4096  # rows of the input pool; a session's rows wrap around it
-CLOSED_SESSIONS_PER_CLIENT = 64  # more than a client can finish in a window
+CLOSED_SESSIONS_PER_CLIENT = 64  # more than a client of long sessions can finish in a window; a mix of short ones says "sessions"
 
 
 def load_mix(name: str, traffic_dir: Path = TRAFFIC_DIR) -> dict:
@@ -209,7 +211,7 @@ def schedule(mix: dict, seed: int, seconds: float) -> Schedule:
     kind = arrival["kind"]
     if kind == "closed":
         k = int(arrival["clients"])
-        flat = plans(k * CLOSED_SESSIONS_PER_CLIENT, clients=k)
+        flat = plans(k * int(arrival.get("sessions", CLOSED_SESSIONS_PER_CLIENT)), clients=k)
         per_client = tuple(tuple(p for p in flat if p.client == c) for c in range(k))
         starts = tuple(-ramp_s + ramp_s * c / k for c in range(k))
         return Schedule("closed", ramp_s, client_plans=per_client, client_starts=starts)

@@ -7,6 +7,7 @@ hand-worked numbers, that each plain reference agrees with the served block
 at a tiny size, and that BENCHMARK.json names what exists.
 """
 
+import io
 import json
 import re
 import subprocess
@@ -61,14 +62,22 @@ def test_same_seed_same_schedule_another_seed_another(name):
     assert sorted(y for _, y in sizes(a)) == sorted(y for _, y in sizes(c))
 
 
-def test_a_mix_may_fix_its_order_and_the_inputs_still_follow_the_seed():
+CHAT_MIXES = sorted(p.stem for p in traffic.TRAFFIC_DIR.glob("chat.*.json"))  # a configuration's own: ``chat`` plus its rate
+
+
+@pytest.mark.parametrize("name", ["toy"] + CHAT_MIXES)
+def test_a_mix_may_fix_its_order_and_the_inputs_still_follow_the_seed(name):
     """``order_seed``: every seed runs the same sessions at the same times
-    (where the order decides the result, PERF.md section 6, PR 30); the rows
-    they send are still the seed's."""
-    fixed = {**MIXES["open_rate"], "order_seed": 77}
+    (where the order decides the result, PERF.md section 6, PRs 30 and 34);
+    the rows they send are still the seed's. Every committed chat mix fixes
+    its order: a few lanes are live at once there, and who decodes beside
+    whom moves the median gap."""
+    plain = MIXES["open_rate"] if name == "toy" else {k: v for k, v in traffic.load_mix(name).items() if k != "order_seed"}
+    fixed = {**plain, "order_seed": 77} if name == "toy" else traffic.load_mix(name)
+    assert "order_seed" in fixed
     a, b = traffic.schedule(fixed, 2**31 + 7, 30.0), traffic.schedule(fixed, 11, 30.0)
-    assert a == b == traffic.schedule(MIXES["open_rate"], 77, 30.0)  # the order that seed would have drawn
-    assert a != traffic.schedule({**fixed, "order_seed": 78}, 11, 30.0)
+    assert a == b == traffic.schedule(plain, fixed["order_seed"], 30.0)  # the order that seed would have drawn
+    assert a != traffic.schedule({**fixed, "order_seed": fixed["order_seed"] + 1}, 11, 30.0)
     assert traffic.input_pool(2**31 + 7, 16).tobytes() != traffic.input_pool(11, 16).tobytes()
 
 
@@ -131,6 +140,17 @@ def test_max_length_is_the_mix_s_or_what_the_session_needs():
         traffic.schedule({**MIXES["max_length"], "max_length": 64}, 1, 10.0)
     with pytest.raises(ValueError, match="arrival kind"):
         traffic.schedule({**MIXES["closed"], "arrival": {"kind": "poisson", "rate_rps": 1.0}}, 1, 10.0)
+
+
+def test_a_closed_mix_may_plan_more_sessions_a_client():
+    """``sessions``: a client of short sessions must not run out of plans
+    before the window ends (it would fall silent, and a lane with it)."""
+    closed = MIXES["closed"]
+    more = {**closed, "arrival": {**closed["arrival"], "sessions": 100}}
+    assert [len(ps) for ps in traffic.schedule(closed, 5, 30.0).client_plans] == [traffic.CLOSED_SESSIONS_PER_CLIENT] * 5
+    assert [len(ps) for ps in traffic.schedule(more, 5, 30.0).client_plans] == [100] * 5
+    mix = traffic.load_mix("chat.mixtral8x7b")  # ~40 sessions a client in a window of 51 s (PERF.md section 6, PR 34)
+    assert all(len(ps) >= 120 for ps in traffic.schedule(mix, 5, BENCHMARK["run_seconds"]).client_plans)
 
 
 def test_mix_files_resolve_their_base():
@@ -652,6 +672,84 @@ def test_memory_readers_tell_the_peak_from_what_serving_holds():
     assert peak.read(record) == 14.0 and peak.MOVES == "setup_s"
     assert steady.read(record) == 6.5 and steady.MOVES == "gap_p50_ms"
     assert steady.read(Record(config={}, t_process=0.0, t0=1.0, seconds=1.0, t_drained=3.0, sessions=[], children=[{}])) is None
+
+
+def _gap_record(sessions):
+    """``sessions``: (first reply, [reply times]) each, seconds; the window is [0, 100]."""
+    made = [loadgen.SessionRecord(plan=None, due=0.0, counted=True, first_reply=first, replies=[(t, i) for i, t in enumerate(times)])
+            for first, times in sessions]
+    return Record(config={}, t_process=0.0, t0=0.0, seconds=100.0, t_drained=101.0, sessions=made, children=[])
+
+
+def _steps(first, gaps_ms):
+    return first, list(first + np.cumsum(np.asarray(gaps_ms) / 1e3))
+
+
+GAP_RECORDS = {
+    # one heap: every step alike, 10 ms give or take 1%
+    "one_heap": _gap_record([_steps(1.0, 10.0 + 0.1 * np.sin(np.arange(400)))]),
+    # two heaps 2 ms apart with the median between them: 45% of the samples alone at 6.1 ms, 55% side by side at 8.1
+    "two_heaps": _gap_record([_steps(1.0, [6.1] * 90 + [8.1] * 55), _steps(1.001 + 90 * 6.1e-3, [8.1] * 55)]),
+    "no_gaps": _gap_record([(1.0, [])]),
+}
+
+
+@pytest.mark.parametrize("case,low,high", [("one_heap", 0.0, 3.0), ("two_heaps", 20.0, 35.0), ("no_gaps", None, None), ("declared", None, None)])
+def test_gap_mid_width_tells_a_median_on_an_edge(case, low, high):
+    """100 x (p60 - p40) / p50 of the gaps: small where they make one heap,
+    as wide as the heaps are apart where the median stands between two, and
+    nothing where there is nothing to read; ``BENCHMARK.json`` repeats the
+    reader's words, for every cell."""
+    reader = load_reader("layer_metrics", "gap_mid_width_pct")
+    if case == "declared":
+        (entry,) = [m for m in BENCHMARK["per_layer"] if m["name"] == "gap_mid_width_pct"]
+        assert (entry["unit"], entry["layer"], entry["moves"]) == (reader.UNIT, reader.LAYER, reader.MOVES) == ("%", "service (due time to reply, perf/loadgen.py)", "gap_p50_ms")
+        assert entry["better"] == "lower" and entry["source"] == "host_clock" and "workloads" not in entry
+        return
+    value = reader.read(GAP_RECORDS[case])
+    if low is None:
+        assert value is None
+    else:
+        assert low <= value <= high
+        assert value == pytest.approx(100 * np.subtract(*np.percentile(GAP_RECORDS[case].gaps_ms(), (60, 40))) / load_reader("end_to_end", "gap_p50_ms").read(GAP_RECORDS[case]))
+
+
+def test_gap_summary_counts_the_heaps_by_who_was_decoding():
+    """perf/gaps.py: the percentiles around the median, the samples by how
+    many sessions were decoding when the reply came, the bins of 0.25 ms; and
+    a run's standard error gives the summary back."""
+    from perf import gaps, prove
+
+    s = gaps.summary(GAP_RECORDS["two_heaps"])
+    assert s["n"] == 200 and s["p40"] == pytest.approx(6.1) and s["p60"] == pytest.approx(8.1)
+    assert s["mid_width_pct"] == pytest.approx(100 * 2.0 / s["p50"])
+    assert set(s["by_lanes"]) == {"1", "2"}
+    assert s["by_lanes"]["1"] == [pytest.approx(0.45, abs=0.01), pytest.approx(6.1)]  # (the second session's last reply comes alone)
+    assert s["by_lanes"]["2"] == [pytest.approx(0.55, abs=0.01), pytest.approx(8.1)]
+    assert {lo: n for lo, n in s["bins"]} == {6.0: 90, 8.0: 110} and s["beyond"] == 0 and s["bin_ms"] == 0.25
+    assert gaps.summary(GAP_RECORDS["no_gaps"]) == {"n": 0}
+    json.dumps(s)  # goes into the run's detail line as it is
+    stderr = f"[perf   100.0s] window and drain over\n[perf   101.0s] detail: {json.dumps({'gaps': s})}\ncompared finite 1 limit 1\n"
+    assert prove.detail_of(stderr) == {"gaps": s} and prove.detail_of("nothing of the kind\n") is None
+    out = io.StringIO()
+    gaps.show("two heaps", s, out)
+    assert "200 gaps" in out.getvalue() and out.getvalue().count("#") > 60
+
+
+@pytest.mark.parametrize("values,whole,trimmed", [
+    ([10.0, 10.1, 10.2, 10.3, 10.4, 10.5], 0.35 / 10.25, 0.3 / 10.2),  # evenly spread: the farthest run is an end
+    ([10.0, 10.0, 10.1, 10.1, 10.2, 13.0], None, 0.15 / 10.1),  # one run far off does no harm
+    ([10.0, 10.1], None, None),  # too few to leave one out
+])
+def test_a_set_s_spread_with_and_without_its_farthest_run(values, whole, trimmed):
+    from perf import prove
+
+    if whole is not None:
+        assert prove.spread(values) == pytest.approx(whole)
+    if trimmed is None:
+        assert prove.trimmed_spread(values) == prove.spread(values)
+    else:
+        assert prove.trimmed_spread(values) == pytest.approx(trimmed) and prove.trimmed_spread(values) <= prove.spread(values)
 
 
 def test_benchmark_json_names_only_what_exists():
