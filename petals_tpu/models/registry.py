@@ -78,6 +78,14 @@ class ModelFamily:
     # None for full attention. A family that declares it has its gathered and
     # held pages counted by the batcher (server/batching.py)
     block_window: Optional[Callable] = None
+    # (cfg, kind) -> what a lane holds for a block of that kind IN PLACE of pages of keys and values: a
+    # state of fixed size whatever the context, as ``((shape, dtype), ...)`` a lane (dtype None: the
+    # cache's own); None for a kind that caches keys and values. The framework keeps the states in a
+    # pool of their own beside the pages, ``[that kind's layers, lanes, *shape]`` a leaf, and hands a
+    # block its lanes' slices as ``kv``; a row at position 0 starts from zeros. A state cannot be cut
+    # back to an earlier position, so what needs that (a rollback, a reused prefix, speculative
+    # verify) is refused for a family that declares one (server/backend.py ``state_layers``)
+    block_state: Optional[Callable] = None
 
     def kind_of(self, cfg, block_index: int) -> Hashable:
         return None if self.block_kind is None else self.block_kind(cfg, block_index)
@@ -96,6 +104,9 @@ class ModelFamily:
 
     def moe_dims_for(self, cfg, kind: Hashable):
         return None if self.moe_dims is None else self.moe_dims(cfg, *_kind_args(kind))
+
+    def state_for(self, cfg, kind: Hashable) -> Optional[tuple]:
+        return None if self.block_state is None else self.block_state(cfg, kind)
 
 
 def _kind_args(kind: Hashable) -> tuple:

@@ -1033,12 +1033,18 @@ def maybe_autotune_paged_attention(
         k_pool = PagedPool(*quantize_kv_rows(k_pool, kv_quant))
         v_pool = PagedPool(*quantize_kv_rows(v_pool, kv_quant))
 
-    def _perturb(pool, f):
+    def _perturb(pool, f, after):
         # quantized pools perturb the SCALES leaf — same effect (the chain
-        # stays data-dependent, CSE can't hoist the gather), legal dtypes
+        # stays data-dependent, CSE can't hoist the gather), legal dtypes.
+        # The factor waits for the link before (``after``, times zero): a
+        # link's pool, its gather and its casts would otherwise depend on
+        # nothing in the chain and be made for every link at once, and at
+        # lanes of 40 pages and 32 kv heads that is 10 GB of a chip that
+        # holds a span's weights (PR 35: the server stopped at start-up)
+        f = f + after.ravel()[0].astype(jnp.float32) * 0.0
         if isinstance(pool, PagedPool):
             return PagedPool(pool.codes, pool.scales * f)
-        return pool * f
+        return pool * f.astype(pool.dtype)
 
     def timed(call):
         # chained data-dependent calls inside one jit; slope between two chain
@@ -1053,7 +1059,7 @@ def maybe_autotune_paged_attention(
                 a = qv
                 for j in range(n):
                     f_j = 1.0 + j / 128.0  # bf16 eps at 1.0: survives the dtype
-                    a = call(a * 1e-2 + qv, _perturb(kp, f_j), _perturb(vp, f_j), tb, ps_)
+                    a = call(a * 1e-2 + qv, _perturb(kp, f_j, a), _perturb(vp, f_j, a), tb, ps_)
                 return a
 
             return tracked_jit(f, name="paged_autotune_chain")

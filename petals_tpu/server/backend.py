@@ -44,6 +44,8 @@ from petals_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 PREFILL_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+# why a span with a recurrent state takes no draft model (``refuse_for_state``: here, the batcher, the server)
+SPEC_CUTS_BACK = "a rejected draft is rolled back by cutting the cache to the last accepted position, and a state cannot be cut back"
 
 
 def bucket_length(n: int) -> int:
@@ -116,7 +118,8 @@ class TransformerBackend:
             # partitioning rule for Mosaic custom calls, shard_map sidesteps it
         self.use_flash = use_flash
 
-        self.num_kv_heads = getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+        # a family may keep more kv heads in its cache than it publishes (heads of zeros, for the device's layout)
+        self.num_kv_heads = getattr(cfg, "cache_kv_heads", None) or getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
         self.head_dim = cfg.head_dim
         self.hidden_size = cfg.hidden_size
 
@@ -142,6 +145,19 @@ class TransformerBackend:
         self.layer_windows = None
         if family.block_window is not None:
             self.layer_windows = [family.block_window(cfg, kind) for kind, _, length in self.runs for _ in range(length)]
+        # what a lane holds for each block: pages of keys and values, or, for a kind whose family declares
+        # a state (ModelFamily.block_state), a state of fixed size in a pool of its own beside the pages.
+        # kv_layers / state_layers: the span's blocks of either sort, in order; _slots[i]: block i's place
+        # among its own sort, which is its layer in its pool; lane_state: a state's leaves, (shape, dtype)
+        states = [family.state_for(cfg, kind) for kind, _, length in self.runs for _ in range(length)]
+        self.state_layers = [i for i, state in enumerate(states) if state is not None]
+        self.kv_layers = [i for i, state in enumerate(states) if state is None]
+        self._slots = [(self.kv_layers if state is None else self.state_layers).index(i) for i, state in enumerate(states)]
+        self.lane_state = tuple(
+            (shape, jnp.dtype(dtype or self.cache_dtype)) for shape, dtype in next((s for s in states if s is not None), ())
+        )
+        if self.state_layers:
+            self._check_state(states, mesh)
         # adapter name -> (stacked {leaf: (A, B)}, scaling); see utils/peft.py
         self.adapters: Dict[str, tuple] = {}
         self._dummy_operands: Dict[tuple, jax.Array] = {}
@@ -190,6 +206,31 @@ class TransformerBackend:
                for leaf in jax.tree_util.tree_leaves(params, is_leaf=lambda x: isinstance(x, QuantizedLinear))):
             raise NotImplementedError(f"{name}: a span of more than one kind of block is not served quantized yet")
 
+    def _check_state(self, states, mesh) -> None:
+        """A span with a recurrent state: what it cannot do yet is refused
+        here, with the reason, not served wrong."""
+        name = self.family.name
+        if len({state for state in states if state is not None}) != 1:
+            raise NotImplementedError(f"{name}: the span's kinds of block declare states of different shapes")
+        if mesh is not None:
+            raise NotImplementedError(f"{name}: a span with a recurrent state is not served over a tp mesh yet")
+        if self.kv_quant_type != "none":
+            raise NotImplementedError(
+                f"{name}: kv_quant_type {self.kv_quant_type!r} is not served for a span with a recurrent state: "
+                f"the state is float32 and has no packed form"
+            )
+
+    def refuse_for_state(self, what: str, why: str) -> None:
+        """Raise for ``what`` if this span keeps a recurrent state: a state
+        holds a whole history at one position and cannot be cut back to an
+        earlier one, so what needs that, and the cache paths that do not
+        carry a state at all, are refused by what the family declares."""
+        if self.state_layers:
+            raise NotImplementedError(
+                f"{self.family.name}: {what} is not served for a span with a recurrent state "
+                f"({len(self.state_layers)} of its {self.n_blocks} blocks keep one): {why}"
+            )
+
     def _by_run(self, params) -> tuple:
         """The span's parameters as one stacked tree per run."""
         return (params,) if len(self.runs) == 1 else tuple(params)
@@ -202,7 +243,7 @@ class TransformerBackend:
 
         return self.mesh is None and not isinstance(w1, QuantizedLinear)
 
-    def _scan_span(self, params, carry, xs, layer, *, stack_experts: bool = True):
+    def _scan_span(self, params, carry, xs, layer, *, stack_experts: bool = True, pass_kind: bool = False):
         """The layer loop of every program: one ``jax.lax.scan`` a run of
         consecutive blocks of one kind over that run's stacked weights (one
         scan for a family whose blocks are all alike), the carry handed from
@@ -222,7 +263,8 @@ class TransformerBackend:
         in place of ``w1`` / ``w3`` / ``w2``), whose decode-shaped calls then
         read the experts their rows reach and no others. A run of one block is
         a stack of one. ``stack_experts`` false leaves the experts in ``xs``:
-        for the program the backward pass differentiates."""
+        for the program the backward pass differentiates. ``pass_kind`` hands
+        ``layer`` the run's kind as ``kind=``."""
         split = self._use_quant_consts
         outs = []
         for (kind, start, length), run_params in zip(self.runs, self._by_run(params)):
@@ -232,8 +274,10 @@ class TransformerBackend:
                 experts = ExpertStack(dense["w1"], dense["w3"], dense["w2"], layer=None)  # the layer: the body's
                 dense = {name: leaf for name, leaf in dense.items() if name not in EXPERT_LEAVES}
             block_apply = self.family.apply_for(kind)
+            run_layer = functools.partial(layer, kind=kind) if pass_kind else layer
 
-            def body(c, scanned, block_apply=block_apply, quant=quant, outliers=outliers, experts=experts, start=start):
+            def body(c, scanned, block_apply=block_apply, quant=quant, outliers=outliers, experts=experts, start=start,
+                     layer=run_layer):
                 p_block, x, block_idx = scanned
                 if quant is not None:
                     p_block = self._reattach_quant(p_block, quant, outliers, block_idx - start)
@@ -265,6 +309,11 @@ class TransformerBackend:
         """(k, v) descriptors for blocks [start, end) of this span; under TP the
         kv-head axis is sharded over the mesh (reference backend.py:88-99's
         per-shard descriptors, expressed as one NamedSharding)."""
+        self.refuse_for_state(
+            "a private cache or a dense lane pool",
+            "only the paged lane pool carries the state (a session of batch size 1 over the whole span with no "
+            "adapter and a max_length within the lanes' length takes a lane)",
+        )
         n = end - start
         shape = (n, batch_size, max_length, self.num_kv_heads, self.head_dim)
         sharding = None
@@ -286,8 +335,10 @@ class TransformerBackend:
         codes in the storage dtype (int8, or uint8 with two split-half-packed
         dims per byte for nf4a) and f32 absmax scales per (page row, kv head).
         The paged path is gated to mesh-less single-host servers
-        (server/batching.py), so no sharding rides these."""
-        n = end - start
+        (server/batching.py), so no sharding rides these. The pool is as deep
+        as the blocks of [start, end) that keep keys and values: a block with
+        a state of its own (``state_cache_descriptors``) has no pages."""
+        n = sum(start <= i < end for i in self.kv_layers)
         shape = (n, n_pages, page_size, self.num_kv_heads, self.head_dim)
         if self.kv_quant_type == "none":
             return (
@@ -306,12 +357,25 @@ class TransformerBackend:
             TensorDescriptor(scales_shape, jnp.float32),
         )
 
+    def state_cache_descriptors(self, n_lanes: int) -> tuple:
+        """Descriptors of the STATE pool beside the pages: one a leaf of the
+        family's state, ``[state layers, n_lanes, *shape]``; none for a span
+        whose blocks all keep keys and values."""
+        return tuple(TensorDescriptor((len(self.state_layers), n_lanes, *shape), dtype) for shape, dtype in self.lane_state)
+
+    def state_bytes_per_lane(self) -> int:
+        """What a lane holds whatever its context: its states over the span's
+        state layers. 0 for a span without one."""
+        return len(self.state_layers) * sum(int(np.prod(shape)) * dtype.itemsize for shape, dtype in self.lane_state)
+
     def cache_bytes_per_token(self) -> int:
-        """LOGICAL (dense fp) bytes per token across the span — sizes the
-        dense lane cache and stays the fp baseline for capacity ratios."""
+        """LOGICAL (dense fp) bytes per token across the span's blocks that
+        keep keys and values — sizes the dense lane cache and stays the fp
+        baseline for capacity ratios. A lane's fixed part is
+        ``state_bytes_per_lane``."""
         return (
             2
-            * self.n_blocks
+            * len(self.kv_layers)
             * self.num_kv_heads
             * self.head_dim
             * jnp.dtype(self.cache_dtype).itemsize
@@ -323,7 +387,7 @@ class TransformerBackend:
         cache_bytes_per_token when kv_quant_type == none."""
         from petals_tpu.ops.paged_attention import kv_wire_bytes_per_token
 
-        return 2 * self.n_blocks * kv_wire_bytes_per_token(
+        return 2 * len(self.kv_layers) * kv_wire_bytes_per_token(
             self.num_kv_heads, self.head_dim, self.kv_quant_type,
             jnp.dtype(self.cache_dtype).itemsize,
         )
@@ -515,6 +579,7 @@ class TransformerBackend:
         fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
+        self.refuse_for_state("the dense lane pool", "it has no place for the state: serve with page_size > 0")
 
         @tracked_jit(
             name="batched_decode", steady=True,
@@ -637,7 +702,7 @@ class TransformerBackend:
             paths.append(path)
         return paths[0] if len(paths) == 1 else "|".join(paths)
 
-    def _scan_paged_span(self, params, k_pool, v_pool, carry, layer):
+    def _scan_paged_span(self, params, k_pool, v_pool, carry, layer, state=(), state_layer=None):
         """The layer loop of every paged step program: ``_scan_span`` over
         the span's blocks with the page pools in the loop's CARRY, updated in
         place, and only the stacked weights (and the layer index) as ``xs``.
@@ -662,11 +727,22 @@ class TransformerBackend:
         ``block_apply``: ``paged(k_span, v_span, tables)`` wraps the
         carried pools and a set of block tables as that block's ``PagedKV``
         pair, and the pools ``block_apply`` hands back go on to the next
-        layer. Returns ``(carry, k_pool, v_pool)``, the pools in their
-        stacked shape."""
+        layer. Returns ``(carry, k_pool, v_pool, state)``, the pools in their
+        stacked shape.
+
+        A span with a recurrent state carries its STATE pool (``state``: one
+        array a leaf, ``[state layers, n_lanes, ...]``) through the same loop
+        beside the pages, donated and written in place as they are. The page
+        pools are then only as deep as the blocks that keep keys and values,
+        and a block's layer in its pool is its place among its own sort
+        (``_slots``), not its index in the span. A block of a kind that
+        declares a state runs ``state_layer(block_apply, carry, p_block,
+        mine) -> (carry, mine)`` on its layer of the state pool, ``mine``
+        one ``[n_lanes, ...]`` array a leaf, and touches no page."""
         from petals_tpu.ops.paged_attention import PagedKV
 
         depth, n_pages = k_pool.shape[0], k_pool.shape[1]
+        by_sort = bool(self.state_layers)
 
         def merged(pool):  # [depth, n_pages, ...] -> [depth * n_pages, ...]
             return jax.tree_util.tree_map(
@@ -678,21 +754,29 @@ class TransformerBackend:
                 lambda a: a.reshape(depth, n_pages, *a.shape[1:]), pool
             )
 
-        def one(block_apply, state, p_block, _x, block_idx):
-            inner, k_span, v_span = state
-            first_page = block_idx * n_pages
+        def one(block_apply, scanned, p_block, slot, block_idx, kind=None):
+            inner, k_span, v_span, state = scanned
+            if by_sort and self.family.state_for(self.cfg, kind) is not None:
+                mine = tuple(jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False) for a in state)
+                inner, mine = state_layer(block_apply, inner, p_block, mine)
+                state = tuple(
+                    jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype), slot, 0) for a, new in zip(state, mine)
+                )
+                return (inner, k_span, v_span, state), None
+            first_page = (slot if by_sort else block_idx) * n_pages
 
             def paged(k_span, v_span, tables):
                 shifted = jnp.where(tables >= 0, tables + first_page, -1)
                 own = (first_page, n_pages)
                 return PagedKV(k_span, shifted, own), PagedKV(v_span, shifted, own)
 
-            return layer(block_apply, inner, p_block, k_span, v_span, paged), None
+            return (*layer(block_apply, inner, p_block, k_span, v_span, paged), state), None
 
-        (carry, k_span, v_span), _ = self._scan_span(
-            params, (carry, merged(k_pool), merged(v_pool)), (), one
+        (carry, k_span, v_span, state), _ = self._scan_span(
+            params, (carry, merged(k_pool), merged(v_pool), tuple(state)),
+            jnp.asarray(self._slots, jnp.int32) if by_sort else (), one, pass_kind=by_sort,
         )
-        return carry, stacked(k_span), stacked(v_span)
+        return carry, stacked(k_span), stacked(v_span), state
 
     def _paged_lanes_layer(self, tables, positions):
         """``_scan_paged_span``'s ``layer`` for a step in which every lane
@@ -709,6 +793,27 @@ class TransformerBackend:
             return out, k_kv.pool, v_kv.pool
 
         return layer
+
+    def _state_lanes_layer(self, positions, max_length):
+        """``_scan_paged_span``'s ``state_layer`` for a step in which every
+        lane feeds one row at its own position: one ``block_apply`` over the
+        lanes' states. An idle lane (``positions`` at ``max_length``) is no
+        live row, and its state comes back as it went in."""
+        cfg = self.cfg
+
+        def layer(block_apply, h, p_block, mine):
+            return block_apply(p_block, h, mine, positions, cfg, use_flash=False, tp_mesh=None, live_rows=positions < max_length)
+
+        return layer
+
+    @staticmethod
+    def _with_state(results: tuple, state: tuple) -> tuple:
+        """A step program's results, its state pool last where it has one."""
+        return (*results, state) if state else results
+
+    @staticmethod
+    def _split_state(results: tuple, state: tuple) -> Tuple[tuple, tuple]:
+        return (results[:-1], tuple(results[-1])) if state else (results, ())
 
     @functools.cached_property
     def _paged_decode_fn(self):
@@ -734,25 +839,27 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_decode", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2),
+            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2, 6),
         )
-        def step(params, k_pool, v_pool, hidden, positions, tables,
+        def step(params, k_pool, v_pool, hidden, positions, tables, state=(),
                  *, kernel_path: str, with_fp: bool):
             # hidden: [n_lanes, 1, hidden]; positions: [n_lanes] int32;
-            # tables: [n_lanes, max_pages] int32 (-1 = unallocated slot)
+            # tables: [n_lanes, max_pages] int32 (-1 = unallocated slot);
+            # state: the state pool's leaves, none for a span without one
             del kernel_path  # static retrace trigger; attend() re-resolves
             hidden = hidden.astype(cache_dtype)
-            hidden, k_pool, v_pool = self._scan_paged_span(
+            hidden, k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
                 self._paged_lanes_layer(tables, positions),
+                state, self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2]),
             )
             if with_fp:
                 # same projection as the dense program: path-invariance —
                 # identical tokens through dense vs paged yield identical
                 # digests (the PR 2/3 bit-exactness contract, observable)
                 fp = fp_ops.fingerprint_rows(hidden[:, -1, :], fp_proj)
-                return hidden, k_pool, v_pool, fp
-            return hidden, k_pool, v_pool
+                return self._with_state((hidden, k_pool, v_pool, fp), state)
+            return self._with_state((hidden, k_pool, v_pool), state)
 
         return step
 
@@ -762,11 +869,13 @@ class TransformerBackend:
 
         Args:
           hidden: [n_lanes, 1, hidden] (idle lanes: any finite filler).
-          pool_kv: (k, v) page pools [n_blocks, n_pages, page_size, hkv, d].
+          pool_kv: (k, v) page pools [kv layers, n_pages, page_size, hkv, d],
+            then the state pool's leaves for a span that keeps one; they
+            come back in the same order.
           positions: int32 [n_lanes]; idle sentinel = max_pages * page_size.
           tables: int32 [n_lanes, max_pages] block tables (-1 unallocated).
         """
-        k_pool, v_pool = pool_kv
+        k_pool, v_pool, *state = pool_kv
         tables = np.asarray(tables, np.int32)
         kernel_path = self._paged_kernel_path(k_pool, tables)
         if not isinstance(hidden, jax.Array):
@@ -775,15 +884,16 @@ class TransformerBackend:
         with self._quant_ctx():
             res = self._paged_decode_fn(
                 self.params, k_pool, v_pool, hidden,
-                np.asarray(positions, np.int32), tables,
+                np.asarray(positions, np.int32), tables, tuple(state),
                 kernel_path=kernel_path, with_fp=with_fp,
             )
+        res, state = self._split_state(res, state)
         if with_fp:
             out, k_pool, v_pool, self._last_step_fp = res
         else:
             out, k_pool, v_pool = res
             self._last_step_fp = None
-        return out, (k_pool, v_pool)
+        return out, (k_pool, v_pool, *state)
 
     @functools.cached_property
     def _paged_gen_decode_fn(self):
@@ -800,11 +910,11 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_gen_decode", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(2, 3),
+            static_argnames=("kernel_path", "with_fp"), donate_argnums=(2, 3, 17),
         )
         def step(params, client_params, k_pool, v_pool, hidden, tokens,
                  use_token, positions, do_sample, temperature, top_k, top_p,
-                 rep_penalty, seeds, draw_idx, seen_mask, tables,
+                 rep_penalty, seeds, draw_idx, seen_mask, tables, state=(),
                  *, kernel_path: str, with_fp: bool):
             del kernel_path  # static retrace trigger; attend() re-resolves
             emb = client_embed(client_params, tokens[:, None], cfg)
@@ -813,9 +923,10 @@ class TransformerBackend:
                 emb.astype(cache_dtype),
                 hidden.astype(cache_dtype),
             )
-            hidden, k_pool, v_pool = self._scan_paged_span(
+            hidden, k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
                 self._paged_lanes_layer(tables, positions),
+                state, self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2]),
             )
             logits = client_head(client_params, hidden, cfg)[:, -1, :]
             next_tok = sample_tokens(
@@ -825,8 +936,8 @@ class TransformerBackend:
             )
             if with_fp:
                 fp = fp_ops.fingerprint_rows(hidden[:, -1, :], fp_proj)
-                return hidden, next_tok, k_pool, v_pool, fp
-            return hidden, next_tok, k_pool, v_pool
+                return self._with_state((hidden, next_tok, k_pool, v_pool, fp), state)
+            return self._with_state((hidden, next_tok, k_pool, v_pool), state)
 
         return step
 
@@ -835,7 +946,7 @@ class TransformerBackend:
                               handles=None):
         """Paged twin of ``batched_gen_decode_step`` (same argument contract
         plus the block tables)."""
-        k_pool, v_pool = pool_kv
+        k_pool, v_pool, *state = pool_kv
         tables = np.asarray(tables, np.int32)
         kernel_path = self._paged_kernel_path(k_pool, tables)
         if not isinstance(hidden, jax.Array):
@@ -849,15 +960,16 @@ class TransformerBackend:
                 np.asarray(positions, np.int32), v["do_sample"],
                 v["temperature"], v["top_k"], v["top_p"],
                 v["repetition_penalty"], v["seeds"], v["draw_idx"],
-                v["seen_mask"], tables, kernel_path=kernel_path,
+                v["seen_mask"], tables, tuple(state), kernel_path=kernel_path,
                 with_fp=with_fp,
             )
+        res, state = self._split_state(res, state)
         if with_fp:
             out, toks, k_pool, v_pool, self._last_step_fp = res
         else:
             out, toks, k_pool, v_pool = res
             self._last_step_fp = None
-        return out, toks, (k_pool, v_pool)
+        return out, toks, (k_pool, v_pool, *state)
 
     @functools.cached_property
     def _paged_spec_verify_fn(self):
@@ -910,7 +1022,7 @@ class TransformerBackend:
             del kernel_path  # static retrace trigger; attend() re-resolves
             S = tokens.shape[1]
             hidden = client_embed(client_params, tokens, cfg).astype(cache_dtype)
-            hidden, k_pool, v_pool = self._scan_paged_span(
+            hidden, k_pool, v_pool, _ = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
                 self._paged_lanes_layer(tables, positions),
             )
@@ -966,6 +1078,7 @@ class TransformerBackend:
         Returns (g_hat [n_lanes, spec_k+1] int32, n_emit [n_lanes] int32,
         pool_kv): lane i must commit exactly g_hat[i, :n_emit[i]].
         """
+        self.refuse_for_state("speculative verify", SPEC_CUTS_BACK)
         k_pool, v_pool = pool_kv
         tables = np.asarray(tables, np.int32)
         kernel_path = self._paged_kernel_path(k_pool, tables)
@@ -1014,11 +1127,11 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_mixed_step", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2),
+            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2, 11),
         )
         def step(params, k_pool, v_pool, hidden, positions, tables,
                  chunk_hidden, chunk_lane, chunk_pos, chunk_n_valid,
-                 chunk_n_total, *, kernel_path: str, with_fp: bool):
+                 chunk_n_total, state=(), *, kernel_path: str, with_fp: bool):
             # hidden: [n_lanes, 1, hidden]; positions: [n_lanes] int32 (idle
             # sentinel = max_len); chunk_hidden: [1, B, hidden] (B = static
             # bucket); chunk_lane/chunk_pos/chunk_n_valid/chunk_n_total:
@@ -1043,8 +1156,27 @@ class TransformerBackend:
                 )
                 return (out_dec, out_pf), k_kv.pool, v_kv.pool
 
-            (hidden, chunk_out), k_pool, v_pool = self._scan_paged_span(
-                params, k_pool, v_pool, (hidden, chunk_hidden), layer
+            decode_state = self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2])
+
+            def state_layer(block_apply, carry, p_block, mine):
+                # the lanes' rows through the one-step form, then the chunk
+                # through the chunked form from its lane's state on (that lane
+                # is idle in the decode half, which left its state alone),
+                # leaving the state and the conv's tail for the next chunk or
+                # the first decode step
+                h_dec, h_pf = carry
+                out_dec, mine = decode_state(block_apply, h_dec, p_block, mine)
+                lane = tuple(jax.lax.dynamic_index_in_dim(a, chunk_lane, 0, keepdims=True) for a in mine)
+                out_pf, lane = block_apply(
+                    p_block, h_pf, lane, chunk_pos, cfg, use_flash=False, n_valid=chunk_n_valid, tp_mesh=None,
+                )
+                mine = tuple(
+                    jax.lax.dynamic_update_index_in_dim(a, new[0].astype(a.dtype), chunk_lane, 0) for a, new in zip(mine, lane)
+                )
+                return (out_dec, out_pf), mine
+
+            (hidden, chunk_out), k_pool, v_pool, state = self._scan_paged_span(
+                params, k_pool, v_pool, (hidden, chunk_hidden), layer, state, state_layer
             )
             if with_fp:
                 fp = fp_ops.fingerprint_rows(hidden[:, -1, :], fp_proj)
@@ -1055,14 +1187,14 @@ class TransformerBackend:
                     chunk_out[0], jnp.clip(chunk_n_valid - 1, 0, B - 1), axis=0
                 )
                 chunk_fp = fp_ops.fingerprint_rows(last_row[None, :], fp_proj)[0]
-                return hidden, chunk_out, k_pool, v_pool, fp, chunk_fp
-            return hidden, chunk_out, k_pool, v_pool
+                return self._with_state((hidden, chunk_out, k_pool, v_pool, fp, chunk_fp), state)
+            return self._with_state((hidden, chunk_out, k_pool, v_pool), state)
 
         return step
 
     def paged_mixed_step(self, hidden, pool_kv, positions, tables,
                          chunk_hidden, chunk_lane, chunk_pos, *,
-                         n_total=None, handles=None):
+                         n_total=None, handles=None, trim: bool = True):
         """One coalesced mixed step: every decode lane (1 token each) plus
         ONE prefill chunk for ``chunk_lane``, in a single jitted program.
 
@@ -1081,9 +1213,16 @@ class TransformerBackend:
             selection — same contract as inference_step); defaults to
             chunk_pos + seq.
 
+          trim: cut ``chunk_out`` back from its bucket to ``seq`` rows here,
+            on the device. That slice is an eager op, compiled once a
+            (bucket, seq) pair: 0.1-0.3 s on the compute thread with every
+            lane waiting, and a mix of long prompts ends nearly every prompt
+            on a length of its own. A caller that fetches the rows anyway
+            (the batcher) passes False and cuts them on the host.
+
         Returns (decode_out [n_lanes, 1, h], chunk_out [1, seq, h], pool_kv).
         """
-        k_pool, v_pool = pool_kv
+        k_pool, v_pool, *state = pool_kv
         tables = np.asarray(tables, np.int32)
         kernel_path = self._paged_kernel_path(k_pool, tables, mixed=True)
         if not isinstance(hidden, jax.Array):
@@ -1108,18 +1247,19 @@ class TransformerBackend:
                 self.params, k_pool, v_pool, hidden,
                 np.asarray(positions, np.int32), tables, chunk_hidden,
                 np.int32(chunk_lane), np.int32(chunk_pos), np.int32(seq),
-                np.int32(n_total), kernel_path=kernel_path,
+                np.int32(n_total), tuple(state), kernel_path=kernel_path,
                 with_fp=with_fp,
             )
+        res, state = self._split_state(res, state)
         if with_fp:
             out, chunk_out, k_pool, v_pool, self._last_step_fp, self._last_chunk_fp = res
         else:
             out, chunk_out, k_pool, v_pool = res
             self._last_step_fp = None
             self._last_chunk_fp = None
-        if chunk_out.shape[1] != seq:
+        if trim and chunk_out.shape[1] != seq:
             chunk_out = chunk_out[:, :seq]
-        return out, chunk_out, (k_pool, v_pool)
+        return out, chunk_out, (k_pool, v_pool, *state)
 
     @functools.cached_property
     def _paged_lane_gather_fn(self):
@@ -1232,6 +1372,31 @@ class TransformerBackend:
                 )
 
             return put(k_pool, k_pages), put(v_pool, v_pages)
+
+        return f
+
+    @functools.cached_property
+    def _lane_state_take_fn(self):
+        """One lane's states out of the state pool, ``[state layers, ...]`` a
+        leaf: what leaves with the lane's pages when it is swapped out.
+        Non-donating, as ``_swap_out_pages_fn``."""
+
+        @tracked_jit(name="lane_state_take")
+        def f(state, lane):
+            return tuple(jax.lax.dynamic_index_in_dim(a, lane, 1, keepdims=False) for a in state)
+
+        return f
+
+    @functools.cached_property
+    def _lane_state_put_fn(self):
+        """The donating twin of ``_lane_state_take_fn``: a lane's states back
+        into the pool, byte for byte."""
+
+        @tracked_jit(name="lane_state_put", donate_argnums=(0,))
+        def f(state, lane_state, lane):
+            return tuple(
+                jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype), lane, 1) for a, new in zip(state, lane_state)
+            )
 
         return f
 
@@ -1470,6 +1635,9 @@ class TransformerBackend:
         final token stays unfed, client-loop convention).
         Returns (tokens [batch, n_tokens] int32, (k_stack, v_stack))."""
         assert client_params is not None
+        self.refuse_for_state(
+            "server-side generation on a private cache", "only the paged lane pool's generation step carries the state"
+        )
         k_stack, v_stack = kv
         batch = k_stack.shape[1]
         if position + n_tokens - 1 > k_stack.shape[2]:
@@ -1658,6 +1826,10 @@ class TransformerBackend:
         variants (LongRoPE short/long factor selection) see the same n_total
         in every chunk instead of flipping factors mid-prompt. Defaults to
         position + seq — exact for unchunked callers."""
+        self.refuse_for_state(
+            "a step on a private or checked-out cache (deep prompts, beam search's hypo_ids, a session that took no lane)",
+            "only the paged lane pool's own step programs carry the state",
+        )
         k_stack, v_stack = kv
         max_length = k_stack.shape[2]
         batch, total_seq, _ = hidden.shape

@@ -188,6 +188,11 @@ class _LaneWaiter:
     trace_id: Optional[str] = None
 
 
+# what a span with a recurrent state answers a request for a lane's cache cut to a position
+_SNAPSHOT = "a snapshot of a lane's cache (session export, migration, parking, a stored prefix)"
+_SNAPSHOT_WHY = "it ships keys and values cut to a position; the state is not shipped yet and cannot be cut"
+
+
 class DecodeBatcher:
     """Shared-pool continuous batcher for one backend (one span of blocks)."""
 
@@ -237,6 +242,16 @@ class DecodeBatcher:
             self.page_size = None
             self.max_pages = 0
             self.n_pages = 0
+        # a span with a recurrent state (ModelFamily.block_state): a lane owns, beside its pages in the
+        # blocks that keep keys and values, its slot in the backend's STATE pool, taken and released
+        # with the lane; a row at position 0 starts from zeros, so a new tenant needs no clearing. Only
+        # the paged pool's step programs carry the state
+        self._n_state = len(getattr(backend, "lane_state", None) or ())
+        if self._n_state and self.page_size is None:
+            backend.refuse_for_state(
+                "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""),
+                "it has no place for the state: serve with page_size > 0",
+            )
         self._pages: Optional[PageAllocator] = None
         self._tables: Optional[np.ndarray] = None  # [n_lanes, max_pages] int32, -1 = unallocated
         # cached tables_are_contiguous result for the stats/debug surface
@@ -278,6 +293,9 @@ class DecodeBatcher:
                     "Speculative decoding needs the client leaves loaded "
                     "(gen_params): the verify step embeds and samples on device"
                 )
+            from petals_tpu.server.backend import SPEC_CUTS_BACK
+
+            self._refuse_for_state("speculative decoding", SPEC_CUTS_BACK)
         # the draft instance whose bucket shapes have been pre-compiled via
         # DraftModel.warmup (first spec tick, on the compute thread); keyed
         # on the object so a swapped-in draft re-warms
@@ -418,6 +436,11 @@ class DecodeBatcher:
         if self._windows:
             self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0, window_pages_held=0, window_pages_in_reach=0)
             self._lane_pos = np.zeros(n_lanes, np.int64)  # the last position each lane fed, for occupancy_info
+        if self._n_state:
+            # a family that declares a state only (_count_state): rows times state layers by the form
+            # their step gave them (the one-step form a decode row, the chunked form a prompt chunk), and,
+            # summed step by step over the lanes that fed rows, the bytes of state and of pages they hold
+            self.stats.update(linattn_recurrent_tokens=0, linattn_chunk_tokens=0, state_bytes_held=0, kv_bytes_held=0)
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
         # (telemetry.journal), and the pool gauges/counters feed the /metrics
@@ -450,6 +473,8 @@ class DecodeBatcher:
                 descs = self.backend.paged_cache_descriptors(
                     self.n_pages, self.page_size, 0, self.backend.n_blocks
                 )
+                if self._n_state:  # the state pool's leaves ride last
+                    descs = (*descs, *self.backend.state_cache_descriptors(self.n_lanes))
             else:
                 descs = self.backend.cache_descriptors(
                     self.n_lanes, self.max_length, 0, self.backend.n_blocks
@@ -511,16 +536,30 @@ class DecodeBatcher:
         quantized pool rides as 4 MemoryCache buffers (codes x2, scales x2)
         and is re-wrapped into PagedPool pytrees HERE, so every caller —
         step bodies, swap, COW, snapshots — keeps the 2-tuple shape."""
-        bufs = self.memory_cache.get_buffers(*self._handles)
+        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self._n_state])
         if len(bufs) == 4:
             from petals_tpu.ops.paged_attention import PagedPool
 
             return PagedPool(bufs[0], bufs[2]), PagedPool(bufs[1], bufs[3])
         return bufs
 
-    def _update(self, k_pool, v_pool) -> None:
+    def _state(self) -> tuple:
+        """The state pool's leaves, which the paged step programs take after
+        the pair of ``_buffers`` and hand back after it; none for a span
+        without a recurrent state."""
+        if not self._n_state:
+            return ()
+        return tuple(self.memory_cache.get_buffers(*self._handles[-self._n_state :]))
+
+    def _refuse_for_state(self, what: str, why: str) -> None:
+        if self._n_state:
+            self.backend.refuse_for_state(what, why)
+
+    def _update(self, k_pool, v_pool, *state) -> None:
         from petals_tpu.ops.paged_attention import PagedPool
 
+        for handle, leaf in zip(self._handles[-self._n_state :] if state else (), state):
+            self.memory_cache.update_cache(handle, leaf)
         if isinstance(k_pool, PagedPool):
             self.memory_cache.update_cache(self._handles[0], k_pool.codes)
             self.memory_cache.update_cache(self._handles[1], v_pool.codes)
@@ -913,6 +952,11 @@ class DecodeBatcher:
         # for unquantized backends)
         return self.backend.kv_bytes_per_token() * self.page_size
 
+    def _state_nbytes(self) -> int:
+        """What a lane holds whatever its context: its slot in the state
+        pool. 0 for a span without a recurrent state."""
+        return int(self.backend.state_bytes_per_lane()) if self._n_state else 0
+
     def _lane_lock(self, lane: int) -> AsyncTryLock:
         lock = self._lane_locks.get(lane)
         if lock is None:
@@ -1033,13 +1077,13 @@ class DecodeBatcher:
             if slots.size == 0:
                 return False
             pages = row[slots].astype(np.int32).copy()
-            nbytes = int(slots.size) * self._page_nbytes()
+            nbytes = int(slots.size) * self._page_nbytes() + self._state_nbytes()
             if not self.swap_pool.try_reserve(nbytes):
                 return False  # swap tier full: this victim is not preemptable
             slot.suspending = True
             try:
-                k_host, v_host = await self.queue.submit(
-                    self._swap_out_device, pages,
+                k_host, v_host, state_host = await self.queue.submit(
+                    self._swap_out_device, pages, lane,
                     priority=PRIORITY_INFERENCE, size=0,
                 )
             except asyncio.CancelledError:
@@ -1075,7 +1119,7 @@ class DecodeBatcher:
             self._tables_mutated()
             slot.swap = SwapEntry(
                 k=k_host, v=v_host, slots=slots, nbytes=nbytes, generation=gen,
-                suspended_at=time.monotonic(),
+                suspended_at=time.monotonic(), state=state_host,
             )
             slot.suspending = False
             sched.stats["preemptions"] += 1
@@ -1099,17 +1143,19 @@ class DecodeBatcher:
         finally:
             lock.release()
 
-    def _swap_out_device(self, pages: np.ndarray):
-        """Compute-thread body: gather the victim's pages and land them in
-        host RAM. Non-donating — the pool stays live; the pages only free
-        once the event loop validates and commits the suspend."""
+    def _swap_out_device(self, pages: np.ndarray, lane: int):
+        """Compute-thread body: gather the victim's pages, and its states
+        where the span keeps any, and land them in host RAM. Non-donating —
+        the pool stays live; the pages only free once the event loop
+        validates and commits the suspend."""
         with self._reset_lock:
             k_pool, v_pool = self._buffers()
             k, v = self.backend._swap_out_pages_fn(k_pool, v_pool, pages)
+            state = self.backend._lane_state_take_fn(self._state(), np.int32(lane)) if self._n_state else ()
             # per-leaf host copy: a quantized pool's SwapEntry holds a
             # PagedPool of numpy arrays — packed wire bytes, never fp pages
             to_host = lambda t: jax.tree_util.tree_map(np.asarray, t)
-            return to_host(k), to_host(v)
+            return to_host(k), to_host(v), to_host(tuple(state))
 
     async def _ensure_resident(self, lane: int) -> None:
         """Transparent resume: if ``lane`` is suspended (or a suspend is in
@@ -1185,7 +1231,8 @@ class DecodeBatcher:
             k_pool, v_pool = self.backend._swap_in_pages_fn(
                 k_pool, v_pool, entry.k, entry.v, pages
             )
-            self._update(k_pool, v_pool)
+            state = self.backend._lane_state_put_fn(self._state(), entry.state, np.int32(lane)) if self._n_state else ()
+            self._update(k_pool, v_pool, *state)
 
     async def _alloc_pages(self, lane: int, slots: np.ndarray) -> List[int]:
         """All-or-nothing page allocation for a swap-in: take len(slots)
@@ -1367,6 +1414,10 @@ class DecodeBatcher:
             # WIRE bytes/token (what a page actually costs under kv quant)
             info["kv_quant"] = getattr(self.backend, "kv_quant_type", "none")
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
+            if self._n_state:
+                # a lane's fixed part, beside what its pages cost a token, and what the busy lanes hold of it
+                info["state_bytes_per_lane"] = self._state_nbytes()
+                info["state_bytes_held"] = info["busy_lanes"] * self._state_nbytes()
             if self._windows and self._tables is not None:
                 # over the lanes that hold pages, at the last position each fed
                 live = np.flatnonzero((self._tables >= 0).any(axis=1))
@@ -2106,6 +2157,24 @@ class DecodeBatcher:
         self.stats["window_pages_held"] += held
         self.stats["window_pages_in_reach"] += reach
 
+    def _count_state(self, tables, positions, *, chunk=None) -> None:
+        """The state counters of one paged step (compute thread; a family
+        that declares a state only), from the shapes the step was started
+        with: every lane that fed a row took the one-step form in each state
+        layer, the ``chunk`` (lane, tokens) of a mixed step the chunked form;
+        and what those lanes hold, their slots in the state pool and their
+        pages in the blocks that keep keys and values."""
+        if not self._n_state or tables is None:
+            return
+        layers = len(self.backend.state_layers)
+        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
+        self.stats["linattn_recurrent_tokens"] += int(lanes.size) * layers
+        if chunk is not None:
+            lanes = np.append(lanes, chunk[0])
+            self.stats["linattn_chunk_tokens"] += int(chunk[1]) * layers
+        self.stats["state_bytes_held"] += int(lanes.size) * self._state_nbytes()
+        self.stats["kv_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
+
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
         variant = "paged" if self.page_size is not None else "dense"
@@ -2124,14 +2193,15 @@ class DecodeBatcher:
                 hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
                 positions[lane] = pos
             k_pool, v_pool = self._buffers()
+            state = self._state()
             # snapshot the tables: the event loop may grow OTHER lanes while
             # this step runs, but never slots this step reads unmasked or
             # writes (prepare_write ran before each entry was enqueued)
             tables = self._tables.copy() if self.page_size is not None else None
             phases.enter("dispatch")
             if tables is not None:
-                out, (k_pool, v_pool) = self.backend.paged_decode_step(
-                    hidden, (k_pool, v_pool), positions, tables,
+                out, (k_pool, v_pool, *state) = self.backend.paged_decode_step(
+                    hidden, (k_pool, v_pool, *state), positions, tables,
                     handles=self._handles,
                 )
             else:
@@ -2150,12 +2220,13 @@ class DecodeBatcher:
                     # the stale stepped buffers would silently break the 'reset
                     # leaves a zeroed pool' recovery invariant.
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
-                self._update(k_pool, v_pool)
+                self._update(k_pool, v_pool, *state)
             self.stats["batched_steps"] += 1
             self.stats["batched_tokens"] += len(batch)
             self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
             self._count_moe(len(batch))
             self._count_window(tables, positions)
+            self._count_state(tables, positions)
             duration = time.perf_counter() - t_step
             if self.page_size is not None:
                 tm.STEP_PAGED.observe(duration)
@@ -2240,23 +2311,24 @@ class DecodeBatcher:
                 positions[lane] = pos
             chunk = st.hidden[:, st.offset : st.offset + take]
             k_pool, v_pool = self._buffers()
+            state = self._state()
             tables = self._tables.copy()
             phases.enter("dispatch")
-            out, chunk_out, (k_pool, v_pool) = self.backend.paged_mixed_step(
-                hidden, (k_pool, v_pool), positions, tables,
+            out, chunk_out, (k_pool, v_pool, *state) = self.backend.paged_mixed_step(
+                hidden, (k_pool, v_pool, *state), positions, tables,
                 chunk, st.lane, st.position, n_total=st.n_total,
-                handles=self._handles,
+                handles=self._handles, trim=False,
             )
             phases.enter("wait")
             host_out = np.asarray(out)  # device sync: the step has fully executed
-            host_chunk = np.asarray(chunk_out)
+            host_chunk = np.asarray(chunk_out)[:, :take]  # the chunk's bucket, cut to its rows here and not on the device
             phases.enter("post")
             with self._reset_lock:
                 if expected != self._generation:
                     # see _run_batch: checked atomically with the swap so a reset
                     # landing mid-step leaves the freshly zeroed pool in place
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
-                self._update(k_pool, v_pool)
+                self._update(k_pool, v_pool, *state)
             self.stats["batched_steps"] += 1
             self.stats["batched_tokens"] += len(batch)
             self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
@@ -2267,6 +2339,7 @@ class DecodeBatcher:
             )
             self._count_moe(len(batch), chunk_tokens=take)
             self._count_window(tables, positions, chunk=(st.lane, st.position, take))
+            self._count_state(tables, positions, chunk=(st.lane, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2320,11 +2393,12 @@ class DecodeBatcher:
                 if st.seen is not None:
                     vecs["seen_mask"][lane] = st.seen
             k_pool, v_pool = self._buffers()
+            state = self._state()
             tables = self._tables.copy() if self.page_size is not None else None
             phases.enter("dispatch")
             if tables is not None:
-                out, toks, (k_pool, v_pool) = self.backend.paged_gen_decode_step(
-                    self.gen_params, hidden, tokens, use_token, (k_pool, v_pool),
+                out, toks, (k_pool, v_pool, *state) = self.backend.paged_gen_decode_step(
+                    self.gen_params, hidden, tokens, use_token, (k_pool, v_pool, *state),
                     positions, tables, sampling_vecs=vecs,
                     handles=self._handles,
                 )
@@ -2342,7 +2416,7 @@ class DecodeBatcher:
                     # see _run_batch: checked atomically with the swap so a reset
                     # landing mid-step leaves the freshly zeroed pool in place
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
-                self._update(k_pool, v_pool)
+                self._update(k_pool, v_pool, *state)
             self.stats["batched_steps"] += 1
             self.stats["batched_tokens"] += len(batch) + len(gen_states)
             self.stats["max_batch"] = max(
@@ -2355,6 +2429,7 @@ class DecodeBatcher:
             )
             self._count_moe(len(batch) + len(gen_states))
             self._count_window(tables, positions)
+            self._count_state(tables, positions)
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
@@ -2568,7 +2643,10 @@ class DecodeBatcher:
         ``write_range=(t0, t1)`` declares the token range the fn writes:
         paged mode allocates/forks those pages up front (prepare_write) so
         the check-in scatter has somewhere to land."""
-
+        self._refuse_for_state(
+            "an exclusive op on a checked-out lane (deep prompts, beam search's hypo_ids, a seeded or imported cache)",
+            "the lane's session-shaped view holds keys and values only",
+        )
         async with self._lane_busy(lane):
             self._check_lane(lane)
             if self.page_size is not None and write_range is not None:
@@ -2719,7 +2797,7 @@ class DecodeBatcher:
         device pair are the same slices still resident in HBM (None under
         lockstep, whose shards are per-process) — the prefix cache's device
         tier pins these so a later hit can seed without re-uploading."""
-
+        self._refuse_for_state(_SNAPSHOT, _SNAPSHOT_WHY)
         self._check_lane(lane)
 
         def run():
@@ -2759,6 +2837,7 @@ class DecodeBatcher:
         ``snapshot_lane``'s host pair, or None when the lane isn't suspended,
         is busy, or its swap entry doesn't cover ``[0, position)`` — the
         caller falls back to the device path."""
+        self._refuse_for_state(_SNAPSHOT, _SNAPSHOT_WHY)
         if self.page_size is None:
             return None
         slot = self._scheduler.lanes.get(lane)

@@ -186,6 +186,14 @@ class TransformerHandler:
         self.server_gen_params = server_gen_params
         self.draft_model = draft_model
         self.spec_k = spec_k
+        if prefix_cache_bytes > 0 and getattr(backend, "state_layers", None):
+            # a hit seeds a session's cache cut to the prefix's end, and a state cannot be cut back: off for
+            # a span that keeps one, by what its family declares (ModelFamily.block_state)
+            logger.info(
+                f"Prefix cache off: {len(backend.state_layers)} of the span's {backend.n_blocks} blocks keep a "
+                f"recurrent state, which cannot be cut back to a stored prefix"
+            )
+            prefix_cache_bytes = 0
         if prefix_cache_bytes > 0:
             from petals_tpu.server.prefix_cache import PrefixCache
             from petals_tpu.telemetry.ledger import get_ledger
@@ -1574,10 +1582,19 @@ class TransformerHandler:
                         raise ValueError(
                             f"start_from_position {start_from} is ahead of cache ({position})"
                         )
+                    if 0 < start_from < position:
+                        backend.refuse_for_state(
+                            f"start_from_position {start_from} behind the cache's position {position}",
+                            "a state cannot be cut back to an earlier position (0 starts the session over)",
+                        )
                     position = int(start_from)  # rollback (speculative decoding)
                     if reg is not None:
                         reg["position"] = position
 
+                if "kv_adopt" in step or "kv_import" in step:
+                    backend.refuse_for_state(
+                        "kv_adopt / kv_import", "they seed keys and values cut to a position; the state is not shipped yet"
+                    )
                 if "kv_adopt" in step:
                     # seed from KV already on this server (migrated or parked)
                     position = await self._install_kv_adopt(

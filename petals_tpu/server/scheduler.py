@@ -72,6 +72,8 @@ class SwapEntry:
     nbytes: int  # WIRE bytes reserved in the HostSwapPool
     generation: int
     suspended_at: float = 0.0  # time.monotonic() at swap-out commit
+    # the lane's slot of the state pool, [state layers, ...] a leaf, for a span with a recurrent state
+    state: tuple = ()
 
 
 @dataclasses.dataclass

@@ -895,6 +895,8 @@ class Server:
                 # quantized pool pages cost wire bytes on device too (packed
                 # codes + f32 scales), so the budget affords ~4x the lanes
                 lane_bytes = self.backend.kv_bytes_per_token() * batch_max_length
+            # pages in the blocks that keep keys and values, and the lane's fixed part: its states
+            lane_bytes += self.backend.state_bytes_per_lane()
             affordable = int(self.memory_cache.max_size_bytes // 2 // max(lane_bytes, 1))
             batch_lanes = max(min(8, affordable), 0)
         return TransformerHandler(
@@ -933,6 +935,9 @@ class Server:
         Any load failure degrades to plain decode, never a dead server."""
         if not self.draft_model_path or self.spec_k < 1:
             return None
+        from petals_tpu.server.backend import SPEC_CUTS_BACK
+
+        self.backend.refuse_for_state("speculative decoding (--draft_model)", SPEC_CUTS_BACK)
         if (
             not self.server_side_generation
             or self.num_blocks != self.cfg.num_hidden_layers

@@ -167,15 +167,23 @@ def measure_compute_rps(
         memory_cache=MemoryCache(None), compute_dtype=compute_dtype, mesh=mesh,
     )
 
-    kd, vd = backend.cache_descriptors(1, 256, 0, 1)
-    kv = (kd.make_zeros(), vd.make_zeros())
     token = np.zeros((1, 1, cfg.hidden_size), np.float32)
+    if backend.state_layers:
+        # a block with a recurrent state has no private cache: the path that serves it is the paged
+        # lane pool's step, here over one lane of four pages beside its slot of the state pool
+        kv = tuple(d.make_zeros() for d in (*backend.paged_cache_descriptors(4, 64, 0, 1), *backend.state_cache_descriptors(1)))
+        tables = np.arange(4, dtype=np.int32)[None]
+        step = lambda kv, position: backend.paged_decode_step(token, kv, np.full(1, position, np.int32), tables)
+    else:
+        kd, vd = backend.cache_descriptors(1, 256, 0, 1)
+        kv = (kd.make_zeros(), vd.make_zeros())
+        step = lambda kv, position: backend.inference_step(token, kv, position)
 
-    out, kv = backend.inference_step(token, kv, 0)
+    out, kv = step(kv, 0)
     jax.block_until_ready(out)
     t0 = time.perf_counter()
     for i in range(n_steps_inference):
-        out, kv = backend.inference_step(token, kv, i + 1)
+        out, kv = step(kv, i + 1)
     jax.block_until_ready(out)
     inference_rps = n_steps_inference / (time.perf_counter() - t0)
 

@@ -291,7 +291,7 @@ STEP_CASES = [
             "place, so each layer's w1, w3 and w2 (940 MB each) are sliced out first (ROADMAP S7)"
         ))] if (config_name, chunk) == ("mixtral-8x7b-span2", 256) else [],
     )
-    for config_name in ("falcon-40b-span5", "mixtral-8x7b-span2", "olmoe-1b-7b-span8", "k-exaone-236b-span5-ep8")
+    for config_name in ("falcon-40b-span5", "mixtral-8x7b-span2", "olmoe-1b-7b-span8", "k-exaone-236b-span5-ep8", "olmo-hybrid-7b-span16")
     for chunk in (0, 256)
 ]
 
@@ -317,17 +317,22 @@ def _compiled_step(v5e, tmp_path, config_name, chunk):
     )
     params = runs[0] if len(runs) == 1 else runs
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=depth, memory_cache=None)
-    pool = v5e((depth, n_pages, page_size, backend.num_kv_heads, backend.head_dim), BF16)
+    # as deep as the blocks that keep keys and values: all of them, but for a span with a recurrent state
+    pool = v5e((len(backend.kv_layers), n_pages, page_size, backend.num_kv_heads, backend.head_dim), BF16)
     avals = [params, pool, pool, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
     step = backend._paged_decode_fn
     if chunk:  # chunk_hidden, then chunk_lane, chunk_pos, chunk_n_valid, chunk_n_total
         step = backend._paged_mixed_step_fn
         avals += [v5e((1, chunk, cfg.hidden_size), BF16)] + [v5e((), I32)] * 4
+    donated = (1, 2)
+    if backend.state_layers:  # the state pool's leaves ride last and are donated with the pages
+        avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
+        donated += (len(avals) - 1,)
     # the raw step under tracked_jit: kernel_path only retraces, attend() resolves the path itself
     step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
-        hlo = jax.jit(step, donate_argnums=(1, 2)).lower(*avals).compile().as_text()
+        hlo = jax.jit(step, donate_argnums=donated).lower(*avals).compile().as_text()
     return hlo, runs, pool
 
 
@@ -341,6 +346,7 @@ def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_na
     ``wk`` / ``wv``, and Mixtral's and OLMoE's likewise."""
     hlo, runs, _ = _compiled_step(v5e, tmp_path, config_name, chunk)
     attention = [run[name].shape for run in runs if run["wq"].shape[0] > 1 for name in ("wq", "wk", "wv", "wo")]
+    attention = attention or [run[name].shape for run in runs for name in ("wq", "wk", "wv", "wo")]  # no run of full layers is a loop
     relayouts, seen = weight_relayouts(
         hlo, {tuple(p.shape) for run in runs for p in run.values()}, min(math.prod(shape[1:]) for shape in attention)
     )
@@ -498,3 +504,23 @@ def test_paged_step_leaves_the_page_pool_in_place(v5e, tmp_path, config_name, ch
     moves, loops_seen = pool_moves(hlo, tuple(pool.shape))
     assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
     assert not moves, f"the step moves the page pool around its {pool.shape[0]} layers: {moves}"
+
+
+@pytest.mark.parametrize("chunk", [0, 512], ids=["decode", "mixed-512"])
+def test_paged_step_leaves_the_state_pool_and_its_pages_in_place(v5e, tmp_path, chunk):
+    """A span with a recurrent state carries its state pool through the layer
+    loop beside the pages (``backend._scan_paged_span``): the compiled step of
+    olmo-hybrid-7b-span16 allocates no second state pool and copies none in
+    ``ENTRY`` (708 MB of float32 at 8 lanes and 12 layers), and the chunked
+    form's triangular solve compiles for the chip."""
+    hlo, _, pool = _compiled_step(v5e, tmp_path, "olmo-hybrid-7b-span16", chunk)
+    assert pool.shape[0] == 4
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    # the float32 state pool, and a page pool: four layers deep, each in a run of one block, which is no loop once
+    # compiled, so ``pool_moves``'s look into the loop bodies has nothing to see here and ENTRY holds it all
+    for what, elements in (("state", 12 * 8 * 30 * 96 * 192), ("page", math.prod(pool.shape))):
+        assert any(math.prod(dims) == elements for _, dims, _, _ in comps[entry]), f"the {what} pool was not found in ENTRY"
+        moved = [f"%{name} = {op}" for name, dims, op, rest in comps[entry] if math.prod(dims) == elements
+                 and (op == "copy" or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
+        assert not moved, f"the step moves the {what} pool: {moved}"

@@ -23,6 +23,7 @@ from tests.utils import (
     make_tiny_mistral,
     make_tiny_mixtral,
     make_tiny_exaone_moe,
+    make_tiny_olmo_hybrid,
     make_tiny_olmoe,
     make_tiny_phi3,
     make_tiny_qwen2,
@@ -35,7 +36,7 @@ MAKERS = {
     "llama": make_tiny_llama, "bloom": make_tiny_bloom, "falcon": make_tiny_falcon,
     "mixtral": make_tiny_mixtral, "olmoe": make_tiny_olmoe, "qwen2": make_tiny_qwen2,
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
-    "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe,
+    "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 

@@ -696,3 +696,69 @@ def make_tiny_exaone_moe(tmpdir: str, *, held: int = 16, first: int = 0) -> str:
         json.dump(config, f)
     save_file(tiny_exaone_moe_tensors(config), os.path.join(path, "model.safetensors"))
     return path
+
+
+TINY_OLMO_HYBRID = {  # the keys Olmo-Hybrid publishes, at a toy size: two periods of three linear layers and a full one
+    "model_type": "olmo_hybrid", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "intermediate_size": 128, "num_hidden_layers": 8, "hidden_act": "silu", "attention_bias": False,
+    "layer_types": (["linear_attention"] * 3 + ["full_attention"]) * 2,
+    "linear_num_key_heads": 4, "linear_num_value_heads": 4, "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+    "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True, "rope_parameters": {"rope_theta": None},
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 256, "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_olmo_hybrid_tensors(config: dict, seed: int = 17) -> dict:
+    """Seeded float32 tensors under the HF names of every layer of ``config``
+    (``assumed.tensor_names`` of perf/configs/olmo-hybrid-7b-span16.json), the
+    embedding, the final norm and the head. Norm vectors are drawn, not ones,
+    so a missing or misplaced one shows; A and the step spread alpha over
+    about 0.3-0.99."""
+    rng = np.random.RandomState(seed)
+    h, hq, hkv, m = (config[k] for k in ("hidden_size", "num_attention_heads", "num_key_value_heads", "intermediate_size"))
+    d = h // hq
+    heads, d_k, d_v, taps = (config[k] for k in ("linear_num_value_heads", "linear_key_head_dim", "linear_value_head_dim", "linear_conv_kernel_dim"))
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda n: rng.uniform(0.5, 1.5, n).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": norm(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i, kind in enumerate(config["layer_types"]):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "post_attention_layernorm.weight": norm(h), p + "post_feedforward_layernorm.weight": norm(h),
+            p + "mlp.gate_proj.weight": normal(m, h), p + "mlp.up_proj.weight": normal(m, h), p + "mlp.down_proj.weight": normal(h, m),
+        })
+        if kind == "full_attention":
+            tensors.update({
+                p + "self_attn.q_proj.weight": normal(hq * d, h), p + "self_attn.k_proj.weight": normal(hkv * d, h),
+                p + "self_attn.v_proj.weight": normal(hkv * d, h), p + "self_attn.o_proj.weight": normal(h, hq * d),
+                p + "self_attn.q_norm.weight": norm(hq * d), p + "self_attn.k_norm.weight": norm(hkv * d),
+            })
+            continue
+        q = p + "linear_attn."
+        dt = np.exp(rng.uniform(np.log(0.001), np.log(0.1), heads))
+        tensors.update({
+            q + "q_proj.weight": normal(heads * d_k, h), q + "k_proj.weight": normal(heads * d_k, h),
+            q + "v_proj.weight": normal(heads * d_v, h), q + "g_proj.weight": normal(heads * d_v, h),
+            q + "a_proj.weight": normal(heads, h), q + "b_proj.weight": normal(heads, h), q + "o_proj.weight": normal(h, heads * d_v),
+            q + "conv1d.weight": (rng.standard_normal((heads * (2 * d_k + d_v), 1, taps)) * 0.4).astype(np.float32),
+            q + "A_log": np.log(rng.uniform(1, 16, heads)).astype(np.float32),
+            q + "dt_bias": (dt + np.log(-np.expm1(-dt))).astype(np.float32), q + "o_norm.weight": norm(d_v),
+        })
+    return tensors
+
+
+@_model_build_cache
+def make_tiny_olmo_hybrid(tmpdir: str) -> str:
+    """An Olmo-Hybrid checkpoint at a toy size, written by hand (the installed
+    transformers has no class for ``olmo_hybrid``)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    path = os.path.join(tmpdir, "tiny-olmo-hybrid")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(TINY_OLMO_HYBRID, f)
+    save_file(tiny_olmo_hybrid_tensors(TINY_OLMO_HYBRID), os.path.join(path, "model.safetensors"))
+    return path
