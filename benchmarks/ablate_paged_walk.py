@@ -1,0 +1,126 @@
+"""A decode row's attention over paged keys and values on the real chip: the
+walk in blocks of table slots (ops/paged_flash_attention.py
+``composed_paged_attend``) at every block width, beside the path it replaced
+(gather every slot of every lane, ``attend_reference`` over the dense view),
+one layer's call at the cells' pool geometries and at lengths their traffic
+gives the lanes.
+
+    chiprun -- python3 benchmarks/ablate_paged_walk.py [shape ...]
+
+What ``WALK_BLOCK_BYTES`` was set from (PERF.md section 5, PR 36). A call is
+timed as the slope between chains of 2 and 10 calls in one program, each link
+fed the last one's output and its tables made to wait for it, so that XLA can
+neither drop a link nor gather once for all of them; the pools ride as jit
+arguments. ``live MB`` is what the lanes hold of keys and values at those
+lengths, ``floor ms`` that over 819 GB/s. On the CPU the numbers mean nothing
+and the sizes are cut to a toy.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+# lanes, table slots, page size, kv heads (as the pool keeps them), head dim, q heads a kv head, the lanes' lengths
+SHAPES = {
+    "olmo-hybrid-7b": (8, 40, 64, 32, 128, 1, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k: 1,024-2,560
+    "olmoe-1b-7b": (8, 16, 64, 16, 128, 1, (90, 130, 170, 210, 250, 290, 330, 370)),  # saturated: 64-128 in, 256 out
+    "mixtral-8x7b": (8, 16, 64, 8, 128, 4, (90, 130, 170, 210, 250, 290, 330, 370)),
+    "falcon-40b": (8, 16, 64, 8, 64, 16, (90, 130, 170, 210, 250, 290, 330, 370)),
+    "k-exaone-236b": (8, 16, 64, 8, 128, 8, (90, 130, 170, 210, 250, 290, 330, 370)),
+    "olmo-hybrid-7b-full": (8, 40, 64, 32, 128, 1, (2559,) * 8),  # every lane at the table's end
+    "olmo-hybrid-7b-one": (8, 40, 64, 32, 128, 1, (2300,) + (0,) * 7),  # one live lane, seven on the idle sentinel (a length of 0)
+}
+LINKS = (2, 10)
+FEED = 2.0 ** -10
+
+
+def main(names) -> None:
+    from petals_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from petals_tpu.ops import paged_attention as pa
+    from petals_tpu.ops import paged_flash_attention as pfa
+    from petals_tpu.ops.attention import attend_reference
+
+    on_chip = jax.default_backend() == "tpu"
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    sink_path = os.path.join(out_dir, "ablate_paged_walk.jsonl")
+
+    def dense(q, kp, vp, tb, pos):  # the path the walk replaced
+        return attend_reference(q, pa.gather_pages(kp, tb), pa.gather_pages(vp, tb), q_offset=pos, kv_length=pos + 1)
+
+    def walk(q, kp, vp, tb, pos):
+        return pfa.composed_paged_attend(q, kp, vp, tb, q_offset=pos, kv_length=pos + 1)
+
+    def timed(call, q, kp, vp, tb, pos) -> float:
+        def chain(n):
+            def f(qv, k, v, t, p):
+                a = qv
+                for _ in range(n):
+                    wait = (a.ravel()[0].astype(jnp.float32) * 0.0).astype(jnp.int32)  # the tables wait for the link before
+                    a = call(qv + a * FEED, k, v, t + wait, p)
+                return a
+            return jax.jit(f)
+        ts = {}
+        for n in LINKS:
+            f = chain(n)
+            jax.block_until_ready(f(q, kp, vp, tb, pos))
+            best = float("inf")
+            for _ in range(3):
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    out = f(q, kp, vp, tb, pos)
+                jax.block_until_ready(out)
+                best = min(best, (time.perf_counter() - t0) / 5)
+            ts[n] = best
+        return (ts[LINKS[1]] - ts[LINKS[0]]) / (LINKS[1] - LINKS[0]) * 1e3
+
+    for name in names:
+        n_lanes, max_pages, page_size, hkv, d, group, lengths = SHAPES[name]
+        if not on_chip:
+            hkv, d = min(hkv, 2), min(d, 32)
+        n_pages = n_lanes * max_pages
+        rng = np.random.default_rng(0)
+        tables = rng.permutation(n_pages).astype(np.int32).reshape(n_lanes, max_pages)
+        idle = np.asarray(lengths) == 0
+        pos = np.where(idle, max_pages * page_size, np.asarray(lengths) - 1).astype(np.int32)
+        held = np.where(idle, 0, -(-(pos + 1) // page_size))
+        for lane in range(n_lanes):
+            tables[lane, held[lane]:] = -1
+        kq, kk, kv = jax.random.split(jax.random.PRNGKey(0), 3)
+        q = jax.random.normal(kq, (n_lanes, 1, hkv * group, d), jnp.bfloat16)
+        kp = jax.random.normal(kk, (n_pages, page_size, hkv, d), jnp.bfloat16)
+        vp = jax.random.normal(kv, (n_pages, page_size, hkv, d), jnp.bfloat16)
+        args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
+        a_slot = n_lanes * page_size * hkv * d * 2
+        live_mb = 2 * int(np.where(idle, 0, pos + 1).sum()) * hkv * d * 2 / 1e6
+        want = np.asarray(jax.jit(dense)(*args), np.float32)[~idle]
+        rows = [("dense", None, timed(dense, *args))]
+        widths = sorted({w for w in (1, 2, 4, 8, 16, max_pages) if w <= max_pages})
+        for block in widths:
+            pfa.WALK_BLOCK_BYTES = (1 << (block - 1).bit_length()) * a_slot  # the whole row: the power of two over it
+            assert pfa.walk_block_pages(n_lanes, max_pages, page_size, hkv, d) == block
+            got = np.asarray(jax.jit(lambda *a: walk(*a))(*args), np.float32)[~idle]  # a new program a width
+            err = float(np.max(np.abs(got - want)))
+            rows.append((f"walk{block}", err, timed(walk, *args)))
+        for variant, err, ms in rows:
+            line = {"shape": name, "variant": variant, "ms": round(ms, 4), "max_err": err, "live_mb": round(live_mb, 1),
+                    "floor_ms": round(live_mb / 819e3 * 1e3, 4), "longest_slots": int(held.max()), "device": jax.devices()[0].device_kind}
+            print(json.dumps(line), flush=True)
+            with open(sink_path, "a") as sink:
+                print(json.dumps(line), file=sink)
+
+
+if __name__ == "__main__":
+    main(sys.argv[1:] or list(SHAPES))

@@ -10,23 +10,31 @@ Layout contract (per span block):
   ``-1`` marks an unallocated slot. ``max_pages * page_size == max_length``
   (the batcher rounds max_length up to a page multiple).
 - ragged lengths per lane ride the existing position vector: attention masks
-  with ``kv_length = position + 1``, so whatever garbage the gather pulls
-  from unallocated/stale pages is multiplied by an exact 0.0 mask weight
-  (ops/attention.py attend_reference) and contributes nothing. Pool content
-  is always finite (zero-init, only ever written with computed values), so
-  paged decode is numerically IDENTICAL to the dense path.
+  with ``kv_length = position + 1``, so whatever a read pulls from
+  unallocated/stale pages carries an exact 0.0 weight and contributes
+  nothing. Pool content is always finite (zero-init, only ever written with
+  computed values), so paged decode agrees with the dense path to float32
+  rounding: the same products, summed block by block.
 
 One attention path: the step programs no longer materialize a dense view in
 front of attention. The (pool, tables) pair rides through the model family's
 block code as a ``PagedKV`` pytree standing in for the dense KV buffer;
 ``models/common.py update_kv_cache`` scatters the new rows straight into the
 pool and ``ops/attention.py attend`` dispatches to the fused ragged kernel
-(ops/paged_flash_attention.py) — or, on CPU / when autotune prefers it, to
-the XLA-composed gather + attend_reference fallback kept in this module.
+(ops/paged_flash_attention.py) or, on CPU / when autotune prefers it, to
+the path composed from XLA (``composed_paged_attend`` there). That one has
+two forms. A decode row (one query row a lane) walks its lane's table in
+blocks of slots with a running softmax, up to the longest live lane's last
+page, each block gathered as the pool stores it (``gather_pages`` over the
+block's columns): it reads what the lanes hold, not the table's width, and
+writes no float32 copy of it. A prompt's chunk and a verify's rows gather the
+whole row (or a static window's reach of it) into a dense view for
+``attend_reference``, as the reference entry points kept in this module do.
 Dense is just the identity block table (lane i owns pages [i*max_pages,
 (i+1)*max_pages)): the identity gather yields byte-identical values to the
-dense reshape, so the XLA fallback stays bit-exact with the dense program,
-and the allocator still prefers identity pages so page reads stay streaming.
+dense reshape, so the dense-view form is bit-exact with the dense program and
+the walk within float32 rounding of it, and the allocator still prefers
+identity pages so page reads stay streaming.
 Sessions joining/leaving mutate TABLE VALUES, never shapes — one compiled
 program, no recompiles, which is the whole reason the dense lane pool
 existed (server/batching.py module docstring).
@@ -327,7 +335,10 @@ def gather_pages(pool: PoolLike, tables: jnp.ndarray) -> jnp.ndarray:
     not own that page (attention masks them to 0.0 weight either way, but
     the dense view escapes attention — kv export, debug dumps — so the
     fallback path must never alias another tenant's content). The fused
-    kernel skips -1 slots entirely, so both paths agree bit-for-bit.
+    kernel skips -1 slots entirely; behind a lane's length both give a
+    weight of exactly zero. ``tables`` may be a block of the table's columns
+    (a decode row's walk, ops/paged_flash_attention.py): the view is then
+    that block's, [n_lanes, block * page_size, hkv, d].
 
     A quantized ``PagedPool`` gathers codes AND scales (holes zero both, so
     a -1 slot dequantizes to exact zeros) and returns the dense bf16 view —

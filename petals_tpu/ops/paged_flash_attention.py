@@ -40,8 +40,9 @@ winner into the step program, the kernel winning only by ``KERNEL_MUST_WIN_BY``
 next); shape classes Mosaic cannot tile are kept off
 the kernel by a static predicate (``paged_kernel_unsupported``), never by
 catching a failed compile. ``PETALS_TPU_PAGED_KERNEL=pallas|xla|auto``
-overrides; off-TPU the XLA-composed path (gather_pages + attend_reference)
-is what runs, so tier-1 CPU runs never depend on interpret-mode Mosaic
+overrides; off-TPU the XLA-composed path (``composed_paged_attend``: a decode
+row's walk over its lane's pages, gather_pages + attend_reference for the
+rest) is what runs, so tier-1 CPU runs never depend on interpret-mode Mosaic
 semantics unless a test asks for the kernel explicitly.
 """
 
@@ -871,14 +872,13 @@ def paged_attend_dispatch(
     logit_softcap: Optional[float] = None,
 ) -> jnp.ndarray:
     """Route a PagedKV attention call (TRACE time, inside the step program)
-    to the fused kernel or the XLA-composed gather + attend_reference.
+    to the fused kernel or the XLA-composed path (``composed_paged_attend``).
 
     Decode vs prefill is distinguished by the position rank: per-lane [n]
     vectors are the decode contract (ragged kv_length = position + 1), a
     scalar is one lane's chunked-prefill bucket. Calls the kernel cannot
     express — gemma2's logit softcap and its TRACED effective window,
-    non-causal — always compose from XLA, with identical math to the old
-    gather/attend sandwich."""
+    non-causal — always compose from XLA."""
     from petals_tpu.ops.paged_attention import kv_quant_kind_of
 
     k_pool, tables = k_kv.pool, k_kv.tables
@@ -931,41 +931,144 @@ def paged_attend_dispatch(
 
 
 def window_pages(window, q_len: int, page_size: int, max_pages: int) -> int:
-    """Table slots the XLA-composed path gathers a lane for ``q_len`` query
-    rows: every slot, or under a STATIC window (and a causal mask) the few
+    """Table slots a lane's ``q_len`` query rows can reach on the XLA-composed
+    path: every slot, or under a STATIC window (and a causal mask) the few
     pages the first row's window and the last row can reach between them
-    (3 of 16 for a decode row, a window of 128 and pages of 64). The batcher
-    counts a step's gathered pages by this."""
+    (3 of 16 for a decode row, a window of 128 and pages of 64). A prompt's
+    chunk and a verify's rows gather that many; a decode row walks them in
+    blocks, as far as the longest live lane (``walk_pages``)."""
     if not isinstance(window, int) or window <= 0:
         return max_pages
     return min((q_len - 1 + window - 1 + page_size - 1) // page_size + 1, max_pages)
+
+
+# Keys (or values) a block of the decode walk holds at once, all lanes'. Small:
+# on the v5e a block of one page of 64 rows a lane is gathered into fast memory
+# and meets the dots there as it is stored, where a wider one is first written
+# out again in float32 (and, at a head_dim of 64, relaid), and a block past
+# the longest lane's end is read for nothing; a loop trip's fixed cost is 3-5
+# us, what 2-4 MB take to read. Set by benchmarks/ablate_paged_walk.py: one
+# page a block is the best or within 0.01 ms a layer of it at every cell's
+# pool (PERF.md section 5, PR 36); pages smaller than the cells' go several a
+# block.
+WALK_BLOCK_BYTES = 512 << 10
+
+
+def walk_block_pages(n_lanes: int, width: int, page_size: int, hkv: int, d: int, itemsize: int = 2) -> int:
+    """Table slots a block of the decode walk takes, from the shapes alone:
+    the largest power of two whose pages, over all lanes, stay within
+    ``WALK_BLOCK_BYTES`` a side, at least one and at most the ``width`` slots
+    there are to walk (one page of 64 rows at every cell's 8 lanes and 8-32
+    kv heads; four pages of 16 rows at 8 kv heads of 128)."""
+    a_slot = n_lanes * page_size * hkv * d * itemsize
+    block = 1
+    while block * 2 * a_slot <= WALK_BLOCK_BYTES:
+        block *= 2
+    return min(block, width)
+
+
+def walk_pages(needed: int, block: int) -> int:
+    """Table slots a lane is read over by a decode walk whose longest live
+    lane needs ``needed`` of them: whole blocks. The batcher counts a step's
+    pages by this, the walk's trip count is this over ``block``."""
+    return _round_up(needed, block)
+
+
+def _walk_decode_rows(
+    q, k_pool, v_pool, tables, *, q_pos, kv_len, alibi_slopes, sliding_window, scale, logit_softcap,
+):
+    """One query row a lane over its table's pages, in blocks of slots with a
+    running softmax: ``attend_reference`` over ``gather_pages`` without the
+    dense view. q [n_lanes, 1, hq, d]; ``q_pos`` / ``kv_len`` [n_lanes] count
+    from the table's first slot, and a lane of ``kv_len`` 0 (idle) attends to
+    nothing and answers zeros. The walk ends with the block that holds the
+    longest lane's last row: its trip count is data, the program is one.
+
+    A block's pages are gathered as the pool stores them (a quantised pool
+    dequantises to bf16, holes read zeros) and meet the dots in that dtype,
+    products summed in float32; max, sum and output run in float32, and the
+    weights are cast to V's dtype for their dot as the fused kernel's are."""
+    from petals_tpu.ops.paged_attention import gather_pages
+
+    n_lanes, width = tables.shape
+    page_size, hkv, d = k_pool.shape[1:]
+    group = q.shape[2] // hkv
+    block = walk_block_pages(n_lanes, width, page_size, hkv, d, jnp.dtype(k_pool.dtype).itemsize)
+    rows = block * page_size
+    tables = jnp.pad(tables, ((0, 0), (0, -width % block)), constant_values=-1)  # whole blocks; the rest are holes
+    qg = q.reshape(n_lanes, hkv, group, d)
+    scale = d**-0.5 if scale is None else scale
+    q_pos, kv_len = q_pos[:, None, None, None], kv_len[:, None, None, None]
+    slopes = None if alibi_slopes is None else alibi_slopes.astype(jnp.float32).reshape(hkv, group, 1)
+
+    def a_block(i, carry):
+        m, l, acc = carry
+        cols = jax.lax.dynamic_slice_in_dim(tables, i * block, block, axis=1)
+        k = gather_pages(k_pool, cols)  # [n_lanes, rows, hkv, d]
+        v = gather_pages(v_pool, cols)
+        s = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32) * scale
+        if logit_softcap is not None:
+            s = jnp.tanh(s / logit_softcap) * logit_softcap
+        kv_pos = i * rows + jnp.arange(rows, dtype=jnp.int32)
+        if slopes is not None:
+            s = s + slopes * kv_pos.astype(jnp.float32)
+        mask = (kv_pos < kv_len) & (kv_pos <= q_pos)
+        if sliding_window is not None:
+            mask = mask & (kv_pos > q_pos - sliding_window)
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1))
+        p = jnp.exp(s - m_new[..., None]) * mask
+        alpha = jnp.exp(m - m_new)
+        pv = jnp.einsum("bkgs,bskd->bkgd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        return m_new, l * alpha + p.sum(axis=-1), acc * alpha[..., None] + pv
+
+    heads = (n_lanes, hkv, group)
+    init = (jnp.full(heads, NEG_INF, jnp.float32), jnp.zeros(heads, jnp.float32), jnp.zeros((*heads, d), jnp.float32))
+    trips = jnp.minimum((jnp.max(kv_len) + rows - 1) // rows, tables.shape[1] // block)
+    _, l, acc = jax.lax.fori_loop(0, trips, a_block, init)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]
+    return out.reshape(n_lanes, 1, hkv * group, d).astype(q.dtype)
 
 
 def composed_paged_attend(
     q, k_pool, v_pool, tables, *, q_offset, kv_length, alibi_slopes=None, sliding_window=None,
     scale=None, causal: bool = True, logit_softcap=None,
 ):
-    """The XLA-composed paged attention: gather the lanes' pages into a dense
-    view and run ``attend_reference`` over it. Under a static window the view
-    holds only the pages a lane's rows can reach: its table row is cut to the
-    ``window_pages`` slots from the first row's window on, and positions are
-    counted from that slot's first one (the masks are differences of
-    positions, so the shift changes nothing; ALiBi is a difference too, but
-    its path is left as it was)."""
+    """The XLA-composed paged attention. A decode row (per-lane positions,
+    one query row a lane, causal) walks its lane's pages in blocks of slots,
+    as they are stored, up to the longest live lane's last one
+    (``_walk_decode_rows``): what it reads follows the lanes' lengths, not the
+    table's width, and agrees with the dense program to float32 rounding, not
+    to the bit. An idle lane (at the sentinel ``max_length``) counts as empty.
+    A prompt's chunk, a verify's rows and a non-causal call gather the lanes'
+    pages into a dense view and run ``attend_reference`` over it. Under a
+    static window either sees only the pages a lane's rows can reach: its
+    table row is cut to the ``window_pages`` slots from the first row's window
+    on, and positions are counted from that slot's first one (the masks are
+    differences of positions, so the shift changes nothing; ALiBi is a
+    difference too, but its path is left as it was)."""
     from petals_tpu.ops.attention import attend_reference
     from petals_tpu.ops.paged_attention import gather_pages
 
     n_lanes, max_pages = tables.shape
     page_size = k_pool.shape[1]
+    pos = jnp.asarray(q_offset, jnp.int32)
+    walk = pos.ndim == 1 and q.shape[1] == 1 and causal and kv_length is not None
+    live = pos < max_pages * page_size  # the idle sentinel is max_length
     reach = window_pages(sliding_window, q.shape[1], page_size, max_pages)
     if reach < max_pages and causal and alibi_slopes is None and kv_length is not None:
-        pos = jnp.asarray(q_offset, jnp.int32)
         first = jnp.maximum(pos - (sliding_window - 1), 0) // page_size  # first slot in reach: scalar or [n_lanes]
         slots = jnp.broadcast_to(first, (n_lanes,))[:, None] + jnp.arange(reach, dtype=jnp.int32)[None, :]
         taken = jnp.take_along_axis(tables, jnp.clip(slots, 0, max_pages - 1), axis=1)
         tables = jnp.where(slots < max_pages, taken, -1)
         q_offset = pos - first * page_size
         kv_length = jnp.asarray(kv_length, jnp.int32) - first * page_size
+    if walk:
+        kv_len = jnp.where(live, jnp.broadcast_to(jnp.asarray(kv_length, jnp.int32), (n_lanes,)), 0)
+        return _walk_decode_rows(
+            q, k_pool, v_pool, tables, q_pos=jnp.asarray(q_offset, jnp.int32), kv_len=kv_len,
+            alibi_slopes=alibi_slopes, sliding_window=sliding_window, scale=scale, logit_softcap=logit_softcap,
+        )
     k = gather_pages(k_pool, tables)
     v = gather_pages(v_pool, tables)
     return attend_reference(

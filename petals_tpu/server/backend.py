@@ -23,6 +23,7 @@ TPU-first redesign:
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import functools
@@ -665,14 +666,63 @@ class TransformerBackend:
         window = getattr(self.cfg, "sliding_window", None)
         return [window if isinstance(window, int) and window > 0 else None]
 
+    @functools.cached_property
+    def _window_layers(self) -> tuple:
+        """``((window, layers), ...)``: the static windows of the span's blocks
+        that keep keys and values (None: full attention) and how many blocks
+        have each."""
+        if self.layer_windows is not None:
+            windows = [self.layer_windows[i] for i in self.kv_layers]
+        else:
+            windows = self._static_windows() * len(self.kv_layers)
+        return tuple(collections.Counter(windows).items())
+
     def pages_gathered(self, q_len: int, max_pages: int, page_size: int) -> int:
         """Table slots one lane's ``q_len`` rows gather over the span's layers
-        in a paged step program (ops/paged_flash_attention.py ``window_pages``:
-        a windowed layer gathers the pages in its reach, a full one its whole
+        where a paged step program makes the dense view (a prompt's chunk, a
+        verify's rows; ops/paged_flash_attention.py ``window_pages``: a
+        windowed layer gathers the pages in its reach, a full one its whole
         table row). For the batcher's ``attn_pages_gathered``."""
         from petals_tpu.ops.paged_flash_attention import window_pages
 
-        return sum(window_pages(w, q_len, page_size, max_pages) for w in self.layer_windows)
+        return sum(layers * window_pages(w, q_len, page_size, max_pages) for w, layers in self._window_layers)
+
+    def decode_walks(self, n_lanes: int, max_pages: int, page_size: int) -> tuple:
+        """``((window, layers, block, cut), ...)``: how a decode step's
+        programs walk a lane pool's tables, a distinct window of the span's
+        layers: the block's width in slots
+        (ops/paged_flash_attention.py ``walk_block_pages``) and whether the
+        table row is first cut to the window's reach. Fixed with the pool's
+        geometry: the batcher asks once."""
+        from petals_tpu.ops.paged_flash_attention import walk_block_pages, window_pages
+
+        itemsize = 2 if self.kv_quant_type != "none" else jnp.dtype(self.cache_dtype).itemsize  # a quantised pool reads as bf16
+        walks = []
+        for window, layers in self._window_layers:
+            width = window_pages(window, 1, page_size, max_pages)
+            block = walk_block_pages(n_lanes, width, page_size, self.num_kv_heads, self.head_dim, itemsize)
+            walks.append((window, layers, block, width < max_pages))
+        return tuple(walks)
+
+    @staticmethod
+    def pages_walked(walks: tuple, last: np.ndarray, page_size: int) -> int:
+        """Table slots a decode step's programs read a lane over the span's
+        layers, its live lanes at the positions ``last``: each layer walks
+        its table (the slots in its window's reach, if ``cut``) in blocks, up
+        to the block that holds the longest lane's last row
+        (ops/paged_flash_attention.py ``composed_paged_attend``, whose
+        arithmetic this is). For the batcher's ``attn_pages_gathered``: a
+        maximum over the lanes and integer arithmetic, a step."""
+        from petals_tpu.ops.paged_flash_attention import walk_pages
+
+        longest = int(last.max())
+        walked = 0
+        for window, layers, block, cut in walks:
+            needed = longest // page_size + 1
+            if cut:  # each lane's slots count from its own window's first one
+                needed = int(np.max(last // page_size - np.maximum(last - (window - 1), 0) // page_size)) + 1
+            walked += layers * walk_pages(needed, block)
+        return walked
 
     def _paged_kernel_path(self, k_pool, tables, *, mixed: bool = False) -> str:
         """Resolve (host-side, O(1) — no table scan) which attention path the

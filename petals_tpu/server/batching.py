@@ -429,12 +429,15 @@ class DecodeBatcher:
             # hit: the stacked-run kernel, the rest ragged_dot), and the times
             # a step's program walked a layer's experts
             self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_hit_tokens=0, moe_weight_passes=0)
-        # a family that declares its layers' windows only (_count_window), on the paged pool: the
-        # table slots the step programs gather against those they are handed, and of the pages the
-        # decoding lanes hold in windowed layers those their windows still reach (summed over steps)
+        # on the paged pool (_count_window): the table slots the step programs read against those they
+        # are handed; and, for a family that declares its layers' windows only, of the pages the decoding
+        # lanes hold in windowed layers those their windows still reach (summed over steps)
+        if self.page_size:
+            self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0)
+            self._walks = backend.decode_walks(n_lanes, self.max_pages, self.page_size)
         self._windows = [w for w in (getattr(backend, "layer_windows", None) or ()) if w] if self.page_size else []
         if self._windows:
-            self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0, window_pages_held=0, window_pages_in_reach=0)
+            self.stats.update(window_pages_held=0, window_pages_in_reach=0)
             self._lane_pos = np.zeros(n_lanes, np.int64)  # the last position each lane fed, for occupancy_info
         if self._n_state:
             # a family that declares a state only (_count_state): rows times state layers by the form
@@ -2133,24 +2136,34 @@ class DecodeBatcher:
         return int(held.sum()) * len(self._windows), reach
 
     def _count_window(self, tables, positions, *, seq: int = 1, chunk=None) -> None:
-        """The window counters of one paged step (compute thread; a family
-        that declares its layers' windows only), from the shapes the step was
-        started with: its programs gather for every lane of the pool, and for
-        the ``chunk`` (lane, first position, tokens) of a mixed step once more
-        at its bucket."""
-        if not self._windows or tables is None:
+        """The attention counters of one paged step (compute thread), from
+        the positions the step was started with: of the table slots its
+        programs are handed (every lane of the pool's, a layer that keeps keys
+        and values), those they read. A decode row's walk reads whole blocks
+        up to the longest live lane's last page, for every lane; a verify's
+        ``seq`` rows and the ``chunk`` (lane, first position, tokens) of a
+        mixed step, at its bucket, gather the slots in reach. For a family
+        that declares its layers' windows, the window counters besides."""
+        if "attn_pages_gathered" not in self.stats or tables is None:
             return
         from petals_tpu.server.backend import bucket_length
 
-        backend, layers = self.backend, self.backend.n_blocks
-        self.stats["attn_pages_gathered"] += self.n_lanes * backend.pages_gathered(seq, self.max_pages, self.page_size)
+        backend, layers = self.backend, len(self.backend.kv_layers)
+        last = positions[positions < self.max_length] + (seq - 1)  # the idle sentinel is max_length
+        if seq > 1:
+            read = backend.pages_gathered(seq, self.max_pages, self.page_size)
+        else:
+            read = backend.pages_walked(self._walks, last, self.page_size) if last.size else 0
+        self.stats["attn_pages_gathered"] += self.n_lanes * read
         self.stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
-        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
-        last = positions[lanes].astype(np.int64) + seq - 1
         if chunk is not None:
             lane, first, take = chunk
             self.stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
             self.stats["attn_pages_tabled"] += self.max_pages * layers
+        if not self._windows:
+            return
+        lanes, last = np.flatnonzero(positions < self.max_length), last.astype(np.int64)
+        if chunk is not None:
             lanes, last = np.append(lanes, lane), np.append(last, first + take - 1)
         self._lane_pos[lanes] = last
         held, reach = self._window_pages(tables, lanes, last)

@@ -400,24 +400,43 @@ def backend_reach(batcher) -> int:
     return batcher.n_lanes * batcher.backend.pages_gathered(1, batcher.max_pages, batcher.page_size)
 
 
-def test_a_family_without_declared_windows_has_no_window_counters(tmp_path):
+def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monkeypatch):
+    """Every family on the paged pool counts the table slots its steps read
+    against those they are handed; the pages a window still reaches are
+    counted for a family that declares windows alone. A decode step's count
+    follows the longest LIVE lane, in whole blocks of the walk."""
+    from petals_tpu.ops import paged_flash_attention as pfa
     from petals_tpu.server.batching import DecodeBatcher
     from petals_tpu.server.task_queue import PriorityTaskQueue
 
-    keys = {"attn_pages_gathered", "attn_pages_tabled", "window_pages_held", "window_pages_in_reach"}
+    read, held = {"attn_pages_gathered", "attn_pages_tabled"}, {"window_pages_held", "window_pages_in_reach"}
     path = make_tiny_falcon(str(tmp_path))
     family, cfg = get_block_config(path)
     stacked = jax.tree_util.tree_map(lambda leaf: leaf[None], load_block_params(path, 0, dtype=jnp.float32))
     backend = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=1, memory_cache=MemoryCache(None),
                                  compute_dtype=jnp.float32, use_flash=False)
+    # two lanes' pages of 16 rows at float32, one slot of the table a block (the batcher asks at its start)
+    monkeypatch.setattr(pfa, "WALK_BLOCK_BYTES", 2 * 16 * backend.num_kv_heads * backend.head_dim * 4)
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
-    assert not keys & set(batcher.stats) and backend.layer_windows is None and len(backend.runs) == 1
-    assert "moe_weight_passes" not in batcher.stats and not keys & set(batcher.occupancy_info())
+    assert read <= set(batcher.stats) and not held & set(batcher.stats) and backend.layer_windows is None and len(backend.runs) == 1
+    assert "moe_weight_passes" not in batcher.stats and not held & set(batcher.occupancy_info())
+    assert batcher._walks == ((None, 1, 1, False),)
+    tables = np.zeros((2, 4), np.int32)
+    for positions, walked in (([5, 64], 1), ([64, 16], 2), ([47, 0], 3), ([64, 64], 0), ([63, 64], 4)):
+        was = dict(batcher.stats)
+        batcher._count_window(tables, np.asarray(positions, np.int32))
+        assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == 2 * walked, positions  # an idle lane is no length
+        assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4
+    was = dict(batcher.stats)
+    batcher._count_window(tables, np.asarray([64, 3], np.int32), chunk=(0, 16, 20))  # a chunk gathers its lane's whole row
+    assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == 2 * 1 + 4
+    assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4 + 4
+    monkeypatch.undo()
     exaone = whole_backend(make_tiny_exaone_moe(str(tmp_path)))
     batcher = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
-    assert keys | {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"} <= set(batcher.stats)
+    assert read | held | {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"} <= set(batcher.stats)
     dense_pool = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=None)
-    assert not keys & set(dense_pool.stats)  # the counters count pages: the paged pool only
+    assert not (read | held) & set(dense_pool.stats)  # the counters count pages: the paged pool only
 
 
 def test_generate_token_identical_over_a_chain_of_two_spans(swarm):

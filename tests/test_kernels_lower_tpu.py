@@ -296,11 +296,12 @@ STEP_CASES = [
 ]
 
 
-def _compiled_step(v5e, tmp_path, config_name, chunk):
+def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16):
     """``(optimized HLO, stacked params, pool aval)`` of ``TransformerBackend``'s
     paged decode step, or of its mixed step with a prompt chunk of ``chunk``
-    riding it, at a cell's widths and depth (8 lanes, 128 pages of 64, 16
-    pages a lane, pools donated), compiled for the v5e."""
+    riding it, at a cell's widths and depth (8 lanes, pages of 64,
+    ``pages_a_lane`` slots a table and as many pages a lane in the pool, pools
+    donated), compiled for the v5e."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -309,7 +310,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk):
     hf_config = load_config(config_file, config_name)["config"]
     (tmp_path / "config.json").write_text(json.dumps(hf_config))
     family, cfg = get_block_config(str(tmp_path))
-    depth, lanes, n_pages, page_size, pages_a_lane = hf_config["num_hidden_layers"], 8, 128, 64, 16
+    depth, lanes, n_pages, page_size = hf_config["num_hidden_layers"], 8, 8 * pages_a_lane, 64
     # one stacked tree a run of consecutive blocks of one kind (K-EXAONE's five blocks: four runs of three trees)
     runs = tuple(
         {name: v5e((length, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind, BF16).items()}
@@ -504,6 +505,34 @@ def test_paged_step_leaves_the_page_pool_in_place(v5e, tmp_path, config_name, ch
     moves, loops_seen = pool_moves(hlo, tuple(pool.shape))
     assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
     assert not moves, f"the step moves the page pool around its {pool.shape[0]} layers: {moves}"
+
+
+@pytest.mark.parametrize("config_name,pages_a_lane", [("olmo-hybrid-7b-span16", 40), ("olmoe-1b-7b-span8", 16)])
+def test_decode_step_makes_no_dense_view_of_the_lanes_tables(v5e, tmp_path, config_name, pages_a_lane):
+    """A decode row walks its lane's pages in blocks (ops/paged_flash_attention.py
+    ``composed_paged_attend``): the compiled decode step, at the cell's table
+    width, produces no array of ``n_lanes x max_pages x page_size`` rows of
+    ``[hkv, d]`` or more besides the pool itself, in any dtype. Until PR 36 a
+    layer made four: the bf16 gather of every table slot of every lane, for
+    keys and for values, and ``attend_reference``'s float32 copy of each
+    (1.0 GB moved a layer and a step at 8 lanes of 40 pages and 32 kv heads)."""
+    hlo, _, pool = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane)
+    comps = _computations(hlo)
+    fused = {m.group(1) for instructions in comps.values() for _, _, op, rest in instructions if op == "fusion" and (m := re.search(r"calls=%([\w.\-]+)", rest))}
+    pool_elements, view_rows = math.prod(pool.shape), 8 * pages_a_lane * 64
+    seen, views = 0, []
+    for computation, instructions in comps.items():
+        if computation in fused:
+            continue  # what a fusion computes inside it is never an array in memory
+        for name, dims, op, _ in instructions:
+            if dims[-2:] != tuple(pool.shape[-2:]) or op in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+                continue
+            if math.prod(dims) == pool_elements:
+                seen += 1  # the pool, written in place by the new rows' scatter
+            elif math.prod(dims[:-2]) >= view_rows:
+                views.append(f"%{name} = {op} -> {list(dims)}")
+    assert seen, "the pool's scatter was not found: has the HLO text changed?"
+    assert not views, f"the decode step makes a dense view of the lanes' tables: {views}"
 
 
 @pytest.mark.parametrize("chunk", [0, 512], ids=["decode", "mixed-512"])

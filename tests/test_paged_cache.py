@@ -86,8 +86,9 @@ def _tiny_backend(model_path):
 
 def test_paged_decode_parity_direct(model_path):
     """Direct backend check of both compiled variants on a fixed seed:
-    identity tables (the contiguous fast path) must be BIT-exact with the
-    dense batched program, and a permuted/oversubscribed table layout (the
+    identity tables (the contiguous layout) must agree with the dense batched
+    program to float32 rounding (a decode row walks its pages block by block
+    with a running softmax: the dense program's sums in another order), and a permuted/oversubscribed table layout (the
     real gather/scatter path) must match per-lane scalar decode."""
     from petals_tpu.ops.paged_attention import identity_tables
 
@@ -127,14 +128,14 @@ def test_paged_decode_parity_direct(model_path):
                 vp[:, page] = v_dense[:, l, s * PS : (s + 1) * PS]
         return jnp.asarray(kp), jnp.asarray(vp)
 
-    # (a) identity layout == the dense program, bit-exact
+    # (a) identity layout == the dense program, to float32 rounding
     ident = identity_tables(L, MAX_PAGES)
     kp, vp = page_pool(ident, L * MAX_PAGES)
     out_paged, _ = backend.paged_decode_step(hidden, (kp, vp), positions, ident)
     out_dense, _ = backend.batched_decode_step(
         hidden, (jnp.asarray(k_dense), jnp.asarray(v_dense)), positions
     )
-    np.testing.assert_array_equal(np.asarray(out_paged), np.asarray(out_dense))
+    np.testing.assert_allclose(np.asarray(out_paged), np.asarray(out_dense), atol=1e-5, rtol=0)
 
     # (b) permuted, oversubscribed-pool layout (gather/scatter path): lanes
     # hold only the pages they need, scattered across a bigger pool

@@ -184,6 +184,91 @@ def test_decode_alibi_and_window(window):
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
 
 
+# ------------------------------------------------- the composed path's walk
+
+# n_lanes 4, kv heads 2; a lane's position, or None for an idle lane (the sentinel max_length). ``block``: table
+# slots a block of the walk (the rule gives the whole row at these toy sizes, so the cases set the bytes it goes by)
+WALK_CASES = {
+    "ragged-16slots-g1-d64": dict(max_pages=16, group=1, d=64, block=4, positions=[127, 3, 7, 0]),  # the table's end beside one page
+    "ragged-40slots-g4-d128": dict(max_pages=40, group=4, d=128, block=8, positions=[319, 5, 64, None]),
+    "g16-d64-idle-between": dict(max_pages=16, group=16, d=64, block=2, positions=[50, None, 9, 100]),
+    "holes-inside-the-length": dict(max_pages=16, group=4, d=64, block=4, positions=[90, 33, 8, 70], holes=[(0, 2), (3, 0)]),
+    "identity-tables": dict(max_pages=16, group=1, d=128, block=8, positions=[64, 63, None, 1], identity=True),
+    "one-block-is-the-row": dict(max_pages=16, group=4, d=64, block=16, positions=[127, 0, None, 40]),
+    "width-no-multiple-of-the-block": dict(max_pages=10, group=1, d=64, block=4, positions=[79, 31, 32, None]),
+    "every-lane-idle": dict(max_pages=16, group=1, d=64, block=4, positions=[None, None, None, None]),
+    "window128": dict(max_pages=16, group=4, d=64, block=4, ps=16, window=128, positions=[255, 10, 130, None]),
+    "int8-pool": dict(max_pages=16, group=4, d=64, block=4, positions=[127, 3, None, 77], kv_quant="int8"),
+    "nf4a-pool": dict(max_pages=40, group=1, d=128, block=8, positions=[200, 319, 15, None], kv_quant="nf4a"),
+    "alibi": dict(max_pages=16, group=4, d=64, block=4, positions=[100, 3, None, 31], alibi=True),
+    "softcap-traced-window": dict(max_pages=16, group=1, d=64, block=4, positions=[100, 3, None, 31], softcap=30.0, traced_window=20),
+}
+
+
+@pytest.mark.parametrize("case", WALK_CASES.values(), ids=WALK_CASES.keys())
+def test_decode_row_walks_its_lane_s_pages_and_gives_the_dense_view_s_answer(case, monkeypatch):
+    """``composed_paged_attend`` for a decode row (per-lane positions, one
+    query row a lane): the walk over the table in blocks of slots with a
+    running softmax against ``attend_reference`` over ``gather_pages`` of the
+    whole table, at float32-accumulation tolerance. And the walk ends with the
+    block that holds the longest LIVE lane's last row: every slot past that
+    block points at a page of NaN, in every lane's row, the idle lanes' too,
+    and no NaN comes out (a weight of zero times NaN is NaN)."""
+    from petals_tpu.ops.paged_attention import PagedPool, quantize_kv_rows
+
+    n_lanes, hkv, ps = 4, 2, case.get("ps", 8)
+    max_pages, group, d, block = case["max_pages"], case["group"], case["d"], case["block"]
+    rng = np.random.default_rng(11)
+    n_pages = n_lanes * max_pages + 1  # the last one is the page of NaN
+    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
+    kp, vp = kp.at[-1].set(jnp.nan), vp.at[-1].set(jnp.nan)
+    idle = np.asarray([p is None for p in case["positions"]])
+    pos = np.asarray([max_pages * ps if p is None else p for p in case["positions"]], np.int32)
+    held = np.where(idle, 0, pos // ps + 1)
+    if case.get("identity"):
+        tables = identity_tables(n_lanes, max_pages).copy()
+    else:
+        tables = rng.permutation(n_pages - 1).astype(np.int32).reshape(n_lanes, max_pages)
+    for lane in range(n_lanes):
+        tables[lane, held[lane]:] = -1  # a lane holds the pages its rows fill
+    for lane, slot in case.get("holes", ()):
+        tables[lane, slot] = -1
+    clean = tables.copy()
+    window = case.get("window")
+    if window is None:  # (under a static window each lane's row is cut to its own reach first: no common last block)
+        walked = -(-int(held.max()) // block) * block
+        tables[:, walked:] = n_pages - 1
+    kind = case.get("kv_quant")
+    if kind:
+        kp, vp = PagedPool(*quantize_kv_rows(kp, kind)), PagedPool(*quantize_kv_rows(vp, kind))
+    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hkv * group, d)), jnp.float32)
+    kw = dict(q_offset=jnp.asarray(pos), kv_length=jnp.asarray(pos) + 1, sliding_window=window)
+    if case.get("alibi"):
+        kw["alibi_slopes"] = jnp.asarray(rng.standard_normal(hkv * group) * 0.1, jnp.float32)
+    if case.get("softcap"):
+        kw.update(logit_softcap=case["softcap"], sliding_window=jnp.int32(case["traced_window"]))
+
+    monkeypatch.setattr(pfa, "WALK_BLOCK_BYTES", block * n_lanes * ps * hkv * d * jnp.dtype(kp.dtype).itemsize)
+    width = pfa.window_pages(window, 1, ps, max_pages)
+    assert pfa.walk_block_pages(n_lanes, width, ps, hkv, d, jnp.dtype(kp.dtype).itemsize) == min(block, width)
+    got = np.asarray(pfa.composed_paged_attend(q, kp, vp, jnp.asarray(tables), **kw))
+    want = attend_reference(q, gather_pages(kp, jnp.asarray(clean)), gather_pages(vp, jnp.asarray(clean)), **kw)
+    assert np.isfinite(got).all(), "the walk read past the block of the longest live lane's last row"
+    # a quantised pool reads as bf16, and the weights meet V in V's dtype
+    np.testing.assert_allclose(got[~idle], np.asarray(want)[~idle], atol=1e-2 if kind else TOL, rtol=0)
+    np.testing.assert_array_equal(got[idle], 0.0)  # an idle lane attends to nothing
+
+
+def test_walk_block_follows_the_shapes_it_sees():
+    """The block's width at the cells' pools (8 lanes, pages of 64, bf16): one
+    page; smaller pages go several a block, never more than there are."""
+    for hkv, d in ((32, 128), (16, 128), (8, 128), (8, 64)):  # olmo-hybrid-7b, olmoe-1b-7b, mixtral / k-exaone, falcon
+        assert pfa.walk_block_pages(8, 40, 64, hkv, d) == 1
+    assert pfa.walk_block_pages(8, 64, 16, 8, 128) == 2 and pfa.walk_block_pages(8, 64, 16, 8, 64) == 4
+    assert pfa.walk_block_pages(2, 3, 16, 8, 64) == 3 and pfa.walk_block_pages(8, 64, 16, 8, 64, itemsize=4) == 2
+    assert pfa.walk_pages(36, 4) == 36 and pfa.walk_pages(6, 8) == 8 and pfa.walk_pages(0, 8) == 0 and pfa.walk_pages(37, 4) == 40
+
+
 # ------------------------------------------------------------ prefill parity
 
 
@@ -387,8 +472,9 @@ def test_dispatch_env_override_decode_and_prefill(monkeypatch):
 
 def test_dispatch_forces_xla_for_softcap_and_traced_window():
     """Kernel-inexpressible requests (gemma2's logit softcap, traced
-    effective window) must compose from XLA even under forced pallas —
-    identical math to the old gather/attend sandwich."""
+    effective window) must compose from XLA even under forced pallas: a
+    decode row then takes the walk, the gather/attend sandwich's math summed
+    block by block."""
     rng = np.random.default_rng(7)
     n_lanes, max_pages, ps, hkv, d = 2, 2, 8, 2, 16
     n_pages = n_lanes * max_pages
@@ -411,7 +497,7 @@ def test_dispatch_forces_xla_for_softcap_and_traced_window():
             q, k_dense, v_dense, q_offset=pos, kv_length=pos + 1,
             sliding_window=traced_window, logit_softcap=30.0,
         )
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
     finally:
         os.environ.pop(pfa._ENV_VAR, None)
 
