@@ -34,6 +34,7 @@ repetition_penalty != 1.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import struct
 from typing import Any, Optional
 
@@ -41,15 +42,25 @@ import msgpack
 
 MAX_FRAME_BYTES = 1 << 30  # 1 GiB hard cap; large tensors stream in chunks far below this
 DEFAULT_CHUNK_BYTES = 4 << 20  # split tensors into ~4 MiB stream items
+_NO_SPAN = contextlib.nullcontext()
 
 
-async def read_frame(reader: asyncio.StreamReader) -> Any:
+async def read_frame_body(reader: asyncio.StreamReader) -> bytes:
+    """The next frame's body, whole and still packed (a caller that times its
+    own unpacking takes the two halves of ``read_frame`` apart here)."""
     header = await reader.readexactly(4)
     (length,) = struct.unpack(">I", header)
     if length > MAX_FRAME_BYTES:
         raise ValueError(f"Frame of {length} bytes exceeds the {MAX_FRAME_BYTES} byte cap")
-    body = await reader.readexactly(length)
+    return await reader.readexactly(length)
+
+
+def decode_frame(body: bytes) -> Any:
     return msgpack.unpackb(body, raw=False, strict_map_key=False)
+
+
+async def read_frame(reader: asyncio.StreamReader) -> Any:
+    return decode_frame(await read_frame_body(reader))
 
 
 def encode_frame(message: Any) -> bytes:
@@ -59,10 +70,14 @@ def encode_frame(message: Any) -> bytes:
     return struct.pack(">I", len(body)) + body
 
 
-async def write_frame(writer: asyncio.StreamWriter, message: Any, lock: asyncio.Lock) -> None:
-    frame = encode_frame(message)
+async def write_frame(writer: asyncio.StreamWriter, message: Any, lock: asyncio.Lock, span=None) -> None:
+    """Pack ``message`` and hand it to the transport, then wait for the
+    transport's buffer to drain below its mark. ``span`` is a context manager
+    the caller wants around the synchronous half (pack and ``writer.write``):
+    it is entered under the lock, so that it closes before anything yields."""
     async with lock:  # interleaving-safe: one frame at a time per connection
-        writer.write(frame)
+        with span or _NO_SPAN:
+            writer.write(encode_frame(message))
         await writer.drain()
 
 

@@ -8,12 +8,14 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import time
 import traceback
 from typing import Any, AsyncIterator, Awaitable, Callable, Dict, Optional
 
 from petals_tpu.data_structures import PeerID
-from petals_tpu.rpc.protocol import read_frame, write_frame
+from petals_tpu.rpc.protocol import decode_frame, read_frame_body, write_frame
 from petals_tpu.utils.logging import get_logger
+from petals_tpu.utils.tracing import device_annotation
 
 logger = get_logger(__name__)
 
@@ -35,6 +37,39 @@ class RpcContext:
     local_peer_id: Optional[PeerID]
     remote_peer_id: Optional[PeerID]
     remote_addr: tuple
+
+
+class StreamRequests:
+    """The inbound side of one streaming call, as its handler sees it: an
+    async iterator over the call's items that this stream alone owns
+    (``RpcContext`` is shared by every stream of a connection), with two
+    readings of the server's own clock (``time.perf_counter``). A handler may
+    read them, and one that does not loses nothing; neither crosses the wire.
+
+    ``read_at``: when the frame of the item last handed out lay whole in
+    memory, before it was unpacked. ``sent_s``: the seconds the item last
+    yielded took to send: packing, the wait for the connection's write lock,
+    ``writer.write`` and the drain of the transport's buffer."""
+
+    __slots__ = ("_queue", "_ended", "read_at", "sent_s")
+
+    def __init__(self, queue: asyncio.Queue):
+        self._queue = queue
+        self._ended = False
+        self.read_at: Optional[float] = None
+        self.sent_s: Optional[float] = None
+
+    def __aiter__(self):
+        return self
+
+    async def __anext__(self):
+        if not self._ended:
+            item, read_at = await self._queue.get()
+            if item is not _END:
+                self.read_at = read_at
+                return item
+            self._ended = True
+        raise StopAsyncIteration
 
 
 UnaryHandler = Callable[[Any, RpcContext], Awaitable[Any]]
@@ -113,7 +148,10 @@ class RpcServer:
                 hello["nonce"] = our_nonce.hex()
             await write_frame(writer, hello, write_lock)
             while True:
-                msg = await read_frame(reader)
+                body = await read_frame_body(reader)
+                read_at = time.perf_counter()
+                with device_annotation("ptu.rpc.recv"):
+                    msg = decode_frame(body)
                 kind = msg.get("t")
                 if kind == "hello":
                     # claims are recorded but remote_peer_id is set ONLY after
@@ -176,7 +214,7 @@ class RpcServer:
                     if queue is not None:
                         item = _END if kind == "send" else msg.get("payload")
                         try:
-                            queue.put_nowait(item)
+                            queue.put_nowait((item, read_at))
                         except asyncio.QueueFull:
                             # The handler is MAX_INBOUND_QUEUE frames behind this
                             # peer: abusive or wedged either way. Kill the call
@@ -244,20 +282,18 @@ class RpcServer:
 
     async def _run_stream(self, msg, queue, ctx, writer, write_lock, call_tasks, inbound_queues):
         call_id = msg["id"]
-
-        async def request_iter():
-            while True:
-                item = await queue.get()
-                if item is _END:
-                    return
-                yield item
-
+        requests = StreamRequests(queue)
         try:
             handler = self._stream.get(msg.get("method"))
             if handler is None:
                 raise RpcError(f"Unknown stream method {msg.get('method')!r}")
-            async for item in handler(request_iter(), ctx):
-                await write_frame(writer, {"t": "sitem", "id": call_id, "payload": item}, write_lock)
+            async for item in handler(requests, ctx):
+                yielded = time.perf_counter()
+                await write_frame(
+                    writer, {"t": "sitem", "id": call_id, "payload": item}, write_lock,
+                    span=device_annotation("ptu.rpc.send"),
+                )
+                requests.sent_s = time.perf_counter() - yielded
             await write_frame(writer, {"t": "send", "id": call_id}, write_lock)
         except asyncio.CancelledError:
             raise

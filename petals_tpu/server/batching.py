@@ -73,7 +73,7 @@ from petals_tpu.telemetry import get_journal
 from petals_tpu.telemetry import instruments as tm
 from petals_tpu.utils.asyncio_utils import log_exception_callback
 from petals_tpu.utils.logging import get_logger
-from petals_tpu.utils.tracing import step_phases
+from petals_tpu.utils.tracing import device_annotation, step_phases
 
 logger = get_logger(__name__)
 
@@ -383,6 +383,12 @@ class DecodeBatcher:
         # a stretch in which the batcher had nothing to run (_step_phases)
         self._flush_spawns = 0
         self._last_step_end: Tuple[float, int] = (0.0, -1)
+        # what _step_phases needs besides to say what the compute thread waited
+        # for between two bodies: the seconds _gather waited since the last
+        # body's return, and whether a lane has come back from a decode reply
+        # since then (so a reply was out while there was nothing to run)
+        self._gathered = 0.0
+        self._back_since_step = False
         # the gather (_gather): each lane's returns after its decode replies,
         # the median wall of the last decode step bodies that carried no
         # prompt chunk (the step a late lane sits out), and the event with
@@ -421,6 +427,27 @@ class DecodeBatcher:
             # given up on
             "gather_waits": 0, "gather_wait_s": 0.0,
             "gather_joined": 0, "gather_missed": 0,
+            # the rest of the compute thread's time between two bodies
+            # (_step_phases): no lane's work there and a decode reply out,
+            # none there and none out, work there and the host in the way.
+            # With the four phases and gather_wait_s they tile that thread's
+            # wall from the first body's return on
+            "lanes_out_s": 0.0, "no_demand_s": 0.0, "handoff_s": 0.0,
+            # a decode token's way out of the server and back in, station by
+            # station, every reading of time.perf_counter in this process
+            # (seconds summed over replies; the layer that owns each stretch
+            # adds it): the last body's return to the flush loop's resolving
+            # the futures (a step that replied, counted in reply_steps), from
+            # there to the lane's handler running again, to the reply yielded,
+            # to its frame handed to the transport and drained
+            # (count_decode_reply, decode_replies); and of a lane that comes
+            # back (step(), lane_returns, the whole trip lane_return_s): its
+            # request's frame read whole to the handler holding the item, to
+            # step() entered. What lane_return_s holds beyond the five
+            # stretches between resolve and step() is the wire and the client
+            "reply_wake_s": 0.0, "reply_steps": 0,
+            "reply_resume_s": 0.0, "reply_build_s": 0.0, "rpc_send_s": 0.0, "decode_replies": 0,
+            "rpc_recv_s": 0.0, "request_handle_s": 0.0, "lane_return_s": 0.0, "lane_returns": 0,
         }
         if getattr(backend, "moe_dims", None) is not None:
             # a family with routed experts only (_count_moe): tokens by the
@@ -1445,6 +1472,17 @@ class DecodeBatcher:
         cached-prefix fast path that never touched the device."""
         return self._step_timing.pop(lane, None)
 
+    def count_decode_reply(self, resumed_s: float, built_s: float, sent_s: float) -> None:
+        """One decode reply's way out, from the handler that made it, once it
+        is sent: the flush loop's resolving of the lane's future
+        (``pop_step_timing``'s ``replied``) to the handler running again, from
+        there to the reply yielded to the RPC server, and what the server took
+        to send it (``rpc.server.StreamRequests.sent_s``)."""
+        self.stats["decode_replies"] += 1
+        self.stats["reply_resume_s"] += resumed_s
+        self.stats["reply_build_s"] += built_s
+        self.stats["rpc_send_s"] += sent_s
+
     def pop_step_fp(self, lane: int) -> Optional[list]:
         """Consume the finished step's fused activation fingerprint for
         ``lane`` (FP_DIM floats; ops/fingerprint.py) — the handler
@@ -1480,16 +1518,32 @@ class DecodeBatcher:
                 "session's KV is gone; the client must re-open the session"
             )
 
-    async def step(self, lane: int, hidden: np.ndarray, position: int) -> np.ndarray:
+    async def step(
+        self, lane: int, hidden: np.ndarray, position: int,
+        arrived: Optional[Tuple[Optional[float], float]] = None,
+    ) -> np.ndarray:
         """One decode token for ``lane`` (hidden [1, 1, hidden]). It rides the
         next batched step together with every lane that is pending when that
         step starts, and the flush loop does not start a step while lanes that
         are predictably on their way back are worth waiting for (``_gather``).
-        A preempted (swapped-out) lane transparently swaps back in first."""
+        A preempted (swapped-out) lane transparently swaps back in first.
+        ``arrived`` is the caller's account of the request's way here, two
+        readings of ``time.perf_counter``: when its frame was read whole (None
+        if nobody took that) and when the caller held the item."""
         t_enq = time.perf_counter()  # before _lane_busy: lock + alloc waits count as queue
         back = self._returns.get(lane)
         if back is not None and back.replied is not None:
+            # the lane is back from a decode reply: the trip's length, and
+            # the server's own share of its way in
+            self.stats["lane_returns"] += 1
+            self.stats["lane_return_s"] += t_enq - back.replied
+            if arrived is not None:
+                read_at, held_at = arrived
+                if read_at is not None:
+                    self.stats["rpc_recv_s"] += held_at - read_at
+                self.stats["request_handle_s"] += t_enq - held_at
             back.came_back(t_enq)
+            self._back_since_step = True
             self._gather_wake.set()  # no longer expected, even if a page wait holds it up
         async with self._lane_busy(lane):
             self._check_lane(lane)
@@ -1576,7 +1630,11 @@ class DecodeBatcher:
                 break
             waited_for.update(lanes)
             try:
-                await asyncio.wait_for(self._gather_wake.wait(), min(until, stop) - now)
+                # the one annotation that spans an await: at most one flush
+                # task is alive, and whatever else the loop's thread runs
+                # during the wait opens and closes inside it
+                with device_annotation("ptu.gather", lanes=len(lanes)):
+                    await asyncio.wait_for(self._gather_wake.wait(), min(until, stop) - now)
             except asyncio.TimeoutError:
                 break
             now = time.perf_counter()
@@ -1594,9 +1652,8 @@ class DecodeBatcher:
                 self.stats["gather_missed"] += 1
                 back.eta = None
         # the batcher chose to have nothing running: that is no hand-off
-        # (turnaround_s: a step to run and the host in the way)
-        ended, spawn = self._last_step_end
-        self._last_step_end = (ended + waited, spawn)
+        # (turnaround_s, handoff_s: a step to run and the host in the way)
+        self._gathered += waited
 
     async def _flush_loop(self) -> None:
         while self._pending or self._gen_states or self._prefill_queue:
@@ -1690,11 +1747,18 @@ class DecodeBatcher:
                 self._maybe_reset_pool()
                 continue
             replied = time.perf_counter()
-            for lane, _, _, fut, _gen in batch:
-                if not fut.done():
-                    fut.set_result(out[lane : lane + 1])
-                    if lane in self._returns:  # not released while the step ran
-                        self._returns[lane].reply_sent(replied)
+            if batch:
+                self.stats["reply_steps"] += 1
+                self.stats["reply_wake_s"] += replied - self._last_step_end[0]
+            with device_annotation("ptu.flush.resolve", lanes=len(batch)):
+                for lane, _, _, fut, _gen in batch:
+                    if not fut.done():
+                        fut.set_result(out[lane : lane + 1])
+                        if lane in self._returns:  # not released while the step ran
+                            self._returns[lane].reply_sent(replied)
+                        timing = self._step_timing.get(lane)
+                        if timing is not None:
+                            timing["replied"] = replied  # for the handler's count_decode_reply
             if pf is not None and chunk_out is not None:
                 self._advance_prefill(pf[0], pf[1], chunk_out)
             if spec_res is not None:
@@ -2079,8 +2143,19 @@ class DecodeBatcher:
             self._returns.pop(lane, None)
         self._gather_wake.set()
 
+    def _arrivals(self, batch, *states) -> List[float]:
+        """When each piece of work a body is about to run was there to be run
+        (``_step_phases``' ``arrived``): a decode entry's and a first prompt
+        chunk's or generating lane's enqueue; 0.0 for what continues and was
+        ready the moment the body before returned."""
+        arrived = [self._enq_t.get(entry[0], 0.0) for entry in batch]
+        for st in states:
+            first = st.offset == 0 if isinstance(st, _LanePrefillState) else not st.started
+            arrived.append(st.enqueued if first else 0.0)
+        return arrived
+
     @contextlib.contextmanager
-    def _step_phases(self, variant: str, lanes: int, prefill_tokens: int = 0):
+    def _step_phases(self, variant: str, lanes: int, prefill_tokens: int = 0, arrived=()):
         """The phase clock shared by the four step bodies (compute thread):
         ``assemble_s`` from the body's entry to the backend call,
         ``dispatch_s`` the backend call (the device starts inside it),
@@ -2090,18 +2165,49 @@ class DecodeBatcher:
         the previous body returned, counted only where the flush task stayed
         alive in between: results to the event loop, futures set, the next
         ``queue.submit``, this thread's wake-up (and whatever else the queue
-        ran meanwhile). Where the flush task ended, the batcher had nothing
-        to run and the stretch is in no counter."""
-        ended, spawn = self._last_step_end
-        if spawn == self._flush_spawns:
-            self.stats["turnaround_s"] += time.perf_counter() - ended
+        ran meanwhile), less what the gather waited.
+
+        Whichever flush task carried it, the time since the previous body
+        returned is split by what this thread, and so the chip, waited for.
+        Up to the earliest of ``arrived`` (``_arrivals``) there was nothing
+        to run: ``lanes_out_s`` where a decode reply was out meanwhile (a lane
+        is still out, or one has come back since), every live lane's token on
+        its way; ``no_demand_s`` where none was, no session decoding. Of the
+        rest ``gather_wait_s`` is what ``_gather`` chose to wait, counted
+        there, and ``handoff_s`` what is left: work there and the host in the
+        way (futures, the task's spawn, ``queue.submit``, this thread's
+        wake-up). The reading that opens ``assemble`` ends that stretch, and
+        the one that closes ``post`` begins the next (``_split_idle``, which
+        runs inside ``assemble``), so the eight clocks tile this thread's
+        wall from the first body's return on."""
+        phases = step_phases(self.stats, variant=variant, lanes=lanes, prefill_tokens=prefill_tokens)
         try:
-            with step_phases(
-                self.stats, variant=variant, lanes=lanes, prefill_tokens=prefill_tokens
-            ) as phases:
+            with phases:
+                self._split_idle(phases.started, arrived)
                 yield phases
         finally:
-            self._last_step_end = (time.perf_counter(), self._flush_spawns)
+            self._back_since_step = False
+            self._last_step_end = (phases.ended, self._flush_spawns)
+
+    def _split_idle(self, entered: float, arrived) -> None:
+        """``_step_phases``' account of the time from the previous body's
+        return to this one's first phase (the same reading opened it, so
+        nothing falls between)."""
+        ended, spawn = self._last_step_end
+        gathered, self._gathered = self._gathered, 0.0
+        if spawn < 0:
+            return  # the first body: no return to reckon from
+        idle = entered - ended - gathered  # what the gather did not choose
+        if spawn == self._flush_spawns:
+            self.stats["turnaround_s"] += idle
+        idle = max(idle, 0.0)
+        empty = min(max(min(arrived, default=0.0) - ended, 0.0), idle)
+        if empty:
+            out = self._back_since_step or any(
+                back.replied is not None for back in list(self._returns.values())
+            )
+            self.stats["lanes_out_s" if out else "no_demand_s"] += empty
+        self.stats["handoff_s"] += idle - empty
 
     def _count_moe(self, tokens: int, *, seq: int = 1, chunk_tokens: int = 0) -> None:
         """The expert counters of one step (compute thread; a family with
@@ -2191,7 +2297,7 @@ class DecodeBatcher:
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
         variant = "paged" if self.page_size is not None else "dense"
-        with self._step_phases(variant, len(batch)) as phases:
+        with self._step_phases(variant, len(batch), arrived=self._arrivals(batch)) as phases:
             # generation guards on BOTH sides of the device step: an exclusive
             # op's failure can reset the pool from the event loop while this
             # task is queued or mid-flight, and decoding against the
@@ -2311,7 +2417,7 @@ class DecodeBatcher:
         The prefill lane rides the decode half at the idle sentinel, so its
         decode-side write drops; its tokens ride the prefill half."""
         st, take = pf
-        with self._step_phases("mixed", len(batch), take) as phases:
+        with self._step_phases("mixed", len(batch), take, arrived=self._arrivals(batch, st)) as phases:
             expected = batch[0][4] if batch else st.generation
             if expected != self._generation or st.generation != self._generation:
                 raise AllocationFailed("Lane pool was reset before this batched step ran")
@@ -2373,7 +2479,9 @@ class DecodeBatcher:
         """Compute-thread body: one jitted step advancing every pending decode
         lane AND every generating lane together (the client leaves embed the
         gen lanes' tokens and sample their next ones on device)."""
-        with self._step_phases("gen", len(batch) + len(gen_states)) as phases:
+        with self._step_phases(
+            "gen", len(batch) + len(gen_states), arrived=self._arrivals(batch, *gen_states.values())
+        ) as phases:
             expected = (
                 batch[0][4] if batch
                 else next(iter(gen_states.values())).generation
@@ -2478,7 +2586,9 @@ class DecodeBatcher:
         share is additionally recorded per lane as the draft_seconds
         'of which' annotation, with proposed/accepted counts feeding the
         per-peer acceptance_rate (/ledger, step_meta usage)."""
-        with self._step_phases("spec", len(spec_states)) as phases:
+        with self._step_phases(
+            "spec", len(spec_states), arrived=self._arrivals((), *spec_states.values())
+        ) as phases:
             expected = next(iter(spec_states.values())).generation
             if expected != self._generation or any(
                 st.generation != self._generation for st in spec_states.values()

@@ -1543,9 +1543,10 @@ class TransformerHandler:
             )
             seen_steps = set()  # dedup: the same step may arrive via client AND push
             pending_store = None  # in-flight prefix-cache store task
+            reply_build = None  # the annotation around a decode reply in the making
             try:
               while True:
-                step = await next_step()
+                step, t_read = await next_step()
                 # serving clock for this step's step_meta: receipt -> reply
                 # ready (everything the client's wall covers except network)
                 t_step_recv = time.perf_counter()
@@ -1814,9 +1815,17 @@ class TransformerHandler:
                         # with whatever other sessions are stepping right now
                         t_tok = time.perf_counter()
                         out = await asyncio.wait_for(
-                            batcher.step(lane, hidden, pos), self.step_timeout
+                            batcher.step(lane, hidden, pos, arrived=(t_read, t_step_recv)),
+                            self.step_timeout,
                         )
-                        tm.TOKEN_LATENCY.observe(time.perf_counter() - t_tok)
+                        t_resumed = time.perf_counter()
+                        if not step.get("gen_tokens"):
+                            # a plain decode reply: nothing awaits from here
+                            # to its yield, so one annotation covers the
+                            # stretch (closed there, or where a raise ends up)
+                            reply_build = device_annotation("ptu.reply.build", lane=lane)
+                            reply_build.__enter__()
+                        tm.TOKEN_LATENCY.observe(t_resumed - t_tok)
                         step_variant = "decode"
                         step_timing = batcher.pop_step_timing(lane)
                         step_fp = batcher.pop_step_fp(lane)
@@ -2174,12 +2183,27 @@ class TransformerHandler:
                     task.add_done_callback(
                         log_exception_callback(logger, "output push")
                     )
-                step_meta["total_s"] = round(time.perf_counter() - t_step_recv, 6)
+                t_built = time.perf_counter()
+                step_meta["total_s"] = round(t_built - t_step_recv, 6)
+                decode_reply = reply_build is not None and step_timing is not None and "replied" in step_timing
+                if reply_build is not None:
+                    reply_build.__exit__(None, None, None)
+                    reply_build = None
                 yield {
                     "tensors": {"hidden": wire_out}, "position": position,
                     "step_meta": step_meta,
                 }
+                if decode_reply:
+                    # the generator runs on from its yield once the RPC server
+                    # has sent the reply; what the sending took is on the
+                    # stream's own object (another caller's iterator keeps none)
+                    batcher.count_decode_reply(
+                        t_resumed - step_timing["replied"], t_built - t_resumed,
+                        getattr(requests, "sent_s", None) or 0.0,
+                    )
             finally:
+                if reply_build is not None:  # a raise between the step and its reply
+                    reply_build.__exit__(None, None, None)
                 if pending_store is not None and not pending_store.done():
                     import sys as _sys
 
@@ -2230,12 +2254,15 @@ class TransformerHandler:
 
         async def _next_client():
             try:
-                return await anext(requests)
+                item = await anext(requests)
             except StopAsyncIteration:
-                return None  # client half-closed
+                return None, None  # client half-closed
             except Exception as e:
                 logger.debug("Client stream error (treating as half-close): %r", e)
-                return None
+                return None, None
+            # when the RPC server read this item's frame (its per-stream
+            # object; any other iterator keeps no such time)
+            return item, getattr(requests, "read_at", None)
 
         async def next_step():
             if "client" not in pending:
@@ -2249,10 +2276,11 @@ class TransformerHandler:
                 await cleanup()
                 raise asyncio.TimeoutError("No inference step within session_timeout")
             task = done.pop()
-            for name, t in list(pending.items()):
-                if t is task:
-                    del pending[name]
-            return task.result()
+            name = next(name for name, t in pending.items() if t is task)
+            del pending[name]
+            # (step, when the RPC server read its frame): a pushed step came
+            # over no stream of this call's, so nobody here read its frame
+            return task.result() if name == "client" else (task.result(), None)
 
         async def cleanup():
             for task in pending.values():

@@ -13,7 +13,8 @@ Three layers:
   through ``assemble`` / ``dispatch`` / ``wait`` / ``post`` on the compute
   thread. Each phase is a ``ptu.step.<name>`` TraceAnnotation inside one
   ``ptu.step`` annotation, and its wall time is added to
-  ``stats["<name>_s"]``. Always on; nothing goes into the span ring (a few
+  ``stats["<name>_s"]``, one reading of the clock closing a phase and
+  opening the next. Always on; nothing goes into the span ring (a few
   hundred steps a second would push the RPC-level spans out of it).
 - device timeline: ``start_jax_trace(logdir)`` / ``stop_jax_trace()`` wrap
   ``jax.profiler`` (served via ``PETALS_TPU_TRACE_DIR`` at server startup;
@@ -30,6 +31,20 @@ arguments), the private, exclusive and dense-prefill inference paths
 ``rpc_probe``. The RPC-level ``inference_step`` span around ``batcher.step``
 and ``batcher.prefill_lane`` lives on the event loop and is not annotated
 (concurrent spans interleave there).
+
+On the event loop's thread (another line of the same plane) a decode token's
+way out and back is annotated where a stretch holds no ``await``, so that
+each closes before its coroutine yields: ``ptu.flush.resolve`` (the flush
+loop sets a step's futures; ``lanes``), ``ptu.reply.build`` (the handler,
+from ``batcher.step``'s return to the reply's yield; ``lane``),
+``ptu.rpc.send`` (msgpack and ``writer.write`` of a stream's item),
+``ptu.rpc.recv`` (``unpackb`` of any frame). One spans an ``await``:
+``ptu.gather`` around the gather's wait (``lanes`` waited for), because at
+most one flush task is alive and whatever else that thread runs meanwhile
+opens and closes inside it. The handler's stretch from a request's receipt
+to ``batcher.step`` holds conditional awaits and has a counter only
+(``request_handle_s``). Their counters are in ``batcher.stats``
+(``server/batching.py``).
 """
 
 from __future__ import annotations
@@ -179,60 +194,50 @@ def device_annotation(name: str, **args):
 STEP_PHASES = ("assemble", "dispatch", "wait", "post")
 
 
-class phase:
-    """One host phase of a batched step, on the thread that runs it: a
-    ``ptu.step.<name>`` annotation around a ``perf_counter`` interval that is
-    added to ``stats[name + "_s"]``, also when the body raises."""
-
-    __slots__ = ("_stats", "_key", "_annotation", "_t0")
-
-    def __init__(self, stats: dict, name: str):
-        self._stats = stats
-        self._key = name + "_s"
-        self._annotation = device_annotation("ptu.step." + name)
-
-    def __enter__(self):
-        self._annotation.__enter__()
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc_info):
-        self._stats[self._key] += time.perf_counter() - self._t0
-        self._annotation.__exit__(*exc_info)
-        return False
-
-
 class step_phases:
     """One batched step as the four ``STEP_PHASES`` in order and without
-    gaps, inside one ``ptu.step`` annotation that carries ``args``. Entering
-    opens ``assemble``; the body calls ``enter(name)`` at each boundary; the
-    exit closes whichever phase is running, so none stays open on a raise."""
+    gaps, on the thread that runs it, inside one ``ptu.step`` annotation that
+    carries ``args``: each phase is a ``ptu.step.<name>`` annotation around
+    a ``perf_counter`` interval that is added to ``stats[name + "_s"]``, also
+    when the body raises. Entering opens ``assemble``; the body calls
+    ``enter(name)`` at each boundary, where one reading of the clock closes a
+    phase and opens the next; the exit closes whichever phase is running, so
+    none stays open on a raise. ``started`` and ``ended`` are the readings
+    that opened the first phase and closed the last, for a caller that tiles
+    the time around the step with the same clock."""
 
-    __slots__ = ("_stats", "_step", "_index", "_phase")
+    __slots__ = ("_stats", "_step", "_index", "_annotation", "_t0", "started", "ended")
 
     def __init__(self, stats: dict, **args):
         self._stats = stats
         self._step = device_annotation("ptu.step", **args)
 
-    def _open(self, index: int) -> None:
+    def _open(self, index: int, now: float) -> None:
         self._index = index
-        self._phase = phase(self._stats, STEP_PHASES[index])
-        self._phase.__enter__()
+        self._annotation = device_annotation("ptu.step." + STEP_PHASES[index])
+        self._annotation.__enter__()
+        self._t0 = now
+
+    def _close(self, exc_info=(None, None, None)) -> float:
+        self._annotation.__exit__(*exc_info)
+        now = time.perf_counter()
+        self._stats[STEP_PHASES[self._index] + "_s"] += now - self._t0
+        return now
 
     def __enter__(self):
         self._step.__enter__()
-        self._open(0)
+        self.started = time.perf_counter()
+        self._open(0, self.started)
         return self
 
     def enter(self, name: str) -> None:
         index = self._index + 1
         if index >= len(STEP_PHASES) or STEP_PHASES[index] != name:
             raise RuntimeError(f"step phase {name!r} cannot follow {STEP_PHASES[self._index]!r}")
-        self._phase.__exit__(None, None, None)
-        self._open(index)
+        self._open(index, self._close())
 
     def __exit__(self, *exc_info):
-        self._phase.__exit__(*exc_info)
+        self.ended = self._close(exc_info)
         self._step.__exit__(*exc_info)
         return False
 

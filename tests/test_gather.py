@@ -108,6 +108,8 @@ def test_gather_counters_are_in_stats_from_construction(tiny):
     assert _gather_stats(batcher) == {
         "gather_waits": 0, "gather_wait_s": 0.0, "gather_joined": 0, "gather_missed": 0,
     }
+    # and the three clocks that tile the time between two bodies with gather_wait_s (PR 37)
+    assert [batcher.stats[key] for key in ("lanes_out_s", "no_demand_s", "handoff_s")] == [0.0, 0.0, 0.0]
 
 
 @pytest.mark.parametrize(

@@ -570,6 +570,9 @@ STATS_BEFORE = {
     "prefill_tokens", "mixed_steps", "max_prefill_tokens_per_step", "spec_steps", "spec_proposed", "spec_accepted",
     "spec_disabled", "max_spec_lanes", "assemble_s", "dispatch_s", "wait_s", "post_s", "turnaround_s", "gather_waits",
     "gather_wait_s", "gather_joined", "gather_missed",
+    # PR 37: what the compute thread waited for between two bodies, and a decode token's round trip
+    "lanes_out_s", "no_demand_s", "handoff_s", "reply_wake_s", "reply_steps", "reply_resume_s", "reply_build_s",
+    "rpc_send_s", "decode_replies", "rpc_recv_s", "request_handle_s", "lane_return_s", "lane_returns",
 }
 
 
