@@ -26,7 +26,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-# lanes, table slots, page size, kv heads (as the pool keeps them), head dim, q heads a kv head, the lanes' lengths
+# lanes, table slots, page size, kv heads (as the cache keeps them), head dim, q heads a kv head, the lanes' lengths
 SHAPES = {
     "olmo-hybrid-7b": (8, 40, 64, 32, 128, 1, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k: 1,024-2,560
     "olmoe-1b-7b": (8, 16, 64, 16, 128, 1, (90, 130, 170, 210, 250, 290, 330, 370)),  # saturated: 64-128 in, 256 out
@@ -58,7 +58,8 @@ def main(names) -> None:
     sink_path = os.path.join(out_dir, "ablate_paged_walk.jsonl")
 
     def dense(q, kp, vp, tb, pos):  # the path the walk replaced
-        return attend_reference(q, pa.gather_pages(kp, tb), pa.gather_pages(vp, tb), q_offset=pos, kv_length=pos + 1)
+        hkv = pa.pool_geometry(kp, q.shape[-1])[2]
+        return attend_reference(q, pa.gather_pages(kp, tb, hkv), pa.gather_pages(vp, tb, hkv), q_offset=pos, kv_length=pos + 1)
 
     def walk(q, kp, vp, tb, pos):
         return pfa.composed_paged_attend(q, kp, vp, tb, q_offset=pos, kv_length=pos + 1)
@@ -102,7 +103,9 @@ def main(names) -> None:
         q = jax.random.normal(kq, (n_lanes, 1, hkv * group, d), jnp.bfloat16)
         kp = jax.random.normal(kk, (n_pages, page_size, hkv, d), jnp.bfloat16)
         vp = jax.random.normal(kv, (n_pages, page_size, hkv, d), jnp.bfloat16)
-        args = (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
+        row = pa.stored_row(hkv, d)  # the pools in the form a server stores them in (folded at a head_dim of 64)
+        unfolded = (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
+        args = (q, pa.fold_rows(kp, row), pa.fold_rows(vp, row), *unfolded[3:])
         a_slot = n_lanes * page_size * hkv * d * 2
         live_mb = 2 * int(np.where(idle, 0, pos + 1).sum()) * hkv * d * 2 / 1e6
         want = np.asarray(jax.jit(dense)(*args), np.float32)[~idle]
@@ -114,6 +117,8 @@ def main(names) -> None:
             got = np.asarray(jax.jit(lambda *a: walk(*a))(*args), np.float32)[~idle]  # a new program a width
             err = float(np.max(np.abs(got - want)))
             rows.append((f"walk{block}", err, timed(walk, *args)))
+            if len(row) == 1 and block == 1:  # what the fold costs the walk: the same walk over pools of [hkv, d] rows
+                rows.append(("walk1-rows-of-hkv-d", None, timed(walk, *unfolded)))
         for variant, err, ms in rows:
             line = {"shape": name, "variant": variant, "ms": round(ms, 4), "max_err": err, "live_mb": round(live_mb, 1),
                     "floor_ms": round(live_mb / 819e3 * 1e3, 4), "longest_slots": int(held.max()), "device": jax.devices()[0].device_kind}

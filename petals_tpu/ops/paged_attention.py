@@ -6,6 +6,23 @@ Layout contract (per span block):
 
 - page pool      [n_pages, page_size, kv_heads, head_dim] x2 (k, v) — ONE
   shared slab in HBM, budgeted through MemoryCache like the dense lane pool.
+  STORED in the layout its step computes in (``stored_row``, the one rule):
+  a row under the chip's 128 lanes (head_dim 64; int8 codes of it; nf4a's
+  packed bytes of a head_dim up to 128) keeps the kv heads folded into it,
+  [n_pages, page_size, kv_heads * d_store] — the same bytes in the same
+  order. Handed over as rows of [kv_heads, 64] such a pool lives on the
+  device with the PAGE index minor, and every step program relaid both
+  pools whole on the way in and on the way out (Falcon-40B: four copies of
+  42 MB a step, PERF.md section 6, PR 38). Who makes a pool asks the rule
+  (server/backend.py ``paged_cache_descriptors``); every consumer here
+  reads the form off the leaf it is handed: the scatters fold the new rows
+  (``_flat_scatter``, ``scatter_lane_pages``), ``gather_pages`` unfolds the
+  pages it took (never the pool), ``pool_geometry`` answers (n_pages,
+  page_size, hkv, d_store) for the kernels and the dispatch. A pool of
+  head_dim 128 keeps its shape and its programs. Off the device everything
+  keeps rows of [kv_heads, d]: swap entries, snapshots and imports, the
+  wire (server/backend.py ``pool_to_wire`` / ``wire_to_pool``: a reshape of
+  the host's copy).
 - block table    [n_lanes, max_pages] int32 — page index per (lane, slot);
   ``-1`` marks an unallocated slot. ``max_pages * page_size == max_length``
   (the batcher rounds max_length up to a page multiple).
@@ -61,6 +78,7 @@ slots gather with ZERO scales, so holes still read as exact zeros.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -72,14 +90,55 @@ from petals_tpu.ops.quant import NF4A_A, NF4A_B, NF4A_CODE
 
 KV_QUANT_KINDS = ("none", "int8", "nf4a")
 
+#: a row of fewer elements than a vector register's 128 lanes is stored folded
+LANES = 128
+
+
+def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
+    """THE storage rule of a page pool: the trailing dims a pool leaf keeps a
+    token row in, ``[..., n_pages, page_size, *stored_row]``, from the leaf's
+    own ``[hkv, d_store]`` (``d_store``: the head dim of values and int8
+    codes, half of it for nf4a's packed bytes).
+
+    A minor dim under the chip's 128 lanes is not a layout a step computes
+    in: XLA keeps such an array with another dim minor (a bf16 pool of
+    head_dim 64 with the PAGE index minor) and the program that is handed it
+    row-major relays it whole on the way in and on the way out, every step.
+    So such a leaf is stored with the kv heads folded into the row,
+    ``(hkv * d_store,)``: the same bytes in the same order, the layout the
+    step programs are handed is the one they compute in, and the fused
+    kernel's lane-trailing view (ops/paged_flash_attention.py
+    ``_pool_views``) is the array itself. A row of 128 lanes or more keeps
+    ``(hkv, d_store)``. Scales stay ``[..., hkv]`` either way.
+
+    Every consumer reads the form off the leaf it is handed (``pool_geometry``,
+    ``fold_rows`` / ``unfold_rows``); only who MAKES a pool asks this function
+    (server/backend.py ``paged_cache_descriptors``, the autotune's harness)."""
+    return (hkv * d_store,) if d_store < LANES else (hkv, d_store)
+
+
+def unfold_rows(a, hkv: int):
+    """Folded rows ``[..., hkv * d]`` as ``[..., hkv, d]`` (jax or numpy)."""
+    return a.reshape(*a.shape[:-1], hkv, a.shape[-1] // hkv)
+
+
+def fold_rows(rows, row: Tuple[int, ...]):
+    """Rows ``[..., hkv, d_store]`` as a leaf stores them, ``row`` being the
+    leaf's own trailing dims: folded for a folded leaf, as they are for one
+    that keeps ``(hkv, d_store)`` (jax or numpy)."""
+    return rows.reshape(*rows.shape[:-2], *row)
+
 
 class PagedPool(NamedTuple):
     """A quantized page pool: per-row codes plus their absmax scales.
 
     ``codes`` is int8 ``[..., n_pages, page_size, hkv, d]`` (kind "int8") or
     uint8 ``[..., n_pages, page_size, hkv, d // 2]`` with two split-half
-    codes per byte (kind "nf4a"); ``scales`` is float32
-    ``[..., n_pages, page_size, hkv]`` — one scale per (token row, kv head).
+    codes per byte (kind "nf4a"), the last two dims folded into one where
+    ``stored_row`` says so (``[..., hkv * d_store]``: a head's codes are
+    still contiguous, so its nf4a halves still are); ``scales`` is float32
+    ``[..., n_pages, page_size, hkv]`` — one scale per (token row, kv head),
+    in either form, and so the one leaf that always says ``hkv``.
     A NamedTuple, so it is a JAX pytree: it rides scan xs / donation /
     MemoryCache buffers wherever a plain pool array does, and its ``shape``/
     ``dtype`` properties answer the LOGICAL (dequantized) geometry so shape-
@@ -94,15 +153,17 @@ class PagedPool(NamedTuple):
 
     @property
     def shape(self) -> Tuple[int, ...]:
-        """Logical (dequantized) shape: the packed nf4a byte axis doubles."""
-        d = self.codes.shape[-1]
+        """Logical (dequantized) shape ``[..., hkv, d]``, whichever form the
+        codes are stored in: the packed nf4a byte axis doubles."""
+        lead = self.scales.shape
+        d = math.prod(self.codes.shape[len(lead) - 1:]) // lead[-1]
         if np.dtype(self.codes.dtype) == np.uint8:
             d *= 2
-        return (*self.codes.shape[:-1], d)
+        return (*lead, d)
 
     @property
     def ndim(self) -> int:
-        return self.codes.ndim
+        return self.scales.ndim + 1
 
     @property
     def dtype(self):
@@ -238,6 +299,12 @@ class PagedKV(NamedTuple):
     isinstance and route to the paged scatter / fused-kernel dispatch instead
     of the dense buffer code.
 
+    The pool is in whichever form ``stored_row`` gave it: ``[n_pages,
+    page_size, hkv, d]`` or, for a row under the 128 lanes, ``[n_pages,
+    page_size, hkv * d]``. The scatter folds the new rows to the pool's own
+    row and the gather unfolds the pages it took (``gather_pages``), so block
+    code and attention see ``[.., hkv, d]`` rows either way.
+
     Inside a step program the pool a block sees is the WHOLE SPAN's, every
     layer's pages end to end (``[n_layers * n_pages, page_size, hkv, d]``, a
     bitcast of the stacked pool the layer loop carries and updates in place),
@@ -249,7 +316,7 @@ class PagedKV(NamedTuple):
     that pool, ``(first_page, n_pages)``; it is None for a pool that holds
     one block alone."""
 
-    pool: PoolLike  # [n_pages, page_size, hkv, d] array, or a PagedPool
+    pool: PoolLike  # [n_pages, page_size, *stored_row] array, or a PagedPool
     tables: jnp.ndarray  # [n_lanes, max_pages] int32; -1 = unallocated slot
     layer: Optional[Tuple] = None  # (first page: int32 scalar, pages a layer: int)
 
@@ -269,7 +336,10 @@ class PagedKV(NamedTuple):
     def shape(self) -> Tuple[int, ...]:
         """Dense-equivalent shape [n_lanes, max_length, hkv, d] — family block
         code reads ``k_all.shape[1]`` for the buffer length (e.g. gemma2's
-        effective-window computation), so the stand-in must answer it."""
+        effective-window computation), so the stand-in must answer it. (A
+        plain pool stored folded cannot say ``hkv`` and answers its own row,
+        ``[n_lanes, max_length, hkv * d]``; who needs the heads has a query
+        beside it: ``pool_geometry``.)"""
         return (self.tables.shape[0], self.max_length, *self.pool.shape[2:])
 
     @property
@@ -312,10 +382,23 @@ def tables_are_contiguous(tables: np.ndarray, n_pages: int) -> bool:
     return bool(np.all((tables == ident) | (tables < 0)))
 
 
+def pool_geometry(pool: PoolLike, head_dim: int) -> Tuple[int, int, int, int]:
+    """``(n_pages, page_size, hkv, d_store)`` of one block's pool (or of the
+    span's, layers end to end) in either stored form. A quantized pool's
+    scales say ``hkv``; a plain pool that is stored folded has only its row's
+    width, and ``head_dim`` (the query's) says how many heads that is."""
+    if isinstance(pool, PagedPool):
+        leaf, hkv = pool.codes, pool.scales.shape[-1]
+    else:
+        leaf, hkv = pool, pool.shape[2] if pool.ndim == 4 else pool.shape[2] // head_dim
+    return leaf.shape[0], leaf.shape[1], hkv, math.prod(leaf.shape[2:]) // hkv
+
+
 def _gather_pages_arr(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     """gather_pages over ONE array (any trailing rank — works for a value
     pool [n_pages, ps, hkv, d], a codes pool [n_pages, ps, hkv, d_store],
-    and a scales pool [n_pages, ps, hkv])."""
+    either of them folded to [n_pages, ps, hkv * d_store], and a scales pool
+    [n_pages, ps, hkv]): the pages as the leaf stores them."""
     n_pages, page_size = pool.shape[0], pool.shape[1]
     n_lanes, max_pages = tables.shape
     flat = tables.reshape(-1)
@@ -326,11 +409,16 @@ def _gather_pages_arr(pool: jnp.ndarray, tables: jnp.ndarray) -> jnp.ndarray:
     return pages.reshape(n_lanes, max_pages * page_size, *pool.shape[2:])
 
 
-def gather_pages(pool: PoolLike, tables: jnp.ndarray) -> jnp.ndarray:
+def gather_pages(pool: PoolLike, tables: jnp.ndarray, hkv: Optional[int] = None) -> jnp.ndarray:
     """Materialize the dense per-lane view of one block's page pool.
 
     pool [n_pages, page_size, hkv, d] + tables [n_lanes, max_pages] ->
-    [n_lanes, max_pages * page_size, hkv, d]. Unallocated slots (-1) read as
+    [n_lanes, max_pages * page_size, hkv, d]. A pool stored folded
+    ([n_pages, page_size, hkv * d], ``stored_row``) gives the same view: the
+    pages are taken as they lie and only what was taken is unfolded (a decode
+    walk's block is a page a lane, in fast memory; the pool itself is never
+    reshaped). A plain folded pool needs ``hkv`` for that; a quantized one's
+    scales say it. Unallocated slots (-1) read as
     ZEROS: they must not surface page 0's live bytes into a lane that does
     not own that page (attention masks them to 0.0 weight either way, but
     the dense view escapes attention — kv export, debug dumps — so the
@@ -346,16 +434,26 @@ def gather_pages(pool: PoolLike, tables: jnp.ndarray) -> jnp.ndarray:
     if isinstance(pool, PagedPool):
         codes = _gather_pages_arr(pool.codes, tables)
         scales = _gather_pages_arr(pool.scales, tables)
+        if codes.ndim == scales.ndim:  # folded: [n_lanes, rows, hkv * d_store]
+            codes = unfold_rows(codes, scales.shape[-1])
         return dequantize_kv(codes, scales, pool.kind, pool.dtype)
-    return _gather_pages_arr(pool, tables)
+    view = _gather_pages_arr(pool, tables)
+    if view.ndim == 3:  # folded: [n_lanes, rows, hkv * d]
+        if hkv is None:
+            raise ValueError(f"a plain pool stored folded ({pool.shape}) does not say its kv heads: pass hkv")
+        view = unfold_rows(view, hkv)
+    return view
 
 
 def _flat_scatter(pool: jnp.ndarray, rows: jnp.ndarray, flat_idx: jnp.ndarray) -> jnp.ndarray:
     """Scatter ``rows [n, *rest]`` into ``pool [n_pages, ps, *rest]`` at flat
     (page*ps + slot) indices; index ``n_pages*ps`` is one-past-the-end and
-    drops. Rank-generic: serves value pools, codes pools, and scales pools."""
+    drops. Rank-generic: serves value pools, codes pools, and scales pools,
+    and rows ``[n, hkv, d_store]`` are folded to the row of a pool that is
+    stored folded (``[n_pages, ps, hkv * d_store]``)."""
     n_pages, page_size = pool.shape[0], pool.shape[1]
     flat = pool.reshape(n_pages * page_size, *pool.shape[2:])
+    rows = rows.reshape(rows.shape[0], *pool.shape[2:])
     flat = flat.at[flat_idx].set(rows.astype(pool.dtype), mode="drop")
     return flat.reshape(pool.shape)
 
@@ -462,11 +560,12 @@ def scatter_lane_pages(
     safe = jnp.where(table_row >= 0, table_row, n_pages)
     if isinstance(pool, PagedPool):
         codes, scales = quantize_kv_rows(lane_pages, pool.kind)
+        codes = fold_rows(codes, pool.codes.shape[2:])
         return PagedPool(
             pool.codes.at[safe].set(codes.astype(pool.codes.dtype), mode="drop"),
             pool.scales.at[safe].set(scales.astype(pool.scales.dtype), mode="drop"),
         )
-    return pool.at[safe].set(lane_pages.astype(pool.dtype), mode="drop")
+    return pool.at[safe].set(fold_rows(lane_pages, pool.shape[2:]).astype(pool.dtype), mode="drop")
 
 
 def paged_update_kv(
@@ -540,8 +639,9 @@ def paged_attend(
     The production decode step fuses this same gather in front of the model
     family's block code (server/backend.py _paged_decode_fn); this entry
     point is the kernel-level contract the parity tests pin down."""
-    k = gather_pages(k_pool, tables)
-    v = gather_pages(v_pool, tables)
+    hkv = pool_geometry(k_pool, q.shape[-1])[2]
+    k = gather_pages(k_pool, tables, hkv)
+    v = gather_pages(v_pool, tables, hkv)
     pos = jnp.asarray(positions, jnp.int32)
     return attend_reference(
         q, k, v, q_offset=pos, kv_length=pos + q.shape[1],
@@ -571,8 +671,9 @@ def paged_prefill_attend(
     production mixed step fuses this gather in front of the model family's
     block code (server/backend.py _paged_mixed_step_fn); this entry point is
     the kernel-level contract the mixed parity tests pin down."""
-    k = gather_pages(k_pool, table_row[None])
-    v = gather_pages(v_pool, table_row[None])
+    hkv = pool_geometry(k_pool, q.shape[-1])[2]
+    k = gather_pages(k_pool, table_row[None], hkv)
+    v = gather_pages(v_pool, table_row[None], hkv)
     pos = jnp.asarray(chunk_pos, jnp.int32).reshape(1)
     kv_len = pos + jnp.asarray(n_valid, jnp.int32).reshape(1)
     return attend_reference(

@@ -16,12 +16,16 @@ kernel serves the dense-shaped steps too.
 
 What the chip said (v5e, PR 21): Mosaic takes the pool only through a
 lane-trailing VIEW ([n_pages, page_size, hkv * d], see the notes above
-``_kv_heads_per_block``), and under TPU tiling that view is a physical
-relayout — XLA copies the whole per-layer K and V pool in front of every
-call. With that and a grid of n_lanes * hkv * max_pages one-page steps, the
-autotune picks the XLA-composed path for decode on the default pool (PERF.md
-section 5). Storing the pool lane-trailing, or fetching all kv heads of a
-page per step, is ROADMAP S3.
+``_kv_heads_per_block``), and under TPU tiling that view of a pool of rows
+of [hkv, d] is a physical relayout — XLA copies the whole per-layer K and V
+pool in front of every call. With that and a grid of n_lanes * hkv *
+max_pages one-page steps, the autotune picks the XLA-composed path for
+decode on the default pool (PERF.md section 5). Since PR 38 a pool whose row
+is under 128 lanes (head_dim 64) is STORED lane-trailing
+(ops/paged_attention.py ``stored_row``): ``_pool_views`` of it is the array
+itself, and both kernels and the composed path read the form off the leaf
+(``pool_geometry``). A pool of head_dim 128 is still relaid for the kernel;
+fetching all kv heads of a page per step is ROADMAP S3.
 
 Structure is lifted from ops/flash_attention.py: online-softmax m/l/acc
 scratch carried across the innermost (arbitrary) grid axis, a shared
@@ -289,7 +293,9 @@ def _kv_heads_per_block(num_kv_heads: int, d_store: int) -> int:
 
 def _pool_views(k_pool, v_pool, quantized: bool):
     """The pool operands in kernel order — k, [ks], v, [vs] — as the
-    lane-trailing views described above."""
+    lane-trailing views described above. A pool stored folded
+    (ops/paged_attention.py ``stored_row``) IS its view: no reshape, no
+    relayout."""
     def codes_view(a):
         return a.reshape(a.shape[0], a.shape[1], -1)
 
@@ -479,15 +485,12 @@ def paged_flash_attend(
     Quantized pools (``PagedPool``) ride as codes + per-row-scale operands;
     the tile loop dequantizes in VMEM right after the DMA (see the in-tile
     dequant helpers above) — the HBM side only ever moves wire bytes."""
-    from petals_tpu.ops.paged_attention import PagedPool
+    from petals_tpu.ops.paged_attention import PagedPool, pool_geometry
 
     quantized = isinstance(k_pool, PagedPool)
     kv_quant = k_pool.kind if quantized else "none"
     n_lanes, q_len, num_q_heads, head_dim = q.shape
-    if quantized:
-        n_pages, page_size, num_kv_heads, d_store = k_pool.codes.shape
-    else:
-        n_pages, page_size, num_kv_heads, d_store = k_pool.shape
+    n_pages, page_size, num_kv_heads, d_store = pool_geometry(k_pool, head_dim)  # in either stored form
     if q_len != 1:
         raise ValueError(f"decode kernel takes one token per lane, got q_len={q_len}")
     assert num_q_heads % num_kv_heads == 0, (num_q_heads, num_kv_heads)
@@ -745,15 +748,12 @@ def paged_flash_prefill_attend(
     (padded-tail rows produce garbage-but-unread outputs, as in the
     reference). The chunk's KV must already be scattered into the pages.
     Quantized pools ride as codes + scales, exactly as in the decode twin."""
-    from petals_tpu.ops.paged_attention import PagedPool
+    from petals_tpu.ops.paged_attention import PagedPool, pool_geometry
 
     quantized = isinstance(k_pool, PagedPool)
     kv_quant = k_pool.kind if quantized else "none"
     batch, q_len, num_q_heads, head_dim = q.shape
-    if quantized:
-        n_pages, page_size, num_kv_heads, d_store = k_pool.codes.shape
-    else:
-        n_pages, page_size, num_kv_heads, d_store = k_pool.shape
+    n_pages, page_size, num_kv_heads, d_store = pool_geometry(k_pool, head_dim)  # in either stored form
     if batch != 1:
         raise ValueError(f"prefill kernel serves one lane's chunk, got batch={batch}")
     assert num_q_heads % num_kv_heads == 0, (num_q_heads, num_kv_heads)
@@ -879,7 +879,7 @@ def paged_attend_dispatch(
     scalar is one lane's chunked-prefill bucket. Calls the kernel cannot
     express — gemma2's logit softcap and its TRACED effective window,
     non-causal — always compose from XLA."""
-    from petals_tpu.ops.paged_attention import kv_quant_kind_of
+    from petals_tpu.ops.paged_attention import kv_quant_kind_of, pool_geometry
 
     k_pool, tables = k_kv.pool, k_kv.tables
     v_pool = v_kv.pool
@@ -899,10 +899,10 @@ def paged_attend_dispatch(
         # handles vector q_offset with q_len > 1 via per-row causal masking).
         or (decode and q.shape[1] != 1)
     )
-    # k_pool.shape is the LOGICAL geometry either way (PagedPool answers it)
+    # the LOGICAL geometry, whichever form the pool is stored in
+    _, page_size, hkv, _ = pool_geometry(k_pool, q.shape[-1])
     key = shape_class(
-        tables.shape[0], tables.shape[1], k_pool.shape[1],
-        k_pool.shape[2], k_pool.shape[3],
+        tables.shape[0], tables.shape[1], page_size, hkv, q.shape[-1],
         sliding_window if window_static else None, kv_quant,
     )
     kind = "decode" if decode else "prefill"
@@ -988,10 +988,11 @@ def _walk_decode_rows(
     dequantises to bf16, holes read zeros) and meet the dots in that dtype,
     products summed in float32; max, sum and output run in float32, and the
     weights are cast to V's dtype for their dot as the fused kernel's are."""
-    from petals_tpu.ops.paged_attention import gather_pages
+    from petals_tpu.ops.paged_attention import gather_pages, pool_geometry
 
     n_lanes, width = tables.shape
-    page_size, hkv, d = k_pool.shape[1:]
+    d = q.shape[-1]
+    _, page_size, hkv, _ = pool_geometry(k_pool, d)
     group = q.shape[2] // hkv
     block = walk_block_pages(n_lanes, width, page_size, hkv, d, jnp.dtype(k_pool.dtype).itemsize)
     rows = block * page_size
@@ -1004,8 +1005,8 @@ def _walk_decode_rows(
     def a_block(i, carry):
         m, l, acc = carry
         cols = jax.lax.dynamic_slice_in_dim(tables, i * block, block, axis=1)
-        k = gather_pages(k_pool, cols)  # [n_lanes, rows, hkv, d]
-        v = gather_pages(v_pool, cols)
+        k = gather_pages(k_pool, cols, hkv)  # [n_lanes, rows, hkv, d]
+        v = gather_pages(v_pool, cols, hkv)
         s = jnp.einsum("bkgd,bskd->bkgs", qg, k, preferred_element_type=jnp.float32) * scale
         if logit_softcap is not None:
             s = jnp.tanh(s / logit_softcap) * logit_softcap
@@ -1048,10 +1049,10 @@ def composed_paged_attend(
     differences of positions, so the shift changes nothing; ALiBi is a
     difference too, but its path is left as it was)."""
     from petals_tpu.ops.attention import attend_reference
-    from petals_tpu.ops.paged_attention import gather_pages
+    from petals_tpu.ops.paged_attention import gather_pages, pool_geometry
 
     n_lanes, max_pages = tables.shape
-    page_size = k_pool.shape[1]
+    _, page_size, hkv, _ = pool_geometry(k_pool, q.shape[-1])
     pos = jnp.asarray(q_offset, jnp.int32)
     walk = pos.ndim == 1 and q.shape[1] == 1 and causal and kv_length is not None
     live = pos < max_pages * page_size  # the idle sentinel is max_length
@@ -1069,8 +1070,8 @@ def composed_paged_attend(
             q, k_pool, v_pool, tables, q_pos=jnp.asarray(q_offset, jnp.int32), kv_len=kv_len,
             alibi_slopes=alibi_slopes, sliding_window=sliding_window, scale=scale, logit_softcap=logit_softcap,
         )
-    k = gather_pages(k_pool, tables)
-    v = gather_pages(v_pool, tables)
+    k = gather_pages(k_pool, tables, hkv)
+    v = gather_pages(v_pool, tables, hkv)
     return attend_reference(
         q, k, v, q_offset=q_offset, kv_length=kv_length,
         alibi_slopes=alibi_slopes, sliding_window=sliding_window,
@@ -1115,7 +1116,7 @@ def maybe_autotune_paged_attention(
 
     import numpy as np
 
-    from petals_tpu.ops.paged_attention import PagedPool, quantize_kv_rows
+    from petals_tpu.ops.paged_attention import PagedPool, fold_rows, quantize_kv_rows, stored_row
 
     hq = hkv * max(int(group), 1)
     n_pages = n_lanes * max_pages
@@ -1132,9 +1133,15 @@ def maybe_autotune_paged_attention(
     q = jax.random.normal(kq, (n_lanes, 1, hq, d), jnp.bfloat16) * 0.1
     k_pool = jax.random.normal(kk, (n_pages, page_size, hkv, d), jnp.bfloat16) * 0.1
     v_pool = jax.random.normal(kv_, (n_pages, page_size, hkv, d), jnp.bfloat16) * 0.1
+    # in the form a server's pool of this class is stored in: what is timed is what a step would run
+    row = stored_row(hkv, _kv_store_dim(d, kv_quant))
     if kv_quant != "none":
-        k_pool = PagedPool(*quantize_kv_rows(k_pool, kv_quant))
-        v_pool = PagedPool(*quantize_kv_rows(v_pool, kv_quant))
+        k_pool, v_pool = (
+            PagedPool(fold_rows(codes, row), scales)
+            for codes, scales in (quantize_kv_rows(k_pool, kv_quant), quantize_kv_rows(v_pool, kv_quant))
+        )
+    else:
+        k_pool, v_pool = fold_rows(k_pool, row), fold_rows(v_pool, row)
 
     def _perturb(pool, f, after):
         # quantized pools perturb the SCALES leaf — same effect (the chain

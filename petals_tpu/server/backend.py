@@ -335,21 +335,22 @@ class TransformerBackend:
         (kv_quant_type != none): (k_codes, v_codes, k_scales, v_scales) — the
         codes in the storage dtype (int8, or uint8 with two split-half-packed
         dims per byte for nf4a) and f32 absmax scales per (page row, kv head).
+        A values or codes leaf whose row is under the chip's 128 lanes
+        (head_dim 64; 128 too for nf4a's packed half) is stored with the kv
+        heads folded into it, [n, n_pages, page_size, hkv * d_store]
+        (``pool_row``; the rule: ops/paged_attention.py ``stored_row``).
         The paged path is gated to mesh-less single-host servers
         (server/batching.py), so no sharding rides these. The pool is as deep
         as the blocks of [start, end) that keep keys and values: a block with
         a state of its own (``state_cache_descriptors``) has no pages."""
         n = sum(start <= i < end for i in self.kv_layers)
-        shape = (n, n_pages, page_size, self.num_kv_heads, self.head_dim)
+        shape = (n, n_pages, page_size, *self.pool_row)
         if self.kv_quant_type == "none":
             return (
                 TensorDescriptor(shape, self.cache_dtype),
                 TensorDescriptor(shape, self.cache_dtype),
             )
-        if self.kv_quant_type == "int8":
-            codes_shape, codes_dtype = shape, jnp.int8
-        else:  # nf4a
-            codes_shape, codes_dtype = (*shape[:-1], self.head_dim // 2), jnp.uint8
+        codes_shape, codes_dtype = shape, jnp.int8 if self.kv_quant_type == "int8" else jnp.uint8
         scales_shape = (n, n_pages, page_size, self.num_kv_heads)
         return (
             TensorDescriptor(codes_shape, codes_dtype),
@@ -357,6 +358,37 @@ class TransformerBackend:
             TensorDescriptor(scales_shape, jnp.float32),
             TensorDescriptor(scales_shape, jnp.float32),
         )
+
+    @functools.cached_property
+    def pool_row(self) -> tuple:
+        """The trailing dims the page pool's values (or codes) keep a token
+        row in: ``(hkv, d_store)``, or ``(hkv * d_store,)`` where the rule
+        folds it (ops/paged_attention.py ``stored_row``). Fixed at start."""
+        from petals_tpu.ops.paged_attention import stored_row
+
+        return stored_row(self.num_kv_heads, self.head_dim // 2 if self.kv_quant_type == "nf4a" else self.head_dim)
+
+    def pool_to_wire(self, pages):
+        """Pages taken out of the stacked pool (``[n_blocks, n_slots,
+        page_size, *row]``: a plain array or a ``PagedPool``, jax or numpy),
+        as everything outside the device holds them: rows of ``[hkv,
+        d_store]``. Reads the form off the leaf; a reshape of the host's copy
+        where it is done on one: free."""
+        from petals_tpu.ops.paged_attention import PagedPool, unfold_rows
+
+        if isinstance(pages, PagedPool):
+            return PagedPool(self.pool_to_wire(pages.codes), pages.scales)
+        return unfold_rows(pages, self.num_kv_heads) if pages.ndim == 4 else pages
+
+    @staticmethod
+    def wire_to_pool(pages, pool):
+        """``pool_to_wire``'s inverse: rows of ``[hkv, d_store]`` folded to
+        the row that the stacked ``pool`` stores."""
+        from petals_tpu.ops.paged_attention import PagedPool, fold_rows
+
+        if isinstance(pages, PagedPool):
+            return PagedPool(fold_rows(pages.codes, pool.codes.shape[3:]), pages.scales)
+        return fold_rows(pages, pool.shape[3:])
 
     def state_cache_descriptors(self, n_lanes: int) -> tuple:
         """Descriptors of the STATE pool beside the pages: one a leaf of the
@@ -733,8 +765,7 @@ class TransformerBackend:
         steady state it is one constant and costs zero extra compiles."""
         from petals_tpu.ops import paged_flash_attention as pfa
 
-        # k_pool.shape answers the LOGICAL geometry for quantized pools too
-        page_size, hkv, d = k_pool.shape[2], k_pool.shape[3], k_pool.shape[4]
+        page_size, hkv, d = k_pool.shape[2], self.num_kv_heads, self.head_dim  # the pool's row may be folded
         keys = [
             pfa.shape_class(tables.shape[0], tables.shape[1], page_size, hkv, d, window, self.kv_quant_type)
             for window in self._static_windows()
@@ -764,7 +795,7 @@ class TransformerBackend:
         come back as ``ys`` are sliced out, copied and written whole into a
         second pool layer by layer, and that pool is copied over the donated
         one after the loop, all to land a lane's one new row. So the pools
-        are flattened to ``[n_blocks * n_pages, page_size, hkv, d]`` (a
+        are flattened to ``[n_blocks * n_pages, page_size, *pool_row]`` (a
         bitcast; a quantized ``PagedPool`` leaf by leaf) and a layer reaches
         its pages through block tables shifted by ``layer * n_pages`` (holes
         stay -1): ``PagedKV``'s scatter and gather work on the carried pool
@@ -1336,15 +1367,16 @@ class TransformerBackend:
 
             def one(pool):
                 # quantized pools dequantize here: the dense lane view is the
-                # fp-facing boundary (prefill compute, kv export, snapshots)
+                # fp-facing boundary (prefill compute, kv export, snapshots),
+                # and rows of [hkv, d] whatever row the pool stores
                 if isinstance(pool, PagedPool):
                     return dequantize_kv(
-                        gather_leaf(pool.codes), gather_leaf(pool.scales), pool.kind
+                        self.pool_to_wire(gather_leaf(pool.codes)), gather_leaf(pool.scales), pool.kind
                     )
                 return gather_leaf(pool)
 
             k, v = one(k_pool), one(v_pool)
-            shape = (n_blocks, 1, max_pages * page_size, *k_pool.shape[3:])
+            shape = (n_blocks, 1, max_pages * page_size, self.num_kv_heads, self.head_dim)
             return k.reshape(shape), v.reshape(shape)
 
         return f
@@ -1365,9 +1397,10 @@ class TransformerBackend:
             safe = jnp.where(table_row >= 0, table_row, n_pages)
 
             def one(pool, buf):
-                pages = buf.reshape(n_blocks, max_pages, page_size, *pool.shape[3:])
+                pages = buf.reshape(n_blocks, max_pages, page_size, *pool.shape[3:])  # a plain pool's own row
                 if isinstance(pool, PagedPool):
                     codes, scales = quantize_kv_rows(pages, pool.kind)
+                    codes = self.wire_to_pool(codes, pool.codes)
                     return PagedPool(
                         pool.codes.at[:, safe].set(
                             codes.astype(pool.codes.dtype), mode="drop"
@@ -1385,9 +1418,10 @@ class TransformerBackend:
     @functools.cached_property
     def _swap_out_pages_fn(self):
         """Gather an explicit page list out of the pool as [n_blocks, n_slots,
-        page_size, hkv, d] pairs, bound for the host swap tier (scheduler
-        preemption). Non-donating: the pool stays live — the pages are only
-        FREED once the host copy has landed (server/batching.py
+        page_size, *pool_row] pairs, bound for the host swap tier (scheduler
+        preemption; the host's copy is held as rows of [hkv, d_store]:
+        ``pool_to_wire`` there, ``wire_to_pool`` on the way back).
+        Non-donating: the pool stays live — the pages are only FREED once the host copy has landed (server/batching.py
         _swap_out_lane validates the lane generation first). Per-leaf, so a
         quantized pool swaps its PACKED codes + scales — the host tier holds
         (and the ledger bills) wire bytes, never re-inflated fp pages."""

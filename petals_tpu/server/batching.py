@@ -529,7 +529,7 @@ class DecodeBatcher:
                 self._tables_mutated()
                 logger.info(
                     f"Paged-batching pool open: {self.n_pages} pages x "
-                    f"{self.page_size} tokens ({self.n_lanes} lanes x "
+                    f"{self.page_size} tokens of {list(getattr(self.backend, 'pool_row', ()))} ({self.n_lanes} lanes x "
                     f"{self.max_pages} table slots) for blocks "
                     f"[{self.backend.first_block}, {self.backend.first_block + self.backend.n_blocks})"
                 )
@@ -1184,8 +1184,11 @@ class DecodeBatcher:
             state = self.backend._lane_state_take_fn(self._state(), np.int32(lane)) if self._n_state else ()
             # per-leaf host copy: a quantized pool's SwapEntry holds a
             # PagedPool of numpy arrays — packed wire bytes, never fp pages
+            # (rows of [hkv, d_store], whatever row the pool stores: a
+            # reshape of the host's copy)
             to_host = lambda t: jax.tree_util.tree_map(np.asarray, t)
-            return to_host(k), to_host(v), to_host(tuple(state))
+            to_wire = self.backend.pool_to_wire
+            return to_wire(to_host(k)), to_wire(to_host(v)), to_host(tuple(state))
 
     async def _ensure_resident(self, lane: int) -> None:
         """Transparent resume: if ``lane`` is suspended (or a suspend is in
@@ -1258,8 +1261,9 @@ class DecodeBatcher:
                     "Lane pool was reset while this session was swapped out"
                 )
             k_pool, v_pool = self._buffers()
+            to_pool = self.backend.wire_to_pool  # the host's rows of [hkv, d_store] as the pool stores them
             k_pool, v_pool = self.backend._swap_in_pages_fn(
-                k_pool, v_pool, entry.k, entry.v, pages
+                k_pool, v_pool, to_pool(entry.k, k_pool), to_pool(entry.v, v_pool), pages
             )
             state = self.backend._lane_state_put_fn(self._state(), entry.state, np.int32(lane)) if self._n_state else ()
             self._update(k_pool, v_pool, *state)
@@ -1443,6 +1447,8 @@ class DecodeBatcher:
             # honest capacity math for clients: the pool's encoding and its
             # WIRE bytes/token (what a page actually costs under kv quant)
             info["kv_quant"] = getattr(self.backend, "kv_quant_type", "none")
+            # the trailing dims the pool keeps a token row in: (hkv, d_store), or folded to one (a row under 128 lanes)
+            info["pool_row"] = list(getattr(self.backend, "pool_row", ()))
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
             if self._n_state:
                 # a lane's fixed part, beside what its pages cost a token, and what the busy lanes hold of it

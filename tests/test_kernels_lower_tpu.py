@@ -34,7 +34,7 @@ from jax.sharding import SingleDeviceSharding  # noqa: E402
 from petals_tpu.ops import paged_flash_attention as pfa  # noqa: E402
 from petals_tpu.ops import quant as Q  # noqa: E402
 from petals_tpu.ops.flash_attention import flash_attend  # noqa: E402
-from petals_tpu.ops.paged_attention import PagedPool  # noqa: E402
+from petals_tpu.ops.paged_attention import PagedPool, stored_row  # noqa: E402
 from petals_tpu.models.registry import span_runs  # noqa: E402
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -70,14 +70,11 @@ def test_flash_attend_lowers(v5e, hq, hkv, d, window):
 
 
 def _pool(v5e, n_pages, page_size, hkv, d, kv_quant):
-    if kv_quant == "none":
-        return v5e((n_pages, page_size, hkv, d), BF16)
-    codes = (
-        v5e((n_pages, page_size, hkv, d), jnp.int8)
-        if kv_quant == "int8"
-        else v5e((n_pages, page_size, hkv, d // 2), jnp.uint8)
-    )
-    return PagedPool(codes, v5e((n_pages, page_size, hkv), F32))
+    """A block's pool as a server stores it: a row under 128 lanes (bf16-d64,
+    nf4a's packed half of 128) folded to ``hkv * d_store``."""
+    row = stored_row(hkv, d // 2 if kv_quant == "nf4a" else d)
+    leaf = v5e((n_pages, page_size, *row), {"none": BF16, "int8": jnp.int8, "nf4a": jnp.uint8}[kv_quant])
+    return leaf if kv_quant == "none" else PagedPool(leaf, v5e((n_pages, page_size, hkv), F32))
 
 
 PAGED_CASES = [
@@ -296,12 +293,14 @@ STEP_CASES = [
 ]
 
 
-def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16):
-    """``(optimized HLO, stacked params, pool aval)`` of ``TransformerBackend``'s
-    paged decode step, or of its mixed step with a prompt chunk of ``chunk``
-    riding it, at a cell's widths and depth (8 lanes, pages of 64,
-    ``pages_a_lane`` slots a table and as many pages a lane in the pool, pools
-    donated), compiled for the v5e."""
+def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none"):
+    """``(optimized HLO, stacked params, pool aval, (hkv, d))`` of
+    ``TransformerBackend``'s paged decode step, or of its mixed step with a
+    prompt chunk of ``chunk`` riding it, at a cell's widths and depth (8 lanes,
+    pages of 64, ``pages_a_lane`` slots a table and as many pages a lane in
+    the pool, pools donated, in the form the backend's descriptors give them:
+    a head_dim of 64 folded to rows of ``hkv * d``), compiled for the v5e. Under
+    ``kv_quant`` the pools are ``PagedPool``s and the aval returned is their codes'."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -317,10 +316,12 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16):
         for kind, _, length in span_runs(family.span_kinds(cfg, 0, depth))
     )
     params = runs[0] if len(runs) == 1 else runs
-    backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=depth, memory_cache=None)
+    backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=depth, memory_cache=None, kv_quant_type=kv_quant)
     # as deep as the blocks that keep keys and values: all of them, but for a span with a recurrent state
-    pool = v5e((len(backend.kv_layers), n_pages, page_size, backend.num_kv_heads, backend.head_dim), BF16)
-    avals = [params, pool, pool, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
+    descs = backend.paged_cache_descriptors(n_pages, page_size, 0, depth)  # in the form the rule stores them
+    pool = v5e(descs[0].shape, BF16 if kv_quant == "none" else descs[0].dtype)
+    pools = pool if kv_quant == "none" else PagedPool(pool, v5e(descs[2].shape, descs[2].dtype))
+    avals = [params, pools, pools, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
     step = backend._paged_decode_fn
     if chunk:  # chunk_hidden, then chunk_lane, chunk_pos, chunk_n_valid, chunk_n_total
         step = backend._paged_mixed_step_fn
@@ -334,7 +335,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16):
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
         hlo = jax.jit(step, donate_argnums=donated).lower(*avals).compile().as_text()
-    return hlo, runs, pool
+    return hlo, runs, pool, (backend.num_kv_heads, backend.head_dim)
 
 
 @pytest.mark.parametrize("config_name,chunk", STEP_CASES)
@@ -345,7 +346,7 @@ def test_paged_step_loop_reads_stacked_weights_in_place(v5e, tmp_path, config_na
     twice a layer for Falcon's ``wq`` (a dynamic-slice fusion, then a
     transposing copy: 27% of the decode loop on the chip), the same pair for
     ``wk`` / ``wv``, and Mixtral's and OLMoE's likewise."""
-    hlo, runs, _ = _compiled_step(v5e, tmp_path, config_name, chunk)
+    hlo, runs, _, _ = _compiled_step(v5e, tmp_path, config_name, chunk)
     attention = [run[name].shape for run in runs if run["wq"].shape[0] > 1 for name in ("wq", "wk", "wv", "wo")]
     attention = attention or [run[name].shape for run in runs for name in ("wq", "wk", "wv", "wo")]  # no run of full layers is a loop
     relayouts, seen = weight_relayouts(
@@ -381,7 +382,7 @@ def test_decode_step_reads_the_experts_hit_out_of_the_stacked_run(v5e, tmp_path,
     themselves, as the loop carries them or the program was handed them: no
     slice, no copy (what ``ragged_dot`` could not do: PERF.md section 6, PR
     31), and nothing else in the step moves a weight either."""
-    hlo, runs, _ = _compiled_step(v5e, tmp_path, config_name, 0)
+    hlo, runs, _, _ = _compiled_step(v5e, tmp_path, config_name, 0)
     expert_runs = [run for run in runs if "w1" in run]
     calls = hit_calls(hlo)
     assert len(calls) == len(expert_runs), [name for name, _ in calls]
@@ -436,11 +437,20 @@ def test_dispatch_rule_picks_hit_only_where_it_can_read_in_place(tmp_path):
 # ---------------------------------------------------------------- the step leaves the page pool where it lies
 
 
-def pool_moves(hlo: str, pool_shape: tuple) -> tuple:
+def _row_of(dims: tuple, rows: set):
+    """Which of ``rows`` (trailing dims of a token row: as the pool stores it,
+    as attention sees it) ``dims`` ends in, or None."""
+    return next((row for row in rows if dims[-len(row):] == row), None)
+
+
+def pool_moves(hlo: str, pool_shape: tuple, heads: tuple) -> tuple:
     """``(moves, loops_seen)``: every instruction of an optimized HLO module
     that moves a page pool, or a layer of one, and computes nothing.
-    ``pool_shape`` is the stacked pool's, ``[layers, n_pages, page_size, hkv,
-    d]``. In ``ENTRY``: an ``AllocateBuffer`` custom call or a ``copy`` of the
+    ``pool_shape`` is the stacked pool's as it is stored, ``[layers, n_pages,
+    page_size, hkv, d]`` or ``[layers, n_pages, page_size, hkv * d]``
+    (ops/paged_attention.py ``stored_row``), ``heads`` its ``(hkv, d)``: a
+    row counts in either form. In ``ENTRY``: an ``AllocateBuffer`` custom
+    call or a ``copy`` of the
     pool's size (a second pool, and the copy back over the donated one). In a
     while body: an instruction that produces a pool layer's worth of rows of
     ``[hkv, d]`` or more and is one of ``_MOVES``, a fusion made of them alone, or a fusion
@@ -452,6 +462,7 @@ def pool_moves(hlo: str, pool_shape: tuple) -> tuple:
     comps = _computations(hlo)
     pool_elements = math.prod(pool_shape)
     layer_elements = pool_elements // pool_shape[0]
+    rows = {tuple(pool_shape[3:]), tuple(heads)}
     entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
     moves = [
         f"ENTRY %{name} = {op} -> {list(dims)}"
@@ -466,8 +477,8 @@ def pool_moves(hlo: str, pool_shape: tuple) -> tuple:
             if op == "get-tuple-element":
                 carried = carried or math.prod(dims) == pool_elements
                 continue
-            if op in ("parameter", "tuple", "bitcast", "constant") or dims[-2:] != pool_shape[-2:]:
-                continue  # not rows of [kv heads, head_dim]: a weight
+            if op in ("parameter", "tuple", "bitcast", "constant") or _row_of(dims, rows) is None:
+                continue  # not rows of [kv heads, head_dim], folded or not: a weight
             if math.prod(dims) < layer_elements:
                 continue
             fused = _fused(comps, op, rest)
@@ -478,14 +489,7 @@ def pool_moves(hlo: str, pool_shape: tuple) -> tuple:
 
 
 POOL_CASES = [
-    pytest.param(
-        config_name, chunk, id=f"{config_name}-{'mixed-256' if chunk else 'decode'}",
-        marks=[pytest.mark.xfail(strict=True, reason=(
-            "a pool of head_dim 64 lives on the device with the page index minor (bf16[5,128,64,8,64]{1,4,3,2,0}: 64 is "
-            "under the 128-lane tile), so ENTRY relays both pools to the logical layout before the loop and back "
-            "after it, once a step; the follow-up stores such a pool 128 wide (ROADMAP S7 (a))"
-        ))] if config_name == "falcon-40b-span5" else [],
-    )
+    pytest.param(config_name, chunk, id=f"{config_name}-{'mixed-256' if chunk else 'decode'}")
     for config_name in ("falcon-40b-span5", "mixtral-8x7b-span2", "olmoe-1b-7b-span8", "k-exaone-236b-span5-ep8")
     for chunk in (0, 256)
 ]
@@ -500,14 +504,36 @@ def test_paged_step_leaves_the_page_pool_in_place(v5e, tmp_path, config_name, ch
     two ``AllocateBuffer`` and two ``copy`` of ``bf16[8,128,64,16,128]`` (268
     MB each) in ``ENTRY`` and, a layer and a pool, a ``dynamic-slice`` fusion,
     a ``copy-start`` / ``copy-done`` and a ``dynamic-update-slice`` fusion of
-    a whole layer in the body: 2.7 ms of a 19.7 ms step on the chip."""
-    hlo, _, pool = _compiled_step(v5e, tmp_path, config_name, chunk)
-    moves, loops_seen = pool_moves(hlo, tuple(pool.shape))
+    a whole layer in the body: 2.7 ms of a 19.7 ms step on the chip. Falcon's
+    pool of head_dim 64, handed over as ``bf16[5,128,64,8,64]``, lived on the
+    device with the page index minor (``{1,4,3,2,0}``: 64 is under the
+    128-lane tile) and ``ENTRY`` relaid both pools before the loop and back
+    after it, 0.8 ms of a 12.3 ms step (until PR 38); stored as
+    ``bf16[5,128,64,512]`` it is handed over in the layout the step computes
+    in."""
+    hlo, _, pool, heads = _compiled_step(v5e, tmp_path, config_name, chunk)
+    if config_name == "falcon-40b-span5":
+        assert tuple(pool.shape[3:]) == (heads[0] * heads[1],), pool.shape
+    moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
     assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
     assert not moves, f"the step moves the page pool around its {pool.shape[0]} layers: {moves}"
 
 
-@pytest.mark.parametrize("config_name,pages_a_lane", [("olmo-hybrid-7b-span16", 40), ("olmoe-1b-7b-span8", 16)])
+@pytest.mark.parametrize("config_name,kv_quant", [("falcon-40b-span5", "int8"), ("mixtral-8x7b-span2", "nf4a")])
+def test_paged_step_leaves_a_quantized_pool_of_narrow_codes_in_place(v5e, tmp_path, config_name, kv_quant):
+    """The rule is the codes' own: int8 codes of a head_dim of 64 are 64 wide
+    and nf4a's packed bytes of a head_dim of 128 are too, and either pool,
+    kept as rows of ``[hkv, d_store]``, is relaid whole four times a step in
+    ``ENTRY`` as Falcon's bf16 pool was (``s8[5,128,64,8,64]``,
+    ``u8[2,128,64,8,64]``: compiled for the v5e, PR 38). Stored folded, none."""
+    hlo, _, codes, heads = _compiled_step(v5e, tmp_path, config_name, 0, kv_quant=kv_quant)
+    assert len(codes.shape) == 4 and codes.shape[3] == heads[0] * 64  # folded; d_store is 64 in both
+    moves, loops_seen = pool_moves(hlo, tuple(codes.shape), (heads[0], 64))
+    assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
+    assert not moves, f"the step moves the codes around their {codes.shape[0]} layers: {moves}"
+
+
+@pytest.mark.parametrize("config_name,pages_a_lane", [("olmo-hybrid-7b-span16", 40), ("olmoe-1b-7b-span8", 16), ("falcon-40b-span5", 16)])
 def test_decode_step_makes_no_dense_view_of_the_lanes_tables(v5e, tmp_path, config_name, pages_a_lane):
     """A decode row walks its lane's pages in blocks (ops/paged_flash_attention.py
     ``composed_paged_attend``): the compiled decode step, at the cell's table
@@ -515,8 +541,12 @@ def test_decode_step_makes_no_dense_view_of_the_lanes_tables(v5e, tmp_path, conf
     ``[hkv, d]`` or more besides the pool itself, in any dtype. Until PR 36 a
     layer made four: the bf16 gather of every table slot of every lane, for
     keys and for values, and ``attend_reference``'s float32 copy of each
-    (1.0 GB moved a layer and a step at 8 lanes of 40 pages and 32 kv heads)."""
-    hlo, _, pool = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane)
+    (1.0 GB moved a layer and a step at 8 lanes of 40 pages and 32 kv heads).
+    Falcon's pool is stored folded (rows of ``hkv * d``): its rows count in
+    that form and as the ``[hkv, d]`` a walk's block unfolds them to."""
+    hlo, runs, pool, heads = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane)
+    rows = {tuple(pool.shape[3:]), heads}
+    weights = {tuple(p.shape)[cut:] for run in runs for p in run.values() for cut in (0, 1)}  # wk is [hidden, hkv * d] too
     comps = _computations(hlo)
     fused = {m.group(1) for instructions in comps.values() for _, _, op, rest in instructions if op == "fusion" and (m := re.search(r"calls=%([\w.\-]+)", rest))}
     pool_elements, view_rows = math.prod(pool.shape), 8 * pages_a_lane * 64
@@ -525,11 +555,12 @@ def test_decode_step_makes_no_dense_view_of_the_lanes_tables(v5e, tmp_path, conf
         if computation in fused:
             continue  # what a fusion computes inside it is never an array in memory
         for name, dims, op, _ in instructions:
-            if dims[-2:] != tuple(pool.shape[-2:]) or op in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+            row = _row_of(dims, rows)
+            if row is None or dims in weights or op in ("parameter", "get-tuple-element", "bitcast", "tuple"):
                 continue
             if math.prod(dims) == pool_elements:
                 seen += 1  # the pool, written in place by the new rows' scatter
-            elif math.prod(dims[:-2]) >= view_rows:
+            elif math.prod(dims[:-len(row)]) >= view_rows:
                 views.append(f"%{name} = {op} -> {list(dims)}")
     assert seen, "the pool's scatter was not found: has the HLO text changed?"
     assert not views, f"the decode step makes a dense view of the lanes' tables: {views}"
@@ -542,7 +573,7 @@ def test_paged_step_leaves_the_state_pool_and_its_pages_in_place(v5e, tmp_path, 
     olmo-hybrid-7b-span16 allocates no second state pool and copies none in
     ``ENTRY`` (708 MB of float32 at 8 lanes and 12 layers), and the chunked
     form's triangular solve compiles for the chip."""
-    hlo, _, pool = _compiled_step(v5e, tmp_path, "olmo-hybrid-7b-span16", chunk)
+    hlo, _, pool, _ = _compiled_step(v5e, tmp_path, "olmo-hybrid-7b-span16", chunk)
     assert pool.shape[0] == 4
     comps = _computations(hlo)
     entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)

@@ -28,6 +28,7 @@ from petals_tpu.ops.paged_attention import (
     paged_update_kv,
     quantize_kv_rows,
     quantize_kv_rows_np,
+    stored_row,
 )
 from petals_tpu.ops.paged_flash_attention import (
     paged_flash_attend,
@@ -162,12 +163,12 @@ def test_backend_descriptors_and_bytes(model_path, kind):
     hkv, d = backend.num_kv_heads, backend.head_dim
     if kind == "none":
         assert len(descs) == 2
-        assert descs[0].shape == (2, 6, 8, hkv, d)
+        assert descs[0].shape == (2, 6, 8, *stored_row(hkv, d))  # a row under 128 lanes is stored folded
         assert backend.kv_bytes_per_token() == backend.cache_bytes_per_token()
         return
     assert len(descs) == 4
     d_store = d if kind == "int8" else d // 2
-    assert descs[0].shape == descs[1].shape == (2, 6, 8, hkv, d_store)
+    assert descs[0].shape == descs[1].shape == (2, 6, 8, *stored_row(hkv, d_store))
     assert descs[2].shape == descs[3].shape == (2, 6, 8, hkv)
     assert jnp.dtype(descs[2].dtype) == jnp.float32
     assert backend.kv_bytes_per_token() < backend.cache_bytes_per_token()

@@ -163,7 +163,7 @@ def test_the_page_pool_is_as_deep_as_the_full_layers_and_the_state_pool_as_the_l
     k, v = backend.paged_cache_descriptors(12, 16, 0, 8)
     # the cache keeps a tile's 8 kv heads for the model's 4, the spare ones zeros (cfg.cache_kv_heads: the pool's layout on the device)
     assert backend.cfg.num_key_value_heads == 4 and backend.num_kv_heads == backend.cfg.cache_kv_heads == 8
-    assert k.shape == v.shape == (2, 12, 16, 8, 16)
+    assert k.shape == v.shape == (2, 12, 16, 8 * 16)  # rows of 8 kv heads of 16, under 128 lanes: stored folded
     matrix, tail = backend.state_cache_descriptors(3)
     assert matrix.shape == (6, 3, 4, 8, 16) and jnp.dtype(matrix.dtype) == jnp.float32  # float32 whatever the cache's dtype
     assert tail.shape == (6, 3, 3, 4 * (8 + 8 + 16))
@@ -587,7 +587,7 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     assert backend.state_bytes_per_lane() == 0
     per_token = 2 * 2 * backend.num_kv_heads * backend.head_dim * 4
     assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == per_token
-    assert [d.shape for d in backend.paged_cache_descriptors(6, 8, 0, 2)] == [(2, 6, 8, backend.num_kv_heads, backend.head_dim)] * 2
+    assert [d.shape for d in backend.paged_cache_descriptors(6, 8, 0, 2)] == [(2, 6, 8, backend.num_kv_heads * backend.head_dim)] * 2  # folded: the toy's head_dim is under 128 lanes, as Falcon's 64 is
     assert [d.shape for d in backend.cache_descriptors(3, 24, 0, 2)] == [(2, 3, 24, backend.num_kv_heads, backend.head_dim)] * 2
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
     # since PR 36 every family on the paged pool counts the table slots its steps read (_count_window)

@@ -151,13 +151,14 @@ def make_tiny_bloom(tmpdir: str, *, n_layers: int = 3, vocab: int = 128) -> str:
 
 
 @_model_build_cache
-def make_tiny_falcon(tmpdir: str, *, variant: str = "new", n_layers: int = 3, vocab: int = 128) -> str:
-    """variant: "new" (40b-style GQA dual-LN), "7b" (MQA parallel), "rw" (MHA alibi serial)."""
+def make_tiny_falcon(tmpdir: str, *, variant: str = "new", n_layers: int = 3, vocab: int = 128, head_dim: int = 16) -> str:
+    """variant: "new" (40b-style GQA dual-LN), "7b" (MQA parallel), "rw" (MHA alibi serial).
+    ``head_dim`` 64 is Falcon-40B's own: four heads of it over two kv heads."""
     from transformers import FalconConfig, FalconForCausalLM
 
     common = dict(
         vocab_size=vocab,
-        hidden_size=64,
+        hidden_size=4 * head_dim,
         num_hidden_layers=n_layers,
         num_attention_heads=4,
         layer_norm_epsilon=1e-5,
@@ -181,7 +182,7 @@ def make_tiny_falcon(tmpdir: str, *, variant: str = "new", n_layers: int = 3, vo
         raise ValueError(variant)
     torch.manual_seed(3)
     model = FalconForCausalLM(cfg).eval()
-    path = os.path.join(tmpdir, f"tiny-falcon-{variant}")
+    path = os.path.join(tmpdir, f"tiny-falcon-{variant}" + (f"-d{head_dim}" if head_dim != 16 else ""))
     model.save_pretrained(path, safe_serialization=True)
     return path
 
