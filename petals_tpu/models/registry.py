@@ -86,6 +86,15 @@ class ModelFamily:
     # back to an earlier position, so what needs that (a rollback, a reused prefix, speculative
     # verify) is refused for a family that declares one (server/backend.py ``state_layers``)
     block_state: Optional[Callable] = None
+    # (cfg, kind) -> what a position caches BESIDE its key and value in a block of that kind: an index row,
+    # ``(width, dtype, keep)`` (dtype None: the cache's own), that a learned sparse attention scores to choose the
+    # ``keep`` cached positions a row attends to (ops/sparse_attention.py); None for a kind without one. Unlike a
+    # state it grows with the context: the framework keeps it in pages of its own under the lane's block
+    # tables, written, freed and reused with the pages of keys and values, and hands a block ``(k, v, index)``
+    # as its ``kv``. Only the paged lane pool's decode, generation and mixed steps carry it; what does not
+    # (a private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised
+    # pages, a tp mesh) is refused for a family that declares one (server/backend.py ``index_row``)
+    block_index: Optional[Callable] = None
 
     def kind_of(self, cfg, block_index: int) -> Hashable:
         return None if self.block_kind is None else self.block_kind(cfg, block_index)
@@ -107,6 +116,9 @@ class ModelFamily:
 
     def state_for(self, cfg, kind: Hashable) -> Optional[tuple]:
         return None if self.block_state is None else self.block_state(cfg, kind)
+
+    def index_for(self, cfg, kind: Hashable) -> Optional[tuple]:
+        return None if self.block_index is None else self.block_index(cfg, kind)
 
 
 def _kind_args(kind: Hashable) -> tuple:

@@ -49,6 +49,13 @@ PREFILL_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 SPEC_CUTS_BACK = "a rejected draft is rolled back by cutting the cache to the last accepted position, and a state cannot be cut back"
 
 
+# why a path that handles keys and values alone refuses a span with an index row (``refuse_for_state``)
+INDEX_ROWS_RIDE = (
+    "only the paged lane pool's decode, generation and mixed steps carry the index rows' pages; a cache without "
+    "them would choose from nothing"
+)
+
+
 def bucket_length(n: int) -> int:
     for b in PREFILL_BUCKETS:
         if n <= b:
@@ -159,6 +166,16 @@ class TransformerBackend:
         )
         if self.state_layers:
             self._check_state(states, mesh)
+        # what a position caches BESIDE its key and value in the span's blocks (ModelFamily.block_index):
+        # an index row, (width, dtype), kept in a third page pool under the lanes' tables, and ``index_keep``,
+        # the positions a row's selection keeps; None for a span without one. Every block that keeps keys and
+        # values then keeps one
+        rows = {family.index_for(cfg, kind) for kind, _, _ in self.runs}
+        self.index_row = None
+        if rows != {None}:
+            self._check_index(rows, mesh)
+            width, dtype, keep = next(iter(rows))
+            self.index_row, self.index_keep = (int(width), jnp.dtype(dtype or self.cache_dtype)), int(keep)
         # adapter name -> (stacked {leaf: (A, B)}, scaling); see utils/peft.py
         self.adapters: Dict[str, tuple] = {}
         self._dummy_operands: Dict[tuple, jax.Array] = {}
@@ -221,15 +238,41 @@ class TransformerBackend:
                 f"the state is float32 and has no packed form"
             )
 
+    def _check_index(self, rows, mesh) -> None:
+        """A span whose positions cache an index row: what it cannot do yet
+        is refused here, with the reason, not served wrong."""
+        name = self.family.name
+        if len(rows) != 1 or self.state_layers:
+            raise NotImplementedError(f"{name}: an index row is served for a span whose blocks all cache the same one")
+        if mesh is not None:
+            raise NotImplementedError(
+                f"{name}: a span whose positions cache an index row is not served over a tp mesh yet: "
+                f"the row has one head, and the dense lane pool a mesh falls back to has no place for it"
+            )
+        if self.kv_quant_type != "none":
+            raise NotImplementedError(
+                f"{name}: kv_quant_type {self.kv_quant_type!r} is not served for a span whose positions cache an "
+                f"index row: the selection fetches single rows of the pool, which has no packed form for that yet"
+            )
+
     def refuse_for_state(self, what: str, why: str) -> None:
         """Raise for ``what`` if this span keeps a recurrent state: a state
         holds a whole history at one position and cannot be cut back to an
         earlier one, so what needs that, and the cache paths that do not
-        carry a state at all, are refused by what the family declares."""
+        carry a state at all, are refused by what the family declares. The
+        same paths carry no index row (``index_row``: a third page pool that
+        only the paged lane pool's decode, generation and mixed steps are
+        handed), so a span that caches one is refused there too, with its
+        own reason."""
         if self.state_layers:
             raise NotImplementedError(
                 f"{self.family.name}: {what} is not served for a span with a recurrent state "
                 f"({len(self.state_layers)} of its {self.n_blocks} blocks keep one): {why}"
+            )
+        if self.index_row is not None:
+            raise NotImplementedError(
+                f"{self.family.name}: {what} is not served for a span whose positions cache an index row beside "
+                f"their keys and values ({self.index_row[0]} wide, the key a learned sparse attention scores): {INDEX_ROWS_RIDE}"
             )
 
     def _by_run(self, params) -> tuple:
@@ -396,6 +439,55 @@ class TransformerBackend:
         whose blocks all keep keys and values."""
         return tuple(TensorDescriptor((len(self.state_layers), n_lanes, *shape), dtype) for shape, dtype in self.lane_state)
 
+    def index_cache_descriptors(self, n_pages: int, page_size: int) -> tuple:
+        """The descriptor of the INDEX pool beside the page pools of keys and
+        values, ``[kv layers, n_pages, *row]``: a page of it is a page of
+        theirs, under the same block tables, its positions' rows of ``width``
+        stored as ops/sparse_attention.py ``index_pool_row`` says (a row under
+        the chip's 128 lanes: several positions to a row of 128); none for a
+        span without an index row."""
+        if self.index_row is None:
+            return ()
+        from petals_tpu.ops.sparse_attention import index_pool_row
+
+        width, dtype = self.index_row
+        return (TensorDescriptor((len(self.kv_layers), n_pages, *index_pool_row(page_size, width)), dtype),)
+
+    def index_bytes_per_token(self) -> int:
+        """What a position caches across the span beside its keys and values:
+        its index rows. 0 for a span without one."""
+        if self.index_row is None:
+            return 0
+        width, dtype = self.index_row
+        return len(self.kv_layers) * width * dtype.itemsize
+
+    def sparse_reads(self, n_lanes: int, max_pages: int, page_size: int, last: np.ndarray, chunk=None) -> dict:
+        """What one paged step's programs do for a span whose blocks select
+        (``index_row``), over its layers, from the shapes the step is started
+        with: the live lanes' rows at positions ``last`` and the ``chunk``
+        (first position, tokens) of a mixed step. For the batcher's
+        ``sparse_*`` counters (ops/sparse_attention.py has the arithmetic)."""
+        from petals_tpu.ops.sparse_attention import chunk_reads, decode_reads
+
+        topk, layers = self.index_keep, len(self.kv_layers)
+        selects = max_pages * page_size > topk  # a table that cannot pass topk positions is attended to whole
+        contexts = [int(p) + 1 for p in last]
+        scored = read = pairs = 0
+        if contexts:
+            scored, read = decode_reads(n_lanes, max_pages, page_size, topk, max(contexts)) if selects else (0, sum(contexts))
+            pairs = scored  # one query row a lane
+        held, over, rows = sum(contexts), sum(c > topk for c in contexts), len(contexts)
+        if chunk is not None:
+            first, take = chunk
+            c_scored, c_pairs, c_read = (
+                chunk_reads(max_pages, page_size, topk, first, take, bucket_length(take)) if selects else (0, 0, first + take)
+            )
+            scored, pairs, read, held = scored + c_scored, pairs + c_pairs, read + c_read, held + first + take
+            over, rows = over + max(first + take - max(first, topk), 0), rows + take
+        return {"sparse_rows_selected": over * layers, "sparse_rows_dense": (rows - over) * layers,
+                "sparse_index_rows_scored": scored * layers, "sparse_score_pairs": pairs * layers,
+                "sparse_kv_rows_read": read * layers, "sparse_kv_rows_held": held * layers}
+
     def state_bytes_per_lane(self) -> int:
         """What a lane holds whatever its context: its states over the span's
         state layers. 0 for a span without one."""
@@ -412,7 +504,7 @@ class TransformerBackend:
             * self.num_kv_heads
             * self.head_dim
             * jnp.dtype(self.cache_dtype).itemsize
-        )
+        ) + self.index_bytes_per_token()
 
     def kv_bytes_per_token(self) -> int:
         """WIRE bytes per token across the span: what the paged pool, host
@@ -423,7 +515,7 @@ class TransformerBackend:
         return 2 * len(self.kv_layers) * kv_wire_bytes_per_token(
             self.num_kv_heads, self.head_dim, self.kv_quant_type,
             jnp.dtype(self.cache_dtype).itemsize,
-        )
+        ) + self.index_bytes_per_token()
 
     # ------------------------------------------------------------- jitted programs
 
@@ -680,6 +772,10 @@ class TransformerBackend:
         from petals_tpu.ops import paged_flash_attention as pfa
 
         cfg = self.cfg
+        if self.index_row is not None and max_pages * page_size > self.index_keep:
+            # the span's blocks select (ops/sparse_attention.py): neither timed program is on their path
+            self._paged_autotuned = True
+            return
         hkv = self.num_kv_heads
         heads = getattr(cfg, "num_attention_heads", hkv)
         for window in self._static_windows():  # one shape class a window
@@ -803,13 +899,13 @@ class TransformerBackend:
         the drop sentinel is one past the end of the flat pool, and the
         donated buffers alias the outputs.
 
-        ``layer(block_apply, carry, p_block, k_span, v_span, paged) ->
-        (carry, k_span, v_span)`` runs one block with its kind's
-        ``block_apply``: ``paged(k_span, v_span, tables)`` wraps the
-        carried pools and a set of block tables as that block's ``PagedKV``
-        pair, and the pools ``block_apply`` hands back go on to the next
-        layer. Returns ``(carry, k_pool, v_pool, state)``, the pools in their
-        stacked shape.
+        ``layer(block_apply, carry, p_block, spans, paged) -> (carry,
+        spans)`` runs one block with its kind's ``block_apply``: ``spans``
+        are the carried pools, keys' and values' (and the index rows' for a
+        span that caches one), ``paged(spans, tables)`` wraps them and a set
+        of block tables as that block's ``PagedKV``s, and the pools
+        ``block_apply`` hands back go on to the next layer. Returns
+        ``(carry, k_pool, v_pool, state)``, the pools in their stacked shape.
 
         A span with a recurrent state carries its STATE pool (``state``: one
         array a leaf, ``[state layers, n_lanes, ...]``) through the same loop
@@ -819,11 +915,18 @@ class TransformerBackend:
         (``_slots``), not its index in the span. A block of a kind that
         declares a state runs ``state_layer(block_apply, carry, p_block,
         mine) -> (carry, mine)`` on its layer of the state pool, ``mine``
-        one ``[n_lanes, ...]`` array a leaf, and touches no page."""
+        one ``[n_lanes, ...]`` array a leaf, and touches no page.
+
+        A span whose positions cache an index row (``index_row``) hands its
+        INDEX pool in as ``state``'s one leaf, ``[kv layers, n_pages,
+        page_size, width]``: a third page pool, flattened and carried as the
+        other two and reached through the same shifted tables, which comes
+        back in ``state``'s place."""
         from petals_tpu.ops.paged_attention import PagedKV
 
         depth, n_pages = k_pool.shape[0], k_pool.shape[1]
         by_sort = bool(self.state_layers)
+        indexed = self.index_row is not None
 
         def merged(pool):  # [depth, n_pages, ...] -> [depth * n_pages, ...]
             return jax.tree_util.tree_map(
@@ -836,28 +939,30 @@ class TransformerBackend:
             )
 
         def one(block_apply, scanned, p_block, slot, block_idx, kind=None):
-            inner, k_span, v_span, state = scanned
+            inner, spans, state = scanned
             if by_sort and self.family.state_for(self.cfg, kind) is not None:
                 mine = tuple(jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False) for a in state)
                 inner, mine = state_layer(block_apply, inner, p_block, mine)
                 state = tuple(
                     jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype), slot, 0) for a, new in zip(state, mine)
                 )
-                return (inner, k_span, v_span, state), None
+                return (inner, spans, state), None
             first_page = (slot if by_sort else block_idx) * n_pages
 
-            def paged(k_span, v_span, tables):
+            def paged(spans, tables):
                 shifted = jnp.where(tables >= 0, tables + first_page, -1)
                 own = (first_page, n_pages)
-                return PagedKV(k_span, shifted, own), PagedKV(v_span, shifted, own)
+                return tuple(PagedKV(span, shifted, own) for span in spans)
 
-            return (*layer(block_apply, inner, p_block, k_span, v_span, paged), state), None
+            return (*layer(block_apply, inner, p_block, spans, paged), state), None
 
-        (carry, k_span, v_span, state), _ = self._scan_span(
-            params, (carry, merged(k_pool), merged(v_pool), tuple(state)),
+        spans = (k_pool, v_pool, *state) if indexed else (k_pool, v_pool)
+        (carry, spans, state), _ = self._scan_span(
+            params, (carry, tuple(merged(pool) for pool in spans), () if indexed else tuple(state)),
             jnp.asarray(self._slots, jnp.int32) if by_sort else (), one, pass_kind=by_sort,
         )
-        return carry, stacked(k_span), stacked(v_span), state
+        k_pool, v_pool, *index = (stacked(span) for span in spans)
+        return carry, k_pool, v_pool, tuple(index) if indexed else state
 
     def _paged_lanes_layer(self, tables, positions):
         """``_scan_paged_span``'s ``layer`` for a step in which every lane
@@ -865,13 +970,13 @@ class TransformerBackend:
         speculative verify): one ``block_apply`` over the lanes' tables."""
         cfg = self.cfg
 
-        def layer(block_apply, h, p_block, k_span, v_span, paged):
-            live = self._live_rows(positions, tables.shape[1] * k_span.shape[1])  # max_pages * page_size
-            out, (k_kv, v_kv) = block_apply(
-                p_block, h, paged(k_span, v_span, tables), positions, cfg,
+        def layer(block_apply, h, p_block, spans, paged):
+            live = self._live_rows(positions, tables.shape[1] * spans[0].shape[1])  # max_pages * page_size
+            out, new_kv = block_apply(
+                p_block, h, paged(spans, tables), positions, cfg,
                 use_flash=False, tp_mesh=None, **live,
             )
-            return out, k_kv.pool, v_kv.pool
+            return out, tuple(kv.pool for kv in new_kv)
 
         return layer
 
@@ -1225,17 +1330,17 @@ class TransformerBackend:
             decode_half = self._paged_lanes_layer(tables, positions)
             extra = {"n_total": chunk_n_total} if takes_n_total else {}
 
-            def layer(block_apply, carry, p_block, k_span, v_span, paged):
+            def layer(block_apply, carry, p_block, spans, paged):
                 h_dec, h_pf = carry
-                out_dec, k_span, v_span = decode_half(block_apply, h_dec, p_block, k_span, v_span, paged)
+                out_dec, spans = decode_half(block_apply, h_dec, p_block, spans, paged)
                 # --- prefill half: the chunk lane's table row as a
                 # single-lane PagedKV over the pools the decode half wrote;
                 # writes land in the pages directly
-                out_pf, (k_kv, v_kv) = block_apply(
-                    p_block, h_pf, paged(k_span, v_span, table_row), chunk_pos, cfg,
+                out_pf, new_kv = block_apply(
+                    p_block, h_pf, paged(spans, table_row), chunk_pos, cfg,
                     use_flash=False, n_valid=chunk_n_valid, tp_mesh=None, **extra,
                 )
-                return (out_dec, out_pf), k_kv.pool, v_kv.pool
+                return (out_dec, out_pf), tuple(kv.pool for kv in new_kv)
 
             decode_state = self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2])
 

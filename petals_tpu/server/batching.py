@@ -252,6 +252,18 @@ class DecodeBatcher:
                 "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""),
                 "it has no place for the state: serve with page_size > 0",
             )
+        # a span whose positions cache an index row beside their keys and values (ModelFamily.block_index):
+        # a third page pool under the same tables, allocated, freed and reused with the pages; it rides the
+        # paged step programs where a state pool would (``_state``), and nothing else carries it
+        self._n_index = 1 if getattr(backend, "index_row", None) is not None else 0
+        if self._n_index and self.page_size is None:
+            backend.refuse_for_state(
+                "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""), "",
+            )
+        if self._n_index and int(swap_host_bytes or 0) > 0:
+            backend.refuse_for_state("the host swap tier (swap_host_bytes > 0)", "")
+        # such a span's rows choose positions only where a table can pass the selection's size (models/keye_vl2/block.py)
+        self._selects = bool(self._n_index) and self.page_size is not None and self.max_length > backend.index_keep
         self._pages: Optional[PageAllocator] = None
         self._tables: Optional[np.ndarray] = None  # [n_lanes, max_pages] int32, -1 = unallocated
         # cached tables_are_contiguous result for the stats/debug surface
@@ -471,6 +483,16 @@ class DecodeBatcher:
             # their step gave them (the one-step form a decode row, the chunked form a prompt chunk), and,
             # summed step by step over the lanes that fed rows, the bytes of state and of pages they hold
             self.stats.update(linattn_recurrent_tokens=0, linattn_chunk_tokens=0, state_bytes_held=0, kv_bytes_held=0)
+        if self._n_index:
+            # a family that declares an index row only (_count_sparse), from the shapes a step is started with,
+            # all times the span's layers: rows whose context was over / at most the selection's size, index
+            # rows their scoring read (and query row x index row pairs it scored), positions of keys and values
+            # the step's programs fetched against those the rows' lanes held, and, summed step by step, the
+            # bytes of index rows and of keys and values those lanes' pages hold
+            self.stats.update(
+                sparse_rows_selected=0, sparse_rows_dense=0, sparse_index_rows_scored=0, sparse_score_pairs=0, sparse_kv_rows_read=0,
+                sparse_kv_rows_held=0, index_bytes_held=0, kv_bytes_held=0,
+            )
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
         # (telemetry.journal), and the pool gauges/counters feed the /metrics
@@ -505,6 +527,8 @@ class DecodeBatcher:
                 )
                 if self._n_state:  # the state pool's leaves ride last
                     descs = (*descs, *self.backend.state_cache_descriptors(self.n_lanes))
+                if self._n_index:  # as the index pool does
+                    descs = (*descs, *self.backend.index_cache_descriptors(self.n_pages, self.page_size))
             else:
                 descs = self.backend.cache_descriptors(
                     self.n_lanes, self.max_length, 0, self.backend.n_blocks
@@ -566,7 +590,7 @@ class DecodeBatcher:
         quantized pool rides as 4 MemoryCache buffers (codes x2, scales x2)
         and is re-wrapped into PagedPool pytrees HERE, so every caller —
         step bodies, swap, COW, snapshots — keeps the 2-tuple shape."""
-        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self._n_state])
+        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self._n_state - self._n_index])
         if len(bufs) == 4:
             from petals_tpu.ops.paged_attention import PagedPool
 
@@ -576,19 +600,21 @@ class DecodeBatcher:
     def _state(self) -> tuple:
         """The state pool's leaves, which the paged step programs take after
         the pair of ``_buffers`` and hand back after it; none for a span
-        without a recurrent state."""
-        if not self._n_state:
+        without a recurrent state. A span that caches an index row hands its
+        index pool over the same way."""
+        n = self._n_state + self._n_index
+        if not n:
             return ()
-        return tuple(self.memory_cache.get_buffers(*self._handles[-self._n_state :]))
+        return tuple(self.memory_cache.get_buffers(*self._handles[-n:]))
 
     def _refuse_for_state(self, what: str, why: str) -> None:
-        if self._n_state:
+        if self._n_state or self._n_index:
             self.backend.refuse_for_state(what, why)
 
     def _update(self, k_pool, v_pool, *state) -> None:
         from petals_tpu.ops.paged_attention import PagedPool
 
-        for handle, leaf in zip(self._handles[-self._n_state :] if state else (), state):
+        for handle, leaf in zip(self._handles[-len(state) :] if state else (), state):
             self.memory_cache.update_cache(handle, leaf)
         if isinstance(k_pool, PagedPool):
             self.memory_cache.update_cache(self._handles[0], k_pool.codes)
@@ -1450,6 +1476,8 @@ class DecodeBatcher:
             # the trailing dims the pool keeps a token row in: (hkv, d_store), or folded to one (a row under 128 lanes)
             info["pool_row"] = list(getattr(self.backend, "pool_row", ()))
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
+            if self._n_index:  # of kv_bytes_per_token, the index rows' part
+                info["index_bytes_per_token"] = int(self.backend.index_bytes_per_token())
             if self._n_state:
                 # a lane's fixed part, beside what its pages cost a token, and what the busy lanes hold of it
                 info["state_bytes_per_lane"] = self._state_nbytes()
@@ -2264,13 +2292,16 @@ class DecodeBatcher:
         last = positions[positions < self.max_length] + (seq - 1)  # the idle sentinel is max_length
         if seq > 1:
             read = backend.pages_gathered(seq, self.max_pages, self.page_size)
+        elif self._selects:
+            read = 0  # the chosen positions' rows are fetched one by one: ``_count_sparse`` adds their pages' worth
         else:
             read = backend.pages_walked(self._walks, last, self.page_size) if last.size else 0
         self.stats["attn_pages_gathered"] += self.n_lanes * read
         self.stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
         if chunk is not None:
             lane, first, take = chunk
-            self.stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
+            if not self._selects:
+                self.stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
             self.stats["attn_pages_tabled"] += self.max_pages * layers
         if not self._windows:
             return
@@ -2299,6 +2330,30 @@ class DecodeBatcher:
             self.stats["linattn_chunk_tokens"] += int(chunk[1]) * layers
         self.stats["state_bytes_held"] += int(lanes.size) * self._state_nbytes()
         self.stats["kv_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
+
+    def _count_sparse(self, tables, positions, *, chunk=None) -> None:
+        """The selection's counters of one paged step (compute thread; a
+        family that declares an index row only), from the shapes the step was
+        started with: what the lanes that fed a row and the ``chunk`` (lane,
+        first position, tokens) of a mixed step made the programs score and
+        fetch (``backend.sparse_reads``), and the bytes of index rows and of
+        keys and values those lanes' pages hold."""
+        if not self._n_index or tables is None:
+            return
+        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
+        reads = self.backend.sparse_reads(
+            self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:]
+        )
+        for key, n in reads.items():
+            self.stats[key] += n
+        if self._selects:
+            self.stats["attn_pages_gathered"] += -(-reads["sparse_kv_rows_read"] // self.page_size)
+        if chunk is not None:
+            lanes = np.append(lanes, chunk[0])
+        pages = int((tables[lanes] >= 0).sum())
+        index = pages * self.page_size * int(self.backend.index_bytes_per_token())
+        self.stats["index_bytes_held"] += index
+        self.stats["kv_bytes_held"] += pages * self._page_nbytes() - index
 
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
@@ -2352,6 +2407,7 @@ class DecodeBatcher:
             self._count_moe(len(batch))
             self._count_window(tables, positions)
             self._count_state(tables, positions)
+            self._count_sparse(tables, positions)
             duration = time.perf_counter() - t_step
             if self.page_size is not None:
                 tm.STEP_PAGED.observe(duration)
@@ -2465,6 +2521,7 @@ class DecodeBatcher:
             self._count_moe(len(batch), chunk_tokens=take)
             self._count_window(tables, positions, chunk=(st.lane, st.position, take))
             self._count_state(tables, positions, chunk=(st.lane, take))
+            self._count_sparse(tables, positions, chunk=(st.lane, st.position, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2557,6 +2614,7 @@ class DecodeBatcher:
             self._count_moe(len(batch) + len(gen_states))
             self._count_window(tables, positions)
             self._count_state(tables, positions)
+            self._count_sparse(tables, positions)
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()

@@ -194,6 +194,14 @@ class TransformerHandler:
                 f"recurrent state, which cannot be cut back to a stored prefix"
             )
             prefix_cache_bytes = 0
+        if prefix_cache_bytes > 0 and getattr(backend, "index_row", None) is not None:
+            # a stored prefix is keys and values (a snapshot, or pinned pages a hit adopts and forks): neither
+            # carries the index rows a span with a learned sparse attention caches beside them
+            logger.info(
+                "Prefix cache off: the span's positions cache an index row beside their keys and values, "
+                "which a stored prefix does not carry"
+            )
+            prefix_cache_bytes = 0
         if prefix_cache_bytes > 0:
             from petals_tpu.server.prefix_cache import PrefixCache
             from petals_tpu.telemetry.ledger import get_ledger

@@ -330,6 +330,9 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     if backend.state_layers:  # the state pool's leaves ride last and are donated with the pages
         avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
         donated += (len(avals) - 1,)
+    if backend.index_row is not None:  # as the index pool does
+        avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.index_cache_descriptors(n_pages, page_size)))
+        donated += (len(avals) - 1,)
     # the raw step under tracked_jit: kernel_path only retraces, attend() resolves the path itself
     step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
@@ -584,3 +587,71 @@ def test_paged_step_leaves_the_state_pool_and_its_pages_in_place(v5e, tmp_path, 
         moved = [f"%{name} = {op}" for name, dims, op, rest in comps[entry] if math.prod(dims) == elements
                  and (op == "copy" or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
         assert not moved, f"the step moves the {what} pool: {moved}"
+
+
+SPARSE_CASES = [pytest.param(0, id="decode"), pytest.param(2048, id="mixed-2048")]
+
+
+@pytest.mark.parametrize("chunk", SPARSE_CASES)
+def test_sparse_step_leaves_the_pages_and_the_index_pool_in_place(v5e, tmp_path, chunk):
+    """A span whose positions cache an index row carries a third pool through
+    the layer loop (``backend._scan_paged_span``): the compiled decode step and
+    the mixed step with a chunk of the budget's 2,048 rows of
+    keye-vl2-30b-a3b-span5, at the cell's 8 lanes and tables of 512 pages,
+    allocate no second pool of keys, values or index rows and copy none in
+    ``ENTRY``, and move no layer of one in the loop. The index row is 64 wide,
+    the shape PR 38 folded for keys and values: stored a position a row,
+    ``bf16[5,4096,64,64]`` lived with the page index minor and ``ENTRY`` relaid
+    it whole on its way in and out (met here, compiling for the chip, before
+    any chip call); stored two positions to a row of 128
+    (ops/sparse_attention.py ``index_pool_row``) it lies as it is handed over."""
+    from petals_tpu.ops.sparse_attention import index_pool_row
+
+    hlo, _, pool, heads = _compiled_step(v5e, tmp_path, "keye-vl2-30b-a3b-span5", chunk, pages_a_lane=512)
+    assert tuple(pool.shape) == (5, 4096, 64, 4, 128) and index_pool_row(64, 64) == (32, 128)
+    moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
+    assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
+    assert not moves, f"the step moves the page pool around its 5 layers: {moves}"
+    index_shape = (5, 4096, 32, 128)
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    assert any(tuple(dims) == index_shape for _, dims, _, _ in comps[entry]), "the index pool was not found in ENTRY"
+    moved = [f"%{name} = {op} -> {list(dims)}" for name, dims, op, rest in comps[entry] if math.prod(dims) == math.prod(index_shape)
+             and (op == "copy" or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
+    assert not moved, f"the step moves the index pool: {moved}"
+    # in the loop: no layer of index rows (4096 pages x 64 positions x 64) sliced out, copied or written back whole
+    layer = math.prod(index_shape[1:])
+    inside = [f"%{name} = {op} -> {list(dims)}" for body in _while_bodies(comps) for name, dims, op, rest in body
+              if dims[-1:] == (128,) and math.prod(dims) == layer and op not in ("parameter", "tuple", "bitcast", "get-tuple-element")
+              and (_only_moves(comps, op, rest) or ((fused := _fused(comps, op, rest)) is not None and fused[-1][2] == "dynamic-update-slice"))]
+    assert not inside, f"the loop moves a layer of the index pool: {inside}"
+
+
+def test_sparse_decode_step_fetches_the_chosen_rows_and_makes_no_view_of_a_lane_s_table(v5e, tmp_path):
+    """A decode row of a span that selects scores its lane's index keys where
+    they lie and fetches the ``topk`` chosen positions' keys and values
+    (ops/sparse_attention.py ``sparse_decode_attend``): the compiled decode
+    step of keye-vl2-30b-a3b-span5 at 8 lanes of 512 pages holds no array of
+    ``8 x 32,768`` rows of ``[4, 128]`` besides the pools (a gather of the
+    whole tables to mask would be 268 MB a pool and a layer); what it does
+    hold is ``8 x 2,048`` rows of keys and of values, the chosen ones."""
+    hlo, runs, pool, heads = _compiled_step(v5e, tmp_path, "keye-vl2-30b-a3b-span5", 0, pages_a_lane=512)
+    weights = {tuple(p.shape)[cut:] for run in runs for p in run.values() for cut in (0, 1)}
+    comps = _computations(hlo)
+    fused = {m.group(1) for instructions in comps.values() for _, _, op, rest in instructions if op == "fusion" and (m := re.search(r"calls=%([\w.\-]+)", rest))}
+    pool_elements, view_rows = math.prod(pool.shape), 8 * 512 * 64
+    seen, views, fetched = 0, [], 0
+    for computation, instructions in comps.items():
+        if computation in fused:
+            continue  # what a fusion computes inside it is never an array in memory
+        for name, dims, op, _ in instructions:
+            if op in ("parameter", "get-tuple-element", "bitcast", "tuple") or dims in weights:
+                continue
+            if math.prod(dims) == pool_elements and dims[-2:] == heads:
+                seen += 1  # a pool, written in place by the new rows' scatter
+            elif dims[-2:] == heads and math.prod(dims[:-2]) >= view_rows:
+                views.append(f"%{name} = {op} -> {list(dims)}")
+            fetched += dims == (8, 2048, *heads)
+    assert seen, "the pool's scatter was not found: has the HLO text changed?"
+    assert fetched, "the chosen rows' gather, [8, 2048, 4, 128], was not found"
+    assert not views, f"the decode step makes a dense view of the lanes' tables: {views}"

@@ -23,6 +23,7 @@ from tests.utils import (
     make_tiny_mistral,
     make_tiny_mixtral,
     make_tiny_exaone_moe,
+    make_tiny_keye_vl2,
     make_tiny_olmo_hybrid,
     make_tiny_olmoe,
     make_tiny_phi3,
@@ -37,6 +38,7 @@ MAKERS = {
     "mixtral": make_tiny_mixtral, "olmoe": make_tiny_olmoe, "qwen2": make_tiny_qwen2,
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
+    "KeyeVL2": make_tiny_keye_vl2,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 
@@ -72,8 +74,9 @@ def test_quantization_applies_to_every_family(family_block, name):
 
     _, family, cfg, params = family_block(name)
     declared = family.quantizable_leaves
-    if family.block_kind is not None and not declared:
-        # a family whose blocks are not all alike may declare none yet: refused by name, never a dense no-op
+    if (family.block_kind is not None or family.block_index is not None) and not declared:
+        # a family whose blocks are not all alike, or whose pages carry an index row, may declare none yet:
+        # refused by name, never a dense no-op
         with pytest.raises(ValueError, match=name):
             convert_block_params(dict(params), name, "nf4", fuse=True)
         return

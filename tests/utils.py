@@ -763,3 +763,63 @@ def make_tiny_olmo_hybrid(tmpdir: str) -> str:
         json.dump(TINY_OLMO_HYBRID, f)
     save_file(tiny_olmo_hybrid_tensors(TINY_OLMO_HYBRID), os.path.join(path, "model.safetensors"))
     return path
+
+
+TINY_KEYE_VL2 = {  # the keys Keye-VL-2.0's language model publishes, at a toy size: a selection of 16 positions a row
+    "model_type": "KeyeVL2", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "intermediate_size": 128, "moe_intermediate_size": 32, "num_experts": 8, "num_local_experts": 8, "num_experts_per_tok": 2,
+    "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [], "num_hidden_layers": 4, "hidden_act": "silu",
+    "attention_bias": False, "rms_norm_eps": 1e-6, "rope_theta": 10000000,
+    "rope_scaling": {"mrope_section": [2, 3, 3], "rope_type": "default", "type": "default"},
+    "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 4, "indexer_num_kv_heads": 1, "kv_chunk_size": 512,
+                  "q_chunk_size": 512, "topk": 16},
+    "sliding_window": None, "use_sliding_window": False, "max_window_layers": 4, "max_position_embeddings": 512,
+    "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_keye_vl2_tensors(config: dict, seed: int = 23) -> dict:
+    """Seeded float32 tensors under the names of perf/configs/keye-vl2-30b-a3b-span5.json's
+    ``assumed.tensor_names`` for every layer of ``config``, the embedding, the
+    final norm and the head. Norm vectors (the indexer's layer norm's bias
+    too) are drawn, not ones and zeros, so a missing or misplaced one shows."""
+    rng = np.random.RandomState(seed)
+    h, hq, hkv, d = (config[k] for k in ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim"))
+    m, n_experts, sa = config["moe_intermediate_size"], config["num_experts"], config["sa_config"]
+    heads, d_idx = sa["indexer_num_heads"], sa["indexer_head_dim"]
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda n: rng.uniform(0.5, 1.5, n).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": norm(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i in range(config["num_hidden_layers"]):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "input_layernorm.weight": norm(h), p + "post_attention_layernorm.weight": norm(h),
+            p + "self_attn.q_proj.weight": normal(hq * d, h), p + "self_attn.k_proj.weight": normal(hkv * d, h),
+            p + "self_attn.v_proj.weight": normal(hkv * d, h), p + "self_attn.o_proj.weight": normal(h, hq * d),
+            p + "self_attn.q_norm.weight": norm(d), p + "self_attn.k_norm.weight": norm(d),
+            p + "self_attn.indexer.wq.weight": normal(heads * d_idx, h) * 3, p + "self_attn.indexer.wk.weight": normal(d_idx, h),
+            p + "self_attn.indexer.weights_proj.weight": normal(heads, h),
+            p + "self_attn.indexer.k_norm.weight": norm(d_idx), p + "self_attn.indexer.k_norm.bias": normal(d_idx),
+            p + "mlp.gate.weight": normal(n_experts, h) * 3,
+        })
+        for e in range(n_experts):
+            q = p + f"mlp.experts.{e}."
+            tensors.update({q + "gate_proj.weight": normal(m, h), q + "up_proj.weight": normal(m, h), q + "down_proj.weight": normal(h, m)})
+    return tensors
+
+
+def make_tiny_keye_vl2(tmpdir: str, **overrides) -> str:
+    """A Keye-VL-2.0 (text) checkpoint at a toy size, written by hand (the
+    installed transformers has no class for ``KeyeVL2``)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = {**TINY_KEYE_VL2, **overrides}
+    path = os.path.join(tmpdir, "tiny-keye-vl2")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_keye_vl2_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
