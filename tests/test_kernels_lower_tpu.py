@@ -651,7 +651,53 @@ def test_sparse_decode_step_fetches_the_chosen_rows_and_makes_no_view_of_a_lane_
                 seen += 1  # a pool, written in place by the new rows' scatter
             elif dims[-2:] == heads and math.prod(dims[:-2]) >= view_rows:
                 views.append(f"%{name} = {op} -> {list(dims)}")
-            fetched += dims == (8, 2048, *heads)
+            fetched += dims[-2:] == heads and math.prod(dims[:-2]) == 8 * 2048  # as the gather leaves them: [16384, 4, 128]
     assert seen, "the pool's scatter was not found: has the HLO text changed?"
     assert fetched, "the chosen rows' gather, [8, 2048, 4, 128], was not found"
     assert not views, f"the decode step makes a dense view of the lanes' tables: {views}"
+
+
+def test_sparse_decode_step_runs_nothing_between_the_pool_and_the_dot_but_the_sort_and_the_fetches(v5e, tmp_path):
+    """Between the index keys' scoring and the two dots a decode row's call
+    holds the sort and the fetches and nothing else that is not arithmetic on
+    a megabyte (ops/sparse_attention.py ``select_rows``, ``_take_rows``). In
+    the compiled decode step of keye-vl2-30b-a3b-span5 at 8 lanes of 512
+    pages, none of the three that PR 39's step ran a layer (PERF.md section
+    5): no gather out of the ``s32[8,512]`` tables with 16,384 results (the
+    chosen positions' pages looked up a scalar at a time: they are read off
+    by a compare with the table's slots), no select that writes a block of
+    fetched index pages or the fetched rows (the fill of ``jnp.take``'s
+    default mode), no copy of ``[16384,4,128]`` (the fetched rows relaid
+    heads-major for the dots: the fill's select had left them in a layout of
+    its own; as the gathers leave them the dots take them). The two fetches
+    of ``[8,2048,4,128]`` are there, as the gathers leave them
+    (``[16384,4,128]``), and the sort is one of two operands, the scores'
+    keys and the positions: a stable sort of one key that carried the pool
+    rows is compiled as a sort of three (PERF.md section 6, PR 40)."""
+    hlo, _, _, heads = _compiled_step(v5e, tmp_path, "keye-vl2-30b-a3b-span5", 0, pages_a_lane=512)
+    comps = _computations(hlo)
+    fused = {m.group(1) for instructions in comps.values() for _, _, op, rest in instructions if op == "fusion" and (m := re.search(r"calls=%([\w.\-]+)", rest))}
+    lookups, fills, relayouts, fetches, sorts = [], [], [], 0, []
+    fetched = ((256, 32, 128), (8, 1024, 128), (16384, *heads), (8, 2048, *heads))  # a block's index pages; the chosen rows
+    for computation, instructions in comps.items():
+        dims_of = {name: dims for name, dims, _, _ in instructions}
+        for name, dims, op, rest in instructions:
+            if op == "gather":  # in a fusion: its operands are the fusion's parameters, so its operand's shape says what it reads
+                operand = dims_of.get(next(iter(re.findall(r"%([\w.\-]+)", rest)), None))
+                if operand == (8, 512) and math.prod(dims) == 8 * 2048:
+                    lookups.append(f"%{name} in %{computation}")
+                fetches += operand == (5 * 4096 * 64, *heads) and math.prod(dims) == 8 * 2048 * math.prod(heads)
+            if computation in fused:
+                continue  # what follows are arrays in memory: a fusion's root is its caller's instruction
+            root = (_fused(comps, op, rest) or [(name, dims, op, rest)])[-1][2]
+            if root == "select" and dims in fetched:
+                fills.append(f"%{name} -> {list(dims)}")
+            if dims == (16384, *heads) and _only_moves(comps, op, rest) and op != "bitcast":
+                relayouts.append(f"%{name} = {op}")
+            if op == "sort" and dims == (8, 32768):
+                sorts.append(re.findall(r"(\w+)\[8,32768\]", hlo.split(f"%{name} = ")[1].split(" sort(")[0]))
+    assert fetches == 2, f"the chosen rows' two gathers out of the pool were not found: {fetches}"
+    assert sorts == [["u32", "s32"]], f"the one sort of a lane's scores with their positions, two operands, was not found: {sorts}"
+    assert not lookups, f"the decode step looks the chosen positions' pages up in the tables: {lookups}"
+    assert not fills, f"the decode step selects fetched rows against a fill value: {fills}"
+    assert not relayouts, f"the decode step relays the fetched rows: {relayouts}"

@@ -137,6 +137,80 @@ def test_the_selection_is_the_top_k_by_score_with_ties_to_the_lower_position(sco
     assert int(got.sum(-1).max()) == TOPK
 
 
+@pytest.mark.parametrize("scores", ["generic", "ties", "zeros"])
+def test_a_decode_row_attends_to_its_top_k_by_score_with_ties_to_the_lower_position(scores):
+    """``sparse_decode_attend`` itself against plain float32 NumPy: score every
+    position the lane sees, sort by (score descending, position ascending),
+    softmax over the first ``topk``. Permuted tables with holes past what a
+    lane holds; lanes of 50, 10 (fewer than ``topk``: it keeps all), 0 (idle,
+    the sentinel position: zeros) and 64 positions (a full table). Pages no
+    lane owns hold NaN, and a hole reads row 0 of the pool, which lane 3 owns.
+    The index keys have two live coordinates, each met by one index head, so
+    a score is ``w0 relu(x) + w1 relu(y)`` to the bit in any order of sums:
+    generic values; values rounded so that the k-th score has many equals;
+    and rows mostly of exact zeros, ``+0.0`` at the bottom of a lane whose
+    weights are positive and ``-0.0`` at the top of one whose weights are
+    negative, so that in both the set is closed among equals by position."""
+    rng = np.random.default_rng(11)
+    lanes, max_pages, ps, hkv, group, d, d_idx = 4, 8, 8, 2, 2, 16, 16
+    n_pages, max_length, hq = 30, max_pages * ps, hkv * group
+    lengths = np.array([50, 10, 0, 64])
+    xy = rng.standard_normal((lanes, max_length, 2)).astype(np.float32)
+    w = np.array([[1.0, -0.5], [0.5, 1.0], [1.0, 1.0], [-1.0, 2.0]], np.float32)
+    if scores == "ties":
+        xy = np.round(xy * 2) / 2
+    if scores == "zeros":
+        xy = np.where(rng.random(xy.shape) < 0.2, np.maximum(xy, 0), -1.0).astype(np.float32)
+        w = np.array([[1.0, 1.0], [1.0, 0.5], [1.0, 1.0], [-1.0, -1.0]], np.float32)
+    k_idx = np.zeros((lanes, max_length, d_idx), np.float32)
+    k_idx[..., 0], k_idx[..., 5] = xy[..., 0], xy[..., 1]
+    q_idx = np.zeros((lanes, 1, 2, d_idx), np.float32)
+    q_idx[:, 0, 0, 0] = q_idx[:, 0, 1, 5] = 1.0
+    q, k, v = (rng.standard_normal(shape).astype(np.float32) for shape in ((lanes, 1, hq, d), (lanes, max_length, hkv, d), (lanes, max_length, hkv, d)))
+
+    free = rng.permutation(np.arange(1, n_pages))
+    tables = np.full((lanes, max_pages), -1, np.int32)
+    for lane, length in enumerate(lengths):
+        held = -(-length // ps)
+        tables[lane, :held], free = free[:held], free[held:]
+    tables[3, 2] = 0  # page 0 is somebody's: what a hole reads is real, finite and masked
+    rows_a_page, row_width = sparse.index_pool_row(ps, d_idx)
+    fold = ps // rows_a_page
+    k_pool, v_pool = (np.full((n_pages, ps, hkv, d), np.nan, np.float32) for _ in range(2))
+    i_pool = np.full((n_pages, rows_a_page, row_width), np.nan, np.float32)
+    for lane in range(lanes):
+        for slot, page in enumerate(tables[lane]):
+            if page >= 0:
+                at = slice(slot * ps, (slot + 1) * ps)
+                k_pool[page], v_pool[page] = k[lane, at], v[lane, at]
+                i_pool[page] = k_idx[lane, at].reshape(rows_a_page, fold * d_idx)
+    positions = np.where(lengths > 0, lengths - 1, max_length).astype(np.int32)
+    kv = [PagedKV(jnp.asarray(pool), jnp.asarray(tables)) for pool in (k_pool, v_pool, i_pool)]
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(jax.jit(lambda *a: sparse.sparse_decode_attend(*a, topk=TOPK))(
+            jnp.asarray(q), jnp.asarray(q_idx), jnp.asarray(w[:, None]), *kv, jnp.asarray(positions)))
+
+    want, sets = np.zeros((lanes, 1, hq, d), np.float32), []
+    for lane, length in enumerate(lengths):
+        dots = np.maximum(k_idx[lane, :length] @ q_idx[lane, 0].T, 0)  # [length, 2]
+        score = w[lane, 0] * dots[:, 0] + w[lane, 1] * dots[:, 1]
+        chosen = np.lexsort((np.arange(length), -score))[:TOPK]
+        sets.append((score, chosen))
+        for head in range(hq):
+            logits = k[lane, chosen, head // group] @ q[lane, 0, head] * d**-0.5
+            p = np.exp(logits - logits.max(initial=-np.inf))
+            want[lane, 0, head] = (p / max(p.sum(), 1e-30)) @ v[lane, chosen, head // group] if length else 0.0
+    assert got.shape == want.shape and np.isfinite(got).all() and not got[2].any()
+    assert np.abs(got - want).max() < 1e-5, np.abs(got - want).reshape(lanes, -1).max(-1)
+    # the cases are what they claim: the set is closed among equals, and in the zeros' rows at a zero of either sign
+    score, chosen = sets[0]
+    kth, dropped = score[chosen[-1]], np.setdiff1d(np.arange(lengths[0]), chosen)
+    if scores != "generic":
+        assert (score[dropped] == kth).any() and dropped[score[dropped] == kth].min() > chosen[score[chosen] == kth].max()
+    if scores == "zeros":
+        assert kth == 0 and not np.signbit(kth) and np.signbit(sets[3][0][sets[3][1]]).all() and (sets[3][0] < 0).any()
+
+
 def test_a_chunk_in_runs_of_rows_is_the_chunk_at_once(monkeypatch):
     """``sparse_chunk_attend`` sends a chunk of more than ``CHUNK_ROWS`` rows
     through in runs (at the published widths 512 of a budget's 2,048): the
