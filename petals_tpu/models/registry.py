@@ -95,6 +95,15 @@ class ModelFamily:
     # (a private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised
     # pages, a tp mesh) is refused for a family that declares one (server/backend.py ``index_row``)
     block_index: Optional[Callable] = None
+    # (cfg, kind) -> what a position caches IN PLACE of its key and value in a block of that kind: a latent row,
+    # ``(latent width, rotated key's width)``, one for all heads, that every head's key and value are linear in
+    # (ops/latent_attention.py); None for a kind that caches keys and values. The framework keeps it where the
+    # pages of keys and values would lie, under the same block tables (the latent a position a row of the first
+    # pool, the rotated key in the second, stored as an index row of its width is), and hands a block ``(c, k_pe)``
+    # as its ``kv``. Only the paged lane pool's decode, generation and mixed steps carry it; what does not (a
+    # private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised pages, a tp
+    # mesh) is refused for a family that declares one (server/backend.py ``latent_row``)
+    block_latent: Optional[Callable] = None
 
     def kind_of(self, cfg, block_index: int) -> Hashable:
         return None if self.block_kind is None else self.block_kind(cfg, block_index)
@@ -119,6 +128,9 @@ class ModelFamily:
 
     def index_for(self, cfg, kind: Hashable) -> Optional[tuple]:
         return None if self.block_index is None else self.block_index(cfg, kind)
+
+    def latent_for(self, cfg, kind: Hashable) -> Optional[tuple]:
+        return None if self.block_latent is None else self.block_latent(cfg, kind)
 
 
 def _kind_args(kind: Hashable) -> tuple:

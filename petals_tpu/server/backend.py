@@ -55,6 +55,12 @@ INDEX_ROWS_RIDE = (
     "them would choose from nothing"
 )
 
+# and why one refuses a span whose positions cache a latent row in place of keys and values
+LATENT_ROWS_RIDE = (
+    "only the paged lane pool's decode, generation and mixed steps carry the latent rows' pages; every other "
+    "cache is laid out for keys and values a head, which such a span never makes"
+)
+
 
 def bucket_length(n: int) -> int:
     for b in PREFILL_BUCKETS:
@@ -176,6 +182,15 @@ class TransformerBackend:
             self._check_index(rows, mesh)
             width, dtype, keep = next(iter(rows))
             self.index_row, self.index_keep = (int(width), jnp.dtype(dtype or self.cache_dtype)), int(keep)
+        # what a position caches IN PLACE of its key and value in the span's blocks (ModelFamily.block_latent):
+        # one row for all heads, (latent width, rotated key's width), kept where the pages of keys and values
+        # would lie: the first pool holds the latents, the second the rotated keys (``paged_cache_descriptors``);
+        # None for a span that caches keys and values
+        rows = {family.latent_for(cfg, kind) for kind, _, _ in self.runs}
+        self.latent_row = None
+        if rows != {None}:
+            self._check_latent(rows, mesh)
+            self.latent_row = tuple(int(width) for width in next(iter(rows)))
         # adapter name -> (stacked {leaf: (A, B)}, scaling); see utils/peft.py
         self.adapters: Dict[str, tuple] = {}
         self._dummy_operands: Dict[tuple, jax.Array] = {}
@@ -255,6 +270,23 @@ class TransformerBackend:
                 f"index row: the selection fetches single rows of the pool, which has no packed form for that yet"
             )
 
+    def _check_latent(self, rows, mesh) -> None:
+        """A span whose positions cache a latent row: what it cannot do yet
+        is refused here, with the reason, not served wrong."""
+        name = self.family.name
+        if len(rows) != 1 or self.state_layers or self.index_row is not None:
+            raise NotImplementedError(f"{name}: a latent row is served for a span whose blocks all cache the same one and nothing else")
+        if mesh is not None:
+            raise NotImplementedError(
+                f"{name}: a span whose positions cache a latent row is not served over a tp mesh yet: the row is "
+                f"one for all heads, and the dense lane pool a mesh falls back to is laid out for keys and values a head"
+            )
+        if self.kv_quant_type != "none":
+            raise NotImplementedError(
+                f"{name}: kv_quant_type {self.kv_quant_type!r} is not served for a span whose positions cache a "
+                f"latent row: the pages' packed forms are of keys and values a head, with a scale a head"
+            )
+
     def refuse_for_state(self, what: str, why: str) -> None:
         """Raise for ``what`` if this span keeps a recurrent state: a state
         holds a whole history at one position and cannot be cut back to an
@@ -262,8 +294,9 @@ class TransformerBackend:
         carry a state at all, are refused by what the family declares. The
         same paths carry no index row (``index_row``: a third page pool that
         only the paged lane pool's decode, generation and mixed steps are
-        handed), so a span that caches one is refused there too, with its
-        own reason."""
+        handed) and no latent row (``latent_row``: pages of another shape
+        than keys' and values'), so a span that caches either is refused
+        there too, with its own reason."""
         if self.state_layers:
             raise NotImplementedError(
                 f"{self.family.name}: {what} is not served for a span with a recurrent state "
@@ -273,6 +306,11 @@ class TransformerBackend:
             raise NotImplementedError(
                 f"{self.family.name}: {what} is not served for a span whose positions cache an index row beside "
                 f"their keys and values ({self.index_row[0]} wide, the key a learned sparse attention scores): {INDEX_ROWS_RIDE}"
+            )
+        if self.latent_row is not None:
+            raise NotImplementedError(
+                f"{self.family.name}: {what} is not served for a span whose positions cache a latent row in place of "
+                f"their keys and values ({' + '.join(map(str, self.latent_row))} wide, one for all heads): {LATENT_ROWS_RIDE}"
             )
 
     def _by_run(self, params) -> tuple:
@@ -387,6 +425,12 @@ class TransformerBackend:
         as the blocks of [start, end) that keep keys and values: a block with
         a state of its own (``state_cache_descriptors``) has no pages."""
         n = sum(start <= i < end for i in self.kv_layers)
+        if self.latent_row is not None:
+            # a latent row in place of keys and values: the latents a position a row, and the rotated keys stored
+            # as an index row of their width is (ops/latent_attention.py ``latent_pool_rows``); stored once
+            from petals_tpu.ops.latent_attention import latent_pool_rows
+
+            return tuple(TensorDescriptor((n, n_pages, *row), self.cache_dtype) for row in latent_pool_rows(page_size, *self.latent_row))
         shape = (n, n_pages, page_size, *self.pool_row)
         if self.kv_quant_type == "none":
             return (
@@ -409,6 +453,8 @@ class TransformerBackend:
         folds it (ops/paged_attention.py ``stored_row``). Fixed at start."""
         from petals_tpu.ops.paged_attention import stored_row
 
+        if self.latent_row is not None:  # one row for all heads, in two pools (``paged_cache_descriptors``)
+            return (sum(self.latent_row),)
         return stored_row(self.num_kv_heads, self.head_dim // 2 if self.kv_quant_type == "nf4a" else self.head_dim)
 
     def pool_to_wire(self, pages):
@@ -488,6 +534,29 @@ class TransformerBackend:
                 "sparse_index_rows_scored": scored * layers, "sparse_score_pairs": pairs * layers,
                 "sparse_kv_rows_read": read * layers, "sparse_kv_rows_held": held * layers}
 
+    def latent_reads(self, n_lanes: int, max_pages: int, page_size: int, last: np.ndarray, chunk=None) -> dict:
+        """What one paged step's programs do for a span whose positions
+        cache a latent row (``latent_row``), over its layers, from the shapes
+        the step is started with: the live lanes' rows at positions ``last``
+        (the absorbed form) and the ``chunk`` (first position, tokens) of a
+        mixed step (the expanded one). For the batcher's ``latent_*`` counters
+        (ops/latent_attention.py has the arithmetic)."""
+        from petals_tpu.ops.latent_attention import chunk_reads, decode_reads
+
+        layers = len(self.kv_layers)
+        contexts = [int(p) + 1 for p in last]
+        read = decode_reads(n_lanes, max_pages, page_size, max(contexts)) if contexts else 0
+        pairs = sum(contexts)
+        expanded = held = rows = 0
+        if chunk is not None:
+            first, rows = chunk
+            expanded, held = chunk_reads(max_pages, page_size, first, rows), first + rows
+            pairs += rows * first + rows * (rows + 1) // 2  # each row of the chunk against the positions up to its own
+        return {"latent_rows_read": read * layers, "latent_rows_held": sum(contexts) * layers,
+                "latent_rows_absorbed": len(contexts) * layers, "latent_rows_expanded": rows * layers,
+                "latent_positions_expanded": expanded * layers, "latent_positions_held": held * layers,
+                "latent_score_pairs": pairs * layers}
+
     def state_bytes_per_lane(self) -> int:
         """What a lane holds whatever its context: its states over the span's
         state layers. 0 for a span without one."""
@@ -497,7 +566,10 @@ class TransformerBackend:
         """LOGICAL (dense fp) bytes per token across the span's blocks that
         keep keys and values — sizes the dense lane cache and stays the fp
         baseline for capacity ratios. A lane's fixed part is
-        ``state_bytes_per_lane``."""
+        ``state_bytes_per_lane``. A span that caches a latent row in place of
+        keys and values: that row's bytes, stored once."""
+        if self.latent_row is not None:
+            return len(self.kv_layers) * sum(self.latent_row) * jnp.dtype(self.cache_dtype).itemsize
         return (
             2
             * len(self.kv_layers)
@@ -512,6 +584,8 @@ class TransformerBackend:
         cache_bytes_per_token when kv_quant_type == none."""
         from petals_tpu.ops.paged_attention import kv_wire_bytes_per_token
 
+        if self.latent_row is not None:
+            return self.cache_bytes_per_token()
         return 2 * len(self.kv_layers) * kv_wire_bytes_per_token(
             self.num_kv_heads, self.head_dim, self.kv_quant_type,
             jnp.dtype(self.cache_dtype).itemsize,
@@ -772,8 +846,9 @@ class TransformerBackend:
         from petals_tpu.ops import paged_flash_attention as pfa
 
         cfg = self.cfg
-        if self.index_row is not None and max_pages * page_size > self.index_keep:
-            # the span's blocks select (ops/sparse_attention.py): neither timed program is on their path
+        if self.latent_row is not None or (self.index_row is not None and max_pages * page_size > self.index_keep):
+            # the span's blocks select (ops/sparse_attention.py) or attend over latent rows
+            # (ops/latent_attention.py): neither timed program is on their path
             self._paged_autotuned = True
             return
         hkv = self.num_kv_heads
@@ -824,6 +899,11 @@ class TransformerBackend:
         geometry: the batcher asks once."""
         from petals_tpu.ops.paged_flash_attention import walk_block_pages, window_pages
 
+        if self.latent_row is not None:  # ops/latent_attention.py ``latent_decode_attend``'s own blocks
+            from petals_tpu.ops.latent_attention import DECODE_BLOCK_ROWS
+            from petals_tpu.ops.sparse_attention import _block_pages
+
+            return ((None, len(self.kv_layers), _block_pages(max_pages, page_size, DECODE_BLOCK_ROWS), False),)
         itemsize = 2 if self.kv_quant_type != "none" else jnp.dtype(self.cache_dtype).itemsize  # a quantised pool reads as bf16
         walks = []
         for window, layers in self._window_layers:
@@ -861,6 +941,8 @@ class TransformerBackend:
         steady state it is one constant and costs zero extra compiles."""
         from petals_tpu.ops import paged_flash_attention as pfa
 
+        if self.latent_row is not None:  # one path (ops/latent_attention.py): nothing to resolve, nothing to retrace for
+            return "latent"
         page_size, hkv, d = k_pool.shape[2], self.num_kv_heads, self.head_dim  # the pool's row may be folded
         keys = [
             pfa.shape_class(tables.shape[0], tables.shape[1], page_size, hkv, d, window, self.kv_quant_type)
@@ -1333,6 +1415,12 @@ class TransformerBackend:
             def layer(block_apply, carry, p_block, spans, paged):
                 h_dec, h_pf = carry
                 out_dec, spans = decode_half(block_apply, h_dec, p_block, spans, paged)
+                if self.latent_row is not None:
+                    # the decode rows' walk reads the pools in a loop of its own, which nothing orders against the
+                    # chunk's writes: left free, the compiler wrote the chunk first and kept a COPY of both pools
+                    # for the walk, every layer (tests/test_kernels_lower_tpu.py). Tied to the walk's result, the
+                    # pools the chunk writes are the ones the walk has read
+                    out_dec, spans = jax.lax.optimization_barrier((out_dec, spans))
                 # --- prefill half: the chunk lane's table row as a
                 # single-lane PagedKV over the pools the decode half wrote;
                 # writes land in the pages directly

@@ -256,11 +256,15 @@ class DecodeBatcher:
         # a third page pool under the same tables, allocated, freed and reused with the pages; it rides the
         # paged step programs where a state pool would (``_state``), and nothing else carries it
         self._n_index = 1 if getattr(backend, "index_row", None) is not None else 0
-        if self._n_index and self.page_size is None:
+        # a span whose positions cache a latent row IN PLACE of keys and values (ModelFamily.block_latent): the two
+        # pools of ``_buffers`` are its latents and its rotated keys, allocated, freed and reused as pages of keys
+        # and values are; only the paged step programs read them
+        self._latent = getattr(backend, "latent_row", None) is not None
+        if (self._n_index or self._latent) and self.page_size is None:
             backend.refuse_for_state(
                 "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""), "",
             )
-        if self._n_index and int(swap_host_bytes or 0) > 0:
+        if (self._n_index or self._latent) and int(swap_host_bytes or 0) > 0:
             backend.refuse_for_state("the host swap tier (swap_host_bytes > 0)", "")
         # such a span's rows choose positions only where a table can pass the selection's size (models/keye_vl2/block.py)
         self._selects = bool(self._n_index) and self.page_size is not None and self.max_length > backend.index_keep
@@ -493,6 +497,16 @@ class DecodeBatcher:
                 sparse_rows_selected=0, sparse_rows_dense=0, sparse_index_rows_scored=0, sparse_score_pairs=0, sparse_kv_rows_read=0,
                 sparse_kv_rows_held=0, index_bytes_held=0, kv_bytes_held=0,
             )
+        if self._latent:
+            # a family that declares a latent row only (_count_latent), from the shapes a step is started with, all
+            # times the span's layers: latent rows the decode rows' walks read against those their lanes held; rows
+            # that took the absorbed form (a decode row) and the expanded one (a chunk's); positions a chunk's walk
+            # expanded against those its lane held; (row, position) pairs scored; and, summed step by step, the
+            # bytes of latent rows the lanes that fed rows hold
+            self.stats.update(
+                latent_rows_read=0, latent_rows_held=0, latent_rows_absorbed=0, latent_rows_expanded=0,
+                latent_positions_expanded=0, latent_positions_held=0, latent_score_pairs=0, latent_bytes_held=0,
+            )
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
         # (telemetry.journal), and the pool gauges/counters feed the /metrics
@@ -608,7 +622,7 @@ class DecodeBatcher:
         return tuple(self.memory_cache.get_buffers(*self._handles[-n:]))
 
     def _refuse_for_state(self, what: str, why: str) -> None:
-        if self._n_state or self._n_index:
+        if self._n_state or self._n_index or self._latent:
             self.backend.refuse_for_state(what, why)
 
     def _update(self, k_pool, v_pool, *state) -> None:
@@ -1478,6 +1492,11 @@ class DecodeBatcher:
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
             if self._n_index:  # of kv_bytes_per_token, the index rows' part
                 info["index_bytes_per_token"] = int(self.backend.index_bytes_per_token())
+            if self._latent:
+                # what a position caches in place of keys and values, (latent, rotated key), and what the pages in
+                # use hold of it
+                info["latent_row"] = list(self.backend.latent_row)
+                info["latent_bytes_held"] = (self.n_pages - info["pages_free"]) * self._page_nbytes()
             if self._n_state:
                 # a lane's fixed part, beside what its pages cost a token, and what the busy lanes hold of it
                 info["state_bytes_per_lane"] = self._state_nbytes()
@@ -2300,7 +2319,11 @@ class DecodeBatcher:
         self.stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
         if chunk is not None:
             lane, first, take = chunk
-            if not self._selects:
+            if self._latent:  # the chunk's walk ends with the block that holds its last row
+                from petals_tpu.ops.latent_attention import chunk_reads
+
+                self.stats["attn_pages_gathered"] += layers * chunk_reads(self.max_pages, self.page_size, first, take) // self.page_size
+            elif not self._selects:
                 self.stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
             self.stats["attn_pages_tabled"] += self.max_pages * layers
         if not self._windows:
@@ -2355,6 +2378,26 @@ class DecodeBatcher:
         self.stats["index_bytes_held"] += index
         self.stats["kv_bytes_held"] += pages * self._page_nbytes() - index
 
+    def _count_latent(self, tables, positions, *, chunk=None) -> None:
+        """The latent attention's counters of one paged step (compute thread;
+        a family that declares a latent row only), from the shapes the step
+        was started with: what the lanes that fed a row (the absorbed form)
+        and the ``chunk`` (lane, first position, tokens) of a mixed step (the
+        expanded one) made the programs read and score
+        (``backend.latent_reads``), and the bytes of latent rows those lanes'
+        pages hold."""
+        if not self._latent or tables is None:
+            return
+        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
+        reads = self.backend.latent_reads(
+            self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:]
+        )
+        for key, n in reads.items():
+            self.stats[key] += n
+        if chunk is not None:
+            lanes = np.append(lanes, chunk[0])
+        self.stats["latent_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
+
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
         variant = "paged" if self.page_size is not None else "dense"
@@ -2408,6 +2451,7 @@ class DecodeBatcher:
             self._count_window(tables, positions)
             self._count_state(tables, positions)
             self._count_sparse(tables, positions)
+            self._count_latent(tables, positions)
             duration = time.perf_counter() - t_step
             if self.page_size is not None:
                 tm.STEP_PAGED.observe(duration)
@@ -2522,6 +2566,7 @@ class DecodeBatcher:
             self._count_window(tables, positions, chunk=(st.lane, st.position, take))
             self._count_state(tables, positions, chunk=(st.lane, take))
             self._count_sparse(tables, positions, chunk=(st.lane, st.position, take))
+            self._count_latent(tables, positions, chunk=(st.lane, st.position, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2615,6 +2660,7 @@ class DecodeBatcher:
             self._count_window(tables, positions)
             self._count_state(tables, positions)
             self._count_sparse(tables, positions)
+            self._count_latent(tables, positions)
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()

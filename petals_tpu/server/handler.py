@@ -202,6 +202,14 @@ class TransformerHandler:
                 "which a stored prefix does not carry"
             )
             prefix_cache_bytes = 0
+        if prefix_cache_bytes > 0 and getattr(backend, "latent_row", None) is not None:
+            # a stored prefix is keys and values a head (a snapshot, or pinned pages a hit adopts and forks through
+            # paths laid out for them): a span that caches a latent row in their place has neither
+            logger.info(
+                "Prefix cache off: the span's positions cache a latent row in place of keys and values, "
+                "which a stored prefix does not carry"
+            )
+            prefix_cache_bytes = 0
         if prefix_cache_bytes > 0:
             from petals_tpu.server.prefix_cache import PrefixCache
             from petals_tpu.telemetry.ledger import get_ledger

@@ -321,7 +321,9 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     descs = backend.paged_cache_descriptors(n_pages, page_size, 0, depth)  # in the form the rule stores them
     pool = v5e(descs[0].shape, BF16 if kv_quant == "none" else descs[0].dtype)
     pools = pool if kv_quant == "none" else PagedPool(pool, v5e(descs[2].shape, descs[2].dtype))
-    avals = [params, pools, pools, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
+    # the second pool is the first one's twin but for a span that caches a latent row in place of keys and values
+    second = pools if backend.latent_row is None else v5e(descs[1].shape, BF16)
+    avals = [params, pools, second, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
     step = backend._paged_decode_fn
     if chunk:  # chunk_hidden, then chunk_lane, chunk_pos, chunk_n_valid, chunk_n_total
         step = backend._paged_mixed_step_fn
@@ -701,3 +703,125 @@ def test_sparse_decode_step_runs_nothing_between_the_pool_and_the_dot_but_the_so
     assert not lookups, f"the decode step looks the chosen positions' pages up in the tables: {lookups}"
     assert not fills, f"the decode step selects fetched rows against a fill value: {fills}"
     assert not relayouts, f"the decode step relays the fetched rows: {relayouts}"
+
+
+# -------------------------------------------------------------------------------------------------
+# a latent row in place of keys and values (kanana2-30b-a3b-span6: ``deepseek_v3``, ops/latent_attention.py)
+# -------------------------------------------------------------------------------------------------
+
+LATENT_CONFIG = "kanana2-30b-a3b-span6"
+LATENT_POOLS = ((6, 4096, 64, 512), (6, 4096, 32, 128))  # the latents a position a row; the rotated keys two positions a row
+
+
+def _arrays_in_memory(comps: dict):
+    """``(computation, name, dims, op, rest)`` of every instruction whose result is an array in memory: not
+    inside a fusion, not a parameter, a tuple's element or a bitcast."""
+    fused = {m.group(1) for instructions in comps.values() for _, _, op, rest in instructions
+             if op == "fusion" and (m := re.search(r"calls=%([\w.\-]+)", rest))}
+    for computation, instructions in comps.items():
+        if computation in fused:
+            continue
+        for name, dims, op, rest in instructions:
+            if op not in ("parameter", "get-tuple-element", "bitcast", "tuple"):
+                yield computation, name, dims, op, rest
+
+
+@pytest.mark.parametrize("chunk", SPARSE_CASES)
+def test_latent_step_leaves_both_pools_in_place_and_reads_its_weights_where_they_lie(v5e, tmp_path, chunk):
+    """A span whose positions cache a latent row hands the layer loop two
+    pools of different shapes where keys and values would ride
+    (``backend.paged_cache_descriptors``): at kanana2-30b-a3b-span6's widths, 8
+    lanes and tables of 512 pages, ``bf16[6,4096,64,512]`` of latents and
+    ``bf16[6,4096,32,128]`` of rotated keys (64 wide: two positions to a row
+    of 128, as an index row is stored; 576 is no multiple of the chip's 128
+    lanes, so one row of 576 would pad to 640). The compiled decode step and
+    the mixed step with a chunk of the budget's 2,048 rows allocate no second
+    pool and copy none, in ``ENTRY`` or in a layer of the loop. The mixed
+    step did, before the chip was ever called: nothing ordered the decode
+    rows' walk (a loop that reads the pools) against the chunk's writes, the
+    compiler wrote the chunk first and kept a copy of both pools for the walk,
+    3.2 GB moved a layer (``backend._paged_mixed_step_fn`` ties the pools the
+    chunk writes to the walk's result). And the loop slices no stacked weight
+    out into a buffer of its own: ``wuk`` / ``wuv`` meet both forms' dots as
+    they are stored."""
+    hlo, runs, pool, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, chunk, pages_a_lane=512)
+    assert tuple(pool.shape) == LATENT_POOLS[0]
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    for shape in LATENT_POOLS:
+        elements, layer = math.prod(shape), math.prod(shape[1:])
+        assert any(tuple(dims) == shape for _, dims, _, _ in comps[entry]), f"the pool {shape} was not found in ENTRY"
+        moved = [f"%{name} = {op} -> {list(dims)} in %{computation}" for computation, name, dims, op, rest in _arrays_in_memory(comps)
+                 if dims[-1:] == shape[-1:] and math.prod(dims) >= layer
+                 and (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest or _only_moves(comps, op, rest)
+                      or (computation != entry and (fused := _fused(comps, op, rest)) is not None and fused[-1][2] == "dynamic-update-slice"
+                          and math.prod(dims) < elements))]
+        assert not moved, f"the step moves the pool {shape}: {moved}"
+    stacked = {tuple(p.shape) for run in runs for p in run.values()}
+    smallest = min(math.prod(run[name].shape[1:]) for run in runs for name in ("wuk", "wuv", "wkva"))
+    relayouts, seen = weight_relayouts(hlo, stacked, smallest)
+    assert seen, "the loop's stacked weights were not found: has the HLO text changed?"
+    # the mixed step's known ones: the chunk's grouped expert dispatch is handed a copy of the layer's w1, w3 and w2
+    # (ragged_dot is a custom call: ROADMAP S7, Keye's mixed step alike), and the chunk's walk, a loop of its own,
+    # is handed ``wuk`` and ``wuv`` as arrays (4 MB each a layer: 10 us of a mixed step's layer)
+    known = {(32, 128, 512), (32, 512, 128), (128, 2048, 768), (128, 768, 2048)} if chunk else set()
+    relayouts = [r for r in relayouts if tuple(json.loads(r.split(" -> ")[1])) not in known]
+    assert not relayouts, f"the step's loop relays a weight in every layer of every step: {relayouts}"
+    moved = entry_weight_moves(hlo, stacked, smallest)
+    assert not moved, f"the step relays a weight of the dense run of one block in every step: {moved}"
+
+
+def test_latent_decode_step_makes_no_view_of_the_tables_and_expands_no_lane_s_keys_or_values(v5e, tmp_path):
+    """A decode row takes the absorbed form (ops/latent_attention.py
+    ``latent_decode_attend``): the compiled decode step holds, besides the
+    pools, no array of ``8 x 32,768`` latent rows (a ``[lanes, table, page,
+    512]`` view of the lanes' tables: 268 MB a layer) nor of that many rotated
+    keys; a block of the walk is ``8 x DECODE_BLOCK_ROWS`` rows. And no key or value of any
+    head is ever made: no array of rows of ``[32, 128]`` (k_nope, v),
+    ``[32, 192]`` or ``[32, 256]`` beyond the lanes' own query rows."""
+    from petals_tpu.ops.latent_attention import DECODE_BLOCK_ROWS
+
+    hlo, runs, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 0, pages_a_lane=512)
+    weights = {tuple(p.shape)[cut:] for run in runs for p in run.values() for cut in (0, 1)}
+    comps = _computations(hlo)
+    pools, views, expanded, blocks = 0, [], [], 0
+    block_pages = 8 * DECODE_BLOCK_ROWS // 64  # the pages a trip fetches over the 8 lanes
+    for computation, name, dims, op, _ in _arrays_in_memory(comps):
+        if dims in weights:
+            continue
+        if any(math.prod(dims) == math.prod(shape) and dims[-1:] == shape[-1:] for shape in LATENT_POOLS):
+            pools += 1  # a pool, written in place by the new rows' scatter
+        elif dims[-1:] in ((512,), (128,), (64,)) and math.prod(dims[:-1]) >= 8 * 512 * 64 // 2:
+            views.append(f"%{name} = {op} -> {list(dims)}")
+        # (block_pages, 32, 128) is a block of the rotated keys as their pool stores them: pages of 32 rows of 128
+        if dims[-2:] in ((32, 128), (32, 192), (32, 256)) and math.prod(dims[:-2]) > 8 and dims != (block_pages, 32, 128):
+            expanded.append(f"%{name} = {op} -> {list(dims)}")
+        blocks += dims[-1:] == (512,) and math.prod(dims[:-1]) == 8 * DECODE_BLOCK_ROWS  # a block of the walk's latent rows
+    assert pools, "the pools' scatters were not found: has the HLO text changed?"
+    assert blocks, "the walk's block of 8 x DECODE_BLOCK_ROWS latent rows was not found"
+    assert not views, f"the decode step makes a dense view of the lanes' tables: {views}"
+    assert not expanded, f"the decode step expands keys or values: {expanded}"
+
+
+def test_latent_mixed_step_expands_a_block_of_positions_at_a_time_and_holds_no_whole_score_matrix(v5e, tmp_path):
+    """A prompt's chunk takes the expanded form inside a walk
+    (``latent_chunk_attend``): the compiled mixed step with a chunk of 2,048
+    rows over a table of 32,768 positions holds the scores of a block (``[32,
+    2048, 128]`` float32, 33 MB), never the 8.1 GB of the whole ``[32, 2048,
+    31k]``, and the keys and values of a block of 128 positions, never a
+    lane's (32,768 x 32 x 256 values, 537 MB)."""
+    hlo, runs, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 2048, pages_a_lane=512)
+    weights = {tuple(p.shape)[cut:] for run in runs for p in run.values() for cut in (0, 1)}
+    comps = _computations(hlo)
+    scores, whole, lane_wide = 0, [], []
+    for computation, name, dims, op, _ in _arrays_in_memory(comps):
+        if dims in weights or any(math.prod(dims) == math.prod(shape) and dims[-1:] == shape[-1:] for shape in LATENT_POOLS):
+            continue
+        scores += dims == (32, 2048, 128)
+        if math.prod(dims) >= 32 * 2048 * 2048:
+            whole.append(f"%{name} = {op} -> {list(dims)}")
+        if dims[-2:] in ((32, 128), (32, 192), (32, 256)) and math.prod(dims[:-2]) > 2048:
+            lane_wide.append(f"%{name} = {op} -> {list(dims)}")
+    assert scores, "a block's scores, [32, 2048, 128], were not found"
+    assert not whole, f"the mixed step holds a chunk's scores against more than a block of positions: {whole}"
+    assert not lane_wide, f"the mixed step expands more than a chunk's rows or a block's positions: {lane_wide}"

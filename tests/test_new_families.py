@@ -16,6 +16,7 @@ from petals_tpu.models.registry import known_families
 from tests.test_full_model import SwarmHarness, _hf_greedy
 from tests.utils import (
     make_tiny_bloom,
+    make_tiny_deepseek_v3,
     make_tiny_falcon,
     make_tiny_gemma,
     make_tiny_gemma2,
@@ -38,7 +39,7 @@ MAKERS = {
     "mixtral": make_tiny_mixtral, "olmoe": make_tiny_olmoe, "qwen2": make_tiny_qwen2,
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
-    "KeyeVL2": make_tiny_keye_vl2,
+    "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 

@@ -823,3 +823,67 @@ def make_tiny_keye_vl2(tmpdir: str, **overrides) -> str:
         json.dump(config, f)
     save_file(tiny_keye_vl2_tensors(config), os.path.join(path, "model.safetensors"))
     return path
+
+
+TINY_DEEPSEEK_V3 = {  # the keys Kanana-2-30B-A3B publishes (transformers' deepseek_v3), at a toy size: kinds D, S, S, S
+    "model_type": "deepseek_v3", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 8,
+    "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "qk_head_dim": 24, "v_head_dim": 16,
+    "intermediate_size": 128, "moe_intermediate_size": 32, "num_hidden_layers": 4, "first_k_dense_replace": 1, "moe_layer_freq": 1,
+    "n_routed_experts": 16, "num_experts_per_tok": 4, "n_shared_experts": 2, "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc",
+    "scoring_func": "sigmoid", "norm_topk_prob": True, "routed_scaling_factor": 2.448, "hidden_act": "silu", "attention_bias": False,
+    "rms_norm_eps": 1e-6, "rope_theta": 1000000, "rope_scaling": None, "rope_interleave": True,
+    "max_position_embeddings": 256, "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_deepseek_v3_tensors(config: dict, seed: int = 29) -> dict:
+    """Seeded float32 tensors under transformers' ``deepseek_v3`` names of
+    every layer of ``config``, the embedding, the final norm and the head.
+    Norm vectors and the router's bias are drawn, not ones and zeros, so a
+    missing or misplaced one shows."""
+    rng = np.random.RandomState(seed)
+    h, heads, dn, dr, dv, latent = (config[k] for k in ("hidden_size", "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim",
+                                                        "v_head_dim", "kv_lora_rank"))
+    m, md, n, shared = config["moe_intermediate_size"], config["intermediate_size"], config["n_routed_experts"], config["n_shared_experts"]
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda width: rng.uniform(0.5, 1.5, width).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": norm(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i in range(config["num_hidden_layers"]):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "input_layernorm.weight": norm(h), p + "post_attention_layernorm.weight": norm(h),
+            p + "self_attn.q_proj.weight": normal(heads * (dn + dr), h) * 3, p + "self_attn.kv_a_proj_with_mqa.weight": normal(latent + dr, h) * 3,
+            p + "self_attn.kv_a_layernorm.weight": norm(latent), p + "self_attn.kv_b_proj.weight": normal(heads * (dn + dv), latent) * 3,
+            p + "self_attn.o_proj.weight": normal(h, heads * dv),
+        })
+        if i < config["first_k_dense_replace"]:
+            tensors.update({p + "mlp.gate_proj.weight": normal(md, h), p + "mlp.up_proj.weight": normal(md, h),
+                            p + "mlp.down_proj.weight": normal(h, md)})
+            continue
+        tensors[p + "mlp.gate.weight"] = normal(n, h) * 5  # scores spread over (0, 1)
+        tensors[p + "mlp.gate.e_score_correction_bias"] = normal(n)
+        for e in range(n):
+            q = p + f"mlp.experts.{e}."
+            tensors.update({q + "gate_proj.weight": normal(m, h), q + "up_proj.weight": normal(m, h), q + "down_proj.weight": normal(h, m)})
+        q = p + "mlp.shared_experts."
+        tensors.update({q + "gate_proj.weight": normal(m * shared, h), q + "up_proj.weight": normal(m * shared, h),
+                        q + "down_proj.weight": normal(h, m * shared)})
+    return tensors
+
+
+def make_tiny_deepseek_v3(tmpdir: str, **overrides) -> str:
+    """A ``deepseek_v3`` checkpoint at a toy size, written by hand under
+    transformers' names (tests/test_deepseek_v3.py loads the same tensors
+    into transformers' own layer)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = {**TINY_DEEPSEEK_V3, **overrides}
+    path = os.path.join(tmpdir, "tiny-deepseek-v3")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_deepseek_v3_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
