@@ -5,8 +5,15 @@ row of 512 + 64 under 32 heads of 128 + 64 | 128), at several block widths:
     chiprun -- python3 benchmarks/ablate_latent_attention.py [--decode-only] [ctx ...]
 
 ``decode`` is the whole decode call of a layer (the absorb, the walk over the
-lanes' latent rows where they lie, the way back out of the latent space) at
-``DECODE_BLOCK_ROWS`` of 2,048 to 32,768 (the whole table) positions a lane a trip;
+lanes' latent rows where they lie, the way back out of the latent space) on
+the composed walk at its ``DECODE_BLOCK_ROWS`` (PR 42 swept 256 to 32,768 here:
+the constant's comment has the rows); ``decode_kernel`` the same call on the
+fused kernel at ``DECODE_KERNEL_PAGES`` of 8 to 64 table slots a grid step
+(PR 43's first two calls also split a block's pass into runs of 256 to 4,096
+positions: the constant's comment has those rows), with ``off`` the
+largest difference of its answer from the composed walk's over the largest
+answer; a context of 0 stands for eight ragged lanes of 16,400 to 30,720
+positions (mean 24.5k, as the cell's decode steps see) and an idle ninth;
 ``chunk`` a chunk of 2,048 rows from ``ctx - 2048`` on (the walk that expands
 a block of positions to keys and values) at ``CHUNK_BLOCK_ROWS`` of 64, 128
 and 256 (PR 42's first two calls had 256 to 4,096 and 128, 256, 512). A stage's repetitions run inside one jitted loop at two lengths and
@@ -47,10 +54,11 @@ def main(contexts) -> None:
     out_dir = os.path.join(ROOT, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
     lanes, max_pages, ps, heads, dn, dr, dv, latent, chunk_rows, reps = (
-        (8, 512, 64, 32, 128, 64, 128, 512, 2048, 20) if on_chip else (4, 8, 16, 4, 16, 16, 16, 32, 32, 2)
+        (8, 512, 64, 32, 128, 64, 128, 512, 2048, 20) if on_chip else (4, 8, 16, 4, 16, 64, 16, 32, 32, 2)
     )
     decode_only = "--decode-only" in contexts
-    contexts = [int(c) for c in contexts if c != "--decode-only"] or ([16384, 24576, 30720] if on_chip else [100])
+    contexts = [int(c) for c in contexts if c != "--decode-only"] or ([16384, 24576, 30720, 0] if on_chip else [100, 0])
+    ragged = (16400, 18000, 20480, 23000, 24576, 27000, 29500, 30720) if on_chip else (100, 7, 64, 128)
     n_pages = lanes * max_pages
     ks = jax.random.split(jax.random.PRNGKey(0), 8)
     dtype = jnp.bfloat16
@@ -81,18 +89,29 @@ def main(contexts) -> None:
     pair_flops, row_bytes = 2 * heads * (dn + dr + dv), (latent + dr) * 2
     with open(os.path.join(out_dir, "ablate_latent_attention.jsonl"), "a") as sink:
         for ctx in contexts:
-            pos = jnp.full((lanes,), ctx - 1, jnp.int32)
+            held = [ctx] * lanes if ctx else list(ragged)
+            pos = jnp.asarray(held, jnp.int32) - 1
             q_nope, q_pe = jax.random.normal(ks[4], (lanes, 1, heads, dn), dtype), jax.random.normal(ks[5], (lanes, 1, heads, dr), dtype)
 
-            def decode(qn, qp, cp, pp, tb):
-                u = la.latent_decode_attend(la.absorb_queries(qn, w_uk), qp, PagedKV(cp, tb), PagedKV(pp, tb), pos, scale=scale)
-                return la.expand_outputs(u, w_uv)
+            def decode(path):
+                def call(qn, qp, cp, pp, tb):
+                    u = la.latent_decode_attend(la.absorb_queries(qn, w_uk), qp, PagedKV(cp, tb), PagedKV(pp, tb), pos, scale=scale, path=path)
+                    return la.expand_outputs(u, w_uv)
 
-            floor = max(lanes * ctx * row_bytes / HBM_BYTES_PER_S, lanes * ctx * pair_flops / BF16_FLOPS_PER_S) * 1e3
-            for block in (2048, 4096, 8192, 16384, 32768) if on_chip else (16, 64):
-                la.DECODE_BLOCK_ROWS = block
-                row = {"stage": "decode", "ctx": ctx, "block_rows": block, "ms": timed(decode, q_nope, q_pe, c_pool, pe_pool, tables), "floor_ms": floor,
-                       "timed": "loop", "device": jax.devices()[0].device_kind}
+                return call
+
+            args = (q_nope, q_pe, c_pool, pe_pool, tables)
+            floor = max(sum(held) * row_bytes / HBM_BYTES_PER_S, sum(held) * pair_flops / BF16_FLOPS_PER_S) * 1e3
+            row = {"stage": "decode", "ctx": ctx, "block_rows": la.DECODE_BLOCK_ROWS, "ms": timed(decode("composed"), *args), "floor_ms": floor,
+                   "timed": "loop", "device": jax.devices()[0].device_kind}
+            print(json.dumps(row), flush=True)
+            sink.write(json.dumps(row) + "\n")
+            want = np.asarray(jax.jit(decode("composed"))(*args), np.float32)
+            for pages in (8, 16, 24, 32, 48, 64) if on_chip else (2, 3):
+                la.DECODE_KERNEL_PAGES = pages
+                got = np.asarray(jax.jit(decode("kernel"))(*args), np.float32)
+                row = {"stage": "decode_kernel", "ctx": ctx, "pages": pages, "ms": timed(decode("kernel"), *args), "floor_ms": floor,
+                       "off": float(np.abs(got - want).max() / np.abs(want).max()), "timed": "loop", "device": jax.devices()[0].device_kind}
                 print(json.dumps(row), flush=True)
                 sink.write(json.dumps(row) + "\n")
             if decode_only or ctx < chunk_rows:

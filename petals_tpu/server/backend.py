@@ -541,11 +541,12 @@ class TransformerBackend:
         (the absorbed form) and the ``chunk`` (first position, tokens) of a
         mixed step (the expanded one). For the batcher's ``latent_*`` counters
         (ops/latent_attention.py has the arithmetic)."""
-        from petals_tpu.ops.latent_attention import chunk_reads, decode_reads
+        from petals_tpu.ops.latent_attention import chunk_reads, decode_path, decode_reads, latent_pool_rows
 
         layers = len(self.kv_layers)
         contexts = [int(p) + 1 for p in last]
-        read = decode_reads(n_lanes, max_pages, page_size, max(contexts)) if contexts else 0
+        kernel = decode_path(*latent_pool_rows(page_size, *self.latent_row), self.cache_dtype) == "kernel"
+        read = decode_reads(n_lanes, max_pages, page_size, contexts, kernel=kernel)
         pairs = sum(contexts)
         expanded = held = rows = 0
         if chunk is not None:
@@ -899,11 +900,8 @@ class TransformerBackend:
         geometry: the batcher asks once."""
         from petals_tpu.ops.paged_flash_attention import walk_block_pages, window_pages
 
-        if self.latent_row is not None:  # ops/latent_attention.py ``latent_decode_attend``'s own blocks
-            from petals_tpu.ops.latent_attention import DECODE_BLOCK_ROWS
-            from petals_tpu.ops.sparse_attention import _block_pages
-
-            return ((None, len(self.kv_layers), _block_pages(max_pages, page_size, DECODE_BLOCK_ROWS), False),)
+        if self.latent_row is not None:  # ops/latent_attention.py ``decode_reads`` counts its own walk: ``latent_reads``
+            return ()
         itemsize = 2 if self.kv_quant_type != "none" else jnp.dtype(self.cache_dtype).itemsize  # a quantised pool reads as bf16
         walks = []
         for window, layers in self._window_layers:

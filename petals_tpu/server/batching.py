@@ -2311,8 +2311,10 @@ class DecodeBatcher:
         last = positions[positions < self.max_length] + (seq - 1)  # the idle sentinel is max_length
         if seq > 1:
             read = backend.pages_gathered(seq, self.max_pages, self.page_size)
-        elif self._selects:
-            read = 0  # the chosen positions' rows are fetched one by one: ``_count_sparse`` adds their pages' worth
+        elif self._selects or self._latent:
+            # the chosen positions' rows are fetched one by one, a latent row's walk counts itself (each lane to its own
+            # end where the kernel runs): ``_count_sparse`` / ``_count_latent`` add their pages' worth
+            read = 0
         else:
             read = backend.pages_walked(self._walks, last, self.page_size) if last.size else 0
         self.stats["attn_pages_gathered"] += self.n_lanes * read
@@ -2394,6 +2396,7 @@ class DecodeBatcher:
         )
         for key, n in reads.items():
             self.stats[key] += n
+        self.stats["attn_pages_gathered"] += reads["latent_rows_read"] // self.page_size
         if chunk is not None:
             lanes = np.append(lanes, chunk[0])
         self.stats["latent_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()

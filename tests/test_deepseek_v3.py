@@ -214,19 +214,40 @@ def test_forward_and_backward_run_a_whole_sequence_in_the_expanded_form(tiny):
 # ---------------------------------------------------------------------------------
 
 
-def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_and_a_full_table():
+ABSORBED_CASES = [  # path, the lanes' lengths, table slots a grid step of the kernel (pages of 8 positions)
+    pytest.param("composed", (50, 10, 0, 64), None, id="composed"),
+    pytest.param("kernel", (50, 10, 0, 64), 2, id="kernel"),
+    pytest.param("kernel", (5, 15, 0, 9), 2, id="kernel-lanes-shorter-than-a-block"),
+    pytest.param("kernel", (16, 32, 8, 48), 2, id="kernel-lanes-that-end-on-a-block-s-and-on-a-page-s-last-position"),
+    pytest.param("kernel", (51, 33, 1, 7), 2, id="kernel-odd-lengths-and-a-lane-of-one-position"),
+    pytest.param("kernel", (0, 0, 0, 0), 2, id="kernel-no-live-lane"),
+    pytest.param("kernel", (50, 10, 0, 64), 3, id="kernel-blocks-that-do-not-divide-the-table"),
+    pytest.param("kernel", (50, 10, 0, 64), 8, id="kernel-a-block-of-the-whole-table"),
+]
+
+
+@pytest.mark.parametrize("path,lengths,pages", ABSORBED_CASES)
+def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_and_a_full_table(monkeypatch, path, lengths, pages):
     """``latent_decode_attend`` (absorbed, the rows met as the pools store
     them, the rotated keys two positions to a row) against
     ``latent_attend_dense`` (expanded, no cache) and plain float32 NumPy, a
     lane: lanes of 50, 10, 0 (idle, the sentinel position: zeros) and 64
     positions (a full table) over permuted tables with holes past what a lane
     holds; pages no lane owns hold NaN, and a hole reads page 0, which lane 3
-    owns. And ``latent_chunk_attend`` (expanded inside a walk): a chunk of 24
-    rows, 19 of them real, from position 30 of lane 0, in blocks of one page."""
+    owns (or nobody, finite). And ``latent_chunk_attend`` (expanded inside a
+    walk): a chunk of 24 rows, 19 of them real, from position 30 of lane 0, in
+    blocks of one page. As cases, the composed walk and the kernel
+    (interpreted), and the kernel's own edges: lanes shorter than a block of
+    ``pages`` table slots, lanes that end on a block's and on a page's last
+    position, odd lengths (the last pool row of rotated keys half filled) and
+    a lane of one position, no live lane, blocks that do not divide the table
+    and one of the whole table."""
+    if pages is not None:
+        monkeypatch.setattr(latent, "DECODE_KERNEL_PAGES", pages)
     rng = np.random.default_rng(11)
     lanes, max_pages, ps, heads, dn, dr, dv, C = 4, 8, 8, 4, 16, 64, 16, 32
     n_pages, max_length = 30, max_pages * ps
-    lengths = np.array([50, 10, 0, 64])
+    lengths = np.array(lengths)
     c, k_pe = rng.standard_normal((lanes, max_length, C)).astype(np.float32), rng.standard_normal((lanes, max_length, dr)).astype(np.float32)
     q_nope, q_pe = rng.standard_normal((lanes, 1, heads, dn)).astype(np.float32), rng.standard_normal((lanes, 1, heads, dr)).astype(np.float32)
     w_uk, w_uv = rng.standard_normal((heads, dn, C)).astype(np.float32) * 0.3, rng.standard_normal((heads, C, dv)).astype(np.float32) * 0.3
@@ -236,7 +257,8 @@ def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_
     for lane, length in enumerate(lengths):
         held = -(-length // ps)
         tables[lane, :held], free = free[:held], free[held:]
-    tables[3, 2] = 0  # page 0 is somebody's: what a hole reads is real, finite and masked
+    if lengths[3] > 2 * ps:
+        tables[3, 2] = 0  # page 0 is somebody's: what a hole reads is real, finite and masked
     (c_rows, c_width), (pe_rows, pe_width) = latent.latent_pool_rows(ps, C, dr)
     assert (c_rows, c_width, pe_rows, pe_width) == (8, 32, 4, 128)  # a rotated key of 64: two positions to a row of 128
     c_pool, pe_pool = np.full((n_pages, c_rows, c_width), np.nan, np.float32), np.full((n_pages, pe_rows, pe_width), np.nan, np.float32)
@@ -245,11 +267,13 @@ def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_
             if page >= 0:
                 at = slice(slot * ps, (slot + 1) * ps)
                 c_pool[page], pe_pool[page] = c[lane, at], k_pe[lane, at].reshape(pe_rows, pe_width)
+    if np.isnan(c_pool[0]).any():  # nobody's: finite all the same
+        c_pool[0], pe_pool[0] = 3.0, -3.0
     positions = np.where(lengths > 0, lengths - 1, max_length).astype(np.int32)
     c_kv, pe_kv = PagedKV(jnp.asarray(c_pool), jnp.asarray(tables)), PagedKV(jnp.asarray(pe_pool), jnp.asarray(tables))
     with jax.default_matmul_precision("highest"):
         u = latent.latent_decode_attend(latent.absorb_queries(jnp.asarray(q_nope), jnp.asarray(w_uk)), jnp.asarray(q_pe), c_kv, pe_kv,
-                                        jnp.asarray(positions), scale=scale)
+                                        jnp.asarray(positions), scale=scale, path=path)
         got = np.asarray(latent.expand_outputs(u, jnp.asarray(w_uv)))
     want = np.zeros((lanes, 1, heads, dv), np.float32)
     for lane, length in enumerate(lengths):
@@ -258,8 +282,10 @@ def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_
             logits = (k_nope[:, head] @ q_nope[lane, 0, head] + k_pe[lane, :length] @ q_pe[lane, 0, head]) * scale
             p = np.exp(logits - logits.max(initial=-np.inf))
             want[lane, 0, head] = (p / max(p.sum(), 1e-30)) @ v[:, head] if length else 0.0
-    assert got.shape == want.shape and np.isfinite(got).all() and not got[2].any()
+    assert got.shape == want.shape and np.isfinite(got).all() and not got[lengths == 0].any()
     assert np.abs(got - want).max() < 1e-5, np.abs(got - want).reshape(lanes, -1).max(-1)
+    if lengths[0] != 50:  # the other two forms are held to lane 0's 50 rows
+        return
     with jax.default_matmul_precision("highest"):  # the last row of the dense form over a lane's whole sequence: the same row
         q_all = np.zeros((1, 50, heads, dn), np.float32), np.zeros((1, 50, heads, dr), np.float32)
         q_all[0][0, -1], q_all[1][0, -1] = q_nope[0, 0], q_pe[0, 0]
@@ -279,7 +305,7 @@ def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_
             p = np.exp(logits - logits.max())
             assert np.abs(chunk[0, t, head] - (p / p.sum()) @ v[: 31 + t, head]).max() < 1e-5
     assert np.isfinite(chunk[0, :19]).all()
-    assert latent.decode_reads(4, 8, 8, 50) == 4 * 64 and latent.chunk_reads(8, 8, 30, 19) == 64  # a table of 64 positions is one block
+    assert latent.decode_reads(4, 8, 8, [50, 10], kernel=False) == 4 * 64 and latent.chunk_reads(8, 8, 30, 19) == 64  # a table of 64 positions is one block
 
 
 def test_the_pools_hold_one_row_a_position_once_and_rows_land_where_the_tables_say(tiny):
@@ -330,12 +356,8 @@ def test_a_chunk_s_rotated_keys_written_a_pool_row_at_a_time_are_the_rows_writte
     assert np.array_equal(got, want) and (n > 0 or np.array_equal(got, np.asarray(pool)))
 
 
-def test_the_published_span_s_cache_is_1152_bytes_a_position_a_layer_and_its_lanes_fit_the_default_budget():
-    """kanana2-30b-a3b-span6 on shapes alone: ISSUE 42's count of the
-    parameters, a position's 1,152 B a layer (6,912 B over the six, where
-    ``num_key_value_heads`` 32 x ``head_dim`` 64 read as keys and values
-    would be 8,192 B a layer), and the configuration's 8 lanes of 32,768 in
-    1.81 GB, inside ``Server``'s default budget of 15% of a 16 GiB chip."""
+def published_span() -> tuple:
+    """``(backend, runs, configuration)`` of kanana2-30b-a3b-span6 on shapes alone."""
     import tempfile
     from pathlib import Path
 
@@ -349,10 +371,19 @@ def test_the_published_span_s_cache_is_1152_bytes_a_position_a_layer_and_its_lan
     S = jax.ShapeDtypeStruct
     runs = tuple({name: S((n, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind, jnp.bfloat16).items()}
                  for kind, n in (("dense", 1), ("sparse", 5)))
+    return TransformerBackend(family, cfg, runs, first_block=0, n_blocks=6, memory_cache=None), runs, config
+
+
+def test_the_published_span_s_cache_is_1152_bytes_a_position_a_layer_and_its_lanes_fit_the_default_budget():
+    """kanana2-30b-a3b-span6 on shapes alone: ISSUE 42's count of the
+    parameters, a position's 1,152 B a layer (6,912 B over the six, where
+    ``num_key_value_heads`` 32 x ``head_dim`` 64 read as keys and values
+    would be 8,192 B a layer), and the configuration's 8 lanes of 32,768 in
+    1.81 GB, inside ``Server``'s default budget of 15% of a 16 GiB chip."""
+    backend, runs, config = published_span()
     matrices = lambda run: sum(int(np.prod(leaf.shape[1:])) for name, leaf in run.items() if leaf.ndim > 2)
     assert matrices(runs[0]) == 64_094_208 and matrices(runs[1]) == 640_024_576
     assert matrices(runs[0]) + 5 * matrices(runs[1]) == 3_264_217_088  # 6.53 GB in bf16, 6.08 GiB
-    backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=6, memory_cache=None)
     args = config["server_args"]
     assert backend.latent_row == (512, 64) and backend.kv_bytes_per_token() == backend.cache_bytes_per_token() == 6 * 1152 == 6912
     assert 2 * 6 * backend.num_kv_heads * backend.head_dim * 2 == 6 * 8192  # what the published keys would size, read as keys and values
@@ -361,7 +392,38 @@ def test_the_published_span_s_cache_is_1152_bytes_a_position_a_layer_and_its_lan
     pool = sum(int(np.prod(d.shape)) * 2 for d in (c, pe))
     assert pool == args["batch_lanes"] * 6912 * args["batch_max_length"] == 1_811_939_328 and "attn_cache_bytes" not in args
     assert pool <= 0.15 * 16 * 2**30
-    assert backend.decode_walks(8, 512, 64) == ((None, 6, latent.DECODE_BLOCK_ROWS // 64, False),)  # the walk's blocks, in pages
+    assert backend.decode_walks(8, 512, 64) == ()  # the latent walk counts itself: ``latent_reads``
+
+
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["composed", "kernel"])
+def test_the_decode_counters_count_what_the_path_that_runs_reads(monkeypatch, on_tpu):
+    """``decode_path`` follows from the backend and the pools' rows alone (the
+    published rows tile for the kernel, the toy's do not), and
+    ``backend.latent_reads`` counts that path's walk at the cell's lengths:
+    the composed walk reads all eight lanes in whole blocks of
+    ``DECODE_BLOCK_ROWS`` up to the longest live lane's; the kernel each live
+    lane's own blocks of ``DECODE_KERNEL_PAGES`` pages, the blocks its grid
+    does not skip (``_live``), within 6% of what the lanes hold. What the
+    roofline's need is made of does not depend on the path."""
+    monkeypatch.setattr(latent, "_on_tpu", lambda: on_tpu)
+    backend, _, _ = published_span()
+    rows = latent.latent_pool_rows(64, 512, 64)
+    assert latent.decode_path(*rows, jnp.bfloat16) == ("kernel" if on_tpu else "composed")
+    assert latent.decode_path(*latent.latent_pool_rows(16, 32, 8), jnp.float32) == "composed"  # the toy's rows: 32 wide, eight keys to a row
+    assert latent.decode_path(*rows, jnp.float16) == "composed" and "float16" in latent.decode_kernel_unsupported(*rows, jnp.float16)
+    contexts = np.array([16_400, 24_576, 30_720, 1, 32_768])  # three lanes idle
+    reads = backend.latent_reads(8, 512, 64, contexts - 1)
+    held = int(contexts.sum())
+    assert reads["latent_rows_held"] == 6 * held and reads["latent_score_pairs"] == 6 * held and reads["latent_rows_absorbed"] == 6 * 5
+    if on_tpu:
+        block = latent.DECODE_KERNEL_PAGES * 64
+        fetched = sum(block for ctx in contexts for i in range(-(-512 * 64 // block)) if latent._live(i, block, ctx))
+        assert reads["latent_rows_read"] == 6 * fetched
+        cell = backend.latent_reads(8, 512, 64, np.array([16_400, 18_000, 20_480, 23_000, 24_576, 27_000, 29_500, 30_720]) - 1)
+        assert 1.0 <= cell["latent_rows_read"] / cell["latent_rows_held"] < 1.06
+    else:
+        assert reads["latent_rows_read"] == 6 * 8 * 32_768
+    assert backend.latent_reads(8, 512, 64, np.array([], np.int64))["latent_rows_read"] == 0
 
 
 def test_lane_auto_sizing_and_the_occupancy_count_the_latent_row(tiny):

@@ -293,14 +293,15 @@ STEP_CASES = [
 ]
 
 
-def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none"):
+def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none", latent_kernel=True):
     """``(optimized HLO, stacked params, pool aval, (hkv, d))`` of
     ``TransformerBackend``'s paged decode step, or of its mixed step with a
     prompt chunk of ``chunk`` riding it, at a cell's widths and depth (8 lanes,
     pages of 64, ``pages_a_lane`` slots a table and as many pages a lane in
     the pool, pools donated, in the form the backend's descriptors give them:
     a head_dim of 64 folded to rows of ``hkv * d``), compiled for the v5e. Under
-    ``kv_quant`` the pools are ``PagedPool``s and the aval returned is their codes'."""
+    ``kv_quant`` the pools are ``PagedPool``s and the aval returned is their codes'.
+    ``latent_kernel`` False: a latent row's decode walk as off the chip, composed."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -339,6 +340,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
+        patch.setattr("petals_tpu.ops.latent_attention._on_tpu", lambda: latent_kernel)  # as would the latent decode walk's kernel
         hlo = jax.jit(step, donate_argnums=donated).lower(*avals).compile().as_text()
     return hlo, runs, pool, (backend.num_kv_heads, backend.head_dim)
 
@@ -771,36 +773,101 @@ def test_latent_step_leaves_both_pools_in_place_and_reads_its_weights_where_they
     assert not moved, f"the step relays a weight of the dense run of one block in every step: {moved}"
 
 
-def test_latent_decode_step_makes_no_view_of_the_tables_and_expands_no_lane_s_keys_or_values(v5e, tmp_path):
-    """A decode row takes the absorbed form (ops/latent_attention.py
-    ``latent_decode_attend``): the compiled decode step holds, besides the
-    pools, no array of ``8 x 32,768`` latent rows (a ``[lanes, table, page,
-    512]`` view of the lanes' tables: 268 MB a layer) nor of that many rotated
-    keys; a block of the walk is ``8 x DECODE_BLOCK_ROWS`` rows. And no key or value of any
-    head is ever made: no array of rows of ``[32, 128]`` (k_nope, v),
-    ``[32, 192]`` or ``[32, 256]`` beyond the lanes' own query rows."""
-    from petals_tpu.ops.latent_attention import DECODE_BLOCK_ROWS
+def latent_walk_calls(hlo: str) -> list:
+    """``[(computation, op_name, [operand dims])]`` of every call of the
+    latent decode walk's kernel (ops/latent_attention.py, named
+    ``latent_decode_walk``) in an optimized HLO module, an operand traced
+    through bitcasts to what it is a view of."""
+    comps, calls = _computations(hlo), []
+    for computation, instructions in comps.items():
+        by_name = {name: (op, dims, rest) for name, dims, op, rest in instructions}
+        for name, _, op, rest in instructions:
+            if op == "custom-call" and name.startswith("latent_decode_walk") and "tpu_custom_call" in rest:
+                operands = []
+                for operand in re.findall(r"%([\w.\-]+)", rest.split("), custom_call_target")[0]):
+                    while by_name[operand][0] == "bitcast":
+                        operand = re.search(r"%([\w.\-]+)", by_name[operand][2]).group(1)
+                    operands.append((by_name[operand][0], _fused(comps, *by_name[operand][::2]), by_name[operand][1]))
+                calls.append((computation, re.search(r'op_name="([^"]*)"', rest).group(1), operands))
+    return calls
 
-    hlo, runs, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 0, pages_a_lane=512)
+
+def latent_decode_findings(hlo: str, runs) -> dict:
+    """What a compiled decode step of kanana2-30b-a3b-span6 holds in memory
+    that the walk's kernel does without: arrays of latent rows or rotated keys
+    beside the two pools (more rows of 512 than the lanes' 8 x 32 absorbed
+    queries, more of 128 than their 8 x 64 doubled rotated ones), float32
+    scores of a block (``[8, 32, more than a latent row]``), keys or values of
+    any head (rows of ``[32, 128 | 192 | 256]`` beyond the lanes' own query
+    rows). And the pools it found, so that "none" is not "not parsed"."""
     weights = {tuple(p.shape)[cut:] for run in runs for p in run.values() for cut in (0, 1)}
-    comps = _computations(hlo)
-    pools, views, expanded, blocks = 0, [], [], 0
-    block_pages = 8 * DECODE_BLOCK_ROWS // 64  # the pages a trip fetches over the 8 lanes
-    for computation, name, dims, op, _ in _arrays_in_memory(comps):
-        if dims in weights:
+    found = {"pools": 0, "rows": [], "scores": [], "expanded": []}
+    for computation, name, dims, op, rest in _arrays_in_memory(_computations(hlo)):
+        line = f"%{name} = {op} -> {list(dims)}"
+        if dims in weights or len(dims) < 2:
             continue
         if any(math.prod(dims) == math.prod(shape) and dims[-1:] == shape[-1:] for shape in LATENT_POOLS):
-            pools += 1  # a pool, written in place by the new rows' scatter
-        elif dims[-1:] in ((512,), (128,), (64,)) and math.prod(dims[:-1]) >= 8 * 512 * 64 // 2:
-            views.append(f"%{name} = {op} -> {list(dims)}")
-        # (block_pages, 32, 128) is a block of the rotated keys as their pool stores them: pages of 32 rows of 128
-        if dims[-2:] in ((32, 128), (32, 192), (32, 256)) and math.prod(dims[:-2]) > 8 and dims != (block_pages, 32, 128):
-            expanded.append(f"%{name} = {op} -> {list(dims)}")
-        blocks += dims[-1:] == (512,) and math.prod(dims[:-1]) == 8 * DECODE_BLOCK_ROWS  # a block of the walk's latent rows
-    assert pools, "the pools' scatters were not found: has the HLO text changed?"
-    assert blocks, "the walk's block of 8 x DECODE_BLOCK_ROWS latent rows was not found"
-    assert not views, f"the decode step makes a dense view of the lanes' tables: {views}"
-    assert not expanded, f"the decode step expands keys or values: {expanded}"
+            found["pools"] += 1  # a pool, written in place by the new rows' scatter
+        elif math.prod(dims[:-1]) > {512: 8 * 32, 128: 8 * 64}.get(dims[-1], math.inf):
+            found["rows"].append(line)
+        if dims[:2] == (8, 32) and math.prod(dims[2:]) > 512:
+            found["scores"].append(line)
+        if dims[-2:] in ((32, 128), (32, 192), (32, 256)) and math.prod(dims[:-2]) > 8:
+            found["expanded"].append(line)
+    return found
+
+
+def test_latent_decode_step_makes_no_view_of_the_tables_and_expands_no_lane_s_keys_or_values(v5e, tmp_path):
+    """A decode row takes the absorbed form in ONE kernel a layer
+    (ops/latent_attention.py ``latent_decode_attend``): the compiled decode
+    step holds one ``tpu_custom_call`` under ``ptu.attn.latent_decode`` in
+    each layer loop's body (the dense run of one block is unrolled into
+    ``ENTRY``), handed the two whole-span pools as the loop carries them and
+    the new rows' scatter leaves them: no copy, no slice. Beside the pools
+    there is no array of latent rows or rotated keys in memory (the composed
+    walk's block was ``bf16[512,64,512]``, 38 MB fetched before a dot), no
+    float32 scores of a block or transposing copy of them (``f32[8,32,2048,2]``,
+    ``f32[8,32,4096]``), and no key or value of any head. The composed walk's
+    step, compiled beside it, shows each of the three: the guard can fail."""
+    hlo, runs, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 0, pages_a_lane=512)
+    calls = latent_walk_calls(hlo)
+    assert len(calls) == len(runs) == 2, calls
+    for computation, op_name, operands in calls:
+        assert "ptu.attn.latent_decode" in op_name, op_name
+        pools = [(op, fused, dims) for op, fused, dims in operands if math.prod(dims) >= math.prod(LATENT_POOLS[1])]
+        assert sorted(math.prod(dims) for _, _, dims in pools) == sorted(math.prod(shape) for shape in LATENT_POOLS), operands
+        for op, fused, dims in pools:  # as the loop carries it, or as the scatter of the new rows (in place: the pools' own test) left it
+            assert op in ("get-tuple-element", "parameter") or (fused is not None and any(i[2] in ("dynamic-update-slice", "scatter") for i in fused)), (op, dims)
+    found = latent_decode_findings(hlo, runs)
+    assert found["pools"], "the pools' scatters were not found: has the HLO text changed?"
+    assert not found["rows"], f"the decode step holds latent rows or rotated keys beside the pools: {found['rows']}"
+    assert not found["scores"], f"the decode step holds a block's scores in memory: {found['scores']}"
+    assert not found["expanded"], f"the decode step expands keys or values: {found['expanded']}"
+    composed, _, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 0, pages_a_lane=512, latent_kernel=False)
+    was = latent_decode_findings(composed, runs)
+    assert not latent_walk_calls(composed) and was["pools"] and was["rows"] and was["scores"], was
+
+
+def test_latent_decode_kernel_lowers_at_the_published_shapes(v5e):
+    """The walk's kernel alone, through Pallas -> Mosaic -> libtpu for the v5e
+    at kanana2-ctx32k's shapes: 8 lanes of 32 heads, tables of 512 pages, the
+    span's pools of 6 x 4,096 pages of ``[64, 512]`` latents and ``[32, 128]``
+    rotated keys, which it is handed whole."""
+    from petals_tpu.ops import latent_attention as latent
+    from petals_tpu.ops.paged_attention import PagedKV
+
+    def walk(q_abs, q_pe, c_pool, pe_pool, tables, positions):
+        return latent.latent_decode_attend(q_abs, q_pe, PagedKV(c_pool, tables), PagedKV(pe_pool, tables), positions, scale=192**-0.5, path="kernel")
+
+    avals = (v5e((8, 1, 32, 512), BF16), v5e((8, 1, 32, 64), BF16), v5e((6 * 4096, 64, 512), BF16), v5e((6 * 4096, 32, 128), BF16),
+             v5e((8, 512), I32), v5e((8,), I32))
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(latent, "_on_tpu", lambda: True)  # the backend here is the CPU: the kernel would be interpreted
+        _compile(walk, *avals)
+        body = str(jax.make_jaxpr(walk)(*avals))
+    # the copies are a loop and the waits one a pool: written out (64 starts at two sites, 64 waits) they were 2 s of every
+    # step program's start, ten programs a server (`setup_s` +40% on the chip)
+    assert body.count("dma_start") == 4 and body.count("dma_wait") == 2, (body.count("dma_start"), body.count("dma_wait"))
 
 
 def test_latent_mixed_step_expands_a_block_of_positions_at_a_time_and_holds_no_whole_score_matrix(v5e, tmp_path):
