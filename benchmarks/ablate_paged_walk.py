@@ -1,13 +1,16 @@
 """A decode row's attention over paged keys and values on the real chip: the
-walk in blocks of table slots (ops/paged_flash_attention.py
-``composed_paged_attend``) at every block width, beside the path it replaced
-(gather every slot of every lane, ``attend_reference`` over the dense view),
-one layer's call at the cells' pool geometries and at lengths their traffic
-gives the lanes.
+composed walk in blocks of table slots (ops/paged_flash_attention.py
+``composed_paged_attend``) at every block width, the walk as ONE kernel that
+reads each lane's own pages where they lie (``path="kernel"``, PR 45) at 1, 2,
+4 and 8 pages a block, and the path both replaced (gather every slot of every
+lane, ``attend_reference`` over the dense view), one layer's call at the
+cells' pool geometries and at lengths their traffic gives the lanes.
 
-    chiprun -- python3 benchmarks/ablate_paged_walk.py [shape ...]
+    chiprun -- python3 benchmarks/ablate_paged_walk.py [shape ...] [--stages dense,walk,kernel]
 
-What ``WALK_BLOCK_BYTES`` was set from (PERF.md section 5, PR 36). A call is
+What ``WALK_BLOCK_BYTES`` (PERF.md section 5, PR 36) and
+``WALK_KERNEL_BLOCK_BYTES`` (PR 45) were set from. ``--stages`` keeps the
+variants it names (``walk`` every width, ``walk1`` that one). A call is
 timed as the slope between chains of 2 and 10 calls in one program, each link
 fed the last one's output and its tables made to wait for it, so that XLA can
 neither drop a link nor gather once for all of them; the pools ride as jit
@@ -30,7 +33,7 @@ sys.path.insert(0, ROOT)
 SHAPES = {
     "olmo-hybrid-7b": (8, 40, 64, 32, 128, 1, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k: 1,024-2,560
     "olmoe-1b-7b": (8, 16, 64, 16, 128, 1, (90, 130, 170, 210, 250, 290, 330, 370)),  # saturated: 64-128 in, 256 out
-    "mixtral-8x7b": (8, 16, 64, 8, 128, 4, (90, 130, 170, 210, 250, 290, 330, 370)),
+    "mixtral-8x7b": (8, 16, 64, 8, 128, 4, (90, 130, 170, 210, 250, 290, 330, 370)),  # chat: 16-120 in, 16-160 out
     "falcon-40b": (8, 16, 64, 8, 64, 16, (90, 130, 170, 210, 250, 290, 330, 370)),
     "k-exaone-236b": (8, 16, 64, 8, 128, 8, (90, 130, 170, 210, 250, 290, 330, 370)),
     "olmo-hybrid-7b-full": (8, 40, 64, 32, 128, 1, (2559,) * 8),  # every lane at the table's end
@@ -40,7 +43,7 @@ LINKS = (2, 10)
 FEED = 2.0 ** -10
 
 
-def main(names) -> None:
+def main(names, stages=("dense", "walk", "kernel")) -> None:
     from petals_tpu.utils.compile_cache import enable_compilation_cache
 
     enable_compilation_cache()
@@ -62,7 +65,13 @@ def main(names) -> None:
         return attend_reference(q, pa.gather_pages(kp, tb, hkv), pa.gather_pages(vp, tb, hkv), q_offset=pos, kv_length=pos + 1)
 
     def walk(q, kp, vp, tb, pos):
-        return pfa.composed_paged_attend(q, kp, vp, tb, q_offset=pos, kv_length=pos + 1)
+        return pfa.composed_paged_attend(q, kp, vp, tb, q_offset=pos, kv_length=pos + 1, path="composed")
+
+    def kernel(q, kp, vp, tb, pos):
+        return pfa.composed_paged_attend(q, kp, vp, tb, q_offset=pos, kv_length=pos + 1, path="kernel")
+
+    def wanted(variant: str) -> bool:
+        return any(variant == stage or variant.rstrip("0123456789") == stage for stage in stages)
 
     def timed(call, q, kp, vp, tb, pos) -> float:
         def chain(n):
@@ -90,7 +99,7 @@ def main(names) -> None:
     for name in names:
         n_lanes, max_pages, page_size, hkv, d, group, lengths = SHAPES[name]
         if not on_chip:
-            hkv, d = min(hkv, 2), min(d, 32)
+            hkv, max_pages, lengths = min(hkv, 16), 4, tuple(min(n, 200) for n in lengths)  # a toy the kernel still takes
         n_pages = n_lanes * max_pages
         rng = np.random.default_rng(0)
         tables = rng.permutation(n_pages).astype(np.int32).reshape(n_lanes, max_pages)
@@ -109,9 +118,11 @@ def main(names) -> None:
         a_slot = n_lanes * page_size * hkv * d * 2
         live_mb = 2 * int(np.where(idle, 0, pos + 1).sum()) * hkv * d * 2 / 1e6
         want = np.asarray(jax.jit(dense)(*args), np.float32)[~idle]
-        rows = [("dense", None, timed(dense, *args))]
+        rows = [("dense", None, timed(dense, *args))] if wanted("dense") else []
         widths = sorted({w for w in (1, 2, 4, 8, 16, max_pages) if w <= max_pages})
         for block in widths:
+            if not wanted(f"walk{block}"):
+                continue
             pfa.WALK_BLOCK_BYTES = (1 << (block - 1).bit_length()) * a_slot  # the whole row: the power of two over it
             assert pfa.walk_block_pages(n_lanes, max_pages, page_size, hkv, d) == block
             got = np.asarray(jax.jit(lambda *a: walk(*a))(*args), np.float32)[~idle]  # a new program a width
@@ -119,6 +130,14 @@ def main(names) -> None:
             rows.append((f"walk{block}", err, timed(walk, *args)))
             if len(row) == 1 and block == 1:  # what the fold costs the walk: the same walk over pools of [hkv, d] rows
                 rows.append(("walk1-rows-of-hkv-d", None, timed(walk, *unfolded)))
+        # the kernel, where it takes the pool as it is stored, at 1 to 8 pages of one lane a block
+        takes = pfa.walk_kernel_unsupported(args[1], q.shape, tables.shape) is None
+        for block in (1, 2, 4, 8) if takes else ():
+            if not wanted(f"kernel{block}") or (not on_chip and block > 1):
+                continue
+            pfa.WALK_KERNEL_BLOCK_BYTES = block * page_size * hkv * d * 2
+            got = np.asarray(jax.jit(lambda *a: kernel(*a))(*args), np.float32)[~idle]
+            rows.append((f"kernel{block}", float(np.max(np.abs(got - want))) if got.size else 0.0, timed(kernel, *args)))
         for variant, err, ms in rows:
             line = {"shape": name, "variant": variant, "ms": round(ms, 4), "max_err": err, "live_mb": round(live_mb, 1),
                     "floor_ms": round(live_mb / 819e3 * 1e3, 4), "longest_slots": int(held.max()), "device": jax.devices()[0].device_kind}
@@ -128,4 +147,10 @@ def main(names) -> None:
 
 
 if __name__ == "__main__":
-    main(sys.argv[1:] or list(SHAPES))
+    import argparse
+
+    parser = argparse.ArgumentParser()
+    parser.add_argument("shapes", nargs="*", default=list(SHAPES))
+    parser.add_argument("--stages", default="dense,walk,kernel")
+    cli = parser.parse_args()
+    main(cli.shapes, tuple(cli.stages.split(",")))

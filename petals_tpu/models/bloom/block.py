@@ -170,5 +170,6 @@ FAMILY = register_family(
             "dense": "wo", "dense_h_to_4h": "w_up", "dense_4h_to_h": "w_down",
         },
         supports_ring_attention=True,
+        block_attention=lambda cfg, kind=None: ("alibi",),  # every block's scores take the slopes' bias
     )
 )

@@ -50,6 +50,12 @@ def _activation(x: jnp.ndarray, name: str) -> jnp.ndarray:
     raise NotImplementedError(f"Falcon activation {name!r} is not supported")
 
 
+def block_attention(cfg: FalconBlockConfig, kind=None) -> tuple:
+    """What the block hands its attention beyond the plain call (``ModelFamily.block_attention``): the
+    RW generation's ALiBi bias in place of the rotary embedding."""
+    return ("alibi",) if cfg.alibi else ()
+
+
 def block_apply(
     params: dict,
     hidden_states: jnp.ndarray,  # [batch, seq, hidden]
@@ -87,7 +93,7 @@ def block_apply(
     v = v.reshape(batch, seq, hkv, d)
 
     alibi_slopes = None
-    if cfg.alibi:
+    if "alibi" in block_attention(cfg):
         # Falcon scales (scores + alibi) jointly by 1/sqrt(d) — unlike BLOOM,
         # where the bias is added unscaled — so pre-scale the slopes here.
         alibi_slopes = build_alibi_slopes(hq) * (d**-0.5)
@@ -259,5 +265,6 @@ FAMILY = register_family(
             "dense": "wo", "dense_h_to_4h": "w_up", "dense_4h_to_h": "w_down",
         },
         supports_ring_attention=True,
+        block_attention=block_attention,
     )
 )

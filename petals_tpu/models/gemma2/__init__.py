@@ -63,5 +63,6 @@ FAMILY = register_family(
         # int32 scalar, not a weight
         cast_exempt=("ln1", "ln1_post", "ln2_pre", "ln2_post", "norm", "attn_window"),
         supports_ring_attention=False,  # softcap has no ring/flash rule
+        block_attention=block_mod.block_attention,
     )
 )

@@ -37,6 +37,12 @@ from petals_tpu.ops.attention import attend
 from petals_tpu.ops.rotary import apply_rotary, rotary_tables
 
 
+def block_attention(cfg: Gemma2BlockConfig, kind=None) -> tuple:
+    """What the block hands its attention beyond the plain call (``ModelFamily.block_attention``): a
+    window that is an array, each block's own out of its parameters, and the soft cap on the scores."""
+    return ("traced_window",) + (("softcap",) if cfg.attn_logit_softcapping is not None else ())
+
+
 def block_apply(
     params: dict,
     hidden_states: jnp.ndarray,  # [batch, seq, hidden]

@@ -20,6 +20,7 @@ import functools
 from typing import Any, Callable, Dict, Hashable, Mapping, Optional
 
 _FAMILIES: Dict[str, "ModelFamily"] = {}
+ATTENTION_EXTRAS = frozenset({"alibi", "softcap", "traced_window"})  # what ``ModelFamily.block_attention`` may name
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +105,15 @@ class ModelFamily:
     # private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised pages, a tp
     # mesh) is refused for a family that declares one (server/backend.py ``latent_row``)
     block_latent: Optional[Callable] = None
+    # (cfg, kind) -> what a block of that kind hands its attention BEYOND the query, the cache, the causal mask and a
+    # static window, as names out of ``ATTENTION_EXTRAS``: "alibi" (a bias a head on the scores), "softcap" (a soft
+    # cap on them), "traced_window" (a window that is an array, a layer's own out of its parameters, where
+    # ``block_window`` / ``cfg.sliding_window`` is a number the program knows). None: the plain call, nearly every
+    # family's. What can only take the plain call goes by this and by nothing else of a family: the decode walk's
+    # kernel over plain pages (ops/paged_flash_attention.py ``walk_kernel_unsupported``), so the path the batcher's
+    # counters count is the one the step runs (server/backend.py ``decode_walks``;
+    # tests/test_paged_kernel.py holds every registered family's block to what it declares here)
+    block_attention: Optional[Callable] = None
 
     def kind_of(self, cfg, block_index: int) -> Hashable:
         return None if self.block_kind is None else self.block_kind(cfg, block_index)
@@ -131,6 +141,12 @@ class ModelFamily:
 
     def latent_for(self, cfg, kind: Hashable) -> Optional[tuple]:
         return None if self.block_latent is None else self.block_latent(cfg, kind)
+
+    def attention_for(self, cfg, kind: Hashable) -> frozenset:
+        extras = frozenset(() if self.block_attention is None else self.block_attention(cfg, kind))
+        if not extras <= ATTENTION_EXTRAS:
+            raise ValueError(f"{self.name}: block_attention names {sorted(extras - ATTENTION_EXTRAS)}, not of {sorted(ATTENTION_EXTRAS)}")
+        return extras
 
 
 def _kind_args(kind: Hashable) -> tuple:

@@ -48,6 +48,16 @@ overrides; off-TPU the XLA-composed path (``composed_paged_attend``: a decode
 row's walk over its lane's pages, gather_pages + attend_reference for the
 rest) is what runs, so tier-1 CPU runs never depend on interpret-mode Mosaic
 semantics unless a test asks for the kernel explicitly.
+
+Since PR 45 the composed path's decode walk is itself ONE kernel a layer on a
+TPU where the pool's stored form allows (``decode_walk_path``: plain rows of
+``[hkv, d]`` of whole tiles; ``_walk_kernel``): the span's pools stay in HBM
+as the layer loop carries them, each live lane's pages of ALL kv heads are
+copied block by block into one of two buffers under the block before, and a
+block is met as one matrix ``[rows * hkv, d]`` with the off-head columns
+masked. No relayout, no ``own_layer()`` slice: what the old fused decode
+kernel above pays for a pool of head_dim 128. The autotune's "xla" arm times
+this walk, so where both can run the faster one is taken.
 """
 
 from __future__ import annotations
@@ -58,6 +68,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1031,16 +1042,274 @@ def _walk_decode_rows(
     return out.reshape(n_lanes, 1, hkv * group, d).astype(q.dtype)
 
 
+# ---------------------------------------------------------------------------
+# the decode walk as one kernel: each lane's own pages read where they lie
+# ---------------------------------------------------------------------------
+
+# Bytes of keys (or values) ONE lane's block of the kernel's walk holds in a
+# buffer: a grid step copies that many whole pages of all kv heads out of each
+# pool and multiplies them in one pass, with the next live block's copies in
+# flight under it (two buffers a pool: 2 MB of fast memory in all, beside the
+# step's weight prefetches). A layer's call on the v5e, ms, at 1 | 2 | 4 | 8
+# pages a block (benchmarks/ablate_paged_walk.py, PR 45, call 2; the composed
+# walk and the bytes' floor beside them): 8 lanes of 1,150-2,300 positions over
+# 32 kv heads (pages of 512 KB) 0.315 | 0.317 | 0.329 | 0.350 (0.807, 0.279);
+# every lane at 2,559 0.448 | 0.447 | 0.450 | 0.450 (0.929, 0.410); one live
+# lane of 2,300 and seven idle 0.065 | 0.059 | 0.054 | 0.063 (0.639, 0.046);
+# 8 lanes of 90-370 over 16 kv heads (pages of 256 KB) 0.028 | 0.023 | 0.021 |
+# 0.040 (0.080, 0.018). A step's fixed cost (~0.35 us: the scalars' reads, the
+# accumulators' trip through scratch) is paid a block, live or skipped, and a
+# lane's last block is copied and multiplied whole: half a megabyte is the best
+# or within 0.005 ms of it at each.
+WALK_KERNEL_BLOCK_BYTES = 512 << 10
+WALK_KERNEL_VMEM_SLACK_BYTES = 16 << 20  # a block's scores and weights, the accumulators, the kernel's own temporaries
+# The lanes' tables ride into the kernel as prefetched scalars, ``n_lanes * slots`` int32 in scalar memory, of which the
+# v5e has 1 MiB a program: half of it compiles (8 lanes of 16,384 slots, compile-only for the v5e, PR 45), all of it
+# does not ("Ran out of memory in memory space smem ... Exceeded smem capacity by 2.6K"). A call with wider tables (64
+# lanes of 128k positions in pages of 64 reach it) takes the composed walk, where it would fail to compile at start.
+WALK_KERNEL_TABLE_BYTES = 512 << 10
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _sublanes(dtype) -> int:
+    """Rows of the chip's tile of ``dtype``: 8 of 32 bits, 16 of 16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def walk_kernel_block_pages(width: int, page_size: int, hkv: int, d: int, itemsize: int = 2) -> int:
+    """Table slots of one lane a grid step of the kernel's walk takes, from
+    the shapes alone: the largest power of two whose pages stay within
+    ``WALK_KERNEL_BLOCK_BYTES`` a pool, at least one and at most the ``width``
+    slots there are to walk."""
+    a_page = page_size * hkv * d * itemsize
+    block = 1
+    while block * 2 * a_page <= WALK_KERNEL_BLOCK_BYTES:
+        block *= 2
+    return min(block, width)
+
+
+def walk_kernel_unsupported(k_pool, q_shape, tables_shape, *, alibi: bool = False, softcap: bool = False, window=None) -> Optional[str]:
+    """Why the decode walk's kernel cannot take this call, or None: a static
+    predicate on the pool's stored form and the call's shape. The kernel copies
+    whole pages ``[page_size, hkv, d]`` out of the pool as it is stored and
+    meets them as ``[page_size * hkv, d]``, so the pool is a plain array of
+    rows of ``[hkv, d]`` with ``d`` whole lanes and ``hkv`` (and a page's rows)
+    whole sublane tiles of its dtype; it knows the walk's masks and nothing
+    else; and the tables, as the walk is handed them (cut to a window's
+    reach), have to fit the scalar memory they are prefetched into. ``k_pool``
+    is the pool or anything with its ``shape`` and ``dtype``."""
+    from petals_tpu.ops.paged_attention import PagedPool
+
+    if isinstance(k_pool, PagedPool):
+        return "a quantised pool: its codes are dequantised by the gather"
+    if len(k_pool.shape) != 4:
+        return f"a pool stored folded {tuple(k_pool.shape)}: its rows are not [kv heads, head_dim]"
+    _, page_size, hkv, d = k_pool.shape
+    if jnp.dtype(k_pool.dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        return f"pages of {jnp.dtype(k_pool.dtype).name}"
+    if d % LANES or q_shape[-1] != d:
+        return f"a head_dim of {d} is no multiple of {LANES}"
+    tile = _sublanes(k_pool.dtype)
+    if hkv % tile or page_size % tile:
+        # 8 kv heads of bfloat16 are half a tile: the pool then lives on the device in a layout of its own and the
+        # compiled step COPIES it whole in front of the kernel, every layer (tests/test_kernels_lower_tpu.py)
+        return f"{hkv} kv heads or a page's {page_size} rows are no multiple of the {tile} sublanes of {jnp.dtype(k_pool.dtype).name}"
+    if q_shape[1] != 1 or q_shape[2] % hkv:
+        return f"{q_shape[1]} query rows a lane of {q_shape[2]} heads"
+    if alibi or softcap:
+        return "ALiBi or a soft cap on the scores"
+    if window is not None and not isinstance(window, int):
+        return "a traced window"
+    if 4 * tables_shape[0] * tables_shape[1] > WALK_KERNEL_TABLE_BYTES:
+        return f"tables of {tuple(tables_shape)} slots are over the {WALK_KERNEL_TABLE_BYTES >> 10} KiB of scalar memory the kernel prefetches them into"
+    return None
+
+
+def decode_walk_path(k_pool, q_shape, tables_shape, *, alibi: bool = False, softcap: bool = False, window=None) -> str:
+    """``"kernel"`` on a TPU backend for a call the kernel takes
+    (``walk_kernel_unsupported``), ``"composed"`` everywhere else: what a
+    decode row's walk in ``composed_paged_attend`` runs and what the batcher's
+    counters count (server/backend.py ``decode_walks``) follow from this
+    alone."""
+    unsupported = walk_kernel_unsupported(k_pool, q_shape, tables_shape, alibi=alibi, softcap=softcap, window=window)
+    return "kernel" if _on_tpu() and unsupported is None else "composed"
+
+
+def _walk_kernel(tables_ref, starts_ref, ends_ref, q_ref, cols_ref, row_head_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, state, m_ref, l_ref, acc_ref, *,
+                 pages: int, scale: float, dot_in_f32: bool):
+    lane, block = pl.program_id(0), pl.program_id(1)
+    n_lanes, page_size, hkv = ends_ref.shape[0], k_hbm.shape[1], k_hbm.shape[2]
+    slots = tables_ref.shape[0] // n_lanes
+    rows = pages * page_size
+    start, end = starts_ref[lane], ends_ref[lane]  # the lane's row sees the positions [start, end) of its table
+
+    def start_copies(of_lane, of_block, slot):
+        """Start the page copies of ``of_lane``'s ``of_block`` into buffer ``slot``: a loop (a step program holds the
+        kernel once a run of layers, and copies written out are lowered one by one at every start)."""
+
+        def a_page(i, _):
+            page = tables_ref[of_lane * slots + of_block * pages + i]
+            to = pl.ds(pl.multiple_of(i * page_size, page_size), page_size)
+            pltpu.make_async_copy(k_hbm.at[page], k_buf.at[slot, to], sems.at[0, slot]).start()
+            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[slot, to], sems.at[1, slot]).start()
+            return _
+
+        jax.lax.fori_loop(0, pages, a_page, 0)
+
+    def wait_for_copies(slot):
+        """One wait a pool: a DMA semaphore counts bytes, so the whole buffer's answers for all its pages' copies."""
+        pltpu.make_async_copy(k_buf.at[slot], k_buf.at[slot], sems.at[0, slot]).wait()
+        pltpu.make_async_copy(v_buf.at[slot], v_buf.at[slot], sems.at[1, slot]).wait()
+
+    @pl.when((lane == 0) & (block == 0))
+    def _():
+        state[0] = 0  # the buffer the next live block is (or will be) copied into
+        state[1] = 0  # whether its copies were started by the block before it
+
+    @pl.when(block == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # blocks past the lane's own end, or wholly before its window's reach, copy nothing and multiply nothing
+    @pl.when((block * rows < end) & ((block + 1) * rows > start))
+    def _():
+        slot = state[0]
+
+        @pl.when(state[1] == 0)
+        def _():
+            start_copies(lane, block, slot)
+
+        # the next live block's copies fly under this block's dots: this lane's next one, or the next live lane's first
+        after = n_lanes
+        for other in reversed(range(n_lanes)):
+            after = jnp.where((other > lane) & (ends_ref[other] > 0), other, after)
+        last = (block + 1) * rows >= end
+        next_lane = jnp.where(last, after, lane)
+        next_block = jnp.where(last, starts_ref[jnp.minimum(after, n_lanes - 1)] // rows, block + 1)
+
+        @pl.when(next_lane < n_lanes)
+        def _():
+            start_copies(next_lane, next_block, 1 - slot)
+
+        state[0] = 1 - slot
+        state[1] = (next_lane < n_lanes).astype(jnp.int32)
+        wait_for_copies(slot)
+
+        def dot(a, b, contract):
+            if dot_in_f32:  # interpret mode: CPU XLA has no bf16 x bf16 -> f32 dot
+                a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+            return jax.lax.dot_general(a, b, (contract, ((), ())), preferred_element_type=jnp.float32)
+
+        # A page lies as [page_size, hkv, d]: the block is met as the rows of ONE matrix [rows * hkv, d] (whole tiles: no
+        # relayout), every query head against every kv head's columns, and a column that is another kv head's is masked
+        # like a position out of sight: its weight is exactly zero, so the same matrix of values takes the weights as
+        # they are. The matrix unit has the room: at 32 kv heads the dots are hidden under the copies (PERF.md section 5).
+        col_pos = block * rows + cols_ref[0:1, :]  # [1, rows * hkv]: a column's position, and its kv head
+        col_head = jnp.where((col_pos >= start) & (col_pos < end), cols_ref[1:2, :], -1)
+        mask = col_head == row_head_ref[...]  # [hq, rows * hkv]: a query head's own kv head, in sight
+        k = k_buf[slot].reshape(rows * hkv, k_buf.shape[-1])
+        v = v_buf[slot].reshape(rows * hkv, v_buf.shape[-1])
+        s = jnp.where(mask, dot(q_ref[...], k, ((1,), (1,))) * scale, NEG_INF)
+        m = m_ref[:, :1]
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_ref[:, :1] * alpha + p.sum(axis=-1, keepdims=True), l_ref.shape)
+        acc_ref[...] = acc_ref[...] * alpha + dot(p.astype(v.dtype), v, ((1,), (0,)))
+
+    @pl.when(block == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+
+
+# A jit of its own: a server warms ten step programs at every start, and the compile cache keeps executables, no traces:
+# under its own jit the kernel's body is traced once a process and lowered once a program (ops/latent_attention.py, PR 43).
+@functools.partial(tracked_jit, name="paged_decode_walk", static_argnames=("scale", "sliding_window", "pages", "interpret"))
+def _walk_decode_rows_kernel(q, k_pool, v_pool, tables, q_pos, kv_len, *, scale: float, sliding_window: Optional[int], pages: int,
+                             interpret: bool):
+    """``_walk_decode_rows`` as ONE Pallas call: grid (lane, block of ``pages``
+    table slots), both pools left in HBM as they are handed over (the span's,
+    as the layer loop carries them) and the tables and each lane's reach
+    prefetched as scalars. A live block's pages, all kv heads of them, are
+    copied into one of two VMEM buffers a pool by the block before it, under
+    that block's dots; ``m``, ``l`` and ``acc`` ride in scratch across a
+    lane's blocks. Each live lane is read to its OWN last block. The
+    arithmetic is the walk's: products of the pool's dtype summed in float32,
+    max / sum / output in float32, the weights cast to V's dtype."""
+    n_lanes, _, hq, d = q.shape
+    _, page_size, hkv, _ = k_pool.shape
+    width = tables.shape[1]
+    rows = pages * page_size
+    # the positions a lane's row sees, [start, end): its own length, the causal mask and the window's reach in two numbers
+    ends = jnp.minimum(kv_len, q_pos + 1)
+    starts = jnp.zeros_like(ends) if sliding_window is None else jnp.maximum(q_pos - (sliding_window - 1), 0)
+    ends = jnp.where(starts < ends, ends, 0)  # an idle lane, or one that sees nothing: no live block
+    # a hole (past a lane's end in its last block) reads the page of the lane's first position in sight: its own, and
+    # masked; a page nobody owns may hold anything, and a weight of zero times NaN is NaN
+    own = jnp.take_along_axis(tables, jnp.clip(starts // page_size, 0, width - 1)[:, None], axis=1)
+    tables = jnp.pad(tables, ((0, 0), (0, -width % pages)), constant_values=-1)
+    tables = jnp.where(tables < 0, jnp.maximum(own, 0), tables)
+    column = np.arange(rows * hkv, dtype=np.int32)  # constants of the program: nothing to compute a layer
+    cols = np.stack([column // hkv, column % hkv])  # a column's position in the block, and its kv head
+    row_head = (np.arange(hq, dtype=np.int32) // (hq // hkv))[:, None]  # a query head's kv head
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_lanes, tables.shape[1] // pages),
+        in_specs=[
+            pl.BlockSpec((None, hq, d), lambda lane, block, *_: (lane, 0, 0)),
+            pl.BlockSpec(cols.shape, lambda lane, block, *_: (0, 0)),
+            pl.BlockSpec(row_head.shape, lambda lane, block, *_: (0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((None, hq, d), lambda lane, block, *_: (lane, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, hkv, d), k_pool.dtype),
+            pltpu.VMEM((2, rows, hkv, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((hq, LANES), jnp.float32),
+            pltpu.VMEM((hq, LANES), jnp.float32),
+            pltpu.VMEM((hq, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, pages=pages, scale=scale, dot_in_f32=interpret),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_lanes, hq, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=4 * rows * hkv * d * itemsize + WALK_KERNEL_VMEM_SLACK_BYTES,
+        ),
+        interpret=interpret,
+        name="paged_decode_walk",
+    )(tables.reshape(-1), starts, ends, q[:, 0].astype(jnp.promote_types(q.dtype, k_pool.dtype)), cols, row_head, k_pool, v_pool)
+    return out[:, None]
+
+
 def composed_paged_attend(
     q, k_pool, v_pool, tables, *, q_offset, kv_length, alibi_slopes=None, sliding_window=None,
-    scale=None, causal: bool = True, logit_softcap=None,
+    scale=None, causal: bool = True, logit_softcap=None, path: Optional[str] = None,
 ):
     """The XLA-composed paged attention. A decode row (per-lane positions,
     one query row a lane, causal) walks its lane's pages in blocks of slots,
-    as they are stored, up to the longest live lane's last one
-    (``_walk_decode_rows``): what it reads follows the lanes' lengths, not the
+    as they are stored: what it reads follows the lanes' lengths, not the
     table's width, and agrees with the dense program to float32 rounding, not
-    to the bit. An idle lane (at the sentinel ``max_length``) counts as empty.
+    to the bit. On a TPU, over a plain pool of rows of ``[hkv, d]`` of whole
+    tiles, the walk is ONE kernel that reads each live lane's own pages where
+    they lie, to that lane's own end (``_walk_decode_rows_kernel``); everywhere
+    else, and as the kernel's reference, a ``fori_loop`` over blocks of every
+    lane up to the longest live lane's last one (``_walk_decode_rows``).
+    ``decode_walk_path`` says which from what the call shows; ``path`` names
+    one for a test (off the chip the kernel is interpreted). An idle lane (at
+    the sentinel ``max_length``) counts as empty.
     A prompt's chunk, a verify's rows and a non-causal call gather the lanes'
     pages into a dense view and run ``attend_reference`` over it. Under a
     static window either sees only the pages a lane's rows can reach: its
@@ -1066,8 +1335,21 @@ def composed_paged_attend(
         kv_length = jnp.asarray(kv_length, jnp.int32) - first * page_size
     if walk:
         kv_len = jnp.where(live, jnp.broadcast_to(jnp.asarray(kv_length, jnp.int32), (n_lanes,)), 0)
+        q_pos = jnp.asarray(q_offset, jnp.int32)
+        if path is None:
+            path = decode_walk_path(
+                k_pool, q.shape, tables.shape, alibi=alibi_slopes is not None, softcap=logit_softcap is not None, window=sliding_window
+            )
+        if path == "kernel":
+            pages = walk_kernel_block_pages(tables.shape[1], page_size, hkv, q.shape[-1], jnp.dtype(k_pool.dtype).itemsize)
+            # the scope holds the copies too; the kernel is ``paged_decode_walk`` in a trace
+            with jax.named_scope("ptu.attn.paged_decode"):
+                return _walk_decode_rows_kernel(
+                    q, k_pool, v_pool, tables, q_pos, kv_len, scale=float(q.shape[-1] ** -0.5 if scale is None else scale),
+                    sliding_window=sliding_window, pages=pages, interpret=not _on_tpu(),
+                )
         return _walk_decode_rows(
-            q, k_pool, v_pool, tables, q_pos=jnp.asarray(q_offset, jnp.int32), kv_len=kv_len,
+            q, k_pool, v_pool, tables, q_pos=q_pos, kv_len=kv_len,
             alibi_slopes=alibi_slopes, sliding_window=sliding_window, scale=scale, logit_softcap=logit_softcap,
         )
     k = gather_pages(k_pool, tables, hkv)
@@ -1113,8 +1395,6 @@ def maybe_autotune_paged_attention(
     if ("decode", *key) in _AUTOTUNE:
         return _AUTOTUNE[("decode", *key)]
     import time
-
-    import numpy as np
 
     from petals_tpu.ops.paged_attention import PagedPool, fold_rows, quantize_kv_rows, stored_row
 

@@ -892,43 +892,88 @@ class TransformerBackend:
         return sum(layers * window_pages(w, q_len, page_size, max_pages) for w, layers in self._window_layers)
 
     def decode_walks(self, n_lanes: int, max_pages: int, page_size: int) -> tuple:
-        """``((window, layers, block, cut), ...)``: how a decode step's
-        programs walk a lane pool's tables, a distinct window of the span's
-        layers: the block's width in slots
-        (ops/paged_flash_attention.py ``walk_block_pages``) and whether the
-        table row is first cut to the window's reach. Fixed with the pool's
-        geometry: the batcher asks once."""
-        from petals_tpu.ops.paged_flash_attention import walk_block_pages, window_pages
+        """``((window, layers, block, cut, path), ...)``: how a decode step's
+        programs walk a lane pool's tables, a distinct attention call of the
+        span's layers: which walk runs (ops/paged_flash_attention.py
+        ``decode_walk_path``: the kernel that reads each lane's own pages, or
+        the composed walk), the block's width in slots
+        (``walk_kernel_block_pages`` / ``walk_block_pages``) and whether the
+        table row is first cut to the window's reach. ``decode_walk_path`` is
+        asked here as ``composed_paged_attend`` asks it in the step: with the
+        pool's form as a step's attention is handed it
+        (``paged_cache_descriptors``) and with what the family says its
+        blocks hand their attention beside the plain call
+        (``ModelFamily.block_attention``). Fixed with the pool's geometry:
+        the batcher asks once."""
+        from petals_tpu.ops import paged_flash_attention as pfa
+        from petals_tpu.ops.paged_attention import PagedPool
 
         if self.latent_row is not None:  # ops/latent_attention.py ``decode_reads`` counts its own walk: ``latent_reads``
             return ()
-        itemsize = 2 if self.kv_quant_type != "none" else jnp.dtype(self.cache_dtype).itemsize  # a quantised pool reads as bf16
+        if self.index_row is not None and max_pages * page_size > self.index_keep:
+            return ()  # every decode row fetches the positions it chose, a row each (ops/sparse_attention.py): no walk runs
+        quantised = self.kv_quant_type != "none"
+        itemsize = 2 if quantised else jnp.dtype(self.cache_dtype).itemsize  # a quantised pool reads as bf16
+        hkv, d = self.num_kv_heads, self.head_dim
+        pool = jax.ShapeDtypeStruct((1, page_size, *self.pool_row), self.cache_dtype)
+        pool = PagedPool(pool, pool) if quantised else pool
+        extras = [self.family.attention_for(self.cfg, kind) for kind, _, length in self.runs for _ in range(length)]
+        windows = self.layer_windows if self.layer_windows is not None else self._static_windows() * self.n_blocks
         walks = []
-        for window, layers in self._window_layers:
-            width = window_pages(window, 1, page_size, max_pages)
-            block = walk_block_pages(n_lanes, width, page_size, self.num_kv_heads, self.head_dim, itemsize)
-            walks.append((window, layers, block, width < max_pages))
+        for (window, extra), layers in collections.Counter((windows[i], extras[i]) for i in self.kv_layers).items():
+            if "traced_window" in extra:  # the walk is handed an array: it cuts nothing and masks by it
+                window, handed = None, jax.ShapeDtypeStruct((), jnp.int32)
+            else:
+                handed = window
+            width = pfa.window_pages(window, 1, page_size, max_pages)
+            path = pfa.decode_walk_path(
+                pool, (n_lanes, 1, hkv, d), (n_lanes, width), alibi="alibi" in extra, softcap="softcap" in extra, window=handed
+            )
+            if path == "kernel":
+                block = pfa.walk_kernel_block_pages(width, page_size, hkv, d, itemsize)
+            else:
+                block = pfa.walk_block_pages(n_lanes, width, page_size, hkv, d, itemsize)
+            walks.append((window, layers, block, width < max_pages, path))
         return tuple(walks)
 
     @staticmethod
-    def pages_walked(walks: tuple, last: np.ndarray, page_size: int) -> int:
-        """Table slots a decode step's programs read a lane over the span's
-        layers, its live lanes at the positions ``last``: each layer walks
-        its table (the slots in its window's reach, if ``cut``) in blocks, up
-        to the block that holds the longest lane's last row
+    def pages_walked(walks: tuple, last: np.ndarray, page_size: int, n_lanes: int) -> tuple:
+        """``(read, by the kernel)``: table slots a decode step's programs
+        read over the span's layers and all lanes, its live lanes at the
+        positions ``last``, and those of them the kernel's grid fetched. Each
+        layer walks its table (the slots in its window's reach, if ``cut``) in
+        blocks: the composed walk every lane of the pool's up to the block
+        that holds the longest lane's last row, the kernel each live lane from
+        the block of its first position in sight to its own last one
         (ops/paged_flash_attention.py ``composed_paged_attend``, whose
-        arithmetic this is). For the batcher's ``attn_pages_gathered``: a
-        maximum over the lanes and integer arithmetic, a step."""
+        arithmetic this is). For the batcher's ``attn_pages_gathered`` /
+        ``attn_pages_kernel``, a step, on the host's serial part: a reduction
+        or two over the lanes and integer arithmetic a walk."""
         from petals_tpu.ops.paged_flash_attention import walk_pages
 
-        longest = int(last.max())
-        walked = 0
-        for window, layers, block, cut in walks:
-            needed = longest // page_size + 1
-            if cut:  # each lane's slots count from its own window's first one
-                needed = int(np.max(last // page_size - np.maximum(last - (window - 1), 0) // page_size)) + 1
-            walked += layers * walk_pages(needed, block)
-        return walked
+        read = by_kernel = 0
+        longest = -1
+        for window, layers, block, cut, path in walks:
+            if path == "kernel" and not window:
+                # a lane reads the blocks up to its last row's: ``last // (block * page_size) + 1`` of them
+                walked = layers * block * (int((last // (block * page_size)).sum()) + last.size)
+                by_kernel += walked
+            elif path == "kernel":
+                first = np.maximum(last - (window - 1), 0) // page_size  # a lane's first slot in sight
+                walked = walk_pages(last // page_size + 1 - (first if cut else 0), block)  # as the walk is handed its table
+                if not cut:
+                    walked = walked - first // block * block  # whole blocks before the window's reach
+                walked = layers * int(walked.sum())
+                by_kernel += walked
+            else:
+                if longest < 0:
+                    longest = int(last.max())
+                needed = longest // page_size + 1
+                if cut:  # each lane's slots count from its own window's first one
+                    needed = int(np.max(last // page_size - np.maximum(last - (window - 1), 0) // page_size)) + 1
+                walked = layers * n_lanes * walk_pages(needed, block)
+            read += walked
+        return read, by_kernel
 
     def _paged_kernel_path(self, k_pool, tables, *, mixed: bool = False) -> str:
         """Resolve (host-side, O(1) — no table scan) which attention path the

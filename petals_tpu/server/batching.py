@@ -476,7 +476,9 @@ class DecodeBatcher:
         # are handed; and, for a family that declares its layers' windows only, of the pages the decoding
         # lanes hold in windowed layers those their windows still reach (summed over steps)
         if self.page_size:
-            self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0)
+            # attn_pages_kernel: of the slots read, those the decode walk's kernel fetched, each live lane to its own
+            # end (0 where the composed walk runs: ``backend.decode_walks`` says which)
+            self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0, attn_pages_kernel=0)
             self._walks = backend.decode_walks(n_lanes, self.max_pages, self.page_size)
         self._windows = [w for w in (getattr(backend, "layer_windows", None) or ()) if w] if self.page_size else []
         if self._windows:
@@ -1489,6 +1491,8 @@ class DecodeBatcher:
             info["kv_quant"] = getattr(self.backend, "kv_quant_type", "none")
             # the trailing dims the pool keeps a token row in: (hkv, d_store), or folded to one (a row under 128 lanes)
             info["pool_row"] = list(getattr(self.backend, "pool_row", ()))
+            # which walk a decode row's attention takes over these pages, a distinct window of the span's layers
+            info["decode_walk"] = [walk[-1] for walk in self._walks]
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
             if self._n_index:  # of kv_bytes_per_token, the index rows' part
                 info["index_bytes_per_token"] = int(self.backend.index_bytes_per_token())
@@ -2309,15 +2313,17 @@ class DecodeBatcher:
 
         backend, layers = self.backend, len(self.backend.kv_layers)
         last = positions[positions < self.max_length] + (seq - 1)  # the idle sentinel is max_length
+        by_kernel = 0
         if seq > 1:
-            read = backend.pages_gathered(seq, self.max_pages, self.page_size)
-        elif self._selects or self._latent:
+            read = self.n_lanes * backend.pages_gathered(seq, self.max_pages, self.page_size)
+        elif self._selects or self._latent or not last.size:
             # the chosen positions' rows are fetched one by one, a latent row's walk counts itself (each lane to its own
             # end where the kernel runs): ``_count_sparse`` / ``_count_latent`` add their pages' worth
             read = 0
         else:
-            read = backend.pages_walked(self._walks, last, self.page_size) if last.size else 0
-        self.stats["attn_pages_gathered"] += self.n_lanes * read
+            read, by_kernel = backend.pages_walked(self._walks, last, self.page_size, self.n_lanes)
+        self.stats["attn_pages_gathered"] += read
+        self.stats["attn_pages_kernel"] += by_kernel
         self.stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
         if chunk is not None:
             lane, first, take = chunk

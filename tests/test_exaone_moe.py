@@ -400,41 +400,70 @@ def backend_reach(batcher) -> int:
     return batcher.n_lanes * batcher.backend.pages_gathered(1, batcher.max_pages, batcher.page_size)
 
 
-def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monkeypatch):
+@pytest.mark.parametrize("path", ["composed", "kernel"])
+def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monkeypatch, path):
     """Every family on the paged pool counts the table slots its steps read
     against those they are handed; the pages a window still reaches are
     counted for a family that declares windows alone. A decode step's count
-    follows the longest LIVE lane, in whole blocks of the walk."""
+    follows the walk that runs (``backend.decode_walks``): the composed walk
+    reads every lane to the longest LIVE lane's block, the kernel each live
+    lane to its own, and ``attn_pages_kernel`` says how many of the slots read
+    the kernel fetched."""
     from petals_tpu.ops import paged_flash_attention as pfa
     from petals_tpu.server.batching import DecodeBatcher
     from petals_tpu.server.task_queue import PriorityTaskQueue
 
-    read, held = {"attn_pages_gathered", "attn_pages_tabled"}, {"window_pages_held", "window_pages_in_reach"}
-    path = make_tiny_falcon(str(tmp_path))
-    family, cfg = get_block_config(path)
-    stacked = jax.tree_util.tree_map(lambda leaf: leaf[None], load_block_params(path, 0, dtype=jnp.float32))
+    read, held = {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel"}, {"window_pages_held", "window_pages_in_reach"}
+    if path == "kernel":  # a pool the kernel takes, 8 kv heads of 128 at float32, on a backend that says it is a TPU
+        monkeypatch.setattr(pfa, "_on_tpu", lambda: True)
+        model = make_tiny_falcon(str(tmp_path), n_layers=1, head_dim=128, heads=8, kv_heads=8)
+    else:
+        model = make_tiny_falcon(str(tmp_path))
+    family, cfg = get_block_config(model)
+    stacked = jax.tree_util.tree_map(lambda leaf: leaf[None], load_block_params(model, 0, dtype=jnp.float32))
     backend = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=1, memory_cache=MemoryCache(None),
                                  compute_dtype=jnp.float32, use_flash=False)
-    # two lanes' pages of 16 rows at float32, one slot of the table a block (the batcher asks at its start)
-    monkeypatch.setattr(pfa, "WALK_BLOCK_BYTES", 2 * 16 * backend.num_kv_heads * backend.head_dim * 4)
+    # one slot of the table a block, of two lanes' pages of 16 rows at float32 or of one's (the batcher asks at its start)
+    a_page = 16 * backend.num_kv_heads * backend.head_dim * 4
+    monkeypatch.setattr(pfa, "WALK_BLOCK_BYTES", 2 * a_page)
+    monkeypatch.setattr(pfa, "WALK_KERNEL_BLOCK_BYTES", a_page)
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
     assert read <= set(batcher.stats) and not held & set(batcher.stats) and backend.layer_windows is None and len(backend.runs) == 1
     assert "moe_weight_passes" not in batcher.stats and not held & set(batcher.occupancy_info())
-    assert batcher._walks == ((None, 1, 1, False),)
+    assert batcher._walks == ((None, 1, 1, False, path),) and batcher.occupancy_info()["decode_walk"] == [path]
     tables = np.zeros((2, 4), np.int32)
-    for positions, walked in (([5, 64], 1), ([64, 16], 2), ([47, 0], 3), ([64, 64], 0), ([63, 64], 4)):
+    # the composed walk: both lanes to the longest live one's page; the kernel: each live lane to its own
+    for positions, walked, own in (([5, 64], 1, 1), ([64, 16], 2, 2), ([47, 0], 3, 4), ([64, 64], 0, 0), ([63, 64], 4, 4), ([63, 17], 4, 6)):
         was = dict(batcher.stats)
         batcher._count_window(tables, np.asarray(positions, np.int32))
-        assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == 2 * walked, positions  # an idle lane is no length
+        want = own if path == "kernel" else 2 * walked  # an idle lane is no length
+        assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == want, positions
+        assert batcher.stats["attn_pages_kernel"] - was["attn_pages_kernel"] == (want if path == "kernel" else 0), positions
         assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4
     was = dict(batcher.stats)
     batcher._count_window(tables, np.asarray([64, 3], np.int32), chunk=(0, 16, 20))  # a chunk gathers its lane's whole row
-    assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == 2 * 1 + 4
+    assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == (1 if path == "kernel" else 2 * 1) + 4
+    assert batcher.stats["attn_pages_kernel"] - was["attn_pages_kernel"] == (1 if path == "kernel" else 0)  # the chunk's gather is no walk
     assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4 + 4
+    # under a static window of 128 (pages of 64, blocks of 2 slots, lanes at 300 and 10): the table cut to its reach, or whole
+    last = np.asarray([300, 10])
+    for cut, want in ((True, 4 + 2), (False, (6 - 2) + 2)):  # the kernel skips the whole blocks before a lane's first position in sight
+        assert backend.pages_walked(((128, 1, 2, cut, "kernel"),), last, 64, 2) == (want, want)
+    assert backend.pages_walked(((128, 1, 2, True, "composed"),), last, 64, 2) == (2 * 4, 0)
+    if path == "kernel":
+        # the same pool under the RW generation's ALiBi bias: the family says what its blocks hand their attention
+        # (ModelFamily.block_attention), the kernel knows no bias, and the counters say so
+        model = make_tiny_falcon(str(tmp_path), variant="rw", n_layers=1, head_dim=128, heads=8, kv_heads=8)
+        family, cfg = get_block_config(model)
+        stacked = jax.tree_util.tree_map(lambda leaf: leaf[None], load_block_params(model, 0, dtype=jnp.float32))
+        biased = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=1, memory_cache=MemoryCache(None),
+                                    compute_dtype=jnp.float32, use_flash=False)
+        assert biased.pool_row == backend.pool_row and biased.decode_walks(2, 4, 16) == ((None, 1, 1, False, "composed"),)
     monkeypatch.undo()
     exaone = whole_backend(make_tiny_exaone_moe(str(tmp_path)))
     batcher = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
     assert read | held | {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"} <= set(batcher.stats)
+    assert batcher.occupancy_info()["decode_walk"] == ["composed"] * len(batcher._walks)  # off the chip
     dense_pool = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=None)
     assert not (read | held) & set(dense_pool.stats)  # the counters count pages: the paged pool only
 

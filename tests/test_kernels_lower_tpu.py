@@ -293,7 +293,7 @@ STEP_CASES = [
 ]
 
 
-def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none", latent_kernel=True):
+def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none", latent_kernel=True, walk_kernel=True):
     """``(optimized HLO, stacked params, pool aval, (hkv, d))`` of
     ``TransformerBackend``'s paged decode step, or of its mixed step with a
     prompt chunk of ``chunk`` riding it, at a cell's widths and depth (8 lanes,
@@ -301,7 +301,8 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     the pool, pools donated, in the form the backend's descriptors give them:
     a head_dim of 64 folded to rows of ``hkv * d``), compiled for the v5e. Under
     ``kv_quant`` the pools are ``PagedPool``s and the aval returned is their codes'.
-    ``latent_kernel`` False: a latent row's decode walk as off the chip, composed."""
+    ``latent_kernel`` False: a latent row's decode walk as off the chip, composed;
+    ``walk_kernel`` False: a decode row's walk over plain pages likewise."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -341,6 +342,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
         patch.setattr("petals_tpu.ops.latent_attention._on_tpu", lambda: latent_kernel)  # as would the latent decode walk's kernel
+        patch.setattr(pfa, "_on_tpu", lambda: walk_kernel)  # and the plain pages' decode walk's
         hlo = jax.jit(step, donate_argnums=donated).lower(*avals).compile().as_text()
     return hlo, runs, pool, (backend.num_kv_heads, backend.head_dim)
 
@@ -589,8 +591,10 @@ def test_paged_step_leaves_the_state_pool_and_its_pages_in_place(v5e, tmp_path, 
     for what, elements in (("state", 12 * 8 * 30 * 96 * 192), ("page", math.prod(pool.shape))):
         assert any(math.prod(dims) == elements for _, dims, _, _ in comps[entry]), f"the {what} pool was not found in ENTRY"
         moved = [f"%{name} = {op}" for name, dims, op, rest in comps[entry] if math.prod(dims) == elements
-                 and (op == "copy" or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
+                 and (op in ("copy", "copy-start") or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
         assert not moved, f"the step moves the {what} pool: {moved}"
+    # the decode rows' walk is a kernel that reads the pools the chunk's scatter writes (PR 45): each full layer holds it once
+    assert len(decode_walk_calls(hlo, "paged_decode_walk")) == 4
 
 
 SPARSE_CASES = [pytest.param(0, id="decode"), pytest.param(2048, id="mixed-2048")]
@@ -773,16 +777,16 @@ def test_latent_step_leaves_both_pools_in_place_and_reads_its_weights_where_they
     assert not moved, f"the step relays a weight of the dense run of one block in every step: {moved}"
 
 
-def latent_walk_calls(hlo: str) -> list:
-    """``[(computation, op_name, [operand dims])]`` of every call of the
-    latent decode walk's kernel (ops/latent_attention.py, named
-    ``latent_decode_walk``) in an optimized HLO module, an operand traced
-    through bitcasts to what it is a view of."""
+def decode_walk_calls(hlo: str, kernel: str = "latent_decode_walk") -> list:
+    """``[(computation, op_name, [operand dims])]`` of every call of a decode
+    walk's kernel (ops/latent_attention.py's, named ``latent_decode_walk``;
+    ops/paged_flash_attention.py's ``paged_decode_walk``) in an optimized HLO
+    module, an operand traced through bitcasts to what it is a view of."""
     comps, calls = _computations(hlo), []
     for computation, instructions in comps.items():
         by_name = {name: (op, dims, rest) for name, dims, op, rest in instructions}
         for name, _, op, rest in instructions:
-            if op == "custom-call" and name.startswith("latent_decode_walk") and "tpu_custom_call" in rest:
+            if op == "custom-call" and name.startswith(kernel) and "tpu_custom_call" in rest:
                 operands = []
                 for operand in re.findall(r"%([\w.\-]+)", rest.split("), custom_call_target")[0]):
                     while by_name[operand][0] == "bitcast":
@@ -830,7 +834,7 @@ def test_latent_decode_step_makes_no_view_of_the_tables_and_expands_no_lane_s_ke
     ``f32[8,32,4096]``), and no key or value of any head. The composed walk's
     step, compiled beside it, shows each of the three: the guard can fail."""
     hlo, runs, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 0, pages_a_lane=512)
-    calls = latent_walk_calls(hlo)
+    calls = decode_walk_calls(hlo)
     assert len(calls) == len(runs) == 2, calls
     for computation, op_name, operands in calls:
         assert "ptu.attn.latent_decode" in op_name, op_name
@@ -845,7 +849,7 @@ def test_latent_decode_step_makes_no_view_of_the_tables_and_expands_no_lane_s_ke
     assert not found["expanded"], f"the decode step expands keys or values: {found['expanded']}"
     composed, _, _, _ = _compiled_step(v5e, tmp_path, LATENT_CONFIG, 0, pages_a_lane=512, latent_kernel=False)
     was = latent_decode_findings(composed, runs)
-    assert not latent_walk_calls(composed) and was["pools"] and was["rows"] and was["scores"], was
+    assert not decode_walk_calls(composed) and was["pools"] and was["rows"] and was["scores"], was
 
 
 def test_latent_decode_kernel_lowers_at_the_published_shapes(v5e):
@@ -892,3 +896,90 @@ def test_latent_mixed_step_expands_a_block_of_positions_at_a_time_and_holds_no_w
     assert scores, "a block's scores, [32, 2048, 128], were not found"
     assert not whole, f"the mixed step holds a chunk's scores against more than a block of positions: {whole}"
     assert not lane_wide, f"the mixed step expands more than a chunk's rows or a block's positions: {lane_wide}"
+
+
+# ---------------------------------------------------------------- a decode row's walk over plain pages, as one kernel
+
+# hq, hkv, table slots a lane as the walk is handed them, window, dtype. The first two are the pools that configurations
+# store and the kernel takes (Olmo-Hybrid's, OLMoE's); the next two its query groups and its window at a shape it takes;
+# the float32 ones are NO configuration's (Mixtral's and K-EXAONE's 8 kv heads of 128 are stored in bfloat16, half a
+# tile, and refused: the next test): a float32 pool is what tests and a float32 server store; the last is the widest
+# table the predicate lets through (``WALK_KERNEL_TABLE_BYTES``: 8 lanes of a million positions)
+WALK_KERNEL_SHAPES = [
+    pytest.param(32, 32, 40, None, BF16, id="olmo-hybrid-32x128-over-40"),
+    pytest.param(16, 16, 16, None, BF16, id="olmoe-16x128-over-16"),
+    pytest.param(64, 16, 16, None, BF16, id="16x128-4-query-heads-a-kv-head"),
+    pytest.param(64, 16, 16, 128, BF16, id="16x128-window-128"),
+    pytest.param(32, 8, 16, None, F32, id="float32-8x128-4-query-heads-a-kv-head"),
+    pytest.param(64, 8, 16, 128, F32, id="float32-8x128-8-query-heads-a-kv-head-window-128"),
+    pytest.param(16, 16, pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8), None, BF16, id="16x128-tables-at-the-scalar-memory-budget"),
+]
+
+
+@pytest.mark.parametrize("hq,hkv,slots,window,dtype", WALK_KERNEL_SHAPES)
+def test_paged_decode_walk_kernel_lowers_at_the_shapes_it_takes(v5e, hq, hkv, slots, window, dtype):
+    """The walk's kernel alone (ops/paged_flash_attention.py ``_walk_kernel``),
+    through Pallas -> Mosaic -> libtpu for the v5e: 8 lanes, pages of 64, the
+    span's pools of 5 x 8 x ``slots`` pages of ``[64, hkv, 128]`` handed whole
+    (at most 1,600 pages: the widest table's pool would not fit the chip);
+    under the window the table is cut to the 3 slots in reach first. One slot
+    a lane over the widest table is the predicate's to refuse: its budget is
+    the largest that was seen to compile."""
+    def walk(q, k_pool, v_pool, tables, positions):
+        return pfa.composed_paged_attend(q, k_pool, v_pool, tables, q_offset=positions, kv_length=positions + 1, sliding_window=window, path="kernel")
+
+    pool = v5e((5 * 8 * min(slots, 40), 64, hkv, 128), dtype)
+    avals = (v5e((8, 1, hq, 128), dtype), pool, pool, v5e((8, slots), I32), v5e((8,), I32))
+    reach = pfa.window_pages(window, 1, 64, slots)
+    assert pfa.walk_kernel_unsupported(pool, avals[0].shape, (8, reach), window=window) is None
+    assert "scalar memory" in pfa.walk_kernel_unsupported(pool, avals[0].shape, (8, pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8) + 1), window=window)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pfa, "_on_tpu", lambda: True)  # the backend here is the CPU: the kernel would be interpreted
+        _compile(walk, *avals)
+        body = str(jax.make_jaxpr(walk)(*avals))
+    # the copies are a loop and the waits one a pool (ops/latent_attention.py, PR 43: written out they were seconds of every start)
+    assert body.count("dma_start") == 4 and body.count("dma_wait") == 2, (body.count("dma_start"), body.count("dma_wait"))
+
+
+@pytest.mark.parametrize("config_name,pages_a_lane,calls_in", [("olmo-hybrid-7b-span16", 40, "ENTRY"), ("olmoe-1b-7b-span8", 16, "loop")])
+def test_decode_step_walks_each_lane_s_pages_in_one_kernel_a_layer(v5e, tmp_path, config_name, pages_a_lane, calls_in):
+    """A decode row's attention over plain pages of head_dim 128 is ONE kernel
+    a layer (ops/paged_flash_attention.py ``composed_paged_attend``): the
+    compiled decode step holds one ``tpu_custom_call`` under
+    ``ptu.attn.paged_decode`` a run of full layers (OLMoE's eight are one loop;
+    Olmo-Hybrid's four runs of one block are unrolled into ``ENTRY``), handed
+    the two whole-span pools as the loop carries them and the new rows'
+    scatter leaves them; there is no gathered block of a page a lane (``[8,
+    64, hkv, 128]``: the composed walk made two a trip and float32 products of
+    each) and no copy of a pool. The composed step, compiled beside it, shows
+    the blocks: the guard can fail."""
+    hlo, runs, pool, (hkv, d) = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane)
+    calls = decode_walk_calls(hlo, "paged_decode_walk")
+    assert len(calls) == (4 if calls_in == "ENTRY" else 1), calls
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    for computation, op_name, operands in calls:
+        assert "ptu.attn.paged_decode" in op_name and (computation == entry) == (calls_in == "ENTRY"), (computation, op_name)
+        pools = [(op, fused, dims) for op, fused, dims in operands if math.prod(dims) == math.prod(pool.shape)]
+        assert len(pools) == 2, operands
+        for op, fused, dims in pools:  # as the loop carries it, or as the scatter of the new rows (in place: the pools' own test) left it
+            assert op in ("get-tuple-element", "parameter") or (fused is not None and any(i[2] in ("dynamic-update-slice", "scatter") for i in fused)), (op, dims)
+
+    def blocks(text):  # a page a lane of all kv heads in memory, in any dtype
+        return [f"%{name} = {op}" for _, name, dims, op, _ in _arrays_in_memory(_computations(text)) if dims == (8, 64, hkv, d)]
+
+    moves, _ = pool_moves(hlo, tuple(pool.shape), (hkv, d))
+    assert not blocks(hlo) and not moves, (blocks(hlo), moves)
+    composed, _, _, _ = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane, walk_kernel=False)
+    assert not decode_walk_calls(composed, "paged_decode_walk") and blocks(composed)
+
+
+def test_a_folded_pool_s_decode_step_is_the_composed_walk_s_whatever_the_backend(v5e, tmp_path):
+    """Falcon's pool (head_dim 64, stored folded) is not the kernel's: the
+    decode step compiled where the kernel may run is the step compiled where it
+    may not, instruction for instruction."""
+    def program(walk_kernel):  # every computation's instructions, without the source lines they were traced from
+        hlo, _, _, _ = _compiled_step(v5e, tmp_path, "falcon-40b-span5", 0, walk_kernel=walk_kernel)
+        return {name: [(i[0], i[1], i[2], re.sub(r", metadata=\{[^}]*\}", "", i[3])) for i in instructions] for name, instructions in _computations(hlo).items()}
+
+    on, off = program(True), program(False)
+    assert len(on) > 10 and "paged_decode_walk" not in str(on) and on == off

@@ -591,7 +591,7 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     assert [d.shape for d in backend.cache_descriptors(3, 24, 0, 2)] == [(2, 3, 24, backend.num_kv_heads, backend.head_dim)] * 2
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
     # since PR 36 every family on the paged pool counts the table slots its steps read (_count_window)
-    assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled"} and batcher._n_state == 0 and batcher._state() == ()
+    assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel"} and batcher._n_state == 0 and batcher._state() == ()
     assert not {"state_bytes_per_lane", "state_bytes_held"} & set(batcher.occupancy_info())
     # the step programs take the pair of pools and give the pair back, and carry what they carried
     k, v = (jnp.zeros(d.shape, d.dtype) for d in backend.paged_cache_descriptors(6, 8, 0, 2))

@@ -269,6 +269,213 @@ def test_walk_block_follows_the_shapes_it_sees():
     assert pfa.walk_pages(36, 4) == 36 and pfa.walk_pages(6, 8) == 8 and pfa.walk_pages(0, 8) == 0 and pfa.walk_pages(37, 4) == 40
 
 
+# ------------------------------------------------- the walk as one kernel
+
+# n_lanes 4, pages of 16 rows, head_dim 128; a lane's position, or None for an idle lane. ``block``: table slots of one
+# lane a grid step takes (the cases set the bytes the rule goes by)
+KERNEL_WALK_CASES = {
+    "lanes-shorter-than-a-block": dict(max_pages=8, block=4, positions=[5, 20, 40, 0]),
+    "ending-on-a-block-s-and-on-a-page-s-last-position": dict(max_pages=8, block=2, positions=[31, 15, 63, 47]),
+    "an-idle-lane-among-live-ones": dict(max_pages=8, block=2, positions=[50, None, 9, 100]),
+    "no-live-lane": dict(max_pages=8, block=2, positions=[None, None, None, None]),
+    "blocks-that-do-not-divide-the-table": dict(max_pages=10, block=4, positions=[159, 31, 32, None]),
+    "window128-table-cut-to-its-reach": dict(max_pages=16, block=2, window=128, positions=[255, 10, 130, None]),
+    "window128-whole-table-blocks-before-its-reach": dict(max_pages=9, block=1, window=128, positions=[143, 130, 20, None]),
+    "4-query-heads-a-kv-head": dict(max_pages=8, block=2, group=4, positions=[100, 3, None, 77]),
+    "8-query-heads-a-kv-head": dict(max_pages=8, block=4, group=8, positions=[127, 64, 1, None]),
+    "32-kv-heads": dict(max_pages=8, block=2, hkv=32, positions=[90, None, 33, 8]),
+    "float32-pool": dict(max_pages=8, block=2, hkv=8, dtype="float32", positions=[100, 3, None, 77]),
+}
+
+
+def _numpy_decode_rows(q, kp, vp, tables, positions, idle, window, ps):
+    """One query row a lane over its table's pages in float32 NumPy: the
+    softmax over the positions in sight whole, no blocks."""
+    q, kp, vp = (np.asarray(a, np.float32) for a in (q, kp, vp))
+    n_lanes, _, hq, d = q.shape
+    group = hq // kp.shape[2]
+    out = np.zeros((n_lanes, 1, hq, d), np.float32)
+    for lane in np.flatnonzero(~idle):
+        at = np.arange(0 if window is None else max(positions[lane] - window + 1, 0), positions[lane] + 1)
+        pages = tables[lane, at // ps]
+        assert (pages >= 0).all()
+        k, v = kp[pages, at % ps], vp[pages, at % ps]  # [positions, hkv, d]
+        s = np.einsum("kgd,skd->kgs", q[lane, 0].reshape(-1, group, d), k) * d**-0.5
+        p = np.exp(s - s.max(axis=-1, keepdims=True))
+        out[lane, 0] = np.einsum("kgs,skd->kgd", p / p.sum(axis=-1, keepdims=True), v).reshape(hq, d)
+    return out
+
+
+@pytest.mark.parametrize("case", KERNEL_WALK_CASES.values(), ids=KERNEL_WALK_CASES.keys())
+def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer(case, monkeypatch):
+    """``composed_paged_attend(path="kernel")`` (interpreted off the chip) for
+    a decode row against float32 NumPy over permuted tables: every page nobody
+    owns, page 0 among them, and every slot past a lane's own last page's
+    block, holds NaN, so a block read past a lane's end, or a hole read from a
+    page of somebody else's, shows (a weight of zero times NaN is NaN). The
+    composed walk, handed the same call with NaN out of its reach, agrees."""
+    n_lanes, ps, d = 4, 16, 128
+    max_pages, block, group, hkv = case["max_pages"], case["block"], case.get("group", 1), case.get("hkv", 16)
+    dtype, window = jnp.dtype(case.get("dtype", "bfloat16")), case.get("window")
+    rng = np.random.default_rng(17)
+    n_pages = n_lanes * max_pages + 8
+    kp, vp = (jnp.asarray(rng.standard_normal((n_pages, ps, hkv, d)), dtype) for _ in range(2))
+    idle = np.asarray([p is None for p in case["positions"]])
+    pos = np.asarray([max_pages * ps if p is None else p for p in case["positions"]], np.int32)
+    held = np.where(idle, 0, pos // ps + 1)
+    owned = rng.permutation(np.arange(1, n_pages)).astype(np.int32)[: n_lanes * max_pages].reshape(n_lanes, max_pages)  # page 0 is nobody's
+    tables = np.where(np.arange(max_pages)[None, :] < held[:, None], owned, -1).astype(np.int32)
+    nobody_s = np.setdiff1d(np.arange(n_pages), tables[tables >= 0])
+    kp, vp = kp.at[nobody_s].set(jnp.nan), vp.at[nobody_s].set(jnp.nan)
+    past = tables.copy()  # every slot past the block of a lane's own last page points at a page of NaN
+    for lane in range(n_lanes):
+        past[lane, -(-held[lane] // block) * block:] = nobody_s[-1]
+    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hkv * group, d)), dtype)
+    kw = dict(q_offset=jnp.asarray(pos), kv_length=jnp.asarray(pos) + 1, sliding_window=window)
+
+    monkeypatch.setattr(pfa, "WALK_KERNEL_BLOCK_BYTES", block * ps * hkv * d * dtype.itemsize)
+    width = pfa.window_pages(window, 1, ps, max_pages)
+    assert pfa.walk_kernel_block_pages(width, ps, hkv, d, dtype.itemsize) == min(block, width)
+    assert pfa.walk_kernel_unsupported(kp, q.shape, (n_lanes, width), window=window) is None
+    cut = width < max_pages  # each lane's row is then cut to its own reach first: the slots past it are never handed over
+    got = np.asarray(pfa.composed_paged_attend(q, kp, vp, jnp.asarray(tables if cut else past), path="kernel", **kw), np.float32)
+    want = _numpy_decode_rows(q, kp, vp, tables, pos, idle, window, ps)
+    assert np.isfinite(got).all(), "the kernel read a block past a lane's own end, or a page nobody owns"
+    tol = TOL if dtype == jnp.float32 else 2e-2  # bfloat16: the weights meet V in V's dtype, the answer is rounded to it
+    np.testing.assert_allclose(got[~idle], want[~idle], atol=tol, rtol=0)
+    np.testing.assert_array_equal(got[idle], 0.0)
+    composed = np.asarray(pfa.composed_paged_attend(q, kp.at[nobody_s].set(0), vp.at[nobody_s].set(0), jnp.asarray(tables), path="composed", **kw), np.float32)
+    np.testing.assert_allclose(got, composed, atol=tol, rtol=0)
+
+
+def _pool_like(shape, dtype=jnp.bfloat16):
+    import jax
+
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+WALK_PATH_CASES = {
+    "plain-rows-of-hkv-d": (dict(), None),
+    "float32": (dict(pool=_pool_like((9, 64, 8, 128), jnp.float32)), None),
+    "static-window": (dict(window=128), None),
+    "folded-pool": (dict(pool=_pool_like((9, 64, 8 * 64)), q=(8, 1, 128, 64)), "folded"),
+    "quantised-pool": (dict(quantised=True), "quantised"),
+    "head-dim-64-unfolded": (dict(pool=_pool_like((9, 64, 16, 64)), q=(8, 1, 16, 64)), "head_dim"),
+    "float16": (dict(pool=_pool_like((9, 64, 16, 128), jnp.float16)), "float16"),
+    "pages-of-8-rows": (dict(pool=_pool_like((9, 8, 16, 128))), "sublanes"),
+    "8-kv-heads-of-bfloat16": (dict(pool=_pool_like((9, 64, 8, 128)), q=(8, 1, 32, 128)), "sublanes"),  # half a tile: the compiled step copies the pool
+    "alibi": (dict(alibi=True), "ALiBi"),
+    "soft-cap": (dict(softcap=True), "soft cap"),
+    "traced-window": (dict(window="traced"), "traced window"),
+    "two-query-rows": (dict(q=(8, 2, 16, 128)), "query rows"),
+    "tables-at-the-scalar-memory-budget": (dict(tables=(8, (512 << 10) // (4 * 8))), None),
+    "tables-over-the-scalar-memory-budget": (dict(tables=(64, 4096)), "scalar memory"),  # 1 MiB: all the v5e has
+}
+
+
+@pytest.mark.parametrize("case", WALK_PATH_CASES.values(), ids=WALK_PATH_CASES.keys())
+def test_decode_walk_path_follows_what_the_call_shows(case, monkeypatch):
+    """Which walk a decode row takes is a static function of the pool's stored
+    form and the call (``decode_walk_path``): the kernel on a TPU backend for a
+    plain pool of rows of ``[hkv, d]`` of whole tiles under the walk's own
+    masks, the composed walk for everything else and everywhere off the
+    chip."""
+    import jax
+
+    from petals_tpu.ops.paged_attention import PagedPool
+
+    kw, why = case
+    pool = kw.get("pool", _pool_like((9, 64, 16, 128)))
+    if kw.get("quantised"):
+        pool = PagedPool(_pool_like((9, 64, 16, 128), jnp.int8), _pool_like((9, 64, 16), jnp.float32))
+    window = jax.numpy.int32(20) if kw.get("window") == "traced" else kw.get("window")
+    args = dict(alibi=kw.get("alibi", False), softcap=kw.get("softcap", False), window=window)
+    q_shape = kw.get("q", (8, 1, 16, 128))
+    tables_shape = kw.get("tables", (8, 40))
+    reason = pfa.walk_kernel_unsupported(pool, q_shape, tables_shape, **args)
+    assert (reason is None) == (why is None) and (why is None or why in reason), reason
+    assert pfa.decode_walk_path(pool, q_shape, tables_shape, **args) == "composed"  # this backend is no TPU
+    monkeypatch.setattr(pfa, "_on_tpu", lambda: True)
+    assert pfa.decode_walk_path(pool, q_shape, tables_shape, **args) == ("kernel" if why is None else "composed")
+
+
+def _tiny_families() -> dict:
+    """A builder of a toy checkpoint directory a registered family (and a
+    second one where a family's attention differs by its configuration)."""
+    from tests import utils
+
+    return {
+        "llama": utils.make_tiny_llama, "mistral": utils.make_tiny_mistral, "qwen2": utils.make_tiny_qwen2, "phi3": utils.make_tiny_phi3,
+        "gemma": utils.make_tiny_gemma, "gemma2": utils.make_tiny_gemma2, "bloom": utils.make_tiny_bloom, "falcon": utils.make_tiny_falcon,
+        "falcon-rw": lambda tmp: utils.make_tiny_falcon(tmp, variant="rw"), "mixtral": utils.make_tiny_mixtral, "olmoe": utils.make_tiny_olmoe,
+        "exaone_moe": utils.make_tiny_exaone_moe, "olmo_hybrid": utils.make_tiny_olmo_hybrid, "KeyeVL2": utils.make_tiny_keye_vl2,
+        "KeyeVL2-table-of-one-page": utils.make_tiny_keye_vl2,  # 16 positions, as many as a row chooses: the plain call
+        "deepseek_v3": utils.make_tiny_deepseek_v3,
+    }
+
+
+def test_every_registered_family_has_a_case_of_the_attention_s_guard():
+    from petals_tpu.models.registry import known_families
+
+    assert {name.split("-")[0] for name in _tiny_families()} == set(known_families()), "a new family: give the next test a toy of it"
+
+
+@pytest.mark.parametrize("name", _tiny_families())
+def test_the_decode_walk_the_backend_counts_is_the_one_its_step_is_handed(name, tmp_path, monkeypatch):
+    """``backend.decode_walks`` asks ``decode_walk_path`` with what the family
+    DECLARES its blocks hand their attention (``ModelFamily.block_attention``,
+    ``block_window``) and the pool's form out of the descriptors; the step
+    asks it with what the blocks DO hand over, at trace time. Both askings are
+    recorded here, over a whole toy model's decode step on a backend that says
+    it is a TPU: they have to be the same calls, whatever the family: its
+    ALiBi bias, its soft cap, a window that is an array, a static one's cut, a
+    folded pool. The counters that say which walk ran rest on nothing else."""
+    import functools
+
+    import jax
+
+    from petals_tpu.models.registry import span_runs
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.from_pretrained import get_block_config
+
+    family, cfg = get_block_config(_tiny_families()[name](str(tmp_path)))
+    depth, lanes, slots, page_size = cfg.num_hidden_layers, 2, 1 if name.endswith("one-page") else 4, 16
+    aval = jax.ShapeDtypeStruct
+    runs = tuple(
+        {leaf: aval((length, *a.shape), a.dtype) for leaf, a in family.param_shapes_for(cfg, kind, jnp.float32).items()}
+        for kind, _, length in span_runs(family.span_kinds(cfg, 0, depth))
+    )
+    backend = TransformerBackend(family, cfg, runs[0] if len(runs) == 1 else runs, first_block=0, n_blocks=depth, memory_cache=None,
+                                 compute_dtype=jnp.float32, use_flash=False)
+    asked = []
+
+    def recorded(pool, q_shape, tables_shape, *, alibi, softcap, window):
+        window = window if window is None or isinstance(window, int) else "an array"
+        asked.append((type(pool).__name__ == "PagedPool", tuple(pool.shape[1:]), str(pool.dtype), q_shape[0], q_shape[1], q_shape[3],
+                      tuple(tables_shape), alibi, softcap, window))
+        return "composed"
+
+    monkeypatch.setattr(pfa, "_on_tpu", lambda: True)
+    monkeypatch.setattr(pfa, "decode_walk_path", recorded)
+    declared = backend.decode_walks(lanes, slots, page_size)
+    counted, asked[:] = set(asked), []
+    assert len(declared) == len(counted)
+
+    descs = backend.paged_cache_descriptors(lanes * slots, page_size, 0, depth)
+    pools = [aval(d.shape, d.dtype) for d in descs[:2]]
+    avals = [backend.params, *pools, aval((lanes, 1, cfg.hidden_size), jnp.float32), aval((lanes,), jnp.int32), aval((lanes, slots), jnp.int32)]
+    if backend.state_layers:
+        avals.append(tuple(aval(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
+    if backend.index_row is not None:
+        avals.append(tuple(aval(d.shape, d.dtype) for d in backend.index_cache_descriptors(lanes * slots, page_size)))
+    jax.eval_shape(functools.partial(backend._paged_decode_fn.__wrapped__, kernel_path="xla", with_fp=False), *avals)
+    assert set(asked) == counted, (sorted(map(str, asked)), sorted(map(str, counted)))
+    # a latent row's walk is its own (ops/latent_attention.py); a row that chooses its positions fetches them one by one
+    assert bool(counted) == (name not in ("deepseek_v3", "KeyeVL2")), "the step's attention never reached the decode walk"
+    extras = {"bloom": (True, False, None), "falcon-rw": (True, False, None), "gemma2": (False, True, "an array")}
+    assert {call[-3:] for call in counted} <= {extras.get(name, (False, False, call[-1])) for call in counted}, counted
+
+
 # ------------------------------------------------------------ prefill parity
 
 

@@ -151,21 +151,21 @@ def make_tiny_bloom(tmpdir: str, *, n_layers: int = 3, vocab: int = 128) -> str:
 
 
 @_model_build_cache
-def make_tiny_falcon(tmpdir: str, *, variant: str = "new", n_layers: int = 3, vocab: int = 128, head_dim: int = 16) -> str:
+def make_tiny_falcon(tmpdir: str, *, variant: str = "new", n_layers: int = 3, vocab: int = 128, head_dim: int = 16, heads: int = 4, kv_heads: int = 2) -> str:
     """variant: "new" (40b-style GQA dual-LN), "7b" (MQA parallel), "rw" (MHA alibi serial).
     ``head_dim`` 64 is Falcon-40B's own: four heads of it over two kv heads."""
     from transformers import FalconConfig, FalconForCausalLM
 
     common = dict(
         vocab_size=vocab,
-        hidden_size=4 * head_dim,
+        hidden_size=heads * head_dim,
         num_hidden_layers=n_layers,
-        num_attention_heads=4,
+        num_attention_heads=heads,
         layer_norm_epsilon=1e-5,
     )
     if variant == "new":
         cfg = FalconConfig(
-            **common, new_decoder_architecture=True, num_kv_heads=2, multi_query=False,
+            **common, new_decoder_architecture=True, num_kv_heads=kv_heads, multi_query=False,
             parallel_attn=True, bias=False, alibi=False,
         )
     elif variant == "7b":
@@ -182,7 +182,7 @@ def make_tiny_falcon(tmpdir: str, *, variant: str = "new", n_layers: int = 3, vo
         raise ValueError(variant)
     torch.manual_seed(3)
     model = FalconForCausalLM(cfg).eval()
-    path = os.path.join(tmpdir, f"tiny-falcon-{variant}" + (f"-d{head_dim}" if head_dim != 16 else ""))
+    path = os.path.join(tmpdir, f"tiny-falcon-{variant}" + (f"-d{head_dim}" if head_dim != 16 else "") + (f"-h{heads}x{kv_heads}" if (heads, kv_heads) != (4, 2) else ""))
     model.save_pretrained(path, safe_serialization=True)
     return path
 
