@@ -256,7 +256,7 @@ def kernel_phase(jax) -> None:
         PagedPool, paged_attend, paged_prefill_attend, quantize_kv_rows,
     )
     from petals_tpu.ops.paged_flash_attention import (
-        paged_flash_attend, paged_flash_prefill_attend,
+        composed_paged_attend, paged_flash_prefill_attend,
     )
 
     d = WIDTHS["head_dim"]
@@ -284,7 +284,7 @@ def kernel_phase(jax) -> None:
             flash_attend(q, k, v, interpret=INTERPRET, **kw), attend_reference(q, k, v, **kw),
         )
 
-    # --- fused paged attention: decode and chunked prefill, every page encoding
+    # --- paged attention: the decode walk and the chunked-prefill kernel, every page encoding
     lanes, max_pages, page = 8, 16, 64
     n_pages = lanes * max_pages
     rng = np.random.default_rng(SEED)
@@ -304,8 +304,8 @@ def kernel_phase(jax) -> None:
                 kp = PagedPool(*quantize_kv_rows(k_fp, kv_quant))
                 vp = PagedPool(*quantize_kv_rows(v_fp, kv_quant))
             _kernel_case(
-                f"paged_flash_attend {hq}/{hkv} pages={kv_quant}",
-                paged_flash_attend(q1, kp, vp, tables_j, positions_j, interpret=INTERPRET),
+                f"composed_paged_attend {hq}/{hkv} pages={kv_quant}",
+                composed_paged_attend(q1, kp, vp, tables_j, q_offset=positions_j, kv_length=positions_j + 1),
                 paged_attend(q1, kp, vp, tables_j, positions_j),
             )
             row = tables_j[7]
@@ -479,13 +479,12 @@ def session_check(model, name: str, ids: np.ndarray, ref: np.ndarray, max_length
         logits_check(f"{name} decode step", model.lm_logits(h[:, -1:])[0, 0], ref[PROMPT_LEN], depth)
 
 
-def logits_checks(jax, model, model_dir: str):
+def logits_checks(jax, model, model_dir: str) -> None:
     """Last-position logits through the swarm against the float32 reference,
     on prompts nothing else has sent (so nothing comes from the prefix
-    cache), each followed by one more token for a decode step. Returns the
-    (ids, reference) row kept back for the forced-kernel session."""
+    cache), each followed by one more token for a decode step."""
     vocab = model.cfg.vocab_size
-    ids = np.concatenate([prompt_ids(PROMPT_LEN + 1, salt=2 + row) for row in range(3)])
+    ids = np.concatenate([prompt_ids(PROMPT_LEN + 1, salt=2 + row) for row in range(2)])
     ref = reference_logits(jax, model_dir, ids)
     logits = np.asarray(model.forward(ids[:1]), np.float32)
     check(logits.shape == (1, PROMPT_LEN + 1, vocab), f"forward: logits shape {logits.shape}")
@@ -497,7 +496,6 @@ def logits_checks(jax, model, model_dir: str):
     # and one too long for a lane (private dense cache: flash prefill)
     session_check(model, "lane session", ids[:1], ref[0], PROMPT_LEN + NEW_TOKENS)
     session_check(model, "private-cache session", ids[1:2], ref[1], 2048)
-    return ids[2:], ref[2]
 
 
 def serve_phase(jax, model_dir: str, *, tp: int = 1) -> list:
@@ -542,7 +540,7 @@ def serve_phase(jax, model_dir: str, *, tp: int = 1) -> list:
             model_dir, initial_peers=[state["bootstrap"].own_addr.to_string()]
         )
         drive_client(model, server_side=True, concurrent=True)
-        spare_ids, spare_ref = logits_checks(jax, model, model_dir)
+        logits_checks(jax, model, model_dir)
 
         stats = dict(batcher.stats)
         say(f"  batcher stats: {stats}")
@@ -563,18 +561,6 @@ def serve_phase(jax, model_dir: str, *, tp: int = 1) -> list:
         check(want <= set(ran), f"step programs missing from the compiled set: {sorted(want - set(ran))}")
         anomalies = [r.fn for r in records if r.anomaly]
         check(not anomalies, f"steady step programs recompiled after warm-up: {anomalies}")
-        if paged:
-            # whichever path the autotune picked for this pool, the fused
-            # kernel must also be right INSIDE the step programs: the override
-            # retraces the mixed and decode steps onto it for one more session
-            os.environ["PETALS_TPU_PAGED_KERNEL"] = "pallas"
-            try:
-                session_check(
-                    model, "lane session, fused kernel forced", spare_ids, spare_ref,
-                    PROMPT_LEN + NEW_TOKENS,
-                )
-            finally:
-                del os.environ["PETALS_TPU_PAGED_KERNEL"]
         return get_observatory().programs()
     finally:
         if model is not None:
@@ -602,22 +588,13 @@ def _check_tp_sharding(jax, server, tp: int) -> None:
 
 
 def programs_phase(records: list, *, paged: bool) -> None:
-    """'The kernel path' must contain a kernel: print what the autotune timed
-    and chose, and find the Mosaic custom call in the lowered step programs."""
-    from petals_tpu.ops.paged_flash_attention import paged_autotune_timings
+    """'The kernel path' must contain a kernel: find the Mosaic custom call
+    in the lowered step programs."""
     from petals_tpu.telemetry.observatory import get_observatory
 
     obs = get_observatory()
-    timings = paged_autotune_timings()
-    for key, (t_pallas, t_xla) in timings.items():
-        say(
-            f"  paged-attention autotune {key}: pallas {t_pallas:.3f} ms vs xla {t_xla:.3f} ms "
-            f"-> {'pallas' if t_pallas <= t_xla else 'xla'}"
-        )
-    if paged:
-        check(timings, "the paged-attention autotune never ran")
-    # the private-cache prefill takes the flash kernel; every paged step ran
-    # on the fused kernel at least once (autotuned onto it, or forced)
+    # the private-cache prefill takes the flash kernel; a decode row's walk
+    # over this pool (32 kv heads of 128) and a prompt's chunk are kernels too
     must = ["inference_step"] + (["paged_decode", "paged_mixed_step"] if paged else [])
     for fn in must:
         texts = [obs.lowered_text(r) for r in records if r.fn == fn]

@@ -113,8 +113,8 @@ def attend(
         it, so the Mosaic kernel (no GSPMD rule) runs per-shard via shard_map.
     """
     # paged KV: the (pool, block-table) pair rides through the family block
-    # as a dense-buffer stand-in; route to the fused ragged kernel or its
-    # XLA-composed fallback (ops/paged_flash_attention.py). Import is local —
+    # as a dense-buffer stand-in; route to the decode walk, the prefill kernel
+    # or the gather (ops/paged_flash_attention.py). Import is local —
     # paged_attention imports attend_reference from this module at load time.
     from petals_tpu.ops.paged_attention import PagedKV
 

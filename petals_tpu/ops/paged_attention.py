@@ -37,15 +37,16 @@ One attention path: the step programs no longer materialize a dense view in
 front of attention. The (pool, tables) pair rides through the model family's
 block code as a ``PagedKV`` pytree standing in for the dense KV buffer;
 ``models/common.py update_kv_cache`` scatters the new rows straight into the
-pool and ``ops/attention.py attend`` dispatches to the fused ragged kernel
-(ops/paged_flash_attention.py) or, on CPU / when autotune prefers it, to
-the path composed from XLA (``composed_paged_attend`` there). That one has
-two forms. A decode row (one query row a lane) walks its lane's table in
-blocks of slots with a running softmax, up to the longest live lane's last
-page, each block gathered as the pool stores it (``gather_pages`` over the
-block's columns): it reads what the lanes hold, not the table's width, and
-writes no float32 copy of it. A prompt's chunk and a verify's rows gather the
-whole row (or a static window's reach of it) into a dense view for
+pool and ``ops/attention.py attend`` dispatches in
+ops/paged_flash_attention.py (``paged_attend_dispatch``). A decode row (one
+query row a lane) walks its lane's table in blocks of slots with a running
+softmax (``composed_paged_attend`` there: one kernel over the pages where they
+lie on a TPU where the pool's form allows, else each block gathered as the
+pool stores it, ``gather_pages`` over the block's columns, up to the longest
+live lane's last page): it reads what the lanes hold, not the table's width,
+and writes no float32 copy of it. A prompt's chunk takes the fused prefill
+kernel on a TPU; off it, and a verify's rows everywhere, gather the whole row
+(or a static window's reach of it) into a dense view for
 ``attend_reference``, as the reference entry points kept in this module do.
 Dense is just the identity block table (lane i owns pages [i*max_pages,
 (i+1)*max_pages)): the identity gather yields byte-identical values to the
@@ -66,7 +67,7 @@ Quantized pools (``--kv_quant_type int8|nf4a``): the pool may instead be a
 array, carried together as one pytree that stands in wherever a plain pool
 array rides (scan xs, donation, MemoryCache buffers, swap entries). Every
 write path quantizes rows on the way in (per-(token, kv-head) absmax over
-the head dim) and every read path — the fused kernel's tile loop
+the head dim) and every read path — the prefill kernel's tile loop
 (ops/paged_flash_attention.py) or the XLA ``gather_pages`` twin here —
 dequantizes on the way out, so decode/mixed/spec-verify steps never touch
 an fp pool. int8 stores one byte per element; nf4a packs two 4-bit codes
@@ -106,14 +107,14 @@ def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
     row-major relays it whole on the way in and on the way out, every step.
     So such a leaf is stored with the kv heads folded into the row,
     ``(hkv * d_store,)``: the same bytes in the same order, the layout the
-    step programs are handed is the one they compute in, and the fused
+    step programs are handed is the one they compute in, and the prefill
     kernel's lane-trailing view (ops/paged_flash_attention.py
     ``_pool_views``) is the array itself. A row of 128 lanes or more keeps
     ``(hkv, d_store)``. Scales stay ``[..., hkv]`` either way.
 
     Every consumer reads the form off the leaf it is handed (``pool_geometry``,
     ``fold_rows`` / ``unfold_rows``); only who MAKES a pool asks this function
-    (server/backend.py ``paged_cache_descriptors``, the autotune's harness)."""
+    (server/backend.py ``paged_cache_descriptors``)."""
     return (hkv * d_store,) if d_store < LANES else (hkv, d_store)
 
 
@@ -296,7 +297,7 @@ class PagedKV(NamedTuple):
     pool plus the per-lane block tables. A NamedTuple, so it is automatically
     a JAX pytree and rides through ``block_apply``'s kv tuple / lax.scan
     carries unchanged; ``update_kv_cache`` and ``attend`` recognise it by
-    isinstance and route to the paged scatter / fused-kernel dispatch instead
+    isinstance and route to the paged scatter / paged attention dispatch instead
     of the dense buffer code.
 
     The pool is in whichever form ``stored_row`` gave it: ``[n_pages,
@@ -349,7 +350,7 @@ class PagedKV(NamedTuple):
     def own_layer(self) -> "PagedKV":
         """The block's own pages as a pool of their own, with the tables it
         was given before the shift: what a consumer takes that would
-        otherwise walk or relay every layer of the span's pool (the fused
+        otherwise walk or relay every layer of the span's pool (the prefill
         kernel relays the pool it is handed into a lane-trailing view)."""
         if self.layer is None:
             return self
@@ -422,7 +423,7 @@ def gather_pages(pool: PoolLike, tables: jnp.ndarray, hkv: Optional[int] = None)
     ZEROS: they must not surface page 0's live bytes into a lane that does
     not own that page (attention masks them to 0.0 weight either way, but
     the dense view escapes attention — kv export, debug dumps — so the
-    fallback path must never alias another tenant's content). The fused
+    fallback path must never alias another tenant's content). The prefill
     kernel skips -1 slots entirely; behind a lane's length both give a
     weight of exactly zero. ``tables`` may be a block of the table's columns
     (a decode row's walk, ops/paged_flash_attention.py): the view is then

@@ -1,70 +1,59 @@
-"""Fused ragged paged-attention Pallas kernel — ONE attention path for dense,
-paged, and mixed steps ("Ragged Paged Attention", arXiv:2604.15464).
+"""Attention over a paged KV pool: what a ``PagedKV`` in a step program reaches
+through ops/attention.py ``attend`` (``paged_attend_dispatch``). Dense is the
+identity block table, so the dense-shaped steps take the same code.
 
-The paged steps used to be composed from XLA as gather -> dense-attend ->
-scatter: every attention call first materialized a transient
-[n_lanes, max_pages*page_size, hkv, d] dense view of the page pool, paying
-HBM bandwidth and memory proportional to max_length instead of the actual
-ragged lengths. This kernel walks the block tables directly: the KV
-BlockSpec index maps read the per-lane table (scalar-prefetched into SMEM)
-and fetch pages straight from the [n_pages, page_size, hkv, d] pool — no
-materialized gather, and pages beyond a lane's ragged frontier
-(kv_length = position + 1), beyond the sliding window, or unallocated (-1)
-are never fetched at all (their DMA is redirected to a repeated block index,
-which Pallas elides). Dense is just the identity block table, so the same
-kernel serves the dense-shaped steps too.
+A DECODE row (per-lane positions) always takes ``composed_paged_attend``. One
+query row a lane walks its lane's pages in blocks of table slots with a
+running softmax, and ``decode_walk_path`` picks the walk from the call's own
+shapes and dtypes: on a TPU, over a plain pool of rows of ``[hkv, d]`` of
+whole tiles, ONE Pallas kernel a layer (``_walk_kernel``): the span's pools
+stay in HBM as the layer loop carries them, each live lane's pages of ALL kv
+heads are copied block by block into one of two buffers under the block
+before, and a block is met as one matrix ``[rows * hkv, d]`` with the off-head
+columns masked, so nothing is relaid and no layer is sliced out; everywhere
+else (off the chip, a folded or quantised pool, half a tile of kv heads,
+ALiBi, a soft cap, a traced window) a ``fori_loop`` in plain ``jax.numpy``
+(``_walk_decode_rows``), which is also the kernel's reference. A verify's
+rows and a non-causal call gather their pages and run ``attend_reference``.
 
-What the chip said (v5e, PR 21): Mosaic takes the pool only through a
-lane-trailing VIEW ([n_pages, page_size, hkv * d], see the notes above
-``_kv_heads_per_block``), and under TPU tiling that view of a pool of rows
-of [hkv, d] is a physical relayout — XLA copies the whole per-layer K and V
-pool in front of every call. With that and a grid of n_lanes * hkv *
-max_pages one-page steps, the autotune picks the XLA-composed path for
-decode on the default pool (PERF.md section 5). Since PR 38 a pool whose row
-is under 128 lanes (head_dim 64) is STORED lane-trailing
+A prompt's CHUNK (a scalar position, one lane's table) takes the fused
+chunked-prefill kernel (``paged_flash_prefill_attend``) where the platform is
+a TPU, the call is one the kernel can express (causal, a static window or
+none, no soft cap) and Mosaic can tile the pool's class
+(``paged_kernel_unsupported``: a static predicate with one WARNING a class,
+never a caught compile failure); else ``composed_paged_attend``'s gather. The
+kernel walks the block table directly: the KV BlockSpec index maps read the
+lane's table (scalar-prefetched into SMEM) and fetch pages straight from the
+pool, so no dense view is made, and pages beyond the chunk's causal frontier,
+beyond the sliding window, or unallocated (-1) are never fetched (their DMA is
+redirected to a repeated block index, which Pallas elides). Mosaic takes the
+pool only through a lane-trailing VIEW (``[n_pages, page_size, hkv * d]``, see
+the notes above ``_kv_heads_per_block``); of a pool of rows of ``[hkv, d]``
+that view is a physical relayout under TPU tiling, so the dispatch hands the
+kernel the block's own layer (``PagedKV.own_layer``), not the span's pool. A
+pool whose row is under 128 lanes (head_dim 64) is STORED lane-trailing
 (ops/paged_attention.py ``stored_row``): ``_pool_views`` of it is the array
-itself, and both kernels and the composed path read the form off the leaf
-(``pool_geometry``). A pool of head_dim 128 is still relaid for the kernel;
-fetching all kv heads of a page per step is ROADMAP S3.
+itself, and the kernel and the composed path read the form off the leaf
+(``pool_geometry``).
 
-Structure is lifted from ops/flash_attention.py: online-softmax m/l/acc
-scratch carried across the innermost (arbitrary) grid axis, a shared
-"needed" predicate between the kernel's @pl.when skip and the index map's
-DMA-elision redirect, and an interior/edge tile split so fully-visible pages
-skip mask construction. Two entries mirror the reference contracts in
-ops/paged_attention.py: ``paged_flash_attend`` (decode: per-lane positions)
-and ``paged_flash_prefill_attend`` (one lane's chunked-prefill bucket).
+Nothing here is timed at start and nothing is read from the environment. Off
+the chip only the composed path runs unless a test asks for a kernel by name
+(``paged_flash_prefill_attend(..., interpret=True)``,
+``composed_paged_attend(..., path="kernel")``), so tier-1 CPU runs never
+depend on interpret-mode Mosaic semantics by accident.
 
-Path selection (``paged_attend_dispatch``, reached via ops/attention.py
-attend() on a PagedKV): per (n_lanes, max_pages, page_size, hkv, d, window)
-shape class, an autotune harness on the maybe_autotune_nf4_decode pattern
-times kernel-vs-XLA-composed on the real chip at startup and traces the
-winner into the step program, the kernel winning only by ``KERNEL_MUST_WIN_BY``
-(a tie in the harness must not flip the step program from one start to the
-next); shape classes Mosaic cannot tile are kept off
-the kernel by a static predicate (``paged_kernel_unsupported``), never by
-catching a failed compile. ``PETALS_TPU_PAGED_KERNEL=pallas|xla|auto``
-overrides; off-TPU the XLA-composed path (``composed_paged_attend``: a decode
-row's walk over its lane's pages, gather_pages + attend_reference for the
-rest) is what runs, so tier-1 CPU runs never depend on interpret-mode Mosaic
-semantics unless a test asks for the kernel explicitly.
-
-Since PR 45 the composed path's decode walk is itself ONE kernel a layer on a
-TPU where the pool's stored form allows (``decode_walk_path``: plain rows of
-``[hkv, d]`` of whole tiles; ``_walk_kernel``): the span's pools stay in HBM
-as the layer loop carries them, each live lane's pages of ALL kv heads are
-copied block by block into one of two buffers under the block before, and a
-block is met as one matrix ``[rows * hkv, d]`` with the off-head columns
-masked. No relayout, no ``own_layer()`` slice: what the old fused decode
-kernel above pays for a pool of head_dim 128. The autotune's "xla" arm times
-this walk, so where both can run the faster one is taken.
+The prefill kernel's structure is lifted from ops/flash_attention.py:
+online-softmax m/l/acc scratch carried across the innermost (arbitrary) grid
+axis, a shared "needed" predicate between the kernel's @pl.when skip and the
+index map's DMA-elision redirect, and an interior/edge tile split so
+fully-visible pages skip mask construction. It mirrors the reference contract
+in ops/paged_attention.py (``paged_prefill_attend``).
 """
 
 from __future__ import annotations
 
 import functools
-import os
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,63 +67,19 @@ from petals_tpu.telemetry.observatory import tracked_jit
 LANES = 128
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-_ENV_VAR = "PETALS_TPU_PAGED_KERNEL"
-_MODES = ("pallas", "xla", "auto")
-
-# (kind, n_lanes, max_pages, page_size, hkv, d, window) -> use_pallas.
-# Populated by maybe_autotune_paged_attention on TPU, or by tests via
-# set_paged_kernel_decision; consulted at TRACE time by the dispatch.
-_AUTOTUNE: dict = {}
-# shape class -> (pallas_ms, xla_ms) per decode step, as the autotune timed it
-_AUTOTUNE_MS: dict = {}
-# The kernel takes a shape class only where the harness times it this share
-# under the composed path. Two reasons, both measured on the v5e (PERF.md
-# section 6, PR 24). Falcon-40B's class (8 lanes, 16 pages of 64, hkv 8, d 64)
-# times 0.45 against 0.45 ms, so `<=` tossed a coin at every start and two
-# starts of one server ran different step programs; and inside the step the
-# kernel costs what the harness does not time (the pool's lane-trailing view
-# is a relayout in front of every layer's call): the coin's two sides were
-# 17.2 and 16.6 ms a step, 37.3 and 36.0 ms a token.
-KERNEL_MUST_WIN_BY = 0.10
-
-
-def kernel_mode() -> str:
-    """The PETALS_TPU_PAGED_KERNEL override, validated. Read per call — the
-    step wrappers pass the resolved path as a STATIC jit argument, so an env
-    flip retraces the step under the new path instead of being ignored."""
-    raw = os.environ.get(_ENV_VAR, "auto").strip().lower()
-    if raw not in _MODES:
-        raise ValueError(f"{_ENV_VAR}={raw!r}: expected one of {_MODES}")
-    return raw
-
 
 def _platform() -> str:
-    # indirection so the autotune decision unit tests can fake a TPU
+    # indirection so the dispatch's table test can fake a TPU
     return jax.default_backend()
 
 
-def shape_class(
-    n_lanes: int, max_pages: int, page_size: int, hkv: int, d: int,
-    window: Optional[int], kv_quant: str = "none",
-) -> Tuple:
-    """The autotune key: every quantity the kernel's tiling/skip behaviour
-    depends on. A traced (non-int) window is keyed as None — such calls are
-    forced to the XLA path anyway (gemma2). ``kv_quant`` joins the key: the
-    quantized tile (in-VMEM dequant, f32 dots) has a different cost profile
-    than the bf16 tile, so each pool encoding autotunes separately."""
-    return (
-        int(n_lanes), int(max_pages), int(page_size), int(hkv), int(d),
-        window if isinstance(window, int) else None, str(kv_quant),
-    )
-
-
-def paged_kernel_unsupported(key: Tuple) -> Optional[str]:
-    """Why Mosaic cannot take this shape class, or None if it can — the
-    static gate in front of the kernel (flash_supported's twin). A KV block
-    must end in a lane multiple or the whole [hkv * d_store] row (see the
-    view notes above ``_kv_heads_per_block``): head widths that neither are a
-    multiple of 128 nor pack evenly into 128 lanes are composed from XLA."""
-    _, _, _, hkv, d, _, kv_quant = key
+def paged_kernel_unsupported(hkv: int, d: int, kv_quant: str = "none") -> Optional[str]:
+    """Why Mosaic cannot take a pool of ``hkv`` kv heads of ``d`` in this
+    encoding, or None if it can — the static gate in front of the prefill
+    kernel (flash_supported's twin). A KV block must end in a lane multiple or
+    the whole [hkv * d_store] row (see the view notes above
+    ``_kv_heads_per_block``): head widths that neither are a multiple of 128
+    nor pack evenly into 128 lanes are composed from XLA."""
     d_store = _kv_store_dim(d, kv_quant)
     hb = _kv_heads_per_block(hkv, d_store)
     if (hb * d_store) % LANES and hkv != 1:
@@ -146,62 +91,6 @@ def paged_kernel_unsupported(key: Tuple) -> Optional[str]:
 
 
 _WARNED_UNSUPPORTED: set = set()
-
-
-def decide_paged_kernel(kind: str, key: Tuple) -> bool:
-    """TRACE-time path choice for one shape class. pallas/xla modes force;
-    auto uses the autotuned winner (untuned TPU shapes default to the kernel,
-    untuned prefill shapes inherit the decode decision for the same class),
-    shape classes Mosaic cannot take (``paged_kernel_unsupported``) compose
-    from XLA with one WARNING, and non-TPU platforms always take the XLA
-    path."""
-    mode = kernel_mode()
-    if mode == "pallas":
-        return True
-    if mode == "xla":
-        return False
-    if _platform() != "tpu":
-        return False
-    reason = paged_kernel_unsupported(key)
-    if reason is not None:
-        if key not in _WARNED_UNSUPPORTED:
-            _WARNED_UNSUPPORTED.add(key)
-            from petals_tpu.utils.logging import get_logger
-
-            get_logger(__name__).warning(
-                f"paged-attention kernel excluded for shape class {key}: {reason}; "
-                f"attention composes from XLA (gather + attend_reference)"
-            )
-        return False
-    return _AUTOTUNE.get((kind, *key), _AUTOTUNE.get(("decode", *key), True))
-
-
-def resolve_paged_kernel_path(kind: str, key: Tuple) -> str:
-    """Host-side resolution for the step wrappers: the returned string rides
-    as a STATIC argument of the jitted step purely so that a changed decision
-    (env flip, fresh autotune) triggers a retrace that re-consults
-    decide_paged_kernel. Steady state: one value, zero extra compiles."""
-    return "pallas" if decide_paged_kernel(kind, key) else "xla"
-
-
-def set_paged_kernel_decision(kind: str, key: Tuple, use_pallas: bool) -> None:
-    _AUTOTUNE[(kind, *key)] = bool(use_pallas)
-
-
-def kernel_wins(t_pallas: float, t_xla: float) -> bool:
-    """The autotune's verdict on two timed arms: the kernel, if it is faster
-    than the composed path by more than ``KERNEL_MUST_WIN_BY`` of it."""
-    return t_pallas < (1.0 - KERNEL_MUST_WIN_BY) * t_xla
-
-
-def reset_paged_autotune() -> None:
-    _AUTOTUNE.clear()
-    _AUTOTUNE_MS.clear()
-
-
-def paged_autotune_timings() -> dict:
-    """{shape class: (pallas_ms, xla_ms)} for every class timed so far."""
-    return dict(_AUTOTUNE_MS)
 
 
 # ---------------------------------------------------------------------------
@@ -283,14 +172,13 @@ def _kv_store_dim(head_dim: int, kv_quant: str) -> int:
 
 # Mosaic block rule: a block's last two dims must be multiples of (8, 128) or
 # span the whole array dims. A [n_pages, page_size, hkv, d] pool blocked one
-# head at a time ends in (1, d) and is refused, so the wrappers hand the
-# kernels VIEWS with page_size/lanes trailing:
+# head at a time ends in (1, d) and is refused, so the wrapper hands the
+# kernel VIEWS with page_size/lanes trailing:
 #   codes/values  [n_pages, page_size, hkv * d_store], block (1, page_size,
 #                 hb * d_store) at (page, 0, h // hb) — heads narrower than
 #                 128 lanes ride ``hb`` to a block and the tile slices its own;
 #   scales        [n_pages, hkv, page_size] (transposed), block (1, hkv,
-#                 page_size) — the tile reads row h, already lane-major;
-#   alibi slopes  [hkv, group, 1], block (1, group, 1) — a ready column.
+#                 page_size) — the tile reads row h, already lane-major.
 
 
 def _kv_heads_per_block(num_kv_heads: int, d_store: int) -> int:
@@ -333,259 +221,6 @@ def _tile_branches(tile, needed, interior, kv_head, heads_per_block: int):
         mine = needed if heads_per_block == 1 else needed & (kv_head % heads_per_block == t)
         pl.when(mine & interior)(functools.partial(tile, False, t))
         pl.when(mine & jnp.logical_not(interior))(functools.partial(tile, True, t))
-
-
-# ---------------------------------------------------------------------------
-# decode kernel: grid (n_lanes, hkv, max_pages), one token row per lane
-# ---------------------------------------------------------------------------
-
-
-def _decode_page_needed(page, slot_start, kv_len, page_size, sliding_window):
-    """Does this page hold any kv position the lane's single query row sees?
-    Shared by the kernel's skip predicate and the kv index map's DMA-elision
-    redirect — the two MUST agree, or a skipped-but-fetched page silently
-    computes on page-0 data. The query row sits at kv_len - 1, so causal
-    masking IS the ragged-length mask; the window frontier keeps only pages
-    whose last position >= kv_len - window."""
-    needed = (page >= 0) & (slot_start < kv_len)
-    if sliding_window is not None:
-        needed &= slot_start + page_size > kv_len - sliding_window
-    return needed
-
-
-def _decode_kernel(
-    # scalar prefetch
-    tables_ref,  # int32[n_lanes, max_pages]
-    kv_lens_ref,  # int32[n_lanes]
-    # then, positionally: inputs / outputs / scratch —
-    #   q_ref [1, 1, group, head_dim];
-    #   k_ref [1, page_size, hb * d_store] (one page of hb heads; raw codes
-    #   if quantized); ks_ref [1, hkv, page_size] f32 (quantized pools only);
-    #   v_ref / vs_ref likewise; slopes_ref [1, group, 1] f32;
-    #   o_ref [1, 1, group, head_dim];
-    #   m/l_scratch [group, LANES] f32, acc_scratch [group, head_dim] f32
-    *refs,
-    scale: float,
-    page_size: int,
-    max_pages: int,
-    group: int,
-    head_dim: int,
-    heads_per_block: int,
-    use_alibi: bool,
-    sliding_window: Optional[int] = None,
-    kv_quant: str = "none",
-):
-    if kv_quant == "none":
-        q_ref, k_ref, v_ref, slopes_ref, o_ref, m_scratch, l_scratch, acc_scratch = refs
-        ks_ref = vs_ref = None
-    else:
-        (q_ref, k_ref, ks_ref, v_ref, vs_ref, slopes_ref, o_ref,
-         m_scratch, l_scratch, acc_scratch) = refs
-    i = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-    d_store = _kv_store_dim(head_dim, kv_quant)
-
-    kv_len = kv_lens_ref[i]
-    page = tables_ref[i, j]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scratch[...] = jnp.full_like(m_scratch, NEG_INF)
-        l_scratch[...] = jnp.zeros_like(l_scratch)
-        acc_scratch[...] = jnp.zeros_like(acc_scratch)
-
-    slot_start = j * page_size
-    needed = _decode_page_needed(page, slot_start, kv_len, page_size, sliding_window)
-
-    # interior pages sit fully inside the lane's visible range: every position
-    # is < kv_len and (with a window) >= kv_len - window — no mask work
-    interior = slot_start + page_size <= kv_len
-    if sliding_window is not None:
-        interior &= slot_start >= kv_len - sliding_window
-
-    def _tile(masked: bool, t: int):
-        q = q_ref[0, 0]  # [group, head_dim]
-        if kv_quant == "none":
-            k = _head_block(k_ref, t, d_store)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-            )  # [group, page_size] f32
-        else:
-            ks_row = ks_ref[0, pl.ds(h, 1), :]  # [1, page_size]
-            s = _quant_k_scores(
-                q, _head_block(k_ref, t, d_store), ks_row, kv_quant, head_dim
-            )
-        s = s * scale
-
-        kv_pos_row = slot_start + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        if use_alibi:
-            s = s + slopes_ref[0] * kv_pos_row.astype(jnp.float32)
-
-        if masked:
-            kv_pos = slot_start + jax.lax.broadcasted_iota(
-                jnp.int32, (group, page_size), 1
-            )
-            mask = kv_pos < kv_len  # causal == ragged length for the decode row
-            if sliding_window is not None:
-                mask &= kv_pos > kv_len - 1 - sliding_window
-            s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_scratch[...]
-        l_prev = l_scratch[...]
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # [group, 1]
-        m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
-
-        alpha = jnp.exp(m_prev[:, :1] - m_new[:, :1])  # [group, 1]
-        p = jnp.exp(s - m_new[:, :1])  # [group, page_size]
-        if masked:
-            p = jnp.where(mask, p, 0.0)
-
-        l_new = alpha * l_prev[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-
-        acc = acc_scratch[...]
-        if kv_quant == "none":
-            v = _head_block(v_ref, t, d_store)
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        else:
-            vs_row = vs_ref[0, pl.ds(h, 1), :]
-            pv = _quant_pv(
-                p, _head_block(v_ref, t, d_store), vs_row, kv_quant, head_dim
-            )
-        acc_scratch[...] = acc * alpha + pv
-
-        m_scratch[...] = m_new
-        l_scratch[...] = jnp.broadcast_to(l_new, l_scratch.shape)
-
-    _tile_branches(_tile, needed, interior, h, heads_per_block)
-
-    @pl.when(j == max_pages - 1)
-    def _finalize():
-        # idle lanes (no needed page) keep l == 0 and emit exact zeros
-        l = l_scratch[:, :1]
-        out = acc_scratch[...] / jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = out.astype(o_ref.dtype)
-
-
-@tracked_jit(
-    name="paged_flash_attend",
-    static_argnames=("scale", "sliding_window", "interpret"),
-)
-def paged_flash_attend(
-    q: jnp.ndarray,
-    k_pool: jnp.ndarray,
-    v_pool: jnp.ndarray,
-    tables: jnp.ndarray,
-    positions: jnp.ndarray,
-    *,
-    alibi_slopes: Optional[jnp.ndarray] = None,
-    sliding_window: Optional[int] = None,
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """Fused ragged paged-attention DECODE: same contract as
-    ops/paged_attention.py paged_attend. q [n_lanes, 1, hq, d]; k/v_pool
-    [n_pages, page_size, hkv, d]; tables [n_lanes, max_pages] int32 (-1 =
-    unallocated, skipped — never fetched); positions [n_lanes] int32 (ragged
-    kv_length = position + 1; idle sentinel lanes produce finite garbage that
-    the caller never reads, exactly like the reference).
-
-    Quantized pools (``PagedPool``) ride as codes + per-row-scale operands;
-    the tile loop dequantizes in VMEM right after the DMA (see the in-tile
-    dequant helpers above) — the HBM side only ever moves wire bytes."""
-    from petals_tpu.ops.paged_attention import PagedPool, pool_geometry
-
-    quantized = isinstance(k_pool, PagedPool)
-    kv_quant = k_pool.kind if quantized else "none"
-    n_lanes, q_len, num_q_heads, head_dim = q.shape
-    n_pages, page_size, num_kv_heads, d_store = pool_geometry(k_pool, head_dim)  # in either stored form
-    if q_len != 1:
-        raise ValueError(f"decode kernel takes one token per lane, got q_len={q_len}")
-    assert num_q_heads % num_kv_heads == 0, (num_q_heads, num_kv_heads)
-    group = num_q_heads // num_kv_heads
-    max_pages = tables.shape[1]
-    if scale is None:
-        scale = head_dim**-0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    # fold q heads as (hkv, group) — the same grouping attend_reference uses,
-    # so each kv head's group of query rows shares one page fetch
-    q4 = q[:, 0].reshape(n_lanes, num_kv_heads, group, head_dim)
-    tables_arr = jnp.asarray(tables, jnp.int32)
-    kv_lens = jnp.asarray(positions, jnp.int32) + 1
-    if alibi_slopes is None:
-        slopes = jnp.zeros((num_kv_heads, group, 1), jnp.float32)
-        use_alibi = False
-    else:
-        slopes = alibi_slopes.astype(jnp.float32).reshape(num_kv_heads, group, 1)
-        use_alibi = True
-    hb = _kv_heads_per_block(num_kv_heads, d_store)
-
-    grid = (n_lanes, num_kv_heads, max_pages)
-
-    kernel = functools.partial(
-        _decode_kernel,
-        scale=scale,
-        page_size=page_size,
-        max_pages=max_pages,
-        group=group,
-        head_dim=head_dim,
-        heads_per_block=hb,
-        use_alibi=use_alibi,
-        sliding_window=sliding_window,
-        kv_quant=kv_quant,
-    )
-
-    def live_page(i, j, tables_ref, kv_lens_ref):
-        # skipped pages redirect to block 0: the repeated index elides the DMA
-        page = tables_ref[i, j]
-        needed = _decode_page_needed(
-            page, j * page_size, kv_lens_ref[i], page_size, sliding_window
-        )
-        return jax.lax.select(needed, page, 0)
-
-    kv_spec = pl.BlockSpec(
-        (1, page_size, hb * d_store), lambda i, h, j, *pf: (live_page(i, j, *pf), 0, h // hb)
-    )
-    scale_spec = pl.BlockSpec(
-        (1, num_kv_heads, page_size), lambda i, h, j, *pf: (live_page(i, j, *pf), 0, 0)
-    )
-    in_specs = [
-        pl.BlockSpec((1, 1, group, head_dim), lambda i, h, j, *pf: (i, h, 0, 0)),
-        *([kv_spec, scale_spec, kv_spec, scale_spec] if quantized else [kv_spec, kv_spec]),
-        pl.BlockSpec((1, group, 1), lambda i, h, j, *pf: (h, 0, 0)),
-    ]
-    operands = [q4, *_pool_views(k_pool, v_pool, quantized), slopes]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, group, head_dim), lambda i, h, j, *pf: (i, h, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, LANES), jnp.float32),
-            pltpu.VMEM((group, head_dim), jnp.float32),
-        ],
-    )
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q4.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(tables_arr, kv_lens, *operands)
-
-    return out.reshape(n_lanes, 1, num_q_heads, head_dim)
 
 
 # ---------------------------------------------------------------------------
@@ -865,7 +500,7 @@ def paged_flash_prefill_attend(
 
 
 # ---------------------------------------------------------------------------
-# dispatch: the one attention path for PagedKV (called from attend())
+# dispatch: what attend() calls on a PagedKV
 # ---------------------------------------------------------------------------
 
 
@@ -882,60 +517,49 @@ def paged_attend_dispatch(
     causal: bool = True,
     logit_softcap: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Route a PagedKV attention call (TRACE time, inside the step program)
-    to the fused kernel or the XLA-composed path (``composed_paged_attend``).
+    """Route a PagedKV attention call (TRACE time, inside the step program).
 
-    Decode vs prefill is distinguished by the position rank: per-lane [n]
-    vectors are the decode contract (ragged kv_length = position + 1), a
-    scalar is one lane's chunked-prefill bucket. Calls the kernel cannot
-    express — gemma2's logit softcap and its TRACED effective window,
-    non-causal — always compose from XLA."""
+    The position's rank tells the two contracts apart: per-lane [n] vectors
+    are decode rows (ragged kv_length = position + 1; a verify's several rows
+    a lane among them) and go to ``composed_paged_attend``, always; a scalar
+    is one lane's chunked-prefill bucket and takes the fused prefill kernel on
+    a TPU, unless the call is one the kernel cannot express — gemma2's logit
+    softcap and its TRACED effective window, non-causal, no kv_length — or
+    Mosaic cannot tile the pool's class (``paged_kernel_unsupported``)."""
     from petals_tpu.ops.paged_attention import kv_quant_kind_of, pool_geometry
 
-    k_pool, tables = k_kv.pool, k_kv.tables
-    v_pool = v_kv.pool
-    kv_quant = kv_quant_kind_of(k_pool)
     pos = jnp.asarray(q_offset, jnp.int32)
-    decode = pos.ndim == 1
-
-    window_static = sliding_window is None or isinstance(sliding_window, int)
-    forced_xla = (
-        logit_softcap is not None
-        or not causal
-        or not window_static
-        or kv_length is None
-        # speculative verify: per-lane positions with q_len > 1 (k candidate
-        # rows per lane) — the decode kernel is strictly one-row-per-lane and
-        # the prefill twin is single-lane, so compose from XLA (the reference
-        # handles vector q_offset with q_len > 1 via per-row causal masking).
-        or (decode and q.shape[1] != 1)
+    expressible = (
+        logit_softcap is None
+        and causal
+        and (sliding_window is None or isinstance(sliding_window, int))
+        and kv_length is not None
     )
-    # the LOGICAL geometry, whichever form the pool is stored in
-    _, page_size, hkv, _ = pool_geometry(k_pool, q.shape[-1])
-    key = shape_class(
-        tables.shape[0], tables.shape[1], page_size, hkv, q.shape[-1],
-        sliding_window if window_static else None, kv_quant,
-    )
-    kind = "decode" if decode else "prefill"
-    if not forced_xla and decide_paged_kernel(kind, key):
-        # the kernel relays the pool it is handed (_pool_views): give it the
-        # block's own layer, not the span's pool the step's loop carries
-        k_kv, v_kv = k_kv.own_layer(), v_kv.own_layer()
-        k_pool, v_pool, tables = k_kv.pool, v_kv.pool, k_kv.tables
-        if decode:
-            return paged_flash_attend(
-                q, k_pool, v_pool, tables, pos,
+    if pos.ndim == 0 and expressible and _platform() == "tpu":
+        # the LOGICAL geometry, whichever form the pool is stored in
+        _, _, hkv, _ = pool_geometry(k_kv.pool, q.shape[-1])
+        cls = (hkv, q.shape[-1], kv_quant_kind_of(k_kv.pool))
+        reason = paged_kernel_unsupported(*cls)
+        if reason is None:
+            # the kernel relays the pool it is handed (_pool_views): give it the
+            # block's own layer, not the span's pool the step's loop carries
+            k_kv, v_kv = k_kv.own_layer(), v_kv.own_layer()
+            kv_len = jnp.asarray(kv_length, jnp.int32).reshape(())
+            return paged_flash_prefill_attend(
+                q, k_kv.pool, v_kv.pool, k_kv.tables[0], pos, kv_len - pos,
                 alibi_slopes=alibi_slopes, sliding_window=sliding_window,
                 scale=scale,
             )
-        kv_len = jnp.asarray(kv_length, jnp.int32).reshape(())
-        return paged_flash_prefill_attend(
-            q, k_pool, v_pool, tables[0], pos.reshape(()), kv_len - pos.reshape(()),
-            alibi_slopes=alibi_slopes, sliding_window=sliding_window,
-            scale=scale,
-        )
+        if cls not in _WARNED_UNSUPPORTED:
+            _WARNED_UNSUPPORTED.add(cls)
+            from petals_tpu.utils.logging import get_logger
+
+            get_logger(__name__).warning(
+                f"paged-attention prefill kernel excluded for (kv heads, head_dim, pages) {cls}: {reason}; "
+                f"a prompt's chunk composes from XLA (gather + attend_reference)"
+            )
     return composed_paged_attend(
-        q, k_pool, v_pool, tables, q_offset=pos, kv_length=kv_length,
+        q, k_kv.pool, v_kv.pool, k_kv.tables, q_offset=pos, kv_length=kv_length,
         alibi_slopes=alibi_slopes, sliding_window=sliding_window,
         scale=scale, causal=causal, logit_softcap=logit_softcap,
     )
@@ -998,7 +622,7 @@ def _walk_decode_rows(
     A block's pages are gathered as the pool stores them (a quantised pool
     dequantises to bf16, holes read zeros) and meet the dots in that dtype,
     products summed in float32; max, sum and output run in float32, and the
-    weights are cast to V's dtype for their dot as the fused kernel's are."""
+    weights are cast to V's dtype for their dot as the prefill kernel's are."""
     from petals_tpu.ops.paged_attention import gather_pages, pool_geometry
 
     n_lanes, width = tables.shape
@@ -1298,11 +922,12 @@ def composed_paged_attend(
     q, k_pool, v_pool, tables, *, q_offset, kv_length, alibi_slopes=None, sliding_window=None,
     scale=None, causal: bool = True, logit_softcap=None, path: Optional[str] = None,
 ):
-    """The XLA-composed paged attention. A decode row (per-lane positions,
-    one query row a lane, causal) walks its lane's pages in blocks of slots,
-    as they are stored: what it reads follows the lanes' lengths, not the
-    table's width, and agrees with the dense program to float32 rounding, not
-    to the bit. On a TPU, over a plain pool of rows of ``[hkv, d]`` of whole
+    """Every decode row's attention over a paged pool, and a chunk's where
+    the prefill kernel does not take it (``paged_attend_dispatch``). A decode
+    row (per-lane positions, one query row a lane, causal) walks its lane's
+    pages in blocks of slots, as they are stored: what it reads follows the
+    lanes' lengths, not the table's width, and agrees with the dense program
+    to float32 rounding, not to the bit. On a TPU, over a plain pool of rows of ``[hkv, d]`` of whole
     tiles, the walk is ONE kernel that reads each live lane's own pages where
     they lie, to that lane's own end (``_walk_decode_rows_kernel``); everywhere
     else, and as the kernel's reference, a ``fori_loop`` over blocks of every
@@ -1359,143 +984,6 @@ def composed_paged_attend(
         alibi_slopes=alibi_slopes, sliding_window=sliding_window,
         scale=scale, causal=causal, logit_softcap=logit_softcap,
     )
-
-
-# ---------------------------------------------------------------------------
-# autotune: time kernel vs XLA-composed per shape class, once per process
-# ---------------------------------------------------------------------------
-
-
-def maybe_autotune_paged_attention(
-    *,
-    n_lanes: int,
-    max_pages: int,
-    page_size: int,
-    hkv: int,
-    d: int,
-    group: int = 1,
-    window: Optional[int] = None,
-    kv_quant: str = "none",
-    steps: int = 12,
-) -> bool:
-    """Measure the fused kernel vs the XLA gather+attend at this decode shape
-    class on the real device, once per process per class; returns the chosen
-    use_pallas and records it for decide_paged_kernel (prefill inherits the
-    decode decision). No-op off-TPU or when PETALS_TPU_PAGED_KERNEL forces a
-    path — the maybe_autotune_nf4_decode pattern (ops/quant.py). A quantized
-    shape class times against QUANTIZED pools on both arms: the kernel pays
-    in-tile dequant, the XLA arm pays the dequantizing gather."""
-    key = shape_class(n_lanes, max_pages, page_size, hkv, d, window, kv_quant)
-    if (
-        kernel_mode() != "auto"
-        or _platform() != "tpu"
-        or paged_kernel_unsupported(key) is not None
-    ):
-        return decide_paged_kernel("decode", key)
-    if ("decode", *key) in _AUTOTUNE:
-        return _AUTOTUNE[("decode", *key)]
-    import time
-
-    from petals_tpu.ops.paged_attention import PagedPool, fold_rows, quantize_kv_rows, stored_row
-
-    hq = hkv * max(int(group), 1)
-    n_pages = n_lanes * max_pages
-    rng = np.random.default_rng(0)
-    # a permuted, ~75%-occupied table: the shape the kernel must win at —
-    # identity tables would let XLA's gather degenerate to a reshape
-    perm = rng.permutation(n_pages).astype(np.int32).reshape(n_lanes, max_pages)
-    occupancy = max(1, (3 * max_pages) // 4)
-    perm[:, occupancy:] = -1
-    tables = jnp.asarray(perm)
-    positions = jnp.full((n_lanes,), occupancy * page_size - 1, jnp.int32)
-    jkey = jax.random.PRNGKey(0)
-    kq, kk, kv_ = jax.random.split(jkey, 3)
-    q = jax.random.normal(kq, (n_lanes, 1, hq, d), jnp.bfloat16) * 0.1
-    k_pool = jax.random.normal(kk, (n_pages, page_size, hkv, d), jnp.bfloat16) * 0.1
-    v_pool = jax.random.normal(kv_, (n_pages, page_size, hkv, d), jnp.bfloat16) * 0.1
-    # in the form a server's pool of this class is stored in: what is timed is what a step would run
-    row = stored_row(hkv, _kv_store_dim(d, kv_quant))
-    if kv_quant != "none":
-        k_pool, v_pool = (
-            PagedPool(fold_rows(codes, row), scales)
-            for codes, scales in (quantize_kv_rows(k_pool, kv_quant), quantize_kv_rows(v_pool, kv_quant))
-        )
-    else:
-        k_pool, v_pool = fold_rows(k_pool, row), fold_rows(v_pool, row)
-
-    def _perturb(pool, f, after):
-        # quantized pools perturb the SCALES leaf — same effect (the chain
-        # stays data-dependent, CSE can't hoist the gather), legal dtypes.
-        # The factor waits for the link before (``after``, times zero): a
-        # link's pool, its gather and its casts would otherwise depend on
-        # nothing in the chain and be made for every link at once, and at
-        # lanes of 40 pages and 32 kv heads that is 10 GB of a chip that
-        # holds a span's weights (PR 35: the server stopped at start-up)
-        f = f + after.ravel()[0].astype(jnp.float32) * 0.0
-        if isinstance(pool, PagedPool):
-            return PagedPool(pool.codes, pool.scales * f)
-        return pool * f.astype(pool.dtype)
-
-    def timed(call):
-        # chained data-dependent calls inside one jit; slope between two chain
-        # lengths cancels dispatch latency and sync cost (the NF4 harness
-        # idiom). Each link perturbs the POOL: the XLA arm's loop-invariant
-        # gather_pages(pool, tables) would otherwise be CSE-hoisted out of the
-        # unrolled chain, excluding exactly the per-call gather cost it pays
-        # in production. Both arms pay the same extra pool pass, so the
-        # comparison stays apples-to-apples.
-        def chain(n):
-            def f(qv, kp, vp, tb, ps_):
-                a = qv
-                for j in range(n):
-                    f_j = 1.0 + j / 128.0  # bf16 eps at 1.0: survives the dtype
-                    a = call(a * 1e-2 + qv, _perturb(kp, f_j, a), _perturb(vp, f_j, a), tb, ps_)
-                return a
-
-            return tracked_jit(f, name="paged_autotune_chain")
-
-        ts = {}
-        for n in (2, 2 + steps):
-            f = chain(n)
-            jax.block_until_ready(f(q, k_pool, v_pool, tables, positions))  # compile
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                for _ in range(5):
-                    out = f(q, k_pool, v_pool, tables, positions)
-                jax.block_until_ready(out)
-                best = min(best, (time.perf_counter() - t0) / 5)
-            ts[n] = best
-        return max((ts[2 + steps] - ts[2]) / steps, 1e-9)
-
-    def pallas_arm(qv, kp, vp, tb, ps_):
-        return paged_flash_attend(qv, kp, vp, tb, ps_, sliding_window=window)
-
-    def xla_arm(qv, kp, vp, tb, ps_):
-        return composed_paged_attend(qv, kp, vp, tb, q_offset=ps_, kv_length=ps_ + 1, sliding_window=window)
-
-    # both arms must compile: this is a timing choice, never a rescue. A
-    # refusal here is a bug in the kernel or in paged_kernel_unsupported —
-    # name the shape and stop (the server calls this at start-up)
-    try:
-        t_pallas = timed(pallas_arm)
-        t_xla = timed(xla_arm)
-    except Exception as e:
-        raise RuntimeError(
-            f"paged-attention autotune failed for shape class "
-            f"(n_lanes, max_pages, page_size, hkv, d, window, kv_quant)={key}, group={group}"
-        ) from e
-    use_pallas = kernel_wins(t_pallas, t_xla)
-    set_paged_kernel_decision("decode", key, use_pallas)
-    _AUTOTUNE_MS[key] = (t_pallas * 1e3, t_xla * 1e3)
-    from petals_tpu.utils.logging import get_logger
-
-    get_logger(__name__).info(
-        f"paged-attention autotune {key}: pallas {t_pallas * 1e3:.3f}ms vs "
-        f"xla-composed {t_xla * 1e3:.3f}ms per step -> "
-        f"{'pallas' if use_pallas else 'xla'}"
-    )
-    return use_pallas
 
 
 def _round_up(x: int, m: int) -> int:

@@ -838,30 +838,6 @@ class TransformerBackend:
             self._last_step_fp = None
         return out, (k_pool, v_pool)
 
-    def autotune_paged_attention(self, n_lanes: int, max_pages: int, page_size: int) -> None:
-        """Time the fused paged-attention kernel against the XLA-composed
-        path for this pool geometry (ops/paged_flash_attention.py; a no-op
-        off-TPU, under a forced path, and for a class already decided).
-        Server.start calls it before the first session, so a kernel that
-        does not compile stops the server there, with the shape named."""
-        from petals_tpu.ops import paged_flash_attention as pfa
-
-        cfg = self.cfg
-        if self.latent_row is not None or (self.index_row is not None and max_pages * page_size > self.index_keep):
-            # the span's blocks select (ops/sparse_attention.py) or attend over latent rows
-            # (ops/latent_attention.py): neither timed program is on their path
-            self._paged_autotuned = True
-            return
-        hkv = self.num_kv_heads
-        heads = getattr(cfg, "num_attention_heads", hkv)
-        for window in self._static_windows():  # one shape class a window
-            pfa.maybe_autotune_paged_attention(
-                n_lanes=n_lanes, max_pages=max_pages, page_size=page_size,
-                hkv=hkv, d=self.head_dim, group=max(1, heads // hkv), window=window,
-                kv_quant=self.kv_quant_type,
-            )
-        self._paged_autotuned = True
-
     def _static_windows(self) -> list:
         """The distinct static attention windows of the span's layers (None:
         full attention): the family's own per kind, else the one of ``cfg``."""
@@ -974,35 +950,6 @@ class TransformerBackend:
                 walked = layers * n_lanes * walk_pages(needed, block)
             read += walked
         return read, by_kernel
-
-    def _paged_kernel_path(self, k_pool, tables, *, mixed: bool = False) -> str:
-        """Resolve (host-side, O(1) — no table scan) which attention path the
-        paged step traces, running the once-per-process autotune for this
-        shape class first. The returned string rides as a STATIC argument of
-        the jitted step: its only job is to force a retrace when the resolved
-        decision changes (env override flip, fresh autotune result) — in
-        steady state it is one constant and costs zero extra compiles."""
-        from petals_tpu.ops import paged_flash_attention as pfa
-
-        if self.latent_row is not None:  # one path (ops/latent_attention.py): nothing to resolve, nothing to retrace for
-            return "latent"
-        page_size, hkv, d = k_pool.shape[2], self.num_kv_heads, self.head_dim  # the pool's row may be folded
-        keys = [
-            pfa.shape_class(tables.shape[0], tables.shape[1], page_size, hkv, d, window, self.kv_quant_type)
-            for window in self._static_windows()
-        ]
-        if not getattr(self, "_paged_autotuned", False):
-            # a backend driven without a Server (tests, benchmarks): the pool
-            # geometry first shows here. Once per backend — later shape
-            # classes (spec-verify lane buckets) inherit the kernel default
-            self.autotune_paged_attention(keys[0][0], keys[0][1], page_size)
-        paths = []
-        for key in keys:  # one class for every family but one whose layers' windows differ
-            path = pfa.resolve_paged_kernel_path("decode", key)
-            if mixed:
-                path = f"dec:{path},pf:{pfa.resolve_paged_kernel_path('prefill', key)}"
-            paths.append(path)
-        return paths[0] if len(paths) == 1 else "|".join(paths)
 
     def _scan_paged_span(self, params, k_pool, v_pool, carry, layer, state=(), state_layer=None):
         """The layer loop of every paged step program: ``_scan_span`` over
@@ -1133,11 +1080,9 @@ class TransformerBackend:
         pair rides through the model family's block code as a ``PagedKV``
         stand-in for the dense buffer — ``update_kv_cache`` scatters the new
         token rows straight into the pages and ``attend`` dispatches to the
-        fused ragged kernel or its XLA-composed fallback
-        (ops/paged_flash_attention.py). ONE attention code path: dense is
-        just the identity block table, with no host-side contiguity special
-        case. ``kernel_path`` is a static pass-through whose only job is to
-        retrace the step when the resolved kernel decision changes.
+        decode walk over the lanes' pages (ops/paged_flash_attention.py
+        ``paged_attend_dispatch``). ONE attention code path: dense is just the
+        identity block table, with no host-side contiguity special case.
 
         The pool a block sees is the whole span's and its tables are shifted
         by the layer: the stacked pools are the layer loop's carry, written
@@ -1150,14 +1095,13 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_decode", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2, 6),
+            static_argnames=("with_fp",), donate_argnums=(1, 2, 6),
         )
         def step(params, k_pool, v_pool, hidden, positions, tables, state=(),
-                 *, kernel_path: str, with_fp: bool):
+                 *, with_fp: bool):
             # hidden: [n_lanes, 1, hidden]; positions: [n_lanes] int32;
             # tables: [n_lanes, max_pages] int32 (-1 = unallocated slot);
             # state: the state pool's leaves, none for a span without one
-            del kernel_path  # static retrace trigger; attend() re-resolves
             hidden = hidden.astype(cache_dtype)
             hidden, k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
@@ -1188,7 +1132,6 @@ class TransformerBackend:
         """
         k_pool, v_pool, *state = pool_kv
         tables = np.asarray(tables, np.int32)
-        kernel_path = self._paged_kernel_path(k_pool, tables)
         if not isinstance(hidden, jax.Array):
             hidden = np.ascontiguousarray(hidden)
         with_fp = fp_ops.enabled()
@@ -1196,7 +1139,7 @@ class TransformerBackend:
             res = self._paged_decode_fn(
                 self.params, k_pool, v_pool, hidden,
                 np.asarray(positions, np.int32), tables, tuple(state),
-                kernel_path=kernel_path, with_fp=with_fp,
+                with_fp=with_fp,
             )
         res, state = self._split_state(res, state)
         if with_fp:
@@ -1221,13 +1164,12 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_gen_decode", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(2, 3, 17),
+            static_argnames=("with_fp",), donate_argnums=(2, 3, 17),
         )
         def step(params, client_params, k_pool, v_pool, hidden, tokens,
                  use_token, positions, do_sample, temperature, top_k, top_p,
                  rep_penalty, seeds, draw_idx, seen_mask, tables, state=(),
-                 *, kernel_path: str, with_fp: bool):
-            del kernel_path  # static retrace trigger; attend() re-resolves
+                 *, with_fp: bool):
             emb = client_embed(client_params, tokens[:, None], cfg)
             hidden = jnp.where(
                 use_token[:, None, None],
@@ -1259,7 +1201,6 @@ class TransformerBackend:
         plus the block tables)."""
         k_pool, v_pool, *state = pool_kv
         tables = np.asarray(tables, np.int32)
-        kernel_path = self._paged_kernel_path(k_pool, tables)
         if not isinstance(hidden, jax.Array):
             hidden = np.ascontiguousarray(hidden)
         v = sampling_vecs
@@ -1271,8 +1212,7 @@ class TransformerBackend:
                 np.asarray(positions, np.int32), v["do_sample"],
                 v["temperature"], v["top_k"], v["top_p"],
                 v["repetition_penalty"], v["seeds"], v["draw_idx"],
-                v["seen_mask"], tables, tuple(state), kernel_path=kernel_path,
-                with_fp=with_fp,
+                v["seen_mask"], tables, tuple(state), with_fp=with_fp,
             )
         res, state = self._split_state(res, state)
         if with_fp:
@@ -1321,16 +1261,14 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_spec_verify", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2),
+            static_argnames=("with_fp",), donate_argnums=(1, 2),
         )
         def step(params, k_pool, v_pool, client_params, tokens, positions,
                  do_sample, temperature, top_k, top_p, rep_penalty, seeds,
-                 draw_idx, seen_mask, tables, *, kernel_path: str,
-                 with_fp: bool):
+                 draw_idx, seen_mask, tables, *, with_fp: bool):
             # tokens: [n_lanes, S] int32 (S = spec_k + 1): column 0 is the
             # lane's last committed token, columns 1..S-1 the draft proposals;
             # positions: [n_lanes] int32, idle sentinel for non-spec lanes
-            del kernel_path  # static retrace trigger; attend() re-resolves
             S = tokens.shape[1]
             hidden = client_embed(client_params, tokens, cfg).astype(cache_dtype)
             hidden, k_pool, v_pool, _ = self._scan_paged_span(
@@ -1392,7 +1330,6 @@ class TransformerBackend:
         self.refuse_for_state("speculative verify", SPEC_CUTS_BACK)
         k_pool, v_pool = pool_kv
         tables = np.asarray(tables, np.int32)
-        kernel_path = self._paged_kernel_path(k_pool, tables)
         v = sampling_vecs
         with_fp = fp_ops.enabled()
         with self._quant_ctx():
@@ -1401,8 +1338,7 @@ class TransformerBackend:
                 np.asarray(tokens, np.int32), np.asarray(positions, np.int32),
                 v["do_sample"], v["temperature"], v["top_k"], v["top_p"],
                 v["repetition_penalty"], v["seeds"], v["draw_idx"],
-                v["seen_mask"], tables, kernel_path=kernel_path,
-                with_fp=with_fp,
+                v["seen_mask"], tables, with_fp=with_fp,
             )
         if with_fp:
             g_hat, n_emit, k_pool, v_pool, self._last_step_fp = res
@@ -1438,16 +1374,15 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_mixed_step", steady=True,
-            static_argnames=("kernel_path", "with_fp"), donate_argnums=(1, 2, 11),
+            static_argnames=("with_fp",), donate_argnums=(1, 2, 11),
         )
         def step(params, k_pool, v_pool, hidden, positions, tables,
                  chunk_hidden, chunk_lane, chunk_pos, chunk_n_valid,
-                 chunk_n_total, state=(), *, kernel_path: str, with_fp: bool):
+                 chunk_n_total, state=(), *, with_fp: bool):
             # hidden: [n_lanes, 1, hidden]; positions: [n_lanes] int32 (idle
             # sentinel = max_len); chunk_hidden: [1, B, hidden] (B = static
             # bucket); chunk_lane/chunk_pos/chunk_n_valid/chunk_n_total:
             # int32 scalars describing the ONE prefill chunk riding this step
-            del kernel_path  # static retrace trigger; attend() re-resolves
             B = chunk_hidden.shape[1]
             hidden = hidden.astype(cache_dtype)
             chunk_hidden = chunk_hidden.astype(cache_dtype)
@@ -1541,7 +1476,6 @@ class TransformerBackend:
         """
         k_pool, v_pool, *state = pool_kv
         tables = np.asarray(tables, np.int32)
-        kernel_path = self._paged_kernel_path(k_pool, tables, mixed=True)
         if not isinstance(hidden, jax.Array):
             hidden = np.ascontiguousarray(hidden)
         seq = chunk_hidden.shape[1]
@@ -1564,8 +1498,7 @@ class TransformerBackend:
                 self.params, k_pool, v_pool, hidden,
                 np.asarray(positions, np.int32), tables, chunk_hidden,
                 np.int32(chunk_lane), np.int32(chunk_pos), np.int32(seq),
-                np.int32(n_total), tuple(state), kernel_path=kernel_path,
-                with_fp=with_fp,
+                np.int32(n_total), tuple(state), with_fp=with_fp,
             )
         res, state = self._split_state(res, state)
         if with_fp:

@@ -272,7 +272,7 @@ class DecodeBatcher:
         self._tables: Optional[np.ndarray] = None  # [n_lanes, max_pages] int32, -1 = unallocated
         # cached tables_are_contiguous result for the stats/debug surface
         # (paged_summary); None = recompute on next read. The STEP path no
-        # longer consults it — the fused kernel serves identity and permuted
+        # longer consults it — paged attention serves identity and permuted
         # tables alike — so the O(n_lanes*max_pages) scan runs only when the
         # tables actually changed AND someone asks (rpc_info), not per tick.
         self._tables_contig: Optional[bool] = None
@@ -828,7 +828,7 @@ class DecodeBatcher:
             )
         alloc = self._pages
         # identity preference keeps tables contiguous at the default pool
-        # size: the fused kernel serves any layout, but identity tables read
+        # size: paged attention serves any layout, but identity tables read
         # pages in sequential HBM order (and keep the tables_contiguous
         # debug flag meaningful)
         identity_base = (
@@ -982,7 +982,7 @@ class DecodeBatcher:
 
     def tables_contiguous(self) -> Optional[bool]:
         """Stats/debug surface ONLY: are the block tables currently the
-        identity layout? The step path no longer branches on this (one fused
+        identity layout? The step path no longer branches on this (one paged
         attention path serves both); the flag is kept for observability —
         identity tables mean page reads stream sequentially through HBM.
         Cached; recomputed lazily after a table mutation."""

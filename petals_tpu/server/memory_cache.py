@@ -94,8 +94,8 @@ class PageAllocator:
         """Take a free page (refs=1) or None when the pool is exhausted.
         ``preferred`` is taken when free — the batcher asks for the identity
         page so pages read in sequential HBM order and the tables_contiguous
-        debug flag stays meaningful (the fused paged-attention kernel serves
-        identity and permuted tables through the same program)."""
+        debug flag stays meaningful (paged attention serves identity and
+        permuted tables through the same program)."""
         if not self._free:
             return None
         if preferred is not None and preferred in self._free_set:

@@ -421,15 +421,6 @@ class Server:
         if self._throughput_spec == "auto" and self.num_hosts > 1:
             await self._measure_multihost_throughput()
         self.handler = self._make_handler()
-        batcher = self.handler.batcher
-        if batcher is not None and batcher.page_size is not None:
-            # decide kernel-vs-XLA paged attention for the pool's geometry
-            # now: a kernel that does not compile stops the start, not a
-            # client's first token
-            await asyncio.get_running_loop().run_in_executor(
-                None, self.backend.autotune_paged_attention,
-                batcher.n_lanes, batcher.max_pages, batcher.page_size,
-            )
         self.handler.register(self.rpc_server)
 
         from petals_tpu.utils.ping import PingAggregator

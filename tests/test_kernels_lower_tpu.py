@@ -89,22 +89,28 @@ PAGED_IDS = ["bf16-mha", "bf16-gqa", "int8-gqa", "nf4a-mha", "bf16-d64"]
 
 
 @pytest.mark.parametrize("hq,hkv,d,kv_quant", PAGED_CASES, ids=PAGED_IDS)
-def test_paged_flash_attend_lowers(v5e, hq, hkv, d, kv_quant):
+def test_paged_decode_row_lowers(v5e, hq, hkv, d, kv_quant):
+    """A decode row's attention (``composed_paged_attend``) compiles for the
+    v5e at each class, on the walk ``decode_walk_path`` names for it: the
+    kernel over a plain pool of whole tiles, the composed walk over half a
+    tile of kv heads, a quantised pool and a folded one."""
     lanes, max_pages, page_size = 8, 16, 64
-    assert pfa.paged_kernel_unsupported(
-        pfa.shape_class(lanes, max_pages, page_size, hkv, d, None, kv_quant)
-    ) is None
     pool = _pool(v5e, lanes * max_pages, page_size, hkv, d, kv_quant)
-    _compile(
-        lambda q, k, v, t, p: pfa.paged_flash_attend(q, k, v, t, p, interpret=False),
-        v5e((lanes, 1, hq, d), BF16), pool, pool,
-        v5e((lanes, max_pages), I32), v5e((lanes,), I32),
-    )
+    q = v5e((lanes, 1, hq, d), BF16)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pfa, "_on_tpu", lambda: True)  # the backend here is the CPU
+        path = pfa.decode_walk_path(pool, q.shape, (lanes, max_pages))
+        assert path == ("kernel" if (hkv, d, kv_quant) == (32, 128, "none") else "composed")
+        hlo = jax.jit(
+            lambda q, k, v, t, p: pfa.composed_paged_attend(q, k, v, t, q_offset=p, kv_length=p + 1)
+        ).lower(q, pool, pool, v5e((lanes, max_pages), I32), v5e((lanes,), I32)).compile().as_text()
+    assert ("tpu_custom_call" in hlo) == (path == "kernel")
 
 
 @pytest.mark.parametrize("hq,hkv,d,kv_quant", PAGED_CASES, ids=PAGED_IDS)
 def test_paged_flash_prefill_attend_lowers(v5e, hq, hkv, d, kv_quant):
     max_pages, page_size, chunk = 16, 64, 512  # chunk 512 -> block_q 256
+    assert pfa.paged_kernel_unsupported(hkv, d, kv_quant) is None
     pool = _pool(v5e, 8 * max_pages, page_size, hkv, d, kv_quant)
     _compile(
         lambda q, k, v, t, c, n: pfa.paged_flash_prefill_attend(
@@ -116,17 +122,10 @@ def test_paged_flash_prefill_attend_lowers(v5e, hq, hkv, d, kv_quant):
 
 
 def test_paged_alibi_and_window_lower(v5e):
-    """The slopes operand (a VMEM column for decode, f32 scalar prefetch for
-    prefill) and the windowed skip predicate ride the same kernels."""
+    """The slopes operand (an f32 scalar prefetch) and the windowed skip
+    predicate ride the same kernel."""
     lanes, max_pages, page_size, hq, hkv, d = 8, 16, 64, 32, 8, 128
     pool = _pool(v5e, lanes * max_pages, page_size, hkv, d, "none")
-    _compile(
-        lambda q, k, v, t, p, s: pfa.paged_flash_attend(
-            q, k, v, t, p, alibi_slopes=s, sliding_window=256, interpret=False
-        ),
-        v5e((lanes, 1, hq, d), BF16), pool, pool,
-        v5e((lanes, max_pages), I32), v5e((lanes,), I32), v5e((hq,), F32),
-    )
     _compile(
         lambda q, k, v, t, c, n, s: pfa.paged_flash_prefill_attend(
             q, k, v, t, c, n, alibi_slopes=s, sliding_window=256, interpret=False
@@ -140,8 +139,7 @@ def test_unsupported_paged_shape_is_gated_not_compiled():
     """A head width that neither is a lane multiple nor packs into 128 lanes
     is what the static gate exists for; the dispatch must never hand it to
     Mosaic on a TPU."""
-    key = pfa.shape_class(8, 16, 64, 8, 80, None, "none")
-    assert pfa.paged_kernel_unsupported(key) is not None
+    assert pfa.paged_kernel_unsupported(8, 80) is not None
 
 
 IN, OUT, N_BLOCKS = 4096, 11008, 2  # Llama-2-7B up/gate projection
@@ -337,8 +335,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     if backend.index_row is not None:  # as the index pool does
         avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.index_cache_descriptors(n_pages, page_size)))
         donated += (len(avals) - 1,)
-    # the raw step under tracked_jit: kernel_path only retraces, attend() resolves the path itself
-    step = functools.partial(step.__wrapped__, kernel_path="xla", with_fp=False)
+    step = functools.partial(step.__wrapped__, with_fp=False)  # the raw step under tracked_jit
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
         patch.setattr("petals_tpu.ops.latent_attention._on_tpu", lambda: latent_kernel)  # as would the latent decode walk's kernel

@@ -1,6 +1,6 @@
 """Quantized paged KV pool tier (``--kv_quant_type``): int8 / packed-nf4a
-codec error bounds and np/jnp bit-compatibility, fused-kernel-vs-XLA parity
-on quantized pages (identity / permuted / holey tables, GQA, windows,
+codec error bounds and np/jnp bit-compatibility, the decode walk's and the
+prefill kernel's parity with the gather on quantized pages (identity / permuted / holey tables, GQA, windows,
 prefill), requantization idempotence on the check-in paths, swap and
 migration byte-exactness of packed pages, COW forks, capacity accounting
 (wire bytes per token, descriptor contract, ledger pricing), the calibrated
@@ -13,7 +13,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from petals_tpu.ops import paged_flash_attention as pfa
 from petals_tpu.ops.paged_attention import (
     KV_QUANT_KINDS,
     PagedKV,
@@ -30,10 +29,7 @@ from petals_tpu.ops.paged_attention import (
     quantize_kv_rows_np,
     stored_row,
 )
-from petals_tpu.ops.paged_flash_attention import (
-    paged_flash_attend,
-    paged_flash_prefill_attend,
-)
+from petals_tpu.ops.paged_flash_attention import composed_paged_attend, paged_flash_prefill_attend
 from tests.utils import make_tiny_llama
 
 pytestmark = pytest.mark.kvquant
@@ -74,13 +70,6 @@ def _holey_permuted(rng, n_lanes, max_pages, n_pages, used_slots):
 @pytest.fixture(scope="module")
 def model_path(tmp_path_factory):
     return make_tiny_llama(str(tmp_path_factory.mktemp("models")))
-
-
-@pytest.fixture(autouse=True)
-def _fresh_autotune():
-    pfa.reset_paged_autotune()
-    yield
-    pfa.reset_paged_autotune()
 
 
 # ------------------------------------------------------------- codec bounds
@@ -206,7 +195,7 @@ def test_decode_parity_identity_tables(kind):
     q = _rows(rng, (n_lanes, 1, hq, d))
     tables = jnp.asarray(identity_tables(n_lanes, max_pages))
     pos = jnp.asarray([0, ps - 1, 2 * ps, 3 * ps + 5], jnp.int32)
-    out = paged_flash_attend(q, kp, vp, tables, pos, interpret=True)
+    out = composed_paged_attend(q, kp, vp, tables, q_offset=pos, kv_length=pos + 1)
     ref = paged_attend(q, kp, vp, tables, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=KERNEL_TOL, rtol=0)
 
@@ -224,7 +213,7 @@ def test_decode_parity_permuted_holey_gqa(kind, group):
     pos = np.array([3 * ps - 1, 2 * ps - 1, ps], np.int32)
     used = [-(-int(p + 1) // ps) for p in pos]
     tables = jnp.asarray(_holey_permuted(rng, n_lanes, max_pages, n_pages, used))
-    out = paged_flash_attend(q, kp, vp, tables, jnp.asarray(pos), interpret=True)
+    out = composed_paged_attend(q, kp, vp, tables, q_offset=jnp.asarray(pos), kv_length=jnp.asarray(pos) + 1)
     ref = paged_attend(q, kp, vp, tables, jnp.asarray(pos))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=KERNEL_TOL, rtol=0)
 
@@ -240,9 +229,9 @@ def test_decode_parity_alibi_window(kind, window):
     perm = rng.permutation(n_lanes * max_pages).astype(np.int32).reshape(n_lanes, max_pages)
     pos = jnp.asarray([0, 2 * ps - 1, 4 * ps - 1], jnp.int32)
     slopes = jnp.asarray(rng.standard_normal(hq) * 0.1, jnp.float32)
-    out = paged_flash_attend(
-        q, kp, vp, jnp.asarray(perm), pos,
-        alibi_slopes=slopes, sliding_window=window, interpret=True,
+    out = composed_paged_attend(
+        q, kp, vp, jnp.asarray(perm), q_offset=pos, kv_length=pos + 1,
+        alibi_slopes=slopes, sliding_window=window,
     )
     ref = paged_attend(
         q, kp, vp, jnp.asarray(perm), pos, alibi_slopes=slopes, sliding_window=window

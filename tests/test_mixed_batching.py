@@ -578,20 +578,29 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
             assert batcher._flush_spawns == spawns + 4
             assert batcher.stats["turnaround_s"] == before
 
-            fast, in_step, step_s = backend.paged_decode_step, threading.Event(), 0.01
+            fast, in_step, queued_behind, step_s = backend.paged_decode_step, threading.Event(), threading.Event(), 0.0
 
-            def slow(*args, **kwargs):  # long enough for the other lane's step to arrive
+            def slow(*args, **kwargs):  # held until the other lane's step has arrived, however busy the host
                 in_step.set()
                 time.sleep(step_s)
+                assert queued_behind.wait(60)
                 return fast(*args, **kwargs)
 
             backend.paged_decode_step = slow
 
-            async def behind_a_step_in_flight(lane_ahead, pos_ahead, lane_behind, pos_behind):
+            async def step_behind_the_one_in_flight(ahead_step, lane_behind, hidden, pos_behind):
+                """Start ``ahead_step``, and once the device holds it, lane_behind's: the step in flight is let go
+                only when that request is in ``_pending``, so the flush task that runs it finds it there."""
                 in_step.clear()
-                ahead = asyncio.create_task(batcher.step(lane_ahead, _hidden(cfg, 20), pos_ahead))
+                queued_behind.clear()
+                ahead = asyncio.create_task(ahead_step)
                 await asyncio.get_running_loop().run_in_executor(None, in_step.wait)
-                await asyncio.gather(ahead, batcher.step(lane_behind, _hidden(cfg, 30), pos_behind))
+                behind = asyncio.create_task(batcher.step(lane_behind, hidden, pos_behind))
+                async with asyncio.timeout(60):
+                    while not any(entry[0] == lane_behind for entry in batcher._pending):
+                        await asyncio.sleep(0)
+                queued_behind.set()
+                return ahead, behind
 
             # two fresh tenants, so neither lane has a return on record and the
             # gather waits for nobody: lane b's step falls in behind lane a's
@@ -600,7 +609,7 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
             batcher.release_lane(b)
             a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
             spawns, waits = batcher._flush_spawns, batcher.stats["gather_waits"]
-            await behind_a_step_in_flight(a, 0, b, 0)
+            await asyncio.gather(*await step_behind_the_one_in_flight(batcher.step(a, _hidden(cfg, 20), 0), b, _hidden(cfg, 30), 0))
             assert batcher._flush_spawns == spawns + 1  # the second step followed the first under one task
             handed_off = batcher.stats["turnaround_s"] - before
             assert handed_off > 0 and batcher.stats["gather_waits"] == waits
@@ -613,10 +622,7 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
                 await batcher.step(a, _hidden(cfg, 40 + i), 1 + i)
                 await asyncio.sleep(0.05)
             spawns, before = batcher._flush_spawns, dict(batcher.stats)
-            in_step.clear()
-            ahead = asyncio.create_task(batcher.step(a, _hidden(cfg, 50), 6))
-            await asyncio.get_running_loop().run_in_executor(None, in_step.wait)
-            behind = asyncio.create_task(batcher.step(b, _hidden(cfg, 51), 1))
+            ahead, behind = await step_behind_the_one_in_flight(batcher.step(a, _hidden(cfg, 50), 6), b, _hidden(cfg, 51), 1)
             await ahead
             await asyncio.sleep(0.05)
             await asyncio.gather(behind, batcher.step(a, _hidden(cfg, 52), 7))

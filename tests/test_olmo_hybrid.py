@@ -602,6 +602,6 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     assert len(pools) == 2 and out.shape == (3, 1, cfg.hidden_size)
     out, chunk, pools = backend.paged_mixed_step(hidden, pools, positions, tables, np.zeros((1, 5, cfg.hidden_size), np.float32), 1, 0)
     assert len(pools) == 2 and chunk.shape == (1, 5, cfg.hidden_size)
-    jaxpr = jax.make_jaxpr(lambda *a: backend._paged_decode_fn.__wrapped__(*a, kernel_path="xla", with_fp=False))(
+    jaxpr = jax.make_jaxpr(lambda *a: backend._paged_decode_fn.__wrapped__(*a, with_fp=False))(
         backend.params, k, v, hidden, positions, tables)
     assert len(jaxpr.out_avals) == 3  # hidden and the two pools: no state rides a span without one

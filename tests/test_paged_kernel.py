@@ -1,10 +1,10 @@
-"""Fused ragged paged-attention kernel (ops/paged_flash_attention.py), run in
-interpret mode on CPU: parity vs the XLA-composed reference
-(gather_pages + attend_reference) across table layouts (dense/identity,
-permuted, holey), ragged lengths (position 0, page boundaries), ALiBi,
-sliding windows, GQA ratios, and chunked prefill; the autotune/dispatch
-decision unit (env override, CPU fallback); and the fingerprint interplay
-(the fused digest must survive the kernel path)."""
+"""Attention over a paged pool (ops/paged_flash_attention.py): a decode row's
+walk, composed and as one kernel (interpreted on the CPU), and the fused
+chunked-prefill kernel (interpreted), each against the reference
+(gather_pages + attend_reference, or NumPy) across table layouts
+(dense/identity, permuted, holey), ragged lengths (position 0, page
+boundaries), ALiBi, sliding windows and GQA ratios; and the dispatch's table:
+which call reaches which implementation."""
 
 import numpy as np
 import pytest
@@ -17,32 +17,15 @@ from petals_tpu.ops.paged_attention import (
     PagedKV,
     gather_pages,
     identity_tables,
-    paged_attend,
     paged_prefill_attend,
 )
-from petals_tpu.ops.paged_flash_attention import (
-    paged_flash_attend,
-    paged_flash_prefill_attend,
-)
-from tests.utils import make_tiny_llama
+from petals_tpu.ops.paged_flash_attention import paged_flash_prefill_attend
 
 pytestmark = pytest.mark.kernel
 
 # the online-softmax accumulation order differs from the reference's one-shot
 # softmax; f32 agreement lands ~1e-6 at these shapes
 TOL = 2e-5
-
-
-@pytest.fixture(scope="module")
-def model_path(tmp_path_factory):
-    return make_tiny_llama(str(tmp_path_factory.mktemp("models")))
-
-
-@pytest.fixture(autouse=True)
-def _fresh_autotune():
-    pfa.reset_paged_autotune()
-    yield
-    pfa.reset_paged_autotune()
 
 
 def _rand_pool(rng, n_pages, ps, hkv, d):
@@ -62,77 +45,6 @@ def _holey_permuted(rng, n_lanes, max_pages, n_pages, used_slots):
     return tables
 
 
-# ------------------------------------------------------------- decode parity
-
-
-def test_decode_parity_identity_and_ragged():
-    """Identity tables (the dense layout) at ragged positions including 0 and
-    page boundaries: kernel vs the XLA-composed reference, and vs
-    attend_reference on the true dense buffer."""
-    rng = np.random.default_rng(0)
-    n_lanes, max_pages, ps, hkv, group, d = 4, 4, 16, 2, 2, 32
-    hq = hkv * group
-    kp, vp = _rand_pool(rng, n_lanes * max_pages, ps, hkv, d)
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    tables = jnp.asarray(identity_tables(n_lanes, max_pages))
-    # position 0, page-boundary-1, page boundary, mid-page
-    pos = jnp.asarray([0, ps - 1, 2 * ps, 3 * ps + 5], jnp.int32)
-
-    out = paged_flash_attend(q, kp, vp, tables, pos, interpret=True)
-    ref = paged_attend(q, kp, vp, tables, pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
-
-    # identity gather == the dense buffer: the kernel also matches plain
-    # attend_reference on the dense view (one attention path, dense included)
-    k_dense = kp.reshape(n_lanes, max_pages * ps, hkv, d)
-    v_dense = vp.reshape(n_lanes, max_pages * ps, hkv, d)
-    dense = attend_reference(q, k_dense, v_dense, q_offset=pos, kv_length=pos + 1)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=TOL, rtol=0)
-
-
-def test_decode_parity_permuted_and_holey():
-    rng = np.random.default_rng(1)
-    n_lanes, max_pages, ps, hkv, group, d = 3, 4, 8, 2, 4, 16
-    hq = hkv * group
-    n_pages = 20  # oversubscribed pool, scattered pages
-    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    pos = np.array([3 * ps - 1, 2 * ps - 1, ps], np.int32)
-    used = [-(-int(p + 1) // ps) for p in pos]
-    tables = _holey_permuted(rng, n_lanes, max_pages, n_pages, used)
-
-    out = paged_flash_attend(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(pos), interpret=True
-    )
-    ref = paged_attend(q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
-
-
-def test_kernel_bit_identical_under_holes():
-    """Unallocated (-1) slots beyond the ragged frontier must not influence
-    the kernel AT ALL: pointing those slots at garbage pages instead must
-    yield BIT-identical output (the kernel never fetches either)."""
-    rng = np.random.default_rng(2)
-    n_lanes, max_pages, ps, hkv, group, d = 2, 4, 8, 2, 2, 16
-    hq = hkv * group
-    n_pages = 16
-    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    pos = jnp.asarray([ps + 3, 2 * ps - 1], jnp.int32)  # lanes use 2 slots each
-
-    holey = _holey_permuted(rng, n_lanes, max_pages, n_pages, [2, 2])
-    garbage = holey.copy()
-    garbage[garbage < 0] = 15  # a live page full of other-tenant bytes
-
-    out_holey = np.asarray(
-        paged_flash_attend(q, kp, vp, jnp.asarray(holey), pos, interpret=True)
-    )
-    out_garbage = np.asarray(
-        paged_flash_attend(q, kp, vp, jnp.asarray(garbage), pos, interpret=True)
-    )
-    np.testing.assert_array_equal(out_holey, out_garbage)
-
-
 def test_gather_pages_zeroes_unallocated_slots():
     """The XLA fallback's dense view must read -1 slots as ZEROS — never page
     0's live bytes (the old behaviour clipped -1 to page 0)."""
@@ -146,47 +58,9 @@ def test_gather_pages_zeroes_unallocated_slots():
     np.testing.assert_array_equal(dense[1], 0.0)
 
 
-@pytest.mark.parametrize("group", [1, 2, 4, 8])
-def test_decode_gqa_ratios(group):
-    rng = np.random.default_rng(3)
-    hq = 8
-    hkv = hq // group
-    n_lanes, max_pages, ps, d = 2, 3, 8, 16
-    n_pages = n_lanes * max_pages
-    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    perm = rng.permutation(n_pages).astype(np.int32).reshape(n_lanes, max_pages)
-    pos = jnp.asarray([2 * ps, 3 * ps - 1], jnp.int32)
-    out = paged_flash_attend(q, kp, vp, jnp.asarray(perm), pos, interpret=True)
-    ref = paged_attend(q, kp, vp, jnp.asarray(perm), pos)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
-
-
-@pytest.mark.parametrize("window", [None, 5, 20])
-def test_decode_alibi_and_window(window):
-    rng = np.random.default_rng(4)
-    n_lanes, max_pages, ps, hkv, group, d = 3, 4, 8, 2, 2, 16
-    hq = hkv * group
-    n_pages = n_lanes * max_pages
-    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    perm = rng.permutation(n_pages).astype(np.int32).reshape(n_lanes, max_pages)
-    pos = jnp.asarray([0, 2 * ps - 1, 4 * ps - 1], jnp.int32)
-    slopes = jnp.asarray(rng.standard_normal(hq) * 0.1, jnp.float32)
-    out = paged_flash_attend(
-        q, kp, vp, jnp.asarray(perm), pos,
-        alibi_slopes=slopes, sliding_window=window, interpret=True,
-    )
-    ref = paged_attend(
-        q, kp, vp, jnp.asarray(perm), pos,
-        alibi_slopes=slopes, sliding_window=window,
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
-
-
 # ------------------------------------------------- the composed path's walk
 
-# n_lanes 4, kv heads 2; a lane's position, or None for an idle lane (the sentinel max_length). ``block``: table
+# a lane a position (None: an idle lane, at the sentinel max_length), kv heads 2 unless ``hkv`` says; ``block``: table
 # slots a block of the walk (the rule gives the whole row at these toy sizes, so the cases set the bytes it goes by)
 WALK_CASES = {
     "ragged-16slots-g1-d64": dict(max_pages=16, group=1, d=64, block=4, positions=[127, 3, 7, 0]),  # the table's end beside one page
@@ -202,6 +76,14 @@ WALK_CASES = {
     "nf4a-pool": dict(max_pages=40, group=1, d=128, block=8, positions=[200, 319, 15, None], kv_quant="nf4a"),
     "alibi": dict(max_pages=16, group=4, d=64, block=4, positions=[100, 3, None, 31], alibi=True),
     "softcap-traced-window": dict(max_pages=16, group=1, d=64, block=4, positions=[100, 3, None, 31], softcap=30.0, traced_window=20),
+    # position 0, a page's last row, a page's first row, mid-page, on the dense layout
+    "identity-tables-at-0-a-page-s-last-and-a-page-s-first": dict(max_pages=4, group=2, d=32, block=2, ps=16, positions=[0, 15, 32, 53], identity=True),
+    # 7 pages for 3 lanes of 4 slots: the lanes' pages lie scattered, the slots they do not fill are holes
+    "oversubscribed-permuted-pool-with-holes": dict(max_pages=4, group=4, d=16, block=2, positions=[23, 15, 8], pool=7),
+    # somebody else's live page in every slot past a lane's end, inside the block that is read: bit the same answer
+    "garbage-pages-past-a-lane-s-end": dict(max_pages=4, group=2, d=16, block=4, positions=[11, 15], garbage=True),
+    **{f"{g}-query-heads-a-kv-head": dict(max_pages=3, group=g, hkv=8 // g, d=16, block=2, positions=[16, 23]) for g in (1, 2, 4, 8)},
+    **{f"alibi-window-{w}": dict(max_pages=4, group=2, d=16, block=2, positions=[0, 15, 31], alibi=True, window=w) for w in (None, 5, 20)},
 }
 
 
@@ -216,10 +98,10 @@ def test_decode_row_walks_its_lane_s_pages_and_gives_the_dense_view_s_answer(cas
     and no NaN comes out (a weight of zero times NaN is NaN)."""
     from petals_tpu.ops.paged_attention import PagedPool, quantize_kv_rows
 
-    n_lanes, hkv, ps = 4, 2, case.get("ps", 8)
+    n_lanes, hkv, ps = len(case["positions"]), case.get("hkv", 2), case.get("ps", 8)
     max_pages, group, d, block = case["max_pages"], case["group"], case["d"], case["block"]
     rng = np.random.default_rng(11)
-    n_pages = n_lanes * max_pages + 1  # the last one is the page of NaN
+    n_pages = case.get("pool", n_lanes * max_pages) + 1  # the last one is the page of NaN
     kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
     kp, vp = kp.at[-1].set(jnp.nan), vp.at[-1].set(jnp.nan)
     idle = np.asarray([p is None for p in case["positions"]])
@@ -227,6 +109,8 @@ def test_decode_row_walks_its_lane_s_pages_and_gives_the_dense_view_s_answer(cas
     held = np.where(idle, 0, pos // ps + 1)
     if case.get("identity"):
         tables = identity_tables(n_lanes, max_pages).copy()
+    elif "pool" in case:
+        tables = _holey_permuted(rng, n_lanes, max_pages, n_pages - 1, held)
     else:
         tables = rng.permutation(n_pages - 1).astype(np.int32).reshape(n_lanes, max_pages)
     for lane in range(n_lanes):
@@ -257,6 +141,10 @@ def test_decode_row_walks_its_lane_s_pages_and_gives_the_dense_view_s_answer(cas
     # a quantised pool reads as bf16, and the weights meet V in V's dtype
     np.testing.assert_allclose(got[~idle], np.asarray(want)[~idle], atol=1e-2 if kind else TOL, rtol=0)
     np.testing.assert_array_equal(got[idle], 0.0)  # an idle lane attends to nothing
+    if case.get("garbage"):
+        other_s = np.where(tables < 0, clean[0, 0], tables)  # lane 0's first page, live and finite, where the holes were
+        assert (other_s != tables).any() and walked > held.min()
+        np.testing.assert_array_equal(got, np.asarray(pfa.composed_paged_attend(q, kp, vp, jnp.asarray(other_s), **kw)))
 
 
 def test_walk_block_follows_the_shapes_it_sees():
@@ -285,6 +173,9 @@ KERNEL_WALK_CASES = {
     "8-query-heads-a-kv-head": dict(max_pages=8, block=4, group=8, positions=[127, 64, 1, None]),
     "32-kv-heads": dict(max_pages=8, block=2, hkv=32, positions=[90, None, 33, 8]),
     "float32-pool": dict(max_pages=8, block=2, hkv=8, dtype="float32", positions=[100, 3, None, 77]),
+    "identity-tables-at-0-a-page-s-last-and-a-page-s-first": dict(max_pages=8, block=2, identity=True, positions=[0, 15, 32, 53]),
+    "garbage-pages-past-a-lane-s-end": dict(max_pages=8, block=4, garbage=True, positions=[19, 40, 5, None]),
+    "2-query-heads-a-kv-head": dict(max_pages=8, block=2, group=2, positions=[16, 23, 100, None]),
 }
 
 
@@ -324,6 +215,8 @@ def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer
     pos = np.asarray([max_pages * ps if p is None else p for p in case["positions"]], np.int32)
     held = np.where(idle, 0, pos // ps + 1)
     owned = rng.permutation(np.arange(1, n_pages)).astype(np.int32)[: n_lanes * max_pages].reshape(n_lanes, max_pages)  # page 0 is nobody's
+    if case.get("identity"):
+        owned = identity_tables(n_lanes, max_pages)
     tables = np.where(np.arange(max_pages)[None, :] < held[:, None], owned, -1).astype(np.int32)
     nobody_s = np.setdiff1d(np.arange(n_pages), tables[tables >= 0])
     kp, vp = kp.at[nobody_s].set(jnp.nan), vp.at[nobody_s].set(jnp.nan)
@@ -346,6 +239,10 @@ def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer
     np.testing.assert_array_equal(got[idle], 0.0)
     composed = np.asarray(pfa.composed_paged_attend(q, kp.at[nobody_s].set(0), vp.at[nobody_s].set(0), jnp.asarray(tables), path="composed", **kw), np.float32)
     np.testing.assert_allclose(got, composed, atol=tol, rtol=0)
+    if case.get("garbage"):  # another lane's live page in the holes of a lane's last block, in place of its own: bit the same
+        other_s = np.where((past < 0) & ~idle[:, None], tables[np.flatnonzero(~idle)[-1], 0], past)
+        assert (other_s != past).any()
+        np.testing.assert_array_equal(got, np.asarray(pfa.composed_paged_attend(q, kp, vp, jnp.asarray(other_s), path="kernel", **kw), np.float32))
 
 
 def _pool_like(shape, dtype=jnp.bfloat16):
@@ -468,7 +365,7 @@ def test_the_decode_walk_the_backend_counts_is_the_one_its_step_is_handed(name, 
         avals.append(tuple(aval(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
     if backend.index_row is not None:
         avals.append(tuple(aval(d.shape, d.dtype) for d in backend.index_cache_descriptors(lanes * slots, page_size)))
-    jax.eval_shape(functools.partial(backend._paged_decode_fn.__wrapped__, kernel_path="xla", with_fp=False), *avals)
+    jax.eval_shape(functools.partial(backend._paged_decode_fn.__wrapped__, with_fp=False), *avals)
     assert set(asked) == counted, (sorted(map(str, asked)), sorted(map(str, counted)))
     # a latent row's walk is its own (ops/latent_attention.py); a row that chooses its positions fetches them one by one
     assert bool(counted) == (name not in ("deepseek_v3", "KeyeVL2")), "the step's attention never reached the decode walk"
@@ -514,20 +411,14 @@ def test_prefill_parity(chunk_pos, n_valid, window):
 def test_parity_when_several_heads_share_a_block():
     """Heads narrower than 128 lanes ride ``128 // d`` to a KV block (the
     shape Mosaic needs for d=64 families): each grid step must slice ITS
-    head out of the block, for decode and for the prefill twin."""
+    head out of the block."""
     rng = np.random.default_rng(11)
     n_lanes, max_pages, ps, hkv, group, d = 3, 4, 8, 4, 2, 64
     assert pfa._kv_heads_per_block(hkv, d) == 2
     hq = hkv * group
     n_pages = 16
     kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
-    pos = np.array([3 * ps - 1, ps + 2, 0], np.int32)
-    used = [-(-int(p + 1) // ps) for p in pos]
-    tables = jnp.asarray(_holey_permuted(rng, n_lanes, max_pages, n_pages, used))
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    out = paged_flash_attend(q, kp, vp, tables, jnp.asarray(pos), interpret=True)
-    ref = paged_attend(q, kp, vp, tables, jnp.asarray(pos))
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
+    tables = jnp.asarray(_holey_permuted(rng, n_lanes, max_pages, n_pages, [3, 2, 1]))
 
     qc = jnp.asarray(rng.standard_normal((1, 16, hq, d)), jnp.float32)
     cp, nv = jnp.int32(8), jnp.int32(13)
@@ -538,150 +429,94 @@ def test_parity_when_several_heads_share_a_block():
     )
 
 
-# ------------------------------------------------- autotune / dispatch unit
+# ------------------------------------------------------- the dispatch's table
 
 
-def test_kernel_mode_env_override(monkeypatch):
-    monkeypatch.delenv(pfa._ENV_VAR, raising=False)
-    assert pfa.kernel_mode() == "auto"
-    key = pfa.shape_class(2, 4, 8, 2, 16, None)
-    # CPU + auto: guaranteed XLA fallback
-    assert pfa.decide_paged_kernel("decode", key) is False
-    assert pfa.resolve_paged_kernel_path("decode", key) == "xla"
-    monkeypatch.setenv(pfa._ENV_VAR, "pallas")
-    assert pfa.decide_paged_kernel("decode", key) is True
-    monkeypatch.setenv(pfa._ENV_VAR, "xla")
-    assert pfa.decide_paged_kernel("decode", key) is False
-    monkeypatch.setenv(pfa._ENV_VAR, "bogus")
-    with pytest.raises(ValueError):
-        pfa.kernel_mode()
+def _dispatch_case(rows="decode", platform="tpu", d=128, **call):
+    return dict(rows=rows, platform=platform, d=d, call=call)
 
 
-def test_autotune_decision_cache(monkeypatch):
-    """On (fake) TPU in auto mode the cached per-shape decision is honored;
-    untuned shapes default to the kernel and prefill inherits the decode
-    decision for its shape class."""
-    monkeypatch.delenv(pfa._ENV_VAR, raising=False)
-    monkeypatch.setattr(pfa, "_platform", lambda: "tpu")
-    key = pfa.shape_class(2, 4, 8, 2, 128, None)
-    other = pfa.shape_class(8, 4, 8, 2, 128, None)
-    assert pfa.decide_paged_kernel("decode", key) is True  # untuned default
-    pfa.set_paged_kernel_decision("decode", key, False)
-    assert pfa.decide_paged_kernel("decode", key) is False
-    assert pfa.decide_paged_kernel("prefill", key) is False  # inherits decode
-    assert pfa.decide_paged_kernel("decode", other) is True  # per-shape
-    # maybe_autotune is a no-op for an already-decided class (returns it)
-    assert (
-        pfa.maybe_autotune_paged_attention(
-            n_lanes=2, max_pages=4, page_size=8, hkv=2, d=128
-        )
-        is False
-    )
+# {decode row, chunk} x {cpu, tpu} x {a class Mosaic takes, head_dim 80}, then what the prefill kernel cannot express
+DISPATCH_CASES = {
+    **{
+        f"{rows}-{platform}-d{d}": _dispatch_case(rows, platform, d)
+        for rows in ("decode", "chunk") for platform in ("cpu", "tpu") for d in (128, 80)
+    },
+    "verify-rows-tpu": _dispatch_case("verify"),
+    "chunk-tpu-soft-cap": _dispatch_case("chunk", logit_softcap=30.0),
+    "chunk-tpu-traced-window": _dispatch_case("chunk", sliding_window="traced"),
+    "chunk-tpu-non-causal": _dispatch_case("chunk", causal=False),
+    "chunk-tpu-no-kv-length": _dispatch_case("chunk", kv_length=None),
+}
 
 
-@pytest.mark.parametrize(
-    "pallas_ms, xla_ms, kernel",
-    [
-        (0.448, 0.452, False),  # Falcon-40B's class on the v5e: a tie in the harness
-        (0.452, 0.448, False),  # ... and the same tie read the other way round
-        (0.41, 0.45, False),  # faster, but inside the margin
-        (0.40, 0.45, True),  # faster by more than the margin
-        (0.50, 0.27, False),  # Mixtral-8x7B's class: the composed path by far
-    ],
-)
-def test_autotune_tie_goes_to_the_composed_path(pallas_ms, xla_ms, kernel):
-    """Two starts of one server must run the same step program: timings the
-    harness cannot tell apart give the composed path, whichever reads lower,
-    and the kernel takes a class only by ``KERNEL_MUST_WIN_BY``."""
-    assert pfa.kernel_wins(pallas_ms * 1e-3, xla_ms * 1e-3) is kernel
-
-
-def test_unsupported_shape_class_is_gated_off_the_kernel(monkeypatch, caplog):
-    """On a TPU in auto mode a head width Mosaic cannot tile (neither a lane
-    multiple nor packing evenly into 128 lanes) composes from XLA by a static
-    predicate — decided before any compile, warned once, never autotuned;
-    the explicit override still reaches the kernel (interpreter tests)."""
+@pytest.mark.parametrize("case", DISPATCH_CASES.values(), ids=DISPATCH_CASES.keys())
+def test_dispatch_sends_a_decode_row_to_the_walk_and_a_chunk_to_the_kernel_where_it_can_run(case, monkeypatch, caplog):
+    """``paged_attend_dispatch``'s table, the callees replaced by recorders:
+    per-lane positions (a decode row, a verify's rows) reach
+    ``composed_paged_attend`` on every platform, with the span's pool as the
+    step carries it (``own_layer`` is never asked); a scalar position (a
+    chunk) reaches ``paged_flash_prefill_attend`` with the block's own layer
+    on a TPU, for a call the kernel can express and a class Mosaic can tile,
+    and a class it cannot is warned of once."""
     import logging
 
-    monkeypatch.delenv(pfa._ENV_VAR, raising=False)
-    monkeypatch.setattr(pfa, "_platform", lambda: "tpu")
+    rows, d, call = case["rows"], case["d"], dict(case["call"])
+    lanes, hkv, ps, max_pages = (3 if rows != "chunk" else 1), 2, 8, 4
+    q_len = {"decode": 1, "verify": 3, "chunk": 16}[rows]
+    pool = jnp.zeros((lanes * max_pages, ps, hkv, d), jnp.float32)
+    tables = jnp.asarray(identity_tables(lanes, max_pages))
+    q = jnp.zeros((lanes, q_len, hkv, d), jnp.float32)
+    pos = jnp.int32(8) if rows == "chunk" else jnp.asarray([8, 9, 10], jnp.int32)
+    if call.get("sliding_window") == "traced":
+        call["sliding_window"] = jnp.int32(20)
+    call.setdefault("kv_length", pos + q_len)
+    reached, own_layers = [], []
+
+    def recorder(name):
+        def record(q, *args, **kw):
+            reached.append(name)
+            return jnp.zeros_like(q)
+        return record
+
+    def own_layer(self):
+        own_layers.append(self)
+        return self
+
+    monkeypatch.setattr(pfa, "_platform", lambda: case["platform"])
     monkeypatch.setattr(pfa, "_WARNED_UNSUPPORTED", set())
-    key = pfa.shape_class(2, 4, 8, 2, 16, None)  # the tiny interpreter shape
-    assert pfa.paged_kernel_unsupported(key) is not None
-    for packed in (
-        pfa.shape_class(8, 16, 64, 8, 64, None),  # two d=64 heads to a block
-        pfa.shape_class(8, 16, 64, 32, 128, None, "nf4a"),  # two packed heads
-        pfa.shape_class(8, 16, 64, 1, 64, None),  # MQA: the block is the whole row
-    ):
-        assert pfa.paged_kernel_unsupported(packed) is None
+    monkeypatch.setattr(pfa, "composed_paged_attend", recorder("composed"))
+    monkeypatch.setattr(pfa, "paged_flash_prefill_attend", recorder("prefill kernel"))
+    monkeypatch.setattr(PagedKV, "own_layer", own_layer)
     logging.getLogger("petals_tpu").propagate = True
     try:
         with caplog.at_level(logging.WARNING, logger="petals_tpu"):
-            assert pfa.decide_paged_kernel("decode", key) is False
-            assert pfa.decide_paged_kernel("prefill", key) is False
+            for _ in range(2):
+                attend(q, PagedKV(pool, tables), PagedKV(pool, tables), q_offset=pos, **call)
     finally:
         logging.getLogger("petals_tpu").propagate = False
-    assert sum("excluded for shape class" in r.message for r in caplog.records) == 1
-    assert pfa.maybe_autotune_paged_attention(
-        n_lanes=2, max_pages=4, page_size=8, hkv=2, d=16
-    ) is False
-    assert pfa._AUTOTUNE == {}  # gated, not tuned
-    monkeypatch.setenv(pfa._ENV_VAR, "pallas")
-    assert pfa.decide_paged_kernel("decode", key) is True
+
+    kernel = rows == "chunk" and case["platform"] == "tpu" and d == 128 and not case["call"]
+    assert reached == ["prefill kernel" if kernel else "composed"] * 2
+    assert len(own_layers) == (4 if kernel else 0)  # keys and values, twice
+    refused = rows == "chunk" and case["platform"] == "tpu" and d == 80
+    assert sum("prefill kernel excluded" in r.message for r in caplog.records) == (1 if refused else 0)
 
 
-def test_autotune_noop_off_tpu(monkeypatch):
-    """CPU: maybe_autotune must not time anything and must leave the decision
-    at the guaranteed XLA fallback."""
-    monkeypatch.delenv(pfa._ENV_VAR, raising=False)
-    assert (
-        pfa.maybe_autotune_paged_attention(
-            n_lanes=2, max_pages=4, page_size=8, hkv=2, d=16
-        )
-        is False
-    )
-    assert pfa._AUTOTUNE == {}  # nothing recorded: not tuned, just fallback
-
-
-def test_dispatch_env_override_decode_and_prefill(monkeypatch):
-    """attend() on a PagedKV honors the env override at trace time: pallas
-    and xla paths agree numerically for both the decode (vector positions)
-    and prefill (scalar position) contracts."""
-    rng = np.random.default_rng(6)
-    n_lanes, max_pages, ps, hkv, group, d = 2, 3, 8, 2, 2, 16
-    hq = hkv * group
-    n_pages = n_lanes * max_pages
-    kp, vp = _rand_pool(rng, n_pages, ps, hkv, d)
-    perm = rng.permutation(n_pages).astype(np.int32).reshape(n_lanes, max_pages)
-    k_kv, v_kv = PagedKV(kp, jnp.asarray(perm)), PagedKV(vp, jnp.asarray(perm))
-
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hq, d)), jnp.float32)
-    pos = jnp.asarray([ps + 1, 2 * ps - 1], jnp.int32)
-    outs = {}
-    for mode in ("pallas", "xla"):
-        monkeypatch.setenv(pfa._ENV_VAR, mode)
-        outs[mode] = np.asarray(
-            attend(q, k_kv, v_kv, q_offset=pos, kv_length=pos + 1)
-        )
-    np.testing.assert_allclose(outs["pallas"], outs["xla"], atol=TOL, rtol=0)
-
-    B, nv, cp = 16, 11, 0
-    qc = jnp.asarray(rng.standard_normal((1, B, hq, d)), jnp.float32)
-    k1, v1 = PagedKV(kp, jnp.asarray(perm[:1])), PagedKV(vp, jnp.asarray(perm[:1]))
-    outs = {}
-    for mode in ("pallas", "xla"):
-        monkeypatch.setenv(pfa._ENV_VAR, mode)
-        outs[mode] = np.asarray(
-            attend(qc, k1, v1, q_offset=jnp.int32(cp), kv_length=jnp.int32(cp + nv))
-        )[:, :nv]
-    np.testing.assert_allclose(outs["pallas"], outs["xla"], atol=TOL, rtol=0)
+def test_unsupported_shape_class_is_gated_off_the_kernel():
+    """A head width Mosaic cannot tile (neither a lane multiple nor packing
+    evenly into 128 lanes) is refused by a static predicate, decided before
+    any compile; the classes the cells' pools have are taken."""
+    assert pfa.paged_kernel_unsupported(2, 16) is not None  # the tiny interpreter shape
+    assert pfa.paged_kernel_unsupported(8, 64) is None  # two d=64 heads to a block
+    assert pfa.paged_kernel_unsupported(32, 128, "nf4a") is None  # two packed heads
+    assert pfa.paged_kernel_unsupported(1, 64) is None  # MQA: the block is the whole row
 
 
 def test_dispatch_forces_xla_for_softcap_and_traced_window():
     """Kernel-inexpressible requests (gemma2's logit softcap, traced
-    effective window) must compose from XLA even under forced pallas: a
-    decode row then takes the walk, the gather/attend sandwich's math summed
-    block by block."""
+    effective window) compose from XLA: a decode row then takes the walk, the
+    gather/attend sandwich's math summed block by block."""
     rng = np.random.default_rng(7)
     n_lanes, max_pages, ps, hkv, d = 2, 2, 8, 2, 16
     n_pages = n_lanes * max_pages
@@ -690,126 +525,14 @@ def test_dispatch_forces_xla_for_softcap_and_traced_window():
     k_kv, v_kv = PagedKV(kp, tables), PagedKV(vp, tables)
     q = jnp.asarray(rng.standard_normal((n_lanes, 1, hkv, d)), jnp.float32)
     pos = jnp.asarray([ps, ps + 3], jnp.int32)
-    import os
-
-    os.environ[pfa._ENV_VAR] = "pallas"
-    try:
-        traced_window = jnp.int32(1000)  # gemma2-style traced effective window
-        out = attend(
-            q, k_kv, v_kv, q_offset=pos, kv_length=pos + 1,
-            sliding_window=traced_window, logit_softcap=30.0,
-        )
-        k_dense, v_dense = gather_pages(kp, tables), gather_pages(vp, tables)
-        ref = attend_reference(
-            q, k_dense, v_dense, q_offset=pos, kv_length=pos + 1,
-            sliding_window=traced_window, logit_softcap=30.0,
-        )
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)
-    finally:
-        os.environ.pop(pfa._ENV_VAR, None)
-
-
-# -------------------------------------------------- backend step integration
-
-
-def _tiny_backend(model_path):
-    import jax
-
-    from petals_tpu.server.backend import TransformerBackend
-    from petals_tpu.server.from_pretrained import get_block_config, load_block_params
-    from petals_tpu.server.memory_cache import MemoryCache
-
-    family, cfg = get_block_config(model_path)
-    per_block = [
-        load_block_params(model_path, i, dtype=jnp.float32, family=family, cfg=cfg)
-        for i in range(2)
-    ]
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *per_block)
-    return TransformerBackend(
-        family, cfg, stacked, first_block=0, n_blocks=2,
-        memory_cache=MemoryCache(None), compute_dtype=jnp.float32, use_flash=False,
-    ), cfg
-
-
-def _seeded_paged_state(backend, cfg, rng, L, PS, MAX_PAGES):
-    """Prefill some per-lane history through the exclusive path, then scatter
-    it into a page pool under a permuted table."""
-    MAXLEN = PS * MAX_PAGES
-    positions = np.array([5, 0, 2 * PS], np.int32)[:L]
-    hidden = rng.standard_normal((L, 1, cfg.hidden_size)).astype(np.float32) * 0.1
-    kd, vd = backend.cache_descriptors(1, MAXLEN, 0, 2)
-    lanes_kv = []
-    for l in range(L):
-        kv = (kd.make_zeros(), vd.make_zeros())
-        if positions[l]:
-            pre = rng.standard_normal((1, positions[l], cfg.hidden_size)).astype(np.float32) * 0.1
-            _, kv = backend.inference_step(pre, kv, 0)
-        lanes_kv.append((np.asarray(kv[0]), np.asarray(kv[1])))
-    k_dense = np.concatenate([kv[0] for kv in lanes_kv], axis=1)
-    v_dense = np.concatenate([kv[1] for kv in lanes_kv], axis=1)
-
-    n_pages = L * MAX_PAGES + 4
-    tables = np.full((L, MAX_PAGES), -1, np.int32)
-    free = list(np.random.default_rng(99).permutation(n_pages))
-    for l in range(L):
-        n_slots = max(1, -(-int(positions[l] + 1) // PS))
-        for s in range(n_slots):
-            tables[l, s] = free.pop()
-    n_blocks, _, _, hkv, hd = k_dense.shape
-    kp = np.zeros((n_blocks, n_pages, PS, hkv, hd), np.float32)
-    vp = np.zeros_like(kp)
-    for l in range(L):
-        for s in range(MAX_PAGES):
-            page = tables[l, s]
-            if page < 0:
-                continue
-            kp[:, page] = k_dense[:, l, s * PS : (s + 1) * PS]
-            vp[:, page] = v_dense[:, l, s * PS : (s + 1) * PS]
-    return hidden, jnp.asarray(kp), jnp.asarray(vp), positions, tables
-
-
-def test_paged_decode_step_env_parity(model_path, monkeypatch):
-    """The production paged decode step under PETALS_TPU_PAGED_KERNEL=pallas
-    (interpret-mode kernel inside the jitted scan) matches the xla path —
-    the static kernel_path argument retraces between modes on ONE backend."""
-    backend, cfg = _tiny_backend(model_path)
-    rng = np.random.default_rng(8)
-    hidden, kp, vp, positions, tables = _seeded_paged_state(
-        backend, cfg, rng, L=3, PS=8, MAX_PAGES=4
+    traced_window = jnp.int32(1000)  # gemma2-style traced effective window
+    out = attend(
+        q, k_kv, v_kv, q_offset=pos, kv_length=pos + 1,
+        sliding_window=traced_window, logit_softcap=30.0,
     )
-    kp_host, vp_host = np.asarray(kp), np.asarray(vp)
-    outs = {}
-    for mode in ("xla", "pallas"):
-        monkeypatch.setenv(pfa._ENV_VAR, mode)
-        # the step donates the pool buffers: each mode gets its own copy
-        out, _ = backend.paged_decode_step(
-            hidden, (jnp.asarray(kp_host), jnp.asarray(vp_host)), positions, tables
-        )
-        outs[mode] = np.asarray(out)
-    np.testing.assert_allclose(outs["pallas"], outs["xla"], atol=1e-4, rtol=0)
-
-
-def test_fingerprint_survives_kernel_path(model_path, monkeypatch):
-    """with_fp interplay: the fused integrity digest computed INSIDE the
-    kernel-path program must match the digest the client re-derives from the
-    step's output rows (the PR 8 verification contract)."""
-    from petals_tpu.ops import fingerprint as fp_ops
-
-    backend, cfg = _tiny_backend(model_path)
-    rng = np.random.default_rng(9)
-    hidden, kp, vp, positions, tables = _seeded_paged_state(
-        backend, cfg, rng, L=3, PS=8, MAX_PAGES=4
+    k_dense, v_dense = gather_pages(kp, tables), gather_pages(vp, tables)
+    ref = attend_reference(
+        q, k_dense, v_dense, q_offset=pos, kv_length=pos + 1,
+        sliding_window=traced_window, logit_softcap=30.0,
     )
-    monkeypatch.setenv(pfa._ENV_VAR, "pallas")
-    fp_ops.set_enabled(True)
-    try:
-        out, _ = backend.paged_decode_step(hidden, (kp, vp), positions, tables)
-        fp = backend._last_step_fp
-        assert fp is not None
-        proj = fp_ops.projection(cfg.hidden_size)
-        rederived = fp_ops.fingerprint_rows(jnp.asarray(out)[:, -1, :], proj)
-        np.testing.assert_allclose(
-            np.asarray(fp), np.asarray(rederived), atol=fp_ops.TOL_EXACT, rtol=0
-        )
-    finally:
-        fp_ops.set_enabled(False)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=TOL, rtol=0)

@@ -179,29 +179,37 @@ def test_step_writes_what_the_per_layer_program_wrote(model_path, program, kind)
 
 
 @pytest.mark.parametrize("program", ["decode", "mixed"])
-def test_fused_kernel_arm_reads_its_own_layer(model_path, monkeypatch, program):
-    """With the fused kernel forced (the interpreter, here), each block's
-    attention takes its own layer out of the carried pool and reads it through
-    the lanes' own tables: the step agrees with the per-layer program composed
-    from XLA in its outputs and in every row it wrote, on every layer."""
+def test_prefill_kernel_arm_reads_its_own_layer(model_path, program):
+    """On a TPU (faked; the kernel is the interpreter's here, which takes the
+    toy's head width too) a prompt's chunk takes the fused prefill kernel:
+    each block's attention then takes its own layer out of the carried pool
+    and reads it through the lane's own table, and the step agrees with the
+    per-layer program composed from XLA in its outputs and in every row it
+    wrote, on every layer. A decode step asks for no layer of its own: its
+    rows walk the span's pool as the loop carries it."""
     from petals_tpu.ops import paged_flash_attention as pfa
+    from petals_tpu.ops.paged_attention import PagedKV
 
     backend, family, cfg = _backend(model_path, "none")
     pool, positions, tables, hidden, rng = _state(backend, cfg, "none", seed=31)
     k_pool, v_pool = pool(), pool()
     copies = jax.tree_util.tree_map(jnp.copy, (k_pool, v_pool))
     chunk_hidden = rng.standard_normal((1, 8, cfg.hidden_size)).astype(np.float32) * 0.1
-    monkeypatch.setenv(pfa._ENV_VAR, "pallas")
-    if program == "decode":
-        out, (k_new, v_new) = backend.paged_decode_step(hidden, (k_pool, v_pool), positions, tables)
-        got = [out]
-    else:
-        out, chunk_out, (k_new, v_new) = backend.paged_mixed_step(
-            hidden, (k_pool, v_pool), positions, tables, chunk_hidden[:, :CHUNK], CHUNK_LANE, CHUNK_POS
-        )
-        got = [out, chunk_out]
-        chunk_hidden[:, CHUNK:] = 0.0  # the bucket's padding
-    monkeypatch.setenv(pfa._ENV_VAR, "xla")
+    own_layers, own_layer = [], PagedKV.own_layer
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pfa, "_platform", lambda: "tpu")
+        patch.setattr(pfa, "paged_kernel_unsupported", lambda *cls: None)
+        patch.setattr(PagedKV, "own_layer", lambda self: own_layers.append(self.layer) or own_layer(self))
+        if program == "decode":
+            out, (k_new, v_new) = backend.paged_decode_step(hidden, (k_pool, v_pool), positions, tables)
+            got = [out]
+        else:
+            out, chunk_out, (k_new, v_new) = backend.paged_mixed_step(
+                hidden, (k_pool, v_pool), positions, tables, chunk_hidden[:, :CHUNK], CHUNK_LANE, CHUNK_POS
+            )
+            got = [out, chunk_out]
+            chunk_hidden[:, CHUNK:] = 0.0  # the bucket's padding
+    assert bool(own_layers) == (program == "mixed")
     want, k_want, v_want = _reference(
         backend, family, cfg, program, *copies, hidden, positions, tables, chunk_hidden
     )
@@ -216,7 +224,7 @@ def test_fused_kernel_arm_reads_its_own_layer(model_path, monkeypatch, program):
 
 
 def test_own_layer_is_the_layer_and_its_tables():
-    """``PagedKV.own_layer`` (what the fused kernel's arm takes): layer 1 of a
+    """``PagedKV.own_layer`` (what the prefill kernel's arm takes): layer 1 of a
     three-layer span pool, and the tables as they were before the shift."""
     rng = np.random.default_rng(3)
     span = jnp.asarray(rng.standard_normal((3 * N_PAGES, PS, 2, 16)).astype(np.float32))
