@@ -1,7 +1,7 @@
 """Mixture-of-experts feed-forward shared by the families that route
-(mixtral, olmoe, exaone_moe): a router over the published number of experts,
-the top k kept, SwiGLU experts stacked ``w1``/``w3`` [E, h, m] and ``w2``
-[E, m, h]. The routing rule is data (``Routing``): a softmax whose kept
+(mixtral, olmoe, exaone_moe, keye_vl2, deepseek_v3, qwen3_next): a router
+over the published number of experts, the top k kept, SwiGLU experts stacked
+``w1``/``w3`` [E, h, m] and ``w2`` [E, m, h]. The routing rule is data (``Routing``): a softmax whose kept
 weights are renormalised or not, or a sigmoid whose choice a bias moves and
 whose kept weights are renormalised and scaled.
 
@@ -12,8 +12,9 @@ returns, a token, the part of the result its held experts give: an
 assignment to an absent expert is dropped, in both dispatches alike (the
 chips that hold the rest add theirs; that exchange is not this module's). A
 shared expert (``ws1``/``ws3`` [h, m], ``ws2`` [m, h]) runs for every token
-and is added whole. With every expert held nothing is dropped and the bits
-are what they were before a share could be told.
+and is added whole, or scaled by a sigmoid gate of the token where the
+parameters have one (``wsg`` [h, 1]). With every expert held nothing is
+dropped and the bits are what they were before a share could be told.
 
 Three dispatches share the routing:
 
@@ -253,12 +254,19 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
 
 
 def _add_shared(params: dict, x: jnp.ndarray, routed: jnp.ndarray) -> jnp.ndarray:
-    """The shared expert's SwiGLU over every token, added whole (a family
-    without one: ``routed`` as it came)."""
+    """The shared expert's SwiGLU over every token, added whole, or, where
+    the parameters have a gate for it (``wsg`` [h, 1]), scaled a token by
+    ``sigmoid(x wsg)`` (a family without a shared expert: ``routed`` as it
+    came)."""
     if "ws1" not in params:
         return routed
     with jax.named_scope("ptu.moe.shared"):
-        return routed + mm(silu(mm(x, params["ws1"])) * mm(x, params["ws3"]), params["ws2"])
+        shared = mm(silu(mm(x, params["ws1"])) * mm(x, params["ws3"]), params["ws2"])
+        if "wsg" not in params:
+            return routed + shared
+    with jax.named_scope("ptu.moe.shared_gate"):
+        gate = jax.nn.sigmoid(mm(x, params["wsg"]).astype(jnp.float32))
+        return routed + (gate * shared).astype(shared.dtype)
 
 
 # What the rule below reckons with, measured on one TPU v5e (PERF.md section 6,

@@ -10,7 +10,8 @@ index, three ``linear_attention`` to every ``full_attention``:
   norm over the WHOLE projected q and k (all heads together, as OLMoE's), no
   rotary embedding (``rope_theta`` is null as published): the causal mask is
   the only positional signal.
-- ``linear_attention``: the gated delta rule (ops/linear_attention.py). It
+- ``linear_attention``: the gated delta rule (models/gated_delta.py, the mixer
+  this family shares with qwen3_next, over ops/linear_attention.py). It
   caches no keys and values. A lane holds, a layer, a STATE of fixed size
   whatever the context: a float32 matrix of ``d_k x d_v`` a head, and the last
   ``K - 1`` rows of the short conv's input. ``block_state`` declares both to
@@ -25,7 +26,6 @@ Norms sit on each sublayer's OUTPUT: ``h = x + n1(mixer(x)); y = h + n2(mlp(h))`
 
 from __future__ import annotations
 
-import math
 from typing import Optional, Tuple
 
 import jax
@@ -33,62 +33,27 @@ import jax.numpy as jnp
 import numpy as np
 
 from petals_tpu.models.common import mm, project_heads, rms_norm, silu, update_kv_cache
+from petals_tpu.models.gated_delta import MixerDims, gated_delta_mixer, state_shapes
 from petals_tpu.models.olmo_hybrid.config import FULL, LINEAR, OlmoHybridBlockConfig
 from petals_tpu.models.registry import ModelFamily, register_family
 from petals_tpu.ops.attention import attend
-from petals_tpu.ops.linear_attention import causal_conv, gated_delta
 
 
 def block_kind(cfg: OlmoHybridBlockConfig, block_index: int) -> str:
     return cfg.layer_types[block_index]
 
 
+def mixer_dims(cfg: OlmoHybridBlockConfig) -> MixerDims:
+    """As many key heads as value heads, beta doubled where ``linear_allow_neg_eigval``."""
+    return MixerDims(cfg.linear_num_heads, cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim,
+                     cfg.linear_conv_kernel_dim, 2.0 if cfg.linear_allow_neg_eigval else 1.0)
+
+
 def block_state(cfg: OlmoHybridBlockConfig, kind: str) -> Optional[tuple]:
     """What a lane holds for a block of ``kind`` in place of pages of keys and
     values: ``((shape, dtype), ...)`` a lane, dtype None for the cache's own.
     None for a block that keeps keys and values."""
-    if kind != LINEAR:
-        return None
-    heads, d_k, d_v = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
-    return (((heads, d_k, d_v), jnp.float32), ((cfg.linear_conv_kernel_dim - 1, cfg.linear_conv_channels), None))
-
-
-def _l2_norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
-    return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
-
-
-def _linear_attention(params: dict, x: jnp.ndarray, state, position, cfg, n_valid, live_rows):
-    """The mixer of a linear layer over ``x`` [batch, seq, hidden] from
-    ``state`` on: (its output, the state after it)."""
-    batch, seq, _ = x.shape
-    heads, d_k, d_v = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
-    u = jnp.concatenate([mm(x, params[name]) for name in ("wq", "wk", "wv")], axis=-1)
-    if state is None:  # no cache: a whole sequence from its start
-        matrix = jnp.zeros((batch, heads, d_k, d_v), jnp.float32)
-        tail = jnp.zeros((batch, cfg.linear_conv_kernel_dim - 1, u.shape[-1]), u.dtype)
-    else:
-        fresh = jnp.broadcast_to(jnp.asarray(position, jnp.int32) == 0, (batch,))
-        matrix = jnp.where(fresh[:, None, None, None], 0.0, state[0])
-        tail = jnp.where(fresh[:, None, None], jnp.zeros((), state[1].dtype), state[1])
-    mixed, tail = causal_conv(u, tail, params["conv"], n_valid)
-    q, k, v = jnp.split(mixed, (heads * d_k, 2 * heads * d_k), axis=-1)
-    q = _l2_norm(q.reshape(batch, seq, heads, d_k)) * (1.0 / math.sqrt(d_k))
-    k = _l2_norm(k.reshape(batch, seq, heads, d_k))
-    v = v.reshape(batch, seq, heads, d_v)
-    beta = jax.nn.sigmoid(mm(x, params["wb"]).astype(jnp.float32)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
-    decay = -jnp.exp(params["a_log"].astype(jnp.float32))
-    g = decay * jax.nn.softplus(mm(x, params["wa"]).astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
-    matrix, out = gated_delta(matrix, q, k, v, g, beta, n_valid)
-    with jax.named_scope("ptu.linattn.gate_norm"):
-        gate = silu(mm(x, params["wz"]).astype(jnp.float32)).reshape(batch, seq, heads, d_v)
-        out = (rms_norm(out, params["o_norm"], cfg.rms_norm_eps) * gate).astype(x.dtype)
-    y = mm(out.reshape(batch, seq, heads * d_v), params["wo"])
-    if state is None:
-        return y, None
-    if live_rows is not None:  # an idle lane's state stays as it was
-        matrix = jnp.where(live_rows[:, None, None, None], matrix, state[0])
-        tail = jnp.where(live_rows[:, None, None], tail, state[1])
-    return y, (matrix, tail.astype(state[1].dtype))
+    return state_shapes(mixer_dims(cfg)) if kind == LINEAR else None
 
 
 def _full_attention(params: dict, x: jnp.ndarray, kv, position, cfg, n_valid, use_flash, tp_mesh):
@@ -121,7 +86,7 @@ def block_apply(
     live_rows=None,  # bool [batch] from a lane pool's step: the rows that are not idle lanes (None: all)
 ) -> Tuple[jnp.ndarray, Optional[tuple]]:
     if kind == LINEAR:
-        mixed, new_kv = _linear_attention(params, hidden_states, kv, position, cfg, n_valid, live_rows)
+        mixed, new_kv = gated_delta_mixer(params, hidden_states, kv, position, mixer_dims(cfg), cfg.rms_norm_eps, n_valid, live_rows)
     else:
         mixed, new_kv = _full_attention(params, hidden_states, kv, position, cfg, n_valid, use_flash, tp_mesh)
     hidden_states = hidden_states + rms_norm(mixed, params["ln1"], cfg.rms_norm_eps)
@@ -174,7 +139,7 @@ def block_param_shapes(cfg: OlmoHybridBlockConfig, kind: str, dtype=jnp.bfloat16
     shapes.update(
         wq=S((h, heads * d_k), dtype), wk=S((h, heads * d_k), dtype), wv=S((h, heads * d_v), dtype),
         wz=S((h, heads * d_v), dtype), wa=S((h, heads), dtype), wb=S((h, heads), dtype), wo=S((heads * d_v, h), dtype),
-        conv=S((cfg.linear_conv_kernel_dim, cfg.linear_conv_channels), dtype),
+        conv=S((cfg.linear_conv_kernel_dim, mixer_dims(cfg).channels), dtype),
         a_log=S((heads,), dtype), dt_bias=S((heads,), dtype), o_norm=S((d_v,), dtype),
     )
     return shapes

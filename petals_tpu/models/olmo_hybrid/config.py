@@ -37,11 +37,6 @@ class OlmoHybridBlockConfig:
         tests/test_kernels_lower_tpu.py); 32 heads keep the pool in the layout the programs write."""
         return -(-self.num_key_value_heads // 8) * 8
 
-    @property
-    def linear_conv_channels(self) -> int:
-        """q, k and v of every head side by side: what the short conv runs over."""
-        return self.linear_num_heads * (2 * self.linear_key_head_dim + self.linear_value_head_dim)
-
     @classmethod
     def from_hf_config(cls, hf_config) -> "OlmoHybridBlockConfig":
         get = lambda key, default=None: getattr(hf_config, key, default)

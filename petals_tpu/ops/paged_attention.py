@@ -93,6 +93,7 @@ KV_QUANT_KINDS = ("none", "int8", "nf4a")
 
 #: a row of fewer elements than a vector register's 128 lanes is stored folded
 LANES = 128
+MIN_ROW_HEADS = 4  # a pool row of fewer kv heads is stored folded (``stored_row``)
 
 
 def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
@@ -109,13 +110,19 @@ def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
     ``(hkv * d_store,)``: the same bytes in the same order, the layout the
     step programs are handed is the one they compute in, and the prefill
     kernel's lane-trailing view (ops/paged_flash_attention.py
-    ``_pool_views``) is the array itself. A row of 128 lanes or more keeps
-    ``(hkv, d_store)``. Scales stay ``[..., hkv]`` either way.
+    ``_pool_views``) is the array itself. So is a row of fewer than
+    ``MIN_ROW_HEADS`` heads, whatever their width: two kv heads of 256 are two
+    rows of a tile of 16, the pool of ``[..., 64, 2, 256]`` was kept in a
+    layout of the compiler's own and both pools copied whole in ``ENTRY`` on
+    the way in and on the way out of every step program (PR 48, compiled for
+    the v5e at 2 x 256; 4 heads of 128 and 8 are handed over as they lie, so
+    the rule stops under 4). Any other row keeps ``(hkv, d_store)``. Scales
+    stay ``[..., hkv]`` either way.
 
     Every consumer reads the form off the leaf it is handed (``pool_geometry``,
     ``fold_rows`` / ``unfold_rows``); only who MAKES a pool asks this function
     (server/backend.py ``paged_cache_descriptors``)."""
-    return (hkv * d_store,) if d_store < LANES else (hkv, d_store)
+    return (hkv * d_store,) if d_store < LANES or hkv < MIN_ROW_HEADS else (hkv, d_store)
 
 
 def unfold_rows(a, hkv: int):

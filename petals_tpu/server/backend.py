@@ -1015,9 +1015,10 @@ class TransformerBackend:
             if by_sort and self.family.state_for(self.cfg, kind) is not None:
                 mine = tuple(jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False) for a in state)
                 inner, mine = state_layer(block_apply, inner, p_block, mine)
-                state = tuple(
-                    jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype), slot, 0) for a, new in zip(state, mine)
-                )
+                with jax.named_scope("ptu.state.write"):  # the pass the compiler fuses a layer's state update into
+                    state = tuple(
+                        jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype), slot, 0) for a, new in zip(state, mine)
+                    )
                 return (inner, spans, state), None
             first_page = (slot if by_sort else block_idx) * n_pages
 

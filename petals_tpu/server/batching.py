@@ -472,6 +472,13 @@ class DecodeBatcher:
             # hit: the stacked-run kernel, the rest ragged_dot), and the times
             # a step's program walked a layer's experts
             self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_hit_tokens=0, moe_weight_passes=0)
+            dims = backend.moe_dims
+            if dims.routed is not None and dims.routed > dims.experts:
+                # a server that holds a share of the routed experts only: the expert-rows (a position through one
+                # expert) a mixed step's chunk half makes its dispatch multiply (positions x held experts under the
+                # all-experts einsum, positions x top k under the grouped one, which is handed every assignment's
+                # row), against those the routing sends here (positions x top k x held / routed)
+                self.stats.update(moe_chunk_rows_computed=0, moe_chunk_rows_routed=0.0)
         # on the paged pool (_count_window): the table slots the step programs read against those they
         # are handed; and, for a family that declares its layers' windows only, of the pages the decoding
         # lanes hold in windowed layers those their windows still reach (summed over steps)
@@ -2278,7 +2285,12 @@ class DecodeBatcher:
         dispatch = self.backend.moe_grouped
         halves = [(dispatch(seq), tokens)]
         if chunk_tokens:
-            halves.append((dispatch(chunk_tokens, chunk=True), chunk_tokens))
+            chunk_took = dispatch(chunk_tokens, chunk=True)
+            halves.append((chunk_took, chunk_tokens))
+            if "moe_chunk_rows_computed" in self.stats:  # a span that holds a share of its routed experts
+                dims = self.backend.moe_dims
+                self.stats["moe_chunk_rows_computed"] += chunk_tokens * (dims.experts if chunk_took == "dense" else dims.top_k)
+                self.stats["moe_chunk_rows_routed"] += chunk_tokens * dims.top_k * dims.experts / dims.routed
         for took, n in halves:
             self.stats["moe_dense_tokens" if took == "dense" else "moe_grouped_tokens"] += n
             if took == "hit":

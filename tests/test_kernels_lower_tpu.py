@@ -84,8 +84,9 @@ PAGED_CASES = [
     (32, 8, 128, "int8"),
     (32, 32, 128, "nf4a"),
     (64, 8, 64, "none"),
+    (16, 2, 256, "none"),  # two kv heads: stored folded whatever their width
 ]
-PAGED_IDS = ["bf16-mha", "bf16-gqa", "int8-gqa", "nf4a-mha", "bf16-d64"]
+PAGED_IDS = ["bf16-mha", "bf16-gqa", "int8-gqa", "nf4a-mha", "bf16-d64", "bf16-2x256"]
 
 
 @pytest.mark.parametrize("hq,hkv,d,kv_quant", PAGED_CASES, ids=PAGED_IDS)
@@ -980,3 +981,104 @@ def test_a_folded_pool_s_decode_step_is_the_composed_walk_s_whatever_the_backend
 
     on, off = program(True), program(False)
     assert len(on) > 10 and "paged_decode_walk" not in str(on) and on == off
+
+
+# ---------------------------------------------------------------- a state pool AND an expert stack in one span (PR 48)
+
+Q3N = "qwen3-next-80b-a3b-span8-ep4"
+
+
+@pytest.mark.parametrize("chunk", [0, 512], ids=["decode", "mixed-512"])
+def test_a_span_with_a_state_and_experts_leaves_its_pools_states_and_stacks_in_place(v5e, tmp_path, chunk):
+    """qwen3-next-80b-a3b-span8-ep4 at the cell's geometry (8 lanes, 40 pages a
+    lane): runs of three linear layers carry the state pool, the page pools
+    AND the run's expert stacks through one loop. The compiled step copies no
+    page pool (two kv heads of 256 stored as a folded row of 512: as rows of
+    ``[2, 256]`` both pools were copied whole four times in ``ENTRY``, 84 MB
+    each), no state pool (201 MB of float32) and no expert stack (a run of
+    three layers' ``w1``: 805 MB), in ``ENTRY`` or in a loop; every layer's
+    decode rows reach their experts through the hit kernel on the stacks as
+    the loop carries them, and a chunk's all-experts einsum reads them where
+    they lie."""
+    hlo, runs, pool, heads = _compiled_step(v5e, tmp_path, Q3N, chunk, pages_a_lane=40)
+    assert heads == (2, 256) and tuple(pool.shape) == (2, 320, 64, 512)
+    moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
+    assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
+    assert not moves, f"the step moves the page pool: {moves}"
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    state_elements = 6 * 8 * 32 * 128 * 128
+    assert any(math.prod(dims) == state_elements for _, dims, _, _ in comps[entry]), "the state pool was not found in ENTRY"
+    moved = [f"%{name} = {op}" for instructions in comps.values() for name, dims, op, rest in instructions if math.prod(dims) == state_elements
+             and (op in ("copy", "copy-start") or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
+    assert not moved, f"the step moves the state pool: {moved}"
+    # the stacks: every run has one, and the hit kernel is handed it as the loop carries it (or as ENTRY was handed it)
+    assert all("w1" in run for run in runs) and [run["w1"].shape for run in runs] == [(3, 128, 2048, 512), (1, 128, 2048, 512)] * 2
+    calls = hit_calls(hlo)
+    assert len(calls) == len(runs), [name for name, _ in calls]
+    for name, operands in calls:
+        assert {op for op, dims in operands if len(dims) == 4} <= {"get-tuple-element", "parameter"}, f"%{name} is handed a weight something made: {operands}"
+    shapes = {tuple(p.shape) for run in runs for p in run.values()}
+    least = 2048 * 512  # one expert's matrix, and the smallest projection of either mixer
+    # a copy-start / copy-done into the alternate memory space is the compiler's prefetch of a weight in the layout it had
+    # (``entry_weight_moves``): the mixed step's loop prefetches a linear run's ``wq`` so
+    prefetches = set(re.findall(r"^\s*%([\w.\-]+) = \(?\w+\[[\d,]*\]\{[^}]*S\(1\)\}[^=]*copy-(?:start|done)\(", hlo, re.MULTILINE))
+    relayouts, seen = weight_relayouts(hlo, shapes, least)
+    relayouts = [r for r in relayouts if r.split(" = ")[0].lstrip("%") not in prefetches]
+    assert seen and not relayouts, relayouts
+    assert not entry_weight_moves(hlo, shapes, least)
+
+
+def test_olmo_hybrid_s_decode_step_is_the_program_it_was_before_the_mixer_moved(v5e, tmp_path, monkeypatch):
+    """models/gated_delta.py is Olmo-Hybrid's ``_linear_attention`` with the
+    head grouping and beta's factor as parameters: with as many key heads as
+    value heads the compiled decode step is, instruction for instruction, the
+    one the family's own function compiled to (the parent's, kept here)."""
+    import petals_tpu.models.olmo_hybrid.block as olmo
+    from petals_tpu.models.common import mm, rms_norm, silu
+    from petals_tpu.ops.linear_attention import causal_conv, gated_delta
+
+    def l2_norm(x, eps=1e-6):
+        return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+    def parents(params, x, state, position, dims, eps, n_valid, live_rows):  # PR 46's olmo_hybrid/block.py _linear_attention
+        cfg = CFG[0]
+        batch, seq, _ = x.shape
+        heads, d_k, d_v = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        u = jnp.concatenate([mm(x, params[name]) for name in ("wq", "wk", "wv")], axis=-1)
+        fresh = jnp.broadcast_to(jnp.asarray(position, jnp.int32) == 0, (batch,))
+        matrix = jnp.where(fresh[:, None, None, None], 0.0, state[0])
+        tail = jnp.where(fresh[:, None, None], jnp.zeros((), state[1].dtype), state[1])
+        mixed, tail = causal_conv(u, tail, params["conv"], n_valid)
+        q, k, v = jnp.split(mixed, (heads * d_k, 2 * heads * d_k), axis=-1)
+        q = l2_norm(q.reshape(batch, seq, heads, d_k)) * (1.0 / math.sqrt(d_k))
+        k = l2_norm(k.reshape(batch, seq, heads, d_k))
+        v = v.reshape(batch, seq, heads, d_v)
+        beta = jax.nn.sigmoid(mm(x, params["wb"]).astype(jnp.float32)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
+        decay = -jnp.exp(params["a_log"].astype(jnp.float32))
+        g = decay * jax.nn.softplus(mm(x, params["wa"]).astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
+        matrix, out = gated_delta(matrix, q, k, v, g, beta, n_valid)
+        with jax.named_scope("ptu.linattn.gate_norm"):
+            gate = silu(mm(x, params["wz"]).astype(jnp.float32)).reshape(batch, seq, heads, d_v)
+            out = (rms_norm(out, params["o_norm"], cfg.rms_norm_eps) * gate).astype(x.dtype)
+        y = mm(out.reshape(batch, seq, heads * d_v), params["wo"])
+        matrix = jnp.where(live_rows[:, None, None, None], matrix, state[0])
+        tail = jnp.where(live_rows[:, None, None], tail, state[1])
+        return y, (matrix, tail.astype(state[1].dtype))
+
+    CFG = []
+    real_dims = olmo.mixer_dims
+
+    def dims_and_cfg(cfg):  # the parent's function read the configuration; the shared one is handed its dims
+        CFG[:] = [cfg]
+        return real_dims(cfg)
+
+    def program():  # every computation's instructions, without the source lines and scopes they were traced from
+        hlo, _, _, _ = _compiled_step(v5e, tmp_path, "olmo-hybrid-7b-span16", 0, pages_a_lane=40)
+        return {name: [(i[0], i[1], i[2], re.sub(r", metadata=\{[^}]*\}", "", i[3])) for i in instructions] for name, instructions in _computations(hlo).items()}
+
+    now = program()
+    monkeypatch.setattr(olmo, "mixer_dims", dims_and_cfg)
+    monkeypatch.setattr(olmo, "gated_delta_mixer", parents)
+    before = program()
+    assert CFG and len(now) > 10 and "paged_decode_walk" in str(now) and now == before

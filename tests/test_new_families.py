@@ -29,6 +29,7 @@ from tests.utils import (
     make_tiny_olmoe,
     make_tiny_phi3,
     make_tiny_qwen2,
+    make_tiny_qwen3_next,
 )
 
 
@@ -39,7 +40,7 @@ MAKERS = {
     "mixtral": make_tiny_mixtral, "olmoe": make_tiny_olmoe, "qwen2": make_tiny_qwen2,
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
-    "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3,
+    "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3, "qwen3_next": make_tiny_qwen3_next,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 

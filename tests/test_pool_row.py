@@ -60,11 +60,12 @@ def _bytes(pool):
     return [np.asarray(leaf).reshape(-1) for leaf in jax.tree_util.tree_leaves(pool)]
 
 
-def test_the_rule_folds_a_row_under_128_lanes_and_no_other():
+def test_the_rule_folds_a_row_under_128_lanes_or_of_under_4_heads_and_no_other():
     assert stored_row(8, 64) == (512,)  # Falcon-40B, bf16: the pool PR 38 was written for
     assert stored_row(8, 128) == (8, 128) and stored_row(16, 128) == (16, 128) and stored_row(32, 128) == (32, 128)  # the other five cells
     assert stored_row(8, 256) == (8, 256)
     assert stored_row(2, 16) == (32,) and stored_row(8, 96) == (768,)
+    assert stored_row(2, 256) == (512,) and stored_row(1, 128) == (128,) and stored_row(4, 128) == (4, 128)  # under 4 heads: folded whatever the width
     rows = np.arange(2 * 3 * 4 * 5).reshape(2, 3, 4, 5)
     assert fold_rows(rows, (20,)).shape == (2, 3, 20) and fold_rows(rows, (4, 5)).shape == rows.shape
     np.testing.assert_array_equal(unfold_rows(fold_rows(rows, (20,)), 4), rows)
@@ -88,20 +89,20 @@ def test_a_pool_of_head_dim_128_is_declared_as_it_was(tmp_path, kind):
     from transformers import LlamaConfig
 
     LlamaConfig(vocab_size=64, hidden_size=512, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
-                num_key_value_heads=2).save_pretrained(str(tmp_path))
+                num_key_value_heads=4).save_pretrained(str(tmp_path))
     family, cfg = get_block_config(str(tmp_path))
     params = {name: jax.ShapeDtypeStruct((2, *leaf.shape), leaf.dtype) for name, leaf in family.block_param_shapes(cfg, jnp.bfloat16).items()}
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=2, memory_cache=None, kv_quant_type=kind)
-    assert (backend.num_kv_heads, backend.head_dim) == (2, 128)
+    assert (backend.num_kv_heads, backend.head_dim) == (4, 128)  # four heads: under four a row folds whatever its width (the rule's test above)
     shapes = [(d.shape, jnp.dtype(d.dtype)) for d in backend.paged_cache_descriptors(6, 8, 0, 2)]
     if kind == "none":
-        assert backend.pool_row == (2, 128) and shapes == [((2, 6, 8, 2, 128), jnp.dtype(backend.cache_dtype))] * 2
+        assert backend.pool_row == (4, 128) and shapes == [((2, 6, 8, 4, 128), jnp.dtype(backend.cache_dtype))] * 2
     elif kind == "int8":
-        assert backend.pool_row == (2, 128)
-        assert shapes == [((2, 6, 8, 2, 128), jnp.dtype(jnp.int8))] * 2 + [((2, 6, 8, 2), jnp.dtype(jnp.float32))] * 2
+        assert backend.pool_row == (4, 128)
+        assert shapes == [((2, 6, 8, 4, 128), jnp.dtype(jnp.int8))] * 2 + [((2, 6, 8, 4), jnp.dtype(jnp.float32))] * 2
     else:
-        assert backend.pool_row == (128,)
-        assert shapes == [((2, 6, 8, 128), jnp.dtype(jnp.uint8))] * 2 + [((2, 6, 8, 2), jnp.dtype(jnp.float32))] * 2
+        assert backend.pool_row == (256,)
+        assert shapes == [((2, 6, 8, 256), jnp.dtype(jnp.uint8))] * 2 + [((2, 6, 8, 4), jnp.dtype(jnp.float32))] * 2
 
 
 def _one_step_apart(got, want, codes_dtype) -> int:

@@ -887,3 +887,89 @@ def make_tiny_deepseek_v3(tmpdir: str, **overrides) -> str:
         json.dump(config, f)
     save_file(tiny_deepseek_v3_tensors(config), os.path.join(path, "model.safetensors"))
     return path
+
+
+TINY_QWEN3_NEXT = {  # the keys Qwen3-Next publishes, at a toy size: two periods of three linear layers and a full one
+    "model_type": "qwen3_next", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "partial_rotary_factor": 0.25, "rope_theta": 10000000, "rope_scaling": None, "intermediate_size": 128,
+    "num_hidden_layers": 8, "full_attention_interval": 4, "hidden_act": "silu", "attention_bias": False,
+    "linear_num_key_heads": 2, "linear_num_value_heads": 4, "linear_key_head_dim": 8, "linear_value_head_dim": 16,
+    "linear_conv_kernel_dim": 4, "moe_intermediate_size": 32, "shared_expert_intermediate_size": 32, "num_experts": 16,
+    "num_experts_per_tok": 4, "norm_topk_prob": True, "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 256, "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def qwen3_next_layer_types(config: dict) -> list:
+    interval = config["full_attention_interval"]
+    return ["linear_attention" if (i + 1) % interval else "full_attention" for i in range(config["num_hidden_layers"])]
+
+
+def tiny_qwen3_next_tensors(config: dict, seed: int = 23) -> dict:
+    """Seeded float32 tensors under transformers' names of every layer of
+    ``config`` (every routed expert, whatever share a server of it holds), the
+    embedding, the final norm and the head. Zero-centred norm vectors are
+    drawn around 0 and the delta rule's output norm around 1, so a missing,
+    misplaced or unfolded one shows; A and the step spread alpha over about
+    0.3-0.99."""
+    rng = np.random.RandomState(seed)
+    h, hq, hkv, d = (config[k] for k in ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim"))
+    hk, hv, d_k, d_v, taps = (config[k] for k in ("linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
+                                                  "linear_value_head_dim", "linear_conv_kernel_dim"))
+    m, ms = config["moe_intermediate_size"], config["shared_expert_intermediate_size"]
+    routed = (config.get("expert_share") or {}).get("routed", config["num_experts"])
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    centred = lambda n: rng.uniform(-0.5, 0.5, n).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": centred(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i, kind in enumerate(qwen3_next_layer_types(config)):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "input_layernorm.weight": centred(h), p + "post_attention_layernorm.weight": centred(h),
+            p + "mlp.gate.weight": normal(routed, h) * 5.0,  # a router that prefers some experts, as a trained one does
+            p + "mlp.shared_expert.gate_proj.weight": normal(ms, h), p + "mlp.shared_expert.up_proj.weight": normal(ms, h),
+            p + "mlp.shared_expert.down_proj.weight": normal(h, ms), p + "mlp.shared_expert_gate.weight": normal(1, h) * 3.0,
+        })
+        for e in range(routed):
+            q = p + f"mlp.experts.{e}."
+            tensors.update({q + "gate_proj.weight": normal(m, h), q + "up_proj.weight": normal(m, h), q + "down_proj.weight": normal(h, m)})
+        if kind == "full_attention":
+            q = p + "self_attn."
+            tensors.update({
+                q + "q_proj.weight": normal(hq * 2 * d, h), q + "k_proj.weight": normal(hkv * d, h),
+                q + "v_proj.weight": normal(hkv * d, h), q + "o_proj.weight": normal(h, hq * d),
+                q + "q_norm.weight": centred(d), q + "k_norm.weight": centred(d),
+            })
+            continue
+        q = p + "linear_attn."
+        dt = np.exp(rng.uniform(np.log(0.001), np.log(0.1), hv))
+        tensors.update({
+            q + "in_proj_qkvz.weight": normal(2 * hk * d_k + 2 * hv * d_v, h), q + "in_proj_ba.weight": normal(2 * hv, h),
+            q + "conv1d.weight": (rng.standard_normal((2 * hk * d_k + hv * d_v, 1, taps)) * 0.4).astype(np.float32),
+            q + "A_log": np.log(rng.uniform(1, 16, hv)).astype(np.float32),
+            q + "dt_bias": (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+            q + "norm.weight": rng.uniform(0.5, 1.5, d_v).astype(np.float32), q + "out_proj.weight": normal(h, hv * d_v),
+        })
+    return tensors
+
+
+@_model_build_cache
+def make_tiny_qwen3_next(tmpdir: str, *, held: int = 16, first: int = 0) -> str:
+    """A Qwen3-Next checkpoint at a toy size, written by hand under
+    transformers' names (tests/test_qwen3_next.py loads the same tensors into
+    transformers' own ``Qwen3NextDecoderLayer``): every routed expert is in the
+    file, and a server of this directory holds ``held`` of the 16 from
+    ``first`` on (``expert_share``; all of them by default)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = dict(TINY_QWEN3_NEXT)
+    if held != 16 or first:
+        config.update(num_experts=held, expert_share={"routed": 16, "first": first})
+    path = os.path.join(tmpdir, f"tiny-qwen3-next-{held}-{first}")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_qwen3_next_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
