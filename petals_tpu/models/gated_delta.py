@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from petals_tpu.models.common import mm, rms_norm, silu
-from petals_tpu.ops.linear_attention import causal_conv, gated_delta
+from petals_tpu.ops.linear_attention import StatePool, causal_conv, gated_delta, gated_delta_pooled
 
 
 class MixerDims(NamedTuple):
@@ -55,17 +55,23 @@ def _l2_norm(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
 def gated_delta_mixer(params: dict, x: jnp.ndarray, state, position, dims: MixerDims, eps: float, n_valid, live_rows):
     """The mixer over ``x`` [batch, seq, hidden] from ``state`` on: (its
     output, the state after it). ``state`` None: a whole sequence from its
-    start, no state handed back."""
+    start, no state handed back. ``state`` a ``StatePool``: a lane pool's
+    step, one row a lane; the matrices stay in the pool, where the one-step
+    rule reads and writes them (``gated_delta_pooled``), only the conv's tail
+    is taken out and put back, and the pool is what comes back."""
     batch, seq, _ = x.shape
     key_heads, heads, d_k, d_v = dims.key_heads, dims.heads, dims.d_k, dims.d_v
+    pooled = isinstance(state, StatePool)
     u = jnp.concatenate([mm(x, params[name]) for name in ("wq", "wk", "wv")], axis=-1)
     if state is None:  # no cache: a whole sequence from its start
         matrix = jnp.zeros((batch, heads, d_k, d_v), jnp.float32)
         tail = jnp.zeros((batch, dims.taps - 1, u.shape[-1]), u.dtype)
     else:
         fresh = jnp.broadcast_to(jnp.asarray(position, jnp.int32) == 0, (batch,))
-        matrix = jnp.where(fresh[:, None, None, None], 0.0, state[0])
-        tail = jnp.where(fresh[:, None, None], jnp.zeros((), state[1].dtype), state[1])
+        held_tail = state.read(1) if pooled else state[1]
+        tail = jnp.where(fresh[:, None, None], jnp.zeros((), held_tail.dtype), held_tail)
+        if not pooled:
+            matrix = jnp.where(fresh[:, None, None, None], 0.0, state[0])
     mixed, tail = causal_conv(u, tail, params["conv"], n_valid)
     q, k, v = jnp.split(mixed, (key_heads * d_k, 2 * key_heads * d_k), axis=-1)
     q = _l2_norm(q.reshape(batch, seq, key_heads, d_k)) * (1.0 / math.sqrt(d_k))
@@ -76,7 +82,13 @@ def gated_delta_mixer(params: dict, x: jnp.ndarray, state, position, dims: Mixer
     beta = jax.nn.sigmoid(mm(x, params["wb"]).astype(jnp.float32)) * dims.beta_scale
     decay = -jnp.exp(params["a_log"].astype(jnp.float32))
     g = decay * jax.nn.softplus(mm(x, params["wa"]).astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
-    matrix, out = gated_delta(matrix, q, k, v, g, beta, n_valid)
+    if pooled:
+        assert seq == 1, "a lane pool's step hands its state pooled with one row a lane"
+        live = jnp.ones((batch,), bool) if live_rows is None else live_rows
+        state, out = gated_delta_pooled(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], live=live, fresh=fresh)
+        out = out[:, None]
+    else:
+        matrix, out = gated_delta(matrix, q, k, v, g, beta, n_valid)
     with jax.named_scope("ptu.linattn.gate_norm"):
         gate = silu(mm(x, params["wz"]).astype(jnp.float32)).reshape(batch, seq, heads, d_v)
         out = (rms_norm(out, params["o_norm"], eps) * gate).astype(x.dtype)
@@ -84,6 +96,8 @@ def gated_delta_mixer(params: dict, x: jnp.ndarray, state, position, dims: Mixer
     if state is None:
         return y, None
     if live_rows is not None:  # an idle lane's state stays as it was
-        matrix = jnp.where(live_rows[:, None, None, None], matrix, state[0])
-        tail = jnp.where(live_rows[:, None, None], tail, state[1])
-    return y, (matrix, tail.astype(state[1].dtype))
+        if not pooled:
+            matrix = jnp.where(live_rows[:, None, None, None], matrix, state[0])
+        tail = jnp.where(live_rows[:, None, None], tail, held_tail)
+    tail = tail.astype(held_tail.dtype)
+    return y, (state.write(1, tail) if pooled else (matrix, tail))

@@ -485,6 +485,20 @@ class TransformerBackend:
         whose blocks all keep keys and values."""
         return tuple(TensorDescriptor((len(self.state_layers), n_lanes, *shape), dtype) for shape, dtype in self.lane_state)
 
+    def state_step_path(self, n_lanes: int) -> str:
+        """``"kernel"`` or ``"plain"``: what a lane pool's decode rows run in
+        the span's state layers, the kernel that moves each live lane's
+        matrices once where they lie in the state pool or the plain form on a
+        layer's slice of it. ops/linear_attention.py ``gated_delta_step_path``
+        is asked here as ``gated_delta_pooled`` asks it in the step: with the
+        pool as the step programs carry it (``state_cache_descriptors``) and
+        one row a lane. Fixed with the pool's geometry: the batcher asks once,
+        for its ``linattn_kernel_tokens``."""
+        from petals_tpu.ops.linear_attention import StatePool, gated_delta_step_path
+
+        leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in self.state_cache_descriptors(n_lanes))
+        return gated_delta_step_path(StatePool(leaves, 0), 1)
+
     def index_cache_descriptors(self, n_pages: int, page_size: int) -> tuple:
         """The descriptor of the INDEX pool beside the page pools of keys and
         values, ``[kv layers, n_pages, *row]``: a page of it is a page of
@@ -986,14 +1000,18 @@ class TransformerBackend:
         and a block's layer in its pool is its place among its own sort
         (``_slots``), not its index in the span. A block of a kind that
         declares a state runs ``state_layer(block_apply, carry, p_block,
-        mine) -> (carry, mine)`` on its layer of the state pool, ``mine``
-        one ``[n_lanes, ...]`` array a leaf, and touches no page.
+        mine) -> (carry, mine)`` on the state pool where it lies, ``mine``
+        the pool's leaves whole and the block's slot in them
+        (ops/linear_attention.py ``StatePool``, a stand-in as ``PagedKV`` is
+        for pages, and not a sliced copy: a copy out and a write back are two
+        passes over every lane's state), and touches no page.
 
         A span whose positions cache an index row (``index_row``) hands its
         INDEX pool in as ``state``'s one leaf, ``[kv layers, n_pages,
         page_size, width]``: a third page pool, flattened and carried as the
         other two and reached through the same shifted tables, which comes
         back in ``state``'s place."""
+        from petals_tpu.ops.linear_attention import StatePool
         from petals_tpu.ops.paged_attention import PagedKV
 
         depth, n_pages = k_pool.shape[0], k_pool.shape[1]
@@ -1013,13 +1031,8 @@ class TransformerBackend:
         def one(block_apply, scanned, p_block, slot, block_idx, kind=None):
             inner, spans, state = scanned
             if by_sort and self.family.state_for(self.cfg, kind) is not None:
-                mine = tuple(jax.lax.dynamic_index_in_dim(a, slot, 0, keepdims=False) for a in state)
-                inner, mine = state_layer(block_apply, inner, p_block, mine)
-                with jax.named_scope("ptu.state.write"):  # the pass the compiler fuses a layer's state update into
-                    state = tuple(
-                        jax.lax.dynamic_update_index_in_dim(a, new.astype(a.dtype), slot, 0) for a, new in zip(state, mine)
-                    )
-                return (inner, spans, state), None
+                inner, mine = state_layer(block_apply, inner, p_block, StatePool(state, slot))
+                return (inner, spans, tuple(mine.leaves)), None
             first_page = (slot if by_sort else block_idx) * n_pages
 
             def paged(spans, tables):
@@ -1056,8 +1069,8 @@ class TransformerBackend:
     def _state_lanes_layer(self, positions, max_length):
         """``_scan_paged_span``'s ``state_layer`` for a step in which every
         lane feeds one row at its own position: one ``block_apply`` over the
-        lanes' states. An idle lane (``positions`` at ``max_length``) is no
-        live row, and its state comes back as it went in."""
+        lanes' states where they lie in the pool. An idle lane (``positions``
+        at ``max_length``) is no live row, and its state stays as it was."""
         cfg = self.cfg
 
         def layer(block_apply, h, p_block, mine):
@@ -1416,17 +1429,13 @@ class TransformerBackend:
                 # through the chunked form from its lane's state on (that lane
                 # is idle in the decode half, which left its state alone),
                 # leaving the state and the conv's tail for the next chunk or
-                # the first decode step
+                # the first decode step; only that ONE lane's state leaves the pool and goes back
                 h_dec, h_pf = carry
                 out_dec, mine = decode_state(block_apply, h_dec, p_block, mine)
-                lane = tuple(jax.lax.dynamic_index_in_dim(a, chunk_lane, 0, keepdims=True) for a in mine)
                 out_pf, lane = block_apply(
-                    p_block, h_pf, lane, chunk_pos, cfg, use_flash=False, n_valid=chunk_n_valid, tp_mesh=None,
+                    p_block, h_pf, mine.lane(chunk_lane), chunk_pos, cfg, use_flash=False, n_valid=chunk_n_valid, tp_mesh=None,
                 )
-                mine = tuple(
-                    jax.lax.dynamic_update_index_in_dim(a, new[0].astype(a.dtype), chunk_lane, 0) for a, new in zip(mine, lane)
-                )
-                return (out_dec, out_pf), mine
+                return (out_dec, out_pf), mine.with_lane(chunk_lane, lane)
 
             (hidden, chunk_out), k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, (hidden, chunk_hidden), layer, state, state_layer

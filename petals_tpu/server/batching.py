@@ -495,7 +495,10 @@ class DecodeBatcher:
             # a family that declares a state only (_count_state): rows times state layers by the form
             # their step gave them (the one-step form a decode row, the chunked form a prompt chunk), and,
             # summed step by step over the lanes that fed rows, the bytes of state and of pages they hold
-            self.stats.update(linattn_recurrent_tokens=0, linattn_chunk_tokens=0, state_bytes_held=0, kv_bytes_held=0)
+            # linattn_kernel_tokens: of the one-step form's, those whose state the kernel moved once where it lies in the
+            # pool (0 where the plain form runs: ``backend.state_step_path`` says which)
+            self.stats.update(linattn_recurrent_tokens=0, linattn_kernel_tokens=0, linattn_chunk_tokens=0, state_bytes_held=0, kv_bytes_held=0)
+            self._state_step = backend.state_step_path(n_lanes)
         if self._n_index:
             # a family that declares an index row only (_count_sparse), from the shapes a step is started with,
             # all times the span's layers: rows whose context was over / at most the selection's size, index
@@ -1500,6 +1503,8 @@ class DecodeBatcher:
             info["pool_row"] = list(getattr(self.backend, "pool_row", ()))
             # which walk a decode row's attention takes over these pages, a distinct window of the span's layers
             info["decode_walk"] = [walk[-1] for walk in self._walks]
+            if self._n_state:  # and which form of the one-step rule a decode row's state layers
+                info["state_step"] = self._state_step
             info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
             if self._n_index:  # of kv_bytes_per_token, the index rows' part
                 info["index_bytes_per_token"] = int(self.backend.index_bytes_per_token())
@@ -2368,6 +2373,8 @@ class DecodeBatcher:
         layers = len(self.backend.state_layers)
         lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
         self.stats["linattn_recurrent_tokens"] += int(lanes.size) * layers
+        if self._state_step == "kernel":
+            self.stats["linattn_kernel_tokens"] += int(lanes.size) * layers
         if chunk is not None:
             lanes = np.append(lanes, chunk[0])
             self.stats["linattn_chunk_tokens"] += int(chunk[1]) * layers

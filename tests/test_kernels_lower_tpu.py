@@ -292,7 +292,7 @@ STEP_CASES = [
 ]
 
 
-def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none", latent_kernel=True, walk_kernel=True):
+def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant="none", latent_kernel=True, walk_kernel=True, state_kernel=True):
     """``(optimized HLO, stacked params, pool aval, (hkv, d))`` of
     ``TransformerBackend``'s paged decode step, or of its mixed step with a
     prompt chunk of ``chunk`` riding it, at a cell's widths and depth (8 lanes,
@@ -301,7 +301,8 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     a head_dim of 64 folded to rows of ``hkv * d``), compiled for the v5e. Under
     ``kv_quant`` the pools are ``PagedPool``s and the aval returned is their codes'.
     ``latent_kernel`` False: a latent row's decode walk as off the chip, composed;
-    ``walk_kernel`` False: a decode row's walk over plain pages likewise."""
+    ``walk_kernel`` False: a decode row's walk over plain pages likewise;
+    ``state_kernel`` False: a decode row's one-step rule in its plain form."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -341,6 +342,8 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
         patch.setattr("petals_tpu.ops.latent_attention._on_tpu", lambda: latent_kernel)  # as would the latent decode walk's kernel
         patch.setattr(pfa, "_on_tpu", lambda: walk_kernel)  # and the plain pages' decode walk's
+        patch.setattr("petals_tpu.ops.linear_attention._on_tpu", lambda: state_kernel)  # and the one-step rule's over the state pool
+        patch.setattr("petals_tpu.ops.linear_attention._interpret", lambda: False)
         hlo = jax.jit(step, donate_argnums=donated).lower(*avals).compile().as_text()
     return hlo, runs, pool, (backend.num_kv_heads, backend.head_dim)
 
@@ -593,6 +596,61 @@ def test_paged_step_leaves_the_state_pool_and_its_pages_in_place(v5e, tmp_path, 
         assert not moved, f"the step moves the {what} pool: {moved}"
     # the decode rows' walk is a kernel that reads the pools the chunk's scatter writes (PR 45): each full layer holds it once
     assert len(decode_walk_calls(hlo, "paged_decode_walk")) == 4
+    # and their one-step rule a kernel on the state pool where it lies (PR 49): one a run of three linear layers
+    _the_rule_is_one_kernel_a_linear_run_on_the_pool_as_the_loop_carries_it(hlo, 4, (12, 8, 30, 96, 192))
+
+
+def _the_rule_is_one_kernel_a_linear_run_on_the_pool_as_the_loop_carries_it(hlo: str, runs: int, pool: tuple) -> None:
+    """A compiled step's one-step rule (ops/linear_attention.py
+    ``_step_kernel``): one ``tpu_custom_call`` under ``ptu.linattn.recurrent``
+    a run of linear layers, handed the state pool as the loop carries it (or
+    as ``ENTRY`` was handed it, or as the call before it in the same layer
+    left it) and aliased to its own result, and nothing left in the program
+    whose result is a whole layer's states (the plain form's passes were
+    fusions of ``[lanes, heads, d_k, d_v]``)."""
+    calls = decode_walk_calls(hlo, "gated_delta_step")
+    assert len(calls) == runs, [op_name for _, op_name, _ in calls]
+    for _, op_name, operands in calls:
+        assert "ptu.linattn.recurrent" in op_name, op_name
+        handed = [op for op, _, dims in operands if tuple(dims) == pool]
+        assert handed and set(handed) <= {"get-tuple-element", "parameter"}, f"the kernel is handed a pool something made: {operands}"
+    assert hlo.count("output_to_operand_aliasing={{1}: (9, {})}") == runs
+    a_layer = "f32[" + ",".join(map(str, pool[1:])) + "]"
+    whole = [line.strip()[:160] for line in hlo.splitlines() if re.search(r"= \(?" + re.escape(a_layer), line)]
+    assert not whole, f"the step still makes a whole layer's states: {whole}"
+
+
+STATE_KERNEL_SHAPES = [
+    pytest.param((6, 8, 32, 128, 128), 8, id="qwen3next-6-layers-of-32x128x128"),
+    pytest.param((12, 8, 30, 96, 192), 6, id="olmohybrid-12-layers-of-30x96x192"),
+]
+
+
+@pytest.mark.parametrize("pool,fewer", STATE_KERNEL_SHAPES)
+def test_gated_delta_step_kernel_lowers_at_both_configurations_sizes(v5e, pool, fewer):
+    """The one-step rule's kernel alone, through Pallas -> Mosaic -> libtpu for
+    the v5e, at ``step_kernel_heads``' grouping (a lane's heads all in one
+    grid step) and at ``fewer`` heads a step: the donated pool is aliased to
+    the result and nothing copies it (Olmo-Hybrid's ``d_v`` of 192 is the
+    array's full last dimension, its 96 rows whole sublane tiles)."""
+    from petals_tpu.ops import linear_attention as la
+
+    _, lanes, heads, d_k, d_v = pool
+    avals = (v5e(pool, F32), v5e((), I32), *(v5e((lanes, heads, d), F32) for d in (d_k, d_k, d_v)), v5e((lanes, heads), F32), v5e((lanes, heads), F32),
+             v5e((lanes,), jnp.bool_), v5e((lanes,), jnp.bool_))
+    assert la.step_kernel_unsupported(la.StatePool((avals[0],), 0), 1) is None
+    assert la.step_kernel_heads(heads, d_k, d_v) == heads
+    for heads_a_step in (None, fewer):
+        def rule(matrix, slot, q, k, v, g, beta, live, fresh):
+            state, out = la.gated_delta_pooled(la.StatePool((matrix,), slot), q, k, v, g, beta, live=live, fresh=fresh, path="kernel", heads_a_step=heads_a_step)
+            return state.leaves[0], out
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(la, "_interpret", lambda: False)  # the backend here is the CPU: the kernel would be interpreted
+            hlo = jax.jit(rule, donate_argnums=(0,)).lower(*avals).compile().as_text()
+        assert "tpu_custom_call" in hlo and "output_to_operand_aliasing={{1}: (9, {})}" in hlo
+        moved = [line.strip()[:120] for line in hlo.splitlines() if re.search(r"= f32\[" + ",".join(map(str, pool)) + r"\]\S* (copy|fusion)\(", line)]
+        assert not moved, moved
 
 
 SPARSE_CASES = [pytest.param(0, id="decode"), pytest.param(2048, id="mixed-2048")]
@@ -1012,6 +1070,8 @@ def test_a_span_with_a_state_and_experts_leaves_its_pools_states_and_stacks_in_p
     moved = [f"%{name} = {op}" for instructions in comps.values() for name, dims, op, rest in instructions if math.prod(dims) == state_elements
              and (op in ("copy", "copy-start") or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
     assert not moved, f"the step moves the state pool: {moved}"
+    # the decode rows' one-step rule is a kernel on the state pool where it lies (PR 49): one a run of three linear layers
+    _the_rule_is_one_kernel_a_linear_run_on_the_pool_as_the_loop_carries_it(hlo, 2, (6, 8, 32, 128, 128))
     # the stacks: every run has one, and the hit kernel is handed it as the loop carries it (or as ENTRY was handed it)
     assert all("w1" in run for run in runs) and [run["w1"].shape for run in runs] == [(3, 128, 2048, 512), (1, 128, 2048, 512)] * 2
     calls = hit_calls(hlo)
@@ -1033,7 +1093,12 @@ def test_olmo_hybrid_s_decode_step_is_the_program_it_was_before_the_mixer_moved(
     """models/gated_delta.py is Olmo-Hybrid's ``_linear_attention`` with the
     head grouping and beta's factor as parameters: with as many key heads as
     value heads the compiled decode step is, instruction for instruction, the
-    one the family's own function compiled to (the parent's, kept here)."""
+    one the family's own function compiled to (the parent's, kept here). Since
+    PR 49 a lane pool's step hands the mixer its layer's states where they lie
+    in the pool (``StatePool``): the parent's function is handed the layer's
+    slice and its result written back, as ``backend._scan_paged_span`` did
+    around it, and both sides keep the one-step rule's plain form (off the
+    chip the shared mixer's is that slice, those selects and that write)."""
     import petals_tpu.models.olmo_hybrid.block as olmo
     from petals_tpu.models.common import mm, rms_norm, silu
     from petals_tpu.ops.linear_attention import causal_conv, gated_delta
@@ -1041,14 +1106,14 @@ def test_olmo_hybrid_s_decode_step_is_the_program_it_was_before_the_mixer_moved(
     def l2_norm(x, eps=1e-6):
         return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
 
-    def parents(params, x, state, position, dims, eps, n_valid, live_rows):  # PR 46's olmo_hybrid/block.py _linear_attention
+    def parents(params, x, pool, position, dims, eps, n_valid, live_rows):  # PR 46's olmo_hybrid/block.py _linear_attention
         cfg = CFG[0]
         batch, seq, _ = x.shape
         heads, d_k, d_v = cfg.linear_num_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
         u = jnp.concatenate([mm(x, params[name]) for name in ("wq", "wk", "wv")], axis=-1)
         fresh = jnp.broadcast_to(jnp.asarray(position, jnp.int32) == 0, (batch,))
-        matrix = jnp.where(fresh[:, None, None, None], 0.0, state[0])
-        tail = jnp.where(fresh[:, None, None], jnp.zeros((), state[1].dtype), state[1])
+        held_tail = pool.read(1)
+        tail = jnp.where(fresh[:, None, None], jnp.zeros((), held_tail.dtype), held_tail)
         mixed, tail = causal_conv(u, tail, params["conv"], n_valid)
         q, k, v = jnp.split(mixed, (heads * d_k, 2 * heads * d_k), axis=-1)
         q = l2_norm(q.reshape(batch, seq, heads, d_k)) * (1.0 / math.sqrt(d_k))
@@ -1057,14 +1122,15 @@ def test_olmo_hybrid_s_decode_step_is_the_program_it_was_before_the_mixer_moved(
         beta = jax.nn.sigmoid(mm(x, params["wb"]).astype(jnp.float32)) * (2.0 if cfg.linear_allow_neg_eigval else 1.0)
         decay = -jnp.exp(params["a_log"].astype(jnp.float32))
         g = decay * jax.nn.softplus(mm(x, params["wa"]).astype(jnp.float32) + params["dt_bias"].astype(jnp.float32))
-        matrix, out = gated_delta(matrix, q, k, v, g, beta, n_valid)
+        held = pool.read(0)  # the layer's slice, the rule between the two selects, the layer written back whole
+        matrix, out = gated_delta(jnp.where(fresh[:, None, None, None], 0.0, held), q, k, v, g, beta, n_valid)
+        pool = pool.write(0, jnp.where(live_rows[:, None, None, None], matrix, held))
         with jax.named_scope("ptu.linattn.gate_norm"):
             gate = silu(mm(x, params["wz"]).astype(jnp.float32)).reshape(batch, seq, heads, d_v)
             out = (rms_norm(out, params["o_norm"], cfg.rms_norm_eps) * gate).astype(x.dtype)
         y = mm(out.reshape(batch, seq, heads * d_v), params["wo"])
-        matrix = jnp.where(live_rows[:, None, None, None], matrix, state[0])
-        tail = jnp.where(live_rows[:, None, None], tail, state[1])
-        return y, (matrix, tail.astype(state[1].dtype))
+        tail = jnp.where(live_rows[:, None, None], tail, held_tail)
+        return y, pool.write(1, tail)
 
     CFG = []
     real_dims = olmo.mixer_dims
@@ -1074,7 +1140,7 @@ def test_olmo_hybrid_s_decode_step_is_the_program_it_was_before_the_mixer_moved(
         return real_dims(cfg)
 
     def program():  # every computation's instructions, without the source lines and scopes they were traced from
-        hlo, _, _, _ = _compiled_step(v5e, tmp_path, "olmo-hybrid-7b-span16", 0, pages_a_lane=40)
+        hlo, _, _, _ = _compiled_step(v5e, tmp_path, "olmo-hybrid-7b-span16", 0, pages_a_lane=40, state_kernel=False)
         return {name: [(i[0], i[1], i[2], re.sub(r", metadata=\{[^}]*\}", "", i[3])) for i in instructions] for name, instructions in _computations(hlo).items()}
 
     now = program()

@@ -22,6 +22,7 @@ from petals_tpu.data_structures import CHAIN_DELIMITER, make_uid
 from petals_tpu.models.gated_delta import MixerDims
 from petals_tpu.models.qwen3_next.block import split_ba, split_qkvz
 from petals_tpu.models.registry import span_runs
+from petals_tpu.ops import linear_attention
 from petals_tpu.rpc import RpcClient
 from petals_tpu.rpc.serialization import deserialize_array, serialize_array
 from petals_tpu.server.backend import TransformerBackend
@@ -36,7 +37,7 @@ from tests.utils import TINY_QWEN3_NEXT, make_tiny_qwen3_next, qwen3_next_layer_
 HF = dict(TINY_QWEN3_NEXT)
 LINEAR, FULL = "linear_attention", "full_attention"
 KINDS = qwen3_next_layer_types(HF)
-STATE_KEYS = {"linattn_recurrent_tokens", "linattn_chunk_tokens", "state_bytes_held", "kv_bytes_held"}
+STATE_KEYS = {"linattn_recurrent_tokens", "linattn_kernel_tokens", "linattn_chunk_tokens", "state_bytes_held", "kv_bytes_held"}
 MOE_KEYS = {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"}
 SHARE_KEYS = {"moe_chunk_rows_computed", "moe_chunk_rows_routed"}
 # float32 on the CPU, the served path against the reference, as a share of the largest output: they differ in
@@ -315,21 +316,29 @@ def test_the_published_span_s_pools_and_what_a_lane_costs():
     assert backend.moe_grouped(1) == "hit" and backend.moe_grouped(512, chunk=True) == "dense"
 
 
-def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_matches_the_reference(tmp_path, tiny):
+@pytest.mark.parametrize("state_step", ["plain", "kernel"])
+def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_matches_the_reference(tmp_path, tiny, monkeypatch, state_step):
     """A server that holds experts 4-11 of 16: sessions B and C decode while
     A's prompt of 40 rides three mixed steps (a budget of 16: the state and
     the conv's tail handed chunk to chunk), then all three decode at once:
     every row of every session against the reference's whole forward pass.
     The counters say which form each row took and what the chunks' dispatch
-    multiplied."""
+    multiplied. ``kernel``: the decode rows' one-step rule named as on a TPU
+    (ops/linear_attention.py ``_step_kernel``, interpreted here), on the
+    state pool where it lies, in the decode steps and in the mixed steps
+    beside A's chunks: the same replies within the same tolerance."""
     _, tensors = tiny
     path = make_tiny_qwen3_next(str(tmp_path), held=8, first=4)
     hf = {**HF, "num_experts": 8, "expert_share": {"routed": 16, "first": 4}}
+
+    if state_step == "kernel":
+        monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)  # ``_interpret`` still sees the CPU
 
     async def main():
         server, client = await start_server(path, batch_lanes=3, batch_max_length=64, page_size=16, prefill_token_budget=16)
         try:
             batcher = server.handler.batcher
+            assert batcher.occupancy_info()["state_step"] == state_step
             assert batcher.page_size == 16 and server.handler.prefix_cache is None
             assert STATE_KEYS | MOE_KEYS | SHARE_KEYS <= set(batcher.stats)
             a_rows, b_rows, c_rows = rows(1, 52), rows(2, 40), rows(3, 40)
@@ -367,6 +376,8 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_match
                     got.append(out)
             decoded = (len(got_b) - 1) + (len(got_c) - 1) + 12  # B's and C's replies but their prompts', and A's 12
             assert batcher.stats["linattn_recurrent_tokens"] - before["linattn_recurrent_tokens"] == decoded * 6
+            # of them, those whose state the kernel moved where it lies: all on a TPU, none on the default CPU path
+            assert batcher.stats["linattn_kernel_tokens"] == (batcher.stats["linattn_recurrent_tokens"] if state_step == "kernel" else 0)
             assert batcher.stats["moe_hit_tokens"] - before["moe_hit_tokens"] == decoded
             assert batcher.stats["state_bytes_held"] > before["state_bytes_held"] and batcher.stats["kv_bytes_held"] > before["kv_bytes_held"]
             info = await client.call("ptu.info", {})
@@ -381,6 +392,25 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_match
             await server.shutdown()
 
     run(main())
+
+
+def test_the_one_step_rule_s_path_follows_from_the_pool_and_the_call_and_gives_its_reason(tiny, monkeypatch):
+    """What the backend tells the batcher its lanes' steps take, and why the
+    span's other calls keep the plain form: a chunk, a call whose state is not
+    the pool's, a head the kernel refuses."""
+    backend = whole_backend(tiny[0])
+    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in backend.state_cache_descriptors(3))
+    pool = linear_attention.StatePool(leaves, 0)
+    assert leaves[0].shape == (6, 3, 4, 8, 16) and backend.state_step_path(3) == "plain"  # off the chip
+    monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
+    assert backend.state_step_path(3) == "kernel" and linear_attention.step_kernel_unsupported(pool, 1) is None
+    assert "16 rows a lane" in linear_attention.step_kernel_unsupported(pool, 16)
+    a_lane = tuple(jnp.zeros((1, *leaf.shape[2:]), leaf.dtype) for leaf in leaves)  # what a chunk's lane is handed
+    assert "no pooled state" in linear_attention.step_kernel_unsupported(a_lane, 1)
+    assert "no pooled state" in linear_attention.step_kernel_unsupported(None, 40)
+    odd = linear_attention.StatePool((jax.ShapeDtypeStruct((6, 3, 4, 12, 16), jnp.float32), leaves[1]), 0)
+    assert "12 is no multiple of the 8 sublanes" in linear_attention.step_kernel_unsupported(odd, 1)
+    assert linear_attention.gated_delta_step_path(odd, 1) == linear_attention.gated_delta_step_path(pool, 16) == "plain"
 
 
 def test_a_server_of_every_expert_counts_no_share(tiny):
