@@ -1087,6 +1087,50 @@ class TransformerBackend:
     def _split_state(results: tuple, state: tuple) -> Tuple[tuple, tuple]:
         return (results[:-1], tuple(results[-1])) if state else (results, ())
 
+    def pack_lanes(self, hidden, positions):
+        """A lane pool step's ``hidden`` [n_lanes, 1, hidden] and ``positions``
+        [n_lanes] as the ONE array its program takes, so that a step is one
+        copy to the device: ``[n_lanes, hidden + 1]`` int32, a lane's float32
+        row bit for bit (a view, nothing converted) and its position in the
+        last column. An integer carrier because a position read as float32
+        bits is a denormal, which a TPU may flush wherever arithmetic touches
+        it; integers pass through copies and slices as they are. An array
+        that already has that form (the batcher's reused buffer) is handed
+        back as it is, ``positions`` being its last column."""
+        if not isinstance(hidden, jax.Array):
+            hidden = np.asarray(hidden)
+        n, hsz = len(hidden), self.hidden_size
+        if hidden.ndim == 2 and hidden.dtype == np.int32 and hidden.shape[1] == hsz + 1:
+            return hidden
+        if isinstance(hidden, jax.Array):  # rows already on the device (tests): packed there
+            rows = jax.lax.bitcast_convert_type(hidden.astype(jnp.float32).reshape(n, hsz), jnp.int32)
+            return jnp.concatenate([rows, jnp.asarray(positions, jnp.int32)[:, None]], axis=1)
+        packed = np.empty((n, hsz + 1), np.int32)
+        packed[:, :hsz].view(np.float32)[...] = np.asarray(hidden, np.float32).reshape(n, hsz)
+        packed[:, hsz] = positions
+        return packed
+
+    @staticmethod
+    def _unpack_lanes(lanes, dtype):
+        """``pack_lanes`` undone inside a step program: ``(hidden [n_lanes, 1,
+        hidden] in ``dtype``, positions [n_lanes] int32)``; the bit-cast back
+        to float32 is exact."""
+        hidden = jax.lax.bitcast_convert_type(lanes[:, :-1], jnp.float32)
+        return hidden[:, None, :].astype(dtype), lanes[:, -1]
+
+    @staticmethod
+    def device_tables(tables: np.ndarray) -> jax.Array:
+        """A snapshot of the block tables on the device, which a caller keeps
+        and hands to the paged step programs for as long as no entry changes
+        (the batcher's ``_step_tables``); never donated."""
+        return jax.device_put(np.array(tables, np.int32))  # np.array copies: what the device reads is nobody's to write
+
+    @staticmethod
+    def _as_tables(tables):
+        """The tables a paged step program is handed: a device array as it is
+        (nothing pulls it back to the host), anything else as int32."""
+        return tables if isinstance(tables, jax.Array) else np.asarray(tables, np.int32)
+
     @functools.cached_property
     def _paged_decode_fn(self):
         """Paged twin of ``_batched_decode_fn``: the pool is page-granular
@@ -1109,14 +1153,15 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_decode", steady=True,
-            static_argnames=("with_fp",), donate_argnums=(1, 2, 6),
+            static_argnames=("with_fp",), donate_argnums=(1, 2, 5),
         )
-        def step(params, k_pool, v_pool, hidden, positions, tables, state=(),
+        def step(params, k_pool, v_pool, lanes, tables, state=(),
                  *, with_fp: bool):
-            # hidden: [n_lanes, 1, hidden]; positions: [n_lanes] int32;
+            # lanes: [n_lanes, hidden + 1] int32, the lanes' rows and positions
+            # as ``pack_lanes`` lays them out (one copy in a step);
             # tables: [n_lanes, max_pages] int32 (-1 = unallocated slot);
             # state: the state pool's leaves, none for a span without one
-            hidden = hidden.astype(cache_dtype)
+            hidden, positions = self._unpack_lanes(lanes, cache_dtype)
             hidden, k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
                 self._paged_lanes_layer(tables, positions),
@@ -1137,23 +1182,23 @@ class TransformerBackend:
         """One coalesced decode step over the whole lane pool, PAGED layout.
 
         Args:
-          hidden: [n_lanes, 1, hidden] (idle lanes: any finite filler).
+          hidden: [n_lanes, 1, hidden] (idle lanes: any finite filler), or
+            the lanes' rows and positions already in ``pack_lanes``' form
+            (the batcher's reused buffer); either way the program is handed
+            that one array, so the two ways in run the same compiled step.
           pool_kv: (k, v) page pools [kv layers, n_pages, page_size, hkv, d],
             then the state pool's leaves for a span that keeps one; they
             come back in the same order.
           positions: int32 [n_lanes]; idle sentinel = max_pages * page_size.
-          tables: int32 [n_lanes, max_pages] block tables (-1 unallocated).
+          tables: int32 [n_lanes, max_pages] block tables (-1 unallocated),
+            on the host or already on the device (``device_tables``).
         """
         k_pool, v_pool, *state = pool_kv
-        tables = np.asarray(tables, np.int32)
-        if not isinstance(hidden, jax.Array):
-            hidden = np.ascontiguousarray(hidden)
         with_fp = fp_ops.enabled()
         with self._quant_ctx():
             res = self._paged_decode_fn(
-                self.params, k_pool, v_pool, hidden,
-                np.asarray(positions, np.int32), tables, tuple(state),
-                with_fp=with_fp,
+                self.params, k_pool, v_pool, self.pack_lanes(hidden, positions),
+                self._as_tables(tables), tuple(state), with_fp=with_fp,
             )
         res, state = self._split_state(res, state)
         if with_fp:
@@ -1214,7 +1259,7 @@ class TransformerBackend:
         """Paged twin of ``batched_gen_decode_step`` (same argument contract
         plus the block tables)."""
         k_pool, v_pool, *state = pool_kv
-        tables = np.asarray(tables, np.int32)
+        tables = self._as_tables(tables)
         if not isinstance(hidden, jax.Array):
             hidden = np.ascontiguousarray(hidden)
         v = sampling_vecs
@@ -1343,7 +1388,7 @@ class TransformerBackend:
         """
         self.refuse_for_state("speculative verify", SPEC_CUTS_BACK)
         k_pool, v_pool = pool_kv
-        tables = np.asarray(tables, np.int32)
+        tables = self._as_tables(tables)
         v = sampling_vecs
         with_fp = fp_ops.enabled()
         with self._quant_ctx():
@@ -1388,17 +1433,18 @@ class TransformerBackend:
 
         @tracked_jit(
             name="paged_mixed_step", steady=True,
-            static_argnames=("with_fp",), donate_argnums=(1, 2, 11),
+            static_argnames=("with_fp",), donate_argnums=(1, 2, 10),
         )
-        def step(params, k_pool, v_pool, hidden, positions, tables,
+        def step(params, k_pool, v_pool, lanes, tables,
                  chunk_hidden, chunk_lane, chunk_pos, chunk_n_valid,
                  chunk_n_total, state=(), *, with_fp: bool):
-            # hidden: [n_lanes, 1, hidden]; positions: [n_lanes] int32 (idle
-            # sentinel = max_len); chunk_hidden: [1, B, hidden] (B = static
+            # lanes: [n_lanes, hidden + 1] int32, the decode half's rows and
+            # positions as ``pack_lanes`` lays them out (idle sentinel =
+            # max_len); chunk_hidden: [1, B, hidden] (B = static
             # bucket); chunk_lane/chunk_pos/chunk_n_valid/chunk_n_total:
             # int32 scalars describing the ONE prefill chunk riding this step
             B = chunk_hidden.shape[1]
-            hidden = hidden.astype(cache_dtype)
+            hidden, positions = self._unpack_lanes(lanes, cache_dtype)
             chunk_hidden = chunk_hidden.astype(cache_dtype)
             table_row = jnp.take(tables, chunk_lane, axis=0)[None]  # [1, max_pages]
             decode_half = self._paged_lanes_layer(tables, positions)
@@ -1461,12 +1507,15 @@ class TransformerBackend:
         ONE prefill chunk for ``chunk_lane``, in a single jitted program.
 
         Args:
-          hidden: [n_lanes, 1, hidden] (idle lanes: any finite filler).
+          hidden: [n_lanes, 1, hidden] (idle lanes: any finite filler), or
+            the lanes' rows and positions in ``pack_lanes``' form, as
+            ``paged_decode_step`` takes them.
           pool_kv: (k, v) page pools [n_blocks, n_pages, page_size, hkv, d].
           positions: int32 [n_lanes]; idle sentinel = max_pages * page_size.
             The chunk lane must carry the sentinel here — its tokens ride the
             prefill half, not the decode half.
-          tables: int32 [n_lanes, max_pages] block tables (-1 unallocated).
+          tables: int32 [n_lanes, max_pages] block tables (-1 unallocated),
+            on the host or already on the device.
           chunk_hidden: [1, seq, hidden], unpadded; bucket padding (and the
             matching n_valid) happens here so callers stay shape-oblivious.
           chunk_lane / chunk_pos: which lane, and the chunk's first absolute
@@ -1485,9 +1534,6 @@ class TransformerBackend:
         Returns (decode_out [n_lanes, 1, h], chunk_out [1, seq, h], pool_kv).
         """
         k_pool, v_pool, *state = pool_kv
-        tables = np.asarray(tables, np.int32)
-        if not isinstance(hidden, jax.Array):
-            hidden = np.ascontiguousarray(hidden)
         seq = chunk_hidden.shape[1]
         bucket = bucket_length(seq)
         if not isinstance(chunk_hidden, jax.Array):
@@ -1505,8 +1551,8 @@ class TransformerBackend:
         with_fp = fp_ops.enabled()
         with self._quant_ctx():
             res = self._paged_mixed_step_fn(
-                self.params, k_pool, v_pool, hidden,
-                np.asarray(positions, np.int32), tables, chunk_hidden,
+                self.params, k_pool, v_pool, self.pack_lanes(hidden, positions),
+                self._as_tables(tables), chunk_hidden,
                 np.int32(chunk_lane), np.int32(chunk_pos), np.int32(seq),
                 np.int32(n_total), tuple(state), with_fp=with_fp,
             )

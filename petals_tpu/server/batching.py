@@ -47,7 +47,7 @@ import statistics
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -60,6 +60,7 @@ from petals_tpu.analysis.sanitizer import (
 from petals_tpu.utils.locks import AsyncTryLock
 from petals_tpu.data_structures import SESSION_PRIORITY_NORMAL
 from petals_tpu.ops.sampling import sampling_vectors
+from petals_tpu.server.backend import bucket_length
 from petals_tpu.server.memory_cache import (
     AllocationFailed,
     HostSwapPool,
@@ -247,6 +248,11 @@ class DecodeBatcher:
         # with the lane; a row at position 0 starts from zeros, so a new tenant needs no clearing. Only
         # the paged pool's step programs carry the state
         self._n_state = len(getattr(backend, "lane_state", None) or ())
+        # fixed with the backend and asked by the step bodies' counters every step: a page's and a lane's state's bytes
+        # (_page_nbytes, _state_nbytes), and the expert dispatch a block call of a shape takes (_moe_dispatch)
+        self._page_bytes = backend.kv_bytes_per_token() * self.page_size if self.page_size else 0
+        self._state_bytes = int(backend.state_bytes_per_lane()) if self._n_state else 0
+        self._moe_took: Dict[tuple, Optional[str]] = {}
         if self._n_state and self.page_size is None:
             backend.refuse_for_state(
                 "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""),
@@ -269,7 +275,24 @@ class DecodeBatcher:
         # such a span's rows choose positions only where a table can pass the selection's size (models/keye_vl2/block.py)
         self._selects = bool(self._n_index) and self.page_size is not None and self.max_length > backend.index_keep
         self._pages: Optional[PageAllocator] = None
-        self._tables: Optional[np.ndarray] = None  # [n_lanes, max_pages] int32, -1 = unallocated
+        # [n_lanes, max_pages] int32, -1 = unallocated. What everyone READS is a view that refuses writes: an
+        # entry changes value through ``_write_tables`` alone, which keeps beside the tables what the step bodies
+        # would otherwise reckon from them every step: ``_lane_held`` (the slots a lane owns) and ``_tables_version``
+        # (bumped after every write), by which ``_step_tables`` knows whether the copy the device holds
+        # (``_tables_on_device``: the version it was made from, the array) is still what the tables say
+        self._tables: Optional[np.ndarray] = None
+        self._tables_rw: Optional[np.ndarray] = None
+        self._lane_held: Optional[np.ndarray] = None  # [n_lanes] int64
+        self._tables_version = 0
+        self._tables_on_device: Tuple[int, Any] = (-1, None)
+        # the lanes' rows and positions of a paged decode or mixed step, in the form its program takes
+        # (backend.pack_lanes: [n_lanes, hidden + 1] int32, a float32 row bit for bit and the position last) and in
+        # ONE buffer, made once: a step body writes its batch's rows and every lane's position and hands it over
+        # whole, one copy to the device. An idle lane keeps the row it last fed (any finite filler will do for a row
+        # at the sentinel position); ``release_lane`` zeroes it, so a new tenant's neighbours never step beside a
+        # stranger's row. ``_lanes_rows`` is the float32 view of the rows
+        self._lanes_in: Optional[np.ndarray] = None
+        self._lanes_rows: Optional[np.ndarray] = None
         # cached tables_are_contiguous result for the stats/debug surface
         # (paged_summary); None = recompute on next read. The STEP path no
         # longer consults it — paged attention serves identity and permuted
@@ -427,6 +450,8 @@ class DecodeBatcher:
         # which code paths had run
         self.stats = {
             "batched_steps": 0, "batched_tokens": 0, "max_batch": 0,
+            # step bodies that copied the block tables to the device (the rest reused the copy there)
+            "tables_sent": 0,
             "gen_steps": 0, "gen_lane_tokens": 0, "max_gen_lanes": 0,
             "exclusive_chunks": 0, "prefill_tokens": 0, "mixed_steps": 0,
             "max_prefill_tokens_per_step": 0,
@@ -575,8 +600,13 @@ class DecodeBatcher:
             self._free_lanes = list(range(self.n_lanes))
             if self.page_size is not None:
                 self._pages = PageAllocator(self.n_pages)
-                self._tables = np.full((self.n_lanes, self.max_pages), -1, np.int32)
-                self._tables_mutated()
+                self._tables_rw = np.full((self.n_lanes, self.max_pages), -1, np.int32)
+                self._tables = self._tables_rw.view()
+                self._tables.flags.writeable = False
+                self._lane_held = np.zeros(self.n_lanes, np.int64)
+                hsz = self.backend.hidden_size
+                self._lanes_in = np.zeros((self.n_lanes, hsz + 1), np.int32)
+                self._lanes_rows = self._lanes_in[:, :hsz].view(np.float32)
                 logger.info(
                     f"Paged-batching pool open: {self.n_pages} pages x "
                     f"{self.page_size} tokens of {list(getattr(self.backend, 'pool_row', ()))} ({self.n_lanes} lanes x "
@@ -791,8 +821,8 @@ class DecodeBatcher:
             for slot in range(self.max_pages):
                 if row[slot] >= 0:
                     self._pages.decref(int(row[slot]))
-            row[:] = -1
-            self._tables_mutated()
+            self._write_tables(lane, slice(None), -1)
+            self._lanes_rows[lane] = 0.0  # the next tenant's neighbours step beside zeros, not this tenant's last row
         # hand straight to the best-placed waiter (priority class, then
         # per-peer fair share, then FIFO), else back to the free list; the
         # new session overwrites the lane from position 0, so no zeroing
@@ -902,8 +932,7 @@ class DecodeBatcher:
                 if self._pages is alloc:
                     alloc.decref(page)  # never reached the table: hand it back
                 raise
-            self._tables[lane, slot] = page
-            self._tables_mutated()
+            self._write_tables(lane, slot, page)
             pages_changed = True
         if pages_changed:
             # attribution rates changed (a grow or a COW fork): settle the
@@ -979,16 +1008,52 @@ class DecodeBatcher:
             self._pages.incref(int(page))
             if cur >= 0:
                 self._pages.decref(cur)
-            row[slot] = int(page)
         if pages:
-            self._tables_mutated()
+            self._write_tables(lane, slice(0, len(pages)), np.asarray(pages, np.int32))
             tm.PREFIX_ADOPT.inc()
             self._ledger_sync()  # the lane now shares the prefix pages' refcounts
 
-    def _tables_mutated(self) -> None:
-        """Invalidate the cached contiguity flag — call after ANY table write
-        (alloc, adopt, release, swap, reset)."""
+    def _write_tables(self, lane, slots, pages) -> None:
+        """The ONE writer of the block tables (event loop): ``pages`` into
+        ``slots`` of ``lane``'s row (ints, slices or index arrays, as
+        ``tables[lane, slots] = pages`` takes them: alloc, COW fork, adopt,
+        release, swap out and in, a pool reset). ``_tables`` itself refuses
+        writes, so nothing goes round this. Beside the write it counts the
+        slots the lane now owns (``_lane_held``), drops the cached contiguity
+        flag and, LAST, bumps ``_tables_version``.
+
+        ``_step_tables`` (compute thread) reads the version FIRST and copies
+        the tables after. Nothing orders the two threads but the task queue:
+        a lane's pages are written (``prepare_write``, ``_swap_in``) before
+        its entry is submitted to the compute thread, so the step that reads
+        the lane's row unmasked sees a version at or past that write's and
+        sends the tables if its copy is older. A write to ANOTHER lane that
+        lands between the read and the copy is in the copy under the old
+        number, and the next step sends again: an extra send, never a stale
+        table. (The other order, bump then write, or copy then read, could
+        record a version whose write the copy lacks.)"""
+        self._tables_rw[lane, slots] = pages
+        self._lane_held[lane] = (self._tables_rw[lane] >= 0).sum(axis=-1)
         self._tables_contig = None
+        self._tables_version += 1
+
+    def _step_tables(self):
+        """The block tables a paged step's program reads, on the device
+        (compute thread): the copy made for an earlier step if no entry has
+        changed value since, else a new snapshot (counted in ``tables_sent``).
+        A sent copy is a snapshot and an unsent one is unchanged by
+        definition, so a step reads what ``self._tables.copy()`` gave it when
+        every step copied: the event loop may grow OTHER lanes while the step
+        runs, but never slots this step reads unmasked or writes
+        (``prepare_write`` ran before each entry was enqueued). Order and
+        races: ``_write_tables``."""
+        version = self._tables_version  # before the copy
+        sent, on_device = self._tables_on_device
+        if on_device is None or sent != version:
+            on_device = self.backend.device_tables(self._tables)
+            self._tables_on_device = (version, on_device)
+            self.stats["tables_sent"] += 1
+        return on_device
 
     def tables_contiguous(self) -> Optional[bool]:
         """Stats/debug surface ONLY: are the block tables currently the
@@ -1025,19 +1090,26 @@ class DecodeBatcher:
         and fair-share accounting)."""
         if self._tables is None:
             return 0
-        return int((self._tables[lane] >= 0).sum())
+        return int(self._lane_held[lane])
 
     def _page_nbytes(self) -> int:
         # WIRE bytes per page: quantized pools swap/reserve packed bytes, so
         # the host-swap budget, ledger swap meters, and victim sizing all
         # bill what actually moves (kv_bytes_per_token == cache_bytes_per_token
         # for unquantized backends)
-        return self.backend.kv_bytes_per_token() * self.page_size
+        return self._page_bytes
 
     def _state_nbytes(self) -> int:
         """What a lane holds whatever its context: its slot in the state
         pool. 0 for a span without a recurrent state."""
-        return int(self.backend.state_bytes_per_lane()) if self._n_state else 0
+        return self._state_bytes
+
+    def _moe_dispatch(self, seq: int, chunk: bool = False) -> Optional[str]:
+        """``backend.moe_grouped``, asked once a shape (``_count_moe`` asks every step)."""
+        took = self._moe_took.get((seq, chunk))
+        if took is None:
+            took = self._moe_took[seq, chunk] = self.backend.moe_grouped(seq, chunk=chunk)
+        return took
 
     def _lane_lock(self, lane: int) -> AsyncTryLock:
         lock = self._lane_locks.get(lane)
@@ -1197,8 +1269,7 @@ class DecodeBatcher:
                 return False
             for page in pages:
                 alloc.decref(int(page))
-            self._tables[lane, slots] = -1
-            self._tables_mutated()
+            self._write_tables(lane, slots, -1)
             slot.swap = SwapEntry(
                 k=k_host, v=v_host, slots=slots, nbytes=nbytes, generation=gen,
                 suspended_at=time.monotonic(), state=state_host,
@@ -1284,8 +1355,7 @@ class DecodeBatcher:
                     alloc.decref(int(page))
             self._maybe_reset_pool()  # the scatter donates the pool buffers
             raise
-        self._tables[lane, entry.slots] = pages_arr
-        self._tables_mutated()
+        self._write_tables(lane, entry.slots, pages_arr)
         slot.swap = None
         slot.resumed_at = time.monotonic()
         self.swap_pool.free(entry.nbytes)
@@ -1519,10 +1589,10 @@ class DecodeBatcher:
                 info["state_bytes_held"] = info["busy_lanes"] * self._state_nbytes()
             if self._windows and self._tables is not None:
                 # over the lanes that hold pages, at the last position each fed
-                live = np.flatnonzero((self._tables >= 0).any(axis=1))
-                info["window_pages_held"], info["window_pages_in_reach"] = self._window_pages(
-                    self._tables, live, self._lane_pos[live]
-                )
+                live = np.flatnonzero(self._lane_held)
+                info["window_pages_held"], info["window_pages_in_reach"] = self._window_pages(live, self._lane_pos[live])
+            # of the step bodies so far, those that copied the block tables to the device (``_step_tables``)
+            info["tables_sent"], info["batched_steps"] = self.stats["tables_sent"], self.stats["batched_steps"]
         info.update(self._scheduler.summary())
         return info
 
@@ -2192,8 +2262,8 @@ class DecodeBatcher:
                     self._pages.freed_event.set()
                 self._pages = PageAllocator(self.n_pages)
                 if self._tables is not None:
-                    self._tables[:] = -1
-                    self._tables_mutated()
+                    self._write_tables(slice(None), slice(None), -1)
+                    self._tables_on_device = (-1, None)  # the device's copy goes with the pool
             for handle in self._handles or ():
                 try:
                     self.memory_cache.reset_buffer(handle)
@@ -2287,10 +2357,10 @@ class DecodeBatcher:
         ``block_apply`` once for the lanes and once for the chunk)."""
         if "moe_weight_passes" not in self.stats:
             return
-        dispatch = self.backend.moe_grouped
+        dispatch = self._moe_dispatch
         halves = [(dispatch(seq), tokens)]
         if chunk_tokens:
-            chunk_took = dispatch(chunk_tokens, chunk=True)
+            chunk_took = dispatch(bucket_length(chunk_tokens), True)
             halves.append((chunk_took, chunk_tokens))
             if "moe_chunk_rows_computed" in self.stats:  # a span that holds a share of its routed experts
                 dims = self.backend.moe_dims
@@ -2302,12 +2372,12 @@ class DecodeBatcher:
                 self.stats["moe_hit_tokens"] += n
         self.stats["moe_weight_passes"] += len(halves)
 
-    def _window_pages(self, tables: np.ndarray, lanes, positions) -> Tuple[int, int]:
+    def _window_pages(self, lanes, positions) -> Tuple[int, int]:
         """(held, in reach): the pages ``lanes`` hold, once a windowed layer
         of the span, and those of them a layer's window still reaches from
         the lane's ``positions`` entry. The rest are held until the session
         ends (freeing them is ROADMAP B3)."""
-        held = (tables[lanes] >= 0).sum(axis=1)
+        held = self._lane_held[lanes]
         pos = np.asarray(positions, np.int64)
         reach = 0
         for window in self._windows:
@@ -2315,21 +2385,32 @@ class DecodeBatcher:
             reach += int(np.minimum(pages, held).sum())
         return int(held.sum()) * len(self._windows), reach
 
-    def _count_window(self, tables, positions, *, seq: int = 1, chunk=None) -> None:
-        """The attention counters of one paged step (compute thread), from
-        the positions the step was started with: of the table slots its
-        programs are handed (every lane of the pool's, a layer that keeps keys
-        and values), those they read. A decode row's walk reads whole blocks
-        up to the longest live lane's last page, for every lane; a verify's
-        ``seq`` rows and the ``chunk`` (lane, first position, tokens) of a
-        mixed step, at its bucket, gather the slots in reach. For a family
-        that declares its layers' windows, the window counters besides."""
-        if "attn_pages_gathered" not in self.stats or tables is None:
-            return
-        from petals_tpu.server.backend import bucket_length
+    def _count_paged(self, positions, *, seq: int = 1, chunk=None) -> None:
+        """The per-layer counters of one paged step (compute thread), every
+        one from the step's shapes and the positions it was started with:
+        ``lanes``, the lanes that fed a row, is reckoned once, and the pages
+        a lane holds are ``_lane_held``'s, kept where the tables are written,
+        so that nothing here walks the tables. ``chunk`` is the (lane, first
+        position, tokens) of a mixed step's prompt chunk."""
+        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
+        self._count_window(positions, lanes, seq=seq, chunk=chunk)
+        self._count_state(lanes, chunk=chunk)
+        self._count_sparse(positions, lanes, chunk=chunk)
+        self._count_latent(positions, lanes, chunk=chunk)
 
+    def _count_window(self, positions, lanes, *, seq: int = 1, chunk=None) -> None:
+        """The attention counters of one paged step, from the positions the
+        step was started with: of the table slots its programs are handed
+        (every lane of the pool's, a layer that keeps keys and values), those
+        they read. A decode row's walk reads whole blocks up to the longest
+        live lane's last page, for every lane; a verify's ``seq`` rows and
+        the ``chunk`` of a mixed step, at its bucket, gather the slots in
+        reach. For a family that declares its layers' windows, the window
+        counters besides."""
+        if "attn_pages_gathered" not in self.stats:
+            return
         backend, layers = self.backend, len(self.backend.kv_layers)
-        last = positions[positions < self.max_length] + (seq - 1)  # the idle sentinel is max_length
+        last = positions[lanes] + (seq - 1)
         by_kernel = 0
         if seq > 1:
             read = self.n_lanes * backend.pages_gathered(seq, self.max_pages, self.page_size)
@@ -2353,44 +2434,45 @@ class DecodeBatcher:
             self.stats["attn_pages_tabled"] += self.max_pages * layers
         if not self._windows:
             return
-        lanes, last = np.flatnonzero(positions < self.max_length), last.astype(np.int64)
+        last = last.astype(np.int64)
         if chunk is not None:
             lanes, last = np.append(lanes, lane), np.append(last, first + take - 1)
         self._lane_pos[lanes] = last
-        held, reach = self._window_pages(tables, lanes, last)
+        held, reach = self._window_pages(lanes, last)
         self.stats["window_pages_held"] += held
         self.stats["window_pages_in_reach"] += reach
 
-    def _count_state(self, tables, positions, *, chunk=None) -> None:
-        """The state counters of one paged step (compute thread; a family
-        that declares a state only), from the shapes the step was started
-        with: every lane that fed a row took the one-step form in each state
-        layer, the ``chunk`` (lane, tokens) of a mixed step the chunked form;
-        and what those lanes hold, their slots in the state pool and their
-        pages in the blocks that keep keys and values."""
-        if not self._n_state or tables is None:
+    def _held_with(self, lanes, chunk) -> int:
+        """The pages ``lanes`` and a mixed step's ``chunk`` lane hold."""
+        return int(self._lane_held[lanes].sum()) + (0 if chunk is None else int(self._lane_held[chunk[0]]))
+
+    def _count_state(self, lanes, *, chunk=None) -> None:
+        """The state counters of one paged step (a family that declares a
+        state only), from the shapes the step was started with: every lane
+        that fed a row took the one-step form in each state layer, the
+        ``chunk`` of a mixed step the chunked form; and what those lanes
+        hold, their slots in the state pool and their pages in the blocks
+        that keep keys and values."""
+        if not self._n_state:
             return
         layers = len(self.backend.state_layers)
-        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
-        self.stats["linattn_recurrent_tokens"] += int(lanes.size) * layers
+        rows = int(lanes.size) * layers
+        self.stats["linattn_recurrent_tokens"] += rows
         if self._state_step == "kernel":
-            self.stats["linattn_kernel_tokens"] += int(lanes.size) * layers
+            self.stats["linattn_kernel_tokens"] += rows
         if chunk is not None:
-            lanes = np.append(lanes, chunk[0])
-            self.stats["linattn_chunk_tokens"] += int(chunk[1]) * layers
-        self.stats["state_bytes_held"] += int(lanes.size) * self._state_nbytes()
-        self.stats["kv_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
+            self.stats["linattn_chunk_tokens"] += int(chunk[2]) * layers
+        self.stats["state_bytes_held"] += (int(lanes.size) + (chunk is not None)) * self._state_nbytes()
+        self.stats["kv_bytes_held"] += self._held_with(lanes, chunk) * self._page_nbytes()
 
-    def _count_sparse(self, tables, positions, *, chunk=None) -> None:
-        """The selection's counters of one paged step (compute thread; a
-        family that declares an index row only), from the shapes the step was
-        started with: what the lanes that fed a row and the ``chunk`` (lane,
-        first position, tokens) of a mixed step made the programs score and
-        fetch (``backend.sparse_reads``), and the bytes of index rows and of
-        keys and values those lanes' pages hold."""
-        if not self._n_index or tables is None:
+    def _count_sparse(self, positions, lanes, *, chunk=None) -> None:
+        """The selection's counters of one paged step (a family that declares
+        an index row only), from the shapes the step was started with: what
+        the lanes that fed a row and the ``chunk`` of a mixed step made the
+        programs score and fetch (``backend.sparse_reads``), and the bytes of
+        index rows and of keys and values those lanes' pages hold."""
+        if not self._n_index:
             return
-        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
         reads = self.backend.sparse_reads(
             self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:]
         )
@@ -2398,33 +2480,42 @@ class DecodeBatcher:
             self.stats[key] += n
         if self._selects:
             self.stats["attn_pages_gathered"] += -(-reads["sparse_kv_rows_read"] // self.page_size)
-        if chunk is not None:
-            lanes = np.append(lanes, chunk[0])
-        pages = int((tables[lanes] >= 0).sum())
+        pages = self._held_with(lanes, chunk)
         index = pages * self.page_size * int(self.backend.index_bytes_per_token())
         self.stats["index_bytes_held"] += index
         self.stats["kv_bytes_held"] += pages * self._page_nbytes() - index
 
-    def _count_latent(self, tables, positions, *, chunk=None) -> None:
-        """The latent attention's counters of one paged step (compute thread;
-        a family that declares a latent row only), from the shapes the step
-        was started with: what the lanes that fed a row (the absorbed form)
-        and the ``chunk`` (lane, first position, tokens) of a mixed step (the
-        expanded one) made the programs read and score
-        (``backend.latent_reads``), and the bytes of latent rows those lanes'
-        pages hold."""
-        if not self._latent or tables is None:
+    def _count_latent(self, positions, lanes, *, chunk=None) -> None:
+        """The latent attention's counters of one paged step (a family that
+        declares a latent row only), from the shapes the step was started
+        with: what the lanes that fed a row (the absorbed form) and the
+        ``chunk`` of a mixed step (the expanded one) made the programs read
+        and score (``backend.latent_reads``), and the bytes of latent rows
+        those lanes' pages hold."""
+        if not self._latent:
             return
-        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
         reads = self.backend.latent_reads(
             self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:]
         )
         for key, n in reads.items():
             self.stats[key] += n
         self.stats["attn_pages_gathered"] += reads["latent_rows_read"] // self.page_size
-        if chunk is not None:
-            lanes = np.append(lanes, chunk[0])
-        self.stats["latent_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
+        self.stats["latent_bytes_held"] += self._held_with(lanes, chunk) * self._page_nbytes()
+
+    def _fill_lanes(self, batch) -> Tuple[np.ndarray, np.ndarray]:
+        """``batch``'s rows and every lane's position written into the
+        lanes' one buffer (compute thread; ``_lanes_in``): ``(the buffer as
+        the step's program takes it, its column of positions)``, both views
+        good until the next step body fills them again. A lane out of the
+        batch rides at the idle sentinel, ``max_length``, with the row it
+        last fed."""
+        positions = self._lanes_in[:, -1]
+        positions[:] = self.max_length
+        rows = self._lanes_rows
+        for lane, h, pos, _fut, _gen in batch:
+            rows[lane] = np.asarray(h, np.float32).reshape(-1)
+            positions[lane] = pos
+        return self._lanes_in, positions
 
     def _run_batch(self, batch) -> np.ndarray:
         """Compute-thread body: ONE jitted step for every pending lane."""
@@ -2437,25 +2528,26 @@ class DecodeBatcher:
             if batch and batch[0][4] != self._generation:
                 raise AllocationFailed("Lane pool was reset before this batched step ran")
             t_step = time.perf_counter()
-            hsz = self.backend.hidden_size
-            hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
-            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-            for lane, h, pos, _fut, _gen in batch:
-                hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
-                positions[lane] = pos
+            paged = self.page_size is not None
             k_pool, v_pool = self._buffers()
             state = self._state()
-            # snapshot the tables: the event loop may grow OTHER lanes while
-            # this step runs, but never slots this step reads unmasked or
-            # writes (prepare_write ran before each entry was enqueued)
-            tables = self._tables.copy() if self.page_size is not None else None
-            phases.enter("dispatch")
-            if tables is not None:
+            if paged:
+                lanes_in, positions = self._fill_lanes(batch)
+                tables = self._step_tables()
+                phases.enter("dispatch")
                 out, (k_pool, v_pool, *state) = self.backend.paged_decode_step(
-                    hidden, (k_pool, v_pool, *state), positions, tables,
+                    lanes_in, (k_pool, v_pool, *state), positions, tables,
                     handles=self._handles,
                 )
+                out.copy_to_host_async()  # queued behind the step: the rows are on their way when it ends
             else:
+                hsz = self.backend.hidden_size
+                hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
+                positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
+                for lane, h, pos, _fut, _gen in batch:
+                    hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
+                    positions[lane] = pos
+                phases.enter("dispatch")
                 out, (k_pool, v_pool) = self.backend.batched_decode_step(
                     hidden, (k_pool, v_pool), positions, handles=self._handles
                 )
@@ -2476,12 +2568,10 @@ class DecodeBatcher:
             self.stats["batched_tokens"] += len(batch)
             self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
             self._count_moe(len(batch))
-            self._count_window(tables, positions)
-            self._count_state(tables, positions)
-            self._count_sparse(tables, positions)
-            self._count_latent(tables, positions)
+            if paged:
+                self._count_paged(positions)
             duration = time.perf_counter() - t_step
-            if self.page_size is not None:
+            if paged:
                 tm.STEP_PAGED.observe(duration)
                 tm.STEPS_PAGED.inc()
             else:
@@ -2556,22 +2646,19 @@ class DecodeBatcher:
             if expected != self._generation or st.generation != self._generation:
                 raise AllocationFailed("Lane pool was reset before this batched step ran")
             t_step = time.perf_counter()
-            hsz = self.backend.hidden_size
-            hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
-            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-            for lane, h, pos, _fut, _gen in batch:
-                hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
-                positions[lane] = pos
+            lanes_in, positions = self._fill_lanes(batch)
             chunk = st.hidden[:, st.offset : st.offset + take]
             k_pool, v_pool = self._buffers()
             state = self._state()
-            tables = self._tables.copy()
+            tables = self._step_tables()
             phases.enter("dispatch")
             out, chunk_out, (k_pool, v_pool, *state) = self.backend.paged_mixed_step(
-                hidden, (k_pool, v_pool, *state), positions, tables,
+                lanes_in, (k_pool, v_pool, *state), positions, tables,
                 chunk, st.lane, st.position, n_total=st.n_total,
                 handles=self._handles, trim=False,
             )
+            out.copy_to_host_async()  # both queued behind the step, as _run_batch's
+            chunk_out.copy_to_host_async()
             phases.enter("wait")
             host_out = np.asarray(out)  # device sync: the step has fully executed
             host_chunk = np.asarray(chunk_out)[:, :take]  # the chunk's bucket, cut to its rows here and not on the device
@@ -2591,10 +2678,7 @@ class DecodeBatcher:
                 self.stats["max_prefill_tokens_per_step"], take
             )
             self._count_moe(len(batch), chunk_tokens=take)
-            self._count_window(tables, positions, chunk=(st.lane, st.position, take))
-            self._count_state(tables, positions, chunk=(st.lane, take))
-            self._count_sparse(tables, positions, chunk=(st.lane, st.position, take))
-            self._count_latent(tables, positions, chunk=(st.lane, st.position, take))
+            self._count_paged(positions, chunk=(st.lane, st.position, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2651,7 +2735,7 @@ class DecodeBatcher:
                     vecs["seen_mask"][lane] = st.seen
             k_pool, v_pool = self._buffers()
             state = self._state()
-            tables = self._tables.copy() if self.page_size is not None else None
+            tables = self._step_tables() if self.page_size is not None else None
             phases.enter("dispatch")
             if tables is not None:
                 out, toks, (k_pool, v_pool, *state) = self.backend.paged_gen_decode_step(
@@ -2685,10 +2769,8 @@ class DecodeBatcher:
                 self.stats["max_gen_lanes"], len(gen_states)
             )
             self._count_moe(len(batch) + len(gen_states))
-            self._count_window(tables, positions)
-            self._count_state(tables, positions)
-            self._count_sparse(tables, positions)
-            self._count_latent(tables, positions)
+            if tables is not None:
+                self._count_paged(positions)
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
@@ -2761,7 +2843,7 @@ class DecodeBatcher:
                 if st.seen is not None:
                     vecs["seen_mask"][lane] = st.seen
             k_pool, v_pool = self._buffers()
-            tables = self._tables.copy()
+            tables = self._step_tables()
             phases.enter("dispatch")
             g_hat, n_emit, (k_pool, v_pool) = self.backend.paged_spec_verify_step(
                 self.gen_params, tokens, (k_pool, v_pool), positions,
@@ -2788,7 +2870,7 @@ class DecodeBatcher:
             self.stats["spec_accepted"] += accepted_total
             self.stats["max_spec_lanes"] = max(self.stats["max_spec_lanes"], n_spec)
             self._count_moe(n_spec * S, seq=S)
-            self._count_window(tables, positions, seq=S)
+            self._count_paged(positions, seq=S)
             duration = time.perf_counter() - t_step
             tm.STEP_SPEC.observe(duration)
             tm.STEPS_SPEC.inc()

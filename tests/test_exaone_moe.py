@@ -431,17 +431,16 @@ def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monk
     assert read <= set(batcher.stats) and not held & set(batcher.stats) and backend.layer_windows is None and len(backend.runs) == 1
     assert "moe_weight_passes" not in batcher.stats and not held & set(batcher.occupancy_info())
     assert batcher._walks == ((None, 1, 1, False, path),) and batcher.occupancy_info()["decode_walk"] == [path]
-    tables = np.zeros((2, 4), np.int32)
     # the composed walk: both lanes to the longest live one's page; the kernel: each live lane to its own
     for positions, walked, own in (([5, 64], 1, 1), ([64, 16], 2, 2), ([47, 0], 3, 4), ([64, 64], 0, 0), ([63, 64], 4, 4), ([63, 17], 4, 6)):
         was = dict(batcher.stats)
-        batcher._count_window(tables, np.asarray(positions, np.int32))
+        batcher._count_paged(np.asarray(positions, np.int32))  # nothing of it reads the tables (PR 51)
         want = own if path == "kernel" else 2 * walked  # an idle lane is no length
         assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == want, positions
         assert batcher.stats["attn_pages_kernel"] - was["attn_pages_kernel"] == (want if path == "kernel" else 0), positions
         assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4
     was = dict(batcher.stats)
-    batcher._count_window(tables, np.asarray([64, 3], np.int32), chunk=(0, 16, 20))  # a chunk gathers its lane's whole row
+    batcher._count_paged(np.asarray([64, 3], np.int32), chunk=(0, 16, 20))  # a chunk gathers its lane's whole row
     assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == (1 if path == "kernel" else 2 * 1) + 4
     assert batcher.stats["attn_pages_kernel"] - was["attn_pages_kernel"] == (1 if path == "kernel" else 0)  # the chunk's gather is no walk
     assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4 + 4

@@ -325,7 +325,8 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     pools = pool if kv_quant == "none" else PagedPool(pool, v5e(descs[2].shape, descs[2].dtype))
     # the second pool is the first one's twin but for a span that caches a latent row in place of keys and values
     second = pools if backend.latent_row is None else v5e(descs[1].shape, BF16)
-    avals = [params, pools, second, v5e((lanes, 1, cfg.hidden_size), BF16), v5e((lanes,), I32), v5e((lanes, pages_a_lane), I32)]
+    # the lanes' rows and positions as one operand (backend.pack_lanes' form: a float32 row bit for bit and its position), then the tables
+    avals = [params, pools, second, v5e((lanes, cfg.hidden_size + 1), I32), v5e((lanes, pages_a_lane), I32)]
     step = backend._paged_decode_fn
     if chunk:  # chunk_hidden, then chunk_lane, chunk_pos, chunk_n_valid, chunk_n_total
         step = backend._paged_mixed_step_fn
@@ -1148,3 +1149,35 @@ def test_olmo_hybrid_s_decode_step_is_the_program_it_was_before_the_mixer_moved(
     monkeypatch.setattr(olmo, "gated_delta_mixer", parents)
     before = program()
     assert CFG and len(now) > 10 and "paged_decode_walk" in str(now) and now == before
+
+
+# ---------------------------------------------------------------- one packed operand for the lanes, the tables a plain operand (PR 51)
+
+
+@pytest.mark.parametrize("config_name,chunk,pages_a_lane", [
+    pytest.param(name, chunk, pages, id=f"{name}-{f'mixed-{chunk}' if chunk else 'decode'}")
+    for name, pages, chunks in (("falcon-40b-span5", 16, (0, 256)), (Q3N, 40, (0, 512))) for chunk in chunks
+])
+def test_step_takes_its_lanes_packed_and_still_aliases_what_it_was_donated(v5e, tmp_path, config_name, chunk, pages_a_lane):
+    """The lanes' rows and positions reach the compiled decode and mixed
+    steps as ONE int32 operand ``[lanes, hidden + 1]`` and the block tables as
+    another, both plain parameters of ``ENTRY`` that alias no result (the
+    batcher keeps the tables' device copy from step to step, so it must never
+    be donated); every donated pool, and each leaf of the state pool, is still
+    aliased to a result, and no copy of a page pool is held."""
+    hlo, _, pool, heads = _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=pages_a_lane)
+    comps = _computations(hlo)
+    entry = comps[re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)]
+    numbered = {name.split(".")[0]: (int(rest.split(")")[0]), dims) for name, dims, op, rest in entry if op == "parameter"}
+    hidden = json.loads((Path(__file__).resolve().parents[1] / "perf" / "configs" / f"{config_name}.json").read_text())["hidden_size"]
+    assert numbered["lanes"][1] == (8, hidden + 1) and numbered["tables"][1] == (8, pages_a_lane)
+    for name in ("lanes", "tables"):
+        assert re.search(rf"%{name}\.\d+ = s32\[", hlo), f"{name} is not an int32 operand"
+    assert not {"hidden", "positions"} & set(numbered), sorted(numbered)
+    header = hlo.splitlines()[0]
+    aliased = {int(n) for n in re.findall(r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header.split("input_output_alias={")[1].split("entry_computation_layout")[0])}
+    donated = {name for name in numbered if name in ("k_pool", "v_pool") or name.startswith("state_")}
+    assert len(donated) == (4 if config_name == Q3N else 2), sorted(numbered)
+    assert aliased == {numbered[name][0] for name in donated}, (aliased, {name: numbered[name][0] for name in donated})
+    moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
+    assert loops_seen and not moves, moves

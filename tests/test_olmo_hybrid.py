@@ -621,7 +621,8 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     assert [d.shape for d in backend.cache_descriptors(3, 24, 0, 2)] == [(2, 3, 24, backend.num_kv_heads, backend.head_dim)] * 2
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
     # since PR 36 every family on the paged pool counts the table slots its steps read (_count_window)
-    assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel"} and batcher._n_state == 0 and batcher._state() == ()
+    # ... and since PR 51 every batcher the step bodies that sent the block tables to the device (_step_tables)
+    assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", "tables_sent"} and batcher._n_state == 0 and batcher._state() == ()
     assert not {"state_bytes_per_lane", "state_bytes_held"} & set(batcher.occupancy_info())
     # the step programs take the pair of pools and give the pair back, and carry what they carried
     k, v = (jnp.zeros(d.shape, d.dtype) for d in backend.paged_cache_descriptors(6, 8, 0, 2))
@@ -633,5 +634,5 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     out, chunk, pools = backend.paged_mixed_step(hidden, pools, positions, tables, np.zeros((1, 5, cfg.hidden_size), np.float32), 1, 0)
     assert len(pools) == 2 and chunk.shape == (1, 5, cfg.hidden_size)
     jaxpr = jax.make_jaxpr(lambda *a: backend._paged_decode_fn.__wrapped__(*a, with_fp=False))(
-        backend.params, k, v, hidden, positions, tables)
+        backend.params, k, v, backend.pack_lanes(hidden, positions), tables)
     assert len(jaxpr.out_avals) == 3  # hidden and the two pools: no state rides a span without one

@@ -360,7 +360,8 @@ def test_the_decode_walk_the_backend_counts_is_the_one_its_step_is_handed(name, 
 
     descs = backend.paged_cache_descriptors(lanes * slots, page_size, 0, depth)
     pools = [aval(d.shape, d.dtype) for d in descs[:2]]
-    avals = [backend.params, *pools, aval((lanes, 1, cfg.hidden_size), jnp.float32), aval((lanes,), jnp.int32), aval((lanes, slots), jnp.int32)]
+    # the lanes' rows and positions in backend.pack_lanes' form, then the tables
+    avals = [backend.params, *pools, aval((lanes, cfg.hidden_size + 1), jnp.int32), aval((lanes, slots), jnp.int32)]
     if backend.state_layers:
         avals.append(tuple(aval(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
     if backend.index_row is not None:
