@@ -37,8 +37,8 @@ _EXACT = jax.lax.Precision.HIGHEST
 LANES, SUBLANES = 128, 8  # the chip's tile of float32
 
 
-def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, taps: jnp.ndarray, n_valid=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Depthwise causal conv of ``K`` taps without bias, then silu.
+def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, taps: jnp.ndarray, n_valid=None, bias=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Depthwise causal conv of ``K`` taps, then silu; ``bias`` [channels] is added in front of the silu where a layer has one.
 
     ``u`` [batch, seq, channels] are the new rows, ``tail`` [batch, K - 1,
     channels] the rows before them (zeros at a sequence's start), ``taps``
@@ -51,6 +51,8 @@ def causal_conv(u: jnp.ndarray, tail: jnp.ndarray, taps: jnp.ndarray, n_valid=No
         fed = jnp.concatenate([tail.astype(u.dtype), u], axis=1)  # [batch, K - 1 + seq, channels]
         w = taps.astype(jnp.float32)
         out = sum(w[j] * fed[:, j : j + seq].astype(jnp.float32) for j in range(width))
+        if bias is not None:
+            out = out + bias.astype(jnp.float32)
         n = seq if n_valid is None else n_valid
         new_tail = jax.lax.dynamic_slice_in_dim(fed, n, width - 1, axis=1).astype(tail.dtype)
         return out * jax.nn.sigmoid(out), new_tail

@@ -85,8 +85,9 @@ PAGED_CASES = [
     (32, 32, 128, "nf4a"),
     (64, 8, 64, "none"),
     (16, 2, 256, "none"),  # two kv heads: stored folded whatever their width
+    (20, 1, 128, "none"),  # one kv head for 20 query heads (not a power of two): a folded row of 128
 ]
-PAGED_IDS = ["bf16-mha", "bf16-gqa", "int8-gqa", "nf4a-mha", "bf16-d64", "bf16-2x256"]
+PAGED_IDS = ["bf16-mha", "bf16-gqa", "int8-gqa", "nf4a-mha", "bf16-d64", "bf16-2x256", "bf16-mqa20"]
 
 
 @pytest.mark.parametrize("hq,hkv,d,kv_quant", PAGED_CASES, ids=PAGED_IDS)
@@ -1181,3 +1182,84 @@ def test_step_takes_its_lanes_packed_and_still_aliases_what_it_was_donated(v5e, 
     assert aliased == {numbered[name][0] for name in donated}, (aliased, {name: numbered[name][0] for name in donated})
     moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
     assert loops_seen and not moves, moves
+
+
+# ---------------------------------------------------------------- a state-space model's state pool, 26 layers deep (PR 52)
+
+JAMBA = "jamba2-3b-span28"
+
+
+def _layout_bytes(dims: tuple, layout: str, itemsize: int) -> int:
+    """Bytes an array of ``dims`` takes in an HLO layout ``{minor_to_major:T(rows,lanes)...}``: its two minor
+    dimensions in whole tiles (a second tile ``(2,1)`` packs two 16-bit values a word: twice the rows)."""
+    order = [int(i) for i in re.match(r"\{([\d,]+)", layout).group(1).split(",")]
+    rows, lanes = (int(n) for n in re.search(r"T\((\d+),(\d+)\)", layout).groups())
+    rows *= 2 if "(2,1)" in layout else 1
+    padded = list(dims)
+    padded[order[0]] = -(-dims[order[0]] // lanes) * lanes
+    padded[order[1]] = -(-dims[order[1]] // rows) * rows
+    return math.prod(padded) * itemsize
+
+
+@pytest.mark.parametrize("chunk", [0, 512], ids=["decode", "mixed-512"])
+def test_a_state_space_span_leaves_its_state_pool_and_its_one_head_pages_in_place(v5e, tmp_path, chunk):
+    """jamba2-3b-span28 at the cell's geometry (8 lanes, 40 pages a lane), all
+    28 blocks of the model in five runs of kinds: the compiled decode and mixed
+    steps copy no page pool (ONE kv head of 128: a folded row, two layers deep)
+    and no state pool (26 layers x 8 lanes x [16, 5120] float32, 170 MB), in
+    ``ENTRY`` or in a run's loop. The state lies as ``block_state`` declares
+    it, channels minor: its tile divides [16, 5120] and the pool takes its
+    declared bytes (as the checkpoint's [5120, 16] a float32 tile would pad
+    16 to 128: 8 times). The conv's tails (6.4 MB declared) lie with the LANES
+    second-minor and take twice their bytes (8 lanes in a tile of 16 rows of
+    bfloat16), and the mixed step, which takes one lane's out, copies them in
+    ``ENTRY``, never in a loop: 0.3 MB a layer, beside 6.9 GB of weights. The
+    decode rows' attention is the composed walk (``decode_walks`` counts it;
+    the walk's kernel names the folded pool as its reason), so no step holds
+    a custom call."""
+    from petals_tpu.server.from_pretrained import get_block_config
+
+    hlo, runs, pool, heads = _compiled_step(v5e, tmp_path, JAMBA, chunk, pages_a_lane=40)
+    assert [run["ln1"].shape[0] for run in runs] == [7, 1, 13, 1, 6] and heads == (1, 128) and tuple(pool.shape) == (2, 320, 64, 128)
+    moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
+    assert not moves, f"the step moves the page pool: {moves}"
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    family, cfg = get_block_config(str(tmp_path))  # ``_compiled_step`` left the published config.json there
+    (state_shape, _), (tail_shape, _) = family.state_for(cfg, "mamba")
+    assert state_shape == (16, 5120) and tail_shape == (3, 5120)
+    state, tail = (26, 8, *state_shape), (26, 8, *tail_shape)
+    copies = lambda dims: [(where, name, op) for where, instructions in comps.items() for name, d, op, rest in instructions if tuple(d) == dims
+                           and (op in ("copy", "copy-start") or (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest))]
+    assert any(tuple(dims) == state for _, dims, _, _ in comps[entry]), "the state pool was not found in ENTRY"
+    assert not copies(state), f"the step moves the state pool: {copies(state)}"
+    assert {where for where, _, _ in copies(tail)} <= ({entry} if chunk else set()), copies(tail)
+    handed = {dims: re.findall(rf"= {dtype}\[{','.join(map(str, dims))}\](\{{[^}}]*\}}) parameter\(\d+\), sharding", hlo)
+              for dims, dtype in ((state, "f32"), (tail, "bf16"))}  # ENTRY's parameters carry a sharding
+    assert all(len(layouts) == 1 for layouts in handed.values()), handed  # as the program is handed each pool, and hands it back
+    assert _layout_bytes(state, handed[state][0], 4) == math.prod(state) * 4 == 26 * 8 * 327_680
+    assert _layout_bytes(tail, handed[tail][0], 2) == 2 * math.prod(tail) * 2
+    assert set(re.findall(r"f32\[26,8,16,5120\](\{[^}]*\})", hlo)) == set(handed[state])  # one layout from the parameter to the result
+    assert "tpu_custom_call" not in hlo and not decode_walk_calls(hlo, "paged_decode_walk")
+
+
+def test_the_state_space_span_s_decode_walk_is_the_composed_one_and_says_why(tmp_path):
+    """What the batcher is told its decode rows' attention runs over one kv
+    head of 128 under 20 query heads, on a backend that says it is a TPU."""
+    from perf.config import load as load_config
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.from_pretrained import get_block_config
+
+    hf = load_config(Path(__file__).resolve().parents[1] / f"perf/configs/{JAMBA}.json", JAMBA)["config"]
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    family, cfg = get_block_config(str(tmp_path))
+    S = jax.ShapeDtypeStruct
+    runs = tuple({name: S((length, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind, BF16).items()}
+                 for kind, _, length in span_runs(family.span_kinds(cfg, 0, 28)))
+    backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=28, memory_cache=None)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pfa, "_on_tpu", lambda: True)
+        assert [(layers, path) for _, layers, _, _, path in backend.decode_walks(8, 40, 64)] == [(2, "composed")]
+        why = pfa.walk_kernel_unsupported(S((320, 64, *backend.pool_row), BF16), (8, 1, 20, 128), (8, 40))
+    assert backend.pool_row == (128,) and "stored folded (320, 64, 128)" in why
+    assert pfa.paged_kernel_unsupported(1, 128, "none") is None  # a prompt's chunk takes the paged prefill kernel

@@ -20,6 +20,7 @@ from tests.utils import (
     make_tiny_falcon,
     make_tiny_gemma,
     make_tiny_gemma2,
+    make_tiny_jamba,
     make_tiny_llama,
     make_tiny_mistral,
     make_tiny_mixtral,
@@ -41,6 +42,7 @@ MAKERS = {
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
     "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3, "qwen3_next": make_tiny_qwen3_next,
+    "jamba": make_tiny_jamba,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 

@@ -307,7 +307,7 @@ def _tiny_families() -> dict:
         "falcon-rw": lambda tmp: utils.make_tiny_falcon(tmp, variant="rw"), "mixtral": utils.make_tiny_mixtral, "olmoe": utils.make_tiny_olmoe,
         "exaone_moe": utils.make_tiny_exaone_moe, "olmo_hybrid": utils.make_tiny_olmo_hybrid, "KeyeVL2": utils.make_tiny_keye_vl2,
         "KeyeVL2-table-of-one-page": utils.make_tiny_keye_vl2,  # 16 positions, as many as a row chooses: the plain call
-        "deepseek_v3": utils.make_tiny_deepseek_v3, "qwen3_next": utils.make_tiny_qwen3_next,
+        "deepseek_v3": utils.make_tiny_deepseek_v3, "qwen3_next": utils.make_tiny_qwen3_next, "jamba": utils.make_tiny_jamba,
     }
 
 

@@ -973,3 +973,73 @@ def make_tiny_qwen3_next(tmpdir: str, *, held: int = 16, first: int = 0) -> str:
         json.dump(config, f)
     save_file(tiny_qwen3_next_tensors(config), os.path.join(path, "model.safetensors"))
     return path
+
+
+TINY_JAMBA = {  # the keys AI21-Jamba2-3B publishes, at a toy size: mamba and attention layers in turns, one kv head
+    "model_type": "jamba", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 1, "intermediate_size": 128,
+    "num_hidden_layers": 4, "attn_layer_period": 2, "attn_layer_offset": 1, "expert_layer_period": 2, "expert_layer_offset": 1,
+    "num_experts": 1, "num_experts_per_tok": 1, "hidden_act": "silu", "mamba_d_state": 16, "mamba_d_conv": 4, "mamba_expand": 2,
+    "mamba_dt_rank": 8, "mamba_conv_bias": True, "mamba_proj_bias": False, "use_mamba_kernels": False, "sliding_window": None,
+    "rms_norm_eps": 1e-6, "max_position_embeddings": 256, "tie_word_embeddings": True, "vocab_size": 128, "num_logits_to_keep": 1,
+}
+
+
+def jamba_layer_types(config: dict) -> list:
+    period, offset = config["attn_layer_period"], config["attn_layer_offset"]
+    return ["attention" if i % period == offset else "mamba" for i in range(config["num_hidden_layers"])]
+
+
+def tiny_jamba_tensors(config: dict, seed: int = 31) -> dict:
+    """Seeded float32 tensors under transformers' names of every layer of
+    ``config``, the embedding and the final norm (the head is tied). Norm
+    vectors, ``D``, ``A_log`` and both biases are drawn, not their
+    initial values, so a missing, misplaced or turned one shows; the step's
+    bias spreads the decays over about 0.2-0.999 a position."""
+    rng = np.random.RandomState(seed)
+    h, hq, hkv, m = (config[k] for k in ("hidden_size", "num_attention_heads", "num_key_value_heads", "intermediate_size"))
+    d, inner = h // hq, config["mamba_expand"] * h
+    n, taps, rank = (config[k] for k in ("mamba_d_state", "mamba_d_conv", "mamba_dt_rank"))
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda size: rng.uniform(0.5, 1.5, size).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.final_layernorm.weight": norm(h)}
+    for i, kind in enumerate(jamba_layer_types(config)):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "input_layernorm.weight": norm(h), p + "pre_ff_layernorm.weight": norm(h),
+            p + "feed_forward.gate_proj.weight": normal(m, h), p + "feed_forward.up_proj.weight": normal(m, h),
+            p + "feed_forward.down_proj.weight": normal(h, m),
+        })
+        if kind == "attention":
+            q = p + "self_attn."
+            tensors.update({q + "q_proj.weight": normal(hq * d, h), q + "k_proj.weight": normal(hkv * d, h),
+                            q + "v_proj.weight": normal(hkv * d, h), q + "o_proj.weight": normal(h, hq * d)})
+            continue
+        q = p + "mamba."
+        dt = np.exp(rng.uniform(np.log(0.001), np.log(0.1), inner))
+        tensors.update({
+            q + "in_proj.weight": normal(2 * inner, h), q + "x_proj.weight": normal(rank + 2 * n, inner),
+            q + "dt_proj.weight": normal(inner, rank), q + "dt_proj.bias": (dt + np.log(-np.expm1(-dt))).astype(np.float32),
+            q + "conv1d.weight": (rng.standard_normal((inner, 1, taps)) * 0.4).astype(np.float32), q + "conv1d.bias": normal(inner),
+            q + "A_log": np.log(rng.uniform(1, 16, (inner, n))).astype(np.float32), q + "D": norm(inner),
+            q + "out_proj.weight": normal(h, inner), q + "dt_layernorm.weight": norm(rank),
+            q + "b_layernorm.weight": norm(n), q + "c_layernorm.weight": norm(n),
+        })
+    return tensors
+
+
+@_model_build_cache
+def make_tiny_jamba(tmpdir: str, **overrides) -> str:
+    """A Jamba checkpoint at a toy size, written by hand under transformers'
+    names (tests/test_jamba.py loads the same tensors into transformers' own
+    decoder layers of both kinds)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = {**TINY_JAMBA, **overrides}
+    path = os.path.join(tmpdir, "tiny-jamba" + "".join(f"-{k}-{v}" for k, v in sorted(overrides.items())))
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_jamba_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
