@@ -1,8 +1,9 @@
 """A decode row's attention over paged keys and values on the real chip: the
 composed walk in blocks of table slots (ops/paged_flash_attention.py
 ``composed_paged_attend``) at every block width, the walk as ONE kernel that
-reads each lane's own pages where they lie (``path="kernel"``, PR 45) at 1, 2,
-4 and 8 pages a block, and the path both replaced (gather every slot of every
+reads each lane's own pages where they lie (``path="kernel"``, PR 45; a folded
+row of fewer than 4 kv heads since PR 53) at 1 to 32 pages a block (up to 2 MB
+a pool), and the path both replaced (gather every slot of every
 lane, ``attend_reference`` over the dense view), one layer's call at the
 cells' pool geometries and at lengths their traffic gives the lanes.
 
@@ -38,6 +39,9 @@ SHAPES = {
     "k-exaone-236b": (8, 16, 64, 8, 128, 8, (90, 130, 170, 210, 250, 290, 330, 370)),
     "olmo-hybrid-7b-full": (8, 40, 64, 32, 128, 1, (2559,) * 8),  # every lane at the table's end
     "olmo-hybrid-7b-one": (8, 40, 64, 32, 128, 1, (2300,) + (0,) * 7),  # one live lane, seven on the idle sentinel (a length of 0)
+    "qwen3-next-80b": (8, 40, 64, 2, 256, 8, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k; a folded row of 512: pages of 64 KB
+    "qwen3-next-80b-four": (8, 40, 64, 2, 256, 8, (1150, 0, 1500, 0, 1850, 0, 2300, 0)),  # its usual step: four of eight lanes live
+    "jamba2-3b": (8, 40, 64, 1, 128, 20, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k; a folded row of 128: pages of 16 KB
 }
 LINKS = (2, 10)
 FEED = 2.0 ** -10
@@ -130,9 +134,9 @@ def main(names, stages=("dense", "walk", "kernel")) -> None:
             rows.append((f"walk{block}", err, timed(walk, *args)))
             if len(row) == 1 and block == 1:  # what the fold costs the walk: the same walk over pools of [hkv, d] rows
                 rows.append(("walk1-rows-of-hkv-d", None, timed(walk, *unfolded)))
-        # the kernel, where it takes the pool as it is stored, at 1 to 8 pages of one lane a block
+        # the kernel, where it takes the pool as it is stored, at 1 to 32 pages of one lane a block (up to 2 MB a pool)
         takes = pfa.walk_kernel_unsupported(args[1], q.shape, tables.shape) is None
-        for block in (1, 2, 4, 8) if takes else ():
+        for block in [b for b in (1, 2, 4, 8, 16, 32) if b * page_size * hkv * d * 2 <= 2 << 20 and b <= max_pages] if takes else ():
             if not wanted(f"kernel{block}") or (not on_chip and block > 1):
                 continue
             pfa.WALK_KERNEL_BLOCK_BYTES = block * page_size * hkv * d * 2

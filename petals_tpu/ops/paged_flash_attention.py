@@ -5,15 +5,17 @@ identity block table, so the dense-shaped steps take the same code.
 A DECODE row (per-lane positions) always takes ``composed_paged_attend``. One
 query row a lane walks its lane's pages in blocks of table slots with a
 running softmax, and ``decode_walk_path`` picks the walk from the call's own
-shapes and dtypes: on a TPU, over a plain pool of rows of ``[hkv, d]`` of
-whole tiles, ONE Pallas kernel a layer (``_walk_kernel``): the span's pools
-stay in HBM as the layer loop carries them, each live lane's pages of ALL kv
-heads are copied block by block into one of two buffers under the block
-before, and a block is met as one matrix ``[rows * hkv, d]`` with the off-head
-columns masked, so nothing is relaid and no layer is sliced out; everywhere
-else (off the chip, a folded or quantised pool, half a tile of kv heads,
-ALiBi, a soft cap, a traced window) a ``fori_loop`` in plain ``jax.numpy``
-(``_walk_decode_rows``), which is also the kernel's reference. A verify's
+shapes and dtypes: on a TPU, over a plain pool of whole tiles, ONE Pallas
+kernel a layer (``_walk_kernel``): the span's pools stay in HBM as the layer
+loop carries them, each live lane's pages of ALL kv heads are copied block by
+block into one of two buffers under the block before, and a block is met as
+one matrix as it lies (rows of ``[hkv, d]``: ``[rows * hkv, d]`` with the
+off-head columns masked; a folded row of fewer than 4 kv heads: ``[rows, hkv *
+d]``, the heads its column blocks), so nothing is relaid and no layer is
+sliced out; everywhere else (off the chip, a quantised pool, half a tile of kv
+heads, a folded row of heads under 128 lanes, ALiBi, a soft cap, a traced
+window) a ``fori_loop`` in plain ``jax.numpy`` (``_walk_decode_rows``), which
+is also the kernel's reference. A verify's
 rows and a non-causal call gather their pages and run ``attend_reference``.
 
 A prompt's CHUNK (a scalar position, one lane's table) takes the fused
@@ -684,7 +686,10 @@ def _walk_decode_rows(
 # 0.040 (0.080, 0.018). A step's fixed cost (~0.35 us: the scalars' reads, the
 # accumulators' trip through scratch) is paid a block, live or skipped, and a
 # lane's last block is copied and multiplied whole: half a megabyte is the best
-# or within 0.005 ms of it at each.
+# or within 0.005 ms of it at each. Folded rows (PR 53, one call of the same script; pages a block in brackets): 8 lanes
+# of 1,150-2,300 over 2 kv heads of 256 (pages of 64 KB) 0.131 (1) | 0.051 (4) | 0.042 (8: half a megabyte) | 0.049 (16)
+# | 0.061 (32) (0.171, 0.035), four of them live 0.018 at 8 and 0.017 at 16 (0.173, 0.017); over one kv head of 128
+# (pages of 16 KB) 0.121 (1) | 0.021 (8) | 0.015 (16) | 0.013 (32: half a megabyte) (0.611 a page a trip, 0.009).
 WALK_KERNEL_BLOCK_BYTES = 512 << 10
 WALK_KERNEL_VMEM_SLACK_BYTES = 16 << 20  # a block's scores and weights, the accumulators, the kernel's own temporaries
 # The lanes' tables ride into the kernel as prefetched scalars, ``n_lanes * slots`` int32 in scalar memory, of which the
@@ -696,6 +701,11 @@ WALK_KERNEL_TABLE_BYTES = 512 << 10
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret() -> bool:
+    # off the chip the walk's kernel runs in the Pallas interpreter; a compile-only test patches this (and ``_on_tpu``)
+    return jax.default_backend() != "tpu"
 
 
 def _sublanes(dtype) -> int:
@@ -718,29 +728,50 @@ def walk_kernel_block_pages(width: int, page_size: int, hkv: int, d: int, itemsi
 def walk_kernel_unsupported(k_pool, q_shape, tables_shape, *, alibi: bool = False, softcap: bool = False, window=None) -> Optional[str]:
     """Why the decode walk's kernel cannot take this call, or None: a static
     predicate on the pool's stored form and the call's shape. The kernel copies
-    whole pages ``[page_size, hkv, d]`` out of the pool as it is stored and
-    meets them as ``[page_size * hkv, d]``, so the pool is a plain array of
-    rows of ``[hkv, d]`` with ``d`` whole lanes and ``hkv`` (and a page's rows)
-    whole sublane tiles of its dtype; it knows the walk's masks and nothing
-    else; and the tables, as the walk is handed them (cut to a window's
-    reach), have to fit the scalar memory they are prefetched into. ``k_pool``
-    is the pool or anything with its ``shape`` and ``dtype``."""
+    whole pages out of the pool as it is stored and meets a block of them as
+    ONE matrix of whole tiles, so it takes a plain pool in either form the
+    storage rule gives it (ops/paged_attention.py ``stored_row``): rows of
+    ``[hkv, d]``, ``d`` whole lanes and ``hkv`` whole sublane tiles of the
+    dtype, met as ``[page_size * hkv, d]``; or folded rows of ``hkv * d``
+    (fewer than 4 kv heads), ``d`` whole lanes, met as ``[page_size, hkv *
+    d]`` with a row's heads as column blocks. A page's rows are whole sublane
+    tiles either way. Still refused, and left to the composed walk: a folded
+    row of heads under 128 lanes (a head_dim of 64: two heads share a tile's
+    lanes), a quantised pool (the gather dequantises its codes; the
+    kernel has no scales), pages of another dtype, rows of ``[hkv, d]`` of
+    half a tile of kv heads (8 of bfloat16: the compiled step would copy the
+    pool whole in front of the kernel), ALiBi, a soft cap and a traced window
+    (the kernel knows the walk's own masks and nothing else), several query
+    rows a lane, and tables wider than the scalar memory they are prefetched
+    into (as the walk is handed them, cut to a window's reach). ``k_pool`` is
+    the pool or anything with its ``shape`` and ``dtype``."""
     from petals_tpu.ops.paged_attention import PagedPool
 
     if isinstance(k_pool, PagedPool):
         return "a quantised pool: its codes are dequantised by the gather"
-    if len(k_pool.shape) != 4:
-        return f"a pool stored folded {tuple(k_pool.shape)}: its rows are not [kv heads, head_dim]"
-    _, page_size, hkv, d = k_pool.shape
     if jnp.dtype(k_pool.dtype) not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
         return f"pages of {jnp.dtype(k_pool.dtype).name}"
-    if d % LANES or q_shape[-1] != d:
-        return f"a head_dim of {d} is no multiple of {LANES}"
+    page_size, d = k_pool.shape[1], q_shape[-1]
     tile = _sublanes(k_pool.dtype)
-    if hkv % tile or page_size % tile:
-        # 8 kv heads of bfloat16 are half a tile: the pool then lives on the device in a layout of its own and the
-        # compiled step COPIES it whole in front of the kernel, every layer (tests/test_kernels_lower_tpu.py)
-        return f"{hkv} kv heads or a page's {page_size} rows are no multiple of the {tile} sublanes of {jnp.dtype(k_pool.dtype).name}"
+    if len(k_pool.shape) == 3:  # stored folded: a row's heads are column blocks of the block's one matrix
+        width = k_pool.shape[2]
+        hkv = width // d
+        if d % LANES or width % d:
+            # heads under 128 lanes share a tile's lanes, and the last step keeps a query head's own block of columns in
+            # whole lanes. The one configuration that stores such a row (Falcon-40B) gains nothing by a step that takes
+            # them apart: its layers are ONE loop over pools that fit the chip's fast memory, and compiled for the v5e
+            # that loop stages both pools whole through it around the call, every layer (PERF.md section 7)
+            return f"a folded row of {width} holds heads of {d}, no whole blocks of {LANES} lanes"
+    else:
+        _, _, hkv, width = k_pool.shape
+        if d % LANES or width != d:
+            return f"a head_dim of {width} is no multiple of {LANES}"
+        if hkv % tile:
+            # 8 kv heads of bfloat16 are half a tile: the pool then lives on the device in a layout of its own and the
+            # compiled step COPIES it whole in front of the kernel, every layer (tests/test_kernels_lower_tpu.py)
+            return f"{hkv} kv heads are no multiple of the {tile} sublanes of {jnp.dtype(k_pool.dtype).name}"
+    if page_size % tile:
+        return f"a page's {page_size} rows are no multiple of the {tile} sublanes of {jnp.dtype(k_pool.dtype).name}"
     if q_shape[1] != 1 or q_shape[2] % hkv:
         return f"{q_shape[1]} query rows a lane of {q_shape[2]} heads"
     if alibi or softcap:
@@ -754,10 +785,17 @@ def walk_kernel_unsupported(k_pool, q_shape, tables_shape, *, alibi: bool = Fals
 
 def decode_walk_path(k_pool, q_shape, tables_shape, *, alibi: bool = False, softcap: bool = False, window=None) -> str:
     """``"kernel"`` on a TPU backend for a call the kernel takes
-    (``walk_kernel_unsupported``), ``"composed"`` everywhere else: what a
-    decode row's walk in ``composed_paged_attend`` runs and what the batcher's
-    counters count (server/backend.py ``decode_walks``) follow from this
-    alone."""
+    (``walk_kernel_unsupported``: a plain bfloat16 or float32 pool of whole
+    tiles, rows of ``[hkv, d]`` or a folded row of fewer than 4 kv heads of
+    whole lanes, one query row a lane under the walk's own masks),
+    ``"composed"`` everywhere else: off the chip, and for what the kernel still
+    refuses, each for a reason of its own: a quantised pool (no scales in the
+    kernel), 8 kv heads of bfloat16 (half a tile: the compiled step would copy
+    the pool), a folded row of heads under 128 lanes (the last step keeps
+    whole lanes), ALiBi, a soft cap, a traced window, tables over the scalar
+    memory. What a decode row's walk in ``composed_paged_attend`` runs and what
+    the batcher's counters count (server/backend.py ``decode_walks``) follow
+    from this alone."""
     unsupported = walk_kernel_unsupported(k_pool, q_shape, tables_shape, alibi=alibi, softcap=softcap, window=window)
     return "kernel" if _on_tpu() and unsupported is None else "composed"
 
@@ -765,7 +803,7 @@ def decode_walk_path(k_pool, q_shape, tables_shape, *, alibi: bool = False, soft
 def _walk_kernel(tables_ref, starts_ref, ends_ref, q_ref, cols_ref, row_head_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, state, m_ref, l_ref, acc_ref, *,
                  pages: int, scale: float, dot_in_f32: bool):
     lane, block = pl.program_id(0), pl.program_id(1)
-    n_lanes, page_size, hkv = ends_ref.shape[0], k_hbm.shape[1], k_hbm.shape[2]
+    n_lanes, page_size = ends_ref.shape[0], k_hbm.shape[1]
     slots = tables_ref.shape[0] // n_lanes
     rows = pages * page_size
     start, end = starts_ref[lane], ends_ref[lane]  # the lane's row sees the positions [start, end) of its table
@@ -833,11 +871,14 @@ def _walk_kernel(tables_ref, starts_ref, ends_ref, q_ref, cols_ref, row_head_ref
         # relayout), every query head against every kv head's columns, and a column that is another kv head's is masked
         # like a position out of sight: its weight is exactly zero, so the same matrix of values takes the weights as
         # they are. The matrix unit has the room: at 32 kv heads the dots are hidden under the copies (PERF.md section 5).
-        col_pos = block * rows + cols_ref[0:1, :]  # [1, rows * hkv]: a column's position, and its kv head
+        # A FOLDED page lies as [page_size, hkv * d] and the block is the matrix [rows, hkv * d] as it is: a column is a
+        # position, the query rows come with zeros in the other kv heads' column blocks (the wrapper's), so a score sums
+        # its own head's products alone, and the weights meet all heads' values, of which the last step keeps a row's own.
+        col_pos = block * rows + cols_ref[0:1, :]  # [1, columns]: a column's position, and its kv head
         col_head = jnp.where((col_pos >= start) & (col_pos < end), cols_ref[1:2, :], -1)
-        mask = col_head == row_head_ref[...]  # [hq, rows * hkv]: a query head's own kv head, in sight
-        k = k_buf[slot].reshape(rows * hkv, k_buf.shape[-1])
-        v = v_buf[slot].reshape(rows * hkv, v_buf.shape[-1])
+        mask = col_head == row_head_ref[...]  # [hq, columns]: a query head's own kv head, in sight
+        k = k_buf[slot].reshape(-1, k_buf.shape[-1])
+        v = v_buf[slot].reshape(-1, v_buf.shape[-1])
         s = jnp.where(mask, dot(q_ref[...], k, ((1,), (1,))) * scale, NEG_INF)
         m = m_ref[:, :1]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
@@ -849,7 +890,14 @@ def _walk_kernel(tables_ref, starts_ref, ends_ref, q_ref, cols_ref, row_head_ref
 
     @pl.when(block == pl.num_programs(1) - 1)
     def _():
-        o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(o_ref.dtype)
+        out = acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+        hq, d = o_ref.shape
+        if out.shape[1] != d:  # a folded row's heads side by side: a query head keeps its own kv head's block of columns
+            group = hq // (out.shape[1] // d)
+            column, row = (jax.lax.broadcasted_iota(jnp.int32, out.shape, axis) for axis in (1, 0))
+            out = jnp.where(column // d == row // group, out, 0.0)
+            out = functools.reduce(jnp.add, [out[:, at:at + d] for at in range(0, out.shape[1], d)])  # the others are zero now
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 # A jit of its own: a server warms ten step programs at every start, and the compile cache keeps executables, no traces:
@@ -859,15 +907,19 @@ def _walk_decode_rows_kernel(q, k_pool, v_pool, tables, q_pos, kv_len, *, scale:
                              interpret: bool):
     """``_walk_decode_rows`` as ONE Pallas call: grid (lane, block of ``pages``
     table slots), both pools left in HBM as they are handed over (the span's,
-    as the layer loop carries them) and the tables and each lane's reach
-    prefetched as scalars. A live block's pages, all kv heads of them, are
+    as the layer loop carries them, rows of ``[hkv, d]`` or folded) and the
+    tables and each lane's reach prefetched as scalars. A live block's pages, all kv heads of them, are
     copied into one of two VMEM buffers a pool by the block before it, under
     that block's dots; ``m``, ``l`` and ``acc`` ride in scratch across a
     lane's blocks. Each live lane is read to its OWN last block. The
     arithmetic is the walk's: products of the pool's dtype summed in float32,
     max / sum / output in float32, the weights cast to V's dtype."""
+    from petals_tpu.ops.paged_attention import pool_geometry
+
     n_lanes, _, hq, d = q.shape
-    _, page_size, hkv, _ = k_pool.shape
+    _, page_size, hkv, _ = pool_geometry(k_pool, d)
+    row = k_pool.shape[2:]
+    folded = len(row) == 1  # [n_pages, page_size, hkv * d]: a block's matrix is [rows, hkv * d], a column a position
     width = tables.shape[1]
     rows = pages * page_size
     # the positions a lane's row sees, [start, end): its own length, the causal mask and the window's reach in two numbers
@@ -879,15 +931,22 @@ def _walk_decode_rows_kernel(q, k_pool, v_pool, tables, q_pos, kv_len, *, scale:
     own = jnp.take_along_axis(tables, jnp.clip(starts // page_size, 0, width - 1)[:, None], axis=1)
     tables = jnp.pad(tables, ((0, 0), (0, -width % pages)), constant_values=-1)
     tables = jnp.where(tables < 0, jnp.maximum(own, 0), tables)
-    column = np.arange(rows * hkv, dtype=np.int32)  # constants of the program: nothing to compute a layer
-    cols = np.stack([column // hkv, column % hkv])  # a column's position in the block, and its kv head
-    row_head = (np.arange(hq, dtype=np.int32) // (hq // hkv))[:, None]  # a query head's kv head
+    grid = (n_lanes, tables.shape[1] // pages)
+    tables = tables.reshape(-1)
+    apart = 1 if folded else hkv  # kv heads that are columns of their own in a block's matrix
+    column = np.arange(rows * apart, dtype=np.int32)  # constants of the program: nothing to compute a layer
+    cols = np.stack([column // apart, column % apart])  # a column's position in the block, and its kv head
+    row_head = (np.arange(hq, dtype=np.int32) // (hq // apart))[:, None]  # a query head's kv head
+    q_rows = q[:, 0].astype(jnp.promote_types(q.dtype, k_pool.dtype))
+    if folded and hkv > 1:  # a query head's row across the folded row: its own kv head's columns, zeros in the others'
+        its_own = (np.arange(hq) // (hq // hkv))[:, None] == np.arange(hkv)[None, :]
+        q_rows = jnp.where(its_own[None, :, :, None], q_rows[:, :, None, :], 0).reshape(n_lanes, hq, hkv * d)
     itemsize = jnp.dtype(k_pool.dtype).itemsize
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n_lanes, tables.shape[1] // pages),
+        grid=grid,
         in_specs=[
-            pl.BlockSpec((None, hq, d), lambda lane, block, *_: (lane, 0, 0)),
+            pl.BlockSpec((None, hq, q_rows.shape[-1]), lambda lane, block, *_: (lane, 0, 0)),
             pl.BlockSpec(cols.shape, lambda lane, block, *_: (0, 0)),
             pl.BlockSpec(row_head.shape, lambda lane, block, *_: (0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -895,13 +954,13 @@ def _walk_decode_rows_kernel(q, k_pool, v_pool, tables, q_pos, kv_len, *, scale:
         ],
         out_specs=pl.BlockSpec((None, hq, d), lambda lane, block, *_: (lane, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, rows, hkv, d), k_pool.dtype),
-            pltpu.VMEM((2, rows, hkv, d), v_pool.dtype),
+            pltpu.VMEM((2, rows, *row), k_pool.dtype),
+            pltpu.VMEM((2, rows, *row), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((hq, LANES), jnp.float32),
             pltpu.VMEM((hq, LANES), jnp.float32),
-            pltpu.VMEM((hq, d), jnp.float32),
+            pltpu.VMEM((hq, row[-1]), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -914,7 +973,7 @@ def _walk_decode_rows_kernel(q, k_pool, v_pool, tables, q_pos, kv_len, *, scale:
         ),
         interpret=interpret,
         name="paged_decode_walk",
-    )(tables.reshape(-1), starts, ends, q[:, 0].astype(jnp.promote_types(q.dtype, k_pool.dtype)), cols, row_head, k_pool, v_pool)
+    )(tables, starts, ends, q_rows, cols, row_head, k_pool, v_pool)
     return out[:, None]
 
 
@@ -927,8 +986,8 @@ def composed_paged_attend(
     row (per-lane positions, one query row a lane, causal) walks its lane's
     pages in blocks of slots, as they are stored: what it reads follows the
     lanes' lengths, not the table's width, and agrees with the dense program
-    to float32 rounding, not to the bit. On a TPU, over a plain pool of rows of ``[hkv, d]`` of whole
-    tiles, the walk is ONE kernel that reads each live lane's own pages where
+    to float32 rounding, not to the bit. On a TPU, over a plain pool of whole
+    tiles (``walk_kernel_unsupported``), the walk is ONE kernel that reads each live lane's own pages where
     they lie, to that lane's own end (``_walk_decode_rows_kernel``); everywhere
     else, and as the kernel's reference, a ``fori_loop`` over blocks of every
     lane up to the longest live lane's last one (``_walk_decode_rows``).
@@ -971,7 +1030,7 @@ def composed_paged_attend(
             with jax.named_scope("ptu.attn.paged_decode"):
                 return _walk_decode_rows_kernel(
                     q, k_pool, v_pool, tables, q_pos, kv_len, scale=float(q.shape[-1] ** -0.5 if scale is None else scale),
-                    sliding_window=sliding_window, pages=pages, interpret=not _on_tpu(),
+                    sliding_window=sliding_window, pages=pages, interpret=_interpret(),
                 )
         return _walk_decode_rows(
             q, k_pool, v_pool, tables, q_pos=q_pos, kv_len=kv_len,

@@ -288,8 +288,8 @@ def test_the_published_span_s_pools_and_what_a_lane_costs():
     """jamba2-3b-span28 on shapes alone: the whole model in five runs of
     kinds, pages 2 layers deep (one kv head of 128: 512 B a position), states
     26 (328 KB of state and 30 KB of conv tail a lane a layer), 2.86 B
-    parameters; the decode walk over one kv head says why it is the composed
-    one."""
+    parameters; the decode walk's kernel takes the one kv head's folded row
+    (on a TPU backend: off the chip the composed walk runs)."""
     import tempfile
     from pathlib import Path
 
@@ -316,9 +316,11 @@ def test_the_published_span_s_pools_and_what_a_lane_costs():
     state, tail = backend.state_cache_descriptors(8)
     assert (state.shape, tail.shape) == ((26, 8, 16, 5120), (26, 8, 3, 5120)) and jnp.dtype(tail.dtype) == jnp.bfloat16
     assert backend.kv_bytes_per_token() == 2 * 512 and backend.state_bytes_per_lane() == 26 * (327_680 + 30_720)
-    why = pfa.walk_kernel_unsupported(S(k_pool.shape[1:], k_pool.dtype), (8, 1, 20, 128), (8, 40))
-    assert "stored folded" in why
-    assert [(layers, path) for _, layers, _, _, path in backend.decode_walks(8, 40, 64)] == [(2, "composed")]
+    assert pfa.walk_kernel_unsupported(S(k_pool.shape[1:], k_pool.dtype), (8, 1, 20, 128), (8, 40)) is None
+    assert [(layers, path) for _, layers, _, _, path in backend.decode_walks(8, 40, 64)] == [(2, "composed")]  # this backend is no TPU
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pfa, "_on_tpu", lambda: True)
+        assert [(layers, block, path) for _, layers, block, _, path in backend.decode_walks(8, 40, 64)] == [(2, 32, "kernel")]
 
 
 def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_matches_the_reference(tiny):

@@ -94,15 +94,17 @@ PAGED_IDS = ["bf16-mha", "bf16-gqa", "int8-gqa", "nf4a-mha", "bf16-d64", "bf16-2
 def test_paged_decode_row_lowers(v5e, hq, hkv, d, kv_quant):
     """A decode row's attention (``composed_paged_attend``) compiles for the
     v5e at each class, on the walk ``decode_walk_path`` names for it: the
-    kernel over a plain pool of whole tiles, the composed walk over half a
-    tile of kv heads, a quantised pool and a folded one."""
+    kernel over a plain pool of whole tiles, rows of ``[hkv, d]`` or a folded
+    row of heads of whole lanes, the composed walk over half a tile of kv
+    heads, a quantised pool and a folded row of heads of 64."""
     lanes, max_pages, page_size = 8, 16, 64
     pool = _pool(v5e, lanes * max_pages, page_size, hkv, d, kv_quant)
     q = v5e((lanes, 1, hq, d), BF16)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pfa, "_on_tpu", lambda: True)  # the backend here is the CPU
+        patch.setattr(pfa, "_interpret", lambda: False)
         path = pfa.decode_walk_path(pool, q.shape, (lanes, max_pages))
-        assert path == ("kernel" if (hkv, d, kv_quant) == (32, 128, "none") else "composed")
+        assert path == ("composed" if kv_quant != "none" or (hkv, d) in ((8, 128), (8, 64)) else "kernel")
         hlo = jax.jit(
             lambda q, k, v, t, p: pfa.composed_paged_attend(q, k, v, t, q_offset=p, kv_length=p + 1)
         ).lower(q, pool, pool, v5e((lanes, max_pages), I32), v5e((lanes,), I32)).compile().as_text()
@@ -344,6 +346,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
         patch.setattr("petals_tpu.ops.latent_attention._on_tpu", lambda: latent_kernel)  # as would the latent decode walk's kernel
         patch.setattr(pfa, "_on_tpu", lambda: walk_kernel)  # and the plain pages' decode walk's
+        patch.setattr(pfa, "_interpret", lambda: False)
         patch.setattr("petals_tpu.ops.linear_attention._on_tpu", lambda: state_kernel)  # and the one-step rule's over the state pool
         patch.setattr("petals_tpu.ops.linear_attention._interpret", lambda: False)
         hlo = jax.jit(step, donate_argnums=donated).lower(*avals).compile().as_text()
@@ -958,62 +961,75 @@ def test_latent_mixed_step_expands_a_block_of_positions_at_a_time_and_holds_no_w
 
 # ---------------------------------------------------------------- a decode row's walk over plain pages, as one kernel
 
-# hq, hkv, table slots a lane as the walk is handed them, window, dtype. The first two are the pools that configurations
-# store and the kernel takes (Olmo-Hybrid's, OLMoE's); the next two its query groups and its window at a shape it takes;
-# the float32 ones are NO configuration's (Mixtral's and K-EXAONE's 8 kv heads of 128 are stored in bfloat16, half a
-# tile, and refused: the next test): a float32 pool is what tests and a float32 server store; the last is the widest
-# table the predicate lets through (``WALK_KERNEL_TABLE_BYTES``: 8 lanes of a million positions)
+# hq, (hkv, d), table slots a lane as the walk is handed them, window, dtype. The first two are the pools of rows of
+# [hkv, 128] that configurations store and the kernel takes (Olmo-Hybrid's, OLMoE's); the next two its query groups and its
+# window at a shape it takes; the float32 ones are NO configuration's (Mixtral's and K-EXAONE's 8 kv heads of 128 are stored
+# in bfloat16, half a tile, and refused: the next tests): a float32 pool is what tests and a float32 server store; then the
+# widest table the predicate lets through (``WALK_KERNEL_TABLE_BYTES``: 8 lanes of a million positions); the last five are
+# FOLDED rows of fewer than 4 kv heads (``stored_row``): Qwen3-Next's 2 of 256 and Jamba's one of 128 under 20 query heads,
+# as their configurations store them, then a window, float32 and three heads at such a row
 WALK_KERNEL_SHAPES = [
-    pytest.param(32, 32, 40, None, BF16, id="olmo-hybrid-32x128-over-40"),
-    pytest.param(16, 16, 16, None, BF16, id="olmoe-16x128-over-16"),
-    pytest.param(64, 16, 16, None, BF16, id="16x128-4-query-heads-a-kv-head"),
-    pytest.param(64, 16, 16, 128, BF16, id="16x128-window-128"),
-    pytest.param(32, 8, 16, None, F32, id="float32-8x128-4-query-heads-a-kv-head"),
-    pytest.param(64, 8, 16, 128, F32, id="float32-8x128-8-query-heads-a-kv-head-window-128"),
-    pytest.param(16, 16, pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8), None, BF16, id="16x128-tables-at-the-scalar-memory-budget"),
+    pytest.param(32, (32, 128), 40, None, BF16, id="olmo-hybrid-32x128-over-40"),
+    pytest.param(16, (16, 128), 16, None, BF16, id="olmoe-16x128-over-16"),
+    pytest.param(64, (16, 128), 16, None, BF16, id="16x128-4-query-heads-a-kv-head"),
+    pytest.param(64, (16, 128), 16, 128, BF16, id="16x128-window-128"),
+    pytest.param(32, (8, 128), 16, None, F32, id="float32-8x128-4-query-heads-a-kv-head"),
+    pytest.param(64, (8, 128), 16, 128, F32, id="float32-8x128-8-query-heads-a-kv-head-window-128"),
+    pytest.param(16, (16, 128), pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8), None, BF16, id="16x128-tables-at-the-scalar-memory-budget"),
+    pytest.param(16, (2, 256), 40, None, BF16, id="qwen3-next-folded-2x256-over-40"),
+    pytest.param(20, (1, 128), 40, None, BF16, id="jamba-folded-1x128-20-query-heads-over-40"),
+    pytest.param(16, (2, 256), 40, 128, BF16, id="folded-2x256-window-128"),
+    pytest.param(8, (2, 128), 16, None, F32, id="float32-folded-2x128"),
+    pytest.param(12, (3, 128), 16, None, BF16, id="folded-3x128-4-query-heads-a-kv-head"),
 ]
 
 
-@pytest.mark.parametrize("hq,hkv,slots,window,dtype", WALK_KERNEL_SHAPES)
-def test_paged_decode_walk_kernel_lowers_at_the_shapes_it_takes(v5e, hq, hkv, slots, window, dtype):
+@pytest.mark.parametrize("hq,heads,slots,window,dtype", WALK_KERNEL_SHAPES)
+def test_paged_decode_walk_kernel_lowers_at_the_shapes_it_takes(v5e, hq, heads, slots, window, dtype):
     """The walk's kernel alone (ops/paged_flash_attention.py ``_walk_kernel``),
     through Pallas -> Mosaic -> libtpu for the v5e: 8 lanes, pages of 64, the
-    span's pools of 5 x 8 x ``slots`` pages of ``[64, hkv, 128]`` handed whole
-    (at most 1,600 pages: the widest table's pool would not fit the chip);
-    under the window the table is cut to the 3 slots in reach first. One slot
-    a lane over the widest table is the predicate's to refuse: its budget is
-    the largest that was seen to compile."""
+    span's pools of 5 x 8 x ``slots`` pages handed whole in the form the
+    storage rule keeps their row in (``[64, hkv, 128]``, or folded ``[64, hkv
+    * d]``; at most 1,600 pages: the widest table's pool would not fit the
+    chip); under the window the table is cut to the 3 slots in reach first.
+    One slot a lane over the widest table is the predicate's to refuse: its
+    budget is the largest that was seen to compile."""
     def walk(q, k_pool, v_pool, tables, positions):
         return pfa.composed_paged_attend(q, k_pool, v_pool, tables, q_offset=positions, kv_length=positions + 1, sliding_window=window, path="kernel")
 
-    pool = v5e((5 * 8 * min(slots, 40), 64, hkv, 128), dtype)
-    avals = (v5e((8, 1, hq, 128), dtype), pool, pool, v5e((8, slots), I32), v5e((8,), I32))
+    pool = v5e((5 * 8 * min(slots, 40), 64, *stored_row(*heads)), dtype)
+    assert (len(pool.shape) == 3) == (heads[0] < 4)
+    avals = (v5e((8, 1, hq, heads[1]), dtype), pool, pool, v5e((8, slots), I32), v5e((8,), I32))
     reach = pfa.window_pages(window, 1, 64, slots)
     assert pfa.walk_kernel_unsupported(pool, avals[0].shape, (8, reach), window=window) is None
     assert "scalar memory" in pfa.walk_kernel_unsupported(pool, avals[0].shape, (8, pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8) + 1), window=window)
     with pytest.MonkeyPatch.context() as patch:
-        patch.setattr(pfa, "_on_tpu", lambda: True)  # the backend here is the CPU: the kernel would be interpreted
+        patch.setattr(pfa, "_interpret", lambda: False)  # the backend here is the CPU: the kernel would be interpreted
         _compile(walk, *avals)
         body = str(jax.make_jaxpr(walk)(*avals))
     # the copies are a loop and the waits one a pool (ops/latent_attention.py, PR 43: written out they were seconds of every start)
     assert body.count("dma_start") == 4 and body.count("dma_wait") == 2, (body.count("dma_start"), body.count("dma_wait"))
 
 
-@pytest.mark.parametrize("config_name,pages_a_lane,calls_in", [("olmo-hybrid-7b-span16", 40, "ENTRY"), ("olmoe-1b-7b-span8", 16, "loop")])
+@pytest.mark.parametrize("config_name,pages_a_lane,calls_in", [
+    ("olmo-hybrid-7b-span16", 40, "ENTRY"), ("olmoe-1b-7b-span8", 16, "loop"), ("qwen3-next-80b-a3b-span8-ep4", 40, "ENTRY"), ("jamba2-3b-span28", 40, "ENTRY"),
+])
 def test_decode_step_walks_each_lane_s_pages_in_one_kernel_a_layer(v5e, tmp_path, config_name, pages_a_lane, calls_in):
-    """A decode row's attention over plain pages of head_dim 128 is ONE kernel
-    a layer (ops/paged_flash_attention.py ``composed_paged_attend``): the
-    compiled decode step holds one ``tpu_custom_call`` under
-    ``ptu.attn.paged_decode`` a run of full layers (OLMoE's eight are one loop;
-    Olmo-Hybrid's four runs of one block are unrolled into ``ENTRY``), handed
-    the two whole-span pools as the loop carries them and the new rows'
-    scatter leaves them; there is no gathered block of a page a lane (``[8,
-    64, hkv, 128]``: the composed walk made two a trip and float32 products of
-    each) and no copy of a pool. The composed step, compiled beside it, shows
-    the blocks: the guard can fail."""
+    """A decode row's attention over plain pages of a head_dim of whole lanes
+    is ONE kernel a layer (ops/paged_flash_attention.py
+    ``composed_paged_attend``): the compiled decode step holds one
+    ``tpu_custom_call`` under ``ptu.attn.paged_decode`` a run of full layers
+    (OLMoE's eight are one loop; Olmo-Hybrid's four runs of one block, and
+    Qwen3-Next's and Jamba's two, are unrolled into ``ENTRY``), handed the two
+    whole-span pools as the loop carries them and the new rows' scatter leaves
+    them, in the form they are stored in (Qwen3-Next's two kv heads of 256 and
+    Jamba's one of 128: folded rows); there is no gathered block of a page a
+    lane (``[8, 64, hkv, d]`` or ``[8, 64, hkv * d]``: the composed walk made
+    two a trip and float32 products of each) and no copy of a pool. The
+    composed step, compiled beside it, shows the blocks: the guard can fail."""
     hlo, runs, pool, (hkv, d) = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane)
     calls = decode_walk_calls(hlo, "paged_decode_walk")
-    assert len(calls) == (4 if calls_in == "ENTRY" else 1), calls
+    assert len(calls) == ({"olmo-hybrid-7b-span16": 4, "olmoe-1b-7b-span8": 1}.get(config_name, 2)), calls
     entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
     for computation, op_name, operands in calls:
         assert "ptu.attn.paged_decode" in op_name and (computation == entry) == (calls_in == "ENTRY"), (computation, op_name)
@@ -1023,18 +1039,21 @@ def test_decode_step_walks_each_lane_s_pages_in_one_kernel_a_layer(v5e, tmp_path
             assert op in ("get-tuple-element", "parameter") or (fused is not None and any(i[2] in ("dynamic-update-slice", "scatter") for i in fused)), (op, dims)
 
     def blocks(text):  # a page a lane of all kv heads in memory, in any dtype
-        return [f"%{name} = {op}" for _, name, dims, op, _ in _arrays_in_memory(_computations(text)) if dims == (8, 64, hkv, d)]
+        return [f"%{name} = {op}" for _, name, dims, op, _ in _arrays_in_memory(_computations(text)) if dims in ((8, 64, hkv, d), (8, 64, hkv * d))]
 
     moves, _ = pool_moves(hlo, tuple(pool.shape), (hkv, d))
     assert not blocks(hlo) and not moves, (blocks(hlo), moves)
     composed, _, _, _ = _compiled_step(v5e, tmp_path, config_name, 0, pages_a_lane, walk_kernel=False)
-    assert not decode_walk_calls(composed, "paged_decode_walk") and blocks(composed)
+    # (over one kv head the composed walk keeps no block of [8, 64, 128] in memory: nothing for the guard to show there)
+    assert not decode_walk_calls(composed, "paged_decode_walk") and (blocks(composed) or config_name == JAMBA)
 
 
 def test_a_folded_pool_s_decode_step_is_the_composed_walk_s_whatever_the_backend(v5e, tmp_path):
-    """Falcon's pool (head_dim 64, stored folded) is not the kernel's: the
-    decode step compiled where the kernel may run is the step compiled where it
-    may not, instruction for instruction."""
+    """Falcon's pool (8 kv heads of 64 folded: two heads share a tile's lanes)
+    is not the kernel's: the decode step compiled where the kernel may run is
+    the step compiled where it may not, instruction for instruction. (Handed
+    to the kernel, the five layers' loop staged both pools, 42 MB each, whole
+    through the chip's fast memory around the call: PERF.md section 7.)"""
     def program(walk_kernel):  # every computation's instructions, without the source lines they were traced from
         hlo, _, _, _ = _compiled_step(v5e, tmp_path, "falcon-40b-span5", 0, walk_kernel=walk_kernel)
         return {name: [(i[0], i[1], i[2], re.sub(r", metadata=\{[^}]*\}", "", i[3])) for i in instructions] for name, instructions in _computations(hlo).items()}
@@ -1062,8 +1081,11 @@ def test_a_span_with_a_state_and_experts_leaves_its_pools_states_and_stacks_in_p
     they lie."""
     hlo, runs, pool, heads = _compiled_step(v5e, tmp_path, Q3N, chunk, pages_a_lane=40)
     assert heads == (2, 256) and tuple(pool.shape) == (2, 320, 64, 512)
-    moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
-    assert loops_seen, "no loop carries the pool: has the HLO text changed, or the pool left the carry?"
+    moves, _ = pool_moves(hlo, tuple(pool.shape), heads)
+    # no loop carries the page pools (the composed walk's did): the two full layers are runs of one block in ``ENTRY``, and
+    # each one's decode rows are a call of the walk's kernel on both pools as they lie
+    walks = decode_walk_calls(hlo, "paged_decode_walk")
+    assert [sum(math.prod(dims) == math.prod(pool.shape) for _, _, dims in operands) for _, _, operands in walks] == [2, 2], walks
     assert not moves, f"the step moves the page pool: {moves}"
     comps = _computations(hlo)
     entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
@@ -1181,7 +1203,8 @@ def test_step_takes_its_lanes_packed_and_still_aliases_what_it_was_donated(v5e, 
     assert len(donated) == (4 if config_name == Q3N else 2), sorted(numbered)
     assert aliased == {numbered[name][0] for name in donated}, (aliased, {name: numbered[name][0] for name in donated})
     moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
-    assert loops_seen and not moves, moves
+    # Qwen3-Next's page pools ride no loop since the walk is a kernel: its two full layers are runs of one block, in ``ENTRY``
+    assert (loops_seen or config_name == Q3N) and not moves, moves
 
 
 # ---------------------------------------------------------------- a state-space model's state pool, 26 layers deep (PR 52)
@@ -1214,9 +1237,9 @@ def test_a_state_space_span_leaves_its_state_pool_and_its_one_head_pages_in_plac
     second-minor and take twice their bytes (8 lanes in a tile of 16 rows of
     bfloat16), and the mixed step, which takes one lane's out, copies them in
     ``ENTRY``, never in a loop: 0.3 MB a layer, beside 6.9 GB of weights. The
-    decode rows' attention is the composed walk (``decode_walks`` counts it;
-    the walk's kernel names the folded pool as its reason), so no step holds
-    a custom call."""
+    decode rows' attention is the walk's kernel over the folded row of one kv
+    head, one call an attention layer in either step, and a step's only
+    custom calls."""
     from petals_tpu.server.from_pretrained import get_block_config
 
     hlo, runs, pool, heads = _compiled_step(v5e, tmp_path, JAMBA, chunk, pages_a_lane=40)
@@ -1240,12 +1263,13 @@ def test_a_state_space_span_leaves_its_state_pool_and_its_one_head_pages_in_plac
     assert _layout_bytes(state, handed[state][0], 4) == math.prod(state) * 4 == 26 * 8 * 327_680
     assert _layout_bytes(tail, handed[tail][0], 2) == 2 * math.prod(tail) * 2
     assert set(re.findall(r"f32\[26,8,16,5120\](\{[^}]*\})", hlo)) == set(handed[state])  # one layout from the parameter to the result
-    assert "tpu_custom_call" not in hlo and not decode_walk_calls(hlo, "paged_decode_walk")
+    assert hlo.count('custom_call_target="tpu_custom_call"') == len(decode_walk_calls(hlo, "paged_decode_walk")) == 2
 
 
-def test_the_state_space_span_s_decode_walk_is_the_composed_one_and_says_why(tmp_path):
+def test_the_state_space_span_s_decode_walk_is_the_kernel_s_over_its_folded_row(tmp_path):
     """What the batcher is told its decode rows' attention runs over one kv
-    head of 128 under 20 query heads, on a backend that says it is a TPU."""
+    head of 128 under 20 query heads, on a backend that says it is a TPU: the
+    kernel, 32 pages of 16 KB a block."""
     from perf.config import load as load_config
     from petals_tpu.server.backend import TransformerBackend
     from petals_tpu.server.from_pretrained import get_block_config
@@ -1259,7 +1283,8 @@ def test_the_state_space_span_s_decode_walk_is_the_composed_one_and_says_why(tmp
     backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=28, memory_cache=None)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pfa, "_on_tpu", lambda: True)
-        assert [(layers, path) for _, layers, _, _, path in backend.decode_walks(8, 40, 64)] == [(2, "composed")]
+        assert [(layers, block, path) for _, layers, block, _, path in backend.decode_walks(8, 40, 64)] == [(2, 32, "kernel")]
         why = pfa.walk_kernel_unsupported(S((320, 64, *backend.pool_row), BF16), (8, 1, 20, 128), (8, 40))
-    assert backend.pool_row == (128,) and "stored folded (320, 64, 128)" in why
+    assert backend.pool_row == (128,) and why is None
+    assert [path for *_, path in backend.decode_walks(8, 40, 64)] == ["composed"]  # this backend is no TPU
     assert pfa.paged_kernel_unsupported(1, 128, "none") is None  # a prompt's chunk takes the paged prefill kernel

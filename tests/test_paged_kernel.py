@@ -15,9 +15,11 @@ from petals_tpu.ops import paged_flash_attention as pfa
 from petals_tpu.ops.attention import attend, attend_reference
 from petals_tpu.ops.paged_attention import (
     PagedKV,
+    fold_rows,
     gather_pages,
     identity_tables,
     paged_prefill_attend,
+    stored_row,
 )
 from petals_tpu.ops.paged_flash_attention import paged_flash_prefill_attend
 
@@ -159,8 +161,9 @@ def test_walk_block_follows_the_shapes_it_sees():
 
 # ------------------------------------------------- the walk as one kernel
 
-# n_lanes 4, pages of 16 rows, head_dim 128; a lane's position, or None for an idle lane. ``block``: table slots of one
-# lane a grid step takes (the cases set the bytes the rule goes by)
+# n_lanes 4, pages of 16 rows, 16 kv heads of 128 unless a case says another ``hkv`` / ``d``; a lane's position, or None for
+# an idle lane. ``block``: table slots of one lane a grid step takes (the cases set the bytes the rule goes by). A
+# ``folded-`` case hands the kernel the pool as the storage rule keeps a row of fewer than 4 kv heads (``stored_row``: ``[.., hkv * d]``)
 KERNEL_WALK_CASES = {
     "lanes-shorter-than-a-block": dict(max_pages=8, block=4, positions=[5, 20, 40, 0]),
     "ending-on-a-block-s-and-on-a-page-s-last-position": dict(max_pages=8, block=2, positions=[31, 15, 63, 47]),
@@ -176,6 +179,19 @@ KERNEL_WALK_CASES = {
     "identity-tables-at-0-a-page-s-last-and-a-page-s-first": dict(max_pages=8, block=2, identity=True, positions=[0, 15, 32, 53]),
     "garbage-pages-past-a-lane-s-end": dict(max_pages=8, block=4, garbage=True, positions=[19, 40, 5, None]),
     "2-query-heads-a-kv-head": dict(max_pages=8, block=2, group=2, positions=[16, 23, 100, None]),
+    "folded-2-kv-heads-of-256-ragged-lanes": dict(max_pages=8, block=2, hkv=2, d=256, group=8, positions=[100, 3, None, 77]),
+    "folded-2-kv-heads-of-128": dict(max_pages=8, block=4, hkv=2, d=128, group=4, positions=[127, 64, 1, None]),
+    "folded-1-kv-head-of-128-under-20-query-heads": dict(max_pages=8, block=4, hkv=1, d=128, group=20, positions=[19, 40, 5, 90]),
+    "folded-1-kv-head-of-256": dict(max_pages=8, block=2, hkv=1, d=256, group=2, positions=[50, None, 9, 100]),
+    "folded-3-kv-heads-of-128-float32": dict(max_pages=8, block=2, hkv=3, d=128, group=2, dtype="float32", positions=[31, 15, 63, 47]),
+    "folded-2-kv-heads-of-256-3-query-heads-a-kv-head": dict(max_pages=8, block=2, hkv=2, d=256, group=3, positions=[100, 3, None, 77]),
+    "folded-a-fresh-lane-at-position-0": dict(max_pages=8, block=4, hkv=2, d=256, group=8, positions=[0, 20, None, 0]),
+    "folded-no-live-lane": dict(max_pages=8, block=2, hkv=2, d=256, group=8, positions=[None, None, None, None]),
+    "folded-blocks-that-do-not-divide-the-table": dict(max_pages=10, block=4, hkv=2, d=256, group=8, positions=[159, 31, 32, None]),
+    "folded-window128-table-cut-to-its-reach": dict(max_pages=16, block=2, hkv=2, d=256, group=8, window=128, positions=[255, 10, 130, None]),
+    "folded-window128-whole-table": dict(max_pages=9, block=1, hkv=1, d=128, group=20, window=128, positions=[143, 130, 20, None]),
+    "folded-garbage-pages-past-a-lane-s-end": dict(max_pages=8, block=4, hkv=2, d=256, group=8, garbage=True, positions=[19, 40, 5, None]),
+    "folded-identity-tables": dict(max_pages=8, block=2, hkv=1, d=128, group=4, identity=True, positions=[0, 15, 32, 53]),
 }
 
 
@@ -197,15 +213,18 @@ def _numpy_decode_rows(q, kp, vp, tables, positions, idle, window, ps):
     return out
 
 
-@pytest.mark.parametrize("case", KERNEL_WALK_CASES.values(), ids=KERNEL_WALK_CASES.keys())
-def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer(case, monkeypatch):
+@pytest.mark.parametrize("name,case", KERNEL_WALK_CASES.items(), ids=KERNEL_WALK_CASES.keys())
+def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer(name, case, monkeypatch):
     """``composed_paged_attend(path="kernel")`` (interpreted off the chip) for
     a decode row against float32 NumPy over permuted tables: every page nobody
     owns, page 0 among them, and every slot past a lane's own last page's
     block, holds NaN, so a block read past a lane's end, or a hole read from a
     page of somebody else's, shows (a weight of zero times NaN is NaN). The
-    composed walk, handed the same call with NaN out of its reach, agrees."""
-    n_lanes, ps, d = 4, 16, 128
+    composed walk, handed the same call with NaN out of its reach, agrees.
+    Both are handed the pool as the storage rule keeps its row: a row of fewer
+    than 4 kv heads folded, its heads the column blocks of the kernel's
+    matrix."""
+    n_lanes, ps, d = 4, 16, case.get("d", 128)
     max_pages, block, group, hkv = case["max_pages"], case["block"], case.get("group", 1), case.get("hkv", 16)
     dtype, window = jnp.dtype(case.get("dtype", "bfloat16")), case.get("window")
     rng = np.random.default_rng(17)
@@ -220,10 +239,14 @@ def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer
     tables = np.where(np.arange(max_pages)[None, :] < held[:, None], owned, -1).astype(np.int32)
     nobody_s = np.setdiff1d(np.arange(n_pages), tables[tables >= 0])
     kp, vp = kp.at[nobody_s].set(jnp.nan), vp.at[nobody_s].set(jnp.nan)
+    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hkv * group, d)), dtype)
+    want = _numpy_decode_rows(q, kp, vp, tables, pos, idle, window, ps)
+    row = stored_row(hkv, d)
+    assert (len(row) == 1) == name.startswith("folded-"), row
+    kp, vp = fold_rows(kp, row), fold_rows(vp, row)
     past = tables.copy()  # every slot past the block of a lane's own last page points at a page of NaN
     for lane in range(n_lanes):
         past[lane, -(-held[lane] // block) * block:] = nobody_s[-1]
-    q = jnp.asarray(rng.standard_normal((n_lanes, 1, hkv * group, d)), dtype)
     kw = dict(q_offset=jnp.asarray(pos), kv_length=jnp.asarray(pos) + 1, sliding_window=window)
 
     monkeypatch.setattr(pfa, "WALK_KERNEL_BLOCK_BYTES", block * ps * hkv * d * dtype.itemsize)
@@ -232,7 +255,6 @@ def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer
     assert pfa.walk_kernel_unsupported(kp, q.shape, (n_lanes, width), window=window) is None
     cut = width < max_pages  # each lane's row is then cut to its own reach first: the slots past it are never handed over
     got = np.asarray(pfa.composed_paged_attend(q, kp, vp, jnp.asarray(tables if cut else past), path="kernel", **kw), np.float32)
-    want = _numpy_decode_rows(q, kp, vp, tables, pos, idle, window, ps)
     assert np.isfinite(got).all(), "the kernel read a block past a lane's own end, or a page nobody owns"
     tol = TOL if dtype == jnp.float32 else 2e-2  # bfloat16: the weights meet V in V's dtype, the answer is rounded to it
     np.testing.assert_allclose(got[~idle], want[~idle], atol=tol, rtol=0)
@@ -255,7 +277,16 @@ WALK_PATH_CASES = {
     "plain-rows-of-hkv-d": (dict(), None),
     "float32": (dict(pool=_pool_like((9, 64, 8, 128), jnp.float32)), None),
     "static-window": (dict(window=128), None),
-    "folded-pool": (dict(pool=_pool_like((9, 64, 8 * 64)), q=(8, 1, 128, 64)), "folded"),
+    "folded-8-kv-heads-of-64": (dict(pool=_pool_like((9, 64, 8 * 64)), q=(8, 1, 128, 64)), "heads of 64"),  # Falcon's: two heads share 128 lanes
+    "folded-2-kv-heads-of-256": (dict(pool=_pool_like((9, 64, 2 * 256)), q=(8, 1, 16, 256)), None),  # Qwen3-Next's
+    "folded-1-kv-head-of-128-under-20-query-heads": (dict(pool=_pool_like((9, 64, 128)), q=(8, 1, 20, 128)), None),  # Jamba's
+    "folded-float32-static-window": (dict(pool=_pool_like((9, 64, 2 * 128), jnp.float32), q=(8, 1, 8, 128), window=128), None),
+    "folded-1-kv-head-of-64": (dict(pool=_pool_like((9, 64, 64)), q=(8, 1, 71, 64)), "folded row of 64"),  # half the lanes
+    "folded-3-kv-heads-of-128": (dict(pool=_pool_like((9, 64, 3 * 128)), q=(8, 1, 12, 128)), None),
+    "folded-a-query-of-another-head-dim": (dict(pool=_pool_like((9, 64, 2 * 256)), q=(8, 1, 16, 384)), "folded row of 512"),
+    "folded-pages-of-8-rows": (dict(pool=_pool_like((9, 8, 2 * 256)), q=(8, 1, 16, 256)), "sublanes"),
+    "folded-alibi": (dict(pool=_pool_like((9, 64, 2 * 128)), q=(8, 1, 8, 128), alibi=True), "ALiBi"),
+    "folded-query-heads-that-do-not-divide": (dict(pool=_pool_like((9, 64, 2 * 256)), q=(8, 1, 15, 256)), "query rows"),
     "quantised-pool": (dict(quantised=True), "quantised"),
     "head-dim-64-unfolded": (dict(pool=_pool_like((9, 64, 16, 64)), q=(8, 1, 16, 64)), "head_dim"),
     "float16": (dict(pool=_pool_like((9, 64, 16, 128), jnp.float16)), "float16"),
@@ -274,8 +305,8 @@ WALK_PATH_CASES = {
 def test_decode_walk_path_follows_what_the_call_shows(case, monkeypatch):
     """Which walk a decode row takes is a static function of the pool's stored
     form and the call (``decode_walk_path``): the kernel on a TPU backend for a
-    plain pool of rows of ``[hkv, d]`` of whole tiles under the walk's own
-    masks, the composed walk for everything else and everywhere off the
+    plain pool of whole tiles, rows of ``[hkv, d]`` or folded, under the walk's
+    own masks, the composed walk for everything else and everywhere off the
     chip."""
     import jax
 

@@ -394,6 +394,47 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_match
     run(main())
 
 
+def test_the_full_layers_decode_rows_walk_their_pages_in_the_kernel_and_the_counters_say_so(tmp_path, monkeypatch):
+    """Two kv heads of whole lanes (128 here, 256 published) are stored as one
+    folded row, and on a backend that says it is a TPU a decode row's attention
+    over them is the walk's kernel (ops/paged_flash_attention.py
+    ``_walk_kernel``, interpreted here) inside the decode steps and the mixed
+    steps: two sessions of other lengths decode side by side after their
+    prompts, every reply against the reference's whole forward pass, and every
+    table slot a decode step's two full layers read is counted as the
+    kernel's (``attn_pages_kernel`` of ``attn_pages_gathered``)."""
+    from petals_tpu.ops import paged_flash_attention as pfa
+
+    hf = {**HF, "head_dim": 128}
+    path, tensors = make_tiny_qwen3_next(str(tmp_path), head_dim=128), tiny_qwen3_next_tensors(hf)
+    monkeypatch.setattr(pfa, "_on_tpu", lambda: True)  # ``_interpret`` still sees the CPU
+
+    async def main():
+        server, client = await start_server(path, batch_lanes=3, batch_max_length=64, page_size=16)
+        try:
+            batcher = server.handler.batcher
+            assert server.backend.pool_row == (2 * 128,) and batcher.occupancy_info()["decode_walk"] == ["kernel"]
+            b_rows, c_rows = rows(2, 45), rows(3, 45)
+            b, c = await open_session(client, path, 64), await open_session(client, path, 64)
+            got_b, got_c = [await step(b, b_rows[:, :30])], [await step(c, c_rows[:, :3])]  # 2 pages and 1
+            before = dict(batcher.stats)
+            for i in range(15):  # B crosses into its third page, C stays in its first two
+                outs = await asyncio.gather(step(b, b_rows[:, 30 + i : 31 + i]), step(c, c_rows[:, 3 + i : 4 + i]))
+                got_b.append(outs[0]), got_c.append(outs[1])
+            walked = batcher.stats["attn_pages_gathered"] - before["attn_pages_gathered"]
+            assert walked == batcher.stats["attn_pages_kernel"] - before["attn_pages_kernel"] > 0
+            for got, data in ((got_b, b_rows), (got_c, c_rows)):
+                got = np.concatenate(got, axis=1)[0]
+                assert off(got, reference_hidden(tensors, data[0, : got.shape[0]], hf=hf)) < CLOSE
+            for stream in (b, c):
+                await stream.end()
+        finally:
+            await client.close()
+            await server.shutdown()
+
+    run(main())
+
+
 def test_the_one_step_rule_s_path_follows_from_the_pool_and_the_call_and_gives_its_reason(tiny, monkeypatch):
     """What the backend tells the batcher its lanes' steps take, and why the
     span's other calls keep the plain form: a chunk, a call whose state is not

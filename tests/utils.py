@@ -954,20 +954,21 @@ def tiny_qwen3_next_tensors(config: dict, seed: int = 23) -> dict:
 
 
 @_model_build_cache
-def make_tiny_qwen3_next(tmpdir: str, *, held: int = 16, first: int = 0) -> str:
+def make_tiny_qwen3_next(tmpdir: str, *, held: int = 16, first: int = 0, head_dim: int = TINY_QWEN3_NEXT["head_dim"]) -> str:
     """A Qwen3-Next checkpoint at a toy size, written by hand under
     transformers' names (tests/test_qwen3_next.py loads the same tensors into
     transformers' own ``Qwen3NextDecoderLayer``): every routed expert is in the
     file, and a server of this directory holds ``held`` of the 16 from
-    ``first`` on (``expert_share``; all of them by default)."""
+    ``first`` on (``expert_share``; all of them by default). ``head_dim``: the
+    full layers' (128 is a head of whole lanes, as the published 256 is)."""
     import json
 
     from safetensors.numpy import save_file
 
-    config = dict(TINY_QWEN3_NEXT)
+    config = dict(TINY_QWEN3_NEXT, head_dim=head_dim)
     if held != 16 or first:
         config.update(num_experts=held, expert_share={"routed": 16, "first": first})
-    path = os.path.join(tmpdir, f"tiny-qwen3-next-{held}-{first}")
+    path = os.path.join(tmpdir, f"tiny-qwen3-next-{held}-{first}-{head_dim}")
     os.makedirs(path, exist_ok=True)
     with open(os.path.join(path, "config.json"), "w") as f:
         json.dump(config, f)
