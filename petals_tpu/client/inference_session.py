@@ -26,7 +26,8 @@ from petals_tpu.client.routing.sequence_manager import RemoteSequenceManager
 from petals_tpu.data_structures import CHAIN_DELIMITER, RemoteSpanInfo
 from petals_tpu.rpc.client import RpcClient, StreamCall
 from petals_tpu.rpc.serialization import CompressionType, deserialize_array, serialize_array
-from petals_tpu.telemetry.spans import MAX_RETIRED_HOPS, HopTrace, build_trace_report
+from petals_tpu.telemetry.spans import MAX_RETIRED_HOPS, ClientTrip, HopTrace, build_trace_report
+from petals_tpu.utils.asyncio_utils import turn_clock_of
 from petals_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -76,6 +77,10 @@ class _ServerInferenceSession:
         # owning InferenceSession: every reply carrying a fused fingerprint
         # is verified against the hidden state actually received
         self.monitor = None
+        # the last step's readings of time.perf_counter, for the owning
+        # session's ClientTrip (telemetry/spans.py): stream.send returned
+        # (K2), the reply's frame read whole (K3), stream.recv returned (K4)
+        self.stations: Optional[tuple] = None
 
     @classmethod
     async def create(
@@ -228,11 +233,11 @@ class _ServerInferenceSession:
             msg["start_from_position"] = int(start_from_position)
         t_rpc = time.perf_counter()
         await self.stream.send(msg)
+        sent_at = time.perf_counter()
         reply = await self.stream.recv(timeout=self.step_timeout)
-        self.hop.record(
-            time.perf_counter() - t_rpc, reply.get("step_meta"),
-            tokens=int(hidden.shape[1]),
-        )
+        held_at = time.perf_counter()
+        self.stations = (sent_at, self.stream.read_at or held_at, held_at)
+        self.hop.record(held_at - t_rpc, reply.get("step_meta"), tokens=int(hidden.shape[1]))
         out = deserialize_array(reply["tensors"]["hidden"])
         self.position = reply["position"]
         meta = reply.get("step_meta") or {}
@@ -354,6 +359,9 @@ class InferenceSession:
         self._steps = 0
         self._tokens = 0
         self._retired_hops: List[HopTrace] = []
+        # the client's own stations of a step (telemetry/spans.py): sums for
+        # trace_report()["client"], one row a step in the process's ring
+        self.trip = ClientTrip(self.trace_id)
         # SLO flight recorder (None unless PETALS_TPU_SLO_*_MS is set; tests
         # and embedders may assign a FlightRecorder directly)
         self.flight = flight_from_env()
@@ -399,6 +407,8 @@ class InferenceSession:
         hypo_ids: Optional[np.ndarray] = None,
     ) -> np.ndarray:
         """Run ``hidden`` through all remote blocks, updating every server's cache."""
+        trip = self.trip
+        trip.on_loop(time.perf_counter())
         assert not self._closed
         if prompts is not None:
             self._last_prompts = prompts
@@ -436,6 +446,7 @@ class InferenceSession:
                     step_id=step_id,
                 )
                 assert outputs.shape == inputs.shape, f"{outputs.shape} != {inputs.shape}"
+                trip.hop(*session.stations)
                 inputs = outputs
                 block_idx = span.end
                 self.seq_manager.on_request_success(span.peer_id)
@@ -465,6 +476,9 @@ class InferenceSession:
             # the cut equals the position, so the adopt never replays)
             await self._maybe_phase_handoff()
         await self._maybe_check_route_upgrade()
+        if trip.steps == 0:  # where a reader of the ring finds this loop's turn clock
+            trip.ring.loop_clock = turn_clock_of(asyncio.get_running_loop())
+        trip.finished(time.perf_counter(), n_input_tokens)
         return inputs
 
     # ------------------------------------------------- critical-path profiler
@@ -522,6 +536,7 @@ class InferenceSession:
             steps=self._steps,
             tokens=self._tokens,
             retired_hops=len(self._retired_hops),
+            client=self.trip.report(),
         )
 
     def usage_report(self) -> dict:
@@ -832,6 +847,7 @@ class InferenceSession:
         history feeds) is the one guaranteed-consistent recovery — and None
         is returned so the caller continues client-side."""
         assert not self._closed
+        self.trip.interrupt()  # no station follows a server-side generation
         n_input = hidden.shape[1]
         if self._position + n_input + n_tokens - 1 > self.max_length:
             return None

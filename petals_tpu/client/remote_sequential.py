@@ -3,6 +3,7 @@
 
 from __future__ import annotations
 
+import time
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -118,7 +119,11 @@ class SyncInferenceSession:
         self._runtime = runtime
 
     def step(self, hidden: np.ndarray, **kwargs) -> np.ndarray:
-        return self._runtime.run(self._session.step(np.asarray(hidden), **kwargs))
+        trip = self._session.trip  # the caller's side of a step's stations (telemetry/spans.py)
+        trip.entered(time.perf_counter())
+        out = self._runtime.run(self._session.step(np.asarray(hidden), **kwargs))
+        trip.woke(time.perf_counter())
+        return out
 
     def generate_remote(self, hidden: np.ndarray, n_tokens: int, embed_fn,
                         sampling=None):
@@ -143,6 +148,10 @@ class SyncInferenceSession:
     @property
     def batch_size(self) -> int:
         return self._session.batch_size
+
+    def trace_report(self) -> dict:
+        """The session's latency waterfall so far (``InferenceSession.trace_report``)."""
+        return self._session.trace_report()
 
     @property
     def integrity(self):

@@ -10,12 +10,18 @@ import asyncio
 import threading
 from typing import Awaitable, TypeVar
 
+from petals_tpu.utils.asyncio_utils import install_turn_clock
+
 T = TypeVar("T")
 
 
 class SwarmRuntime:
     def __init__(self):
         self.loop = asyncio.new_event_loop()
+        # how full this one thread is and how long a ready socket waits for it
+        # (None on a loop without a Python selector); sampled, because a
+        # client's process takes no marks
+        self.turn_clock = install_turn_clock(self.loop, sample=True)
         self._thread = threading.Thread(target=self._run, name="ptu-client-loop", daemon=True)
         self._thread.start()
 

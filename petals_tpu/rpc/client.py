@@ -11,11 +11,12 @@ from __future__ import annotations
 
 import asyncio
 import itertools
+import time
 from typing import Any, AsyncIterator, Optional
 
 from petals_tpu import chaos
 from petals_tpu.data_structures import PeerID
-from petals_tpu.rpc.protocol import read_frame, write_frame
+from petals_tpu.rpc.protocol import decode_frame, read_frame_body, write_frame
 from petals_tpu.rpc.server import RpcError
 from petals_tpu.utils.logging import get_logger
 
@@ -25,7 +26,12 @@ _END = object()
 
 
 class StreamCall:
-    """A bidirectional stream: ``send``/``end`` feed the server, iterate to read."""
+    """A bidirectional stream: ``send``/``end`` feed the server, iterate to read.
+
+    ``read_at`` is this process's ``time.perf_counter`` when the frame of the
+    item last received lay whole in memory, before it was unpacked (what
+    ``rpc/server.py StreamRequests.read_at`` is to a handler); it never
+    crosses the wire."""
 
     def __init__(self, client: "RpcClient", call_id: int, method: Optional[str] = None):
         self._client = client
@@ -33,6 +39,7 @@ class StreamCall:
         self._method = method  # chaos-injection detail for rpc.stream_recv
         self._inbound: asyncio.Queue = asyncio.Queue()
         self._closed = False
+        self.read_at: Optional[float] = None
 
     async def send(self, payload: Any) -> None:
         if self._closed:
@@ -47,13 +54,14 @@ class StreamCall:
         """Next response item; raises StopAsyncIteration at end of stream."""
         if chaos.ENABLED:
             await chaos.inject(chaos.SITE_RPC_STREAM_RECV, detail=self._method)
-        item = await asyncio.wait_for(self._inbound.get(), timeout)
+        item, read_at = await asyncio.wait_for(self._inbound.get(), timeout)
         if item is _END:
             self._closed = True
             raise StopAsyncIteration
         if isinstance(item, Exception):
             self._closed = True
             raise item
+        self.read_at = read_at
         return item
 
     def __aiter__(self) -> AsyncIterator[Any]:
@@ -71,8 +79,8 @@ class StreamCall:
                 pass
         self._client._streams.pop(self._call_id, None)
 
-    def _push(self, item: Any) -> None:
-        self._inbound.put_nowait(item)
+    def _push(self, item: Any, read_at: Optional[float] = None) -> None:
+        self._inbound.put_nowait((item, read_at))
 
 
 class RpcClient:
@@ -229,7 +237,9 @@ class RpcClient:
         error: Exception = RpcError("Connection closed")
         try:
             while True:
-                msg = await read_frame(self._reader)
+                body = await read_frame_body(self._reader)
+                read_at = time.perf_counter()
+                msg = decode_frame(body)
                 kind = msg.get("t")
                 if kind == "hello":
                     await self._on_server_hello(msg)
@@ -252,7 +262,7 @@ class RpcClient:
                 elif kind == "sitem":
                     stream = self._streams.get(msg["id"])
                     if stream is not None:
-                        stream._push(msg.get("payload"))
+                        stream._push(msg.get("payload"), read_at)
                 elif kind == "send":
                     stream = self._streams.pop(msg["id"], None)
                     if stream is not None:

@@ -489,6 +489,13 @@ class DecodeBatcher:
             "reply_wake_s": 0.0, "reply_steps": 0,
             "reply_resume_s": 0.0, "reply_build_s": 0.0, "rpc_send_s": 0.0, "decode_replies": 0,
             "rpc_recv_s": 0.0, "request_handle_s": 0.0, "lane_return_s": 0.0, "lane_returns": 0,
+            # the event loop's turns, added by its turn clock once Server.start
+            # attaches this dict (utils/asyncio_utils.install_turn_clock): the
+            # stretches between one select()'s return and the next one's call,
+            # summed, their squares summed, counted. busy_s over an elapsed time
+            # is how full the loop's thread was, busy_sq / (2 x elapsed) how long
+            # a socket that became ready at a random moment waited to be read
+            "loop_busy_s": 0.0, "loop_busy_sq": 0.0, "loop_turns": 0,
         }
         if getattr(backend, "moe_dims", None) is not None:
             # a family with routed experts only (_count_moe): tokens by the

@@ -28,7 +28,7 @@ from petals_tpu.server.from_pretrained import get_block_config, load_block_param
 from petals_tpu.server.handler import TransformerHandler
 from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.utils.convert_block import QuantType, block_size_bytes, convert_block_params
-from petals_tpu.utils.asyncio_utils import log_exception_callback
+from petals_tpu.utils.asyncio_utils import install_turn_clock, log_exception_callback
 from petals_tpu.utils.dht_utils import declare_active_modules
 from petals_tpu.utils.logging import get_logger
 
@@ -258,6 +258,7 @@ class Server:
         self.draft_window = draft_window
         self.draft_quant_type = draft_quant_type
         self._draft_model = None  # loaded lazily by _make_handler
+        self._turn_clock = None  # the running loop's, from start() on (utils/asyncio_utils.py)
         self.request_timeout = request_timeout
         self.session_timeout = session_timeout
         self.step_timeout = step_timeout
@@ -298,6 +299,7 @@ class Server:
     async def start(self) -> None:
         from petals_tpu.utils.compile_cache import enable_compilation_cache
 
+        self._turn_clock = install_turn_clock(asyncio.get_running_loop())
         cache_dir = enable_compilation_cache()
         devices = jax.local_devices()
         logger.info(
@@ -628,6 +630,7 @@ class Server:
             except Exception as e:
                 logger.warning(f"multihost worker shutdown broadcast failed: {e!r}")
         if self.handler is not None:
+            self._time_loop_turns(self.handler, False)
             self.handler.shutdown()
         # flush + close the journal's JSONL write-through sink AFTER the
         # handler stops emitting: the last scheduler decisions of this run
@@ -890,7 +893,7 @@ class Server:
             lane_bytes += self.backend.state_bytes_per_lane()
             affordable = int(self.memory_cache.max_size_bytes // 2 // max(lane_bytes, 1))
             batch_lanes = max(min(8, affordable), 0)
-        return TransformerHandler(
+        handler = TransformerHandler(
             self.backend,
             dht_prefix=self.dht_prefix,
             memory_cache=self.memory_cache,
@@ -917,6 +920,15 @@ class Server:
             draft_model=self._load_draft_model(),
             spec_k=self.spec_k if self.draft_model_path else None,
         )
+        self._time_loop_turns(handler, True)
+        return handler
+
+    def _time_loop_turns(self, handler: TransformerHandler, serving: bool) -> None:
+        """While a handler serves, the loop's turn clock adds to its
+        ``batcher.stats["loop_busy_s" | "loop_busy_sq" | "loop_turns"]``."""
+        batcher = getattr(handler, "batcher", None)
+        if self._turn_clock is not None and batcher is not None:
+            (self._turn_clock.attach if serving else self._turn_clock.detach)(batcher.stats)
 
     def _load_draft_model(self):
         """Speculative-decoding draft (server/spec_decode.py): a small full
@@ -1321,6 +1333,7 @@ class Server:
         self.handler = self._make_handler()
         self.handler.register(self.rpc_server)  # replaces the old registrations
         if old_handler is not None:
+            self._time_loop_turns(old_handler, False)
             with contextlib.suppress(Exception):
                 old_handler.shutdown()
         self._next_pings = {}

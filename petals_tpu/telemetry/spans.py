@@ -9,7 +9,12 @@ those into one :class:`HopTrace` per server span and
 session's wall-clock to named components:
 
 - ``network``  — client-observed step wall minus the server's reported
-  residency (wire + framing + event-loop handoff on both ends)
+  residency (wire + framing + event-loop handoff on both ends). Since PR 54
+  the report's ``client`` key splits the client's end of it: ``recv_s`` and
+  ``build_s`` are this process's framing and handoff inside a hop's wall,
+  ``away_s`` is the whole wall seen from the frame's last byte read (so
+  ``away_s`` less the hops' ``server_s`` is the wire and both loops'
+  lateness alone)
 - ``queue``    — time the step waited for a lane / page / compute slot
 - ``compute``  — time inside the compiled device step
 - ``serialize``— server-side reply serialization
@@ -20,15 +25,47 @@ The five components are exhaustive by construction, so the report's
 ``attributed_fraction`` is ~1.0 whenever clocks behave; the per-hop,
 per-component shares are the routing/blame signal.
 
+The client's own stations of a step (PR 54, :class:`ClientTrip`): seven
+readings of ``time.perf_counter`` tile a session's time from one request
+written to the next, one reading closing a stretch and opening the next.
+
+====  ==========================================================  ============
+K3    ``RpcClient._read_loop``: the reply's frame read whole       ``away_s``
+K4    ``_ServerInferenceSession.step``: ``stream.recv`` returned   ``recv_s``
+K5    ``InferenceSession.step`` about to return                    ``finish_s``
+K6    ``SyncInferenceSession.step`` holds the result               ``wake_s``
+K0    ``SyncInferenceSession.step`` entered again                  ``user_s``
+K1    ``InferenceSession.step`` running on the loop                ``submit_s``
+K2    ``_ServerInferenceSession.step``: ``stream.send`` returned   ``build_s``
+====  ==========================================================  ============
+
+``away_s`` runs from K2 to K3 (the wire, the server, the wire, this loop's
+lateness on the ready socket); a caller of the async session has K6 = K5 and
+K1 = K0; in a chain, K2 is the first hop's, K3/K4 the last hop's, and what
+lies between one hop's reply read and the next hop's request written goes to
+``relay_s``. The sums are a session's (``trace_report()["client"]``), and
+every step leaves one row in :data:`STEP_RING`, bounded and process-wide, for
+a reader that needs a slice of time (``perf/client_trip.py``). The loops' own
+turn clocks are ``utils/asyncio_utils.install_turn_clock``.
+
 All durations are perf_counter/monotonic deltas — never wall clock
 (swarmlint ``no-naive-wallclock-in-span``).
 """
 
 from __future__ import annotations
 
+from collections import deque
 from typing import List, Optional
 
 COMPONENTS = ("network", "queue", "compute", "serialize", "other")
+
+# a step's stretches in the order a row holds them, after ROW_HEAD
+CLIENT_STRETCHES = ("away_s", "recv_s", "finish_s", "wake_s", "user_s", "submit_s", "build_s", "relay_s")
+ROW_HEAD = ("read_at", "trace_id", "step", "hops", "tokens")  # K3, the session, its step's number, hops, tokens in
+ROW = (*ROW_HEAD, *CLIENT_STRETCHES)
+_WAKE, _USER, _SUBMIT, _BUILD = (ROW.index(name) for name in ("wake_s", "user_s", "submit_s", "build_s"))
+# eight lanes' steps of a benchmark window (51 s of 6 ms gaps); a row is ~0.4 KB
+STEP_RING_ROWS = 65536
 
 # retired (failed-over / migrated-away) hop traces kept per session, so a
 # report after a repair still accounts for time spent on the dead server
@@ -146,6 +183,112 @@ class HopTrace:
         return usage
 
 
+class StepRing:
+    """The newest ``STEP_RING_ROWS`` steps of every session of this process,
+    oldest overwritten first: ``rows`` holds one list a step, laid out as
+    ``ROW``, appended when the step's reply is in the caller's hands and
+    filled in place as the later readings come (``wake_s``, then ``user_s`` /
+    ``submit_s`` / ``build_s`` when the session's next request is written; a
+    session's last step keeps None there). ``loop_clock`` is the turn clock of
+    the loop that ran the newest session's steps, if it has one."""
+
+    def __init__(self, rows: int = STEP_RING_ROWS):
+        self.rows: deque = deque(maxlen=rows)
+        self.loop_clock = None
+
+
+STEP_RING = StepRing()
+
+
+class ClientTrip:
+    """One session's stations of a step (the module docstring's table).
+    ``_t`` is the last reading: every call closes the stretch since it, so
+    the stretches tile the session's time whatever happened in between (a
+    retry's back-off lands in the stretch that was open). One step at a time
+    touches a trip, on the caller's thread (K0, K6) or the loop's."""
+
+    __slots__ = ("trace_id", "ring", "steps", "turns", "sums", "_t", "_row", "_entered", "_user", "_submit",
+                 "_hops", "_away", "_relay", "_held_at")
+
+    def __init__(self, trace_id: Optional[str], ring: Optional[StepRing] = None):
+        self.trace_id = trace_id
+        self.ring = STEP_RING if ring is None else ring
+        self.steps = 0  # replies handed to the caller
+        self.turns = 0  # of them, those a next request followed: what user_s, submit_s and build_s were summed over
+        self.sums = dict.fromkeys(CLIENT_STRETCHES, 0.0)
+        self._t: Optional[float] = None
+        self._row: Optional[list] = None  # the last step's row, until the next request is written
+        self._entered = False
+        self._user = self._submit = 0.0
+        self._hops, self._away, self._relay, self._held_at = 0, 0.0, 0.0, 0.0
+
+    def entered(self, now: float) -> None:
+        """K0, on the caller's thread."""
+        if self._row is not None:
+            self._user, self._t = now - self._t, now
+        self._entered = True
+
+    def on_loop(self, now: float) -> None:
+        """K1. A caller that never passed K0 is on the loop already: K6 = K5, K0 = K1."""
+        row = self._row
+        if row is not None:
+            if self._entered:
+                self._submit = now - self._t
+            else:
+                row[_WAKE], self._user, self._submit = 0.0, now - self._t, 0.0
+        self._t, self._entered = now, False
+        self._hops, self._away, self._relay = 0, 0.0, 0.0
+
+    def hop(self, sent_at: float, read_at: float, held_at: float) -> None:
+        """One hop's K2, K3 and K4, as its ``_ServerInferenceSession`` read them."""
+        if self._hops:
+            self._relay += sent_at - self._t
+        else:
+            row = self._row
+            if row is not None:  # K2 closes the turn after the step before
+                row[_USER], row[_SUBMIT], row[_BUILD] = self._user, self._submit, sent_at - self._t
+                self.sums["user_s"] += row[_USER]
+                self.sums["submit_s"] += row[_SUBMIT]
+                self.sums["build_s"] += row[_BUILD]
+                self.turns += 1
+                self._row = None
+        self._away += read_at - sent_at
+        self._t, self._held_at = read_at, held_at
+        self._hops += 1
+
+    def finished(self, now: float, tokens: int) -> None:
+        """K5: the step's row goes into the ring."""
+        if not self._hops:
+            return
+        recv, finish = self._held_at - self._t, now - self._held_at
+        row = [self._t, self.trace_id, self.steps, self._hops, tokens,
+               self._away, recv, finish, None, None, None, None, self._relay]
+        sums = self.sums
+        sums["away_s"] += self._away
+        sums["recv_s"] += recv
+        sums["finish_s"] += finish
+        sums["relay_s"] += self._relay
+        self.steps += 1
+        self.ring.rows.append(row)
+        self._row, self._t = row, now
+
+    def woke(self, now: float) -> None:
+        """K6, on the caller's thread."""
+        row = self._row
+        if row is not None:
+            row[_WAKE] = now - self._t
+            self.sums["wake_s"] += row[_WAKE]
+            self._t = now
+
+    def interrupt(self) -> None:
+        """The session did something the stations do not follow (a server-side
+        generation): the open turn is dropped and counted nowhere."""
+        self._row, self._entered = None, False
+
+    def report(self) -> dict:
+        return {**{k: round(v, 6) for k, v in self.sums.items()}, "steps": self.steps, "turns": self.turns}
+
+
 def build_trace_report(
     trace_id: Optional[str],
     hops: List[HopTrace],
@@ -154,6 +297,7 @@ def build_trace_report(
     steps: int,
     tokens: int,
     retired_hops: int = 0,
+    client: Optional[dict] = None,
 ) -> dict:
     """Assemble the per-request waterfall: per-hop component splits, swarm
     totals (client-side overhead folded into ``other``), and the single
@@ -185,7 +329,7 @@ def build_trace_report(
                 }
 
     attributed = sum(totals.values())
-    return {
+    report = {
         "trace_id": trace_id,
         "steps": steps,
         "tokens": tokens,
@@ -197,6 +341,9 @@ def build_trace_report(
         "critical_path": critical,
         "attributed_fraction": round(attributed / denom, 4) if wall_s > 0 else 0.0,
     }
+    if client is not None:
+        report["client"] = client  # ClientTrip.report(): the client's own stations, summed
+    return report
 
 
 _BAR_CHARS = {"network": "~", "queue": ".", "compute": "#", "serialize": "=", "other": " "}
@@ -242,15 +389,27 @@ def format_waterfall(report: dict, width: int = 48) -> str:
             + "  ".join(f"{k} {float(totals.get(k, 0.0)):.3f}s" for k in COMPONENTS)
             + f"  (attributed {100.0 * float(report.get('attributed_fraction', 0.0)):.0f}%)"
         )
+    client = report.get("client")
+    if client and client.get("steps"):
+        lines.append(
+            "  client: "
+            + "  ".join(f"{k[:-2]} {float(client.get(k, 0.0)):.3f}s" for k in CLIENT_STRETCHES)
+            + f"  ({client['steps']} steps, {client.get('turns', 0)} followed)"
+        )
     legend = "  legend: " + "  ".join(f"{c}={k}" for k, c in _BAR_CHARS.items() if k != "other")
     lines.append(legend)
     return "\n".join(lines)
 
 
 __all__ = [
+    "CLIENT_STRETCHES",
     "COMPONENTS",
     "MAX_RETIRED_HOPS",
+    "ROW",
+    "STEP_RING",
+    "ClientTrip",
     "HopTrace",
+    "StepRing",
     "build_trace_report",
     "format_waterfall",
 ]

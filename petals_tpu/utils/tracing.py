@@ -45,6 +45,21 @@ opens and closes inside it. The handler's stretch from a request's receipt
 to ``batcher.step`` holds conditional awaits and has a counter only
 (``request_handle_s``). Their counters are in ``batcher.stats``
 (``server/batching.py``).
+
+The other half of that trip is timed where it runs (PR 54), always on and
+with counters only, every reading ``time.perf_counter()``: the client's seven
+stations of a step, K3 (``rpc/client.py _read_loop``: the reply's frame read
+whole, handed on with the item as ``StreamCall.read_at``) to K2
+(``stream.send`` returned for the session's next request), are
+``telemetry/spans.py ClientTrip``: sums a session in
+``trace_report()["client"]`` (``away_s``, ``recv_s``, ``finish_s``,
+``wake_s``, ``user_s``, ``submit_s``, ``build_s``, ``relay_s``) and one row a
+step in the process's bounded ``STEP_RING``. And an event loop's turns, the
+stretches between one ``select()``'s return and the next one's call, are
+``utils/asyncio_utils.install_turn_clock``: ``loop_busy_s``,
+``loop_busy_sq`` and ``loop_turns``, in ``batcher.stats`` for the loop
+``Server.start()`` runs on, in ``SwarmRuntime.turn_clock`` (sampled about
+every 0.1 s) for a client's. No stamp crosses the wire.
 """
 
 from __future__ import annotations

@@ -622,7 +622,9 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
     # since PR 36 every family on the paged pool counts the table slots its steps read (_count_window)
     # ... and since PR 51 every batcher the step bodies that sent the block tables to the device (_step_tables)
-    assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", "tables_sent"} and batcher._n_state == 0 and batcher._state() == ()
+    # ... and since PR 54 the event loop's turns (utils/asyncio_utils.install_turn_clock)
+    assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", "tables_sent",
+                                                 "loop_busy_s", "loop_busy_sq", "loop_turns"} and batcher._n_state == 0 and batcher._state() == ()
     assert not {"state_bytes_per_lane", "state_bytes_held"} & set(batcher.occupancy_info())
     # the step programs take the pair of pools and give the pair back, and carry what they carried
     k, v = (jnp.zeros(d.shape, d.dtype) for d in backend.paged_cache_descriptors(6, 8, 0, 2))
