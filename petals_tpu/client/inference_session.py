@@ -22,9 +22,10 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from petals_tpu import chaos
 from petals_tpu.client.routing.sequence_manager import RemoteSequenceManager
 from petals_tpu.data_structures import CHAIN_DELIMITER, RemoteSpanInfo
-from petals_tpu.rpc.client import RpcClient, StreamCall
+from petals_tpu.rpc.client import THREAD_FRAME_BYTES, RpcClient, StreamCall
 from petals_tpu.rpc.serialization import CompressionType, deserialize_array, serialize_array
 from petals_tpu.telemetry.spans import MAX_RETIRED_HOPS, ClientTrip, HopTrace, build_trace_report
 from petals_tpu.utils.asyncio_utils import turn_clock_of
@@ -206,7 +207,7 @@ class _ServerInferenceSession:
             raise RuntimeError(f"kv_adopt rejected: {reply}")
         self.position = position
 
-    async def step(
+    def build_step(
         self,
         hidden: np.ndarray,
         *,
@@ -214,7 +215,9 @@ class _ServerInferenceSession:
         hypo_ids: Optional[np.ndarray] = None,
         start_from_position: Optional[int] = None,
         step_id: Optional[str] = None,
-    ) -> np.ndarray:
+    ) -> dict:
+        """The first half of what a step means, whichever way it is exchanged:
+        the request that rides the stream."""
         if start_from_position is not None:
             self._rollback_history(start_from_position)
 
@@ -231,12 +234,20 @@ class _ServerInferenceSession:
             msg["tensors"]["hypo_ids"] = serialize_array(np.asarray(hypo_ids, np.int64))
         if start_from_position is not None:
             msg["start_from_position"] = int(start_from_position)
-        t_rpc = time.perf_counter()
-        await self.stream.send(msg)
-        sent_at = time.perf_counter()
-        reply = await self.stream.recv(timeout=self.step_timeout)
-        held_at = time.perf_counter()
-        self.stations = (sent_at, self.stream.read_at or held_at, held_at)
+        return msg
+
+    def accept_reply(
+        self, hidden: np.ndarray, hypo_ids: Optional[np.ndarray], reply: dict,
+        t_rpc: float, sent_at: float, held_at: float,
+    ) -> np.ndarray:
+        """The second half: the reply into the hop's trace, the position, the
+        integrity check and the history. ``t_rpc``, ``sent_at`` and ``held_at``
+        are the stepper's readings before the send, after it (K2) and with the
+        reply in hand (K4)."""
+        read_at = self.stream.read_at or held_at
+        # a thread that hands its frame over may lose the GIL before it reads K2, and the reply
+        # can be read meanwhile: K2 is then K3, so that no stretch is negative and they still tile
+        self.stations = (min(sent_at, read_at), read_at, held_at)
         self.hop.record(held_at - t_rpc, reply.get("step_meta"), tokens=int(hidden.shape[1]))
         out = deserialize_array(reply["tensors"]["hidden"])
         self.position = reply["position"]
@@ -257,6 +268,37 @@ class _ServerInferenceSession:
             )
         self.history.append((np.asarray(hidden), None if hypo_ids is None else np.asarray(hypo_ids)))
         return out
+
+    async def step(
+        self,
+        hidden: np.ndarray,
+        *,
+        prompts: Optional[np.ndarray] = None,
+        hypo_ids: Optional[np.ndarray] = None,
+        start_from_position: Optional[int] = None,
+        step_id: Optional[str] = None,
+    ) -> np.ndarray:
+        """One step exchanged by a coroutine on the loop."""
+        msg = self.build_step(
+            hidden, prompts=prompts, hypo_ids=hypo_ids,
+            start_from_position=start_from_position, step_id=step_id,
+        )
+        t_rpc = time.perf_counter()
+        await self.stream.send(msg)
+        sent_at = time.perf_counter()
+        reply = await self.stream.recv(timeout=self.step_timeout)
+        return self.accept_reply(hidden, hypo_ids, reply, t_rpc, sent_at, time.perf_counter())
+
+    def step_from_thread(self, hidden: np.ndarray, step_id: Optional[str]) -> np.ndarray:
+        """The same step exchanged by the caller's own thread, which is not the
+        loop's: a plain step (no prompts, lane reorder or rollback rides it)
+        whose frame the stream takes whole (``rpc/client.py``)."""
+        msg = self.build_step(hidden, step_id=step_id)
+        t_rpc = time.perf_counter()
+        self.stream.send_from_thread(msg)
+        sent_at = time.perf_counter()
+        reply = self.stream.recv_in_thread(self.step_timeout)
+        return self.accept_reply(hidden, None, reply, t_rpc, sent_at, time.perf_counter())
 
     async def step_generate(
         self, hidden: np.ndarray, n_tokens: int, embed_fn,
@@ -323,6 +365,26 @@ class _ServerInferenceSession:
             await self.stream.cancel()
 
 
+# what a step's message holds beside ``hidden``'s bytes (a step id, a push
+# target, msgpack's framing): room left under THREAD_FRAME_BYTES for it
+_FRAME_SLACK_BYTES = 4096
+
+
+class _Walk:
+    """One step's way through the chain: where it stands and what it carries."""
+
+    __slots__ = ("inputs", "step_id", "t_step0", "prompts", "hypo_ids", "block_idx", "attempt")
+
+    def __init__(self, inputs, step_id, t_step0, prompts=None, hypo_ids=None):
+        self.inputs, self.step_id, self.t_step0 = inputs, step_id, t_step0  # a hop's outputs have its inputs' shape
+        self.prompts, self.hypo_ids = prompts, hypo_ids
+        self.block_idx = self.attempt = 0
+
+    @property
+    def n_input_tokens(self) -> int:
+        return self.inputs.shape[1]
+
+
 class InferenceSession:
     """Whole-model autoregressive session with mid-generation failover."""
 
@@ -336,6 +398,8 @@ class InferenceSession:
         self._max_retries = seq_manager.config.max_retries
         self._last_prompts: Optional[np.ndarray] = None
         self._last_route_check = time.monotonic()
+        # the loop this session's coroutines run on, known from its first step
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
         # prompt-prefix routing affinity: same prompt -> same replicas ->
         # server-side prefix-cache hits (sequence_manager._edge_cost)
         self._affinity_seed: Optional[int] = None
@@ -407,12 +471,17 @@ class InferenceSession:
         hypo_ids: Optional[np.ndarray] = None,
     ) -> np.ndarray:
         """Run ``hidden`` through all remote blocks, updating every server's cache."""
-        trip = self.trip
-        trip.on_loop(time.perf_counter())
-        assert not self._closed
+        self.trip.on_loop(time.perf_counter())
+        self._admit(hidden)
         if prompts is not None:
             self._last_prompts = prompts
+        t_step0 = time.perf_counter()  # route building counts toward TTFT
+        await self._ensure_route(hidden)
+        # the step id dedups client relay vs server push downstream
+        return await self._walk(_Walk(np.asarray(hidden), uuid.uuid4().hex, t_step0, prompts, hypo_ids))
 
+    def _admit(self, hidden: np.ndarray) -> None:
+        assert not self._closed
         n_input_tokens = hidden.shape[1]
         if self._position + n_input_tokens > self.max_length:
             raise ValueError(
@@ -420,66 +489,158 @@ class InferenceSession:
                 f" exceeds pre-allocated maximum {self.max_length}"
             )
 
-        t_step0 = time.perf_counter()  # route building counts toward TTFT
-        await self._ensure_route(hidden)
-
-        attempt = 0
-        block_idx = 0
-        step_id = uuid.uuid4().hex  # dedups client relay vs server push downstream
-        inputs = np.asarray(hidden)
-        while block_idx < self.num_blocks:
-            server_idx = self._find_session_index(block_idx)
+    async def _walk(self, walk: "_Walk", failed: Optional[tuple] = None) -> np.ndarray:
+        """A step's hops from ``walk.block_idx`` on, each exchanged by a
+        coroutine, with the one retry loop a step has: ``step`` enters at block
+        0, ``step_from_thread`` where its own exchange raised, handing
+        ``failed`` (the exception, the hop's session or None) so that the
+        failure is counted, waited out and repaired here as any other."""
+        if self._loop is None:  # where a caller's thread posts what is the loop's to touch
+            self._loop = asyncio.get_running_loop()
+        prompts, hypo_ids = walk.prompts, walk.hypo_ids
+        if failed is not None:
+            await self._hop_failed(walk, *failed)
+        while walk.block_idx < self.num_blocks:
             session = None
             try:
-                if server_idx is None:
-                    raise RuntimeError(f"No active session covers block {block_idx}")
-                session = self._sessions[server_idx]
+                session = self._session_at(walk.block_idx)
                 span = session.span
                 server_prompts = prompts[span.start : span.end] if prompts is not None else None
                 rollback = self._position if session.position > self._position else None
 
                 outputs = await session.step(
-                    inputs,
+                    walk.inputs,
                     prompts=server_prompts,
                     hypo_ids=hypo_ids,
                     start_from_position=rollback,
-                    step_id=step_id,
+                    step_id=walk.step_id,
                 )
-                assert outputs.shape == inputs.shape, f"{outputs.shape} != {inputs.shape}"
-                trip.hop(*session.stations)
-                inputs = outputs
-                block_idx = span.end
-                self.seq_manager.on_request_success(span.peer_id)
-                self._maybe_blame_hop(session)
+                self._hop_done(walk, session, outputs)
             except Exception as e:
-                attempt += 1
-                peer = session.span.peer_id if session is not None else None
-                self.seq_manager.on_request_failure(peer)
-                if self._max_retries is not None and attempt > self._max_retries:
-                    raise
-                delay = min(
-                    self.seq_manager.config.min_backoff * (2 ** (attempt - 1)),
-                    self.seq_manager.config.max_backoff,
-                )
-                logger.warning(
-                    f"Caught exception from block {block_idx} "
-                    f"(peer {peer.to_string()[:8] if peer else '?'}), retrying in {delay:.1f}s: {e}"
-                )
-                await asyncio.sleep(delay)
-                block_idx = await self._repair_chain(block_idx)
+                await self._hop_failed(walk, e, session)
 
-        self._position += n_input_tokens
-        self._account_step(time.perf_counter() - t_step0, n_input_tokens)
+        self._step_done(walk)
         if self._steps == 1 and self._phase == "prefill":
             # prefill done, decode begins: hand the finished KV to a
             # decode-tier replica over the page-push path (step boundary —
             # the cut equals the position, so the adopt never replays)
             await self._maybe_phase_handoff()
         await self._maybe_check_route_upgrade()
+        trip = self.trip
         if trip.steps == 0:  # where a reader of the ring finds this loop's turn clock
-            trip.ring.loop_clock = turn_clock_of(asyncio.get_running_loop())
-        trip.finished(time.perf_counter(), n_input_tokens)
-        return inputs
+            trip.ring.loop_clock = turn_clock_of(self._loop)
+        trip.finished(time.perf_counter(), walk.n_input_tokens)
+        return walk.inputs
+
+    def _session_at(self, block_idx: int) -> _ServerInferenceSession:
+        server_idx = self._find_session_index(block_idx)
+        if server_idx is None:
+            raise RuntimeError(f"No active session covers block {block_idx}")
+        return self._sessions[server_idx]
+
+    def _hop_done(self, walk: "_Walk", session: _ServerInferenceSession, outputs: np.ndarray) -> None:
+        """A hop answered: on to the next one with its outputs."""
+        assert outputs.shape == walk.inputs.shape, f"{outputs.shape} != {walk.inputs.shape}"
+        self.trip.hop(*session.stations)
+        walk.inputs = outputs
+        walk.block_idx = session.span.end
+        peer = session.span.peer_id
+        if self.seq_manager.has_failure_record(peer):
+            self._tell_router(self.seq_manager.on_request_success, peer)
+        self._maybe_blame_hop(session)
+
+    async def _hop_failed(self, walk: "_Walk", error: Exception, session: Optional[_ServerInferenceSession]) -> None:
+        """A hop raised: count it, ban the peer, wait, repair the chain at
+        that hop; the walk resumes there with the inputs it had."""
+        if self._closed:  # closed under a step that was out: no peer's fault, and no chain to repair
+            raise error
+        walk.attempt += 1
+        peer = session.span.peer_id if session is not None else None
+        self.seq_manager.on_request_failure(peer)
+        if self._max_retries is not None and walk.attempt > self._max_retries:
+            raise error
+        delay = min(
+            self.seq_manager.config.min_backoff * (2 ** (walk.attempt - 1)),
+            self.seq_manager.config.max_backoff,
+        )
+        logger.warning(
+            f"Caught exception from block {walk.block_idx} "
+            f"(peer {peer.to_string()[:8] if peer else '?'}), retrying in {delay:.1f}s: {error}"
+        )
+        await asyncio.sleep(delay)
+        walk.block_idx = await self._repair_chain(walk.block_idx)
+
+    def _step_done(self, walk: "_Walk") -> None:
+        self._position += walk.n_input_tokens
+        self._account_step(time.perf_counter() - walk.t_step0, walk.n_input_tokens)
+
+    # --------------------------------------------- a step on the caller's thread
+
+    def can_step_from_thread(self, hidden: np.ndarray) -> bool:
+        """Whether this step may take the direct way, read off the call and
+        the session as they stand: the caller is on no loop; a route is open
+        and this is not its first step; no hop has a rollback to be told; the
+        frame fits ``THREAD_FRAME_BYTES`` (a codec only shrinks ``hidden``);
+        fault injection is off (``rpc.stream_recv`` keeps its one home, the
+        coroutine's ``recv``); no route-upgrade check is due (the phase
+        hand-off is due after a first step only, which is a coroutine's).
+        The facade asks only for a step that carries neither ``prompts`` nor
+        ``hypo_ids``."""
+        if self._loop is None or self._steps == 0 or self._closed or not self._sessions or chaos.ENABLED:
+            return False
+        if hidden.nbytes + _FRAME_SLACK_BYTES > THREAD_FRAME_BYTES or asyncio._get_running_loop() is not None:
+            return False
+        period = self.seq_manager.config.route_upgrade_period
+        if period and time.monotonic() - self._last_route_check >= period:
+            return False
+        position = self._position
+        return all(s.position <= position for s in self._sessions)
+
+    def step_from_thread(self, hidden: np.ndarray, run) -> np.ndarray:
+        """``step`` for a caller on a thread of its own, where
+        ``can_step_from_thread`` allows it: the same hops in the same order,
+        each exchanged by this thread (``_ServerInferenceSession.step_from_thread``),
+        so the step crosses to the loop once a hop each way and starts no
+        coroutine. The first exchange that raises hands the rest of the step,
+        from that hop on, to ``_walk`` on the loop through ``run`` (the
+        runtime's): there is one retry loop and one repair.
+
+        What this touches off the loop, and why it may: the hop's ``HopTrace``,
+        ``history``, ``position`` and ``stations``, the integrity monitor, the
+        trip, ``_account_step``'s sums and ``_position`` are this session's and
+        one step at a time runs on a session (the loop touches them only inside
+        a step, which this thread then waits for); the ring takes an atomic
+        ``append``; the flight recorder and the journal have their locks; the
+        stream's inbox is thread-safe and its frame is written on the loop;
+        the router's tables (``seq_manager``) are read here and written on the
+        loop alone (``_tell_router``)."""
+        trip = self.trip
+        trip.on_loop(time.perf_counter())  # K1 is the build's beginning: nothing is crossed before it
+        self._admit(hidden)
+        walk = _Walk(hidden, uuid.uuid4().hex, time.perf_counter())
+        while walk.block_idx < self.num_blocks:
+            session = None
+            try:
+                session = self._session_at(walk.block_idx)
+                outputs = session.step_from_thread(walk.inputs, walk.step_id)
+                self._hop_done(walk, session, outputs)
+            except Exception as e:
+                return run(self._walk(walk, failed=(e, session)))
+        self._step_done(walk)
+        trip.finished(time.perf_counter(), walk.n_input_tokens, direct=True)
+        return walk.inputs
+
+    def _tell_router(self, fn, *args) -> None:
+        """The router's tables are the loop's: a caller's thread posts its
+        word there and does not wait for it."""
+        loop = self._loop
+        if loop is None or asyncio._get_running_loop() is loop:
+            fn(*args)
+            return
+        try:
+            loop.call_soon_threadsafe(fn, *args)
+        except RuntimeError:
+            pass  # the loop is closed: there is no router left to tell
 
     # ------------------------------------------------- critical-path profiler
 
@@ -512,7 +673,7 @@ class InferenceSession:
             return
         report = getattr(self.seq_manager, "report_congestion", None)
         if report is not None:
-            report(session.span.peer_id, share)
+            self._tell_router(report, session.span.peer_id, share)
 
     def _on_integrity_divergence(self, peer_id) -> None:
         """A hop's reply diverged from its fused fingerprint: hand routing
@@ -520,7 +681,7 @@ class InferenceSession:
         any repair this session performs — steers off the replica."""
         report = getattr(self.seq_manager, "report_integrity", None)
         if report is not None:
-            report(peer_id)
+            self._tell_router(report, peer_id)
 
     def trace_report(self) -> dict:
         """The session's per-hop latency waterfall so far: wall-clock
@@ -567,6 +728,8 @@ class InferenceSession:
         return {
             "trace_id": self.trace_id,
             "tokens": self._tokens,
+            # of the session's steps, those the caller's thread exchanged itself (step_from_thread)
+            "direct_steps": self.trip.direct,
             "total": total,
             "peers": per_peer,
         }

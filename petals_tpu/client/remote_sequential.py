@@ -118,10 +118,20 @@ class SyncInferenceSession:
         self._session = session
         self._runtime = runtime
 
-    def step(self, hidden: np.ndarray, **kwargs) -> np.ndarray:
-        trip = self._session.trip  # the caller's side of a step's stations (telemetry/spans.py)
+    def step(
+        self, hidden: np.ndarray, *, prompts: Optional[np.ndarray] = None, hypo_ids: Optional[np.ndarray] = None
+    ) -> np.ndarray:
+        """A plain step of an open session is exchanged by this thread itself
+        (``InferenceSession.step_from_thread``); any other, a session's first
+        included, is a coroutine on the runtime's loop, as is what is left of
+        a step whose exchange failed."""
+        session, hidden = self._session, np.asarray(hidden)
+        trip = session.trip  # the caller's side of a step's stations (telemetry/spans.py)
         trip.entered(time.perf_counter())
-        out = self._runtime.run(self._session.step(np.asarray(hidden), **kwargs))
+        if prompts is None and hypo_ids is None and session.can_step_from_thread(hidden):
+            out = session.step_from_thread(hidden, self._runtime.run)
+        else:
+            out = self._runtime.run(session.step(hidden, prompts=prompts, hypo_ids=hypo_ids))
         trip.woke(time.perf_counter())
         return out
 

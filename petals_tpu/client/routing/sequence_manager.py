@@ -322,6 +322,11 @@ class RemoteSequenceManager:
     def on_request_success(self, peer_id: PeerID) -> None:
         self._banned.pop(peer_id, None)
 
+    def has_failure_record(self, peer_id: PeerID) -> bool:
+        """Whether ``on_request_success(peer_id)`` has anything to lift: a
+        read, which any thread may make (the tables are written on the loop)."""
+        return peer_id in self._banned
+
     def _is_banned(self, peer_id: PeerID) -> bool:
         entry = self._banned.get(peer_id)
         if entry is None:

@@ -11,12 +11,13 @@ from __future__ import annotations
 
 import asyncio
 import itertools
+import queue
 import time
 from typing import Any, AsyncIterator, Optional
 
 from petals_tpu import chaos
 from petals_tpu.data_structures import PeerID
-from petals_tpu.rpc.protocol import decode_frame, read_frame_body, write_frame
+from petals_tpu.rpc.protocol import decode_frame, encode_frame, read_frame_body, write_frame
 from petals_tpu.rpc.server import RpcError
 from petals_tpu.utils.logging import get_logger
 
@@ -24,9 +25,25 @@ logger = get_logger(__name__)
 
 _END = object()
 
+# The largest frame a thread hands to the connection itself (``send_from_thread``):
+# such a frame is written whole without waiting for ``drain``, so what keeps the
+# transport's buffer bounded is that a stream has one of them out at a time and
+# none is larger than this (a decode step's is 16-33 KB; a prompt's takes
+# ``send``, its lock and its ``drain``).
+THREAD_FRAME_BYTES = 1 << 17
+
 
 class StreamCall:
     """A bidirectional stream: ``send``/``end`` feed the server, iterate to read.
+
+    One consumer at a time reads it, a coroutine on the connection's loop
+    (``send`` / ``recv``) or a thread that is not the loop's
+    (``send_from_thread`` / ``recv_in_thread``: no coroutine, task, timer or
+    future is made, and the loop is crossed once each way). Both take their
+    items from one thread-safe inbox, which the connection's reader fills, so
+    a stream may change hands between two exchanges and whatever wakes a
+    parked coroutine (an item, an abort, the connection lost, ``cancel``)
+    wakes a parked thread.
 
     ``read_at`` is this process's ``time.perf_counter`` when the frame of the
     item last received lay whole in memory, before it was unpacked (what
@@ -37,7 +54,8 @@ class StreamCall:
         self._client = client
         self._call_id = call_id
         self._method = method  # chaos-injection detail for rpc.stream_recv
-        self._inbound: asyncio.Queue = asyncio.Queue()
+        self._inbound: queue.SimpleQueue = queue.SimpleQueue()  # (item, read_at), put on the loop
+        self._waiter: Optional[asyncio.Future] = None  # of the coroutine parked in recv(); the loop's alone
         self._closed = False
         self.read_at: Optional[float] = None
 
@@ -54,7 +72,20 @@ class StreamCall:
         """Next response item; raises StopAsyncIteration at end of stream."""
         if chaos.ENABLED:
             await chaos.inject(chaos.SITE_RPC_STREAM_RECV, detail=self._method)
-        item, read_at = await asyncio.wait_for(self._inbound.get(), timeout)
+        while True:
+            try:
+                entry = self._inbound.get_nowait()
+                break
+            except queue.Empty:
+                self._waiter = waiter = asyncio.get_running_loop().create_future()
+                try:
+                    await asyncio.wait_for(waiter, timeout)
+                finally:
+                    self._waiter = None
+        return self._take(entry)
+
+    def _take(self, entry: tuple) -> Any:
+        item, read_at = entry
         if item is _END:
             self._closed = True
             raise StopAsyncIteration
@@ -63,6 +94,33 @@ class StreamCall:
             raise item
         self.read_at = read_at
         return item
+
+    def send_from_thread(self, payload: Any) -> None:
+        """``send`` for a caller on a thread that is not the loop's: the frame
+        is packed here and handed to the loop as one ``write`` of a whole
+        frame, which cannot interleave with a coroutine's (``write_frame``
+        writes a whole frame in one call too; its lock orders the drains).
+        It does not wait for ``drain``: hence ``THREAD_FRAME_BYTES``."""
+        client = self._client
+        if self._closed:
+            raise RpcError("Stream is closed")
+        if client._closed:
+            raise RpcError("Client connection is closed")
+        frame = encode_frame({"t": "sitem", "id": self._call_id, "payload": payload})
+        if len(frame) > THREAD_FRAME_BYTES:
+            raise ValueError(f"A frame of {len(frame)} bytes takes send(): over {THREAD_FRAME_BYTES}")
+        try:
+            client._loop.call_soon_threadsafe(client._writer.write, frame)
+        except RuntimeError as e:  # the loop is closed
+            raise RpcError(f"Client connection is closed: {e}") from e
+
+    def recv_in_thread(self, timeout: Optional[float] = None) -> Any:
+        """``recv`` for the same caller: parks the thread on the inbox."""
+        try:
+            entry = self._inbound.get(timeout=timeout)
+        except queue.Empty:
+            raise asyncio.TimeoutError() from None
+        return self._take(entry)
 
     def __aiter__(self) -> AsyncIterator[Any]:
         return self
@@ -73,6 +131,7 @@ class StreamCall:
     async def cancel(self) -> None:
         if not self._closed:
             self._closed = True
+            self._push(RpcError("Stream is closed"))  # whoever is parked on it now
             try:
                 await self._client._send({"t": "cancel", "id": self._call_id})
             except (ConnectionError, RpcError):
@@ -80,7 +139,11 @@ class StreamCall:
         self._client._streams.pop(self._call_id, None)
 
     def _push(self, item: Any, read_at: Optional[float] = None) -> None:
-        self._inbound.put_nowait((item, read_at))
+        """On the loop: the reader's, an abort's or ``cancel``'s item to whoever waits."""
+        self._inbound.put((item, read_at))
+        waiter = self._waiter
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
 
 
 class RpcClient:
@@ -89,6 +152,7 @@ class RpcClient:
         import secrets
 
         self._reader, self._writer = reader, writer
+        self._loop = asyncio.get_running_loop()  # where StreamCall.send_from_thread posts its frame
         self._identity = identity
         self._peer_id = identity.peer_id if identity is not None else peer_id
         self._nonce = secrets.token_bytes(16)

@@ -31,13 +31,31 @@ written to the next, one reading closing a stretch and opening the next.
 
 ====  ==========================================================  ============
 K3    ``RpcClient._read_loop``: the reply's frame read whole       ``away_s``
-K4    ``_ServerInferenceSession.step``: ``stream.recv`` returned   ``recv_s``
-K5    ``InferenceSession.step`` about to return                    ``finish_s``
+K4    ``_ServerInferenceSession``: the reply is in the stepper's   ``recv_s``
+      hands (``stream.recv`` / ``recv_in_thread`` returned)
+K5    ``InferenceSession``: the step accepted, about to return     ``finish_s``
 K6    ``SyncInferenceSession.step`` holds the result               ``wake_s``
 K0    ``SyncInferenceSession.step`` entered again                  ``user_s``
-K1    ``InferenceSession.step`` running on the loop                ``submit_s``
-K2    ``_ServerInferenceSession.step``: ``stream.send`` returned   ``build_s``
+K1    ``InferenceSession``: the step begins where it will be built ``submit_s``
+K2    ``_ServerInferenceSession``: the request handed to the       ``build_s``
+      connection (``stream.send`` / ``send_from_thread`` returned)
 ====  ==========================================================  ============
+
+A step crosses the client's process one of two ways (PR 55). **The
+coroutine's**: ``SyncInferenceSession.step`` submits ``InferenceSession.step``
+to the loop, so K1 is the coroutine running there (``submit_s`` is a thread
+crossing), K2 ``stream.send`` returned (the frame written and drained), K4
+``stream.recv`` returned on the loop, K6 the caller's thread awake again
+(``wake_s`` is the second crossing). **The direct way**
+(``InferenceSession.step_from_thread``, a sync caller's steady-state step):
+the caller's thread does it all, so K1 follows K0 at once (``submit_s`` ~0:
+no crossing before the build), K2 is the packed frame handed to the loop
+(``build_s``; the loop's delay until it is written lies in ``away_s``; a reply
+read before the thread had read K2 makes K2 = K3), K4 is
+the caller's thread holding the item (``recv_s`` is now the reader's unpacking
+and the one loop-to-thread crossing), and K6 follows K5 on the same thread
+(``wake_s`` ~0). ``ClientTrip.direct`` counts those steps and a row's last
+column marks one.
 
 ``away_s`` runs from K2 to K3 (the wire, the server, the wire, this loop's
 lateness on the ready socket); a caller of the async session has K6 = K5 and
@@ -62,7 +80,7 @@ COMPONENTS = ("network", "queue", "compute", "serialize", "other")
 # a step's stretches in the order a row holds them, after ROW_HEAD
 CLIENT_STRETCHES = ("away_s", "recv_s", "finish_s", "wake_s", "user_s", "submit_s", "build_s", "relay_s")
 ROW_HEAD = ("read_at", "trace_id", "step", "hops", "tokens")  # K3, the session, its step's number, hops, tokens in
-ROW = (*ROW_HEAD, *CLIENT_STRETCHES)
+ROW = (*ROW_HEAD, *CLIENT_STRETCHES, "direct")  # last: 1 for a step taken the direct way, 0 for a coroutine's
 _WAKE, _USER, _SUBMIT, _BUILD = (ROW.index(name) for name in ("wake_s", "user_s", "submit_s", "build_s"))
 # eight lanes' steps of a benchmark window (51 s of 6 ms gaps); a row is ~0.4 KB
 STEP_RING_ROWS = 65536
@@ -205,9 +223,10 @@ class ClientTrip:
     ``_t`` is the last reading: every call closes the stretch since it, so
     the stretches tile the session's time whatever happened in between (a
     retry's back-off lands in the stretch that was open). One step at a time
-    touches a trip, on the caller's thread (K0, K6) or the loop's."""
+    touches a trip, on the caller's thread (K0, K6; every station of a direct
+    step) or the loop's."""
 
-    __slots__ = ("trace_id", "ring", "steps", "turns", "sums", "_t", "_row", "_entered", "_user", "_submit",
+    __slots__ = ("trace_id", "ring", "steps", "turns", "direct", "sums", "_t", "_row", "_entered", "_user", "_submit",
                  "_hops", "_away", "_relay", "_held_at")
 
     def __init__(self, trace_id: Optional[str], ring: Optional[StepRing] = None):
@@ -215,6 +234,7 @@ class ClientTrip:
         self.ring = STEP_RING if ring is None else ring
         self.steps = 0  # replies handed to the caller
         self.turns = 0  # of them, those a next request followed: what user_s, submit_s and build_s were summed over
+        self.direct = 0  # of them, those the caller's thread exchanged itself (InferenceSession.step_from_thread)
         self.sums = dict.fromkeys(CLIENT_STRETCHES, 0.0)
         self._t: Optional[float] = None
         self._row: Optional[list] = None  # the last step's row, until the next request is written
@@ -229,7 +249,8 @@ class ClientTrip:
         self._entered = True
 
     def on_loop(self, now: float) -> None:
-        """K1. A caller that never passed K0 is on the loop already: K6 = K5, K0 = K1."""
+        """K1: on the loop, or on the caller's thread where a direct step's build begins.
+        A caller that never passed K0 is on the loop already: K6 = K5, K0 = K1."""
         row = self._row
         if row is not None:
             if self._entered:
@@ -256,19 +277,20 @@ class ClientTrip:
         self._t, self._held_at = read_at, held_at
         self._hops += 1
 
-    def finished(self, now: float, tokens: int) -> None:
+    def finished(self, now: float, tokens: int, direct: bool = False) -> None:
         """K5: the step's row goes into the ring."""
         if not self._hops:
             return
         recv, finish = self._held_at - self._t, now - self._held_at
         row = [self._t, self.trace_id, self.steps, self._hops, tokens,
-               self._away, recv, finish, None, None, None, None, self._relay]
+               self._away, recv, finish, None, None, None, None, self._relay, int(direct)]
         sums = self.sums
         sums["away_s"] += self._away
         sums["recv_s"] += recv
         sums["finish_s"] += finish
         sums["relay_s"] += self._relay
         self.steps += 1
+        self.direct += direct
         self.ring.rows.append(row)
         self._row, self._t = row, now
 
@@ -286,7 +308,8 @@ class ClientTrip:
         self._row, self._entered = None, False
 
     def report(self) -> dict:
-        return {**{k: round(v, 6) for k, v in self.sums.items()}, "steps": self.steps, "turns": self.turns}
+        return {**{k: round(v, 6) for k, v in self.sums.items()}, "steps": self.steps, "turns": self.turns,
+                "direct": self.direct}
 
 
 def build_trace_report(
@@ -394,7 +417,7 @@ def format_waterfall(report: dict, width: int = 48) -> str:
         lines.append(
             "  client: "
             + "  ".join(f"{k[:-2]} {float(client.get(k, 0.0)):.3f}s" for k in CLIENT_STRETCHES)
-            + f"  ({client['steps']} steps, {client.get('turns', 0)} followed)"
+            + f"  ({client['steps']} steps, {client.get('turns', 0)} followed, {client.get('direct', 0)} direct)"
         )
     legend = "  legend: " + "  ".join(f"{c}={k}" for k, c in _BAR_CHARS.items() if k != "other")
     lines.append(legend)
