@@ -33,7 +33,7 @@ Pre-norm: ``h = x + attn(ln1(x)); y = h + mlp(ln2(h))``.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +53,73 @@ from petals_tpu.ops.latent_attention import (
 )
 from petals_tpu.ops.paged_attention import PagedKV
 from petals_tpu.ops.rotary import apply_rotary, rotary_tables
+
+
+class LatentDims(NamedTuple):
+    """The static shapes and numbers of one latent attention (``latent_attention``)."""
+
+    heads: int
+    nope: int  # qk_nope_head_dim
+    rope: int  # qk_rope_head_dim
+    value: int  # v_head_dim
+    latent: int  # kv_lora_rank
+    rope_theta: float
+    norm_eps: float  # of the norm over the latent, and over the low-rank query where there is one
+    q_scale: float = 1.0  # on the whole query (a low-rank query's ``sqrt(hidden / q_lora_rank)``)
+    kv_scale: float = 1.0  # on the normed latent BEFORE ``kv_b_proj`` (``sqrt(hidden / kv_lora_rank)``): the cached row is the scaled one
+
+
+def latent_attention(params: dict, x: jnp.ndarray, kv, position, dims: LatentDims, *, n_valid=None, who: str = "deepseek_v3"):
+    """One multi-head latent attention over the normed rows ``x`` [batch, seq,
+    hidden], its output projection included: ``(out [batch, seq, hidden],
+    new_kv)``. ``kv`` is None (a whole sequence, no cache) or ``(c, k_pe)``,
+    two ``PagedKV`` over the lane pool's pages. The query is one matrix
+    (``wq``) or, where ``params`` has ``wqa``, low-rank: ``wqb(rms(x wqa,
+    q_norm))``. ``dims.q_scale`` multiplies both parts of the query, so it is
+    taken into the softmax's scale (the scores are its only readers);
+    ``dims.kv_scale`` multiplies the normed latent before anything reads it,
+    so the row a position caches is the scaled one and both forms meet it as
+    they meet any other."""
+    batch, seq, _ = x.shape
+    heads, dn, dr, dv, latent = dims.heads, dims.nope, dims.rope, dims.value, dims.latent
+    scale = dims.q_scale * (dn + dr) ** -0.5
+    if "wqa" in params:
+        q = project_heads(rms_norm(mm(x, params["wqa"]), params["q_norm"], dims.norm_eps), params["wqb"])
+    else:
+        q = project_heads(x, params["wq"])
+    q = q.reshape(batch, seq, heads, dn + dr)
+    q_nope, q_pe = q[..., :dn], q[..., dn:]
+    row = mm(x, params["wkva"])  # [b, s, latent + dr]: one row for all heads
+    c = rms_norm(row[..., :latent], params["kv_norm"], dims.norm_eps)
+    if dims.kv_scale != 1.0:
+        c = (c.astype(jnp.float32) * dims.kv_scale).astype(c.dtype)
+    cos, sin = rotary_tables(absolute_positions(position, batch, seq), dr, theta=dims.rope_theta)
+    q_pe = apply_rotary(q_pe, cos, sin)
+    k_pe = apply_rotary(row[..., None, latent:], cos, sin)[:, :, 0]
+
+    if kv is None:  # a whole sequence, no cache: the stateless forward and backward passes
+        attn = latent_attend_dense(q_nope, q_pe, c, k_pe, params["wuk"], params["wuv"], scale=scale)
+        new_kv = None
+    else:
+        if len(kv) != 2 or not isinstance(kv[0], PagedKV):
+            raise NotImplementedError(
+                f"{who}: a cache without the latent rows' pages is not served: only the paged lane pool carries them"
+            )
+        c_kv, pe_kv = scatter_latent_rows(kv[0], kv[1], c, k_pe, position, n_valid)
+        if jnp.ndim(position) == 1:  # one row a lane: absorbed, no key or value is made
+            u = latent_decode_attend(absorb_queries(q_nope, params["wuk"]), q_pe, c_kv, pe_kv, position, scale=scale)
+            attn = expand_outputs(u, params["wuv"])
+        else:  # a prompt's chunk over one lane's table: expanded a block of positions at a time
+            attn = latent_chunk_attend(q_nope, q_pe, params["wuk"], params["wuv"], c_kv, pe_kv, position, n_valid, scale=scale)
+        new_kv = (c_kv, pe_kv)
+    return mm(attn.reshape(batch, seq, heads * dv), params["wo"]), new_kv
+
+
+def latent_dims(cfg: DeepseekV3BlockConfig) -> LatentDims:
+    return LatentDims(
+        cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank,
+        cfg.rope_theta, cfg.rms_norm_eps,
+    )
 
 
 def block_kind(cfg: DeepseekV3BlockConfig, block_index: int) -> str:
@@ -84,36 +151,10 @@ def block_apply(
     n_valid=None,
     live_rows=None,  # bool [batch] from a lane pool's step: the rows that are not idle lanes (None: all)
 ) -> Tuple[jnp.ndarray, Optional[tuple]]:
-    batch, seq, _ = hidden_states.shape
-    heads, dn, dr, dv, latent = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    scale = (dn + dr) ** -0.5
-
-    residual = hidden_states
+    seq = hidden_states.shape[1]
     x = rms_norm(hidden_states, params["ln1"], cfg.rms_norm_eps)
-    q = project_heads(x, params["wq"]).reshape(batch, seq, heads, dn + dr)
-    q_nope, q_pe = q[..., :dn], q[..., dn:]
-    row = mm(x, params["wkva"])  # [b, s, latent + dr]: one row for all heads
-    c = rms_norm(row[..., :latent], params["kv_norm"], cfg.rms_norm_eps)
-    cos, sin = rotary_tables(absolute_positions(position, batch, seq), dr, theta=cfg.rope_theta)
-    q_pe = apply_rotary(q_pe, cos, sin)
-    k_pe = apply_rotary(row[..., None, latent:], cos, sin)[:, :, 0]
-
-    if kv is None:  # a whole sequence, no cache: the stateless forward and backward passes
-        attn = latent_attend_dense(q_nope, q_pe, c, k_pe, params["wuk"], params["wuv"], scale=scale)
-        new_kv = None
-    else:
-        if len(kv) != 2 or not isinstance(kv[0], PagedKV):
-            raise NotImplementedError(
-                "deepseek_v3: a cache without the latent rows' pages is not served: only the paged lane pool carries them"
-            )
-        c_kv, pe_kv = scatter_latent_rows(kv[0], kv[1], c, k_pe, position, n_valid)
-        if jnp.ndim(position) == 1:  # one row a lane: absorbed, no key or value is made
-            u = latent_decode_attend(absorb_queries(q_nope, params["wuk"]), q_pe, c_kv, pe_kv, position, scale=scale)
-            attn = expand_outputs(u, params["wuv"])
-        else:  # a prompt's chunk over one lane's table: expanded a block of positions at a time
-            attn = latent_chunk_attend(q_nope, q_pe, params["wuk"], params["wuv"], c_kv, pe_kv, position, n_valid, scale=scale)
-        new_kv = (c_kv, pe_kv)
-    hidden_states = residual + mm(attn.reshape(batch, seq, heads * dv), params["wo"])
+    attn, new_kv = latent_attention(params, x, kv, position, latent_dims(cfg), n_valid=n_valid)
+    hidden_states = hidden_states + attn
 
     residual = hidden_states
     x = rms_norm(hidden_states, params["ln2"], cfg.rms_norm_eps)
@@ -141,6 +182,12 @@ def rope_halves(width: int) -> np.ndarray:
     return np.concatenate([np.arange(0, width, 2), np.arange(1, width, 2)])
 
 
+def fold_rope_columns(w: np.ndarray, plain: int) -> np.ndarray:
+    """``w`` [.., plain + rope] with its last ``rope`` columns, the checkpoint's
+    pairs ``(2j, 2j + 1)``, put in rotate-half's order; the first ``plain`` as they are."""
+    return np.concatenate([w[..., :plain], w[..., plain:][..., rope_halves(w.shape[-1] - plain)]], axis=-1)
+
+
 def hf_to_block_params(tensors: dict, cfg: DeepseekV3BlockConfig, kind: str) -> dict:
     heads, dn, dr, dv, latent = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
 
@@ -149,10 +196,8 @@ def hf_to_block_params(tensors: dict, cfg: DeepseekV3BlockConfig, kind: str) -> 
 
     wq, wkva = t("self_attn.q_proj.weight"), t("self_attn.kv_a_proj_with_mqa.weight")
     if cfg.rope_interleave:  # pairs (2j, 2j + 1) to halves, on both sides of q_pe . k_pe
-        order = rope_halves(dr)
-        wq = wq.reshape(-1, heads, dn + dr)
-        wq = np.ascontiguousarray(np.concatenate([wq[..., :dn], wq[..., dn:][..., order]], axis=-1).reshape(-1, heads * (dn + dr)))
-        wkva = np.ascontiguousarray(np.concatenate([wkva[:, :latent], wkva[:, latent:][:, order]], axis=-1))
+        wq = np.ascontiguousarray(fold_rope_columns(wq.reshape(-1, heads, dn + dr), dn).reshape(-1, heads * (dn + dr)))
+        wkva = np.ascontiguousarray(fold_rope_columns(wkva, latent))
     wkvb = np.asarray(tensors["self_attn.kv_b_proj.weight"]).reshape(heads, dn + dv, latent)  # a head: [k_nope | v] x latent
     params = {
         "ln1": np.asarray(tensors["input_layernorm.weight"]),

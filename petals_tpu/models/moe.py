@@ -2,8 +2,18 @@
 (mixtral, olmoe, exaone_moe, keye_vl2, deepseek_v3, qwen3_next): a router
 over the published number of experts, the top k kept, SwiGLU experts stacked
 ``w1``/``w3`` [E, h, m] and ``w2`` [E, m, h]. The routing rule is data (``Routing``): a softmax whose kept
-weights are renormalised or not, or a sigmoid whose choice a bias moves and
-whose kept weights are renormalised and scaled.
+weights are renormalised or not, a sigmoid whose choice a bias moves and
+whose kept weights are renormalised and scaled, or a softmax whose choice a
+bias moves and whose kept weights are scaled as they are.
+
+The router may be WIDER than the experts that exist: its last ``identities``
+outputs are identity ("zero-compute") experts, whose output is the token
+itself. A pick of one is a weight on the token and nothing else: their
+weighted sum, ``(sum of the kept weights of identity picks) * x``, is one
+fused elementwise op for every token (``ptu.moe.zero``), whatever share of the
+experts a server holds (every chip computes it alike, as it would a shared
+expert, and it needs no exchange). An identity pick is in no dispatch: no slot
+of "hit", no group of "grouped", no weight in the einsum's combine.
 
 A server may hold a SHARE of a layer's experts: ``w1`` stacks the ``E`` it
 holds, the router (``gate`` [h, routed]) is as wide as the model publishes,
@@ -78,13 +88,19 @@ class MoeDims(NamedTuple):
     expert_width: int
     routed: Optional[int] = None  # the router's width; None: every expert is held
     first: int = 0  # which of the routed experts the first held one is
+    identities: int = 0  # of the router's outputs, the last that are identity experts: ``routed - identities`` experts exist
+
+    @property
+    def share(self) -> bool:
+        """Whether fewer experts are held than exist (the router's identity outputs are no experts)."""
+        return self.routed is not None and self.routed - self.identities > self.experts
 
 
 class Routing(NamedTuple):
     """How a router's logits become the kept experts and their weights."""
 
     top_k: int
-    scoring: str = "softmax"  # or "sigmoid": chosen by score + ``gate_bias``, weighed by score
+    scoring: str = "softmax"  # or "sigmoid" | "softmax_bias": chosen by score + ``gate_bias``, weighed by score
     renormalize: bool = False  # kept weights divided by their sum
     scale: float = 1.0  # and multiplied by this (a sigmoid router's ``routed_scaling_factor``)
 
@@ -113,13 +129,13 @@ def route(params: dict, x: jnp.ndarray, routing: Routing):
         if routing.renormalize:
             top_probs = top_probs / top_probs.sum(axis=-1, keepdims=True)
         return top_idx, top_probs
-    if routing.scoring != "sigmoid":
+    if routing.scoring not in ("sigmoid", "softmax_bias"):
         raise ValueError(f"unknown routing rule {routing.scoring!r}")
-    # the published router runs in float32 (HF DeepseekV3TopkRouter)
+    # the published router runs in float32 (HF DeepseekV3TopkRouter, LongcatFlashTopkRouter)
     logits = jnp.matmul(
         x.astype(jnp.float32), params["gate"].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
     )
-    scores = jax.nn.sigmoid(logits)
+    scores = jax.nn.sigmoid(logits) if routing.scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
     _, top_idx = jax.lax.top_k(scores + params["gate_bias"].astype(jnp.float32), routing.top_k)
     top_scores = jnp.take_along_axis(scores, top_idx, axis=-1)  # the bias chooses, it does not weigh
     if routing.renormalize:
@@ -194,13 +210,13 @@ def _experts_hit(x, stack: ExpertStack, top_idx, top_probs, live_rows) -> jnp.nd
 
 
 def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, dispatch: str = "dense",
-              scoring: str = "softmax", scale: float = 1.0, first: int = 0, live_rows=None) -> jnp.ndarray:
+              scoring: str = "softmax", scale: float = 1.0, first: int = 0, identities: int = 0, live_rows=None) -> jnp.ndarray:
     """x: [batch, seq, hidden] -> what the held experts give each token of the
     mixture of its top-k experts (HF-exact routing), plus the shared expert
     where ``params`` has one. ``renormalize`` divides the kept weights by
     their sum (Mixtral's rule; OLMoE's ``norm_topk_prob`` false keeps the
     softmax mass as it is); ``scoring`` and ``scale`` are ``Routing``'s,
-    ``first`` is ``MoeDims.first``.
+    ``first`` and ``identities`` are ``MoeDims``'.
 
     ``dispatch`` is ``grouped_dispatch``'s answer (``choose_dispatch`` asks it
     for a block). The expert weights are ``params``' ``w1`` / ``w3`` / ``w2``
@@ -211,22 +227,29 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
 
     stack = params.get("experts")
     n_experts = stack.w1.shape[1] if stack is not None else params["w1"].shape[0]
-    share = n_experts != params["gate"].shape[-1]
+    share = n_experts != params["gate"].shape[-1]  # a pick may fall outside the held: an absent expert's, or an identity's
     with jax.named_scope("ptu.moe.router"):
         top_idx, top_probs = route(params, x, Routing(top_k, scoring, renormalize, scale))
-        if share:
-            top_idx = top_idx - first  # among the held; outside [0, n_experts): absent
+    if identities:
+        with jax.named_scope("ptu.moe.zero"):
+            to_self = jnp.where(top_idx >= params["gate"].shape[-1] - identities, top_probs, 0.0).sum(axis=-1)
+            zero = (to_self[..., None] * x.astype(jnp.float32)).astype(x.dtype)
+    if share:
+        top_idx = top_idx - first  # among the held; outside [0, n_experts): absent, or an identity (beyond every expert)
+
+    def with_rest(y):
+        return _add_shared(params, x, y + zero if identities else y)
 
     if dispatch == "hit":
         with jax.named_scope("ptu.moe.experts.hit"):
             y = _experts_hit(x, stack, top_idx, top_probs, live_rows)
-        return _add_shared(params, x, y)
+        return with_rest(y)
 
     w1, w3, w2 = stack.of_layer() if stack is not None else (params["w1"], params["w3"], params["w2"])
     if dispatch == "grouped":
         with jax.named_scope("ptu.moe.experts.grouped"):
             y = _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share)
-        return _add_shared(params, x, y)
+        return with_rest(y)
 
     with jax.named_scope("ptu.moe.experts.dense"):
         # combine weights per held expert: [b, s, E] (an index outside them one-hots to nothing)
@@ -250,7 +273,7 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
             up = jnp.einsum("bsh,ehm->ebsm", x, w3)
             expert_out = jnp.einsum("ebsm,emh->ebsh", silu(gate_out) * up, w2)
         y = jnp.einsum("ebsh,bse->bsh", expert_out, combine)
-    return _add_shared(params, x, y)
+    return with_rest(y)
 
 
 def _add_shared(params: dict, x: jnp.ndarray, routed: jnp.ndarray) -> jnp.ndarray:
@@ -320,13 +343,15 @@ def grouped_dispatch(dims: MoeDims, seq: int, *, stacked: bool = False, quantize
         return "dense"
     if seq < GROUPED_MIN_SEQ:
         return "hit" if stacked else "dense"
-    share = dims.routed is not None and dims.routed > dims.experts
+    share = dims.share
     if dims.experts <= FEW_EXPERTS and not share:
         return "grouped"
     expert_params = 3 * dims.hidden * dims.expert_width
     read_s = dims.experts * 2 * expert_params / HBM_BYTES_PER_S
     dense_s = max(read_s, seq * 2 * dims.experts * expert_params / DENSE_FLOPS_PER_S)
-    grouped_s = read_s + dims.experts * GROUP_COST_S + seq * 2 * dims.top_k * expert_params / GROUPED_FLOPS_PER_S
+    # of a token's picks, those that are experts': an identity pick is no row of any group
+    top_k = dims.top_k * (1 - dims.identities / dims.routed) if dims.identities else dims.top_k
+    grouped_s = read_s + dims.experts * GROUP_COST_S + seq * 2 * top_k * expert_params / GROUPED_FLOPS_PER_S
     if share:
         grouped_s += 2 * read_s  # the layer's held experts copied out of the stacked run
     return "grouped" if grouped_s < dense_s else "dense"

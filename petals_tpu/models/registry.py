@@ -105,6 +105,15 @@ class ModelFamily:
     # private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised pages, a tp
     # mesh) is refused for a family that declares one (server/backend.py ``latent_row``)
     block_latent: Optional[Callable] = None
+    # (cfg, kind) -> how many cache rows a position a block of that kind keeps: the number of attention
+    # SUB-LAYERS in it, each with a cache of its own (a checkpoint layer that is two attentions and two
+    # feed-forwards around one expert layer cannot be split on the wire). None: one, every other family's. The
+    # page pools then hold that many layers of pages a block, one after the other, and ``block_apply`` is handed a
+    # tuple of that many ``kv``, one a sub-layer in order, each over the span's pools with its own layer's tables:
+    # a sub-layer that writes hands the next the pools it wrote (``PagedKV._replace(pool=...)``) and the block
+    # returns the tuple of what each returned. Served for a span whose positions cache a latent row
+    # (server/backend.py ``block_rows``)
+    block_sublayers: Optional[Callable] = None
     # (cfg, kind) -> what a block of that kind hands its attention BEYOND the query, the cache, the causal mask and a
     # static window, as names out of ``ATTENTION_EXTRAS``: "alibi" (a bias a head on the scores), "softcap" (a soft
     # cap on them), "traced_window" (a window that is an array, a layer's own out of its parameters, where
@@ -141,6 +150,9 @@ class ModelFamily:
 
     def latent_for(self, cfg, kind: Hashable) -> Optional[tuple]:
         return None if self.block_latent is None else self.block_latent(cfg, kind)
+
+    def sublayers_for(self, cfg, kind: Hashable) -> int:
+        return 1 if self.block_sublayers is None else int(self.block_sublayers(cfg, kind))
 
     def attention_for(self, cfg, kind: Hashable) -> frozenset:
         extras = frozenset(() if self.block_attention is None else self.block_attention(cfg, kind))

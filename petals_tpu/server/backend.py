@@ -191,6 +191,18 @@ class TransformerBackend:
         if rows != {None}:
             self._check_latent(rows, mesh)
             self.latent_row = tuple(int(width) for width in next(iter(rows)))
+        # how many cache rows a position a block keeps (ModelFamily.block_sublayers: the attention sub-layers of
+        # a block, each with pages of its own): the page pools hold ``page_layers`` layers of pages, a block's
+        # one after the other, and everything that multiplies by layers of pages multiplies by that
+        counts = {family.sublayers_for(cfg, kind) for kind, _, _ in self.runs}
+        self.block_rows = next(iter(counts)) if len(counts) == 1 else 0
+        if self.block_rows != 1 and (self.block_rows < 1 or self.latent_row is None):
+            raise NotImplementedError(
+                f"{family.name}: more than one cache row a position a block ({sorted(counts)} sub-layers) is served for a "
+                f"span whose blocks all keep the same number of latent rows: keys' and values' pages, a state and an index "
+                f"row are laid out one layer a block"
+            )
+        self.page_layers = len(self.kv_layers) * self.block_rows
         # adapter name -> (stacked {leaf: (A, B)}, scaling); see utils/peft.py
         self.adapters: Dict[str, tuple] = {}
         self._dummy_operands: Dict[tuple, jax.Array] = {}
@@ -424,7 +436,7 @@ class TransformerBackend:
         (server/batching.py), so no sharding rides these. The pool is as deep
         as the blocks of [start, end) that keep keys and values: a block with
         a state of its own (``state_cache_descriptors``) has no pages."""
-        n = sum(start <= i < end for i in self.kv_layers)
+        n = sum(start <= i < end for i in self.kv_layers) * self.block_rows
         if self.latent_row is not None:
             # a latent row in place of keys and values: the latents a position a row, and the rotated keys stored
             # as an index row of their width is (ops/latent_attention.py ``latent_pool_rows``); stored once
@@ -557,7 +569,7 @@ class TransformerBackend:
         (ops/latent_attention.py has the arithmetic)."""
         from petals_tpu.ops.latent_attention import chunk_reads, decode_path, decode_reads, latent_pool_rows
 
-        layers = len(self.kv_layers)
+        layers = self.page_layers
         contexts = [int(p) + 1 for p in last]
         kernel = decode_path(*latent_pool_rows(page_size, *self.latent_row), self.cache_dtype) == "kernel"
         read = decode_reads(n_lanes, max_pages, page_size, contexts, kernel=kernel)
@@ -584,7 +596,7 @@ class TransformerBackend:
         ``state_bytes_per_lane``. A span that caches a latent row in place of
         keys and values: that row's bytes, stored once."""
         if self.latent_row is not None:
-            return len(self.kv_layers) * sum(self.latent_row) * jnp.dtype(self.cache_dtype).itemsize
+            return self.page_layers * sum(self.latent_row) * jnp.dtype(self.cache_dtype).itemsize
         return (
             2
             * len(self.kv_layers)
@@ -989,8 +1001,11 @@ class TransformerBackend:
         spans)`` runs one block with its kind's ``block_apply``: ``spans``
         are the carried pools, keys' and values' (and the index rows' for a
         span that caches one), ``paged(spans, tables)`` wraps them and a set
-        of block tables as that block's ``PagedKV``s, and the pools
-        ``block_apply`` hands back go on to the next layer. Returns
+        of block tables as that block's ``PagedKV``s (for a block of more
+        than one attention sub-layer, ``block_rows``: one tuple of them a
+        sub-layer, each shifted to its own layer of pages, the block's
+        ``block_rows`` layers lying one after the other in the pools), and
+        the pools ``block_apply`` hands back go on to the next layer. Returns
         ``(carry, k_pool, v_pool, state)``, the pools in their stacked shape.
 
         A span with a recurrent state carries its STATE pool (``state``: one
@@ -1014,7 +1029,7 @@ class TransformerBackend:
         from petals_tpu.ops.linear_attention import StatePool
         from petals_tpu.ops.paged_attention import PagedKV
 
-        depth, n_pages = k_pool.shape[0], k_pool.shape[1]
+        depth, n_pages, rows = k_pool.shape[0], k_pool.shape[1], self.block_rows
         by_sort = bool(self.state_layers)
         indexed = self.index_row is not None
 
@@ -1033,11 +1048,13 @@ class TransformerBackend:
             if by_sort and self.family.state_for(self.cfg, kind) is not None:
                 inner, mine = state_layer(block_apply, inner, p_block, StatePool(state, slot))
                 return (inner, spans, tuple(mine.leaves)), None
-            first_page = (slot if by_sort else block_idx) * n_pages
+            first_page = (slot if by_sort else block_idx) * (rows * n_pages)
 
-            def paged(spans, tables):
-                shifted = jnp.where(tables >= 0, tables + first_page, -1)
-                own = (first_page, n_pages)
+            def paged(spans, tables, sub=None):
+                if sub is None and rows > 1:  # one ``kv`` a sub-layer, each over its own layer of pages
+                    return tuple(paged(spans, tables, sub) for sub in range(rows))
+                own = (first_page + (sub or 0) * n_pages, n_pages)
+                shifted = jnp.where(tables >= 0, tables + own[0], -1)
                 return tuple(PagedKV(span, shifted, own) for span in spans)
 
             return (*layer(block_apply, inner, p_block, spans, paged), state), None
@@ -1062,9 +1079,18 @@ class TransformerBackend:
                 p_block, h, paged(spans, tables), positions, cfg,
                 use_flash=False, tp_mesh=None, **live,
             )
-            return out, tuple(kv.pool for kv in new_kv)
+            return out, self._pools_of(new_kv)
 
         return layer
+
+    @staticmethod
+    def _pools_of(new_kv: tuple) -> tuple:
+        """The carried pools out of what a block returned as its ``kv``: its
+        ``PagedKV``s' pools, the LAST sub-layer's for a block of more than
+        one (``block_rows``: each wrote the pools the one before handed on)."""
+        from petals_tpu.ops.paged_attention import PagedKV
+
+        return tuple(kv.pool for kv in (new_kv if isinstance(new_kv[0], PagedKV) else new_kv[-1]))
 
     def _state_lanes_layer(self, positions, max_length):
         """``_scan_paged_span``'s ``state_layer`` for a step in which every
@@ -1466,7 +1492,7 @@ class TransformerBackend:
                     p_block, h_pf, paged(spans, table_row), chunk_pos, cfg,
                     use_flash=False, n_valid=chunk_n_valid, tp_mesh=None, **extra,
                 )
-                return (out_dec, out_pf), tuple(kv.pool for kv in new_kv)
+                return (out_dec, out_pf), self._pools_of(new_kv)
 
             decode_state = self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2])
 

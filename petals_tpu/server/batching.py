@@ -505,7 +505,7 @@ class DecodeBatcher:
             # a step's program walked a layer's experts
             self.stats.update(moe_dense_tokens=0, moe_grouped_tokens=0, moe_hit_tokens=0, moe_weight_passes=0)
             dims = backend.moe_dims
-            if dims.routed is not None and dims.routed > dims.experts:
+            if dims.share:
                 # a server that holds a share of the routed experts only: the expert-rows (a position through one
                 # expert) a mixed step's chunk half makes its dispatch multiply (positions x held experts under the
                 # all-experts einsum, positions x top k under the grouped one, which is handed every assignment's
@@ -2416,7 +2416,7 @@ class DecodeBatcher:
         counters besides."""
         if "attn_pages_gathered" not in self.stats:
             return
-        backend, layers = self.backend, len(self.backend.kv_layers)
+        backend, layers = self.backend, self.backend.page_layers
         last = positions[lanes] + (seq - 1)
         by_kernel = 0
         if seq > 1:

@@ -314,7 +314,7 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     hf_config = load_config(config_file, config_name)["config"]
     (tmp_path / "config.json").write_text(json.dumps(hf_config))
     family, cfg = get_block_config(str(tmp_path))
-    depth, lanes, n_pages, page_size = hf_config["num_hidden_layers"], 8, 8 * pages_a_lane, 64
+    depth, lanes, n_pages, page_size = cfg.num_hidden_layers, 8, 8 * pages_a_lane, 64  # the BLOCKS of the span (a double layer is one)
     # one stacked tree a run of consecutive blocks of one kind (K-EXAONE's five blocks: four runs of three trees)
     runs = tuple(
         {name: v5e((length, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind, BF16).items()}
@@ -1288,3 +1288,109 @@ def test_the_state_space_span_s_decode_walk_is_the_kernel_s_over_its_folded_row(
     assert backend.pool_row == (128,) and why is None
     assert [path for *_, path in backend.decode_walks(8, 40, 64)] == ["composed"]  # this backend is no TPU
     assert pfa.paged_kernel_unsupported(1, 128, "none") is None  # a prompt's chunk takes the paged prefill kernel
+
+
+SCMOE = "longcat-flash-span4-ep32"
+SCMOE_POOLS = ((8, 320, 64, 512), (8, 320, 32, 128))  # 2 layers of pages a block x 4 blocks, 8 lanes x 40 pages; latents, rotated keys
+
+
+@pytest.mark.parametrize("chunk", [0, 512], ids=["decode", "mixed-512"])
+def test_a_block_of_two_latent_attentions_walks_its_own_layers_of_pages_and_moves_no_weight(v5e, tmp_path, chunk):
+    """longcat-flash-span4-ep32's decode and mixed-512 steps (8 lanes, tables
+    of 40 pages: the cell's), compiled for the v5e. The pools are as deep as
+    the span's ATTENTIONS (2 a block: ``[8, 320, ...]``, 2 x 576 values a
+    position a block and nothing padded). The layer loop's body holds the
+    absorbed walk's kernel TWICE, both at 64 heads, both handed the two
+    whole-span pools; the pool of latents (168 MB, eight ninths of the cache)
+    is allocated once, never copied and no layer of it sliced out or written
+    back, in ``ENTRY`` or in the loop. The pool of rotated keys is small
+    enough at these lanes (21 MB; kanana2-30b-a3b-span6's is 201 MB) that the
+    compiler stages it whole through the chip's fast memory around a layer's
+    calls: ONE sliced prefetch and ONE copy back a layer, not one a walk
+    (PERF.md section 7, Left by PR 56; what Left by PR 53 warned of): held
+    here to that, so that a second round trip a layer shows. The hit
+    dispatch's kernel reads the run's expert stacks as the loop carries them;
+    the identities' weighted add is one fusion a half of the step (compare,
+    select, reduce, multiply: no gather, no scatter); no stacked weight is
+    relaid in the loop but ``wuk`` / ``wuv``, which the chunk's walk, a loop
+    of its own, is handed as arrays (8 MB each an attention: kanana2's
+    known one)."""
+    hlo, runs, pool, _ = _compiled_step(v5e, tmp_path, SCMOE, chunk, pages_a_lane=40)
+    assert tuple(pool.shape) == SCMOE_POOLS[0] and len(runs) == 1 and runs[0]["w1"].shape == (4, 16, 6144, 2048)
+    assert sum(math.prod(shape) for shape in SCMOE_POOLS) == 4 * 2 * 576 * 8 * 40 * 64  # 2 x 576 values a position a block
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    staged = []
+    for shape in SCMOE_POOLS:
+        elements, layer = math.prod(shape), math.prod(shape[1:])
+        assert any(tuple(dims) == shape for _, dims, _, _ in comps[entry]), f"the pool {shape} was not found in ENTRY"
+        moved = [(computation, name, op, dims) for computation, name, dims, op, rest in _arrays_in_memory(comps)
+                 if dims[-1:] == shape[-1:] and math.prod(dims) >= layer and dims not in ((64, 512, 128), (512, 64, 128))  # ``wuv``, below
+                 and (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest or _only_moves(comps, op, rest)
+                      or (computation != entry and (fused := _fused(comps, op, rest)) is not None and fused[-1][2] == "dynamic-update-slice"
+                          and math.prod(dims) < elements))]
+        if shape == SCMOE_POOLS[0]:
+            assert not moved, f"the step moves the pool of latents: {moved}"
+        else:
+            staged = moved
+    assert all(computation != entry for computation, *_ in staged), staged
+    back = [name for _, name, op, dims in staged if op == "copy-done" and math.prod(dims) == math.prod(SCMOE_POOLS[1])]
+    assert len(back) == 1 and len(staged) == 2, f"the rotated keys' pool crosses the chip's fast memory more than once a layer: {staged}"
+    calls = decode_walk_calls(hlo)
+    assert len(calls) == 2 and len({computation for computation, _, _ in calls}) == 1, calls  # two walks in the one layer loop's body
+    for _, op_name, operands in calls:
+        assert "ptu.attn.latent_decode" in op_name and (8, 64, 512) in [dims for _, _, dims in operands], operands  # 64 heads' absorbed queries
+        assert sorted(math.prod(dims) for _, _, dims in operands)[-2:] == sorted(math.prod(shape) for shape in SCMOE_POOLS)
+    hits = hit_calls(hlo)
+    assert len(hits) == 1 and sorted(dims for op, dims in hits[0][1] if len(dims) == 4) == sorted(tuple(runs[0][w].shape) for w in ("w1", "w3", "w2"))
+    assert {op for op, dims in hits[0][1] if len(dims) == 4} <= {"get-tuple-element", "parameter"}
+    fused = {m.group(1) for instructions in comps.values() for _, _, op, rest in instructions
+             if op == "fusion" and (m := re.search(r"calls=%([\w.\-]+)", rest))}
+    zero = [(computation in fused, op) for computation, instructions in comps.items() for _, _, op, rest in instructions if "ptu.moe.zero" in rest]
+    assert zero, "the scope ptu.moe.zero was not found: has the HLO text changed?"
+    assert {op for inside, op in zero if inside} <= {"broadcast", "compare", "convert", "multiply", "reduce", "select", "parameter", "add", "bitcast", "constant"}
+    assert sum(op == "fusion" for inside, op in zero if not inside) == (2 if chunk else 1)  # one a half of the step
+    assert "ptu.scmoe.shortcut" in hlo and not {op for _, op in zero} & {"gather", "scatter", "sort", "while", "dynamic-update-slice"}
+    stacked = {tuple(p.shape) for p in runs[0].values()}
+    relayouts, seen = weight_relayouts(hlo, stacked, math.prod(runs[0]["wkva_0"].shape[1:]))
+    known = {(64, 128, 512), (64, 512, 128)} if chunk else set()
+    relayouts = [r for r in relayouts if tuple(json.loads(r.split(" -> ")[1])) not in known]
+    assert seen and not relayouts, f"the step's loop relays a weight in every layer of every step: {relayouts}"
+    assert not entry_weight_moves(hlo, stacked, math.prod(runs[0]["wkva_0"].shape[1:]))
+
+
+def test_the_absorbed_walk_s_kernel_takes_64_heads_and_the_counters_count_it(v5e, tmp_path):
+    """The walk's kernel alone at the cell's shapes, through Pallas -> Mosaic
+    -> libtpu for the v5e: 8 lanes of 64 heads (``q`` and ``acc`` of ``[64,
+    512]`` float32: twice kanana2's 32), tables of 40 pages, the span's pools
+    of 8 x 320 pages handed whole. And what the batcher is told runs:
+    ``decode_path`` says the kernel for these rows on a TPU backend, and
+    ``latent_reads`` counts each live lane's own blocks, in 8 sub-layers."""
+    import numpy as np
+
+    from perf.config import load as load_config
+    from petals_tpu.ops import latent_attention as latent
+    from petals_tpu.ops.paged_attention import PagedKV
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.from_pretrained import get_block_config
+
+    def walk(q_abs, q_pe, c_pool, pe_pool, tables, positions):
+        return latent.latent_decode_attend(q_abs, q_pe, PagedKV(c_pool, tables), PagedKV(pe_pool, tables), positions, scale=2 * 192**-0.5, path="kernel")
+
+    avals = (v5e((8, 1, 64, 512), BF16), v5e((8, 1, 64, 64), BF16), v5e((8 * 320, 64, 512), BF16), v5e((8 * 320, 32, 128), BF16),
+             v5e((8, 40), I32), v5e((8,), I32))
+    hf = load_config(Path(__file__).resolve().parents[1] / f"perf/configs/{SCMOE}.json", SCMOE)["config"]
+    (tmp_path / "config.json").write_text(json.dumps(hf))
+    family, cfg = get_block_config(str(tmp_path))
+    params = {name: jax.ShapeDtypeStruct((4, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, None, BF16).items()}
+    backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=4, memory_cache=None)
+    contexts = np.array([1100, 1800, 2049, 2560, 1, 64])  # two lanes idle
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(latent, "_on_tpu", lambda: True)  # the backend here is the CPU: the kernel would be interpreted
+        _compile(walk, *avals)
+        assert latent.decode_path(*latent.latent_pool_rows(64, 512, 64), BF16) == "kernel"
+        reads = backend.latent_reads(8, 40, 64, contexts - 1)
+    block = latent.DECODE_KERNEL_PAGES * 64
+    assert reads["latent_rows_read"] == 8 * sum(min(-(-int(ctx) // block) * block, 2 * block) for ctx in contexts)  # a table of 40 pages: two blocks
+    assert reads["latent_rows_held"] == reads["latent_score_pairs"] == 8 * int(contexts.sum()) and reads["latent_rows_absorbed"] == 8 * 6
+    assert backend.latent_reads(8, 40, 64, contexts - 1)["latent_rows_read"] == 8 * 8 * 2560  # off the chip: the composed walk, every lane to the longest

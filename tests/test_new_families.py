@@ -21,6 +21,7 @@ from tests.utils import (
     make_tiny_gemma,
     make_tiny_gemma2,
     make_tiny_jamba,
+    make_tiny_longcat_flash,
     make_tiny_llama,
     make_tiny_mistral,
     make_tiny_mixtral,
@@ -42,7 +43,7 @@ MAKERS = {
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
     "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3, "qwen3_next": make_tiny_qwen3_next,
-    "jamba": make_tiny_jamba,
+    "jamba": make_tiny_jamba, "longcat_flash": make_tiny_longcat_flash,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 
@@ -78,8 +79,8 @@ def test_quantization_applies_to_every_family(family_block, name):
 
     _, family, cfg, params = family_block(name)
     declared = family.quantizable_leaves
-    if (family.block_kind is not None or family.block_index is not None) and not declared:
-        # a family whose blocks are not all alike, or whose pages carry an index row, may declare none yet:
+    if (family.block_kind is not None or family.block_index is not None or family.block_latent is not None) and not declared:
+        # a family whose blocks are not all alike, or whose pages carry an index row or a latent row, may declare none yet:
         # refused by name, never a dense no-op
         with pytest.raises(ValueError, match=name):
             convert_block_params(dict(params), name, "nf4", fuse=True)

@@ -276,7 +276,7 @@ def test_the_page_pool_is_as_deep_as_the_full_layers_and_the_state_pool_as_the_l
     backend = whole_backend(path)
     assert backend.kv_layers == [3, 7] and backend.state_layers == [0, 1, 2, 4, 5, 6] and backend._slots == [0, 1, 2, 0, 3, 4, 5, 1]
     assert [kind for kind, _, _ in backend.runs] == [LINEAR, FULL, LINEAR, FULL]
-    assert backend.moe_dims == (16, 4, 64, 32, 16, 0) and backend.moe_grouped(1) == "hit"  # every run's experts ride the stack
+    assert backend.moe_dims == (16, 4, 64, 32, 16, 0, 0) and backend.moe_grouped(1) == "hit"  # every run's experts ride the stack
     k, v = backend.paged_cache_descriptors(12, 16, 0, 8)
     assert backend.num_kv_heads == 2 and k.shape == v.shape == (2, 12, 16, 2 * 16)  # rows of 2 kv heads of 16, under 128 lanes: folded
     matrix, tail = backend.state_cache_descriptors(3)

@@ -889,6 +889,74 @@ def make_tiny_deepseek_v3(tmpdir: str, **overrides) -> str:
     return path
 
 
+TINY_LONGCAT_FLASH = {  # the keys LongCat-Flash-Chat publishes (transformers' longcat_flash), at a toy size: 2 double layers
+    "model_type": "longcat_flash", "hidden_size": 64, "num_attention_heads": 4, "num_layers": 2, "num_hidden_layers": 4,
+    "q_lora_rank": 32, "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16, "head_dim": 8,
+    "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32, "n_routed_experts": 8, "zero_expert_num": 4, "zero_expert_type": "identity",
+    "moe_topk": 3, "routed_scaling_factor": 6.0, "hidden_act": "silu", "attention_bias": False, "router_bias": False,
+    "mla_scale_q_lora": True, "mla_scale_kv_lora": True, "attention_method": "MLA", "rms_norm_eps": 1e-5, "rope_theta": 10000000,
+    "rope_scaling": None, "max_position_embeddings": 256, "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_longcat_flash_tensors(config: dict, seed: int = 31) -> dict:
+    """Seeded float32 tensors under transformers' ``longcat_flash`` names of
+    every double layer of ``config`` (ALL the FFN experts that exist, whatever
+    share a directory holds), the embedding, the final norm and the head. Norm
+    vectors and the router's bias are drawn, not ones and zeros, so a missing
+    or misplaced one shows; the bias at the scale of a softmax score over the
+    router's width, so that it moves picks and fixes none."""
+    rng = np.random.RandomState(seed)
+    h, heads, dn, dr, dv, latent, rq = (config[k] for k in ("hidden_size", "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim",
+                                                            "v_head_dim", "kv_lora_rank", "q_lora_rank"))
+    m, me, zeros = config["ffn_hidden_size"], config["expert_ffn_hidden_size"], config["zero_expert_num"]
+    n = (config.get("expert_share") or {}).get("routed", config["n_routed_experts"])
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda width: rng.uniform(0.5, 1.5, width).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": norm(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i in range(config["num_layers"]):
+        p = f"model.layers.{i}."
+        for j in (0, 1):
+            a = p + f"self_attn.{j}."
+            tensors.update({
+                p + f"input_layernorm.{j}.weight": norm(h), p + f"post_attention_layernorm.{j}.weight": norm(h),
+                a + "q_a_proj.weight": normal(rq, h) * 3, a + "q_a_layernorm.weight": norm(rq), a + "q_b_proj.weight": normal(heads * (dn + dr), rq),
+                a + "kv_a_proj_with_mqa.weight": normal(latent + dr, h) * 3, a + "kv_a_layernorm.weight": norm(latent),
+                a + "kv_b_proj.weight": normal(heads * (dn + dv), latent), a + "o_proj.weight": normal(h, heads * dv),
+                p + f"mlps.{j}.gate_proj.weight": normal(m, h), p + f"mlps.{j}.up_proj.weight": normal(m, h),
+                p + f"mlps.{j}.down_proj.weight": normal(h, m),
+            })
+        tensors[p + "mlp.router.classifier.weight"] = normal(n + zeros, h) * 3
+        tensors[p + "mlp.router.e_score_correction_bias"] = normal(n + zeros) * 0.3
+        for e in range(n):
+            q = p + f"mlp.experts.{e}."
+            tensors.update({q + "gate_proj.weight": normal(me, h) * 3, q + "up_proj.weight": normal(me, h) * 3, q + "down_proj.weight": normal(h, me) * 3})
+    return tensors
+
+
+def make_tiny_longcat_flash(tmpdir: str, *, held: int = 8, first: int = 0, **overrides) -> str:
+    """A ``longcat_flash`` checkpoint at a toy size, written by hand under
+    transformers' names (tests/test_longcat_flash.py loads the same tensors
+    into transformers' own layer). All 8 FFN experts are in the file, and a
+    server of this directory holds ``held`` of them from ``first`` on
+    (``expert_share``; all of them by default)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = {**TINY_LONGCAT_FLASH, **overrides}
+    tensors = tiny_longcat_flash_tensors(config)
+    if held != 8 or first:
+        config.update(n_routed_experts=held, expert_share={"routed": 8, "first": first})
+    path = os.path.join(tmpdir, f"tiny-longcat-flash-{held}-{first}")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tensors, os.path.join(path, "model.safetensors"))
+    return path
+
+
 TINY_QWEN3_NEXT = {  # the keys Qwen3-Next publishes, at a toy size: two periods of three linear layers and a full one
     "model_type": "qwen3_next", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "partial_rotary_factor": 0.25, "rope_theta": 10000000, "rope_scaling": None, "intermediate_size": 128,
