@@ -9,7 +9,8 @@ A traced run's line carries the thirteen metrics of the traced slice; this scrip
 traced and an untraced run alike, the same readers over the measured window (the children's marks
 ``window`` / ``window_end`` shown to them as the slice's), the server's stations beside them, and
 ``client_direct_step_share`` (PR 55: of the span's decode steps, the share the caller's thread exchanged itself; and of its
-other steps, prompts, ``client_direct_other_share``), and the tiling check: the mean of the load generator's own decode gaps (``SessionRecord.replies``,
+other steps, prompts, ``client_direct_other_share``), ``client_direct_write_share`` (PR 58: of those direct decode steps' frames, the share
+the caller's thread wrote to the socket itself; the rest it left to the loop, ``client_direct_frames_deferred``), and the tiling check: the mean of the load generator's own decode gaps (``SessionRecord.replies``,
 read on the caller's thread after ``step()`` returned) whose reply came inside the span, against
 ``client_turn_ms + client_away_ms`` of the same span. One JSON line on standard output, appended
 to ``chiprun_out/client_trip_table.jsonl``. ``--cpu 1`` adds ``client_cpu_share`` and ``client_cpu_ms_per_step``: this process's
@@ -42,12 +43,18 @@ def direct_shares(decode_rows, lo: float, hi: float) -> dict:
 
     row, ring = getattr(spans, "ROW", ()), getattr(spans, "STEP_RING", None)
     if "direct" not in row or ring is None:
-        return {"client_direct_step_share": None, "client_direct_other_share": None}
+        return {"client_direct_step_share": None, "client_direct_other_share": None, "client_direct_write_share": None}
     at, hops, tokens, direct = (row.index(name) for name in ("read_at", "hops", "tokens", "direct"))
     others = [r[direct] for r in list(ring.rows) if lo <= r[at] <= hi and not (r[hops] == 1 and r[tokens] == 1)]
     mine = [r["direct"] for r in decode_rows]
-    return {"client_direct_step_share": 100.0 * sum(mine) / len(mine) if mine else None,
-            "client_direct_other_share": 100.0 * sum(others) / len(others) if others else None, "other_steps_in_span": len(others)}
+    out = {"client_direct_step_share": 100.0 * sum(mine) / len(mine) if mine else None,
+           "client_direct_other_share": 100.0 * sum(others) / len(others) if others else None, "other_steps_in_span": len(others)}
+    # PR 58: a row's "wrote" counts its hops' frames that the caller's thread wrote to the socket (None on a program without the column)
+    frames = sum(r["hops"] for r in decode_rows if r["direct"]) if "wrote" in row else 0
+    wrote = sum(r["wrote"] for r in decode_rows if r["direct"]) if frames else 0
+    out["client_direct_write_share"] = 100.0 * wrote / frames if frames else None
+    out["client_direct_frames_deferred"] = frames - wrote if frames else None
+    return out
 
 
 def cpu_sampler(samples: list, every: float = 0.05) -> threading.Event:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import random
 import time
 import uuid
 from typing import List, Optional, Sequence
@@ -82,6 +83,8 @@ class _ServerInferenceSession:
         # session's ClientTrip (telemetry/spans.py): stream.send returned
         # (K2), the reply's frame read whole (K3), stream.recv returned (K4)
         self.stations: Optional[tuple] = None
+        # whether the last step_from_thread's frame was written to the socket by the caller's thread (else by the loop)
+        self.wrote = False
 
     @classmethod
     async def create(
@@ -292,10 +295,12 @@ class _ServerInferenceSession:
     def step_from_thread(self, hidden: np.ndarray, step_id: Optional[str]) -> np.ndarray:
         """The same step exchanged by the caller's own thread, which is not the
         loop's: a plain step (no prompts, lane reorder or rollback rides it)
-        whose frame the stream takes whole (``rpc/client.py``)."""
+        whose frame the stream takes whole (``rpc/client.py``) and this thread
+        writes to the socket itself where the connection can take it right now
+        (``wrote``; else the loop writes it, behind what it has to write)."""
         msg = self.build_step(hidden, step_id=step_id)
         t_rpc = time.perf_counter()
-        self.stream.send_from_thread(msg)
+        self.wrote = self.stream.send_from_thread(msg)
         sent_at = time.perf_counter()
         reply = self.stream.recv_in_thread(self.step_timeout)
         return self.accept_reply(hidden, None, reply, t_rpc, sent_at, time.perf_counter())
@@ -600,7 +605,9 @@ class InferenceSession:
         """``step`` for a caller on a thread of its own, where
         ``can_step_from_thread`` allows it: the same hops in the same order,
         each exchanged by this thread (``_ServerInferenceSession.step_from_thread``),
-        so the step crosses to the loop once a hop each way and starts no
+        so the step crosses to the loop once a hop, on the reply's way in (and
+        on the request's way out only where the connection cannot take the
+        frame at once: ``ClientTrip.deferred``), and starts no
         coroutine. The first exchange that raises hands the rest of the step,
         from that hop on, to ``_walk`` on the loop through ``run`` (the
         runtime's): there is one retry loop and one repair.
@@ -611,23 +618,29 @@ class InferenceSession:
         one step at a time runs on a session (the loop touches them only inside
         a step, which this thread then waits for); the ring takes an atomic
         ``append``; the flight recorder and the journal have their locks; the
-        stream's inbox is thread-safe and its frame is written on the loop;
-        the router's tables (``seq_manager``) are read here and written on the
-        loop alone (``_tell_router``)."""
+        stream's inbox is thread-safe; the socket's write side is shared with
+        the loop under the connection's one short lock (``rpc/client.py
+        _Outlet``): frames are whole and in the order they were handed over, a
+        frame that cannot go out whole at once is the loop's to write, and the
+        reader stays on the loop; the router's tables (``seq_manager``) are
+        read here and written on the loop alone (``_tell_router``)."""
         trip = self.trip
         trip.on_loop(time.perf_counter())  # K1 is the build's beginning: nothing is crossed before it
         self._admit(hidden)
-        walk = _Walk(hidden, uuid.uuid4().hex, time.perf_counter())
+        # the step id as uuid4().hex is, without its system call: a place where this thread would give the GIL away mid-build
+        walk = _Walk(hidden, "%032x" % random.getrandbits(128), time.perf_counter())
+        wrote = 0
         while walk.block_idx < self.num_blocks:
             session = None
             try:
                 session = self._session_at(walk.block_idx)
                 outputs = session.step_from_thread(walk.inputs, walk.step_id)
                 self._hop_done(walk, session, outputs)
+                wrote += session.wrote
             except Exception as e:
                 return run(self._walk(walk, failed=(e, session)))
         self._step_done(walk)
-        trip.finished(time.perf_counter(), walk.n_input_tokens, direct=True)
+        trip.finished(time.perf_counter(), walk.n_input_tokens, direct=True, wrote=wrote)
         return walk.inputs
 
     def _tell_router(self, fn, *args) -> None:
@@ -730,6 +743,9 @@ class InferenceSession:
             "tokens": self._tokens,
             # of the session's steps, those the caller's thread exchanged itself (step_from_thread)
             "direct_steps": self.trip.direct,
+            # of those steps' frames (one a hop), those that thread wrote to the socket itself, and those it left to the loop
+            "direct_frames_wrote": self.trip.wrote,
+            "direct_frames_deferred": self.trip.deferred,
             "total": total,
             "peers": per_peer,
         }

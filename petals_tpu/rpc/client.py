@@ -12,6 +12,8 @@ from __future__ import annotations
 import asyncio
 import itertools
 import queue
+import socket
+import threading
 import time
 from typing import Any, AsyncIterator, Optional
 
@@ -31,6 +33,110 @@ _END = object()
 # none is larger than this (a decode step's is 16-33 KB; a prompt's takes
 # ``send``, its lock and its ``drain``).
 THREAD_FRAME_BYTES = 1 << 17
+
+
+class _Outlet:
+    """A connection's write side, which two kinds of writer share: coroutines
+    on the connection's loop (``write_frame`` takes this for its writer) and
+    threads that are not the loop's (``write_from_thread``).
+
+    The ordering rule, whichever thread writes: frames reach the wire whole
+    and in the order they were handed over. One short lock lies around "look
+    at what is buffered, then send or append" on both sides and is held for no
+    blocking call (the socket is non-blocking, ``transport.write`` never
+    waits). A thread writes its frame to the socket itself when the connection
+    can take it whole right now: a plain TCP socket, nothing in the
+    transport's buffer, nothing queued here. Otherwise the frame is queued
+    for the loop, and so is what a partial ``send`` left over, in front of
+    everything later: the loop writes the queue ahead of its own next frame,
+    or on the turn a thread posted for it.
+
+    The socket a thread sends on is a duplicate of the transport's (the
+    public way to one: ``TransportSocket`` offers no ``send``); ``close``
+    gives it back, so that the connection's end is the transport's to say."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop, writer: asyncio.StreamWriter):
+        self._loop, self._writer, self._transport = loop, writer, writer.transport
+        self._lock = threading.Lock()
+        self._queue: list = []  # threads' frames that are the loop's to write, oldest first
+        self._posted = False  # a turn of the loop is on its way for the queue
+        self._sock = self._plain_socket(self._transport)
+
+    @staticmethod
+    def _plain_socket(transport) -> Optional[socket.socket]:
+        """None where a frame cannot be written beside the transport: no
+        socket under it (a test's in-memory pair), TLS over it, not a stream."""
+        if transport.get_extra_info("sslcontext") is not None or not hasattr(transport, "get_write_buffer_size"):
+            return None
+        sock = transport.get_extra_info("socket")
+        if sock is None or sock.type != socket.SOCK_STREAM:
+            return None
+        try:
+            return sock.dup()
+        except OSError:  # no descriptor to spare: the loop writes every frame, as it did
+            return None
+
+    # ------------------------------------------------------------ the loop's side
+
+    def write(self, data: bytes) -> None:
+        with self._lock:
+            self._write_queue()
+            self._writer.write(data)
+
+    async def drain(self) -> None:
+        await self._writer.drain()
+
+    def _flush(self) -> None:
+        with self._lock:
+            self._posted = False
+            self._write_queue()
+
+    def _write_queue(self) -> None:
+        if self._queue:
+            self._writer.write(b"".join(self._queue))
+            self._queue.clear()
+
+    # ------------------------------------------------------------ a thread's side
+
+    def write_from_thread(self, frame: bytes) -> bool:
+        """Whether this thread wrote the whole frame to the socket itself
+        (False: it is the loop's to write, whole or from where ``send``
+        stopped). A ``send`` that fails raises ``RpcError``."""
+        with self._lock:
+            sent = 0
+            if self._sock is not None and not self._queue and self._transport_idle():
+                try:
+                    sent = self._sock.send(frame)
+                except (BlockingIOError, InterruptedError):
+                    pass
+                except OSError as e:
+                    raise RpcError(f"Connection lost: {type(e).__name__}: {e}") from e
+                if sent == len(frame):
+                    return True
+            self._queue.append(frame[sent:] if sent else frame)
+            if not self._posted:
+                try:
+                    self._loop.call_soon_threadsafe(self._flush)
+                except RuntimeError as e:  # the loop is closed
+                    self._queue.clear()
+                    raise RpcError(f"Client connection is closed: {e}") from e
+                self._posted = True
+        return False
+
+    def _transport_idle(self) -> bool:
+        """Under the lock: the transport holds no byte and takes none until the
+        lock is free (only ``write`` above hands it any)."""
+        transport = self._transport
+        try:
+            return not transport.is_closing() and transport.get_write_buffer_size() == 0
+        except RuntimeError:  # its buffer changed under the count: it holds bytes
+            return False
+
+    def close(self) -> None:
+        with self._lock:
+            sock, self._sock = self._sock, None
+        if sock is not None:
+            sock.close()
 
 
 class StreamCall:
@@ -95,12 +201,17 @@ class StreamCall:
         self.read_at = read_at
         return item
 
-    def send_from_thread(self, payload: Any) -> None:
+    def send_from_thread(self, payload: Any) -> bool:
         """``send`` for a caller on a thread that is not the loop's: the frame
-        is packed here and handed to the loop as one ``write`` of a whole
-        frame, which cannot interleave with a coroutine's (``write_frame``
-        writes a whole frame in one call too; its lock orders the drains).
-        It does not wait for ``drain``: hence ``THREAD_FRAME_BYTES``."""
+        is packed here and written to the connection's socket by this thread
+        when the connection can take it whole right now (True), else handed
+        to the loop, which writes it on a turn of its own (False): a prompt's
+        frame is draining, a ``send`` came up short, the transport offers no
+        plain socket. Either way frames reach the wire whole and in the order
+        they were handed over, a coroutine's ``write_frame`` among them
+        (``_Outlet``: its one lock and its rule). It does not wait for
+        ``drain``: hence ``THREAD_FRAME_BYTES``. A ``send`` that fails raises
+        ``RpcError``, as a connection that is gone does."""
         client = self._client
         if self._closed:
             raise RpcError("Stream is closed")
@@ -109,10 +220,7 @@ class StreamCall:
         frame = encode_frame({"t": "sitem", "id": self._call_id, "payload": payload})
         if len(frame) > THREAD_FRAME_BYTES:
             raise ValueError(f"A frame of {len(frame)} bytes takes send(): over {THREAD_FRAME_BYTES}")
-        try:
-            client._loop.call_soon_threadsafe(client._writer.write, frame)
-        except RuntimeError as e:  # the loop is closed
-            raise RpcError(f"Client connection is closed: {e}") from e
+        return client._outlet.write_from_thread(frame)
 
     def recv_in_thread(self, timeout: Optional[float] = None) -> Any:
         """``recv`` for the same caller: parks the thread on the inbox."""
@@ -152,7 +260,8 @@ class RpcClient:
         import secrets
 
         self._reader, self._writer = reader, writer
-        self._loop = asyncio.get_running_loop()  # where StreamCall.send_from_thread posts its frame
+        self._loop = asyncio.get_running_loop()
+        self._outlet = _Outlet(self._loop, writer)  # every frame's way out, a coroutine's and a thread's
         self._identity = identity
         self._peer_id = identity.peer_id if identity is not None else peer_id
         self._nonce = secrets.token_bytes(16)
@@ -266,7 +375,7 @@ class RpcClient:
     async def _send(self, message: Any) -> None:
         if self._closed:
             raise RpcError("Client connection is closed")
-        await write_frame(self._writer, message, self._write_lock)
+        await write_frame(self._outlet, message, self._write_lock)
 
     async def call(self, method: str, payload: Any = None, timeout: Optional[float] = None) -> Any:
         if chaos.ENABLED:
@@ -342,6 +451,7 @@ class RpcClient:
             error = RpcError(f"Client read loop crashed: {e}")
         finally:
             self._closed = True
+            self._outlet.close()
             # unblock connect(): a connection that died mid-handshake should
             # fail immediately (connect checks _closed), not wait out the timeout
             self._handshake_done.set()
@@ -360,6 +470,7 @@ class RpcClient:
             await self._loop_task
         except asyncio.CancelledError:
             pass
+        self._outlet.close()
         self._writer.close()
         try:
             await self._writer.wait_closed()

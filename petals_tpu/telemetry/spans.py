@@ -49,13 +49,20 @@ crossing), K2 ``stream.send`` returned (the frame written and drained), K4
 (``wake_s`` is the second crossing). **The direct way**
 (``InferenceSession.step_from_thread``, a sync caller's steady-state step):
 the caller's thread does it all, so K1 follows K0 at once (``submit_s`` ~0:
-no crossing before the build), K2 is the packed frame handed to the loop
-(``build_s``; the loop's delay until it is written lies in ``away_s``; a reply
-read before the thread had read K2 makes K2 = K3), K4 is
+no crossing before the build), K2 is the packed frame handed to the socket or
+to the loop (``build_s``). Since PR 58 the thread writes the frame to the
+connection's socket itself when the connection can take it whole right now (a
+plain TCP socket, nothing buffered, nothing queued: ``rpc/client.py _Outlet``)
+and K2 is then the ``send`` returned; otherwise (a prompt's frame draining, a
+short ``send``, no plain socket) the frame is the loop's to write, behind what
+it holds, and the loop's delay until it is written lies in ``away_s``.
+``ClientTrip.wrote`` / ``deferred`` count a direct step's frames each way, a
+row's last column (``wrote``) those its thread wrote. A reply
+read before the thread had read K2 makes K2 = K3. K4 is
 the caller's thread holding the item (``recv_s`` is now the reader's unpacking
 and the one loop-to-thread crossing), and K6 follows K5 on the same thread
-(``wake_s`` ~0). ``ClientTrip.direct`` counts those steps and a row's last
-column marks one.
+(``wake_s`` ~0). ``ClientTrip.direct`` counts those steps and a row's
+``direct`` column marks one.
 
 ``away_s`` runs from K2 to K3 (the wire, the server, the wire, this loop's
 lateness on the ready socket); a caller of the async session has K6 = K5 and
@@ -80,7 +87,8 @@ COMPONENTS = ("network", "queue", "compute", "serialize", "other")
 # a step's stretches in the order a row holds them, after ROW_HEAD
 CLIENT_STRETCHES = ("away_s", "recv_s", "finish_s", "wake_s", "user_s", "submit_s", "build_s", "relay_s")
 ROW_HEAD = ("read_at", "trace_id", "step", "hops", "tokens")  # K3, the session, its step's number, hops, tokens in
-ROW = (*ROW_HEAD, *CLIENT_STRETCHES, "direct")  # last: 1 for a step taken the direct way, 0 for a coroutine's
+# direct: 1 for a step taken the direct way, 0 for a coroutine's; wrote: of its hops' frames, those the caller's thread wrote to the socket
+ROW = (*ROW_HEAD, *CLIENT_STRETCHES, "direct", "wrote")
 _WAKE, _USER, _SUBMIT, _BUILD = (ROW.index(name) for name in ("wake_s", "user_s", "submit_s", "build_s"))
 # eight lanes' steps of a benchmark window (51 s of 6 ms gaps); a row is ~0.4 KB
 STEP_RING_ROWS = 65536
@@ -226,7 +234,7 @@ class ClientTrip:
     touches a trip, on the caller's thread (K0, K6; every station of a direct
     step) or the loop's."""
 
-    __slots__ = ("trace_id", "ring", "steps", "turns", "direct", "sums", "_t", "_row", "_entered", "_user", "_submit",
+    __slots__ = ("trace_id", "ring", "steps", "turns", "direct", "wrote", "deferred", "sums", "_t", "_row", "_entered", "_user", "_submit",
                  "_hops", "_away", "_relay", "_held_at")
 
     def __init__(self, trace_id: Optional[str], ring: Optional[StepRing] = None):
@@ -235,6 +243,8 @@ class ClientTrip:
         self.steps = 0  # replies handed to the caller
         self.turns = 0  # of them, those a next request followed: what user_s, submit_s and build_s were summed over
         self.direct = 0  # of them, those the caller's thread exchanged itself (InferenceSession.step_from_thread)
+        # of the direct steps' frames (one a hop): written to the socket by that thread; left to the loop to write
+        self.wrote = self.deferred = 0
         self.sums = dict.fromkeys(CLIENT_STRETCHES, 0.0)
         self._t: Optional[float] = None
         self._row: Optional[list] = None  # the last step's row, until the next request is written
@@ -277,20 +287,24 @@ class ClientTrip:
         self._t, self._held_at = read_at, held_at
         self._hops += 1
 
-    def finished(self, now: float, tokens: int, direct: bool = False) -> None:
-        """K5: the step's row goes into the ring."""
+    def finished(self, now: float, tokens: int, direct: bool = False, wrote: int = 0) -> None:
+        """K5: the step's row goes into the ring. ``wrote``: of a direct step's
+        frames, those its thread wrote to the socket itself."""
         if not self._hops:
             return
         recv, finish = self._held_at - self._t, now - self._held_at
         row = [self._t, self.trace_id, self.steps, self._hops, tokens,
-               self._away, recv, finish, None, None, None, None, self._relay, int(direct)]
+               self._away, recv, finish, None, None, None, None, self._relay, int(direct), wrote]
         sums = self.sums
         sums["away_s"] += self._away
         sums["recv_s"] += recv
         sums["finish_s"] += finish
         sums["relay_s"] += self._relay
         self.steps += 1
-        self.direct += direct
+        if direct:
+            self.direct += 1
+            self.wrote += wrote
+            self.deferred += self._hops - wrote
         self.ring.rows.append(row)
         self._row, self._t = row, now
 
@@ -309,7 +323,7 @@ class ClientTrip:
 
     def report(self) -> dict:
         return {**{k: round(v, 6) for k, v in self.sums.items()}, "steps": self.steps, "turns": self.turns,
-                "direct": self.direct}
+                "direct": self.direct, "wrote": self.wrote, "deferred": self.deferred}
 
 
 def build_trace_report(

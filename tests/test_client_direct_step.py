@@ -11,6 +11,7 @@ repaired); the second drives a stream of ``rpc/`` alone against
 
 import asyncio
 import contextlib
+import socket
 import threading
 import time
 
@@ -127,20 +128,49 @@ def test_the_direct_way_and_the_coroutine_give_the_same_step(swarm, remote_of, c
         assert [mine[k] for k in ("step", "hops", "tokens")] == [theirs[k] for k in ("step", "hops", "tokens")]
 
 
-def _step_frames(sync_session, hop=0):
-    """Record what the connection of ``hop``'s stream writes from now on; gives the list the frames go to."""
-    stream = sync_session._session._sessions[hop].stream
-    writer, frames = stream._client._writer, []
-    real = writer.write
+def _tap(stream, on_frame):
+    """Every frame the connection of ``stream`` is handed from now on goes through ``on_frame(message) -> bool`` (whether it
+    goes on to the wire), whoever writes it: the loop (``writer.write``, an instance attribute over the class's method) or a
+    caller's thread (the outlet's socket). Gives the undo."""
+    client = stream._client
+    writer, outlet = client._writer, client._outlet
+    real_write, real_sock = writer.write, outlet._sock
 
-    def write(data):
-        message = decode_frame(bytes(data)[4:])
+    def kept(data) -> bool:
+        data, keep = bytes(data), True
+        while data:  # whole frames, one after another
+            size = 4 + int.from_bytes(data[:4], "big")
+            keep, data = on_frame(decode_frame(data[4:size])) and keep, data[size:]
+        return keep
+
+    class Socket:
+        def send(self, data):
+            return real_sock.send(data) if kept(data) else len(data)
+
+        def close(self):
+            real_sock.close()
+
+    writer.write = lambda data: real_write(data) if kept(data) else None
+    outlet._sock = Socket()
+
+    def undo():
+        writer.__dict__.pop("write", None)
+        if outlet._sock is not None:  # not closed meanwhile
+            outlet._sock = real_sock
+
+    return undo
+
+
+def _step_frames(sync_session, hop=0):
+    """Record what the connection of ``hop``'s stream is handed for that stream from now on; gives the list the frames go to."""
+    stream, frames = sync_session._session._sessions[hop].stream, []
+
+    def on_frame(message):
         if message.get("t") == "sitem" and message.get("id") == stream._call_id:
             frames.append(message)
-        real(data)
+        return True
 
-    writer.write = write  # an instance attribute over the class's method, taken off by the caller
-    return frames, lambda: writer.__dict__.pop("write", None)
+    return frames, _tap(stream, on_frame)
 
 
 def test_a_step_s_frame_on_the_wire_is_the_coroutine_s_but_for_its_random_id(swarm, remote_of, coroutine_only):
@@ -298,18 +328,15 @@ def test_a_direct_step_s_stretches_are_numbers_tile_and_cross_nothing_before_the
 
 def _swallow_next_step(sync_session, hop):
     """The next request of ``hop``'s stream never reaches the wire: whoever sent it stays parked. Gives the event set at the swallow."""
-    stream = sync_session._session._sessions[hop].stream
-    writer, swallowed = stream._client._writer, threading.Event()
-    real = writer.write
+    stream, swallowed = sync_session._session._sessions[hop].stream, threading.Event()
 
-    def write(data):
-        message = decode_frame(bytes(data)[4:])
+    def on_frame(message):
         if not swallowed.is_set() and message.get("t") == "sitem" and message.get("id") == stream._call_id:
             swallowed.set()
-            return
-        real(data)
+            return False
+        return True
 
-    writer.write = write
+    _tap(stream, on_frame)
     return swallowed
 
 
@@ -548,3 +575,189 @@ def test_eight_threads_frames_stay_whole_beside_a_coroutine_s_32_mb_frame(wired)
     assert all(echo.seen[k] == list(range(rounds)) for k in range(lanes)) and echo.seen[lanes] == [0]
     during = sum(span["t0"] < t < span["t1"] for lane_sends in sends for t in lane_sends)
     assert during >= 1, (span, "no decode frame was handed over while the prompt's drain was pending")
+
+
+# ------------------------------------------------- who writes a thread's frame (PR 58)
+
+
+def test_a_frame_goes_to_the_loop_while_the_transport_holds_bytes(wired):
+    """A coroutine's 32 MB frame is draining: a thread's frame handed over meanwhile is not written beside it (``False``) but
+    appended behind it by the loop; with the buffer empty again the thread writes its own (``True``)."""
+    runtime, client, echo = wired
+    big, transport = 32 << 20, client._writer.transport
+    prompt_stream, stream = runtime.run(client.open_stream("echo")), runtime.run(client.open_stream("echo"))
+    assert stream.send_from_thread(_item(0, 0)) is True and _checks(stream.recv_in_thread(30), 0, 0)
+
+    async def prompt():
+        await prompt_stream.send(_item(1, 0, size=big))
+        return await prompt_stream.recv(timeout=120)
+
+    future = asyncio.run_coroutine_threadsafe(prompt(), runtime.loop)
+    while transport.get_write_buffer_size() == 0 and not future.done():
+        time.sleep(0.0002)
+    assert not future.done(), "test setup: the prompt's frame was gone before a thread could meet it"
+    under_the_drain = stream.send_from_thread(_item(0, 1))
+    assert _checks(stream.recv_in_thread(120), 0, 1)  # behind the 32 MB, whole
+    assert under_the_drain is False
+    assert _checks(future.result(300), 1, 0, size=big) and echo.seen[0] == [0, 1] and echo.seen[1] == [0]
+    assert stream.send_from_thread(_item(0, 2)) is True and _checks(stream.recv_in_thread(30), 0, 2)  # and direct again after it
+
+
+class _CutSocket:
+    """The outlet's socket, whose first ``send`` takes ``keep`` bytes only."""
+
+    def __init__(self, real, keep):
+        self.real, self.keep, self.calls = real, keep, []
+
+    def send(self, data):
+        sent = self.real.send(data[: self.keep] if not self.calls else data)
+        self.calls.append((len(data), sent))
+        return sent
+
+    def close(self):
+        self.real.close()
+
+
+@pytest.mark.parametrize("how", ["cut-by-hand", "small-sndbuf"])
+def test_a_partial_send_s_remainder_goes_out_in_front_of_every_later_frame(wired, how):
+    """Frames handed over back to back from a thread, none waited for: whatever a ``send`` leaves is the loop's to write, and
+    every later frame (the same thread's, which finds the queue not empty) goes behind it: whole frames, in order."""
+    runtime, client, echo = wired
+    outlet, n, size = client._outlet, 24, 96 << 10
+    stream = runtime.run(client.open_stream("echo"))
+    first = 0
+    if how == "cut-by-hand":
+        outlet._sock = cut = _CutSocket(outlet._sock, keep=1000)
+        held, release = threading.Event(), threading.Event()
+        runtime.loop.call_soon_threadsafe(lambda: (held.set(), release.wait(60)))  # the loop cannot write what it is left
+        assert held.wait(60)
+        try:
+            wrote = [stream.send_from_thread(_item(3, k, size=size)) for k in range(2)]
+        finally:
+            release.set()
+        assert [sent for _, sent in cut.calls] == [1000] and wrote[0] is False  # one send, cut short: the remainder is the loop's
+        assert wrote[1] is False  # and the frame right behind it waits its turn, whatever the socket could take
+        first = 2
+    else:
+        client._writer.get_extra_info("socket").setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+    for k in range(first, n):
+        stream.send_from_thread(_item(3, k, size=size))
+    replies = [stream.recv_in_thread(120) for _ in range(n)]
+    assert all(_checks(reply, 3, k, size=size) for k, reply in enumerate(replies)) and echo.seen[3] == list(range(n))
+    assert stream.send_from_thread(_item(3, n, size=size)) in (True, False) and _checks(stream.recv_in_thread(120), 3, n, size=size)
+
+
+def test_a_transport_without_a_plain_socket_defers_every_frame(wired):
+    from petals_tpu.rpc.client import _Outlet
+
+    class NoSocket:
+        def __init__(self, **info):
+            self.info = info
+
+        def get_extra_info(self, name, default=None):
+            return self.info.get(name, default)
+
+        def get_write_buffer_size(self):
+            return 0
+
+    class Datagram:
+        type = 2  # socket.SOCK_DGRAM
+
+    assert _Outlet._plain_socket(NoSocket()) is None  # an in-memory pair
+    assert _Outlet._plain_socket(NoSocket(socket=object(), sslcontext=object())) is None  # TLS over it
+    assert _Outlet._plain_socket(NoSocket(socket=Datagram())) is None
+    runtime, client, echo = wired
+    assert client._outlet._sock is not None  # loopback TCP: a plain socket
+    client._outlet.close()  # the same connection with none to write beside the transport
+    stream = runtime.run(client.open_stream("echo"))
+    for n in range(4):
+        assert stream.send_from_thread(_item(5, n)) is False
+        assert _checks(stream.recv_in_thread(30), 5, n)
+    assert echo.seen[5] == [0, 1, 2, 3]
+
+
+def test_the_frame_a_thread_writes_is_byte_for_byte_the_loop_s(wired):
+    runtime, client, echo = wired
+    outlet, stream = client._outlet, runtime.run(client.open_stream("echo"))
+    by_thread, by_loop = [], []
+    real_sock, real_write = outlet._sock, client._writer.write
+
+    class Recorded:
+        def send(self, data):
+            by_thread.append(bytes(data))
+            return real_sock.send(data)
+
+        def close(self):
+            real_sock.close()
+
+    client._writer.write = lambda data: (by_loop.append(bytes(data)), real_write(data))[1]
+    try:
+        outlet._sock = Recorded()
+        assert stream.send_from_thread(_item(6, 0)) is True and _checks(stream.recv_in_thread(30), 6, 0)
+        outlet._sock = None
+        assert stream.send_from_thread(_item(6, 0)) is False and _checks(stream.recv_in_thread(30), 6, 0)
+    finally:
+        client._writer.__dict__.pop("write", None)
+        outlet._sock = real_sock
+    assert len(by_thread) == len(by_loop) == 1 and by_thread[0] == by_loop[0]
+    assert decode_frame(by_thread[0][4:]) == {"t": "sitem", "id": stream._call_id, "payload": _item(6, 0)}
+
+
+def test_a_deferred_frame_is_counted_deferred_and_a_written_one_wrote(swarm, remote_of, monkeypatch):
+    from petals_tpu.rpc.client import _Outlet
+
+    remote = remote_of(4)
+    with remote.inference_session(max_length=16) as session:
+        inner = session._session
+        session.step(_hidden(swarm, 3))
+        session.step(_hidden(swarm, 1, seed=1))
+        assert (inner.trip.direct, inner.trip.wrote, inner.trip.deferred) == (1, 2, 0)  # two hops, two frames, both this thread's
+        with monkeypatch.context() as patch:
+            patch.setattr(_Outlet, "_transport_idle", lambda self: False)  # as if every transport held bytes
+            session.step(_hidden(swarm, 1, seed=2))
+        assert (inner.trip.direct, inner.trip.wrote, inner.trip.deferred) == (2, 2, 2)
+        session.step(_hidden(swarm, 1, seed=3))
+        trace, usage = session.trace_report(), inner.usage_report()
+        report = trace["client"]
+        assert (report["direct"], report["wrote"], report["deferred"]) == (3, 4, 2)
+        assert (usage["direct_steps"], usage["direct_frames_wrote"], usage["direct_frames_deferred"]) == (3, 4, 2)
+        assert [(r["direct"], r["wrote"]) for r in _rows_of(trace["trace_id"])] == [(0, 0), (1, 2), (1, 0), (1, 2)]
+
+
+def test_a_send_that_raises_fails_the_hop_once_and_bans_as_the_coroutine_s_failure_does(swarm, remote_of, monkeypatch):
+    """The second hop's socket refuses the caller's thread's ``send``: ``RpcError`` out of ``send_from_thread``, the rest
+    of the step on the loop, one failure told of that peer, one repair at that hop; the first hop is not asked again."""
+    remote = remote_of(4, use_server_to_server=False)
+    with remote.inference_session(max_length=16) as reference:
+        want = [reference.step(_hidden(swarm, 4))] + [reference.step(_hidden(swarm, 1, seed=t + 1)) for t in range(3)]
+    with remote.inference_session(max_length=16) as session:
+        inner = session._session
+        got = [session.step(_hidden(swarm, 4)), session.step(_hidden(swarm, 1, seed=1))]
+        first, second = inner._sessions
+        failures = _count_calls(monkeypatch, remote.sequence_manager, "on_request_failure")
+        hop_failures = _count_calls(monkeypatch, inner, "_hop_failed")
+        repairs = _count_calls(monkeypatch, inner, "_repair_chain")
+        outlet = second.stream._client._outlet
+        real_sock = outlet._sock
+
+        class Broken:
+            def send(self, data):
+                raise BrokenPipeError(32, "Broken pipe")
+
+            def close(self):
+                real_sock.close()
+
+        outlet._sock = Broken()
+        try:
+            got.append(session.step(_hidden(swarm, 1, seed=2)))
+        finally:
+            if outlet._sock is not None:
+                outlet._sock = real_sock
+        assert len(hop_failures) == 1 and isinstance(hop_failures[0][1], RpcError) and "BrokenPipeError" in str(hop_failures[0][1])
+        assert failures == [(second.span.peer_id,)] and repairs == [(2,)]
+        assert inner._sessions[0] is first and first.hop.steps == 3 and inner._sessions[1] is not second
+        assert (inner.trip.steps, inner.trip.direct) == (3, 1)  # the failed step was finished by the coroutine
+        got.append(session.step(_hidden(swarm, 1, seed=3)))
+        assert (inner.trip.steps, inner.trip.direct) == (4, 2)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(a, b, atol=1e-5, rtol=0)

@@ -84,8 +84,10 @@ def test_a_sync_session_s_stretches_tile_its_time_reply_to_reply(swarm):
     assert all(r["user_s"] >= think for r in rows[:-1])
     # the session's sums are the rows' columns, each stretch counted for the steps that have it
     client = report["client"]
-    assert set(client) == {*CLIENT_STRETCHES, "steps", "turns", "direct"} and (client["steps"], client["turns"]) == (n + 1, n)
+    assert set(client) == {*CLIENT_STRETCHES, "steps", "turns", "direct", "wrote", "deferred"} and (client["steps"], client["turns"]) == (n + 1, n)
     assert client["direct"] == n and [r["direct"] for r in rows] == [0] + [1] * n  # a first step is a coroutine's (PR 55)
+    # and of the direct steps' frames (one hop each), each is written by the caller's thread or left to the loop (PR 58)
+    assert client["wrote"] + client["deferred"] == n and [r["wrote"] for r in rows][0] == 0 and sum(r["wrote"] for r in rows) == client["wrote"]
     for k in CLIENT_STRETCHES:
         assert client[k] == pytest.approx(sum(r[k] for r in rows if r[k] is not None), abs=1e-5)
     # seen from outside: a hop's wall (the send included) holds what was away, and what the client held the reply for
@@ -187,7 +189,7 @@ def test_the_trip_s_stretches_on_hand_worked_readings():
                                                        "relay_s": pytest.approx(0.004), "user_s": None, "submit_s": None, "build_s": None}
     assert third["hops"] == 2 and third["read_at"] == pytest.approx(t - 0.056)  # the LAST hop's K3
     assert trip.report() == {"away_s": 0.016, "recv_s": 0.024, "finish_s": 0.048, "wake_s": 0.096, "user_s": 0.128, "submit_s": 0.002,
-                             "build_s": 0.004, "relay_s": 0.004, "steps": 3, "turns": 2, "direct": 0}
+                             "build_s": 0.004, "relay_s": 0.004, "steps": 3, "turns": 2, "direct": 0, "wrote": 0, "deferred": 0}
     trip.interrupt()  # a server-side generation: the open turn is dropped, the next step closes none
     _step(trip, t + 5.0)
     assert trip.turns == 2 and trip.sums["user_s"] == pytest.approx(0.128) and list(ring.rows)[2][ROW.index("user_s")] is None
