@@ -32,6 +32,14 @@ class PTuneMixin:
         self.deep_prompt_embeddings: Optional[jnp.ndarray] = None
         if self.ptune.tuning_mode is None or self.ptune.pre_seq_len == 0:
             return
+        family = getattr(self, "family", None)
+        if family is not None and family.block_stream is not None:
+            raise NotImplementedError(
+                f"{family.name}: prompt tuning ({self.ptune.tuning_mode}) is not served for a family whose hidden state "
+                f"between blocks is a stream wider than the model ({family.stream_for(self.cfg)[0]} against "
+                f"{self.cfg.hidden_size}): a trained prompt is a row of hidden_size, and where it enters the stream's "
+                f"rows is the model's to say"
+            )
         key = jax.random.PRNGKey(seed)
         scale = 1.0 / np.sqrt(self.cfg.hidden_size)
         self.prompt_embeddings = (

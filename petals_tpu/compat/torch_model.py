@@ -100,6 +100,12 @@ class TorchDistributedModelForCausalLM(torch.nn.Module):
 
     def __init__(self, native: DistributedModelForCausalLM, *, pre_seq_len: int = 0):
         super().__init__()
+        if native.family.block_stream is not None:
+            raise NotImplementedError(
+                f"{native.family.name}: the torch surface is not served for a family whose hidden state between blocks is "
+                f"a stream wider than the model ({native.family.stream_for(native.cfg)[0]} against "
+                f"{native.cfg.hidden_size}): its soft prompts and its callers' hooks take rows of hidden_size"
+            )
         self.native = native
         self.cfg = native.cfg
         self.blocks = TorchRemoteSequential(native.remote)

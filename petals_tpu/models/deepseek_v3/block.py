@@ -67,6 +67,7 @@ class LatentDims(NamedTuple):
     norm_eps: float  # of the norm over the latent, and over the low-rank query where there is one
     q_scale: float = 1.0  # on the whole query (a low-rank query's ``sqrt(hidden / q_lora_rank)``)
     kv_scale: float = 1.0  # on the normed latent BEFORE ``kv_b_proj`` (``sqrt(hidden / kv_lora_rank)``): the cached row is the scaled one
+    rope_scaling: Optional[tuple] = None  # the published ``rope_scaling``'s items (ops/rotary.py: yarn); its softmax ``mscale^2`` rides ``q_scale``
 
 
 def latent_attention(params: dict, x: jnp.ndarray, kv, position, dims: LatentDims, *, n_valid=None, who: str = "deepseek_v3"):
@@ -93,7 +94,10 @@ def latent_attention(params: dict, x: jnp.ndarray, kv, position, dims: LatentDim
     c = rms_norm(row[..., :latent], params["kv_norm"], dims.norm_eps)
     if dims.kv_scale != 1.0:
         c = (c.astype(jnp.float32) * dims.kv_scale).astype(c.dtype)
-    cos, sin = rotary_tables(absolute_positions(position, batch, seq), dr, theta=dims.rope_theta)
+    cos, sin = rotary_tables(
+        absolute_positions(position, batch, seq), dr, theta=dims.rope_theta,
+        rope_scaling=None if dims.rope_scaling is None else dict(dims.rope_scaling),
+    )
     q_pe = apply_rotary(q_pe, cos, sin)
     k_pe = apply_rotary(row[..., None, latent:], cos, sin)[:, :, 0]
 
@@ -138,6 +142,19 @@ def moe_dims(cfg: DeepseekV3BlockConfig, kind: str) -> Optional[MoeDims]:
     return MoeDims(cfg.num_experts, cfg.num_experts_per_tok, cfg.hidden_size, cfg.moe_intermediate_size)
 
 
+def feed_forward(params: dict, x: jnp.ndarray, cfg: DeepseekV3BlockConfig, kind: str, *, tp_mesh=None, live_rows=None) -> jnp.ndarray:
+    """The block's feed-forward half over the normed rows ``x``: a dense
+    layer's SwiGLU, or a sparse layer's routed experts beside the shared one
+    (``xing4_0``'s too, inside its stream's wrap)."""
+    if kind == DENSE:
+        return mm(silu(mm(x, params["wg"])) * mm(x, params["wu"]), params["wd"])
+    return moe_apply(
+        params, x, top_k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob,
+        dispatch=choose_dispatch(params, moe_dims(cfg, kind), x.shape[1], mesh=tp_mesh is not None),
+        scoring="sigmoid", scale=cfg.routed_scaling_factor, live_rows=live_rows,
+    )
+
+
 def block_apply(
     params: dict,
     hidden_states: jnp.ndarray,
@@ -151,22 +168,11 @@ def block_apply(
     n_valid=None,
     live_rows=None,  # bool [batch] from a lane pool's step: the rows that are not idle lanes (None: all)
 ) -> Tuple[jnp.ndarray, Optional[tuple]]:
-    seq = hidden_states.shape[1]
     x = rms_norm(hidden_states, params["ln1"], cfg.rms_norm_eps)
     attn, new_kv = latent_attention(params, x, kv, position, latent_dims(cfg), n_valid=n_valid)
     hidden_states = hidden_states + attn
-
-    residual = hidden_states
     x = rms_norm(hidden_states, params["ln2"], cfg.rms_norm_eps)
-    if kind == DENSE:
-        mlp = mm(silu(mm(x, params["wg"])) * mm(x, params["wu"]), params["wd"])
-    else:
-        mlp = moe_apply(
-            params, x, top_k=cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob,
-            dispatch=choose_dispatch(params, moe_dims(cfg, kind), seq, mesh=tp_mesh is not None),
-            scoring="sigmoid", scale=cfg.routed_scaling_factor, live_rows=live_rows,
-        )
-    return residual + mlp, new_kv
+    return hidden_states + feed_forward(params, x, cfg, kind, tp_mesh=tp_mesh, live_rows=live_rows), new_kv
 
 
 # ----------------------------------------------------------------------------------
@@ -188,35 +194,54 @@ def fold_rope_columns(w: np.ndarray, plain: int) -> np.ndarray:
     return np.concatenate([w[..., :plain], w[..., plain:][..., rope_halves(w.shape[-1] - plain)]], axis=-1)
 
 
-def hf_to_block_params(tensors: dict, cfg: DeepseekV3BlockConfig, kind: str) -> dict:
+def attention_params(tensors: dict, cfg: DeepseekV3BlockConfig) -> dict:
+    """The leaves ``latent_attention`` reads, from ``self_attn.*`` of a layer:
+    one query matrix (``wq``) or, where the checkpoint has ``q_a_proj``, the
+    low-rank query's two and the norm between them (``xing4_0``'s)."""
     heads, dn, dr, dv, latent = cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
 
     def t(name):
         return np.ascontiguousarray(np.asarray(tensors[name]).T)
 
-    wq, wkva = t("self_attn.q_proj.weight"), t("self_attn.kv_a_proj_with_mqa.weight")
+    low_rank = "self_attn.q_a_proj.weight" in tensors
+    wq, wkva = t("self_attn.q_b_proj.weight" if low_rank else "self_attn.q_proj.weight"), t("self_attn.kv_a_proj_with_mqa.weight")
     if cfg.rope_interleave:  # pairs (2j, 2j + 1) to halves, on both sides of q_pe . k_pe
         wq = np.ascontiguousarray(fold_rope_columns(wq.reshape(-1, heads, dn + dr), dn).reshape(-1, heads * (dn + dr)))
         wkva = np.ascontiguousarray(fold_rope_columns(wkva, latent))
     wkvb = np.asarray(tensors["self_attn.kv_b_proj.weight"]).reshape(heads, dn + dv, latent)  # a head: [k_nope | v] x latent
-    params = {
-        "ln1": np.asarray(tensors["input_layernorm.weight"]),
-        "wq": wq,
+    query = {"wqa": t("self_attn.q_a_proj.weight"), "q_norm": np.asarray(tensors["self_attn.q_a_layernorm.weight"]), "wqb": wq} if low_rank else {"wq": wq}
+    return {
+        **query,
         "wkva": wkva,
         "kv_norm": np.asarray(tensors["self_attn.kv_a_layernorm.weight"]),
         "wuk": np.ascontiguousarray(wkvb[:, :dn]),  # [H, dn, latent]
         "wuv": np.ascontiguousarray(wkvb[:, dn:].transpose(0, 2, 1)),  # [H, latent, dv]
         "wo": t("self_attn.o_proj.weight"),
-        "ln2": np.asarray(tensors["post_attention_layernorm.weight"]),
     }
+
+
+def hf_to_block_params(tensors: dict, cfg: DeepseekV3BlockConfig, kind: str) -> dict:
+    return {
+        "ln1": np.asarray(tensors["input_layernorm.weight"]),
+        **attention_params(tensors, cfg),
+        "ln2": np.asarray(tensors["post_attention_layernorm.weight"]),
+        **feed_forward_params(tensors, cfg, kind),
+    }
+
+
+def feed_forward_params(tensors: dict, cfg: DeepseekV3BlockConfig, kind: str) -> dict:
+    """The leaves ``feed_forward`` reads, from ``mlp.*`` of a layer of ``kind``."""
+
+    def t(name):
+        return np.ascontiguousarray(np.asarray(tensors[name]).T)
+
     if kind == DENSE:
-        params.update(wg=t("mlp.gate_proj.weight"), wu=t("mlp.up_proj.weight"), wd=t("mlp.down_proj.weight"))
-        return params
+        return dict(wg=t("mlp.gate_proj.weight"), wu=t("mlp.up_proj.weight"), wd=t("mlp.down_proj.weight"))
 
     def stack(proj):
         return np.stack([t(f"mlp.experts.{e}.{proj}.weight") for e in range(cfg.num_experts)])
 
-    params.update(
+    params = dict(
         gate=t("mlp.gate.weight"),
         gate_bias=np.asarray(tensors["mlp.gate.e_score_correction_bias"], np.float32),
         w1=stack("gate_proj"), w2=stack("down_proj"), w3=stack("up_proj"),
@@ -230,22 +255,32 @@ def hf_to_block_params(tensors: dict, cfg: DeepseekV3BlockConfig, kind: str) -> 
     return params
 
 
-def block_param_shapes(cfg: DeepseekV3BlockConfig, kind: str, dtype=jnp.bfloat16) -> dict:
+def attention_shapes(cfg: DeepseekV3BlockConfig, dtype=jnp.bfloat16) -> dict:
+    """``attention_params``' leaves: a low-rank query's where ``cfg`` has a ``q_lora_rank``."""
     h, heads, dn, dr, dv, latent = (
         cfg.hidden_size, cfg.num_attention_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank,
     )
-    S = jax.ShapeDtypeStruct
-    shapes = {
-        "ln1": S((h,), dtype), "wq": S((h, heads * (dn + dr)), dtype), "wkva": S((h, latent + dr), dtype),
-        "kv_norm": S((latent,), dtype), "wuk": S((heads, dn, latent), dtype), "wuv": S((heads, latent, dv), dtype),
-        "wo": S((heads * dv, h), dtype), "ln2": S((h,), dtype),
+    S, rq = jax.ShapeDtypeStruct, getattr(cfg, "q_lora_rank", None)
+    query = {"wqa": S((h, rq), dtype), "q_norm": S((rq,), dtype), "wqb": S((rq, heads * (dn + dr)), dtype)} if rq else {"wq": S((h, heads * (dn + dr)), dtype)}
+    return {
+        **query, "wkva": S((h, latent + dr), dtype), "kv_norm": S((latent,), dtype), "wuk": S((heads, dn, latent), dtype),
+        "wuv": S((heads, latent, dv), dtype), "wo": S((heads * dv, h), dtype),
     }
+
+
+def block_param_shapes(cfg: DeepseekV3BlockConfig, kind: str, dtype=jnp.bfloat16) -> dict:
+    S = jax.ShapeDtypeStruct
+    return {"ln1": S((cfg.hidden_size,), dtype), **attention_shapes(cfg, dtype), "ln2": S((cfg.hidden_size,), dtype),
+            **feed_forward_shapes(cfg, kind, dtype)}
+
+
+def feed_forward_shapes(cfg: DeepseekV3BlockConfig, kind: str, dtype=jnp.bfloat16) -> dict:
+    h, S = cfg.hidden_size, jax.ShapeDtypeStruct
     if kind == DENSE:
         m = cfg.intermediate_size
-        shapes.update(wg=S((h, m), dtype), wu=S((h, m), dtype), wd=S((m, h), dtype))
-        return shapes
+        return dict(wg=S((h, m), dtype), wu=S((h, m), dtype), wd=S((m, h), dtype))
     m, E = cfg.moe_intermediate_size, cfg.num_experts
-    shapes.update(
+    shapes = dict(
         gate=S((h, E), dtype), gate_bias=S((E,), jnp.float32),
         w1=S((E, h, m), dtype), w2=S((E, m, h), dtype), w3=S((E, h, m), dtype),
     )

@@ -123,6 +123,14 @@ class ModelFamily:
     # counters count is the one the step runs (server/backend.py ``decode_walks``;
     # tests/test_paged_kernel.py holds every registered family's block to what it declares here)
     block_attention: Optional[Callable] = None
+    # cfg -> what crosses the wire between two blocks where that is NOT a row of ``cfg.hidden_size``: ``(width, mixes)``,
+    # the width of the hidden state a block takes and hands on (a residual stream of several rows, flat: the rows
+    # ARE the state and cannot be collapsed at a span's edge) and how many times a block mixes it (its wrapped
+    # sub-layers, which the batcher counts). None: ``cfg.hidden_size``, every other family's. Everything that sizes a
+    # buffer, checks a frame or probes a server reads the width through ``stream_for`` (server/backend.py
+    # ``hidden_size``); what cannot carry a state wider than the model yet (deep prompts, a tp mesh, quantised
+    # weights, an adapter) is refused for a family that declares one
+    block_stream: Optional[Callable] = None
 
     def kind_of(self, cfg, block_index: int) -> Hashable:
         return None if self.block_kind is None else self.block_kind(cfg, block_index)
@@ -153,6 +161,13 @@ class ModelFamily:
 
     def sublayers_for(self, cfg, kind: Hashable) -> int:
         return 1 if self.block_sublayers is None else int(self.block_sublayers(cfg, kind))
+
+    def stream_for(self, cfg) -> tuple:
+        """``(the width of the hidden state between two blocks, the mixes of it a block)``."""
+        if self.block_stream is None:
+            return int(cfg.hidden_size), 0
+        width, mixes = self.block_stream(cfg)
+        return int(width), int(mixes)
 
     def attention_for(self, cfg, kind: Hashable) -> frozenset:
         extras = frozenset(() if self.block_attention is None else self.block_attention(cfg, kind))

@@ -3,7 +3,10 @@
 A fingerprint is a seeded random projection of a hidden-state row into
 ``FP_DIM`` float32 components: ``fp = h[hidden] @ P[hidden, FP_DIM]``.
 The projection matrix is a deterministic function of ``(seed,
-hidden_size)``, so every party — the server program that fuses the
+hidden_size)``, ``hidden_size`` being the width of what crosses the wire
+between two blocks (``TransformerBackend.hidden_size``: the model's, or a
+residual stream's of several rows where the family declares one,
+``ModelFamily.block_stream``), so every party — the server program that fuses the
 matmul into its batched step, the client that re-derives the digest from
 the reply it received, and the canary prober comparing replicas — builds
 the SAME matrix independently and digests are comparable without any

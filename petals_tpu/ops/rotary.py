@@ -11,6 +11,7 @@ halves [x1, x2]; rotated = [x1*cos - x2*sin, x2*cos + x1*sin].
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
@@ -28,7 +29,7 @@ def rotary_tables(
     """Compute cos/sin tables [batch, seq, head_dim] for the given positions.
 
     ``rope_scaling`` supports HF-style dicts with rope_type "linear",
-    "llama3", or "longrope" (others raise NotImplementedError). Computation
+    "llama3", "longrope" or "yarn" (others raise NotImplementedError). Computation
     is float32 throughout for parity with HF. ``n_valid``/``n_total`` only
     matter to "longrope", whose factor selection depends on the REAL
     sequence length — padded bucket tails must not count, and a chunked
@@ -48,6 +49,8 @@ def rotary_tables(
             inv_freq, table_scale = _longrope_inv_freq(
                 inv_freq, positions, rope_scaling, n_valid, n_total
             )
+        elif rope_type == "yarn":
+            inv_freq, table_scale = _yarn_inv_freq(head_dim, theta, rope_scaling)
         elif rope_type in ("default", None):
             pass
         else:
@@ -90,8 +93,6 @@ def _longrope_inv_freq(
     config_from_hf injects ``factor`` and
     ``original_max_position_embeddings`` from the top-level HF config.
     Returns (inv_freq [b, 1, d/2], table_scale)."""
-    import math
-
     short = jnp.asarray(cfg["short_factor"], jnp.float32)
     long = jnp.asarray(cfg["long_factor"], jnp.float32)
     orig = float(cfg["original_max_position_embeddings"])
@@ -114,6 +115,48 @@ def _longrope_inv_freq(
     use_long = (seq_len > orig)[:, None, None]  # [b, 1, 1]
     ext = jnp.where(use_long, long[None, None, :], short[None, None, :])
     return inv_freq / ext, float(attention_factor)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 mscale ln(factor) + 1`` past a factor of 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_inv_freq(dim: int, theta: float, cfg: dict):
+    """YaRN (mirrors HF's ``_compute_yarn_parameters``): each frequency is
+    blended between ``1 / theta^(2i/d)`` (kept: it turns more than
+    ``beta_fast`` times over the pretrained window) and that over ``factor``
+    (interpolated: fewer than ``beta_slow`` turns) by a linear ramp between
+    the two correction dims. The tables are scaled by ``attention_factor``,
+    or by ``mscale(factor, mscale) / mscale(factor, mscale_all_dim)`` where
+    both are given, else by ``mscale(factor)``. The softmax's own ``mscale^2``
+    of a ``deepseek_v3`` attention is the caller's. ``original_max_position_
+    embeddings`` must be in ``cfg`` (HF falls back to the model's
+    ``max_position_embeddings``: a family's config puts it in).
+    Returns (inv_freq [d/2], table_scale)."""
+    factor = float(cfg["factor"])
+    attention_factor = cfg.get("attention_factor")
+    if attention_factor is None:
+        mscale, mscale_all_dim = cfg.get("mscale"), cfg.get("mscale_all_dim")
+        if mscale and mscale_all_dim:
+            attention_factor = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)
+        else:
+            attention_factor = yarn_mscale(factor)
+    beta_fast, beta_slow = cfg.get("beta_fast") or 32, cfg.get("beta_slow") or 1
+    window = cfg["original_max_position_embeddings"]
+
+    def correction_dim(turns: float) -> float:  # the dim whose frequency turns ``turns`` times over the window
+        return dim * math.log(window / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    low, high = correction_dim(beta_fast), correction_dim(beta_slow)
+    if cfg.get("truncate", True):
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, dim - 1)
+    if low == high:
+        high += 0.001  # no singularity
+    kept = 1.0 - jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    freqs = theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    return (1.0 / (factor * freqs)) * (1.0 - kept) + (1.0 / freqs) * kept, float(attention_factor)
 
 
 def _llama3_scale_inv_freq(inv_freq: jnp.ndarray, cfg: dict) -> jnp.ndarray:

@@ -135,7 +135,10 @@ class TransformerBackend:
         # a family may keep more kv heads in its cache than it publishes (heads of zeros, for the device's layout)
         self.num_kv_heads = getattr(cfg, "cache_kv_heads", None) or getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
         self.head_dim = cfg.head_dim
-        self.hidden_size = cfg.hidden_size
+        # what crosses the wire between two blocks: ``cfg.hidden_size``, or a residual stream of several rows, flat, for
+        # a family that declares one (ModelFamily.block_stream), with the times a block mixes it (0: no stream). Every
+        # buffer, frame check, probe and fingerprint is sized by ``hidden_size``, none by ``cfg.hidden_size``
+        self.hidden_size, self.stream_mixes = family.stream_for(cfg)
 
         if mesh is None and jax.default_backend() == "tpu":
             from petals_tpu.ops.quant import QuantizedLinear, maybe_autotune_nf4_decode
@@ -323,6 +326,18 @@ class TransformerBackend:
             raise NotImplementedError(
                 f"{self.family.name}: {what} is not served for a span whose positions cache a latent row in place of "
                 f"their keys and values ({' + '.join(map(str, self.latent_row))} wide, one for all heads): {LATENT_ROWS_RIDE}"
+            )
+
+    def refuse_deep_prompts(self, prompts) -> None:
+        """Raise for deep prompts over a span whose hidden state is a stream
+        wider than the model (ModelFamily.block_stream): a trained prompt is
+        a row of ``cfg.hidden_size`` and which rows of the stream it is added
+        to is the model's to say, not this server's."""
+        if prompts is not None and self.stream_mixes:
+            raise NotImplementedError(
+                f"{self.family.name}: deep prompts are not served for a span whose hidden state is a stream of "
+                f"{self.hidden_size // self.cfg.hidden_size} rows ({self.hidden_size} wide against the model's "
+                f"{self.cfg.hidden_size}): a prompt is a row of hidden_size and no published rule says where it enters the stream"
             )
 
     def _by_run(self, params) -> tuple:
@@ -802,7 +817,7 @@ class TransformerBackend:
         pool's kv-head axis is sharded, and block_apply inserts the psum —
         decode steps are seq==1, so no sp handling is needed here."""
         cfg = self.cfg
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
         self.refuse_for_state("the dense lane pool", "it has no place for the state: serve with page_size > 0")
@@ -1173,7 +1188,7 @@ class TransformerBackend:
         in place (``_scan_paged_span``), and come back in the donated
         buffers."""
         cfg = self.cfg
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -1243,7 +1258,7 @@ class TransformerBackend:
         each block's tables shifted by its layer (``_scan_paged_span``)."""
         family, cfg = self.family, self.cfg
         client_embed, client_head = family.client_embed, family.client_head
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -1340,7 +1355,7 @@ class TransformerBackend:
         and its tables are shifted by the layer."""
         family, cfg = self.family, self.cfg
         client_embed, client_head = family.client_embed, family.client_head
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -1453,7 +1468,7 @@ class TransformerBackend:
         drops), so decode-before-prefill ordering is immaterial."""
         family, cfg = self.family, self.cfg
         takes_n_total = "n_total" in inspect.signature(family.block_apply).parameters
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -2052,7 +2067,7 @@ class TransformerBackend:
         let greedy and sampling sessions coexist in the same step."""
         family, cfg = self.family, self.cfg
         client_embed, client_head = family.client_embed, family.client_head
-        fp_proj = fp_ops.projection(cfg.hidden_size)  # baked constant
+        fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
 
@@ -2161,6 +2176,7 @@ class TransformerBackend:
         variants (LongRoPE short/long factor selection) see the same n_total
         in every chunk instead of flipping factors mid-prompt. Defaults to
         position + seq — exact for unchunked callers."""
+        self.refuse_deep_prompts(prompts)
         self.refuse_for_state(
             "a step on a private or checked-out cache (deep prompts, beam search's hypo_ids, a session that took no lane)",
             "only the paged lane pool's own step programs carry the state",
@@ -2323,6 +2339,7 @@ class TransformerBackend:
         active_adapter: Optional[str] = None,
     ) -> jax.Array:
         """Training-style forward over the span (no KV cache)."""
+        self.refuse_deep_prompts(prompts)
         hidden = jnp.asarray(hidden, self.compute_dtype)
         span_params = self.params_for(active_adapter)
         with_prompts = prompts is not None
@@ -2340,6 +2357,7 @@ class TransformerBackend:
     ) -> Tuple[jax.Array, Optional[jax.Array]]:
         """Grads wrt inputs (and deep prompts if given) — recomputes the chain
         forward like the reference (run_rpc_backward, block_functions.py:84-141)."""
+        self.refuse_deep_prompts(prompts)
         hidden = jnp.asarray(hidden, self.compute_dtype)
         grad_out = jnp.asarray(grad_out, self.compute_dtype)
         with_prompts = prompts is not None

@@ -455,6 +455,9 @@ class DecodeBatcher:
             "gen_steps": 0, "gen_lane_tokens": 0, "max_gen_lanes": 0,
             "exclusive_chunks": 0, "prefill_tokens": 0, "mixed_steps": 0,
             "max_prefill_tokens_per_step": 0,
+            # the bytes of hidden state the batched steps took in and handed back (_count_stream): a row of
+            # ``backend.hidden_size`` float32 each way, as the wire carries it
+            "stream_bytes_in": 0, "stream_bytes_out": 0,
             "spec_steps": 0, "spec_proposed": 0, "spec_accepted": 0,
             "spec_disabled": 0, "max_spec_lanes": 0,
             # where the compute thread's time went, cumulative seconds: the
@@ -541,6 +544,10 @@ class DecodeBatcher:
                 sparse_rows_selected=0, sparse_rows_dense=0, sparse_index_rows_scored=0, sparse_score_pairs=0, sparse_kv_rows_read=0,
                 sparse_kv_rows_held=0, index_bytes_held=0, kv_bytes_held=0,
             )
+        if getattr(backend, "stream_mixes", 0):
+            # a family whose hidden state is a stream of several rows only (ModelFamily.block_stream; _count_stream):
+            # rows times the mixes of the stream the span's blocks make of each (a hyper-connection a sub-layer)
+            self.stats["hc_rows"] = 0
         if self._latent:
             # a family that declares a latent row only (_count_latent), from the shapes a step is started with, all
             # times the span's layers: latent rows the decode rows' walks read against those their lanes held; rows
@@ -2379,6 +2386,20 @@ class DecodeBatcher:
                 self.stats["moe_hit_tokens"] += n
         self.stats["moe_weight_passes"] += len(halves)
 
+    def _count_stream(self, wire_rows: int, rows: Optional[int] = None) -> None:
+        """The hidden state one batched step took in and handed back (compute
+        thread), from the step's shapes: ``wire_rows`` rows of
+        ``backend.hidden_size`` float32 each way (a lane that generates on
+        the server takes and returns a token, no row); and, for a family whose
+        hidden state is a stream of several rows, the ``rows`` the step
+        computed (default: ``wire_rows``) times the mixes a block makes of
+        each times the span's blocks."""
+        nbytes = wire_rows * self.backend.hidden_size * 4
+        self.stats["stream_bytes_in"] += nbytes
+        self.stats["stream_bytes_out"] += nbytes
+        if "hc_rows" in self.stats:
+            self.stats["hc_rows"] += (wire_rows if rows is None else rows) * self.backend.stream_mixes * self.backend.n_blocks
+
     def _window_pages(self, lanes, positions) -> Tuple[int, int]:
         """(held, in reach): the pages ``lanes`` hold, once a windowed layer
         of the span, and those of them a layer's window still reaches from
@@ -2575,6 +2596,7 @@ class DecodeBatcher:
             self.stats["batched_tokens"] += len(batch)
             self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
             self._count_moe(len(batch))
+            self._count_stream(len(batch))
             if paged:
                 self._count_paged(positions)
             duration = time.perf_counter() - t_step
@@ -2685,6 +2707,7 @@ class DecodeBatcher:
                 self.stats["max_prefill_tokens_per_step"], take
             )
             self._count_moe(len(batch), chunk_tokens=take)
+            self._count_stream(len(batch) + take)
             self._count_paged(positions, chunk=(st.lane, st.position, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
@@ -2776,6 +2799,7 @@ class DecodeBatcher:
                 self.stats["max_gen_lanes"], len(gen_states)
             )
             self._count_moe(len(batch) + len(gen_states))
+            self._count_stream(len(batch), len(batch) + len(gen_states))
             if tables is not None:
                 self._count_paged(positions)
             duration = time.perf_counter() - t_step

@@ -1230,7 +1230,7 @@ class TransformerHandler:
         """Reject malformed step tensors with a clean error instead of an opaque
         XLA/scan failure — and keep clients from forcing fresh compilations with
         novel batch sizes on the serving hot path."""
-        hsz = self.backend.cfg.hidden_size
+        hsz = self.backend.hidden_size
         if hidden is not None and (
             hidden.ndim != 3 or hidden.shape[0] != batch_size or hidden.shape[2] != hsz
         ):
@@ -1279,9 +1279,9 @@ class TransformerHandler:
         reply_comp = self._reply_compression(payload)  # reject bad codecs up front
         hidden = self._get_tensor(payload, "hidden")
         prompts = self._get_tensor(payload, "prompts")
-        if hidden is None or hidden.ndim != 3 or hidden.shape[2] != self.backend.cfg.hidden_size:
+        if hidden is None or hidden.ndim != 3 or hidden.shape[2] != self.backend.hidden_size:
             raise ValueError(
-                f"rpc_forward expects a [batch, seq, hidden={self.backend.cfg.hidden_size}] "
+                f"rpc_forward expects a [batch, seq, hidden={self.backend.hidden_size}] "
                 f"tensor, got {None if hidden is None else tuple(hidden.shape)}"
             )
         backend = self._sub_backend(start, end)
@@ -1312,9 +1312,9 @@ class TransformerHandler:
         prompts = self._get_tensor(payload, "prompts")
         if hidden is None or grad_out is None:
             raise ValueError("rpc_backward expects hidden and grad_out tensors")
-        if hidden.ndim != 3 or hidden.shape[2] != self.backend.cfg.hidden_size:
+        if hidden.ndim != 3 or hidden.shape[2] != self.backend.hidden_size:
             raise ValueError(
-                f"rpc_backward expects a [batch, seq, hidden={self.backend.cfg.hidden_size}] "
+                f"rpc_backward expects a [batch, seq, hidden={self.backend.hidden_size}] "
                 f"tensor, got {tuple(hidden.shape)}"
             )
         if grad_out.shape != hidden.shape:
@@ -1396,7 +1396,7 @@ class TransformerHandler:
 
         seed = int(payload.get("seed", fp_ops.fp_seed()))
         n_tokens = max(1, min(int(payload.get("tokens", 4)), 16))
-        hsz = self.backend.cfg.hidden_size
+        hsz = self.backend.hidden_size
         rng = np.random.RandomState(seed & 0x7FFFFFFF)
         # activation-scale golden input: magnitudes typical of embedding
         # outputs, so the forward pass exercises realistic numerics

@@ -1386,13 +1386,13 @@ class Server:
 
         from petals_tpu.server.throughput import RELAY_PENALTY, measure_network_rps
 
-        cfg = self.cfg
         rng = np.random.RandomState(0)
-        step_h = rng.randn(1, 1, cfg.hidden_size).astype(np.float32) * 0.01
+        width = self.backend.hidden_size  # what crosses the wire: a stream's rows where the family has one
+        step_h = rng.randn(1, 1, width).astype(np.float32) * 0.01
         # 1024-token forwards: the SAME basis as the single-host probe
         # (throughput.py measure_compute_rps) — announced numbers must be
         # comparable across servers or routing deprioritizes multi-host spans
-        fwd_h = rng.randn(1, 1024, cfg.hidden_size).astype(np.float32) * 0.01
+        fwd_h = rng.randn(1, 1024, width).astype(np.float32) * 0.01
 
         descriptors = self.backend.cache_descriptors(1, 64, 0, self.num_blocks)
         async with self.memory_cache.allocate_cache(*descriptors) as handles:
@@ -1421,7 +1421,7 @@ class Server:
                 None, probe
             )
         network_mbps = await self._resolve_network_mbps()
-        network_rps = measure_network_rps(cfg.hidden_size, network_mbps=network_mbps)
+        network_rps = measure_network_rps(width, network_mbps=network_mbps)
         if self.relay_via is not None:
             network_rps *= RELAY_PENALTY
         # the span probe already spreads compute over num_blocks blocks

@@ -62,7 +62,7 @@ def get_server_throughput(
     cache_key = json.dumps(
         {
             "family": family.name,
-            "hidden": cfg.hidden_size,
+            "hidden": family.stream_for(cfg)[0],
             "intermediate": getattr(cfg, "intermediate_size", None),
             "kv_heads": getattr(cfg, "num_key_value_heads", None),
             "head_dim": getattr(cfg, "head_dim", None),
@@ -96,7 +96,7 @@ def get_server_throughput(
     # the network figure is NEVER cached: the caller's swarm probe (or a
     # --network_mbps override) must always win — a cached compute entry
     # otherwise silently pins the network number from a past environment
-    info["network_rps"] = measure_network_rps(cfg.hidden_size, network_mbps=network_mbps)
+    info["network_rps"] = measure_network_rps(family.stream_for(cfg)[0], network_mbps=network_mbps)
 
     # blended throughput (reference throughput.py:96-106): compute spread over
     # the hosted blocks vs what the network can carry
@@ -167,7 +167,7 @@ def measure_compute_rps(
         memory_cache=MemoryCache(None), compute_dtype=compute_dtype, mesh=mesh,
     )
 
-    token = np.zeros((1, 1, cfg.hidden_size), np.float32)
+    token = np.zeros((1, 1, backend.hidden_size), np.float32)
     if backend.state_layers or backend.index_row is not None or backend.latent_row is not None:
         # a block with a recurrent state, or one whose positions cache an index row or a latent row, has no
         # private cache: the path that serves it is the paged lane pool's step, here over one lane of four pages
@@ -189,7 +189,7 @@ def measure_compute_rps(
     jax.block_until_ready(out)
     inference_rps = n_steps_inference / (time.perf_counter() - t0)
 
-    batch = np.zeros((1, 1024, cfg.hidden_size), np.float32)
+    batch = np.zeros((1, 1024, backend.hidden_size), np.float32)
     jax.block_until_ready(backend.forward(batch))
     t0 = time.perf_counter()
     for _ in range(n_steps_forward):

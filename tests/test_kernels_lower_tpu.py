@@ -329,11 +329,11 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     # the second pool is the first one's twin but for a span that caches a latent row in place of keys and values
     second = pools if backend.latent_row is None else v5e(descs[1].shape, BF16)
     # the lanes' rows and positions as one operand (backend.pack_lanes' form: a float32 row bit for bit and its position), then the tables
-    avals = [params, pools, second, v5e((lanes, cfg.hidden_size + 1), I32), v5e((lanes, pages_a_lane), I32)]
+    avals = [params, pools, second, v5e((lanes, backend.hidden_size + 1), I32), v5e((lanes, pages_a_lane), I32)]
     step = backend._paged_decode_fn
     if chunk:  # chunk_hidden, then chunk_lane, chunk_pos, chunk_n_valid, chunk_n_total
         step = backend._paged_mixed_step_fn
-        avals += [v5e((1, chunk, cfg.hidden_size), BF16)] + [v5e((), I32)] * 4
+        avals += [v5e((1, chunk, backend.hidden_size), BF16)] + [v5e((), I32)] * 4
     donated = (1, 2)
     if backend.state_layers:  # the state pool's leaves ride last and are donated with the pages
         avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
@@ -1394,3 +1394,56 @@ def test_the_absorbed_walk_s_kernel_takes_64_heads_and_the_counters_count_it(v5e
     assert reads["latent_rows_read"] == 8 * sum(min(-(-int(ctx) // block) * block, 2 * block) for ctx in contexts)  # a table of 40 pages: two blocks
     assert reads["latent_rows_held"] == reads["latent_score_pairs"] == 8 * int(contexts.sum()) and reads["latent_rows_absorbed"] == 8 * 6
     assert backend.latent_reads(8, 40, 64, contexts - 1)["latent_rows_read"] == 8 * 8 * 2560  # off the chip: the composed walk, every lane to the longest
+
+
+STREAM = "xing4-29b-a4b-span8"
+STREAM_POOLS = ((8, 128, 64, 512), (8, 128, 32, 128))  # 8 blocks, 8 lanes x 16 pages of 64; latents, rotated keys
+STREAM_WIDTH = 4 * 3584
+
+
+@pytest.mark.parametrize("chunk", [0, 512], ids=["decode", "mixed-512"])
+def test_a_stream_of_four_rows_crosses_every_layer_flat_and_no_wrap_relays_it(v5e, tmp_path, chunk):
+    """xing4-29b-a4b-span8's decode and mixed-512 steps (8 lanes, tables of 16
+    pages, the lanes' rows 14,336 wide), compiled for the v5e. The stream is
+    never transposed or copied a sub-layer: in a layer loop's body the only
+    instructions that merely move an array 14,336 wide are ONE asynchronous
+    copy of the lanes' ``[8, 1, 14336]`` (229 KB) a layer, and none of the
+    chunk's ``[1, 512, 14336]``; the three named scopes of the wrap are all
+    there. The pool of latents is allocated once and never copied; the pool
+    of rotated keys is staged through the chip's fast memory at most once a
+    layer (longcat-flash-span4-ep32's finding, PERF.md section 7); the walk's
+    kernel and the hit dispatch's are in each loop's body once; no stacked
+    weight is relaid in a loop but the mixed step's known ones (the chunk's
+    walk is handed ``wuk`` / ``wuv`` as arrays, the chunk's grouped dispatch a
+    copy of ``w1`` / ``w3`` / ``w2``: ROADMAP S7), and none of the two ``phi``
+    (688 KB a layer: prefetched by an asynchronous slice, not copied)."""
+    hlo, runs, pool, _ = _compiled_step(v5e, tmp_path, STREAM, chunk, pages_a_lane=16)
+    assert tuple(pool.shape) == STREAM_POOLS[0] and [run["wkva"].shape[0] for run in runs] == [2, 6]
+    assert runs[0]["hc_phi_attn"].shape == (2, STREAM_WIDTH, 24) and runs[1]["w1"].shape == (6, 64, 3584, 1024)
+    comps = _computations(hlo)
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    assert all(scope in hlo for scope in ("ptu.hc.coef", "ptu.hc.sinkhorn", "ptu.hc.mix"))
+    in_memory = list(_arrays_in_memory(comps))
+    moves = [(computation, op, tuple(dims)) for computation, _, dims, op, rest in in_memory
+             if STREAM_WIDTH in dims and computation != entry and (op in ("copy", "transpose", "copy-start") or _only_moves(comps, op, rest))]
+    assert all(op in ("copy-start", "copy-done") and dims == (8, 1, STREAM_WIDTH) for _, op, dims in moves), f"a layer moves the stream: {moves}"
+    assert sum(op == "copy-start" for _, op, _ in moves) <= 2, moves  # one a loop's body (the dense run's, the sparse run's)
+    latents, keys = STREAM_POOLS
+    for shape in STREAM_POOLS:
+        assert any(tuple(dims) == shape for _, dims, _, _ in comps[entry]), f"the pool {shape} was not found in ENTRY"
+    moved = [(computation, op, tuple(dims)) for computation, _, dims, op, rest in in_memory
+             if dims[-1:] == latents[-1:] and math.prod(dims) >= math.prod(latents[1:]) and tuple(dims) not in ((32, 128, 512),)
+             and (op == "custom-call" and 'custom_call_target="AllocateBuffer"' in rest or _only_moves(comps, op, rest))]
+    assert not moved, f"the step moves the pool of latents: {moved}"
+    staged = [(computation, op) for computation, _, dims, op, _ in in_memory
+              if op == "copy-done" and dims[-1:] == keys[-1:] and math.prod(dims) >= math.prod(keys[1:])]
+    assert all(computation != entry for computation, _ in staged) and len(staged) <= 2, staged  # at most once a loop's body
+    walks, hits = decode_walk_calls(hlo), hit_calls(hlo)
+    assert len(walks) == 2 and len({computation for computation, _, _ in walks}) == 2 and len(hits) == 1
+    stacked = {tuple(p.shape) for run in runs for p in run.values()}
+    smallest = math.prod(runs[0]["hc_phi_attn"].shape[1:])
+    relayouts, seen = weight_relayouts(hlo, stacked, smallest)
+    known = {(32, 128, 512), (32, 512, 128), (64, 3584, 1024), (64, 1024, 3584)} if chunk else set()
+    relayouts = [r for r in relayouts if tuple(json.loads(r.split(" -> ")[1])) not in known]
+    assert seen and not relayouts, f"the step's loop relays a weight in every layer of every step: {relayouts}"
+    assert not entry_weight_moves(hlo, stacked, smallest)

@@ -32,6 +32,7 @@ from tests.utils import (
     make_tiny_phi3,
     make_tiny_qwen2,
     make_tiny_qwen3_next,
+    make_tiny_xing4_0,
 )
 
 
@@ -43,7 +44,7 @@ MAKERS = {
     "mistral": make_tiny_mistral, "gemma": make_tiny_gemma, "phi3": make_tiny_phi3,
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
     "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3, "qwen3_next": make_tiny_qwen3_next,
-    "jamba": make_tiny_jamba, "longcat_flash": make_tiny_longcat_flash,
+    "jamba": make_tiny_jamba, "longcat_flash": make_tiny_longcat_flash, "xing4_0": make_tiny_xing4_0,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 
@@ -157,6 +158,53 @@ def test_lora_targets_name_existing_leaves(family_block, name):
     else:
         with pytest.raises(ValueError, match=f"no LoRA tensor.*{name}"):
             load_adapter(adapter_path, name, block_range=range(2))
+
+
+@pytest.mark.parametrize("name", known_families())
+def test_buffers_and_frames_are_sized_by_the_family_s_stream_width(family_block, name):
+    """What crosses the wire between two blocks is as wide as the family says
+    (``ModelFamily.block_stream``; ``cfg.hidden_size`` where it says nothing,
+    every family's but ``xing4_0``, whose stream of four rows is 256 wide at
+    the toy's 64), and the backend, the batcher and the handler size by that
+    one width: the lanes' packed rows, the pool's reused buffer, the frame
+    check, which names the width it wants. A frame as wide as the model is
+    refused where the stream is wider."""
+    import asyncio
+    import types
+
+    import jax
+
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.batching import DecodeBatcher
+    from petals_tpu.server.handler import TransformerHandler
+    from petals_tpu.server.memory_cache import MemoryCache
+    from petals_tpu.server.task_queue import PriorityTaskQueue
+
+    _, family, cfg, params = family_block(name)
+    width, mixes = family.stream_for(cfg)
+    assert (width, mixes) == ((4 * cfg.hidden_size, 2) if name == "xing4_0" else (cfg.hidden_size, 0))
+    stacked = jax.tree_util.tree_map(lambda leaf: jnp.asarray(leaf)[None], params)
+    backend = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=1, memory_cache=MemoryCache(None),
+                                 compute_dtype=jnp.float32, use_flash=False)
+    assert backend.hidden_size == width and backend.stream_mixes == mixes
+    assert backend.pack_lanes(np.zeros((3, 1, width), np.float32), np.arange(3)).shape == (3, width + 1)
+    handler = types.SimpleNamespace(backend=backend)
+    TransformerHandler._validate_step_tensors(handler, np.zeros((1, 5, width), np.float32), None, None, 1, 1)
+    refused = [width + 8] + ([cfg.hidden_size] if width != cfg.hidden_size else [])
+    for wrong in refused:
+        with pytest.raises(ValueError, match=f"hidden={width}\\], got \\(1, 5, {wrong}\\)"):
+            TransformerHandler._validate_step_tensors(handler, np.zeros((1, 5, wrong), np.float32), None, None, 1, 1)
+    batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=32, page_size=8)
+    assert ("hc_rows" in batcher.stats) == bool(mixes) and batcher.stats["stream_bytes_in"] == 0
+
+    async def opened():
+        await batcher.ensure_open()
+        try:
+            return batcher._lanes_in.shape
+        finally:
+            await batcher.close()
+
+    assert asyncio.run(opened()) == (2, width + 1)
 
 
 @pytest.mark.parametrize("maker", [make_tiny_qwen2, make_tiny_mistral])

@@ -339,7 +339,7 @@ def _tiny_families() -> dict:
         "exaone_moe": utils.make_tiny_exaone_moe, "olmo_hybrid": utils.make_tiny_olmo_hybrid, "KeyeVL2": utils.make_tiny_keye_vl2,
         "KeyeVL2-table-of-one-page": utils.make_tiny_keye_vl2,  # 16 positions, as many as a row chooses: the plain call
         "deepseek_v3": utils.make_tiny_deepseek_v3, "qwen3_next": utils.make_tiny_qwen3_next, "jamba": utils.make_tiny_jamba,
-        "longcat_flash": utils.make_tiny_longcat_flash,
+        "longcat_flash": utils.make_tiny_longcat_flash, "xing4_0": utils.make_tiny_xing4_0,
     }
 
 
@@ -393,7 +393,7 @@ def test_the_decode_walk_the_backend_counts_is_the_one_its_step_is_handed(name, 
     descs = backend.paged_cache_descriptors(lanes * slots, page_size, 0, depth)
     pools = [aval(d.shape, d.dtype) for d in descs[:2]]
     # the lanes' rows and positions in backend.pack_lanes' form, then the tables
-    avals = [backend.params, *pools, aval((lanes, cfg.hidden_size + 1), jnp.int32), aval((lanes, slots), jnp.int32)]
+    avals = [backend.params, *pools, aval((lanes, backend.hidden_size + 1), jnp.int32), aval((lanes, slots), jnp.int32)]
     if backend.state_layers:
         avals.append(tuple(aval(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
     if backend.index_row is not None:
@@ -401,7 +401,7 @@ def test_the_decode_walk_the_backend_counts_is_the_one_its_step_is_handed(name, 
     jax.eval_shape(functools.partial(backend._paged_decode_fn.__wrapped__, with_fp=False), *avals)
     assert set(asked) == counted, (sorted(map(str, asked)), sorted(map(str, counted)))
     # a latent row's walk is its own (ops/latent_attention.py); a row that chooses its positions fetches them one by one
-    assert bool(counted) == (name not in ("deepseek_v3", "KeyeVL2", "longcat_flash")), "the step's attention never reached the decode walk"
+    assert bool(counted) == (name not in ("deepseek_v3", "KeyeVL2", "longcat_flash", "xing4_0")), "the step's attention never reached the decode walk"
     extras = {"bloom": (True, False, None), "falcon-rw": (True, False, None), "gemma2": (False, True, "an array")}
     assert {call[-3:] for call in counted} <= {extras.get(name, (False, False, call[-1])) for call in counted}, counted
 

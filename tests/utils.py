@@ -1112,3 +1112,61 @@ def make_tiny_jamba(tmpdir: str, **overrides) -> str:
         json.dump(config, f)
     save_file(tiny_jamba_tensors(config), os.path.join(path, "model.safetensors"))
     return path
+
+
+TINY_XING4_0 = {  # the keys Xing4.0-29B-A4B publishes, at a toy size: kinds D, D, S, S under a stream of four rows
+    "model_type": "xing4_0", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4, "num_hidden_layers": 4,
+    "first_k_dense_replace": 2, "q_lora_rank": 24, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "intermediate_size": 128, "moe_intermediate_size": 32, "n_routed_experts": 8, "num_experts_per_tok": 2, "n_shared_experts": 1,
+    "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc", "scoring_func": "sigmoid", "norm_topk_prob": True,
+    "routed_scaling_factor": 2, "moe_layer_freq": 1, "ep_size": 1, "hidden_act": "silu", "attention_bias": False,
+    "num_nextn_predict_layers": 1, "hc_mult": 4, "hc_sinkhorn_iters": 20, "hc_eps": 1e-6, "mhc_h_res_clamp_min": -30,
+    "mhc_h_res_clamp_max": 30, "rms_norm_eps": 1e-5, "rope_theta": 10000,
+    # a window of 32 puts the ramp's two correction dims inside the toy rotary's four frequencies
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 32, "type": "yarn"},
+    "max_position_embeddings": 256, "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_xing4_0_tensors(config: dict, seed: int = 37) -> dict:
+    """Seeded float32 tensors of every layer of ``config`` under transformers'
+    ``deepseek_v3`` names for the sub-layers (a low-rank query) and
+    ``attn_hc.*`` / ``mlp_hc.*`` for the two hyper-connections of a layer, the
+    embedding, the final norm and the head. The three ``phi`` at a scale that
+    makes the coefficients' logits of order 1 (a unit-RMS row of ``n*C``
+    values against std 0.1), ``alpha`` and ``b`` drawn, so that the mixes move
+    with the input and a missing or misplaced tensor shows."""
+    tensors = tiny_deepseek_v3_tensors(config, seed)
+    rng = np.random.RandomState(seed + 1)
+    h, heads, dn, dr, rq, n = (config[k] for k in ("hidden_size", "num_attention_heads", "qk_nope_head_dim", "qk_rope_head_dim",
+                                                   "q_lora_rank", "hc_mult"))
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    for i in range(config["num_hidden_layers"]):
+        p = f"model.layers.{i}."
+        del tensors[p + "self_attn.q_proj.weight"]
+        tensors.update({p + "self_attn.q_a_proj.weight": normal(rq, h) * 3, p + "self_attn.q_a_layernorm.weight": rng.uniform(0.5, 1.5, rq).astype(np.float32),
+                        p + "self_attn.q_b_proj.weight": normal(heads * (dn + dr), rq) * 3})
+        for wrap in ("attn_hc.", "mlp_hc."):
+            for c, rows in (("pre", n), ("post", n), ("res", n * n)):
+                tensors[p + wrap + f"phi_{c}.weight"] = normal(rows, n * h)
+                tensors[p + wrap + f"alpha_{c}"] = rng.uniform(0.3, 0.9, 1).astype(np.float32)
+                tensors[p + wrap + f"b_{c}"] = normal(n, n) if c == "res" else normal(n)
+    return tensors
+
+
+def make_tiny_xing4_0(tmpdir: str, **overrides) -> str:
+    """An ``xing4_0`` checkpoint at a toy size, written by hand (transformers
+    has no class for the model_type; tests/test_xing4_0.py loads the
+    sub-layers' tensors into transformers' ``deepseek_v3`` modules)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = {**TINY_XING4_0, **overrides}
+    path = os.path.join(tmpdir, "tiny-xing4-0")
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_xing4_0_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
