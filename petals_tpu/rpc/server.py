@@ -49,27 +49,62 @@ class StreamRequests:
     ``read_at``: when the frame of the item last handed out lay whole in
     memory, before it was unpacked. ``sent_s``: the seconds the item last
     yielded took to send: packing, the wait for the connection's write lock,
-    ``writer.write`` and the drain of the transport's buffer."""
+    ``writer.write`` and the drain of the transport's buffer.
 
-    __slots__ = ("_queue", "_ended", "read_at", "sent_s")
+    ``sink``: a handler may set it to a ``callable(item, read_at) -> bool`` and
+    take items without the queue. The connection's reader then calls it with
+    an ``sitem``'s payload in the turn that read the frame, on the reader's own
+    task, so frames that came in one ``recv`` are taken in one pass with no
+    queue hop and no task woken an item. It must not await; True says the item
+    is the sink's, False leaves it to the queue, a raise fails this call alone
+    (the peer gets the ``resp`` a raise in the handler would have sent). Which
+    frames may bypass the queue: an ``sitem`` only, and only while the
+    iterator's consumer is parked in ``__anext__`` on an EMPTY queue. Order
+    within a stream holds because of that: an item is only ever handed over
+    when nothing of its stream is queued or on its way from the queue to the
+    consumer (a ``put`` leaves the item in the queue until the consumer has
+    run), so whatever arrives behind a queued item is queued behind it, and
+    the half-close (``send``) always takes the queue."""
 
-    def __init__(self, queue: asyncio.Queue):
-        self._queue = queue
+    __slots__ = ("_queue", "_ended", "_parked", "read_at", "sent_s", "sink")
+
+    def __init__(self):
+        # Per-call inbound buffer, bounded (MAX_INBOUND_QUEUE, above).
+        self._queue: asyncio.Queue = asyncio.Queue(maxsize=MAX_INBOUND_QUEUE)
         self._ended = False
+        self._parked = False  # the consumer waits in __anext__, nothing handed to it yet
         self.read_at: Optional[float] = None
         self.sent_s: Optional[float] = None
+        self.sink: Optional[Callable[[Any, float], bool]] = None
 
     def __aiter__(self):
         return self
 
     async def __anext__(self):
         if not self._ended:
-            item, read_at = await self._queue.get()
+            self._parked = True
+            try:
+                item, read_at = await self._queue.get()
+            finally:
+                self._parked = False  # in the step of the task that took the item: no turn between
             if item is not _END:
                 self.read_at = read_at
                 return item
             self._ended = True
         raise StopAsyncIteration
+
+    def deliver(self, item: Any, read_at: float) -> None:
+        """The reader's side of an ``sitem``: to the sink if it may go there and
+        the sink takes it, else into the queue (``asyncio.QueueFull`` past the
+        bound)."""
+        sink = self.sink
+        if sink is not None and self._parked and self._queue.empty() and sink(item, read_at):
+            return
+        self._queue.put_nowait((item, read_at))
+
+    def end(self, read_at: float) -> None:
+        """The reader's side of a ``send``: the half-close, behind whatever is queued."""
+        self._queue.put_nowait((_END, read_at))
 
 
 UnaryHandler = Callable[[Any, RpcContext], Awaitable[Any]]
@@ -130,7 +165,7 @@ class RpcServer:
         self._conn_tasks.add(task)
         write_lock = asyncio.Lock()
         call_tasks: Dict[int, asyncio.Task] = {}
-        inbound_queues: Dict[int, asyncio.Queue] = {}
+        inbound: Dict[int, StreamRequests] = {}
         ctx = RpcContext(
             local_peer_id=self.peer_id,
             remote_peer_id=None,
@@ -204,17 +239,18 @@ class RpcServer:
                         self._run_unary(msg, ctx, writer, write_lock, call_tasks)
                     )
                 elif kind == "sopen":
-                    queue: asyncio.Queue = asyncio.Queue(maxsize=MAX_INBOUND_QUEUE)
-                    inbound_queues[msg["id"]] = queue
+                    requests = inbound[msg["id"]] = StreamRequests()
                     call_tasks[msg["id"]] = asyncio.create_task(
-                        self._run_stream(msg, queue, ctx, writer, write_lock, call_tasks, inbound_queues)
+                        self._run_stream(msg, requests, ctx, writer, write_lock, call_tasks, inbound)
                     )
                 elif kind in ("sitem", "send"):
-                    queue = inbound_queues.get(msg["id"])
-                    if queue is not None:
-                        item = _END if kind == "send" else msg.get("payload")
+                    requests = inbound.get(msg["id"])
+                    if requests is not None:
                         try:
-                            queue.put_nowait((item, read_at))
+                            if kind == "send":
+                                requests.end(read_at)
+                            else:
+                                requests.deliver(msg.get("payload"), read_at)
                         except asyncio.QueueFull:
                             # The handler is MAX_INBOUND_QUEUE frames behind this
                             # peer: abusive or wedged either way. Kill the call
@@ -223,21 +259,18 @@ class RpcServer:
                                 f"Inbound queue overflow on call {msg['id']} from "
                                 f"{ctx.remote_addr}; cancelling the call"
                             )
-                            stuck = call_tasks.get(msg["id"])
-                            if stuck is not None:
-                                stuck.cancel()
-                            inbound_queues.pop(msg["id"], None)
                             # tell the peer: its pending recv should fail fast,
                             # not hang until its own timeout
-                            await write_frame(
-                                writer,
-                                {
-                                    "t": "resp",
-                                    "id": msg["id"],
-                                    "ok": False,
-                                    "error": "RpcError: inbound queue overflow, call cancelled",
-                                },
-                                write_lock,
+                            await self._abort_stream(
+                                msg["id"], "RpcError: inbound queue overflow, call cancelled",
+                                writer, write_lock, call_tasks, inbound,
+                            )
+                        except Exception as e:
+                            # the stream's sink raised on this item: that call's
+                            # failure, as a raise in its handler is, not this loop's
+                            logger.debug(f"Stream sink of call {msg['id']} failed: {e}\n{traceback.format_exc()}")
+                            await self._abort_stream(
+                                msg["id"], _format_error(e), writer, write_lock, call_tasks, inbound
                             )
                 elif kind == "cancel":
                     task_to_cancel = call_tasks.get(msg["id"])
@@ -258,6 +291,15 @@ class RpcServer:
                 await asyncio.gather(*call_tasks.values(), return_exceptions=True)
             writer.close()
             self._conn_tasks.discard(task)
+
+    @staticmethod
+    async def _abort_stream(call_id, error: str, writer, write_lock, call_tasks, inbound) -> None:
+        """Cancel a streaming call from the reader's side and answer it as failed."""
+        stuck = call_tasks.get(call_id)
+        if stuck is not None:
+            stuck.cancel()
+        inbound.pop(call_id, None)
+        await write_frame(writer, {"t": "resp", "id": call_id, "ok": False, "error": error}, write_lock)
 
     async def _run_unary(self, msg, ctx, writer, write_lock, call_tasks):
         call_id = msg["id"]
@@ -280,9 +322,8 @@ class RpcServer:
         finally:
             call_tasks.pop(call_id, None)
 
-    async def _run_stream(self, msg, queue, ctx, writer, write_lock, call_tasks, inbound_queues):
+    async def _run_stream(self, msg, requests, ctx, writer, write_lock, call_tasks, inbound):
         call_id = msg["id"]
-        requests = StreamRequests(queue)
         try:
             handler = self._stream.get(msg.get("method"))
             if handler is None:
@@ -307,7 +348,7 @@ class RpcServer:
                 pass
         finally:
             call_tasks.pop(call_id, None)
-            inbound_queues.pop(call_id, None)
+            inbound.pop(call_id, None)
 
 
 def _format_error(e: Exception) -> str:

@@ -492,6 +492,10 @@ class DecodeBatcher:
             "reply_wake_s": 0.0, "reply_steps": 0,
             "reply_resume_s": 0.0, "reply_build_s": 0.0, "rpc_send_s": 0.0, "decode_replies": 0,
             "rpc_recv_s": 0.0, "request_handle_s": 0.0, "lane_return_s": 0.0, "lane_returns": 0,
+            # decode steps by the way their request came in: handed over by the
+            # connection's reader in the turn that read the frame (begin_step),
+            # or through the stream's queue and the handler's own turn (step)
+            "rpc_intake_direct": 0, "rpc_intake_queued": 0,
             # the event loop's turns, added by its turn clock once Server.start
             # attaches this dict (utils/asyncio_utils.install_turn_clock): the
             # stretches between one select()'s return and the next one's call,
@@ -881,21 +885,13 @@ class DecodeBatcher:
                 f"({self.max_length} tokens)"
             )
         alloc = self._pages
-        # identity preference keeps tables contiguous at the default pool
-        # size: paged attention serves any layout, but identity tables read
-        # pages in sequential HBM order (and keep the tables_contiguous
-        # debug flag meaningful)
-        identity_base = (
-            lane * self.max_pages
-            if self.n_pages == self.n_lanes * self.max_pages else None
-        )
         deadline = None if timeout is None else time.monotonic() + timeout
         pages_changed = False
         for slot in range(t0 // self.page_size, (t1 - 1) // self.page_size + 1):
             cur = int(self._tables[lane, slot])
             if cur >= 0 and alloc.refs[cur] == 1:
                 continue  # already exclusively owned
-            preferred = None if identity_base is None else identity_base + slot
+            preferred = self._identity_page(lane, slot)
             while True:
                 page = alloc.try_alloc(preferred=preferred)
                 if page is not None:
@@ -953,6 +949,33 @@ class DecodeBatcher:
             # ledger here, not on the next admission boundary — page-seconds
             # accrued under the old rates up to this instant
             self._ledger_sync()
+
+    def _identity_page(self, lane: int, slot: int) -> Optional[int]:
+        """The page to ask for first: identity preference keeps tables
+        contiguous at the default pool size. Paged attention serves any
+        layout, but identity tables read pages in sequential HBM order (and
+        keep the tables_contiguous debug flag meaningful)."""
+        if self.n_pages != self.n_lanes * self.max_pages:
+            return None
+        return lane * self.max_pages + slot
+
+    def _own_page_now(self, lane: int, position: int) -> bool:
+        """``prepare_write`` for one token, as far as it goes without a wait:
+        True with the token's page the lane's own (already, or taken from the
+        free list here). False, and nothing changed, where it would have to
+        wait for a page, fork a shared one, or raise."""
+        if position >= self.max_length:
+            return False
+        slot = position // self.page_size
+        cur = int(self._tables[lane, slot])
+        if cur >= 0:
+            return self._pages.refs[cur] == 1
+        page = self._pages.try_alloc(preferred=self._identity_page(lane, slot))
+        if page is None:
+            return False
+        self._write_tables(lane, slot, page)
+        self._ledger_sync()  # a grow: page-seconds accrued under the old rates up to here
+        return True
 
     def _copy_page(self, src: int, dst: int) -> None:
         """Compute-thread body: device copy of one page (all blocks) — the
@@ -1140,22 +1163,28 @@ class DecodeBatcher:
         for the op's duration. No await between the resident check returning
         and the increment, so the pair is atomic on the event loop."""
         await self._ensure_resident(lane)
-        self._inflight[lane] = self._inflight.get(lane, 0) + 1
-        self._scheduler.touch(lane)
+        self._enter_lane(lane)
         try:
             yield
         finally:
-            self._inflight[lane] -= 1
-            # a step boundary IS the preemption opportunity: when decode is
-            # compute-bound, lanes are idle only in the sliver between ops,
-            # which timer polls almost always miss — wake page waiters now
-            # so they re-attempt victim selection while this lane is idle
-            if (
-                self._inflight[lane] == 0
-                and self._pages is not None
-                and self.swap_pool.max_size_bytes > 0
-            ):
-                self._pages.freed_event.set()
+            self._leave_lane(lane)
+
+    def _enter_lane(self, lane: int) -> None:
+        self._inflight[lane] = self._inflight.get(lane, 0) + 1
+        self._scheduler.touch(lane)
+
+    def _leave_lane(self, lane: int) -> None:
+        self._inflight[lane] -= 1
+        # a step boundary IS the preemption opportunity: when decode is
+        # compute-bound, lanes are idle only in the sliver between ops,
+        # which timer polls almost always miss — wake page waiters now
+        # so they re-attempt victim selection while this lane is idle
+        if (
+            self._inflight[lane] == 0
+            and self._pages is not None
+            and self.swap_pool.max_size_bytes > 0
+        ):
+            self._pages.freed_event.set()
 
     def _lane_idle(self, lane: int, *, ignore_lock: bool = False) -> bool:
         """A lane is preemptable only while NOTHING is touching it: no step
@@ -1327,13 +1356,17 @@ class DecodeBatcher:
             to_wire = self.backend.pool_to_wire
             return to_wire(to_host(k)), to_wire(to_host(v)), to_host(tuple(state))
 
+    def _resident_now(self, lane: int) -> bool:
+        """Neither swapped out nor on its way out: an op may touch the lane without a swap-in first."""
+        slot = self._scheduler.lanes.get(lane)
+        return slot is None or (slot.swap is None and not slot.suspending)
+
     async def _ensure_resident(self, lane: int) -> None:
         """Transparent resume: if ``lane`` is suspended (or a suspend is in
         flight — the lock serializes us behind it), swap its KV back in
         before the caller's op proceeds."""
         sched = self._scheduler
-        slot = sched.lanes.get(lane)
-        if slot is None or (slot.swap is None and not slot.suspending):
+        if self._resident_now(lane):
             return
         async with self._lane_lock(lane):
             slot = sched.lanes.get(lane)
@@ -1684,6 +1717,52 @@ class DecodeBatcher:
         readings of ``time.perf_counter``: when its frame was read whole (None
         if nobody took that) and when the caller held the item."""
         t_enq = time.perf_counter()  # before _lane_busy: lock + alloc waits count as queue
+        self.stats["rpc_intake_queued"] += 1
+        self._count_return(lane, t_enq, arrived)
+        async with self._lane_busy(lane):
+            self._check_lane(lane)
+            if self.page_size is not None:
+                # grow the lane to cover this token BEFORE the device step —
+                # allocation can await a freed page; the step itself never
+                # blocks. alloc_timeout bounds the wait: without it, N
+                # sessions each needing one more page from an exhausted pool
+                # (and none willing to release) deadlock forever
+                await self.prepare_write(
+                    lane, int(position), int(position) + 1,
+                    timeout=self.alloc_timeout,
+                )
+            fut = asyncio.get_running_loop().create_future()
+            self._enqueue(lane, hidden, position, fut, t_enq)
+            return await fut
+
+    def begin_step(
+        self, lane: int, hidden: np.ndarray, position: int, fut: asyncio.Future,
+        arrived: Optional[Tuple[Optional[float], float]] = None,
+    ) -> bool:
+        """``step()`` up to its await, for a caller that may not wait (the
+        connection's reader, on its own turn): the token is queued for the
+        next batched step, which resolves ``fut`` as it would ``step()``'s
+        own, and the caller owes an ``end_step(lane)`` once ``fut`` is done.
+        False, with nothing changed, where ``step()`` would have had to wait
+        or to raise: a lane swapped out or on its way out, a pool that was
+        reset, a page to take from an empty pool or to fork."""
+        if not self._resident_now(lane) or self._lane_generation.get(lane) != self._generation:
+            return False
+        if self.page_size is not None and not self._own_page_now(lane, int(position)):
+            return False
+        t_enq = time.perf_counter()
+        self.stats["rpc_intake_direct"] += 1
+        self._count_return(lane, t_enq, arrived)
+        self._enter_lane(lane)
+        self._enqueue(lane, hidden, position, fut, t_enq)
+        return True
+
+    def end_step(self, lane: int) -> None:
+        """A ``begin_step`` 's future is done (or given up): the lane is no
+        longer held for it."""
+        self._leave_lane(lane)
+
+    def _count_return(self, lane: int, t_enq: float, arrived) -> None:
         back = self._returns.get(lane)
         if back is not None and back.replied is not None:
             # the lane is back from a decode reply: the trip's length, and
@@ -1698,28 +1777,16 @@ class DecodeBatcher:
             back.came_back(t_enq)
             self._back_since_step = True
             self._gather_wake.set()  # no longer expected, even if a page wait holds it up
-        async with self._lane_busy(lane):
-            self._check_lane(lane)
-            if self.page_size is not None:
-                # grow the lane to cover this token BEFORE the device step —
-                # allocation can await a freed page; the step itself never
-                # blocks. alloc_timeout bounds the wait: without it, N
-                # sessions each needing one more page from an exhausted pool
-                # (and none willing to release) deadlock forever
-                await self.prepare_write(
-                    lane, int(position), int(position) + 1,
-                    timeout=self.alloc_timeout,
-                )
-            fut = asyncio.get_running_loop().create_future()
-            self._enq_t[lane] = t_enq  # written under _lane_busy: no overwrite race
-            self._pending.append((lane, hidden, int(position), fut, self._generation))
-            # its way back is on record from here until release_lane: the
-            # reply of a step in flight finds no entry for a lane released
-            # meanwhile, so the next tenant starts without a history
-            self._returns.setdefault(lane, _LaneReturn())
-            self._gather_wake.set()
-            self._spawn_flush_loop()
-            return await fut
+
+    def _enqueue(self, lane: int, hidden: np.ndarray, position: int, fut: asyncio.Future, t_enq: float) -> None:
+        self._enq_t[lane] = t_enq  # written under _lane_busy: no overwrite race
+        self._pending.append((lane, hidden, int(position), fut, self._generation))
+        # its way back is on record from here until release_lane: the
+        # reply of a step in flight finds no entry for a lane released
+        # meanwhile, so the next tenant starts without a history
+        self._returns.setdefault(lane, _LaneReturn())
+        self._gather_wake.set()
+        self._spawn_flush_loop()
 
     def _spawn_flush_loop(self) -> None:
         """(Re)start the flush loop if it is not already draining. The strong

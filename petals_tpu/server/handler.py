@@ -37,7 +37,7 @@ from petals_tpu import chaos
 from petals_tpu.data_structures import CHAIN_DELIMITER, ModuleUID, parse_uid
 from petals_tpu.rpc.protocol import validate_gen_sampling
 from petals_tpu.rpc.serialization import deserialize_array, serialize_array, CompressionType
-from petals_tpu.rpc.server import RpcContext, RpcServer
+from petals_tpu.rpc.server import RpcContext, RpcServer, StreamRequests
 from petals_tpu.server.backend import TransformerBackend
 from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.task_queue import (
@@ -60,6 +60,128 @@ from petals_tpu.utils.misc import is_dummy
 from petals_tpu.utils.tracing import device_annotation, get_tracer
 
 logger = get_logger(__name__)
+
+
+class _TakenStep:
+    """A plain decode step that the connection's reader handed to the batcher
+    in the turn that read its frame, while its session's loop was parked
+    (``rpc/server.py StreamRequests.sink``): what the loop holds at its own
+    ``batcher.step`` for a step that came through the queue, and, once the
+    batcher has replied, the step's ``out``."""
+
+    __slots__ = ("step", "read_at", "held_at", "hidden", "t_tok", "span", "batcher", "lane", "out")
+
+    def __init__(self, step, read_at, held_at, hidden, t_tok, span, batcher, lane):
+        self.step, self.read_at, self.held_at, self.hidden, self.t_tok = step, read_at, held_at, hidden, t_tok
+        self.span, self.batcher, self.lane = span, batcher, lane
+        self.out = None
+
+    def close(self) -> None:
+        """The step's future is done, or given up: what leaving ``with span: await batcher.step(...)`` does."""
+        self.span.__exit__(None, None, None)
+        self.batcher.end_step(self.lane)
+
+
+_PLAIN_STEP_KEYS, _PLAIN_STEP_TENSORS = frozenset(("tensors", "step_id")), frozenset(("hidden",))
+
+
+def _is_plain_decode_step(item, batch_size: int) -> bool:
+    """One new token's hidden state a sequence and, at most, a step id: no
+    prompts, no hypo_ids, no rollback, no push_to, no kv to install, nothing
+    to generate. Read off the wire form, before anything is unpacked."""
+    if not isinstance(item, dict) or not item.keys() <= _PLAIN_STEP_KEYS:
+        return False
+    tensors = item.get("tensors")
+    if not isinstance(tensors, dict) or tensors.keys() != _PLAIN_STEP_TENSORS or not isinstance(tensors["hidden"], dict):
+        return False
+    shape = tensors["hidden"].get("shape")
+    return isinstance(shape, list) and len(shape) == 3 and shape[:2] == [batch_size, 1]
+
+
+def _expire(fut: asyncio.Future, *why: str) -> None:
+    if not fut.done():
+        fut.set_exception(asyncio.TimeoutError(*why))
+
+
+class _StepSource:
+    """Where a session's loop gets its next step: the client's stream or the
+    push queue, whichever has one first. Pending getters persist across calls
+    (no per-step task churn, no cancelled-task noise at teardown). Pulls
+    straight from the request iterator — no intermediate buffer, so the
+    transport's bounded inbound queue is the *only* buffer and its
+    backpressure actually engages for flooding peers.
+
+    The loop waits on ONE future, ``parked``. A getter that finishes resolves
+    it, the session's timeout fails it, and a sink that took a decode step in
+    the reader's turn gives it to the batcher as that step's own future
+    (``take``): the batcher's reply then wakes the loop exactly as it wakes a
+    caller of ``batcher.step``, and ``next()`` hands back the ``_TakenStep``."""
+
+    def __init__(self, requests, push_queue, timeout: float):
+        self._requests, self._push_queue, self._timeout = requests, push_queue, timeout
+        self._pending: Dict[str, asyncio.Task] = {}
+        self.parked: Optional[asyncio.Future] = None  # the loop waits, and no step of its is with the batcher
+        self._taken: Optional[_TakenStep] = None
+        self._timer: Optional[asyncio.TimerHandle] = None
+
+    async def _next_client(self):
+        try:
+            item = await anext(self._requests)
+        except StopAsyncIteration:
+            return None, None  # client half-closed
+        except Exception as e:
+            logger.debug("Client stream error (treating as half-close): %r", e)
+            return None, None
+        # when the RPC server read this item's frame (its per-stream
+        # object; any other iterator keeps no such time)
+        return item, getattr(self._requests, "read_at", None)
+
+    def _wake(self, _getter) -> None:
+        if self.parked is not None and not self.parked.done():
+            self.parked.set_result(None)
+
+    def _get(self, name: str, coro) -> None:
+        if name not in self._pending:
+            task = self._pending[name] = asyncio.create_task(coro())
+            task.add_done_callback(self._wake)
+
+    def take(self, taken: _TakenStep, timeout: float) -> None:
+        """``parked`` went to the batcher with ``taken``: it is that step's future now, under a step's timeout."""
+        fut, self.parked, self._taken = self.parked, None, taken
+        self._timer.cancel()
+        self._timer = fut.get_loop().call_later(timeout, _expire, fut)
+
+    async def next(self):
+        """(step, when the RPC server read its frame), or (a ``_TakenStep`` with its ``out``, the same)."""
+        self._get("client", self._next_client)
+        if self._push_queue is not None:
+            self._get("push", self._push_queue.get)
+        if not any(task.done() for task in self._pending.values()):
+            loop = asyncio.get_running_loop()
+            fut = self.parked = loop.create_future()
+            self._timer = loop.call_later(self._timeout, _expire, fut, "No inference step within session_timeout")
+            try:
+                out = await fut
+            finally:
+                self.parked = None
+                self._timer.cancel()
+                taken, self._taken = self._taken, None
+                if taken is not None:
+                    taken.close()
+            if taken is not None:
+                taken.out = out
+                return taken, taken.read_at
+        name = next(name for name, task in self._pending.items() if task.done())
+        result = self._pending.pop(name).result()
+        # a pushed step came over no stream of this call's, so nobody here read its frame
+        return result if name == "client" else (result, None)
+
+    async def cleanup(self) -> None:
+        for task in self._pending.values():
+            task.cancel()
+            with contextlib.suppress(asyncio.CancelledError, Exception):
+                await task
+        self._pending.clear()
 
 
 class TransformerHandler:
@@ -1554,18 +1676,57 @@ class TransformerHandler:
                 "open_wait_s": round(open_wait_s, 6),
             }
 
-            next_step, cleanup_steps = self._step_source(
-                requests, push_queue, self.session_timeout
-            )
+            steps = _StepSource(requests, push_queue, self.session_timeout)
             seen_steps = set()  # dedup: the same step may arrive via client AND push
             pending_store = None  # in-flight prefix-cache store task
             reply_build = None  # the annotation around a decode reply in the making
+
+            def take_decode_step(item, read_at: float) -> bool:
+                """This stream's sink (``StreamRequests.sink``): the connection's
+                reader offers an item in the turn that read its frame. A plain
+                decode step that finds this loop parked is parsed and handed to
+                the batcher here, as the loop does below up to ``batcher.step``,
+                and the loop wakes with the step's output; anything the loop
+                would have to wait for, re-order or refuse is left to the queue
+                and to the loop (False), with nothing changed."""
+                held_at = time.perf_counter()
+                fut = steps.parked
+                if (
+                    fut is None or fut.done() or pending_store is not None
+                    or self.draining or chaos.ENABLED
+                    or not _is_plain_decode_step(item, batch_size)
+                    or item.get("step_id") in seen_steps or position >= max_length
+                ):
+                    return False
+                hidden = self._get_tensor(item, "hidden")
+                if hidden is None:
+                    return False
+                self._validate_step_tensors(hidden, None, None, batch_size, end - start)
+                t_tok = time.perf_counter()
+                if not batcher.begin_step(lane, hidden, position, fut, arrived=(read_at, held_at)):
+                    return False
+                span = get_tracer().span(
+                    "inference_step", annotate=False, trace_id=trace_id,
+                    blocks=end - start, batch=batch_size, seq=1,
+                )
+                span.__enter__()
+                steps.take(_TakenStep(item, read_at, held_at, hidden, t_tok, span, batcher, lane), self.step_timeout)
+                return True
+
+            if lane is not None and isinstance(requests, StreamRequests):
+                requests.sink = take_decode_step
             try:
               while True:
-                step, t_read = await next_step()
+                step, t_read = await steps.next()
+                # a decode step the reader handed to the batcher while this
+                # loop was parked: its output is in hand, and what follows
+                # finds in it nothing to wait for, to install or to parse
+                taken = step if isinstance(step, _TakenStep) else None
+                if taken is not None:
+                    step = taken.step
                 # serving clock for this step's step_meta: receipt -> reply
                 # ready (everything the client's wall covers except network)
-                t_step_recv = time.perf_counter()
+                t_step_recv = time.perf_counter() if taken is None else taken.held_at
                 # a later step may mutate the rows being stored (rollback,
                 # overwrite): finish the store first so content stays honest
                 if pending_store is not None:
@@ -1645,10 +1806,13 @@ class TransformerHandler:
                     yield {"position": position, "kv_import": True}
                     continue
 
-                hidden = self._get_tensor(step, "hidden")
-                prompts = self._get_tensor(step, "prompts")
-                hypo_ids = self._get_tensor(step, "hypo_ids")
-                self._validate_step_tensors(hidden, prompts, hypo_ids, batch_size, end - start)
+                if taken is not None:
+                    hidden, prompts, hypo_ids = taken.hidden, None, None  # parsed and held to the shapes by the sink
+                else:
+                    hidden = self._get_tensor(step, "hidden")
+                    prompts = self._get_tensor(step, "prompts")
+                    hypo_ids = self._get_tensor(step, "hypo_ids")
+                    self._validate_step_tensors(hidden, prompts, hypo_ids, batch_size, end - start)
                 seq = 0 if hidden is None else hidden.shape[1]
                 if hidden is not None and position + seq > max_length:
                     raise ValueError(
@@ -1818,10 +1982,10 @@ class TransformerHandler:
                 step_timing = None
                 step_fp = None  # fused activation fingerprint (integrity)
                 step_variant = "cached"
-                with get_tracer().span(
+                with contextlib.nullcontext() if taken is not None else get_tracer().span(
                     "inference_step", annotate=False, trace_id=trace_id,
                     blocks=end - start, batch=batch_size, seq=seq,
-                ):
+                ):  # a taken step's span ran from the sink to the batcher's reply
                     if exec_hidden.shape[1] == 0:
                         # the whole prefill was cached: no device work at all
                         out = prefix_out
@@ -1829,11 +1993,14 @@ class TransformerHandler:
                     elif lane is not None and seq == 1 and prompts is None and hypo_ids is None:
                         # the continuous-batching hot path: one token, coalesced
                         # with whatever other sessions are stepping right now
-                        t_tok = time.perf_counter()
-                        out = await asyncio.wait_for(
-                            batcher.step(lane, hidden, pos, arrived=(t_read, t_step_recv)),
-                            self.step_timeout,
-                        )
+                        if taken is not None:
+                            t_tok, out = taken.t_tok, taken.out
+                        else:
+                            t_tok = time.perf_counter()
+                            out = await asyncio.wait_for(
+                                batcher.step(lane, hidden, pos, arrived=(t_read, t_step_recv)),
+                                self.step_timeout,
+                            )
                         t_resumed = time.perf_counter()
                         if not step.get("gen_tokens"):
                             # a plain decode reply: nothing awaits from here
@@ -2250,62 +2417,15 @@ class TransformerHandler:
                             # never leak the store task holding the lane
                             pending_store.cancel()
                             raise
-                await cleanup_steps()
+                if isinstance(requests, StreamRequests):
+                    requests.sink = None  # and with it the stream's hold on this frame
+                await steps.cleanup()
                 if session_id:
                     self._push_queues.pop(session_id, None)
                     self._session_registry.pop(session_id, None)
                 # drop the ambient trace id (reset_trace_id tolerates the
                 # generator resuming under a different Context at teardown)
                 reset_trace_id(_trace_token)
-
-    @staticmethod
-    def _step_source(requests, push_queue, timeout):
-        """Callable yielding the next step from either the client stream or the
-        push queue. Pending getters persist across calls (no per-step task
-        churn, no cancelled-task noise at teardown). Pulls straight from the
-        request iterator — no intermediate buffer, so the transport's bounded
-        inbound queue is the *only* buffer and its backpressure actually
-        engages for flooding peers."""
-        pending: Dict[str, asyncio.Task] = {}
-
-        async def _next_client():
-            try:
-                item = await anext(requests)
-            except StopAsyncIteration:
-                return None, None  # client half-closed
-            except Exception as e:
-                logger.debug("Client stream error (treating as half-close): %r", e)
-                return None, None
-            # when the RPC server read this item's frame (its per-stream
-            # object; any other iterator keeps no such time)
-            return item, getattr(requests, "read_at", None)
-
-        async def next_step():
-            if "client" not in pending:
-                pending["client"] = asyncio.create_task(_next_client())
-            if push_queue is not None and "push" not in pending:
-                pending["push"] = asyncio.create_task(push_queue.get())
-            done, _ = await asyncio.wait(
-                set(pending.values()), timeout=timeout, return_when=asyncio.FIRST_COMPLETED
-            )
-            if not done:
-                await cleanup()
-                raise asyncio.TimeoutError("No inference step within session_timeout")
-            task = done.pop()
-            name = next(name for name, t in pending.items() if t is task)
-            del pending[name]
-            # (step, when the RPC server read its frame): a pushed step came
-            # over no stream of this call's, so nobody here read its frame
-            return task.result() if name == "client" else (task.result(), None)
-
-        async def cleanup():
-            for task in pending.values():
-                task.cancel()
-                with contextlib.suppress(asyncio.CancelledError, Exception):
-                    await task
-            pending.clear()
-
-        return next_step, cleanup
 
     async def _push_outputs(self, push_to: dict, wire_out, step_id, start_from, wire_hypo=None) -> None:
         """Forward our outputs straight to the next server in the chain

@@ -127,6 +127,7 @@ _BENCHMARK_AS_IT_STOOD = {
     "test_deepseek_v3_family": {"configs": "kanana2-30b-a3b-span6", "workloads": "kanana2-ctx32k", "per_layer": "latent_absorbed_row_share"},
     "test_qwen3_next_family": {"per_layer": "moe_chunk_rows_per_routed"},
     "test_jamba_family": {"per_layer": "ssm_one_step_row_share"},
+    "test_xing4_0_family": {"per_layer": "hc_stream_kib_per_row"},  # PR 60 appended `intake_direct_share` behind PR 59's three
 }
 
 

@@ -624,9 +624,11 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     # ... and since PR 51 every batcher the step bodies that sent the block tables to the device (_step_tables)
     # ... and since PR 54 the event loop's turns (utils/asyncio_utils.install_turn_clock)
     # ... and since PR 59 every batcher the bytes of hidden state its steps took in and handed back (_count_stream)
+    # ... and since PR 60 the decode steps by the way their request came in (begin_step / step)
     assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", "tables_sent",
                                                  "loop_busy_s", "loop_busy_sq", "loop_turns", "stream_bytes_in",
-                                                 "stream_bytes_out"} and batcher._n_state == 0 and batcher._state() == ()
+                                                 "stream_bytes_out", "rpc_intake_direct",
+                                                 "rpc_intake_queued"} and batcher._n_state == 0 and batcher._state() == ()
     assert not {"state_bytes_per_lane", "state_bytes_held"} & set(batcher.occupancy_info())
     # the step programs take the pair of pools and give the pair back, and carry what they carried
     k, v = (jnp.zeros(d.shape, d.dtype) for d in backend.paged_cache_descriptors(6, 8, 0, 2))
