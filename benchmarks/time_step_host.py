@@ -83,12 +83,7 @@ def main(argv=None) -> int:
     jax.block_until_ready(params)
     print(f"[time_step_host] {config['name']}: {depth} blocks made on {jax.devices()[0].device_kind} in {time.perf_counter() - t:.1f}s", file=sys.stderr, flush=True)
     backend = TransformerBackend(family, cfg, params, first_block=server_args["first_block"], n_blocks=depth, memory_cache=None, compute_dtype=dtype)
-    descs = (
-        *backend.paged_cache_descriptors(n_pages, page_size, 0, depth),
-        *backend.state_cache_descriptors(n_lanes),
-        *backend.index_cache_descriptors(n_pages, page_size),
-    )
-    pools = tuple(d.make_zeros() for d in descs)
+    pools = tuple(d.make_zeros() for d in backend.cache.pool_descriptors(n_pages, page_size, n_lanes, 0, depth))
     hsz = backend.hidden_size
     rng = np.random.default_rng(0)
     hidden = rng.standard_normal((n_lanes, 1, hsz)).astype(np.float32)
@@ -182,7 +177,7 @@ def main(argv=None) -> int:
 
     rows: dict = {
         "config": config["name"], "device": jax.devices()[0].device_kind, "lanes": n_lanes, "live": live, "steps": args.steps,
-        "leaves": leaves, "runs": len(backend.runs), "state_leaves": len(backend.lane_state), "switch_interval_ms": 1e3 * sys.getswitchinterval(),
+        "leaves": leaves, "runs": len(backend.runs), "state_leaves": len(backend.cache.lane_state), "switch_interval_ms": 1e3 * sys.getswitchinterval(),
     }
     run(rows, "")
 

@@ -9,8 +9,15 @@ data on the family, set where it is registered, and ``parallel/tp.py``,
 ``utils/convert_block.py`` and ``utils/peft.py`` read them through
 ``get_family``; a family built with ``dataclasses.replace`` over another
 (mistral, qwen2, phi3, gemma over llama) inherits them with no line of its
-own. So a new family of an existing kind touches ``models/<family>/``, one
-import in ``models/__init__.py``, and the benchmark's own data files.
+own. What a lane holds for a block (pages of keys and values, a state, an
+index row, a latent row, how many rows a block) is declared through the
+``block_*`` hooks below and read by ``server/span_cache.py`` alone
+(``SpanCache``: the layout, what is refused, the pools, the bytes, the
+counters). So a new family of an existing kind of cache touches
+``models/<family>/``, one import in ``models/__init__.py``, and the
+benchmark's own data files; a new KIND of cache touches
+``server/span_cache.py`` (a row of ``CONTENTS``, its fields, pools and
+counters) and the step program that carries it (``server/backend.py``).
 """
 
 from __future__ import annotations
@@ -77,7 +84,7 @@ class ModelFamily:
     block_kind: Optional[Callable] = None
     # (cfg, kind) -> the STATIC window of that kind's attention in positions,
     # None for full attention. A family that declares it has its gathered and
-    # held pages counted by the batcher (server/batching.py)
+    # held pages counted for the batcher (server/span_cache.py ``LanePool``)
     block_window: Optional[Callable] = None
     # (cfg, kind) -> what a lane holds for a block of that kind IN PLACE of pages of keys and values: a
     # state of fixed size whatever the context, as ``((shape, dtype), ...)`` a lane (dtype None: the
@@ -85,7 +92,7 @@ class ModelFamily:
     # pool of their own beside the pages, ``[that kind's layers, lanes, *shape]`` a leaf, and hands a
     # block its lanes' slices as ``kv``; a row at position 0 starts from zeros. A state cannot be cut
     # back to an earlier position, so what needs that (a rollback, a reused prefix, speculative
-    # verify) is refused for a family that declares one (server/backend.py ``state_layers``)
+    # verify) is refused for a family that declares one (server/span_cache.py ``SpanCache.refuse``; ``state_layers``)
     block_state: Optional[Callable] = None
     # (cfg, kind) -> what a position caches BESIDE its key and value in a block of that kind: an index row,
     # ``(width, dtype, keep)`` (dtype None: the cache's own), that a learned sparse attention scores to choose the
@@ -94,7 +101,7 @@ class ModelFamily:
     # tables, written, freed and reused with the pages of keys and values, and hands a block ``(k, v, index)``
     # as its ``kv``. Only the paged lane pool's decode, generation and mixed steps carry it; what does not
     # (a private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised
-    # pages, a tp mesh) is refused for a family that declares one (server/backend.py ``index_row``)
+    # pages, a tp mesh) is refused for a family that declares one (server/span_cache.py ``SpanCache``: ``index_row``)
     block_index: Optional[Callable] = None
     # (cfg, kind) -> what a position caches IN PLACE of its key and value in a block of that kind: a latent row,
     # ``(latent width, rotated key's width)``, one for all heads, that every head's key and value are linear in
@@ -103,7 +110,7 @@ class ModelFamily:
     # pool, the rotated key in the second, stored as an index row of its width is), and hands a block ``(c, k_pe)``
     # as its ``kv``. Only the paged lane pool's decode, generation and mixed steps carry it; what does not (a
     # private cache, the dense pool, swap, snapshots, a stored prefix, speculative verify, quantised pages, a tp
-    # mesh) is refused for a family that declares one (server/backend.py ``latent_row``)
+    # mesh) is refused for a family that declares one (server/span_cache.py ``SpanCache``: ``latent_row``)
     block_latent: Optional[Callable] = None
     # (cfg, kind) -> how many cache rows a position a block of that kind keeps: the number of attention
     # SUB-LAYERS in it, each with a cache of its own (a checkpoint layer that is two attentions and two
@@ -112,7 +119,7 @@ class ModelFamily:
     # tuple of that many ``kv``, one a sub-layer in order, each over the span's pools with its own layer's tables:
     # a sub-layer that writes hands the next the pools it wrote (``PagedKV._replace(pool=...)``) and the block
     # returns the tuple of what each returned. Served for a span whose positions cache a latent row
-    # (server/backend.py ``block_rows``)
+    # (server/span_cache.py ``SpanCache``: ``block_rows``)
     block_sublayers: Optional[Callable] = None
     # (cfg, kind) -> what a block of that kind hands its attention BEYOND the query, the cache, the causal mask and a
     # static window, as names out of ``ATTENTION_EXTRAS``: "alibi" (a bias a head on the scores), "softcap" (a soft
@@ -120,7 +127,7 @@ class ModelFamily:
     # ``block_window`` / ``cfg.sliding_window`` is a number the program knows). None: the plain call, nearly every
     # family's. What can only take the plain call goes by this and by nothing else of a family: the decode walk's
     # kernel over plain pages (ops/paged_flash_attention.py ``walk_kernel_unsupported``), so the path the batcher's
-    # counters count is the one the step runs (server/backend.py ``decode_walks``;
+    # counters count is the one the step runs (server/span_cache.py ``LanePool.walks``;
     # tests/test_paged_kernel.py holds every registered family's block to what it declares here)
     block_attention: Optional[Callable] = None
     # cfg -> what crosses the wire between two blocks where that is NOT a row of ``cfg.hidden_size``: ``(width, mixes)``,

@@ -8,7 +8,7 @@ head's key and value are linear in, and one rotated key of
     score[h, t, s] = (q_nope[t, h] . k_nope[s, h] + q_pe[t, h] . k_pe_s) * scale      (s <= t)
 
 On the lane pool the row lives in pages under the lane's block tables, in the
-place of keys and values (server/backend.py ``latent_row``): ``c`` a position
+place of keys and values (server/span_cache.py ``latent_row``): ``c`` a position
 a row of its pool, ``k_pe`` (64 wide, under the chip's 128 lanes) several
 positions to a row of its own, as an index row is stored
 (ops/sparse_attention.py ``index_pool_row``). Neither pool holds a key or a

@@ -238,7 +238,7 @@ def gated_delta_step_path(state, rows: int) -> str:
     """``"kernel"`` on a TPU backend for a call the kernel takes
     (``step_kernel_unsupported``), ``"plain"`` everywhere else: what a lane
     pool's decode rows run in ``gated_delta_pooled`` and what the batcher's
-    ``linattn_kernel_tokens`` counts (server/backend.py ``state_step_path``)
+    ``linattn_kernel_tokens`` counts (server/span_cache.py ``LanePool.state_step``)
     follow from this alone."""
     return "kernel" if _on_tpu() and step_kernel_unsupported(state, rows) is None else "plain"
 

@@ -14,7 +14,7 @@ Layout contract (per span block):
   device with the PAGE index minor, and every step program relaid both
   pools whole on the way in and on the way out (Falcon-40B: four copies of
   42 MB a step, PERF.md section 6, PR 38). Who makes a pool asks the rule
-  (server/backend.py ``paged_cache_descriptors``); every consumer here
+  (server/span_cache.py ``pool_descriptors``); every consumer here
   reads the form off the leaf it is handed: the scatters fold the new rows
   (``_flat_scatter``, ``scatter_lane_pages``), ``gather_pages`` unfolds the
   pages it took (never the pool), ``pool_geometry`` answers (n_pages,
@@ -121,7 +121,7 @@ def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
 
     Every consumer reads the form off the leaf it is handed (``pool_geometry``,
     ``fold_rows`` / ``unfold_rows``); only who MAKES a pool asks this function
-    (server/backend.py ``paged_cache_descriptors``)."""
+    (server/span_cache.py ``pool_descriptors``)."""
     return (hkv * d_store,) if d_store < LANES or hkv < MIN_ROW_HEADS else (hkv, d_store)
 
 

@@ -611,6 +611,45 @@ def walk_pages(needed: int, block: int) -> int:
     return _round_up(needed, block)
 
 
+def pages_walked(walks: tuple, last, page_size: int, n_lanes: int) -> tuple:
+    """``(read, by the kernel)``: table slots a decode step's programs read
+    over a span's layers and all lanes, its live lanes at the positions
+    ``last`` (a numpy array), and those of them the kernel's grid fetched.
+    ``walks`` is ``((window, layers, block, cut, path), ...)``, a distinct
+    attention call of the span's layers (server/span_cache.py
+    ``LanePool.walks``). Each layer walks its table (the slots in its window's
+    reach, if ``cut``) in blocks: the composed walk every lane of the pool's
+    up to the block that holds the longest lane's last row, the kernel each
+    live lane from the block of its first position in sight to its own last
+    one (``composed_paged_attend``, whose arithmetic this is). For the
+    batcher's ``attn_pages_gathered`` / ``attn_pages_kernel``, a step, on the
+    host's serial part: a reduction or two over the lanes and integer
+    arithmetic a walk."""
+    read = by_kernel = 0
+    longest = -1
+    for window, layers, block, cut, path in walks:
+        if path == "kernel" and not window:
+            # a lane reads the blocks up to its last row's: ``last // (block * page_size) + 1`` of them
+            walked = layers * block * (int((last // (block * page_size)).sum()) + last.size)
+            by_kernel += walked
+        elif path == "kernel":
+            first = np.maximum(last - (window - 1), 0) // page_size  # a lane's first slot in sight
+            walked = walk_pages(last // page_size + 1 - (first if cut else 0), block)  # as the walk is handed its table
+            if not cut:
+                walked = walked - first // block * block  # whole blocks before the window's reach
+            walked = layers * int(walked.sum())
+            by_kernel += walked
+        else:
+            if longest < 0:
+                longest = int(last.max())
+            needed = longest // page_size + 1
+            if cut:  # each lane's slots count from its own window's first one
+                needed = int(np.max(last // page_size - np.maximum(last - (window - 1), 0) // page_size)) + 1
+            walked = layers * n_lanes * walk_pages(needed, block)
+        read += walked
+    return read, by_kernel
+
+
 def _walk_decode_rows(
     q, k_pool, v_pool, tables, *, q_pos, kv_len, alibi_slopes, sliding_window, scale, logit_softcap,
 ):
@@ -794,7 +833,7 @@ def decode_walk_path(k_pool, q_shape, tables_shape, *, alibi: bool = False, soft
     the pool), a folded row of heads under 128 lanes (the last step keeps
     whole lanes), ALiBi, a soft cap, a traced window, tables over the scalar
     memory. What a decode row's walk in ``composed_paged_attend`` runs and what
-    the batcher's counters count (server/backend.py ``decode_walks``) follow
+    the batcher's counters count (server/span_cache.py ``LanePool.walks``) follow
     from this alone."""
     unsupported = walk_kernel_unsupported(k_pool, q_shape, tables_shape, alibi=alibi, softcap=softcap, window=window)
     return "kernel" if _on_tpu() and unsupported is None else "composed"

@@ -23,7 +23,6 @@ TPU-first redesign:
 
 from __future__ import annotations
 
-import collections
 import contextlib
 import dataclasses
 import functools
@@ -39,27 +38,15 @@ from petals_tpu.models.registry import ModelFamily, kind_label, span_runs
 from petals_tpu.ops import fingerprint as fp_ops
 from petals_tpu.ops.sampling import sample_tokens, sampling_vectors
 from petals_tpu.server.memory_cache import MemoryCache, TensorDescriptor
+from petals_tpu.server.span_cache import SpanCache, cache_kv_heads
 from petals_tpu.telemetry.observatory import tracked_jit
 from petals_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
 
 PREFILL_BUCKETS = (8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
-# why a span with a recurrent state takes no draft model (``refuse_for_state``: here, the batcher, the server)
+# why a span with a recurrent state takes no draft model (``SpanCache.refuse``: here, the batcher, the server)
 SPEC_CUTS_BACK = "a rejected draft is rolled back by cutting the cache to the last accepted position, and a state cannot be cut back"
-
-
-# why a path that handles keys and values alone refuses a span with an index row (``refuse_for_state``)
-INDEX_ROWS_RIDE = (
-    "only the paged lane pool's decode, generation and mixed steps carry the index rows' pages; a cache without "
-    "them would choose from nothing"
-)
-
-# and why one refuses a span whose positions cache a latent row in place of keys and values
-LATENT_ROWS_RIDE = (
-    "only the paged lane pool's decode, generation and mixed steps carry the latent rows' pages; every other "
-    "cache is laid out for keys and values a head, which such a span never makes"
-)
 
 
 def bucket_length(n: int) -> int:
@@ -132,8 +119,7 @@ class TransformerBackend:
             # partitioning rule for Mosaic custom calls, shard_map sidesteps it
         self.use_flash = use_flash
 
-        # a family may keep more kv heads in its cache than it publishes (heads of zeros, for the device's layout)
-        self.num_kv_heads = getattr(cfg, "cache_kv_heads", None) or getattr(cfg, "num_key_value_heads", cfg.num_attention_heads)
+        self.num_kv_heads = cache_kv_heads(cfg)
         self.head_dim = cfg.head_dim
         # what crosses the wire between two blocks: ``cfg.hidden_size``, or a residual stream of several rows, flat, for
         # a family that declares one (ModelFamily.block_stream), with the times a block mixes it (0: no stream). Every
@@ -157,55 +143,10 @@ class TransformerBackend:
         # every kind of block that has one)
         dims = [family.moe_dims_for(cfg, kind) for kind, _, _ in self.runs]
         self.moe_dims = next((d for d in dims if d is not None), None)
-        # a family that declares its layers' static windows: one per block of the span (None: full
-        # attention); None for every other family
-        self.layer_windows = None
-        if family.block_window is not None:
-            self.layer_windows = [family.block_window(cfg, kind) for kind, _, length in self.runs for _ in range(length)]
-        # what a lane holds for each block: pages of keys and values, or, for a kind whose family declares
-        # a state (ModelFamily.block_state), a state of fixed size in a pool of its own beside the pages.
-        # kv_layers / state_layers: the span's blocks of either sort, in order; _slots[i]: block i's place
-        # among its own sort, which is its layer in its pool; lane_state: a state's leaves, (shape, dtype)
-        states = [family.state_for(cfg, kind) for kind, _, length in self.runs for _ in range(length)]
-        self.state_layers = [i for i, state in enumerate(states) if state is not None]
-        self.kv_layers = [i for i, state in enumerate(states) if state is None]
-        self._slots = [(self.kv_layers if state is None else self.state_layers).index(i) for i, state in enumerate(states)]
-        self.lane_state = tuple(
-            (shape, jnp.dtype(dtype or self.cache_dtype)) for shape, dtype in next((s for s in states if s is not None), ())
-        )
-        if self.state_layers:
-            self._check_state(states, mesh)
-        # what a position caches BESIDE its key and value in the span's blocks (ModelFamily.block_index):
-        # an index row, (width, dtype), kept in a third page pool under the lanes' tables, and ``index_keep``,
-        # the positions a row's selection keeps; None for a span without one. Every block that keeps keys and
-        # values then keeps one
-        rows = {family.index_for(cfg, kind) for kind, _, _ in self.runs}
-        self.index_row = None
-        if rows != {None}:
-            self._check_index(rows, mesh)
-            width, dtype, keep = next(iter(rows))
-            self.index_row, self.index_keep = (int(width), jnp.dtype(dtype or self.cache_dtype)), int(keep)
-        # what a position caches IN PLACE of its key and value in the span's blocks (ModelFamily.block_latent):
-        # one row for all heads, (latent width, rotated key's width), kept where the pages of keys and values
-        # would lie: the first pool holds the latents, the second the rotated keys (``paged_cache_descriptors``);
-        # None for a span that caches keys and values
-        rows = {family.latent_for(cfg, kind) for kind, _, _ in self.runs}
-        self.latent_row = None
-        if rows != {None}:
-            self._check_latent(rows, mesh)
-            self.latent_row = tuple(int(width) for width in next(iter(rows)))
-        # how many cache rows a position a block keeps (ModelFamily.block_sublayers: the attention sub-layers of
-        # a block, each with pages of its own): the page pools hold ``page_layers`` layers of pages, a block's
-        # one after the other, and everything that multiplies by layers of pages multiplies by that
-        counts = {family.sublayers_for(cfg, kind) for kind, _, _ in self.runs}
-        self.block_rows = next(iter(counts)) if len(counts) == 1 else 0
-        if self.block_rows != 1 and (self.block_rows < 1 or self.latent_row is None):
-            raise NotImplementedError(
-                f"{family.name}: more than one cache row a position a block ({sorted(counts)} sub-layers) is served for a "
-                f"span whose blocks all keep the same number of latent rows: keys' and values' pages, a state and an index "
-                f"row are laid out one layer a block"
-            )
-        self.page_layers = len(self.kv_layers) * self.block_rows
+        # what a lane holds for each block of the span, beside or in place of pages of keys and values: the layout the
+        # step programs read at trace time, what is refused for it, its pools, its bytes and its counters
+        # (server/span_cache.py, which alone asks the family's hooks and refuses here what is declared and not served)
+        self.cache = SpanCache(family, cfg, self.runs, cache_dtype=self.cache_dtype, kv_quant_type=kv_quant_type, mesh=mesh)
         # adapter name -> (stacked {leaf: (A, B)}, scaling); see utils/peft.py
         self.adapters: Dict[str, tuple] = {}
         self._dummy_operands: Dict[tuple, jax.Array] = {}
@@ -253,80 +194,6 @@ class TransformerBackend:
         if any(isinstance(leaf, QuantizedLinear)
                for leaf in jax.tree_util.tree_leaves(params, is_leaf=lambda x: isinstance(x, QuantizedLinear))):
             raise NotImplementedError(f"{name}: a span of more than one kind of block is not served quantized yet")
-
-    def _check_state(self, states, mesh) -> None:
-        """A span with a recurrent state: what it cannot do yet is refused
-        here, with the reason, not served wrong."""
-        name = self.family.name
-        if len({state for state in states if state is not None}) != 1:
-            raise NotImplementedError(f"{name}: the span's kinds of block declare states of different shapes")
-        if mesh is not None:
-            raise NotImplementedError(f"{name}: a span with a recurrent state is not served over a tp mesh yet")
-        if self.kv_quant_type != "none":
-            raise NotImplementedError(
-                f"{name}: kv_quant_type {self.kv_quant_type!r} is not served for a span with a recurrent state: "
-                f"the state is float32 and has no packed form"
-            )
-
-    def _check_index(self, rows, mesh) -> None:
-        """A span whose positions cache an index row: what it cannot do yet
-        is refused here, with the reason, not served wrong."""
-        name = self.family.name
-        if len(rows) != 1 or self.state_layers:
-            raise NotImplementedError(f"{name}: an index row is served for a span whose blocks all cache the same one")
-        if mesh is not None:
-            raise NotImplementedError(
-                f"{name}: a span whose positions cache an index row is not served over a tp mesh yet: "
-                f"the row has one head, and the dense lane pool a mesh falls back to has no place for it"
-            )
-        if self.kv_quant_type != "none":
-            raise NotImplementedError(
-                f"{name}: kv_quant_type {self.kv_quant_type!r} is not served for a span whose positions cache an "
-                f"index row: the selection fetches single rows of the pool, which has no packed form for that yet"
-            )
-
-    def _check_latent(self, rows, mesh) -> None:
-        """A span whose positions cache a latent row: what it cannot do yet
-        is refused here, with the reason, not served wrong."""
-        name = self.family.name
-        if len(rows) != 1 or self.state_layers or self.index_row is not None:
-            raise NotImplementedError(f"{name}: a latent row is served for a span whose blocks all cache the same one and nothing else")
-        if mesh is not None:
-            raise NotImplementedError(
-                f"{name}: a span whose positions cache a latent row is not served over a tp mesh yet: the row is "
-                f"one for all heads, and the dense lane pool a mesh falls back to is laid out for keys and values a head"
-            )
-        if self.kv_quant_type != "none":
-            raise NotImplementedError(
-                f"{name}: kv_quant_type {self.kv_quant_type!r} is not served for a span whose positions cache a "
-                f"latent row: the pages' packed forms are of keys and values a head, with a scale a head"
-            )
-
-    def refuse_for_state(self, what: str, why: str) -> None:
-        """Raise for ``what`` if this span keeps a recurrent state: a state
-        holds a whole history at one position and cannot be cut back to an
-        earlier one, so what needs that, and the cache paths that do not
-        carry a state at all, are refused by what the family declares. The
-        same paths carry no index row (``index_row``: a third page pool that
-        only the paged lane pool's decode, generation and mixed steps are
-        handed) and no latent row (``latent_row``: pages of another shape
-        than keys' and values'), so a span that caches either is refused
-        there too, with its own reason."""
-        if self.state_layers:
-            raise NotImplementedError(
-                f"{self.family.name}: {what} is not served for a span with a recurrent state "
-                f"({len(self.state_layers)} of its {self.n_blocks} blocks keep one): {why}"
-            )
-        if self.index_row is not None:
-            raise NotImplementedError(
-                f"{self.family.name}: {what} is not served for a span whose positions cache an index row beside "
-                f"their keys and values ({self.index_row[0]} wide, the key a learned sparse attention scores): {INDEX_ROWS_RIDE}"
-            )
-        if self.latent_row is not None:
-            raise NotImplementedError(
-                f"{self.family.name}: {what} is not served for a span whose positions cache a latent row in place of "
-                f"their keys and values ({' + '.join(map(str, self.latent_row))} wide, one for all heads): {LATENT_ROWS_RIDE}"
-            )
 
     def refuse_deep_prompts(self, prompts) -> None:
         """Raise for deep prompts over a span whose hidden state is a stream
@@ -418,7 +285,7 @@ class TransformerBackend:
         """(k, v) descriptors for blocks [start, end) of this span; under TP the
         kv-head axis is sharded over the mesh (reference backend.py:88-99's
         per-shard descriptors, expressed as one NamedSharding)."""
-        self.refuse_for_state(
+        self.cache.refuse(
             "a private cache or a dense lane pool",
             "only the paged lane pool carries the state (a session of batch size 1 over the whole span with no "
             "adapter and a max_length within the lanes' length takes a lane)",
@@ -436,53 +303,6 @@ class TransformerBackend:
             TensorDescriptor(shape, self.cache_dtype, sharding),
             TensorDescriptor(shape, self.cache_dtype, sharding),
         )
-
-    def paged_cache_descriptors(self, n_pages: int, page_size: int, start: int, end: int):
-        """Descriptors for the PAGED pool of blocks [start, end). Unquantized:
-        (k, v), each [n, n_pages, page_size, hkv, d] in cache_dtype. Quantized
-        (kv_quant_type != none): (k_codes, v_codes, k_scales, v_scales) — the
-        codes in the storage dtype (int8, or uint8 with two split-half-packed
-        dims per byte for nf4a) and f32 absmax scales per (page row, kv head).
-        A values or codes leaf whose row is under the chip's 128 lanes
-        (head_dim 64; 128 too for nf4a's packed half) is stored with the kv
-        heads folded into it, [n, n_pages, page_size, hkv * d_store]
-        (``pool_row``; the rule: ops/paged_attention.py ``stored_row``).
-        The paged path is gated to mesh-less single-host servers
-        (server/batching.py), so no sharding rides these. The pool is as deep
-        as the blocks of [start, end) that keep keys and values: a block with
-        a state of its own (``state_cache_descriptors``) has no pages."""
-        n = sum(start <= i < end for i in self.kv_layers) * self.block_rows
-        if self.latent_row is not None:
-            # a latent row in place of keys and values: the latents a position a row, and the rotated keys stored
-            # as an index row of their width is (ops/latent_attention.py ``latent_pool_rows``); stored once
-            from petals_tpu.ops.latent_attention import latent_pool_rows
-
-            return tuple(TensorDescriptor((n, n_pages, *row), self.cache_dtype) for row in latent_pool_rows(page_size, *self.latent_row))
-        shape = (n, n_pages, page_size, *self.pool_row)
-        if self.kv_quant_type == "none":
-            return (
-                TensorDescriptor(shape, self.cache_dtype),
-                TensorDescriptor(shape, self.cache_dtype),
-            )
-        codes_shape, codes_dtype = shape, jnp.int8 if self.kv_quant_type == "int8" else jnp.uint8
-        scales_shape = (n, n_pages, page_size, self.num_kv_heads)
-        return (
-            TensorDescriptor(codes_shape, codes_dtype),
-            TensorDescriptor(codes_shape, codes_dtype),
-            TensorDescriptor(scales_shape, jnp.float32),
-            TensorDescriptor(scales_shape, jnp.float32),
-        )
-
-    @functools.cached_property
-    def pool_row(self) -> tuple:
-        """The trailing dims the page pool's values (or codes) keep a token
-        row in: ``(hkv, d_store)``, or ``(hkv * d_store,)`` where the rule
-        folds it (ops/paged_attention.py ``stored_row``). Fixed at start."""
-        from petals_tpu.ops.paged_attention import stored_row
-
-        if self.latent_row is not None:  # one row for all heads, in two pools (``paged_cache_descriptors``)
-            return (sum(self.latent_row),)
-        return stored_row(self.num_kv_heads, self.head_dim // 2 if self.kv_quant_type == "nf4a" else self.head_dim)
 
     def pool_to_wire(self, pages):
         """Pages taken out of the stacked pool (``[n_blocks, n_slots,
@@ -505,133 +325,6 @@ class TransformerBackend:
         if isinstance(pages, PagedPool):
             return PagedPool(fold_rows(pages.codes, pool.codes.shape[3:]), pages.scales)
         return fold_rows(pages, pool.shape[3:])
-
-    def state_cache_descriptors(self, n_lanes: int) -> tuple:
-        """Descriptors of the STATE pool beside the pages: one a leaf of the
-        family's state, ``[state layers, n_lanes, *shape]``; none for a span
-        whose blocks all keep keys and values."""
-        return tuple(TensorDescriptor((len(self.state_layers), n_lanes, *shape), dtype) for shape, dtype in self.lane_state)
-
-    def state_step_path(self, n_lanes: int) -> str:
-        """``"kernel"`` or ``"plain"``: what a lane pool's decode rows run in
-        the span's state layers, the kernel that moves each live lane's
-        matrices once where they lie in the state pool or the plain form on a
-        layer's slice of it. ops/linear_attention.py ``gated_delta_step_path``
-        is asked here as ``gated_delta_pooled`` asks it in the step: with the
-        pool as the step programs carry it (``state_cache_descriptors``) and
-        one row a lane. Fixed with the pool's geometry: the batcher asks once,
-        for its ``linattn_kernel_tokens``."""
-        from petals_tpu.ops.linear_attention import StatePool, gated_delta_step_path
-
-        leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in self.state_cache_descriptors(n_lanes))
-        return gated_delta_step_path(StatePool(leaves, 0), 1)
-
-    def index_cache_descriptors(self, n_pages: int, page_size: int) -> tuple:
-        """The descriptor of the INDEX pool beside the page pools of keys and
-        values, ``[kv layers, n_pages, *row]``: a page of it is a page of
-        theirs, under the same block tables, its positions' rows of ``width``
-        stored as ops/sparse_attention.py ``index_pool_row`` says (a row under
-        the chip's 128 lanes: several positions to a row of 128); none for a
-        span without an index row."""
-        if self.index_row is None:
-            return ()
-        from petals_tpu.ops.sparse_attention import index_pool_row
-
-        width, dtype = self.index_row
-        return (TensorDescriptor((len(self.kv_layers), n_pages, *index_pool_row(page_size, width)), dtype),)
-
-    def index_bytes_per_token(self) -> int:
-        """What a position caches across the span beside its keys and values:
-        its index rows. 0 for a span without one."""
-        if self.index_row is None:
-            return 0
-        width, dtype = self.index_row
-        return len(self.kv_layers) * width * dtype.itemsize
-
-    def sparse_reads(self, n_lanes: int, max_pages: int, page_size: int, last: np.ndarray, chunk=None) -> dict:
-        """What one paged step's programs do for a span whose blocks select
-        (``index_row``), over its layers, from the shapes the step is started
-        with: the live lanes' rows at positions ``last`` and the ``chunk``
-        (first position, tokens) of a mixed step. For the batcher's
-        ``sparse_*`` counters (ops/sparse_attention.py has the arithmetic)."""
-        from petals_tpu.ops.sparse_attention import chunk_reads, decode_reads
-
-        topk, layers = self.index_keep, len(self.kv_layers)
-        selects = max_pages * page_size > topk  # a table that cannot pass topk positions is attended to whole
-        contexts = [int(p) + 1 for p in last]
-        scored = read = pairs = 0
-        if contexts:
-            scored, read = decode_reads(n_lanes, max_pages, page_size, topk, max(contexts)) if selects else (0, sum(contexts))
-            pairs = scored  # one query row a lane
-        held, over, rows = sum(contexts), sum(c > topk for c in contexts), len(contexts)
-        if chunk is not None:
-            first, take = chunk
-            c_scored, c_pairs, c_read = (
-                chunk_reads(max_pages, page_size, topk, first, take, bucket_length(take)) if selects else (0, 0, first + take)
-            )
-            scored, pairs, read, held = scored + c_scored, pairs + c_pairs, read + c_read, held + first + take
-            over, rows = over + max(first + take - max(first, topk), 0), rows + take
-        return {"sparse_rows_selected": over * layers, "sparse_rows_dense": (rows - over) * layers,
-                "sparse_index_rows_scored": scored * layers, "sparse_score_pairs": pairs * layers,
-                "sparse_kv_rows_read": read * layers, "sparse_kv_rows_held": held * layers}
-
-    def latent_reads(self, n_lanes: int, max_pages: int, page_size: int, last: np.ndarray, chunk=None) -> dict:
-        """What one paged step's programs do for a span whose positions
-        cache a latent row (``latent_row``), over its layers, from the shapes
-        the step is started with: the live lanes' rows at positions ``last``
-        (the absorbed form) and the ``chunk`` (first position, tokens) of a
-        mixed step (the expanded one). For the batcher's ``latent_*`` counters
-        (ops/latent_attention.py has the arithmetic)."""
-        from petals_tpu.ops.latent_attention import chunk_reads, decode_path, decode_reads, latent_pool_rows
-
-        layers = self.page_layers
-        contexts = [int(p) + 1 for p in last]
-        kernel = decode_path(*latent_pool_rows(page_size, *self.latent_row), self.cache_dtype) == "kernel"
-        read = decode_reads(n_lanes, max_pages, page_size, contexts, kernel=kernel)
-        pairs = sum(contexts)
-        expanded = held = rows = 0
-        if chunk is not None:
-            first, rows = chunk
-            expanded, held = chunk_reads(max_pages, page_size, first, rows), first + rows
-            pairs += rows * first + rows * (rows + 1) // 2  # each row of the chunk against the positions up to its own
-        return {"latent_rows_read": read * layers, "latent_rows_held": sum(contexts) * layers,
-                "latent_rows_absorbed": len(contexts) * layers, "latent_rows_expanded": rows * layers,
-                "latent_positions_expanded": expanded * layers, "latent_positions_held": held * layers,
-                "latent_score_pairs": pairs * layers}
-
-    def state_bytes_per_lane(self) -> int:
-        """What a lane holds whatever its context: its states over the span's
-        state layers. 0 for a span without one."""
-        return len(self.state_layers) * sum(int(np.prod(shape)) * dtype.itemsize for shape, dtype in self.lane_state)
-
-    def cache_bytes_per_token(self) -> int:
-        """LOGICAL (dense fp) bytes per token across the span's blocks that
-        keep keys and values — sizes the dense lane cache and stays the fp
-        baseline for capacity ratios. A lane's fixed part is
-        ``state_bytes_per_lane``. A span that caches a latent row in place of
-        keys and values: that row's bytes, stored once."""
-        if self.latent_row is not None:
-            return self.page_layers * sum(self.latent_row) * jnp.dtype(self.cache_dtype).itemsize
-        return (
-            2
-            * len(self.kv_layers)
-            * self.num_kv_heads
-            * self.head_dim
-            * jnp.dtype(self.cache_dtype).itemsize
-        ) + self.index_bytes_per_token()
-
-    def kv_bytes_per_token(self) -> int:
-        """WIRE bytes per token across the span: what the paged pool, host
-        swap, and migration actually store/ship per token. Equals
-        cache_bytes_per_token when kv_quant_type == none."""
-        from petals_tpu.ops.paged_attention import kv_wire_bytes_per_token
-
-        if self.latent_row is not None:
-            return self.cache_bytes_per_token()
-        return 2 * len(self.kv_layers) * kv_wire_bytes_per_token(
-            self.num_kv_heads, self.head_dim, self.kv_quant_type,
-            jnp.dtype(self.cache_dtype).itemsize,
-        ) + self.index_bytes_per_token()
 
     # ------------------------------------------------------------- jitted programs
 
@@ -820,7 +513,7 @@ class TransformerBackend:
         fp_proj = fp_ops.projection(self.hidden_size)  # baked constant
 
         cache_dtype = jnp.dtype(self.cache_dtype)
-        self.refuse_for_state("the dense lane pool", "it has no place for the state: serve with page_size > 0")
+        self.cache.refuse("the dense lane pool", "it has no place for the state: serve with page_size > 0")
 
         @tracked_jit(
             name="batched_decode", steady=True,
@@ -879,119 +572,6 @@ class TransformerBackend:
             self._last_step_fp = None
         return out, (k_pool, v_pool)
 
-    def _static_windows(self) -> list:
-        """The distinct static attention windows of the span's layers (None:
-        full attention): the family's own per kind, else the one of ``cfg``."""
-        if self.layer_windows is not None:
-            return list(dict.fromkeys(self.layer_windows))
-        window = getattr(self.cfg, "sliding_window", None)
-        return [window if isinstance(window, int) and window > 0 else None]
-
-    @functools.cached_property
-    def _window_layers(self) -> tuple:
-        """``((window, layers), ...)``: the static windows of the span's blocks
-        that keep keys and values (None: full attention) and how many blocks
-        have each."""
-        if self.layer_windows is not None:
-            windows = [self.layer_windows[i] for i in self.kv_layers]
-        else:
-            windows = self._static_windows() * len(self.kv_layers)
-        return tuple(collections.Counter(windows).items())
-
-    def pages_gathered(self, q_len: int, max_pages: int, page_size: int) -> int:
-        """Table slots one lane's ``q_len`` rows gather over the span's layers
-        where a paged step program makes the dense view (a prompt's chunk, a
-        verify's rows; ops/paged_flash_attention.py ``window_pages``: a
-        windowed layer gathers the pages in its reach, a full one its whole
-        table row). For the batcher's ``attn_pages_gathered``."""
-        from petals_tpu.ops.paged_flash_attention import window_pages
-
-        return sum(layers * window_pages(w, q_len, page_size, max_pages) for w, layers in self._window_layers)
-
-    def decode_walks(self, n_lanes: int, max_pages: int, page_size: int) -> tuple:
-        """``((window, layers, block, cut, path), ...)``: how a decode step's
-        programs walk a lane pool's tables, a distinct attention call of the
-        span's layers: which walk runs (ops/paged_flash_attention.py
-        ``decode_walk_path``: the kernel that reads each lane's own pages, or
-        the composed walk), the block's width in slots
-        (``walk_kernel_block_pages`` / ``walk_block_pages``) and whether the
-        table row is first cut to the window's reach. ``decode_walk_path`` is
-        asked here as ``composed_paged_attend`` asks it in the step: with the
-        pool's form as a step's attention is handed it
-        (``paged_cache_descriptors``) and with what the family says its
-        blocks hand their attention beside the plain call
-        (``ModelFamily.block_attention``). Fixed with the pool's geometry:
-        the batcher asks once."""
-        from petals_tpu.ops import paged_flash_attention as pfa
-        from petals_tpu.ops.paged_attention import PagedPool
-
-        if self.latent_row is not None:  # ops/latent_attention.py ``decode_reads`` counts its own walk: ``latent_reads``
-            return ()
-        if self.index_row is not None and max_pages * page_size > self.index_keep:
-            return ()  # every decode row fetches the positions it chose, a row each (ops/sparse_attention.py): no walk runs
-        quantised = self.kv_quant_type != "none"
-        itemsize = 2 if quantised else jnp.dtype(self.cache_dtype).itemsize  # a quantised pool reads as bf16
-        hkv, d = self.num_kv_heads, self.head_dim
-        pool = jax.ShapeDtypeStruct((1, page_size, *self.pool_row), self.cache_dtype)
-        pool = PagedPool(pool, pool) if quantised else pool
-        extras = [self.family.attention_for(self.cfg, kind) for kind, _, length in self.runs for _ in range(length)]
-        windows = self.layer_windows if self.layer_windows is not None else self._static_windows() * self.n_blocks
-        walks = []
-        for (window, extra), layers in collections.Counter((windows[i], extras[i]) for i in self.kv_layers).items():
-            if "traced_window" in extra:  # the walk is handed an array: it cuts nothing and masks by it
-                window, handed = None, jax.ShapeDtypeStruct((), jnp.int32)
-            else:
-                handed = window
-            width = pfa.window_pages(window, 1, page_size, max_pages)
-            path = pfa.decode_walk_path(
-                pool, (n_lanes, 1, hkv, d), (n_lanes, width), alibi="alibi" in extra, softcap="softcap" in extra, window=handed
-            )
-            if path == "kernel":
-                block = pfa.walk_kernel_block_pages(width, page_size, hkv, d, itemsize)
-            else:
-                block = pfa.walk_block_pages(n_lanes, width, page_size, hkv, d, itemsize)
-            walks.append((window, layers, block, width < max_pages, path))
-        return tuple(walks)
-
-    @staticmethod
-    def pages_walked(walks: tuple, last: np.ndarray, page_size: int, n_lanes: int) -> tuple:
-        """``(read, by the kernel)``: table slots a decode step's programs
-        read over the span's layers and all lanes, its live lanes at the
-        positions ``last``, and those of them the kernel's grid fetched. Each
-        layer walks its table (the slots in its window's reach, if ``cut``) in
-        blocks: the composed walk every lane of the pool's up to the block
-        that holds the longest lane's last row, the kernel each live lane from
-        the block of its first position in sight to its own last one
-        (ops/paged_flash_attention.py ``composed_paged_attend``, whose
-        arithmetic this is). For the batcher's ``attn_pages_gathered`` /
-        ``attn_pages_kernel``, a step, on the host's serial part: a reduction
-        or two over the lanes and integer arithmetic a walk."""
-        from petals_tpu.ops.paged_flash_attention import walk_pages
-
-        read = by_kernel = 0
-        longest = -1
-        for window, layers, block, cut, path in walks:
-            if path == "kernel" and not window:
-                # a lane reads the blocks up to its last row's: ``last // (block * page_size) + 1`` of them
-                walked = layers * block * (int((last // (block * page_size)).sum()) + last.size)
-                by_kernel += walked
-            elif path == "kernel":
-                first = np.maximum(last - (window - 1), 0) // page_size  # a lane's first slot in sight
-                walked = walk_pages(last // page_size + 1 - (first if cut else 0), block)  # as the walk is handed its table
-                if not cut:
-                    walked = walked - first // block * block  # whole blocks before the window's reach
-                walked = layers * int(walked.sum())
-                by_kernel += walked
-            else:
-                if longest < 0:
-                    longest = int(last.max())
-                needed = longest // page_size + 1
-                if cut:  # each lane's slots count from its own window's first one
-                    needed = int(np.max(last // page_size - np.maximum(last - (window - 1), 0) // page_size)) + 1
-                walked = layers * n_lanes * walk_pages(needed, block)
-            read += walked
-        return read, by_kernel
-
     def _scan_paged_span(self, params, k_pool, v_pool, carry, layer, state=(), state_layer=None):
         """The layer loop of every paged step program: ``_scan_span`` over
         the span's blocks with the page pools in the loop's CARRY, updated in
@@ -1017,7 +597,7 @@ class TransformerBackend:
         are the carried pools, keys' and values' (and the index rows' for a
         span that caches one), ``paged(spans, tables)`` wraps them and a set
         of block tables as that block's ``PagedKV``s (for a block of more
-        than one attention sub-layer, ``block_rows``: one tuple of them a
+        than one attention sub-layer, ``cache.block_rows``: one tuple of them a
         sub-layer, each shifted to its own layer of pages, the block's
         ``block_rows`` layers lying one after the other in the pools), and
         the pools ``block_apply`` hands back go on to the next layer. Returns
@@ -1028,7 +608,7 @@ class TransformerBackend:
         beside the pages, donated and written in place as they are. The page
         pools are then only as deep as the blocks that keep keys and values,
         and a block's layer in its pool is its place among its own sort
-        (``_slots``), not its index in the span. A block of a kind that
+        (``cache.slots``), not its index in the span. A block of a kind that
         declares a state runs ``state_layer(block_apply, carry, p_block,
         mine) -> (carry, mine)`` on the state pool where it lies, ``mine``
         the pool's leaves whole and the block's slot in them
@@ -1036,7 +616,7 @@ class TransformerBackend:
         for pages, and not a sliced copy: a copy out and a write back are two
         passes over every lane's state), and touches no page.
 
-        A span whose positions cache an index row (``index_row``) hands its
+        A span whose positions cache an index row (``cache.index_row``) hands its
         INDEX pool in as ``state``'s one leaf, ``[kv layers, n_pages,
         page_size, width]``: a third page pool, flattened and carried as the
         other two and reached through the same shifted tables, which comes
@@ -1044,9 +624,9 @@ class TransformerBackend:
         from petals_tpu.ops.linear_attention import StatePool
         from petals_tpu.ops.paged_attention import PagedKV
 
-        depth, n_pages, rows = k_pool.shape[0], k_pool.shape[1], self.block_rows
-        by_sort = bool(self.state_layers)
-        indexed = self.index_row is not None
+        depth, n_pages, rows = k_pool.shape[0], k_pool.shape[1], self.cache.block_rows
+        by_sort = bool(self.cache.state_layers)
+        indexed = self.cache.index_row is not None
 
         def merged(pool):  # [depth, n_pages, ...] -> [depth * n_pages, ...]
             return jax.tree_util.tree_map(
@@ -1060,7 +640,7 @@ class TransformerBackend:
 
         def one(block_apply, scanned, p_block, slot, block_idx, kind=None):
             inner, spans, state = scanned
-            if by_sort and self.family.state_for(self.cfg, kind) is not None:
+            if by_sort and kind in self.cache.state_kinds:
                 inner, mine = state_layer(block_apply, inner, p_block, StatePool(state, slot))
                 return (inner, spans, tuple(mine.leaves)), None
             first_page = (slot if by_sort else block_idx) * (rows * n_pages)
@@ -1077,7 +657,7 @@ class TransformerBackend:
         spans = (k_pool, v_pool, *state) if indexed else (k_pool, v_pool)
         (carry, spans, state), _ = self._scan_span(
             params, (carry, tuple(merged(pool) for pool in spans), () if indexed else tuple(state)),
-            jnp.asarray(self._slots, jnp.int32) if by_sort else (), one, pass_kind=by_sort,
+            jnp.asarray(self.cache.slots, jnp.int32) if by_sort else (), one, pass_kind=by_sort,
         )
         k_pool, v_pool, *index = (stacked(span) for span in spans)
         return carry, k_pool, v_pool, tuple(index) if indexed else state
@@ -1427,7 +1007,7 @@ class TransformerBackend:
         Returns (g_hat [n_lanes, spec_k+1] int32, n_emit [n_lanes] int32,
         pool_kv): lane i must commit exactly g_hat[i, :n_emit[i]].
         """
-        self.refuse_for_state("speculative verify", SPEC_CUTS_BACK)
+        self.cache.refuse("speculative verify", SPEC_CUTS_BACK)
         k_pool, v_pool = pool_kv
         tables = self._as_tables(tables)
         v = sampling_vecs
@@ -1494,7 +1074,7 @@ class TransformerBackend:
             def layer(block_apply, carry, p_block, spans, paged):
                 h_dec, h_pf = carry
                 out_dec, spans = decode_half(block_apply, h_dec, p_block, spans, paged)
-                if self.latent_row is not None:
+                if self.cache.latent_row is not None:
                     # the decode rows' walk reads the pools in a loop of its own, which nothing orders against the
                     # chunk's writes: left free, the compiler wrote the chunk first and kept a COPY of both pools
                     # for the walk, every layer (tests/test_kernels_lower_tpu.py). Tied to the walk's result, the
@@ -1985,7 +1565,7 @@ class TransformerBackend:
         final token stays unfed, client-loop convention).
         Returns (tokens [batch, n_tokens] int32, (k_stack, v_stack))."""
         assert client_params is not None
-        self.refuse_for_state(
+        self.cache.refuse(
             "server-side generation on a private cache", "only the paged lane pool's generation step carries the state"
         )
         k_stack, v_stack = kv
@@ -2177,7 +1757,7 @@ class TransformerBackend:
         in every chunk instead of flipping factors mid-prompt. Defaults to
         position + seq — exact for unchunked callers."""
         self.refuse_deep_prompts(prompts)
-        self.refuse_for_state(
+        self.cache.refuse(
             "a step on a private or checked-out cache (deep prompts, beam search's hypo_ids, a session that took no lane)",
             "only the paged lane pool's own step programs carry the state",
         )

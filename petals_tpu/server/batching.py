@@ -243,37 +243,24 @@ class DecodeBatcher:
             self.page_size = None
             self.max_pages = 0
             self.n_pages = 0
-        # a span with a recurrent state (ModelFamily.block_state): a lane owns, beside its pages in the
-        # blocks that keep keys and values, its slot in the backend's STATE pool, taken and released
-        # with the lane; a row at position 0 starts from zeros, so a new tenant needs no clearing. Only
-        # the paged pool's step programs carry the state
-        self._n_state = len(getattr(backend, "lane_state", None) or ())
-        # fixed with the backend and asked by the step bodies' counters every step: a page's and a lane's state's bytes
-        # (_page_nbytes, _state_nbytes), and the expert dispatch a block call of a shape takes (_moe_dispatch)
-        self._page_bytes = backend.kv_bytes_per_token() * self.page_size if self.page_size else 0
-        self._state_bytes = int(backend.state_bytes_per_lane()) if self._n_state else 0
-        self._moe_took: Dict[tuple, Optional[str]] = {}
-        if self._n_state and self.page_size is None:
-            backend.refuse_for_state(
+        # what a lane holds for the span's blocks beside or in place of pages of keys and values (server/span_cache.py). A
+        # recurrent state: beside its pages, a lane owns its slot in the STATE pool, taken and released with the lane; a row
+        # at position 0 starts from zeros, so a new tenant needs no clearing. An index row: a third page pool under the same
+        # tables, allocated, freed and reused with the pages; it rides the paged step programs where a state pool would
+        # (``_state``). A latent row: the two pools of ``_buffers`` are its latents and its rotated keys, allocated, freed
+        # and reused as pages of keys and values are. Only the paged pool's step programs carry any of them
+        cache = backend.cache
+        if self.page_size is None:
+            cache.refuse(
                 "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""),
                 "it has no place for the state: serve with page_size > 0",
             )
-        # a span whose positions cache an index row beside their keys and values (ModelFamily.block_index):
-        # a third page pool under the same tables, allocated, freed and reused with the pages; it rides the
-        # paged step programs where a state pool would (``_state``), and nothing else carries it
-        self._n_index = 1 if getattr(backend, "index_row", None) is not None else 0
-        # a span whose positions cache a latent row IN PLACE of keys and values (ModelFamily.block_latent): the two
-        # pools of ``_buffers`` are its latents and its rotated keys, allocated, freed and reused as pages of keys
-        # and values are; only the paged step programs read them
-        self._latent = getattr(backend, "latent_row", None) is not None
-        if (self._n_index or self._latent) and self.page_size is None:
-            backend.refuse_for_state(
-                "the dense lane pool" + (" (which a tp mesh or a multi-host group falls back to)" if page_size else ""), "",
-            )
-        if (self._n_index or self._latent) and int(swap_host_bytes or 0) > 0:
-            backend.refuse_for_state("the host swap tier (swap_host_bytes > 0)", "")
-        # such a span's rows choose positions only where a table can pass the selection's size (models/keye_vl2/block.py)
-        self._selects = bool(self._n_index) and self.page_size is not None and self.max_length > backend.index_keep
+        if cache.content in ("index", "latent") and int(swap_host_bytes or 0) > 0:
+            cache.refuse("the host swap tier (swap_host_bytes > 0)", "")
+        # fixed with the backend and asked by the step bodies' counters every step: what a paged step reads, a page's and a lane's
+        # state's bytes (``_pool``: server/span_cache.py ``LanePool``), and the expert dispatch a block call of a shape takes
+        self._pool = cache.lane_pool(n_lanes, self.max_pages, self.page_size) if self.page_size else None
+        self._moe_took: Dict[tuple, Optional[str]] = {}
         self._pages: Optional[PageAllocator] = None
         # [n_lanes, max_pages] int32, -1 = unallocated. What everyone READS is a view that refuses writes: an
         # entry changes value through ``_write_tables`` alone, which keeps beside the tables what the step bodies
@@ -334,7 +321,7 @@ class DecodeBatcher:
                 )
             from petals_tpu.server.backend import SPEC_CUTS_BACK
 
-            self._refuse_for_state("speculative decoding", SPEC_CUTS_BACK)
+            cache.refuse("speculative decoding", SPEC_CUTS_BACK)
         # the draft instance whose bucket shapes have been pre-compiled via
         # DraftModel.warmup (first spec tick, on the compute thread); keyed
         # on the object so a swapped-in draft re-warms
@@ -396,12 +383,9 @@ class DecodeBatcher:
         self._ledger = ledger
         # price the pool for /ledger readers: wire bytes per cached token
         # (quantized pools cost ~4x less) and the storage kind. Guarded by
-        # hasattr because unit-test stub backends/ledgers lack the accessors.
-        if hasattr(backend, "kv_bytes_per_token") and hasattr(ledger, "set_kv_cost"):
-            ledger.set_kv_cost(
-                getattr(backend, "kv_quant_type", "none"),
-                backend.kv_bytes_per_token(),
-            )
+        # hasattr because unit-test stub ledgers lack the accessor.
+        if hasattr(ledger, "set_kv_cost"):
+            ledger.set_kv_cost(cache.kv_quant_type, cache.kv_bytes_per_token())
         self._ledger_keys: Dict[int, str] = {}  # lane -> ledger session key
         self._scheduler = SessionScheduler(
             self.swap_pool, policy=preemption_policy, pages_fn=self._lane_pages,
@@ -518,50 +502,14 @@ class DecodeBatcher:
                 # all-experts einsum, positions x top k under the grouped one, which is handed every assignment's
                 # row), against those the routing sends here (positions x top k x held / routed)
                 self.stats.update(moe_chunk_rows_computed=0, moe_chunk_rows_routed=0.0)
-        # on the paged pool (_count_window): the table slots the step programs read against those they
-        # are handed; and, for a family that declares its layers' windows only, of the pages the decoding
-        # lanes hold in windowed layers those their windows still reach (summed over steps)
-        if self.page_size:
-            # attn_pages_kernel: of the slots read, those the decode walk's kernel fetched, each live lane to its own
-            # end (0 where the composed walk runs: ``backend.decode_walks`` says which)
-            self.stats.update(attn_pages_gathered=0, attn_pages_tabled=0, attn_pages_kernel=0)
-            self._walks = backend.decode_walks(n_lanes, self.max_pages, self.page_size)
-        self._windows = [w for w in (getattr(backend, "layer_windows", None) or ()) if w] if self.page_size else []
-        if self._windows:
-            self.stats.update(window_pages_held=0, window_pages_in_reach=0)
-            self._lane_pos = np.zeros(n_lanes, np.int64)  # the last position each lane fed, for occupancy_info
-        if self._n_state:
-            # a family that declares a state only (_count_state): rows times state layers by the form
-            # their step gave them (the one-step form a decode row, the chunked form a prompt chunk), and,
-            # summed step by step over the lanes that fed rows, the bytes of state and of pages they hold
-            # linattn_kernel_tokens: of the one-step form's, those whose state the kernel moved once where it lies in the
-            # pool (0 where the plain form runs: ``backend.state_step_path`` says which)
-            self.stats.update(linattn_recurrent_tokens=0, linattn_kernel_tokens=0, linattn_chunk_tokens=0, state_bytes_held=0, kv_bytes_held=0)
-            self._state_step = backend.state_step_path(n_lanes)
-        if self._n_index:
-            # a family that declares an index row only (_count_sparse), from the shapes a step is started with,
-            # all times the span's layers: rows whose context was over / at most the selection's size, index
-            # rows their scoring read (and query row x index row pairs it scored), positions of keys and values
-            # the step's programs fetched against those the rows' lanes held, and, summed step by step, the
-            # bytes of index rows and of keys and values those lanes' pages hold
-            self.stats.update(
-                sparse_rows_selected=0, sparse_rows_dense=0, sparse_index_rows_scored=0, sparse_score_pairs=0, sparse_kv_rows_read=0,
-                sparse_kv_rows_held=0, index_bytes_held=0, kv_bytes_held=0,
-            )
+        # on the paged pool: what a step's programs read of what the lanes hold, the keys that the span's content opens
+        # (server/span_cache.py ``LanePool.new_stats``; ``count_step`` adds a step's)
+        if self._pool is not None:
+            self.stats.update(self._pool.new_stats())
         if getattr(backend, "stream_mixes", 0):
             # a family whose hidden state is a stream of several rows only (ModelFamily.block_stream; _count_stream):
             # rows times the mixes of the stream the span's blocks make of each (a hyper-connection a sub-layer)
             self.stats["hc_rows"] = 0
-        if self._latent:
-            # a family that declares a latent row only (_count_latent), from the shapes a step is started with, all
-            # times the span's layers: latent rows the decode rows' walks read against those their lanes held; rows
-            # that took the absorbed form (a decode row) and the expanded one (a chunk's); positions a chunk's walk
-            # expanded against those its lane held; (row, position) pairs scored; and, summed step by step, the
-            # bytes of latent rows the lanes that fed rows hold
-            self.stats.update(
-                latent_rows_read=0, latent_rows_held=0, latent_rows_absorbed=0, latent_rows_expanded=0,
-                latent_positions_expanded=0, latent_positions_held=0, latent_score_pairs=0, latent_bytes_held=0,
-            )
         # swarm telemetry plane: every admission / victim-selection / swap
         # decision is journaled WITH the occupancy snapshot that justified it
         # (telemetry.journal), and the pool gauges/counters feed the /metrics
@@ -590,14 +538,11 @@ class DecodeBatcher:
             # pool would deadlock the group at open)
             if self.page_size is not None:
                 # 2 descriptors (k, v) unquantized; 4 (k/v codes, k/v scales)
-                # when the backend stores the pool quantized
-                descs = self.backend.paged_cache_descriptors(
-                    self.n_pages, self.page_size, 0, self.backend.n_blocks
+                # when the backend stores the pool quantized; the state pool's
+                # leaves ride last, as the index pool does
+                descs = self.backend.cache.pool_descriptors(
+                    self.n_pages, self.page_size, self.n_lanes, 0, self.backend.n_blocks
                 )
-                if self._n_state:  # the state pool's leaves ride last
-                    descs = (*descs, *self.backend.state_cache_descriptors(self.n_lanes))
-                if self._n_index:  # as the index pool does
-                    descs = (*descs, *self.backend.index_cache_descriptors(self.n_pages, self.page_size))
             else:
                 descs = self.backend.cache_descriptors(
                     self.n_lanes, self.max_length, 0, self.backend.n_blocks
@@ -627,7 +572,7 @@ class DecodeBatcher:
                 self._lanes_rows = self._lanes_in[:, :hsz].view(np.float32)
                 logger.info(
                     f"Paged-batching pool open: {self.n_pages} pages x "
-                    f"{self.page_size} tokens of {list(getattr(self.backend, 'pool_row', ()))} ({self.n_lanes} lanes x "
+                    f"{self.page_size} tokens of {list(self.backend.cache.pool_row)} ({self.n_lanes} lanes x "
                     f"{self.max_pages} table slots) for blocks "
                     f"[{self.backend.first_block}, {self.backend.first_block + self.backend.n_blocks})"
                 )
@@ -664,7 +609,7 @@ class DecodeBatcher:
         quantized pool rides as 4 MemoryCache buffers (codes x2, scales x2)
         and is re-wrapped into PagedPool pytrees HERE, so every caller —
         step bodies, swap, COW, snapshots — keeps the 2-tuple shape."""
-        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self._n_state - self._n_index])
+        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self.backend.cache.pools_beside_pages])
         if len(bufs) == 4:
             from petals_tpu.ops.paged_attention import PagedPool
 
@@ -676,14 +621,10 @@ class DecodeBatcher:
         the pair of ``_buffers`` and hand back after it; none for a span
         without a recurrent state. A span that caches an index row hands its
         index pool over the same way."""
-        n = self._n_state + self._n_index
+        n = self.backend.cache.pools_beside_pages
         if not n:
             return ()
         return tuple(self.memory_cache.get_buffers(*self._handles[-n:]))
-
-    def _refuse_for_state(self, what: str, why: str) -> None:
-        if self._n_state or self._n_index or self._latent:
-            self.backend.refuse_for_state(what, why)
 
     def _update(self, k_pool, v_pool, *state) -> None:
         from petals_tpu.ops.paged_attention import PagedPool
@@ -999,7 +940,7 @@ class DecodeBatcher:
         HBM residency to tenants through the ledger."""
         if self.page_size is None:
             return 0
-        return self._page_nbytes()
+        return self._pool.page_bytes
 
     def pin_lane_pages(self, lane: int, t0: int, t1: int) -> Optional[List[int]]:
         """Take a reference on the pages backing token range [t0, t1) of
@@ -1128,18 +1069,6 @@ class DecodeBatcher:
         if self._tables is None:
             return 0
         return int(self._lane_held[lane])
-
-    def _page_nbytes(self) -> int:
-        # WIRE bytes per page: quantized pools swap/reserve packed bytes, so
-        # the host-swap budget, ledger swap meters, and victim sizing all
-        # bill what actually moves (kv_bytes_per_token == cache_bytes_per_token
-        # for unquantized backends)
-        return self._page_bytes
-
-    def _state_nbytes(self) -> int:
-        """What a lane holds whatever its context: its slot in the state
-        pool. 0 for a span without a recurrent state."""
-        return self._state_bytes
 
     def _moe_dispatch(self, seq: int, chunk: bool = False) -> Optional[str]:
         """``backend.moe_grouped``, asked once a shape (``_count_moe`` asks every step)."""
@@ -1274,7 +1203,7 @@ class DecodeBatcher:
             if slots.size == 0:
                 return False
             pages = row[slots].astype(np.int32).copy()
-            nbytes = int(slots.size) * self._page_nbytes() + self._state_nbytes()
+            nbytes = int(slots.size) * self._pool.page_bytes + self._pool.state_bytes
             if not self.swap_pool.try_reserve(nbytes):
                 return False  # swap tier full: this victim is not preemptable
             slot.suspending = True
@@ -1347,7 +1276,7 @@ class DecodeBatcher:
         with self._reset_lock:
             k_pool, v_pool = self._buffers()
             k, v = self.backend._swap_out_pages_fn(k_pool, v_pool, pages)
-            state = self.backend._lane_state_take_fn(self._state(), np.int32(lane)) if self._n_state else ()
+            state = self.backend._lane_state_take_fn(self._state(), np.int32(lane)) if self.backend.cache.lane_state else ()
             # per-leaf host copy: a quantized pool's SwapEntry holds a
             # PagedPool of numpy arrays — packed wire bytes, never fp pages
             # (rows of [hkv, d_store], whatever row the pool stores: a
@@ -1434,7 +1363,7 @@ class DecodeBatcher:
             k_pool, v_pool = self.backend._swap_in_pages_fn(
                 k_pool, v_pool, to_pool(entry.k, k_pool), to_pool(entry.v, v_pool), pages
             )
-            state = self.backend._lane_state_put_fn(self._state(), entry.state, np.int32(lane)) if self._n_state else ()
+            state = self.backend._lane_state_put_fn(self._state(), entry.state, np.int32(lane)) if self.backend.cache.lane_state else ()
             self._update(k_pool, v_pool, *state)
 
     async def _alloc_pages(self, lane: int, slots: np.ndarray) -> List[int]:
@@ -1615,29 +1544,30 @@ class DecodeBatcher:
                 info["largest_free_run"] = frag["largest_run"]
             # honest capacity math for clients: the pool's encoding and its
             # WIRE bytes/token (what a page actually costs under kv quant)
-            info["kv_quant"] = getattr(self.backend, "kv_quant_type", "none")
+            cache, pool = self.backend.cache, self._pool
+            info["kv_quant"] = cache.kv_quant_type
             # the trailing dims the pool keeps a token row in: (hkv, d_store), or folded to one (a row under 128 lanes)
-            info["pool_row"] = list(getattr(self.backend, "pool_row", ()))
+            info["pool_row"] = list(cache.pool_row)
             # which walk a decode row's attention takes over these pages, a distinct window of the span's layers
-            info["decode_walk"] = [walk[-1] for walk in self._walks]
-            if self._n_state:  # and which form of the one-step rule a decode row's state layers
-                info["state_step"] = self._state_step
-            info["kv_bytes_per_token"] = int(self.backend.kv_bytes_per_token())
-            if self._n_index:  # of kv_bytes_per_token, the index rows' part
-                info["index_bytes_per_token"] = int(self.backend.index_bytes_per_token())
-            if self._latent:
+            info["decode_walk"] = [walk[-1] for walk in pool.walks]
+            info["kv_bytes_per_token"] = int(cache.kv_bytes_per_token())
+            if cache.content == "state":
+                # which form of the one-step rule a decode row's state layers take; a lane's fixed part, beside what
+                # its pages cost a token, and what the busy lanes hold of it
+                info["state_step"] = pool.state_step
+                info["state_bytes_per_lane"] = pool.state_bytes
+                info["state_bytes_held"] = info["busy_lanes"] * pool.state_bytes
+            elif cache.content == "index":  # of kv_bytes_per_token, the index rows' part
+                info["index_bytes_per_token"] = int(cache.index_bytes_per_token())
+            elif cache.content == "latent":
                 # what a position caches in place of keys and values, (latent, rotated key), and what the pages in
                 # use hold of it
-                info["latent_row"] = list(self.backend.latent_row)
-                info["latent_bytes_held"] = (self.n_pages - info["pages_free"]) * self._page_nbytes()
-            if self._n_state:
-                # a lane's fixed part, beside what its pages cost a token, and what the busy lanes hold of it
-                info["state_bytes_per_lane"] = self._state_nbytes()
-                info["state_bytes_held"] = info["busy_lanes"] * self._state_nbytes()
-            if self._windows and self._tables is not None:
+                info["latent_row"] = list(cache.latent_row)
+                info["latent_bytes_held"] = (self.n_pages - info["pages_free"]) * pool.page_bytes
+            if pool.windows and self._tables is not None:
                 # over the lanes that hold pages, at the last position each fed
                 live = np.flatnonzero(self._lane_held)
-                info["window_pages_held"], info["window_pages_in_reach"] = self._window_pages(live, self._lane_pos[live])
+                info["window_pages_held"], info["window_pages_in_reach"] = pool.window_pages(live, self._lane_held)
             # of the step bodies so far, those that copied the block tables to the device (``_step_tables``)
             info["tables_sent"], info["batched_steps"] = self.stats["tables_sent"], self.stats["batched_steps"]
         info.update(self._scheduler.summary())
@@ -2467,136 +2397,6 @@ class DecodeBatcher:
         if "hc_rows" in self.stats:
             self.stats["hc_rows"] += (wire_rows if rows is None else rows) * self.backend.stream_mixes * self.backend.n_blocks
 
-    def _window_pages(self, lanes, positions) -> Tuple[int, int]:
-        """(held, in reach): the pages ``lanes`` hold, once a windowed layer
-        of the span, and those of them a layer's window still reaches from
-        the lane's ``positions`` entry. The rest are held until the session
-        ends (freeing them is ROADMAP B3)."""
-        held = self._lane_held[lanes]
-        pos = np.asarray(positions, np.int64)
-        reach = 0
-        for window in self._windows:
-            pages = pos // self.page_size - np.maximum(pos - window + 1, 0) // self.page_size + 1
-            reach += int(np.minimum(pages, held).sum())
-        return int(held.sum()) * len(self._windows), reach
-
-    def _count_paged(self, positions, *, seq: int = 1, chunk=None) -> None:
-        """The per-layer counters of one paged step (compute thread), every
-        one from the step's shapes and the positions it was started with:
-        ``lanes``, the lanes that fed a row, is reckoned once, and the pages
-        a lane holds are ``_lane_held``'s, kept where the tables are written,
-        so that nothing here walks the tables. ``chunk`` is the (lane, first
-        position, tokens) of a mixed step's prompt chunk."""
-        lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
-        self._count_window(positions, lanes, seq=seq, chunk=chunk)
-        self._count_state(lanes, chunk=chunk)
-        self._count_sparse(positions, lanes, chunk=chunk)
-        self._count_latent(positions, lanes, chunk=chunk)
-
-    def _count_window(self, positions, lanes, *, seq: int = 1, chunk=None) -> None:
-        """The attention counters of one paged step, from the positions the
-        step was started with: of the table slots its programs are handed
-        (every lane of the pool's, a layer that keeps keys and values), those
-        they read. A decode row's walk reads whole blocks up to the longest
-        live lane's last page, for every lane; a verify's ``seq`` rows and
-        the ``chunk`` of a mixed step, at its bucket, gather the slots in
-        reach. For a family that declares its layers' windows, the window
-        counters besides."""
-        if "attn_pages_gathered" not in self.stats:
-            return
-        backend, layers = self.backend, self.backend.page_layers
-        last = positions[lanes] + (seq - 1)
-        by_kernel = 0
-        if seq > 1:
-            read = self.n_lanes * backend.pages_gathered(seq, self.max_pages, self.page_size)
-        elif self._selects or self._latent or not last.size:
-            # the chosen positions' rows are fetched one by one, a latent row's walk counts itself (each lane to its own
-            # end where the kernel runs): ``_count_sparse`` / ``_count_latent`` add their pages' worth
-            read = 0
-        else:
-            read, by_kernel = backend.pages_walked(self._walks, last, self.page_size, self.n_lanes)
-        self.stats["attn_pages_gathered"] += read
-        self.stats["attn_pages_kernel"] += by_kernel
-        self.stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
-        if chunk is not None:
-            lane, first, take = chunk
-            if self._latent:  # the chunk's walk ends with the block that holds its last row
-                from petals_tpu.ops.latent_attention import chunk_reads
-
-                self.stats["attn_pages_gathered"] += layers * chunk_reads(self.max_pages, self.page_size, first, take) // self.page_size
-            elif not self._selects:
-                self.stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
-            self.stats["attn_pages_tabled"] += self.max_pages * layers
-        if not self._windows:
-            return
-        last = last.astype(np.int64)
-        if chunk is not None:
-            lanes, last = np.append(lanes, lane), np.append(last, first + take - 1)
-        self._lane_pos[lanes] = last
-        held, reach = self._window_pages(lanes, last)
-        self.stats["window_pages_held"] += held
-        self.stats["window_pages_in_reach"] += reach
-
-    def _held_with(self, lanes, chunk) -> int:
-        """The pages ``lanes`` and a mixed step's ``chunk`` lane hold."""
-        return int(self._lane_held[lanes].sum()) + (0 if chunk is None else int(self._lane_held[chunk[0]]))
-
-    def _count_state(self, lanes, *, chunk=None) -> None:
-        """The state counters of one paged step (a family that declares a
-        state only), from the shapes the step was started with: every lane
-        that fed a row took the one-step form in each state layer, the
-        ``chunk`` of a mixed step the chunked form; and what those lanes
-        hold, their slots in the state pool and their pages in the blocks
-        that keep keys and values."""
-        if not self._n_state:
-            return
-        layers = len(self.backend.state_layers)
-        rows = int(lanes.size) * layers
-        self.stats["linattn_recurrent_tokens"] += rows
-        if self._state_step == "kernel":
-            self.stats["linattn_kernel_tokens"] += rows
-        if chunk is not None:
-            self.stats["linattn_chunk_tokens"] += int(chunk[2]) * layers
-        self.stats["state_bytes_held"] += (int(lanes.size) + (chunk is not None)) * self._state_nbytes()
-        self.stats["kv_bytes_held"] += self._held_with(lanes, chunk) * self._page_nbytes()
-
-    def _count_sparse(self, positions, lanes, *, chunk=None) -> None:
-        """The selection's counters of one paged step (a family that declares
-        an index row only), from the shapes the step was started with: what
-        the lanes that fed a row and the ``chunk`` of a mixed step made the
-        programs score and fetch (``backend.sparse_reads``), and the bytes of
-        index rows and of keys and values those lanes' pages hold."""
-        if not self._n_index:
-            return
-        reads = self.backend.sparse_reads(
-            self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:]
-        )
-        for key, n in reads.items():
-            self.stats[key] += n
-        if self._selects:
-            self.stats["attn_pages_gathered"] += -(-reads["sparse_kv_rows_read"] // self.page_size)
-        pages = self._held_with(lanes, chunk)
-        index = pages * self.page_size * int(self.backend.index_bytes_per_token())
-        self.stats["index_bytes_held"] += index
-        self.stats["kv_bytes_held"] += pages * self._page_nbytes() - index
-
-    def _count_latent(self, positions, lanes, *, chunk=None) -> None:
-        """The latent attention's counters of one paged step (a family that
-        declares a latent row only), from the shapes the step was started
-        with: what the lanes that fed a row (the absorbed form) and the
-        ``chunk`` of a mixed step (the expanded one) made the programs read
-        and score (``backend.latent_reads``), and the bytes of latent rows
-        those lanes' pages hold."""
-        if not self._latent:
-            return
-        reads = self.backend.latent_reads(
-            self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:]
-        )
-        for key, n in reads.items():
-            self.stats[key] += n
-        self.stats["attn_pages_gathered"] += reads["latent_rows_read"] // self.page_size
-        self.stats["latent_bytes_held"] += self._held_with(lanes, chunk) * self._page_nbytes()
-
     def _fill_lanes(self, batch) -> Tuple[np.ndarray, np.ndarray]:
         """``batch``'s rows and every lane's position written into the
         lanes' one buffer (compute thread; ``_lanes_in``): ``(the buffer as
@@ -2665,7 +2465,7 @@ class DecodeBatcher:
             self._count_moe(len(batch))
             self._count_stream(len(batch))
             if paged:
-                self._count_paged(positions)
+                self._pool.count_step(self.stats, positions, self._lane_held)
             duration = time.perf_counter() - t_step
             if paged:
                 tm.STEP_PAGED.observe(duration)
@@ -2775,7 +2575,7 @@ class DecodeBatcher:
             )
             self._count_moe(len(batch), chunk_tokens=take)
             self._count_stream(len(batch) + take)
-            self._count_paged(positions, chunk=(st.lane, st.position, take))
+            self._pool.count_step(self.stats, positions, self._lane_held, chunk=(st.lane, st.position, take))
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2868,7 +2668,7 @@ class DecodeBatcher:
             self._count_moe(len(batch) + len(gen_states))
             self._count_stream(len(batch), len(batch) + len(gen_states))
             if tables is not None:
-                self._count_paged(positions)
+                self._pool.count_step(self.stats, positions, self._lane_held)
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
@@ -2968,7 +2768,7 @@ class DecodeBatcher:
             self.stats["spec_accepted"] += accepted_total
             self.stats["max_spec_lanes"] = max(self.stats["max_spec_lanes"], n_spec)
             self._count_moe(n_spec * S, seq=S)
-            self._count_paged(positions, seq=S)
+            self._pool.count_step(self.stats, positions, self._lane_held, seq=S)
             duration = time.perf_counter() - t_step
             tm.STEP_SPEC.observe(duration)
             tm.STEPS_SPEC.inc()
@@ -3084,7 +2884,7 @@ class DecodeBatcher:
         ``write_range=(t0, t1)`` declares the token range the fn writes:
         paged mode allocates/forks those pages up front (prepare_write) so
         the check-in scatter has somewhere to land."""
-        self._refuse_for_state(
+        self.backend.cache.refuse(
             "an exclusive op on a checked-out lane (deep prompts, beam search's hypo_ids, a seeded or imported cache)",
             "the lane's session-shaped view holds keys and values only",
         )
@@ -3238,7 +3038,7 @@ class DecodeBatcher:
         device pair are the same slices still resident in HBM (None under
         lockstep, whose shards are per-process) — the prefix cache's device
         tier pins these so a later hit can seed without re-uploading."""
-        self._refuse_for_state(_SNAPSHOT, _SNAPSHOT_WHY)
+        self.backend.cache.refuse(_SNAPSHOT, _SNAPSHOT_WHY)
         self._check_lane(lane)
 
         def run():
@@ -3278,7 +3078,7 @@ class DecodeBatcher:
         ``snapshot_lane``'s host pair, or None when the lane isn't suspended,
         is busy, or its swap entry doesn't cover ``[0, position)`` — the
         caller falls back to the device path."""
-        self._refuse_for_state(_SNAPSHOT, _SNAPSHOT_WHY)
+        self.backend.cache.refuse(_SNAPSHOT, _SNAPSHOT_WHY)
         if self.page_size is None:
             return None
         slot = self._scheduler.lanes.get(lane)

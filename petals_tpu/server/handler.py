@@ -308,29 +308,10 @@ class TransformerHandler:
         self.server_gen_params = server_gen_params
         self.draft_model = draft_model
         self.spec_k = spec_k
-        if prefix_cache_bytes > 0 and getattr(backend, "state_layers", None):
-            # a hit seeds a session's cache cut to the prefix's end, and a state cannot be cut back: off for
-            # a span that keeps one, by what its family declares (ModelFamily.block_state)
-            logger.info(
-                f"Prefix cache off: {len(backend.state_layers)} of the span's {backend.n_blocks} blocks keep a "
-                f"recurrent state, which cannot be cut back to a stored prefix"
-            )
-            prefix_cache_bytes = 0
-        if prefix_cache_bytes > 0 and getattr(backend, "index_row", None) is not None:
-            # a stored prefix is keys and values (a snapshot, or pinned pages a hit adopts and forks): neither
-            # carries the index rows a span with a learned sparse attention caches beside them
-            logger.info(
-                "Prefix cache off: the span's positions cache an index row beside their keys and values, "
-                "which a stored prefix does not carry"
-            )
-            prefix_cache_bytes = 0
-        if prefix_cache_bytes > 0 and getattr(backend, "latent_row", None) is not None:
-            # a stored prefix is keys and values a head (a snapshot, or pinned pages a hit adopts and forks through
-            # paths laid out for them): a span that caches a latent row in their place has neither
-            logger.info(
-                "Prefix cache off: the span's positions cache a latent row in place of keys and values, "
-                "which a stored prefix does not carry"
-            )
+        refusal = backend.cache.prefix_cache_refusal() if prefix_cache_bytes > 0 else None
+        if refusal is not None:
+            # off for a span that holds more than keys and values, by what its family declares (server/span_cache.py)
+            logger.info(refusal)
             prefix_cache_bytes = 0
         if prefix_cache_bytes > 0:
             from petals_tpu.server.prefix_cache import PrefixCache
@@ -1474,7 +1455,7 @@ class TransformerHandler:
         info = dict(self.server_info_fn()) if self.server_info_fn else {}
         info.update(
             cache_tokens_available=max(
-                self.memory_cache.bytes_left // max(self.backend.cache_bytes_per_token(), 1), 0
+                self.memory_cache.bytes_left // max(self.backend.cache.cache_bytes_per_token(), 1), 0
             ),
             first_block=self.backend.first_block,
             n_blocks=self.backend.n_blocks,
@@ -1761,7 +1742,7 @@ class TransformerHandler:
                             f"start_from_position {start_from} is ahead of cache ({position})"
                         )
                     if 0 < start_from < position:
-                        backend.refuse_for_state(
+                        backend.cache.refuse(
                             f"start_from_position {start_from} behind the cache's position {position}",
                             "a state cannot be cut back to an earlier position (0 starts the session over)",
                         )
@@ -1770,7 +1751,7 @@ class TransformerHandler:
                         reg["position"] = position
 
                 if "kv_adopt" in step or "kv_import" in step:
-                    backend.refuse_for_state(
+                    backend.cache.refuse(
                         "kv_adopt / kv_import", "they seed keys and values cut to a position; the state is not shipped yet"
                     )
                 if "kv_adopt" in step:

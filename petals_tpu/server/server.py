@@ -651,11 +651,11 @@ class Server:
     def _server_info(self, state: ServerState) -> ServerInfo:
         cache_tokens_left = None
         if self.memory_cache is not None and self.backend is not None:
-            per_token = self.backend.cache_bytes_per_token()
+            per_token = self.backend.cache.cache_bytes_per_token()
             if getattr(self, "kv_quant_type", "none") != "none":
                 # quantized paged pool: a cached token costs wire bytes, so
                 # the same budget advertises ~4x the remaining capacity
-                per_token = self.backend.kv_bytes_per_token()
+                per_token = self.backend.cache.kv_bytes_per_token()
             cache_tokens_left = int(self.memory_cache.bytes_left // max(per_token, 1))
         rps = getattr(self, "_rps_info", None) or {}
         return ServerInfo(
@@ -884,13 +884,7 @@ class Server:
         batch_max_length = self.batch_max_length or min(self.inference_max_length, 1024)
         batch_lanes = self.batch_lanes
         if batch_lanes is None:
-            lane_bytes = self.backend.cache_bytes_per_token() * batch_max_length
-            if self.kv_quant_type != "none":
-                # quantized pool pages cost wire bytes on device too (packed
-                # codes + f32 scales), so the budget affords ~4x the lanes
-                lane_bytes = self.backend.kv_bytes_per_token() * batch_max_length
-            # pages in the blocks that keep keys and values, and the lane's fixed part: its states
-            lane_bytes += self.backend.state_bytes_per_lane()
+            lane_bytes = self.backend.cache.lane_bytes(batch_max_length)
             affordable = int(self.memory_cache.max_size_bytes // 2 // max(lane_bytes, 1))
             batch_lanes = max(min(8, affordable), 0)
         handler = TransformerHandler(
@@ -940,7 +934,7 @@ class Server:
             return None
         from petals_tpu.server.backend import SPEC_CUTS_BACK
 
-        self.backend.refuse_for_state("speculative decoding (--draft_model)", SPEC_CUTS_BACK)
+        self.backend.cache.refuse("speculative decoding (--draft_model)", SPEC_CUTS_BACK)
         if (
             not self.server_side_generation
             or self.num_blocks != self.cfg.num_hidden_layers

@@ -168,12 +168,11 @@ def measure_compute_rps(
     )
 
     token = np.zeros((1, 1, backend.hidden_size), np.float32)
-    if backend.state_layers or backend.index_row is not None or backend.latent_row is not None:
+    if backend.cache.paged_only:
         # a block with a recurrent state, or one whose positions cache an index row or a latent row, has no
         # private cache: the path that serves it is the paged lane pool's step, here over one lane of four pages
         # beside its slot of the state pool (or its pages of the index pool)
-        descs = (*backend.paged_cache_descriptors(4, 64, 0, 1), *backend.state_cache_descriptors(1), *backend.index_cache_descriptors(4, 64))
-        kv = tuple(d.make_zeros() for d in descs)
+        kv = tuple(d.make_zeros() for d in backend.cache.pool_descriptors(4, 64, 1, 0, 1))
         tables = np.arange(4, dtype=np.int32)[None]
         step = lambda kv, position: backend.paged_decode_step(token, kv, np.full(1, position, np.int32), tables)
     else:

@@ -80,7 +80,10 @@ def test_a_sync_session_s_stretches_tile_its_time_reply_to_reply(swarm):
     _assert_tiles(rows)
     last = rows[-1]  # no request followed it: a wake, and none of the three stretches up to a K2
     assert last["wake_s"] > 0 and [last[k] for k in ("user_s", "submit_s", "build_s")] == [None] * 3
-    assert all(r[k] > 0 for r in rows[:-1] for k in (*TURN, "away_s")) and all(r["relay_s"] == 0.0 for r in rows)
+    assert all(r[k] > 0 for r in rows[:-1] for k in TURN) and all(r["relay_s"] == 0.0 for r in rows)
+    # away_s runs from K2 to K3, and a reply read before the thread that wrote its own frame had read K2 makes K2 = K3
+    # (telemetry/spans.py): exactly 0.0 then, which a busy host brings about, and nowhere else
+    assert all(r["away_s"] > 0 or (r["away_s"] == 0.0 and r["wrote"] == 1) for r in rows[:-1]) and rows[0]["away_s"] > 0
     assert all(r["user_s"] >= think for r in rows[:-1])
     # the session's sums are the rows' columns, each stretch counted for the steps that have it
     client = report["client"]

@@ -31,7 +31,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_DEEPSEEK_V3, make_tiny_deepseek_v3, tiny_deepseek_v3_tensors
+from tests.utils import counted, lane_pools, make_tiny_deepseek_v3, tiny_deepseek_v3_tensors, TINY_DEEPSEEK_V3
 
 HF = dict(TINY_DEEPSEEK_V3)
 LAYERS, KINDS = HF["num_hidden_layers"], reference.layer_kinds(HF)
@@ -311,13 +311,13 @@ def test_absorbed_equals_expanded_over_ragged_lanes_permuted_pages_an_idle_lane_
 def test_the_pools_hold_one_row_a_position_once_and_rows_land_where_the_tables_say(tiny):
     path, _ = tiny
     backend = whole_backend(path)
-    assert backend.latent_row == (32, 8) and backend.index_row is None and backend.state_layers == [] and backend.kv_layers == [0, 1, 2, 3]
+    assert backend.cache.latent_row == (32, 8) and backend.cache.index_row is None and backend.cache.state_layers == () and backend.cache.kv_layers == (0, 1, 2, 3)
     assert [run[0] for run in backend.runs] == ["dense", "sparse"]
-    c, pe = backend.paged_cache_descriptors(12, 16, 0, 4)
+    c, pe = lane_pools(backend, 12, 16, end=4)[0]
     assert c.shape == (4, 12, 16, 32) and pe.shape == (4, 12, 1, 128)  # 16 rotated keys of 8 to a row of 128; no pool of keys, none of values
-    assert backend.index_cache_descriptors(12, 16) == () and backend.state_cache_descriptors(3) == ()
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == 4 * ROW == 640 and backend.pool_row == (40,)
-    assert sum(int(np.prod(d.shape)) * 4 for d in (c, pe)) == 12 * 16 * backend.kv_bytes_per_token()  # stored once, nothing padded
+    assert lane_pools(backend, 12, 16)[1] == () and lane_pools(backend, 1, 1, 3)[1] == ()
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == 4 * ROW == 640 and backend.cache.pool_row == (40,)
+    assert sum(int(np.prod(d.shape)) * 4 for d in (c, pe)) == 12 * 16 * backend.cache.kv_bytes_per_token()  # stored once, nothing padded
     c_kv = PagedKV(jnp.zeros((6, 16, 32), jnp.float32), jnp.asarray([[4, 1, -1], [0, 5, 2]], jnp.int32))
     pe_kv = PagedKV(jnp.zeros((6, 1, 128), jnp.float32), c_kv.tables)
     new_c, new_pe = jnp.arange(2 * 32, dtype=jnp.float32).reshape(2, 1, 32) + 1, jnp.arange(2 * 8, dtype=jnp.float32).reshape(2, 1, 8) + 1
@@ -385,14 +385,14 @@ def test_the_published_span_s_cache_is_1152_bytes_a_position_a_layer_and_its_lan
     assert matrices(runs[0]) == 64_094_208 and matrices(runs[1]) == 640_024_576
     assert matrices(runs[0]) + 5 * matrices(runs[1]) == 3_264_217_088  # 6.53 GB in bf16, 6.08 GiB
     args = config["server_args"]
-    assert backend.latent_row == (512, 64) and backend.kv_bytes_per_token() == backend.cache_bytes_per_token() == 6 * 1152 == 6912
+    assert backend.cache.latent_row == (512, 64) and backend.cache.kv_bytes_per_token() == backend.cache.cache_bytes_per_token() == 6 * 1152 == 6912
     assert 2 * 6 * backend.num_kv_heads * backend.head_dim * 2 == 6 * 8192  # what the published keys would size, read as keys and values
-    c, pe = backend.paged_cache_descriptors(8 * 512, 64, 0, 6)
+    c, pe = lane_pools(backend, 8 * 512, 64, end=6)[0]
     assert c.shape == (6, 4096, 64, 512) and pe.shape == (6, 4096, 32, 128)
     pool = sum(int(np.prod(d.shape)) * 2 for d in (c, pe))
     assert pool == args["batch_lanes"] * 6912 * args["batch_max_length"] == 1_811_939_328 and "attn_cache_bytes" not in args
     assert pool <= 0.15 * 16 * 2**30
-    assert backend.decode_walks(8, 512, 64) == ()  # the latent walk counts itself: ``latent_reads``
+    assert backend.cache.lane_pool(8, 512, 64).walks == ()  # the latent walk counts itself: ``latent_reads``
 
 
 @pytest.mark.parametrize("on_tpu", [False, True], ids=["composed", "kernel"])
@@ -412,18 +412,18 @@ def test_the_decode_counters_count_what_the_path_that_runs_reads(monkeypatch, on
     assert latent.decode_path(*latent.latent_pool_rows(16, 32, 8), jnp.float32) == "composed"  # the toy's rows: 32 wide, eight keys to a row
     assert latent.decode_path(*rows, jnp.float16) == "composed" and "float16" in latent.decode_kernel_unsupported(*rows, jnp.float16)
     contexts = np.array([16_400, 24_576, 30_720, 1, 32_768])  # three lanes idle
-    reads = backend.latent_reads(8, 512, 64, contexts - 1)
+    reads = counted(backend, 8, 512, 64, contexts - 1)
     held = int(contexts.sum())
     assert reads["latent_rows_held"] == 6 * held and reads["latent_score_pairs"] == 6 * held and reads["latent_rows_absorbed"] == 6 * 5
     if on_tpu:
         block = latent.DECODE_KERNEL_PAGES * 64
         fetched = sum(block for ctx in contexts for i in range(-(-512 * 64 // block)) if latent._live(i, block, ctx))
         assert reads["latent_rows_read"] == 6 * fetched
-        cell = backend.latent_reads(8, 512, 64, np.array([16_400, 18_000, 20_480, 23_000, 24_576, 27_000, 29_500, 30_720]) - 1)
+        cell = counted(backend, 8, 512, 64, np.array([16_400, 18_000, 20_480, 23_000, 24_576, 27_000, 29_500, 30_720]) - 1)
         assert 1.0 <= cell["latent_rows_read"] / cell["latent_rows_held"] < 1.06
     else:
         assert reads["latent_rows_read"] == 6 * 8 * 32_768
-    assert backend.latent_reads(8, 512, 64, np.array([], np.int64))["latent_rows_read"] == 0
+    assert counted(backend, 8, 512, 64, np.array([], np.int64))["latent_rows_read"] == 0
 
 
 def test_lane_auto_sizing_and_the_occupancy_count_the_latent_row(tiny):
@@ -438,7 +438,7 @@ def test_lane_auto_sizing_and_the_occupancy_count_the_latent_row(tiny):
         await server.start()
         try:
             batcher = server.handler.batcher
-            assert batcher.n_lanes == 4 and batcher._latent and batcher._n_index == 0 and batcher._n_state == 0
+            assert batcher.n_lanes == 4 and batcher.backend.cache.latent_row is not None and batcher.backend.cache.index_row is None and len(batcher.backend.cache.lane_state) == 0
             await batcher.ensure_open()
             info = batcher.occupancy_info()
             assert info["kv_bytes_per_token"] == per_token and info["latent_row"] == [32, 8] and info["latent_bytes_held"] == 0
@@ -548,7 +548,7 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     LOGITS of every position against the reference's whole forward pass."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b._latent for b in batchers) and [b.page_size for b in batchers] == [8, 16]
+    assert all(b is not None and b.backend.cache.latent_row is not None for b in batchers) and [b.page_size for b in batchers] == [8, 16]
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, 85)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))
@@ -558,7 +558,7 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     logits = np.asarray(model.lm_logits(np.concatenate(outs, axis=1)))[0]
     np.testing.assert_allclose(logits, reference_logits(tensors, ids[0]), atol=3e-4, rtol=0)
     for batcher, was in zip(batchers, before):
-        layers = len(batcher.backend.kv_layers)
+        layers = len(batcher.backend.cache.kv_layers)
         assert batcher.stats["mixed_steps"] - was["mixed_steps"] == 3
         assert batcher.stats["latent_rows_expanded"] - was["latent_rows_expanded"] == 70 * layers
         assert batcher.stats["latent_rows_absorbed"] - was["latent_rows_absorbed"] == 15 * layers
@@ -660,7 +660,7 @@ def test_what_ships_or_cuts_a_cache_is_refused_over_the_wire_and_the_prefix_cach
     async def main():
         server, client = await start_server(path, batch_lanes=2, batch_max_length=64, page_size=8)  # prefix_cache_bytes: the default
         try:
-            assert server.handler.prefix_cache is None and server.handler.batcher._latent
+            assert server.handler.prefix_cache is None and server.handler.batcher.backend.cache.latent_row is not None
             data = rows(21, 40)
             stream = await open_session(client, path, 64)
             await step(stream, data[:, :30])
@@ -699,7 +699,7 @@ def test_a_family_without_a_latent_row_opens_the_pools_and_programs_it_had(tmp_p
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *(load_block_params(path, i, dtype=jnp.float32) for i in range(2)))
     backend = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=2, memory_cache=MemoryCache(None),
                                  compute_dtype=jnp.float32, use_flash=False)
-    k, v = backend.paged_cache_descriptors(6, 8, 0, 2)
-    assert backend.latent_row is None and k.shape == v.shape and backend.kv_bytes_per_token() == 2 * 2 * backend.num_kv_heads * backend.head_dim * 4
+    k, v = lane_pools(backend, 6, 8, end=2)[0]
+    assert backend.cache.latent_row is None and k.shape == v.shape and backend.cache.kv_bytes_per_token() == 2 * 2 * backend.num_kv_heads * backend.head_dim * 4
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
-    assert not batcher._latent and not LATENT_KEYS & set(batcher.stats) and "latent_row" not in batcher.occupancy_info()
+    assert batcher.backend.cache.latent_row is None and not LATENT_KEYS & set(batcher.stats) and "latent_row" not in batcher.occupancy_info()

@@ -333,7 +333,7 @@ def test_private_cache_step_and_stateless_forward_walk_the_runs(tiny):
     path, tensors = tiny
     backend = whole_backend(path)
     assert [(kind, start, length) for kind, start, length in backend.runs] == span_runs(KINDS)
-    assert backend.moe_dims == MoeDims(16, 4, 64, 32, routed=16, first=0) and backend.layer_windows == [8, 8, 8, None, 8]
+    assert backend.moe_dims == MoeDims(16, 4, 64, 32, routed=16, first=0) and backend.cache.layer_windows == (8, 8, 8, None, 8)
     x = np.random.RandomState(5).randn(1, SEQ, 64).astype(np.float32)
     want = reference_hidden(HF, tensors, x[0])
     np.testing.assert_allclose(np.asarray(backend.forward(x))[0], want, atol=1e-4, rtol=0)
@@ -397,7 +397,7 @@ def test_paged_prefill_then_decode_matches_the_reference_s_full_pass(swarm):
 
 
 def backend_reach(batcher) -> int:
-    return batcher.n_lanes * batcher.backend.pages_gathered(1, batcher.max_pages, batcher.page_size)
+    return batcher.n_lanes * batcher._pool._pages_gathered(1)
 
 
 @pytest.mark.parametrize("path", ["composed", "kernel"])
@@ -405,7 +405,7 @@ def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monk
     """Every family on the paged pool counts the table slots its steps read
     against those they are handed; the pages a window still reaches are
     counted for a family that declares windows alone. A decode step's count
-    follows the walk that runs (``backend.decode_walks``): the composed walk
+    follows the walk that runs (``LanePool.walks``): the composed walk
     reads every lane to the longest LIVE lane's block, the kernel each live
     lane to its own, and ``attn_pages_kernel`` says how many of the slots read
     the kernel fetched."""
@@ -428,27 +428,27 @@ def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monk
     monkeypatch.setattr(pfa, "WALK_BLOCK_BYTES", 2 * a_page)
     monkeypatch.setattr(pfa, "WALK_KERNEL_BLOCK_BYTES", a_page)
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
-    assert read <= set(batcher.stats) and not held & set(batcher.stats) and backend.layer_windows is None and len(backend.runs) == 1
+    assert read <= set(batcher.stats) and not held & set(batcher.stats) and backend.cache.layer_windows is None and len(backend.runs) == 1
     assert "moe_weight_passes" not in batcher.stats and not held & set(batcher.occupancy_info())
-    assert batcher._walks == ((None, 1, 1, False, path),) and batcher.occupancy_info()["decode_walk"] == [path]
+    assert batcher._pool.walks == ((None, 1, 1, False, path),) and batcher.occupancy_info()["decode_walk"] == [path]
     # the composed walk: both lanes to the longest live one's page; the kernel: each live lane to its own
     for positions, walked, own in (([5, 64], 1, 1), ([64, 16], 2, 2), ([47, 0], 3, 4), ([64, 64], 0, 0), ([63, 64], 4, 4), ([63, 17], 4, 6)):
         was = dict(batcher.stats)
-        batcher._count_paged(np.asarray(positions, np.int32))  # nothing of it reads the tables (PR 51)
+        batcher._pool.count_step(batcher.stats, np.asarray(positions, np.int32), batcher._lane_held)  # nothing of it reads the tables (PR 51)
         want = own if path == "kernel" else 2 * walked  # an idle lane is no length
         assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == want, positions
         assert batcher.stats["attn_pages_kernel"] - was["attn_pages_kernel"] == (want if path == "kernel" else 0), positions
         assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4
     was = dict(batcher.stats)
-    batcher._count_paged(np.asarray([64, 3], np.int32), chunk=(0, 16, 20))  # a chunk gathers its lane's whole row
+    batcher._pool.count_step(batcher.stats, np.asarray([64, 3], np.int32), batcher._lane_held, chunk=(0, 16, 20))  # a chunk gathers its lane's whole row
     assert batcher.stats["attn_pages_gathered"] - was["attn_pages_gathered"] == (1 if path == "kernel" else 2 * 1) + 4
     assert batcher.stats["attn_pages_kernel"] - was["attn_pages_kernel"] == (1 if path == "kernel" else 0)  # the chunk's gather is no walk
     assert batcher.stats["attn_pages_tabled"] - was["attn_pages_tabled"] == 2 * 4 + 4
     # under a static window of 128 (pages of 64, blocks of 2 slots, lanes at 300 and 10): the table cut to its reach, or whole
     last = np.asarray([300, 10])
     for cut, want in ((True, 4 + 2), (False, (6 - 2) + 2)):  # the kernel skips the whole blocks before a lane's first position in sight
-        assert backend.pages_walked(((128, 1, 2, cut, "kernel"),), last, 64, 2) == (want, want)
-    assert backend.pages_walked(((128, 1, 2, True, "composed"),), last, 64, 2) == (2 * 4, 0)
+        assert pfa.pages_walked(((128, 1, 2, cut, "kernel"),), last, 64, 2) == (want, want)
+    assert pfa.pages_walked(((128, 1, 2, True, "composed"),), last, 64, 2) == (2 * 4, 0)
     if path == "kernel":
         # the same pool under the RW generation's ALiBi bias: the family says what its blocks hand their attention
         # (ModelFamily.block_attention), the kernel knows no bias, and the counters say so
@@ -457,12 +457,12 @@ def test_a_family_without_declared_windows_has_no_window_counters(tmp_path, monk
         stacked = jax.tree_util.tree_map(lambda leaf: leaf[None], load_block_params(model, 0, dtype=jnp.float32))
         biased = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=1, memory_cache=MemoryCache(None),
                                     compute_dtype=jnp.float32, use_flash=False)
-        assert biased.pool_row == backend.pool_row and biased.decode_walks(2, 4, 16) == ((None, 1, 1, False, "composed"),)
+        assert biased.cache.pool_row == backend.cache.pool_row and biased.cache.lane_pool(2, 4, 16).walks == ((None, 1, 1, False, "composed"),)
     monkeypatch.undo()
     exaone = whole_backend(make_tiny_exaone_moe(str(tmp_path)))
     batcher = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=16)
     assert read | held | {"moe_dense_tokens", "moe_grouped_tokens", "moe_hit_tokens", "moe_weight_passes"} <= set(batcher.stats)
-    assert batcher.occupancy_info()["decode_walk"] == ["composed"] * len(batcher._walks)  # off the chip
+    assert batcher.occupancy_info()["decode_walk"] == ["composed"] * len(batcher._pool.walks)  # off the chip
     dense_pool = DecodeBatcher(exaone, exaone.memory_cache, PriorityTaskQueue(), n_lanes=2, max_length=64, page_size=None)
     assert not (read | held) & set(dense_pool.stats)  # the counters count pages: the paged pool only
 

@@ -6,10 +6,12 @@ less than the step the returning lanes would otherwise sit out
 answer within a few milliseconds settle into groups that take turns, and a
 step of eight lanes' width carries four tokens.
 
-Every case runs a real ``DecodeBatcher`` on the tiny two-block backend whose
-step programs are wrapped to take a set time (the ``slow`` pattern of
-tests/test_mixed_batching.py); clients are coroutines that come back after a
-set delay."""
+The cases that drive a live batcher run a real ``DecodeBatcher`` on the tiny
+two-block backend whose step programs are wrapped to take a set time (the
+``slow`` pattern of tests/test_mixed_batching.py); clients are coroutines that
+come back after a set delay. The cases that test the RULE (``_gather_until``,
+which takes the time) hand it times they choose, on a clock they step
+(``_loop_on_a_stepped_clock``): no sleep, nothing a busy host can stretch."""
 
 import asyncio
 import collections
@@ -243,31 +245,55 @@ def test_lanes_that_return_fast_settle_into_one_step_a_round(tiny, k):
 # ---------------------------------------------------- (b) slow returns: as today
 
 
+def _loop_on_a_stepped_clock(batcher, step_s, clients):
+    """The flush loop under the rule on a clock the test steps, no thread and no sleep: ``clients`` are ``(lane, its first
+    request's time, the delays between a reply and the lane's next request)``; a request is booked as ``step()`` books it
+    (``_count_return``, ``_enqueue``), ``_gather_until(now)`` is asked before every step with the time the clock shows, a
+    step of ``step_s`` starts when it says start now (or when the time it gave runs out) and books its replies as the
+    flush loop does. Returns ``(what the rule answered, the lanes each step carried)``."""
+    batcher._step_s = step_s
+    arrivals = sorted((first, lane, iter(delays)) for lane, first, delays in clients)
+    asked, rides, now = [], [], 0.0
+    while arrivals or batcher._pending:
+        while arrivals and arrivals[0][0] <= now:
+            at, lane, delays = arrivals.pop(0)
+            back = batcher._returns.setdefault(lane, _LaneReturn())
+            if back.replied is not None:
+                back.came_back(at)
+            batcher._pending.append((lane, delays, 0, None, 0))
+        if not batcher._pending:
+            now = arrivals[0][0]
+            continue
+        asked.append(batcher._gather_until(now))
+        until = asked[-1][0]
+        if until is not None and arrivals and arrivals[0][0] < until:
+            now = arrivals[0][0]  # an arrival wakes the gather, which asks again
+            continue
+        now = (now if until is None else until) + step_s
+        rides.append(tuple(lane for lane, *_ in batcher._pending))
+        for lane, delays, *_ in batcher._pending:
+            batcher._returns[lane].reply_sent(now)
+            delay = next(delays, None)
+            if delay is not None:
+                arrivals.append((now + delay, lane, delays))
+        arrivals.sort(key=lambda arrival: arrival[:2])
+        batcher._pending = []
+    return asked, rides
+
+
 def test_a_lane_that_returns_slower_than_a_step_is_never_waited_for(tiny):
     """Two lanes of a server that is one hop of many: each comes back three
-    steps after its reply. The gather never waits, and the steps, their order
-    and what rides them are what the loop without a gather (the parent's)
-    gives."""
-
-    async def drive(gathering):
-        async with _rig(tiny, 2, 0.03) as rig:
-            batcher = rig.batcher
-            if not gathering:
-                async def start_now():
-                    return None
-
-                batcher._gather = start_now
-            a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
-            await asyncio.gather(
-                _client(rig, a, 6, 0.09), _client(rig, b, 6, 0.09, start_after=0.06)
-            )
-            return [ride[2] for ride in rig.rides], dict(batcher.stats), (a, b)
-
-    with_gather, stats, (a, b) = run(drive(True))
-    without, stats_parent, _ = run(drive(False))
-    assert with_gather == without == [(a,), (b,)] * 6
-    assert stats["gather_waits"] == 0 and stats["gather_wait_s"] == 0.0, stats
-    assert stats["batched_steps"] == stats_parent["batched_steps"] == 14  # the warm-up's two and twelve
+    steps after its reply. The rule finds nobody to wait for at any step's
+    start, and the steps, their order and what rides them are what the loop
+    without a gather (the parent's) gives. On a clock the test steps: the
+    rule is what is tested, and a busy host cannot stretch a return."""
+    backend, _cfg = tiny
+    batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=2)
+    a, b = 0, 1
+    asked, rides = _loop_on_a_stepped_clock(batcher, 0.03, [(a, 0.0, [0.09] * 5), (b, 0.06, [0.09] * 5)])
+    assert rides == [(a,), (b,)] * 6
+    assert asked == [(None, [])] * 12
+    assert all(len(batcher._returns[lane].returns) == 5 and min(batcher._returns[lane].returns) >= 0.09 - 1e-9 for lane in (a, b))
 
 
 def test_slow_returns_with_jitter_are_never_waited_for(tiny):
@@ -275,46 +301,21 @@ def test_slow_returns_with_jitter_are_never_waited_for(tiny):
     steps after its reply, give or take one (gauss(120 ms, 30 ms) on a step
     of 30 ms), so at any start some lane is due or overdue. The rule finds
     nobody to wait for at any of them: the gather never suspends, which makes
-    the loop the parent's, step for step; and the steps are as many as the
-    loop without a gather runs on the same returns."""
-
-    async def drive(gathering):
-        async with _rig(tiny, 4, 0.03) as rig:
-            batcher = rig.batcher
-            asked = []
-            if gathering:
-                rule = batcher._gather_until
-
-                def asking(now):
-                    asked.append(rule(now))
-                    return asked[-1]
-
-                batcher._gather_until = asking
-            else:
-                async def start_now():
-                    return None
-
-                batcher._gather = start_now
-            lanes = [await batcher.acquire_lane() for _ in range(4)]
-
-            async def client(i, lane):
-                rng = random.Random(i)
-                await asyncio.sleep(rng.uniform(0, 0.12))
-                for r in range(16):
-                    await batcher.step(lane, _hidden(rig.cfg, 97 * lane + r), r)
-                    await asyncio.sleep(max(rng.gauss(0.12, 0.03), 0.04))
-
-            await asyncio.gather(*(client(i, lane) for i, lane in enumerate(lanes)))
-            assert all(len(batcher._returns[lane].returns) == 5 for lane in lanes)  # on record all the same
-            return asked, dict(batcher.stats)
-
-    asked, stats = run(drive(True))
-    _, stats_parent = run(drive(False))
-    assert len(asked) >= stats["batched_steps"] - 2  # asked before every step (the rig's warm-up ran two)
+    the loop the parent's, step for step. On a clock the test steps, with the
+    returns the seeds give: the rule is what is tested."""
+    backend, _cfg = tiny
+    batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=4)
+    clients = []
+    for lane in range(4):
+        rng = random.Random(lane)
+        first = rng.uniform(0, 0.12)
+        clients.append((lane, first, [max(rng.gauss(0.12, 0.03), 0.04) for _ in range(15)]))
+    asked, rides = _loop_on_a_stepped_clock(batcher, 0.03, clients)
+    assert all(len(batcher._returns[lane].returns) == 5 for lane in range(4))  # on record all the same
+    assert len(asked) == len(rides) and sum(map(len, rides)) == 4 * 16  # asked before every step, and once
     assert all(answer == (None, []) for answer in asked), [answer for answer in asked if answer[1]]
-    assert [stats[key] for key in GATHER_KEYS] == [0, 0.0, 0, 0], stats
-    assert stats["batched_tokens"] == stats_parent["batched_tokens"] == 4 * 16 + 1
-    assert abs(stats["batched_steps"] - stats_parent["batched_steps"]) <= 0.1 * stats_parent["batched_steps"]
+    assert len(rides) > 16  # the lanes never settled into one step a round: each start found some of them out
+    assert [batcher.stats[key] for key in GATHER_KEYS] == [0, 0.0, 0, 0]
 
 
 # ------------------------------------------------ (c) a lane that stops coming back

@@ -30,7 +30,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_JAMBA, jamba_layer_types, make_tiny_jamba, tiny_jamba_tensors
+from tests.utils import jamba_layer_types, lane_pools, make_tiny_jamba, tiny_jamba_tensors, TINY_JAMBA
 
 HF = dict(TINY_JAMBA)
 MAMBA, ATTENTION = "mamba", "attention"
@@ -269,18 +269,18 @@ def test_forward_and_backward_run_the_chunked_form_from_a_zero_state(tiny):
 def test_the_page_pool_is_as_deep_as_the_attention_layers_and_the_state_pool_as_the_mamba_ones(tiny):
     path, _ = tiny
     backend = whole_backend(path)
-    assert backend.kv_layers == [1, 3] and backend.state_layers == [0, 2] and backend._slots == [0, 0, 1, 1]
+    assert backend.cache.kv_layers == (1, 3) and backend.cache.state_layers == (0, 2) and backend.cache.slots == (0, 0, 1, 1)
     assert [kind for kind, _, _ in backend.runs] == [MAMBA, ATTENTION, MAMBA, ATTENTION] and backend.moe_dims is None
-    k, v = backend.paged_cache_descriptors(12, 16, 0, N)
+    k, v = lane_pools(backend, 12, 16, end=N)[0]
     assert backend.num_kv_heads == 1 and k.shape == v.shape == (2, 12, 16, 16)  # one kv head of 16: a folded row
-    state, tail = backend.state_cache_descriptors(3)
+    state, tail = lane_pools(backend, 1, 1, 3)[1]
     assert state.shape == (2, 3, 16, 128) and jnp.dtype(state.dtype) == jnp.float32  # [d_state, channels], float32 whatever the cache's dtype
     assert tail.shape == (2, 3, 3, 128)
-    assert backend.state_bytes_per_lane() == 2 * (16 * 128 + 3 * 128) * 4
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == 2 * 2 * 1 * 16 * 4  # two layers of pages, not four
+    assert backend.cache.state_bytes_per_lane() == 2 * (16 * 128 + 3 * 128) * 4
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == 2 * 2 * 1 * 16 * 4  # two layers of pages, not four
     # the one-step form has no kernel of its own yet: the gated delta rule's says why it is not that state's
-    assert backend.state_step_path(3) == "plain"
-    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in backend.state_cache_descriptors(3))
+    assert backend.cache.lane_pool(3, 4, 16).state_step == "plain"
+    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in lane_pools(backend, 1, 1, 3)[1])
     assert "not [layers, lanes, heads, d_k, d_v]" in linear_attention.step_kernel_unsupported(linear_attention.StatePool(leaves, 0), 1)
 
 
@@ -310,17 +310,17 @@ def test_the_published_span_s_pools_and_what_a_lane_costs():
     n_params = sum(int(np.prod(leaf.shape)) for run in runs for leaf in run.values())
     assert 2.86e9 < n_params < 2.87e9
     backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=28, memory_cache=None)
-    assert len(backend.kv_layers) == 2 and len(backend.state_layers) == 26
-    k_pool = backend.paged_cache_descriptors(320, 64, 0, 28)[0]
+    assert len(backend.cache.kv_layers) == 2 and len(backend.cache.state_layers) == 26
+    k_pool = lane_pools(backend, 320, 64, end=28)[0][0]
     assert k_pool.shape == (2, 320, 64, 128)  # one kv head: a folded row (stored_row)
-    state, tail = backend.state_cache_descriptors(8)
+    state, tail = lane_pools(backend, 1, 1, 8)[1]
     assert (state.shape, tail.shape) == ((26, 8, 16, 5120), (26, 8, 3, 5120)) and jnp.dtype(tail.dtype) == jnp.bfloat16
-    assert backend.kv_bytes_per_token() == 2 * 512 and backend.state_bytes_per_lane() == 26 * (327_680 + 30_720)
+    assert backend.cache.kv_bytes_per_token() == 2 * 512 and backend.cache.state_bytes_per_lane() == 26 * (327_680 + 30_720)
     assert pfa.walk_kernel_unsupported(S(k_pool.shape[1:], k_pool.dtype), (8, 1, 20, 128), (8, 40)) is None
-    assert [(layers, path) for _, layers, _, _, path in backend.decode_walks(8, 40, 64)] == [(2, "composed")]  # this backend is no TPU
+    assert [(layers, path) for _, layers, _, _, path in backend.cache.lane_pool(8, 40, 64).walks] == [(2, "composed")]  # this backend is no TPU
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pfa, "_on_tpu", lambda: True)
-        assert [(layers, block, path) for _, layers, block, _, path in backend.decode_walks(8, 40, 64)] == [(2, 32, "kernel")]
+        assert [(layers, block, path) for _, layers, block, _, path in backend.cache.lane_pool(8, 40, 64).walks] == [(2, 32, "kernel")]
 
 
 def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_matches_the_reference(tiny):
@@ -440,8 +440,8 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     the reference's whole forward pass."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b.page_size == 8 and b._n_state == 2 for b in batchers)
-    assert [len(b.backend.state_layers) for b in batchers] == [1, 1] and [len(b.backend.kv_layers) for b in batchers] == [1, 1]
+    assert all(b is not None and b.page_size == 8 and len(b.backend.cache.lane_state) == 2 for b in batchers)
+    assert [len(b.backend.cache.state_layers) for b in batchers] == [1, 1] and [len(b.backend.cache.kv_layers) for b in batchers] == [1, 1]
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, 50)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))
@@ -498,7 +498,7 @@ def test_cache_paths_that_do_not_carry_a_state_refuse_it_with_the_reason(tiny, w
     with pytest.raises(NotImplementedError, match="jamba: .* recurrent state .*2 of its 4 blocks"):
         REFUSED_BY_THE_BACKEND[what](backend)
     attention_only = whole_backend(tiny[0], 1, 1)  # a span of this family without a state layer is served like any other
-    assert not attention_only.state_layers and attention_only.lane_state == () and len(attention_only.cache_descriptors(1, 32, 0, 1)) == 2
+    assert not attention_only.cache.state_layers and attention_only.cache.lane_state == () and len(attention_only.cache_descriptors(1, 32, 0, 1)) == 2
 
 
 def test_options_the_family_cannot_take_yet_are_refused(tiny, tmp_path):
@@ -535,7 +535,7 @@ def test_what_cuts_a_cache_back_is_refused_over_the_wire_and_the_prefix_cache_is
     async def main():
         server, client = await start_server(path, batch_lanes=2, batch_max_length=32, page_size=8)  # prefix_cache_bytes: the default
         try:
-            assert server.handler.prefix_cache is None and server.handler.batcher._n_state == 2
+            assert server.handler.prefix_cache is None and len(server.handler.batcher.backend.cache.lane_state) == 2
             data = rows(21, 12)
             stream = await open_session(client, path, 32)
             await step(stream, data[:, :8])

@@ -36,6 +36,7 @@ from petals_tpu.ops import quant as Q  # noqa: E402
 from petals_tpu.ops.flash_attention import flash_attend  # noqa: E402
 from petals_tpu.ops.paged_attention import PagedPool, stored_row  # noqa: E402
 from petals_tpu.models.registry import span_runs  # noqa: E402
+from tests.utils import counted, lane_pools  # noqa: E402
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -323,11 +324,11 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
     params = runs[0] if len(runs) == 1 else runs
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=depth, memory_cache=None, kv_quant_type=kv_quant)
     # as deep as the blocks that keep keys and values: all of them, but for a span with a recurrent state
-    descs = backend.paged_cache_descriptors(n_pages, page_size, 0, depth)  # in the form the rule stores them
+    descs = lane_pools(backend, n_pages, page_size, end=depth)[0]  # in the form the rule stores them
     pool = v5e(descs[0].shape, BF16 if kv_quant == "none" else descs[0].dtype)
     pools = pool if kv_quant == "none" else PagedPool(pool, v5e(descs[2].shape, descs[2].dtype))
     # the second pool is the first one's twin but for a span that caches a latent row in place of keys and values
-    second = pools if backend.latent_row is None else v5e(descs[1].shape, BF16)
+    second = pools if backend.cache.latent_row is None else v5e(descs[1].shape, BF16)
     # the lanes' rows and positions as one operand (backend.pack_lanes' form: a float32 row bit for bit and its position), then the tables
     avals = [params, pools, second, v5e((lanes, backend.hidden_size + 1), I32), v5e((lanes, pages_a_lane), I32)]
     step = backend._paged_decode_fn
@@ -335,11 +336,11 @@ def _compiled_step(v5e, tmp_path, config_name, chunk, pages_a_lane=16, kv_quant=
         step = backend._paged_mixed_step_fn
         avals += [v5e((1, chunk, backend.hidden_size), BF16)] + [v5e((), I32)] * 4
     donated = (1, 2)
-    if backend.state_layers:  # the state pool's leaves ride last and are donated with the pages
-        avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
+    if backend.cache.state_layers:  # the state pool's leaves ride last and are donated with the pages
+        avals.append(tuple(v5e(d.shape, d.dtype) for d in lane_pools(backend, 1, 1, lanes)[1]))
         donated += (len(avals) - 1,)
-    if backend.index_row is not None:  # as the index pool does
-        avals.append(tuple(v5e(d.shape, d.dtype) for d in backend.index_cache_descriptors(n_pages, page_size)))
+    if backend.cache.index_row is not None:  # as the index pool does
+        avals.append(tuple(v5e(d.shape, d.dtype) for d in lane_pools(backend, n_pages, page_size)[1]))
         donated += (len(avals) - 1,)
     step = functools.partial(step.__wrapped__, with_fp=False)  # the raw step under tracked_jit
     with pytest.MonkeyPatch.context() as patch:  # the backend here is the CPU: the hit dispatch's kernel would be interpreted
@@ -1283,10 +1284,10 @@ def test_the_state_space_span_s_decode_walk_is_the_kernel_s_over_its_folded_row(
     backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=28, memory_cache=None)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pfa, "_on_tpu", lambda: True)
-        assert [(layers, block, path) for _, layers, block, _, path in backend.decode_walks(8, 40, 64)] == [(2, 32, "kernel")]
-        why = pfa.walk_kernel_unsupported(S((320, 64, *backend.pool_row), BF16), (8, 1, 20, 128), (8, 40))
-    assert backend.pool_row == (128,) and why is None
-    assert [path for *_, path in backend.decode_walks(8, 40, 64)] == ["composed"]  # this backend is no TPU
+        assert [(layers, block, path) for _, layers, block, _, path in backend.cache.lane_pool(8, 40, 64).walks] == [(2, 32, "kernel")]
+        why = pfa.walk_kernel_unsupported(S((320, 64, *backend.cache.pool_row), BF16), (8, 1, 20, 128), (8, 40))
+    assert backend.cache.pool_row == (128,) and why is None
+    assert [path for *_, path in backend.cache.lane_pool(8, 40, 64).walks] == ["composed"]  # this backend is no TPU
     assert pfa.paged_kernel_unsupported(1, 128, "none") is None  # a prompt's chunk takes the paged prefill kernel
 
 
@@ -1389,11 +1390,11 @@ def test_the_absorbed_walk_s_kernel_takes_64_heads_and_the_counters_count_it(v5e
         patch.setattr(latent, "_on_tpu", lambda: True)  # the backend here is the CPU: the kernel would be interpreted
         _compile(walk, *avals)
         assert latent.decode_path(*latent.latent_pool_rows(64, 512, 64), BF16) == "kernel"
-        reads = backend.latent_reads(8, 40, 64, contexts - 1)
+        reads = counted(backend, 8, 40, 64, contexts - 1)
     block = latent.DECODE_KERNEL_PAGES * 64
     assert reads["latent_rows_read"] == 8 * sum(min(-(-int(ctx) // block) * block, 2 * block) for ctx in contexts)  # a table of 40 pages: two blocks
     assert reads["latent_rows_held"] == reads["latent_score_pairs"] == 8 * int(contexts.sum()) and reads["latent_rows_absorbed"] == 8 * 6
-    assert backend.latent_reads(8, 40, 64, contexts - 1)["latent_rows_read"] == 8 * 8 * 2560  # off the chip: the composed walk, every lane to the longest
+    assert counted(backend, 8, 40, 64, contexts - 1)["latent_rows_read"] == 8 * 8 * 2560  # off the chip: the composed walk, every lane to the longest
 
 
 STREAM = "xing4-29b-a4b-span8"

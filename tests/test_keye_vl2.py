@@ -30,7 +30,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_KEYE_VL2, make_tiny_keye_vl2, tiny_keye_vl2_tensors
+from tests.utils import lane_pools, make_tiny_keye_vl2, tiny_keye_vl2_tensors, TINY_KEYE_VL2
 
 HF = dict(TINY_KEYE_VL2)
 LAYERS, TOPK = HF["num_hidden_layers"], HF["sa_config"]["topk"]
@@ -287,15 +287,15 @@ def _reference_traced(tensors, x):
 def test_the_index_pool_lies_beside_the_pages_and_a_narrow_row_is_stored_several_positions_to_a_row(tiny):
     path, _ = tiny
     backend = whole_backend(path)
-    assert backend.index_row == (8, jnp.dtype(jnp.float32)) and backend.state_layers == [] and backend.kv_layers == [0, 1, 2, 3]
-    k, v = backend.paged_cache_descriptors(12, 16, 0, 4)
-    (index,) = backend.index_cache_descriptors(12, 16)
+    assert backend.cache.index_row == (8, jnp.dtype(jnp.float32)) and backend.cache.state_layers == () and backend.cache.kv_layers == (0, 1, 2, 3)
+    k, v = lane_pools(backend, 12, 16, end=4)[0]
+    (index,) = lane_pools(backend, 12, 16)[1]
     assert k.shape == v.shape == (4, 12, 16, 2 * 16)  # rows of 2 kv heads of 16, under 128 lanes: folded over the heads
     assert index.shape == (4, 12, 1, 128) and sparse.index_pool_row(16, 8) == (1, 128)  # 16 positions of 8 to a row of 128
     assert sparse.index_pool_row(8, 8) == (8, 8) and sparse.index_fold(64, 64) == 2 and sparse.index_pool_row(64, 64) == (32, 128)
     assert sparse.index_pool_row(64, 128) == (64, 128) and sparse.index_pool_row(64, 96) == (64, 96)
-    assert backend.index_bytes_per_token() == 4 * 8 * 4
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == 4 * (2 * 2 * 16 + 8) * 4
+    assert backend.cache.index_bytes_per_token() == 4 * 8 * 4
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == 4 * (2 * 2 * 16 + 8) * 4
     # rows written through permuted tables land where the fold says, and nowhere else
     pool = jnp.zeros((6, 2, 64), jnp.float32)  # 6 pages of 16 positions of width 8: fold 8, two rows a page
     tables = jnp.asarray([[4, 1, -1], [0, 5, 2]], jnp.int32)
@@ -331,13 +331,13 @@ def test_the_published_span_s_cache_is_2176_bytes_a_position_a_layer_and_its_lan
     assert sum(int(np.prod(leaf.shape[1:])) for leaf in params.values()) == 625_377_280 + 4 * 2048 // 2 * 0 + 2 * 2048 + 2 * 128 + 2 * 64
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=5, memory_cache=None)
     args = config["server_args"]
-    assert backend.index_row == (64, jnp.dtype(jnp.bfloat16))
-    assert backend.kv_bytes_per_token() == backend.cache_bytes_per_token() == 5 * 2176 == 10_880 and backend.index_bytes_per_token() == 5 * 128
-    k, v = backend.paged_cache_descriptors(8 * 512, 64, 0, 5)
-    (index,) = backend.index_cache_descriptors(8 * 512, 64)
+    assert backend.cache.index_row == (64, jnp.dtype(jnp.bfloat16))
+    assert backend.cache.kv_bytes_per_token() == backend.cache.cache_bytes_per_token() == 5 * 2176 == 10_880 and backend.cache.index_bytes_per_token() == 5 * 128
+    k, v = lane_pools(backend, 8 * 512, 64, end=5)[0]
+    (index,) = lane_pools(backend, 8 * 512, 64)[1]
     assert k.shape == (5, 4096, 64, 4, 128) and index.shape == (5, 4096, 32, 128)
     pool = sum(int(np.prod(d.shape)) * 2 for d in (k, v, index))
-    lane = backend.cache_bytes_per_token() * args["batch_max_length"]
+    lane = backend.cache.cache_bytes_per_token() * args["batch_max_length"]
     assert pool == args["batch_lanes"] * lane == 2_852_126_720 <= args["attn_cache_bytes"]
     assert args["attn_cache_bytes"] // 2 // lane == 4  # what Server._make_handler would size with no batch_lanes given
     assert args["attn_cache_bytes"] // 2 // (5 * 2048 * args["batch_max_length"]) == 4 and (2176 - 2048) / 2176 == pytest.approx(0.0588, abs=1e-4)
@@ -350,14 +350,14 @@ def test_lane_auto_sizing_and_the_occupancy_count_the_index_rows(tiny):
 
     async def main():
         backend = whole_backend(path)
-        per_token = backend.cache_bytes_per_token()
+        per_token = backend.cache.cache_bytes_per_token()
         assert per_token == 1152 and (2 * 5 * per_token * 32 + 100) // 2 // ((per_token - 128) * 32) == 5
         server = Server(path, compute_dtype=jnp.float32, use_flash=False, batch_max_length=32, page_size=16,
                         attn_cache_bytes=2 * 4 * per_token * 32 + 9 * per_token, prefix_cache_bytes=0)
         await server.start()
         try:
             batcher = server.handler.batcher
-            assert batcher.n_lanes == 4 and batcher._n_index == 1 and batcher._n_state == 0
+            assert batcher.n_lanes == 4 and batcher.backend.cache.index_row is not None and len(batcher.backend.cache.lane_state) == 0
             await batcher.ensure_open()
             info = batcher.occupancy_info()
             assert info["kv_bytes_per_token"] == per_token and info["index_bytes_per_token"] == 128
@@ -467,7 +467,7 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     LOGITS of every position against the reference's whole forward pass."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b._n_index == 1 for b in batchers) and [b.page_size for b in batchers] == [8, 16]
+    assert all(b is not None and b.backend.cache.index_row is not None for b in batchers) and [b.page_size for b in batchers] == [8, 16]
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, 85)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))
@@ -477,7 +477,7 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     logits = np.asarray(model.lm_logits(np.concatenate(outs, axis=1)))[0]
     np.testing.assert_allclose(logits, reference_logits(tensors, ids[0]), atol=3e-4, rtol=0)
     for batcher, was in zip(batchers, before):
-        layers = len(batcher.backend.kv_layers)
+        layers = len(batcher.backend.cache.kv_layers)
         assert batcher.stats["mixed_steps"] - was["mixed_steps"] == 3
         assert batcher.stats["sparse_rows_selected"] - was["sparse_rows_selected"] == (85 - TOPK) * layers
         assert batcher.stats["sparse_rows_dense"] - was["sparse_rows_dense"] == TOPK * layers
@@ -576,7 +576,7 @@ def test_what_ships_or_cuts_a_cache_is_refused_over_the_wire_and_the_prefix_cach
     async def main():
         server, client = await start_server(path, batch_lanes=2, batch_max_length=64, page_size=8)  # prefix_cache_bytes: the default
         try:
-            assert server.handler.prefix_cache is None and server.handler.batcher._n_index == 1
+            assert server.handler.prefix_cache is None and server.handler.batcher.backend.cache.index_row is not None
             data = rows(21, 40)
             stream = await open_session(client, path, 64)
             await step(stream, data[:, :30])
@@ -615,6 +615,6 @@ def test_a_family_without_an_index_row_opens_the_pools_and_programs_it_had(tmp_p
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *(load_block_params(path, i, dtype=jnp.float32) for i in range(2)))
     backend = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=2, memory_cache=MemoryCache(None),
                                  compute_dtype=jnp.float32, use_flash=False)
-    assert backend.index_row is None and backend.index_cache_descriptors(6, 8) == () and backend.index_bytes_per_token() == 0
+    assert backend.cache.index_row is None and lane_pools(backend, 6, 8)[1] == () and backend.cache.index_bytes_per_token() == 0
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
-    assert batcher._n_index == 0 and not SPARSE_KEYS & set(batcher.stats) and "index_bytes_per_token" not in batcher.occupancy_info()
+    assert batcher.backend.cache.index_row is None and not SPARSE_KEYS & set(batcher.stats) and "index_bytes_per_token" not in batcher.occupancy_info()

@@ -30,7 +30,7 @@ from petals_tpu.ops.paged_attention import (
     stored_row,
 )
 from petals_tpu.ops.paged_flash_attention import composed_paged_attend, paged_flash_prefill_attend
-from tests.utils import make_tiny_llama
+from tests.utils import lane_pools, make_tiny_llama
 
 pytestmark = pytest.mark.kvquant
 
@@ -148,23 +148,23 @@ def test_wire_bytes_per_token_and_capacity_ratio():
 @pytest.mark.parametrize("kind", ("none",) + KINDS)
 def test_backend_descriptors_and_bytes(model_path, kind):
     backend, cfg = _tiny_backend(model_path, kind)
-    descs = backend.paged_cache_descriptors(6, 8, 0, 2)
+    descs = lane_pools(backend, 6, 8, end=2)[0]
     hkv, d = backend.num_kv_heads, backend.head_dim
     if kind == "none":
         assert len(descs) == 2
         assert descs[0].shape == (2, 6, 8, *stored_row(hkv, d))  # a row under 128 lanes is stored folded
-        assert backend.kv_bytes_per_token() == backend.cache_bytes_per_token()
+        assert backend.cache.kv_bytes_per_token() == backend.cache.cache_bytes_per_token()
         return
     assert len(descs) == 4
     d_store = d if kind == "int8" else d // 2
     assert descs[0].shape == descs[1].shape == (2, 6, 8, *stored_row(hkv, d_store))
     assert descs[2].shape == descs[3].shape == (2, 6, 8, hkv)
     assert jnp.dtype(descs[2].dtype) == jnp.float32
-    assert backend.kv_bytes_per_token() < backend.cache_bytes_per_token()
+    assert backend.cache.kv_bytes_per_token() < backend.cache.cache_bytes_per_token()
     # the descriptor bytes ARE the advertised wire bytes: the whole 4-array
     # pool divided by its token capacity equals kv_bytes_per_token
     total = sum(t.nbytes for t in descs)
-    assert total == backend.kv_bytes_per_token() * 6 * 8
+    assert total == backend.cache.kv_bytes_per_token() * 6 * 8
 
 
 def test_backend_rejects_bad_kv_quant(model_path):

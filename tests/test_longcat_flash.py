@@ -33,7 +33,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_LONGCAT_FLASH, make_tiny_longcat_flash, tiny_longcat_flash_tensors
+from tests.utils import counted, lane_pools, make_tiny_longcat_flash, tiny_longcat_flash_tensors, TINY_LONGCAT_FLASH
 
 HF = dict(TINY_LONGCAT_FLASH)
 BLOCKS, SUBLAYERS = HF["num_layers"], 2
@@ -344,12 +344,12 @@ def test_forward_and_backward_run_a_whole_sequence_through_both_attentions(tiny)
 def test_the_pools_hold_two_layers_of_pages_a_block_and_each_attention_writes_its_own(tiny):
     path, _ = tiny
     backend = whole_backend(path)
-    assert backend.latent_row == (16, 8) and backend.block_rows == 2 and backend.page_layers == 4 and backend.kv_layers == [0, 1]
-    c, pe = backend.paged_cache_descriptors(6, 16, 0, BLOCKS)
+    assert backend.cache.latent_row == (16, 8) and backend.cache.block_rows == 2 and backend.cache.page_layers == 4 and backend.cache.kv_layers == (0, 1)
+    c, pe = lane_pools(backend, 6, 16, end=BLOCKS)[0]
     assert c.shape == (4, 6, 16, 16) and pe.shape == (4, 6, 1, 128)  # [2 x blocks, ...]: a block's two layers one after the other
-    assert backend.paged_cache_descriptors(6, 16, 1, 2)[0].shape[0] == 2
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == 4 * ROW == 384 and backend.decode_walks(3, 2, 16) == ()
-    reads = backend.latent_reads(3, 2, 16, np.array([4, 20]), chunk=(0, 10))
+    assert lane_pools(backend, 6, 16, start=1, end=2)[0][0].shape[0] == 2
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == 4 * ROW == 384 and backend.cache.lane_pool(3, 2, 16).walks == ()
+    reads = counted(backend, 3, 2, 16, np.array([4, 20]), chunk=(0, 10))
     assert reads["latent_rows_held"] == 4 * (5 + 21) and reads["latent_rows_absorbed"] == 4 * 2 and reads["latent_rows_expanded"] == 4 * 10
     assert reads["latent_positions_held"] == 4 * 10 and reads["latent_score_pairs"] == 4 * (5 + 21 + 55)
     # one decode step of two lanes (the third idle) over zeroed pools: each of the four layers of pages holds exactly
@@ -381,7 +381,7 @@ def test_a_family_of_one_cache_row_a_block_keeps_its_pools_and_more_rows_without
     make = lambda fam: TransformerBackend(fam, cfg, stacked, first_block=0, n_blocks=2, memory_cache=MemoryCache(None),
                                           compute_dtype=jnp.float32, use_flash=False)
     backend = make(family)
-    assert backend.block_rows == 1 and backend.page_layers == 2 and backend.paged_cache_descriptors(6, 8, 0, 2)[0].shape[0] == 2
+    assert backend.cache.block_rows == 1 and backend.cache.page_layers == 2 and lane_pools(backend, 6, 8, end=2)[0][0].shape[0] == 2
     with pytest.raises(NotImplementedError, match="falcon: more than one cache row a position a block .* latent rows"):
         make(dataclasses.replace(family, block_sublayers=lambda cfg, kind: 2))
 
@@ -413,15 +413,15 @@ def test_the_published_span_is_62_percent_of_a_chip_and_a_position_caches_2304_b
     assert matrices == 1_242_824_704 and 0.62 < 4 * matrices * 2 / 16e9 < 0.63
     assert cfg.num_hidden_layers == 4 and cfg.router_width == 768 and (cfg.num_experts, cfg.num_experts_exist, cfg.first_expert) == (16, 512, 0)
     assert (cfg.q_scale, round(cfg.kv_scale, 3)) == (2.0, 3.464)
-    assert backend.latent_row == (512, 64) and backend.page_layers == 8 and backend.kv_bytes_per_token() == 4 * 2 * 1152 == 9216
-    c, pe = backend.paged_cache_descriptors(8 * 40, 64, 0, 4)
+    assert backend.cache.latent_row == (512, 64) and backend.cache.page_layers == 8 and backend.cache.kv_bytes_per_token() == 4 * 2 * 1152 == 9216
+    c, pe = lane_pools(backend, 8 * 40, 64, end=4)[0]
     assert c.shape == (8, 320, 64, 512) and pe.shape == (8, 320, 32, 128)
     pool = sum(int(np.prod(d.shape)) * 2 for d in (c, pe))
     assert pool == args["batch_lanes"] * 9216 * args["batch_max_length"] == 188_743_680 and pool <= 0.15 * 16 * 2**30
     dims = backend.moe_dims
     assert (dims.experts, dims.top_k, dims.routed, dims.identities) == (16, 12, 768, 256)
     assert backend.moe_grouped(1) == "hit" and backend.moe_grouped(512, chunk=True) == "dense"
-    reads = backend.latent_reads(8, 40, 64, np.full(8, 1799))
+    reads = counted(backend, 8, 40, 64, np.full(8, 1799))
     assert reads["latent_rows_held"] == 8 * 8 * 1800 and reads["latent_rows_absorbed"] == 8 * 8
 
 
@@ -449,7 +449,7 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_and_a
         try:
             batcher = server.handler.batcher
             assert batcher.page_size == 16 and server.handler.prefix_cache is None and LATENT_KEYS <= set(batcher.stats)
-            assert batcher._page_nbytes() == 16 * 4 * ROW
+            assert batcher._pool.page_bytes == 16 * 4 * ROW
             assert {"moe_chunk_rows_computed", "moe_chunk_rows_routed"} <= set(batcher.stats)  # a share of the experts that exist
             a_rows, b_rows, c_rows = rows(1, 130), rows(2, 140), rows(3, 60)
             b, c = await open_session(client, path, 160), await open_session(client, path, 160)
@@ -523,7 +523,7 @@ def test_remote_sequential_prefill_in_chunks_then_decode_matches_the_reference_s
     model = AutoDistributedModelForCausalLM.from_pretrained(path, initial_peers=harness.initial_peers)
     try:
         batchers = [server.handler.batcher for server in harness.servers]
-        assert all(b is not None and b._latent and b.backend.page_layers == 2 for b in batchers) and [b.page_size for b in batchers] == [8, 16]
+        assert all(b is not None and b.backend.cache.latent_row is not None and b.backend.cache.page_layers == 2 for b in batchers) and [b.page_size for b in batchers] == [8, 16]
         before = [dict(b.stats) for b in batchers]
         ids = np.random.RandomState(3).randint(0, 128, (1, 85)).astype(np.int64)
         hidden = np.asarray(model.embed(ids))
@@ -618,7 +618,7 @@ def test_what_ships_or_cuts_a_cache_is_refused_over_the_wire_and_the_prefix_cach
     async def main():
         server, client = await start_server(path, batch_lanes=2, batch_max_length=64, page_size=8)  # prefix_cache_bytes: the default
         try:
-            assert server.handler.prefix_cache is None and server.handler.batcher._latent
+            assert server.handler.prefix_cache is None and server.handler.batcher.backend.cache.latent_row is not None
             data = rows(21, 40)
             stream = await open_session(client, path, 64)
             await step(stream, data[:, :30])

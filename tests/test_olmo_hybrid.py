@@ -29,7 +29,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_OLMO_HYBRID, make_tiny_falcon, make_tiny_olmo_hybrid, tiny_olmo_hybrid_tensors
+from tests.utils import lane_pools, make_tiny_falcon, make_tiny_olmo_hybrid, tiny_olmo_hybrid_tensors, TINY_OLMO_HYBRID
 
 HF = dict(TINY_OLMO_HYBRID)
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -160,19 +160,19 @@ def test_forward_and_backward_run_the_chunked_form_from_a_zero_state(tiny):
 def test_the_page_pool_is_as_deep_as_the_full_layers_and_the_state_pool_as_the_linear_ones(tiny):
     path, _ = tiny
     backend = whole_backend(path)
-    assert backend.kv_layers == [3, 7] and backend.state_layers == [0, 1, 2, 4, 5, 6] and backend._slots == [0, 1, 2, 0, 3, 4, 5, 1]
-    k, v = backend.paged_cache_descriptors(12, 16, 0, 8)
+    assert backend.cache.kv_layers == (3, 7) and backend.cache.state_layers == (0, 1, 2, 4, 5, 6) and backend.cache.slots == (0, 1, 2, 0, 3, 4, 5, 1)
+    k, v = lane_pools(backend, 12, 16, end=8)[0]
     # the cache keeps a tile's 8 kv heads for the model's 4, the spare ones zeros (cfg.cache_kv_heads: the pool's layout on the device)
     assert backend.cfg.num_key_value_heads == 4 and backend.num_kv_heads == backend.cfg.cache_kv_heads == 8
     assert k.shape == v.shape == (2, 12, 16, 8 * 16)  # rows of 8 kv heads of 16, under 128 lanes: stored folded
-    matrix, tail = backend.state_cache_descriptors(3)
+    matrix, tail = lane_pools(backend, 1, 1, 3)[1]
     assert matrix.shape == (6, 3, 4, 8, 16) and jnp.dtype(matrix.dtype) == jnp.float32  # float32 whatever the cache's dtype
     assert tail.shape == (6, 3, 3, 4 * (8 + 8 + 16))
-    assert backend.state_bytes_per_lane() == 6 * (4 * 8 * 16 + 3 * 128) * 4
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == 2 * 2 * 8 * 16 * 4  # two layers of pages, not eight
+    assert backend.cache.state_bytes_per_lane() == 6 * (4 * 8 * 16 + 3 * 128) * 4
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == 2 * 2 * 8 * 16 * 4  # two layers of pages, not eight
     linear_only = whole_backend(path, 0, 3)  # a span with no full layer: no pages at all
-    assert linear_only.kv_layers == [] and linear_only.paged_cache_descriptors(12, 16, 0, 3)[0].shape[0] == 0
-    assert linear_only.kv_bytes_per_token() == 0 and len(linear_only.runs) == 1
+    assert linear_only.cache.kv_layers == () and lane_pools(linear_only, 12, 16, end=3)[0][0].shape[0] == 0
+    assert linear_only.cache.kv_bytes_per_token() == 0 and len(linear_only.runs) == 1
 
 
 def test_the_published_span_s_pools_and_the_lanes_the_default_budget_affords():
@@ -196,14 +196,14 @@ def test_the_published_span_s_pools_and_the_lanes_the_default_budget_affords():
     runs = tuple({name: S((length, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind, jnp.bfloat16).items()}
                  for kind, _, length in span_runs(family.span_kinds(cfg, 0, 16)))
     backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=16, memory_cache=None)
-    assert len(backend.kv_layers) == 4 and len(backend.state_layers) == 12
+    assert len(backend.cache.kv_layers) == 4 and len(backend.cache.state_layers) == 12
     assert cfg.num_key_value_heads == 30 and cfg.cache_kv_heads == 32
-    assert backend.paged_cache_descriptors(320, 64, 0, 16)[0].shape == (4, 320, 64, 32, 128)
-    matrix, tail = backend.state_cache_descriptors(8)
+    assert lane_pools(backend, 320, 64, end=16)[0][0].shape == (4, 320, 64, 32, 128)
+    matrix, tail = lane_pools(backend, 1, 1, 8)[1]
     assert (matrix.shape, tail.shape) == ((12, 8, 30, 96, 192), (12, 8, 3, 11520)) and jnp.dtype(tail.dtype) == jnp.bfloat16
-    assert backend.state_bytes_per_lane() == 12 * (2_211_840 + 69_120) == 27_371_520
-    lane = backend.cache_bytes_per_token() * 2560 + backend.state_bytes_per_lane()
-    assert backend.cache_bytes_per_token() == 4 * 16384 and lane == 195_143_680
+    assert backend.cache.state_bytes_per_lane() == 12 * (2_211_840 + 69_120) == 27_371_520
+    lane = backend.cache.cache_bytes_per_token() * 2560 + backend.cache.state_bytes_per_lane()
+    assert backend.cache.cache_bytes_per_token() == 4 * 16384 and lane == 195_143_680
     budget = int(15.75 * 2**30 * 0.15)  # Server's default cache budget on a v5e
     assert budget // 2 // lane == 6 and budget // 2 // (16 * 16384 * 2560) == 1
 
@@ -215,8 +215,8 @@ def test_lane_auto_sizing_counts_the_state(tiny):
 
     async def main():
         backend = whole_backend(path)
-        lane = backend.cache_bytes_per_token() * 32 + backend.state_bytes_per_lane()
-        assert (2 * 5 * lane + 100) // 2 // (backend.cache_bytes_per_token() * 32) == 6  # what the pages alone would afford
+        lane = backend.cache.cache_bytes_per_token() * 32 + backend.cache.state_bytes_per_lane()
+        assert (2 * 5 * lane + 100) // 2 // (backend.cache.cache_bytes_per_token() * 32) == 6  # what the pages alone would afford
         server = Server(path, compute_dtype=jnp.float32, use_flash=False, batch_max_length=32, page_size=16,
                         attn_cache_bytes=2 * 5 * lane + 100, prefix_cache_bytes=0)
         await server.start()
@@ -225,10 +225,10 @@ def test_lane_auto_sizing_counts_the_state(tiny):
             assert batcher.n_lanes == 5
             await batcher.ensure_open()
             info = batcher.occupancy_info()
-            assert info["state_bytes_per_lane"] == backend.state_bytes_per_lane() and info["state_bytes_held"] == 0
-            assert info["kv_bytes_per_token"] == backend.kv_bytes_per_token()
+            assert info["state_bytes_per_lane"] == backend.cache.state_bytes_per_lane() and info["state_bytes_held"] == 0
+            assert info["kv_bytes_per_token"] == backend.cache.kv_bytes_per_token()
             a = await batcher.acquire_lane(timeout=5)
-            assert batcher.occupancy_info()["state_bytes_held"] == backend.state_bytes_per_lane()
+            assert batcher.occupancy_info()["state_bytes_held"] == backend.cache.state_bytes_per_lane()
             batcher.release_lane(a)
         finally:
             await server.shutdown()
@@ -311,11 +311,11 @@ def test_the_one_step_rule_s_path_follows_from_the_pool_and_the_call_and_gives_i
     span's other calls keep the plain form: a chunk, a call whose state is not
     the pool's, a head the kernel refuses."""
     backend = whole_backend(tiny[0])
-    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in backend.state_cache_descriptors(3))
+    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in lane_pools(backend, 1, 1, 3)[1])
     pool = linear_attention.StatePool(leaves, 0)
-    assert leaves[0].shape == (6, 3, 4, 8, 16) and backend.state_step_path(3) == "plain"  # off the chip
+    assert leaves[0].shape == (6, 3, 4, 8, 16) and backend.cache.lane_pool(3, 4, 16).state_step == "plain"  # off the chip
     monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
-    assert backend.state_step_path(3) == "kernel" and linear_attention.step_kernel_unsupported(pool, 1) is None
+    assert backend.cache.lane_pool(3, 4, 16).state_step == "kernel" and linear_attention.step_kernel_unsupported(pool, 1) is None
     assert "16 rows a lane" in linear_attention.step_kernel_unsupported(pool, 16)
     a_lane = tuple(jnp.zeros((1, *leaf.shape[2:]), leaf.dtype) for leaf in leaves)  # what a chunk's lane is handed
     assert "no pooled state" in linear_attention.step_kernel_unsupported(a_lane, 1)
@@ -382,7 +382,7 @@ def test_swapped_out_and_in_a_lane_gives_the_reply_of_an_undisturbed_one(tiny):
                     assert await batcher._swap_out_lane(lane)
                     entry = batcher._scheduler.lanes[lane].swap
                     assert len(entry.state) == 2 and entry.state[0].shape == (6, 4, 8, 16)
-                    assert entry.nbytes == held * batcher._page_nbytes() + batcher.backend.state_bytes_per_lane()
+                    assert entry.nbytes == held * batcher._pool.page_bytes + batcher.backend.cache.state_bytes_per_lane()
                     # the slot is overwritten while the lane is away: what comes back is the host's copy
                     batcher._update(*batcher._buffers(), *(jnp.full_like(leaf, 3.0) for leaf in batcher._state()))
                 got += [await step(stream, data[:, p : p + 1]) for p in range(20, 30)]
@@ -412,7 +412,7 @@ def test_server_side_generation_s_pooled_step_carries_the_state_as_the_decode_st
     client_params = load_client_params(path, dtype=jnp.float32)
     rng = np.random.default_rng(11)
     made = lambda descs: tuple(jnp.asarray(rng.standard_normal(d.shape).astype(np.float32) * 0.1).astype(d.dtype) for d in descs)
-    pool = (*made(backend.paged_cache_descriptors(lanes * max_pages, ps, 0, 8)), *made(backend.state_cache_descriptors(lanes)))
+    pool = (*made(lane_pools(backend, lanes * max_pages, ps, end=8)[0]), *made(lane_pools(backend, 1, 1, lanes)[1]))
     assert len(pool) == 4 and pool[0].shape[0] == 2 and pool[2].shape[:2] == (6, lanes)
     tables = rng.permutation(lanes * max_pages).astype(np.int32).reshape(lanes, max_pages)
     positions = np.array([13, ps * max_pages, 6], np.int32)  # lane 1 idle
@@ -452,8 +452,8 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     LOGITS of every position against the reference's whole forward pass."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b.page_size == 8 and b._n_state == 2 for b in batchers)
-    assert [len(b.backend.state_layers) for b in batchers] == [4, 2] and [len(b.backend.kv_layers) for b in batchers] == [1, 1]
+    assert all(b is not None and b.page_size == 8 and len(b.backend.cache.lane_state) == 2 for b in batchers)
+    assert [len(b.backend.cache.state_layers) for b in batchers] == [4, 2] and [len(b.backend.cache.kv_layers) for b in batchers] == [1, 1]
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, 50)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))
@@ -463,7 +463,7 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     logits = np.asarray(model.lm_logits(np.concatenate(outs, axis=1)))[0]
     np.testing.assert_allclose(logits, reference_logits(tensors, ids[0]), atol=3e-4, rtol=0)
     for batcher, was in zip(batchers, before):
-        layers = len(batcher.backend.state_layers)
+        layers = len(batcher.backend.cache.state_layers)
         assert batcher.stats["mixed_steps"] - was["mixed_steps"] == 3
         assert batcher.stats["linattn_chunk_tokens"] - was["linattn_chunk_tokens"] == 37 * layers
         assert batcher.stats["linattn_recurrent_tokens"] - was["linattn_recurrent_tokens"] == 13 * layers
@@ -511,7 +511,7 @@ def test_cache_paths_that_do_not_carry_a_state_refuse_it_with_the_reason(tiny, w
     with pytest.raises(NotImplementedError, match="olmo_hybrid: .* recurrent state .*6 of its 8 blocks"):
         REFUSED_BY_THE_BACKEND[what](backend)
     full_only = whole_backend(tiny[0], 3, 1)  # a span of this family without a state layer is served like any other
-    assert not full_only.state_layers and full_only.lane_state == () and len(full_only.cache_descriptors(1, 32, 0, 1)) == 2
+    assert not full_only.cache.state_layers and full_only.cache.lane_state == () and len(full_only.cache_descriptors(1, 32, 0, 1)) == 2
 
 
 def test_options_the_family_cannot_take_yet_are_refused(tiny, tmp_path):
@@ -559,7 +559,7 @@ def test_what_cuts_a_cache_back_is_refused_over_the_wire_and_the_prefix_cache_is
     async def main():
         server, client = await start_server(path, batch_lanes=2, batch_max_length=32, page_size=8)  # prefix_cache_bytes: the default
         try:
-            assert server.handler.prefix_cache is None and server.handler.batcher._n_state == 2
+            assert server.handler.prefix_cache is None and len(server.handler.batcher.backend.cache.lane_state) == 2
             data = rows(21, 12)
             stream = await open_session(client, path, 32)
             await step(stream, data[:, :8])
@@ -613,11 +613,11 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *(load_block_params(path, i, dtype=jnp.float32) for i in range(2)))
     backend = TransformerBackend(family, cfg, stacked, first_block=0, n_blocks=2, memory_cache=MemoryCache(None),
                                  compute_dtype=jnp.float32, use_flash=False)
-    assert backend.state_layers == [] and backend.kv_layers == [0, 1] and backend.lane_state == () and backend.state_cache_descriptors(4) == ()
-    assert backend.state_bytes_per_lane() == 0
+    assert backend.cache.state_layers == () and backend.cache.kv_layers == (0, 1) and backend.cache.lane_state == () and lane_pools(backend, 1, 1, 4)[1] == ()
+    assert backend.cache.state_bytes_per_lane() == 0
     per_token = 2 * 2 * backend.num_kv_heads * backend.head_dim * 4
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == per_token
-    assert [d.shape for d in backend.paged_cache_descriptors(6, 8, 0, 2)] == [(2, 6, 8, backend.num_kv_heads * backend.head_dim)] * 2  # folded: the toy's head_dim is under 128 lanes, as Falcon's 64 is
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == per_token
+    assert [d.shape for d in lane_pools(backend, 6, 8, end=2)[0]] == [(2, 6, 8, backend.num_kv_heads * backend.head_dim)] * 2  # folded: the toy's head_dim is under 128 lanes, as Falcon's 64 is
     assert [d.shape for d in backend.cache_descriptors(3, 24, 0, 2)] == [(2, 3, 24, backend.num_kv_heads, backend.head_dim)] * 2
     batcher = DecodeBatcher(backend, backend.memory_cache, PriorityTaskQueue(), n_lanes=3, max_length=24, page_size=8)
     # since PR 36 every family on the paged pool counts the table slots its steps read (_count_window)
@@ -628,10 +628,10 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", "tables_sent",
                                                  "loop_busy_s", "loop_busy_sq", "loop_turns", "stream_bytes_in",
                                                  "stream_bytes_out", "rpc_intake_direct",
-                                                 "rpc_intake_queued"} and batcher._n_state == 0 and batcher._state() == ()
+                                                 "rpc_intake_queued"} and len(batcher.backend.cache.lane_state) == 0 and batcher._state() == ()
     assert not {"state_bytes_per_lane", "state_bytes_held"} & set(batcher.occupancy_info())
     # the step programs take the pair of pools and give the pair back, and carry what they carried
-    k, v = (jnp.zeros(d.shape, d.dtype) for d in backend.paged_cache_descriptors(6, 8, 0, 2))
+    k, v = (jnp.zeros(d.shape, d.dtype) for d in lane_pools(backend, 6, 8, end=2)[0])
     tables = np.arange(6, dtype=np.int32).reshape(3, 2)
     tables = np.concatenate([tables, np.full((3, 1), -1, np.int32)], axis=1)
     hidden, positions = np.zeros((3, 1, cfg.hidden_size), np.float32), np.array([0, 24, 3], np.int32)

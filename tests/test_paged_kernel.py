@@ -22,6 +22,7 @@ from petals_tpu.ops.paged_attention import (
     stored_row,
 )
 from petals_tpu.ops.paged_flash_attention import paged_flash_prefill_attend
+from tests.utils import lane_pools
 
 pytestmark = pytest.mark.kernel
 
@@ -386,18 +387,18 @@ def test_the_decode_walk_the_backend_counts_is_the_one_its_step_is_handed(name, 
 
     monkeypatch.setattr(pfa, "_on_tpu", lambda: True)
     monkeypatch.setattr(pfa, "decode_walk_path", recorded)
-    declared = backend.decode_walks(lanes, slots, page_size)
+    declared = backend.cache.lane_pool(lanes, slots, page_size).walks
     counted, asked[:] = set(asked), []
     assert len(declared) == len(counted)
 
-    descs = backend.paged_cache_descriptors(lanes * slots, page_size, 0, depth)
+    descs = lane_pools(backend, lanes * slots, page_size, end=depth)[0]
     pools = [aval(d.shape, d.dtype) for d in descs[:2]]
     # the lanes' rows and positions in backend.pack_lanes' form, then the tables
     avals = [backend.params, *pools, aval((lanes, backend.hidden_size + 1), jnp.int32), aval((lanes, slots), jnp.int32)]
-    if backend.state_layers:
-        avals.append(tuple(aval(d.shape, d.dtype) for d in backend.state_cache_descriptors(lanes)))
-    if backend.index_row is not None:
-        avals.append(tuple(aval(d.shape, d.dtype) for d in backend.index_cache_descriptors(lanes * slots, page_size)))
+    if backend.cache.state_layers:
+        avals.append(tuple(aval(d.shape, d.dtype) for d in lane_pools(backend, 1, 1, lanes)[1]))
+    if backend.cache.index_row is not None:
+        avals.append(tuple(aval(d.shape, d.dtype) for d in lane_pools(backend, lanes * slots, page_size)[1]))
     jax.eval_shape(functools.partial(backend._paged_decode_fn.__wrapped__, with_fp=False), *avals)
     assert set(asked) == counted, (sorted(map(str, asked)), sorted(map(str, counted)))
     # a latent row's walk is its own (ops/latent_attention.py); a row that chooses its positions fetches them one by one

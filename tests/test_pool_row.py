@@ -22,7 +22,7 @@ from petals_tpu.server.backend import TransformerBackend
 from petals_tpu.server.from_pretrained import get_block_config, load_block_params
 from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server
-from tests.utils import make_tiny_falcon
+from tests.utils import lane_pools, make_tiny_falcon
 
 LANES, MAX_PAGES, PAGE_SIZE, N_PAGES = 3, 2, 8, 6
 SENTINEL = MAX_PAGES * PAGE_SIZE
@@ -49,7 +49,7 @@ def _d_store(kind, d):
 def _pools(backend, folded):
     """The pair of zeroed pools, as the rule stores them or with the rows of
     ``[hkv, d_store]`` every pool had before it."""
-    descs = backend.paged_cache_descriptors(N_PAGES, PAGE_SIZE, 0, 2)
+    descs = lane_pools(backend, N_PAGES, PAGE_SIZE, end=2)[0]
     leaves = [jnp.zeros(d.shape if folded or i >= 2 else (*d.shape[:3], backend.num_kv_heads, d.shape[3] // backend.num_kv_heads), d.dtype)
               for i, d in enumerate(descs)]
     return (leaves[0], leaves[1]) if len(leaves) == 2 else (PagedPool(leaves[0], leaves[2]), PagedPool(leaves[1], leaves[3]))
@@ -94,14 +94,14 @@ def test_a_pool_of_head_dim_128_is_declared_as_it_was(tmp_path, kind):
     params = {name: jax.ShapeDtypeStruct((2, *leaf.shape), leaf.dtype) for name, leaf in family.block_param_shapes(cfg, jnp.bfloat16).items()}
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=2, memory_cache=None, kv_quant_type=kind)
     assert (backend.num_kv_heads, backend.head_dim) == (4, 128)  # four heads: under four a row folds whatever its width (the rule's test above)
-    shapes = [(d.shape, jnp.dtype(d.dtype)) for d in backend.paged_cache_descriptors(6, 8, 0, 2)]
+    shapes = [(d.shape, jnp.dtype(d.dtype)) for d in lane_pools(backend, 6, 8, end=2)[0]]
     if kind == "none":
-        assert backend.pool_row == (4, 128) and shapes == [((2, 6, 8, 4, 128), jnp.dtype(backend.cache_dtype))] * 2
+        assert backend.cache.pool_row == (4, 128) and shapes == [((2, 6, 8, 4, 128), jnp.dtype(backend.cache_dtype))] * 2
     elif kind == "int8":
-        assert backend.pool_row == (4, 128)
+        assert backend.cache.pool_row == (4, 128)
         assert shapes == [((2, 6, 8, 4, 128), jnp.dtype(jnp.int8))] * 2 + [((2, 6, 8, 4), jnp.dtype(jnp.float32))] * 2
     else:
-        assert backend.pool_row == (256,)
+        assert backend.cache.pool_row == (256,)
         assert shapes == [((2, 6, 8, 256), jnp.dtype(jnp.uint8))] * 2 + [((2, 6, 8, 4), jnp.dtype(jnp.float32))] * 2
 
 
@@ -190,8 +190,8 @@ def test_a_folded_pool_runs_the_program_of_rows_of_hkv_d(model_path, kind):
     themselves: ``test_rows_land_and_come_back_as_the_same_bytes_in_either_form``."""
     backend, cfg = _backend(model_path, kind)
     hkv, d_store = backend.num_kv_heads, _d_store(kind, backend.head_dim)
-    assert backend.pool_row == (hkv * d_store,)
-    descs = backend.paged_cache_descriptors(N_PAGES, PAGE_SIZE, 0, 2)
+    assert backend.cache.pool_row == (hkv * d_store,)
+    descs = lane_pools(backend, N_PAGES, PAGE_SIZE, end=2)[0]
     assert descs[0].shape == descs[1].shape == (2, N_PAGES, PAGE_SIZE, hkv * d_store)
     if kind != "none":
         assert descs[2].shape == descs[3].shape == (2, N_PAGES, PAGE_SIZE, hkv)
@@ -249,7 +249,7 @@ def test_what_leaves_the_device_keeps_rows_of_hkv_d(model_path, kind):
             await batcher.ensure_open()
             n_blocks, hkv, d = backend.n_blocks, backend.num_kv_heads, backend.head_dim
             d_store = _d_store(kind, d)
-            assert batcher.occupancy_info()["pool_row"] == [hkv * d_store] == list(backend.pool_row)
+            assert batcher.occupancy_info()["pool_row"] == [hkv * d_store] == list(backend.cache.pool_row)
             for pool in batcher._buffers():
                 leaves = jax.tree_util.tree_leaves(pool)
                 assert leaves[0].shape == (n_blocks, 4, 8, hkv * d_store)

@@ -32,7 +32,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_QWEN3_NEXT, make_tiny_qwen3_next, qwen3_next_layer_types, tiny_qwen3_next_tensors
+from tests.utils import lane_pools, make_tiny_qwen3_next, qwen3_next_layer_types, tiny_qwen3_next_tensors, TINY_QWEN3_NEXT
 
 HF = dict(TINY_QWEN3_NEXT)
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -274,16 +274,16 @@ def test_forward_and_backward_run_the_chunked_form_from_a_zero_state(tiny):
 def test_the_page_pool_is_as_deep_as_the_full_layers_and_the_state_pool_as_the_linear_ones(tiny):
     path, _ = tiny
     backend = whole_backend(path)
-    assert backend.kv_layers == [3, 7] and backend.state_layers == [0, 1, 2, 4, 5, 6] and backend._slots == [0, 1, 2, 0, 3, 4, 5, 1]
+    assert backend.cache.kv_layers == (3, 7) and backend.cache.state_layers == (0, 1, 2, 4, 5, 6) and backend.cache.slots == (0, 1, 2, 0, 3, 4, 5, 1)
     assert [kind for kind, _, _ in backend.runs] == [LINEAR, FULL, LINEAR, FULL]
     assert backend.moe_dims == (16, 4, 64, 32, 16, 0, 0) and backend.moe_grouped(1) == "hit"  # every run's experts ride the stack
-    k, v = backend.paged_cache_descriptors(12, 16, 0, 8)
+    k, v = lane_pools(backend, 12, 16, end=8)[0]
     assert backend.num_kv_heads == 2 and k.shape == v.shape == (2, 12, 16, 2 * 16)  # rows of 2 kv heads of 16, under 128 lanes: folded
-    matrix, tail = backend.state_cache_descriptors(3)
+    matrix, tail = lane_pools(backend, 1, 1, 3)[1]
     assert matrix.shape == (6, 3, 4, 8, 16) and jnp.dtype(matrix.dtype) == jnp.float32  # a state a VALUE head, float32 whatever the cache's dtype
     assert tail.shape == (6, 3, 3, 2 * 2 * 8 + 4 * 16)
-    assert backend.state_bytes_per_lane() == 6 * (4 * 8 * 16 + 3 * 96) * 4
-    assert backend.cache_bytes_per_token() == backend.kv_bytes_per_token() == 2 * 2 * 2 * 16 * 4  # two layers of pages, not eight
+    assert backend.cache.state_bytes_per_lane() == 6 * (4 * 8 * 16 + 3 * 96) * 4
+    assert backend.cache.cache_bytes_per_token() == backend.cache.kv_bytes_per_token() == 2 * 2 * 2 * 16 * 4  # two layers of pages, not eight
 
 
 def test_the_published_span_s_pools_and_what_a_lane_costs():
@@ -308,11 +308,11 @@ def test_the_published_span_s_pools_and_what_a_lane_costs():
     n_params = sum(int(np.prod(leaf.shape)) for run in runs for leaf in run.values())
     assert 3.51e9 < n_params < 3.52e9
     backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=8, memory_cache=None)
-    assert len(backend.kv_layers) == 2 and len(backend.state_layers) == 6
-    assert backend.paged_cache_descriptors(320, 64, 0, 8)[0].shape == (2, 320, 64, 512)  # two kv heads: a folded row (stored_row)
-    matrix, tail = backend.state_cache_descriptors(8)
+    assert len(backend.cache.kv_layers) == 2 and len(backend.cache.state_layers) == 6
+    assert lane_pools(backend, 320, 64, end=8)[0][0].shape == (2, 320, 64, 512)  # two kv heads: a folded row (stored_row)
+    matrix, tail = lane_pools(backend, 1, 1, 8)[1]
     assert (matrix.shape, tail.shape) == ((6, 8, 32, 128, 128), (6, 8, 3, 8192)) and jnp.dtype(tail.dtype) == jnp.bfloat16
-    assert backend.kv_bytes_per_token() == 2 * 2048 and backend.state_bytes_per_lane() == 6 * (2_097_152 + 49_152)
+    assert backend.cache.kv_bytes_per_token() == 2 * 2048 and backend.cache.state_bytes_per_lane() == 6 * (2_097_152 + 49_152)
     assert backend.moe_grouped(1) == "hit" and backend.moe_grouped(512, chunk=True) == "dense"
 
 
@@ -413,7 +413,7 @@ def test_the_full_layers_decode_rows_walk_their_pages_in_the_kernel_and_the_coun
         server, client = await start_server(path, batch_lanes=3, batch_max_length=64, page_size=16)
         try:
             batcher = server.handler.batcher
-            assert server.backend.pool_row == (2 * 128,) and batcher.occupancy_info()["decode_walk"] == ["kernel"]
+            assert server.backend.cache.pool_row == (2 * 128,) and batcher.occupancy_info()["decode_walk"] == ["kernel"]
             b_rows, c_rows = rows(2, 45), rows(3, 45)
             b, c = await open_session(client, path, 64), await open_session(client, path, 64)
             got_b, got_c = [await step(b, b_rows[:, :30])], [await step(c, c_rows[:, :3])]  # 2 pages and 1
@@ -440,11 +440,11 @@ def test_the_one_step_rule_s_path_follows_from_the_pool_and_the_call_and_gives_i
     span's other calls keep the plain form: a chunk, a call whose state is not
     the pool's, a head the kernel refuses."""
     backend = whole_backend(tiny[0])
-    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in backend.state_cache_descriptors(3))
+    leaves = tuple(jax.ShapeDtypeStruct(d.shape, d.dtype) for d in lane_pools(backend, 1, 1, 3)[1])
     pool = linear_attention.StatePool(leaves, 0)
-    assert leaves[0].shape == (6, 3, 4, 8, 16) and backend.state_step_path(3) == "plain"  # off the chip
+    assert leaves[0].shape == (6, 3, 4, 8, 16) and backend.cache.lane_pool(3, 4, 16).state_step == "plain"  # off the chip
     monkeypatch.setattr(linear_attention, "_on_tpu", lambda: True)
-    assert backend.state_step_path(3) == "kernel" and linear_attention.step_kernel_unsupported(pool, 1) is None
+    assert backend.cache.lane_pool(3, 4, 16).state_step == "kernel" and linear_attention.step_kernel_unsupported(pool, 1) is None
     assert "16 rows a lane" in linear_attention.step_kernel_unsupported(pool, 16)
     a_lane = tuple(jnp.zeros((1, *leaf.shape[2:]), leaf.dtype) for leaf in leaves)  # what a chunk's lane is handed
     assert "no pooled state" in linear_attention.step_kernel_unsupported(a_lane, 1)
@@ -517,8 +517,8 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     against the reference's whole forward pass."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b.page_size == 8 and b._n_state == 2 for b in batchers)
-    assert [len(b.backend.state_layers) for b in batchers] == [4, 2] and [len(b.backend.kv_layers) for b in batchers] == [1, 1]
+    assert all(b is not None and b.page_size == 8 and len(b.backend.cache.lane_state) == 2 for b in batchers)
+    assert [len(b.backend.cache.state_layers) for b in batchers] == [4, 2] and [len(b.backend.cache.kv_layers) for b in batchers] == [1, 1]
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, 50)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))
@@ -528,7 +528,7 @@ def test_remote_sequential_session_prefill_in_chunks_then_decode_matches_the_ref
     logits = np.asarray(model.lm_logits(np.concatenate(outs, axis=1)))[0]
     np.testing.assert_allclose(logits, reference_logits(tensors, ids[0]), atol=3e-4, rtol=0)
     for batcher, was in zip(batchers, before):
-        layers = len(batcher.backend.state_layers)
+        layers = len(batcher.backend.cache.state_layers)
         assert batcher.stats["mixed_steps"] - was["mixed_steps"] == 3
         assert batcher.stats["linattn_chunk_tokens"] - was["linattn_chunk_tokens"] == 37 * layers
         assert batcher.stats["linattn_recurrent_tokens"] - was["linattn_recurrent_tokens"] == 13 * layers
@@ -584,7 +584,7 @@ def test_cache_paths_that_do_not_carry_a_state_refuse_it_with_the_reason(tiny, w
     with pytest.raises(NotImplementedError, match="qwen3_next: .* recurrent state .*6 of its 8 blocks"):
         REFUSED_BY_THE_BACKEND[what](backend)
     full_only = whole_backend(tiny[0], 3, 1)  # a span of this family without a state layer is served like any other
-    assert not full_only.state_layers and full_only.lane_state == () and len(full_only.cache_descriptors(1, 32, 0, 1)) == 2
+    assert not full_only.cache.state_layers and full_only.cache.lane_state == () and len(full_only.cache_descriptors(1, 32, 0, 1)) == 2
 
 
 def test_options_the_family_cannot_take_yet_are_refused(tiny, tmp_path):
@@ -621,7 +621,7 @@ def test_what_cuts_a_cache_back_is_refused_over_the_wire_and_the_prefix_cache_is
     async def main():
         server, client = await start_server(path, batch_lanes=2, batch_max_length=32, page_size=8)  # prefix_cache_bytes: the default
         try:
-            assert server.handler.prefix_cache is None and server.handler.batcher._n_state == 2
+            assert server.handler.prefix_cache is None and len(server.handler.batcher.backend.cache.lane_state) == 2
             data = rows(21, 12)
             stream = await open_session(client, path, 32)
             await step(stream, data[:, :8])

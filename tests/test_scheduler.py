@@ -243,7 +243,7 @@ def test_batcher_swap_roundtrip_relocates_pages(model_path):
             assert sched.lanes[a].suspended and sched.suspended_count == 1
             assert batcher._pages.n_free == free_before + 2
             assert np.all(batcher._tables[a] == -1)
-            assert batcher.swap_pool.bytes_in_use == 2 * batcher._page_nbytes()
+            assert batcher.swap_pool.bytes_in_use == 2 * batcher._pool.page_bytes
             assert sched.stats["preemptions"] == 1 and sched.stats["swap_outs"] == 1
             # an idle-but-suspended lane is not a victim candidate anymore
             assert not batcher._lane_idle(a)
@@ -265,7 +265,7 @@ def test_batcher_swap_roundtrip_relocates_pages(model_path):
             assert not sched.lanes[a].suspended
             assert sched.lanes[b].suspended, "swap-in had to evict b for room"
             assert sched.stats["swap_ins"] == 1
-            assert batcher.swap_pool.bytes_in_use == 3 * batcher._page_nbytes()
+            assert batcher.swap_pool.bytes_in_use == 3 * batcher._pool.page_bytes
             np.testing.assert_array_equal(a_after[0], a_before[0])
             np.testing.assert_array_equal(a_after[1], a_before[1])
 

@@ -19,12 +19,14 @@ import numpy as np
 import pytest
 
 from petals_tpu.models.registry import span_runs
+from petals_tpu.ops import paged_flash_attention as pfa
 from petals_tpu.server.backend import TransformerBackend, bucket_length
 from petals_tpu.server.batching import DecodeBatcher
 from petals_tpu.server.from_pretrained import get_block_config, load_block_params
 from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.utils import (
+    counted,
     make_tiny_deepseek_v3,
     make_tiny_exaone_moe,
     make_tiny_keye_vl2,
@@ -61,11 +63,7 @@ def span_backend(sort: str, tmp_path_factory) -> TransformerBackend:
 
 def make_pools(backend, n_pages: int, seed: int) -> tuple:
     """(k, v, *state or index): pools of small noise, so that a row read off the wrong page shows."""
-    descs = (
-        *backend.paged_cache_descriptors(n_pages, PAGE, 0, backend.n_blocks),
-        *backend.state_cache_descriptors(LANES),
-        *backend.index_cache_descriptors(n_pages, PAGE),
-    )
+    descs = backend.cache.pool_descriptors(n_pages, PAGE, LANES, 0, backend.n_blocks)
     rng = np.random.default_rng(seed)
     return tuple(jnp.asarray(rng.standard_normal(d.shape).astype(np.float32) * 0.05, d.dtype) for d in descs)
 
@@ -318,71 +316,73 @@ def old_counts(batcher, stats: dict, tables: np.ndarray, positions: np.ndarray, 
     """``_count_window``, ``_count_state``, ``_count_sparse`` and
     ``_count_latent`` as they stood before PR 51, every one indexing and
     reducing the step's copy of the tables, into ``stats``."""
-    self, backend = batcher, batcher.backend
+    self, backend, pool = batcher, batcher.backend, batcher._pool
     if "attn_pages_gathered" in stats:
-        layers = len(backend.kv_layers)
+        layers = backend.cache.page_layers
         last = positions[positions < self.max_length]
         by_kernel = 0
-        if self._selects or self._latent or not last.size:
+        if pool.selects or backend.cache.latent_row is not None or not last.size:
             read = 0
         else:
-            read, by_kernel = backend.pages_walked(self._walks, last, self.page_size, self.n_lanes)
+            read, by_kernel = pfa.pages_walked(pool.walks, last, self.page_size, self.n_lanes)
         stats["attn_pages_gathered"] += read
         stats["attn_pages_kernel"] += by_kernel
         stats["attn_pages_tabled"] += self.n_lanes * self.max_pages * layers
         if chunk is not None:
             lane, first, take = chunk
-            if self._latent:
+            if backend.cache.latent_row is not None:
                 from petals_tpu.ops.latent_attention import chunk_reads
 
                 stats["attn_pages_gathered"] += layers * chunk_reads(self.max_pages, self.page_size, first, take) // self.page_size
-            elif not self._selects:
-                stats["attn_pages_gathered"] += backend.pages_gathered(bucket_length(take), self.max_pages, self.page_size)
+            elif not pool.selects:
+                stats["attn_pages_gathered"] += pool._pages_gathered(bucket_length(take))
             stats["attn_pages_tabled"] += self.max_pages * layers
-        if self._windows:
+        if pool.windows:
             lanes, last = np.flatnonzero(positions < self.max_length), last.astype(np.int64)
             if chunk is not None:
                 lanes, last = np.append(lanes, lane), np.append(last, first + take - 1)
             held = (tables[lanes] >= 0).sum(axis=1)
             reach = 0
-            for window in self._windows:
+            for window in pool.windows:
                 pages = last // self.page_size - np.maximum(last - window + 1, 0) // self.page_size + 1
                 reach += int(np.minimum(pages, held).sum())
-            stats["window_pages_held"] += int(held.sum()) * len(self._windows)
+            stats["window_pages_held"] += int(held.sum()) * len(pool.windows)
             stats["window_pages_in_reach"] += reach
-    if self._n_state:
-        layers = len(backend.state_layers)
+    if backend.cache.lane_state:
+        layers = len(backend.cache.state_layers)
         lanes = np.flatnonzero(positions < self.max_length)
         stats["linattn_recurrent_tokens"] += int(lanes.size) * layers
-        if self._state_step == "kernel":
+        if pool.state_step == "kernel":
             stats["linattn_kernel_tokens"] += int(lanes.size) * layers
         if chunk is not None:
             lanes = np.append(lanes, chunk[0])
             stats["linattn_chunk_tokens"] += int(chunk[2]) * layers
-        stats["state_bytes_held"] += int(lanes.size) * self._state_nbytes()
-        stats["kv_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
-    if self._n_index:
+        stats["state_bytes_held"] += int(lanes.size) * pool.state_bytes
+        stats["kv_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._pool.page_bytes
+    if backend.cache.index_row is not None:
         lanes = np.flatnonzero(positions < self.max_length)
-        reads = backend.sparse_reads(self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:])
+        reads = counted(backend, self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:])
         for key, n in reads.items():
-            stats[key] += n
-        if self._selects:
+            if key.startswith("sparse_"):  # the selection's own, of all that ``count_step`` adds
+                stats[key] += n
+        if pool.selects:
             stats["attn_pages_gathered"] += -(-reads["sparse_kv_rows_read"] // self.page_size)
         if chunk is not None:
             lanes = np.append(lanes, chunk[0])
         pages = int((tables[lanes] >= 0).sum())
-        index = pages * self.page_size * int(backend.index_bytes_per_token())
+        index = pages * self.page_size * int(backend.cache.index_bytes_per_token())
         stats["index_bytes_held"] += index
-        stats["kv_bytes_held"] += pages * self._page_nbytes() - index
-    if self._latent:
+        stats["kv_bytes_held"] += pages * self._pool.page_bytes - index
+    if backend.cache.latent_row is not None:
         lanes = np.flatnonzero(positions < self.max_length)
-        reads = backend.latent_reads(self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:])
+        reads = counted(backend, self.n_lanes, self.max_pages, self.page_size, positions[lanes], chunk=None if chunk is None else chunk[1:])
         for key, n in reads.items():
-            stats[key] += n
+            if key.startswith("latent_"):  # the latent attention's own (no page is held there: its bytes count 0)
+                stats[key] += n
         stats["attn_pages_gathered"] += reads["latent_rows_read"] // self.page_size
         if chunk is not None:
             lanes = np.append(lanes, chunk[0])
-        stats["latent_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._page_nbytes()
+        stats["latent_bytes_held"] += int((tables[lanes] >= 0).sum()) * self._pool.page_bytes
 
 
 COUNTED = {
@@ -443,7 +443,7 @@ def test_counters_equal_the_per_step_reductions_over_the_tables(tmp_path_factory
                 live = np.flatnonzero((length > 0) & (rng.random(LANES) < 0.8))  # a lane may sit a step out
                 positions[live] = length[live]
                 old_counts(batcher, want, batcher._tables.copy(), positions, chunk)
-                batcher._count_paged(positions, chunk=chunk)
+                batcher._pool.count_step(batcher.stats, positions, batcher._lane_held, chunk=chunk)
                 length[live] += 1
                 if chunk is not None:
                     hold(chunk[0], 0)

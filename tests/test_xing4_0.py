@@ -313,7 +313,7 @@ def test_forward_and_backward_run_a_whole_sequence_over_the_dense_sparse_boundar
     path, tensors = tiny
     backend = whole_backend(path)
     assert backend.hidden_size == WIDTH and backend.stream_mixes == 2 and [kind for kind, _, _ in backend.runs] == ["dense", "sparse"]
-    assert backend.latent_row == (32, 8) and backend.pack_lanes(np.zeros((3, 1, WIDTH), np.float32), np.arange(3)).shape == (3, WIDTH + 1)
+    assert backend.cache.latent_row == (32, 8) and backend.pack_lanes(np.zeros((3, 1, WIDTH), np.float32), np.arange(3)).shape == (3, WIDTH + 1)
     x, grad_out = rows(4, 60), rows(5, 60)
 
     def traced(h):
@@ -351,7 +351,7 @@ def test_the_published_span_is_4_726_259_712_parameters_and_a_position_caches_11
     params = tuple({name: jax.ShapeDtypeStruct((length, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind).items()}
                    for kind, length in (("dense", 2), ("sparse", 6)))
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=8, memory_cache=None)
-    assert backend.kv_bytes_per_token() == 8 * 1152 and backend.hidden_size == 14336
+    assert backend.cache.kv_bytes_per_token() == 8 * 1152 and backend.hidden_size == 14336
     assert cfg.softmax_mscale == pytest.approx(2.0047, abs=1e-4) and dict(cfg.rope_scaling)["original_max_position_embeddings"] == 4096
 
 
@@ -373,7 +373,7 @@ def test_prompt_in_mixed_steps_beside_two_decoding_lanes_then_decode_matches_the
         server, client = await start_server(path, batch_lanes=4, batch_max_length=160, page_size=16, n_pages=30, prefill_token_budget=16)
         try:
             batcher = server.handler.batcher
-            assert batcher.page_size == 16 and batcher._latent and {"hc_rows", "stream_bytes_in", "stream_bytes_out"} <= set(batcher.stats)
+            assert batcher.page_size == 16 and batcher.backend.cache.latent_row is not None and {"hc_rows", "stream_bytes_in", "stream_bytes_out"} <= set(batcher.stats)
             assert batcher._lanes_in.shape == (4, WIDTH + 1) if batcher._lanes_in is not None else True
             a_rows, b_rows, c_rows = rows(1, 130), rows(2, 140), rows(3, 60)
             b, c = await open_session(client, path, 160), await open_session(client, path, 160)
@@ -456,7 +456,7 @@ def test_client_embed_two_servers_in_a_chain_client_norm_is_the_whole_model_s_re
     the LOGITS of every position against the whole model's reference."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b._latent and b.backend.hidden_size == WIDTH for b in batchers)
+    assert all(b is not None and b.backend.cache.latent_row is not None and b.backend.hidden_size == WIDTH for b in batchers)
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, 85)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))

@@ -1170,3 +1170,31 @@ def make_tiny_xing4_0(tmpdir: str, **overrides) -> str:
         json.dump(config, f)
     save_file(tiny_xing4_0_tensors(config), os.path.join(path, "model.safetensors"))
     return path
+
+
+# ---------------------------------------------------------------------------
+# what a lane holds for a span's blocks (server/span_cache.py), as the tests ask a backend for it
+
+
+def lane_pools(backend, n_pages: int, page_size: int, n_lanes: int = 1, *, start: int = 0, end=None) -> tuple:
+    """``(the page pools' descriptors, those of the pools beside them)`` of a paged lane pool over blocks [start, end)
+    of ``backend``'s span (default: to its end): ``SpanCache.pool_descriptors``, split where the step programs split it."""
+    cache = backend.cache
+    descs = cache.pool_descriptors(n_pages, page_size, n_lanes, start, backend.n_blocks if end is None else end)
+    n = len(descs) - cache.pools_beside_pages
+    return descs[:n], descs[n:]
+
+
+def counted(backend, n_lanes: int, max_pages: int, page_size: int, last, chunk=None, held=None) -> dict:
+    """What ``LanePool.count_step`` adds for one step of a pool of these shapes whose first lanes are live at the
+    positions ``last`` (the rest idle), with a mixed step's ``chunk`` (first position, tokens) on lane 0; the lanes hold
+    ``held`` pages each (default: none)."""
+    import numpy as np
+
+    pool = backend.cache.lane_pool(n_lanes, max_pages, page_size)
+    positions = np.full(n_lanes, pool.max_length, np.int32)
+    positions[: len(last)] = last
+    stats = pool.new_stats()
+    pool.count_step(stats, positions, np.zeros(n_lanes, np.int64) if held is None else np.asarray(held, np.int64),
+                    chunk=None if chunk is None else (0, *chunk))
+    return stats
