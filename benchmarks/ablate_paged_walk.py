@@ -41,6 +41,9 @@ SHAPES = {
     "olmo-hybrid-7b-one": (8, 40, 64, 32, 128, 1, (2300,) + (0,) * 7),  # one live lane, seven on the idle sentinel (a length of 0)
     "qwen3-next-80b": (8, 40, 64, 2, 256, 8, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k; a folded row of 512: pages of 64 KB
     "qwen3-next-80b-four": (8, 40, 64, 2, 256, 8, (1150, 0, 1500, 0, 1850, 0, 2300, 0)),  # its usual step: four of eight lanes live
+    # ctx16k, 16 lanes of 2k-15k: a full layer's table of 256 slots, and a windowed layer's cut to the 65 its window of 4,096 reaches
+    "smallthinker-21b": (16, 256, 64, 4, 128, 7, (2500, 3300, 4100, 4900, 5700, 6500, 7300, 8100, 8900, 9700, 10500, 11300, 12100, 12900, 13700, 14500)),
+    "smallthinker-21b-window": (16, 65, 64, 4, 128, 7, (2500, 3300) + (4160,) * 14),
     "jamba2-3b": (8, 40, 64, 1, 128, 20, (1150, 1300, 1500, 1700, 1850, 2000, 2150, 2300)),  # ctx2k; a folded row of 128: pages of 16 KB
 }
 LINKS = (2, 10)
@@ -123,11 +126,12 @@ def main(names, stages=("dense", "walk", "kernel")) -> None:
         live_mb = 2 * int(np.where(idle, 0, pos + 1).sum()) * hkv * d * 2 / 1e6
         want = np.asarray(jax.jit(dense)(*args), np.float32)[~idle]
         rows = [("dense", None, timed(dense, *args))] if wanted("dense") else []
-        widths = sorted({w for w in (1, 2, 4, 8, 16, max_pages) if w <= max_pages})
+        widths = sorted({w for w in (1, 2, 4, 8, 16, 32, 64, max_pages) if w <= max_pages})
         for block in widths:
             if not wanted(f"walk{block}"):
                 continue
             pfa.WALK_BLOCK_BYTES = (1 << (block - 1).bit_length()) * a_slot  # the whole row: the power of two over it
+            pfa.WALK_MAX_TRIPS = max_pages  # every width as asked, whatever the table's
             assert pfa.walk_block_pages(n_lanes, max_pages, page_size, hkv, d) == block
             got = np.asarray(jax.jit(lambda *a: walk(*a))(*args), np.float32)[~idle]  # a new program a width
             err = float(np.max(np.abs(got - want)))

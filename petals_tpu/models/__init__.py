@@ -19,5 +19,6 @@ import petals_tpu.models.qwen3_next  # noqa: F401
 import petals_tpu.models.jamba  # noqa: F401
 import petals_tpu.models.longcat_flash  # noqa: F401
 import petals_tpu.models.xing4_0  # noqa: F401
+import petals_tpu.models.smallthinker  # noqa: F401
 
 __all__ = ["get_family", "register_family"]

@@ -1,7 +1,9 @@
 """Mixture-of-experts feed-forward shared by the families that route
-(mixtral, olmoe, exaone_moe, keye_vl2, deepseek_v3, qwen3_next): a router
-over the published number of experts, the top k kept, SwiGLU experts stacked
-``w1``/``w3`` [E, h, m] and ``w2`` [E, m, h]. The routing rule is data (``Routing``): a softmax whose kept
+(mixtral, olmoe, exaone_moe, keye_vl2, deepseek_v3, qwen3_next, smallthinker): a router
+over the published number of experts, the top k kept, gated experts (SwiGLU, or
+ReGLU where the family says ``activation="relu"``) stacked
+``w1``/``w3`` [E, h, m] and ``w2`` [E, m, h]. Routing (``moe_route``) and application (``moe_experts``) are two calls, so
+that a block may route on one tensor and feed its experts another; ``moe_apply`` is both on one tensor. The routing rule is data (``Routing``): a softmax whose kept
 weights are renormalised or not, a sigmoid whose choice a bias moves and
 whose kept weights are renormalised and scaled, or a softmax whose choice a
 bias moves and whose kept weights are scaled as they are.
@@ -143,7 +145,10 @@ def route(params: dict, x: jnp.ndarray, routing: Routing):
     return top_idx, top_scores * routing.scale
 
 
-def _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share: bool = False) -> jnp.ndarray:
+ACTIVATIONS = {"silu": silu, "relu": jax.nn.relu}  # an expert's gate activation: SwiGLU | ReGLU, data of the family
+
+
+def _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share: bool = False, activation: str = "silu") -> jnp.ndarray:
     """Grouped-matmul dispatch: FLOPs proportional to N * top_k. ``top_idx``
     counts among the held experts; under a ``share`` an index outside them is
     an assignment to an absent expert: sorted behind every group, in none of
@@ -162,7 +167,7 @@ def _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share: bool = False) -> 
     group_sizes = jnp.bincount(flat_experts, length=E).astype(jnp.int32)
     g1 = jax.lax.ragged_dot(xg, w1, group_sizes)
     g3 = jax.lax.ragged_dot(xg, w3, group_sizes)
-    out = jax.lax.ragged_dot(silu(g1) * g3, w2, group_sizes)  # [N*k, h]
+    out = jax.lax.ragged_dot(ACTIVATIONS[activation](g1) * g3, w2, group_sizes)  # [N*k, h]
     wts = jnp.take(top_probs.reshape(n_assign), order).astype(jnp.float32)
     contribution = out.astype(jnp.float32) * wts[:, None]
     if share:  # rows past the last group are whatever ragged_dot left there
@@ -195,7 +200,7 @@ def hit_slots(top_idx, top_probs, live, n_experts: int):
     return slot_expert, n_hit, combine
 
 
-def _experts_hit(x, stack: ExpertStack, top_idx, top_probs, live_rows) -> jnp.ndarray:
+def _experts_hit(x, stack: ExpertStack, top_idx, top_probs, live_rows, activation: str = "silu") -> jnp.ndarray:
     """The experts the live rows reach, read out of the stacked run in place."""
     from petals_tpu.ops.expert_hit import hit_experts
 
@@ -205,18 +210,40 @@ def _experts_hit(x, stack: ExpertStack, top_idx, top_probs, live_rows) -> jnp.nd
     slot_expert, n_hit, combine = hit_slots(
         top_idx.reshape(b * s, k), top_probs.reshape(b * s, k), live, stack.w1.shape[1]
     )
-    y = hit_experts(x.reshape(b * s, h), stack.w1, stack.w3, stack.w2, stack.layer, slot_expert, n_hit, combine)
+    y = hit_experts(
+        x.reshape(b * s, h), stack.w1, stack.w3, stack.w2, stack.layer, slot_expert, n_hit, combine, activation=activation
+    )
     return y.astype(x.dtype).reshape(b, s, h)
 
 
+def moe_route(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, scoring: str = "softmax", scale: float = 1.0):
+    """The routing half of ``moe_apply``: ``(top_idx, top_weights)`` [b, s, k] from the tensor the router reads, under the
+    scope ``ptu.moe.router``. A block whose router reads another tensor than its experts are fed (one that routes on its
+    input before its attention) calls this there and hands the pair to ``moe_experts`` later."""
+    with jax.named_scope("ptu.moe.router"):
+        return route(params, x, Routing(top_k, scoring, renormalize, scale))
+
+
 def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, dispatch: str = "dense",
-              scoring: str = "softmax", scale: float = 1.0, first: int = 0, identities: int = 0, live_rows=None) -> jnp.ndarray:
+              scoring: str = "softmax", scale: float = 1.0, first: int = 0, identities: int = 0, live_rows=None,
+              activation: str = "silu") -> jnp.ndarray:
     """x: [batch, seq, hidden] -> what the held experts give each token of the
     mixture of its top-k experts (HF-exact routing), plus the shared expert
     where ``params`` has one. ``renormalize`` divides the kept weights by
     their sum (Mixtral's rule; OLMoE's ``norm_topk_prob`` false keeps the
     softmax mass as it is); ``scoring`` and ``scale`` are ``Routing``'s,
-    ``first`` and ``identities`` are ``MoeDims``'.
+    ``first`` and ``identities`` are ``MoeDims``'. Routing (``moe_route``) and
+    application (``moe_experts``) on the one tensor."""
+    routed = moe_route(params, x, top_k=top_k, renormalize=renormalize, scoring=scoring, scale=scale)
+    return moe_experts(params, x, *routed, dispatch=dispatch, first=first, identities=identities, live_rows=live_rows,
+                       activation=activation)
+
+
+def moe_experts(params: dict, x: jnp.ndarray, top_idx, top_probs, *, dispatch: str = "dense", first: int = 0,
+                identities: int = 0, live_rows=None, activation: str = "silu") -> jnp.ndarray:
+    """The application half of ``moe_apply``: the experts ``top_idx`` (among the ROUTED, as ``moe_route`` gives them) fed
+    ``x`` and weighed by ``top_probs``. ``activation`` is the experts' gate activation (``ACTIVATIONS``: SwiGLU or
+    ReGLU), static, the same in all three dispatches.
 
     ``dispatch`` is ``grouped_dispatch``'s answer (``choose_dispatch`` asks it
     for a block). The expert weights are ``params``' ``w1`` / ``w3`` / ``w2``
@@ -225,11 +252,10 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
     by "hit" alone: the other two compute dead rows like any other."""
     from petals_tpu.ops.quant import QuantizedLinear, quant_matmul
 
+    act = ACTIVATIONS[activation]
     stack = params.get("experts")
     n_experts = stack.w1.shape[1] if stack is not None else params["w1"].shape[0]
     share = n_experts != params["gate"].shape[-1]  # a pick may fall outside the held: an absent expert's, or an identity's
-    with jax.named_scope("ptu.moe.router"):
-        top_idx, top_probs = route(params, x, Routing(top_k, scoring, renormalize, scale))
     if identities:
         with jax.named_scope("ptu.moe.zero"):
             to_self = jnp.where(top_idx >= params["gate"].shape[-1] - identities, top_probs, 0.0).sum(axis=-1)
@@ -242,13 +268,13 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
 
     if dispatch == "hit":
         with jax.named_scope("ptu.moe.experts.hit"):
-            y = _experts_hit(x, stack, top_idx, top_probs, live_rows)
+            y = _experts_hit(x, stack, top_idx, top_probs, live_rows, activation)
         return with_rest(y)
 
     w1, w3, w2 = stack.of_layer() if stack is not None else (params["w1"], params["w3"], params["w2"])
     if dispatch == "grouped":
         with jax.named_scope("ptu.moe.experts.grouped"):
-            y = _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share)
+            y = _experts_grouped(x, w1, w2, w3, top_idx, top_probs, share, activation)
         return with_rest(y)
 
     with jax.named_scope("ptu.moe.experts.dense"):
@@ -263,7 +289,7 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
                 def slice_q(q):
                     return QuantizedLinear(q.kind, q.data[e], q.scales[e], q.in_features, q.out_features)
 
-                g = silu(quant_matmul(x, slice_q(w1))) * quant_matmul(x, slice_q(w3))
+                g = act(quant_matmul(x, slice_q(w1))) * quant_matmul(x, slice_q(w3))
                 return quant_matmul(g, slice_q(w2))
 
             expert_out = jnp.stack([expert(e) for e in range(n_experts)])  # [E, b, s, h]
@@ -271,7 +297,7 @@ def moe_apply(params: dict, x: jnp.ndarray, *, top_k: int, renormalize: bool, di
             # dense expert compute on stacked weights: w1/w3 [E, h, m], w2 [E, m, h]
             gate_out = jnp.einsum("bsh,ehm->ebsm", x, w1)
             up = jnp.einsum("bsh,ehm->ebsm", x, w3)
-            expert_out = jnp.einsum("ebsm,emh->ebsh", silu(gate_out) * up, w2)
+            expert_out = jnp.einsum("ebsm,emh->ebsh", act(gate_out) * up, w2)
         y = jnp.einsum("ebsh,bse->bsh", expert_out, combine)
     return with_rest(y)
 

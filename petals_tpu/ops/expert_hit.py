@@ -1,4 +1,4 @@
-"""The "hit" expert dispatch's kernel (models/moe.py): SwiGLU experts read out
+"""The "hit" expert dispatch's kernel (models/moe.py): gated experts (SwiGLU, or ReGLU: one static argument) read out
 of a run's STACKED weights (``w1`` / ``w3`` [L, E, h, m], ``w2`` [L, E, m, h])
 for the experts a decode step's live rows reach, and no others.
 
@@ -44,7 +44,7 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _kernel(layer_ref, slot_ref, n_hit_ref, x_ref, cw_ref, w1_ref, w3_ref, w2_ref, o_ref, *, dot_in_f32: bool):
+def _kernel(layer_ref, slot_ref, n_hit_ref, x_ref, cw_ref, w1_ref, w3_ref, w2_ref, o_ref, *, dot_in_f32: bool, activation: str):
     del layer_ref, slot_ref  # the index maps' business
     s, j = pl.program_id(0), pl.program_id(1)
 
@@ -59,14 +59,15 @@ def _kernel(layer_ref, slot_ref, n_hit_ref, x_ref, cw_ref, w1_ref, w3_ref, w2_re
             x, w1, w3, w2 = (a.astype(jnp.float32) for a in (x, w1, w3, w2))
         gate = jnp.dot(x, w1, preferred_element_type=jnp.float32)
         up = jnp.dot(x, w3, preferred_element_type=jnp.float32)
-        g = (gate * jax.nn.sigmoid(gate) * up * cw_ref[...]).astype(x.dtype)  # silu(gate) * up, [rows, tile] x [rows, 1]
+        gate = jnp.maximum(gate, 0.0) if activation == "relu" else gate * jax.nn.sigmoid(gate)  # relu | silu
+        g = (gate * up * cw_ref[...]).astype(x.dtype)  # act(gate) * up, [rows, tile] x [rows, 1]
         o_ref[...] += jnp.dot(g, w2, preferred_element_type=jnp.float32)
 
 
-def hit_experts(x, w1, w3, w2, layer, slot_expert, n_hit, combine, *, interpret=None):
-    """``sum over s < n_hit of (silu(x @ w1[layer, e_s]) * (x @ w3[layer, e_s])
+def hit_experts(x, w1, w3, w2, layer, slot_expert, n_hit, combine, *, activation: str = "silu", interpret=None):
+    """``sum over s < n_hit of (act(x @ w1[layer, e_s]) * (x @ w3[layer, e_s])
     * combine[s][:, None]) @ w2[layer, e_s]`` in float32, ``e_s =
-    slot_expert[s]``.
+    slot_expert[s]``, ``act`` the static ``activation`` (``silu`` | ``relu``).
 
     x: [rows, h]; w1, w3: [L, E, h, m]; w2: [L, E, m, h]; layer, n_hit: int32
     scalars; slot_expert: int32 [S], every entry a held expert, those past
@@ -107,7 +108,7 @@ def hit_experts(x, w1, w3, w2, layer, slot_expert, n_hit, combine, *, interpret=
     )
     weight_tiles = 2 * 3 * h * tile * w1.dtype.itemsize
     out = pl.pallas_call(
-        functools.partial(_kernel, dot_in_f32=interpret),
+        functools.partial(_kernel, dot_in_f32=interpret, activation=activation),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((padded, h), jnp.float32),
         compiler_params=pltpu.CompilerParams(

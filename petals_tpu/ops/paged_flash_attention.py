@@ -589,6 +589,13 @@ def window_pages(window, q_len: int, page_size: int, max_pages: int) -> int:
 # pool (PERF.md section 5, PR 36); pages smaller than the cells' go several a
 # block.
 WALK_BLOCK_BYTES = 512 << 10
+# and the most trips a walk takes over a table: past it a block is as many slots as keep the walk within it. A trip gathers
+# a page a lane whatever the block's bytes say, and over a table of hundreds of slots the trips are the walk: 16 lanes of
+# 4 kv heads of 128 at contexts of 2.5k-14.5k (a table of 256 slots, 227 walked) take 2.85 ms a layer at one page a
+# trip, 2.15 at four, 2.04 at eight, 2.40 at 32; a window's 65 slots 0.82 at one, 0.69 at two, 0.64 at four
+# (benchmarks/ablate_paged_walk.py smallthinker-21b smallthinker-21b-window, PR 64). No table of 64 slots or fewer, every
+# other cell's, is touched
+WALK_MAX_TRIPS = 64
 
 
 def walk_block_pages(n_lanes: int, width: int, page_size: int, hkv: int, d: int, itemsize: int = 2) -> int:
@@ -596,10 +603,14 @@ def walk_block_pages(n_lanes: int, width: int, page_size: int, hkv: int, d: int,
     the largest power of two whose pages, over all lanes, stay within
     ``WALK_BLOCK_BYTES`` a side, at least one and at most the ``width`` slots
     there are to walk (one page of 64 rows at every cell's 8 lanes and 8-32
-    kv heads; four pages of 16 rows at 8 kv heads of 128)."""
+    kv heads; four pages of 16 rows at 8 kv heads of 128); and over a table
+    wider than ``WALK_MAX_TRIPS`` slots as many as keep the trips within that
+    (four pages over 16-lane tables of 256 slots, two over a window's 65)."""
     a_slot = n_lanes * page_size * hkv * d * itemsize
     block = 1
     while block * 2 * a_slot <= WALK_BLOCK_BYTES:
+        block *= 2
+    while block * WALK_MAX_TRIPS < width:  # a table of hundreds of slots: no more than ``WALK_MAX_TRIPS`` trips
         block *= 2
     return min(block, width)
 

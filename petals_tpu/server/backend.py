@@ -624,6 +624,10 @@ class TransformerBackend:
         from petals_tpu.ops.linear_attention import StatePool
         from petals_tpu.ops.paged_attention import PagedKV
 
+        if self.cache.grouped and len(state) == 2 * (len(self.cache.page_groups) - 1):
+            # handed a pair of pools a page group (the others' where a state pool would ride) and tables a group
+            carry, (k_pool, v_pool, *others) = self._scan_grouped_span(params, (k_pool, v_pool, *state), carry, layer)
+            return carry, k_pool, v_pool, tuple(others)
         depth, n_pages, rows = k_pool.shape[0], k_pool.shape[1], self.cache.block_rows
         by_sort = bool(self.cache.state_layers)
         indexed = self.cache.index_row is not None
@@ -662,6 +666,39 @@ class TransformerBackend:
         k_pool, v_pool, *index = (stacked(span) for span in spans)
         return carry, k_pool, v_pool, tuple(index) if indexed else state
 
+    def _scan_grouped_span(self, params, pools, carry, layer):
+        """``_scan_paged_span`` for a span whose layers keep pages in GROUPS by static window (``cache.page_groups``): ``pools``
+        is a pair (k, v) a group, ``[the group's layers, the group's pages, page_size, *pool_row]``, and the tables the
+        ``layer`` closes over are ``[groups, n_lanes, max_pages]`` (a chunk lane's row ``[groups, 1, max_pages]``). Every
+        group's pools are flattened and carried through every run's loop as the one pool is, written in place; a block
+        reaches its own group's through that group's tables shifted by its layer in that pool (``cache.group_slots``), and
+        the other groups' pass through its trip untouched. Returns ``(carry, pools)``, stacked again."""
+        from petals_tpu.ops.paged_attention import PagedKV
+
+        groups = self.cache.page_groups
+        shapes = [pools[2 * g].shape[:2] for g in range(len(groups))]  # (layers, pages) a group
+        merged = tuple(
+            tuple(a.reshape(depth * n_pages, *a.shape[2:]) for a in pools[2 * g : 2 * g + 2]) for g, (depth, n_pages) in enumerate(shapes)
+        )
+        group_of = {kind: self.cache.group_slots[start][0] for kind, start, _ in self.runs}  # a kind has one window
+
+        def one(block_apply, scanned, p_block, slot, block_idx, kind=None):
+            inner, spans = scanned
+            g = group_of[kind]
+            own = (slot * shapes[g][1], shapes[g][1])
+
+            def paged(mine, tables):
+                tables = tables[g]
+                return tuple(PagedKV(span, jnp.where(tables >= 0, tables + own[0], -1), own) for span in mine)
+
+            inner, mine = layer(block_apply, inner, p_block, spans[g], paged)
+            return (inner, (*spans[:g], tuple(mine), *spans[g + 1 :])), None
+
+        slots = jnp.asarray([slot for _, slot in self.cache.group_slots], jnp.int32)
+        (carry, spans), _ = self._scan_span(params, (carry, merged), slots, one, pass_kind=True)
+        stacked = tuple(a.reshape(depth, n_pages, *a.shape[1:]) for pair, (depth, n_pages) in zip(spans, shapes) for a in pair)
+        return carry, stacked
+
     def _paged_lanes_layer(self, tables, positions):
         """``_scan_paged_span``'s ``layer`` for a step in which every lane
         feeds rows at its own position (decode, server-side generation,
@@ -669,7 +706,7 @@ class TransformerBackend:
         cfg = self.cfg
 
         def layer(block_apply, h, p_block, spans, paged):
-            live = self._live_rows(positions, tables.shape[1] * spans[0].shape[1])  # max_pages * page_size
+            live = self._live_rows(positions, tables.shape[-1] * spans[0].shape[1])  # max_pages * page_size
             out, new_kv = block_apply(
                 p_block, h, paged(spans, tables), positions, cfg,
                 use_flash=False, tp_mesh=None, **live,
@@ -786,7 +823,7 @@ class TransformerBackend:
             hidden, k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
                 self._paged_lanes_layer(tables, positions),
-                state, self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2]),
+                state, self._state_lanes_layer(positions, tables.shape[-1] * k_pool.shape[2]),
             )
             if with_fp:
                 # same projection as the dense program: path-invariance —
@@ -859,7 +896,7 @@ class TransformerBackend:
             hidden, k_pool, v_pool, state = self._scan_paged_span(
                 params, k_pool, v_pool, hidden,
                 self._paged_lanes_layer(tables, positions),
-                state, self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2]),
+                state, self._state_lanes_layer(positions, tables.shape[-1] * k_pool.shape[2]),
             )
             logits = client_head(client_params, hidden, cfg)[:, -1, :]
             next_tok = sample_tokens(
@@ -1067,7 +1104,7 @@ class TransformerBackend:
             B = chunk_hidden.shape[1]
             hidden, positions = self._unpack_lanes(lanes, cache_dtype)
             chunk_hidden = chunk_hidden.astype(cache_dtype)
-            table_row = jnp.take(tables, chunk_lane, axis=0)[None]  # [1, max_pages]
+            table_row = jnp.expand_dims(jnp.take(tables, chunk_lane, axis=-2), -2)  # [1, max_pages] (a group: [groups, 1, max_pages])
             decode_half = self._paged_lanes_layer(tables, positions)
             extra = {"n_total": chunk_n_total} if takes_n_total else {}
 
@@ -1089,7 +1126,7 @@ class TransformerBackend:
                 )
                 return (out_dec, out_pf), self._pools_of(new_kv)
 
-            decode_state = self._state_lanes_layer(positions, tables.shape[1] * k_pool.shape[2])
+            decode_state = self._state_lanes_layer(positions, tables.shape[-1] * k_pool.shape[2])
 
             def state_layer(block_apply, carry, p_block, mine):
                 # the lanes' rows through the one-step form, then the chunk

@@ -124,7 +124,8 @@ class _LanePrefillState:
     prefills, bounded by the per-tick token budget) until ``offset`` reaches
     the full length, then resolves ``future`` with the concatenated span
     outputs. Pages for the WHOLE range were prepared at admission, so the
-    flush loop never blocks on allocation mid-prefill."""
+    flush loop never blocks on allocation mid-prefill (a grouped pool's
+    windowed groups: a chunk at a time, ``_window_pages_for_tick``)."""
 
     future: asyncio.Future
     generation: int
@@ -139,6 +140,7 @@ class _LanePrefillState:
     wait_observed: bool = False  # first chunk already recorded the queue wait
     queue_s: float = 0.0  # admission -> first chunk (handler step_meta)
     compute_s: float = 0.0  # cumulative mixed-step wall across chunks
+    starved: float = 0.0  # time.monotonic() since which its next chunk has waited for a windowed layer's page (0: not waiting)
 
 
 @dataclasses.dataclass
@@ -192,6 +194,23 @@ class _LaneWaiter:
 # what a span with a recurrent state answers a request for a lane's cache cut to a position
 _SNAPSHOT = "a snapshot of a lane's cache (session export, migration, parking, a stored prefix)"
 _SNAPSHOT_WHY = "it ships keys and values cut to a position; the state is not shipped yet and cannot be cut"
+
+
+class _WindowGroup:
+    """One page group of a grouped pool beside the first (server/span_cache.py ``SpanCache.page_groups``): the layers of one
+    static ``window``, with a pool, an allocator and lane tables of their own. The first group is the lane's own table
+    (``DecodeBatcher._tables``, ``_pages``) and keeps every page; a lane here holds the pages its window can still reach
+    and those of the rows being fed, and gives the rest back (``DecodeBatcher._window_pages_now``). ``tables`` is written
+    on the event loop alone, as the first group's are (``_write_tables`` says how the compute thread reads them)."""
+
+    def __init__(self, window: int, layers: int, n_pages: int, n_lanes: int, max_pages: int):
+        self.window, self.layers, self.n_pages = int(window), int(layers), int(n_pages)
+        self.alloc = PageAllocator(self.n_pages)
+        self.tables = np.full((n_lanes, max_pages), -1, np.int32)
+        self.lane_held = np.zeros(n_lanes, np.int64)
+        # a lane's held slots are one run, [first, last]: two numbers a lane, so that a step's give-and-take walks no row
+        self.first = [0] * n_lanes
+        self.last = [-1] * n_lanes
 
 
 class DecodeBatcher:
@@ -257,9 +276,17 @@ class DecodeBatcher:
             )
         if cache.content in ("index", "latent") and int(swap_host_bytes or 0) > 0:
             cache.refuse("the host swap tier (swap_host_bytes > 0)", "")
+        # a span whose layers keep pages in groups by static window (``cache.page_groups``): on the paged pool every group
+        # beside the first has a pool, an allocator and lane tables of its own (``_win``, made with the pool) and gives a
+        # lane's pages back as its window moves; what ships, stores, cuts back or adopts a lane's pages is refused by name
+        self._grouped = self.page_size is not None and cache.grouped
+        self._win: List[_WindowGroup] = []
+        self._group_pages: tuple = ()
+        if self._grouped and int(swap_host_bytes or 0) > 0:
+            cache.refuse("the host swap tier (swap_host_bytes > 0)", "", paged=True)
         # fixed with the backend and asked by the step bodies' counters every step: what a paged step reads, a page's and a lane's
         # state's bytes (``_pool``: server/span_cache.py ``LanePool``), and the expert dispatch a block call of a shape takes
-        self._pool = cache.lane_pool(n_lanes, self.max_pages, self.page_size) if self.page_size else None
+        self._pool = cache.lane_pool(n_lanes, self.max_pages, self.page_size, grouped=self._grouped) if self.page_size else None
         self._moe_took: Dict[tuple, Optional[str]] = {}
         self._pages: Optional[PageAllocator] = None
         # [n_lanes, max_pages] int32, -1 = unallocated. What everyone READS is a view that refuses writes: an
@@ -298,6 +325,8 @@ class DecodeBatcher:
         # lanes keep stepping while prefills stream in
         self._prefill_queue: List[_LanePrefillState] = []
         self.prefill_token_budget = max(int(prefill_token_budget), 1)
+        if self._grouped:  # pages a group: the first group's as asked, a windowed group's as many lanes' worth
+            self._group_pages = cache.group_pages(self.n_lanes, self.max_pages, self.page_size, self.prefill_token_budget, self.n_pages)
         # speculative decoding (server/spec_decode.py): with a draft model
         # loaded, eligible gen lanes move onto the draft-verify path — k
         # drafts verified in ONE paged step per tick, up to k+1 tokens
@@ -321,7 +350,7 @@ class DecodeBatcher:
                 )
             from petals_tpu.server.backend import SPEC_CUTS_BACK
 
-            cache.refuse("speculative decoding", SPEC_CUTS_BACK)
+            cache.refuse("speculative decoding", SPEC_CUTS_BACK, paged=self._grouped)
         # the draft instance whose bucket shapes have been pre-compiled via
         # DraftModel.warmup (first spec tick, on the compute thread); keyed
         # on the object so a swapped-in draft re-warms
@@ -541,7 +570,7 @@ class DecodeBatcher:
                 # when the backend stores the pool quantized; the state pool's
                 # leaves ride last, as the index pool does
                 descs = self.backend.cache.pool_descriptors(
-                    self.n_pages, self.page_size, self.n_lanes, 0, self.backend.n_blocks
+                    self._group_pages if self._grouped else self.n_pages, self.page_size, self.n_lanes, 0, self.backend.n_blocks
                 )
             else:
                 descs = self.backend.cache_descriptors(
@@ -567,6 +596,7 @@ class DecodeBatcher:
                 self._tables = self._tables_rw.view()
                 self._tables.flags.writeable = False
                 self._lane_held = np.zeros(self.n_lanes, np.int64)
+                self._win = self._new_window_groups()
                 hsz = self.backend.hidden_size
                 self._lanes_in = np.zeros((self.n_lanes, hsz + 1), np.int32)
                 self._lanes_rows = self._lanes_in[:, :hsz].view(np.float32)
@@ -575,6 +605,7 @@ class DecodeBatcher:
                     f"{self.page_size} tokens of {list(self.backend.cache.pool_row)} ({self.n_lanes} lanes x "
                     f"{self.max_pages} table slots) for blocks "
                     f"[{self.backend.first_block}, {self.backend.first_block + self.backend.n_blocks})"
+                    + "".join(f"; {g.n_pages} pages for the {g.layers} layers of window {g.window}, given back as it moves" for g in self._win)
                 )
             else:
                 logger.info(
@@ -609,7 +640,7 @@ class DecodeBatcher:
         quantized pool rides as 4 MemoryCache buffers (codes x2, scales x2)
         and is re-wrapped into PagedPool pytrees HERE, so every caller —
         step bodies, swap, COW, snapshots — keeps the 2-tuple shape."""
-        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self.backend.cache.pools_beside_pages])
+        bufs = self.memory_cache.get_buffers(*self._handles[: len(self._handles) - self._beside])
         if len(bufs) == 4:
             from petals_tpu.ops.paged_attention import PagedPool
 
@@ -621,10 +652,32 @@ class DecodeBatcher:
         the pair of ``_buffers`` and hand back after it; none for a span
         without a recurrent state. A span that caches an index row hands its
         index pool over the same way."""
-        n = self.backend.cache.pools_beside_pages
+        n = self._beside
         if not n:
             return ()
         return tuple(self.memory_cache.get_buffers(*self._handles[-n:]))
+
+    @property
+    def grouped(self) -> bool:
+        """The pool keeps pages by kind of layer and gives the windowed groups' back (``SpanCache.page_groups``)."""
+        return self._grouped
+
+    @property
+    def _beside(self) -> int:
+        """How many of the pool's buffers ride the step programs after the pair of ``_buffers``: the state's leaves or the
+        index pool, or, for a grouped pool, the other groups' pairs of pools."""
+        return 2 * (len(self._group_pages) - 1) if self._grouped else self.backend.cache.pools_beside_pages
+
+    def _new_window_groups(self) -> List[_WindowGroup]:
+        groups = self.backend.cache.page_groups[1:] if self._grouped else ()
+        return [
+            _WindowGroup(window, len(blocks), n_pages, self.n_lanes, self.max_pages)
+            for (window, blocks), n_pages in zip(groups, self._group_pages[1:])
+        ]
+
+    def _held_by_group(self) -> Optional[tuple]:
+        """A grouped pool's pages a lane, a group (``LanePool.count_step``'s ``group_held``); None for a pool of one group."""
+        return (self._lane_held, *(g.lane_held for g in self._win)) if self._grouped else None
 
     def _update(self, k_pool, v_pool, *state) -> None:
         from petals_tpu.ops.paged_attention import PagedPool
@@ -781,6 +834,8 @@ class DecodeBatcher:
                 if row[slot] >= 0:
                     self._pages.decref(int(row[slot]))
             self._write_tables(lane, slice(None), -1)
+            for group in self._win:
+                self._window_release(group, lane, self.max_pages)
             self._lanes_rows[lane] = 0.0  # the next tenant's neighbours step beside zeros, not this tenant's last row
         # hand straight to the best-placed waiter (priority class, then
         # per-peer fair share, then FIFO), else back to the free list; the
@@ -809,14 +864,18 @@ class DecodeBatcher:
     # ------------------------------------------------------------------ pages
 
     async def prepare_write(
-        self, lane: int, t0: int, t1: int, timeout: Optional[float] = None
+        self, lane: int, t0: int, t1: int, timeout: Optional[float] = None, *, windowed: bool = True
     ) -> None:
         """Make token range [t0, t1) of ``lane`` writable: allocate missing
         pages on demand and copy-on-write-fork any page shared with the
         prefix cache (refs > 1). Blocks on an exhausted pool until a page
         frees (release_lane / prefix-cache eviction), raising
         AllocationFailed at ``timeout`` — MemoryCache's backpressure
-        contract at page grain. No-op in dense mode."""
+        contract at page grain. No-op in dense mode. A grouped pool's
+        windowed groups take the range's pages too and give back what lies
+        behind its first row's window (``_window_pages_now``), unless
+        ``windowed`` is false: a prompt admitted whole takes its pages there a
+        chunk at a time, as each chunk is fed (``_flush_loop``)."""
         if self.page_size is None or t1 <= t0:
             return
         self._check_lane(lane)
@@ -890,6 +949,66 @@ class DecodeBatcher:
             # ledger here, not on the next admission boundary — page-seconds
             # accrued under the old rates up to this instant
             self._ledger_sync()
+        while windowed and self._win and not self._window_pages_now(lane, t0, t1):
+            remaining = None if deadline is None else deadline - time.monotonic()
+            if remaining is not None and remaining <= 0:
+                tm.ALLOC_FAILED.inc()
+                raise AllocationFailed(f"No free KV page of a windowed layer within {timeout} s ({self._occupancy()})")
+            await asyncio.sleep(0.005 if remaining is None else min(remaining, 0.005))  # a page goes back as another lane's window moves
+            if self._pages is not alloc:
+                raise AllocationFailed("Lane pool was reset while waiting for a free page")
+            self._check_lane(lane)
+
+    def _window_release(self, group: _WindowGroup, lane: int, below: int, above: Optional[int] = None) -> int:
+        """Give ``lane``'s pages in ``group``'s slots under ``below`` (and, where given, over ``above``: what a rollback
+        left ahead of the lane) back to the group's allocator; how many went. A lane's held slots are one run,
+        ``[group.first[lane], group.last[lane]]``, cut here from either end."""
+        first, last = group.first[lane], group.last[lane]
+        keep_to = last if above is None else min(last, above)
+        row = group.tables[lane]
+        gone = [slot for slot in (*range(first, min(below, last + 1)), *range(max(keep_to + 1, first, below), last + 1)) if row[slot] >= 0]
+        if not gone:
+            return 0
+        for slot in gone:
+            group.alloc.decref(int(row[slot]))
+        row[gone] = -1
+        group.first[lane], group.last[lane] = max(first, below), keep_to
+        if group.first[lane] > group.last[lane]:  # nothing held: the next page taken starts a run of its own
+            group.first[lane], group.last[lane] = 0, -1
+        group.lane_held[lane] -= len(gone)
+        self._tables_version += 1
+        return len(gone)
+
+    def _window_pages_now(self, lane: int, t0: int, t1: int) -> bool:
+        """A grouped pool's windowed groups made ready for ``lane`` to feed rows [t0, t1), without a wait (event loop): in
+        each, the pages wholly behind the first row's window (every position under ``t0 - window + 1``) go back to the
+        group's allocator, counted in ``window_pages_released``, and the pages of [t0, t1) the lane lacks are taken. So a
+        lane holds there, when a step starts, exactly the pages its rows' windows reach. False where a group has no free
+        page yet (what was taken stays with the lane; the caller waits and asks again)."""
+        ps = self.page_size
+        for group in self._win:
+            self.stats["window_pages_released"] += self._window_release(group, lane, max(t0 - group.window + 1, 0) // ps, (t1 - 1) // ps)
+            for slot in range(max(t0 // ps, group.last[lane] + 1), (t1 - 1) // ps + 1):
+                page = group.alloc.try_alloc()
+                if page is None:
+                    return False
+                group.tables[lane, slot] = page
+                if group.last[lane] < group.first[lane]:
+                    group.first[lane] = slot
+                group.last[lane] = slot
+                group.lane_held[lane] += 1
+                self._tables_version += 1
+        return True
+
+    def window_reach_held(self, lane: int, position: int) -> bool:
+        """Whether ``lane`` still holds, in every windowed group, the pages a row at ``position`` reaches behind itself: a
+        rollback to there can be served (a page behind a window that has moved on has gone back to the pool, with its rows)."""
+        ps = self.page_size
+        if position <= 0:
+            return True
+        return all(
+            (group.tables[lane, max(position - group.window + 1, 0) // ps : (position - 1) // ps + 1] >= 0).all() for group in self._win
+        )
 
     def _identity_page(self, lane: int, slot: int) -> Optional[int]:
         """The page to ask for first: identity preference keeps tables
@@ -910,13 +1029,13 @@ class DecodeBatcher:
         slot = position // self.page_size
         cur = int(self._tables[lane, slot])
         if cur >= 0:
-            return self._pages.refs[cur] == 1
+            return self._pages.refs[cur] == 1 and self._window_pages_now(lane, position, position + 1)
         page = self._pages.try_alloc(preferred=self._identity_page(lane, slot))
         if page is None:
             return False
         self._write_tables(lane, slot, page)
         self._ledger_sync()  # a grow: page-seconds accrued under the old rates up to here
-        return True
+        return self._window_pages_now(lane, position, position + 1)
 
     def _copy_page(self, src: int, dst: int) -> None:
         """Compute-thread body: device copy of one page (all blocks) — the
@@ -1028,7 +1147,9 @@ class DecodeBatcher:
         version = self._tables_version  # before the copy
         sent, on_device = self._tables_on_device
         if on_device is None or sent != version:
-            on_device = self.backend.device_tables(self._tables)
+            # a grouped pool: the tables a group, [groups, n_lanes, max_pages], the lane's own first
+            tables = np.stack([self._tables, *(g.tables for g in self._win)]) if self._win else self._tables
+            on_device = self.backend.device_tables(tables)
             self._tables_on_device = (version, on_device)
             self.stats["tables_sent"] += 1
         return on_device
@@ -1059,6 +1180,7 @@ class DecodeBatcher:
             "pages_free": alloc.n_free if alloc is not None else self.n_pages,
             "tables_contiguous": self.tables_contiguous(),
             **({f"pages_{k}": v for k, v in alloc.stats.items()} if alloc else {}),
+            **({"page_groups": [{"window": g.window, "n_pages": g.n_pages, "pages_free": g.alloc.n_free} for g in self._win]} if self._win else {}),
         }
 
     # -------------------------------------------------------- preemption / swap
@@ -1567,7 +1689,15 @@ class DecodeBatcher:
             if pool.windows and self._tables is not None:
                 # over the lanes that hold pages, at the last position each fed
                 live = np.flatnonzero(self._lane_held)
-                info["window_pages_held"], info["window_pages_in_reach"] = pool.window_pages(live, self._lane_held)
+                info["window_pages_held"], info["window_pages_in_reach"] = pool.window_pages(live, self._lane_held, self._held_by_group())
+            if self._grouped:
+                # pages by kind of layer: a windowed group's free pages and the most a lane holds there when a step starts
+                info["page_groups"] = [
+                    {"window": None, "layers": len(cache.page_groups[0][1]), "n_pages": self.n_pages, "pages_free": info["pages_free"],
+                     "lane_pages": self.max_pages},
+                    *({"window": g.window, "layers": g.layers, "n_pages": g.n_pages, "pages_free": g.alloc.n_free,
+                       "lane_pages": cache.lane_pages(g.window, self.max_pages, self.page_size, self.prefill_token_budget)} for g in self._win),
+                ]
             # of the step bodies so far, those that copied the block tables to the device (``_step_tables``)
             info["tables_sent"], info["batched_steps"] = self.stats["tables_sent"], self.stats["batched_steps"]
         info.update(self._scheduler.summary())
@@ -1843,6 +1973,10 @@ class DecodeBatcher:
                 len(batch) + len(gen_states) + len(spec_states),
                 spec_tokens=len(spec_states) * (self.spec_k + 1),
             )
+            if self._win:
+                pf = self._window_pages_for_tick(pf, gen_states)
+                if pf is None and self._prefill_queue and not batch and not gen_states:
+                    await asyncio.sleep(0.005)  # a chunk waits for a windowed layer's page, which goes back as another lane moves on
             if not batch and not gen_states and not spec_states and pf is None:
                 continue
             try:
@@ -1936,6 +2070,33 @@ class DecodeBatcher:
                         st.future.set_result(
                             np.asarray([st.collected], np.int32)
                         )
+
+    def _window_pages_for_tick(self, pf, gen_states):
+        """A grouped pool, before a tick's step is started (event loop): the windowed groups' pages of the prompt chunk
+        ``pf`` about to ride (a chunk at a time, never a prompt at a time) and of each generating lane's next row
+        (``_window_pages_now``). A chunk whose pages are not all there yet sits this tick out (``pf`` comes back None) and
+        its prompt fails once it has waited ``alloc_timeout``; a generating lane that cannot have its page fails at once."""
+        for lane, st in list(gen_states.items()):
+            if not self._window_pages_now(lane, st.position, st.position + 1):
+                del gen_states[lane]
+                self._gen_states.pop(lane, None)
+                if not st.future.done():
+                    st.future.set_exception(AllocationFailed(f"No free KV page of a windowed layer ({self._occupancy()})"))
+        if pf is None:
+            return None
+        st, take = pf
+        if self._window_pages_now(st.lane, st.position, st.position + take):
+            st.starved = 0.0
+            return pf
+        now = time.monotonic()
+        st.starved = st.starved or now
+        if now - st.starved > (30.0 if self.alloc_timeout is None else self.alloc_timeout):
+            self._prefill_queue.remove(st)
+            if not st.future.done():
+                st.future.set_exception(AllocationFailed(f"No free KV page of a windowed layer for a prompt's chunk ({self._occupancy()})"))
+        elif len(self._prefill_queue) > 1:
+            self._prefill_queue.append(self._prefill_queue.pop(0))  # another prompt's chunk may fit
+        return None
 
     def _pick_spec_lanes(self, gen_states) -> Dict[int, _LaneGenState]:
         """Partition this tick's generating lanes: lanes eligible to
@@ -2113,7 +2274,7 @@ class DecodeBatcher:
                     f"the lane buffer ({self.max_length} tokens)"
                 )
             await self.prepare_write(
-                lane, position, position + total, timeout=self.alloc_timeout
+                lane, position, position + total, timeout=self.alloc_timeout, windowed=False
             )
             plan = self.backend.chunk_plan(
                 1, total, kv_buf_len=self.max_length,
@@ -2167,7 +2328,8 @@ class DecodeBatcher:
             if self.page_size is not None and n_tokens > 1:
                 # reserve the whole stream's pages up front: the flush loop can't
                 # await page allocation mid-generation
-                await self.prepare_write(lane, int(position), int(position) + int(n_tokens) - 1)
+                # a grouped pool's windowed groups take a generating lane's pages a row at a time (``_window_pages_for_tick``)
+                await self.prepare_write(lane, int(position), int(position) + int(n_tokens) - 1, windowed=False)
 
             # bootstrap: t0 comes from the caller's hidden, not a pool step —
             # submitted through the queue so it serializes with batched steps
@@ -2273,6 +2435,7 @@ class DecodeBatcher:
                     self._pages.freed_event.set()
                 self._pages = PageAllocator(self.n_pages)
                 if self._tables is not None:
+                    self._win = self._new_window_groups()
                     self._write_tables(slice(None), slice(None), -1)
                     self._tables_on_device = (-1, None)  # the device's copy goes with the pool
             for handle in self._handles or ():
@@ -2465,7 +2628,7 @@ class DecodeBatcher:
             self._count_moe(len(batch))
             self._count_stream(len(batch))
             if paged:
-                self._pool.count_step(self.stats, positions, self._lane_held)
+                self._pool.count_step(self.stats, positions, self._lane_held, group_held=self._held_by_group())
             duration = time.perf_counter() - t_step
             if paged:
                 tm.STEP_PAGED.observe(duration)
@@ -2575,7 +2738,7 @@ class DecodeBatcher:
             )
             self._count_moe(len(batch), chunk_tokens=take)
             self._count_stream(len(batch) + take)
-            self._pool.count_step(self.stats, positions, self._lane_held, chunk=(st.lane, st.position, take))
+            self._pool.count_step(self.stats, positions, self._lane_held, chunk=(st.lane, st.position, take), group_held=self._held_by_group())
             duration = time.perf_counter() - t_step
             tm.STEP_MIXED.observe(duration)
             tm.STEPS_MIXED.inc()
@@ -2668,7 +2831,7 @@ class DecodeBatcher:
             self._count_moe(len(batch) + len(gen_states))
             self._count_stream(len(batch), len(batch) + len(gen_states))
             if tables is not None:
-                self._pool.count_step(self.stats, positions, self._lane_held)
+                self._pool.count_step(self.stats, positions, self._lane_held, group_held=self._held_by_group())
             duration = time.perf_counter() - t_step
             tm.STEP_GEN.observe(duration)
             tm.STEPS_GEN.inc()
@@ -2886,7 +3049,7 @@ class DecodeBatcher:
         the check-in scatter has somewhere to land."""
         self.backend.cache.refuse(
             "an exclusive op on a checked-out lane (deep prompts, beam search's hypo_ids, a seeded or imported cache)",
-            "the lane's session-shaped view holds keys and values only",
+            "the lane's session-shaped view holds keys and values only", paged=self._grouped,
         )
         async with self._lane_busy(lane):
             self._check_lane(lane)
@@ -3038,7 +3201,7 @@ class DecodeBatcher:
         device pair are the same slices still resident in HBM (None under
         lockstep, whose shards are per-process) — the prefix cache's device
         tier pins these so a later hit can seed without re-uploading."""
-        self.backend.cache.refuse(_SNAPSHOT, _SNAPSHOT_WHY)
+        self.backend.cache.refuse(_SNAPSHOT, _SNAPSHOT_WHY, paged=self._grouped)
         self._check_lane(lane)
 
         def run():
@@ -3078,7 +3241,7 @@ class DecodeBatcher:
         ``snapshot_lane``'s host pair, or None when the lane isn't suspended,
         is busy, or its swap entry doesn't cover ``[0, position)`` — the
         caller falls back to the device path."""
-        self.backend.cache.refuse(_SNAPSHOT, _SNAPSHOT_WHY)
+        self.backend.cache.refuse(_SNAPSHOT, _SNAPSHOT_WHY, paged=self._grouped)
         if self.page_size is None:
             return None
         slot = self._scheduler.lanes.get(lane)

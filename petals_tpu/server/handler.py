@@ -308,7 +308,8 @@ class TransformerHandler:
         self.server_gen_params = server_gen_params
         self.draft_model = draft_model
         self.spec_k = spec_k
-        refusal = backend.cache.prefix_cache_refusal() if prefix_cache_bytes > 0 else None
+        paged = self.batcher is not None and self.batcher.page_size is not None
+        refusal = backend.cache.prefix_cache_refusal(paged=paged) if prefix_cache_bytes > 0 else None
         if refusal is not None:
             # off for a span that holds more than keys and values, by what its family declares (server/span_cache.py)
             logger.info(refusal)
@@ -1742,9 +1743,12 @@ class TransformerHandler:
                             f"start_from_position {start_from} is ahead of cache ({position})"
                         )
                     if 0 < start_from < position:
+                        # a paged lane of a span with page groups: served where every windowed layer still holds what a
+                        # row at ``start_from`` reaches, refused behind that (the pages went back as the window moved)
+                        gone = lane is not None and batcher.grouped and not batcher.window_reach_held(lane, int(start_from))
                         backend.cache.refuse(
                             f"start_from_position {start_from} behind the cache's position {position}",
-                            "a state cannot be cut back to an earlier position (0 starts the session over)",
+                            "a state cannot be cut back to an earlier position (0 starts the session over)", paged=gone,
                         )
                     position = int(start_from)  # rollback (speculative decoding)
                     if reg is not None:
@@ -1752,7 +1756,8 @@ class TransformerHandler:
 
                 if "kv_adopt" in step or "kv_import" in step:
                     backend.cache.refuse(
-                        "kv_adopt / kv_import", "they seed keys and values cut to a position; the state is not shipped yet"
+                        "kv_adopt / kv_import", "they seed keys and values cut to a position; the state is not shipped yet",
+                        paged=lane is not None and batcher.grouped,
                     )
                 if "kv_adopt" in step:
                     # seed from KV already on this server (migrated or parked)

@@ -38,6 +38,13 @@ LATENT_ROWS_RIDE = (
     "cache is laid out for keys and values a head, which such a span never makes"
 )
 
+# why a path that ships, stores, cuts back or adopts a lane's pages refuses a paged lane pool whose windowed layers give
+# pages back (``SpanCache.refuse(..., paged=True)``)
+GROUPS_RIDE = (
+    "only the paged lane pool's decode, generation and mixed steps carry pages by kind of layer; a windowed layer's "
+    "pages behind its window have gone back to the pool, so a lane's cache is no longer whole keys and values a layer"
+)
+
 
 # one kind of thing a lane holds for a block beside or in place of pages of keys and values, as a refusal speaks of it: the
 # ``ModelFamily`` accessor that declares it a kind of block; the thing, with its article; a span that holds it; (SpanCache) ->
@@ -158,7 +165,25 @@ class SpanCache:
         self.latent_row = tuple(int(width) for width in found["latent"][0]) if "latent" in found else None
         # how many cache rows a position a block keeps (ModelFamily.block_sublayers): the page pools hold ``page_layers`` layers
         # of pages, a block's one after the other, and everything that multiplies by layers of pages multiplies by that
-        self.block_rows, self.page_layers = block_rows, len(self.kv_layers) * block_rows
+        self.block_rows = block_rows
+        # PAGE GROUPS: the blocks that keep keys and values, grouped by static window (None: full attention), as ((window,
+        # (block, ...)), ...), the full group first. A paged lane pool keeps a pool, an allocator and lane tables a group, and
+        # a windowed group gives a lane's pages back as its window moves past them (server/batching.py). More than one
+        # group only for a family that declares its layers' windows, over plain pages of keys and values one row a block:
+        # any other span has ONE group, and allocates, counts and compiles what it did before groups. group_slots[i]:
+        # block i's (group, layer in that group's pool), None for a block without pages
+        by_kind = bool(self.layer_windows) and not found and kv_quant_type == "none" and block_rows == 1
+        by_window = collections.defaultdict(list)
+        for i in self.kv_layers:
+            by_window[self.layer_windows[i] if by_kind else None].append(i)
+        if len(by_window) == 1:
+            by_window = {None: list(self.kv_layers)}  # one kind of layer: one pool under one table, every page kept
+        order = sorted(by_window, key=lambda w: (w is not None, -(w or 0)))
+        self.page_groups = tuple((w, tuple(by_window[w])) for w in order)
+        self.group_slots = tuple(
+            next(((g, blocks.index(i)) for g, (_, blocks) in enumerate(self.page_groups) if i in blocks), None) for i in range(len(kinds))
+        )
+        self.page_layers = len(self.kv_layers) * block_rows
 
     def __setattr__(self, name, value):
         if "page_layers" in self.__dict__:  # the last field ``__init__`` sets
@@ -178,20 +203,37 @@ class SpanCache:
         """The span has content that only the paged lane pool carries: it has no private cache and no dense pool."""
         return self.content is not None
 
-    def refuse(self, what: str, why: str) -> None:
+    @property
+    def grouped(self) -> bool:
+        """The span's layers keep pages in more than one group (``page_groups``): a paged lane pool gives the windowed
+        groups' pages back, and what needs a lane's whole cache is refused there (``refuse(..., paged=True)``)."""
+        return len(self.page_groups) > 1
+
+    def refuse(self, what: str, why: str, *, paged: bool = False) -> None:
         """Raise for ``what`` if the span holds more than keys and values: what cuts a cache back to an earlier position and
         the cache paths that carry keys and values alone are refused by what the family declares, a state with the caller's
-        ``why``, a row with its own reason (``CONTENTS``)."""
+        ``why``, a row with its own reason (``CONTENTS``). ``paged``: ``what`` is asked of a PAGED lane pool, which for a
+        span of more than one page group has given pages back: what ships, stores, cuts back or adopts a lane's pages is
+        refused there (``GROUPS_RIDE``); the same span's private caches and dense pool keep every position and serve it."""
         if self.content is not None:
             content = CONTENTS[self.content]
             raise _refusal(self.family, what, f"{content.span} ({content.detail(self)})", content.rides or why)
+        if paged and self.grouped:
+            raise _refusal(self.family, what, f"a span whose layers keep pages in groups by window ({self._groups_detail()})", GROUPS_RIDE)
 
-    def prefix_cache_refusal(self) -> Optional[str]:
+    def _groups_detail(self) -> str:
+        return ", ".join(f"{len(blocks)} {'full' if window is None else f'of window {window}'}" for window, blocks in self.page_groups)
+
+    def prefix_cache_refusal(self, paged: bool = False) -> Optional[str]:
         """None, or why this span stores no prefix, as a sentence for the log. A hit seeds a session's cache cut to the
         prefix's end, and a state cannot be cut back; a stored prefix is keys and values a head (a snapshot, or pinned pages a
         hit adopts and forks through paths laid out for them), and carries neither the index rows a span with a learned sparse
-        attention caches beside them nor a latent row in their place."""
+        attention caches beside them nor a latent row in their place. ``paged``: the server keeps a paged lane pool, where a
+        span of more than one page group has no whole prefix to store: a windowed layer's is gone once the window passed it."""
         if self.content is None:
+            if paged and self.grouped:
+                return (f"Prefix cache off for a span whose layers keep pages in groups by window ({self._groups_detail()}): "
+                        f"a stored prefix of a windowed layer is gone once the window passed it")
             return None
         content = CONTENTS[self.content]
         cannot = "which cannot be cut back to a stored prefix" if self.content == "state" else "which a stored prefix does not carry"
@@ -207,9 +249,29 @@ class SpanCache:
             return (sum(self.latent_row),)
         return paged_attention.stored_row(self.kv_heads, self.head_dim // 2 if self.kv_quant_type == "nf4a" else self.head_dim)
 
-    def pool_descriptors(self, n_pages: int, page_size: int, n_lanes: int, start: int, end: int) -> tuple:
+    def group_pages(self, n_lanes: int, max_pages: int, page_size: int, chunk: int, n_pages: Optional[int] = None) -> tuple:
+        """Pages a group for a paged lane pool of these shapes, ``n_pages`` the full group's (None: a lane's whole table,
+        every lane). A windowed group gets as many lanes' worth: a lane there holds at most the pages its window reaches
+        and those of a prompt's chunk of up to ``chunk`` positions being fed (``lane_pages``), never less than one lane's."""
+        full = n_lanes * max_pages if n_pages is None else int(n_pages)
+        a_lane = [self.lane_pages(window, max_pages, page_size, chunk) for window, _ in self.page_groups]
+        return tuple(full if window is None else max(-(-full * pages // max_pages), pages) for (window, _), pages in zip(self.page_groups, a_lane))
+
+    @staticmethod
+    def lane_pages(window: Optional[int], max_pages: int, page_size: int, chunk: int) -> int:
+        """The most pages a lane holds in a group of ``window`` when a step starts: its whole table in a full group; in a
+        windowed one the pages the first row's window and the last row of a chunk of ``chunk`` positions reach between
+        them (``ceil(window / page) + 1`` pages and one chunk: ops/paged_flash_attention.py ``window_pages``)."""
+        return pfa.window_pages(window, max(int(chunk), 1), page_size, max_pages)
+
+    def pool_descriptors(self, n_pages, page_size: int, n_lanes: int, start: int, end: int) -> tuple:
         """Descriptors of everything a PAGED lane pool of blocks [start, end) allocates, in the order the step programs take
         it: the page pools, then the state pool's leaves, then the index pool.
+
+        ``n_pages`` a TUPLE, one number a page group (``group_pages``): a pair (k, v) a group, in the groups' order, each as
+        deep as the group's blocks in [start, end); the step programs are then handed the first pair as their pools, the
+        others where a state pool would ride, and block tables a group (server/backend.py ``_scan_paged_span``). A number:
+        one pool for every block that keeps keys and values, under one table a lane, as before groups.
 
         The page pools, unquantized: (k, v), each [n, n_pages, page_size, hkv, d] in cache_dtype. Quantized (kv_quant_type !=
         none): (k_codes, v_codes, k_scales, v_scales) — the codes in the storage dtype (int8, or uint8 with two
@@ -224,6 +286,12 @@ class SpanCache:
         layers, n_pages, *row]``: a page of it is a page of theirs, under the same block tables, its positions' rows of
         ``width`` stored as ops/sparse_attention.py ``index_pool_row`` says (a row under the chip's 128 lanes: several
         positions to a row of 128); none for a span without an index row."""
+        if isinstance(n_pages, (tuple, list)):
+            assert len(n_pages) == len(self.page_groups) and self.content is None, (n_pages, self.page_groups, self.content)
+            return tuple(
+                TensorDescriptor((sum(start <= i < end for i in blocks), int(pages), page_size, *self.pool_row), self.cache_dtype)
+                for pages, (_, blocks) in zip(n_pages, self.page_groups) for _ in range(2)
+            )
         n = sum(start <= i < end for i in self.kv_layers) * self.block_rows
         if self.latent_row is not None:
             # a latent row in place of keys and values: the latents a position a row, and the rotated keys stored
@@ -278,11 +346,15 @@ class SpanCache:
         (quantized pool pages cost wire bytes on device too, packed codes + f32 scales, so a budget affords ~4x the lanes),
         and the lane's fixed part, its states."""
         per_token = self.cache_bytes_per_token() if self.kv_quant_type == "none" else self.kv_bytes_per_token()
+        if self.grouped:  # a windowed group's layers hold what their window reaches (a chunk being fed besides: ``lane_pages``)
+            a_layer = per_token // len(self.kv_layers)
+            return sum(len(blocks) * a_layer * min(max_length, window or max_length) for window, blocks in self.page_groups)
         return per_token * max_length + self.state_bytes_per_lane()
 
-    def lane_pool(self, n_lanes: int, max_pages: int, page_size: int) -> "LanePool":
-        """The counters of a paged lane pool of these shapes over this span."""
-        return LanePool(self, n_lanes, max_pages, page_size)
+    def lane_pool(self, n_lanes: int, max_pages: int, page_size: int, grouped: bool = False) -> "LanePool":
+        """The counters of a paged lane pool of these shapes over this span; ``grouped``: one that keeps a pool and lane
+        tables a page group (``page_groups``) and gives the windowed groups' pages back."""
+        return LanePool(self, n_lanes, max_pages, page_size, grouped)
 
 
 # the keys a content opens in the batcher's ``stats`` (``LanePool.new_stats``), every one from the shapes a step is started
@@ -311,10 +383,14 @@ class LanePool:
     started with: the batcher's per-layer counters (``new_stats`` opens them, ``count_step`` adds a step's). What is fixed with
     the pool's geometry is asked once, here (``page_bytes``, ``state_bytes``, ``walks``, ``state_step``, ``selects``)."""
 
-    def __init__(self, cache: SpanCache, n_lanes: int, max_pages: int, page_size: int):
+    def __init__(self, cache: SpanCache, n_lanes: int, max_pages: int, page_size: int, grouped: bool = False):
         from petals_tpu.server.backend import bucket_length  # how a step program pads a prompt's chunk
 
         self.cache, self.n_lanes, self.max_pages, self.page_size = cache, n_lanes, max_pages, page_size
+        # a pool that keeps pages by group (``SpanCache.page_groups``): the bytes of one page of each group's pool, all its layers
+        self.grouped = bool(grouped) and cache.grouped
+        a_layer_page = 2 * cache.kv_heads * cache.head_dim * cache.cache_dtype.itemsize * page_size
+        self.group_page_bytes = tuple(len(blocks) * a_layer_page for _, blocks in cache.page_groups)
         self._bucket = bucket_length
         self.max_length = max_pages * page_size
         # WIRE bytes per page: quantized pools swap/reserve packed bytes, so the host-swap budget, ledger swap meters, and
@@ -344,6 +420,7 @@ class LanePool:
         # the batcher's occupancy_info, read back through ``window_pages``)
         self.windows = [w for w in (cache.layer_windows or ()) if w]
         self.lane_pos = np.zeros(n_lanes, np.int64)
+        self.lane_first = np.zeros(n_lanes, np.int64)  # the first position of the rows it fed then (a chunk's; a decode row's own)
 
     def new_stats(self) -> dict:
         """The counters this span's content opens, zeroed: the keys of the batcher's ``stats`` that ``count_step`` adds to.
@@ -352,15 +429,20 @@ class LanePool:
         ``walks`` says which). For a family that declares its layers' windows only: of the pages the decoding lanes hold in
         windowed layers those their windows still reach (summed over steps). And the content's own (``_CONTENT_KEYS``)."""
         keys = ("attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", *(("window_pages_held", "window_pages_in_reach") if self.windows else ()))
-        return dict.fromkeys((*keys, *_CONTENT_KEYS[self.cache.content]), 0)
+        # a pool that keeps pages by group: pages its windowed groups gave back (the batcher adds them where it frees them),
+        # and, summed step by step over the lanes that fed rows, the bytes their pages hold against what they would hold
+        # with every page kept, and the (row, cached position) pairs the step's rows score, a layer
+        grouped = ("window_pages_released", "kv_bytes_held", "kv_bytes_unfreed", "attn_score_pairs") if self.grouped else ()
+        return dict.fromkeys((*keys, *grouped, *_CONTENT_KEYS[self.cache.content]), 0)
 
-    def count_step(self, stats: dict, positions, lane_held, *, seq: int = 1, chunk=None) -> None:
+    def count_step(self, stats: dict, positions, lane_held, *, seq: int = 1, chunk=None, group_held=None) -> None:
         """Add one paged step's counters to ``stats`` (the batcher's, on its compute thread), every one from the step's shapes
         and the positions it was started with: ``lanes``, the lanes that fed a row, is reckoned once, and the pages a lane
         holds are ``lane_held``'s, kept where the tables are written, so that nothing here walks the tables. ``seq`` is a
-        verify's rows a lane, ``chunk`` the (lane, first position, tokens) of a mixed step's prompt chunk."""
+        verify's rows a lane, ``chunk`` the (lane, first position, tokens) of a mixed step's prompt chunk, ``group_held``
+        a grouped pool's pages a lane a group (``lane_held`` is then its first group's)."""
         lanes = np.flatnonzero(positions < self.max_length)  # the idle sentinel is max_length
-        self._attention_reads(stats, positions, lanes, lane_held, seq, chunk)
+        self._attention_reads(stats, positions, lanes, lane_held, seq, chunk, group_held)
         content = self.cache.content
         if content is None:
             return
@@ -373,9 +455,20 @@ class LanePool:
         else:
             self._latent_reads(stats, positions[lanes], pages, chunk)
 
-    def window_pages(self, lanes, lane_held) -> Tuple[int, int]:
+    def window_pages(self, lanes, lane_held, group_held=None) -> Tuple[int, int]:
         """(held, in reach): the pages ``lanes`` hold, once a windowed layer of the span, and those of them a layer's window
-        still reaches from the last position the lane fed. The rest are held until the session ends (freeing them is ROADMAP B3)."""
+        still reaches from the last position the lane fed. In a pool of one group the rest are held until the session ends;
+        a grouped pool (``group_held``: its pages a lane, a group) holds in a windowed group what the rows a lane last fed
+        reach between them, a chunk's first row's window to its last row, and has given the rest back."""
+        if group_held is not None:
+            first, pos, total, reach = self.lane_first[lanes], self.lane_pos[lanes], 0, 0
+            for (window, blocks), held in zip(self.cache.page_groups, group_held):
+                if window is None:
+                    continue
+                pages = pos // self.page_size - np.maximum(first - window + 1, 0) // self.page_size + 1
+                total += int(held[lanes].sum()) * len(blocks)
+                reach += int(np.minimum(pages, held[lanes]).sum()) * len(blocks)
+            return total, reach
         held, pos, reach = lane_held[lanes], self.lane_pos[lanes], 0
         for window in self.windows:
             pages = pos // self.page_size - np.maximum(pos - window + 1, 0) // self.page_size + 1
@@ -384,7 +477,7 @@ class LanePool:
 
     # ------------------------------------------------------------- the attention over pages
 
-    def _attention_reads(self, stats, positions, lanes, lane_held, seq, chunk) -> None:
+    def _attention_reads(self, stats, positions, lanes, lane_held, seq, chunk, group_held=None) -> None:
         """The attention counters of one paged step, from the positions the step was started with: of the table slots its
         programs are handed (every lane of the pool's, a layer that keeps keys and values), those they read. A decode row's
         walk reads whole blocks up to the longest live lane's last page, for every lane; a verify's ``seq`` rows and the
@@ -414,12 +507,24 @@ class LanePool:
         if not self.windows:
             return
         last = last.astype(np.int64)
+        began = positions[lanes].astype(np.int64)
         if chunk is not None:
-            lanes, last = np.append(lanes, lane), np.append(last, first + take - 1)
-        self.lane_pos[lanes] = last
-        held, reach = self.window_pages(lanes, lane_held)
+            lanes, last, began = np.append(lanes, lane), np.append(last, first + take - 1), np.append(began, first)
+        self.lane_pos[lanes], self.lane_first[lanes] = last, began
+        held, reach = self.window_pages(lanes, lane_held, group_held)
         stats["window_pages_held"] += held
         stats["window_pages_in_reach"] += reach
+        if group_held is not None:
+            # the pages up to each lane's last row, in every group; a row at position p scores min(p + 1, window) cached
+            # positions in a layer: a decode row its own, a chunk's rows theirs
+            unfreed = int((last // self.page_size + 1).sum())
+            rows = began[: began.size - (chunk is not None)] + 1
+            of_chunk = () if chunk is None else np.arange(first, first + take, dtype=np.int64) + 1
+            for (window, blocks), nbytes, held in zip(self.cache.page_groups, self.group_page_bytes, group_held):
+                stats["kv_bytes_held"] += int(held[lanes].sum()) * nbytes
+                stats["kv_bytes_unfreed"] += unfreed * nbytes
+                cap = window or self.max_length
+                stats["attn_score_pairs"] += (int(np.minimum(rows, cap).sum()) + int(np.minimum(of_chunk, cap).sum())) * len(blocks)
 
     def _pages_gathered(self, q_len: int) -> int:
         """Table slots one lane's ``q_len`` rows gather over the span's layers where a paged step program makes the dense view

@@ -369,7 +369,7 @@ def test_paged_prefill_then_decode_matches_the_reference_s_full_pass(swarm):
     layer's decode gathers 3 of a lane's 12 table slots, a full one all 12."""
     path, tensors, harness, model = swarm
     batchers = [server.handler.batcher for server in harness.servers]
-    assert all(b is not None and b.page_size == 4 and b.max_pages == 12 for b in batchers)
+    assert all(b is not None and b.page_size == 4 and b.max_pages == 12 for b in batchers) and [b.grouped for b in batchers] == [False, True]
     before = [dict(b.stats) for b in batchers]
     ids = np.random.RandomState(3).randint(0, 128, (1, SEQ)).astype(np.int64)
     hidden = np.asarray(model.embed(ids))
@@ -390,8 +390,12 @@ def test_paged_prefill_then_decode_matches_the_reference_s_full_pass(swarm):
         assert backend_reach(batcher) == decode_gathered
         assert delta["attn_pages_tabled"] == (lanes * delta["batched_steps"] + delta["mixed_steps"]) * 12 * len(windows)
         assert pure * decode_gathered < delta["attn_pages_gathered"] < delta["attn_pages_tabled"]
-        # the decoding lane held 6-10 pages a windowed layer, of which its window reached 2 or 3
-        assert 0 < delta["window_pages_in_reach"] < delta["window_pages_held"]
+        if batcher.grouped:
+            # windowed and full layers in one span: pages by kind of layer, and a windowed layer's go back as its window
+            # moves, so a lane holds there, when a step starts, exactly the 2 or 3 pages its window reaches
+            assert 0 < delta["window_pages_in_reach"] == delta["window_pages_held"] and delta["window_pages_released"] >= 6
+        else:  # one kind of layer: one pool under one table, the decoding lane held 6-10 pages a windowed layer, its window reached 2 or 3
+            assert 0 < delta["window_pages_in_reach"] < delta["window_pages_held"] and "window_pages_released" not in delta
         info = batcher.occupancy_info()
         assert info["window_pages_held"] == 0 == info["window_pages_in_reach"]  # the session is closed
 

@@ -1448,3 +1448,79 @@ def test_a_stream_of_four_rows_crosses_every_layer_flat_and_no_wrap_relays_it(v5
     relayouts = [r for r in relayouts if tuple(json.loads(r.split(" -> ")[1])) not in known]
     assert seen and not relayouts, f"the step's loop relays a weight in every layer of every step: {relayouts}"
     assert not entry_weight_moves(hlo, stacked, smallest)
+
+
+# ---------------------------------------------------------------------------
+# pages by kind of layer: a pair of pools a page group, tables a group (server/span_cache.py ``page_groups``)
+
+
+def _compiled_grouped_step(v5e, tmp_path, config_name, chunk, lanes, pages_a_lane, budget):
+    """``(optimized HLO, the groups' pool avals, (hkv, d))`` of the paged decode step, or of the mixed step with a chunk of
+    ``chunk`` positions, as a GROUPED lane pool hands it over: a pair of pools a page group (the first as the step's own,
+    the others where a state pool rides), tables ``[groups, lanes, pages_a_lane]``, every pool donated; the groups' pages
+    as ``SpanCache.group_pages`` sizes them for ``lanes`` lanes and a prefill budget of ``budget``."""
+    from perf.config import load as load_config
+    from petals_tpu.server.backend import TransformerBackend
+    from petals_tpu.server.from_pretrained import get_block_config
+
+    config_file = Path(__file__).resolve().parents[1] / "perf" / "configs" / f"{config_name}.json"
+    (tmp_path / "config.json").write_text(json.dumps(load_config(config_file, config_name)["config"]))
+    family, cfg = get_block_config(str(tmp_path))
+    depth, page_size = cfg.num_hidden_layers, 64
+    runs = tuple(
+        {name: v5e((length, *leaf.shape), leaf.dtype) for name, leaf in family.param_shapes_for(cfg, kind, BF16).items()}
+        for kind, _, length in span_runs(family.span_kinds(cfg, 0, depth))
+    )
+    backend = TransformerBackend(family, cfg, runs, first_block=0, n_blocks=depth, memory_cache=None)
+    cache = backend.cache
+    assert cache.grouped, cache.page_groups
+    n_pages = cache.group_pages(lanes, pages_a_lane, page_size, budget)
+    pools = tuple(v5e(d.shape, BF16) for d in cache.pool_descriptors(n_pages, page_size, lanes, 0, depth))
+    avals = [runs, pools[0], pools[1], v5e((lanes, backend.hidden_size + 1), I32), v5e((len(n_pages), lanes, pages_a_lane), I32)]
+    step = backend._paged_decode_fn
+    if chunk:
+        step = backend._paged_mixed_step_fn
+        avals += [v5e((1, chunk, backend.hidden_size), BF16)] + [v5e((), I32)] * 4
+    avals.append(pools[2:])
+    step = functools.partial(step.__wrapped__, with_fp=False)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
+        patch.setattr(pfa, "_on_tpu", lambda: True)
+        patch.setattr(pfa, "_platform", lambda: "tpu")  # a chunk takes the prefill kernel, as on the chip
+        patch.setattr(pfa, "_interpret", lambda: False)
+        hlo = jax.jit(step, donate_argnums=(1, 2, len(avals) - 1)).lower(*avals).compile().as_text()
+    return hlo, pools, (backend.num_kv_heads, backend.head_dim)
+
+
+@pytest.mark.parametrize("config_name,chunk,lanes,pages_a_lane,budget", [
+    pytest.param("smallthinker-21b-a3b-span12", 0, 16, 256, 2048, id="smallthinker-decode"),
+    pytest.param("smallthinker-21b-a3b-span12", 2048, 16, 256, 2048, id="smallthinker-mixed-2048"),
+    pytest.param("k-exaone-236b-span5-ep8", 0, 8, 16, 512, id="k-exaone-decode"),
+    pytest.param("k-exaone-236b-span5-ep8", 256, 8, 16, 512, id="k-exaone-mixed-256", marks=pytest.mark.xfail(strict=True, reason=(
+        "the full group's pool is ONE layer of 128 pages, 16.8 MB a side: under a kernel call (the chunk's prefill kernel) in a "
+        "layer loop, a pool that fits the chip's fast memory is staged through it whole (copy-start / copy-done of "
+        "bf16[1,128,64,8,128] into S(1)), the hazard PERF.md section 7 'Left by PR 53' (1) names; a mixed step of this cell, one "
+        "in ~250 steps, pays ~40 us for it (PERF.md section 7, Left by PR 64)"
+    ))),
+])
+def test_a_grouped_step_leaves_every_group_s_pool_in_place(v5e, tmp_path, config_name, chunk, lanes, pages_a_lane, budget):
+    """Two pools where there was one is where a whole-pool copy or relay would appear: the compiled step of a span whose
+    layers keep pages by kind (SmallThinker's three full and nine windowed layers at the cell's 16 lanes of 256 slots;
+    K-EXAONE's one full and four windowed) allocates no second pool of either group, copies none in ``ENTRY``, and no
+    layer of either is sliced out, copied or written back whole in a run's loop; and every loop carries both."""
+    hlo, pools, heads = _compiled_grouped_step(v5e, tmp_path, config_name, chunk, lanes, pages_a_lane, budget)
+    assert len(pools) == 4 and pools[0].shape[1] == lanes * pages_a_lane and pools[2].shape[1] < pools[0].shape[1]
+    assert pools[0].shape[0] + pools[2].shape[0] == {"smallthinker-21b-a3b-span12": 12, "k-exaone-236b-span5-ep8": 5}[config_name]
+    for pool in (pools[0], pools[2]):
+        moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
+        assert loops_seen, f"no loop carries the pool {pool.shape}: has the HLO text changed, or the pool left the carry?"
+        # a chunk's prefill kernel is handed its block's OWN layer of its group's pool (``PagedKV.own_layer``: the kernel
+        # relays what it is handed), as it is of a pool of one group: a slice of ONE layer, keys and values, in the run's
+        # loop, and nothing else; a decode step moves nothing at all
+        a_layer = f"constant_dynamic-slice_fusion -> {list(pool.shape[1:])}" if chunk else None
+        others = [move for move in moves if a_layer is None or not (move.split(" = ")[1].replace("fusion", "constant_dynamic-slice_fusion") == a_layer and "dynamic-slice" in move)]
+        assert not others, f"the step moves the page pool {pool.shape} around: {others}"
+    sizes = {"bf16[" + ",".join(map(str, pool.shape)) + "]" for pool in (pools[0], pools[2])}
+    whole = [line.strip()[:200] for line in hlo.splitlines() if re.search(r"= \(?(bf16\[[\d,]+\])\S* copy-(start|done)\(", line)
+             and re.search(r"= \(?(bf16\[[\d,]+\])", line).group(1) in sizes]
+    assert not whole, whole

@@ -32,6 +32,7 @@ from tests.utils import (
     make_tiny_phi3,
     make_tiny_qwen2,
     make_tiny_qwen3_next,
+    make_tiny_smallthinker,
     make_tiny_xing4_0,
 )
 
@@ -45,6 +46,7 @@ MAKERS = {
     "gemma2": make_tiny_gemma2, "exaone_moe": make_tiny_exaone_moe, "olmo_hybrid": make_tiny_olmo_hybrid,
     "KeyeVL2": make_tiny_keye_vl2, "deepseek_v3": make_tiny_deepseek_v3, "qwen3_next": make_tiny_qwen3_next,
     "jamba": make_tiny_jamba, "longcat_flash": make_tiny_longcat_flash, "xing4_0": make_tiny_xing4_0,
+    "smallthinker": make_tiny_smallthinker,
 }
 LLAMA_ALIASES = ("mistral", "qwen2", "phi3", "gemma")  # dataclasses.replace over llama
 

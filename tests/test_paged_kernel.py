@@ -340,7 +340,7 @@ def _tiny_families() -> dict:
         "exaone_moe": utils.make_tiny_exaone_moe, "olmo_hybrid": utils.make_tiny_olmo_hybrid, "KeyeVL2": utils.make_tiny_keye_vl2,
         "KeyeVL2-table-of-one-page": utils.make_tiny_keye_vl2,  # 16 positions, as many as a row chooses: the plain call
         "deepseek_v3": utils.make_tiny_deepseek_v3, "qwen3_next": utils.make_tiny_qwen3_next, "jamba": utils.make_tiny_jamba,
-        "longcat_flash": utils.make_tiny_longcat_flash, "xing4_0": utils.make_tiny_xing4_0,
+        "longcat_flash": utils.make_tiny_longcat_flash, "xing4_0": utils.make_tiny_xing4_0, "smallthinker": utils.make_tiny_smallthinker,
     }
 
 

@@ -1172,6 +1172,58 @@ def make_tiny_xing4_0(tmpdir: str, **overrides) -> str:
     return path
 
 
+TINY_SMALLTHINKER = {  # the keys SmallThinker publishes, at a toy size: two periods of G L L L, a window of 8
+    "model_type": "smallthinker", "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "moe_ffn_hidden_size": 32, "moe_num_primary_experts": 8, "moe_num_active_primary_experts": 3,
+    "moe_primary_router_apply_softmax": True, "norm_topk_prob": True, "num_hidden_layers": 8,
+    "rope_layout": [0, 1, 1, 1] * 2, "sliding_window_layout": [0, 1, 1, 1] * 2, "sliding_window_size": 8,
+    "rope_scaling": None, "rope_theta": 1500000, "rms_norm_eps": 1e-6, "max_position_embeddings": 256,
+    "tie_word_embeddings": False, "vocab_size": 128,
+}
+
+
+def tiny_smallthinker_tensors(config: dict, seed: int = 41) -> dict:
+    """Seeded float32 tensors under the HF names of every layer of ``config``, the embedding, the final norm and the
+    head. Norm vectors are drawn, not ones, so a missing or misplaced one shows; the router's weights are large
+    enough that its choice is no tie."""
+    rng = np.random.RandomState(seed)
+    h, hq, hkv, d = (config[k] for k in ("hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim"))
+    m, n_experts = config["moe_ffn_hidden_size"], config["moe_num_primary_experts"]
+    normal = lambda *shape: (rng.standard_normal(shape) * 0.1).astype(np.float32)
+    norm = lambda n: rng.uniform(0.5, 1.5, n).astype(np.float32)
+    tensors = {"model.embed_tokens.weight": normal(config["vocab_size"], h), "model.norm.weight": norm(h),
+               "lm_head.weight": normal(config["vocab_size"], h)}
+    for i in range(config["num_hidden_layers"]):
+        p = f"model.layers.{i}."
+        tensors.update({
+            p + "input_layernorm.weight": norm(h), p + "post_attention_layernorm.weight": norm(h),
+            p + "self_attn.q_proj.weight": normal(hq * d, h), p + "self_attn.k_proj.weight": normal(hkv * d, h),
+            p + "self_attn.v_proj.weight": normal(hkv * d, h), p + "self_attn.o_proj.weight": normal(h, hq * d),
+            p + "block_sparse_moe.primary_router.weight": normal(n_experts, h) * 5,
+        })
+        for e in range(n_experts):
+            q = p + f"block_sparse_moe.experts.{e}."
+            tensors.update({q + "gate.weight": normal(m, h), q + "up.weight": normal(m, h), q + "down.weight": normal(h, m)})
+    return tensors
+
+
+@_model_build_cache
+def make_tiny_smallthinker(tmpdir: str, **overrides) -> str:
+    """A SmallThinker checkpoint at a toy size, written by hand (the installed transformers has no class for
+    ``smallthinker``)."""
+    import json
+
+    from safetensors.numpy import save_file
+
+    config = {**TINY_SMALLTHINKER, **overrides}
+    path = os.path.join(tmpdir, "tiny-smallthinker" + "".join(f"-{k}-{v}" for k, v in sorted(overrides.items())))
+    os.makedirs(path, exist_ok=True)
+    with open(os.path.join(path, "config.json"), "w") as f:
+        json.dump(config, f)
+    save_file(tiny_smallthinker_tensors(config), os.path.join(path, "model.safetensors"))
+    return path
+
+
 # ---------------------------------------------------------------------------
 # what a lane holds for a span's blocks (server/span_cache.py), as the tests ask a backend for it
 
