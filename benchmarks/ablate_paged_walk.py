@@ -2,16 +2,20 @@
 composed walk in blocks of table slots (ops/paged_flash_attention.py
 ``composed_paged_attend``) at every block width, the walk as ONE kernel that
 reads each lane's own pages where they lie (``path="kernel"``, PR 45; a folded
-row of fewer than 4 kv heads since PR 53) at 1 to 32 pages a block (up to 2 MB
-a pool), and the path both replaced (gather every slot of every
+row of fewer than 4 kv heads since PR 53, of 4 since PR 65) at 1 to 32 pages a
+block (up to 2 MB a pool), and the path both replaced (gather every slot of every
 lane, ``attend_reference`` over the dense view), one layer's call at the
 cells' pool geometries and at lengths their traffic gives the lanes.
 
-    chiprun -- python3 benchmarks/ablate_paged_walk.py [shape ...] [--stages dense,walk,kernel]
+    chiprun -- python3 benchmarks/ablate_paged_walk.py [shape ...] [--stages dense,walk,kernel] [--fold]
 
 What ``WALK_BLOCK_BYTES`` (PERF.md section 5, PR 36) and
 ``WALK_KERNEL_BLOCK_BYTES`` (PR 45) were set from. ``--stages`` keeps the
-variants it names (``walk`` every width, ``walk1`` that one). A call is
+variants it names (``walk`` every width, ``walk1`` that one). ``--fold`` hands
+the pools over folded (``[.., hkv * d]``) whatever the storage rule says of
+their row: what the kernel would make of a pool no server stores that way
+(Mixtral's and K-EXAONE's 8 x 128 as rows of 1,024); the rows it prints are
+named ``<shape>+folded``. A call is
 timed as the slope between chains of 2 and 10 calls in one program, each link
 fed the last one's output and its tables made to wait for it, so that XLA can
 neither drop a link nor gather once for all of them; the pools ride as jit
@@ -50,7 +54,7 @@ LINKS = (2, 10)
 FEED = 2.0 ** -10
 
 
-def main(names, stages=("dense", "walk", "kernel")) -> None:
+def main(names, stages=("dense", "walk", "kernel"), fold: bool = False) -> None:
     from petals_tpu.utils.compile_cache import enable_compilation_cache
 
     enable_compilation_cache()
@@ -119,7 +123,8 @@ def main(names, stages=("dense", "walk", "kernel")) -> None:
         q = jax.random.normal(kq, (n_lanes, 1, hkv * group, d), jnp.bfloat16)
         kp = jax.random.normal(kk, (n_pages, page_size, hkv, d), jnp.bfloat16)
         vp = jax.random.normal(kv, (n_pages, page_size, hkv, d), jnp.bfloat16)
-        row = pa.stored_row(hkv, d)  # the pools in the form a server stores them in (folded at a head_dim of 64)
+        # the pools in the form a server stores them in (folded at a head_dim of 64, and up to 4 kv heads), or folded as asked
+        row = (hkv * d,) if fold else pa.stored_row(hkv, d)
         unfolded = (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos))
         args = (q, pa.fold_rows(kp, row), pa.fold_rows(vp, row), *unfolded[3:])
         a_slot = n_lanes * page_size * hkv * d * 2
@@ -147,7 +152,7 @@ def main(names, stages=("dense", "walk", "kernel")) -> None:
             got = np.asarray(jax.jit(lambda *a: kernel(*a))(*args), np.float32)[~idle]
             rows.append((f"kernel{block}", float(np.max(np.abs(got - want))) if got.size else 0.0, timed(kernel, *args)))
         for variant, err, ms in rows:
-            line = {"shape": name, "variant": variant, "ms": round(ms, 4), "max_err": err, "live_mb": round(live_mb, 1),
+            line = {"shape": name + "+folded" * fold, "variant": variant, "ms": round(ms, 4), "max_err": err, "live_mb": round(live_mb, 1),
                     "floor_ms": round(live_mb / 819e3 * 1e3, 4), "longest_slots": int(held.max()), "device": jax.devices()[0].device_kind}
             print(json.dumps(line), flush=True)
             with open(sink_path, "a") as sink:
@@ -160,5 +165,6 @@ if __name__ == "__main__":
     parser = argparse.ArgumentParser()
     parser.add_argument("shapes", nargs="*", default=list(SHAPES))
     parser.add_argument("--stages", default="dense,walk,kernel")
+    parser.add_argument("--fold", action="store_true")
     cli = parser.parse_args()
-    main(cli.shapes, tuple(cli.stages.split(",")))
+    main(cli.shapes, tuple(cli.stages.split(",")), cli.fold)

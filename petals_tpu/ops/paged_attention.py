@@ -8,7 +8,8 @@ Layout contract (per span block):
   shared slab in HBM, budgeted through MemoryCache like the dense lane pool.
   STORED in the layout its step computes in (``stored_row``, the one rule):
   a row under the chip's 128 lanes (head_dim 64; int8 codes of it; nf4a's
-  packed bytes of a head_dim up to 128) keeps the kv heads folded into it,
+  packed bytes of a head_dim up to 128), or of up to 4 kv heads where the
+  span's decode rows walk its pages, keeps the kv heads folded into it,
   [n_pages, page_size, kv_heads * d_store] — the same bytes in the same
   order. Handed over as rows of [kv_heads, 64] such a pool lives on the
   device with the PAGE index minor, and every step program relaid both
@@ -19,10 +20,10 @@ Layout contract (per span block):
   (``_flat_scatter``, ``scatter_lane_pages``), ``gather_pages`` unfolds the
   pages it took (never the pool), ``pool_geometry`` answers (n_pages,
   page_size, hkv, d_store) for the kernels and the dispatch. A pool of
-  head_dim 128 keeps its shape and its programs. Off the device everything
-  keeps rows of [kv_heads, d]: swap entries, snapshots and imports, the
-  wire (server/backend.py ``pool_to_wire`` / ``wire_to_pool``: a reshape of
-  the host's copy).
+  8 kv heads of 128 and more keeps its shape and its programs. Off the
+  device everything keeps rows of [kv_heads, d]: swap entries, snapshots
+  and imports, the wire (server/backend.py ``pool_to_wire`` /
+  ``wire_to_pool``: a reshape of the host's copy).
 - block table    [n_lanes, max_pages] int32 — page index per (lane, slot);
   ``-1`` marks an unallocated slot. ``max_pages * page_size == max_length``
   (the batcher rounds max_length up to a page multiple).
@@ -93,10 +94,10 @@ KV_QUANT_KINDS = ("none", "int8", "nf4a")
 
 #: a row of fewer elements than a vector register's 128 lanes is stored folded
 LANES = 128
-MIN_ROW_HEADS = 4  # a pool row of fewer kv heads is stored folded (``stored_row``)
+FOLDED_ROW_HEADS = 4  # a pool row of up to this many kv heads is stored folded where its pages are walked (``stored_row``)
 
 
-def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
+def stored_row(hkv: int, d_store: int, row_fetch: bool = False) -> Tuple[int, ...]:
     """THE storage rule of a page pool: the trailing dims a pool leaf keeps a
     token row in, ``[..., n_pages, page_size, *stored_row]``, from the leaf's
     own ``[hkv, d_store]`` (``d_store``: the head dim of values and int8
@@ -110,19 +111,30 @@ def stored_row(hkv: int, d_store: int) -> Tuple[int, ...]:
     ``(hkv * d_store,)``: the same bytes in the same order, the layout the
     step programs are handed is the one they compute in, and the prefill
     kernel's lane-trailing view (ops/paged_flash_attention.py
-    ``_pool_views``) is the array itself. So is a row of fewer than
-    ``MIN_ROW_HEADS`` heads, whatever their width: two kv heads of 256 are two
-    rows of a tile of 16, the pool of ``[..., 64, 2, 256]`` was kept in a
-    layout of the compiler's own and both pools copied whole in ``ENTRY`` on
-    the way in and on the way out of every step program (PR 48, compiled for
-    the v5e at 2 x 256; 4 heads of 128 and 8 are handed over as they lie, so
-    the rule stops under 4). Any other row keeps ``(hkv, d_store)``. Scales
-    stay ``[..., hkv]`` either way.
+    ``_pool_views``) is the array itself. So is a row of up to
+    ``FOLDED_ROW_HEADS`` heads, whatever their width. Under 4, because two kv
+    heads of 256 are two rows of a tile of 16: the pool of ``[..., 64, 2,
+    256]`` was kept in a layout of the compiler's own and both pools copied
+    whole in ``ENTRY`` on the way in and on the way out of every step program
+    (PR 48, compiled for the v5e at 2 x 256). At 4, because a page of ``[64,
+    4, 128]`` is a quarter of a bfloat16 tile a row: handed over as it lies it
+    moves nothing, but the decode walk's kernel cannot meet it as one matrix
+    of whole tiles and the composed walk's gather of 64 one-tile rows a page
+    ran at a sixth of the bandwidth its bytes need (12 walks of a 25 ms step,
+    PERF.md section 6, PRs 64 and 65); folded, ``[64, 512]``, a page is that
+    matrix as it lies. ``row_fetch`` (a span whose decode rows fetch SINGLE
+    rows out of the pool and walk no page: one with an index row,
+    ops/sparse_attention.py ``_take_rows``) stops the rule under 4 as it
+    stood: there a row of ``[4, 128]`` is a tile of its own, 1 KB in one
+    piece, and in a folded bfloat16 pool two positions share a tile's packed
+    sublanes. Any other row (8 kv heads and more) keeps ``(hkv, d_store)``.
+    Scales stay ``[..., hkv]`` either way.
 
     Every consumer reads the form off the leaf it is handed (``pool_geometry``,
     ``fold_rows`` / ``unfold_rows``); only who MAKES a pool asks this function
-    (server/span_cache.py ``pool_descriptors``)."""
-    return (hkv * d_store,) if d_store < LANES or hkv < MIN_ROW_HEADS else (hkv, d_store)
+    (server/span_cache.py ``pool_row``)."""
+    up_to = FOLDED_ROW_HEADS - 1 if row_fetch else FOLDED_ROW_HEADS
+    return (hkv * d_store,) if d_store < LANES or hkv <= up_to else (hkv, d_store)
 
 
 def unfold_rows(a, hkv: int):
@@ -308,10 +320,11 @@ class PagedKV(NamedTuple):
     of the dense buffer code.
 
     The pool is in whichever form ``stored_row`` gave it: ``[n_pages,
-    page_size, hkv, d]`` or, for a row under the 128 lanes, ``[n_pages,
-    page_size, hkv * d]``. The scatter folds the new rows to the pool's own
-    row and the gather unfolds the pages it took (``gather_pages``), so block
-    code and attention see ``[.., hkv, d]`` rows either way.
+    page_size, hkv, d]`` or, for a row under the 128 lanes or of up to 4 kv
+    heads, ``[n_pages, page_size, hkv * d]``. The scatter folds the new rows
+    to the pool's own row and the gather unfolds the pages it took
+    (``gather_pages``), so block code and attention see ``[.., hkv, d]`` rows
+    either way.
 
     Inside a step program the pool a block sees is the WHOLE SPAN's, every
     layer's pages end to end (``[n_layers * n_pages, page_size, hkv, d]``, a

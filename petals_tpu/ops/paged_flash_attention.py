@@ -10,7 +10,7 @@ kernel a layer (``_walk_kernel``): the span's pools stay in HBM as the layer
 loop carries them, each live lane's pages of ALL kv heads are copied block by
 block into one of two buffers under the block before, and a block is met as
 one matrix as it lies (rows of ``[hkv, d]``: ``[rows * hkv, d]`` with the
-off-head columns masked; a folded row of fewer than 4 kv heads: ``[rows, hkv *
+off-head columns masked; a folded row of up to 4 kv heads: ``[rows, hkv *
 d]``, the heads its column blocks), so nothing is relaid and no layer is
 sliced out; everywhere else (off the chip, a quantised pool, half a tile of kv
 heads, a folded row of heads under 128 lanes, ALiBi, a soft cap, a traced
@@ -591,9 +591,11 @@ def window_pages(window, q_len: int, page_size: int, max_pages: int) -> int:
 WALK_BLOCK_BYTES = 512 << 10
 # and the most trips a walk takes over a table: past it a block is as many slots as keep the walk within it. A trip gathers
 # a page a lane whatever the block's bytes say, and over a table of hundreds of slots the trips are the walk: 16 lanes of
-# 4 kv heads of 128 at contexts of 2.5k-14.5k (a table of 256 slots, 227 walked) take 2.85 ms a layer at one page a
-# trip, 2.15 at four, 2.04 at eight, 2.40 at 32; a window's 65 slots 0.82 at one, 0.69 at two, 0.64 at four
-# (benchmarks/ablate_paged_walk.py smallthinker-21b smallthinker-21b-window, PR 64). No table of 64 slots or fewer, every
+# 4 kv heads of 128 kept as rows of [4, 128] at contexts of 2.5k-14.5k (a table of 256 slots, 227 walked) took 2.85 ms a
+# layer at one page a trip, 2.15 at four, 2.04 at eight, 2.40 at 32; a window's 65 slots 0.82 at one, 0.69 at two, 0.64 at
+# four (benchmarks/ablate_paged_walk.py smallthinker-21b smallthinker-21b-window, PR 64). That pool is stored folded since
+# PR 65 and its walks are the kernel's; the bound stays for what still takes the composed walk over a wide table (a
+# quantised pool, ALiBi, a soft cap, a traced window, and everything off the chip). No table of 64 slots or fewer, every
 # other cell's, is touched
 WALK_MAX_TRIPS = 64
 
@@ -782,10 +784,12 @@ def walk_kernel_unsupported(k_pool, q_shape, tables_shape, *, alibi: bool = Fals
     ONE matrix of whole tiles, so it takes a plain pool in either form the
     storage rule gives it (ops/paged_attention.py ``stored_row``): rows of
     ``[hkv, d]``, ``d`` whole lanes and ``hkv`` whole sublane tiles of the
-    dtype, met as ``[page_size * hkv, d]``; or folded rows of ``hkv * d``
-    (fewer than 4 kv heads), ``d`` whole lanes, met as ``[page_size, hkv *
-    d]`` with a row's heads as column blocks. A page's rows are whole sublane
-    tiles either way. Still refused, and left to the composed walk: a folded
+    dtype, met as ``[page_size * hkv, d]``; or folded rows of ``hkv * d`` (up
+    to 4 kv heads where a server made the pool: Qwen3-Next's 2 x 256, Jamba's
+    1 x 128, SmallThinker's 4 x 128 under 28 query heads), ``d`` whole lanes,
+    met as ``[page_size, hkv * d]`` with a row's heads as column blocks. A
+    page's rows are whole sublane tiles either way.
+    Still refused, and left to the composed walk: a folded
     row of heads under 128 lanes (a head_dim of 64: two heads share a tile's
     lanes), a quantised pool (the gather dequantises its codes; the
     kernel has no scales), pages of another dtype, rows of ``[hkv, d]`` of
@@ -836,7 +840,7 @@ def walk_kernel_unsupported(k_pool, q_shape, tables_shape, *, alibi: bool = Fals
 def decode_walk_path(k_pool, q_shape, tables_shape, *, alibi: bool = False, softcap: bool = False, window=None) -> str:
     """``"kernel"`` on a TPU backend for a call the kernel takes
     (``walk_kernel_unsupported``: a plain bfloat16 or float32 pool of whole
-    tiles, rows of ``[hkv, d]`` or a folded row of fewer than 4 kv heads of
+    tiles, rows of ``[hkv, d]`` or a folded row of up to 4 kv heads of
     whole lanes, one query row a lane under the walk's own masks),
     ``"composed"`` everywhere else: off the chip, and for what the kernel still
     refuses, each for a reason of its own: a quantised pool (no scales in the

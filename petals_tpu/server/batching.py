@@ -1668,7 +1668,8 @@ class DecodeBatcher:
             # WIRE bytes/token (what a page actually costs under kv quant)
             cache, pool = self.backend.cache, self._pool
             info["kv_quant"] = cache.kv_quant_type
-            # the trailing dims the pool keeps a token row in: (hkv, d_store), or folded to one (a row under 128 lanes)
+            # the trailing dims the pool keeps a token row in: (hkv, d_store), or folded to one (``stored_row``: a row under 128 lanes,
+            # or of up to 4 kv heads)
             info["pool_row"] = list(cache.pool_row)
             # which walk a decode row's attention takes over these pages, a distinct window of the span's layers
             info["decode_walk"] = [walk[-1] for walk in pool.walks]

@@ -244,10 +244,13 @@ class SpanCache:
     @functools.cached_property
     def pool_row(self) -> tuple:
         """The trailing dims the page pool's values (or codes) keep a token row in: ``(hkv, d_store)``, or ``(hkv *
-        d_store,)`` where the rule folds it (ops/paged_attention.py ``stored_row``). Fixed at start."""
+        d_store,)`` where the rule folds it (ops/paged_attention.py ``stored_row``). Fixed at start. The rule is told what the
+        span's decode rows do with the pool: walk their lanes' pages, or, with an index row, fetch the rows they chose one by
+        one (4 kv heads of 128 then stay a row of ``[4, 128]``, a tile of its own)."""
         if self.latent_row is not None:  # one row for all heads, in two pools (``pool_descriptors``)
             return (sum(self.latent_row),)
-        return paged_attention.stored_row(self.kv_heads, self.head_dim // 2 if self.kv_quant_type == "nf4a" else self.head_dim)
+        d_store = self.head_dim // 2 if self.kv_quant_type == "nf4a" else self.head_dim
+        return paged_attention.stored_row(self.kv_heads, d_store, row_fetch=self.index_row is not None)
 
     def group_pages(self, n_lanes: int, max_pages: int, page_size: int, chunk: int, n_pages: Optional[int] = None) -> tuple:
         """Pages a group for a paged lane pool of these shapes, ``n_pages`` the full group's (None: a lane's whole table,
@@ -276,8 +279,9 @@ class SpanCache:
         The page pools, unquantized: (k, v), each [n, n_pages, page_size, hkv, d] in cache_dtype. Quantized (kv_quant_type !=
         none): (k_codes, v_codes, k_scales, v_scales) — the codes in the storage dtype (int8, or uint8 with two
         split-half-packed dims per byte for nf4a) and f32 absmax scales per (page row, kv head). A values or codes leaf whose
-        row is under the chip's 128 lanes (head_dim 64; 128 too for nf4a's packed half) is stored with the kv heads folded
-        into it, [n, n_pages, page_size, hkv * d_store] (``pool_row``; the rule: ops/paged_attention.py ``stored_row``). The
+        row is under the chip's 128 lanes (head_dim 64; 128 too for nf4a's packed half), or of up to 4 kv heads, is stored
+        with the kv heads folded into it, [n, n_pages, page_size, hkv * d_store] (``pool_row``; the rule:
+        ops/paged_attention.py ``stored_row``). The
         paged path is gated to mesh-less single-host servers (server/batching.py), so no sharding rides these. The pools are
         as deep as the blocks of [start, end) that keep keys and values: a block with a state of its own has no pages.
 

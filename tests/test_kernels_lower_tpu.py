@@ -962,48 +962,54 @@ def test_latent_mixed_step_expands_a_block_of_positions_at_a_time_and_holds_no_w
 
 # ---------------------------------------------------------------- a decode row's walk over plain pages, as one kernel
 
-# hq, (hkv, d), table slots a lane as the walk is handed them, window, dtype. The first two are the pools of rows of
+# hq, (hkv, d), table slots a lane as the walk is handed them, window, dtype, lanes. The first two are the pools of rows of
 # [hkv, 128] that configurations store and the kernel takes (Olmo-Hybrid's, OLMoE's); the next two its query groups and its
 # window at a shape it takes; the float32 ones are NO configuration's (Mixtral's and K-EXAONE's 8 kv heads of 128 are stored
 # in bfloat16, half a tile, and refused: the next tests): a float32 pool is what tests and a float32 server store; then the
-# widest table the predicate lets through (``WALK_KERNEL_TABLE_BYTES``: 8 lanes of a million positions); the last five are
-# FOLDED rows of fewer than 4 kv heads (``stored_row``): Qwen3-Next's 2 of 256 and Jamba's one of 128 under 20 query heads,
-# as their configurations store them, then a window, float32 and three heads at such a row
+# widest table the predicate lets through (``WALK_KERNEL_TABLE_BYTES``: 8 lanes of a million positions); then five
+# FOLDED rows of up to 4 kv heads (``stored_row``): Qwen3-Next's 2 of 256 and Jamba's one of 128 under 20 query heads,
+# as their configurations store them, then a window, float32 and three heads at such a row; the last two are SmallThinker's
+# two walks at its cell's shapes (PR 65): 16 lanes, 28 query heads over a folded row of 4 x 128, a full layer's table of 256
+# slots and a windowed layer's cut to the 65 its window of 4,096 reaches
 WALK_KERNEL_SHAPES = [
-    pytest.param(32, (32, 128), 40, None, BF16, id="olmo-hybrid-32x128-over-40"),
-    pytest.param(16, (16, 128), 16, None, BF16, id="olmoe-16x128-over-16"),
-    pytest.param(64, (16, 128), 16, None, BF16, id="16x128-4-query-heads-a-kv-head"),
-    pytest.param(64, (16, 128), 16, 128, BF16, id="16x128-window-128"),
-    pytest.param(32, (8, 128), 16, None, F32, id="float32-8x128-4-query-heads-a-kv-head"),
-    pytest.param(64, (8, 128), 16, 128, F32, id="float32-8x128-8-query-heads-a-kv-head-window-128"),
-    pytest.param(16, (16, 128), pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8), None, BF16, id="16x128-tables-at-the-scalar-memory-budget"),
-    pytest.param(16, (2, 256), 40, None, BF16, id="qwen3-next-folded-2x256-over-40"),
-    pytest.param(20, (1, 128), 40, None, BF16, id="jamba-folded-1x128-20-query-heads-over-40"),
-    pytest.param(16, (2, 256), 40, 128, BF16, id="folded-2x256-window-128"),
-    pytest.param(8, (2, 128), 16, None, F32, id="float32-folded-2x128"),
-    pytest.param(12, (3, 128), 16, None, BF16, id="folded-3x128-4-query-heads-a-kv-head"),
+    pytest.param(32, (32, 128), 40, None, BF16, 8, id="olmo-hybrid-32x128-over-40"),
+    pytest.param(16, (16, 128), 16, None, BF16, 8, id="olmoe-16x128-over-16"),
+    pytest.param(64, (16, 128), 16, None, BF16, 8, id="16x128-4-query-heads-a-kv-head"),
+    pytest.param(64, (16, 128), 16, 128, BF16, 8, id="16x128-window-128"),
+    pytest.param(32, (8, 128), 16, None, F32, 8, id="float32-8x128-4-query-heads-a-kv-head"),
+    pytest.param(64, (8, 128), 16, 128, F32, 8, id="float32-8x128-8-query-heads-a-kv-head-window-128"),
+    pytest.param(16, (16, 128), pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8), None, BF16, 8, id="16x128-tables-at-the-scalar-memory-budget"),
+    pytest.param(16, (2, 256), 40, None, BF16, 8, id="qwen3-next-folded-2x256-over-40"),
+    pytest.param(20, (1, 128), 40, None, BF16, 8, id="jamba-folded-1x128-20-query-heads-over-40"),
+    pytest.param(16, (2, 256), 40, 128, BF16, 8, id="folded-2x256-window-128"),
+    pytest.param(8, (2, 128), 16, None, F32, 8, id="float32-folded-2x128"),
+    pytest.param(12, (3, 128), 16, None, BF16, 8, id="folded-3x128-4-query-heads-a-kv-head"),
+    pytest.param(28, (4, 128), 256, None, BF16, 16, id="smallthinker-folded-4x128-28-query-heads-16-lanes-over-256"),
+    pytest.param(28, (4, 128), 256, 4096, BF16, 16, id="smallthinker-folded-4x128-28-query-heads-16-lanes-window-4096"),
 ]
 
 
-@pytest.mark.parametrize("hq,heads,slots,window,dtype", WALK_KERNEL_SHAPES)
-def test_paged_decode_walk_kernel_lowers_at_the_shapes_it_takes(v5e, hq, heads, slots, window, dtype):
+@pytest.mark.parametrize("hq,heads,slots,window,dtype,lanes", WALK_KERNEL_SHAPES)
+def test_paged_decode_walk_kernel_lowers_at_the_shapes_it_takes(v5e, hq, heads, slots, window, dtype, lanes):
     """The walk's kernel alone (ops/paged_flash_attention.py ``_walk_kernel``),
-    through Pallas -> Mosaic -> libtpu for the v5e: 8 lanes, pages of 64, the
-    span's pools of 5 x 8 x ``slots`` pages handed whole in the form the
+    through Pallas -> Mosaic -> libtpu for the v5e: ``lanes`` lanes, pages of 64,
+    the span's pools of 5 x ``lanes`` x ``slots`` pages handed whole in the form the
     storage rule keeps their row in (``[64, hkv, 128]``, or folded ``[64, hkv
-    * d]``; at most 1,600 pages: the widest table's pool would not fit the
-    chip); under the window the table is cut to the 3 slots in reach first.
+    * d]``; at most 40 slots a lane's worth: the widest table's pool would not fit the
+    chip); under the window the table is cut to the slots in reach first (3 of
+    16 at a window of 128, 65 of 256 at one of 4,096).
     One slot a lane over the widest table is the predicate's to refuse: its
     budget is the largest that was seen to compile."""
     def walk(q, k_pool, v_pool, tables, positions):
         return pfa.composed_paged_attend(q, k_pool, v_pool, tables, q_offset=positions, kv_length=positions + 1, sliding_window=window, path="kernel")
 
-    pool = v5e((5 * 8 * min(slots, 40), 64, *stored_row(*heads)), dtype)
-    assert (len(pool.shape) == 3) == (heads[0] < 4)
-    avals = (v5e((8, 1, hq, heads[1]), dtype), pool, pool, v5e((8, slots), I32), v5e((8,), I32))
+    pool = v5e((5 * lanes * min(slots, 40), 64, *stored_row(*heads)), dtype)
+    assert (len(pool.shape) == 3) == (heads[0] <= 4)
+    avals = (v5e((lanes, 1, hq, heads[1]), dtype), pool, pool, v5e((lanes, slots), I32), v5e((lanes,), I32))
     reach = pfa.window_pages(window, 1, 64, slots)
-    assert pfa.walk_kernel_unsupported(pool, avals[0].shape, (8, reach), window=window) is None
-    assert "scalar memory" in pfa.walk_kernel_unsupported(pool, avals[0].shape, (8, pfa.WALK_KERNEL_TABLE_BYTES // (4 * 8) + 1), window=window)
+    assert reach == {None: slots, 128: 3, 4096: 65}[window]
+    assert pfa.walk_kernel_unsupported(pool, avals[0].shape, (lanes, reach), window=window) is None
+    assert "scalar memory" in pfa.walk_kernel_unsupported(pool, avals[0].shape, (lanes, pfa.WALK_KERNEL_TABLE_BYTES // (4 * lanes) + 1), window=window)
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(pfa, "_interpret", lambda: False)  # the backend here is the CPU: the kernel would be interpreted
         _compile(walk, *avals)
@@ -1487,6 +1493,7 @@ def _compiled_grouped_step(v5e, tmp_path, config_name, chunk, lanes, pages_a_lan
         patch.setattr("petals_tpu.ops.expert_hit._interpret", lambda: False)
         patch.setattr(pfa, "_on_tpu", lambda: True)
         patch.setattr(pfa, "_platform", lambda: "tpu")  # a chunk takes the prefill kernel, as on the chip
+        patch.setattr(pfa, "paged_flash_prefill_attend", functools.partial(pfa.paged_flash_prefill_attend, interpret=False))  # and not interpreted
         patch.setattr(pfa, "_interpret", lambda: False)
         hlo = jax.jit(step, donate_argnums=(1, 2, len(avals) - 1)).lower(*avals).compile().as_text()
     return hlo, pools, (backend.num_kv_heads, backend.head_dim)
@@ -1507,13 +1514,34 @@ def test_a_grouped_step_leaves_every_group_s_pool_in_place(v5e, tmp_path, config
     """Two pools where there was one is where a whole-pool copy or relay would appear: the compiled step of a span whose
     layers keep pages by kind (SmallThinker's three full and nine windowed layers at the cell's 16 lanes of 256 slots;
     K-EXAONE's one full and four windowed) allocates no second pool of either group, copies none in ``ENTRY``, and no
-    layer of either is sliced out, copied or written back whole in a run's loop; and every loop carries both."""
+    layer of either is sliced out, copied or written back whole in a run's loop; and every loop carries the pools its
+    layers reach."""
     hlo, pools, heads = _compiled_grouped_step(v5e, tmp_path, config_name, chunk, lanes, pages_a_lane, budget)
     assert len(pools) == 4 and pools[0].shape[1] == lanes * pages_a_lane and pools[2].shape[1] < pools[0].shape[1]
     assert pools[0].shape[0] + pools[2].shape[0] == {"smallthinker-21b-a3b-span12": 12, "k-exaone-236b-span5-ep8": 5}[config_name]
-    for pool in (pools[0], pools[2]):
+    # the decode rows' walks: SmallThinker's folded rows of 4 x 128 take the kernel in BOTH groups since PR 65, one call a run
+    # of layers, handed its own group's two pools as the loop carries them or as the new rows' scatter left them (the full
+    # layers are runs of ONE block, unrolled into ``ENTRY``; the windowed runs of three are loops); K-EXAONE's 8 x 128 none
+    walks = decode_walk_calls(hlo, "paged_decode_walk")
+    entry = re.search(r"^ENTRY\s+%([\w.\-]+)", hlo, re.MULTILINE).group(1)
+    handed = {0: [], 2: []}
+    for computation, op_name, operands in walks:
+        assert "ptu.attn.paged_decode" in op_name, op_name
+        for g in handed:
+            mine = [(op, fused) for op, fused, dims in operands if math.prod(dims) == math.prod(pools[g].shape)]
+            assert len(mine) in (0, 2), operands
+            for op, fused in mine:
+                assert op in ("get-tuple-element", "parameter") or (fused is not None and any(i[2] in ("dynamic-update-slice", "scatter") for i in fused)), (op, operands)
+            handed[g] += [computation == entry] * (len(mine) // 2)
+    if config_name == "smallthinker-21b-a3b-span12":
+        assert pools[0].shape[2:] == pools[2].shape[2:] == (64, 512) and handed == {0: [True] * 3, 2: [False] * 3}, (pools, handed)
+    else:
+        assert pools[0].shape[2:] == (64, 8, 128) and not walks, walks
+    for g, pool in ((0, pools[0]), (2, pools[2])):
         moves, loops_seen = pool_moves(hlo, tuple(pool.shape), heads)
-        assert loops_seen, f"no loop carries the pool {pool.shape}: has the HLO text changed, or the pool left the carry?"
+        # a run's loop carries the pools its layers reach; a group whose layers all run in ``ENTRY`` under the walk's kernel
+        # (no loop of a composed walk's own either) is found there, as the kernel's operand
+        assert loops_seen or any(handed[g]), f"no loop carries the pool {pool.shape}: has the HLO text changed, or the pool left the carry?"
         # a chunk's prefill kernel is handed its block's OWN layer of its group's pool (``PagedKV.own_layer``: the kernel
         # relays what it is handed), as it is of a pool of one group: a slice of ONE layer, keys and values, in the run's
         # loop, and nothing else; a decode step moves nothing at all
@@ -1524,3 +1552,9 @@ def test_a_grouped_step_leaves_every_group_s_pool_in_place(v5e, tmp_path, config
     whole = [line.strip()[:200] for line in hlo.splitlines() if re.search(r"= \(?(bf16\[[\d,]+\])\S* copy-(start|done)\(", line)
              and re.search(r"= \(?(bf16\[[\d,]+\])", line).group(1) in sizes]
     assert not whole, whole
+    if config_name == "smallthinker-21b-a3b-span12" and chunk:
+        # the folded pool IS the prefill kernel's lane-trailing view: a layer handed to it is sliced out and no more. As rows
+        # of [4, 128] (tiles of T(4,128)) every layer's slice was relaid besides, ``reshape`` to [pages, 64, 512] in tiles of
+        # T(8,128): 268 MB a side a full layer, 102 MB a windowed one, every layer of every mixed step (compiled at PR 64's tree)
+        relaid = [line.strip()[:160] for line in hlo.splitlines() if re.search(r"= bf16\[(4096|1552),64,512\]\S* (reshape|copy|transpose)\(", line)]
+        assert not relaid, relaid

@@ -16,7 +16,7 @@ from petals_tpu.server.memory_cache import AllocationFailed
 from petals_tpu.server.span_cache import GROUPS_RIDE
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_smallthinker import HF, reference_hidden, whole_backend
-from tests.utils import lane_pools, make_tiny_exaone_moe, make_tiny_smallthinker, tiny_smallthinker_tensors
+from tests.utils import lane_pools, make_tiny_exaone_moe, make_tiny_smallthinker, published_span_cache, tiny_smallthinker_tensors
 
 PAGE, WINDOW = 4, 8
 
@@ -298,3 +298,78 @@ def test_exaone_moe_s_step_over_two_groups_is_its_step_over_one_pool(tmp_path, c
         np.testing.assert_array_equal(np.asarray(a)[1], np.asarray(b)[1])
     np.testing.assert_array_equal(np.asarray(one[0])[3], np.asarray(two[0])[0])  # the full layer's pages, the same rows
     np.testing.assert_array_equal(np.asarray(one[1])[[0, 1, 2, 4]], np.asarray(two[3]))  # the windowed layers' values
+
+
+@pytest.mark.parametrize("on_tpu", [True, False], ids=["tpu", "off-the-chip"])
+def test_a_span_of_4_kv_heads_of_128_walks_both_its_groups_in_the_kernel_and_the_counters_say_so(tmp_path, monkeypatch, on_tpu):
+    """The published SmallThinker span on shapes alone, as its cell runs it (16 lanes, tables of 256 slots, pages of 64): its
+    pools keep a row of 4 kv heads of 128 folded (``stored_row``), so on a TPU ``LanePool.walks`` says ``kernel`` for the
+    three full layers and for the nine of window 4,096 (blocks of 8 pages: half a megabyte of a folded row of 512), and a
+    decode step's ``attn_pages_kernel`` is all of its ``attn_pages_gathered``: each live lane read to its OWN last block,
+    in a windowed layer from its window's first slot. Off the chip both are the composed walk's and the kernel counts nothing."""
+    from petals_tpu.ops import paged_flash_attention as pfa
+
+    cache, _ = published_span_cache("smallthinker-21b-a3b-span12", tmp_path)
+    assert cache.pool_row == (512,) and [(w, len(blocks)) for w, blocks in cache.page_groups] == [(None, 3), (4096, 9)]
+    monkeypatch.setattr(pfa, "_on_tpu", lambda: on_tpu)
+    lanes, slots, page = 16, 256, 64
+    pool = cache.lane_pool(lanes, slots, page, grouped=True)
+    path = "kernel" if on_tpu else "composed"
+    # (window, layers, block, cut to the window's reach, path); off the chip the composed walk's blocks: ``WALK_MAX_TRIPS``
+    assert sorted(pool.walks, key=str) == sorted([(None, 3, 8 if on_tpu else 4, False, path), (4096, 9, 8 if on_tpu else 2, True, path)], key=str)
+    positions = np.full(lanes, slots * page, np.int32)  # two idle lanes
+    positions[:14] = [2500, 3300, 4100, 4900, 5700, 6500, 7300, 8100, 8900, 9700, 10500, 11300, 12100, 14500]
+    live = positions[positions < slots * page].astype(np.int64)
+    full_held = np.where(positions < slots * page, positions // page + 1, 0).astype(np.int64)
+    window_held = np.where(positions < slots * page, positions // page - np.maximum(positions - 4095, 0) // page + 1, 0).astype(np.int64)
+    stats = pool.new_stats()
+    pool.count_step(stats, positions, full_held, group_held=[full_held, window_held])
+    own_full = 3 * 8 * int((live // (8 * page) + 1).sum())  # whole blocks of 8 slots up to each lane's own last row
+    own_window = 9 * int((-(-(live // page - np.maximum(live - 4095, 0) // page + 1) // 8) * 8).sum())  # from the window's first slot
+    if on_tpu:
+        assert stats["attn_pages_kernel"] == stats["attn_pages_gathered"] == own_full + own_window
+    else:  # every lane of the pool's to the longest live lane's last block, in blocks of 4 slots and of 2
+        assert stats["attn_pages_kernel"] == 0
+        assert stats["attn_pages_gathered"] == 3 * lanes * 228 + 9 * lanes * 66
+    assert stats["attn_pages_tabled"] == 12 * lanes * slots
+    before = dict(stats)  # a mixed step: the decode rows' walks are the kernel's, the chunk's rows gather their reach
+    pool.count_step(stats, positions, full_held, chunk=(15, 0, 300), group_held=[full_held, window_held])
+    kernel, gathered = (stats[key] - before[key] for key in ("attn_pages_kernel", "attn_pages_gathered"))
+    assert kernel == (own_full + own_window if on_tpu else 0) and gathered > before["attn_pages_gathered"]
+
+
+def test_two_lanes_of_4_kv_heads_of_128_decode_through_the_kernel_in_both_groups_and_answer_as_the_reference(tmp_path, monkeypatch):
+    """The toy SmallThinker at the published row (4 kv heads of 128 under 8 query heads, four layers: a full one and three
+    of window 16, pages of 8) on a backend that says it is a TPU: the grouped pools are folded rows of 512, every decode
+    row's walk, a full layer's and a windowed layer's cut to its reach, is the walk's kernel (interpreted here) inside the
+    grouped decode and mixed steps, two lanes of other lengths side by side, one of them past its window so that pages
+    went back; every reply against the reference's whole forward pass, and all a decode step read counted as the kernel's."""
+    from petals_tpu.ops import paged_flash_attention as pfa
+
+    hf = {**HF, "head_dim": 128, "num_key_value_heads": 4, "num_attention_heads": 8, "num_hidden_layers": 4, "sliding_window_size": 16,
+          "rope_layout": [0, 1, 1, 1], "sliding_window_layout": [0, 1, 1, 1]}
+    overrides = {key: value for key, value in hf.items() if HF[key] != value}
+    backend, tensors = whole_backend(make_tiny_smallthinker(str(tmp_path), **overrides), n_blocks=4), tiny_smallthinker_tensors(hf)
+    assert backend.cache.pool_row == (512,) and [(w, len(blocks)) for w, blocks in backend.cache.page_groups] == [(None, 1), (16, 3)]
+    monkeypatch.setattr(pfa, "_on_tpu", lambda: True)  # ``_interpret`` still sees the CPU
+    a_rows, b_rows = rows(5, 44), rows(6, 20)
+
+    async def main():
+        async with rig(backend, page_size=8, max_length=48) as batcher:
+            a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
+            info = batcher.occupancy_info()
+            assert info["decode_walk"] == ["kernel", "kernel"] and info["pool_row"] == [512]
+            got_a, got_b = [await batcher.prefill_lane(a, a_rows[:, :30], 0)], [await batcher.prefill_lane(b, b_rows[:, :6], 0)]
+            before = dict(batcher.stats)
+            for i in range(14):  # A at 30..43, its window two to three pages behind it; B at 6..19, its whole context in sight
+                await asyncio.gather(batcher.prepare_write(a, 30 + i, 31 + i), batcher.prepare_write(b, 6 + i, 7 + i))
+                outs = await asyncio.gather(batcher.step(a, a_rows[:, 30 + i : 31 + i], 30 + i), batcher.step(b, b_rows[:, 6 + i : 7 + i], 6 + i))
+                got_a.append(outs[0]), got_b.append(outs[1])
+            walked = batcher.stats["attn_pages_gathered"] - before["attn_pages_gathered"]
+            assert walked == batcher.stats["attn_pages_kernel"] - before["attn_pages_kernel"] > 0
+            assert batcher.stats["window_pages_released"] > 0
+            return np.concatenate([np.asarray(o) for o in got_a], axis=1), np.concatenate([np.asarray(o) for o in got_b], axis=1)
+
+    got_a, got_b = asyncio.run(main())
+    for got, data in ((got_a, a_rows), (got_b, b_rows)):
+        np.testing.assert_allclose(got[0], reference_hidden(hf, tensors, data[0], last=4), atol=1e-4, rtol=0)

@@ -164,7 +164,7 @@ def test_walk_block_follows_the_shapes_it_sees():
 
 # n_lanes 4, pages of 16 rows, 16 kv heads of 128 unless a case says another ``hkv`` / ``d``; a lane's position, or None for
 # an idle lane. ``block``: table slots of one lane a grid step takes (the cases set the bytes the rule goes by). A
-# ``folded-`` case hands the kernel the pool as the storage rule keeps a row of fewer than 4 kv heads (``stored_row``: ``[.., hkv * d]``)
+# ``folded-`` case hands the kernel the pool as the storage rule keeps a row of up to 4 kv heads (``stored_row``: ``[.., hkv * d]``)
 KERNEL_WALK_CASES = {
     "lanes-shorter-than-a-block": dict(max_pages=8, block=4, positions=[5, 20, 40, 0]),
     "ending-on-a-block-s-and-on-a-page-s-last-position": dict(max_pages=8, block=2, positions=[31, 15, 63, 47]),
@@ -193,6 +193,10 @@ KERNEL_WALK_CASES = {
     "folded-window128-whole-table": dict(max_pages=9, block=1, hkv=1, d=128, group=20, window=128, positions=[143, 130, 20, None]),
     "folded-garbage-pages-past-a-lane-s-end": dict(max_pages=8, block=4, hkv=2, d=256, group=8, garbage=True, positions=[19, 40, 5, None]),
     "folded-identity-tables": dict(max_pages=8, block=2, hkv=1, d=128, group=4, identity=True, positions=[0, 15, 32, 53]),
+    # SmallThinker's row (PR 65): 28 query heads over 4 kv heads of 128, a full layer's walk and a windowed layer's, an idle lane in each
+    "folded-4-kv-heads-of-128-under-28-query-heads": dict(max_pages=8, block=4, hkv=4, d=128, group=7, positions=[100, 3, None, 77]),
+    "folded-4-kv-heads-of-128-under-28-query-heads-window128": dict(max_pages=16, block=2, hkv=4, d=128, group=7, window=128, positions=[255, 10, 130, None]),
+    "folded-4-kv-heads-of-128-under-28-query-heads-window128-whole-table": dict(max_pages=9, block=1, hkv=4, d=128, group=7, window=128, positions=[143, None, 20, 130]),
 }
 
 
@@ -222,8 +226,8 @@ def test_decode_walk_kernel_reads_each_lane_s_own_pages_and_gives_numpy_s_answer
     block, holds NaN, so a block read past a lane's end, or a hole read from a
     page of somebody else's, shows (a weight of zero times NaN is NaN). The
     composed walk, handed the same call with NaN out of its reach, agrees.
-    Both are handed the pool as the storage rule keeps its row: a row of fewer
-    than 4 kv heads folded, its heads the column blocks of the kernel's
+    Both are handed the pool as the storage rule keeps its row: a row of up to
+    4 kv heads folded, its heads the column blocks of the kernel's
     matrix."""
     n_lanes, ps, d = 4, 16, case.get("d", 128)
     max_pages, block, group, hkv = case["max_pages"], case["block"], case.get("group", 1), case.get("hkv", 16)
@@ -284,6 +288,10 @@ WALK_PATH_CASES = {
     "folded-float32-static-window": (dict(pool=_pool_like((9, 64, 2 * 128), jnp.float32), q=(8, 1, 8, 128), window=128), None),
     "folded-1-kv-head-of-64": (dict(pool=_pool_like((9, 64, 64)), q=(8, 1, 71, 64)), "folded row of 64"),  # half the lanes
     "folded-3-kv-heads-of-128": (dict(pool=_pool_like((9, 64, 3 * 128)), q=(8, 1, 12, 128)), None),
+    "folded-4-kv-heads-of-128-under-28-query-heads": (dict(pool=_pool_like((9, 64, 4 * 128)), q=(16, 1, 28, 128), tables=(16, 256)), None),  # SmallThinker's full layers
+    "folded-4-kv-heads-of-128-static-window-of-4096": (dict(pool=_pool_like((9, 64, 4 * 128)), q=(16, 1, 28, 128), tables=(16, 65), window=4096), None),  # its windowed ones
+    "rows-of-4-kv-heads-of-128": (dict(pool=_pool_like((9, 64, 4, 128)), q=(16, 1, 28, 128), tables=(16, 256)), "sublanes"),  # as a span with an index row keeps them
+    "folded-4-kv-heads-of-128-int8-codes": (dict(pool=_pool_like((9, 64, 4 * 128), jnp.int8), scales=(9, 64, 4), q=(16, 1, 28, 128)), "quantised"),
     "folded-a-query-of-another-head-dim": (dict(pool=_pool_like((9, 64, 2 * 256)), q=(8, 1, 16, 384)), "folded row of 512"),
     "folded-pages-of-8-rows": (dict(pool=_pool_like((9, 8, 2 * 256)), q=(8, 1, 16, 256)), "sublanes"),
     "folded-alibi": (dict(pool=_pool_like((9, 64, 2 * 128)), q=(8, 1, 8, 128), alibi=True), "ALiBi"),
@@ -317,6 +325,8 @@ def test_decode_walk_path_follows_what_the_call_shows(case, monkeypatch):
     pool = kw.get("pool", _pool_like((9, 64, 16, 128)))
     if kw.get("quantised"):
         pool = PagedPool(_pool_like((9, 64, 16, 128), jnp.int8), _pool_like((9, 64, 16), jnp.float32))
+    if "scales" in kw:  # a quantised pool whose codes the case gives
+        pool = PagedPool(pool, _pool_like(kw["scales"], jnp.float32))
     window = jax.numpy.int32(20) if kw.get("window") == "traced" else kw.get("window")
     args = dict(alibi=kw.get("alibi", False), softcap=kw.get("softcap", False), window=window)
     q_shape = kw.get("q", (8, 1, 16, 128))

@@ -22,7 +22,7 @@ from petals_tpu.server.backend import TransformerBackend
 from petals_tpu.server.from_pretrained import get_block_config, load_block_params
 from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server
-from tests.utils import lane_pools, make_tiny_falcon
+from tests.utils import lane_pools, make_tiny_falcon, published_span_cache
 
 LANES, MAX_PAGES, PAGE_SIZE, N_PAGES = 3, 2, 8, 6
 SENTINEL = MAX_PAGES * PAGE_SIZE
@@ -60,17 +60,23 @@ def _bytes(pool):
     return [np.asarray(leaf).reshape(-1) for leaf in jax.tree_util.tree_leaves(pool)]
 
 
-def test_the_rule_folds_a_row_under_128_lanes_or_of_under_4_heads_and_no_other():
+def test_the_rule_folds_a_row_under_128_lanes_or_of_up_to_4_heads_and_no_other():
     assert stored_row(8, 64) == (512,)  # Falcon-40B, bf16: the pool PR 38 was written for
     assert stored_row(8, 128) == (8, 128) and stored_row(16, 128) == (16, 128) and stored_row(32, 128) == (32, 128)  # the other five cells
-    assert stored_row(8, 256) == (8, 256)
+    assert stored_row(8, 256) == (8, 256) and stored_row(30, 128) == (30, 128) and stored_row(5, 128) == (5, 128)
     assert stored_row(2, 16) == (32,) and stored_row(8, 96) == (768,)
-    assert stored_row(2, 256) == (512,) and stored_row(1, 128) == (128,) and stored_row(4, 128) == (4, 128)  # under 4 heads: folded whatever the width
+    assert stored_row(2, 256) == (512,) and stored_row(1, 128) == (128,) and stored_row(3, 128) == (384,)  # up to 4 heads: folded whatever the width
+    assert stored_row(4, 128) == (512,) and stored_row(4, 256) == (1024,)  # a page of 4 x 128 is one matrix of whole tiles where pages are walked
+    # a span whose decode rows fetch single rows (one with an index row) stops the rule under 4: a row of [4, 128] is a tile of its own
+    assert stored_row(4, 128, row_fetch=True) == (4, 128) and stored_row(4, 256, row_fetch=True) == (4, 256)
+    for hkv, d in ((8, 64), (2, 16), (8, 96), (2, 256), (1, 128), (3, 128), (8, 128), (16, 128), (30, 128)):  # and changes nothing else
+        assert stored_row(hkv, d, row_fetch=True) == stored_row(hkv, d)
     rows = np.arange(2 * 3 * 4 * 5).reshape(2, 3, 4, 5)
     assert fold_rows(rows, (20,)).shape == (2, 3, 20) and fold_rows(rows, (4, 5)).shape == rows.shape
     np.testing.assert_array_equal(unfold_rows(fold_rows(rows, (20,)), 4), rows)
     pool = jnp.zeros((6, 8, 128))
     assert pool_geometry(pool, 64) == (6, 8, 2, 64) and pool_geometry(pool.reshape(6, 8, 2, 64), 64) == (6, 8, 2, 64)
+    assert pool_geometry(jnp.zeros((6, 8, 512)), 128) == (6, 8, 4, 128)
     quantized = PagedPool(jnp.zeros((6, 8, 64), jnp.uint8), jnp.zeros((6, 8, 2)))
     assert pool_geometry(quantized, 64) == (6, 8, 2, 32) and quantized.shape == (6, 8, 2, 64) and quantized.ndim == 4
     assert PagedPool(jnp.zeros((6, 8, 2, 32), jnp.uint8), jnp.zeros((6, 8, 2))).shape == (6, 8, 2, 64)
@@ -80,29 +86,49 @@ def test_the_rule_folds_a_row_under_128_lanes_or_of_under_4_heads_and_no_other()
     assert PagedKV(pool, jnp.zeros((3, 2), jnp.int32)).shape == (3, 16, 128) and PagedKV(pool, jnp.zeros((3, 2), jnp.int32)).page_size == 8
 
 
+@pytest.mark.parametrize("hkv", [4, 8])
 @pytest.mark.parametrize("kind", KV_QUANT_KINDS)
-def test_a_pool_of_head_dim_128_is_declared_as_it_was(tmp_path, kind):
-    """The descriptors of a span whose rows fill the lanes are the parent's:
-    ``[.., hkv, d]`` values or int8 codes, scales ``[.., hkv]``. (nf4a packs
-    two dims a byte, so its codes of a head_dim of 128 are 64 wide and fold,
-    as any row of 64 does; its scales keep ``[.., hkv]``.)"""
+def test_a_pool_of_head_dim_128_is_declared_by_its_heads(tmp_path, kind, hkv):
+    """The descriptors of a span whose rows fill the lanes. Of 8 kv heads they are the parent's: ``[.., hkv, d]`` values
+    or int8 codes. Of 4 (up to ``FOLDED_ROW_HEADS``) the row is folded, values and int8 codes alike, ``[.., 512]``: a
+    page is then one matrix of whole tiles for the decode walk's kernel. (nf4a packs two dims a byte, so its codes of a
+    head_dim of 128 are 64 wide and fold, as any row of 64 does.) Scales keep ``[.., hkv]`` in every case."""
     from transformers import LlamaConfig
 
-    LlamaConfig(vocab_size=64, hidden_size=512, intermediate_size=128, num_hidden_layers=2, num_attention_heads=4,
-                num_key_value_heads=4).save_pretrained(str(tmp_path))
+    LlamaConfig(vocab_size=64, hidden_size=128 * hkv, intermediate_size=128, num_hidden_layers=2, num_attention_heads=hkv,
+                num_key_value_heads=hkv).save_pretrained(str(tmp_path))
     family, cfg = get_block_config(str(tmp_path))
     params = {name: jax.ShapeDtypeStruct((2, *leaf.shape), leaf.dtype) for name, leaf in family.block_param_shapes(cfg, jnp.bfloat16).items()}
     backend = TransformerBackend(family, cfg, params, first_block=0, n_blocks=2, memory_cache=None, kv_quant_type=kind)
-    assert (backend.num_kv_heads, backend.head_dim) == (4, 128)  # four heads: under four a row folds whatever its width (the rule's test above)
+    assert (backend.num_kv_heads, backend.head_dim) == (hkv, 128) and backend.cache.index_row is None
     shapes = [(d.shape, jnp.dtype(d.dtype)) for d in lane_pools(backend, 6, 8, end=2)[0]]
-    if kind == "none":
-        assert backend.cache.pool_row == (4, 128) and shapes == [((2, 6, 8, 4, 128), jnp.dtype(backend.cache_dtype))] * 2
-    elif kind == "int8":
-        assert backend.cache.pool_row == (4, 128)
-        assert shapes == [((2, 6, 8, 4, 128), jnp.dtype(jnp.int8))] * 2 + [((2, 6, 8, 4), jnp.dtype(jnp.float32))] * 2
-    else:
-        assert backend.cache.pool_row == (256,)
-        assert shapes == [((2, 6, 8, 256), jnp.dtype(jnp.uint8))] * 2 + [((2, 6, 8, 4), jnp.dtype(jnp.float32))] * 2
+    d_store = _d_store(kind, 128)
+    row = (hkv, d_store) if hkv == 8 and d_store == 128 else (hkv * d_store,)
+    codes = {"none": jnp.dtype(backend.cache_dtype), "int8": jnp.dtype(jnp.int8), "nf4a": jnp.dtype(jnp.uint8)}[kind]
+    assert backend.cache.pool_row == row
+    assert shapes == [((2, 6, 8, *row), codes)] * 2 + ([] if kind == "none" else [((2, 6, 8, hkv), jnp.dtype(jnp.float32))] * 2)
+    # which walk a decode row of such a pool takes on a TPU: the kernel over the plain folded row, the composed one over codes
+    from petals_tpu.ops import paged_flash_attention as pfa
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(pfa, "_on_tpu", lambda: True)
+        walks = backend.cache.lane_pool(3, 4, 16).walks
+    assert [walk[-1] for walk in walks] == ["kernel" if (hkv, kind) == (4, "none") else "composed"]
+
+
+@pytest.mark.parametrize("name,row,index", [
+    ("keye-vl2-30b-a3b-span5", (4, 128), True), ("smallthinker-21b-a3b-span12", (512,), False), ("qwen3-next-80b-a3b-span8-ep4", (512,), False),
+    ("jamba2-3b-span28", (128,), False), ("mixtral-8x7b-span2", (8, 128), False), ("falcon-40b-span5", (512,), False),
+])
+def test_a_published_span_s_row_follows_what_its_decode_rows_do_with_the_pool(tmp_path, name, row, index):
+    """``SpanCache.pool_row`` tells the rule what the span's decode rows do: 4 kv heads of 128 are folded where pages are
+    walked (SmallThinker's two page groups) and stay rows of ``[4, 128]`` in a span WITH an index row, whose sparse
+    attention fetches the rows it chose one by one (Keye-VL-2.0's; its compiled programs are the parent's). The others
+    as they were: on the configurations' shapes alone."""
+    cache, blocks = published_span_cache(name, tmp_path)
+    assert (cache.index_row is not None) == index and cache.pool_row == row
+    pages = cache.pool_descriptors(cache.group_pages(2, 4, 64, 64) if cache.grouped else 8, 64, 2, 0, blocks)
+    assert all(tuple(d.shape[3:]) == row for d in pages[: 2 * max(len(cache.page_groups), 1)])
 
 
 def _one_step_apart(got, want, codes_dtype) -> int:

@@ -1228,6 +1228,26 @@ def make_tiny_smallthinker(tmpdir: str, **overrides) -> str:
 # what a lane holds for a span's blocks (server/span_cache.py), as the tests ask a backend for it
 
 
+def published_span_cache(name: str, tmp_path):
+    """``(SpanCache, blocks)`` of the benchmark's configuration ``perf/configs/<name>.json`` on shapes alone (no weights, no
+    backend), bfloat16 pages as a chip serves them."""
+    import json
+    from pathlib import Path
+
+    import jax.numpy as jnp
+
+    from perf.config import load as load_config
+    from petals_tpu.models.registry import span_runs
+    from petals_tpu.server.from_pretrained import get_block_config
+    from petals_tpu.server.span_cache import SpanCache
+
+    config = load_config(Path(__file__).resolve().parents[1] / "perf" / "configs" / f"{name}.json", name)
+    (Path(tmp_path) / "config.json").write_text(json.dumps(config["config"]))
+    family, cfg = get_block_config(str(tmp_path))
+    blocks = config["server_args"]["num_blocks"]
+    return SpanCache(family, cfg, span_runs(family.span_kinds(cfg, 0, blocks)), cache_dtype=jnp.bfloat16), blocks
+
+
 def lane_pools(backend, n_pages: int, page_size: int, n_lanes: int = 1, *, start: int = 0, end=None) -> tuple:
     """``(the page pools' descriptors, those of the pools beside them)`` of a paged lane pool over blocks [start, end)
     of ``backend``'s span (default: to its end): ``SpanCache.pool_descriptors``, split where the step programs split it."""
