@@ -17,6 +17,12 @@ owned), and times, a step, on this thread's clock (medians over ``--steps`` step
 ``copies``    ``jax.device_put`` of each host array a step sends, alone: ``put`` until it returns, ``done`` until
               the array is on the device
 ``back``      ``np.asarray`` of a result that is already computed: the copy back alone
+``behind``    two ``packed`` launches back to back, as the batcher makes them for two groups of lanes that take turns
+              (``DecodeBatcher._start_behind``): a second thread waits for the first step's rows (``first.home``, from
+              the first launch's start) while this one launches the second behind it. ``idle.dispatch`` is the launch
+              on an idle chip, ``behind.dispatch`` the same launch beside a step in flight and a thread that waits
+              for it; ``second.home`` is when the second step's rows are there, and ``second.after_first`` what lies
+              between the two: a step's time on the device where the second launch was hidden behind the first
 ``leaves``    the arrays that cross the jit boundary a call (weights, pools, state, the step's inputs)
 
 Each row twice: with the interpreter quiet, and (``busy``) with a second thread that builds and serialises
@@ -36,6 +42,7 @@ import sys
 import threading
 import time
 from pathlib import Path
+from queue import SimpleQueue
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
@@ -140,6 +147,43 @@ def main(argv=None) -> int:
             return {"dispatch": t1 - t0, "wait": time.perf_counter() - t1}
 
         rows[f"packed{tag}"] = loop(packed)
+
+        buffers = (buffer, buffer.copy())  # a launch does not write rows whose copy in may still be read
+        waiting: SimpleQueue = SimpleQueue()
+        home: SimpleQueue = SimpleQueue()
+
+        def readback() -> None:
+            for out in iter(waiting.get, None):
+                np.asarray(out)
+                home.put(time.perf_counter())
+
+        waiter = threading.Thread(target=readback, daemon=True)
+        waiter.start()
+
+        def behind(step):
+            nonlocal pools
+            for lanes in buffers:
+                lanes[:, hsz] = positions_at(step)
+            t0 = time.perf_counter()
+            first, pools = backend.paged_decode_step(buffers[0], pools, buffers[0][:, hsz], tables_dev)
+            first.copy_to_host_async()
+            t1 = time.perf_counter()
+            waiting.put(first)
+            t2 = time.perf_counter()
+            second, pools = backend.paged_decode_step(buffers[1], pools, buffers[1][:, hsz], tables_dev)
+            second.copy_to_host_async()
+            t3 = time.perf_counter()
+            np.asarray(second)
+            t4 = time.perf_counter()
+            first_home = home.get()
+            return {"idle.dispatch": t1 - t0, "behind.dispatch": t3 - t2, "first.home": first_home - t0,
+                    "second.home": t4 - t0, "second.after_first": t4 - first_home}
+
+        try:
+            rows[f"behind{tag}"] = loop(behind)
+        finally:
+            waiting.put(None)
+            waiter.join()
         host_arrays = {"lanes": buffer, "tables": tables, "positions": positions_at(0)}  # the last two: what a step sent before PR 51
 
         def copies(step):

@@ -34,6 +34,11 @@ sides of that sum are measured, per lane and per server, and nothing is
 configured). Without it lanes whose clients answer within a few milliseconds
 settle into groups that take turns, and every token's gap is two steps.
 Single-stream latency is untouched (a lone request flushes immediately).
+Where lanes do ride as groups that take turns (clients further away than a
+step), a group's step is launched while the other's is still on the chip
+(DecodeBatcher._start_behind, by the same rule) and a step's rows leave before
+its bookkeeping, so a lane's gap holds its own step's host part and not the
+other group's as well.
 """
 
 from __future__ import annotations
@@ -47,6 +52,7 @@ import statistics
 import threading
 import time
 from collections import deque
+from queue import SimpleQueue
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -69,7 +75,7 @@ from petals_tpu.server.memory_cache import (
 )
 from petals_tpu.server.scheduler import SessionScheduler, SwapEntry
 from petals_tpu.server.spec_decode import min_accept_floor
-from petals_tpu.server.task_queue import PRIORITY_INFERENCE, PriorityTaskQueue
+from petals_tpu.server.task_queue import PRIORITY_INFERENCE, PriorityTaskQueue, TaskRejected
 from petals_tpu.telemetry import get_journal
 from petals_tpu.telemetry import instruments as tm
 from petals_tpu.utils.asyncio_utils import log_exception_callback
@@ -155,14 +161,22 @@ class _LaneReturn:
     eta: Optional[float] = None  # predicted arrival; None: not expected
     returns: deque = dataclasses.field(default_factory=lambda: deque(maxlen=5))
 
+    def usual(self) -> Optional[float]:
+        """The return to reckon with; None for a lane that has never come back, which is not predicted."""
+        return sum(self.returns) / len(self.returns) if self.returns else None
+
     def reply_sent(self, now: float) -> None:
         self.replied = now
-        if self.returns:  # a lane that has never come back is not predicted
-            self.eta = now + sum(self.returns) / len(self.returns)
+        if self.returns:
+            self.eta = now + self.usual()
 
     def came_back(self, now: float) -> None:
         self.returns.append(now - self.replied)
         self.replied = self.eta = None
+
+    def predictable(self, step_s: float) -> bool:
+        """It has come back before, each of its last returns in less than a step."""
+        return bool(self.returns) and max(self.returns) < step_s
 
     def expected(self, now: float, step_s: float) -> bool:
         """Is this lane on its way back so that a step could wait for it: out
@@ -173,7 +187,40 @@ class _LaneReturn:
         thinks)."""
         if self.eta is None:
             return False
-        return max(self.returns) < step_s and now - self.eta < self.eta - self.replied
+        return self.predictable(step_s) and now - self.eta < self.eta - self.replied
+
+
+@dataclasses.dataclass(eq=False)
+class _StepInFlight:
+    """One plain decode step of the paged pool from the flush loop's decision
+    to start it to its bookkeeping, handed from thread to thread and written
+    by one at a time: the event loop makes it (``DecodeBatcher._launch``), the
+    compute thread launches it (``_launch_batch``), the readback thread waits
+    for its rows (``_readback_loop``), the event loop hands them to the lanes
+    (``_step_home``) and the compute thread books it (``_finish_batch``)."""
+
+    batch: list  # the entries of ``_pending`` it carries
+    generation: int
+    loop: asyncio.AbstractEventLoop
+    behind: bool  # started with another step in flight: it queues behind that one on the device
+    end_eta: float  # when its rows should be on the host, as the start reckoned (S and the lead as measured then)
+    # the launch's (compute thread)
+    t_step: float = 0.0
+    out: Any = None  # [n_lanes, 1, hidden] on the device, its copy to the host queued behind the step
+    fp: Any = None  # the step's fused fingerprints on the device, or None
+    # every lane's position as the step was fed and the pages it held then (a group): the counters' view of the
+    # step, kept because the lanes have moved on by the time it is booked
+    positions: Optional[np.ndarray] = None
+    held: Tuple[np.ndarray, Optional[tuple]] = (None, None)
+    # the readback's
+    rows: Optional[np.ndarray] = None
+    fp_rows: Optional[np.ndarray] = None
+    rows_at: float = 0.0  # the rows were on the host
+    error: Optional[BaseException] = None
+
+    @property
+    def lanes(self) -> List[int]:
+        return [entry[0] for entry in self.batch]
 
 
 @dataclasses.dataclass
@@ -214,7 +261,25 @@ class _WindowGroup:
 
 
 class DecodeBatcher:
-    """Shared-pool continuous batcher for one backend (one span of blocks)."""
+    """Shared-pool continuous batcher for one backend (one span of blocks).
+
+    Who owns the pools. The pool's buffers live in the ``MemoryCache`` and every
+    program that touches them runs from the compute thread, one run at a time:
+    a run reads the buffers there (``_buffers``, ``_state``), calls its program
+    with them donated, and swaps what the call returned back in before it ends
+    (``_update``, under ``_reset_lock`` with the generation check). What it
+    swaps in may be arrays the device has not computed yet: JAX hands them on
+    as such, and **the device runs the programs of one process in the order
+    they were launched**, so the next run's program (a decode step launched
+    behind the one in flight, an exclusive op, a copy-on-write fork, a swap, a
+    snapshot) reads pools that every launch before it has written, though no
+    thread has waited for any of them. Nothing else orders two steps in
+    flight, and nothing else needs to: no step's program starts against a pool
+    that a step launched before it has yet to write
+    (tests/test_batching_overlap.py holds the tokens of two overlapping groups
+    to the serial batcher's, bit for bit). A reply is another matter: a lane's
+    rows leave only once THEY are on the host (``_readback_loop``), and a pool
+    reset fails the lanes of every step in flight (``_step_home``)."""
 
     def __init__(
         self,
@@ -300,13 +365,16 @@ class DecodeBatcher:
         self._tables_version = 0
         self._tables_on_device: Tuple[int, Any] = (-1, None)
         # the lanes' rows and positions of a paged decode or mixed step, in the form its program takes
-        # (backend.pack_lanes: [n_lanes, hidden + 1] int32, a float32 row bit for bit and the position last) and in
-        # ONE buffer, made once: a step body writes its batch's rows and every lane's position and hands it over
-        # whole, one copy to the device. An idle lane keeps the row it last fed (any finite filler will do for a row
-        # at the sentinel position); ``release_lane`` zeroes it, so a new tenant's neighbours never step beside a
-        # stranger's row. ``_lanes_rows`` is the float32 view of the rows
-        self._lanes_in: Optional[np.ndarray] = None
+        # (backend.pack_lanes: [n_lanes, hidden + 1] int32, a float32 row bit for bit and the position last), in
+        # TWO buffers made once and filled in turn (``_fill_lanes``): a step body writes its batch's rows and every
+        # lane's position into one and hands it over whole, one copy to the device; the launch after it takes the
+        # other, because the first's copy may still be read when a second step is launched behind it. An idle lane
+        # keeps the row it last fed there (any finite filler will do for a row at the sentinel position);
+        # ``release_lane`` zeroes it in both, so a new tenant's neighbours never step beside a stranger's row.
+        # ``_lanes_rows`` is the float32 view of the rows
+        self._lanes_in: Optional[np.ndarray] = None  # [2, n_lanes, hidden + 1]
         self._lanes_rows: Optional[np.ndarray] = None
+        self._lanes_turn = 0
         # cached tables_are_contiguous result for the stats/debug surface
         # (paged_summary); None = recompute on next read. The STEP path no
         # longer consults it — paged attention serves identity and permuted
@@ -439,8 +507,21 @@ class DecodeBatcher:
         # for between two bodies: the seconds _gather waited since the last
         # body's return, and whether a lane has come back from a decode reply
         # since then (so a reply was out while there was nothing to run)
-        self._gathered = 0.0
+        # (from, to) of each gather's wait, for _split_idle to lay over its gap (the event loop appends, the compute
+        # thread pops from the left: a deque, so neither loses the other's), and since when one is waiting now
+        self._gathered: deque = deque()
+        self._gathering: Optional[float] = None
         self._back_since_step = False
+        # plain decode steps of the paged pool that are started and whose rows are not yet with their lanes (event
+        # loop; at most two, ``_start_behind``; ``_home_wake`` is set when one comes home), those launched whose time
+        # in flight the compute thread has yet to count (its own list: ``_split_idle``), and the thread that waits
+        # for a launched step's rows (``_readback_loop``: started with the first launch, ended once the batcher is
+        # closed and no step is in flight, ``_end_readback``)
+        self._flights: List[_StepInFlight] = []
+        self._home_wake = asyncio.Event()
+        self._aloft: List[_StepInFlight] = []
+        self._readback: SimpleQueue = SimpleQueue()
+        self._readback_thread: Optional[threading.Thread] = None
         # the gather (_gather): each lane's returns after its decode replies,
         # the median wall of the last decode step bodies that carried no
         # prompt chunk (the step a late lane sits out), and the event with
@@ -448,6 +529,10 @@ class DecodeBatcher:
         self._returns: Dict[int, _LaneReturn] = {}
         self._step_walls: deque = deque(maxlen=9)
         self._step_s = 0.0
+        # and of the last launches, the host's part before the device has the step (assemble and dispatch): how long
+        # before a step in flight ends the one behind it is due (``_start_behind``)
+        self._lead_walls: deque = deque(maxlen=9)
+        self._lead_s = 0.0
         self._gather_wake = asyncio.Event()
         self._open_lock = make_async_lock("batching._open_lock")
         self._closed = False
@@ -463,6 +548,8 @@ class DecodeBatcher:
         # which code paths had run
         self.stats = {
             "batched_steps": 0, "batched_tokens": 0, "max_batch": 0,
+            # of the batched steps, the decode steps launched while the step before was still in flight (_start_behind)
+            "overlapped_steps": 0,
             # step bodies that copied the block tables to the device (the rest reused the copy there)
             "tables_sent": 0,
             "gen_steps": 0, "gen_lane_tokens": 0, "max_gen_lanes": 0,
@@ -598,8 +685,8 @@ class DecodeBatcher:
                 self._lane_held = np.zeros(self.n_lanes, np.int64)
                 self._win = self._new_window_groups()
                 hsz = self.backend.hidden_size
-                self._lanes_in = np.zeros((self.n_lanes, hsz + 1), np.int32)
-                self._lanes_rows = self._lanes_in[:, :hsz].view(np.float32)
+                self._lanes_in = np.zeros((2, self.n_lanes, hsz + 1), np.int32)
+                self._lanes_rows = self._lanes_in[:, :, :hsz].view(np.float32)
                 logger.info(
                     f"Paged-batching pool open: {self.n_pages} pages x "
                     f"{self.page_size} tokens of {list(self.backend.cache.pool_row)} ({self.n_lanes} lanes x "
@@ -617,6 +704,7 @@ class DecodeBatcher:
     async def close(self) -> None:
         self._closed = True
         self._gather_wake.set()
+        self._end_readback()
         for w in self._lane_waiters:
             if not w.fut.done():
                 w.fut.set_exception(AllocationFailed("Batcher is shutting down"))
@@ -836,7 +924,7 @@ class DecodeBatcher:
             self._write_tables(lane, slice(None), -1)
             for group in self._win:
                 self._window_release(group, lane, self.max_pages)
-            self._lanes_rows[lane] = 0.0  # the next tenant's neighbours step beside zeros, not this tenant's last row
+            self._lanes_rows[:, lane] = 0.0  # the next tenant's neighbours step beside zeros, not this tenant's last row
         # hand straight to the best-placed waiter (priority class, then
         # per-peer fair share, then FIFO), else back to the free list; the
         # new session overwrites the lane from position 0, so no zeroing
@@ -1701,6 +1789,8 @@ class DecodeBatcher:
                 ]
             # of the step bodies so far, those that copied the block tables to the device (``_step_tables``)
             info["tables_sent"], info["batched_steps"] = self.stats["tables_sent"], self.stats["batched_steps"]
+            # ... and those launched while the step before was still in flight (``_start_behind``)
+            info["overlapped_steps"] = self.stats["overlapped_steps"]
         info.update(self._scheduler.summary())
         return info
 
@@ -1861,7 +1951,7 @@ class DecodeBatcher:
                 log_exception_callback(logger, "decode flush loop")
             )
 
-    def _gather_until(self, now: float) -> Tuple[Optional[float], List[int]]:
+    def _gather_until(self, now: float, flight: Optional[_StepInFlight] = None) -> Tuple[Optional[float], List[int]]:
         """The rule of the gather. N units of work are ready (pending decode
         lanes, generating and speculating lanes, one admitted prompt chunk)
         and some lanes are expected (``_LaneReturn.expected``: the decode
@@ -1872,18 +1962,42 @@ class DecodeBatcher:
         w < S x M / (N + M). Over the expected lanes in the order of their
         predicted arrival, the largest M whose arrival lies inside that bound
         gives the lanes to wait for, and the bound itself the time until
-        which their coming still pays; (None, []) says start now."""
+        which their coming still pays; (None, []) says start now.
+
+        S is what a lane that misses the step sits out. For a tick that
+        carries a prompt chunk or a generating lane that is a step's wall, as
+        ever. For a plain decode step of the paged pool it is less: the late
+        lanes' own step is launched behind this one (``_start_behind``), its
+        host part is paid while this one is on the chip, and what they sit out
+        is this step's time on the device, its wall less a launch's lead (and
+        no less than that lead: the compute thread launches one step at a
+        time). The sum is the same one, N x w against M x (S - w), with the
+        term measured that the overlap changed.
+
+        Asked with a step in flight (``_start_behind``, which asks as at that
+        step's end), ``flight``'s lanes are expected too: their replies leave
+        when its rows are home, so each is due its usual return after that."""
         step_s = self._step_s
         ready = len(self._pending) + len(self._gen_states) + bool(self._prefill_queue)
         if not step_s or not ready:
             return None, []
-        expected = sorted(
+        sits_out = step_s
+        if self.page_size is not None and not self._gen_states and not self._prefill_queue:
+            sits_out = max(step_s - self._lead_s, min(self._lead_s, step_s))
+        expected = [
             (back.eta, lane) for lane, back in self._returns.items()
             if back.expected(now, step_s)
-        )
+        ]
+        if flight is not None:
+            home = max(flight.end_eta, now)
+            for lane in flight.lanes:
+                back = self._returns.get(lane)
+                if back is not None and back.predictable(step_s):
+                    expected.append((home + back.usual(), lane))
+        expected.sort()
         until, count = None, 0
         for m, (eta, _lane) in enumerate(expected, 1):
-            pays_for = step_s * m / (ready + m)
+            pays_for = sits_out * m / (ready + m)
             if eta - now < pays_for:
                 until, count = now + pays_for, m
         return until, [lane for _eta, lane in expected[:count]]
@@ -1910,6 +2024,7 @@ class DecodeBatcher:
             if until is None:
                 break
             waited_for.update(lanes)
+            self._gathering = start
             try:
                 # the one annotation that spans an await: at most one flush
                 # task is alive, and whatever else the loop's thread runs
@@ -1919,11 +2034,11 @@ class DecodeBatcher:
             except asyncio.TimeoutError:
                 break
             now = time.perf_counter()
+        self._gathering = None
         if not waited_for:
             return
         waited = time.perf_counter() - start
         self.stats["gather_waits"] += 1
-        self.stats["gather_wait_s"] += waited
         pending = {entry[0] for entry in self._pending}
         for lane in waited_for:
             back = self._returns.get(lane)
@@ -1933,38 +2048,103 @@ class DecodeBatcher:
                 self.stats["gather_missed"] += 1
                 back.eta = None
         # the batcher chose to have nothing running: that is no hand-off
-        # (turnaround_s, handoff_s: a step to run and the host in the way)
-        self._gathered += waited
+        # (turnaround_s, handoff_s: a step to run and the host in the way), and
+        # gather_wait_s is what of it the compute thread had nothing in flight
+        # and nothing to run (_split_idle lays it over that thread's gap)
+        self._gathered.append((start, start + waited))
+
+    async def _start_behind(self) -> bool:
+        """In ``_gather``'s place while a step is in flight: whether to start
+        the pending lanes' step behind it. The device runs the two programs in
+        order, so the second gains nothing by an early start but loses the
+        lanes that would still have joined it: it is due when the host's part
+        of a launch (``_lead_s``: assemble and dispatch, as measured) will
+        just be over as the step ahead ends (``_StepInFlight.end_eta``).
+        Until then nothing that arrives changes anything and only a step
+        coming home ends the wait; from then ``_gather_until`` is asked what
+        it would be asked at that end, with the lanes in flight among the
+        expected. So which lanes ride together is decided as it is with one
+        step at a time; what moves is when the host's part of that step is
+        paid. True: start it now (the rule said so, or the time it gave ran
+        out). False: look again (a step came home, a lane arrived or left, the
+        step became due). At most two steps are in flight, because a third
+        could only queue behind the second; a tick that carries a prompt
+        chunk or a generating lane waits here until none is; and nothing is
+        started behind a step whose launch has not returned (the compute
+        thread could not run it yet: look again when a lane arrives or that
+        step comes home)."""
+        ahead, now, until = self._flights[-1], time.perf_counter(), None
+        one = len(self._flights) < 2 and not self._gen_states and not self._prefill_queue and not self._closed
+        wake, wait = self._gather_wake, None  # nothing to start: until a lane arrives or a step comes home
+        if one and self._pending and ahead.out is not None:
+            due = ahead.end_eta - self._lead_s
+            if now < due:
+                wake, wait = self._home_wake, due - now
+            else:
+                until, _lanes = self._gather_until(now, ahead)
+                if until is None:
+                    return True
+                wait = until - now
+        wake.clear()
+        if wait is None:
+            await wake.wait()
+            return False
+        try:
+            await asyncio.wait_for(wake.wait(), wait)
+        except asyncio.TimeoutError:
+            return until is not None
+        return False
+
+    def _drop_stale(self) -> None:
+        """Whatever was queued before a pool reset fails loudly (event loop):
+        running it against the rematerialized (zeroed) pool would be the
+        silent corruption the generation machinery exists to prevent."""
+        stale = [e for e in self._pending if e[4] != self._generation]
+        if stale:
+            self._pending = [e for e in self._pending if e[4] == self._generation]
+        for *_, fut, _gen in stale:
+            if not fut.done():
+                fut.set_exception(AllocationFailed(
+                    "Lane pool was reset while this step was pending"
+                ))
+        # same staleness rule for mid-generation lanes
+        for lane, st in list(self._gen_states.items()):
+            if st.generation != self._generation:
+                del self._gen_states[lane]
+                if not st.future.done():
+                    st.future.set_exception(AllocationFailed(
+                        "Lane pool was reset while this step was pending"
+                    ))
+        # ...and for admitted prefills
+        for pst in [p for p in self._prefill_queue if p.generation != self._generation]:
+            self._prefill_queue.remove(pst)
+            if not pst.future.done():
+                pst.future.set_exception(AllocationFailed(
+                    "Lane pool was reset while this step was pending"
+                ))
 
     async def _flush_loop(self) -> None:
-        while self._pending or self._gen_states or self._prefill_queue:
-            await self._gather()
+        """One tick a turn: wait for the lanes worth waiting for, take what is
+        pending, run it as one step, hand the rows back. A plain decode step
+        of the paged pool is not awaited: it is started (``_launch``) and the
+        loop comes round again at once, and while its rows are not home the
+        pending lanes' next step may be started behind it (``_start_behind``),
+        so that the host's part of a step, its launch as much as its
+        bookkeeping and the hand-offs around them, is off the path of the
+        lanes that did not ride it. The rows of such a step reach its lanes
+        from ``_step_home``. Every other tick (a prompt chunk, generating or
+        speculating lanes, the dense pool) starts with nothing in flight and
+        is awaited whole, as ever."""
+        while self._pending or self._gen_states or self._prefill_queue or self._flights:
+            if self._flights:
+                start = await self._start_behind()
+            else:
+                await self._gather()
+                start = True
+            self._drop_stale()
+            if not start:
+                continue
             batch, self._pending = self._pending, []
-            # entries enqueued before a pool reset must fail loudly — running
-            # them against the rematerialized (zeroed) pool would be the
-            # silent corruption the generation machinery exists to prevent
-            stale = [e for e in batch if e[4] != self._generation]
-            batch = [e for e in batch if e[4] == self._generation]
-            for *_, fut, _gen in stale:
-                if not fut.done():
-                    fut.set_exception(AllocationFailed(
-                        "Lane pool was reset while this step was pending"
-                    ))
-            # same staleness rule for mid-generation lanes
-            for lane, st in list(self._gen_states.items()):
-                if st.generation != self._generation:
-                    del self._gen_states[lane]
-                    if not st.future.done():
-                        st.future.set_exception(AllocationFailed(
-                            "Lane pool was reset while this step was pending"
-                        ))
-            # ...and for admitted prefills
-            for pst in [p for p in self._prefill_queue if p.generation != self._generation]:
-                self._prefill_queue.remove(pst)
-                if not pst.future.done():
-                    pst.future.set_exception(AllocationFailed(
-                        "Lane pool was reset while this step was pending"
-                    ))
             gen_states = dict(self._gen_states)
             # speculating lanes leave the plain gen dict for this tick and
             # ride their own draft-verify step; their verify rows share the
@@ -1981,7 +2161,7 @@ class DecodeBatcher:
             if not batch and not gen_states and not spec_states and pf is None:
                 continue
             try:
-                toks = chunk_out = spec_res = None
+                out = toks = chunk_out = spec_res = None
                 if spec_states:
                     spec_res = await self.queue.submit(
                         self._run_batch_spec, spec_states,
@@ -2007,6 +2187,9 @@ class DecodeBatcher:
                         self._run_batch_mixed, batch, pf,
                         priority=PRIORITY_INFERENCE, size=len(batch) + pf[1],
                     )
+                elif batch and self.page_size is not None:
+                    self._launch(batch)
+                    continue
                 elif batch:
                     out = await self.queue.submit(
                         self._run_batch, batch, priority=PRIORITY_INFERENCE,
@@ -2031,19 +2214,7 @@ class DecodeBatcher:
                         pst.future.set_exception(e)
                 self._maybe_reset_pool()
                 continue
-            replied = time.perf_counter()
-            if batch:
-                self.stats["reply_steps"] += 1
-                self.stats["reply_wake_s"] += replied - self._last_step_end[0]
-            with device_annotation("ptu.flush.resolve", lanes=len(batch)):
-                for lane, _, _, fut, _gen in batch:
-                    if not fut.done():
-                        fut.set_result(out[lane : lane + 1])
-                        if lane in self._returns:  # not released while the step ran
-                            self._returns[lane].reply_sent(replied)
-                        timing = self._step_timing.get(lane)
-                        if timing is not None:
-                            timing["replied"] = replied  # for the handler's count_decode_reply
+            self._reply(batch, out, self._last_step_end[0])
             if pf is not None and chunk_out is not None:
                 self._advance_prefill(pf[0], pf[1], chunk_out)
             if spec_res is not None:
@@ -2071,6 +2242,101 @@ class DecodeBatcher:
                         st.future.set_result(
                             np.asarray([st.collected], np.int32)
                         )
+
+    def _reply(self, batch, out, since: float) -> None:
+        """A step's rows to its lanes (event loop): each future resolved, the
+        lane's way back on record from this moment, and the step's
+        ``_step_timing`` stamped for the handler's ``count_decode_reply``.
+        ``since`` is when the rows were there to be handed out (a whole
+        body's return, the readback's for a step that was launched)."""
+        replied = time.perf_counter()
+        if batch:
+            self.stats["reply_steps"] += 1
+            self.stats["reply_wake_s"] += replied - since
+        with device_annotation("ptu.flush.resolve", lanes=len(batch)):
+            for lane, _, _, fut, _gen in batch:
+                if not fut.done():
+                    fut.set_result(out[lane : lane + 1])
+                    if lane in self._returns:  # not released while the step ran
+                        self._returns[lane].reply_sent(replied)
+                    timing = self._step_timing.get(lane)
+                    if timing is not None:
+                        timing["replied"] = replied  # for the handler's count_decode_reply
+
+    def _launch(self, batch) -> None:
+        """Start ``batch``'s plain decode step (event loop): its launch goes
+        to the compute thread and nobody waits for it here. ``end_eta`` is
+        when its rows should be home: a step's wall from now on an idle chip;
+        behind another, that one's end plus what a step takes the device."""
+        now = time.perf_counter()
+        ahead = self._flights[-1] if self._flights else None
+        end_eta = now + self._step_s
+        if ahead is not None:
+            end_eta = max(end_eta, ahead.end_eta + self._step_s - self._lead_s)
+        flight = _StepInFlight(batch, batch[0][4], asyncio.get_running_loop(), ahead is not None, end_eta)
+        if self._readback_thread is None:
+            self._readback_thread = threading.Thread(target=self._readback_loop, name="ptu-readback", daemon=True)
+            self._readback_thread.start()
+        self.queue.put(lambda: self._launch_batch(flight), priority=PRIORITY_INFERENCE, size=len(batch))
+        self._flights.append(flight)
+
+    def _step_home(self, flight: _StepInFlight) -> None:
+        """A launched step's rows are on the host, or it failed (event loop,
+        called from the readback thread or, for a launch that raised, the
+        compute thread). The flush loop is woken first, then the lanes get
+        their rows with what a reply carries of the step (``_step_timing``,
+        ``_step_fp``); the step's bookkeeping (``_finish_batch``) is queued
+        behind the turns those replies have just been given (``_book``):
+        nothing a lane waits for lies behind a counter. The generation is
+        checked here, on the thread that
+        resets the pool: a reset that landed after the launch fails the lanes
+        of every step in flight, whose results the zeroed pool no longer
+        holds."""
+        self._flights.remove(flight)
+        self._home_wake.set()
+        self._gather_wake.set()
+        error = flight.error
+        if error is None and flight.generation != self._generation:
+            error = AllocationFailed("Lane pool was reset while this batched step ran")
+        if error is None:
+            self._record_decode_timing(flight.batch, flight.t_step, flight.rows_at - flight.t_step)
+            if flight.fp_rows is not None:
+                for lane in flight.lanes:
+                    self._step_fp[lane] = [float(x) for x in flight.fp_rows[lane]]
+            self._reply(flight.batch, flight.rows, flight.rows_at)
+        else:
+            for *_, fut, _gen in flight.batch:
+                if not fut.done():
+                    fut.set_exception(error)
+            # a step whose results the launch swapped in and which then failed has left in the pools what no lane wrote
+            self._maybe_reset_pool(broken=flight.error is not None and flight.out is not None)
+        if flight.out is not None:
+            flight.loop.call_soon(self._book, flight)
+        self._end_readback()
+
+    def _book(self, flight: _StepInFlight) -> None:
+        """Queue a step's bookkeeping (event loop, a turn of its own that
+        ``_step_home`` asked for once the lanes' futures were resolved). The
+        replies of a step are a chain of turns of this loop under one
+        interpreter lock, and the gap is set by the LAST lane back:
+        bookkeeping started beside the chain would take the lock at the first
+        reply's write and hold up every reply after it, as much as it did in
+        front of them all. The loop runs its turns in the order they were
+        asked for, so this one comes after each woken handler has had its
+        turn, in which a decode reply is built and written."""
+        try:
+            self.queue.put(lambda: self._finish_batch(flight), priority=PRIORITY_INFERENCE)
+        except TaskRejected:
+            pass  # the queue is shut down: nobody reads the counters
+
+    def _end_readback(self) -> None:
+        """Once the batcher is closed and every step in flight is home, let
+        the readback thread go (event loop). Not before: a launch that was
+        running at ``close`` still puts its step there, and its lanes still
+        get their rows (one that was only queued fails them loudly)."""
+        if self._closed and not self._flights and self._readback_thread is not None:
+            self._readback.put(None)
+            self._readback_thread = None
 
     def _window_pages_for_tick(self, pf, gen_states):
         """A grouped pool, before a tick's step is started (event loop): the windowed groups' pages of the prompt chunk
@@ -2385,20 +2651,23 @@ class DecodeBatcher:
                 if self._gen_states.get(lane) is st:
                     del self._gen_states[lane]
 
-    def _maybe_reset_pool(self) -> None:
-        """A failed batched step may have CONSUMED the donated pool buffers.
-        Zero the pool and invalidate every outstanding lane (generation bump)
-        — their KV is unrecoverable, and letting tenants silently decode
-        against zeros would corrupt outputs; their next step errors instead,
-        so clients re-open through the normal failover path."""
+    def _maybe_reset_pool(self, broken: bool = False) -> None:
+        """A failed batched step may have CONSUMED the donated pool buffers
+        (or, ``broken``, is known to have left them unusable: a launched step
+        that failed once its results were the pool). Zero the pool and
+        invalidate every outstanding lane (generation bump) — their KV is
+        unrecoverable, and letting tenants silently decode against zeros
+        would corrupt outputs; their next step errors instead, so clients
+        re-open through the normal failover path."""
         if self._handles is None:
             return
-        try:
-            k_pool, v_pool = self._buffers()
-            broken = k_pool.is_deleted() or v_pool.is_deleted()
-        except Exception as e:
-            logger.debug("Pool liveness probe raised (treating as consumed): %r", e)
-            broken = True
+        if not broken:
+            try:
+                k_pool, v_pool = self._buffers()
+                broken = k_pool.is_deleted() or v_pool.is_deleted()
+            except Exception as e:
+                logger.debug("Pool liveness probe raised (treating as consumed): %r", e)
+                broken = True
         if not broken:
             return  # routine failures (cancellation, rejects) leave the pool intact
         if self._lockstep:
@@ -2469,53 +2738,81 @@ class DecodeBatcher:
         return arrived
 
     @contextlib.contextmanager
-    def _step_phases(self, variant: str, lanes: int, prefill_tokens: int = 0, arrived=()):
-        """The phase clock shared by the four step bodies (compute thread):
+    def _step_phases(self, variant: str, lanes: int, prefill_tokens: int = 0, arrived=(), first: str = "assemble", spawn: Optional[int] = None):
+        """The phase clock shared by the step bodies (compute thread):
         ``assemble_s`` from the body's entry to the backend call,
         ``dispatch_s`` the backend call (the device starts inside it),
         ``wait_s`` blocked until the outputs are on the host, ``post_s`` from
         there to the return; the same boundaries are ``ptu.step.*``
-        annotations in a profiler trace. ``turnaround_s`` is the time since
-        the previous body returned, counted only where the flush task stayed
-        alive in between: results to the event loop, futures set, the next
-        ``queue.submit``, this thread's wake-up (and whatever else the queue
-        ran meanwhile), less what the gather waited.
+        annotations in a profiler trace. A plain decode step of the paged pool
+        is two runs of this thread, its launch (``assemble``, ``dispatch``)
+        and its bookkeeping (``first="post"``), and this thread does not block
+        in between (``_readback_loop`` does): ``wait_s`` is then the time this
+        thread had a step in flight (launched, its rows not yet on the host by
+        the readback's reading) and nothing to run. With two in flight that
+        time is one stretch, not two, so the clocks keep tiling the wall.
 
-        Whichever flush task carried it, the time since the previous body
-        returned is split by what this thread, and so the chip, waited for.
-        Up to the earliest of ``arrived`` (``_arrivals``) there was nothing
-        to run: ``lanes_out_s`` where a decode reply was out meanwhile (a lane
-        is still out, or one has come back since), every live lane's token on
+        ``turnaround_s`` is the time since this thread's last run ended with
+        nothing in flight, counted only where the flush task stayed alive in
+        between: results to the event loop, futures set, the next
+        ``queue.put``, this thread's wake-up (and whatever else the queue ran
+        meanwhile), less what the gather waited. A step started behind another
+        has no turnaround: that is the point of starting it there.
+
+        Whichever flush task carried it, that time with nothing in flight is
+        split by what this thread, and so the chip, waited for. Up to the
+        earliest of ``arrived`` (``_arrivals``) there was nothing to run:
+        ``lanes_out_s`` where a decode reply was out meanwhile (a lane is
+        still out, or one has come back since), every live lane's token on
         its way; ``no_demand_s`` where none was, no session decoding. Of the
-        rest ``gather_wait_s`` is what ``_gather`` chose to wait, counted
-        there, and ``handoff_s`` what is left: work there and the host in the
-        way (futures, the task's spawn, ``queue.submit``, this thread's
-        wake-up). The reading that opens ``assemble`` ends that stretch, and
-        the one that closes ``post`` begins the next (``_split_idle``, which
-        runs inside ``assemble``), so the eight clocks tile this thread's
+        rest ``gather_wait_s`` is what ``_gather`` chose to wait, and
+        ``handoff_s`` what is left: work there and the host in the way
+        (futures, the task's spawn, ``queue.put``, this thread's wake-up).
+        The reading that opens a run's first phase ends that stretch, and
+        the one that closes its last begins the next (``_split_idle``, which
+        runs inside the first phase), so the eight clocks tile this thread's
         wall from the first body's return on."""
-        phases = step_phases(self.stats, variant=variant, lanes=lanes, prefill_tokens=prefill_tokens)
+        phases = step_phases(self.stats, first, variant=variant, lanes=lanes, prefill_tokens=prefill_tokens)
         try:
             with phases:
                 self._split_idle(phases.started, arrived)
                 yield phases
         finally:
             self._back_since_step = False
-            self._last_step_end = (phases.ended, self._flush_spawns)
+            # the flush task that carried the step, which is alive while this body runs (a launched step's bookkeeping, which
+            # runs whenever, leaves the last launch's in place)
+            self._last_step_end = (phases.ended, self._flush_spawns if spawn is None else spawn)
 
     def _split_idle(self, entered: float, arrived) -> None:
-        """``_step_phases``' account of the time from the previous body's
-        return to this one's first phase (the same reading opened it, so
-        nothing falls between)."""
+        """``_step_phases``' account of the time from this thread's last run
+        to this one's first phase (the same reading opened it, so nothing
+        falls between)."""
         ended, spawn = self._last_step_end
-        gathered, self._gathered = self._gathered, 0.0
         if spawn < 0:
             return  # the first body: no return to reckon from
-        idle = entered - ended - gathered  # what the gather did not choose
-        if spawn == self._flush_spawns:
+        if self._aloft:
+            # a step was in flight: up to the reading its rows came home with (the readback's), this thread had
+            # nothing to run but that step's end to wait for
+            landed = [flight.rows_at for flight in self._aloft]
+            self._aloft = [flight for flight, at in zip(self._aloft, landed) if not at]
+            covered = entered if self._aloft else min(max(max(landed), ended), entered)
+            self.stats["wait_s"] += covered - ended
+            if covered == entered:
+                return
+            ended = covered
+        # what the gather chose to wait, as far as it lies in this gap (a wait that began while the
+        # step before was being booked is that run's time up to there)
+        gathered = [self._gathered.popleft() for _ in range(len(self._gathered))]
+        if self._gathering is not None:  # a step's bookkeeping that runs during a gather: the wait so far
+            gathered.append((self._gathering, entered))
+        waited = sum(max(min(to, entered) - max(since, ended), 0.0) for since, to in gathered)
+        self.stats["gather_wait_s"] += waited
+        idle = entered - ended - waited
+        if arrived is not None and spawn == self._flush_spawns:
             self.stats["turnaround_s"] += idle
         idle = max(idle, 0.0)
-        empty = min(max(min(arrived, default=0.0) - ended, 0.0), idle)
+        # (``arrived`` None: a step's bookkeeping, which nothing waited for: until it ran there was nothing to run)
+        empty = idle if arrived is None else min(max(min(arrived, default=0.0) - ended, 0.0), idle)
         if empty:
             out = self._back_since_step or any(
                 back.replied is not None for back in list(self._returns.values())
@@ -2563,34 +2860,43 @@ class DecodeBatcher:
 
     def _fill_lanes(self, batch) -> Tuple[np.ndarray, np.ndarray]:
         """``batch``'s rows and every lane's position written into the
-        lanes' one buffer (compute thread; ``_lanes_in``): ``(the buffer as
-        the step's program takes it, its column of positions)``, both views
-        good until the next step body fills them again. A lane out of the
-        batch rides at the idle sentinel, ``max_length``, with the row it
-        last fed."""
-        positions = self._lanes_in[:, -1]
+        lanes' buffer whose turn it is (compute thread; ``_lanes_in``): ``(the
+        buffer as the step's program takes it, its column of positions)``,
+        both views good until the step body after the next fills them again.
+        A lane out of the batch rides at the idle sentinel, ``max_length``,
+        with the row it last fed there."""
+        self._lanes_turn ^= 1
+        lanes_in = self._lanes_in[self._lanes_turn]
+        positions = lanes_in[:, -1]
         positions[:] = self.max_length
-        rows = self._lanes_rows
+        rows = self._lanes_rows[self._lanes_turn]
         for lane, h, pos, _fut, _gen in batch:
             rows[lane] = np.asarray(h, np.float32).reshape(-1)
             positions[lane] = pos
-        return self._lanes_in, positions
+        return lanes_in, positions
 
-    def _run_batch(self, batch) -> np.ndarray:
-        """Compute-thread body: ONE jitted step for every pending lane."""
-        variant = "paged" if self.page_size is not None else "dense"
-        with self._step_phases(variant, len(batch), arrived=self._arrivals(batch)) as phases:
-            # generation guards on BOTH sides of the device step: an exclusive
-            # op's failure can reset the pool from the event loop while this
-            # task is queued or mid-flight, and decoding against the
-            # rematerialized zeros must fail loudly, never resolve futures
-            if batch and batch[0][4] != self._generation:
-                raise AllocationFailed("Lane pool was reset before this batched step ran")
-            t_step = time.perf_counter()
-            paged = self.page_size is not None
-            k_pool, v_pool = self._buffers()
-            state = self._state()
-            if paged:
+    def _launch_batch(self, flight: _StepInFlight) -> None:
+        """Compute-thread body, first half: ONE jitted step for every pending
+        lane of the paged pool, launched and not waited for. The pools the
+        call returns are swapped in at once, as arrays the device has yet to
+        compute (the class docstring says why that is safe); the rows' copy to
+        the host is queued behind the step and ``_readback_loop`` waits for
+        it. A launch that raises hands its lanes the failure itself."""
+        batch = flight.batch
+        try:
+            with self._step_phases("paged", len(batch), arrived=self._arrivals(batch)) as phases:
+                # generation guards on BOTH sides of the call: an exclusive
+                # op's failure can reset the pool from the event loop while this
+                # task is queued or mid-call, and decoding against the
+                # rematerialized zeros must fail loudly, never resolve futures
+                # (a reset that lands later still: ``_step_home``)
+                if flight.generation != self._generation:
+                    raise AllocationFailed("Lane pool was reset before this batched step ran")
+                if self._closed:  # queued when ``close`` came, which has freed the pool
+                    raise AllocationFailed("Batcher is shutting down")
+                flight.t_step = time.perf_counter()
+                k_pool, v_pool = self._buffers()
+                state = self._state()
                 lanes_in, positions = self._fill_lanes(batch)
                 tables = self._step_tables()
                 phases.enter("dispatch")
@@ -2599,66 +2905,137 @@ class DecodeBatcher:
                     handles=self._handles,
                 )
                 out.copy_to_host_async()  # queued behind the step: the rows are on their way when it ends
+                pop_fp = getattr(self.backend, "pop_step_fp", None)  # the next launch's would take their place
+                fp = pop_fp()[0] if pop_fp is not None else None
+                with self._reset_lock:
+                    if flight.generation != self._generation:
+                        # the reset landed while the step was launched: the buffers it
+                        # read were either consumed (we would have raised) or already
+                        # zeroed. Checked atomically with the swap (under the reset
+                        # lock) so the freshly reset pool stays zeroed — swapping in
+                        # the stale stepped buffers would silently break the 'reset
+                        # leaves a zeroed pool' recovery invariant.
+                        raise AllocationFailed("Lane pool was reset while this batched step ran")
+                    self._update(k_pool, v_pool, *state)
+                by_group = self._held_by_group()
+                flight.held = (self._lane_held.copy(), by_group and tuple(held.copy() for held in by_group))
+                flight.out, flight.fp, flight.positions = out, fp, positions.copy()
+                self._aloft.append(flight)
+            self._lead_walls.append(phases.ended - phases.started)
+            self._lead_s = statistics.median(self._lead_walls)
+        except BaseException as e:  # noqa: BLE001 — deliver to every waiter
+            flight.error = e
+            flight.loop.call_soon_threadsafe(self._step_home, flight)
+            return
+        self._readback.put(flight)
+
+    def _readback_loop(self) -> None:
+        """The one thread that blocks until a launched step's rows are on the
+        host, so that the compute thread never does while a launch is due. It
+        holds no counter and no clock but the reading the rows came with, and
+        hands each step to the event loop in the order of the launches (the
+        device runs them in that order)."""
+        while True:
+            flight = self._readback.get()
+            if flight is None:
+                return
+            try:
+                with device_annotation("ptu.readback", lanes=len(flight.batch)):
+                    flight.rows = np.asarray(flight.out)  # device sync: the step has fully executed
+                    if flight.fp is not None:
+                        flight.fp_rows = np.asarray(flight.fp)
+            except BaseException as e:  # noqa: BLE001 — deliver to every waiter
+                flight.error = e
+            flight.rows_at = time.perf_counter()
+            try:
+                flight.loop.call_soon_threadsafe(self._step_home, flight)
+            except RuntimeError:
+                logger.debug("A step's rows came home to a closed event loop")
+                return
+
+    def _finish_batch(self, flight: _StepInFlight) -> None:
+        """Compute-thread body, second half: everything a launched step is
+        counted by, after its lanes have their rows (``_book`` queued
+        this). A step whose lanes were failed is not counted; its time is."""
+        batch = flight.batch
+        with self._step_phases("paged", len(batch), arrived=None, first="post", spawn=self._last_step_end[1]):
+            if flight.error is not None or flight.generation != self._generation:
+                return
+            duration = flight.rows_at - flight.t_step
+            self._book_decode_step(batch, flight.positions, duration, *flight.held)
+            if flight.behind:
+                self.stats["overlapped_steps"] += 1
             else:
-                hsz = self.backend.hidden_size
-                hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
-                positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
-                for lane, h, pos, _fut, _gen in batch:
-                    hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
-                    positions[lane] = pos
-                phases.enter("dispatch")
-                out, (k_pool, v_pool) = self.backend.batched_decode_step(
-                    hidden, (k_pool, v_pool), positions, handles=self._handles
-                )
+                self._note_step_wall(duration)
+
+    def _book_decode_step(self, batch, positions, duration: float, lane_held=None, group_held=None) -> None:
+        """The counters of one plain decode step (compute thread); of the paged pool's, with the pages each lane held
+        when the step was launched."""
+        paged = self.page_size is not None
+        self.stats["batched_steps"] += 1
+        self.stats["batched_tokens"] += len(batch)
+        self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
+        self._count_moe(len(batch))
+        self._count_stream(len(batch))
+        if paged:
+            self._pool.count_step(self.stats, positions, lane_held, group_held=group_held)
+            tm.STEP_PAGED.observe(duration)
+            tm.STEPS_PAGED.inc()
+        else:
+            tm.STEP_DENSE.observe(duration)
+            tm.STEPS_DENSE.inc()
+        tm.DECODE_TOKENS.inc(len(batch))
+        self._ledger_account_step(duration, decode_lanes=[entry[0] for entry in batch])
+
+    def _run_batch(self, batch) -> np.ndarray:
+        """Compute-thread body of the dense pool's decode step, whole: the
+        launch, the wait and the bookkeeping in one run."""
+        with self._step_phases("dense", len(batch), arrived=self._arrivals(batch)) as phases:
+            if batch and batch[0][4] != self._generation:  # see _launch_batch
+                raise AllocationFailed("Lane pool was reset before this batched step ran")
+            t_step = time.perf_counter()
+            k_pool, v_pool = self._buffers()
+            hsz = self.backend.hidden_size
+            hidden = np.zeros((self.n_lanes, 1, hsz), np.float32)
+            positions = np.full((self.n_lanes,), self.max_length, np.int32)  # idle sentinel
+            for lane, h, pos, _fut, _gen in batch:
+                hidden[lane] = np.asarray(h, np.float32).reshape(1, hsz)
+                positions[lane] = pos
+            phases.enter("dispatch")
+            out, (k_pool, v_pool) = self.backend.batched_decode_step(
+                hidden, (k_pool, v_pool), positions, handles=self._handles
+            )
             phases.enter("wait")
             host_out = np.asarray(out)  # device sync: the step has fully executed
             phases.enter("post")
             with self._reset_lock:
-                if batch and batch[0][4] != self._generation:
-                    # the reset landed while this step executed: the buffers it
-                    # read were either consumed (we would have raised) or already
-                    # zeroed. Checked atomically with the swap (under the reset
-                    # lock) so the freshly reset pool stays zeroed — swapping in
-                    # the stale stepped buffers would silently break the 'reset
-                    # leaves a zeroed pool' recovery invariant.
+                if batch and batch[0][4] != self._generation:  # see _launch_batch
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
-                self._update(k_pool, v_pool, *state)
-            self.stats["batched_steps"] += 1
-            self.stats["batched_tokens"] += len(batch)
-            self.stats["max_batch"] = max(self.stats["max_batch"], len(batch))
-            self._count_moe(len(batch))
-            self._count_stream(len(batch))
-            if paged:
-                self._pool.count_step(self.stats, positions, self._lane_held, group_held=self._held_by_group())
+                self._update(k_pool, v_pool)
             duration = time.perf_counter() - t_step
-            if paged:
-                tm.STEP_PAGED.observe(duration)
-                tm.STEPS_PAGED.inc()
-            else:
-                tm.STEP_DENSE.observe(duration)
-                tm.STEPS_DENSE.inc()
-            tm.DECODE_TOKENS.inc(len(batch))
+            self._book_decode_step(batch, positions, duration)
             self._note_step_wall(duration)
             self._record_decode_timing(batch, t_step, duration)
             self._capture_step_fp([entry[0] for entry in batch])
-            self._ledger_account_step(
-                duration, decode_lanes=[entry[0] for entry in batch]
-            )
         return host_out
 
     def _note_step_wall(self, duration: float) -> None:
         """S of the gather's rule: the median wall of the last decode and gen
-        step bodies (compute thread). A median, so that a body that compiled
-        or stalled does not pass for the step a late lane would sit out; a
-        mixed step is left out because its chunk makes it longer than the
-        step the rule reckons with, which errs towards waiting less."""
+        steps, from the launch to the rows on the host (compute thread). A
+        median, so that a step that compiled or stalled does not pass for the
+        step a late lane would sit out; a mixed step is left out because its
+        chunk makes it longer than the step the rule reckons with, which errs
+        towards waiting less, and so is a step launched behind another, whose
+        wall holds its wait for the device."""
         self._step_walls.append(duration)
         self._step_s = statistics.median(self._step_walls)
 
     def _record_decode_timing(self, batch, t_step: float, duration: float) -> None:
         """Per-lane queue/compute split for the handler's step_meta: queue is
         enqueue -> compute start, compute is the shared batched-step wall (the
-        lane rode the whole program). Runs on the compute thread; see _enq_t."""
+        lane rode the whole program). Runs on the compute thread (a whole
+        body's) or the event loop (``_step_home``), before the lanes' futures
+        resolve; see _enq_t."""
         variant = "paged" if self.page_size is not None else "dense"
         for lane, _h, _pos, _fut, _gen in batch:
             enq = self._enq_t.pop(lane, None)
@@ -2717,7 +3094,7 @@ class DecodeBatcher:
                 chunk, st.lane, st.position, n_total=st.n_total,
                 handles=self._handles, trim=False,
             )
-            out.copy_to_host_async()  # both queued behind the step, as _run_batch's
+            out.copy_to_host_async()  # both queued behind the step, as _launch_batch's
             chunk_out.copy_to_host_async()
             phases.enter("wait")
             host_out = np.asarray(out)  # device sync: the step has fully executed
@@ -2725,7 +3102,7 @@ class DecodeBatcher:
             phases.enter("post")
             with self._reset_lock:
                 if expected != self._generation:
-                    # see _run_batch: checked atomically with the swap so a reset
+                    # see _launch_batch: checked atomically with the swap so a reset
                     # landing mid-step leaves the freshly zeroed pool in place
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
                 self._update(k_pool, v_pool, *state)
@@ -2815,7 +3192,7 @@ class DecodeBatcher:
             phases.enter("post")
             with self._reset_lock:
                 if expected != self._generation:
-                    # see _run_batch: checked atomically with the swap so a reset
+                    # see _launch_batch: checked atomically with the swap so a reset
                     # landing mid-step leaves the freshly zeroed pool in place
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
                 self._update(k_pool, v_pool, *state)
@@ -2917,7 +3294,7 @@ class DecodeBatcher:
             phases.enter("post")
             with self._reset_lock:
                 if expected != self._generation:
-                    # see _run_batch: checked atomically with the swap so a reset
+                    # see _launch_batch: checked atomically with the swap so a reset
                     # landing mid-step leaves the freshly zeroed pool in place
                     raise AllocationFailed("Lane pool was reset while this batched step ran")
                 self._update(k_pool, v_pool)
@@ -2994,7 +3371,7 @@ class DecodeBatcher:
         swap runs under the reset lock: a reset landing mid-way would
         otherwise let the insert donate the freshly zeroed pool's buffers (or
         swap stale pre-reset buffers back in), breaking the 'reset leaves a
-        zeroed pool' invariant — the same TOCTOU _run_batch guards against.
+        zeroed pool' invariant — the same TOCTOU _launch_batch guards against.
         The lane check raises BEFORE any buffer is donated, so a failed
         insert leaves the new pool untouched."""
         k2, v2 = kv_lane

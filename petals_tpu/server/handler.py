@@ -156,7 +156,9 @@ class _StepSource:
         self._get("client", self._next_client)
         if self._push_queue is not None:
             self._get("push", self._push_queue.get)
-        if not any(task.done() for task in self._pending.values()):
+        # (while, not if: a getter's ``_wake`` runs a turn after the getter ended, and if the loop took that getter's
+        # item meanwhile and came round again without a suspension, that wake resolves THIS future with nothing done)
+        while not any(task.done() for task in self._pending.values()):
             loop = asyncio.get_running_loop()
             fut = self.parked = loop.create_future()
             self._timer = loop.call_later(self._timeout, _expire, fut, "No inference step within session_timeout")

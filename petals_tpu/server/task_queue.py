@@ -50,14 +50,23 @@ class PriorityTaskQueue:
         self._thread = threading.Thread(target=self._worker, name=f"ptu-{self.name}", daemon=True)
         self._thread.start()
 
-    async def submit(
-        self, fn: Callable[..., Any], *args, priority: float = PRIORITY_TRAINING, size: int = 0, **kwargs
-    ) -> Any:
-        """Run ``fn(*args, **kwargs)`` on the compute thread; lowest priority first."""
+    def put(self, run: Callable[[], Any], *, priority: float = PRIORITY_TRAINING, size: int = 0) -> None:
+        """Queue ``run()`` for the compute thread and return (any thread): nobody waits for it, so ``run`` delivers its own
+        result and failure. ``submit`` is this plus a future of the caller's loop."""
         if self.max_task_size is not None and size > self.max_task_size:
             raise TaskRejected(
                 f"Task of size {size} exceeds queue limit {self.max_task_size}"
             )
+        with self._cv:
+            if self._shutdown:
+                raise TaskRejected("Task queue is shut down")
+            heapq.heappush(self._heap, (priority, next(self._counter), run))
+            self._cv.notify()
+
+    async def submit(
+        self, fn: Callable[..., Any], *args, priority: float = PRIORITY_TRAINING, size: int = 0, **kwargs
+    ) -> Any:
+        """Run ``fn(*args, **kwargs)`` on the compute thread; lowest priority first."""
         loop = asyncio.get_running_loop()
         future: asyncio.Future = loop.create_future()
 
@@ -69,11 +78,7 @@ class PriorityTaskQueue:
             else:
                 loop.call_soon_threadsafe(_set_result, future, result)
 
-        with self._cv:
-            if self._shutdown:
-                raise TaskRejected("Task queue is shut down")
-            heapq.heappush(self._heap, (priority, next(self._counter), run))
-            self._cv.notify()
+        self.put(run, priority=priority, size=size)
         return await future
 
     def _worker(self) -> None:
@@ -84,7 +89,10 @@ class PriorityTaskQueue:
                 if self._shutdown and not self._heap:
                     return
                 _, _, run = heapq.heappop(self._heap)
-            run()
+            try:
+                run()
+            except Exception:  # a task that failed to deliver its own failure must not take the compute thread with it
+                logger.exception("A task of the %s queue raised", self.name)
 
     def shutdown(self) -> None:
         with self._cv:

@@ -26,7 +26,10 @@ after the process, so look for the line that holds these events): every
 batched step of the lane pool (``DecodeBatcher._run_batch``,
 ``_run_batch_mixed``, ``_run_batch_gen``, ``_run_batch_spec``: ``ptu.step``
 and its four phases, with ``variant``, ``lanes`` and ``prefill_tokens`` as
-arguments), the private, exclusive and dense-prefill inference paths
+arguments; a plain decode step of the paged pool is two ``ptu.step``s,
+``_launch_batch``'s with ``assemble`` and ``dispatch`` and
+``_finish_batch``'s with ``post``, and the wait for its rows is
+``ptu.readback`` on the readback thread's line), the private, exclusive and dense-prefill inference paths
 (``inference_step``), ``server_gen``, ``rpc_forward``, ``rpc_backward`` and
 ``rpc_probe``. The RPC-level ``inference_step`` span around ``batcher.step``
 and ``batcher.prefill_lane`` lives on the event loop and is not annotated
@@ -219,12 +222,17 @@ class step_phases:
     phase and opens the next; the exit closes whichever phase is running, so
     none stays open on a raise. ``started`` and ``ended`` are the readings
     that opened the first phase and closed the last, for a caller that tiles
-    the time around the step with the same clock."""
+    the time around the step with the same clock. A step whose body is split
+    where it blocks (``DecodeBatcher._launch_batch`` / ``_finish_batch``) walks
+    them in two runs on the same thread, ``assemble`` and ``dispatch`` in one
+    and, ``first="post"``, the last phase in another; what lies between is the
+    caller's to count."""
 
-    __slots__ = ("_stats", "_step", "_index", "_annotation", "_t0", "started", "ended")
+    __slots__ = ("_stats", "_step", "_first", "_index", "_annotation", "_t0", "started", "ended")
 
-    def __init__(self, stats: dict, **args):
+    def __init__(self, stats: dict, first: str = STEP_PHASES[0], **args):
         self._stats = stats
+        self._first = STEP_PHASES.index(first)
         self._step = device_annotation("ptu.step", **args)
 
     def _open(self, index: int, now: float) -> None:
@@ -242,7 +250,7 @@ class step_phases:
     def __enter__(self):
         self._step.__enter__()
         self.started = time.perf_counter()
-        self._open(0, self.started)
+        self._open(self._first, self.started)
         return self
 
     def enter(self, name: str) -> None:

@@ -15,7 +15,7 @@ from petals_tpu.data_structures import CHAIN_DELIMITER, make_uid
 from petals_tpu.rpc import RpcClient
 from petals_tpu.rpc.serialization import deserialize_array, serialize_array
 from petals_tpu.server.server import Server, default_dht_prefix
-from tests.utils import make_tiny_llama
+from tests.utils import make_tiny_llama, steps_booked
 
 
 @pytest.fixture(scope="module")
@@ -91,6 +91,8 @@ def test_batched_sessions_token_identical(model_path):
                 await asyncio.sleep(0.1)
                 barrier.set()
             results = await asyncio.gather(*tasks)
+            if server.handler.batcher:
+                await steps_booked(server.handler.batcher)
             stats = dict(server.handler.batcher.stats) if server.handler.batcher else {}
             return results, stats
         finally:
@@ -446,19 +448,17 @@ def test_pool_reset_after_consumed_buffers(model_path):
             cfg = server.cfg
 
             # simulate a device failure that consumed the donated buffers
-            orig_run = batcher._run_batch
-
-            def exploding_run(batch):
+            def exploding_step(*args, **kwargs):
                 k_pool, v_pool = batcher._buffers()
                 k_pool.delete()
                 v_pool.delete()
                 raise RuntimeError("simulated device failure mid-donation")
 
-            batcher._run_batch = exploding_run
+            batcher.backend.paged_decode_step = batcher.backend.batched_decode_step = exploding_step
             h = np.zeros((1, 1, cfg.hidden_size), np.float32)
             with pytest.raises(RuntimeError, match="simulated device failure"):
                 await batcher.step(lane, h, 0)
-            batcher._run_batch = orig_run
+            del batcher.backend.paged_decode_step, batcher.backend.batched_decode_step
 
             # the outstanding lane is invalidated...
             from petals_tpu.server.memory_cache import AllocationFailed

@@ -31,7 +31,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import counted, lane_pools, make_tiny_deepseek_v3, tiny_deepseek_v3_tensors, TINY_DEEPSEEK_V3
+from tests.utils import counted, lane_pools, make_tiny_deepseek_v3, steps_booked, tiny_deepseek_v3_tensors, TINY_DEEPSEEK_V3
 
 HF = dict(TINY_DEEPSEEK_V3)
 LAYERS, KINDS = HF["num_hidden_layers"], reference.layer_kinds(HF)
@@ -498,6 +498,7 @@ def test_prompt_in_mixed_steps_beside_two_decoding_lanes_of_other_lengths_then_d
                                             step(c, c_rows[:, pos_c + i : pos_c + i + 1]))
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
+            await steps_booked(batcher)
             now = batcher.stats
             delta = {key: now[key] - before[key] for key in LATENT_KEYS}
             decoded = (pos_b - 70) + (pos_c - 3) + 3 * 12

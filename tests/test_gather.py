@@ -27,7 +27,7 @@ from petals_tpu.server.batching import DecodeBatcher, _LaneReturn
 from petals_tpu.server.memory_cache import AllocationFailed
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_mixed_batching import _hidden, _tiny_backend
-from tests.utils import make_tiny_llama
+from tests.utils import make_tiny_llama, steps_booked
 
 pytestmark = pytest.mark.mixed
 
@@ -72,6 +72,7 @@ async def _rig(tiny, n_lanes, step_s):
         await batcher.prefill_lane(warm, _hidden(cfg, 1, 5), 0)
         await batcher.step(warm, _hidden(cfg, 2), 5)
         batcher.release_lane(warm)
+        await steps_booked(batcher)  # the warm-up's counters are all in before a test copies them
         backend.paged_decode_step = slowed("decode", backend.paged_decode_step)
         backend.paged_mixed_step = slowed("mixed", backend.paged_mixed_step)
         yield rig
@@ -219,6 +220,7 @@ def test_lanes_that_return_fast_settle_into_one_step_a_round(tiny, k):
                 _client(rig, lane, rounds, 0.002 + 0.0005 * i, start_after=0.03 * i)
                 for i, lane in enumerate(lanes)
             ))
+            await steps_booked(batcher)
             stats = batcher.stats
             assert stats["batched_tokens"] == k * rounds + 1  # + the warm-up's token
             # from the first step that carried all K to the one in which the

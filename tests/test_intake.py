@@ -549,3 +549,34 @@ def test_a_step_the_sink_refuses_fails_its_session_as_the_loop_would_and_no_othe
             await server.shutdown()
 
     run(main())
+
+
+def test_a_getter_s_late_wake_parks_the_loop_again():
+    """A getter's ``_wake`` runs a turn after the getter ended. A loop that took
+    the getter's item in the turn it ended and came round again without a
+    suspension (a step it had seen already, over the push plane and the stream)
+    is parked on a new future when that wake comes: it parks again, and the
+    next item is handed over when it is there."""
+    from petals_tpu.server.handler import _StepSource
+
+    async def main():
+        items = asyncio.Queue()
+
+        class Requests:
+            def __aiter__(self):
+                return self
+
+            async def __anext__(self):
+                return await items.get()
+
+        source = _StepSource(Requests(), None, 30.0)
+        items.put_nowait("one")
+        source._get("client", source._next_client)
+        await asyncio.sleep(0)  # the getter ends in this turn, ahead of this task; its wake is left for the next
+        assert await source.next() == ("one", None)
+        asyncio.get_running_loop().call_later(0.01, items.put_nowait, "two")
+        assert await source.next() == ("two", None)  # parked before the stale wake ran
+        await source.cleanup()
+
+    run(main())
+

@@ -30,7 +30,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import jamba_layer_types, lane_pools, make_tiny_jamba, tiny_jamba_tensors, TINY_JAMBA
+from tests.utils import jamba_layer_types, lane_pools, make_tiny_jamba, steps_booked, tiny_jamba_tensors, TINY_JAMBA
 
 HF = dict(TINY_JAMBA)
 MAMBA, ATTENTION = "mamba", "attention"
@@ -367,6 +367,7 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_match
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
             decoded = (len(got_b) - 1) + (len(got_c) - 1) + 12  # B's and C's replies but their prompts', and A's 12
+            await steps_booked(batcher)
             assert batcher.stats["linattn_recurrent_tokens"] - before["linattn_recurrent_tokens"] == decoded * 2
             assert batcher.stats["linattn_kernel_tokens"] == 0  # the plain form: no kernel takes this state
             assert batcher.stats["state_bytes_held"] > before["state_bytes_held"] and batcher.stats["kv_bytes_held"] > before["kv_bytes_held"]

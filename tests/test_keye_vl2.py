@@ -30,7 +30,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import lane_pools, make_tiny_keye_vl2, tiny_keye_vl2_tensors, TINY_KEYE_VL2
+from tests.utils import lane_pools, make_tiny_keye_vl2, steps_booked, tiny_keye_vl2_tensors, TINY_KEYE_VL2
 
 HF = dict(TINY_KEYE_VL2)
 LAYERS, TOPK = HF["num_hidden_layers"], HF["sa_config"]["topk"]
@@ -416,6 +416,7 @@ def test_prompt_in_mixed_steps_beside_two_decoding_lanes_of_other_lengths_then_d
                                             step(c, c_rows[:, pos_c + i : pos_c + i + 1]))
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
+            await steps_booked(batcher)
             now = batcher.stats
             fed = 100 + (pos_b - 70) + (pos_c - 3) + 3 * 12  # rows through the span since ``before``
             assert (now["sparse_rows_selected"] - before["sparse_rows_selected"]) + (now["sparse_rows_dense"] - before["sparse_rows_dense"]) == fed * LAYERS

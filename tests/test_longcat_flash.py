@@ -33,7 +33,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import counted, lane_pools, make_tiny_longcat_flash, tiny_longcat_flash_tensors, TINY_LONGCAT_FLASH
+from tests.utils import counted, lane_pools, make_tiny_longcat_flash, steps_booked, tiny_longcat_flash_tensors, TINY_LONGCAT_FLASH
 
 HF = dict(TINY_LONGCAT_FLASH)
 BLOCKS, SUBLAYERS = HF["num_layers"], 2
@@ -481,6 +481,7 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_and_a
                                             step(c, c_rows[:, pos_c + i : pos_c + i + 1]))
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
+            await steps_booked(batcher)
             now = batcher.stats
             delta = {key: now[key] - before[key] for key in LATENT_KEYS}
             decoded, layers = (pos_b - 70) + (pos_c - 3) + 3 * 12, BLOCKS * SUBLAYERS

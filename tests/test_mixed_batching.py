@@ -23,7 +23,7 @@ from petals_tpu.server.batching import DecodeBatcher
 from petals_tpu.server.memory_cache import AllocationFailed, MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
-from tests.utils import make_tiny_llama
+from tests.utils import make_tiny_llama, steps_booked
 
 pytestmark = pytest.mark.mixed
 
@@ -535,17 +535,22 @@ def test_phase_counters_sum_to_the_step_walls(model_path):
             a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
             await batcher.prefill_lane(a, _hidden(cfg, 1, 5), 0)  # compiles the mixed step
             await batcher.step(a, _hidden(cfg, 2), 5)  # ...and the decode step
+            await steps_booked(batcher)
             before = dict(batcher.stats)
             walls = 0.0
             for i in range(6):
                 await batcher.step(a, _hidden(cfg, 10 + i), 6 + i)
                 walls += batcher.pop_step_timing(a)["compute_s"]
+                await steps_booked(batcher)  # or the next launch could come before this step's post
+            behind_the_walls = batcher.stats["post_s"] - before["post_s"]
             await batcher.prefill_lane(b, _hidden(cfg, 3, 5), 0)
             walls += batcher.pop_step_timing(b)["compute_s"]
             delta = {k: batcher.stats[k] - before[k] for k in batcher.stats}
             assert delta["batched_steps"] == 7 and delta["mixed_steps"] == 1
             assert all(delta[k] > 0 for k in PHASE_KEYS), delta
-            phases = sum(delta[k] for k in PHASE_KEYS)
+            # a launched step's wall ends with its rows on the host, where its wait ends too (the readback's one
+            # reading): the six decode steps' post is booked behind their walls, the mixed step's is inside its own
+            phases = sum(delta[k] for k in PHASE_KEYS) - behind_the_walls
             # measured here: 12.60 ms of phases on 12.52 ms of walls
             assert 0.98 * walls <= phases <= 1.1 * walls + 2e-3, (phases, walls, delta)
         finally:
@@ -608,11 +613,15 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
             batcher.release_lane(a)
             batcher.release_lane(b)
             a, b = await batcher.acquire_lane(), await batcher.acquire_lane()
-            spawns, waits = batcher._flush_spawns, batcher.stats["gather_waits"]
+            await steps_booked(batcher)
+            spawns, waits, behind = batcher._flush_spawns, batcher.stats["gather_waits"], batcher.stats["overlapped_steps"]
             await asyncio.gather(*await step_behind_the_one_in_flight(batcher.step(a, _hidden(cfg, 20), 0), b, _hidden(cfg, 30), 0))
+            await steps_booked(batcher)
             assert batcher._flush_spawns == spawns + 1  # the second step followed the first under one task
+            # ... after a hand-off and not behind it: a's step was held in its launch, and nothing is started behind a
+            # step whose launch has not returned
             handed_off = batcher.stats["turnaround_s"] - before
-            assert handed_off > 0 and batcher.stats["gather_waits"] == waits
+            assert handed_off > 0 and batcher.stats["overlapped_steps"] == behind and batcher.stats["gather_waits"] == waits
 
             # now lane a comes back 50 ms after its replies, on a step of 200 ms:
             # lane b falls in behind a's step again, and this time the gather
@@ -621,12 +630,14 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
             for i in range(5):  # S, the rule's step, is the median of the last nine walls
                 await batcher.step(a, _hidden(cfg, 40 + i), 1 + i)
                 await asyncio.sleep(0.05)
+            await steps_booked(batcher)
             spawns, before = batcher._flush_spawns, dict(batcher.stats)
             ahead, behind = await step_behind_the_one_in_flight(batcher.step(a, _hidden(cfg, 50), 6), b, _hidden(cfg, 51), 1)
             await ahead
             await asyncio.sleep(0.05)
             await asyncio.gather(behind, batcher.step(a, _hidden(cfg, 52), 7))
             del backend.paged_decode_step
+            await steps_booked(batcher)
             assert batcher._flush_spawns == spawns + 1
             delta = {k: batcher.stats[k] - before[k] for k in before}
             assert delta["batched_steps"] == 2 and delta["batched_tokens"] == 3, delta
@@ -644,7 +655,9 @@ def test_turnaround_counts_only_inside_one_flush_task(model_path):
 def test_step_emits_its_phases_in_order(model_path, monkeypatch, variant):
     """With TraceAnnotation replaced by a recorder, a step is one ``ptu.step``
     (carrying variant, lanes and prefill tokens) around assemble, dispatch,
-    wait and post, each opened once and closed before the next."""
+    wait and post, each opened once and closed before the next; a plain
+    decode step of the paged pool is two, its launch and its bookkeeping, and
+    the wait between them is no run of the compute thread's."""
     from tests.utils import record_step_annotations, recorded_steps
 
     backend, cfg = _tiny_backend(model_path)
@@ -660,6 +673,7 @@ def test_step_emits_its_phases_in_order(model_path, monkeypatch, variant):
                 await batcher.prefill_lane(lane, _hidden(cfg, 1, 5), 0)
             else:
                 await batcher.step(lane, _hidden(cfg, 1), 0)
+            await steps_booked(batcher)
         finally:
             await batcher.close()
             queue.shutdown()
@@ -667,13 +681,15 @@ def test_step_emits_its_phases_in_order(model_path, monkeypatch, variant):
     run(main())
     steps = recorded_steps(events)
     want = {"variant": variant, "lanes": 0 if variant == "mixed" else 1, "prefill_tokens": 5 if variant == "mixed" else 0}
-    assert steps == [(want, PHASE_NAMES)], steps
+    runs = [PHASE_NAMES[:2], PHASE_NAMES[3:]] if variant == "paged" else [PHASE_NAMES]
+    assert steps == [(want, names) for names in runs], steps
 
 
 def test_phases_close_when_the_body_raises(model_path, monkeypatch):
-    """A pool reset that lands between the two generation guards makes the
-    body raise in ``post``: every phase was opened once, none stays open, the
-    time is counted and the step is not."""
+    """A pool reset that lands between the launch's two generation guards
+    makes it raise in ``dispatch``: both phases were opened once, neither
+    stays open, the time is counted and the step is not, and no bookkeeping
+    follows a step that was never in flight."""
     from tests.utils import record_step_annotations, recorded_steps
 
     backend, cfg = _tiny_backend(model_path)
@@ -695,14 +711,16 @@ def test_phases_close_when_the_body_raises(model_path, monkeypatch):
             with pytest.raises(AllocationFailed, match="reset while this batched step ran"):
                 await batcher.step(lane, _hidden(cfg, 1), 0)
             del backend.paged_decode_step
-            assert batcher.stats["batched_steps"] == 0
-            assert all(batcher.stats[k] > 0 for k in PHASE_KEYS), batcher.stats
+            await steps_booked(batcher)
+            assert batcher.stats["batched_steps"] == 0 and not batcher._aloft and not batcher._flights
+            assert all(batcher.stats[k] > 0 for k in PHASE_KEYS[:2]), batcher.stats
+            assert all(batcher.stats[k] == 0 for k in PHASE_KEYS[2:]), batcher.stats
         finally:
             await batcher.close()
             queue.shutdown()
 
     run(main())
-    assert [names for _args, names in recorded_steps(events)] == [PHASE_NAMES]
+    assert [names for _args, names in recorded_steps(events)] == [PHASE_NAMES[:2]]
 
 
 def test_phase_order_is_enforced():

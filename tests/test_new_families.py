@@ -206,7 +206,7 @@ def test_buffers_and_frames_are_sized_by_the_family_s_stream_width(family_block,
         finally:
             await batcher.close()
 
-    assert asyncio.run(opened()) == (2, width + 1)
+    assert asyncio.run(opened()) == (2, 2, width + 1)  # two buffers, filled in turn (a launch beside a step in flight)
 
 
 @pytest.mark.parametrize("maker", [make_tiny_qwen2, make_tiny_mistral])

@@ -29,7 +29,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import lane_pools, make_tiny_falcon, make_tiny_olmo_hybrid, tiny_olmo_hybrid_tensors, TINY_OLMO_HYBRID
+from tests.utils import lane_pools, make_tiny_falcon, make_tiny_olmo_hybrid, steps_booked, tiny_olmo_hybrid_tensors, TINY_OLMO_HYBRID
 
 HF = dict(TINY_OLMO_HYBRID)
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -288,6 +288,7 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_match
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
             decoded = (len(got_b) - 1) + (len(got_c) - 1) + 12  # B's and C's replies but their prompts', and A's 12
+            await steps_booked(batcher)
             assert batcher.stats["linattn_recurrent_tokens"] - before["linattn_recurrent_tokens"] == decoded * 6
             # of them, those whose state the kernel moved where it lies: all on a TPU, none on the default CPU path
             assert batcher.stats["linattn_kernel_tokens"] == (batcher.stats["linattn_recurrent_tokens"] if state_step == "kernel" else 0)
@@ -625,10 +626,11 @@ def test_a_falcon_span_s_pools_programs_and_stats_are_what_they_were(tmp_path):
     # ... and since PR 54 the event loop's turns (utils/asyncio_utils.install_turn_clock)
     # ... and since PR 59 every batcher the bytes of hidden state its steps took in and handed back (_count_stream)
     # ... and since PR 60 the decode steps by the way their request came in (begin_step / step)
+    # ... and since PR 66 the decode steps launched while the step before was in flight (_start_behind)
     assert set(batcher.stats) == STATS_BEFORE | {"attn_pages_gathered", "attn_pages_tabled", "attn_pages_kernel", "tables_sent",
                                                  "loop_busy_s", "loop_busy_sq", "loop_turns", "stream_bytes_in",
                                                  "stream_bytes_out", "rpc_intake_direct",
-                                                 "rpc_intake_queued"} and len(batcher.backend.cache.lane_state) == 0 and batcher._state() == ()
+                                                 "rpc_intake_queued", "overlapped_steps"} and len(batcher.backend.cache.lane_state) == 0 and batcher._state() == ()
     assert not {"state_bytes_per_lane", "state_bytes_held"} & set(batcher.occupancy_info())
     # the step programs take the pair of pools and give the pair back, and carry what they carried
     k, v = (jnp.zeros(d.shape, d.dtype) for d in lane_pools(backend, 6, 8, end=2)[0])

@@ -32,7 +32,7 @@ from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from petals_tpu.server.task_queue import PriorityTaskQueue
 from tests.test_full_model import SwarmHarness
-from tests.utils import lane_pools, make_tiny_qwen3_next, qwen3_next_layer_types, tiny_qwen3_next_tensors, TINY_QWEN3_NEXT
+from tests.utils import lane_pools, make_tiny_qwen3_next, qwen3_next_layer_types, steps_booked, tiny_qwen3_next_tensors, TINY_QWEN3_NEXT
 
 HF = dict(TINY_QWEN3_NEXT)
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -375,6 +375,7 @@ def test_prompt_in_three_mixed_steps_beside_two_decoding_lanes_then_decode_match
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
             decoded = (len(got_b) - 1) + (len(got_c) - 1) + 12  # B's and C's replies but their prompts', and A's 12
+            await steps_booked(batcher)
             assert batcher.stats["linattn_recurrent_tokens"] - before["linattn_recurrent_tokens"] == decoded * 6
             # of them, those whose state the kernel moved where it lies: all on a TPU, none on the default CPU path
             assert batcher.stats["linattn_kernel_tokens"] == (batcher.stats["linattn_recurrent_tokens"] if state_step == "kernel" else 0)

@@ -23,7 +23,7 @@ from petals_tpu.server.task_queue import PriorityTaskQueue
 from petals_tpu.utils.tracing import STEP_PHASES
 from tests.test_gather import _client, _rig
 from tests.test_mixed_batching import _hidden, _tiny_backend
-from tests.utils import make_tiny_llama
+from tests.utils import make_tiny_llama, steps_booked
 
 pytestmark = pytest.mark.mixed
 
@@ -111,6 +111,7 @@ def test_a_decode_token_s_round_trip_station_by_station(path):
             t0 = time.perf_counter()
             await asyncio.gather(*(decode(stream, data) for stream, data in zip(streams, rows)))
             wall = time.perf_counter() - t0
+            await steps_booked(batcher)
             stats = dict(batcher.stats)
             # every decode reply is counted, and a lane's return after each but a session's last
             assert stats["decode_replies"] == 2 * n and stats["lane_returns"] == 2 * n - 2
@@ -212,6 +213,7 @@ def test_what_the_compute_thread_waited_for(tiny):
             assert first["no_demand_s"] > before["no_demand_s"] and first["lanes_out_s"] == before["lanes_out_s"]
             rounds, away = 8, 0.008
             await _client(rig, lane, rounds, away, pos0=5)
+            await steps_booked(batcher)
             after = dict(batcher.stats)
             out = after["lanes_out_s"] - first["lanes_out_s"]
             assert (rounds - 1) * away <= out <= rounds * (away + 0.004)  # every return but the first follows a reply
@@ -225,7 +227,7 @@ def test_what_the_compute_thread_waited_for(tiny):
             await asyncio.sleep(0.06)
             lane = await batcher.acquire_lane()
             await batcher.prefill_lane(lane, _hidden(rig.cfg, 3, 5), 0)
-            last = dict(batcher.stats)
+            last = dict(batcher.stats)  # (a prompt's chunk rides a whole body: booked when it returns)
             assert last["no_demand_s"] - after["no_demand_s"] >= 0.055  # the released lane's reply is out no more
             assert last["lanes_out_s"] - after["lanes_out_s"] < 0.02  # the last reply's 8 ms at most
             assert last["handoff_s"] > before["handoff_s"]

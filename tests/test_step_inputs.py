@@ -32,6 +32,7 @@ from tests.utils import (
     make_tiny_keye_vl2,
     make_tiny_llama,
     make_tiny_olmo_hybrid,
+    steps_booked,
     make_tiny_qwen3_next,
 )
 
@@ -208,7 +209,7 @@ def test_device_tables_follow_every_writer_and_rest_between_writes(tmp_path_fact
             await rig.step(b, rows(backend, 20), 0, 1, "another session's first page")
             await rig.step(a, rows(backend, 21), 10, 0, "two sessions, no write")
             batcher.release_lane(b)
-            assert not batcher._lanes_rows[b].any()  # its row went with it
+            assert not batcher._lanes_rows[:, b].any()  # its row went with it, in both buffers
             await rig.step(a, rows(backend, 22), 11, 1, "a session ended")
 
             # swap out and in: lane a's pages go to the host and come back
@@ -233,6 +234,7 @@ def test_device_tables_follow_every_writer_and_rest_between_writes(tmp_path_fact
             batcher.unpin_pages(pinned, batcher.page_epoch)
 
             # a failed donating step: the pool is reset and the device's copy of the tables goes with it
+            await steps_booked(batcher)
             steps = batcher.stats["batched_steps"]
             batcher._buffers()[0].delete()
             batcher._maybe_reset_pool()
@@ -242,6 +244,7 @@ def test_device_tables_follow_every_writer_and_rest_between_writes(tmp_path_fact
             e = await batcher.acquire_lane()
             await rig.step(e, rows(backend, 30), 0, 1, "the pool was reset")
             await rig.step(e, rows(backend, 31), 1, 0, "the step after the reset")
+            await steps_booked(batcher)
             info = batcher.occupancy_info()
             assert (info["tables_sent"], info["batched_steps"]) == (rig.sent, steps + 2)
         finally:
@@ -296,13 +299,14 @@ def test_an_idle_lanes_stale_row_does_not_reach_a_live_lanes_output(tmp_path_fac
             poison = rows(backend, 2)
             poison[0, 0, ::2], poison[0, 0, 1::2] = np.nan, 1e30
             await batcher.step(a, poison, 0)
-            assert np.isnan(batcher._lanes_rows[a]).any()
+            assert np.isnan(batcher._lanes_rows[:, a]).any()
             for pos in (1, 2):
                 got = await rig.step(b, rows(backend, 2 + pos), pos, 0, "beside a stale row of NaN")
                 assert np.isfinite(got).all()
-            assert np.isnan(batcher._lanes_rows[a]).any() and batcher._lanes_in[a, -1] == batcher.max_length
+            # (the lanes' two buffers are filled in turn: the stale row rode one of the two steps, the zeros it started as the other)
+            assert np.isnan(batcher._lanes_rows[:, a]).any() and (batcher._lanes_in[:, a, -1] == batcher.max_length).all()
             batcher.release_lane(a)
-            assert not batcher._lanes_rows[a].any()
+            assert not batcher._lanes_rows[:, a].any()
         finally:
             await rig.close()
 

@@ -9,6 +9,8 @@ import time
 import numpy as np
 import pytest
 
+from tests.utils import steps_booked
+
 from petals_tpu.data_structures import PeerID, RemoteModuleInfo, ServerInfo, ServerState
 from petals_tpu.server.block_selection import (
     choose_best_start,
@@ -438,6 +440,7 @@ def test_span_reload_pooled_decode_uses_new_weights(tmp_path):
             await s.end()
             # the session must have used the POOL (the regression's subject)
             assert server.handler.batcher is not None
+            await steps_booked(server.handler.batcher)
             assert server.handler.batcher.stats["batched_tokens"] >= 1
 
             # ground truth: the moved span's blocks, fresh

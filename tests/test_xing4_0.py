@@ -32,7 +32,7 @@ from petals_tpu.server.from_pretrained import get_block_config, load_block_param
 from petals_tpu.server.memory_cache import MemoryCache
 from petals_tpu.server.server import Server, default_dht_prefix
 from tests.test_full_model import SwarmHarness
-from tests.utils import TINY_XING4_0, make_tiny_deepseek_v3, make_tiny_xing4_0, tiny_xing4_0_tensors
+from tests.utils import TINY_XING4_0, make_tiny_deepseek_v3, make_tiny_xing4_0, steps_booked, tiny_xing4_0_tensors
 
 HF = dict(TINY_XING4_0)
 LAYERS, KINDS = HF["num_hidden_layers"], reference.layer_kinds(HF)
@@ -404,6 +404,7 @@ def test_prompt_in_mixed_steps_beside_two_decoding_lanes_then_decode_matches_the
                 for got, out in zip((got_a, got_b, got_c), outs):
                     got.append(out)
             stepped = (pos_b - 70) + (pos_c - 3) + 3 * 12 + 100  # decode rows and the prompt's
+            await steps_booked(batcher)
             delta = {key: batcher.stats[key] - before[key] for key in ("hc_rows", "stream_bytes_in", "stream_bytes_out", "batched_tokens", "prefill_tokens")}
             assert delta["batched_tokens"] + delta["prefill_tokens"] == stepped
             assert delta["hc_rows"] == stepped * 2 * LAYERS and delta["stream_bytes_in"] == delta["stream_bytes_out"] == stepped * WIDTH * 4

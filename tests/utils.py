@@ -8,6 +8,7 @@ of module fixtures. The first build lands in a shared per-run cache dir and
 later requests copy the saved files into the caller's tmpdir (~ms) — callers
 still own a private, mutable checkpoint (several tests edit theirs)."""
 
+import asyncio
 import functools
 import os
 import shutil
@@ -583,6 +584,20 @@ async def drive_coalescing_sessions(
         return elapsed, await c.call("ptu.info", {}, timeout=30)
     finally:
         await c.close()
+
+
+async def steps_booked(batcher) -> None:
+    """Wait until the compute thread has booked every decode step started so
+    far: a launched step's counters move after its replies
+    (``DecodeBatcher._finish_batch``, queued by ``_book`` in the loop's turn
+    behind the replies'), so a test that reads ``batcher.stats`` behind an
+    awaited step lets that turn come and the compute queue run empty."""
+    from petals_tpu.server.task_queue import PRIORITY_BARRIER
+
+    while batcher._flights:
+        await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    await batcher.queue.submit(lambda: None, priority=PRIORITY_BARRIER)
 
 
 def record_step_annotations(monkeypatch) -> list:
